@@ -12,13 +12,7 @@ from __future__ import annotations
 __version__ = "0.1.0"
 __git_branch__ = "main"
 
-# before any submodule import: modules reference jax.shard_map at call time,
-# and users' own code may too, as soon as deepspeed_tpu is imported
-from deepspeed_tpu.utils.compat import install_jax_compat  # noqa: E402
-
-install_jax_compat()
-
-from deepspeed_tpu import comm  # noqa: F401,E402
+from deepspeed_tpu import comm  # noqa: F401
 from deepspeed_tpu.runtime import zero  # noqa: F401
 from deepspeed_tpu.accelerator import get_accelerator  # noqa: F401
 from deepspeed_tpu.runtime.config import DeepSpeedConfig  # noqa: F401

@@ -21,10 +21,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         self._platform = platform
 
     def _devices(self):
-        try:
-            return jax.devices(self._platform)
-        except RuntimeError:
-            return jax.devices()
+        return jax.devices(self._platform)
 
     def device_name(self, device_index: Optional[int] = None) -> str:
         if device_index is None:

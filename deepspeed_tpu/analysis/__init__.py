@@ -1,7 +1,6 @@
 """dslint: AST-level invariant checker for this repo's incident-derived
 correctness rules (see docs/LINT.md for the catalogue):
 
-- DSL001 donation safety (raw device_put vs donate_argnums callees)
 - DSL002 sync-free hot paths (no hidden device syncs in step/decode/drain
   loops or disabled-telemetry branches)
 - DSL003 jax-free operator tools (whole import-graph closure)
@@ -20,8 +19,7 @@ package's own DSL003 closure check keeps it that way).  Run via::
 
 from .engine import (Finding, META_RULE, Project, RULES, Rule,  # noqa: F401
                      rule_ids, run_paths)
-from . import dsl001_donation  # noqa: F401  (registration side effect)
-from . import dsl002_sync  # noqa: F401
+from . import dsl002_sync  # noqa: F401  (registration side effect)
 from . import dsl003_jaxfree  # noqa: F401
 from . import dsl004_metrics  # noqa: F401
 from . import dsl005_scope  # noqa: F401

@@ -9,7 +9,7 @@ regression rule DSL003 exists to catch.
 
 Suppression syntax (checked, not free-form):
 
-    x = risky()  # dslint: disable=DSL001 -- <why this site is safe>
+    x = risky()  # dslint: disable=DSL002 -- <why this site is safe>
     # dslint: disable-file=DSL004 -- <why this whole file is exempt>
 
 A ``disable`` without the `` -- reason`` tail, or naming an unknown rule,

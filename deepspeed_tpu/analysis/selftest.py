@@ -11,15 +11,13 @@ import os
 import tempfile
 from typing import Dict, List, Tuple
 
-from . import (dsl001_donation, dsl002_sync, dsl003_jaxfree, dsl004_metrics,
-               dsl005_scope, dsl006_shared)
+from . import (dsl002_sync, dsl003_jaxfree, dsl004_metrics, dsl005_scope,
+               dsl006_shared)
 from .engine import META_RULE, run_paths
 
 # (rule id, bad source, good source, in-tree filename) — file-level rules
 # (DSL005 is scoped to comm/ directories, so its fixture lives there)
 _FILE_CASES = [
-    ("DSL001", dsl001_donation.SELFTEST_BAD, dsl001_donation.SELFTEST_GOOD,
-     "case.py"),
     ("DSL002", dsl002_sync.SELFTEST_BAD, dsl002_sync.SELFTEST_GOOD,
      "case.py"),
     ("DSL004", dsl004_metrics.SELFTEST_BAD, dsl004_metrics.SELFTEST_GOOD,

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.models.layers import norm, rope_dim
 from deepspeed_tpu.ops.pallas import rope_angles
 from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
-                                             fused_norm_qkv, fused_proj_norm)
+                                             fused_norm_qkv, fused_proj_norm,
+                                             paged_kv_append)
 
 
 def supports_fused_decode(cfg, *, quantized_kv: bool = False,
@@ -190,12 +191,9 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             # paged append: row b writes at row pos[b] % page of physical
             # page page_table[b, pos[b] // page] (parked rows' tables
             # point at the junk page 0 — their writes land where no live
-            # slot reads); same one-batched-scatter aliasing argument
-            page = kc_all.shape[3]
-            pp = page_table[jnp.arange(B), pos // page]
-            po = pos % page
-            kc_all = kc_all.at[l, pp, :, po, :].set(k.astype(kc_all.dtype))
-            vc_all = vc_all.at[l, pp, :, po, :].set(v.astype(vc_all.dtype))
+            # slot reads)
+            kc_all, vc_all = paged_kv_append(kc_all, vc_all, k, v, pos,
+                                             page_table, layer=l, impl=impl)
         elif per_row:
             # per-slot append: row b writes at its own depth pos[b], as ONE
             # batched scatter.  Measured (CPU, 16-step scan, donated

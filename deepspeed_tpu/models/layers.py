@@ -30,41 +30,27 @@ from deepspeed_tpu.comm.mesh import axis_size, data_axes
 from deepspeed_tpu.ops.pallas import (apply_rotary_pos_emb, flash_attention,
                                       layer_norm, mha_reference, rms_norm,
                                       rope_angles)
-from deepspeed_tpu.ops.pallas.common import resolve_impl
+from deepspeed_tpu.ops.pallas.common import kernel_or_reference, resolve_impl
 
 
-def constrain(x, mesh: Optional[Mesh], *spec):
-    """Pin activation sharding; no-op without a mesh.
+def _mesh_spec(x, mesh: Optional[Mesh], *spec) -> Optional[P]:
+    """The part of the logical ``spec`` that ``mesh`` can apply to ``x``, or
+    None when there is nothing to shard over.
 
     Axis names absent from ``mesh`` are dropped, so the built-in models'
-    (dp/fsdp/tp/sp/ep) constraints degrade gracefully on custom meshes.
-    Axes that are MANUAL in the current trace context (the model running
-    inside a shard_map region, e.g. the ZeRO++ or 1-bit paths) are dropped
-    too — with_sharding_constraint rejects manual axes, and the data is
-    already device-local there.
+    (dp/fsdp/tp/sp/ep) layouts degrade gracefully on custom meshes.  Axes
+    that are MANUAL in the current trace context (the model running inside
+    a shard_map region, e.g. the ZeRO++ or 1-bit paths) are dropped too —
+    the data is already device-local there.  An entry whose dim does not
+    divide across its axes is dropped whole (e.g. batch-1 serving on a
+    multi-chip data mesh).
     """
     if mesh is None or mesh.empty:
-        return x
-    # jax < 0.4.36 has no jax.sharding.get_abstract_mesh; fall back to the
-    # private accessor, else assume no manual axes (pre-shard_map-manual jax)
-    get_am = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_am is None:
-        from jax._src import mesh as _mesh_lib
-        get_am = getattr(_mesh_lib, "get_abstract_mesh", None)
-    am = get_am() if get_am is not None else None
-    manual = set(getattr(am, "manual_axes", ()) or ())
-    # jax 0.4.x experimental shard_map does not surface its manual axes on
-    # the abstract mesh; inside the region they ARE bound named axes, so
-    # the trace-time axis env names them (observed: the overlap schedule's
-    # full-manual train step tracing the model's constrain calls)
-    try:
-        from jax._src import core as _jcore
-        manual |= set(getattr(_jcore.get_axis_env(), "axis_sizes", {}))
-    except Exception:
-        pass
+        return None
+    manual = set(jax.sharding.get_abstract_mesh().manual_axes)
     names = set(mesh.axis_names) - manual
     if not names:
-        return x  # fully-manual region: nothing left to constrain
+        return None  # fully-manual region
 
     def keep(entry, dim_size):
         if entry is None:
@@ -73,8 +59,6 @@ def constrain(x, mesh: Optional[Mesh], *spec):
             kept = tuple(a for a in entry if a in names)
         else:
             kept = (entry,) if entry in names else ()
-        # drop the whole entry if the dim doesn't divide across it (e.g.
-        # batch-1 serving on a multi-chip data mesh)
         total = 1
         for a in kept:
             total *= axis_size(mesh, a)
@@ -82,15 +66,41 @@ def constrain(x, mesh: Optional[Mesh], *spec):
             return None
         return kept if len(kept) > 1 else kept[0]
 
-    entries = tuple(keep(e, d) for e, d in zip(spec, x.shape))
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, P(*entries)))
+    return P(*(keep(e, d) for e, d in zip(spec, x.shape)))
 
 
-def norm(x, params, kind: str, eps: float):
-    """Dispatch to the fused Pallas norm kernels (csrc layer_norm/rms_norm)."""
+def constrain(x, mesh: Optional[Mesh], *spec):
+    """Pin activation sharding to what :func:`_mesh_spec` keeps of ``spec``;
+    no-op without a mesh or inside a fully-manual region
+    (with_sharding_constraint rejects manual axes)."""
+    pspec = _mesh_spec(x, mesh, *spec)
+    if pspec is None:
+        return x
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, pspec))
+
+
+def norm(x, params, kind: str, eps: float, mesh: Optional[Mesh] = None):
+    """Dispatch to the fused Pallas norm kernels (csrc layer_norm/rms_norm).
+
+    GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so under a multi-device ``mesh`` the kernel runs per shard
+    of the activation layout (batch over the data axes, sequence over
+    ``sp``): rows are independent, and the scale/bias arrive whole."""
     if kind == "rmsnorm":
-        return rms_norm(x, params["scale"], eps=eps)
-    return layer_norm(x, params["scale"], params["bias"], eps=eps)
+        fn = functools.partial(rms_norm, eps=eps)
+        weights = (params["scale"],)
+    else:
+        fn = functools.partial(layer_norm, eps=eps)
+        weights = (params["scale"], params["bias"])
+    if mesh is None or mesh.empty or mesh.size == 1 \
+            or resolve_impl(None) == "xla":
+        return fn(x, *weights)
+    rows = (("dp", "fsdp", "ep"), "sp")[:x.ndim - 1]
+    spec = _mesh_spec(x, mesh, *rows, *((None,) * (x.ndim - len(rows))))
+    if spec is None:
+        return fn(x, *weights)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) + (P(),) * len(weights),
+                         out_specs=spec, check_vma=False)(x, *weights)
 
 
 def activation_fn(name: str):
@@ -135,6 +145,21 @@ def alibi_bias(num_heads: int, q_pos, k_pos) -> jnp.ndarray:
     return slopes[:, None, None] * rel[None]
 
 
+def flash_reference_reason(b: int, h: int, s: int, nb: int = 1, ntp: int = 1,
+                           nsp: int = 1) -> Optional[str]:
+    """Why training attention on [b, h, s, Dh] cannot take the flash kernel
+    under a mesh with ``nb`` data shards, ``ntp`` tensor shards and ``nsp``
+    sequence shards (None = it can)."""
+    if s % 128:
+        return f"sequence length {s} is not a multiple of the 128-lane tile"
+    if b % nb or h % ntp:
+        return (f"batch {b} / heads {h} do not divide over {nb} data / "
+                f"{ntp} tp shards")
+    if nsp > 1:
+        return f"sequence length {s} does not divide over {nsp} sp shards"
+    return None
+
+
 def attention_core(q, k, v, mesh: Optional[Mesh], causal: bool = True,
                    impl: Optional[str] = None, sp_mode: str = "auto",
                    alibi: bool = False, ring_q: bool = False,
@@ -147,8 +172,10 @@ def attention_core(q, k, v, mesh: Optional[Mesh], causal: bool = True,
     - sp > 1 otherwise (or ``sp_mode="ring"``) → **ring attention**: KV
       rotation via ppermute, O(S/P) memory.
     - sp == 1 on TPU with a compatible layout → flash kernel under shard_map
-      (batch over data axes, heads over ``tp``).
-    - anything else → jnp reference under plain GSPMD.
+      (batch over data axes, heads over ``tp``), or called directly when
+      there is no mesh.
+    - what :func:`flash_reference_reason` names → jnp reference under plain
+      GSPMD, logged.
     """
     impl = resolve_impl(impl)
     b, h, s, d = q.shape
@@ -160,7 +187,11 @@ def attention_core(q, k, v, mesh: Optional[Mesh], causal: bool = True,
         return alibi_bias(h, pos, pos)[None]
 
     if mesh is None or mesh.empty:
-        return mha_reference(q, k, v, causal=causal, bias=ref_bias())
+        impl = kernel_or_reference("flash_attention", impl,
+                                   flash_reference_reason(b, h, s))
+        if impl == "xla":
+            return mha_reference(q, k, v, causal=causal, bias=ref_bias())
+        return flash_attention(q, k, v, causal=causal, alibi=alibi, impl=impl)
     batch_ax = data_axes(mesh)
     nb = 1
     for a in batch_ax:
@@ -189,14 +220,17 @@ def attention_core(q, k, v, mesh: Optional[Mesh], causal: bool = True,
         raise NotImplementedError(
             "alibi + tensor parallelism needs per-shard head-slope offsets; "
             "serve BLOOM-class models with tp=1 for now")
-    if impl != "pallas" or nsp > 1 or not divisible or s % 128 != 0:
+    impl = kernel_or_reference("flash_attention", impl,
+                               flash_reference_reason(b, h, s, nb, ntp, nsp))
+    if impl == "xla":
         return mha_reference(q, k, v, causal=causal, bias=ref_bias())
     spec = P(batch_ax, "tp", None, None)
 
     @functools.partial(jax.shard_map, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     def _sharded(qq, kk, vv):
-        return flash_attention(qq, kk, vv, causal=causal, alibi=alibi)
+        return flash_attention(qq, kk, vv, causal=causal, alibi=alibi,
+                               impl=impl)
 
     return _sharded(q, k, v)
 

@@ -207,7 +207,7 @@ class CausalLM:
         mesh = self.mesh
         B, S, D = x.shape
         H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        h = norm(x, lp["attn_norm"], cfg.norm, cfg.norm_eps)
+        h = norm(x, lp["attn_norm"], cfg.norm, cfg.norm_eps, mesh)
         a = lp["attn"]
         q = h @ a["wq"]
         k = h @ a["wk"]
@@ -243,7 +243,7 @@ class CausalLM:
     def _mlp_block(self, lp, x, k_mlp, batch_ax, use_drop):
         cfg = self.config
         mesh = self.mesh
-        h = norm(x, lp["mlp_norm"], cfg.norm, cfg.norm_eps)
+        h = norm(x, lp["mlp_norm"], cfg.norm, cfg.norm_eps, mesh)
         if cfg.is_moe:
             from deepspeed_tpu.moe.sharded_moe import moe_mlp
             # split: the RTS permutation and the dropout mask below must not
@@ -338,7 +338,8 @@ class CausalLM:
             S = tokens.shape[1]
             x = x + params["embed"]["pos"][:S][None]
         if cfg.embed_norm:  # bloom word_embeddings_layernorm
-            x = norm(x, params["embed"]["norm"], "layernorm", cfg.norm_eps)
+            x = norm(x, params["embed"]["norm"], "layernorm", cfg.norm_eps,
+                     mesh)
         x = constrain(x, mesh, batch_ax, "sp", None)
 
         if cfg.position == "rope":
@@ -595,7 +596,7 @@ class CausalLM:
                 aux_loss = aux_loss + aux
 
         if labels is None:
-            x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
+            x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps, mesh)
             head = (params["embed"]["tok"].T if cfg.tie_embeddings
                     else params["lm_head"]).astype(x.dtype)
             logits = x @ head
@@ -618,7 +619,7 @@ class CausalLM:
         cfg = self.config
         mesh = self.mesh
         batch_ax = ("dp", "fsdp", "ep")
-        h = norm(x, fnorm, cfg.norm, cfg.norm_eps)
+        h = norm(x, fnorm, cfg.norm, cfg.norm_eps, mesh)
         head = head.astype(h.dtype)
         shifted_labels = labels[:, 1:]
         shifted_mask = loss_mask[:, 1:] if loss_mask is not None else None
@@ -669,7 +670,7 @@ class CausalLM:
             if cfg.position == "learned":
                 x = x + embed["pos"][: toks.shape[1]][None]
             if cfg.embed_norm:
-                x = norm(x, embed["norm"], "layernorm", cfg.norm_eps)
+                x = norm(x, embed["norm"], "layernorm", cfg.norm_eps, mesh)
             return constrain(x, mesh, batch_ax, "sp", None)
 
         def layer_fwd(lp, x, key, cos, sin, use_drop):

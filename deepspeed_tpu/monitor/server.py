@@ -318,13 +318,8 @@ class _Handler(BaseHTTPRequestHandler):
         the profile broker and block this HTTP worker (ThreadingHTTPServer
         — the scrape endpoints stay responsive) until a live engine
         fulfills it.  Returns (status_code, json_payload)."""
-        from deepspeed_tpu.profiling.device_trace import (get_profile_broker,
-                                                          perfetto_supported)
+        from deepspeed_tpu.profiling.device_trace import get_profile_broker
 
-        if not perfetto_supported():
-            return 501, {"error": "this jax's start_trace has no "
-                                  "create_perfetto_trace; device-true "
-                                  "profiling unavailable"}
         try:
             steps = int(qs.get("steps", ["2"])[0])
             timeout = float(qs.get("timeout", ["60"])[0])

@@ -2,18 +2,27 @@
 
 TPU-native analog of the reference's JIT path in ``op_builder/builder.py``
 (SURVEY.md §2.1): where the reference shells out to nvcc via torch
-cpp_extension, we compile host-side C++ (csrc/) with g++ once per source
-change and bind via ctypes (no pybind11 in this image).  ``DS_BUILD_*``-style
-forcing is honored through ``DS_TPU_REBUILD_OPS=1``.
+cpp_extension, we compile host-side C++ (csrc/) with g++ and bind via ctypes
+(no pybind11 in this image).  ``DS_BUILD_*``-style forcing is honored through
+``DS_TPU_REBUILD_OPS=1``.
+
+The build uses ``-march=native``, so a library is only good on the kind of
+CPU that built it, and the build directory travels with a copied checkout.
+The output name therefore carries a hash of the source text, the flags and
+the machine: a library built from other sources or on another CPU is never
+found, and this process builds its own.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import os
+import platform
 import subprocess
 import threading
-from typing import List, Optional
+from typing import List
 
 from deepspeed_tpu.utils.logging import logger
 
@@ -21,6 +30,25 @@ _REPO_ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), "..", "..",
 _BUILD_DIR = os.environ.get(
     "DS_TPU_BUILD_DIR", os.path.join(_REPO_ROOT, "build", "ops"))
 _LOCK = threading.Lock()
+
+
+@functools.lru_cache(maxsize=None)
+def _machine_id() -> str:
+    """What ``-march=native`` compiles for: the architecture plus the first
+    CPU's model and feature flags."""
+    parts = [platform.machine()]
+    seen = set()
+    try:
+        with open("/proc/cpuinfo") as fh:
+            for line in fh:
+                key = line.split(":")[0].strip()
+                if key in ("model name", "flags", "Features") \
+                        and key not in seen:
+                    seen.add(key)
+                    parts.append(line.strip())
+    except OSError:
+        pass
+    return "\n".join(parts)
 
 
 class NativeOpBuilder:
@@ -32,18 +60,23 @@ class NativeOpBuilder:
 
     _cache: dict = {}
 
+    def build_key(self) -> str:
+        """Hash of everything the library's bytes depend on."""
+        h = hashlib.sha256()
+        for src in self.SOURCES:
+            with open(os.path.join(_REPO_ROOT, src), "rb") as fh:
+                h.update(fh.read())
+        h.update("\0".join([*self.CXX_FLAGS, *self.LDFLAGS,
+                            _machine_id()]).encode())
+        return h.hexdigest()[:16]
+
     def lib_path(self) -> str:
-        return os.path.join(_BUILD_DIR, f"lib_ds_{self.NAME}.so")
+        return os.path.join(_BUILD_DIR,
+                            f"lib_ds_{self.NAME}_{self.build_key()}.so")
 
     def _needs_build(self) -> bool:
-        out = self.lib_path()
-        if os.environ.get("DS_TPU_REBUILD_OPS"):
-            return True
-        if not os.path.exists(out):
-            return True
-        out_m = os.path.getmtime(out)
-        return any(os.path.getmtime(os.path.join(_REPO_ROOT, s)) > out_m
-                   for s in self.SOURCES)
+        return bool(os.environ.get("DS_TPU_REBUILD_OPS")) \
+            or not os.path.exists(self.lib_path())
 
     def build(self) -> str:
         with _LOCK:

@@ -12,6 +12,8 @@ these kernels collapse each layer to four launches:
 - :func:`flash_decode`     — online-softmax attention over the KV cache in a
   single kernel, length-aware via scalar-prefetched position (the DMA index
   map clamps beyond ``pos`` so HBM traffic tracks the generated length)
+- :func:`paged_kv_append`  — this step's K/V rows into the paged pool, in
+  place (the paged serving path; the contiguous layouts append in XLA)
 - :func:`fused_proj_norm`  — attention out-projection → residual add → norm
 - :func:`fused_mlp`        — (gated) MLP → residual add, blocked over the
   FFN dim so VMEM holds one weight tile at a time
@@ -35,7 +37,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.common import interpret_flag, resolve_impl
+from deepspeed_tpu.ops.pallas.common import (interpret_flag,
+                                             kernel_or_reference,
+                                             resolve_impl)
 
 NEG_INF = -1e30
 
@@ -45,8 +49,9 @@ _TILE_BYTES = 6 * 2**20
 
 
 def _col_block(d_in: int, n_cols: int, itemsize: int = 2) -> int:
-    """Largest 128-multiple column block with d_in*block*itemsize under the
-    tile budget, and dividing n_cols (falls back to n_cols for small ops)."""
+    """Largest 128-multiple block of the tiled weight dim (``n_cols`` long,
+    ``d_in`` elements across) with d_in*block*itemsize under the tile
+    budget, and dividing n_cols (falls back to n_cols for small ops)."""
     cap = max(128, _TILE_BYTES // max(1, d_in * itemsize) // 128 * 128)
     if n_cols <= cap:
         return n_cols
@@ -157,6 +162,7 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
         out_shape=jax.ShapeDtypeStruct((B, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((B, D), x.dtype)],
         interpret=interpret_flag(impl),
+        name="fused_norm_qkv",
     )(x, scale.reshape(1, D), bias.reshape(1, D), wqkv, ws, bq)
 
 
@@ -249,6 +255,83 @@ def _flash_decode_paged_kernel(pos_ref, pt_ref, q_ref, k_ref, v_ref,
                          m_scr, l_scr, acc_scr, **kw)
 
 
+def paged_decode_reference_reason(page: int) -> Optional[str]:
+    """Why the paged kernel cannot take this page size (None = it can):
+    the page is the kernel's key block, which the score tile lays along
+    the 128 lanes."""
+    if page % 128:
+        return f"page of {page} tokens is not a multiple of the 128-lane tile"
+    return None
+
+
+def decode_reference_reason(cache_len: int, block: int) -> Optional[str]:
+    """Why the contiguous kernel cannot take this cache (None = it can)."""
+    if cache_len % block:
+        return (f"cache length {cache_len} is not a multiple of the "
+                f"{block}-token block")
+    return None
+
+
+def _kv_append_kernel(pp_ref, po_ref, kn_ref, vn_ref, ko_ref, vo_ref,
+                      k_out, v_out, *, rows, hkv):
+    del pp_ref                    # consumed by the index maps
+    r = po_ref[pl.program_id(0) // hkv] % rows
+    hit = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == r
+    k_out[0] = jnp.where(hit, kn_ref[0], ko_ref[0])
+    v_out[0] = jnp.where(hit, vn_ref[0], vo_ref[0])
+
+
+def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
+                    impl: Optional[str] = None):
+    """Write this step's K/V rows into the stacked paged pool, in place:
+    row b of ``k``/``v`` [B, Hkv, Dh] lands at row ``pos[b] % page`` of
+    physical page ``page_table[b, pos[b] // page]`` of layer ``layer``.
+
+    The XLA form is one batched scatter.  On the chip that scatter wants
+    its index dims minor-most while the flash-decode kernel takes the pool
+    row-major, so XLA re-laid-out the WHOLE pool around every layer's
+    kernel call (at gpt2-xl the decode block then asks for more HBM than
+    the chip has).  The kernel form rewrites only the tile-aligned group of
+    rows holding the new one, aliased onto the pool, so the pool keeps one
+    layout from the first layer to the last."""
+    impl = resolve_impl(impl)
+    L, P, Hkv, page, Dh = kcache.shape
+    B = k.shape[0]
+    pp = page_table[jnp.arange(B), pos // page]
+    po = pos % page
+    impl = kernel_or_reference("paged_kv_append", impl,
+                               paged_decode_reference_reason(page))
+    if impl == "xla":
+        return (kcache.at[layer, pp, :, po, :].set(k.astype(kcache.dtype)),
+                vcache.at[layer, pp, :, po, :].set(v.astype(vcache.dtype)))
+    rows = 32 // kcache.dtype.itemsize      # one (sublane x lane) tile
+    base = layer * P * Hkv
+    kernel = functools.partial(_kv_append_kernel, rows=rows, hkv=Hkv)
+
+    def group(g, pp_ref, po_ref):
+        b = g // Hkv
+        return base + pp_ref[b] * Hkv + g % Hkv, po_ref[b] // rows, 0
+
+    new = pl.BlockSpec((1, 1, Dh), lambda g, pp_ref, po_ref: (g, 0, 0))
+    old = pl.BlockSpec((1, rows, Dh), group)
+    pool = jax.ShapeDtypeStruct((L * P * Hkv, page, Dh), kcache.dtype)
+    k3, v3 = pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(B * Hkv,),
+            in_specs=[new, new, old, old], out_specs=[old, old]),
+        out_shape=[pool, pool],
+        # operands count the two scalar-prefetch arrays: the pools are 4, 5
+        input_output_aliases={4: 0, 5: 1},
+        interpret=interpret_flag(impl),
+        name="paged_kv_append",
+    )(pp.astype(jnp.int32), po.astype(jnp.int32),
+      k.astype(kcache.dtype).reshape(B * Hkv, 1, Dh),
+      v.astype(vcache.dtype).reshape(B * Hkv, 1, Dh),
+      kcache.reshape(pool.shape), vcache.reshape(pool.shape))
+    return k3.reshape(kcache.shape), v3.reshape(vcache.shape)
+
+
 def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
                         layer: Optional[int], alibi: bool, impl: str):
     """Decode attention over the PAGED pool (``serving/paged_kv.py``):
@@ -260,13 +343,15 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
     lands on the right physical page and — exactly as in the contiguous
     kernel — blocks past each row's ``pos`` are neither fetched nor
     computed.  The XLA path gathers the logical per-slot view and runs
-    the dense reference (the fallback for CPU tests and non-tile-aligned
-    page sizes)."""
+    the dense reference (CPU tests, and the page sizes
+    :func:`paged_decode_reference_reason` names)."""
     B, H, Dh = q.shape
     kc = kcache if layer is None else kcache[layer]
     vc = vcache if layer is None else vcache[layer]
     Hkv, page = kc.shape[1], kc.shape[2]
-    if impl == "xla" or page % 128:
+    impl = kernel_or_reference("flash_decode_paged", impl,
+                               paged_decode_reference_reason(page))
+    if impl == "xla":
         from deepspeed_tpu.models.decoding import paged_logical_view
 
         return _flash_decode_ref(q, paged_logical_view(kc, page_table),
@@ -312,6 +397,7 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BG, rep, Dh), q.dtype),
         interpret=interpret_flag(impl),
+        name="flash_decode_paged",
     )(pos, page_table.astype(jnp.int32), q4, k3, v3, slopes)
     return o.reshape(B, H, Dh)
 
@@ -351,7 +437,9 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
     # odd cache lengths (not a block multiple) would hand the kernel a
     # non-tile-aligned block — route them to the dense reference, the same
     # policy the unfused decode uses for small caches
-    if impl == "xla" or Smax % block:
+    impl = kernel_or_reference("flash_decode", impl,
+                               decode_reference_reason(Smax, block))
+    if impl == "xla":
         return _flash_decode_ref(q, kc, vc, pos, scale=scale, alibi=alibi)
     B, H, Dh = q.shape
     Hkv = kc.shape[1]
@@ -391,6 +479,7 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((BG, rep, Dh), q.dtype),
         interpret=interpret_flag(impl),
+        name="flash_decode",
     )(pos, q4, k3, v3, slopes)
     return o.reshape(B, H, Dh)
 
@@ -415,19 +504,30 @@ def _proj_norm_ref(ctx, resid, wo, bo, scale, bias, *, kind, eps, parallel,
 
 
 def _proj_norm_kernel(ctx_ref, res_ref, wo_ref, ws_ref, bo_ref, s_ref, b_ref,
-                      r_ref, h_ref, *, kind, eps, parallel, has_bias, quant):
+                      r_ref, h_ref, acc_scr, *, kind, eps, parallel, has_bias,
+                      quant, nm):
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
     wo = _deq(wo_ref[:], ws_ref[:], ctx_ref.dtype) if quant else wo_ref[:]
-    o = jax.lax.dot_general(ctx_ref[:], wo, (((1,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    if has_bias:
-        o = o + bo_ref[:].astype(jnp.float32)
-    res32 = res_ref[:].astype(jnp.float32)
-    r32 = res32 + o
-    nsrc = res32 if parallel else r32
-    h = _normalize(nsrc, s_ref[:].astype(jnp.float32),
-                   b_ref[:].astype(jnp.float32), kind, eps)
-    r_ref[:] = r32.astype(r_ref.dtype)
-    h_ref[:] = h.astype(h_ref.dtype)
+    acc_scr[:] += jax.lax.dot_general(ctx_ref[:], wo, (((1,), (0,)), ((), ())),
+                                      preferred_element_type=jnp.float32)
+
+    @pl.when(j == nm - 1)
+    def _finish():
+        o = acc_scr[:]
+        if has_bias:
+            o = o + bo_ref[:].astype(jnp.float32)
+        res32 = res_ref[:].astype(jnp.float32)
+        r32 = res32 + o
+        nsrc = res32 if parallel else r32
+        h = _normalize(nsrc, s_ref[:].astype(jnp.float32),
+                       b_ref[:].astype(jnp.float32), kind, eps)
+        r_ref[:] = r32.astype(r_ref.dtype)
+        h_ref[:] = h.astype(h_ref.dtype)
 
 
 def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
@@ -452,25 +552,31 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
     D = wo.shape[1]
     quant = wscale is not None
     has_bias = bo is not None
+    # blocked over the contraction dim (wo's rows): the norm needs whole
+    # output rows, so grid step j accumulates ctx[:, j] @ wo[j, :] into an
+    # fp32 scratch and the last step adds the residual and norms.  Sized
+    # like fused_norm_qkv (quant counts the fp32 dequant intermediate).
+    bm = _col_block(D, M, 4 if quant else wo.dtype.itemsize)
     bo2 = (bo if has_bias else jnp.zeros((D,), ctx.dtype)).reshape(1, D)
     ws = (wscale if quant else jnp.ones((D,), jnp.float32)).reshape(1, D)
     kernel = functools.partial(_proj_norm_kernel, kind=kind, eps=eps,
                                parallel=parallel, has_bias=has_bias,
-                               quant=quant)
+                               quant=quant, nm=M // bm)
+    row = pl.BlockSpec((1, D), lambda j: (0, 0))
+    act = pl.BlockSpec((B, D), lambda j: (0, 0))
     r, h = pl.pallas_call(
         kernel,
-        in_specs=[pl.BlockSpec((B, M), lambda: (0, 0)),
-                  pl.BlockSpec((B, D), lambda: (0, 0)),
-                  pl.BlockSpec((M, D), lambda: (0, 0)),
-                  pl.BlockSpec((1, D), lambda: (0, 0)),
-                  pl.BlockSpec((1, D), lambda: (0, 0)),
-                  pl.BlockSpec((1, D), lambda: (0, 0)),
-                  pl.BlockSpec((1, D), lambda: (0, 0))],
-        out_specs=[pl.BlockSpec((B, D), lambda: (0, 0)),
-                   pl.BlockSpec((B, D), lambda: (0, 0))],
+        grid=(M // bm,),
+        in_specs=[pl.BlockSpec((B, bm), lambda j: (0, j)),
+                  act,
+                  pl.BlockSpec((bm, D), lambda j: (j, 0)),
+                  row, row, row, row],
+        out_specs=[act, act],
         out_shape=[jax.ShapeDtypeStruct((B, D), ctx.dtype),
                    jax.ShapeDtypeStruct((B, D), ctx.dtype)],
+        scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         interpret=interpret_flag(impl),
+        name="fused_proj_norm",
     )(ctx, resid, wo, ws, bo2, scale.reshape(1, D), bias.reshape(1, D))
     return r, h
 
@@ -606,4 +712,5 @@ def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
         out_shape=jax.ShapeDtypeStruct((B, D), h.dtype),
         scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         interpret=interpret_flag(impl),
+        name="fused_mlp",
     )(h, r, w_up, wg, w_down, su2, sg2, sd2, bu2, bg2, bd2)

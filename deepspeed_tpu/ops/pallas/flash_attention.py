@@ -173,6 +173,7 @@ def _flash_fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret):
                         pltpu.VMEM((bq, 1), jnp.float32),
                         pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q3, k3, v3, _head_slopes(B, H, alibi))
     return o.reshape(B, H, S, D), lse.reshape(B, H, S)
 
@@ -312,6 +313,7 @@ def _flash_bwd(res, g, causal, alibi, scale, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q3, k3, v3, do3, lse3, delta3, slopes)
 
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -333,6 +335,7 @@ def _flash_bwd(res, g, causal, alibi, scale, block_q, block_k, interpret):
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q3, k3, v3, do3, lse3, delta3, slopes)
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, Sk, D), dv.reshape(B, H, Sk, D))
 

@@ -85,11 +85,14 @@ def fused_adam_update(param, grad, m, v, step, *, lr: float, beta1: float = 0.9,
 
     pf, gf, mf, vf = flat(param), flat(grad), flat(m), flat(v)
     rows = pf.shape[0]
+    # a short tensor is one whole-array block (legal at any row count); a
+    # long one runs 512-row blocks and leaves the last one ragged — the
+    # update is elementwise, so what a ragged block reads past the end only
+    # reaches lanes its masked write drops.  (Halving the block until it
+    # divides the rows ends at a (2, 128) tile for the [50257, 768]
+    # embedding, which the TPU tiling refuses.)
     block_rows = min(rows, _BLOCK // _LANE)
-    while rows % block_rows:
-        block_rows //= 2
-    block_rows = max(1, block_rows)
-    grid = rows // block_rows
+    grid = pl.cdiv(rows, block_rows)
     kernel = functools.partial(_adam_kernel, beta1=beta1, beta2=beta2, eps=eps,
                                weight_decay=weight_decay, adam_w_mode=adam_w_mode)
     c1a = jnp.asarray([c1], jnp.float32)
@@ -112,6 +115,7 @@ def fused_adam_update(param, grad, m, v, step, *, lr: float, beta1: float = 0.9,
                    jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
                    jax.ShapeDtypeStruct((rows, _LANE), jnp.float32)],
         interpret=interpret_flag(impl),
+        name="fused_adam",
     )(c1a, c2a, lra, pf, gf, mf, vf)
     unflat = lambda x: x.reshape(-1)[:n].reshape(orig_shape)
     return unflat(p_new), unflat(m_new), unflat(v_new)

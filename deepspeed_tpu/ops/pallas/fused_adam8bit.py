@@ -142,6 +142,7 @@ def fused_adam8bit_update(p2d, g2d, mq, ms, vq, vs, c1, c2, lr, seed, *,
                    jax.ShapeDtypeStruct((nb, block), jnp.int8),
                    jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
         interpret=interpret_flag(impl),
+        name="fused_adam8bit",
     )(jnp.asarray([c1], jnp.float32), jnp.asarray([c2], jnp.float32),
       jnp.asarray([lr], jnp.float32), jnp.asarray([seed], jnp.int32),
       p2d, g2d, mq, ms, vq, vs)

@@ -35,7 +35,7 @@ _BLOCK = 64 * 1024
 
 def _lamb_phase1_kernel(c1_ref, c2_ref, p_ref, g_ref, m_ref, v_ref,
                         u_out, m_out, v_out, norms_out, acc, *, beta1, beta2,
-                        eps, weight_decay):
+                        eps, weight_decay, rows, block_rows):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -52,8 +52,16 @@ def _lamb_phase1_kernel(c1_ref, c2_ref, p_ref, g_ref, m_ref, v_ref,
     u_out[:] = u
     m_out[:] = m_new
     v_out[:] = v_new
-    acc[0, 0] += jnp.sum(p * p)
-    acc[0, 1] += jnp.sum(u * u)
+    if rows % block_rows:
+        # ragged last block: what it read past the end must not reach the
+        # norms (the writes above are masked by the pipeline)
+        row = i * block_rows + jax.lax.broadcasted_iota(jnp.int32, p.shape, 0)
+        p = jnp.where(row < rows, p, 0.0)
+        u = jnp.where(row < rows, u, 0.0)
+    # per-lane partial sums: Mosaic cannot store a scalar to VMEM, so the
+    # cross-lane reduction happens outside, on the [2, 128] result
+    acc[0:1, :] += jnp.sum(p * p, axis=0, keepdims=True)
+    acc[1:2, :] += jnp.sum(u * u, axis=0, keepdims=True)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _finish():
@@ -98,31 +106,32 @@ def fused_lamb_update(param, grad, m, v, step, *, lr, beta1: float = 0.9,
 
     pf, gf, mf, vf = flat(param), flat(grad), flat(m), flat(v)
     rows = pf.shape[0]
+    # same blocking as fused_adam_update: whole array when short, else
+    # 512-row blocks with a ragged tail
     block_rows = min(rows, _BLOCK // _LANE)
-    while rows % block_rows:
-        block_rows //= 2
-    block_rows = max(1, block_rows)
-    grid = rows // block_rows
+    grid = pl.cdiv(rows, block_rows)
     bspec = pl.BlockSpec((block_rows, _LANE), lambda i, *_: (i, 0))
-    nspec = pl.BlockSpec((1, _LANE), lambda i, *_: (0, 0))
+    nspec = pl.BlockSpec((2, _LANE), lambda i, *_: (0, 0))
     kernel = functools.partial(_lamb_phase1_kernel, beta1=beta1, beta2=beta2,
-                               eps=eps, weight_decay=weight_decay)
+                               eps=eps, weight_decay=weight_decay, rows=rows,
+                               block_rows=block_rows)
     u, m_new, v_new, norms = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(grid,),
             in_specs=[bspec, bspec, bspec, bspec],
             out_specs=[bspec, bspec, bspec, nspec],
-            scratch_shapes=[pltpu.VMEM((1, _LANE), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((2, _LANE), jnp.float32)]),
         out_shape=[jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
                    jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
                    jax.ShapeDtypeStruct((rows, _LANE), jnp.float32),
-                   jax.ShapeDtypeStruct((1, _LANE), jnp.float32)],
+                   jax.ShapeDtypeStruct((2, _LANE), jnp.float32)],
         interpret=interpret_flag(impl),
+        name="fused_lamb_moments",
     )(jnp.asarray([c1], jnp.float32), jnp.asarray([c2], jnp.float32),
       pf, gf, mf, vf)
-    w_norm = jnp.sqrt(norms[0, 0])
-    u_norm = jnp.sqrt(norms[0, 1])
+    w_norm = jnp.sqrt(jnp.sum(norms[0]))
+    u_norm = jnp.sqrt(jnp.sum(norms[1]))
     trust = jnp.where((w_norm > 0) & (u_norm > 0), w_norm / u_norm, 1.0)
     scale = jnp.asarray([lr], jnp.float32) * trust
     p_new = pl.pallas_call(
@@ -132,6 +141,7 @@ def fused_lamb_update(param, grad, m, v, step, *, lr, beta1: float = 0.9,
             in_specs=[bspec, bspec], out_specs=bspec),
         out_shape=jax.ShapeDtypeStruct((rows, _LANE), param.dtype),
         interpret=interpret_flag(impl),
+        name="fused_lamb_apply",
     )(scale.reshape(1), pf, u)
     unflat = lambda x: x.reshape(-1)[:n].reshape(orig_shape)
     return unflat(p_new), unflat(m_new), unflat(v_new)

@@ -132,6 +132,7 @@ def layer_norm(x, gamma, beta, eps: float = 1e-5, impl: Optional[str] = None):
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         interpret=interpret_flag(impl),
+        name="layer_norm_fwd",
     )(x2, gamma.reshape(1, n), beta.reshape(1, n))
     return y.reshape(orig)
 
@@ -184,6 +185,7 @@ def _layer_norm_bwd_vjp(eps, impl, res, dy):
                        jax.ShapeDtypeStruct((1, n), jnp.float32),
                        jax.ShapeDtypeStruct((1, n), jnp.float32)],
             interpret=interpret_flag(impl),
+            name="layer_norm_bwd",
         )(x2, gamma.reshape(1, n), dy2)
         dg, db = dg_part[0], db_part[0]
     return dx.reshape(orig), dg.astype(gamma.dtype), db.astype(gamma.dtype)
@@ -217,6 +219,7 @@ def rms_norm(x, gamma, eps: float = 1e-6, impl: Optional[str] = None):
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         interpret=interpret_flag(impl),
+        name="rms_norm_fwd",
     )(x2, gamma.reshape(1, n))
     return y.reshape(orig)
 
@@ -255,6 +258,7 @@ def _rms_norm_bwd_vjp(eps, impl, res, dy):
             out_shape=[jax.ShapeDtypeStruct((rows, n), x.dtype),
                        jax.ShapeDtypeStruct((1, n), jnp.float32)],
             interpret=interpret_flag(impl),
+            name="rms_norm_bwd",
         )(x2, gamma.reshape(1, n), dy2)
         dg = dg_part[0]
     return dx.reshape(orig), dg.astype(gamma.dtype)

@@ -18,19 +18,24 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.common import interpret_flag, resolve_impl
+from deepspeed_tpu.ops.pallas.common import (interpret_flag, resolve_impl,
+                                             round_up)
 
 _LANE = 128
 
 
+# fp32 elements per grid step: 2MB in + 0.5MB out, double-buffered, stays
+# well inside the 16MB scoped-VMEM default at every block width
+_TILE_ELEMS = 512 * 1024
+
+
 def _quant_kernel(x_ref, q_ref, scale_ref, *, qmax):
-    x = x_ref[:].astype(jnp.float32)                 # [1, block]
+    x = x_ref[:].astype(jnp.float32)                 # [rows, block]
     absmax = jnp.max(jnp.abs(x), axis=-1, keepdims=True)
     scale = jnp.where(absmax == 0, 1.0, absmax / qmax)
     q_ref[:] = jnp.clip(jnp.round(x / scale), -qmax, qmax).astype(jnp.int8)
-    scale_ref[:] = jnp.broadcast_to(scale, scale_ref.shape)
+    scale_ref[:] = scale
 
 
 def quantize(x, bits: int = 8, block: int = 2048,
@@ -51,11 +56,18 @@ def quantize(x, bits: int = 8, block: int = 2048,
         # caller granularity (quantized collectives use small blocks)
         block = max(_LANE, block)
     pad = (-n) % block
+    nb = (n + pad) // block
+    # a kernel grid step takes `rows` whole blocks: the TPU tiling wants
+    # the second-to-last block dim a multiple of 8 (a (1, block) tile is
+    # refused), so the block count is padded up to a row-tile multiple with
+    # zero blocks that are sliced off again below
+    rows = max(8, min(256, _TILE_ELEMS // block // 8 * 8, round_up(nb, 8)))
+    nbp = nb if impl == "xla" else round_up(nb, rows)
     flat = x.reshape(-1).astype(jnp.float32)
-    if pad:
-        flat = jnp.concatenate([flat, jnp.zeros((pad,), jnp.float32)])
-    blocks = flat.reshape(-1, block)
-    nb = blocks.shape[0]
+    if nbp * block != n:
+        flat = jnp.concatenate(
+            [flat, jnp.zeros((nbp * block - n,), jnp.float32)])
+    blocks = flat.reshape(nbp, block)
     if impl == "xla":
         absmax = jnp.max(jnp.abs(blocks), axis=-1, keepdims=True)
         scale = jnp.where(absmax == 0, 1.0, absmax / qmax)
@@ -63,15 +75,16 @@ def quantize(x, bits: int = 8, block: int = 2048,
         return q, scale[:, 0], pad
     q, scale = pl.pallas_call(
         functools.partial(_quant_kernel, qmax=qmax),
-        grid=(nb,),
-        in_specs=[pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((1, block), lambda i: (i, 0)),
-                   pl.BlockSpec((1, _LANE), lambda i: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.int8),
-                   jax.ShapeDtypeStruct((nb, _LANE), jnp.float32)],
+        grid=(nbp // rows,),
+        in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
+        out_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
+                   pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((nbp, block), jnp.int8),
+                   jax.ShapeDtypeStruct((nbp, 1), jnp.float32)],
         interpret=interpret_flag(impl),
+        name="quantize",
     )(blocks)
-    return q, scale[:, 0], pad
+    return q[:nb], scale[:nb, 0], pad
 
 
 def dequantize(q, scale, pad: int, shape, dtype=jnp.float32):

@@ -78,6 +78,7 @@ def _rope_fwd(x, cos, sin, impl):
         out_specs=pl.BlockSpec((1, S, D), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((lead, S, D), x.dtype),
         interpret=interpret_flag(impl),
+        name="rope",
     )(x3, cos, sin)
     return y.reshape(orig)
 

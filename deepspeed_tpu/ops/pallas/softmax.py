@@ -61,6 +61,7 @@ def scaled_masked_softmax(x, mask=None, scale: float = 1.0, impl: Optional[str] 
             out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
             interpret=interpret_flag(impl),
+            name="scaled_softmax",
         )(x2)
     else:
         mask2 = jnp.broadcast_to(mask, orig).reshape(-1, n).astype(jnp.int32)
@@ -72,6 +73,7 @@ def scaled_masked_softmax(x, mask=None, scale: float = 1.0, impl: Optional[str] 
             out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
             interpret=interpret_flag(impl),
+            name="scaled_masked_softmax",
         )(x2, mask2)
     return y.reshape(orig)
 
@@ -119,5 +121,6 @@ def bias_act(x, bias, act: str = "gelu", impl: Optional[str] = None):
         out_specs=pl.BlockSpec((br, n), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, n), x.dtype),
         interpret=interpret_flag(impl),
+        name="bias_act",
     )(x2, bias.reshape(1, n))
     return y.reshape(orig)

@@ -47,7 +47,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from .device_trace import analyze_capture, perfetto_supported
+from .device_trace import analyze_capture
 
 SCHEMA_VERSION = 1
 
@@ -386,7 +386,7 @@ class ContinuousProfiler:
         upcoming_step + capture_steps - 1``.  The CALLER guarantees no
         other capture slot (profile_trace, /profilez, watchdog) owns the
         global profiler session."""
-        if self._cap is not None or not perfetto_supported():
+        if self._cap is not None:
             return False
         if not self.due(upcoming_step):
             return False

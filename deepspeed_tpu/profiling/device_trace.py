@@ -8,7 +8,7 @@ wall-clocked from the host.  The device truth was always in the trace:
 every collective wrapper emits a ``ds_comm_<op>`` ``jax.named_scope``, the
 train step carries ``ds_fwd_bwd`` / ``ds_optimizer_step``, and the serving
 loop emits ``ds_serve_prefill`` / ``ds_serve_decode`` host ranges.  This
-module closes the loop: jax 0.4.37's ``start_trace(...,
+module closes the loop: ``jax.profiler.start_trace(...,
 create_perfetto_trace=True)`` writes ``perfetto_trace.json.gz`` — plain
 trace-event JSON, stdlib gzip+json parseable, no xplane proto dep — and
 the post-processor here walks it, separates device tracks from host
@@ -65,19 +65,10 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..monitor.comms import KNOWN_OPS, busbw_factor
 
 
-def perfetto_supported() -> bool:
-    """Whether this jax writes perfetto trace-event JSON (delegates to
-    profiling/trace.py).  Lazy on purpose: only LIVE capture paths (the
-    broker, TraceCapture) need jax — the offline parse half of this
-    module stays importable with no jax installed."""
-    from .trace import perfetto_supported as _probe  # dslint: disable=DSL003 -- live-capture path only; the offline parse (tools/trace_report.py) never calls it, and on an engine box jax is already present
-
-    return _probe()
-
 __all__ = ["find_perfetto_trace", "load_trace_events", "summarize_trace",
            "publish_summary", "analyze_capture", "ensure_registered",
            "ProfileBroker",
-           "ProfileRequest", "get_profile_broker", "perfetto_supported",
+           "ProfileRequest", "get_profile_broker",
            "TRAIN_SCOPES", "SERVE_SCOPES"]
 
 # the named scopes the engines emit (see monitor/comms.py, runtime/engine.py,

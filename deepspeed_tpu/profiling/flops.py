@@ -12,11 +12,14 @@ the metrics registry (and thus the ``_report`` MonitorMaster bridge and
 ``/statz``).
 
 ``peak_flops()`` (bf16 peak per chip, by device kind) lives here so
-bench.py and the gauges share one table.
+bench.py and the gauges share one table.  A device that is not in the table
+is an error, not a default: a utilisation against a guessed peak is not a
+measurement.
 """
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Any, Dict, Optional
 
@@ -25,22 +28,35 @@ from deepspeed_tpu.monitor.metrics import MetricsRegistry, get_registry
 __all__ = ["PEAK_FLOPS", "peak_flops", "lm_flops_per_token",
            "lm_layer_flops", "TrainFlopsMeter"]
 
-PEAK_FLOPS = {  # bf16 peak per chip
+PEAK_FLOPS = {  # bf16 peak per chip (Google Cloud TPU documentation)
     "tpu v5 lite": 197e12, "tpu v5e": 197e12, "tpu v5": 459e12,
-    "tpu v4": 275e12, "tpu v6 lite": 918e12, "cpu": 1e12,
+    "tpu v4": 275e12, "tpu v6 lite": 918e12,
 }
 
 
 def peak_flops(device=None) -> float:
-    """Peak bf16 FLOP/s of (the first) local device; 197 TF/s fallback."""
+    """Peak bf16 FLOP/s of (the first) local device, by ``device_kind``.
+    Raises ``KeyError`` for a kind the table does not hold (the CPU is
+    one)."""
     import jax
 
     d = device if device is not None else jax.devices()[0]
-    kind = getattr(d, "device_kind", "cpu").lower()
+    kind = d.device_kind.lower()
     for k, v in PEAK_FLOPS.items():
         if kind.startswith(k):
             return v
-    return 197e12
+    raise KeyError(f"no peak FLOP/s on record for device kind "
+                   f"{d.device_kind!r}; known: {sorted(PEAK_FLOPS)}")
+
+
+@functools.lru_cache(maxsize=None)
+def _peak_or_none() -> Optional[float]:
+    """The first device's peak, looked up once; None where the table has
+    none (the MFU gauge is then never set)."""
+    try:
+        return peak_flops()
+    except KeyError:
+        return None
 
 
 def lm_flops_per_token(n_params: int, num_layers: int, hidden_size: int,
@@ -86,6 +102,8 @@ class TrainFlopsMeter:
     dispatch, not compute (a tight loop dispatches several steps before
     the first finishes) — the ``anchor`` (the step's loss output) is
     blocked on first, pinning each boundary to real device completion.
+    ``ds_train_mfu`` is published only where :func:`peak_flops` knows the
+    device; elsewhere (the CPU) only the TFLOP/s gauge moves.
     The sync happens ONLY while the registry is enabled: telemetry users
     pay a boundary bubble (the ``wall_clock_breakdown`` trade, scoped the
     same way); disabled runs are untouched.  The first call only arms the
@@ -102,7 +120,6 @@ class TrainFlopsMeter:
             "ds_train_mfu", "model FLOPs utilization: ds_train_tflops / "
             "device peak")
         self._last_t: Optional[float] = None
-        self._peak: Optional[float] = None
 
     def reset_clock(self) -> None:
         self._last_t = None
@@ -129,11 +146,8 @@ class TrainFlopsMeter:
         dt = now - last
         if dt <= 0:
             return
-        if self._peak is None:
-            try:
-                self._peak = peak_flops()
-            except Exception:
-                self._peak = 197e12
         tflops = flops_per_step / dt / 1e12
         self._tflops.set(round(tflops, 4))
-        self._mfu.set(round(tflops * 1e12 / self._peak, 6))
+        peak = _peak_or_none()
+        if peak is not None:
+            self._mfu.set(round(tflops * 1e12 / peak, 6))

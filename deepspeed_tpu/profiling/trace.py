@@ -13,7 +13,6 @@ device ops carry the ``ds_fwd_bwd`` / ``ds_optimizer_step``
 
 from __future__ import annotations
 
-import contextlib
 import os
 from typing import Optional
 
@@ -30,12 +29,9 @@ def annotate(name: str):
     ``ds_serve_prefill`` / ``ds_serve_decode``) so the xplane device
     timeline lines up with the host-side ``ds_serve_*`` histograms
     (monitor/metrics.py) phase for phase.  Near-free when no trace is being
-    captured; degrades to a no-op on jax builds without TraceAnnotation.
+    captured.
     """
-    try:
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:  # pragma: no cover - jax without profiler support
-        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
 
 
 def scope(name: str):
@@ -46,30 +42,7 @@ def scope(name: str):
     trace it would time tracing, not execution.)  Trace-time metadata only —
     zero runtime cost, and applied unconditionally so toggling telemetry
     never changes the compiled program."""
-    try:
-        return jax.named_scope(name)
-    except Exception:  # pragma: no cover - ancient jax
-        return contextlib.nullcontext()
-
-
-def perfetto_supported() -> bool:
-    """Whether this jax's ``start_trace`` can write the perfetto
-    trace-event JSON (``create_perfetto_trace=``, present in jax 0.4.37)
-    — the input of the device-truth post-processor
-    (profiling/device_trace.py).  Probed once, by signature."""
-    global _PERFETTO_SUPPORTED
-    if _PERFETTO_SUPPORTED is None:
-        import inspect
-
-        try:
-            sig = inspect.signature(jax.profiler.start_trace)
-            _PERFETTO_SUPPORTED = "create_perfetto_trace" in sig.parameters
-        except (TypeError, ValueError):  # pragma: no cover - exotic builds
-            _PERFETTO_SUPPORTED = False
-    return _PERFETTO_SUPPORTED
-
-
-_PERFETTO_SUPPORTED = None
+    return jax.named_scope(name)
 
 
 class TraceCapture:
@@ -83,8 +56,7 @@ class TraceCapture:
     ``perfetto=True`` additionally asks jax for the perfetto trace-event
     JSON (``perfetto_trace.json.gz`` next to the xplane file — stdlib
     gzip+json parseable), which the device-truth post-processor
-    (profiling/device_trace.py) consumes; silently ignored on jax builds
-    without ``create_perfetto_trace`` (check :func:`perfetto_supported`).
+    (profiling/device_trace.py) consumes.
     """
 
     def __init__(self, output_path: str, start_step: int = 2,
@@ -120,11 +92,8 @@ class TraceCapture:
         # anchor IMMEDIATELY before start_trace: the trace file's ts
         # epoch is the session start (measured within ~100us of the call)
         self._stamp_clock()
-        if self.perfetto and perfetto_supported():
-            jax.profiler.start_trace(self.output_path,
-                                     create_perfetto_trace=True)
-        else:
-            jax.profiler.start_trace(self.output_path)
+        jax.profiler.start_trace(self.output_path,
+                                 create_perfetto_trace=self.perfetto)
         self.active = True
         # training may end inside the window; close() is idempotent
         atexit.register(self.close)

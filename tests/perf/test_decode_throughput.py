@@ -1,8 +1,7 @@
 """Decode throughput microbench (VERDICT r2 item 8 done-criterion).
 
-Runs the jitted lax.while_loop generation path and reports tokens/sec.
-On the CPU mesh this is a smoke-scale sanity run; on real TPU
-(``DSTPU_TEST_ON_TPU=1``) it measures serving decode speed.
+Runs the jitted lax.while_loop generation path at smoke scale on the CPU
+mesh: a sanity run, not a speed (the tests are pinned to the CPU).
 """
 
 import time

@@ -213,9 +213,10 @@ def test_zero3_training_smoke_exposes_comm_and_mfu_via_statz(mesh8):
         assert snap["ds_comm_all_gather_seconds"]["count"] >= 3
         assert snap["ds_comm_all_gather_seconds"]["sum"] > 0
         assert snap["ds_comm_reduce_scatter_bytes_total"]
-        # MFU/TFLOPS gauges: set from the 2nd boundary on
+        # TFLOPS gauge: set from the 2nd boundary on.  MFU needs a peak,
+        # and the CPU has none on record, so that gauge never moves here
         assert snap["ds_train_tflops"] > 0
-        assert 0 < snap["ds_train_mfu"] < 10  # sanity, CPU "peak" is fake
+        assert not snap["ds_train_mfu"]
         # ISSUE 7 step-numerics gauges: loss + grad norm at the boundary
         # (values the engine already computed for _report)
         assert snap["ds_train_loss"] == pytest.approx(losses_on[-1])

@@ -27,12 +27,7 @@ from deepspeed_tpu.comm.mesh import build_mesh, set_global_mesh
 from deepspeed_tpu.models import causal_lm
 from deepspeed_tpu.monitor.metrics import MetricsRegistry, get_registry
 from deepspeed_tpu.profiling import continuous
-from deepspeed_tpu.profiling.device_trace import perfetto_supported
 from tests.unit.simple_model import SimpleModel, random_dataset
-
-needs_perfetto = pytest.mark.skipif(
-    not perfetto_supported(),
-    reason="this jax's start_trace has no create_perfetto_trace")
 
 PHASES = ("fwd_bwd", "optimizer", "comm", "other", "gap")
 
@@ -310,7 +305,6 @@ def _assert_window_contract(w, engine):
     assert w["coverage_ratio"] <= w["overhead_ratio"] <= 1.0
 
 
-@needs_perfetto
 def test_training_engine_produces_scheduled_windows(tmp_path):
     """A stepping CPU engine with the profiler armed at forced cadence
     commits >=2 history windows with NOBODY calling /profilez."""
@@ -354,7 +348,6 @@ def test_training_engine_produces_scheduled_windows(tmp_path):
             continuous._ACTIVE.pop("train", None)
 
 
-@needs_perfetto
 def test_serving_engine_produces_scheduled_windows(tmp_path, devices):
     hist = str(tmp_path / "hist")
     mesh = build_mesh(fsdp=8, devices=devices)

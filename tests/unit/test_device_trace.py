@@ -335,12 +335,6 @@ def test_missing_trace_raises(tmp_path):
 # ---------------------------------------------------------------------------
 
 
-needs_perfetto = pytest.mark.skipif(
-    not device_trace.perfetto_supported(),
-    reason="this jax's start_trace has no create_perfetto_trace")
-
-
-@needs_perfetto
 def test_profilez_live_training_engine(tmp_path):
     """`/profilez?steps=2` against a stepping engine returns a JSON phase
     summary; ds_fwd_bwd appears (host annotation ranges on CPU); the
@@ -399,7 +393,6 @@ def test_profilez_live_training_engine(tmp_path):
     assert dev is not None and dev.value > 0
 
 
-@needs_perfetto
 def test_profilez_no_engine_times_out():
     """Without a stepping engine the request must clear cleanly (504) and
     leave the broker reusable."""

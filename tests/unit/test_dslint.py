@@ -71,7 +71,6 @@ def test_selftest_wired():
 
 
 @pytest.mark.parametrize("fixture,rule,min_hits", [
-    ("dsl001_bad.py", "DSL001", 2),           # donated arg + state sink
     ("dsl002_bad.py", "DSL002", 3),           # disabled branch + 2 syncs
     ("dsl004_bad.py", "DSL004", 1),           # non-ds_ literal
     ("deepspeed_tpu/comm/dsl005_bad.py", "DSL005", 2),  # no scope + cond
@@ -217,19 +216,6 @@ def _mutate(tmp_path, rel, old, new):
     dst.parent.mkdir(parents=True, exist_ok=True)
     dst.write_text(src.replace(old, new))
     return str(dst)
-
-
-def test_dsl001_catches_reverted_owned_put(tmp_path):
-    """Reverting this PR's _step_param_offload fix (raw device_put back
-    into the donated state) re-fires DSL001 at the same site."""
-    p = _mutate(
-        tmp_path, "deepspeed_tpu/runtime/engine.py",
-        "new_params = _owned_device_put_tree(compute,\n"
-        "                                                self._param_shardings)",
-        "new_params = jax.device_put(compute, self._param_shardings)")
-    findings = _lint([p], root=str(tmp_path), rules={"DSL001"})
-    assert any("_replace(params=" in f.message for f in findings), \
-        [f.render() for f in findings]
 
 
 def test_dsl005_catches_stripped_scope(tmp_path):

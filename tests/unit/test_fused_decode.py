@@ -13,7 +13,8 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.ops.pallas.decode import (
     _flash_decode_ref, _mlp_ref, _norm_qkv_ref, _proj_norm_ref,
-    flash_decode, fused_mlp, fused_norm_qkv, fused_proj_norm)
+    flash_decode, fused_mlp, fused_norm_qkv, fused_proj_norm,
+    paged_kv_append)
 
 
 def _rand(key, *shape, dtype=jnp.float32):
@@ -121,6 +122,32 @@ def test_flash_decode_paged_layer_stacked():
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_kv_append_matches_scatter(dtype):
+    """The in-place append kernel writes exactly what the XLA scatter
+    writes: one row per (slot, head) at its page and depth, nothing else —
+    rows at the start, middle and end of a page and of a tile group, and
+    two parked slots that share the junk page 0."""
+    L, P, Hkv, page, Dh, B = 3, 5, 2, 128, 64, 4
+    kc = _rand(0, L, P, Hkv, page, Dh, dtype=dtype)
+    vc = _rand(1, L, P, Hkv, page, Dh, dtype=dtype)
+    k = _rand(2, B, Hkv, Dh, dtype=dtype)
+    v = _rand(3, B, Hkv, Dh, dtype=dtype)
+    pt = jnp.asarray([[3, 1], [0, 0], [2, 4], [0, 0]], jnp.int32)
+    pos = jnp.asarray([0, 17, 128 + 127, 63], jnp.int32)
+    want = paged_kv_append(kc, vc, k, v, pos, pt, layer=1, impl="xla")
+    got = paged_kv_append(kc, vc, k, v, pos, pt, layer=1, impl="interpret")
+    for g, w in zip(got, want):
+        # page 0 is the junk page: two parked slots wrote to it, and which
+        # of them wins is nobody's business
+        np.testing.assert_array_equal(np.asarray(g[:, 1:], np.float32),
+                                      np.asarray(w[:, 1:], np.float32))
+    assert np.array_equal(np.asarray(got[0][1, 3, :, 0], np.float32),
+                          np.asarray(k[0], np.float32))
+    assert np.array_equal(np.asarray(got[1][1, 4, :, 127], np.float32),
+                          np.asarray(v[2], np.float32))
+
+
 def test_flash_decode_paged_small_page_falls_back():
     """Pages below the 128-lane tile route to the gathered dense
     reference (the CPU / tiny-config path) — and still match."""
@@ -192,6 +219,24 @@ def test_flash_decode_stacked_layer_offset():
         want = _flash_decode_ref(q, k[l], v[l], jnp.int32(300),
                                  scale=Dh ** -0.5)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+def test_proj_norm_blocked_grid():
+    """M large enough that the out-projection is tiled over its rows and
+    accumulated across grid steps (the D=4096 presets on the chip)."""
+    B, M, D = 2, 6144, 2048
+    ctx = _rand(0, B, M, dtype=jnp.bfloat16)
+    resid = _rand(1, B, D, dtype=jnp.bfloat16)
+    wo = (_rand(2, M, D) * M ** -0.5).astype(jnp.bfloat16)
+    scale = jnp.ones((D,), jnp.bfloat16)
+    bias = jnp.zeros((D,), jnp.bfloat16)
+    got = fused_proj_norm(ctx, resid, wo, None, scale, bias, kind="rmsnorm",
+                          impl="interpret")
+    want = _proj_norm_ref(ctx, resid, wo, None, scale, bias, kind="rmsnorm",
+                          eps=1e-5, parallel=False)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.float32(g), np.float32(w),
+                                   rtol=2e-2, atol=2e-2)
 
 
 @pytest.mark.parametrize("kind,parallel", [("layernorm", False),

@@ -197,9 +197,12 @@ class TestBiasAct:
 
 
 class TestFusedAdam:
-    def test_parity_with_optax(self):
-        p = rand(257, 33)  # odd size exercises padding
-        g = rand(257, 33, seed=1)
+    # odd sizes exercise the lane padding; the second is long enough for
+    # two 512-row blocks, the last one ragged
+    @pytest.mark.parametrize("shape", [(257, 33), (601, 129)])
+    def test_parity_with_optax(self, shape):
+        p = rand(*shape)
+        g = rand(*shape, seed=1)
         m = jnp.zeros_like(p)
         v = jnp.zeros_like(p)
         import optax
@@ -327,13 +330,16 @@ def test_norm_backward_multiblock_grid():
 class TestFusedLamb:
     """Fused LAMB kernel parity (reference: csrc/lamb; SURVEY.md §2.2)."""
 
-    def test_kernel_matches_xla_reference(self, rng):
+    # 300: one padded block; 70001: two 512-row blocks, the last ragged
+    # (what it reads past the end must stay out of the trust-ratio norms)
+    @pytest.mark.parametrize("n", [300, 70001])
+    def test_kernel_matches_xla_reference(self, rng, n):
         from deepspeed_tpu.ops.pallas.fused_lamb import fused_lamb_update
 
-        p = jax.random.normal(rng, (300,)) * 0.1
-        g = jax.random.normal(jax.random.fold_in(rng, 1), (300,))
-        m = jnp.zeros((300,), jnp.float32)
-        v = jnp.zeros((300,), jnp.float32)
+        p = jax.random.normal(rng, (n,)) * 0.1
+        g = jax.random.normal(jax.random.fold_in(rng, 1), (n,))
+        m = jnp.zeros((n,), jnp.float32)
+        v = jnp.zeros((n,), jnp.float32)
         step = jnp.asarray(1, jnp.int32)
         for i in range(3):
             step = jnp.asarray(i + 1, jnp.int32)
@@ -411,12 +417,16 @@ class TestQuantizerKernels:
         bound = float(jnp.abs(x).max()) / qmax + 1e-6
         assert np.abs(np.asarray(out - x)).max() <= bound
 
-    def test_kernel_matches_xla(self, rng):
+    # 4096: 8 blocks in one row tile; 300000: 586 blocks over three row
+    # tiles, the last padded with zero blocks that are sliced off again
+    @pytest.mark.parametrize("n", [4096, 300000])
+    def test_kernel_matches_xla(self, rng, n):
         from deepspeed_tpu.ops.pallas.quantizer import quantize
 
-        x = jax.random.normal(rng, (4096,))
+        x = jax.random.normal(rng, (n,))
         qk, sk, _ = quantize(x, block=512, impl="interpret")
         qx, sx, _ = quantize(x, block=512, impl="xla")
+        assert qk.shape == qx.shape and sk.shape == sx.shape
         np.testing.assert_array_equal(np.asarray(qk), np.asarray(qx))
         np.testing.assert_allclose(np.asarray(sk), np.asarray(sx), rtol=1e-6)
 

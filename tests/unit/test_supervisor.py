@@ -76,15 +76,11 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    jax.config.update("jax_compilation_cache_dir",
-                      os.environ.get("DSTPU_XLA_CACHE_DIR",
-                                     "/tmp/dstpu_xla_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-except Exception:
-    pass
-
 import deepspeed_tpu
+from deepspeed_tpu.utils.compile_cache import place_compile_cache
+
+place_compile_cache()
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 SAVE_DIR, RESULT = sys.argv[1], sys.argv[2]
 TOTAL_STEPS, KILL_AT = 8, 4

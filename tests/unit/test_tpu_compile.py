@@ -1,0 +1,229 @@
+"""AOT-compile the Pallas kernels of the train and serve paths for a v5e
+that is described, not attached (``/opt/skills/guides/on-chip-measurement``
+§2, rehearsal 3).
+
+Interpret mode cannot see what the chip's compiler refuses: a block shape
+the (8, 128) tiling rejects, more VMEM than a kernel may hold, a scalar
+store to vector memory.  Each case lowers one kernel with ``impl="pallas"``
+at the widths of a model the repo advertises and asserts the compiled text
+carries the ``tpu_custom_call``.  Nothing runs, so this says nothing about
+results (the interpret-mode parity tests do) or times.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.pallas import (flash_attention, fused_adam_update,
+                                      layer_norm, quantize, rms_norm)
+from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
+                                             fused_norm_qkv, fused_proj_norm,
+                                             paged_kv_append)
+from deepspeed_tpu.ops.pallas.fused_adam8bit import fused_adam8bit_update
+from deepspeed_tpu.ops.pallas.fused_lamb import fused_lamb_update
+from deepspeed_tpu.serving.paged_kv import default_page_tokens
+
+BF16, F32, I8, I32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
+
+# D, heads, kv heads, head dim, FFN dim, vocab, gated MLP, norm kind
+WIDTHS = {
+    "gpt2-small": dict(D=768, H=12, Hkv=12, Dh=64, F=3072, V=50257,
+                       glu=False, kind="layernorm"),
+    "gpt2-xl": dict(D=1600, H=25, Hkv=25, Dh=64, F=6400, V=50257,
+                    glu=False, kind="layernorm"),
+    "d4096-gqa8": dict(D=4096, H=32, Hkv=8, Dh=128, F=14336, V=32000,
+                       glu=True, kind="rmsnorm"),
+}
+SLOTS, SEQ, CACHE = 8, 1024, 1024
+ADAM8_BLOCK = 512      # adam8bit()'s default state block
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """Sharding on one described v5e device.  The persistent compile cache
+    is off around the module: an AOT compile for an absent chip is written
+    to it but cannot be read back, and the next one warns."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or it cannot describe a v5e
+        pytest.skip(f"cannot describe a v5e:2x2 topology: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _custom_calls(fn, sharding, *shapes) -> int:
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text().count(
+        'custom_call_target="tpu_custom_call"')
+
+
+def _flash_fwd_bwd(w):
+    qkv = ((1, w["H"], SEQ, w["Dh"]), BF16)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               impl="pallas").astype(F32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2)), [qkv] * 3, 3
+
+
+def _norm_qkv(w):
+    D, N = w["D"], (w["H"] + 2 * w["Hkv"]) * w["Dh"]
+    fn = lambda x, s, b, wq, bq: fused_norm_qkv(
+        x, s, b, wq, bq, kind=w["kind"], impl="pallas")
+    return fn, [((SLOTS, D), BF16), ((D,), BF16), ((D,), BF16),
+                ((D, N), BF16), ((N,), BF16)], 1
+
+
+def _norm_qkv_int8(w):
+    D, N = w["D"], (w["H"] + 2 * w["Hkv"]) * w["Dh"]
+    fn = lambda x, s, b, wq, ws: fused_norm_qkv(
+        x, s, b, wq, kind=w["kind"], wscale=ws, impl="pallas")
+    return fn, [((SLOTS, D), BF16), ((D,), BF16), ((D,), BF16),
+                ((D, N), I8), ((N,), F32)], 1
+
+
+def _decode_contiguous(w):
+    cache = ((SLOTS, w["Hkv"], CACHE, w["Dh"]), BF16)
+    fn = lambda q, k, v, pos: flash_decode(q, k, v, pos, impl="pallas")
+    return fn, [((SLOTS, w["H"], w["Dh"]), BF16), cache, cache,
+                ((SLOTS,), I32)], 1
+
+
+def _decode_paged(w):
+    """The serving default: stacked pool, page = what ``kv_page_tokens: 0``
+    resolves to, read at a static layer offset through the page table."""
+    page = default_page_tokens(CACHE)
+    pool = ((2, SLOTS * CACHE // page + 1, w["Hkv"], page, w["Dh"]), BF16)
+    fn = lambda q, k, v, pos, pt: flash_decode(
+        q, k, v, pos, layer=1, page_table=pt, impl="pallas")
+    return fn, [((SLOTS, w["H"], w["Dh"]), BF16), pool, pool,
+                ((SLOTS,), I32), ((SLOTS, CACHE // page), I32)], 1
+
+
+def _kv_append(w):
+    page = default_page_tokens(CACHE)
+    pool = ((2, SLOTS * CACHE // page + 1, w["Hkv"], page, w["Dh"]), BF16)
+    new = ((SLOTS, w["Hkv"], w["Dh"]), BF16)
+    fn = lambda kc, vc, k, v, pos, pt: paged_kv_append(
+        kc, vc, k, v, pos, pt, layer=1, impl="pallas")
+    return fn, [pool, pool, new, new, ((SLOTS,), I32),
+                ((SLOTS, CACHE // page), I32)], 1
+
+
+def _proj_norm(w):
+    D, M = w["D"], w["H"] * w["Dh"]
+    fn = lambda c, r, wo, bo, s, b: fused_proj_norm(
+        c, r, wo, bo, s, b, kind=w["kind"], impl="pallas")
+    return fn, [((SLOTS, M), BF16), ((SLOTS, D), BF16), ((M, D), BF16),
+                ((D,), BF16), ((D,), BF16), ((D,), BF16)], 1
+
+
+def _proj_norm_int8(w):
+    D, M = w["D"], w["H"] * w["Dh"]
+    fn = lambda c, r, wo, ws, s, b: fused_proj_norm(
+        c, r, wo, None, s, b, kind=w["kind"], wscale=ws, impl="pallas")
+    return fn, [((SLOTS, M), BF16), ((SLOTS, D), BF16), ((M, D), I8),
+                ((D,), F32), ((D,), BF16), ((D,), BF16)], 1
+
+
+def _mlp(w):
+    D, F = w["D"], w["F"]
+    if w["glu"]:
+        fn = lambda h, r, wu, wd, wg: fused_mlp(h, r, wu, wd, wg,
+                                                act="silu", impl="pallas")
+        extra = [((D, F), BF16)]
+    else:
+        fn = lambda h, r, wu, wd: fused_mlp(h, r, wu, wd, act="gelu",
+                                            impl="pallas")
+        extra = []
+    return fn, [((SLOTS, D), BF16), ((SLOTS, D), BF16), ((D, F), BF16),
+                ((F, D), BF16)] + extra, 1
+
+
+def _norm_fwd_bwd(w):
+    D = w["D"]
+    if w["kind"] == "rmsnorm":
+        loss = lambda x, g: rms_norm(x, g, impl="pallas").astype(F32).sum()
+        return (jax.value_and_grad(loss, argnums=(0, 1)),
+                [((2, SEQ, D), BF16), ((D,), F32)], 2)
+    loss = lambda x, g, b: layer_norm(x, g, b,
+                                      impl="pallas").astype(F32).sum()
+    return (jax.value_and_grad(loss, argnums=(0, 1, 2)),
+            [((2, SEQ, D), BF16), ((D,), F32), ((D,), F32)], 2)
+
+
+def _optimizer_leaves(w):
+    """An MLP weight (rows divide the 512-row block) and the embedding
+    (V=50257 leaves the last block ragged)."""
+    return [((w["D"], w["F"]), F32)] * 4 + [((w["V"], w["D"]), F32)] * 4
+
+
+def _adam(w):
+    def fn(p, g, m, v, pe, ge, me, ve, t):
+        kw = dict(lr=1e-3, weight_decay=0.01, impl="pallas")
+        return (fused_adam_update(p, g, m, v, t, **kw),
+                fused_adam_update(pe, ge, me, ve, t, **kw))
+
+    return fn, _optimizer_leaves(w) + [((), I32)], 2
+
+
+def _adam8bit(w):
+    nb = w["D"] * w["F"] // ADAM8_BLOCK
+    tile, scale = (nb, ADAM8_BLOCK), (nb, 1)
+    fn = lambda p, g, mq, ms, vq, vs, seed: fused_adam8bit_update(
+        p, g, mq, ms, vq, vs, 1.1, 1.2, 1e-3, seed, b1=0.9, b2=0.999,
+        eps=1e-8, wd=0.01, sr=True, impl="pallas")
+    return fn, [(tile, BF16), (tile, BF16), (tile, I8), (scale, F32),
+                (tile, I8), (scale, F32), ((), I32)], 1
+
+
+def _quantize(w):
+    fn = lambda x: quantize(x, bits=8, block=ADAM8_BLOCK, impl="pallas")[:2]
+    return fn, [((w["D"], w["F"]), F32)], 1
+
+
+def _lamb(w):
+    def fn(p, g, m, v, pe, ge, me, ve, t):
+        kw = dict(lr=1e-3, weight_decay=0.01, impl="pallas")
+        return (fused_lamb_update(p, g, m, v, t, **kw),
+                fused_lamb_update(pe, ge, me, ve, t, **kw))
+
+    return fn, _optimizer_leaves(w) + [((), I32)], 4
+
+
+KERNELS = {
+    "flash_attention_fwd_bwd": _flash_fwd_bwd,
+    "fused_norm_qkv": _norm_qkv,
+    "fused_norm_qkv_int8": _norm_qkv_int8,
+    "flash_decode_contiguous": _decode_contiguous,
+    "flash_decode_paged": _decode_paged,
+    "paged_kv_append": _kv_append,
+    "fused_proj_norm": _proj_norm,
+    "fused_proj_norm_int8": _proj_norm_int8,
+    "fused_mlp": _mlp,
+    "norm_fwd_bwd": _norm_fwd_bwd,
+    "fused_adam": _adam,
+    "fused_adam8bit": _adam8bit,
+    "quantize": _quantize,
+    "fused_lamb": _lamb,
+}
+
+
+@pytest.mark.parametrize("widths", sorted(WIDTHS))
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(v5e, kernel, widths):
+    fn, shapes, want = KERNELS[kernel](WIDTHS[widths])
+    assert _custom_calls(fn, v5e, *shapes) >= want

@@ -128,7 +128,6 @@ def test_engine_trip_one_capture_one_dump(tmp_path):
 
     import deepspeed_tpu
     from deepspeed_tpu.monitor.flight_recorder import get_flight_recorder
-    from deepspeed_tpu.profiling.trace import perfetto_supported
     from tests.unit.simple_model import SimpleModel, random_dataset
 
     x, y = random_dataset(n=16)
@@ -164,18 +163,16 @@ def test_engine_trip_one_capture_one_dump(tmp_path):
         dumps = glob.glob(os.path.join(dump_dir, "ds_flight_*.json"))
         assert len(dumps) == 1, dumps
         armed = engine._aux_trace
-        if perfetto_supported():
-            assert armed is not None and armed[1] == "watchdog"
+        assert armed is not None and armed[1] == "watchdog"
         # keep stepping: no re-trigger storm — still exactly one dump, and
         # the armed capture closes into a summary
         for _ in range(3):
             one_step()
         assert len(glob.glob(os.path.join(dump_dir,
                                           "ds_flight_*.json"))) == 1
-        if perfetto_supported():
-            assert engine._aux_trace is None
-            assert os.path.exists(os.path.join(
-                trace_dir, "ds_watchdog_summary.json"))
+        assert engine._aux_trace is None
+        assert os.path.exists(os.path.join(
+            trace_dir, "ds_watchdog_summary.json"))
     finally:
         rec.disable()
         rec.reset()
