@@ -556,8 +556,13 @@ def bench_serving(num_requests: int = 64, num_slots: int = 8, qps: float = 50.0,
                         round(snap["ds_serve_ttft_seconds"]["p99"], 4),
                     "queue_wait_p99_s":
                         round(snap["ds_serve_queue_wait_seconds"]["p99"], 4),
+                    # None here: this wave neither streams nor sets an
+                    # EOS, so every token is fetched at a request's finish
+                    # and the engine records no per-token pace (and its
+                    # TTFT is the request latency)
                     "tpot_p50_s":
-                        round(snap["ds_serve_tpot_seconds"]["p50"], 5),
+                        (round(snap["ds_serve_tpot_seconds"]["p50"], 5)
+                         if snap["ds_serve_tpot_seconds"]["count"] else None),
                     "mean_slot_occupancy":
                         round(snap["ds_serve_occupancy_ratio"]["mean"], 3),
                     "kv_util": round(util.get("mean", 0.0), 3),

@@ -50,7 +50,9 @@ class SloWatcher:
 
     - ``goodput_ratio`` (MIN): ledger goodput below the threshold burns.
     - ``ttft_p99_s`` (MAX): serving TTFT p99 (``ds_serve_ttft_seconds``)
-      above the threshold burns.
+      above the threshold burns.  That histogram times the first token's
+      value on the host; a non-streaming request without EOS contributes
+      its whole latency there, so the rule burns on slow batch traffic too.
     - ``shed_ratio`` (MAX): ``ds_serve_shed_total / ds_serve_submitted_total``
       above the threshold burns.
 

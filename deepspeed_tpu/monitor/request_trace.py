@@ -4,7 +4,7 @@ The PR 2/4 serving metrics are *aggregate*: a p99 TTFT histogram can say
 the tail is slow, but not WHICH requests were slow or WHY — queue wait vs
 chunked prefill vs decode stretch vs paged-KV preemption.  This module is
 the per-request half: the scheduler and serving engine already own every
-lifecycle edge (submit, admit, each prefill chunk, first-token dispatch,
+lifecycle edge (submit, admit, each prefill chunk, first token on the host,
 decode blocks, preempt/requeue, EOS-drain fetch, finish), and the
 :class:`RequestTracer` records them into one timeline per request.
 
@@ -17,9 +17,10 @@ Two layers per timeline, with distinct semantics:
   ``t_finish - t_submit``: the phase-attribution histograms
   (``ds_serve_phase_*_seconds``, recorded at finish) reconcile with the
   existing ``ds_serve_request_latency_seconds`` observations by
-  construction (tested).  ``prefill`` here is admit → first-token
-  dispatch (it includes the slot's share of interleaving with other
-  slots' chunks — that IS the latency the request experienced);
+  construction (tested).  ``prefill`` here is admit → the first
+  token's value on the host (it includes the slot's share of interleaving
+  with other slots' chunks and the wait for the chip — that IS the
+  latency the request experienced);
   ``preempted_wait`` is preempt → re-admission.
 - **spans** — the measured host dispatch windows inside those phases
   (``prefill_chunk`` / ``decode_block`` / ``drain_fetch``, each with its
@@ -236,8 +237,10 @@ class RequestTracer:
         rec["edges"].append((t, "prefill"))
 
     def decode_start(self, rid: int, t: float) -> None:
-        """Prefix fully cache-resident; first-token dispatched (or
-        re-reached after a preempt-resume re-prefill)."""
+        """The prefill-sampled token's value is on the host (again, after
+        a preempt-resume re-prefill): the prefill phase ends where a token
+        exists for a client.  A non-streaming request without EOS defers
+        that fetch to its finish, so its decode phase is ~0."""
         if not self.enabled:
             return
         rec = self._open.get(rid)

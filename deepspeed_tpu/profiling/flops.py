@@ -103,22 +103,26 @@ class TrainFlopsMeter:
     the first finishes) — the ``anchor`` (the step's loss output) is
     blocked on first, pinning each boundary to real device completion.
     ``ds_train_mfu`` is published only where :func:`peak_flops` knows the
-    device; elsewhere (the CPU) only the TFLOP/s gauge moves.
+    device; elsewhere (the CPU) only the TFLOP/s gauge moves.  The FLOPs
+    are the whole mesh's (the global batch), so the peak is one device's
+    times ``num_devices``.
     The sync happens ONLY while the registry is enabled: telemetry users
     pay a boundary bubble (the ``wall_clock_breakdown`` trade, scoped the
     same way); disabled runs are untouched.  The first call only arms the
     clock.  One branch + no work while the registry is disabled.
     """
 
-    def __init__(self, registry: Optional[MetricsRegistry] = None):
+    def __init__(self, registry: Optional[MetricsRegistry] = None,
+                 num_devices: int = 1):
         reg = registry if registry is not None else get_registry()
         self._registry = reg
+        self._num_devices = max(1, int(num_devices))
         self._tflops = reg.gauge(
             "ds_train_tflops", "achieved train TFLOP/s (static FLOP "
             "estimate / boundary-to-boundary wall time)")
         self._mfu = reg.gauge(
             "ds_train_mfu", "model FLOPs utilization: ds_train_tflops / "
-            "device peak")
+            "(device peak x devices in the mesh)")
         self._last_t: Optional[float] = None
 
     def reset_clock(self) -> None:
@@ -150,4 +154,5 @@ class TrainFlopsMeter:
         self._tflops.set(round(tflops, 4))
         peak = _peak_or_none()
         if peak is not None:
-            self._mfu.set(round(tflops * 1e12 / peak, 6))
+            self._mfu.set(round(tflops * 1e12 / (peak * self._num_devices),
+                                6))

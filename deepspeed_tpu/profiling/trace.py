@@ -14,24 +14,64 @@ device ops carry the ``ds_fwd_bwd`` / ``ds_optimizer_step``
 from __future__ import annotations
 
 import os
+import time
 from typing import Optional
 
 import jax
 
+from deepspeed_tpu.monitor.metrics import MetricsRegistry, get_registry
 from deepspeed_tpu.utils.logging import logger
 
 
 def annotate(name: str):
     """Host-timeline named range in the xplane trace (the NVTX-range
-    analog): ``with annotate("ds_serve_decode"): ...``.
-
-    Used by the serving loop for its per-phase ranges (``ds_serve_admit`` /
-    ``ds_serve_prefill`` / ``ds_serve_decode``) so the xplane device
-    timeline lines up with the host-side ``ds_serve_*`` histograms
-    (monitor/metrics.py) phase for phase.  Near-free when no trace is being
-    captured.
+    analog): ``with annotate("ds_fwd_bwd"): ...``.  Near-free when no
+    trace is being captured.  The serving loop's ranges go through
+    :class:`phase`, which writes the matching counter too.
     """
     return jax.profiler.TraceAnnotation(name)
+
+
+class phase:
+    """A host span and its counter, written together:
+    ``with phase("ds_serve_wake"): ...`` opens the ``TraceAnnotation``
+    ``<name>`` (a ``StepTraceAnnotation`` where ``step_num`` is given: it
+    gives the trace its ``Steps`` line) and, while the registry is enabled,
+    adds the elapsed ``perf_counter()`` seconds to the counter
+    ``<name>_seconds_total``.
+
+    One helper for both, so a span in the profiler's trace and the counter
+    a scrape reads cannot come to time different code.  The counter is
+    plain and unlabelled on purpose: it is what a benchmark's snapshot of
+    the registry keeps.  A parent's self time is its own seconds minus its
+    children's.  How often a phase was entered is not counted here: the
+    code inside counts what it does (``ds_serve_steps_total``,
+    ``ds_serve_prefill_chunks_total``).  With no profiler session and the
+    registry disabled this is one ``TraceAnnotation`` enter/exit and one
+    branch.
+    """
+
+    __slots__ = ("_ann", "_seconds", "_t0")
+
+    def __init__(self, name: str, registry: Optional[MetricsRegistry] = None,
+                 step_num: Optional[int] = None):
+        self._ann = (jax.profiler.TraceAnnotation(name) if step_num is None
+                     else jax.profiler.StepTraceAnnotation(
+                         name, step_num=step_num))
+        reg = registry if registry is not None else get_registry()
+        self._seconds = (reg.counter(name + "_seconds_total")
+                         if reg.enabled else None)
+
+    def __enter__(self) -> "phase":
+        self._ann.__enter__()
+        if self._seconds is not None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._seconds is not None:
+            self._seconds.inc(time.perf_counter() - self._t0)
+        self._ann.__exit__(*exc)
 
 
 def scope(name: str):

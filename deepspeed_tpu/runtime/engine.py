@@ -609,7 +609,7 @@ class DeepSpeedEngine:
         self._comm_plan = None            # set by _setup_state_telemetry
         self._flops_per_step_fn = None    # (micro, seq) -> train FLOPs
         self._flops_since_boundary = 0.0
-        self._flops_meter = TrainFlopsMeter()
+        self._flops_meter = TrainFlopsMeter(num_devices=self.mesh.devices.size)
         self._mem_telemetry = MemoryTelemetry()
         # training step timeline (docs/OBSERVABILITY.md "Distributed
         # tracing"): shares the telemetry master switch — a process that
@@ -3369,7 +3369,11 @@ class DeepSpeedEngine:
         # the host range cannot separate them (device scope rows can)
         self._goodput.push("compute")
         try:
-            with annotate("ds_fwd_bwd"):
+            # the step range gives the profiler's trace its Steps line
+            # (the serve engine's ds_serve_step is the same kind of range)
+            with jax.profiler.StepTraceAnnotation(
+                    "ds_train_step", step_num=self._host_steps), \
+                    annotate("ds_fwd_bwd"):
                 if self._anomaly_select:
                     self.state, loss, gnorm, overflow = self._fused_fn(
                         self.state, stacked, rng, self._anomaly.bound)

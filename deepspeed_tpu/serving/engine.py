@@ -73,7 +73,7 @@ from deepspeed_tpu.monitor.goodput import get_goodput_ledger
 from deepspeed_tpu.monitor.health import get_health
 from deepspeed_tpu.monitor.metrics import get_registry
 from deepspeed_tpu.monitor.request_trace import get_request_tracer
-from deepspeed_tpu.profiling.trace import annotate
+from deepspeed_tpu.profiling.trace import phase
 from deepspeed_tpu.serving.host_tier import HostPageStore
 from deepspeed_tpu.serving.paged_kv import PagedKVPool, init_paged_kv_cache
 from deepspeed_tpu.serving.prefix_cache import PrefixCache
@@ -81,6 +81,39 @@ from deepspeed_tpu.serving.scheduler import (PREFILLING, QUEUED, RUNNING,
                                              IterationScheduler, QueueFull,
                                              Request)
 from deepspeed_tpu.utils.logging import log_dist
+
+# Host phases of one scheduler iteration, all on the engine thread and
+# nested: span name (= counter prefix) -> what it wraps.  A span in the
+# profiler's trace and ``<name>_seconds_total`` in the registry each
+# (profiling/trace.py ``phase``).  The two fetches block on
+# the chip; the rest is host work during which the chip runs dry unless
+# earlier dispatches still cover it.
+SERVE_PHASES = {
+    "ds_serve_step": "one scheduler iteration",
+    "ds_serve_admit": "slot admission and prefix-cache lookup",
+    "ds_serve_prefill": "this iteration's prefill chunks",
+    "ds_serve_decode": "the decode block and its lag-1 drain",
+    "ds_serve_pages": "page allocation, eviction and preemption",
+    "ds_serve_prefill_dispatch": "chunk build and prefill program enqueue",
+    "ds_serve_first_token_fetch": "blocking fetch of the prefill-sampled "
+                                  "token (stream / EOS / last-token path)",
+    "ds_serve_wake": "slot wake-up after the last chunk",
+    "ds_serve_decode_dispatch": "argument build and decode block enqueue",
+    "ds_serve_block_fetch": "blocking fetch of deferred tokens (a block's, "
+                            "or the deferred first token)",
+    "ds_serve_release": "slot park, prefix-cache insert, page release",
+}
+
+
+def _in_phase(name: str):
+    """Run a whole engine method inside the host phase ``name``."""
+    def wrap(method):
+        @functools.wraps(method)
+        def inside(self, *args, **kwargs):
+            with self._phase(name):
+                return method(self, *args, **kwargs)
+        return inside
+    return wrap
 
 
 class ServingEngine:
@@ -166,6 +199,7 @@ class ServingEngine:
         # MetricsRegistry + HealthState PER engine so the router's /statz
         # poll and /healthz drain signal stay per-replica truths
         self._registry = registry if registry is not None else get_registry()
+        self._phase = functools.partial(phase, registry=self._registry)
         self.health = health if health is not None else get_health()
         self.scheduler = IterationScheduler(
             self.num_slots, registry=self._registry,
@@ -354,15 +388,19 @@ class ServingEngine:
         # disabled — see docs/OBSERVABILITY.md for the schema)
         reg = self._registry
         self._m_ttft = reg.histogram(
-            "ds_serve_ttft_seconds", "submit -> first-token dispatch")
+            "ds_serve_ttft_seconds",
+            "submit -> first output token's value on the host (for a "
+            "non-streaming request without EOS that is its finish)")
         self._m_tpot = reg.histogram(
             "ds_serve_tpot_seconds",
-            "per-output-token latency (first token -> finish)")
-        self._m_prefill_s = reg.histogram(
-            "ds_serve_prefill_chunk_seconds", "one chunked-prefill dispatch")
-        self._m_decode_s = reg.histogram(
-            "ds_serve_decode_block_seconds",
-            "one compiled decode-block dispatch (host side)")
+            "per-output-token latency (first token on the host -> finish); "
+            "streaming and EOS requests only: the others fetch every token "
+            "at their finish")
+        # host phases of one scheduler iteration: a span in the profiler's
+        # trace and a seconds counter each, written by phase()
+        for name, what in SERVE_PHASES.items():
+            reg.counter(name + "_seconds_total",
+                        f"host seconds inside {name}: {what}")
         self._m_prefill_chunks = reg.counter(
             "ds_serve_prefill_chunks_total", "prefill chunks dispatched")
         self._m_prefill_toks = reg.counter(
@@ -532,7 +570,8 @@ class ServingEngine:
         # (or `drain` during a drain window).  Ticks ride the same seam.
         self._goodput.push("compute")
         try:
-            return self._step_inner()
+            with self._phase("ds_serve_step", step_num=self.steps):
+                return self._step_inner()
         finally:
             self._goodput.pop()
             self._goodput.tick()
@@ -553,7 +592,7 @@ class ServingEngine:
         # 1. admission: freed slots pick up the oldest queued requests;
         #    a prefix-cache hit pre-populates the slot's page table with
         #    shared pages and moves the prefill frontier past them
-        with annotate("ds_serve_admit"):
+        with self._phase("ds_serve_admit"):
             for req in self.scheduler.admit():
                 self._pos[req.slot] = 0
                 self._active[req.slot] = False
@@ -562,12 +601,12 @@ class ServingEngine:
                     self._admit_prefix(req)
         # 2. chunked prefill, oldest admissions first (bounded per
         #    iteration so running slots' decode latency stays bounded)
-        with annotate("ds_serve_prefill"):
+        with self._phase("ds_serve_prefill"):
             for req in self.scheduler.prefilling()[: self.max_prefill_chunks]:
                 self._prefill_one_chunk(req)
         # 3. decode one block for every active slot
         if self._active.any():
-            with annotate("ds_serve_decode"):
+            with self._phase("ds_serve_decode"):
                 self._decode_block()
         elif self._outstanding:
             # nothing left to dispatch: flush pending finish events so the
@@ -1545,6 +1584,7 @@ class ServingEngine:
     # ------------------------------------------------------------------
     # paged-pool allocation + preemption
     # ------------------------------------------------------------------
+    @_in_phase("ds_serve_pages")
     def _ensure_pages(self, req: Request, tokens: int) -> bool:
         """Allocate pages so ``req``'s slot covers ``tokens`` tokens.
         Under pool pressure, first drain any deferred finish events (a
@@ -1633,51 +1673,44 @@ class ServingEngine:
         c = min(self.prefill_chunk, n_prefix - off)
         if self.paged and not self._ensure_pages(req, off + c):
             return                       # self-preempted: resumes later
-        cb = pow2_bucket(c, lo=8, cap=self.cache_len - off)  # pow2 bucket
-        chunk = np.zeros((1, cb), np.int32)
-        chunk[0, :c] = prefix[off:off + c]
-        self._rng, srng = jax.random.split(self._rng)
-        if self.paged:
-            tok_dev, self._cache = self._prefill_fn(cb)(
-                self.engine._params, self._cache,
-                jnp.asarray(self.pool.page_table[slot]), jnp.asarray(chunk),
-                jnp.asarray(off, jnp.int32), jnp.asarray(c - 1, jnp.int32),
-                srng)
-        else:
-            tok_dev, self._cache = self._prefill_fn(cb)(
-                self.engine._params, self._cache, jnp.asarray(chunk),
-                jnp.asarray(slot, jnp.int32), jnp.asarray(off, jnp.int32),
-                jnp.asarray(c - 1, jnp.int32), srng)
-        req.prefill_pos += c
-        t1 = time.perf_counter()
-        self._tracer.span(req.request_id, "prefill_chunk", t0, t1, c)
-        self._m_prefill_s.record(t1 - t0)
-        self._m_prefill_chunks.inc()
-        self._m_prefill_toks.inc(c)
-        # parked rows write junk at their own pos; keeping pos = prefill
-        # progress means the NEXT chunk overwrites that row before any
-        # query attends it
-        self._pos[slot] = req.prefill_pos
-        if req.prefill_pos < n_prefix:
-            # mirror the frontier onto the DEVICE pos carry: the decode
-            # block's parked junk write for this row must land at the
-            # frontier (overwritten by the next chunk), not at row 0 the
-            # previous chunk already filled
-            self._pos_dev = self._setpos_fn(
-                self._pos_dev, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(req.prefill_pos, jnp.int32))
-            return
+        with self._phase("ds_serve_prefill_dispatch"):
+            cb = pow2_bucket(c, lo=8, cap=self.cache_len - off)
+            chunk = np.zeros((1, cb), np.int32)
+            chunk[0, :c] = prefix[off:off + c]
+            self._rng, srng = jax.random.split(self._rng)
+            if self.paged:
+                tok_dev, self._cache = self._prefill_fn(cb)(
+                    self.engine._params, self._cache,
+                    jnp.asarray(self.pool.page_table[slot]),
+                    jnp.asarray(chunk), jnp.asarray(off, jnp.int32),
+                    jnp.asarray(c - 1, jnp.int32), srng)
+            else:
+                tok_dev, self._cache = self._prefill_fn(cb)(
+                    self.engine._params, self._cache, jnp.asarray(chunk),
+                    jnp.asarray(slot, jnp.int32), jnp.asarray(off, jnp.int32),
+                    jnp.asarray(c - 1, jnp.int32), srng)
+            req.prefill_pos += c
+            self._tracer.span(req.request_id, "prefill_chunk", t0,
+                              time.perf_counter(), c)
+            self._m_prefill_chunks.inc()
+            self._m_prefill_toks.inc(c)
+            # parked rows write junk at their own pos; keeping pos =
+            # prefill progress means the NEXT chunk overwrites that row
+            # before any query attends it
+            self._pos[slot] = req.prefill_pos
+            if req.prefill_pos < n_prefix:
+                # mirror the frontier onto the DEVICE pos carry: the decode
+                # block's parked junk write for this row must land at the
+                # frontier (overwritten by the next chunk), not at row 0
+                # the previous chunk already filled
+                self._pos_dev = self._setpos_fn(
+                    self._pos_dev, jnp.asarray(slot, jnp.int32),
+                    jnp.asarray(req.prefill_pos, jnp.int32))
+                return
         # prefix fully resident: the next token came out of the final
         # chunk's program.  Its VALUE is only fetched when scheduling
         # depends on it (EOS) — otherwise it stays on device and the
         # pipeline keeps flowing.
-        tpf = time.perf_counter()
-        if not req.t_first_token:        # not re-recorded on a resume
-            req.t_first_token = tpf
-            # dispatch-time TTFT: on the sync-free path the token VALUE is
-            # still device-resident, but it exists and later work is
-            # ordered behind it
-            self._m_ttft.record(req.t_first_token - req.t_submit)
         if req.prefill_only:
             # prefill-role finish (disaggregated serving): the prompt KV
             # is resident — capture the full prompt pages for the
@@ -1688,9 +1721,6 @@ class ServingEngine:
             self._capture_handoff(req)
             self._release(req, "prefill_done")
             return
-        # prefix resident + first token dispatched: the request's decode
-        # phase begins here (re-entered after a preempt-resume re-prefill)
-        self._tracer.decode_start(req.request_id, tpf)
         S = n_prefix
         # The position bound is ABSOLUTE, so it is invariant across
         # preempt-resume (prefix grows by exactly the tokens produced).
@@ -1707,7 +1737,9 @@ class ServingEngine:
             # streaming requests also take the sync: the first token IS
             # the first chunk on the wire — deferring it would hold TTFT
             # hostage to the first decode block's drain
-            first = int(tok_dev)         # the once-per-request EOS sync
+            with self._phase("ds_serve_first_token_fetch"):
+                first = int(tok_dev)     # the once-per-request EOS sync
+            self._first_token_on_host(req)
             req.output_tokens.append(first)
             if req.eos_token_id >= 0 and first == req.eos_token_id:
                 self._release(req, "eos")
@@ -1720,16 +1752,31 @@ class ServingEngine:
                 return
         else:
             req.pending_blocks.append(("tok", tok_dev))
-        req.state = RUNNING
-        self._last_dev = self._last_dev.at[slot].set(tok_dev)
-        self._pos_dev, self._act_dev = self._wake_fn(
-            self._pos_dev, self._act_dev, jnp.asarray(slot, jnp.int32),
-            jnp.asarray(S, jnp.int32))
-        self._pos[slot] = S
-        self._drained_pos[slot] = S
-        self._limit[slot] = limit
-        self._eos[slot] = req.eos_token_id
-        self._active[slot] = True
+        with self._phase("ds_serve_wake"):
+            req.state = RUNNING
+            self._last_dev = self._last_dev.at[slot].set(tok_dev)
+            self._pos_dev, self._act_dev = self._wake_fn(
+                self._pos_dev, self._act_dev, jnp.asarray(slot, jnp.int32),
+                jnp.asarray(S, jnp.int32))
+            self._pos[slot] = S
+            self._drained_pos[slot] = S
+            self._limit[slot] = limit
+            self._eos[slot] = req.eos_token_id
+            self._active[slot] = True
+
+    def _first_token_on_host(self, req: Request) -> None:
+        """The prefill-sampled token's VALUE has just reached the host:
+        the one place ``t_first_token``, ``ds_serve_ttft_seconds`` and the
+        tracer's prefill -> decode edge are stamped, on every path (right
+        after ``int(tok_dev)`` for a stream / EOS / last-token request; at
+        the deferred fetch, which is the finish, for the rest).  After a
+        preempt-resume the re-prefill's token passes here again: the edge
+        is recorded, the first-token stamp is not taken twice."""
+        t = time.perf_counter()
+        if not req.t_first_token:
+            req.t_first_token = t
+            self._m_ttft.record(t - req.t_submit)
+        self._tracer.decode_start(req.request_id, t)
 
     def _prefill_fn(self, cb: int):
         """Per-slot chunked prefill, compiled once per pow2 chunk bucket.
@@ -1839,15 +1886,15 @@ class ServingEngine:
             running = [r for r in running if r.state == RUNNING]
             if not self._active.any():
                 return
-        args = [self._loop_params(), self._cache, self._last_dev,
-                self._pos_dev, self._act_dev, jnp.asarray(self._limit),
-                jnp.asarray(self._eos), self._rng]
-        if self.paged:
-            args.append(jnp.asarray(self.pool.page_table))
-        (toks, valid, self._last_dev, self._pos_dev, self._act_dev,
-         self._cache, self._rng) = self._block()(*args)
+        with self._phase("ds_serve_decode_dispatch"):
+            args = [self._loop_params(), self._cache, self._last_dev,
+                    self._pos_dev, self._act_dev, jnp.asarray(self._limit),
+                    jnp.asarray(self._eos), self._rng]
+            if self.paged:
+                args.append(jnp.asarray(self.pool.page_table))
+            (toks, valid, self._last_dev, self._pos_dev, self._act_dev,
+             self._cache, self._rng) = self._block()(*args)
         t1 = time.perf_counter()
-        self._m_decode_s.record(t1 - t0)
         idx = self._next_block
         self._next_block += 1
         refs = 0
@@ -1897,9 +1944,10 @@ class ServingEngine:
         the sync-free tests instrument."""
         entry = self._block_np.get(idx)
         if entry is None:
-            toks = np.asarray(self._blocks[idx])  # dslint: disable=DSL002 -- THE deliberate deferred fetch: drains run >=1 block behind dispatch (lag 1), finish-fetches overlap queued blocks; pinned structurally in test_paged_kv
-            valid = (np.asarray(self._block_valid[idx])  # dslint: disable=DSL002 -- same deferred-fetch seam (valid mask rides the same memoized entry)
-                     if idx in self._block_valid else None)
+            with self._phase("ds_serve_block_fetch"):
+                toks = np.asarray(self._blocks[idx])  # dslint: disable=DSL002 -- THE deliberate deferred fetch: drains run >=1 block behind dispatch (lag 1), finish-fetches overlap queued blocks; pinned structurally in test_paged_kv
+                valid = (np.asarray(self._block_valid[idx])  # dslint: disable=DSL002 -- same deferred-fetch seam (valid mask rides the same memoized entry)
+                         if idx in self._block_valid else None)
             entry = self._block_np[idx] = (toks, valid)
         return entry
 
@@ -1942,6 +1990,7 @@ class ServingEngine:
         while self._outstanding:
             self._drain_one()
 
+    @_in_phase("ds_serve_release")
     def _release(self, req: Request, reason: str) -> None:
         """Finish the request, park its slot at depth 0 (the parked row's
         junk writes land on row 0 / the junk page, overwritten or never
@@ -1973,7 +2022,11 @@ class ServingEngine:
             self._m_pages_free.set(self.pool.pages_free)
         req.finish_reason = reason
         n = len(req.output_tokens)
-        if n > 1 and req.t_first_token:
+        # a pace only where tokens reached the host as they were made: a
+        # non-streaming request without EOS fetched all of its tokens at
+        # one instant, its finish (t_first_token is stamped there too)
+        if (n > 1 and req.t_first_token
+                and (req.stream or req.eos_token_id >= 0)):
             self._m_tpot.record((time.perf_counter() - req.t_first_token)
                                 / (n - 1))
         self.scheduler.finish(req)
@@ -1988,7 +2041,10 @@ class ServingEngine:
         already queued behind it."""
         for entry in req.pending_blocks:
             if entry[0] == "tok":                 # prefill-sampled token
-                req.output_tokens.append(int(entry[1]))
+                with self._phase("ds_serve_block_fetch"):
+                    first = int(entry[1])
+                self._first_token_on_host(req)
+                req.output_tokens.append(first)
                 continue
             idx, n = entry
             toks, _ = self._fetch_block(idx)

@@ -72,6 +72,10 @@ class Request:
     pending_blocks: List = field(default_factory=list)
     t_submit: float = 0.0
     t_admit: float = 0.0                # slot assignment (queue wait ends)
+    # first output token's VALUE on the host (after the fetch returned):
+    # the earliest a client can be sent it.  A non-streaming request
+    # without an EOS id defers that fetch to its finish (the sync-free
+    # path), so there this is stamped at finish.  Not re-stamped on resume.
     t_first_token: float = 0.0
     t_finish: float = 0.0
     # absolute service deadline (perf_counter clock; 0 = none): a request

@@ -10,6 +10,7 @@ import os
 import re
 import sys
 import threading
+import types
 
 import pytest
 
@@ -644,6 +645,24 @@ def test_metrics_dump_renders_snapshot_and_csv(tmp_path):
 
 _DOC = os.path.join(os.path.dirname(__file__), "..", "..", "docs",
                     "OBSERVABILITY.md")
+
+
+@pytest.mark.parametrize("num_devices", [1, 4])
+def test_train_mfu_divides_by_the_whole_meshs_peak(monkeypatch, num_devices):
+    """The step's FLOPs are the global batch's, so utilisation is against
+    one device's peak times the devices in the mesh."""
+    from deepspeed_tpu.profiling import flops
+
+    monkeypatch.setattr(flops, "_peak_or_none", lambda: 100e12)
+    clock = iter([10.0, 12.0])
+    monkeypatch.setattr(flops, "time", types.SimpleNamespace(
+        perf_counter=lambda: next(clock)))
+    reg = MetricsRegistry().enable()
+    meter = flops.TrainFlopsMeter(reg, num_devices=num_devices)
+    meter.observe_boundary(80e12)          # arms the clock
+    meter.observe_boundary(80e12)          # 80 TFLOP in 2 s = 40 TFLOP/s
+    assert reg.get("ds_train_tflops").value == pytest.approx(40.0)
+    assert reg.get("ds_train_mfu").value == pytest.approx(0.4 / num_devices)
 
 
 def test_namespace_guard_all_metrics_documented(devices):

@@ -1,0 +1,337 @@
+"""The serve path's own spans, counters and stamps (ISSUE 25): the first
+token is stamped where its value reaches the host, on every path; a host
+phase's span and its counter are written together and cost nothing the
+chip runs; and the benchmark's readers of them decompose the TTFT it times
+from outside, and the chip's idle time, without remainder.  CPU, tiny
+model: counts and identities, never a speed."""
+
+import os
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import deepspeed_tpu
+from deepspeed_tpu.comm.mesh import build_mesh, set_global_mesh
+from deepspeed_tpu.models import causal_lm
+from deepspeed_tpu.monitor.metrics import MetricsRegistry, get_registry
+from deepspeed_tpu.monitor.request_trace import get_request_tracer
+from deepspeed_tpu.profiling.trace import phase
+from deepspeed_tpu.serving.engine import SERVE_PHASES
+from tests.unit.hlo_text import program_text
+
+from benchmarks.lib import host_spans
+from benchmarks.lib import trace_reduce as tr
+from benchmarks.lib.manifest import Bench
+from benchmarks.lib.stats import median
+from benchmarks.lib.traffic import Arrival
+
+FETCH_DELAY_S = 0.03
+
+
+@pytest.fixture(scope="module")
+def serve(devices):
+    mesh = build_mesh(fsdp=8, devices=devices)
+    set_global_mesh(mesh)
+    model = causal_lm("llama-tiny", mesh=mesh, num_layers=2, hidden_size=64,
+                      intermediate_size=128, num_heads=4, num_kv_heads=2,
+                      vocab_size=256, remat=False)
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    engine = deepspeed_tpu.init_serving(
+        model, config={"dtype": "float32", "max_out_tokens": 64,
+                       "kv_page_tokens": 16},
+        num_slots=2, prefill_chunk=4, decode_block_tokens=3)
+    engine.set_params(params)
+    yield engine
+    engine.close()
+
+
+def _prompts(n, seed=0):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, 256, int(k), dtype=np.int32)
+            for k in rng.integers(3, 12, n)]
+
+
+# ---------------------------------------------------------------------------
+# (a) the first token is stamped where it reaches the host
+# ---------------------------------------------------------------------------
+
+class _SlowToken:
+    """A prefill program's sampled token whose VALUE takes a while to
+    reach the host, as behind a busy chip; still an array to jax."""
+
+    def __init__(self, array):
+        self.array = array
+
+    def __int__(self):
+        time.sleep(FETCH_DELAY_S)
+        return int(self.array)
+
+    def __jax_array__(self):
+        return self.array
+
+
+@pytest.mark.parametrize("path", ["stream", "eos", "deferred"])
+def test_first_token_is_stamped_after_the_fetch(serve, monkeypatch, path):
+    real = serve._prefill_fn
+
+    def slow(cb):
+        def program(*args):
+            tok, cache = real(cb)(*args)
+            return _SlowToken(tok), cache
+        return program
+
+    monkeypatch.setattr(serve, "_prefill_fn", slow)
+    reg = get_registry()
+    reg.enable()
+    reg.reset()
+    tracer = get_request_tracer()
+    tracer.reset()
+    tracer.enable()
+    kw = {"stream": {"stream": True}, "eos": {"eos_token_id": 255},
+          "deferred": {}}[path]
+    reqs = [serve.submit(p, max_new_tokens=6, **kw) for p in _prompts(3)]
+    seen_at = {}
+    while serve.scheduler.has_work:
+        serve.step()
+        for r in reqs:
+            if r.output_tokens and r.request_id not in seen_at:
+                seen_at[r.request_id] = time.perf_counter()
+    by_id = {r["id"]: r for r in tracer.completed()}
+    for r in reqs:
+        assert r.done and r.output_tokens
+        assert r.t_submit <= r.t_admit
+        # the value reached the host a fetch later than its program was
+        # enqueued (the last chunk's span ends there), and no token was
+        # visible outside before the stamp
+        enqueued = max(t1 for kind, _, t1, _ in by_id[r.request_id]["spans"]
+                       if kind == "prefill_chunk")
+        assert r.t_first_token - enqueued >= FETCH_DELAY_S
+        assert r.t_first_token <= seen_at[r.request_id]
+        assert r.t_first_token <= r.t_finish
+        if path == "deferred":
+            # the sync-free path fetches at finish, by design
+            assert r.pending_blocks == []
+            assert r.t_finish - r.t_first_token < FETCH_DELAY_S
+        else:
+            assert r.t_finish - r.t_first_token > 0
+        # the tracer's prefill -> decode edge is the same instant
+        assert by_id[r.request_id]["t_first_token"] == r.t_first_token
+    ttft = reg.get("ds_serve_ttft_seconds")
+    assert ttft.count == len(reqs)
+    assert ttft.sum == pytest.approx(
+        sum(r.t_first_token - r.t_submit for r in reqs), rel=1e-9)
+    assert ttft.sum >= len(reqs) * FETCH_DELAY_S
+    # a pace is recorded where tokens reached the host as they were made:
+    # a deferred request got all of its tokens at one instant, its finish
+    assert reg.get("ds_serve_tpot_seconds").count == (
+        0 if path == "deferred" else len(reqs))
+
+
+# ---------------------------------------------------------------------------
+# (b) lateness + queue + prefill + step tail = the TTFT timed from outside
+# ---------------------------------------------------------------------------
+
+def test_ttft_parts_sum_to_the_outside_ttft(serve):
+    bench = Bench()
+    driver = bench.driver("serve_open_loop")
+    rng = np.random.default_rng(3)
+    # more requests than slots, some due together: queueing and lateness
+    schedule = [Arrival(0.02 * (i // 2), p, int(rng.integers(2, 7)))
+                for i, p in enumerate(_prompts(10, seed=3))]
+    res = driver.drive(serve, schedule, 0.5, 60.0)
+    reg = get_registry()
+    reg.enable()             # a run that calls a reader has the registry on
+    ctx = {"loop": {"records": res["records"], "schedule": schedule,
+                    "late_s": res["late_s"], "until_s": 0.5},
+           "trace_window": None, "counters": {"begin": reg.snapshot()}}
+    parts = host_spans.ttft_parts(ctx)
+    assert len(parts) == len(schedule)
+    for p, late_s in zip(parts, res["late_s"]):
+        assert min(p["queue"], p["prefill"]) >= 0
+        # the loop's clock and submit()'s stamp are microseconds apart on
+        # a quiet host; a loaded one may put a thread switch between them
+        assert p["late"] > late_s - 1e-3 and p["tail"] > -1e-3
+        assert p["late"] + p["queue"] + p["prefill"] + p["tail"] == \
+            pytest.approx(p["ttft"], abs=1e-6)
+    assert median([p["late"] - l for p, l in zip(parts, res["late_s"])]) \
+        == pytest.approx(0.0, abs=1e-4)
+    assert max(p["queue"] for p in parts) > 0      # someone waited for a slot
+    for name, part in (("ttft_queue_p50_ms", "queue"),
+                       ("ttft_prefill_p50_ms", "prefill"),
+                       ("ttft_step_tail_p50_ms", "tail")):
+        assert bench.reader(name).read(ctx) == pytest.approx(
+            median([p[part] for p in parts]) * 1e3)
+    # a traced run reads requests due a second before the profiler started
+    ctx["trace_window"] = (0.5, 0.6)
+    assert host_spans.ttft_parts(ctx) == []
+    assert bench.reader("ttft_queue_p50_ms").read(ctx) is None
+
+
+def test_readers_return_nothing_for_a_program_without_the_stamps():
+    from types import SimpleNamespace as NS
+
+    # its stamps look the same; the phases' counters are what it lacks
+    old = NS(t_submit=1.0, t_admit=1.1, t_first_token=1.2, preemptions=0)
+    counters = {"ds_serve_steps_total": 1, "ds_serve_prefill_chunks_total": 1}
+    ctx = {"loop": {"records": [NS(req=old, t_first=0.3)],
+                    "schedule": [NS(due_s=0.0)], "late_s": [0.0],
+                    "until_s": 1.0},
+           "trace_window": None, "counters": {"begin": counters,
+                                              "trace_start": counters}}
+    bench = Bench()
+    for name in ("ttft_queue_p50_ms", "ttft_prefill_p50_ms",
+                 "ttft_step_tail_p50_ms", "host_work_share"):
+        assert bench.reader(name).read(ctx) is None
+    ctx["trace"] = None
+    assert bench.reader("idle_fetch_share").read(ctx) is None
+
+
+def test_host_shares_read_the_phase_counters(serve):
+    reg = get_registry()
+    reg.enable()
+    reg.reset()
+    snap = lambda: {k: v for k, v in reg.snapshot().items()
+                    if isinstance(v, (int, float))}
+    begin = snap()
+    t0 = time.perf_counter()
+    reqs = [serve.submit(p, max_new_tokens=5, stream=True)
+            for p in _prompts(4, seed=5)]
+    serve.run()
+    wall = time.perf_counter() - t0
+    end = snap()
+    assert all(r.done for r in reqs)
+    ctx = {"counters": {"begin": begin, "trace_start": end},
+           "loop": {"until_s": wall}}
+    bench = Bench()
+    work = bench.reader("host_work_share").read(ctx)
+    step_s = end["ds_serve_step_seconds_total"]
+    blocked_s = (end["ds_serve_first_token_fetch_seconds_total"]
+                 + end["ds_serve_block_fetch_seconds_total"])
+    assert work == pytest.approx(100.0 * (step_s - blocked_s) / wall)
+    assert 0 < work < 100 and blocked_s > 0
+    # every phase was entered and has one counter, its seconds; how often
+    # is counted by the code inside (steps, chunks); children fit inside
+    # their parents
+    for name in SERVE_PHASES:
+        assert end[name + "_seconds_total"] > 0, name
+        assert name + "_total" not in end
+    assert end["ds_serve_steps_total"] > 0
+    assert end["ds_serve_prefill_chunks_total"] >= len(reqs)
+    parents = sum(end[f"ds_serve_{p}_seconds_total"]
+                  for p in ("admit", "prefill", "decode"))
+    assert parents <= step_s
+    assert end["ds_serve_prefill_dispatch_seconds_total"] + \
+        end["ds_serve_first_token_fetch_seconds_total"] + \
+        end["ds_serve_wake_seconds_total"] <= \
+        end["ds_serve_prefill_seconds_total"]
+
+
+# ---------------------------------------------------------------------------
+# (c) phase(): one span, one counter, nothing the chip runs
+# ---------------------------------------------------------------------------
+
+def test_phase_counter_equals_its_spans(tmp_path):
+    reg = MetricsRegistry()
+    with phase("ds_test_phase", registry=reg):           # registry off
+        time.sleep(0.001)
+    assert reg.names() == []
+    reg.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for i in range(8):
+            with phase("ds_test_phase", registry=reg):
+                time.sleep(0.005)
+            with phase("ds_test_step", registry=reg, step_num=i):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    trace = tr.load_xplane(tr.find_xplane(str(tmp_path)))
+    for name, least in (("ds_test_phase", 0.04), ("ds_test_step", 0.016)):
+        spans = tr.host_events(trace, name)
+        assert len(spans) == 8
+        assert reg.get(name + "_total") is None         # seconds only
+        seconds = reg.get(name + "_seconds_total").value
+        assert seconds >= least
+        assert seconds == pytest.approx(sum(e.dur for e in spans) / 1e9,
+                                        rel=0.05)
+
+
+def _programs(serve):
+    serve._block_fn, serve._prefill_fns = None, {}       # trace them anew
+    serve.pool.ensure(0, 8)
+    try:
+        block = serve._block().lower(
+            serve._loop_params(), serve._cache, serve._last_dev,
+            serve._pos_dev, serve._act_dev, jnp.asarray(serve._limit),
+            jnp.asarray(serve._eos), serve._rng,
+            jnp.asarray(serve.pool.page_table))
+        prefill = serve._prefill_fn(8).lower(
+            serve.engine._params, serve._cache,
+            jnp.asarray(serve.pool.page_table[0]), jnp.zeros((1, 8), jnp.int32),
+            jnp.asarray(0, jnp.int32), jnp.asarray(7, jnp.int32), serve._rng)
+    finally:
+        serve.pool.release(0)
+    return program_text(block.compile()), program_text(prefill.compile())
+
+
+def test_programs_are_the_same_with_tracing_on_and_off(serve, tmp_path):
+    reg, tracer = get_registry(), get_request_tracer()
+    reg.disable()
+    tracer.disable()
+    off = _programs(serve)
+    reg.enable()
+    tracer.enable()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        on = _programs(serve)
+    finally:
+        jax.profiler.stop_trace()
+    assert all("ENTRY" in t for t in off) and on == off
+
+
+# ---------------------------------------------------------------------------
+# (d) the chip's idle time, range by range, on a trace recorded on a v5e
+# (my chip run, PR 25): 150 ms from the middle of the traced window of
+# mistral-7b-L8.serve-chat, cut by `python -m benchmarks.lib.host_spans
+# <trace dir> --cut`
+# ---------------------------------------------------------------------------
+
+SPANS_FIXTURE = os.path.join(os.path.dirname(tr.__file__), os.pardir,
+                             "tests", "fixtures",
+                             "v5e_serve_spans_150ms.json.gz")
+
+
+def test_idle_shares_partition_the_device_idle_share():
+    trace = tr.load_events(SPANS_FIXTURE)
+    idle = host_spans.idle_by_span(trace)
+    summary = tr.summarize(trace, host_scopes=(
+        "ds_serve_admit", "ds_serve_prefill", "ds_serve_decode"))
+    share = lambda s: 100.0 * s / idle["window_s"]
+    device_idle_share = 100.0 * (
+        1.0 - summary["busy_s_chip0"] / summary["window_s"])
+    assert share(idle["fetch_s"]) + share(idle["host_work_s"]) + \
+        share(idle["outside_s"]) == pytest.approx(device_idle_share, abs=0.1)
+    assert idle["idle_s"] > 0 and idle["host_work_s"] > 0
+    assert sum(idle["by_span"].values()) == pytest.approx(idle["idle_s"])
+    # the children refine the three parents the ledger's idle_gaps has:
+    # what summarize() puts under a parent is that parent's self time plus
+    # its children's
+    spans = idle["by_span"]
+    under = {"ds_serve_prefill": ("ds_serve_prefill_dispatch",
+                                  "ds_serve_first_token_fetch",
+                                  "ds_serve_wake"),
+             "ds_serve_decode": ("ds_serve_decode_dispatch",)}
+    for parent, children in under.items():
+        assert spans[parent] <= summary["idle_gaps"].get(parent, 0.0) + 1e-9
+        assert sum(spans.get(c, 0.0) for c in children) <= \
+            summary["idle_gaps"].get(parent, 0.0) + 1e-9
+    assert "ds_serve_step" in idle["spans"]
+    # a trace of a program without the ranges gives nothing, not zeros
+    for lines in trace.values():
+        for line, evs in lines.items():
+            lines[line] = [e for e in evs if e.name != host_spans.STEP]
+    assert host_spans.idle_by_span(trace) is None
