@@ -39,7 +39,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.pallas.common import (interpret_flag,
                                              kernel_or_reference,
-                                             resolve_impl)
+                                             resolve_impl, round_up)
 
 NEG_INF = -1e30
 
@@ -195,10 +195,16 @@ def _flash_decode_ref(q, kcache, vcache, pos, *, scale, alibi=False):
     return o.reshape(B, H, Dh).astype(q.dtype)
 
 
-def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, slope_ref, o_ref,
-                         m_scr, l_scr, acc_scr, *, scale, block, nb, rep,
-                         hkv, alibi):
-    j = pl.program_id(1)
+def _flash_decode_kernel(*refs, scale, block, nb, alibi):
+    """One grid step = one batch row x ``hb`` KV heads x one key block (a
+    page of the paged pool): online softmax with a leading head axis.  The
+    scalar-prefetched refs lead (``pos`` first; the paged layout adds its
+    page table, which only the index maps read — it picks WHICH physical
+    page the step's K and V blocks DMA; the math here is
+    position-logical)."""
+    pos_ref = refs[0]
+    q_ref, k_ref, v_ref, slope_ref, o_ref, m_scr, l_scr, acc_scr = refs[-8:]
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
@@ -206,28 +212,29 @@ def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, slope_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # grid axis 0 walks (batch, kv-head) pairs; each batch row has its own
-    # position (continuous batching) — the scalar-prefetch buffer holds [B]
-    pos = pos_ref[pl.program_id(0) // hkv]
+    # each batch row has its own position (continuous batching)
+    pos = pos_ref[pl.program_id(0)]
 
     @pl.when(j * block <= pos)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)            # [rep, Dh]
-        k = k_ref[0].astype(jnp.float32)            # [block, Dh]
-        v = v_ref[0].astype(jnp.float32)            # [block, Dh]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+        q, k = q_ref[0], k_ref[0]                   # [hb, rep | block, Dh]
+        if q.dtype != k.dtype:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        # operands as stored: bf16 products are exact in the float32 sum
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        key_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        key_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
         if alibi:
-            s = s + slope_ref[0] * (key_pos - pos).astype(jnp.float32)
-        s = jnp.where(key_pos <= pos, s, NEG_INF)
+            s = s + slope_ref[:] * (key_pos - pos).astype(jnp.float32)
+        s = jnp.where(key_pos <= pos, s, NEG_INF)   # [hb, rep, block]
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+            p, v_ref[0].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
     @pl.when(j == nb - 1)
@@ -236,23 +243,64 @@ def _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, slope_ref, o_ref,
         o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _alibi_or_zero_slopes(B, H, Hkv, rep, alibi):
+# VMEM the decode attention kernel may spend on its double-buffered K and V
+# blocks (bytes): 4 buffers of [hb, block, Dh padded to 128 lanes].  Leaves
+# the ~16MB scoped VMEM room for the f32 copy of V and the score tiles.
+_DECODE_KV_VMEM_BYTES = 4 * 2**20
+
+
+def _kv_heads_per_step(hkv: int, block: int, dh: int, itemsize: int) -> int:
+    """Most KV heads one grid step of the decode attention kernel can take:
+    the largest divisor of ``hkv`` whose K and V blocks, double-buffered,
+    fit :data:`_DECODE_KV_VMEM_BYTES` (1 when even a single head does not)."""
+    head = 4 * block * round_up(dh, 128) * itemsize
+    return max((d for d in range(1, hkv + 1)
+                if hkv % d == 0 and d * head <= _DECODE_KV_VMEM_BYTES),
+               default=1)
+
+
+def _decode_attention(q, kcache, vcache, prefetch, kv_map, *, block, nb,
+                      scale, alibi, impl, name):
+    """The one ``pallas_call`` behind both cache layouts.  The caches are
+    taken as ``[N, Hkv, S, Dh]`` views (N = stacked layers x batch rows, or
+    x physical pages: a free reshape); ``kv_map(b, g, j, *prefetch_refs)``
+    places the ``(1, hb, block, Dh)`` K and V blocks of grid step
+    ``(b, g, j)`` of ``grid=(B, Hkv // hb, nb)``."""
+    B, H, Dh = q.shape
+    view = (-1,) + kcache.shape[-3:]
+    hkv = view[1]
+    rep = H // hkv
+    hb = _kv_heads_per_step(hkv, block, Dh, kcache.dtype.itemsize)
     if alibi:
         from deepspeed_tpu.models.layers import alibi_slopes
 
-        return jnp.tile(alibi_slopes(H).reshape(Hkv, rep, 1),
-                        (B, 1, 1)).reshape(B * Hkv, rep, 1)
-    return jnp.zeros((B * Hkv, rep, 1), jnp.float32)
-
-
-def _flash_decode_paged_kernel(pos_ref, pt_ref, q_ref, k_ref, v_ref,
-                               slope_ref, o_ref, m_scr, l_scr, acc_scr, **kw):
-    # the page table is consumed by the index maps (it picks WHICH physical
-    # page each block fetch DMAs); the in-kernel math is position-logical
-    # and identical to the contiguous kernel
-    del pt_ref
-    _flash_decode_kernel(pos_ref, q_ref, k_ref, v_ref, slope_ref, o_ref,
-                         m_scr, l_scr, acc_scr, **kw)
+        slopes = alibi_slopes(H).reshape(hkv, rep, 1)
+    else:
+        slopes = jnp.zeros((hkv, rep, 1), jnp.float32)
+    kernel = functools.partial(_flash_decode_kernel, scale=scale, block=block,
+                               nb=nb, alibi=alibi)
+    # index maps see the scalar-prefetch refs AFTER the grid indices (the
+    # kernel body sees them first)
+    heads = pl.BlockSpec((1, hb, rep, Dh), lambda b, g, j, *_: (b, g, 0, 0))
+    kv = pl.BlockSpec((1, hb, block, Dh), kv_map)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(B, hkv // hb, nb),
+        in_specs=[heads, kv, kv,
+                  pl.BlockSpec((hb, rep, 1), lambda b, g, j, *_: (g, 0, 0))],
+        out_specs=heads,
+        scratch_shapes=[pltpu.VMEM((hb, rep, 1), jnp.float32),
+                        pltpu.VMEM((hb, rep, 1), jnp.float32),
+                        pltpu.VMEM((hb, rep, Dh), jnp.float32)],
+    )
+    o = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, hkv, rep, Dh), q.dtype),
+        interpret=interpret_flag(impl),
+        name=name,
+    )(*prefetch, q.reshape(B, hkv, rep, Dh), kcache.reshape(view),
+      vcache.reshape(view), slopes)
+    return o.reshape(B, H, Dh)
 
 
 def paged_decode_reference_reason(page: int) -> Optional[str]:
@@ -273,10 +321,10 @@ def decode_reference_reason(cache_len: int, block: int) -> Optional[str]:
 
 
 def _kv_append_kernel(pp_ref, po_ref, kn_ref, vn_ref, ko_ref, vo_ref,
-                      k_out, v_out, *, rows, hkv):
+                      k_out, v_out, *, rows):
     del pp_ref                    # consumed by the index maps
-    r = po_ref[pl.program_id(0) // hkv] % rows
-    hit = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0) == r
+    r = po_ref[pl.program_id(0)] % rows
+    hit = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) == r
     k_out[0] = jnp.where(hit, kn_ref[0], ko_ref[0])
     v_out[0] = jnp.where(hit, vn_ref[0], vo_ref[0])
 
@@ -305,20 +353,18 @@ def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
         return (kcache.at[layer, pp, :, po, :].set(k.astype(kcache.dtype)),
                 vcache.at[layer, pp, :, po, :].set(v.astype(vcache.dtype)))
     rows = 32 // kcache.dtype.itemsize      # one (sublane x lane) tile
-    base = layer * P * Hkv
-    kernel = functools.partial(_kv_append_kernel, rows=rows, hkv=Hkv)
+    kernel = functools.partial(_kv_append_kernel, rows=rows)
 
-    def group(g, pp_ref, po_ref):
-        b = g // Hkv
-        return base + pp_ref[b] * Hkv + g % Hkv, po_ref[b] // rows, 0
+    def group(b, pp_ref, po_ref):
+        return layer * P + pp_ref[b], 0, po_ref[b] // rows, 0
 
-    new = pl.BlockSpec((1, 1, Dh), lambda g, pp_ref, po_ref: (g, 0, 0))
-    old = pl.BlockSpec((1, rows, Dh), group)
-    pool = jax.ShapeDtypeStruct((L * P * Hkv, page, Dh), kcache.dtype)
-    k3, v3 = pl.pallas_call(
+    new = pl.BlockSpec((1, Hkv, 1, Dh), lambda b, pp_ref, po_ref: (b, 0, 0, 0))
+    old = pl.BlockSpec((1, Hkv, rows, Dh), group)
+    pool = jax.ShapeDtypeStruct((L * P, Hkv, page, Dh), kcache.dtype)
+    k4, v4 = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(B * Hkv,),
+            num_scalar_prefetch=2, grid=(B,),
             in_specs=[new, new, old, old], out_specs=[old, old]),
         out_shape=[pool, pool],
         # operands count the two scalar-prefetch arrays: the pools are 4, 5
@@ -326,10 +372,10 @@ def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
         interpret=interpret_flag(impl),
         name="paged_kv_append",
     )(pp.astype(jnp.int32), po.astype(jnp.int32),
-      k.astype(kcache.dtype).reshape(B * Hkv, 1, Dh),
-      v.astype(vcache.dtype).reshape(B * Hkv, 1, Dh),
+      k.astype(kcache.dtype).reshape(B, Hkv, 1, Dh),
+      v.astype(vcache.dtype).reshape(B, Hkv, 1, Dh),
       kcache.reshape(pool.shape), vcache.reshape(pool.shape))
-    return k3.reshape(kcache.shape), v3.reshape(vcache.shape)
+    return k4.reshape(kcache.shape), v4.reshape(vcache.shape)
 
 
 def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
@@ -337,18 +383,21 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
     """Decode attention over the PAGED pool (``serving/paged_kv.py``):
     caches [P, Hkv, page, Dh] (or stacked [L, P, Hkv, page, Dh] with
     ``layer=l``), ``page_table`` [B, maxp] int32 naming each row's
-    physical page per logical block.  The kernel's DMA block IS the page:
-    the block index map indirects through the scalar-prefetched table
-    (``pt_ref[row, min(j, pos // page)]``), so each block-sized fetch
-    lands on the right physical page and — exactly as in the contiguous
-    kernel — blocks past each row's ``pos`` are neither fetched nor
-    computed.  The XLA path gathers the logical per-slot view and runs
-    the dense reference (CPU tests, and the page sizes
-    :func:`paged_decode_reference_reason` names)."""
-    B, H, Dh = q.shape
+    physical page per logical block.  One grid step is one batch row x
+    ``hb`` KV heads x one logical page (``grid=(B, Hkv // hb, maxp)``;
+    :func:`_kv_heads_per_step` sizes ``hb`` from the shapes, all of ``Hkv``
+    at GQA widths): a physical page holds its KV heads contiguously, so
+    the step's K and V blocks are ``[hb, page, Dh]`` slabs of one page.
+    The block index map indirects through the scalar-prefetched table
+    (``pt_ref[row, min(j, pos // page)]``), so each fetch lands on the
+    right physical page and — exactly as in the contiguous kernel —
+    pages past each row's ``pos`` are neither fetched nor computed (they
+    still cost their grid step).  The XLA path gathers the logical
+    per-slot view and runs the dense reference (CPU tests, and the page
+    sizes :func:`paged_decode_reference_reason` names)."""
     kc = kcache if layer is None else kcache[layer]
     vc = vcache if layer is None else vcache[layer]
-    Hkv, page = kc.shape[1], kc.shape[2]
+    page = kc.shape[2]
     impl = kernel_or_reference("flash_decode_paged", impl,
                                paged_decode_reference_reason(page))
     if impl == "xla":
@@ -357,49 +406,16 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
         return _flash_decode_ref(q, paged_logical_view(kc, page_table),
                                  paged_logical_view(vc, page_table), pos,
                                  scale=scale, alibi=alibi)
-    rep = H // Hkv
-    maxp = page_table.shape[1]
-    BG = B * Hkv
-    q4 = q.reshape(BG, rep, Dh)
-    if layer is None:
-        P = kcache.shape[0]
-        k3 = kcache.reshape(P * Hkv, page, Dh)
-        v3 = vcache.reshape(P * Hkv, page, Dh)
-        base = 0
-    else:
-        P = kcache.shape[1]
-        k3 = kcache.reshape(kcache.shape[0] * P * Hkv, page, Dh)
-        v3 = vcache.reshape(vcache.shape[0] * P * Hkv, page, Dh)
-        base = layer * P * Hkv
-    slopes = _alibi_or_zero_slopes(B, H, Hkv, rep, alibi)
-    kernel = functools.partial(_flash_decode_paged_kernel, scale=scale,
-                               block=page, nb=maxp, rep=rep, hkv=Hkv,
-                               alibi=alibi)
+    base = 0 if layer is None else layer * kc.shape[0]
 
-    def page_map(b, j, pos_ref, pt_ref):
-        row = b // Hkv
-        jl = jnp.minimum(j, pos_ref[row] // page)   # per-row DMA clamp
-        return base + pt_ref[row, jl] * Hkv + b % Hkv, 0, 0
+    def page_map(b, g, j, pos_ref, pt_ref):
+        jl = jnp.minimum(j, pos_ref[b] // page)     # per-row DMA clamp
+        return base + pt_ref[b, jl], g, 0, 0
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(BG, maxp),
-        in_specs=[pl.BlockSpec((1, rep, Dh), lambda b, j, p, t: (b, 0, 0)),
-                  pl.BlockSpec((1, page, Dh), page_map),
-                  pl.BlockSpec((1, page, Dh), page_map),
-                  pl.BlockSpec((1, rep, 1), lambda b, j, p, t: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, rep, Dh), lambda b, j, p, t: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((rep, 1), jnp.float32),
-                        pltpu.VMEM((rep, 1), jnp.float32),
-                        pltpu.VMEM((rep, Dh), jnp.float32)],
-    )
-    o = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BG, rep, Dh), q.dtype),
-        interpret=interpret_flag(impl),
-        name="flash_decode_paged",
-    )(pos, page_table.astype(jnp.int32), q4, k3, v3, slopes)
-    return o.reshape(B, H, Dh)
+    return _decode_attention(
+        q, kcache, vcache, (pos, page_table.astype(jnp.int32)), page_map,
+        block=page, nb=page_table.shape[1], scale=scale, alibi=alibi,
+        impl=impl, name="flash_decode_paged")
 
 
 def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
@@ -427,12 +443,9 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
         return _flash_decode_paged(q, kcache, vcache, pos, page_table,
                                    scale=scale, layer=layer, alibi=alibi,
                                    impl=impl)
-    if layer is None:
-        kc, vc = kcache, vcache
-        off = 0
-    else:
-        kc, vc = kcache[layer], vcache[layer]
-        off = layer  # the xla path slices; the pallas path offsets the map
+    # the xla path slices the stacked cache; the pallas path offsets the map
+    kc = kcache if layer is None else kcache[layer]
+    vc = vcache if layer is None else vcache[layer]
     Smax = kc.shape[2]
     # odd cache lengths (not a block multiple) would hand the kernel a
     # non-tile-aligned block — route them to the dense reference, the same
@@ -441,47 +454,14 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
                                decode_reference_reason(Smax, block))
     if impl == "xla":
         return _flash_decode_ref(q, kc, vc, pos, scale=scale, alibi=alibi)
-    B, H, Dh = q.shape
-    Hkv = kc.shape[1]
-    rep = H // Hkv
-    blk = block
-    nb = Smax // blk
-    slopes = _alibi_or_zero_slopes(B, H, Hkv, rep, alibi)
-    BG = B * Hkv
-    q4 = q.reshape(BG, rep, Dh)
-    if layer is None:
-        k3 = kcache.reshape(BG, Smax, Dh)
-        v3 = vcache.reshape(BG, Smax, Dh)
-    else:
-        k3 = kcache.reshape(kcache.shape[0] * BG, Smax, Dh)
-        v3 = vcache.reshape(vcache.shape[0] * BG, Smax, Dh)
-    base = off * BG
-    kernel = functools.partial(_flash_decode_kernel, scale=scale, block=blk,
-                               nb=nb, rep=rep, hkv=Hkv, alibi=alibi)
-    # index maps see scalar-prefetch refs AFTER the grid indices (the kernel
-    # body sees them first); b // Hkv recovers the batch row, whose own
-    # position bounds the DMA clamp (per-row length awareness)
-    clamp = lambda b, j, pos_ref: (base + b,
-                                   jnp.minimum(j, pos_ref[b // Hkv] // blk), 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(BG, nb),
-        in_specs=[pl.BlockSpec((1, rep, Dh), lambda b, j, pos_ref: (b, 0, 0)),
-                  pl.BlockSpec((1, blk, Dh), clamp),
-                  pl.BlockSpec((1, blk, Dh), clamp),
-                  pl.BlockSpec((1, rep, 1), lambda b, j, pos_ref: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, rep, Dh), lambda b, j, pos_ref: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((rep, 1), jnp.float32),
-                        pltpu.VMEM((rep, 1), jnp.float32),
-                        pltpu.VMEM((rep, Dh), jnp.float32)],
-    )
-    o = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((BG, rep, Dh), q.dtype),
-        interpret=interpret_flag(impl),
-        name="flash_decode",
-    )(pos, q4, k3, v3, slopes)
-    return o.reshape(B, H, Dh)
+    base = 0 if layer is None else layer * q.shape[0]
+
+    def clamp(b, g, j, pos_ref):                    # per-row DMA clamp
+        return base + b, g, jnp.minimum(j, pos_ref[b] // block), 0
+
+    return _decode_attention(
+        q, kcache, vcache, (pos,), clamp, block=block, nb=Smax // block,
+        scale=scale, alibi=alibi, impl=impl, name="flash_decode")
 
 
 # ---------------------------------------------------------------------------

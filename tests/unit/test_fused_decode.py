@@ -81,6 +81,11 @@ def _paged_from_logical(k, v, maxp, page, seed=7):
     return jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt)
 
 
+# (Hkv, rep, Dh) of the serving configurations: the benchmark's GQA cell,
+# gpt2-xl (25 heads: five per grid step by the VMEM rule) and gpt2-small
+PAGED_WIDTHS = [(8, 4, 128), (25, 1, 64), (12, 1, 64)]
+
+
 @pytest.mark.parametrize("pos", [[5, 300], [255, 256], [767, 0]])
 @pytest.mark.parametrize("alibi", [False, True])
 def test_flash_decode_paged_matches_logical(pos, alibi):
@@ -101,18 +106,60 @@ def test_flash_decode_paged_matches_logical(pos, alibi):
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
 
 
-def test_flash_decode_paged_layer_stacked():
-    """decode_step reads the stacked [L, P, Hkv, page, Dh] pool at a
-    static layer offset through the index map — no slice materializes."""
-    B, Hkv, Dh, page, maxp, L = 2, 2, 64, 256, 2, 2
+def _park(pt, row):
+    """Row ``row`` as the serving engine parks a slot: every logical page
+    on junk page 0 (the caller gives it ``pos`` 0)."""
+    return pt.at[row].set(0)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("Hkv,rep,Dh", PAGED_WIDTHS)
+def test_flash_decode_paged_mixed_batch(Hkv, rep, Dh, alibi, dtype):
+    """One batch as a serving step sees it, at the widths that are served:
+    a parked row on junk page 0, a row at ``pos`` 0, the two sides of a
+    page boundary and a row in the last page, under a shuffled
+    (non-monotone) page table.  In bf16 the kernel multiplies q and K as
+    stored; the reference multiplies their float32 copies: the same
+    products, summed in another order."""
+    page, maxp = 256, 3
+    pos = [0, 0, 255, 256, 700]
+    B, H = len(pos), Hkv * rep
+    q = _rand(0, B, H, Dh, dtype=dtype)
+    k = _rand(1, B, Hkv, maxp * page, Dh, dtype=dtype)
+    v = _rand(2, B, Hkv, maxp * page, Dh, dtype=dtype)
+    kp, vp, pt = _paged_from_logical(k.astype(jnp.float32),
+                                     v.astype(jnp.float32), maxp, page)
+    kp, vp = kp.astype(dtype), vp.astype(dtype)
+    pt = _park(pt, 0)
+    assert np.any(np.diff(np.asarray(pt[1:]), axis=1) < 0)   # shuffled
+    posv = jnp.asarray(pos, jnp.int32)
+    got = flash_decode(q, kp, vp, posv, page_table=pt, alibi=alibi,
+                       impl="interpret")
+    want = _flash_decode_ref(q, k, v, posv, scale=Dh ** -0.5, alibi=alibi)
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2
+    # row 0 is parked: it attends the junk page and nobody reads it
+    np.testing.assert_allclose(np.float32(got[1:]), np.float32(want[1:]),
+                               rtol=tol, atol=tol)
+    assert np.all(np.isfinite(np.float32(got[0])))
+
+
+def _stacked_pools(B, Hkv, Dh, page, maxp, L, seed=3):
     ks, vs, pools = [], [], []
     for l in range(L):
         k = _rand(10 + l, B, Hkv, maxp * page, Dh)
         v = _rand(20 + l, B, Hkv, maxp * page, Dh)
-        kp, vp, pt = _paged_from_logical(k, v, maxp, page, seed=3)
+        kp, vp, pt = _paged_from_logical(k, v, maxp, page, seed=seed)
         ks.append(k); vs.append(v); pools.append((kp, vp))
-    kp_all = jnp.stack([p[0] for p in pools])
-    vp_all = jnp.stack([p[1] for p in pools])
+    return (ks, vs, jnp.stack([p[0] for p in pools]),
+            jnp.stack([p[1] for p in pools]), pt)
+
+
+def test_flash_decode_paged_layer_stacked():
+    """decode_step reads the stacked [L, P, Hkv, page, Dh] pool at a
+    static layer offset through the index map — no slice materializes."""
+    B, Hkv, Dh, page, maxp, L = 2, 2, 64, 256, 2, 2
+    ks, vs, kp_all, vp_all, pt = _stacked_pools(B, Hkv, Dh, page, maxp, L)
     q = _rand(0, B, Hkv, Dh)
     posv = jnp.asarray([300, 511], jnp.int32)
     for l in range(L):
@@ -120,6 +167,41 @@ def test_flash_decode_paged_layer_stacked():
                            impl="interpret")
         want = _flash_decode_ref(q, ks[l], vs[l], posv, scale=Dh ** -0.5)
         np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("Hkv,rep,Dh", PAGED_WIDTHS)
+def test_flash_decode_paged_layer_stacked_widths(Hkv, rep, Dh, alibi):
+    """The stacked pool at the served widths: the layer offset counts
+    whole pages of ALL KV heads, whatever share of them a grid step takes,
+    and a parked row rides along."""
+    B, page, maxp, L = 3, 256, 2, 2
+    ks, vs, kp_all, vp_all, pt = _stacked_pools(B, Hkv, Dh, page, maxp, L)
+    pt = _park(pt, 1)
+    q = _rand(0, B, Hkv * rep, Dh)
+    posv = jnp.asarray([300, 0, 511], jnp.int32)
+    live = np.asarray([0, 2])
+    for l in range(L):
+        got = flash_decode(q, kp_all, vp_all, posv, layer=l, page_table=pt,
+                           alibi=alibi, impl="interpret")
+        want = _flash_decode_ref(q, ks[l], vs[l], posv, scale=Dh ** -0.5,
+                                 alibi=alibi)
+        np.testing.assert_allclose(got[live], want[live], rtol=2e-4,
+                                   atol=2e-4)
+
+
+@pytest.mark.parametrize("Hkv,page,Dh,want", [
+    (8, 256, 128, 8), (25, 256, 64, 5), (12, 256, 64, 12), (25, 128, 64, 25),
+    (8, 1024, 128, 4), (7, 2048, 128, 1)])
+def test_kv_heads_per_step_follows_the_vmem_budget(Hkv, page, Dh, want):
+    """``hb`` comes from the shapes: the largest divisor of Hkv whose four
+    K/V buffers (Dh padded to 128 lanes, bf16) fit the stated budget."""
+    from deepspeed_tpu.ops.pallas.decode import (_DECODE_KV_VMEM_BYTES,
+                                                 _kv_heads_per_step)
+
+    hb = _kv_heads_per_step(Hkv, page, Dh, 2)
+    assert hb == want and Hkv % hb == 0
+    assert hb == 1 or hb * 4 * page * 128 * 2 <= _DECODE_KV_VMEM_BYTES
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
@@ -146,6 +228,35 @@ def test_paged_kv_append_matches_scatter(dtype):
                           np.asarray(k[0], np.float32))
     assert np.array_equal(np.asarray(got[1][1, 4, :, 127], np.float32),
                           np.asarray(v[2], np.float32))
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("Hkv,Dh", [(8, 128), (25, 64)])
+def test_paged_kv_append_touches_only_its_rows(Hkv, Dh, dtype):
+    """At the served KV widths, rows whose ``pos % page`` falls in
+    different 16-row (bf16) / 8-row (float32) tile groups: the written rows
+    hold the new K/V of every head, and every other element of the pool —
+    other rows of the same tile group, other pages, other layers, the junk
+    page no row here parks on — is bit-identical to what went in."""
+    L, P, page, layer = 2, 6, 128, 1
+    pt = jnp.asarray([[3, 1], [5, 2], [4, 0], [2, 5]], jnp.int32)
+    pos = jnp.asarray([0, 15, 16, 128 + 77], jnp.int32)   # tiles 0, 0, 1, 4
+    pt = pt.at[3, 1].set(1)                # row 3's second page: page 1
+    B = pos.shape[0]
+    kc = _rand(0, L, P, Hkv, page, Dh, dtype=dtype)
+    vc = _rand(1, L, P, Hkv, page, Dh, dtype=dtype)
+    k = _rand(2, B, Hkv, Dh, dtype=dtype)
+    v = _rand(3, B, Hkv, Dh, dtype=dtype)
+    got = paged_kv_append(kc, vc, k, v, pos, pt, layer=layer,
+                          impl="interpret")
+    bits = lambda a: np.asarray(a.astype(jnp.float32))
+    for new, old, out in ((k, kc, got[0]), (v, vc, got[1])):
+        want = bits(old).copy()
+        for b in range(B):
+            pp = int(pt[b, int(pos[b]) // page])
+            want[layer, pp, :, int(pos[b]) % page, :] = bits(new[b])
+        np.testing.assert_array_equal(bits(out), want)
+        assert out.dtype == old.dtype and out.shape == old.shape
 
 
 def test_flash_decode_paged_small_page_falls_back():

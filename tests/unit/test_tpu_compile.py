@@ -102,25 +102,35 @@ def _decode_contiguous(w):
                 ((SLOTS,), I32)], 1
 
 
-def _decode_paged(w):
-    """The serving default: stacked pool, page = what ``kv_page_tokens: 0``
-    resolves to, read at a static layer offset through the page table."""
-    page = default_page_tokens(CACHE)
-    pool = ((2, SLOTS * CACHE // page + 1, w["Hkv"], page, w["Dh"]), BF16)
+def _paged_pool(w, slots, page, maxp, layers, pool_pages):
+    """Shapes of a stacked paged pool and its page table; the defaults are
+    the serving defaults (page = what ``kv_page_tokens: 0`` resolves to,
+    every slot's reach backed, plus the junk page)."""
+    page = page or default_page_tokens(CACHE)
+    maxp = maxp or CACHE // page
+    pool_pages = pool_pages or slots * maxp + 1
+    return (((layers, pool_pages, w["Hkv"], page, w["Dh"]), BF16),
+            ((slots, maxp), I32))
+
+
+def _decode_paged(w, slots=SLOTS, page=None, maxp=None, layers=2,
+                  pool_pages=None):
+    """The serving default: stacked pool read at a static layer offset
+    through the page table."""
+    pool, table = _paged_pool(w, slots, page, maxp, layers, pool_pages)
     fn = lambda q, k, v, pos, pt: flash_decode(
-        q, k, v, pos, layer=1, page_table=pt, impl="pallas")
-    return fn, [((SLOTS, w["H"], w["Dh"]), BF16), pool, pool,
-                ((SLOTS,), I32), ((SLOTS, CACHE // page), I32)], 1
+        q, k, v, pos, layer=layers - 1, page_table=pt, impl="pallas")
+    return fn, [((slots, w["H"], w["Dh"]), BF16), pool, pool,
+                ((slots,), I32), table], 1
 
 
-def _kv_append(w):
-    page = default_page_tokens(CACHE)
-    pool = ((2, SLOTS * CACHE // page + 1, w["Hkv"], page, w["Dh"]), BF16)
-    new = ((SLOTS, w["Hkv"], w["Dh"]), BF16)
+def _kv_append(w, slots=SLOTS, page=None, maxp=None, layers=2,
+               pool_pages=None):
+    pool, table = _paged_pool(w, slots, page, maxp, layers, pool_pages)
+    new = ((slots, w["Hkv"], w["Dh"]), BF16)
     fn = lambda kc, vc, k, v, pos, pt: paged_kv_append(
-        kc, vc, k, v, pos, pt, layer=1, impl="pallas")
-    return fn, [pool, pool, new, new, ((SLOTS,), I32),
-                ((SLOTS, CACHE // page), I32)], 1
+        kc, vc, k, v, pos, pt, layer=layers - 1, impl="pallas")
+    return fn, [pool, pool, new, new, ((slots,), I32), table], 1
 
 
 def _proj_norm(w):
@@ -226,4 +236,18 @@ KERNELS = {
 @pytest.mark.parametrize("kernel", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(v5e, kernel, widths):
     fn, shapes, want = KERNELS[kernel](WIDTHS[widths])
+    assert _custom_calls(fn, v5e, *shapes) >= want
+
+
+# the benchmark's mistral-7b-L8.serve-chat cell: 64 slots, pages of 256, 4
+# pages a row, 8 layers over a 32,768-token pool and the junk page
+SERVE_CHAT = dict(slots=64, page=256, maxp=4, layers=8, pool_pages=129)
+
+
+@pytest.mark.parametrize("kernel", [_decode_paged, _kv_append],
+                         ids=["flash_decode_paged", "paged_kv_append"])
+def test_paged_kernels_compile_at_the_serve_chat_shape(v5e, kernel):
+    """The block sizes the benchmark's serve cell runs (all 8 KV heads of a
+    page in one grid step) are ones the chip's compiler has accepted."""
+    fn, shapes, want = kernel(WIDTHS["d4096-gqa8"], **SERVE_CHAT)
     assert _custom_calls(fn, v5e, *shapes) >= want

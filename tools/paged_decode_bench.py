@@ -1,0 +1,165 @@
+"""Time the paged decode kernels alone on the chip, at one shape.
+
+``flash_decode`` over the paged pool and ``paged_kv_append``, each called
+once per layer of a stacked pool as a decode step calls them, for a list of
+position mixes.  The default shape is the benchmark's
+``mistral-7b-L8.serve-chat`` cell (64 slots, 32 heads over 8 KV heads of
+128, pages of 256, 4 pages a row, 8 layers x 129 pages, bf16).  One row of
+JSON per mix, appended to ``chiprun_out/paged_decode_bench.jsonl``.
+
+    python3 tools/paged_decode_bench.py [--tree <checkout>] [--label parent]
+        [--heads 32 --kv-heads 8 --head-dim 128 --slots 64 --page 256
+         --maxp 4 --layers 8] [--mixes 0,255,300,1023,cell]
+
+``--tree`` imports ``deepspeed_tpu`` from another checkout (the parent
+commit, unpacked beside this one), so one call times both on one chip.  A
+mix is a position shared by every row, or ``cell``: 33 rows at 150-500 and
+31 parked (position 0 on the junk page 0), the cell's mean occupancy.  The
+time of a call is the host clock around ``--reps`` programs of ``layers``
+x ``--rounds`` kernel calls each, ending in ``block_until_ready``.  TPU only.
+"""
+
+from __future__ import annotations
+
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--tree", default=None)
+    ap.add_argument("--label", default="change")
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=8)
+    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--slots", type=int, default=64)
+    ap.add_argument("--page", type=int, default=256)
+    ap.add_argument("--maxp", type=int, default=4)
+    ap.add_argument("--layers", type=int, default=8)
+    ap.add_argument("--mixes", default="0,255,300,1023,cell")
+    ap.add_argument("--rounds", type=int, default=4,
+                    help="passes over the layers inside one program")
+    ap.add_argument("--reps", type=int, default=30)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="rehearse the control flow (interpret mode)")
+    args = ap.parse_args()
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, os.path.abspath(args.tree) if args.tree else repo)
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.pallas.decode import flash_decode, paged_kv_append
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not args.allow_cpu:
+        print(f"paged_decode_bench: needs a TPU, found {dev.platform}",
+              file=sys.stderr)
+        return 1
+    impl = "pallas" if dev.platform == "tpu" else "interpret"
+    B, H, Hkv, Dh = args.slots, args.heads, args.kv_heads, args.head_dim
+    page, maxp, L = args.page, args.maxp, args.layers
+    P = B * maxp // 2 + 1             # the cell's pool: half the slots' reach
+    rng = np.random.RandomState(args.seed)
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 5)
+    pool = (L, P, Hkv, page, Dh)
+    kc = jax.random.normal(keys[0], pool, jnp.bfloat16)
+    vc = jax.random.normal(keys[1], pool, jnp.bfloat16)
+    q = jax.random.normal(keys[2], (B, H, Dh), jnp.bfloat16)
+    kn = jax.random.normal(keys[3], (B, Hkv, Dh), jnp.bfloat16)
+    vn = jax.random.normal(keys[4], (B, Hkv, Dh), jnp.bfloat16)
+
+    def mix(name):
+        """(pos [B], page_table [B, maxp]) of one mix; live rows own
+        distinct shuffled pages as far as the pool goes, parked rows sit on
+        the junk page."""
+        if name == "cell":
+            live = np.zeros(B, bool)
+            live[rng.permutation(B)[:B * 33 // 64]] = True
+            pos = np.where(live, rng.randint(150, 501, B), 0)
+            pos = np.minimum(pos, maxp * page - 1)
+        else:
+            live = np.ones(B, bool)
+            pos = np.full(B, int(name))
+        pt = np.zeros((B, maxp), np.int32)
+        free = list(rng.permutation(P - 1) + 1)
+        for b in np.flatnonzero(live):
+            for j in range(pos[b] // page + 1):
+                pt[b, j] = free.pop() if free else 1 + (b * maxp + j) % (P - 1)
+        return jnp.asarray(pos, jnp.int32), jnp.asarray(pt)
+
+    layers = list(range(L)) * args.rounds
+
+    @jax.jit
+    def attend(q, kc, vc, pos, pt):
+        for l in layers:              # each call feeds the next: in order
+            q = flash_decode(q, kc, vc, pos, layer=l, page_table=pt,
+                             impl=impl)
+        return q
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def append(kc, vc, kn, vn, pos, pt):
+        for l in layers:
+            kc, vc = paged_kv_append(kc, vc, kn, vn, pos, pt, layer=l,
+                                     impl=impl)
+        return kc, vc
+
+    def worst_error(pos, pt):
+        """Largest |kernel - jnp reference| of one layer's attention, and
+        whether the append wrote what the scatter writes off the junk page
+        (parked rows race there)."""
+        l = L - 1
+        got = flash_decode(q, kc, vc, pos, layer=l, page_table=pt, impl=impl)
+        want = flash_decode(q, kc, vc, pos, layer=l, page_table=pt,
+                            impl="xla")
+        err = jnp.max(jnp.abs(got.astype(jnp.float32)
+                              - want.astype(jnp.float32)))
+        ka, _ = paged_kv_append(kc, vc, kn, vn, pos, pt, layer=l, impl=impl)
+        kx, _ = paged_kv_append(kc, vc, kn, vn, pos, pt, layer=l, impl="xla")
+        return float(err), bool(jnp.array_equal(ka[:, 1:], kx[:, 1:]))
+
+    def timed(fn, reps):
+        jax.block_until_ready(fn())
+        t0 = time.perf_counter()
+        for _ in range(reps):
+            out = fn()
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t0) / reps / len(layers) * 1e6
+
+    out_dir = os.path.join(repo, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    shape = dict(slots=B, heads=H, kv_heads=Hkv, head_dim=Dh, page=page,
+                 maxp=maxp, layers=L, pool_pages=P)
+    reps = args.reps if dev.platform == "tpu" else 1
+    for name in args.mixes.split(","):
+        pos, pt = mix(name)
+        err, same = worst_error(pos, pt)
+        attn_us = timed(lambda: attend(q, kc, vc, pos, pt), reps)
+
+        def step():
+            nonlocal kc, vc
+            kc, vc = append(kc, vc, kn, vn, pos, pt)
+            return kc
+        app_us = timed(step, reps)
+        pages = int(jnp.sum(pos // page + 1))
+        row = {"label": args.label, "device": dev.device_kind, "mix": name,
+               "live_pages": pages, "context_tokens": int(jnp.sum(pos + 1)),
+               "flash_decode_paged_us_per_call": attn_us,
+               "paged_kv_append_us_per_call": app_us,
+               "attention_max_abs_err": err, "append_matches_scatter": same,
+               **shape}
+        print(json.dumps(row), flush=True)
+        with open(os.path.join(out_dir, "paged_decode_bench.jsonl"),
+                  "a") as f:
+            f.write(json.dumps(row) + "\n")
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
