@@ -170,7 +170,7 @@ class InferenceEngine:
         if not ok:
             if force or self._config.use_fused_decode:
                 log_dist("kernel injection requested but unsupported for "
-                         "this model/config (MoE, int8 KV cache, or tp>1; "
+                         "this model/config (int8 KV cache or tp>1; "
                          "int8 WEIGHTS alone are supported): using the "
                          "unfused decode path", ranks=[0])
             return
@@ -178,7 +178,9 @@ class InferenceEngine:
         # the largest single tensors) stay ALIASED to self._params instead
         # of being copied by a jit boundary.  The per-layer unstacked
         # weights are genuinely new buffers (that is the injection), so
-        # layer weights are resident twice — prefill keeps the plain tree.
+        # those layer weights are resident twice — prefill keeps the plain
+        # tree.  Expert weights of an MoE model pass through stacked, by
+        # reference, and are resident once.
         self._dparams = inject_decode_params(self._params, cfg)
 
     def load_checkpoint(self, path: str) -> None:

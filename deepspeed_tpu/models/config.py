@@ -9,6 +9,9 @@ families instead; `ModelConfig` spans them with feature flags:
 - Llama family  : RMSNorm + RoPE + SwiGLU + GQA   (``llama`` presets)
 - GPT-2 family  : LayerNorm + learned positions + GELU (``gpt2`` presets)
 - Mixtral family: Llama backbone + top-k MoE MLP  (``mixtral`` presets)
+- OLMoE         : the same with QK-norm, 64 experts top-8, dropless and an
+  unnormalised router (``qk_norm``, ``moe_drop_tokens=False``,
+  ``moe_norm_topk_prob=False``; benchmarks/configs/olmoe-1b-7b-L8.json)
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -42,6 +45,9 @@ class ModelConfig:
     mlp_bias: bool = False                 # biases on the MLP only (gpt-j)
     lm_head_bias: bool = False             # bias on the LM head (gpt-j)
     embed_norm: bool = False               # layernorm after token embed (bloom)
+    # RMSNorm over the WHOLE q and k projections (all heads at once), before
+    # the head split and RoPE (olmoe)
+    qk_norm: bool = False
     # gpt-neox/pythia: x + attn(ln1(x)) + mlp(ln2(x)) — the MLP reads the
     # LAYER INPUT, not the post-attention stream
     parallel_residual: bool = False
@@ -52,7 +58,12 @@ class ModelConfig:
     num_experts_per_tok: int = 2
     moe_capacity_factor: float = 1.25
     moe_aux_loss_coef: float = 0.01
-    moe_drop_tokens: bool = True       # False -> capacity covers every token
+    # True: GShard capacity, overflow dropped.  False: dropless (tokens
+    # sorted by expert, grouped matmuls; moe/sharded_moe.py)
+    moe_drop_tokens: bool = True
+    # renormalise the kept top-k router weights to sum to 1 (mixtral);
+    # False keeps the softmax probabilities as they are (olmoe)
+    moe_norm_topk_prob: bool = True
     moe_use_rts: bool = False          # random token selection for capacity
     # "scatter": O(N·k·D) scatter/gather dispatch (default);
     # "einsum": GShard one-hot [N,E,C] einsums (O(N²·k/E), parity reference)

@@ -24,7 +24,8 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
-                                         constrain, norm, _repeat_kv, rope_dim)
+                                         constrain, norm, qk_norm, _repeat_kv,
+                                         rope_dim)
 from deepspeed_tpu.ops.pallas import rope_angles
 
 NEG_INF = -1e30
@@ -341,12 +342,23 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         cos = sin = jnp.zeros((), x.dtype)
     scale = 1.0 / (Dh ** 0.5)
 
+    # A dropless MoE block takes the STACKED expert arrays whole and its own
+    # layer's index (moe/sharded_moe.py:_moe_grouped): scanned like the other
+    # weights, each layer's 0.8 GB would be copied out in front of every
+    # grouped matmul.  So they stay out of the scan's slices.
+    layers = params["layers"]
+    experts = {}
+    if cfg.is_moe and not cfg.moe_drop_tokens:
+        experts = {k: v for k, v in layers["mlp"].items() if k != "gate_w"}
+        layers = {**layers, "mlp": {"gate_w": layers["mlp"]["gate_w"]}}
+    layer_ids = jnp.arange(cfg.num_layers, dtype=jnp.int32)
+
     def layer_step(carry, xs):
         h_in = carry
         if quant_kv:
-            lp, kc, vc, ksc, vsc = xs
+            lp, l, kc, vc, ksc, vsc = xs
         else:
-            lp, kc, vc = xs
+            lp, l, kc, vc = xs
             ksc = vsc = None
         x0 = h_in  # layer input (parallel residual reads it twice)
         h = norm(h_in, lp["attn_norm"], cfg.norm, cfg.norm_eps)
@@ -358,6 +370,9 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             q = q + a["bq"].astype(h.dtype)
             k = k + a["bk"].astype(h.dtype)
             v = v + a["bv"].astype(h.dtype)
+        if cfg.qk_norm:
+            q, k = qk_norm(q, k, a["q_norm"]["scale"], a["k_norm"]["scale"],
+                           cfg.norm_eps, mesh)
         q = q.reshape(B, s, H, Dh).transpose(0, 2, 1, 3)
         k = k.reshape(B, s, Hkv, Dh).transpose(0, 2, 1, 3)
         v = v.reshape(B, s, Hkv, Dh).transpose(0, 2, 1, 3)
@@ -425,8 +440,10 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         h = norm(mlp_src, lp["mlp_norm"], cfg.norm, cfg.norm_eps)
         if cfg.is_moe:
             from deepspeed_tpu.moe.sharded_moe import moe_mlp
-            mlp_out, _ = moe_mlp(jax.tree.map(lambda a: a.astype(h.dtype), lp["mlp"]),
-                                 h, cfg, mesh)
+            mlp_out, _ = moe_mlp(
+                jax.tree.map(lambda a: a.astype(h.dtype),
+                             {**lp["mlp"], **experts}),
+                h, cfg, mesh, layer=l if experts else None)
         else:
             act = activation_fn(cfg.activation)
             m = lp["mlp"]
@@ -450,13 +467,13 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
 
     if quant_kv:
         x, (kc_new, vc_new, ks_new, vs_new) = jax.lax.scan(
-            layer_step, x, (params["layers"], cache["k"], cache["v"],
+            layer_step, x, (layers, layer_ids, cache["k"], cache["v"],
                             cache["k_scale"], cache["v_scale"]))
         new_cache = {"k": kc_new, "v": vc_new, "k_scale": ks_new,
                      "v_scale": vs_new, "x_dtype": cache["x_dtype"]}
     else:
         x, (kc_new, vc_new) = jax.lax.scan(
-            layer_step, x, (params["layers"], cache["k"], cache["v"]))
+            layer_step, x, (layers, layer_ids, cache["k"], cache["v"]))
         new_cache = {"k": kc_new, "v": vc_new}
     x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
     if cfg.tie_embeddings:

@@ -8,7 +8,9 @@ modules, :func:`inject_decode_params` re-lays the weights for the fused
 kernels (QKV concatenated into one [D, N] matmul per layer — the reference's
 fused-QKV transform), and :func:`decode_step` runs a single token through
 four kernel launches per layer (``ops/pallas/decode.py``) instead of the
-~25-op unfused HLO chain.
+~25-op unfused HLO chain.  A mixture-of-experts model keeps four of the five
+kernels and swaps ``fused_mlp`` for ``fused_moe_mlp``, which reads the
+model's own stacked expert arrays: those are never laid out a second time.
 
 Prefill keeps the standard :func:`~deepspeed_tpu.models.decoding.
 forward_with_cache` path (it is matmul-bound, already MXU-shaped); only the
@@ -24,19 +26,22 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.models.layers import norm, rope_dim
+from deepspeed_tpu.models.layers import norm, qk_norm, rope_dim
 from deepspeed_tpu.ops.pallas import rope_angles
 from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
-                                             fused_norm_qkv, fused_proj_norm,
-                                             paged_kv_append)
+                                             fused_moe_mlp, fused_norm_qkv,
+                                             fused_proj_norm, paged_kv_append)
+
+EXPERT_WEIGHTS = ("w_up", "w_gate", "w_down")
 
 
 def supports_fused_decode(cfg, *, quantized_kv: bool = False,
                           tp: int = 1) -> bool:
-    """The fused path covers the dense model zoo including int8 weights
-    (dequant in-kernel); MoE MLPs, int8 KV caches, and tp>1 fall back to
+    """The fused path covers the model zoo, dense and mixture-of-experts,
+    including int8 weights (dequant in-kernel; expert weights are never
+    quantized, ``models/quant.py``); int8 KV caches and tp>1 fall back to
     the reference-shaped loop."""
-    return (not cfg.is_moe and not quantized_kv
+    return (not quantized_kv
             and tp == 1 and cfg.position in ("rope", "learned", "alibi"))
 
 
@@ -48,7 +53,12 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
     Pallas kernel a whole array — profiling showed that slicing a stacked
     [L, ...] weight per layer inside the program re-materializes the
     slice (a full per-layer weight copy per token).  The QKV concat is the
-    reference's fused-QKV injection transform."""
+    reference's fused-QKV injection transform.
+
+    Expert weights are the exception: ``fused_moe_mlp`` indexes the STACKED
+    [L, E, ...] arrays at a static layer offset inside its index maps (as
+    ``flash_decode`` indexes the stacked cache), so ``out["experts"]`` holds
+    the caller's own arrays by reference and they stay resident once."""
     from deepspeed_tpu.models.quant import QTensor, is_qtensor
 
     ly = params["layers"]
@@ -65,9 +75,14 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
         "wo": attn["wo"],
         "n1_scale": ly["attn_norm"]["scale"],
         "n2_scale": ly["mlp_norm"]["scale"],
-        "w_up": mlp["w_up"],
-        "w_down": mlp["w_down"],
     }
+    if cfg.is_moe:
+        stacked["gate_w"] = mlp["gate_w"]
+    else:
+        stacked.update({k: mlp[k] for k in EXPERT_WEIGHTS if k in mlp})
+    if cfg.qk_norm:
+        stacked["q_norm"] = attn["q_norm"]["scale"]
+        stacked["k_norm"] = attn["k_norm"]["scale"]
     if cfg.norm == "layernorm":
         stacked["n1_bias"] = ly["attn_norm"]["bias"]
         stacked["n2_bias"] = ly["mlp_norm"]["bias"]
@@ -81,8 +96,6 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
         stacked["b_down"] = mlp["b_down"]
         if cfg.glu:
             stacked["b_gate"] = mlp["b_gate"]
-    if cfg.glu:
-        stacked["w_gate"] = mlp["w_gate"]
     def unstack(v, l):
         if is_qtensor(v):
             return QTensor(v.q[l], v.scale[l])
@@ -93,6 +106,8 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
         for l in range(cfg.num_layers))
     out = {"embed": params["embed"], "final_norm": params["final_norm"],
            "layers": layers}
+    if cfg.is_moe:
+        out["experts"] = {k: mlp[k] for k in EXPERT_WEIGHTS if k in mlp}
     if not cfg.tie_embeddings:
         out["lm_head"] = params["lm_head"]
     if cfg.lm_head_bias:
@@ -100,8 +115,29 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
     return out
 
 
+def moe_combine(h, gate_w, cfg):
+    """Router of one layer on rows ``h`` [B, D]: float32 logits, softmax,
+    top-k -> (combine [B, E] float32, each row's weight per expert and 0
+    where not chosen; chosen [B, E] bool).  ``fused_moe_mlp``'s input."""
+    from deepspeed_tpu.moe.sharded_moe import topk_weights
+
+    gates = jax.nn.softmax(
+        h.astype(jnp.float32) @ gate_w.astype(jnp.float32), axis=-1)
+    weight, idx = topk_weights(gates, cfg.num_experts_per_tok,
+                               cfg.moe_norm_topk_prob)
+    onehot = jax.nn.one_hot(idx, cfg.num_experts, dtype=jnp.float32)
+    return (jnp.sum(onehot * weight[..., None], axis=1),
+            jnp.sum(onehot, axis=1) > 0)
+
+
+def moe_counts_zero(cfg):
+    """Zeros of ``decode_step``'s routing counts (its ``moe_live`` result)."""
+    return (jnp.zeros((cfg.num_experts,), jnp.int32),
+            jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
+
+
 def decode_step(cfg, dparams, tokens, cache, pos, *,
-                page_table=None, impl: Optional[str] = None):
+                page_table=None, moe_live=None, impl: Optional[str] = None):
     """One generation step: ``tokens`` [B, 1] at absolute position ``pos``
     -> (logits [B, V] fp32, cache).
 
@@ -114,6 +150,13 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
     ([L, num_pages, Hkv, page, Dh], ``serving/paged_kv.py``): appends
     scatter through the table and the flash-decode kernel indirects its
     DMA index map through it (per-row positions required).
+
+    ``moe_live`` [B] bool (the rows that are really decoding) adds a third
+    result: a mixture-of-experts model's routing of this step over those
+    rows, summed over layers — (assignments per expert [E], (layer, expert)
+    pairs with at least one row, the fullest expert's rows summed over
+    layers), int32, what ``ds_serve_moe_*`` count — and None for a dense
+    model.
 
     Four kernel launches per layer: norm+QKV, flash-decode attention,
     out-proj+residual+norm, MLP+residual (ops/pallas/decode.py); the cache
@@ -179,13 +222,18 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             return w.q, w.scale
         return w, None
 
+    count_moe = cfg.is_moe and moe_live is not None
+    moe_stats = moe_counts_zero(cfg) if count_moe else None
     for l, lp in enumerate(dparams["layers"]):
         wqkv, s_qkv = wq_pair(lp["wqkv"])
         qkv = fused_norm_qkv(x, lp["n1_scale"], lp.get("n1_bias"),
                              wqkv, lp.get("bqkv"), kind=kind, eps=eps,
                              wscale=s_qkv, impl=impl)
-        q = rope_rows(qkv[:, :M].reshape(B, H, Dh))
-        k = rope_rows(qkv[:, M:M + Mkv].reshape(B, Hkv, Dh))
+        q, k = qkv[:, :M], qkv[:, M:M + Mkv]
+        if cfg.qk_norm:
+            q, k = qk_norm(q, k, lp["q_norm"], lp["k_norm"], eps)
+        q = rope_rows(q.reshape(B, H, Dh))
+        k = rope_rows(k.reshape(B, Hkv, Dh))
         v = qkv[:, M + Mkv:].reshape(B, Hkv, Dh)
         if page_table is not None:
             # paged append: row b writes at row pos[b] % page of physical
@@ -220,13 +268,27 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
                                lp["n2_scale"], lp.get("n2_bias"), kind=kind,
                                eps=eps, parallel=cfg.parallel_residual,
                                wscale=s_wo, impl=impl)
-        wu, su = wq_pair(lp["w_up"])
-        wd, sd = wq_pair(lp["w_down"])
-        wg, sg = (wq_pair(lp["w_gate"]) if "w_gate" in lp else (None, None))
-        wscales = (su, sg, sd) if su is not None else None
-        x = fused_mlp(h, r, wu, wd, wg,
-                      lp.get("b_up"), lp.get("b_gate"), lp.get("b_down"),
-                      act=cfg.activation, wscales=wscales, impl=impl)
+        if cfg.is_moe:
+            combine, chosen = moe_combine(h, lp["gate_w"], cfg)
+            ex = dparams["experts"]
+            x = fused_moe_mlp(h, r, combine, ex["w_up"], ex["w_down"],
+                              ex.get("w_gate"), layer=l, act=cfg.activation,
+                              impl=impl)
+            if count_moe:
+                load = jnp.sum(chosen & moe_live[:, None], axis=0,
+                               dtype=jnp.int32)
+                moe_stats = (moe_stats[0] + load,
+                             moe_stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
+                             moe_stats[2] + jnp.max(load))
+        else:
+            wu, su = wq_pair(lp["w_up"])
+            wd, sd = wq_pair(lp["w_down"])
+            wg, sg = (wq_pair(lp["w_gate"]) if "w_gate" in lp
+                      else (None, None))
+            wscales = (su, sg, sd) if su is not None else None
+            x = fused_mlp(h, r, wu, wd, wg,
+                          lp.get("b_up"), lp.get("b_gate"), lp.get("b_down"),
+                          act=cfg.activation, wscales=wscales, impl=impl)
     new_cache = {"k": kc_all, "v": vc_all}
     x = norm(x, dparams["final_norm"], kind, eps)
     if cfg.tie_embeddings:
@@ -236,4 +298,6 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
     logits = (x @ head).astype(jnp.float32)
     if cfg.lm_head_bias:
         logits = logits + dparams["lm_head_bias"].astype(jnp.float32)
+    if moe_live is not None:
+        return logits, new_cache, moe_stats
     return logits, new_cache

@@ -103,6 +103,27 @@ def norm(x, params, kind: str, eps: float, mesh: Optional[Mesh] = None):
                          out_specs=spec, check_vma=False)(x, *weights)
 
 
+def qk_norm(q, k, q_scale, k_scale, eps: float,
+            mesh: Optional[Mesh] = None):
+    """QK-norm (olmoe): RMSNorm over the WHOLE q and k projections
+    (``[..., H*Dh]`` / ``[..., Hkv*Dh]``, all heads at once) in float32,
+    before the head split and RoPE.  The mean of squares spans the columns
+    ``tp`` splits, so under ``tp > 1`` it would be a cross-shard reduction;
+    that is not built, and a per-shard norm is another model."""
+    if mesh is not None and not mesh.empty and axis_size(mesh, "tp") > 1:
+        raise NotImplementedError(
+            "qk_norm with tp > 1: the norm spans the tp-split projection "
+            "columns and its tp reduction is not built")
+
+    def one(t, scale):
+        t32 = t.astype(jnp.float32)
+        var = jnp.mean(t32 * t32, axis=-1, keepdims=True)
+        return (t32 * jax.lax.rsqrt(var + eps)
+                * scale.astype(jnp.float32)).astype(t.dtype)
+
+    return one(q, q_scale), one(k, k_scale)
+
+
 def activation_fn(name: str):
     return {"silu": jax.nn.silu,
             "gelu": functools.partial(jax.nn.gelu, approximate=True),

@@ -37,7 +37,8 @@ from deepspeed_tpu.comm.mesh import axis_size, get_global_mesh
 from deepspeed_tpu.models.config import ModelConfig, get_model_config
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
                                          attention_core, constrain, norm,
-                                         _repeat_kv, rope_cache, rope_dim)
+                                         qk_norm, _repeat_kv, rope_cache,
+                                         rope_dim)
 from deepspeed_tpu.ops.pallas import apply_rotary_pos_emb
 
 
@@ -97,6 +98,9 @@ class CausalLM:
                         bv=jnp.zeros((L, Hkv * Dh), dtype))
         if cfg.use_bias:
             attn.update(bo=jnp.zeros((L, D), dtype))
+        if cfg.qk_norm:
+            attn.update(q_norm={"scale": jnp.ones((L, H * Dh), dtype)},
+                        k_norm={"scale": jnp.ones((L, Hkv * Dh), dtype)})
         if cfg.is_moe:
             mlp = {
                 "gate_w": _uniform(next(keys), (L, D, E), s_in, dtype),
@@ -159,6 +163,9 @@ class CausalLM:
             attn.update(bq=P(None, "tp"), bk=P(None, "tp"), bv=P(None, "tp"))
         if cfg.use_bias:
             attn.update(bo=P(None, None))
+        if cfg.qk_norm:  # replicated; layers.qk_norm refuses tp > 1
+            attn.update(q_norm={"scale": P(None, None)},
+                        k_norm={"scale": P(None, None)})
         if cfg.is_moe:
             mlp = {"gate_w": P(None, None, None),
                    "w_up": P(None, "ep", None, "tp"),
@@ -214,6 +221,9 @@ class CausalLM:
         v = h @ a["wv"]
         if cfg.use_bias or cfg.qkv_bias:
             q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
+        if cfg.qk_norm:
+            q, k = qk_norm(q, k, a["q_norm"]["scale"], a["k_norm"]["scale"],
+                           cfg.norm_eps, mesh)
         q = q.reshape(B, S, H, Dh).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)
         v = v.reshape(B, S, Hkv, Dh).transpose(0, 2, 1, 3)

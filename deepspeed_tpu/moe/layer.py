@@ -25,7 +25,10 @@ class MoE:
 
     Mirrors the reference constructor surface.  ``ep_size`` is informational
     on TPU: expert placement is governed by the mesh's ``ep`` axis (a mismatch
-    logs a warning rather than resizing process groups).
+    logs a warning rather than resizing process groups).  ``drop_tokens=False``
+    takes ``moe_mlp``'s dropless path (tokens sorted by expert, grouped
+    matmuls; no capacity, so the capacity factors and ``use_rts`` have
+    nothing to act on); it is not built under ``ep > 1`` and raises there.
     """
 
     def __init__(self, hidden_size: int, num_experts: int = 1, k: int = 1,

@@ -17,6 +17,18 @@ same choices GShard itself made:
 - Load-balancing aux loss (the reference's ``l_aux``): ``E · Σ_e mean_prob_e
   · frac_tokens_e`` over the top-1 assignment.
 
+Two paths, chosen by ``cfg.moe_drop_tokens``:
+
+- ``True`` (default; the DeepSpeed contract above): GShard capacity with
+  drops, scatter or einsum dispatch, the ``ep`` all-to-all.  Training.
+- ``False``: **dropless**.  The ``N*k`` assignments are sorted by expert and
+  the three projections run as grouped matmuls over ``[N*k, D]`` with
+  ``group_sizes [E]`` (``jax.lax.ragged_dot``), then un-sorted and combined
+  with the router weights: the cost is the routed tokens, not ``E x N``,
+  and no capacity exists to overflow.  Serving prefill and the unfused
+  decode loop run it; the fused decode path has its own kernel
+  (``ops/pallas/decode.py:fused_moe_mlp``).  Not built under ``ep > 1``.
+
 Expert weights are sharded over ``ep`` (expert parallelism) and optionally
 ``tp`` (intra-expert tensor parallelism) via the model's logical specs; the
 expert-data-parallel hybrid (reference ``ep_size`` < world) falls out of the
@@ -40,8 +52,29 @@ def compute_capacity(num_tokens: int, num_experts: int, k: int,
                int(math.ceil(k * num_tokens / num_experts * capacity_factor)))
 
 
+def topk_weights(gates, k: int, normalize: bool = True):
+    """The router without capacity: (weight [N, k] fp32, expert_idx [N, k]),
+    best first (ties to the lower index, as ``argmax`` breaks them).
+    ``normalize`` rescales the k kept probabilities to sum to 1 (Mixtral,
+    ``top2gating``); off, they stay the softmax's own (OLMoE's
+    ``norm_topk_prob: false``).  k = 1 always keeps the raw probability, so
+    the router still gets gradient from the task loss (``top1gating``)."""
+    weight, idx = jax.lax.top_k(gates, k)
+    if normalize and k > 1:
+        weight = weight / jnp.maximum(weight.sum(-1, keepdims=True), 1e-9)
+    return weight, idx
+
+
+def load_balance_loss(gates, top1_idx):
+    """The reference's ``l_aux``: E * sum_e mean_prob_e * frac_tokens_e over
+    the top-1 assignment."""
+    E = gates.shape[-1]
+    ce = jnp.mean(jax.nn.one_hot(top1_idx, E, dtype=jnp.float32), axis=0)
+    return E * jnp.sum(jnp.mean(gates, axis=0) * ce)
+
+
 def topk_assignments(gates, k: int, capacity: int, rng=None,
-                     use_rts: bool = False):
+                     use_rts: bool = False, normalize: bool = True):
     """Compact top-k assignment: (expert_idx [N,k], pos [N,k], weight [N,k],
     aux scalar).  Same gating math as :func:`topk_gating` but without the
     [N, E, C] one-hot tensors — feeds the O(N·k·D) scatter/gather dispatch
@@ -55,7 +88,8 @@ def topk_assignments(gates, k: int, capacity: int, rng=None,
         N = gates.shape[0]
         perm = jax.random.permutation(rng, N)
         inv = jnp.argsort(perm)
-        e_idx, pos, w, aux = topk_assignments(gates[perm], k, capacity)
+        e_idx, pos, w, aux = topk_assignments(gates[perm], k, capacity,
+                                              normalize=normalize)
         return e_idx[inv], pos[inv], w[inv], aux
     N, E = gates.shape
     C = capacity
@@ -68,9 +102,7 @@ def topk_assignments(gates, k: int, capacity: int, rng=None,
         idx = jnp.argmax(remaining, axis=-1)                      # [N]
         onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)        # [N, E]
         if slot == 0:
-            me = jnp.mean(gates, axis=0)
-            ce = jnp.mean(onehot, axis=0)
-            aux = E * jnp.sum(me * ce)
+            aux = load_balance_loss(gates, idx)
         pos_in_e = jnp.cumsum(onehot, axis=0) - onehot + location_base[None]
         pos = jnp.sum(pos_in_e * onehot, axis=-1).astype(jnp.int32)
         keep = (pos < C).astype(jnp.float32)
@@ -82,13 +114,13 @@ def topk_assignments(gates, k: int, capacity: int, rng=None,
         location_base = location_base + jnp.sum(onehot, axis=0).astype(jnp.int32)
         remaining = jnp.where(onehot > 0, -jnp.inf, remaining)
     weight = jnp.stack(ws, axis=1)                                # [N, k]
-    if k > 1:
+    if normalize and k > 1:
         weight = weight / jnp.maximum(kept_gate_sum, 1e-9)[:, None]
     return (jnp.stack(idxs, axis=1), jnp.stack(poss, axis=1), weight, aux)
 
 
 def topk_gating(gates, k: int, capacity: int, rng=None,
-                use_rts: bool = False):
+                use_rts: bool = False, normalize: bool = True):
     """GShard top-k gating with fixed capacity.
 
     gates: [N, E] softmax router probabilities (fp32).
@@ -101,7 +133,8 @@ def topk_gating(gates, k: int, capacity: int, rng=None,
         N = gates.shape[0]
         perm = jax.random.permutation(rng, N)
         inv = jnp.argsort(perm)
-        combine, dispatch, aux = topk_gating(gates[perm], k, capacity)
+        combine, dispatch, aux = topk_gating(gates[perm], k, capacity,
+                                             normalize=normalize)
         return combine[inv], dispatch[inv], aux
     N, E = gates.shape
     C = capacity
@@ -115,9 +148,7 @@ def topk_gating(gates, k: int, capacity: int, rng=None,
         idx = jnp.argmax(remaining, axis=-1)                      # [N]
         onehot = jax.nn.one_hot(idx, E, dtype=jnp.float32)        # [N, E]
         if slot == 0:
-            me = jnp.mean(gates, axis=0)                          # mean router prob
-            ce = jnp.mean(onehot, axis=0)                         # token fraction
-            aux = E * jnp.sum(me * ce)
+            aux = load_balance_loss(gates, idx)
         # position of each token within its chosen expert's capacity buffer
         pos_in_e = jnp.cumsum(onehot, axis=0) - onehot + location_base[None]
         pos = jnp.sum(pos_in_e * onehot, axis=-1).astype(jnp.int32)  # [N]
@@ -130,7 +161,7 @@ def topk_gating(gates, k: int, capacity: int, rng=None,
         location_base = location_base + jnp.sum(onehot, axis=0).astype(jnp.int32)
         remaining = jnp.where(onehot > 0, -jnp.inf, remaining)
 
-    if k > 1:
+    if normalize and k > 1:
         # normalize combine weights over the kept top-k experts per token
         # (Mixtral/top2gating convention); k=1 keeps the raw gate probability
         # so the router still gets gradient from the task loss (top1gating).
@@ -139,20 +170,66 @@ def topk_gating(gates, k: int, capacity: int, rng=None,
     return combine, dispatch, aux
 
 
-def moe_mlp(params, x, cfg, mesh=None, rng=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
+def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None):
+    """Dropless expert block on tokens ``xt`` [N, D] with router
+    probabilities ``gates`` [N, E]: sort the N*k (token, expert) assignments
+    by expert, run each projection as ONE grouped matmul over the sorted rows
+    (``group_sizes`` [E] = tokens per expert; an expert nobody chose is an
+    empty group), un-sort, and sum each token's k outputs under its router
+    weights in float32.  Returns (y [N, D], aux).
+
+    ``layer`` (a traced index) says the expert arrays are the model's STACKED
+    [L, E, ...] ones: they go to the grouped matmul whole, as L*E groups of
+    which the other layers' are empty.  Slicing one layer out, dynamically or
+    statically, copies its 0.8 GB in front of every call (the grouped matmul
+    is a custom call, which no slice fuses into): 5.2 against 2.9 ms a layer
+    at OLMoE's widths (tools/moe_grouped_bench.py, PERF.md Findings PR 27);
+    an empty group costs the matmul next to nothing."""
+    N, D = xt.shape
+    E, k = cfg.num_experts, cfg.num_experts_per_tok
+    weight, e_idx = topk_weights(gates, k, normalize)            # [N, k]
+    aux = load_balance_loss(gates, e_idx[:, 0])
+    flat = e_idx.reshape(-1)
+    order = jnp.argsort(flat)                   # stable: token order inside
+    sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
+    rows = xt[order // k]                                        # [N*k, D]
+    if layer is not None:
+        groups = params["w_up"].shape[0] * E
+        sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((groups,), jnp.int32), sizes, (layer * E,))
+
+    def dot(a, w):
+        if layer is not None:
+            w = w.reshape((groups,) + w.shape[2:])
+        return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes)
+
+    act = activation_fn(cfg.activation)
+    up = dot(rows, params["w_up"])
+    hidden = act(dot(rows, params["w_gate"])) * up if cfg.glu else act(up)
+    out = dot(hidden, params["w_down"])[jnp.argsort(order)]      # un-sort
+    y = jnp.sum(out.reshape(N, k, D).astype(jnp.float32) * weight[..., None],
+                axis=1)
+    return y.astype(xt.dtype), aux
+
+
+def moe_mlp(params, x, cfg, mesh=None, rng=None, layer=None
+            ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """One MoE feed-forward block on [B, S, D] hidden states.
 
     ``params``: {"gate_w" [D, E], "w_up" [E, D, F], ("w_gate" [E, D, F]),
     "w_down" [E, F, D]} — the per-layer slice of the model's stacked MoE
-    weights.  Returns (output [B, S, D], aux_loss scalar).
+    weights.  Returns (output [B, S, D], aux_loss scalar).  With ``layer``
+    (dropless path only; inference, where no gradient of the stack is
+    wanted) the three expert arrays are the stacked [L, E, ...] ones and
+    ``gate_w`` still this layer's: see :func:`_moe_grouped`.
 
     ``cfg.moe_drop_tokens=False`` (reference ``drop_tokens=False``): the
-    capacity covers the worst-case expert load (C = N — XLA's static shapes
-    forbid the reference's runtime max-load capacity), so no token is ever
-    dropped.  ``cfg.moe_use_rts``: Random Token Selection for capacity
-    slots; the permutation key is ``rng`` (the layer's dropout key when the
-    model has one) or, failing that, derived from the batch content so it
-    still varies across batches inside one compiled step.
+    sorted-and-grouped path (:func:`_moe_grouped`), which has no capacity and
+    drops nothing.  ``cfg.moe_use_rts``: Random Token Selection for capacity
+    slots (nothing to select without a capacity); the permutation key is
+    ``rng`` (the layer's dropout key when the model has one) or, failing
+    that, derived from the batch content so it still varies across batches
+    inside one compiled step.
     """
     B, S, D = x.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
@@ -161,23 +238,32 @@ def moe_mlp(params, x, cfg, mesh=None, rng=None) -> Tuple[jnp.ndarray, jnp.ndarr
 
     logits = xt.astype(jnp.float32) @ params["gate_w"].astype(jnp.float32)
     gates = jax.nn.softmax(logits, axis=-1)
-    drop = getattr(cfg, "moe_drop_tokens", True)
+    normalize = bool(getattr(cfg, "moe_norm_topk_prob", True))
+    if not getattr(cfg, "moe_drop_tokens", True):
+        if mesh is not None and not getattr(mesh, "empty", False) \
+                and dict(mesh.shape).get("ep", 1) > 1:
+            raise NotImplementedError(
+                "moe_drop_tokens=False under ep > 1: the dropless grouped "
+                "path has no expert-parallel exchange yet (ROADMAP R1, the "
+                "training half); the capacity path would drop tokens")
+        y, aux = _moe_grouped(params, xt, gates, cfg, normalize, layer)
+        return y.reshape(B, S, D), aux
+    if layer is not None:
+        raise ValueError("moe_mlp(layer=...) reads stacked expert arrays, "
+                         "which only the dropless path does")
     use_rts = bool(getattr(cfg, "moe_use_rts", False))
     if use_rts and rng is None:
         seed = jax.lax.bitcast_convert_type(
             xt.astype(jnp.float32).sum(), jnp.int32)
         rng = jax.random.fold_in(jax.random.PRNGKey(17), seed)
-    if drop:
-        C = compute_capacity(N, E, k, cfg.moe_capacity_factor,
-                             getattr(cfg, "moe_min_capacity", 4))
-    else:
-        C = N  # worst case: every token routed to the same expert
+    C = compute_capacity(N, E, k, cfg.moe_capacity_factor,
+                         getattr(cfg, "moe_min_capacity", 4))
     use_scatter = getattr(cfg, "moe_dispatch", "scatter") == "scatter"
     if use_scatter:
         # O(N·k·D) scatter dispatch / gather combine (VERDICT r2 weak #9):
         # the [N, E, C] one-hot einsum is O(N²·k/E) because C ~ k·N/E.
-        e_idx, pos, weight, aux = topk_assignments(gates, k, C, rng,
-                                                   use_rts)     # [N, k]
+        e_idx, pos, weight, aux = topk_assignments(
+            gates, k, C, rng, use_rts, normalize)               # [N, k]
         keep = pos < C
         safe_pos = jnp.clip(pos, 0, C - 1)
         contrib = jnp.where(keep.reshape(-1)[:, None],
@@ -185,7 +271,8 @@ def moe_mlp(params, x, cfg, mesh=None, rng=None) -> Tuple[jnp.ndarray, jnp.ndarr
         expert_in = jnp.zeros((E, C, D), x.dtype).at[
             e_idx.reshape(-1), safe_pos.reshape(-1)].add(contrib)
     else:
-        combine, dispatch, aux = topk_gating(gates, k, C, rng, use_rts)
+        combine, dispatch, aux = topk_gating(gates, k, C, rng, use_rts,
+                                             normalize)
         # dispatch: tokens (sharded over data axes) -> expert buffers
         # (sharded over ep) — GSPMD inserts the all-to-all here
         # (reference: _AllToAll).

@@ -17,6 +17,9 @@ these kernels collapse each layer to four launches:
 - :func:`fused_proj_norm`  — attention out-projection → residual add → norm
 - :func:`fused_mlp`        — (gated) MLP → residual add, blocked over the
   FFN dim so VMEM holds one weight tile at a time
+- :func:`fused_moe_mlp`    — the same for a mixture of experts: every
+  expert's weights stream through VMEM once, all rows run against each, and
+  a dense [B, E] combine matrix (zero where not chosen) weighs the sum
 
 Each op keeps a pure-jnp reference (the CPU path and the parity target); the
 Pallas kernels run in interpret mode on CPU for tests, matching the dispatch
@@ -694,3 +697,94 @@ def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
         interpret=interpret_flag(impl),
         name="fused_mlp",
     )(h, r, w_up, wg, w_down, su2, sg2, sd2, bu2, bg2, bd2)
+
+
+# ---------------------------------------------------------------------------
+# fused_moe_mlp: r + sum_e combine[:, e] * down_e(act(h gate_e) * (h up_e))
+# ---------------------------------------------------------------------------
+
+def _moe_mlp_ref(h, r, combine, w_up, w_gate, w_down, *, act):
+    """Every expert on every row, weighed by ``combine`` (parity target)."""
+    up = jnp.einsum("bd,edf->ebf", h, w_up,
+                    preferred_element_type=jnp.float32)
+    if w_gate is not None:
+        a = _act(act, jnp.einsum("bd,edf->ebf", h, w_gate,
+                                 preferred_element_type=jnp.float32)) * up
+    else:
+        a = _act(act, up)
+    y = jnp.einsum("ebf,efd->ebd", a.astype(h.dtype), w_down,
+                   preferred_element_type=jnp.float32)
+    y = jnp.einsum("be,ebd->bd", combine.astype(jnp.float32), y)
+    return (r.astype(jnp.float32) + y).astype(h.dtype)
+
+
+def _moe_mlp_kernel(h_ref, r_ref, c_ref, wu_ref, wg_ref, wd_ref, o_ref,
+                    acc_scr, *, act, glu, ne, nf):
+    """One grid step = one expert x one FFN tile: all rows against the tile,
+    the down-projection weighed by this expert's combine column and added
+    into the float32 accumulator (which starts at the residual)."""
+    e, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((e == 0) & (j == 0))
+    def _init():
+        acc_scr[:] = r_ref[:].astype(jnp.float32)
+
+    h = h_ref[:]
+    dot = functools.partial(jax.lax.dot_general,
+                            dimension_numbers=(((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    up = dot(h, wu_ref[:])
+    a = _act(act, dot(h, wg_ref[:])) * up if glu else _act(act, up)
+    acc_scr[:] += c_ref[:] * dot(a.astype(h.dtype), wd_ref[:])
+
+    @pl.when((e == ne - 1) & (j == nf - 1))
+    def _finish():
+        o_ref[:] = acc_scr[:].astype(o_ref.dtype)
+
+
+def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
+                  layer: Optional[int] = None, act: str = "silu",
+                  impl: Optional[str] = None):
+    """h: [B, D] (normed); r: [B, D] (residual); ``combine`` [B, E] float32,
+    each row's router weight per expert and 0 where the expert was not
+    chosen.  Expert weights [E, D, F] / [E, F, D] — or, with ``layer=l``,
+    the model's STACKED [L, E, D, F] / [L, E, F, D] arrays read at static
+    layer offset ``l`` through the index maps, so the decode path holds no
+    second copy of them.  Returns r + sum_e combine[:, e] * mlp_e(h).
+
+    The grid walks (expert, FFN tile): each expert's matrices pass through
+    VMEM once and ALL rows run against them, chosen or not — an unchosen
+    expert adds ``0 *`` a finite number, so the result is the exact dropless
+    mixture whatever the routing.  At decode batch sizes the block is bound
+    by those weight bytes (every expert is hit by some row almost every
+    step), not by the E/k times more FLOPs than the rows asked for."""
+    impl = resolve_impl(impl)
+    glu = w_gate is not None
+    if impl == "xla":
+        pick = (lambda w: w) if layer is None else (lambda w: w[layer])
+        return _moe_mlp_ref(h, r, combine, pick(w_up),
+                            pick(w_gate) if glu else None, pick(w_down),
+                            act=act)
+    B, D = h.shape
+    E, _, F = w_up.shape[-3:]
+    bf = _col_block(D * (3 if glu else 2), F, w_up.dtype.itemsize)
+    base = 0 if layer is None else layer * E
+    kernel = functools.partial(_moe_mlp_kernel, act=act, glu=glu, ne=E,
+                               nf=F // bf)
+    rows = pl.BlockSpec((B, D), lambda e, j: (0, 0))
+    cols = pl.BlockSpec((None, D, bf), lambda e, j: (base + e, 0, j))
+    return pl.pallas_call(
+        kernel,
+        grid=(E, F // bf),
+        in_specs=[rows, rows,
+                  pl.BlockSpec((None, B, 1), lambda e, j: (e, 0, 0)),
+                  cols, cols,
+                  pl.BlockSpec((None, bf, D), lambda e, j: (base + e, j, 0))],
+        out_specs=rows,
+        out_shape=jax.ShapeDtypeStruct((B, D), h.dtype),
+        scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
+        interpret=interpret_flag(impl),
+        name="fused_moe_mlp",
+    )(h, r, combine.astype(jnp.float32).T[:, :, None],
+      w_up.reshape(-1, D, F), (w_gate if glu else w_up).reshape(-1, D, F),
+      w_down.reshape(-1, F, D))

@@ -105,6 +105,21 @@ SERVE_PHASES = {
 }
 
 
+# Routing counters of a mixture-of-experts model on the fused decode path,
+# counted on the device over LIVE rows by the decode-block program itself
+# and fetched with a block's tokens, only while the registry is on.
+SERVE_MOE_COUNTERS = {
+    "ds_serve_moe_assignments_total":
+        "(row, expert) assignments of live decode rows, over layers and steps",
+    "ds_serve_moe_expert_hits_total":
+        "(layer, step, expert) triples with at least one live row",
+    "ds_serve_moe_expert_slots_total":
+        "(layer, step, expert) triples run: experts x layers x decode steps",
+    "ds_serve_moe_max_load_total":
+        "rows of the fullest expert, summed over layers and decode steps",
+}
+
+
 def _in_phase(name: str):
     """Run a whole engine method inside the host phase ``name``."""
     def wrap(method):
@@ -318,6 +333,7 @@ class ServingEngine:
         self._block_valid = {}   # idx -> device valid [K, B] (drain blocks)
         self._block_np = {}      # idx -> (toks np, valid np | None)
         self._block_refs = {}    # idx -> pending consumers (refs + drains)
+        self._block_moe = {}     # idx -> device routing counts (registry on)
         self._outstanding = deque()   # [(idx, [eos Request, ...])]
         self._drain_lag = 1
         self._next_block = 0
@@ -401,6 +417,8 @@ class ServingEngine:
         for name, what in SERVE_PHASES.items():
             reg.counter(name + "_seconds_total",
                         f"host seconds inside {name}: {what}")
+        self._m_moe = {name: reg.counter(name, what)
+                       for name, what in SERVE_MOE_COUNTERS.items()}
         self._m_prefill_chunks = reg.counter(
             "ds_serve_prefill_chunks_total", "prefill chunks dispatched")
         self._m_prefill_toks = reg.counter(
@@ -1893,7 +1911,7 @@ class ServingEngine:
             if self.paged:
                 args.append(jnp.asarray(self.pool.page_table))
             (toks, valid, self._last_dev, self._pos_dev, self._act_dev,
-             self._cache, self._rng) = self._block()(*args)
+             self._cache, self._rng, moe) = self._block()(*args)
         t1 = time.perf_counter()
         idx = self._next_block
         self._next_block += 1
@@ -1926,6 +1944,8 @@ class ServingEngine:
             self._block_refs[idx] = refs
             if drainers:
                 self._block_valid[idx] = valid
+            if moe is not None and self._registry.enabled:
+                self._block_moe[idx] = moe
         if drainers:
             self._outstanding.append((idx, drainers))
             while len(self._outstanding) > self._drain_lag:
@@ -1948,14 +1968,28 @@ class ServingEngine:
                 toks = np.asarray(self._blocks[idx])  # dslint: disable=DSL002 -- THE deliberate deferred fetch: drains run >=1 block behind dispatch (lag 1), finish-fetches overlap queued blocks; pinned structurally in test_paged_kv
                 valid = (np.asarray(self._block_valid[idx])  # dslint: disable=DSL002 -- same deferred-fetch seam (valid mask rides the same memoized entry)
                          if idx in self._block_valid else None)
+                moe = self._block_moe.pop(idx, None)
+                if moe is not None:
+                    self._count_moe(*(np.asarray(a) for a in moe))  # dslint: disable=DSL002 -- rides the block's own deferred fetch, registry on only
             entry = self._block_np[idx] = (toks, valid)
         return entry
+
+    def _count_moe(self, per_expert, hits, max_load) -> None:
+        """One decode block's routing (``decode_step``'s ``moe_live``
+        result, summed over the block's steps) into ``ds_serve_moe_*``."""
+        cfg = self.module.config
+        m = self._m_moe
+        m["ds_serve_moe_assignments_total"].inc(int(per_expert.sum()))
+        m["ds_serve_moe_expert_hits_total"].inc(int(hits))
+        m["ds_serve_moe_expert_slots_total"].inc(
+            cfg.num_experts * cfg.num_layers * self._K)
+        m["ds_serve_moe_max_load_total"].inc(int(max_load))
 
     def _unref(self, idx: int) -> None:
         self._block_refs[idx] -= 1
         if self._block_refs[idx] == 0:
             for d in (self._blocks, self._block_valid, self._block_np,
-                      self._block_refs):
+                      self._block_refs, self._block_moe):
                 d.pop(idx, None)
 
     def _drain_one(self) -> None:
@@ -2059,21 +2093,22 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _step_fn(self):
         """One decode micro-step at per-row positions: (params, tokens
-        [B, 1], cache, pos [B], page_table|None) -> (logits [B, V],
-        cache)."""
+        [B, 1], cache, pos [B], page_table|None, live [B]) -> (logits
+        [B, V], cache, routing counts of the live rows | None: a
+        mixture-of-experts model on the fused path, ``decode_step``)."""
         model = self.module
         if self.engine._dparams is not None:
             from deepspeed_tpu.models.fused_decode import decode_step
 
-            def fused(params, tok, cache, pos, page_table):
+            def fused(params, tok, cache, pos, page_table, live):
                 return decode_step(model.config, params, tok, cache, pos,
-                                   page_table=page_table)
+                                   page_table=page_table, moe_live=live)
             return fused
 
-        def unfused(params, tok, cache, pos, page_table):
+        def unfused(params, tok, cache, pos, page_table, live):
             logits, cache = forward_with_cache(model, params, tok, cache,
                                                pos, page_table=page_table)
-            return logits[:, -1], cache
+            return logits[:, -1], cache, None
         return unfused
 
     def _block(self):
@@ -2082,22 +2117,29 @@ class ServingEngine:
         active mask AND positions as device carries (EOS termination folded
         into the step — a row goes inactive the step its EOS is sampled,
         with no host involvement).  Parked rows keep static shapes alive at
-        their frozen pos; the host reads (toks, valid) lazily."""
+        their frozen pos; the host reads (toks, valid) lazily.  The last
+        result is the block's routing counts (``SERVE_MOE_COUNTERS``; None
+        for a dense model): always computed, fetched only when wanted."""
         if self._block_fn is not None:
             return self._block_fn
         self._m_compiles.inc()
         step_fn = self._step_fn()
         do_sample, temperature, top_k, top_p = self._sample
         K = self._K
+        moe0 = None
+        if self.engine._dparams is not None and self.module.config.is_moe:
+            from deepspeed_tpu.models.fused_decode import moe_counts_zero
+            moe0 = moe_counts_zero(self.module.config)
 
         def body(params, cache, last, pos, active, limit, eos, rng,
                  page_table):
             def sub(carry, _):
-                cache, last, pos, act, rng = carry
+                cache, last, pos, act, rng, moe = carry
                 valid = act & (pos < limit)
                 rng, srng = jax.random.split(rng)
-                logits, cache = step_fn(params, last[:, None], cache, pos,
-                                        page_table)
+                logits, cache, routed = step_fn(params, last[:, None], cache,
+                                                pos, page_table, valid)
+                moe = jax.tree.map(jnp.add, moe, routed)
                 nxt = sample_token(logits, srng, temperature=temperature,
                                    top_k=top_k, top_p=top_p,
                                    do_sample=do_sample).astype(last.dtype)
@@ -2105,11 +2147,11 @@ class ServingEngine:
                 hit = valid & (eos >= 0) & (nxt == eos)
                 act = act & ~hit
                 pos = pos + valid.astype(pos.dtype)
-                return (cache, nxt, pos, act, rng), (nxt, valid)
+                return (cache, nxt, pos, act, rng, moe), (nxt, valid)
 
-            (cache, last, pos, act, rng), (toks, valid) = jax.lax.scan(
-                sub, (cache, last, pos, active, rng), None, length=K)
-            return toks, valid, last, pos, act, cache, rng
+            (cache, last, pos, act, rng, moe), (toks, valid) = jax.lax.scan(
+                sub, (cache, last, pos, active, rng, moe0), None, length=K)
+            return toks, valid, last, pos, act, cache, rng, moe
 
         if self.paged:
             block = jax.jit(body, donate_argnums=(1, 2, 3, 4))

@@ -1,0 +1,325 @@
+"""OLMoE through the program against its plain reference
+(``benchmarks/reference/olmoe.py``), at a tiny size on the CPU: QK-norm, a
+router that may leave its top-k weights unnormalised, the dropless
+sorted-and-grouped expert block, and the expert block of the fused decode
+path (``fused_moe_mlp``, interpret mode).
+
+Float32 program against float32 reference, so no routing decision can flip
+on a rounding.  TOLERANCE: ``rtol`` 2e-4 (with an ``atol`` of 2e-5 for logits
+near zero) on logits.  Both sides are float32, but a token's output is a sum
+of k expert outputs and of matmul partial sums which the two sides add in
+another order (grouped rows, kernel tiles, chunks of a prefill), through two
+layers and a 64-wide head.  A bf16 expert matmul would miss it by 30x: bf16
+keeps 8 bits, 4e-3 a product.
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.comm.mesh import build_mesh
+from deepspeed_tpu.models import CausalLM, ModelConfig, get_model_config
+from deepspeed_tpu.moe.sharded_moe import moe_mlp
+from deepspeed_tpu.ops.pallas import common
+from deepspeed_tpu.ops.pallas.decode import fused_moe_mlp
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+RTOL, ATOL = 2e-4, 2e-5
+VOCAB = 256
+
+
+def _load(rel, name):
+    spec = importlib.util.spec_from_file_location(
+        name, os.path.join(REPO, "benchmarks", *rel.split("/")))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+ref = _load("reference/olmoe.py", "_ref_olmoe")
+taps_lib = _load("lib/serve_taps.py", "_serve_taps")
+
+
+def tiny(top_k=2, **over):
+    fields = dict(vocab_size=VOCAB, hidden_size=64, intermediate_size=32,
+                  num_layers=2, num_heads=4, num_kv_heads=4, head_dim=16,
+                  max_seq_len=512, num_experts=8, num_experts_per_tok=top_k,
+                  moe_drop_tokens=False, moe_norm_topk_prob=False,
+                  qk_norm=True, moe_aux_loss_coef=0.0)
+    fields.update(over)
+    return ModelConfig(**fields)
+
+
+def ref_config(cfg):
+    """The keys of the HF configuration the reference reads."""
+    return dict(num_hidden_layers=cfg.num_layers,
+                num_attention_heads=cfg.num_heads,
+                num_key_value_heads=cfg.num_kv_heads,
+                rms_norm_eps=cfg.norm_eps, rope_theta=cfg.rope_theta,
+                num_experts_per_tok=cfg.num_experts_per_tok,
+                norm_topk_prob=cfg.moe_norm_topk_prob, qk_norm=cfg.qk_norm)
+
+
+def seeded(model, seed=0):
+    """Weights with every norm scale off 1, so a dropped scale shows."""
+    params = model.init(jax.random.PRNGKey(seed))
+    noise = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
+    return jax.tree.map(
+        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+
+
+def tokens_of(n, seed=0):
+    return np.random.default_rng(seed).integers(0, VOCAB, n, dtype=np.int32)
+
+
+def ref_logits(params, cfg, tokens, rows):
+    return np.asarray(ref.logits_rows(params, ref_config(cfg), tokens,
+                                      list(rows), jax.devices()[0]))
+
+
+# -- (a), (f), (g): the full forward ------------------------------------
+@pytest.mark.parametrize("case", ["top2", "top4", "norm_topk", "no_qk_norm"])
+def test_full_forward_logits_match_the_reference(case):
+    cfg = {"top2": tiny(2), "top4": tiny(4),
+           "norm_topk": tiny(2, moe_norm_topk_prob=True),
+           "no_qk_norm": tiny(2, qk_norm=False)}[case]
+    model = CausalLM(cfg, None)
+    params = seeded(model)
+    toks = tokens_of(48)
+    with jax.default_matmul_precision("highest"):
+        got = np.asarray(model.apply(params, toks[None]))[0]
+    np.testing.assert_allclose(got, ref_logits(params, cfg, toks, range(48)),
+                               rtol=RTOL, atol=ATOL)
+
+
+@pytest.mark.parametrize("field", ["qk_norm", "moe_norm_topk_prob"])
+def test_each_new_field_changes_the_logits(field):
+    """The two fields describe the model: flipped on the SAME weights they
+    give another function (the reference follows either way, above)."""
+    model = CausalLM(tiny(), None)
+    params = seeded(model)
+    other = tiny(**{field: not getattr(model.config, field)})
+    toks = tokens_of(32)[None]
+    a = np.asarray(model.apply(params, toks))
+    b = np.asarray(CausalLM(other, None).apply(params, toks))
+    assert np.abs(a - b).max() > 1e-2
+
+
+def test_qk_norm_under_tp_raises(devices):
+    mesh = build_mesh(tp=2, devices=devices[:2])
+    model = CausalLM(tiny(), mesh)
+    params = model.init(jax.random.PRNGKey(0))
+    with pytest.raises(NotImplementedError, match="qk_norm with tp > 1"):
+        model.apply(params, tokens_of(16)[None])
+
+
+# -- (b): gradients through the grouped path ----------------------------
+def test_loss_gradients_match_the_reference():
+    cfg = tiny(2)
+    model = CausalLM(cfg, None)
+    params = seeded(model)
+    toks = tokens_of(40)
+
+    def ref_loss(p):
+        logits = ref.logits_rows(p, ref_config(cfg), toks, list(range(39)),
+                                 jax.devices()[0])
+        lse = jax.scipy.special.logsumexp(logits, axis=-1)
+        return jnp.mean(lse - logits[jnp.arange(39), toks[1:]])
+
+    with jax.default_matmul_precision("highest"):
+        loss, grads = jax.value_and_grad(
+            lambda p: model.apply(p, toks[None], toks[None]))(params)
+        want_loss, want = jax.value_and_grad(ref_loss)(params)
+    assert float(loss) == pytest.approx(float(want_loss), rel=1e-5)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    for (path, g), w in zip(flat, jax.tree.leaves(want)):
+        scale = float(jnp.abs(w).max())
+        assert scale > 0, path                 # every leaf takes part
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-3,
+                                   atol=1e-4 * scale, err_msg=str(path))
+
+
+# -- (c), (f): serving, prefill in chunks + paged decode, fused path -----
+@pytest.fixture
+def interpret_kernels(monkeypatch):
+    monkeypatch.setattr(common, "default_impl", lambda: "interpret")
+
+
+SERVE_CASES = {
+    "olmoe-top2": lambda: tiny(2),
+    "olmoe-top4": lambda: tiny(4),
+    # the preset's router renormalises (moe_norm_topk_prob=True); cut to two
+    # layers and a small vocabulary for the interpreter, and dropless: the
+    # preset's capacity of 1.25 drops tokens in prefill, which no reference
+    # does
+    "mixtral-tiny": lambda: get_model_config(
+        "mixtral-tiny", num_layers=2, vocab_size=VOCAB, max_seq_len=512,
+        moe_drop_tokens=False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SERVE_CASES))
+def test_served_logits_match_the_reference_at_every_generated_position(
+        case, devices, interpret_kernels):
+    """Through ``ServingEngine.step()``: chunked prefill (the grouped path)
+    then the paged decode block (``fused_moe_mlp``), with requests of
+    unequal length, a prompt of three chunks that crosses a page, slots
+    that park while others run and one never used; each chunk is long
+    enough to overflow capacity 1.25."""
+    cfg = SERVE_CASES[case]()
+    mesh = build_mesh(devices=devices[:1])
+    model = CausalLM(cfg, mesh)
+    params = seeded(model)
+    prompts = [tokens_of(150, 1), tokens_of(20, 2), tokens_of(37, 3)]
+    new = [6, 9, 4]
+    with taps_lib.ServeTaps() as taps:
+        serve = deepspeed_tpu.init_serving(
+            model, params=params, mesh=mesh,
+            config={"dtype": "float32", "num_slots": 4, "prefill_chunk": 64,
+                    "decode_block_tokens": 4, "max_out_tokens": 256,
+                    "kv_page_tokens": 128, "kv_pool_tokens": 1024})
+        assert serve.engine._dparams is not None          # the fused path
+        experts = serve.engine._dparams["experts"]
+        assert all(experts[k] is serve.engine._params["layers"]["mlp"][k]
+                   for k in experts)                      # resident once
+        served = taps_lib.serve_and_read(taps, serve, prompts, new)
+        serve.pool.check_no_leak()
+        serve.close()
+    rcfg = ref_config(cfg)
+    for rec, prompt, n in zip(served, prompts, new):
+        assert len(rec["tokens"]) == n
+        seq = np.concatenate([prompt, np.asarray(rec["tokens"], np.int32)])
+        rows = range(len(prompt) - 1, len(seq) - 1)
+        want = ref_logits(params, cfg, seq, rows)
+        np.testing.assert_allclose(rec["logits"], want, rtol=RTOL, atol=ATOL)
+        # float32 both sides: the program's routers chose as the reference's
+        _, _, chosen = ref.hidden_states(params, rcfg, seq[:-1],
+                                         jax.devices()[0],
+                                         return_routing=True)
+        for l, mine in enumerate(rec["routing"]):
+            assert (np.sort(mine, -1) == np.sort(chosen[l], -1)).all()
+        # and the reference, handed the program's choices, agrees too
+        given = np.asarray(ref.logits_rows(
+            params, rcfg, seq[:-1], list(rows), jax.devices()[0],
+            routing=rec["routing"]))
+        np.testing.assert_allclose(rec["logits"], given, rtol=RTOL, atol=ATOL)
+
+
+def test_decode_block_counts_routing_of_live_rows(devices, interpret_kernels):
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+
+    cfg = tiny(2)
+    mesh = build_mesh(devices=devices[:1])
+    model = CausalLM(cfg, mesh)
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(
+        model, params=seeded(model), mesh=mesh, registry=reg,
+        config={"dtype": "float32", "num_slots": 4, "prefill_chunk": 64,
+                "decode_block_tokens": 4, "max_out_tokens": 256,
+                "kv_page_tokens": 128, "kv_pool_tokens": 1024})
+    serve.submit(tokens_of(12, 5), max_new_tokens=9)
+    serve.submit(tokens_of(9, 6), max_new_tokens=5)
+    serve.run()
+    serve.close()
+    value = lambda name: reg.get("ds_serve_moe_" + name).value
+    # decode steps run: 8 + 4 tokens after each request's first, 2 experts
+    # a token a layer over 2 layers
+    assert value("assignments_total") == (8 + 4) * 2 * 2
+    blocks = value("expert_slots_total") / (8 * 2 * 4)
+    assert blocks == int(blocks) and blocks >= 2
+    assert 0 < value("expert_hits_total") <= value("assignments_total")
+    # the fullest expert holds between its fair share and every live row
+    assert value("assignments_total") / 8 <= value("max_load_total") <= 12 * 2
+
+
+# -- (d): the kernel alone ------------------------------------------------
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("glu", [True, False])
+def test_fused_moe_mlp_against_jnp_on_stacked_weights(layer, glu):
+    L, E, D, F, B = 3, 8, 64, 256, 5                    # B: no multiple of 8
+    k = jax.random.split(jax.random.PRNGKey(layer), 7)
+    h, r = jax.random.normal(k[0], (B, D)), jax.random.normal(k[1], (B, D))
+    wu, wg = (0.1 * jax.random.normal(k[i], (L, E, D, F)) for i in (2, 3))
+    wd = 0.1 * jax.random.normal(k[4], (L, E, F, D))
+    combine = jax.random.uniform(k[5], (B, E)) * \
+        (jax.random.uniform(k[6], (B, E)) > 0.6)
+    got = fused_moe_mlp(h, r, combine, wu, wd, wg if glu else None,
+                        layer=layer, act="silu", impl="interpret")
+    want = r
+    for e in range(E):
+        up = h @ wu[layer, e]
+        a = jax.nn.silu(h @ wg[layer, e]) * up if glu else jax.nn.silu(up)
+        want = want + combine[:, e:e + 1] * (a @ wd[layer, e])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+
+
+# -- (e): grouped path == capacity path where nothing drops ---------------
+def _block(router):
+    cfg = tiny(2)
+    k = jax.random.split(jax.random.PRNGKey(3), 5)
+    params = {"gate_w": jax.random.normal(k[0], (64, 8)),
+              "w_up": 0.2 * jax.random.normal(k[1], (8, 64, 32)),
+              "w_gate": 0.2 * jax.random.normal(k[2], (8, 64, 32)),
+              "w_down": 0.2 * jax.random.normal(k[3], (8, 32, 64))}
+    x = jax.random.normal(k[4], (2, 24, 64))
+    if router == "one_expert_empty":       # nobody can choose expert 5
+        params["gate_w"] = params["gate_w"].at[:, 5].set(0.0)
+        x = x.at[..., 0].set(30.0)
+        params["gate_w"] = params["gate_w"].at[0].set(1.0).at[0, 5].set(-9.0)
+    elif router == "all_on_two":           # every token's top-2 is (1, 6)
+        x = x.at[..., 0].set(30.0)
+        params["gate_w"] = params["gate_w"].at[0].set(-1.0).at[0, 1].set(
+            3.0).at[0, 6].set(2.0)
+    return cfg, params, x
+
+
+@pytest.mark.parametrize("norm_topk", [False, True])
+@pytest.mark.parametrize("router", ["seeded", "one_expert_empty",
+                                    "all_on_two"])
+def test_grouped_path_equals_capacity_path_that_drops_nothing(router,
+                                                              norm_topk):
+    cfg, params, x = _block(router)
+    cfg.moe_norm_topk_prob = norm_topk
+    y, aux = moe_mlp(params, x, cfg)
+    cfg.moe_drop_tokens, cfg.moe_capacity_factor = True, 8 / 2   # C = N
+    y_cap, aux_cap = moe_mlp(params, x, cfg)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_cap), rtol=1e-5,
+                               atol=1e-5)
+    assert float(aux) == pytest.approx(float(aux_cap), rel=1e-6)
+    logits = x.reshape(-1, 64) @ params["gate_w"]
+    top = np.asarray(jax.lax.top_k(logits, 2)[1])
+    if router == "one_expert_empty":
+        assert 5 not in top
+    if router == "all_on_two":
+        assert (top == [1, 6]).all()
+
+
+def test_grouped_path_reads_stacked_expert_arrays_at_a_layer_index():
+    """Inference hands the model's [L, E, ...] arrays over whole, with the
+    layer's index: the other layers' groups are empty."""
+    cfg, params, x = _block("seeded")
+    want, _ = moe_mlp(params, x, cfg)
+    stack = {k: jnp.stack([jnp.zeros_like(v), v, 7.0 * jnp.ones_like(v)])
+             for k, v in params.items() if k != "gate_w"}
+    got, _ = jax.jit(lambda l: moe_mlp(
+        {"gate_w": params["gate_w"], **stack}, x, cfg, layer=l))(
+            jnp.asarray(1, jnp.int32))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
+                               atol=1e-5)
+    cfg.moe_drop_tokens = True
+    with pytest.raises(ValueError, match="only the dropless path"):
+        moe_mlp({"gate_w": params["gate_w"], **stack}, x, cfg, layer=1)
+
+
+def test_dropless_under_expert_parallelism_raises(devices):
+    cfg, params, x = _block("seeded")
+    mesh = build_mesh(ep=2, devices=devices[:2])
+    with pytest.raises(NotImplementedError, match="ep > 1"):
+        moe_mlp(params, x, cfg, mesh)

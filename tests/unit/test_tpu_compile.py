@@ -20,8 +20,8 @@ from jax.sharding import SingleDeviceSharding
 from deepspeed_tpu.ops.pallas import (flash_attention, fused_adam_update,
                                       layer_norm, quantize, rms_norm)
 from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
-                                             fused_norm_qkv, fused_proj_norm,
-                                             paged_kv_append)
+                                             fused_moe_mlp, fused_norm_qkv,
+                                             fused_proj_norm, paged_kv_append)
 from deepspeed_tpu.ops.pallas.fused_adam8bit import fused_adam8bit_update
 from deepspeed_tpu.ops.pallas.fused_lamb import fused_lamb_update
 from deepspeed_tpu.serving.paged_kv import default_page_tokens
@@ -250,4 +250,29 @@ def test_paged_kernels_compile_at_the_serve_chat_shape(v5e, kernel):
     """The block sizes the benchmark's serve cell runs (all 8 KV heads of a
     page in one grid step) are ones the chip's compiler has accepted."""
     fn, shapes, want = kernel(WIDTHS["d4096-gqa8"], **SERVE_CHAT)
+    assert _custom_calls(fn, v5e, *shapes) >= want
+
+
+# the benchmark's olmoe-1b-7b-L8.serve-chat cell: MHA 16 x 128 (all 16 KV
+# heads of a page are exactly the attention kernel's 4 MiB of K and V
+# buffers), 64 experts of width 1024 read from the stacked arrays
+OLMOE = dict(D=2048, H=16, Hkv=16, Dh=128, F=1024, V=50304, glu=True,
+             kind="rmsnorm")
+OLMOE_EXPERTS, OLMOE_LAYERS = 64, 8
+
+
+def _moe_mlp(w, slots=64, **_):
+    D, F, E, L = w["D"], w["F"], OLMOE_EXPERTS, OLMOE_LAYERS
+    fn = lambda h, r, c, wu, wd, wg: fused_moe_mlp(
+        h, r, c, wu, wd, wg, layer=L - 1, act="silu", impl="pallas")
+    return fn, [((slots, D), BF16), ((slots, D), BF16), ((slots, E), F32),
+                ((L, E, D, F), BF16), ((L, E, F, D), BF16),
+                ((L, E, D, F), BF16)], 1
+
+
+@pytest.mark.parametrize("kernel", [_decode_paged, _kv_append, _moe_mlp],
+                         ids=["flash_decode_paged", "paged_kv_append",
+                              "fused_moe_mlp"])
+def test_kernels_compile_at_the_olmoe_serve_chat_shape(v5e, kernel):
+    fn, shapes, want = kernel(OLMOE, **SERVE_CHAT)
     assert _custom_calls(fn, v5e, *shapes) >= want
