@@ -40,7 +40,7 @@ HOT_ZONES = {
     "deepspeed_tpu/serving/engine.py": {
         "step", "_decode_block", "_drain_one", "_flush_outstanding",
         "_fetch_block", "_materialize", "_prefill_one_chunk",
-        "_admit_prefix", "_release",
+        "_settle_first_tokens", "_admit_prefix", "_release",
     },
     "deepspeed_tpu/runtime/engine.py": {
         "step", "train_step", "train_batch", "forward",

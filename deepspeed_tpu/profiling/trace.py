@@ -34,7 +34,7 @@ def annotate(name: str):
 
 class phase:
     """A host span and its counter, written together:
-    ``with phase("ds_serve_wake"): ...`` opens the ``TraceAnnotation``
+    ``with phase("ds_serve_release"): ...`` opens the ``TraceAnnotation``
     ``<name>`` (a ``StepTraceAnnotation`` where ``step_num`` is given: it
     gives the trace its ``Steps`` line) and, while the registry is enabled,
     adds the elapsed ``perf_counter()`` seconds to the counter

@@ -38,8 +38,13 @@ scheduler never blocks on the block it just dispatched:
   stopped the row the step its EOS appeared — the host merely LEARNS of
   it from a DEFERRED drain: after dispatching block ``i`` it fetches
   block ``i-1``'s (tokens, valid) pair, so the fetch RTT overlaps live
-  device work and the only per-request sync left is the prefill
-  first-token check.  Slot frees land at most one decode block late.
+  device work.  Slot frees land at most one decode block late;
+- the prefill-sampled first token: the last chunk's program wakes the
+  slot itself (``last`` / ``pos`` / ``active`` are its carries too, and
+  a first token that IS the EOS never goes active), so the host fetches
+  the value only after the iteration's decode block is queued behind
+  the chunk — it never waits for a first token with the chip's queue
+  empty.
 
 Slot-reuse safety (why freed slots need no cache zeroing): a query at
 position p only attends cache rows <= p, and every row <= p has been
@@ -96,8 +101,8 @@ SERVE_PHASES = {
     "ds_serve_pages": "page allocation, eviction and preemption",
     "ds_serve_prefill_dispatch": "chunk build and prefill program enqueue",
     "ds_serve_first_token_fetch": "blocking fetch of the prefill-sampled "
-                                  "token (stream / EOS / last-token path)",
-    "ds_serve_wake": "slot wake-up after the last chunk",
+                                  "token (stream / EOS / last-token path), "
+                                  "after the decode block's enqueue",
     "ds_serve_decode_dispatch": "argument build and decode block enqueue",
     "ds_serve_block_fetch": "blocking fetch of deferred tokens (a block's, "
                             "or the deferred first token)",
@@ -276,15 +281,12 @@ class ServingEngine:
         self._eos = np.full(self.num_slots, -1, np.int32)
         self._drained_pos = np.zeros(self.num_slots, np.int32)
         # device-resident decode state: last sampled token, per-row
-        # position, per-row active mask — carried (donated) block to block
-        # so neither no-EOS nor EOS scheduling ever syncs per step
+        # position, per-row active mask — carried (donated) through every
+        # chunk program (which wakes the slot it prefilled) and block to
+        # block, so neither no-EOS nor EOS scheduling ever syncs per step
         self._last_dev = jnp.zeros(self.num_slots, jnp.int32)
         self._pos_dev = jnp.zeros(self.num_slots, jnp.int32)
         self._act_dev = jnp.zeros(self.num_slots, bool)
-        self._wake_fn = jax.jit(
-            lambda pos, act, slot, s: (pos.at[slot].set(s),
-                                       act.at[slot].set(True)),
-            donate_argnums=(0, 1))
         self._park_fn = jax.jit(
             lambda pos, act, slot: (pos.at[slot].set(0),
                                     act.at[slot].set(False)),
@@ -335,6 +337,10 @@ class ServingEngine:
         self._block_refs = {}    # idx -> pending consumers (refs + drains)
         self._block_moe = {}     # idx -> device routing counts (registry on)
         self._outstanding = deque()   # [(idx, [eos Request, ...])]
+        # first tokens owed in this iteration: (Request, device scalar),
+        # copy to the host started, value not read yet.  Settled before
+        # step() returns (_settle_first_tokens)
+        self._owed = deque()
         self._drain_lag = 1
         self._next_block = 0
         self.steps = 0
@@ -419,6 +425,11 @@ class ServingEngine:
                         f"host seconds inside {name}: {what}")
         self._m_moe = {name: reg.counter(name, what)
                        for name, what in SERVE_MOE_COUNTERS.items()}
+        self._m_first_overlapped = reg.counter(
+            "ds_serve_first_token_overlapped_total",
+            "first tokens fetched with a decode block already enqueued "
+            "behind their chunk (over finished requests: the share of first "
+            "tokens that cost the chip no gap)")
         self._m_prefill_chunks = reg.counter(
             "ds_serve_prefill_chunks_total", "prefill chunks dispatched")
         self._m_prefill_toks = reg.counter(
@@ -579,8 +590,9 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def step(self) -> List[Request]:
         """One scheduler iteration: admit → prefill chunk(s) → decode
-        block → drain deferred finish events.  Returns the requests that
-        finished during this iteration."""
+        block → fetch the chunks' first tokens → drain deferred finish
+        events.  Returns the requests that finished during this
+        iteration."""
         if self.engine._params is None:
             raise RuntimeError("no weights: set_params() first")
         # ledger: one scheduler iteration is a `compute` region (admit +
@@ -622,7 +634,11 @@ class ServingEngine:
         with self._phase("ds_serve_prefill"):
             for req in self.scheduler.prefilling()[: self.max_prefill_chunks]:
                 self._prefill_one_chunk(req)
-        # 3. decode one block for every active slot
+            if not self._active.any():
+                # no block will be queued behind the chunks: fetch now
+                self._settle_first_tokens()
+        # 3. decode one block for every active slot; the first tokens the
+        #    chunks owe are fetched right behind its enqueue
         if self._active.any():
             with self._phase("ds_serve_decode"):
                 self._decode_block()
@@ -852,6 +868,7 @@ class ServingEngine:
         if req.state == QUEUED:
             self.scheduler.cancel(req)
             return
+        self._settle_first_tokens()      # it may finish at its first token
         if (req.state in (PREFILLING, RUNNING)
                 and req.slot >= 0
                 and self.scheduler.request_in(req.slot) is req):
@@ -1608,7 +1625,10 @@ class ServingEngine:
         Under pool pressure, first drain any deferred finish events (a
         pending EOS release may free pages for free), then evict
         refcount-0 prefix-cache pages (LRU — cached history is
-        reclaimed BEFORE any live request suffers), and only then preempt
+        reclaimed BEFORE any live request suffers), then fetch the first
+        tokens still owed (the last thing that can free pages without a
+        victim; it waits on the chip with nothing queued behind, and no
+        victim is chosen with a first token owed), and only then preempt
         the YOUNGEST-admitted occupant (LIFO — possibly ``req`` itself,
         in which case False is returned and the caller skips this
         dispatch) and requeue it at the queue head.  The oldest request
@@ -1619,6 +1639,9 @@ class ServingEngine:
                 self._flush_outstanding()
                 continue
             if self.prefix_cache is not None and self.prefix_cache.evict_lru():
+                continue
+            if self._owed:
+                self._settle_first_tokens()
                 continue
             victim = self._youngest_victim()
             if victim is None:
@@ -1687,48 +1710,62 @@ class ServingEngine:
         t0 = time.perf_counter()
         slot, off = req.slot, req.prefill_pos
         prefix = req.prefix              # prompt (+ outputs after a resume)
-        n_prefix = req.prefix_len
-        c = min(self.prefill_chunk, n_prefix - off)
+        S = req.prefix_len
+        c = min(self.prefill_chunk, S - off)
         if self.paged and not self._ensure_pages(req, off + c):
             return                       # self-preempted: resumes later
+        last_chunk = off + c == S
+        wake = False
+        if last_chunk and not req.prefill_only:
+            # The position bound is ABSOLUTE, so it is invariant across
+            # preempt-resume (prefix grows by exactly the tokens produced).
+            # limit <= S: the cache budget is already exhausted by the
+            # prefix — the prefill-sampled token is the only one left to
+            # emit.  The bound is the LOGICAL max_out_tokens, not the
+            # page/block-rounded physical depth, so a request emits exactly
+            # what generate() would
+            req_bound = req.prompt_len + req.max_new_tokens - 1
+            limit = min(req_bound, self.max_out - 1)
+            req.limit_reason = ("length" if limit == req_bound
+                                else "cache_budget")
+            # the row decodes unless the host already knows that this token
+            # is the request's last (the device adds: unless it is the EOS)
+            wake = (len(req.output_tokens) + 1 < req.max_new_tokens
+                    and limit > S)
         with self._phase("ds_serve_prefill_dispatch"):
             cb = pow2_bucket(c, lo=8, cap=self.cache_len - off)
             chunk = np.zeros((1, cb), np.int32)
             chunk[0, :c] = prefix[off:off + c]
             self._rng, srng = jax.random.split(self._rng)
-            if self.paged:
-                tok_dev, self._cache = self._prefill_fn(cb)(
-                    self.engine._params, self._cache,
-                    jnp.asarray(self.pool.page_table[slot]),
-                    jnp.asarray(chunk), jnp.asarray(off, jnp.int32),
-                    jnp.asarray(c - 1, jnp.int32), srng)
-            else:
-                tok_dev, self._cache = self._prefill_fn(cb)(
-                    self.engine._params, self._cache, jnp.asarray(chunk),
-                    jnp.asarray(slot, jnp.int32), jnp.asarray(off, jnp.int32),
-                    jnp.asarray(c - 1, jnp.int32), srng)
+            # one transfer: slot, chunk offset, index of its last real
+            # token, wake flag, EOS id (-1: none)
+            meta = jnp.asarray(
+                [slot, off, c - 1, int(wake), req.eos_token_id], jnp.int32)
+            tok_dev, self._cache, carries = self._prefill_fn(cb)(
+                self.engine._params, self._cache,
+                (self._last_dev, self._pos_dev, self._act_dev),
+                (jnp.asarray(self.pool.page_table[slot]) if self.paged
+                 else None),
+                jnp.asarray(chunk), meta, srng)
+            self._last_dev, self._pos_dev, self._act_dev = carries
             req.prefill_pos += c
             self._tracer.span(req.request_id, "prefill_chunk", t0,
                               time.perf_counter(), c)
             self._m_prefill_chunks.inc()
             self._m_prefill_toks.inc(c)
             # parked rows write junk at their own pos; keeping pos =
-            # prefill progress means the NEXT chunk overwrites that row
+            # prefill progress (host view here, device carry inside the
+            # chunk's program) means the NEXT chunk overwrites that row
             # before any query attends it
             self._pos[slot] = req.prefill_pos
-            if req.prefill_pos < n_prefix:
-                # mirror the frontier onto the DEVICE pos carry: the decode
-                # block's parked junk write for this row must land at the
-                # frontier (overwritten by the next chunk), not at row 0
-                # the previous chunk already filled
-                self._pos_dev = self._setpos_fn(
-                    self._pos_dev, jnp.asarray(slot, jnp.int32),
-                    jnp.asarray(req.prefill_pos, jnp.int32))
-                return
+        if not last_chunk:
+            return
         # prefix fully resident: the next token came out of the final
-        # chunk's program.  Its VALUE is only fetched when scheduling
-        # depends on it (EOS) — otherwise it stays on device and the
-        # pipeline keeps flowing.
+        # chunk's program, which also woke the slot.  Its VALUE is only
+        # fetched when scheduling depends on it (EOS) or a client waits for
+        # it (stream), and then after the decode block is queued behind the
+        # chunk — otherwise it stays on device and the pipeline keeps
+        # flowing.
         if req.prefill_only:
             # prefill-role finish (disaggregated serving): the prompt KV
             # is resident — capture the full prompt pages for the
@@ -1739,48 +1776,49 @@ class ServingEngine:
             self._capture_handoff(req)
             self._release(req, "prefill_done")
             return
-        S = n_prefix
-        # The position bound is ABSOLUTE, so it is invariant across
-        # preempt-resume (prefix grows by exactly the tokens produced).
-        # limit <= S: the cache budget is already exhausted by the prefix —
-        # the prefill-sampled token is the only one left to emit.  The
-        # bound is the LOGICAL max_out_tokens, not the page/block-rounded
-        # physical depth, so a request emits exactly what generate() would
-        req_bound = req.prompt_len + req.max_new_tokens - 1
-        limit = min(req_bound, self.max_out - 1)
-        req.limit_reason = "length" if limit == req_bound else "cache_budget"
-        if (req.eos_token_id >= 0 or req.stream
-                or len(req.output_tokens) + 1 >= req.max_new_tokens
-                or limit <= S):
-            # streaming requests also take the sync: the first token IS
+        if req.eos_token_id >= 0 or req.stream or not wake:
+            # streaming requests are owed the value too: the first token IS
             # the first chunk on the wire — deferring it would hold TTFT
             # hostage to the first decode block's drain
-            with self._phase("ds_serve_first_token_fetch"):
-                first = int(tok_dev)     # the once-per-request EOS sync
-            self._first_token_on_host(req)
-            req.output_tokens.append(first)
-            if req.eos_token_id >= 0 and first == req.eos_token_id:
-                self._release(req, "eos")
-                return
-            if len(req.output_tokens) >= req.max_new_tokens:
-                self._release(req, "length")
-                return
-            if limit <= S:
-                self._release(req, req.limit_reason)
-                return
+            tok_dev.copy_to_host_async()
+            self._owed.append((req, tok_dev))
         else:
             req.pending_blocks.append(("tok", tok_dev))
-        with self._phase("ds_serve_wake"):
+        if wake:
             req.state = RUNNING
-            self._last_dev = self._last_dev.at[slot].set(tok_dev)
-            self._pos_dev, self._act_dev = self._wake_fn(
-                self._pos_dev, self._act_dev, jnp.asarray(slot, jnp.int32),
-                jnp.asarray(S, jnp.int32))
-            self._pos[slot] = S
             self._drained_pos[slot] = S
             self._limit[slot] = limit
             self._eos[slot] = req.eos_token_id
             self._active[slot] = True
+        elif S >= self.cache_len:
+            # the prefix fills the slot's window: no row is left for a
+            # parked slot to write its junk on while a block runs
+            self._settle_first_tokens()
+
+    def _settle_first_tokens(self, overlapped: bool = False) -> None:
+        """Fetch the first tokens this iteration's last chunks owe, in
+        dispatch order, and make them visible.  The copies were started at
+        dispatch, so each value reaches the host when its chunk ends;
+        ``overlapped`` says a decode block is already queued behind the
+        chunks.  Whether the row decodes was decided on the device (a first
+        token that is the EOS never went active) or known before dispatch
+        (its last token); the host learns it here and releases the request
+        as ``_drain_one`` releases a row whose finish it could not predict:
+        its view only over-allocated pages for one block."""
+        while self._owed:
+            req, tok_dev = self._owed.popleft()
+            with self._phase("ds_serve_first_token_fetch"):
+                first = int(tok_dev)
+            self._first_token_on_host(req)
+            req.output_tokens.append(first)
+            if overlapped:
+                self._m_first_overlapped.inc()
+            if req.eos_token_id >= 0 and first == req.eos_token_id:
+                self._release(req, "eos")
+            elif len(req.output_tokens) >= req.max_new_tokens:
+                self._release(req, "length")
+            elif req.state != RUNNING:       # never woke: limit <= S
+                self._release(req, req.limit_reason)
 
     def _first_token_on_host(self, req: Request) -> None:
         """The prefill-sampled token's VALUE has just reached the host:
@@ -1797,7 +1835,10 @@ class ServingEngine:
         self._tracer.decode_start(req.request_id, t)
 
     def _prefill_fn(self, cb: int):
-        """Per-slot chunked prefill, compiled once per pow2 chunk bucket.
+        """Per-slot chunked prefill, compiled once per pow2 chunk bucket:
+        ``(params, cache, (last, pos, active), page-table row | None, chunk
+        [1, cb], meta, rng) -> (token, cache, (last, pos, active))``, cache
+        and carries donated.
 
         Fixed layout: slice the slot's cache rows out, run the standard
         (batch-1) prefill forward at the chunk's absolute offset, write the
@@ -1811,7 +1852,14 @@ class ServingEngine:
         attended AFTER being overwritten by the next chunk / decode step
         (queries attend key_pos <= q_pos, and every row <= q_pos has been
         rewritten by then); junk landing past the allocated pages goes to
-        the junk page."""
+        the junk page.
+
+        The program also updates the decode block's carries for its slot:
+        ``last`` takes the sampled token, ``pos`` the prefill frontier (so
+        a parked row's junk write lands where the next chunk or decode step
+        overwrites it), and ``active`` is set where ``meta`` says the row
+        decodes and the token is not its EOS — the wake costs no dispatch
+        of its own and waits for no value on the host."""
         if cb in self._prefill_fns:
             return self._prefill_fns[cb]
         self._m_compiles.inc()
@@ -1820,52 +1868,43 @@ class ServingEngine:
         if self.paged:
             maxp, page = self.pool.slot_pages, self.pool.page
 
-            @functools.partial(jax.jit, donate_argnums=(1,))
-            def prefill(params, cache, pt_row, chunk, start, last_idx, srng):
-                def gather(v):
-                    g = v[:, pt_row]            # [L, maxp, Hkv, page, D]
-                    L, mp, Hkv, pg, D = g.shape
-                    return g.transpose(0, 2, 1, 3, 4).reshape(
-                        L, 1, Hkv, mp * pg, D)
+        @functools.partial(jax.jit, donate_argnums=(1, 2))
+        def prefill(params, cache, carries, pt_row, chunk, meta, srng):
+            slot, start, last_idx, wake, eos = meta
 
-                def scatter(dst, s):
-                    L, _, Hkv, _, D = s.shape
-                    pages = s.reshape(L, Hkv, maxp, page, D).transpose(
-                        0, 2, 1, 3, 4)
-                    return dst.at[:, pt_row].set(pages)
+            def view(v):                 # the slot's rows, contiguous
+                if pt_row is None:
+                    return jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
+                g = v[:, pt_row]                # [L, maxp, Hkv, page, D]
+                L, mp, Hkv, pg, D = g.shape
+                return g.transpose(0, 2, 1, 3, 4).reshape(
+                    L, 1, Hkv, mp * pg, D)
 
-                sub = {k: (gather(v) if v.ndim == 5 else v)
-                       for k, v in cache.items()}
-                logits, sub = forward_with_cache(model, params, chunk, sub,
-                                                 start)
-                out = {k: (scatter(cache[k], sub[k])
-                           if cache[k].ndim == 5 else sub[k])
-                       for k in cache}
-                last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1,
-                                                    keepdims=False)
-                tok = sample_token(last, srng, temperature=temperature,
-                                   top_k=top_k, top_p=top_p,
-                                   do_sample=do_sample)[0].astype(jnp.int32)
-                return tok, out
+            def write_back(dst, s):
+                if pt_row is None:
+                    return jax.lax.dynamic_update_slice_in_dim(
+                        dst, s, slot, axis=1)
+                L, _, Hkv, _, D = s.shape
+                pages = s.reshape(L, Hkv, maxp, page, D).transpose(
+                    0, 2, 1, 3, 4)
+                return dst.at[:, pt_row].set(pages)
 
-            self._prefill_fns[cb] = prefill
-            return prefill
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def prefill(params, cache, chunk, slot, start, last_idx, srng):
-            sub = {k: (jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-                       if v.ndim == 5 else v) for k, v in cache.items()}
+            sub = {k: (view(v) if v.ndim == 5 else v)
+                   for k, v in cache.items()}
             logits, sub = forward_with_cache(model, params, chunk, sub, start)
-            out = {k: (jax.lax.dynamic_update_slice_in_dim(cache[k], sub[k],
-                                                           slot, axis=1)
+            out = {k: (write_back(cache[k], sub[k])
                        if cache[k].ndim == 5 else sub[k])
                    for k in cache}
-            last = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1,
-                                                keepdims=False)
-            tok = sample_token(last, srng, temperature=temperature,
+            logits = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1,
+                                                  keepdims=False)
+            tok = sample_token(logits, srng, temperature=temperature,
                                top_k=top_k, top_p=top_p,
                                do_sample=do_sample)[0].astype(jnp.int32)
-            return tok, out
+            last, pos, active = carries
+            return tok, out, (
+                last.at[slot].set(tok),
+                pos.at[slot].set(start + last_idx + 1),
+                active.at[slot].set((wake != 0) & (tok != eos)))
 
         self._prefill_fns[cb] = prefill
         return prefill
@@ -1885,7 +1924,12 @@ class ServingEngine:
           of this block and fetches the block's (toks, valid) pair only
           after the NEXT block is dispatched (lag 1) — the fetch RTT
           overlaps live device work, and the valid mask tells exactly how
-          many tokens each row emitted before its EOS stopped it."""
+          many tokens each row emitted before its EOS stopped it.
+
+        Right behind the enqueue the host fetches the first tokens this
+        iteration's last chunks owe (``_settle_first_tokens``): the chunks
+        are ahead of the block in the chip's queue, so the wait for their
+        values is covered by the block."""
         t0 = time.perf_counter()
         running = self.scheduler.running()
         if self.paged:
@@ -1903,6 +1947,7 @@ class ServingEngine:
             # a preemption above may have demoted someone mid-list
             running = [r for r in running if r.state == RUNNING]
             if not self._active.any():
+                self._settle_first_tokens()
                 return
         with self._phase("ds_serve_decode_dispatch"):
             args = [self._loop_params(), self._cache, self._last_dev,
@@ -1948,8 +1993,11 @@ class ServingEngine:
                 self._block_moe[idx] = moe
         if drainers:
             self._outstanding.append((idx, drainers))
-            while len(self._outstanding) > self._drain_lag:
-                self._drain_one()
+        # the one wait of an iteration that nothing earlier covers comes
+        # here, with the block queued behind the chunks it waits for
+        self._settle_first_tokens(overlapped=True)
+        while len(self._outstanding) > self._drain_lag:
+            self._drain_one()
         for req in running:              # finish AFTER refs registered
             if (req.eos_token_id < 0 and not req.stream
                     and not self._active[req.slot]

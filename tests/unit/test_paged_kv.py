@@ -111,6 +111,16 @@ def _ref_out(ref, prompt, n):
                                    do_sample=False))[0, len(prompt):]
 
 
+def _truncate(seq, eos):
+    """``seq`` up to and including its first ``eos``."""
+    out = []
+    for t in seq:
+        out.append(int(t))
+        if int(t) == eos:
+            break
+    return out
+
+
 def test_pool_pressure_preempts_and_resumes_token_identical(setup, rng):
     """An oversubscribed pool (5 pages for two 3-page requests) must
     preempt the YOUNGEST slot, requeue it at the queue head, and resume it
@@ -221,15 +231,7 @@ def test_eos_decode_runs_sync_free(setup, rng):
                int((set(range(256)) - set(base[1].tolist())).pop()),
                int(base[2][-2])]
 
-    def truncate(seq, eos):
-        out = []
-        for t in seq:
-            out.append(int(t))
-            if int(t) == eos:
-                break
-        return out
-
-    want = [truncate(b, e) for b, e in zip(base, eos_ids)]
+    want = [_truncate(b, e) for b, e in zip(base, eos_ids)]
     fetches = []
     real_fetch = serve._fetch_block
 
@@ -298,3 +300,224 @@ def test_fixed_slot_fallback_parity(setup, rng):
     for i, (req, w) in enumerate(zip(reqs, want)):
         np.testing.assert_array_equal(np.asarray(req.output_tokens), w,
                                       err_msg=f"fixed-slot request {i}")
+
+
+# ---------------------------------------------------------------------------
+# first tokens fetched behind the decode block (ISSUE 28): the last chunk's
+# program wakes its slot on the device and the host reads the value only
+# after the iteration's block is enqueued.  Served tokens must be what they
+# were when the host read each first token right behind its chunk: equal
+# to generate() (greedy), and equal to the same engine run in that older
+# order from the same key (greedy and sampled; same programs, same splits).
+# ---------------------------------------------------------------------------
+
+OVERLAP_PATHS = ["stream", "eos_later", "eos_first", "max_new_1",
+                 "window_less_one", "window_full", "two_last_chunks",
+                 "abort_owed"]
+
+
+@pytest.fixture(scope="module", params=["paged", "fixed"])
+def overlap(request, setup):
+    """One greedy and one sampling engine per layout, two slots, ample
+    pool; a reference with room for one token past the serving window."""
+    model, params, _ = setup
+    over = {} if request.param == "paged" else {"paged_kv_cache": False}
+    cfg = {"dtype": "float32", "max_out_tokens": 64, "kv_page_tokens": 16,
+           **over}
+    engines = {}
+    for name, kw in (("greedy", {}),
+                     ("sampled", {"do_sample": True, "temperature": 0.8,
+                                  "top_k": 24})):
+        engines[name] = deepspeed_tpu.init_serving(
+            model, config=dict(cfg), num_slots=2, prefill_chunk=4,
+            decode_block_tokens=3, **kw)
+        engines[name].set_params(params)
+    ref = deepspeed_tpu.init_inference(
+        model, config={"dtype": "float32", "max_out_tokens": 128})
+    ref.set_params(params)
+    yield engines, ref
+    for e in engines.values():
+        e.close()
+
+
+def _fetch_behind_each_chunk(serve, monkeypatch):
+    """The order before ISSUE 28: every first token is read right behind
+    its own chunk's enqueue, before anything else is dispatched."""
+    real = serve._prefill_one_chunk
+
+    def chunk_then_fetch(req):
+        real(req)
+        serve._settle_first_tokens()
+
+    monkeypatch.setattr(serve, "_prefill_one_chunk", chunk_then_fetch)
+
+
+def _serve_from_key(serve, submits, hook=None):
+    """Serve ``submits`` ([(prompt, kwargs)]) from a fixed sampling key;
+    returns [(tokens, finish reason)]."""
+    serve._rng = jax.random.PRNGKey(28)
+    while serve.prefix_cache is not None and serve.prefix_cache.evict_lru():
+        pass              # an earlier run's prompts: every run computes all
+    reqs = [serve.submit(p, **kw) for p, kw in submits]
+    if hook is not None:
+        hook(reqs)
+    serve.run()
+    assert not serve._owed and not serve._outstanding
+    if serve.pool is not None:
+        assert serve.pool.pages_used == 0
+        serve.pool.check_no_leak()
+    assert all(r.done for r in reqs)
+    return [(list(r.output_tokens), r.finish_reason) for r in reqs]
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "sampled"])
+@pytest.mark.parametrize("path", OVERLAP_PATHS)
+def test_first_token_behind_the_block_serves_the_same_tokens(
+        overlap, monkeypatch, path, sampling):
+    engines, ref = overlap
+    serve = engines[sampling]
+    rng = np.random.default_rng(OVERLAP_PATHS.index(path))
+    prompt = lambda n: rng.integers(0, 256, n, dtype=np.int32)
+    # what the three prompts emit undisturbed (request 0's chunk is the
+    # first program from the key, so its first tokens hold in any mix)
+    prompts = [prompt(n) for n in (3, 9, 6)]
+    base = _serve_from_key(
+        serve, [(p, dict(max_new_tokens=8, stream=True)) for p in prompts])
+    unseen = int((set(range(256))
+                  - {t for toks, _ in base for t in toks}).pop())
+    hook, want_reasons = None, None
+    if path == "stream":
+        submits = [(p, dict(max_new_tokens=8, stream=True)) for p in prompts]
+        want_reasons = ["length"] * 3
+    elif path == "eos_later":
+        submits = [(prompts[0], dict(max_new_tokens=8,
+                                     eos_token_id=base[0][0][3])),
+                   (prompts[1], dict(max_new_tokens=8, eos_token_id=unseen)),
+                   (prompts[2], dict(max_new_tokens=8, stream=True))]
+    elif path == "eos_first":
+        submits = [(prompts[0], dict(max_new_tokens=8,
+                                     eos_token_id=base[0][0][0])),
+                   (prompts[1], dict(max_new_tokens=8, stream=True)),
+                   (prompts[2], dict(max_new_tokens=8))]
+    elif path == "max_new_1":
+        submits = [(prompts[0], dict(max_new_tokens=1)),
+                   (prompts[1], dict(max_new_tokens=1, stream=True)),
+                   (prompts[2], dict(max_new_tokens=1, eos_token_id=unseen))]
+        want_reasons = ["length"] * 3
+    elif path in ("window_less_one", "window_full"):
+        # limit <= S: the prefix leaves the window no room to decode; a
+        # full window also leaves no row for the parked slot's junk
+        n = serve.max_out - (path == "window_less_one")
+        submits = [(prompt(n), dict(max_new_tokens=8, stream=True)),
+                   (prompts[1], dict(max_new_tokens=8, stream=True))]
+        want_reasons = ["cache_budget", "length"]
+    elif path == "two_last_chunks":
+        # both fit one chunk: two last chunks in the first iteration, and
+        # again when the third takes a freed slot beside a running one
+        submits = [(prompt(4), dict(max_new_tokens=5, stream=True)),
+                   (prompt(2), dict(max_new_tokens=7, eos_token_id=unseen)),
+                   (prompt(3), dict(max_new_tokens=4, stream=True))]
+        want_reasons = ["length"] * 3
+    else:                                       # abort_owed
+        submits = [(prompts[0], dict(max_new_tokens=8, stream=True)),
+                   (prompts[1], dict(max_new_tokens=8, stream=True))]
+        want_reasons = ["cancelled", "length"]
+
+        def hook(reqs):
+            # an abort that meets the request between its last chunk and
+            # the block: prompt 0 is one chunk, so that is iteration one
+            real = serve._decode_block
+
+            def abort_then_decode():
+                monkeypatch.setattr(serve, "_decode_block", real)
+                serve._process_abort(reqs[0])
+                assert reqs[0].output_tokens and not serve._owed
+                real()
+
+            monkeypatch.setattr(serve, "_decode_block", abort_then_decode)
+
+    got = _serve_from_key(serve, submits, hook)
+    with monkeypatch.context() as m:
+        _fetch_behind_each_chunk(serve, m)
+        before = _serve_from_key(serve, submits, hook)
+    if (path, sampling) == ("eos_first", "sampled"):
+        # the one difference: the host learns of an EOS at the first token
+        # with a block already enqueued (as of any EOS, one block late), and
+        # that block's key splits move the LATER draws of a sampling engine
+        assert got[0] == before[0]
+        assert [(len(t), r) for t, r in got] == \
+            [(len(t), r) for t, r in before]
+    else:
+        assert got == before
+    if want_reasons is not None:
+        assert [r for _, r in got] == want_reasons
+    if path == "eos_first":
+        assert got[0] == ([base[0][0][0]], "eos")
+    if path == "eos_later":
+        assert got[0] == (base[0][0][:4], "eos")
+    if path == "abort_owed":
+        assert got[0][0] == base[0][0][:1]
+    if sampling == "greedy":
+        for (p, kw), (toks, reason) in zip(submits, got):
+            if reason == "cancelled":
+                continue
+            want = _ref_out(ref, p, kw["max_new_tokens"])
+            want = _truncate(want, kw.get("eos_token_id", -1))
+            if reason == "cache_budget":
+                want = want[:len(toks)]
+                assert len(toks) == 1
+            assert toks == want
+
+
+@pytest.mark.parametrize("sampling", ["greedy", "sampled"])
+def test_pool_pressure_meets_an_owed_first_token(setup, monkeypatch,
+                                                 sampling):
+    """Preemption with a first token owed: a dry pool reads the owed
+    value before it chooses a victim (before ISSUE 28 it had been read
+    behind its chunk), so the same requests are preempted and resume
+    token-identically."""
+    model, params, ref = setup
+    kw = ({} if sampling == "greedy"
+          else {"do_sample": True, "temperature": 0.8, "top_k": 24})
+    serve = deepspeed_tpu.init_serving(
+        model, config={"dtype": "float32", "max_out_tokens": 64,
+                       "kv_page_tokens": 16, "kv_pool_tokens": 64},
+        num_slots=2, prefill_chunk=4, decode_block_tokens=3, **kw)
+    serve.set_params(params)
+    rng = np.random.default_rng(7)
+    # four pages of 16: while the short request decodes inside two pages
+    # the long one's 32 tokens fill two, a chunk an iteration; the block
+    # behind its last chunk needs a fifth page with its first token owed
+    submits = [(rng.integers(0, 256, n, dtype=np.int32),
+                dict(max_new_tokens=m, stream=True))
+               for n, m in ((3, 40), (32, 10))]
+    met = []
+    real_ensure, real_preempt = serve._ensure_pages, serve._preempt
+
+    def ensure(req, tokens):
+        owed = len(serve._owed)
+        ok = real_ensure(req, tokens)
+        if owed and not serve._owed:
+            met.append(owed)
+        return ok
+
+    def preempt(victim):
+        assert not serve._owed
+        real_preempt(victim)
+
+    monkeypatch.setattr(serve, "_ensure_pages", ensure)
+    monkeypatch.setattr(serve, "_preempt", preempt)
+    reqs = []
+    got = _serve_from_key(serve, submits, reqs.extend)
+    preempted = [r.preemptions for r in reqs]
+    assert sum(preempted) >= 1 and met, (preempted, met)
+    with monkeypatch.context() as m:
+        _fetch_behind_each_chunk(serve, m)
+        reqs = []
+        before = _serve_from_key(serve, submits, reqs.extend)
+    assert got == before and [r.preemptions for r in reqs] == preempted
+    if sampling == "greedy":
+        for (p, kw), (toks, reason) in zip(submits, got):
+            assert toks == _ref_out(ref, p, kw["max_new_tokens"]).tolist()
+            assert reason == "length"
+    serve.close()

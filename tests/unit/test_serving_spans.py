@@ -70,8 +70,8 @@ class _SlowToken:
         time.sleep(FETCH_DELAY_S)
         return int(self.array)
 
-    def __jax_array__(self):
-        return self.array
+    def copy_to_host_async(self):
+        self.array.copy_to_host_async()
 
 
 @pytest.mark.parametrize("path", ["stream", "eos", "deferred"])
@@ -80,8 +80,8 @@ def test_first_token_is_stamped_after_the_fetch(serve, monkeypatch, path):
 
     def slow(cb):
         def program(*args):
-            tok, cache = real(cb)(*args)
-            return _SlowToken(tok), cache
+            tok, cache, carries = real(cb)(*args)
+            return _SlowToken(tok), cache, carries
         return program
 
     monkeypatch.setattr(serve, "_prefill_fn", slow)
@@ -105,8 +105,9 @@ def test_first_token_is_stamped_after_the_fetch(serve, monkeypatch, path):
         assert r.done and r.output_tokens
         assert r.t_submit <= r.t_admit
         # the value reached the host a fetch later than its program was
-        # enqueued (the last chunk's span ends there), and no token was
-        # visible outside before the stamp
+        # enqueued (the last chunk's span ends there; the fetch itself now
+        # follows the decode block's enqueue), and no token was visible
+        # outside before the stamp
         enqueued = max(t1 for kind, _, t1, _ in by_id[r.request_id]["spans"]
                        if kind == "prefill_chunk")
         assert r.t_first_token - enqueued >= FETCH_DELAY_S
@@ -129,6 +130,71 @@ def test_first_token_is_stamped_after_the_fetch(serve, monkeypatch, path):
     # a deferred request got all of its tokens at one instant, its finish
     assert reg.get("ds_serve_tpot_seconds").count == (
         0 if path == "deferred" else len(reqs))
+
+
+class _LoggedToken(_SlowToken):
+    """A sampled token that says when its value is read."""
+
+    def __init__(self, array, log):
+        super().__init__(array)
+        self.log = log
+
+    def __int__(self):
+        self.log.append("first_token_fetch")
+        return int(self.array)
+
+
+def test_no_fetch_between_the_last_chunk_and_the_block(serve, monkeypatch):
+    """ISSUE 28: in an iteration that decodes, the host reads nothing from
+    the device between a chunk's enqueue and the block's: every fetch of
+    the iteration (a first token's, the lag-1 drain's) comes behind the
+    block, and the first tokens are visible when that ``step()`` returns."""
+    log = []
+    real_prefill, real_block = serve._prefill_fn, serve._block
+    real_fetch = serve._fetch_block
+
+    def prefill(cb):
+        def program(*args):
+            log.append("chunk")
+            tok, cache, carries = real_prefill(cb)(*args)
+            return _LoggedToken(tok, log), cache, carries
+        return program
+
+    def block():
+        def program(*args):
+            log.append("block")
+            return real_block()(*args)
+        return program
+
+    def fetch_block(idx):
+        log.append("block_fetch")
+        return real_fetch(idx)
+
+    monkeypatch.setattr(serve, "_prefill_fn", prefill)
+    monkeypatch.setattr(serve, "_block", block)
+    monkeypatch.setattr(serve, "_fetch_block", fetch_block)
+    reg = get_registry()
+    reg.enable()
+    reg.reset()
+    reqs = [serve.submit(p, max_new_tokens=6, stream=True)
+            for p in _prompts(5, seed=11)]
+    overlapped = 0
+    while serve.scheduler.has_work:
+        del log[:]
+        had = [len(r.output_tokens) for r in reqs]
+        serve.step()
+        assert not serve._owed
+        firsts = log.count("first_token_fetch")
+        assert firsts == sum(1 for r, k in zip(reqs, had)
+                             if k == 0 and r.output_tokens)
+        if "block" in log:
+            at = log.index("block")
+            assert set(log[:at]) <= {"chunk"}, log
+            overlapped += firsts
+    assert all(r.done and len(r.output_tokens) == 6 for r in reqs)
+    assert overlapped == len(reqs)
+    assert reg.get("ds_serve_first_token_overlapped_total").value == \
+        len(reqs)
 
 
 # ---------------------------------------------------------------------------
@@ -224,10 +290,14 @@ def test_host_shares_read_the_phase_counters(serve):
     parents = sum(end[f"ds_serve_{p}_seconds_total"]
                   for p in ("admit", "prefill", "decode"))
     assert parents <= step_s
-    assert end["ds_serve_prefill_dispatch_seconds_total"] + \
-        end["ds_serve_first_token_fetch_seconds_total"] + \
-        end["ds_serve_wake_seconds_total"] <= \
+    assert end["ds_serve_prefill_dispatch_seconds_total"] <= \
         end["ds_serve_prefill_seconds_total"]
+    # every request here decodes, so each first token was fetched inside
+    # ds_serve_decode, behind the block's enqueue
+    assert end["ds_serve_first_token_overlapped_total"] == len(reqs)
+    assert end["ds_serve_decode_dispatch_seconds_total"] + \
+        end["ds_serve_first_token_fetch_seconds_total"] <= \
+        end["ds_serve_decode_seconds_total"]
 
 
 # ---------------------------------------------------------------------------
@@ -271,8 +341,9 @@ def _programs(serve):
             jnp.asarray(serve.pool.page_table))
         prefill = serve._prefill_fn(8).lower(
             serve.engine._params, serve._cache,
+            (serve._last_dev, serve._pos_dev, serve._act_dev),
             jnp.asarray(serve.pool.page_table[0]), jnp.zeros((1, 8), jnp.int32),
-            jnp.asarray(0, jnp.int32), jnp.asarray(7, jnp.int32), serve._rng)
+            jnp.asarray([0, 0, 7, 1, -1], jnp.int32), serve._rng)
     finally:
         serve.pool.release(0)
     return program_text(block.compile()), program_text(prefill.compile())
@@ -294,19 +365,34 @@ def test_programs_are_the_same_with_tracing_on_and_off(serve, tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# (d) the chip's idle time, range by range, on a trace recorded on a v5e
-# (my chip run, PR 25): 150 ms from the middle of the traced window of
-# mistral-7b-L8.serve-chat, cut by `python -m benchmarks.lib.host_spans
-# <trace dir> --cut`
+# (d) the chip's idle time, range by range, on traces recorded on a v5e:
+# 150 ms from the middle of the traced window of mistral-7b-L8.serve-chat,
+# cut by `python -m benchmarks.lib.host_spans <trace dir> --cut`.  One from
+# before ISSUE 28 (my chip run, PR 25: the first token fetched and the slot
+# woken inside ds_serve_prefill), one from after it (my chip run, PR 28:
+# the fetch inside ds_serve_decode, behind the block's enqueue; no wake)
 # ---------------------------------------------------------------------------
 
-SPANS_FIXTURE = os.path.join(os.path.dirname(tr.__file__), os.pardir,
-                             "tests", "fixtures",
-                             "v5e_serve_spans_150ms.json.gz")
+FIXTURES = os.path.join(os.path.dirname(tr.__file__), os.pardir, "tests",
+                        "fixtures")
+SPANS_FIXTURES = {
+    "pr25": (os.path.join(FIXTURES, "v5e_serve_spans_150ms.json.gz"),
+             {"ds_serve_prefill": ("ds_serve_prefill_dispatch",
+                                   "ds_serve_first_token_fetch",
+                                   "ds_serve_wake"),
+              "ds_serve_decode": ("ds_serve_decode_dispatch",)}),
+    "pr28": (os.path.join(os.path.dirname(__file__), "fixtures",
+                          "v5e_serve_spans_pr28_150ms.json.gz"),
+             {"ds_serve_prefill": ("ds_serve_prefill_dispatch",),
+              "ds_serve_decode": ("ds_serve_decode_dispatch",
+                                  "ds_serve_first_token_fetch")}),
+}
 
 
-def test_idle_shares_partition_the_device_idle_share():
-    trace = tr.load_events(SPANS_FIXTURE)
+@pytest.mark.parametrize("recorded", sorted(SPANS_FIXTURES))
+def test_idle_shares_partition_the_device_idle_share(recorded):
+    path, under = SPANS_FIXTURES[recorded]
+    trace = tr.load_events(path)
     idle = host_spans.idle_by_span(trace)
     summary = tr.summarize(trace, host_scopes=(
         "ds_serve_admit", "ds_serve_prefill", "ds_serve_decode"))
@@ -321,15 +407,30 @@ def test_idle_shares_partition_the_device_idle_share():
     # what summarize() puts under a parent is that parent's self time plus
     # its children's
     spans = idle["by_span"]
-    under = {"ds_serve_prefill": ("ds_serve_prefill_dispatch",
-                                  "ds_serve_first_token_fetch",
-                                  "ds_serve_wake"),
-             "ds_serve_decode": ("ds_serve_decode_dispatch",)}
     for parent, children in under.items():
         assert spans[parent] <= summary["idle_gaps"].get(parent, 0.0) + 1e-9
         assert sum(spans.get(c, 0.0) for c in children) <= \
             summary["idle_gaps"].get(parent, 0.0) + 1e-9
     assert "ds_serve_step" in idle["spans"]
+    fetches = tr.host_events(trace, "ds_serve_first_token_fetch")
+    assert fetches
+    if recorded == "pr28":
+        # each first token is read inside a decode range, after that
+        # range's dispatch: the block is queued behind the chunk
+        assert "ds_serve_wake" not in idle["spans"]
+        end = lambda e: e.start + e.dur
+        behind = 0
+        for f in fetches:
+            (dec,) = [d for d in tr.host_events(trace, "ds_serve_decode")
+                      if d.start <= f.start and end(f) <= end(d)]
+            inside = [d for d in tr.host_events(
+                trace, "ds_serve_decode_dispatch")
+                if dec.start <= d.start and end(d) <= end(dec)]
+            # (the cut may have taken a range's beginning, dispatch and all)
+            assert all(end(d) <= f.start for d in inside)
+            behind += bool(inside)
+        assert behind
+        assert device_idle_share < 5.0
     # a trace of a program without the ranges gives nothing, not zeros
     for lines in trace.values():
         for line, evs in lines.items():

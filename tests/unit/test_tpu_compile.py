@@ -276,3 +276,58 @@ def _moe_mlp(w, slots=64, **_):
 def test_kernels_compile_at_the_olmoe_serve_chat_shape(v5e, kernel):
     fn, shapes, want = kernel(OLMOE, **SERVE_CHAT)
     assert _custom_calls(fn, v5e, *shapes) >= want
+
+
+def test_last_chunk_program_aliases_cache_and_carries_at_serve_chat(v5e):
+    """ISSUE 28: the chunk program of the ``mistral-7b-L8.serve-chat`` cell
+    (64 slots, pages of 256, bucket 256; two layers of the cell's eight,
+    which changes no shape the aliasing turns on) compiles for the v5e with
+    every donated argument taken: K and V pools AND the decode block's
+    three carries (``last``, ``pos``, ``active``), which the program now
+    updates for its slot, come back in their own buffers."""
+    import json
+    import re
+    import warnings
+
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.models import CausalLM, ModelConfig
+    from deepspeed_tpu.serving.engine import ServingEngine
+
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                         "benchmarks")
+    with open(os.path.join(bench, "configs", "mistral-7b-L8.json")) as f:
+        fields = dict(json.load(f)["model_config"], num_layers=2)
+    with open(os.path.join(bench, "workloads",
+                           "mistral-7b-L8.serve-chat.json")) as f:
+        engine = dict(json.load(f)["engine"], dtype="bfloat16")
+    (device,) = v5e.device_set
+    model = CausalLM(ModelConfig(**fields), build_mesh(devices=[device]))
+    serve = ServingEngine(model, engine)
+    assert (serve.num_slots, serve.pool.page) == (64, 256)
+    bucket = serve.prefill_chunk
+    assert bucket == 256
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda key: jax.tree.map(lambda x: x.astype(BF16), model.init(key)),
+        jax.random.PRNGKey(0)))
+    carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # "donated buffers not usable"
+        text = serve._prefill_fn(bucket).lower(
+            params, on_chip(serve._cache), on_chip(carries),
+            jax.ShapeDtypeStruct((serve.pool.slot_pages,), I32, sharding=v5e),
+            jax.ShapeDtypeStruct((1, bucket), I32, sharding=v5e),
+            jax.ShapeDtypeStruct((5,), I32, sharding=v5e),
+            on_chip(serve._rng)).compile().as_text()
+    header = text[:text.index("entry_computation_layout")]
+    aliased = {int(arg): int(out) for out, arg in re.findall(
+        r"\{(\d+)\}: \((\d+), \{\}", header)}
+    first = len(jax.tree.leaves(params))
+    donated = len(jax.tree.leaves((serve._cache, carries)))
+    assert donated == 5
+    # results: the token, then the pools and the carries in argument order
+    assert aliased == {first + i: 1 + i for i in range(donated)}
