@@ -1,20 +1,14 @@
 # Single-command entries the builder's verify recipe runs before the
 # suite (see ROADMAP.md for the canonical tier-1 line).
 
-.PHONY: lint lint-json tier1 chaos perf-diff profile-report
+.PHONY: lint lint-json tier1 chaos profile-report
 
 # dslint: AST-level invariant checker (docs/LINT.md) — no jax needed
 lint:
-	python tools/dslint.py deepspeed_tpu tools bench.py
+	python tools/dslint.py deepspeed_tpu tools
 
 lint-json:
-	python tools/dslint.py --json deepspeed_tpu tools bench.py
-
-# perf regression gate over the committed BENCH_*/MULTICHIP_* ledgers
-# (tools/perf_ledger.py --check exits 1 when the trajectory tip regresses
-# beyond tolerance; no jax needed)
-perf-diff:
-	python tools/perf_ledger.py --check --all
+	python tools/dslint.py --json deepspeed_tpu tools
 
 # newest continuous-profiler window + window-over-window regression
 # verdict from the on-disk history ring (docs/OBSERVABILITY.md
@@ -28,11 +22,11 @@ tier1: lint
 		--continue-on-collection-errors -p no:cacheprovider \
 		-p no:xdist -p no:randomly
 
-# the slow-marked chaos suites (outside tier-1): the serving fleet
-# matrix + bench_fleet_chaos, and the TRAINING matrix
-# (tests/perf/test_train_chaos.py — randomized kill-sweep across an
-# elastic 4->2->8->4 cycle, multi-round gradient bombs, and the
-# bench_elastic_resume rung) at CPU smoke scale
+# the slow-marked chaos suites (outside tier-1): the mid-stream
+# decode-replica kill in tests/unit/test_serving_chaos.py and the
+# TRAINING matrix (tests/perf/test_train_chaos.py — randomized
+# kill-sweep across an elastic 4->2->8->4 cycle, multi-round gradient
+# bombs) at CPU smoke scale
 chaos:
 	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m slow -k chaos \
 		--continue-on-collection-errors -p no:cacheprovider \

@@ -4,7 +4,7 @@ correctness rules (see docs/LINT.md for the catalogue):
 - DSL002 sync-free hot paths (no hidden device syncs in step/decode/drain
   loops or disabled-telemetry branches)
 - DSL003 jax-free operator tools (whole import-graph closure)
-- DSL004 metric-namespace literals + the bench summary-block ledger
+- DSL004 metric-namespace literals
 - DSL005 unconditional ds_comm_<op> named_scope on collective wrappers
 - DSL006 flight/trace shared-structure mutation discipline
 
@@ -12,7 +12,7 @@ This package is stdlib-only and uses RELATIVE imports exclusively:
 ``tools/dslint.py`` loads it by file path on boxes with no jax (and the
 package's own DSL003 closure check keeps it that way).  Run via::
 
-    python tools/dslint.py deepspeed_tpu tools bench.py
+    python tools/dslint.py deepspeed_tpu tools
     python tools/dslint.py --selftest
     make lint
 """

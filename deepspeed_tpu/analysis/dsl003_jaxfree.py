@@ -38,8 +38,8 @@ from .engine import FileContext, Finding, Project, Rule, register_rule
 # contract (each states it in its docstring; dslint itself is one)
 JAXFREE_TOOLS = ("router.py", "fleet_dump.py", "ckpt_verify.py",
                  "train_supervisor.py", "serve_supervisor.py",
-                 "trace_report.py", "metrics_dump.py", "perf_ledger.py",
-                 "goodput_report.py", "dslint.py")
+                 "trace_report.py", "metrics_dump.py", "goodput_report.py",
+                 "dslint.py")
 BANNED_ROOTS = {"jax", "jaxlib", "flax", "optax"}
 PACKAGE = "deepspeed_tpu"
 
@@ -132,7 +132,7 @@ def _module_to_rel(name: str, importer_rel: str, level: int,
         if os.path.isfile(os.path.join(root, cand)):
             out.append(cand)
             return out
-    # a bare module that happens to live at repo root (bench etc.)
+    # a bare module that happens to live at repo root (chip_smoke etc.)
     cand = parts[0] + ".py"
     if os.path.isfile(os.path.join(root, cand)):
         out.append(cand)

@@ -1,30 +1,12 @@
-"""DSL004 — metric-namespace literals + the bench summary-block ledger.
+"""DSL004 — metric-namespace literals.
 
-Originating incidents: PR 2 established the runtime namespace guard
+Originating incident: PR 2 established the runtime namespace guard
 (every REGISTERED metric must be ``ds_``-prefixed and documented in
 docs/OBSERVABILITY.md) — but the runtime guard only sees a name when its
 registration branch executes; a metric born behind a rarely-taken branch
 escapes until production takes that branch.  This rule extracts every
 ``Counter``/``Gauge``/``Histogram`` name LITERAL (and every f-string
 prefix) statically and applies the same two checks at parse time.
-
-Second half (PR 10's bench handshake): the runner parses — and truncates
-around ~2k chars — the LAST stdout line of bench.py, so
-``summary_lines`` caps the final line at ``BENCH_SUMMARY_MAX_CHARS`` by
-dropping optional blocks from an explicit victim list.  A NEW dict-valued
-summary block that is not in that list silently re-opens the BENCH_r05
-``"parsed": null`` bug the first time it pushes the line over budget.
-This rule cross-checks every ``summary["<key>"] = <dict-ish>`` in
-``summary_lines`` against the victim tuple of the cap loop.
-
-Third half (PR 17's perf ledger): ``tools/perf_ledger.py`` builds
-per-metric trajectories over the committed BENCH_*.json blocks and
-attributes regressions to environment drift — which only works when
-every block stamps its provenance.  When ``summary_lines`` emits blocks
-at all, it must also stamp a ``summary["run_meta"]`` block built by a
-``run_metadata()`` helper whose dict carries a ``schema_version`` key;
-a bench block without the stamp is a trajectory point that can never be
-attributed, so this rule requires it statically.
 """
 
 from __future__ import annotations
@@ -34,7 +16,7 @@ import os
 import re
 from typing import Iterable, List, Optional, Set, Tuple
 
-from .astutil import const_str, tail_name
+from .astutil import const_str
 from .engine import FileContext, Finding, Project, Rule, register_rule
 
 FAMILY_METHODS = {"counter", "gauge", "histogram"}
@@ -114,19 +96,15 @@ def _pattern_matches(name: str, patterns: Set[str], raw_text: str) -> bool:
 
 class MetricNamespaceRule(Rule):
     id = "DSL004"
-    title = "metric name literals: ds_ prefix + documented; bench summary ledger"
+    title = "metric name literals: ds_ prefix + documented"
     incident = ("PR 2's runtime namespace guard only fires when the "
-                "registration branch executes; PR 10's BENCH_r05 record "
-                "was lost to an uncapped final-line summary block")
+                "registration branch executes")
 
     def check_file(self, ctx: FileContext,
                    project: Project) -> Iterable[Finding]:
-        findings: List[Finding] = []
-        if not ctx.rel.endswith(EXEMPT_SUFFIXES):
-            findings.extend(self._check_names(ctx, project))
-        if ctx.rel.endswith("bench.py"):
-            findings.extend(self._check_bench_summary(ctx))
-        return findings
+        if ctx.rel.endswith(EXEMPT_SUFFIXES):
+            return []
+        return self._check_names(ctx, project)
 
     @staticmethod
     def _docs(project: Project):
@@ -175,99 +153,6 @@ class MetricNamespaceRule(Rule):
                     end_line=node.end_lineno or node.lineno))
         return findings
 
-    # -- bench summary-block ledger ------------------------------------
-    def _check_bench_summary(self, ctx: FileContext) -> List[Finding]:
-        fn = None
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.FunctionDef) \
-                    and node.name == "summary_lines":
-                fn = node
-                break
-        if fn is None:
-            return []
-        block_assigns: List[Tuple[str, ast.Assign]] = []
-        victims: Set[str] = set()
-        victim_node = None
-        for node in ast.walk(fn):
-            if isinstance(node, ast.Assign) and len(node.targets) == 1:
-                t = node.targets[0]
-                if isinstance(t, ast.Subscript) \
-                        and isinstance(t.value, ast.Name) \
-                        and t.value.id == "summary":
-                    key = const_str(t.slice)
-                    # a "block" is a dict-valued entry: dict literal /
-                    # comprehension / a call to a known dict builder
-                    # (_strip_bulky).  Attribute calls (``ov.get(...)``)
-                    # and scalar builtins (``len(...)``) are cap-exempt.
-                    dictish = isinstance(node.value,
-                                         (ast.Dict, ast.DictComp)) or (
-                        isinstance(node.value, ast.Call)
-                        and isinstance(node.value.func, ast.Name)
-                        and node.value.func.id in ("dict", "_strip_bulky",
-                                                   "run_metadata"))
-                    if key is not None and dictish:
-                        block_assigns.append((key, node))
-            elif isinstance(node, ast.For):
-                if isinstance(node.target, ast.Name) \
-                        and node.target.id == "victim" \
-                        and isinstance(node.iter, (ast.Tuple, ast.List)):
-                    victim_node = node
-                    for el in node.iter.elts:
-                        s = const_str(el)
-                        if s:
-                            victims.add(s)
-        findings: List[Finding] = []
-        if block_assigns and victim_node is None:
-            a = block_assigns[0][1]
-            return [Finding(
-                self.id, ctx.rel, a.lineno, a.col_offset,
-                "summary_lines writes summary blocks but has no "
-                "'for victim in (...)' cap loop — the final-line byte "
-                "budget (BENCH_SUMMARY_MAX_CHARS) is unenforced")]
-        for key, node in block_assigns:
-            if key not in victims:
-                findings.append(Finding(
-                    self.id, ctx.rel, node.lineno, node.col_offset,
-                    f"BENCH_JSON summary block {key!r} is not in the "
-                    f"final-line cap's victim list — an oversized line "
-                    f"truncates to non-JSON and the whole record is lost "
-                    f"(the BENCH_r05 'parsed: null' bug)",
-                    end_line=node.end_lineno or node.lineno))
-        if block_assigns:
-            findings.extend(self._check_run_meta_stamp(ctx, fn,
-                                                       block_assigns))
-        return findings
-
-    def _check_run_meta_stamp(self, ctx: FileContext, fn: ast.FunctionDef,
-                              block_assigns: List[Tuple[str, ast.Assign]],
-                              ) -> List[Finding]:
-        """Blocks exist → a ``run_meta`` stamp with schema_version must too."""
-        has_run_meta = any(k == "run_meta" for k, _ in block_assigns)
-        schema_ok = False
-        for node in ast.walk(ctx.tree):
-            if isinstance(node, ast.FunctionDef) \
-                    and node.name == "run_metadata":
-                for sub in ast.walk(node):
-                    if isinstance(sub, ast.Dict) and any(
-                            const_str(k) == "schema_version"
-                            for k in sub.keys if k is not None):
-                        schema_ok = True
-                    elif isinstance(sub, ast.Assign) \
-                            and len(sub.targets) == 1 \
-                            and isinstance(sub.targets[0], ast.Subscript) \
-                            and const_str(sub.targets[0].slice) \
-                            == "schema_version":
-                        schema_ok = True
-        if has_run_meta and schema_ok:
-            return []
-        return [Finding(
-            self.id, ctx.rel, fn.lineno, fn.col_offset,
-            "BENCH_JSON blocks carry no run-metadata stamp — add "
-            "summary['run_meta'] = run_metadata() with a "
-            "'schema_version' key so tools/perf_ledger.py can attribute "
-            "a regression to environment drift (git sha / jax version) "
-            "instead of the code under test")]
-
 
 register_rule(MetricNamespaceRule())
 
@@ -311,20 +196,4 @@ from deepspeed_tpu.monitor.metrics import get_registry
 reg = get_registry()
 ok = reg.counter("ds_prof_windows_total", "documented")
 lab = reg.gauge("ds_prof_scope_device_seconds", labels={"scope": "comm"})
-'''
-
-SELFTEST_BAD_BENCH = '''\
-import json
-
-
-def summary_lines(record, rung_serving):
-    summary = {"metric": record["metric"]}
-    summary["big_new_block"] = {"a": 1, "b": 2}      # <- not a victim
-    line = json.dumps(summary)
-    for victim in ("train_metrics",):
-        if len(line) <= 1800:
-            break
-        summary.pop(victim, None)
-        line = json.dumps(summary)
-    return [line]
 '''

@@ -69,15 +69,6 @@ def run_selftest(verbose: bool = False) -> List[str]:
                   f"{rule_id} stays quiet on the clean fixture "
                   f"(got {[f.render() for f in clean]})")
 
-        # DSL004 bench summary-block ledger (needs the bench.py filename)
-        sub = os.path.join(td, "dsl004_bench")
-        os.makedirs(sub, exist_ok=True)
-        hits = [f for f in _lint_source(dsl004_metrics.SELFTEST_BAD_BENCH,
-                                        sub, "bench.py")
-                if f.rule == "DSL004"]
-        check(bool(hits), "DSL004 flags a summary block outside the "
-                          "cap victim list")
-
         # DSL004 documented-name check over the ds_prof_* continuous-
         # profiler family: a fixture docs file documents two names (one
         # labeled); an undocumented ds_prof_ literal must be flagged, the

@@ -26,9 +26,8 @@ into a scheduled, low-duty-cycle attribution feed:
 - a window-over-window differ names the regressing scope when the
   step-time decomposition drifts past tolerance (flight event
   ``prof_regression`` + ``ds_prof_regressions_total{scope=}``); the
-  tolerance semantics — substring rules, first match wins — are the
-  ``tools/perf_ledger.py`` contract, and perf_ledger's
-  ``--profile-history`` mode runs this differ over a ring on disk.
+  tolerance rules are ``(substring, tol)`` pairs, first match wins
+  (:func:`tolerance_for`).
 
 Layout contract: everything above the ``live capture half`` marker is
 stdlib-only with RELATIVE imports, so jax-less operator tools load this
@@ -55,11 +54,10 @@ SCHEMA_VERSION = 1
 # per-step phase seconds sum to the per-step wall clock)
 PHASE_SCOPES = ("fwd_bwd", "optimizer", "comm", "other", "gap")
 
-# regression-tolerance semantics shared with tools/perf_ledger.py:
-# (substring, tol) rules, FIRST match wins, default otherwise.  All
-# window scopes are seconds — lower is better; a relative increase past
-# tolerance is a regression.  gap/other are the noisy remainder lanes,
-# so they get a looser default bar.
+# regression tolerances: (substring, tol) rules, FIRST match wins,
+# default otherwise.  All window scopes are seconds — lower is better;
+# a relative increase past tolerance is a regression.  gap/other are
+# the noisy remainder lanes, so they get a looser default bar.
 DEFAULT_TOLERANCE = 0.25
 SCOPE_TOLERANCES: Tuple[Tuple[str, float], ...] = (
     ("gap", 0.50),
@@ -72,8 +70,8 @@ _WINDOW_RE = re.compile(r"^ds_prof_window_(\d+)\.json$")
 def tolerance_for(name: str,
                   tolerances: Optional[List[Tuple[str, float]]] = None,
                   default: float = DEFAULT_TOLERANCE) -> float:
-    """First substring match wins (the perf_ledger ``_tolerance_for``
-    contract), falling back to the built-in scope rules, then default."""
+    """First substring match wins: the caller's rules, then the built-in
+    scope rules, then default."""
     for sub, tol in list(tolerances or []) + list(SCOPE_TOLERANCES):
         if sub in name:
             return float(tol)
@@ -219,10 +217,10 @@ def diff_windows(prev: Dict[str, Any], cur: Dict[str, Any], *,
     device-seconds (plus the synthesized ``step_time`` = per-step wall
     clock) and name every scope whose time grew past tolerance.
 
-    Same shape as ``perf_ledger.find_regressions``: relative drift
-    ``(cur - prev) / prev`` against a substring-matched tolerance; scopes
-    below the ``min_seconds`` noise floor in the BASELINE window are
-    skipped (a 2us scope tripling is measurement noise, not a finding).
+    Relative drift ``(cur - prev) / prev`` against a substring-matched
+    tolerance (:func:`tolerance_for`); scopes below the ``min_seconds``
+    noise floor in the BASELINE window are skipped (a 2us scope
+    tripling is measurement noise, not a finding).
     Returns regressions sorted worst-first."""
     def scope_map(w: Dict[str, Any]) -> Dict[str, float]:
         out = dict(w.get("scopes") or {})
@@ -396,7 +394,7 @@ class ContinuousProfiler:
             # every single boundary while the budget recovers
             self._last_t = self._clock()
             return False
-        from .trace import TraceCapture  # dslint: disable=DSL003 -- live-capture path only; the offline half (tools/trace_report.py --history, perf_ledger --profile-history) never opens a window, and on an engine box jax is already present
+        from .trace import TraceCapture  # dslint: disable=DSL003 -- live-capture path only; the offline half (tools/trace_report.py --history, tools/metrics_dump.py --profile) never opens a window, and on an engine box jax is already present
         trace_dir = os.path.join(self.ring.directory, "_capture")
         cap = TraceCapture(trace_dir, start_step=upcoming_step,
                            num_steps=self.capture_steps, perfetto=True)

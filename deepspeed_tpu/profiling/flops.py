@@ -11,10 +11,9 @@ time, published as ``ds_train_tflops`` / ``ds_train_mfu`` gauges through
 the metrics registry (and thus the ``_report`` MonitorMaster bridge and
 ``/statz``).
 
-``peak_flops()`` (bf16 peak per chip, by device kind) lives here so
-bench.py and the gauges share one table.  A device that is not in the table
-is an error, not a default: a utilisation against a guessed peak is not a
-measurement.
+``peak_flops()`` (bf16 peak per chip, by device kind) is the gauges'
+table.  A device that is not in the table is an error, not a default: a
+utilisation against a guessed peak is not a measurement.
 """
 
 from __future__ import annotations
@@ -63,8 +62,7 @@ def lm_flops_per_token(n_params: int, num_layers: int, hidden_size: int,
                        seq: int) -> float:
     """Train (fwd+bwd) FLOPs per token for a dense causal LM: ``6N`` matmul
     (2N fwd + 4N bwd) + ``6·L·D·S`` causal attention (12·L·D·S for the
-    full score/value matmuls, halved by causality) — the same accounting
-    bench.py's MFU headline uses."""
+    full score/value matmuls, halved by causality)."""
     return 6.0 * n_params + 6.0 * num_layers * hidden_size * seq
 
 

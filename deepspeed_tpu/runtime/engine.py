@@ -2434,10 +2434,10 @@ class DeepSpeedEngine:
         return total
 
     def _pp_bubble_share(self) -> Optional[float]:
-        """Analytic pipeline bubble fraction of the step's schedule (the
-        bench.py pp-rung formula): ``(pp-1)/(M+2(pp-1))`` under 1F1B,
-        ``(pp-1)/(M+pp-1)`` under GPipe; ``None`` when the mesh has no
-        pp extent (no bubble to attribute)."""
+        """Analytic pipeline bubble fraction of the step's schedule:
+        ``(pp-1)/(M+2(pp-1))`` under 1F1B, ``(pp-1)/(M+pp-1)`` under
+        GPipe; ``None`` when the mesh has no pp extent (no bubble to
+        attribute)."""
         pp = self.mesh.shape.get("pp", 1)
         if pp <= 1:
             return None

@@ -140,12 +140,19 @@ class ParamStreamer:
         self._slots = None               # staging ring (device payloads)
         self._slot_idx = 0
         if self.pinned:
-            from jax.sharding import NamedSharding
+            from jax.sharding import NamedSharding, PartitionSpec
 
             self._put_sh = jax.tree.map(
                 lambda s: NamedSharding(s.mesh, s.spec,
                                         memory_kind=self._host_kind),
                 layer_shardings)
+            # int8 codes and scales wait replicated in host memory over
+            # the layers' mesh, i.e. on the devices of the consuming
+            # program's other arguments (codes committed to device 0
+            # alone are refused beside tokens placed on the whole mesh)
+            self._codes_sh = NamedSharding(
+                jax.tree.leaves(layer_shardings)[0].mesh, PartitionSpec(),
+                memory_kind=self._host_kind)
         else:
             self._put_sh = layer_shardings
 
@@ -187,12 +194,7 @@ class ParamStreamer:
             # leaf shapes are [nb, block]/[nb, 1], unrelated to the layer
             # shardings
             if self.pinned:
-                from jax.sharding import SingleDeviceSharding
-
-                kind = self._host_kind
-                dev = jax.devices()[0]
-                sh = SingleDeviceSharding(dev, memory_kind=kind)
-                return jax.tree.map(lambda a: jax.device_put(a, sh), payload)
+                return jax.device_put(payload, self._codes_sh)
             return jax.tree.map(jax.device_put, payload)
         dev = jax.device_put(payload, self._put_sh)
         if not self.pinned and self.staging_slots:
@@ -294,11 +296,7 @@ class ParamStreamer:
         if self.meter.registry.enabled:
             self.meter.h2d_bytes.inc(_tree_nbytes(payload))
         if self.pinned:
-            from jax.sharding import SingleDeviceSharding
-
-            sh = SingleDeviceSharding(jax.devices()[0],
-                                      memory_kind=self._host_kind)
-            return jax.tree.map(lambda a: jax.device_put(a, sh), payload)
+            return jax.device_put(payload, self._codes_sh)
         return jax.tree.map(jax.device_put, payload)
 
     def materialize_aux(self, name: str, payload, dtype=None):

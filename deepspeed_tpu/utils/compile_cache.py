@@ -1,6 +1,6 @@
 """Where jax's persistent compilation cache lives.
 
-Every entry script (``chip_smoke.py``, ``bench.py``, ``__graft_entry__.py``)
+Every entry script (``chip_smoke.py``, ``__graft_entry__.py``)
 and the test harness call :func:`place_compile_cache` before their first
 compile.  The rule is the on-chip-measurement guide's: whoever runs the
 program places the cache from outside with ``JAX_COMPILATION_CACHE_DIR``,

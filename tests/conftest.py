@@ -35,8 +35,8 @@ jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
 
 
 def pytest_configure(config):
-    # tier-1 runs with -m 'not slow'; register the marker so slow-marked
-    # benches (tests/perf/test_serving_bench.py) don't warn
+    # tier-1 runs with -m 'not slow'; register the marker so the
+    # slow-marked suites under tests/perf/ don't warn
     config.addinivalue_line("markers",
                             "slow: long benchmark; excluded from tier-1")
 

@@ -1,8 +1,8 @@
 """Training chaos matrix (slow; ``make chaos``): the ISSUE 14 elastic
-resilience scenarios at larger-than-tier-1 scale — the
-``bench_elastic_resume`` rung, a randomized kill-at-byte sweep across an
-elastic save/resume cycle, and a multi-round gradient-bomb campaign with
-world changes between rounds.  The fast tier-1 chaos coverage lives in
+resilience scenarios at larger-than-tier-1 scale — a randomized
+kill-at-byte sweep across an elastic save/resume cycle, and a
+multi-round gradient-bomb campaign with world changes between rounds.
+The fast tier-1 chaos coverage lives in
 ``tests/unit/test_elastic_train.py`` / ``test_anomaly.py`` /
 ``test_resilience.py``."""
 
@@ -51,23 +51,6 @@ def _steps(engine, n, start=0):
             lo = ((i % 4) * TBS + g * per) % 56
             engine.forward((X[lo:lo + per], Y[lo:lo + per]))
         engine.step()
-
-
-def test_elastic_resume_bench_scenario(capsys):
-    from bench import bench_elastic_resume
-
-    out = bench_elastic_resume(tiny=True)
-    assert out["status"] == "ok", out
-    assert out["loss_parity"] is True
-    assert out["steps_to_recover_max"] == 0, \
-        "the first post-resume step should already track the trajectory"
-    assert out["resume_latency_s_max"] > 0
-    assert set(out["resumes"]) == {str(w) for w in out["worlds"]}
-    with capsys.disabled():
-        print(f"\nelastic resume bench (tiny/CPU): save@{out['world_save']}"
-              f" -> {out['worlds']}, resume latency max "
-              f"{out['resume_latency_s_max']}s, steps-to-recover "
-              f"{out['steps_to_recover_max']}, parity {out['loss_parity']}")
 
 
 def test_chaos_matrix_random_kill_sweep_elastic_cycle(tmp_path):

@@ -143,7 +143,7 @@ def test_diff_windows_tolerance_rules_and_noise_floor():
     assert continuous.diff_windows(prev, cur) == []
     assert [r["scope"] for r in
             continuous.diff_windows(prev, _window(gap=0.0016))] == ["gap"]
-    # shared-substring override (the perf_ledger contract: first wins)
+    # shared-substring override: the caller's rule comes first and wins
     assert continuous.tolerance_for("comm_all_gather",
                                     [("all_gather", 0.9)]) == 0.9
     assert continuous.tolerance_for("gap") == 0.50
@@ -321,6 +321,12 @@ def test_training_engine_produces_scheduled_windows(tmp_path):
         rng=jax.random.PRNGKey(0))
     try:
         assert engine._cprof is not None
+        # one CPU capture costs seconds (profiler start/stop) against
+        # millisecond steps, so after the first window the projected
+        # overhead exceeds ANY duty cap <= 1 and the second is deferred;
+        # the duty policy has its own fake-clock tests above, so lift the
+        # cap here (as the serving twin does) and test only the cadence
+        engine._cprof.max_duty_cycle = 100.0
         ring = engine._cprof.ring
         n = 0
         import time as _time

@@ -256,30 +256,6 @@ def test_goodput_report_tool_selftest():
     assert rep.selftest() == 0
 
 
-def test_bench_goodput_window_reconciles():
-    """bench.goodput_window: the snapshot-delta block telescopes and the
-    token count reconciles exactly against steps * batch * seq."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    zero = {c: 0.0 for c in core.CATEGORIES}
-    before = {"wall_s": 2.0, "tokens": 100,
-              "categories": dict(zero, compute=1.5, idle=0.5)}
-    after = {"wall_s": 5.0, "tokens": 1636,
-             "categories": dict(zero, compute=4.2, recompile=0.3,
-                                idle=0.5)}
-    blk = bench.goodput_window(before, after, loop_s=2.9,
-                               tokens_expected=1536)
-    assert blk["wall_s"] == pytest.approx(3.0)
-    assert blk["telescopes"] is True
-    assert blk["goodput_ratio"] == pytest.approx(2.7 / 3.0, abs=1e-4)
-    assert blk["tokens"] == 1536 and blk["tokens_reconcile"] is True
-    assert blk["categories"]["recompile"] == pytest.approx(0.3)
-    assert "idle" not in blk["categories"]     # zero-delta categories drop
-
-
 # ---------------------------------------------------------------------------
 # engine e2e: real seams feed the ledger
 # ---------------------------------------------------------------------------

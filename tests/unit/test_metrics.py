@@ -1,7 +1,7 @@
 """Metrics registry (monitor/metrics.py): histogram bucket/quantile
 correctness, snapshot consistency under concurrent writes, the Prometheus
 exposition golden format, the disabled-path cost contract (one branch, no
-allocation), the MonitorMaster bridge, the bench BENCH_JSON handshake, and
+allocation), the MonitorMaster bridge, the metrics_dump renderings, and
 the tier-1 NAMESPACE GUARD — every metric the suite registers must live in
 the ``ds_`` namespace and be documented in docs/OBSERVABILITY.md."""
 
@@ -268,270 +268,6 @@ def test_statz_window_two_scrapes():
             assert json.load(r)["metrics"]["ds_t_reqs_total"] == 13
     finally:
         server.stop()
-
-
-# ---------------------------------------------------------------------------
-# bench handshake (satellite: BENCH_r05 "parsed": null)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_summary_last_line_roundtrips_json():
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    record = {"metric": "m", "value": 1.5, "unit": "tok/s",
-              "vs_baseline": 0.5,
-              "detail": {"mfu": 0.4, "backend": "cpu",
-                         # the prefix-caching acceptance rung rides the
-                         # record detail and surfaces in the summary
-                         "prefix_serving_125m": {
-                             "prefill_savings_ratio": 0.64,
-                             "prefix_hit_ratio": 0.64,
-                             "outputs_token_identical": True,
-                             "prefix_goodput_speedup": 1.04,
-                             "cache_on": {"ttft_p99_s": 0.017},
-                             "cache_off": {"ttft_p99_s": 0.019}}}}
-    serving = {"goodput_speedup": 2.0,
-               "continuous": {"goodput_tok_s": 100.0, "p99_latency_s": 0.5},
-               "metrics": {"ttft_p50_s": 0.01, "ttft_p99_s": 0.05,
-                           "queue_wait_p99_s": 0.2,
-                           "mean_slot_occupancy": 0.9,
-                           "tail_attribution": {
-                               "p": 0.99, "n": 64, "tail_n": 2,
-                               "cut_s": 1.2, "dominant_phase": "queue",
-                               "phase_share": {"queue": 0.8},
-                               "exemplars": [7, 3]}}}
-    lines = bench.summary_lines(record, serving)
-    # the runner parses the LAST stdout line: it must be the bare object
-    parsed = json.loads(lines[-1])
-    assert parsed["metric"] == "m"
-    assert parsed["serving_metrics"]["queue_wait_p99_s"] == 0.2
-    # the ISSUE 7 tail-attribution sub-object rides BENCH_JSON verbatim
-    ta = parsed["serving_metrics"]["tail_attribution"]
-    assert ta["dominant_phase"] == "queue" and ta["exemplars"] == [7, 3]
-    # the prefix-caching acceptance pair rides BENCH_JSON (round-trip
-    # pinned: savings ratio + token-identity + hit ratio)
-    pf = parsed["serving_prefix"]
-    assert pf["prefill_savings_ratio"] == 0.64
-    assert pf["outputs_token_identical"] is True
-    assert pf["prefix_hit_ratio"] == 0.64
-    assert pf["ttft_p99_on_s"] == 0.017 and pf["ttft_p99_off_s"] == 0.019
-    # the human-greppable prefixed line stays, directly above it
-    assert lines[-2] == "BENCH_JSON: " + lines[-1]
-    # no serving rung (CPU smoke): still a parseable bare last line
-    bare = {"metric": "m", "value": 1.5, "unit": "tok/s",
-            "vs_baseline": 0.5, "detail": {"mfu": 0.4, "backend": "cpu"}}
-    parsed = json.loads(bench.summary_lines(bare, None)[-1])
-    assert "serving_metrics" not in parsed and "serving_prefix" not in parsed
-
-
-def test_bench_summary_new_rungs_roundtrip_and_strip_bulk():
-    """ISSUE 11 blocks ride BENCH_JSON (streamed_offload relay +
-    serving_host_tier acceptance pair), and per-capture device_profile
-    payloads are STRIPPED from the capped final line (they stay in the
-    record line)."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    record = {"metric": "m", "value": 1.5, "unit": "tok/s",
-              "vs_baseline": 0.5,
-              "detail": {
-                  "mfu": 0.4, "backend": "cpu",
-                  "metrics": {"tflops": 1.0,
-                              "device_profile": {"huge": "x" * 500}},
-                  "goodput": {
-                      "wall_s": 1.0, "loop_s": 0.9, "goodput_ratio": 0.91,
-                      "telescopes": True,
-                      "categories": {"compute": 0.91, "recompile": 0.02,
-                                     "idle": 0.07},
-                      "tokens": 1536, "tokens_expected": 1536,
-                      "tokens_reconcile": True, "tokens_per_sec": 1536.0},
-                  "overlap_1b4": {
-                      "overlap_speedup": 1.2, "loss_parity": True,
-                      "off": {"tokens_per_sec": 100.0, "mfu": 0.5,
-                              "comm_s": 0.004, "comm_s_source": "analytic",
-                              "loss": 1.0},
-                      "on": {"tokens_per_sec": 120.0, "mfu": 0.6,
-                             "comm_s": 0.003, "comm_s_source": "device",
-                             "loss": 1.0}},
-                  "streamed_offload": {
-                      "status": "ok", "streamed_speedup": 1.6,
-                      "relay_bytes_ratio": 1.9, "loss_parity": True,
-                      "gap_share": 0.31,
-                      "bf16": {"relay_MBps": 14.0,
-                               "device_profile": {"huge": "y" * 500}},
-                      "int8": {"relay_MBps": 27.0}},
-                  "host_tier_serving": {
-                      "hit_ratio_on": 0.61, "hit_ratio_off": 0.42,
-                      "outputs_token_identical": True, "demotes": 6,
-                      "promotes": 5, "goodput_speedup": 1.1},
-                  "fleet_chaos": {
-                      "goodput_retention": 0.83,
-                      "clean": {"goodput_tok_s": 120.0, "shed_429": 0},
-                      "chaos": {"goodput_tok_s": 99.6, "shed_429": 2},
-                      "ttft_p99_clean_s": 0.05, "ttft_p99_chaos_s": 0.4,
-                      "restarts_observed": 1,
-                      "answered_exactly_once": True,
-                      "outputs_token_identical": True},
-                  "disagg_serving": {
-                      "handoff_compression": 1.94,
-                      "handoff_wire_bytes": 54272,
-                      "handoff_dense_bytes": 105472,
-                      "disagg_goodput_ratio": 1.07,
-                      "ttft_stream_over_total": 0.31,
-                      "outputs_token_identical": True,
-                      "mono": {"plain": {"goodput_tok_s": 90.0},
-                               "stream": {"goodput_tok_s": 91.0}},
-                      "disagg": {
-                          "plain": {"goodput_tok_s": 95.0,
-                                    "ttft_p50_s": 0.021,
-                                    "device_profile": {"huge": "z" * 500}},
-                          "stream": {"goodput_tok_s": 96.0,
-                                     "ttft_p50_s": 0.012,
-                                     "client_p50_s": 0.04}}},
-                  "elastic_resume": {
-                      "status": "ok", "world_save": 4, "worlds": [2, 8],
-                      "resume_latency_s_max": 0.68,
-                      "steps_to_recover_max": 0, "loss_parity": True,
-                      "resumes": {"2": {"resume_latency_s": 0.68}}},
-                  "quant_comm": {
-                      "status": "ok",
-                      "compression": {"q_all_reduce": 3.44,
-                                      "q_all_gather": 3.94,
-                                      "q_reduce_scatter": 3.94},
-                      "loss_parity": {"all_reduce": True,
-                                      "gather_rs": True},
-                      "families": {
-                          "all_reduce": {"speedup": 0.82,
-                                         "dense": {"loss": 6.13},
-                                         "int8": {"loss": 6.13}},
-                          "gather_rs": {"speedup": 0.9,
-                                        "dense": {"loss": 6.13},
-                                        "int8": {"loss": 6.13}}}},
-                  "pipe": {
-                      "status": "ok",
-                      "compression": {"pp2": 3.94, "pp4": 3.94},
-                      "loss_parity": {"pp2": True, "pp4": True},
-                      "bubble_share": {"pp2": 0.1667, "pp4": 0.3},
-                      "rungs": {
-                          "pp2": {"speedup": 1.0,
-                                  "dense": {"loss": 6.14,
-                                            "boundary_bytes": 6291456},
-                                  "int8": {"loss": 6.14,
-                                           "boundary_bytes": 1597440}},
-                          "pp4": {"speedup": 1.15,
-                                  "dense": {"loss": 6.12},
-                                  "int8": {"loss": 6.12}}}}}}
-    lines = bench.summary_lines(record, None)
-    parsed = json.loads(lines[-1])
-    # the ISSUE 18 goodput row rides BENCH_JSON: ratio + categories +
-    # the telescoping / exact-token-reconciliation bits
-    gpb = parsed["goodput"]
-    assert gpb["goodput_ratio"] == 0.91 and gpb["telescopes"] is True
-    assert gpb["tokens_reconcile"] is True
-    assert gpb["tokens_per_sec"] == 1536.0
-    assert gpb["categories"]["compute"] == 0.91
-    # the overlap ablation's comm_s carries its source label (bench
-    # honesty: analytic comm-plan pricing on CPU, device truth otherwise)
-    ova = parsed["overlap_ablation"]
-    assert ova["off"]["comm_s"] == 0.004
-    assert ova["off"]["comm_s_source"] == "analytic"
-    assert ova["on"]["comm_s_source"] == "device"
-    st = parsed["streamed_offload"]
-    assert st["streamed_speedup"] == 1.6
-    assert st["relay_bytes_ratio"] == 1.9 and st["loss_parity"] is True
-    assert st["gap_share"] == 0.31
-    assert st["relay_MBps"] == {"bf16": 14.0, "int8": 27.0}
-    ht = parsed["serving_host_tier"]
-    assert ht["hit_ratio_on"] == 0.61 and ht["hit_ratio_off"] == 0.42
-    assert ht["outputs_token_identical"] is True
-    assert ht["demotes"] == 6 and ht["promotes"] == 5
-    # the ISSUE 13 fleet-chaos acceptance row rides BENCH_JSON
-    fc = parsed["fleet_chaos"]
-    assert fc["goodput_retention"] == 0.83
-    assert fc["goodput_clean_tok_s"] == 120.0
-    assert fc["goodput_chaos_tok_s"] == 99.6
-    assert fc["restarts_observed"] == 1 and fc["shed_429"] == 2
-    assert fc["answered_exactly_once"] is True
-    assert fc["outputs_token_identical"] is True
-    # the ISSUE 19 disaggregated-serving acceptance row rides BENCH_JSON:
-    # role-split goodput ratio, user-visible streaming TTFT, int8 KV
-    # handoff compression vs the dense twin, grid-wide token identity
-    dg = parsed["disagg_serving"]
-    assert dg["disagg_goodput_ratio"] == 1.07
-    assert dg["ttft_stream_p50_s"] == 0.012
-    assert dg["ttft_stream_over_total"] == 0.31
-    assert dg["handoff_compression"] == 1.94
-    assert dg["outputs_token_identical"] is True
-    # the ISSUE 14 elastic-resume acceptance row rides BENCH_JSON
-    er = parsed["elastic_resume"]
-    assert er["resume_latency_s"] == 0.68
-    assert er["steps_to_recover"] == 0 and er["loss_parity"] is True
-    assert er["world_save"] == 4 and er["worlds"] == [2, 8]
-    # the ISSUE 15 quantized-collective ablation row rides BENCH_JSON
-    qc = parsed["quant_comm"]
-    assert qc["compression"]["q_all_reduce"] == 3.44
-    assert qc["compression"]["q_all_gather"] == 3.94
-    assert qc["loss_parity"] == {"all_reduce": True, "gather_rs": True}
-    assert qc["speedup"] == {"all_reduce": 0.82, "gather_rs": 0.9}
-    # the ISSUE 16 pipeline boundary ablation row rides BENCH_JSON
-    pi = parsed["pipe"]
-    assert pi["compression"] == {"pp2": 3.94, "pp4": 3.94}
-    assert pi["loss_parity"] == {"pp2": True, "pp4": True}
-    assert pi["bubble_share"] == {"pp2": 0.1667, "pp4": 0.3}
-    assert pi["speedup"] == {"pp2": 1.0, "pp4": 1.15}
-    # bulky capture payloads never reach the final line
-    assert "device_profile" not in json.dumps(parsed)
-    assert lines[-2] == "BENCH_JSON: " + lines[-1]
-
-
-def test_bench_summary_line_capped():
-    """An oversized summary drops optional blocks (recorded under
-    ``truncated``) instead of emitting a line the runner would truncate
-    into non-JSON — the BENCH_r05 ``"parsed": null`` regression class."""
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    record = {"metric": "m", "value": 1.5, "unit": "tok/s",
-              "vs_baseline": 0.5,
-              "detail": {"mfu": 0.4, "backend": "cpu",
-                         "metrics": {"filler": "x" * 4000}}}
-    line = bench.summary_lines(record, None)[-1]
-    assert len(line) <= bench.BENCH_SUMMARY_MAX_CHARS
-    parsed = json.loads(line)
-    assert parsed["truncated"] == ["train_metrics"]
-    assert parsed["metric"] == "m"       # headline survives the cap
-
-
-def test_bench_emit_contract_subprocess():
-    """THE handshake pin: run bench.py in emit-only mode as a REAL
-    subprocess and assert the literal last stdout line is the parseable
-    bare summary (flushed, nothing after it), with the prefixed twin
-    directly above."""
-    import subprocess
-
-    root = os.path.abspath(os.path.join(os.path.dirname(__file__),
-                                        "..", ".."))
-    env = dict(os.environ, DSTPU_BENCH_EMIT_ONLY="1", JAX_PLATFORMS="cpu",
-               DS_ACCELERATOR="cpu")
-    proc = subprocess.run(
-        [sys.executable, os.path.join(root, "bench.py")], env=env,
-        capture_output=True, text=True, timeout=300, cwd=root)
-    assert proc.returncode == 0, proc.stderr[-800:]
-    assert proc.stdout.endswith("\n")
-    lines = proc.stdout.rstrip("\n").split("\n")
-    last = lines[-1]
-    parsed = json.loads(last)            # the runner's exact read
-    assert parsed["metric"] == "emit_selftest"
-    assert len(last) <= 1800
-    assert lines[-2] == "BENCH_JSON: " + last
-    json.loads(lines[-3])                # the full record line parses too
 
 
 def test_metrics_dump_serving_prefix_hit_ratio_line():

@@ -106,25 +106,42 @@ def _streamer(**kw):
     return s, np_layers
 
 
-def test_staging_ring_recycles_buffers():
+def test_staging_ring_recycles_buffers(monkeypatch):
     """The persistent staging ring: consumed payloads cycle over exactly
     ``staging_slots`` device buffers (jit-only consumption — a numpy view
-    would pin the buffer externally and legitimately break reuse)."""
+    would pin the buffer externally and legitimately break reuse).
+
+    The ring is the one-memory-space route; jax 0.9.0's CPU client
+    advertises ``pinned_host``, which routes around it, so the test turns
+    that off.  Asserted on the ring's own accounting, not on what the
+    allocator does with a freed pointer: every payload IS the ring's
+    slot, and the slot's previous occupant was consumed by the donation
+    (nothing but the ring holds a layer-sized buffer)."""
+    from deepspeed_tpu.accelerator import real_accelerator
+
+    monkeypatch.setattr(real_accelerator, "supports_pinned_host",
+                        lambda: False)
     s, np_layers = _streamer(staging_slots=2)
+    assert not s.pinned
     read = jax.jit(lambda t: t["w"].sum() + t["b"].sum())
-    ptrs, sums = [], []
+    sums = []
     for i in range(6):
+        # the first put builds the ring; from then on the slot about to
+        # be written holds the payload of layer i - 2
+        old = s._slots[s._slot_idx] if s._slots else None
         s.prefetch(i)
         lp = s.take(i)
         sums.append(float(read(lp)))
-        ptrs.append(lp["w"].unsafe_buffer_pointer())
-        del lp
+        assert len(s._slots) == 2
+        assert all(a is b for a, b in zip(jax.tree.leaves(lp),
+                                          jax.tree.leaves(s._slots[i % 2])))
+        if old is not None:
+            assert all(a.is_deleted() for a in jax.tree.leaves(old)), \
+                f"slot {i % 2} was not donated at layer {i}"
+        del lp, old
     want = [float(np_layers["w"][i].sum() + np_layers["b"][i].sum())
             for i in range(6)]
     assert sums == pytest.approx(want)
-    assert len(set(ptrs)) == 2, f"staging not recycled: {ptrs}"
-    # ring order: slot i and slot i+2 share a buffer
-    assert ptrs[0::2] == [ptrs[0]] * 3 and ptrs[1::2] == [ptrs[1]] * 3
 
 
 def test_streamer_prefetch_hit_miss_accounting():

@@ -429,8 +429,8 @@ def test_pp_quantized_boundary_parity_and_ledger(devices, rng):
                              quantize_boundary=quant)[0]
 
     # per-element error is amplified by the downstream tanh(c @ w) layers
-    # (~0.3*sqrt(D) per matmul), so the parity contract is LOSS parity —
-    # what the bench rung pins — not elementwise activation identity
+    # (~0.3*sqrt(D) per matmul), so the parity contract is LOSS parity,
+    # not elementwise activation identity
     y_d = jax.jit(lambda w, x: run(w, x, False))(x=x, w=w)
     y_q = jax.jit(lambda w, x: run(w, x, True))(x=x, w=w)
     diff = np.asarray(y_q) - np.asarray(y_d)

@@ -179,9 +179,9 @@ def _shared_prefix_prompts(rng, prefix_len=48, tails=(4, 7, 2)):
 def test_shared_prefix_parity_and_prefill_savings(setup, rng):
     """The tentpole acceptance shape at tier-1 size: a shared-prefix wave
     through a WARM cache must stay token-identical to generate() while
-    computing under 60% of the prefill tokens a cold engine pays (the
-    bench trace pins the >= 40% savings at scale; here every follow-up
-    request shares a 3-page prefix, so savings are deterministic)."""
+    computing under 60% of the prefill tokens a cold engine pays (every
+    follow-up request shares a 3-page prefix, so savings are
+    deterministic)."""
     from deepspeed_tpu.monitor.metrics import get_registry
 
     model, params, ref = setup

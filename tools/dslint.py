@@ -4,8 +4,7 @@ correctness rules (donation safety, sync-free hot paths, jax-free tools,
 telemetry contracts).  See docs/LINT.md for the rule catalogue and the
 suppression syntax.
 
-    python tools/dslint.py                          # lint the default set
-    python tools/dslint.py deepspeed_tpu tools bench.py
+    python tools/dslint.py                          # deepspeed_tpu tools
     python tools/dslint.py --json                   # machine-readable
     python tools/dslint.py --rules DSL003,DSL004    # subset
     python tools/dslint.py --list-rules
@@ -30,7 +29,7 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # literal so the DSL003 resolver can follow this loader statically
 _ANALYSIS_INIT = os.path.join("deepspeed_tpu", "analysis", "__init__.py")
 
-DEFAULT_PATHS = ("deepspeed_tpu", "tools", "bench.py")
+DEFAULT_PATHS = ("deepspeed_tpu", "tools")
 
 
 def _load_analysis():
