@@ -27,6 +27,7 @@ the model returns the scalar LM loss (fp32 accumulation), else logits.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional
 
 import jax
@@ -36,9 +37,9 @@ from jax.sharding import Mesh, PartitionSpec as P
 from deepspeed_tpu.comm.mesh import axis_size, get_global_mesh
 from deepspeed_tpu.models.config import ModelConfig, get_model_config
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
-                                         attention_core, constrain, norm,
-                                         qk_norm, _repeat_kv, rope_cache,
-                                         rope_dim)
+                                         attention_core, constrain,
+                                         _mesh_spec, norm, qk_norm,
+                                         _repeat_kv, rope_cache, rope_dim)
 from deepspeed_tpu.ops.pallas import apply_rotary_pos_emb
 
 
@@ -46,6 +47,15 @@ def _uniform(rng, shape, scale, dtype):
     return jax.random.uniform(rng, shape, dtype, -scale, scale)
 
 
+# the mesh axes a batch is split over (the sequence goes over "sp")
+_BATCH_AX = ("dp", "fsdp", "ep")
+
+
+def _axes(entry) -> tuple:
+    """One PartitionSpec entry as a tuple of axis names."""
+    if entry is None:
+        return ()
+    return (entry,) if isinstance(entry, str) else tuple(entry)
 
 
 class CausalLM:
@@ -301,7 +311,7 @@ class CausalLM:
     def apply(self, params, tokens, labels=None, rngs=None, loss_mask=None):
         cfg = self.config
         mesh = self.mesh
-        batch_ax = ("dp", "fsdp", "ep")
+        batch_ax = _BATCH_AX
         if cfg.param_offload:
             # ZeRO-Infinity param tiering: non-layer params come over once
             # here; scanned layer weights stream per-layer inside the scan
@@ -625,29 +635,40 @@ class CausalLM:
         is load-bearing for the offload tests).  ``head`` is [D, V].
 
         Next-token objective (HF CausalLM convention: shift inside when
-        labels == input_ids): logits[t] predicts labels[t+1]."""
+        labels == input_ids): logits[t] predicts labels[t+1].  Every one of
+        the S positions stays (the last takes the ignore label), so each
+        shard of the data axes keeps its own B_local x S rows.
+
+        The head is a gathered weight like any layer's: whatever dimension
+        ZeRO-3 sharded it on over the data axes, it is replicated over them
+        here (after the cast, so the gather moves the compute dtype) and the
+        tokens stay where they are.  Only ``tp`` keeps its split of the
+        vocabulary."""
         cfg = self.config
         mesh = self.mesh
-        batch_ax = ("dp", "fsdp", "ep")
         h = norm(x, fnorm, cfg.norm, cfg.norm_eps, mesh)
-        head = head.astype(h.dtype)
-        shifted_labels = labels[:, 1:]
-        shifted_mask = loss_mask[:, 1:] if loss_mask is not None else None
+        head = constrain(head.astype(h.dtype), mesh, None, "tp")
+        if loss_mask is not None:
+            labels = jnp.where(loss_mask > 0, labels, -100)
+        # the shift crosses the shards of "sp": it is done on whole rows of
+        # ints, which is how a batch arrives (split over the data axes only)
+        labels = constrain(labels, mesh, _BATCH_AX, None)
+        targets = constrain(jnp.concatenate(
+            [labels[:, 1:], jnp.full_like(labels[:, :1], -100)], axis=1),
+            mesh, _BATCH_AX, None)
         B, S, _ = h.shape
         chunk = cfg.ce_chunk
         if chunk is None:  # auto: chunk when the fp32 logits would be >2^28 elts
             chunk = 2048 if B * S * cfg.vocab_size > (1 << 28) else 0
         if chunk:
-            return blockwise_cross_entropy(h[:, :-1], head, shifted_labels,
-                                           chunk=chunk, z_loss=cfg.z_loss,
-                                           mask=shifted_mask,
-                                           head_bias=head_bias)
-        logits = h[:, :-1] @ head
+            return blockwise_cross_entropy(h, head, targets, chunk=chunk,
+                                           z_loss=cfg.z_loss,
+                                           head_bias=head_bias, mesh=mesh)
+        logits = h @ head
         if head_bias is not None:
             logits = logits + head_bias.astype(logits.dtype)
-        logits = constrain(logits, mesh, batch_ax, "sp", "tp")
-        return cross_entropy(logits, shifted_labels, z_loss=cfg.z_loss,
-                             mask=shifted_mask)
+        logits = constrain(logits, mesh, _BATCH_AX, "sp", "tp")
+        return cross_entropy(logits, targets, z_loss=cfg.z_loss)
 
     # flax-style call-through so `model.apply(params, batch...)` also accepts
     # dict batches via engine's kwargs path
@@ -672,7 +693,7 @@ class CausalLM:
         mesh = self.mesh
         if mesh is not None and not mesh.empty and axis_size(mesh, "pp") > 1:
             return None
-        batch_ax = ("dp", "fsdp", "ep")
+        batch_ax = _BATCH_AX
 
         def embed_fwd(embed, tokens):
             toks = constrain(tokens, mesh, batch_ax, "sp")
@@ -735,7 +756,7 @@ def cross_entropy(logits, labels, z_loss: float = 0.0, mask=None):
 
 def blockwise_cross_entropy(x, head, labels, chunk: int, z_loss: float = 0.0,
                             mask=None, return_sums: bool = False,
-                            head_bias=None):
+                            head_bias=None, mesh: Optional[Mesh] = None):
     """LM loss without materializing the full [B, S, V] logits.
 
     The reference's fused-softmax CUDA kernels attack the same bandwidth
@@ -745,46 +766,71 @@ def blockwise_cross_entropy(x, head, labels, chunk: int, z_loss: float = 0.0,
     with ``jax.checkpoint`` so the backward pass recomputes the block instead
     of saving it.  Peak logits memory drops from O(B·S·V) to O(chunk·V) while
     the matmuls stay MXU-sized.
-    """
+
+    Under ``mesh`` every shard of the activation layout (batch over the data
+    axes, sequence over ``sp``) chunks its OWN rows: a scan step takes
+    ``chunk`` rows from each shard (``[shards, chunk, ...]``), so ``chunk`` is
+    the rows of logits one chip holds at a time, the loop dimension is
+    unsharded and no block of rows or logits ever crosses chips.  ``head``
+    arrives as the caller placed it (``CausalLM._loss_tail`` gathers it over
+    the data axes)."""
     B, S, D = x.shape
-    N = B * S
-    xf = x.reshape(N, D)
-    lf = labels.reshape(N)
-    mf = None if mask is None else mask.reshape(N)
-    pad = (-N) % chunk
-    if pad:
-        xf = jnp.concatenate([xf, jnp.zeros((pad, D), xf.dtype)])
-        lf = jnp.concatenate([lf, jnp.full((pad,), -100, lf.dtype)])
-        if mf is not None:
-            mf = jnp.concatenate([mf, jnp.zeros((pad,), mf.dtype)])
-    n_blocks = xf.shape[0] // chunk
-    xs = xf.reshape(n_blocks, chunk, D)
-    ls = lf.reshape(n_blocks, chunk)
-    ms = None if mf is None else mf.reshape(n_blocks, chunk)
+    if mask is not None:
+        labels = jnp.where(mask > 0, labels, -100)
+    spec = _mesh_spec(x, mesh, _BATCH_AX, "sp", None)
+    b_ax, s_ax = (_axes(e) for e in (spec or (None, None))[:2])
+    nb, ns = (math.prod(axis_size(mesh, a) for a in ax) for ax in (b_ax, s_ax))
+    rows, shards = b_ax + s_ax, nb * ns
+    n_local = (B // nb) * (S // ns)
+    n_blocks = -(-n_local // chunk)
+
+    def lay_out(a, fill):
+        """[B, S, ...] -> [n_blocks, shards, chunk, ...]: each shard's rows
+        flattened and padded apart from the others', the loop dimension
+        first, the shard dimension split over ``rows`` as ``a`` was."""
+        tail = a.shape[2:]
+        none = (None,) * len(tail)
+        a = a.reshape(nb, B // nb, ns, S // ns, *tail)
+        a = constrain(a, mesh, b_ax, None, s_ax, None, *none)
+        a = jnp.moveaxis(a, 2, 1).reshape(shards, n_local, *tail)
+        a = jnp.pad(a, ((0, 0), (0, n_blocks * chunk - n_local))
+                    + ((0, 0),) * len(tail), constant_values=fill)
+        a = jnp.moveaxis(a.reshape(shards, n_blocks, chunk, *tail), 1, 0)
+        return constrain(a, mesh, None, rows, None, *none)
+
+    # One copy of the head a shard, as the batch dimension of the block's
+    # matmul: its gradient then accumulates where it is computed, and the
+    # chips sum it once after the loop (the transpose of this broadcast), not
+    # in every step.
+    head = constrain(jnp.broadcast_to(head, (shards, *head.shape)), mesh,
+                     rows, None, "tp")
 
     @jax.checkpoint
     def block(carry, args):
-        xc, lc = args[0], args[1]
-        mc = args[2] if len(args) > 2 else None
-        logits = (xc @ head).astype(jnp.float32)
+        xc, lc = args                                  # [shards, chunk, ...]
+        # the logits as ONE [rows, V] block (a chip's [chunk, V]): with the
+        # shard dimension kept, XLA leaves the float32 cast and the row max
+        # out of the matmul's fusion (+6 ms a step in gpt2-xl.train-zero3)
+        logits = jnp.einsum("rcd,rdv->rcv", xc, head).astype(jnp.float32)
+        logits = logits.reshape(shards * chunk, -1)
         if head_bias is not None:
             logits = logits + head_bias.astype(jnp.float32)
+        logits = constrain(logits, mesh, rows, "tp")
         lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, jnp.maximum(lc, 0)[:, None],
-                                   axis=-1).squeeze(-1)
+        gold = jnp.take_along_axis(
+            logits, jnp.maximum(lc, 0).reshape(-1, 1), axis=-1).squeeze(-1)
         nll = lse - gold
         if z_loss:
             nll = nll + z_loss * lse ** 2
-        valid = lc >= 0
-        if mc is not None:
-            valid = valid & (mc > 0)
-        tot, cnt = carry
-        return (tot + jnp.where(valid, nll, 0.0).sum(),
-                cnt + valid.sum()), None
+        nll = jnp.where(lc >= 0, nll.reshape(shards, chunk), 0.0)
+        tot, cnt = carry  # one partial sum a shard: the chips meet once, below
+        return (tot + nll.sum(1), cnt + (lc >= 0).sum(1)), None
 
-    xs_args = (xs, ls) if ms is None else (xs, ls, ms)
-    (tot, cnt), _ = jax.lax.scan(block, (jnp.zeros((), jnp.float32),
-                                         jnp.zeros((), jnp.int32)), xs_args)
+    init = (constrain(jnp.zeros((shards,), jnp.float32), mesh, rows),
+            constrain(jnp.zeros((shards,), jnp.int32), mesh, rows))
+    (tot, cnt), _ = jax.lax.scan(block, init,
+                                 (lay_out(x, 0), lay_out(labels, -100)))
+    tot, cnt = tot.sum(), cnt.sum()
     if return_sums:
         return tot, cnt
     return tot / jnp.maximum(cnt, 1)

@@ -269,9 +269,11 @@ def _quantile_from_counts(bounds: Tuple[float, ...], counts: List[int],
 def _fmt(v: float) -> str:
     """Prometheus float formatting: integral values render bare; others at
     9 significant digits (stable across scrapes, and distinct for every
-    log bucket bound — adjacent bounds differ by ~78%)."""
+    log bucket bound — adjacent bounds differ by ~78%).  A gauge may hold
+    ``inf`` or ``nan`` (a gradient norm on an overflow step): they render
+    as ``inf`` / ``nan``, which the text format parses."""
     f = float(v)
-    if f == int(f) and abs(f) < 1e15:
+    if math.isfinite(f) and f == int(f) and abs(f) < 1e15:
         return str(int(f))
     return format(f, ".9g")
 

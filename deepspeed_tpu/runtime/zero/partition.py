@@ -18,6 +18,11 @@ dimension divisible by the axis size.  Parameters smaller than
 ``persistence_threshold`` stay replicated — the same role as the reference's
 ``stage3_param_persistence_threshold`` (keep small params resident) with the
 same config key.
+
+A parameter's ``fsdp`` sharding never becomes the sharding of an activation:
+every consumer gathers the weight and keeps the batch where it is, the loss
+tail included (``CausalLM._loss_tail`` gathers its head, tied table or
+``lm_head``, like any layer, whichever dimension was chosen here).
 """
 
 from __future__ import annotations

@@ -192,7 +192,8 @@ def test_slo_watcher_serving_rules():
     series are skipped, not burned."""
     reg = get_registry()
     reg.enable()
-    try:
+    reg.reset()     # the registry is the process's: an earlier file's serving
+    try:            # series on this worker must not read as this test's
         w = SloWatcher({"ttft_p99_s": 0.1, "shed_ratio": 0.25,
                         "unknown_rule": 1.0})
         assert set(w.rules) == {"ttft_p99_s", "shed_ratio"}

@@ -168,6 +168,17 @@ def test_prometheus_exposition_golden():
     assert reg.prometheus_text() == GOLDEN
 
 
+@pytest.mark.parametrize("value, text", [(float("inf"), "inf"),
+                                         (float("-inf"), "-inf"),
+                                         (float("nan"), "nan")])
+def test_prometheus_exposition_renders_non_finite_gauges(value, text):
+    """A gauge left at ``inf`` (a gradient norm on an overflow step) must
+    not take the whole scrape down with an OverflowError."""
+    reg = MetricsRegistry().enable()
+    reg.gauge("ds_t_norm").set(value)
+    assert f"ds_t_norm {text}\n" in reg.prometheus_text()
+
+
 def test_statz_json_roundtrip():
     reg = MetricsRegistry().enable()
     reg.counter("ds_t_reqs_total").inc(2)

@@ -331,3 +331,57 @@ def test_last_chunk_program_aliases_cache_and_carries_at_serve_chat(v5e):
     assert donated == 5
     # results: the token, then the pools and the carries in argument order
     assert aliased == {first + i: 1 + i for i in range(donated)}
+
+
+def test_train_zero3_loss_tail_keeps_logits_on_their_chip(v5e):
+    """ISSUE 30: value and grad of the ``gpt2-xl.train-zero3`` cell's loss
+    tail (fsdp=4 over the 2x2 host, 16 x 1,024 tokens a chip, the tied
+    table hidden-sharded as ``choose_pspec`` leaves it, chunks of 2,048)
+    compiled for the described host: the only collectives with a
+    vocabulary-sized operand are the head's gather and its gradient's sum,
+    both outside the chunk loop and in bf16; no ``[2048, 50257]`` block
+    and no block of rows or labels crosses chips."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.models import causal_lm
+    from deepspeed_tpu.runtime.zero.partition import choose_pspec
+    from tests.unit.hlo_text import collectives
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    mesh = build_mesh(fsdp=4, devices=topo.devices)
+    w = WIDTHS["gpt2-xl"]
+    V, D, B, S = w["V"], w["D"], 64, SEQ
+    model = causal_lm("gpt2-xl", mesh=mesh, num_layers=1)
+    assert model.config.ce_chunk is None and B * S * V > 1 << 28  # chunked
+    sh = lambda *s: NamedSharding(mesh, P(*s))
+    table = choose_pspec((V, D), mesh)
+    assert table == P(None, "fsdp")
+    ln = {"scale": sh(), "bias": sh()}
+    args = (jax.ShapeDtypeStruct((V, D), F32, sharding=sh(*table)),
+            {k: jax.ShapeDtypeStruct((D,), F32, sharding=s)
+             for k, s in ln.items()},
+            jax.ShapeDtypeStruct((B, S, D), BF16, sharding=sh("fsdp")),
+            jax.ShapeDtypeStruct((B, S), I32, sharding=sh("fsdp")))
+    text = jax.jit(
+        jax.value_and_grad(
+            lambda tok, fnorm, x, labels: model._loss_tail(
+                fnorm, tok.T, x, labels, None), argnums=(0, 1, 2)),
+        out_shardings=(sh(), (sh(*table), ln, sh("fsdp")))).lower(
+            *args).compile().as_text()
+    # the block's matmul, the float32 cast and the row max stay ONE fusion
+    # (6 ms a step on the chip against a cast in a fusion of its own)
+    assert re.search(r"= \([^=]*f32\[2048,50257\][^=]*\) fusion\(.*"
+                     r"kind=kOutput", text), "float32 logits left the matmul"
+    # all that may be left beside the head: scalars and [D] sums
+    big = [(kind, dtype, dims, entry)
+           for kind, results, entry in collectives(text)
+           for dtype, dims in results if max(dims, default=0) > D]
+    assert sorted(big) == [("all-gather", "bf16", (V, D), True),
+                           ("all-reduce", "bf16", (D, V), True)] or \
+        sorted(big) == [("all-gather", "bf16", (V, D), True),
+                        ("reduce-scatter", "bf16", (D // 4, V), True)], big
