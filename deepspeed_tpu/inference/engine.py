@@ -387,6 +387,11 @@ class InferenceEngine:
         """
         if self._params is None:
             raise RuntimeError("no weights: pass params=, config.checkpoint, or set_params()")
+        if getattr(getattr(self.module, "config", None), "is_eva", False):
+            raise NotImplementedError(
+                "attention='eva' is served through deepspeed_tpu.init_serving "
+                "(the paged pool holds its window and summary rows); "
+                "generate()'s contiguous cache has no layout for them")
         with self._gen_lock:
             if self._generating:
                 raise RuntimeError(

@@ -12,6 +12,14 @@ families instead; `ModelConfig` spans them with feature flags:
 - OLMoE         : the same with QK-norm, 64 experts top-8, dropless and an
   unnormalised router (``qk_norm``, ``moe_drop_tokens=False``,
   ``moe_norm_topk_prob=False``; benchmarks/configs/olmoe-1b-7b-L8.json)
+- EvaByte       : Llama backbone over bytes with EVA attention (an exact
+  window joined in one softmax with learned per-chunk summaries of every
+  earlier window), RMSNorm scaled by ``1 + weight``, a float32 residual
+  stream and ``num_pred_heads`` output heads (``attention="eva"``,
+  ``eva_window``, ``eva_chunk``, ``norm_add_unit_offset``,
+  ``fp32_residual``; benchmarks/configs/evabyte-L6.json).  SERVED ONLY, on
+  seeded weights: no checkpoint import, no training loss over the eight
+  heads, no multi-byte self-speculative decoding, no image tokenizer
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -48,6 +56,23 @@ class ModelConfig:
     # RMSNorm over the WHOLE q and k projections (all heads at once), before
     # the head split and RoPE (olmoe)
     qk_norm: bool = False
+    # "full": causal softmax over every earlier token.  "eva" (evabyte): a
+    # query attends exactly to the tokens of its own ``eva_window`` and, in
+    # the same softmax, to one learned summary per ``eva_chunk`` tokens of
+    # every earlier window (parameters ``attn.eva_mu`` / ``attn.eva_phi``
+    # [L, H, Dh]; models/eva.py)
+    attention: str = "full"
+    eva_window: int = 2048
+    eva_chunk: int = 16
+    # output heads: head p's ``vocab_size`` columns of ``lm_head``
+    # [D, num_pred_heads * vocab_size] predict token i + 1 + p (evabyte);
+    # serving samples from head 0
+    num_pred_heads: int = 1
+    # RMSNorm multiplies by (1 + scale): the stored gain starts at 0
+    norm_add_unit_offset: bool = False
+    # the residual stream (and the logits) stay float32 whatever dtype the
+    # weights and matmul inputs are served in
+    fp32_residual: bool = False
     # gpt-neox/pythia: x + attn(ln1(x)) + mlp(ln2(x)) — the MLP reads the
     # LAYER INPUT, not the post-attention stream
     parallel_residual: bool = False
@@ -121,6 +146,19 @@ class ModelConfig:
         if self.position not in ("rope", "learned", "alibi"):
             raise ValueError(f"position must be 'rope', 'learned' or "
                              f"'alibi', got {self.position!r}")
+        if self.attention not in ("full", "eva"):
+            raise ValueError(f"attention must be 'full' or 'eva', got "
+                             f"{self.attention!r}")
+        if self.is_eva:
+            if self.eva_chunk < 1 or self.eva_window % self.eva_chunk:
+                raise ValueError(
+                    f"eva_window {self.eva_window} must be a multiple of "
+                    f"eva_chunk {self.eva_chunk}")
+            if self.num_kv_heads != self.num_heads or self.position != "rope":
+                raise ValueError("attention='eva' is built for RoPE and one "
+                                 "KV head a query head (evabyte)")
+        if self.num_pred_heads < 1:
+            raise ValueError("num_pred_heads must be >= 1")
 
     @property
     def has_mlp_bias(self) -> bool:
@@ -129,6 +167,10 @@ class ModelConfig:
     @property
     def is_moe(self) -> bool:
         return self.num_experts > 0
+
+    @property
+    def is_eva(self) -> bool:
+        return self.attention == "eva"
 
 
 _PRESETS = {

@@ -23,9 +23,10 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import eva
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
-                                         constrain, norm, qk_norm, _repeat_kv,
-                                         rope_dim)
+                                         constrain, model_norm, norm, qk_norm,
+                                         _repeat_kv, rope_dim)
 from deepspeed_tpu.ops.pallas import rope_angles
 
 NEG_INF = -1e30
@@ -255,6 +256,16 @@ def _scatter_paged_rows(buf, rows, pos, page_table):
     return buf.at[pp, :, po, :].set(rows[:, :, 0, :].astype(buf.dtype))
 
 
+def _scatter_view(buf, view, page_table):
+    """Inverse of :func:`paged_logical_view`: write each row's logical view
+    [B, Hkv, maxp*page, D] back through its pages.  Unallocated entries all
+    name the junk page, which takes whichever write lands last."""
+    B, Hkv, _, D = view.shape
+    mp, pg = page_table.shape[1], buf.shape[2]
+    return buf.at[page_table].set(
+        view.reshape(B, Hkv, mp, pg, D).transpose(0, 2, 1, 3, 4))
+
+
 def _scatter_rows(buf, rows, start_pos):
     """Write ``rows`` [B, Hx, s, D] into ``buf`` [B, Hx, Smax, D] at
     per-row start positions ``start_pos`` [B] (each batch row lands at its
@@ -285,9 +296,18 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     positions); serving prefill gathers the slot's pages around this
     function instead.
 
-    Returns (logits [B, s, V], new_cache).  Used for prefill (s = prompt
-    length, start_pos=0), decode (s = 1), and chunked per-slot prefill
-    (s = chunk, scalar start_pos = chunk offset).
+    Under ``attention="eva"`` the cache (or the paged pool's logical view)
+    has the layout of ``models/eva.py``: ``eva_window`` window rows, position
+    ``p`` at row ``p % W``, then the summary rows.  The ``s`` tokens of a
+    call must lie in one window (the serving engine's chunks do:
+    ``prefill_chunk`` divides ``W``); each call also pools its window's rows
+    into that window's summary rows, so the call that fills a window leaves
+    its summaries behind.  The paged form gathers each row's pages, works on
+    the view and scatters them back: the reference for the fused path.
+
+    Returns (logits [B, s, num_pred_heads * V], new_cache).  Used for prefill
+    (s = prompt length, start_pos=0), decode (s = 1), and chunked per-slot
+    prefill (s = chunk, scalar start_pos = chunk offset).
     """
     cfg = model.config
     mesh = model.mesh
@@ -301,6 +321,10 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     if paged and (not per_row or s != 1):
         raise ValueError("paged KV decode requires per-row positions and "
                          "s == 1 (prefill runs on a gathered slot view)")
+    if cfg.is_eva and quant_kv:
+        raise NotImplementedError(
+            "attention='eva' with an int8 KV cache: the summary rows are "
+            "pooled from the window rows and have no scales of their own")
     x = jnp.take(params["embed"]["tok"], tokens, axis=0)
     if cfg.position == "learned":
         if per_row:
@@ -311,7 +335,10 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             x = x + jnp.take(params["embed"]["pos"], pos_idx, axis=0)[None]
     if cfg.embed_norm:  # bloom word_embeddings_layernorm
         x = norm(x, params["embed"]["norm"], "layernorm", cfg.norm_eps)
-    x = x.astype(cache["x_dtype"].dtype if quant_kv else cache["k"].dtype)
+    cdt = cache["x_dtype"].dtype if quant_kv else cache["k"].dtype
+    # matmul inputs in the cache's dtype; the stream itself in float32
+    # where the model says so (evabyte's fp32_skip_add)
+    x = x.astype(jnp.float32 if cfg.fp32_residual else cdt)
     x = constrain(x, mesh, batch_ax, None, None)
     if per_row:
         q_pos = start_pos[:, None] + jnp.arange(s)             # [B, s]
@@ -326,7 +353,12 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     # logical sequence capacity: the paged pool's per-slot window is the
     # page table width x page depth, not the physical buffer's last dim
     s_max = cache["k"].shape[-2] * (page_table.shape[1] if paged else 1)
-    if cfg.position == "rope":
+    if cfg.is_eva:
+        # positions are not cache rows here: angles straight from q_pos
+        cos, sin = (t.reshape(q_pos.shape + t.shape[-1:]).astype(cdt)
+                    for t in rope_angles(q_pos.reshape(-1), rope_dim(cfg),
+                                         theta=cfg.rope_theta))
+    elif cfg.position == "rope":
         # angles for the whole cache window once; gather the query slice
         cos_all, sin_all = rope_angles(jnp.arange(s_max),
                                        rope_dim(cfg), theta=cfg.rope_theta)
@@ -341,6 +373,7 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     else:
         cos = sin = jnp.zeros((), x.dtype)
     scale = 1.0 / (Dh ** 0.5)
+    W = cfg.eva_window
 
     # A dropless MoE block takes the STACKED expert arrays whole and its own
     # layer's index (moe/sharded_moe.py:_moe_grouped): scanned like the other
@@ -361,7 +394,7 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             lp, l, kc, vc = xs
             ksc = vsc = None
         x0 = h_in  # layer input (parallel residual reads it twice)
-        h = norm(h_in, lp["attn_norm"], cfg.norm, cfg.norm_eps)
+        h = model_norm(cfg, h_in, lp["attn_norm"]).astype(cdt)
         a = lp["attn"]
         q = h @ a["wq"].astype(h.dtype)
         k = h @ a["wk"].astype(h.dtype)
@@ -383,7 +416,30 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             else:
                 q = apply_partial_rope(q, cos, sin)
                 k = apply_partial_rope(k, cos, sin)
-        if quant_kv:
+        if cfg.is_eva:
+            win_pos = start_pos % W
+            kv_view, vv_view = ((paged_logical_view(kc, page_table),
+                                 paged_logical_view(vc, page_table))
+                                if paged else (kc, vc))
+            if per_row:
+                kv_view = _scatter_rows(kv_view, k, win_pos)
+                vv_view = _scatter_rows(vv_view, v, win_pos)
+            else:
+                kv_view = jax.lax.dynamic_update_slice(
+                    kv_view, k.astype(kc.dtype), (0, 0, win_pos, 0))
+                vv_view = jax.lax.dynamic_update_slice(
+                    vv_view, v.astype(vc.dtype), (0, 0, win_pos, 0))
+            o = eva.cached_attention(q, kv_view, vv_view, q_pos, window=W,
+                                     chunk=cfg.eva_chunk, scale=scale)
+            kv_view, vv_view = eva.write_window_summaries(
+                kv_view, vv_view, a["eva_mu"], a["eva_phi"], start_pos // W,
+                window=W, chunk=cfg.eva_chunk)
+            if paged:
+                kc = _scatter_view(kc, kv_view, page_table)
+                vc = _scatter_view(vc, vv_view, page_table)
+            else:
+                kc, vc = kv_view, vv_view
+        elif quant_kv:
             kq, ks = _quantize_kv_rows(k)
             vq, vs = _quantize_kv_rows(v)
             if paged:
@@ -414,7 +470,9 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
                                               (0, 0, start_pos, 0))
             vc = jax.lax.dynamic_update_slice(vc, v.astype(vc.dtype),
                                               (0, 0, start_pos, 0))
-        if paged:
+        if cfg.is_eva:
+            pass                       # attended above, on the view
+        elif paged:
             # XLA fallback read: gather the logical per-slot view through
             # the table (junk-page rows sit past every live position and
             # mask out); the Pallas kernel path never materializes this
@@ -430,6 +488,7 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         o = o @ a["wo"].astype(h.dtype)
         if cfg.use_bias:
             o = o + a["bo"].astype(h.dtype)
+        o = o.astype(h_in.dtype)
         if cfg.parallel_residual:
             # gpt-neox: MLP reads the LAYER INPUT; both branches add at once
             mlp_src = x0
@@ -437,7 +496,7 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             h_in = h_in + o
             mlp_src = h_in
 
-        h = norm(mlp_src, lp["mlp_norm"], cfg.norm, cfg.norm_eps)
+        h = model_norm(cfg, mlp_src, lp["mlp_norm"]).astype(cdt)
         if cfg.is_moe:
             from deepspeed_tpu.moe.sharded_moe import moe_mlp
             mlp_out, _ = moe_mlp(
@@ -460,6 +519,7 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             mlp_out = gated @ m["w_down"].astype(h.dtype)
             if cfg.has_mlp_bias:
                 mlp_out = mlp_out + m["b_down"].astype(h.dtype)
+        mlp_out = mlp_out.astype(h_in.dtype)
         h_in = (x0 + o + mlp_out) if cfg.parallel_residual else (h_in + mlp_out)
         if quant_kv:
             return h_in, (kc, vc, ksc, vsc)
@@ -475,15 +535,31 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         x, (kc_new, vc_new) = jax.lax.scan(
             layer_step, x, (layers, layer_ids, cache["k"], cache["v"]))
         new_cache = {"k": kc_new, "v": vc_new}
-    x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps)
-    if cfg.tie_embeddings:
-        head = params["embed"]["tok"].T.astype(x.dtype)
-    else:
-        head = params["lm_head"].astype(x.dtype)  # QTensor-aware (.astype)
-    logits = (x @ head).astype(jnp.float32)
+    logits = output_logits(cfg, params, x)
     if cfg.lm_head_bias:
         logits = logits + params["lm_head_bias"].astype(jnp.float32)
     return logits, new_cache
+
+
+def output_logits(cfg, params, x):
+    """Final norm and output head of the cached forwards (``params`` the
+    plain or the kernel-injected tree): float32 logits [..., num_pred_heads
+    * V].  A float32 residual stream (evabyte's fp32_logits) is normed in
+    float32, meets the head in the head's dtype and is accumulated in
+    float32; otherwise the product is rounded to ``x``'s dtype first."""
+    x = model_norm(cfg, x, params["final_norm"])
+    head = (params["embed"]["tok"].T if cfg.tie_embeddings
+            else params["lm_head"])
+    if cfg.fp32_residual:
+        return jnp.dot(x.astype(head.dtype), head,
+                       preferred_element_type=jnp.float32)
+    return (x @ head.astype(x.dtype)).astype(jnp.float32)  # QTensor-aware
+
+
+def next_token_logits(cfg, logits):
+    """The columns the next token is sampled from: head 0's ``vocab_size``
+    of a model with several prediction heads."""
+    return logits[..., :cfg.vocab_size] if cfg.num_pred_heads > 1 else logits
 
 
 def sample_token(logits, rng, temperature: float = 1.0, top_k: int = 0,

@@ -26,9 +26,12 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models.decoding import output_logits
 from deepspeed_tpu.models.layers import norm, qk_norm, rope_dim
 from deepspeed_tpu.ops.pallas import rope_angles
-from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
+from deepspeed_tpu.ops.pallas.decode import (eva_decode_paged,
+                                             eva_summarize_paged,
+                                             flash_decode, fused_mlp,
                                              fused_moe_mlp, fused_norm_qkv,
                                              fused_proj_norm, paged_kv_append)
 
@@ -40,7 +43,16 @@ def supports_fused_decode(cfg, *, quantized_kv: bool = False,
     """The fused path covers the model zoo, dense and mixture-of-experts,
     including int8 weights (dequant in-kernel; expert weights are never
     quantized, ``models/quant.py``); int8 KV caches and tp>1 fall back to
-    the reference-shaped loop."""
+    the reference-shaped loop.
+
+    What a cache page holds on this path depends on the attention form.
+    ``attention="full"``: the K and V rows of ``page`` consecutive positions,
+    for ever (``flash_decode`` masks by one length a row).  ``"eva"``, paged
+    pool only: a WINDOW page holds rows ``p % W`` of the current window and
+    is overwritten in place by the next one; a SUMMARY page holds ``page``
+    pooled chunk summaries, ``ktilde`` in K and ``vtilde`` in V
+    (``eva_decode_paged`` masks by two lengths a row, ``eva_summarize_paged``
+    fills the summary rows when a step fills a window)."""
     return (not quantized_kv
             and tp == 1 and cfg.position in ("rope", "learned", "alibi"))
 
@@ -70,12 +82,18 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
                              attn["wv"].scale], -1))
     else:
         wqkv = jnp.concatenate([attn["wq"], attn["wk"], attn["wv"]], axis=-1)
+    # a unit-offset norm's gain is 1 + scale, taken in float32 once, here
+    gain = ((lambda g: 1.0 + g.astype(jnp.float32))
+            if cfg.norm_add_unit_offset else (lambda g: g))
     stacked: Dict[str, Any] = {
         "wqkv": wqkv,
         "wo": attn["wo"],
-        "n1_scale": ly["attn_norm"]["scale"],
-        "n2_scale": ly["mlp_norm"]["scale"],
+        "n1_scale": gain(ly["attn_norm"]["scale"]),
+        "n2_scale": gain(ly["mlp_norm"]["scale"]),
     }
+    if cfg.is_eva:
+        stacked["eva_mu"] = attn["eva_mu"]
+        stacked["eva_phi"] = attn["eva_phi"]
     if cfg.is_moe:
         stacked["gate_w"] = mlp["gate_w"]
     else:
@@ -139,7 +157,7 @@ def moe_counts_zero(cfg):
 def decode_step(cfg, dparams, tokens, cache, pos, *,
                 page_table=None, moe_live=None, impl: Optional[str] = None):
     """One generation step: ``tokens`` [B, 1] at absolute position ``pos``
-    -> (logits [B, V] fp32, cache).
+    -> (logits [B, num_pred_heads * V] fp32, cache).
 
     ``pos`` is a traced scalar (static batch: every row at the same depth)
     or an int32 [B] vector of per-row positions (continuous batching: each
@@ -169,14 +187,21 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
     per_row = pos.ndim == 1                  # [B] per-slot depths
     if page_table is not None and not per_row:
         raise ValueError("paged KV decode requires per-row positions")
+    if cfg.is_eva and page_table is None:
+        raise NotImplementedError(
+            "attention='eva' decodes through the paged pool "
+            "(serving/paged_kv.py); the contiguous caches hold no window "
+            "and summary rows on the fused path")
     x = jnp.take(dparams["embed"]["tok"], tokens[:, 0], axis=0)
     if cfg.position == "learned":
         x = x + jnp.take(dparams["embed"]["pos"],
                          pos if per_row else pos[None], axis=0)
     if cfg.embed_norm:  # bloom word_embeddings_layernorm
         x = norm(x, dparams["embed"]["norm"], "layernorm", cfg.norm_eps)
-    dtype = cache["k"].dtype
-    x = x.astype(dtype)
+    # the stream between the kernels: the cache's dtype, or float32 where
+    # the model keeps its residual there (evabyte); the kernels hand the
+    # matmuls the weights' dtype either way
+    x = x.astype(jnp.float32 if cfg.fp32_residual else cache["k"].dtype)
 
     if cfg.position == "rope":
         rd = rope_dim(cfg)
@@ -235,7 +260,13 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
         q = rope_rows(q.reshape(B, H, Dh))
         k = rope_rows(k.reshape(B, Hkv, Dh))
         v = qkv[:, M + Mkv:].reshape(B, Hkv, Dh)
-        if page_table is not None:
+        if cfg.is_eva:
+            # window rows are reused in place: position p lives at row
+            # p % W of the row's window pages (the table's first W / page)
+            W, C = cfg.eva_window, cfg.eva_chunk
+            kc_all, vc_all = paged_kv_append(kc_all, vc_all, k, v, pos % W,
+                                             page_table, layer=l, impl=impl)
+        elif page_table is not None:
             # paged append: row b writes at row pos[b] % page of physical
             # page page_table[b, pos[b] // page] (parked rows' tables
             # point at the junk page 0 — their writes land where no live
@@ -260,9 +291,18 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             vc_all = jax.lax.dynamic_update_slice(
                 vc_all, v[None, :, :, None, :].astype(vc_all.dtype),
                 (l, pos0, pos0, pos, pos0))
-        ctx = flash_decode(q, kc_all, vc_all, pos, sm_scale=scale,
-                           layer=l, alibi=cfg.position == "alibi",
-                           page_table=page_table, impl=impl)
+        if cfg.is_eva:
+            ctx = eva_decode_paged(q, kc_all, vc_all, pos, page_table,
+                                   layer=l, window=W, chunk=C,
+                                   sm_scale=scale, impl=impl)
+            # rows whose step filled their window leave its summaries behind
+            kc_all, vc_all = eva_summarize_paged(
+                kc_all, vc_all, lp["eva_mu"], lp["eva_phi"], pos, page_table,
+                layer=l, window=W, chunk=C, impl=impl)
+        else:
+            ctx = flash_decode(q, kc_all, vc_all, pos, sm_scale=scale,
+                               layer=l, alibi=cfg.position == "alibi",
+                               page_table=page_table, impl=impl)
         wo, s_wo = wq_pair(lp["wo"])
         r, h = fused_proj_norm(ctx.reshape(B, M), x, wo, lp.get("bo"),
                                lp["n2_scale"], lp.get("n2_bias"), kind=kind,
@@ -290,12 +330,7 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
                           lp.get("b_up"), lp.get("b_gate"), lp.get("b_down"),
                           act=cfg.activation, wscales=wscales, impl=impl)
     new_cache = {"k": kc_all, "v": vc_all}
-    x = norm(x, dparams["final_norm"], kind, eps)
-    if cfg.tie_embeddings:
-        head = dparams["embed"]["tok"].T.astype(x.dtype)
-    else:
-        head = dparams["lm_head"].astype(x.dtype)
-    logits = (x @ head).astype(jnp.float32)
+    logits = output_logits(cfg, dparams, x)
     if cfg.lm_head_bias:
         logits = logits + dparams["lm_head_bias"].astype(jnp.float32)
     if moe_live is not None:

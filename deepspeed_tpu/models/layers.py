@@ -103,6 +103,16 @@ def norm(x, params, kind: str, eps: float, mesh: Optional[Mesh] = None):
                          out_specs=spec, check_vma=False)(x, *weights)
 
 
+def model_norm(cfg, x, params, mesh: Optional[Mesh] = None):
+    """A model's own norm of ``x``: :func:`norm` with the configuration's
+    kind and eps, the stored gain taken as ``1 + scale`` in float32 where
+    ``cfg.norm_add_unit_offset`` says so (evabyte)."""
+    if cfg.norm_add_unit_offset:
+        params = {**params,
+                  "scale": 1.0 + params["scale"].astype(jnp.float32)}
+    return norm(x, params, cfg.norm, cfg.norm_eps, mesh)
+
+
 def qk_norm(q, k, q_scale, k_scale, eps: float,
             mesh: Optional[Mesh] = None):
     """QK-norm (olmoe): RMSNorm over the WHOLE q and k projections

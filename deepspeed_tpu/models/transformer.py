@@ -38,9 +38,12 @@ from deepspeed_tpu.comm.mesh import axis_size, get_global_mesh
 from deepspeed_tpu.models.config import ModelConfig, get_model_config
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
                                          attention_core, constrain,
-                                         _mesh_spec, norm, qk_norm,
+                                         _mesh_spec, model_norm, norm, qk_norm,
                                          _repeat_kv, rope_cache, rope_dim)
 from deepspeed_tpu.ops.pallas import apply_rotary_pos_emb
+
+
+EVA_INIT_STD = 0.01275     # EvaByte's published ``init_std``
 
 
 def _uniform(rng, shape, scale, dtype):
@@ -93,7 +96,9 @@ class CausalLM:
             # python-loop is a forward-pass choice, not a layout choice.
             return _uniform(key, (L,) + shape, scale, dtype)
 
-        norm_p = {"scale": jnp.ones((L, D), dtype)}
+        # a unit-offset norm multiplies by 1 + gain: its gain starts at 0
+        gain = jnp.zeros if cfg.norm_add_unit_offset else jnp.ones
+        norm_p = {"scale": gain((L, D), dtype)}
         if cfg.norm == "layernorm":
             norm_p["bias"] = jnp.zeros((L, D), dtype)
         attn = {
@@ -111,6 +116,14 @@ class CausalLM:
         if cfg.qk_norm:
             attn.update(q_norm={"scale": jnp.ones((L, H * Dh), dtype)},
                         k_norm={"scale": jnp.ones((L, Hkv * Dh), dtype)})
+        if cfg.is_eva:
+            # the release's adaptive_mu_k / adaptive_phi, initialised as its
+            # other weights are (normal, the published init_std)
+            attn.update(
+                eva_mu=jax.random.normal(next(keys), (L, H, Dh), dtype)
+                * EVA_INIT_STD,
+                eva_phi=jax.random.normal(next(keys), (L, H, Dh), dtype)
+                * EVA_INIT_STD)
         if cfg.is_moe:
             mlp = {
                 "gate_w": _uniform(next(keys), (L, D, E), s_in, dtype),
@@ -134,7 +147,7 @@ class CausalLM:
         layers = {"attn_norm": norm_p,
                   "mlp_norm": jax.tree.map(jnp.copy, norm_p),
                   "attn": attn, "mlp": mlp}
-        fnorm = {"scale": jnp.ones((D,), dtype)}
+        fnorm = {"scale": gain((D,), dtype)}
         if cfg.norm == "layernorm":
             fnorm["bias"] = jnp.zeros((D,), dtype)
         params = {
@@ -149,7 +162,9 @@ class CausalLM:
             params["embed"]["norm"] = {"scale": jnp.ones((D,), dtype),
                                        "bias": jnp.zeros((D,), dtype)}
         if not cfg.tie_embeddings:
-            params["lm_head"] = jax.random.normal(next(keys), (D, V), dtype) * s_in
+            # head p's V columns predict token i + 1 + p (num_pred_heads)
+            params["lm_head"] = jax.random.normal(
+                next(keys), (D, cfg.num_pred_heads * V), dtype) * s_in
         if cfg.lm_head_bias:
             params["lm_head_bias"] = jnp.zeros((V,), dtype)
         return params
@@ -176,6 +191,9 @@ class CausalLM:
         if cfg.qk_norm:  # replicated; layers.qk_norm refuses tp > 1
             attn.update(q_norm={"scale": P(None, None)},
                         k_norm={"scale": P(None, None)})
+        if cfg.is_eva:  # replicated; _attn_out refuses tp > 1 and sp > 1
+            attn.update(eva_mu=P(None, None, None),
+                        eva_phi=P(None, None, None))
         if cfg.is_moe:
             mlp = {"gate_w": P(None, None, None),
                    "w_up": P(None, "ep", None, "tp"),
@@ -224,8 +242,10 @@ class CausalLM:
         mesh = self.mesh
         B, S, D = x.shape
         H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-        h = norm(x, lp["attn_norm"], cfg.norm, cfg.norm_eps, mesh)
+        h = model_norm(cfg, x, lp["attn_norm"], mesh)
         a = lp["attn"]
+        if cfg.fp32_residual:     # matmul inputs in the weights' dtype
+            h = h.astype(a["wq"].dtype)
         q = h @ a["wq"]
         k = h @ a["wk"]
         v = h @ a["wv"]
@@ -242,11 +262,24 @@ class CausalLM:
             k = apply_partial_rope(k, cos, sin)
         k = _repeat_kv(k, H // Hkv)
         v = _repeat_kv(v, H // Hkv)
-        o = attention_core(q, k, v, mesh, causal=True, sp_mode=cfg.sp_mode,
-                           alibi=cfg.position == "alibi",
-                           ring_q=getattr(cfg, "seq_ring_q", False),
-                           ring_q_block=getattr(cfg, "comm_quant_block",
-                                                256))
+        if cfg.is_eva:
+            if mesh is not None and not mesh.empty and (
+                    axis_size(mesh, "tp") > 1 or axis_size(mesh, "sp") > 1):
+                raise NotImplementedError(
+                    "attention='eva' with tp > 1 or sp > 1: eva_mu/eva_phi "
+                    "are replicated per head and the windows are not split "
+                    "over chips")
+            from deepspeed_tpu.models.eva import eva_attention
+            o = eva_attention(q, k, v, a["eva_mu"], a["eva_phi"],
+                              window=cfg.eva_window, chunk=cfg.eva_chunk,
+                              scale=Dh ** -0.5)
+        else:
+            o = attention_core(q, k, v, mesh, causal=True,
+                               sp_mode=cfg.sp_mode,
+                               alibi=cfg.position == "alibi",
+                               ring_q=getattr(cfg, "seq_ring_q", False),
+                               ring_q_block=getattr(cfg, "comm_quant_block",
+                                                    256))
         o = o.transpose(0, 2, 1, 3).reshape(B, S, H * Dh)
         o = o @ a["wo"]
         if cfg.use_bias:
@@ -263,7 +296,9 @@ class CausalLM:
     def _mlp_block(self, lp, x, k_mlp, batch_ax, use_drop):
         cfg = self.config
         mesh = self.mesh
-        h = norm(x, lp["mlp_norm"], cfg.norm, cfg.norm_eps, mesh)
+        h = model_norm(cfg, x, lp["mlp_norm"], mesh)
+        if cfg.fp32_residual:
+            h = h.astype(jax.tree.leaves(lp["mlp"])[0].dtype)
         if cfg.is_moe:
             from deepspeed_tpu.moe.sharded_moe import moe_mlp
             # split: the RTS permutation and the dropout mask below must not
@@ -361,10 +396,13 @@ class CausalLM:
             x = norm(x, params["embed"]["norm"], "layernorm", cfg.norm_eps,
                      mesh)
         x = constrain(x, mesh, batch_ax, "sp", None)
+        w_dtype = x.dtype
+        if cfg.fp32_residual:
+            x = x.astype(jnp.float32)
 
         if cfg.position == "rope":
             cos, sin = rope_cache(tokens.shape[1], rope_dim(cfg), cfg.rope_theta)
-            cos, sin = cos.astype(x.dtype), sin.astype(x.dtype)
+            cos, sin = cos.astype(w_dtype), sin.astype(w_dtype)
         else:
             cos = sin = jnp.zeros((), x.dtype)
 
@@ -616,7 +654,7 @@ class CausalLM:
                 aux_loss = aux_loss + aux
 
         if labels is None:
-            x = norm(x, params["final_norm"], cfg.norm, cfg.norm_eps, mesh)
+            x = model_norm(cfg, x, params["final_norm"], mesh)
             head = (params["embed"]["tok"].T if cfg.tie_embeddings
                     else params["lm_head"]).astype(x.dtype)
             logits = x @ head
@@ -646,7 +684,13 @@ class CausalLM:
         vocabulary."""
         cfg = self.config
         mesh = self.mesh
-        h = norm(x, fnorm, cfg.norm, cfg.norm_eps, mesh)
+        if cfg.num_pred_heads > 1:
+            raise NotImplementedError(
+                f"the training loss over num_pred_heads={cfg.num_pred_heads} "
+                "output heads (head p against the token p + 1 ahead) is not "
+                "built: CausalLM._loss_tail knows one next-token head; such "
+                "a model is served only")
+        h = model_norm(cfg, x, fnorm, mesh)
         head = constrain(head.astype(h.dtype), mesh, None, "tp")
         if loss_mask is not None:
             labels = jnp.where(loss_mask > 0, labels, -100)

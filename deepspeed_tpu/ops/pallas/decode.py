@@ -14,6 +14,10 @@ these kernels collapse each layer to four launches:
   map clamps beyond ``pos`` so HBM traffic tracks the generated length)
 - :func:`paged_kv_append`  — this step's K/V rows into the paged pool, in
   place (the paged serving path; the contiguous layouts append in XLA)
+- :func:`eva_decode_paged` — the same online softmax over a row's window
+  pages and its summary pages, two valid lengths a row (``models/eva.py``)
+- :func:`eva_summarize_paged` — the rows whose step filled a window pool it
+  into that window's summary rows, in place
 - :func:`fused_proj_norm`  — attention out-projection → residual add → norm
 - :func:`fused_mlp`        — (gated) MLP → residual add, blocked over the
   FFN dim so VMEM holds one weight tile at a time
@@ -27,7 +31,10 @@ policy in :mod:`deepspeed_tpu.ops.pallas.common`.
 
 All softmax/norm/accumulation math is fp32; matmul operands stay in the
 serving dtype (bf16) for MXU rate, accumulating fp32 — the same contract as
-the training kernels in this package.
+the training kernels in this package.  The residual stream may be wider than
+the weights (evabyte keeps it float32): ``fused_norm_qkv`` then hands the
+matmul the normed rows in the weights' dtype, and ``fused_proj_norm`` and
+``fused_mlp`` return the stream in the dtype it came in.
 """
 
 from __future__ import annotations
@@ -100,15 +107,16 @@ def _deq(w, ws, dtype):
 
 
 def _norm_qkv_ref(x, scale, bias, wqkv, bqkv, *, kind, eps, wscale=None):
+    cd = x.dtype if wscale is not None else wqkv.dtype
     h = _normalize(x.astype(jnp.float32), scale.astype(jnp.float32),
-                   bias.astype(jnp.float32), kind, eps).astype(x.dtype)
+                   bias.astype(jnp.float32), kind, eps).astype(cd)
     if wscale is not None:
-        wqkv = _deq(wqkv, wscale.reshape(1, -1), x.dtype)
+        wqkv = _deq(wqkv, wscale.reshape(1, -1), cd)
     y = jax.lax.dot_general(h, wqkv, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if bqkv is not None:
         y = y + bqkv.astype(jnp.float32)
-    return y.astype(x.dtype)
+    return y.astype(cd)
 
 
 def _norm_qkv_kernel(x_ref, s_ref, b_ref, w_ref, ws_ref, bq_ref, o_ref,
@@ -130,8 +138,10 @@ def _norm_qkv_kernel(x_ref, s_ref, b_ref, w_ref, ws_ref, bq_ref, o_ref,
 
 def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
                    eps: float = 1e-5, wscale=None, impl: Optional[str] = None):
-    """x: [B, D]; wqkv: [D, N]; returns [B, N] in x.dtype.  ``wscale``
-    [N]-broadcastable fp32 marks ``wqkv`` as int8 (dequant in-kernel).
+    """x: [B, D]; wqkv: [D, N]; returns [B, N] in the weights' dtype (the
+    dtype the normed rows meet them in; ``x`` may be wider, a float32
+    residual stream).  ``wscale`` [N]-broadcastable fp32 marks ``wqkv`` as
+    int8 (dequant in-kernel; rows and result then keep ``x.dtype``).
 
     Reference: fused ln/rmsnorm + qkv_gemm of ``(R)
     csrc/transformer/inference`` (one launch instead of norm + 3 GEMVs)."""
@@ -144,11 +154,12 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
     B, D = x.shape
     N = wqkv.shape[1]
     quant = wscale is not None
+    cd = x.dtype if quant else wqkv.dtype
     # quant sizing counts the in-kernel fp32 dequant intermediate, not the
     # int8 payload — a payload-sized block would overflow VMEM at 1B+ scale
     bn = _col_block(D, N, 4 if quant else wqkv.dtype.itemsize)
     has_bias = bqkv is not None
-    bq = (bqkv if has_bias else jnp.zeros((N,), x.dtype)).reshape(1, N)
+    bq = (bqkv if has_bias else jnp.zeros((N,), cd)).reshape(1, N)
     ws = (wscale if quant else jnp.ones((N,), jnp.float32)).reshape(1, N)
     kernel = functools.partial(_norm_qkv_kernel, kind=kind, eps=eps,
                                has_bias=has_bias, quant=quant)
@@ -162,8 +173,8 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
                   pl.BlockSpec((1, bn), lambda j: (0, j)),
                   pl.BlockSpec((1, bn), lambda j: (0, j))],
         out_specs=pl.BlockSpec((B, bn), lambda j: (0, j)),
-        out_shape=jax.ShapeDtypeStruct((B, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((B, D), x.dtype)],
+        out_shape=jax.ShapeDtypeStruct((B, N), cd),
+        scratch_shapes=[pltpu.VMEM((B, D), cd)],
         interpret=interpret_flag(impl),
         name="fused_norm_qkv",
     )(x, scale.reshape(1, D), bias.reshape(1, D), wqkv, ws, bq)
@@ -209,11 +220,7 @@ def _flash_decode_kernel(*refs, scale, block, nb, alibi):
     q_ref, k_ref, v_ref, slope_ref, o_ref, m_scr, l_scr, acc_scr = refs[-8:]
     j = pl.program_id(2)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    pl.when(j == 0)(functools.partial(_softmax_init, m_scr, l_scr, acc_scr))
 
     # each batch row has its own position (continuous batching)
     pos = pos_ref[pl.program_id(0)]
@@ -230,20 +237,36 @@ def _flash_decode_kernel(*refs, scale, block, nb, alibi):
         if alibi:
             s = s + slope_ref[:] * (key_pos - pos).astype(jnp.float32)
         s = jnp.where(key_pos <= pos, s, NEG_INF)   # [hb, rep, block]
-        m_prev = m_scr[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
+        _softmax_block(s, v_ref, m_scr, l_scr, acc_scr)
 
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_scr[:]
-        o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+    pl.when(j == nb - 1)(
+        functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
+
+
+def _softmax_init(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _softmax_finish(o_ref, l_scr, acc_scr):
+    l = l_scr[:]
+    o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _softmax_block(s, v_ref, m_scr, l_scr, acc_scr):
+    """One key block of the online softmax: masked scores ``s`` [hb, rep,
+    block] and the block's values into the running max, sum and weighted
+    values."""
+    m_prev = m_scr[:]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    alpha = jnp.exp(m_prev - m_new)
+    l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+    acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+        p, v_ref[0].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32)
+    m_scr[:] = m_new
 
 
 # VMEM the decode attention kernel may spend on its double-buffered K and V
@@ -263,12 +286,13 @@ def _kv_heads_per_step(hkv: int, block: int, dh: int, itemsize: int) -> int:
 
 
 def _decode_attention(q, kcache, vcache, prefetch, kv_map, *, block, nb,
-                      scale, alibi, impl, name):
-    """The one ``pallas_call`` behind both cache layouts.  The caches are
+                      scale, alibi, impl, name, kernel=None):
+    """The one ``pallas_call`` behind every cache layout.  The caches are
     taken as ``[N, Hkv, S, Dh]`` views (N = stacked layers x batch rows, or
     x physical pages: a free reshape); ``kv_map(b, g, j, *prefetch_refs)``
     places the ``(1, hb, block, Dh)`` K and V blocks of grid step
-    ``(b, g, j)`` of ``grid=(B, Hkv // hb, nb)``."""
+    ``(b, g, j)`` of ``grid=(B, Hkv // hb, nb)``.  ``kernel`` replaces the
+    body (same refs; ``_eva_decode_kernel`` masks by two lengths a row)."""
     B, H, Dh = q.shape
     view = (-1,) + kcache.shape[-3:]
     hkv = view[1]
@@ -280,8 +304,9 @@ def _decode_attention(q, kcache, vcache, prefetch, kv_map, *, block, nb,
         slopes = alibi_slopes(H).reshape(hkv, rep, 1)
     else:
         slopes = jnp.zeros((hkv, rep, 1), jnp.float32)
-    kernel = functools.partial(_flash_decode_kernel, scale=scale, block=block,
-                               nb=nb, alibi=alibi)
+    if kernel is None:
+        kernel = functools.partial(_flash_decode_kernel, scale=scale,
+                                   block=block, nb=nb, alibi=alibi)
     # index maps see the scalar-prefetch refs AFTER the grid indices (the
     # kernel body sees them first)
     heads = pl.BlockSpec((1, hb, rep, Dh), lambda b, g, j, *_: (b, g, 0, 0))
@@ -468,6 +493,199 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
 
 
 # ---------------------------------------------------------------------------
+# EVA (models/eva.py): decode attention over window pages + summary pages,
+# and the pooling of a filled window into its summary rows
+# ---------------------------------------------------------------------------
+
+def _eva_decode_kernel(*refs, scale, block, nb, wp, window, per):
+    """``_flash_decode_kernel`` with two valid lengths a row: grid steps
+    ``j < wp`` walk the row's window pages, of which rows ``[0, pos % W]``
+    count; steps ``j >= wp`` its summary pages, of which the first
+    ``(pos // W) * W/C`` rows count.  One online softmax over both."""
+    pos_ref = refs[0]
+    q_ref, k_ref, v_ref, _, o_ref, m_scr, l_scr, acc_scr = refs[-8:]
+    j = pl.program_id(2)
+
+    pl.when(j == 0)(functools.partial(_softmax_init, m_scr, l_scr, acc_scr))
+
+    pos = pos_ref[pl.program_id(0)]
+    # rows of this page that count
+    n_valid = jnp.where(j < wp, pos % window + 1 - j * block,
+                        (pos // window) * per - (j - wp) * block)
+
+    @pl.when(n_valid > 0)
+    def _compute():
+        q, k = q_ref[0], k_ref[0]                   # [hb, 1 | block, Dh]
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        _softmax_block(jnp.where(row < n_valid, s, NEG_INF), v_ref, m_scr,
+                       l_scr, acc_scr)
+
+    pl.when(j == nb - 1)(
+        functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
+
+
+def eva_reference_reason(page: int, window: int, chunk: int) -> Optional[str]:
+    """Why the EVA kernels cannot take these sizes (None = they can): a page
+    is the decode kernel's key block along the 128 lanes, and the pooling
+    kernel writes a page's ``page / chunk`` summaries as whole 16-row
+    tiles."""
+    if page % 128:
+        return f"page of {page} tokens is not a multiple of the 128-lane tile"
+    if window % page or (page // chunk) % 16:
+        return (f"window {window} / page {page} / chunk {chunk}: a page's "
+                f"summaries are not whole 16-row tiles")
+    return None
+
+
+def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer: int,
+                     window: int, chunk: int,
+                     sm_scale: Optional[float] = None,
+                     impl: Optional[str] = None):
+    """EVA decode attention over the paged pool.  q [B, H, Dh] at absolute
+    positions ``pos`` [B]; caches stacked [L, P, H, page, Dh] read at static
+    layer ``layer``; ``page_table`` [B, wp + sp]: a row's ``wp = W / page``
+    window pages, then its summary pages (``serving/paged_kv.py``).  One
+    grid step is one row x ``hb`` heads x one page, window pages first; the
+    index map clamps past the last page with a row that counts, so pages a
+    row does not attend are neither fetched nor computed."""
+    impl = resolve_impl(impl)
+    B, H, Dh = q.shape
+    L, P, _, page, _ = kcache.shape
+    scale = sm_scale if sm_scale is not None else 1.0 / (Dh ** 0.5)
+    pos = jnp.asarray(pos, jnp.int32)
+    impl = kernel_or_reference("eva_decode_paged", impl,
+                               eva_reference_reason(page, window, chunk))
+    if impl == "xla":
+        from deepspeed_tpu.models import eva
+        from deepspeed_tpu.models.decoding import paged_logical_view
+
+        return eva.cached_attention(
+            q[:, :, None], paged_logical_view(kcache[layer], page_table),
+            paged_logical_view(vcache[layer], page_table), pos[:, None],
+            window=window, chunk=chunk, scale=scale)[:, :, 0]
+    wp, per = window // page, window // chunk
+    nb = page_table.shape[1]
+    base = layer * P
+
+    def page_map(b, g, j, pos_ref, pt_ref):
+        p = pos_ref[b]
+        last_win = (p % window) // page
+        sum_pages = ((p // window) * per + page - 1) // page
+        col = jnp.where(
+            j < wp, jnp.minimum(j, last_win),
+            jnp.where(sum_pages > 0,
+                      wp + jnp.minimum(j - wp, sum_pages - 1), last_win))
+        return base + pt_ref[b, col], g, 0, 0
+
+    kernel = functools.partial(_eva_decode_kernel, scale=scale, block=page,
+                               nb=nb, wp=wp, window=window, per=per)
+    return _decode_attention(
+        q, kcache, vcache, (pos, page_table.astype(jnp.int32)), page_map,
+        block=page, nb=nb, scale=scale, alibi=False, impl=impl,
+        name="eva_decode_paged", kernel=kernel)
+
+
+def _eva_summarize_kernel(pp_ref, sp_ref, k_ref, v_ref, mu_ref, phi_ref,
+                          ks_out, vs_out, *, chunk):
+    """One grid step = one closing row x ``hb`` heads x one window page: the
+    page's ``page / chunk`` chunk summaries, written as one tile group of
+    the row's summary page."""
+    del pp_ref, sp_ref                # consumed by the index maps
+    hb, page, Dh = k_ref.shape[1:]
+    kc = k_ref[0].astype(jnp.float32).reshape(hb, page // chunk, chunk, Dh)
+    vc = v_ref[0].astype(jnp.float32).reshape(hb, page // chunk, chunk, Dh)
+
+    def weights(w_ref):
+        z = jnp.sum(kc * w_ref[:].astype(jnp.float32)[:, None, None, :],
+                    axis=-1, keepdims=True)            # [hb, n, chunk, 1]
+        e = jnp.exp(z - jnp.max(z, axis=-2, keepdims=True))
+        return e / jnp.sum(e, axis=-2, keepdims=True)
+
+    ks_out[0] = jnp.sum(weights(mu_ref) * kc, axis=-2).astype(ks_out.dtype)
+    vs_out[0] = jnp.sum(weights(phi_ref) * vc, axis=-2).astype(vs_out.dtype)
+
+
+def eva_summarize_paged(kcache, vcache, mu, phi, pos, page_table, *,
+                        layer: int, window: int, chunk: int,
+                        impl: Optional[str] = None):
+    """The window close of a decode step, in place on the stacked pool: for
+    every row whose position ``pos[b]`` (already appended) is the last of its
+    window, pool the ``W`` window rows of layer ``layer`` into the ``W/C``
+    summary rows of window ``pos[b] // W`` (``models/eva.py:summarize``; mu,
+    phi [H, Dh]).  Other rows cost nothing: the kernel's grid is (rows that
+    close, head groups, window pages), its first bound read from the
+    positions at run time, so a step in which no row closes a window (all
+    but one in ``W`` a row) launches a kernel of no steps."""
+    impl = resolve_impl(impl)
+    L, P, H, page, Dh = kcache.shape
+    pos = jnp.asarray(pos, jnp.int32)
+    B = pos.shape[0]
+    closing = (pos + 1) % window == 0
+    wp, per = window // page, window // chunk
+    impl = kernel_or_reference("eva_summarize_paged", impl,
+                               eva_reference_reason(page, window, chunk))
+    if impl == "xla":
+        from deepspeed_tpu.models import eva
+        from deepspeed_tpu.models.decoding import (_scatter_view,
+                                                   paged_logical_view)
+
+        def pool(kc, vc):
+            kv, vv = (paged_logical_view(c[layer], page_table)
+                      for c in (kc, vc))
+            ks, vs = eva.write_window_summaries(
+                kv, vv, mu, phi, pos // window, window=window, chunk=chunk)
+            keep = closing[:, None, None, None]
+            # rows that close nothing scatter back what they gathered
+            return (kc.at[layer].set(_scatter_view(
+                        kc[layer], jnp.where(keep, ks, kv), page_table)),
+                    vc.at[layer].set(_scatter_view(
+                        vc[layer], jnp.where(keep, vs, vv), page_table)))
+
+        return jax.lax.cond(jnp.any(closing), pool,
+                            lambda kc, vc: (kc, vc), kcache, vcache)
+    n_out = page // chunk                       # summaries of one window page
+    groups = page // n_out                      # tile groups of a page
+    hb = max(d for d in range(1, 9) if H % d == 0)
+    # the closing rows first; entries past their count are never visited
+    rows = jnp.nonzero(closing, size=B, fill_value=0)[0]
+    table = page_table[rows].astype(jnp.int32)
+    # each (closing row, window page): its physical window page, and the
+    # physical summary page and tile group its summaries go to
+    srow = ((pos[rows] // window) * per)[:, None] \
+        + jnp.arange(wp, dtype=jnp.int32)[None] * n_out
+    sp = (jnp.take_along_axis(table, wp + srow // page, axis=1) * groups
+          + (srow % page) // n_out)
+    pool = jax.ShapeDtypeStruct((L * P, H, page, Dh), kcache.dtype)
+    base = layer * P
+    vec = pl.BlockSpec((hb, Dh), lambda i, g, j, *_: (g, 0))
+    src = pl.BlockSpec(
+        (1, hb, page, Dh),
+        lambda i, g, j, pp_ref, sp_ref: (base + pp_ref[i, j], g, 0, 0))
+    dst = pl.BlockSpec(
+        (1, hb, n_out, Dh),
+        lambda i, g, j, pp_ref, sp_ref: (base + sp_ref[i, j] // groups, g,
+                                         sp_ref[i, j] % groups, 0))
+    k4, v4 = pl.pallas_call(
+        functools.partial(_eva_summarize_kernel, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(jnp.sum(closing, dtype=jnp.int32), H // hb, wp),
+            in_specs=[src, src, vec, vec], out_specs=[dst, dst]),
+        out_shape=[pool, pool],
+        # operands count the two scalar-prefetch arrays: the pools, of which
+        # window pages are read and summary groups written, are 2 and 3
+        input_output_aliases={2: 0, 3: 1},
+        interpret=interpret_flag(impl),
+        name="eva_summarize_paged",
+    )(table[:, :wp], sp.astype(jnp.int32),
+      kcache.reshape(pool.shape), vcache.reshape(pool.shape),
+      mu.astype(jnp.float32), phi.astype(jnp.float32))
+    return k4.reshape(kcache.shape), v4.reshape(vcache.shape)
+
+
+# ---------------------------------------------------------------------------
 # fused_proj_norm: ctx @ wo (+bo) + resid -> r; norm(r | resid) -> h
 # ---------------------------------------------------------------------------
 
@@ -483,7 +701,7 @@ def _proj_norm_ref(ctx, resid, wo, bo, scale, bias, *, kind, eps, parallel,
     nsrc = resid.astype(jnp.float32) if parallel else r32
     h = _normalize(nsrc, scale.astype(jnp.float32),
                    bias.astype(jnp.float32), kind, eps)
-    return r32.astype(ctx.dtype), h.astype(ctx.dtype)
+    return r32.astype(resid.dtype), h.astype(ctx.dtype)
 
 
 def _proj_norm_kernel(ctx_ref, res_ref, wo_ref, ws_ref, bo_ref, s_ref, b_ref,
@@ -518,7 +736,8 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
                     parallel: bool = False, wscale=None,
                     impl: Optional[str] = None):
     """ctx: [B, M]; wo: [M, D]; resid: [B, D].  Returns (r, h): the updated
-    residual stream and the normed MLP input (``parallel=True`` norms the
+    residual stream (in ``resid.dtype``) and the normed MLP input (in
+    ``ctx.dtype``) (``parallel=True`` norms the
     layer input instead — gpt-neox parallel residual).  ``wscale`` marks
     ``wo`` as int8 (dequant in-kernel).
 
@@ -555,7 +774,7 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
                   pl.BlockSpec((bm, D), lambda j: (j, 0)),
                   row, row, row, row],
         out_specs=[act, act],
-        out_shape=[jax.ShapeDtypeStruct((B, D), ctx.dtype),
+        out_shape=[jax.ShapeDtypeStruct((B, D), resid.dtype),
                    jax.ShapeDtypeStruct((B, D), ctx.dtype)],
         scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         interpret=interpret_flag(impl),
@@ -593,7 +812,7 @@ def _mlp_ref(h, r, w_up, w_gate, w_down, b_up, b_gate, b_down, *, act,
                             preferred_element_type=jnp.float32)
     if b_down is not None:
         y = y + b_down.astype(jnp.float32)
-    return (r.astype(jnp.float32) + y).astype(h.dtype)
+    return (r.astype(jnp.float32) + y).astype(r.dtype)
 
 
 def _mlp_kernel(h_ref, r_ref, wu_ref, wg_ref, wd_ref, su_ref, sg_ref,
@@ -635,8 +854,8 @@ def _mlp_kernel(h_ref, r_ref, wu_ref, wg_ref, wd_ref, su_ref, sg_ref,
 def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
               b_down=None, *, act: str = "gelu", wscales=None,
               impl: Optional[str] = None):
-    """h: [B, D] (normed); r: [B, D] (residual).  Returns r + mlp(h).
-    ``wscales`` = (up, gate, down) per-out-channel fp32 scales marking the
+    """h: [B, D] (normed); r: [B, D] (residual).  Returns r + mlp(h) in
+    ``r.dtype``.  ``wscales`` = (up, gate, down) per-out-channel fp32 scales marking the
     weights as int8 (dequant in-kernel; gate entry ignored when no GLU).
 
     Blocked over the FFN dim: grid step j computes the partial product of
@@ -692,7 +911,7 @@ def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
                   pl.BlockSpec((1, bf), lambda j: (0, j)),
                   pl.BlockSpec((1, D), lambda j: (0, 0))],
         out_specs=pl.BlockSpec((B, D), lambda j: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, D), h.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, D), r.dtype),
         scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         interpret=interpret_flag(impl),
         name="fused_mlp",

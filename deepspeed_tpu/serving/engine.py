@@ -72,7 +72,7 @@ import numpy as np
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.engine import InferenceEngine, pow2_bucket
 from deepspeed_tpu.models.decoding import (forward_with_cache, init_kv_cache,
-                                           sample_token)
+                                           next_token_logits, sample_token)
 from deepspeed_tpu.monitor.flight_recorder import get_flight_recorder
 from deepspeed_tpu.monitor.goodput import get_goodput_ledger
 from deepspeed_tpu.monitor.health import get_health
@@ -134,6 +134,50 @@ def _in_phase(name: str):
                 return method(self, *args, **kwargs)
         return inside
     return wrap
+
+
+# EVA attention (models/eva.py), counted on the host from the positions the
+# engine already holds, only while the registry is on.
+SERVE_EVA_COUNTERS = {
+    "ds_serve_eva_window_closes_total":
+        "windows closed (pooled into summary rows), by prefill chunks and "
+        "decode steps",
+    "ds_serve_eva_window_rows_total":
+        "window rows attended by live decode rows, summed over steps",
+    "ds_serve_eva_summary_rows_total":
+        "summary rows attended by live decode rows, summed over steps",
+}
+
+
+def _eva_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
+    """What an ``attention="eva"`` model cannot be served with, each named
+    by the module that assumes a page holds the K and V of fixed positions
+    for ever (a window page is overwritten every ``eva_window`` tokens)."""
+    if not config.paged_kv_cache:
+        raise NotImplementedError(
+            "attention='eva' is served from the paged pool "
+            "(serving/paged_kv.py holds its window and summary pages); "
+            "paged_kv_cache=False has no layout for them")
+    if role != "both":
+        raise NotImplementedError(
+            f"role={role!r} with attention='eva': serving/handoff.py ships "
+            "pages as the K and V of a token prefix, which a window page "
+            "is not")
+    if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
+        raise NotImplementedError(
+            "kv_host_tier_pages > 0 with attention='eva': "
+            "serving/host_tier.py demotes and promotes pages keyed by the "
+            "token prefix they hold, which a window page does not keep")
+    if config.quantize_kv_cache:
+        raise NotImplementedError(
+            "quantize_kv_cache with attention='eva': models/decoding.py "
+            "scales int8 rows a position at a time, and summary rows are "
+            "pooled from window rows with no scales of their own")
+    if cfg.eva_window % prefill_chunk or prefill_chunk & (prefill_chunk - 1):
+        raise ValueError(
+            f"prefill_chunk={prefill_chunk} must be a power of two that "
+            f"divides eva_window={cfg.eva_window}: a prefill chunk may not "
+            f"straddle a window boundary")
 
 
 class ServingEngine:
@@ -227,12 +271,17 @@ class ServingEngine:
             shed_retry_after_s=float(self._config.shed_retry_after_s))
 
         cfg = self.module.config
+        self._eva = bool(getattr(cfg, "is_eva", False))
+        if self._eva:
+            _eva_refusals(cfg, self._config, role, self.prefill_chunk)
         self.paged = bool(self._config.paged_kv_cache)
         if self.paged:
             self.pool = PagedKVPool(
                 self.num_slots, self._config.max_out_tokens,
                 page_tokens=self._config.kv_page_tokens,
-                pool_tokens=self._config.kv_pool_tokens)
+                pool_tokens=self._config.kv_pool_tokens,
+                window_tokens=cfg.eva_window if self._eva else 0,
+                chunk_tokens=cfg.eva_chunk if self._eva else 0)
             self._cache = init_paged_kv_cache(
                 cfg, self.pool.num_pages, self.pool.page,
                 dtype=engine.dtype,
@@ -254,7 +303,12 @@ class ServingEngine:
         # store that eviction victims demote into (instead of dropping)
         # and admissions promote back out of — the effective prefix cache
         # becomes host-RAM-sized (docs/OBSERVABILITY.md "KV host tier")
-        if self.paged and self._config.prefix_caching:
+        if self._eva and self._config.prefix_caching:
+            log_dist("prefix caching is off for attention='eva': "
+                     "serving/prefix_cache.py shares pages as the K and V of "
+                     "a token prefix, and a window page is overwritten every "
+                     f"{cfg.eva_window} tokens", ranks=[0])
+        if self.paged and self._config.prefix_caching and not self._eva:
             host_pages = int(getattr(self._config, "kv_host_tier_pages", 0))
             self.host_store = (
                 HostPageStore(host_pages, registry=self._registry)
@@ -425,6 +479,8 @@ class ServingEngine:
                         f"host seconds inside {name}: {what}")
         self._m_moe = {name: reg.counter(name, what)
                        for name, what in SERVE_MOE_COUNTERS.items()}
+        self._m_eva = {name: reg.counter(name, what)
+                       for name, what in SERVE_EVA_COUNTERS.items()}
         self._m_first_overlapped = reg.counter(
             "ds_serve_first_token_overlapped_total",
             "first tokens fetched with a decode block already enqueued "
@@ -462,6 +518,13 @@ class ServingEngine:
             "ds_serve_kv_pages_used", "KV pool pages allocated to slots")
         self._m_pages_free = reg.gauge(
             "ds_serve_kv_pages_free", "KV pool pages on the free list")
+        self._m_pages_kind = {
+            kind: reg.gauge(
+                "ds_serve_kv_pages_used_by_kind",
+                "KV pool pages held by slots, by what they hold (EVA: window "
+                "rows reused in place, or chunk summaries; full attention: "
+                "all window)", labels={"kind": kind})
+            for kind in ("window", "summary")}
         self._m_preempted = reg.counter(
             "ds_serve_preempted_total",
             "requests preempted (pages reclaimed, requeued at queue head)")
@@ -526,6 +589,9 @@ class ServingEngine:
             layout = (f"paged pool: {self.pool.num_pages - 1} x "
                       f"{self.pool.page}-token pages, "
                       f"{self.num_slots} slots x {self.cache_len} window")
+            if self._eva:
+                layout += (f" ({self.pool.window_pages} window + "
+                           f"{self.pool.summary_pages} summary pages a slot)")
         else:
             layout = f"{self.num_slots} slots x {self.cache_len} tokens"
         log_dist(f"serving engine: {layout}, prefill_chunk="
@@ -560,6 +626,11 @@ class ServingEngine:
                 "engine is draining/drained: not admitting new requests "
                 "(the router should have stopped sending — /healthz is "
                 "503; resume_admission() re-opens)")
+        if prefill_only and self._eva:
+            raise NotImplementedError(
+                "prefill_only with attention='eva': serving/handoff.py ships "
+                "pages as the K and V of a token prefix, which a window "
+                "page is not")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -1653,9 +1724,15 @@ class ServingEngine:
             self._preempt(victim)
             if victim is req:
                 return False
+        self._page_gauges()
+        return True
+
+    def _page_gauges(self) -> None:
         self._m_pages_used.set(self.pool.pages_used)
         self._m_pages_free.set(self.pool.pages_free)
-        return True
+        if self._eva and self._registry.enabled:
+            for kind, n in self.pool.pages_used_by_kind().items():
+                self._m_pages_kind[kind].set(n)
 
     def _youngest_victim(self) -> Optional[Request]:
         cands = self.scheduler.running() + self.scheduler.prefilling()
@@ -1700,8 +1777,7 @@ class ServingEngine:
                                 tokens_reclaimed=freed * self.pool.page,
                                 trace=victim.trace_id)
         self._m_preempted.inc()
-        self._m_pages_used.set(self.pool.pages_used)
-        self._m_pages_free.set(self.pool.pages_free)
+        self._page_gauges()
 
     # ------------------------------------------------------------------
     def _prefill_one_chunk(self, req: Request) -> None:
@@ -1753,6 +1829,8 @@ class ServingEngine:
                               time.perf_counter(), c)
             self._m_prefill_chunks.inc()
             self._m_prefill_toks.inc(c)
+            if self._eva and (off + c) % self.module.config.eva_window == 0:
+                self._m_eva["ds_serve_eva_window_closes_total"].inc()
             # parked rows write junk at their own pos; keeping pos =
             # prefill progress (host view here, device carry inside the
             # chunk's program) means the NEXT chunk overwrites that row
@@ -1865,6 +1943,7 @@ class ServingEngine:
         self._m_compiles.inc()
         model = self.module
         do_sample, temperature, top_k, top_p = self._sample
+        eva = self._eva
         if self.paged:
             maxp, page = self.pool.slot_pages, self.pool.page
 
@@ -1875,7 +1954,14 @@ class ServingEngine:
             def view(v):                 # the slot's rows, contiguous
                 if pt_row is None:
                     return jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-                g = v[:, pt_row]                # [L, maxp, Hkv, page, D]
+                if eva:
+                    # a dozen pages a slot whatever its length: a slice
+                    # each, where the gather below walks the whole pool
+                    g = jnp.concatenate(
+                        [jax.lax.dynamic_slice_in_dim(v, pt_row[i], 1, axis=1)
+                         for i in range(maxp)], axis=1)
+                else:
+                    g = v[:, pt_row]            # [L, maxp, Hkv, page, D]
                 L, mp, Hkv, pg, D = g.shape
                 return g.transpose(0, 2, 1, 3, 4).reshape(
                     L, 1, Hkv, mp * pg, D)
@@ -1887,6 +1973,11 @@ class ServingEngine:
                 L, _, Hkv, _, D = s.shape
                 pages = s.reshape(L, Hkv, maxp, page, D).transpose(
                     0, 2, 1, 3, 4)
+                if eva:
+                    for i in range(maxp):
+                        dst = jax.lax.dynamic_update_slice_in_dim(
+                            dst, pages[:, i:i + 1], pt_row[i], axis=1)
+                    return dst
                 return dst.at[:, pt_row].set(pages)
 
             sub = {k: (view(v) if v.ndim == 5 else v)
@@ -1895,8 +1986,8 @@ class ServingEngine:
             out = {k: (write_back(cache[k], sub[k])
                        if cache[k].ndim == 5 else sub[k])
                    for k in cache}
-            logits = jax.lax.dynamic_index_in_dim(logits, last_idx, axis=1,
-                                                  keepdims=False)
+            logits = next_token_logits(model.config, jax.lax.dynamic_index_in_dim(
+                logits, last_idx, axis=1, keepdims=False))
             tok = sample_token(logits, srng, temperature=temperature,
                                top_k=top_k, top_p=top_p,
                                do_sample=do_sample)[0].astype(jnp.int32)
@@ -1965,6 +2056,8 @@ class ServingEngine:
         for req in running:
             b = req.slot
             n = int(min(self._K, self._limit[b] - self._pos[b]))
+            if self._eva and self._registry.enabled:
+                self._count_eva(int(self._pos[b]), n)
             self._pos[b] += n
             # one span per participating row: the block's host dispatch
             # window with this request's scheduled token count
@@ -2021,6 +2114,20 @@ class ServingEngine:
                     self._count_moe(*(np.asarray(a) for a in moe))  # dslint: disable=DSL002 -- rides the block's own deferred fetch, registry on only
             entry = self._block_np[idx] = (toks, valid)
         return entry
+
+    def _count_eva(self, pos: int, n: int) -> None:
+        """A row's ``n`` decode steps from position ``pos`` into
+        ``ds_serve_eva_*``: the rows each step attends (``pos % W + 1``
+        window rows and the ``W/C`` summaries of each closed window) and the
+        windows the steps close.  Host arithmetic on positions the engine
+        holds anyway; an EOS row that stops early is counted to its bound."""
+        cfg = self.module.config
+        W, per = cfg.eva_window, cfg.eva_window // cfg.eva_chunk
+        p = np.arange(pos, pos + n)
+        m = self._m_eva
+        m["ds_serve_eva_window_rows_total"].inc(int((p % W + 1).sum()))
+        m["ds_serve_eva_summary_rows_total"].inc(int((p // W).sum()) * per)
+        m["ds_serve_eva_window_closes_total"].inc(int(((p + 1) % W == 0).sum()))
 
     def _count_moe(self, per_expert, hits, max_load) -> None:
         """One decode block's routing (``decode_step``'s ``moe_live``
@@ -2100,8 +2207,7 @@ class ServingEngine:
                     self.prefix_cache.insert(
                         req.prompt, self.pool.owned(b)[:full])
             self.pool.release(b)
-            self._m_pages_used.set(self.pool.pages_used)
-            self._m_pages_free.set(self.pool.pages_free)
+            self._page_gauges()
         req.finish_reason = reason
         n = len(req.output_tokens)
         # a pace only where tokens reached the host as they were made: a
@@ -2188,6 +2294,7 @@ class ServingEngine:
                 logits, cache, routed = step_fn(params, last[:, None], cache,
                                                 pos, page_table, valid)
                 moe = jax.tree.map(jnp.add, moe, routed)
+                logits = next_token_logits(self.module.config, logits)
                 nxt = sample_token(logits, srng, temperature=temperature,
                                    top_k=top_k, top_p=top_p,
                                    do_sample=do_sample).astype(last.dtype)

@@ -19,6 +19,32 @@ state, shipped into every compiled program; reads indirect through it
 block; the XLA fallback gathers a logical view) and per-row appends
 scatter through it.
 
+What a page holds depends on the model's attention form.  Under
+``attention="full"`` (every model but one) a page holds the K and V rows of
+``page_tokens`` consecutive positions, for ever: a slot's pages grow by one
+every ``page_tokens`` tokens, and prefix caching, the prefill->decode
+hand-off and the host tier all rest on that (a page is position-pure: its
+content is a function of the token prefix alone).  Under ``attention="eva"``
+(``models/eva.py``; ``window_tokens`` / ``chunk_tokens`` below) a slot
+holds TWO KINDS of page out of the same pool and the same free list, and
+its table has two column ranges:
+
+- **window pages**, columns ``[0, W / page)``: position ``p`` lives at row
+  ``p % W``, so these ``W / page`` pages are filled once and then
+  overwritten in place, window after window — a window page is NOT
+  position-pure;
+- **summary pages**, the columns after them: chunk summary ``c`` (one per
+  ``chunk_tokens`` positions of a CLOSED window; ``ktilde`` in the K buffer,
+  ``vtilde`` in the V buffer) lives at row ``c`` of the summary range, so a
+  summary page stands for ``page * chunk_tokens`` tokens and a slot gains
+  ``W / chunk_tokens`` summary rows each time a window closes.
+
+Pages needed are therefore a function of the POSITION, not of the length
+(:meth:`PagedKVPool.pages_for`): a slot never holds more than ``W / page``
+window pages, and window pages are all granted before the first summary
+page, so the table's columns still fill in order.  ``pool_tokens`` keeps
+its meaning, rows of the pool: pages x ``page_tokens``.
+
 Physical **page 0 is reserved as the junk page**: it is never allocated,
 and a released slot's table rows all point at it, so the parked row's
 junk K/V writes (inactive rows still execute in the static-shape compiled
@@ -51,12 +77,13 @@ Prefix caching (``serving/prefix_cache.py``) rides on two extensions:
 
 from __future__ import annotations
 
-from typing import Any, Dict, List
+from typing import Any, Dict, List, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models.decoding import DECODE_BLOCK
+from deepspeed_tpu.models.eva import closed_summary_rows, summary_rows
 
 
 def default_page_tokens(max_out_tokens: int) -> int:
@@ -111,16 +138,34 @@ class PagedKVPool:
         it lower oversubscribes slots against a fixed HBM budget; the pool
         always holds at least one slot's full budget so a lone request can
         never deadlock.
+    window_tokens, chunk_tokens:
+        EVA attention's window and chunk (0 = full attention): the slot's
+        table is then ``window_tokens / page`` window columns followed by
+        the summary columns (module docstring), and ``cache_len`` stays the
+        LOGICAL budget in positions, which the table no longer spans.
     """
 
     def __init__(self, num_slots: int, max_out_tokens: int, *,
-                 page_tokens: int = 0, pool_tokens: int = 0):
+                 page_tokens: int = 0, pool_tokens: int = 0,
+                 window_tokens: int = 0, chunk_tokens: int = 0):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.page = int(page_tokens) or default_page_tokens(max_out_tokens)
-        self.slot_pages = -(-int(max_out_tokens) // self.page)
-        self.cache_len = self.slot_pages * self.page
-        want = int(pool_tokens) or num_slots * self.cache_len
+        self.window, self.chunk = int(window_tokens), int(chunk_tokens)
+        self.cache_len = -(-int(max_out_tokens) // self.page) * self.page
+        if self.window:
+            if self.window % self.page:
+                raise ValueError(
+                    f"EVA window of {self.window} tokens is not a whole "
+                    f"number of {self.page}-token pages")
+            self.window_pages = self.window // self.page
+            self.summary_pages = -(-summary_rows(
+                max_out_tokens, self.window, self.chunk) // self.page)
+            self.slot_pages = self.window_pages + self.summary_pages
+        else:
+            self.window_pages, self.summary_pages = 0, 0
+            self.slot_pages = self.cache_len // self.page
+        want = int(pool_tokens) or num_slots * self.slot_pages * self.page
         usable = max(self.slot_pages, -(-want // self.page))
         self.num_pages = usable + 1          # + the reserved junk page 0
         self.num_slots = num_slots
@@ -139,6 +184,18 @@ class PagedKVPool:
         self._free: List[int] = list(range(usable, 0, -1))
 
     # -- allocation ----------------------------------------------------
+    def pages_for(self, tokens: int) -> int:
+        """Pages a slot holds once positions ``[0, tokens)`` are written.
+        Full attention: one every ``page`` tokens.  EVA: the window's pages
+        (at most ``W / page``, then reused in place) plus the pages of the
+        summary rows of the windows that have closed."""
+        tokens = int(tokens)
+        if not self.window:
+            return -(-tokens // self.page)
+        return (-(-min(tokens, self.window) // self.page)
+                + -(-closed_summary_rows(tokens, self.window, self.chunk)
+                    // self.page))
+
     def ensure(self, slot: int, tokens: int) -> bool:
         """Grow the slot's table to cover ``tokens`` logical tokens.
         Returns False when the pool is exhausted — pages already granted
@@ -148,7 +205,7 @@ class PagedKVPool:
             raise ValueError(f"slot needs {tokens} tokens > per-slot budget "
                              f"{self.cache_len}")
         owned = self._owned[slot]
-        need = -(-int(tokens) // self.page)
+        need = self.pages_for(tokens)
         while len(owned) < need:
             if not self._free:
                 return False
@@ -246,6 +303,15 @@ class PagedKVPool:
 
     def slot_pages_used(self, slot: int) -> int:
         return len(self._owned[slot])
+
+    def pages_used_by_kind(self) -> Dict[str, int]:
+        """Pages held by slots as ``{"window": n, "summary": n}`` (EVA; a
+        slot's first ``W / page`` pages are its window's).  Under full
+        attention every page counts as ``"window"``."""
+        held = [len(o) for o in self._owned]
+        cap = self.window_pages or self.slot_pages
+        window = sum(min(n, cap) for n in held)
+        return {"window": window, "summary": sum(held) - window}
 
     def owned(self, slot: int) -> List[int]:
         """The slot's page ids in logical order (a copy — the engine's

@@ -1,0 +1,436 @@
+"""EvaByte (``attention="eva"``) at a tiny size on the CPU, seeded weights:
+window 32, chunk 4, page 8, 2 layers, 4 heads, 8 prediction heads, float32.
+
+The plain reference (``benchmarks/reference/evabyte.py``, written from the
+issue's equations) against each of the three forwards on all ``8 x V``
+logits, the pool's two page kinds through finish, preemption and failure,
+and what the model refuses.
+
+TOLERANCE.  Everything here runs in float32, program and reference, so the
+two differ by summation order only: about 2e-6 on logits of magnitude 4
+(measured).  ``ATOL = 2e-5`` leaves that ten times its size and is tight
+enough that each of the three faults below misses it by orders of
+magnitude (``test_the_tolerance_catches_*``): the residual carried in bf16
+(about 3e-2), the chunk summaries lost (about 1e-1), a window page read one
+window stale (about 1e-1).
+"""
+
+import importlib.util
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.models import CausalLM, ModelConfig, eva
+from deepspeed_tpu.models.decoding import (_scatter_view, forward_with_cache,
+                                           paged_logical_view)
+from deepspeed_tpu.models.fused_decode import (decode_step,
+                                               inject_decode_params)
+from deepspeed_tpu.serving.paged_kv import PagedKVPool, init_paged_kv_cache
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+ATOL = 2e-5
+V, W, C, PAGE = 40, 32, 4, 8
+TINY = dict(vocab_size=V, hidden_size=64, intermediate_size=96, num_layers=2,
+            num_heads=4, head_dim=16, max_seq_len=256, rope_theta=100000.0,
+            attention="eva", eva_window=W, eva_chunk=C, num_pred_heads=8,
+            norm_add_unit_offset=True, fp32_residual=True)
+REF_CONFIG = dict(num_hidden_layers=2, num_attention_heads=4,
+                  rms_norm_eps=1e-5, rope_theta=100000.0, window_size=W,
+                  chunk_size=C, vocab_size=V)
+ENGINE = dict(dtype="float32", num_slots=3, prefill_chunk=16,
+              max_prefill_chunks=2, decode_block_tokens=4,
+              max_out_tokens=160, kv_page_tokens=PAGE)
+
+
+@pytest.fixture(scope="module")
+def ref():
+    spec = importlib.util.spec_from_file_location(
+        "_ref_evabyte",
+        os.path.join(REPO, "benchmarks", "reference", "evabyte.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+@pytest.fixture(scope="module")
+def model():
+    return CausalLM(ModelConfig(**TINY))
+
+
+@pytest.fixture(scope="module")
+def params(model):
+    """Seeded weights with gains and pooling vectors away from their
+    initial values, so that ``1 + g`` and both pooling softmaxes matter."""
+    p = model.init(jax.random.PRNGKey(0))
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 8))
+    for name in ("attn_norm", "mlp_norm"):
+        g = p["layers"][name]["scale"]
+        p["layers"][name]["scale"] = 0.1 * jax.random.normal(next(keys),
+                                                             g.shape)
+    p["final_norm"]["scale"] = 0.1 * jax.random.normal(next(keys), (64,))
+    for name in ("eva_mu", "eva_phi"):
+        p["layers"]["attn"][name] = p["layers"]["attn"][name] * 40.0
+    return p
+
+
+def ref_logits(ref, params, seq, rows=None, **kw):
+    rows = list(range(len(seq))) if rows is None else rows
+    return np.asarray(ref.logits_rows(params, REF_CONFIG, np.asarray(seq),
+                                      rows, jax.devices()[0], all_heads=True,
+                                      **kw))
+
+
+TOKENS = np.random.default_rng(0).integers(0, V, size=104)
+
+
+# -- the reference itself ---------------------------------------------------
+
+def test_reference_windows_equal_bruteforce_mask(ref, params):
+    """Step 3 by windows and query blocks against ONE masked O(T^2) softmax
+    over every key and every chunk summary."""
+    a = ref_logits(ref, params, TOKENS)
+    b = ref_logits(ref, params, TOKENS, attention=ref.attention_bruteforce)
+    assert a.shape == (len(TOKENS), 8 * V)
+    np.testing.assert_allclose(a, b, atol=ATOL)
+
+
+def test_summarize_is_step_two(ref, params):
+    """The summariser alone against step 2 written out a chunk at a time."""
+    rng = np.random.default_rng(3)
+    k = rng.normal(size=(4, 24, 16)).astype(np.float32)
+    v = rng.normal(size=(4, 24, 16)).astype(np.float32)
+    mu = rng.normal(size=(4, 16)).astype(np.float32)
+    phi = rng.normal(size=(4, 16)).astype(np.float32)
+    ks, vs = eva.summarize(jnp.asarray(k), jnp.asarray(v), jnp.asarray(mu),
+                           jnp.asarray(phi), C)
+    for h in range(4):
+        for c in range(24 // C):
+            kk, vv = k[h, C * c:C * c + C], v[h, C * c:C * c + C]
+            wk = np.exp(kk @ mu[h]); wk /= wk.sum()
+            wv = np.exp(kk @ phi[h]); wv /= wv.sum()
+            np.testing.assert_allclose(ks[h, c], wk @ kk, atol=1e-5)
+            np.testing.assert_allclose(vs[h, c], wv @ vv, atol=1e-5)
+    rk, rv = ref.chunk_summaries(jnp.asarray(k), jnp.asarray(v),
+                                 jnp.asarray(mu), jnp.asarray(phi), C)
+    np.testing.assert_allclose(ks, rk, atol=1e-5)
+    np.testing.assert_allclose(vs, rv, atol=1e-5)
+
+
+# -- the three forwards -------------------------------------------------------
+
+def test_apply_matches_reference_on_all_heads(ref, model, params):
+    """``CausalLM.apply`` (no cache), three windows and a part."""
+    got = np.asarray(model.apply(params, jnp.asarray(TOKENS)[None]))[0]
+    np.testing.assert_allclose(got, ref_logits(ref, params, TOKENS),
+                               atol=ATOL)
+
+
+def _prefill_paged(model, params, pool, cache, tokens, chunk=16):
+    """``tokens`` into slot 0 in chunks through ``forward_with_cache`` on the
+    slot's gathered view, as the engine's chunk program does.  Returns the
+    chunks' logits [len(tokens), 8 V] and the cache."""
+    logits = []
+    for off in range(0, len(tokens), chunk):
+        part = tokens[off:off + chunk]
+        assert pool.ensure(0, off + len(part))
+        pt = jnp.asarray(pool.page_table[:1])
+        view = {n: jax.vmap(lambda b: paged_logical_view(b, pt))(cache[n])
+                for n in ("k", "v")}
+        lg, view = forward_with_cache(model, params,
+                                      jnp.asarray(part)[None], view, off)
+        cache = {n: jax.vmap(lambda b, s: _scatter_view(b, s, pt))(
+            cache[n], view[n]) for n in ("k", "v")}
+        logits.append(np.asarray(lg[0]))
+    return np.concatenate(logits), cache
+
+
+@pytest.mark.parametrize("path", ["fused", "unfused"])
+def test_prefill_chunks_then_decode_across_two_boundaries(ref, model, params,
+                                                          path):
+    """40 tokens prefilled in chunks of 16 (one window closes in prefill),
+    then 64 decoded one at a time through the paged pool, across the
+    boundaries at 64 and 96, teacher-forced: every position's ``8 x V``
+    logits against the reference's one full forward."""
+    cfg = model.config
+    pool = PagedKVPool(1, 160, page_tokens=PAGE, window_tokens=W,
+                       chunk_tokens=C)
+    cache = init_paged_kv_cache(cfg, pool.num_pages, PAGE, dtype=jnp.float32)
+    n_prompt = 40
+    got, cache = _prefill_paged(model, params, pool, cache, TOKENS[:n_prompt])
+    dparams = inject_decode_params(params, cfg)
+    rows = [got]
+    for p in range(n_prompt, len(TOKENS)):
+        assert pool.ensure(0, p + 1)
+        pt = jnp.asarray(pool.page_table)
+        tok, pos = jnp.asarray(TOKENS[p:p + 1])[None], jnp.asarray([p])
+        if path == "fused":
+            lg, cache = decode_step(cfg, dparams, tok, cache, pos,
+                                    page_table=pt)
+        else:
+            lg, cache = forward_with_cache(model, params, tok, cache, pos,
+                                           page_table=pt)
+            lg = lg[:, -1]
+        rows.append(np.asarray(lg))
+    # 4 window pages, and the summary pages of the three closed windows
+    assert pool.slot_pages_used(0) == 4 + -(-3 * (W // C) // PAGE)
+    np.testing.assert_allclose(np.concatenate(rows),
+                               ref_logits(ref, params, TOKENS), atol=ATOL)
+
+
+@pytest.mark.parametrize("fault", ["bf16_residual", "no_summaries",
+                                   "stale_window"])
+def test_the_tolerance_catches(ref, params, fault):
+    """Each fault, put into the reference (the difference is symmetric),
+    moves some logit past ``ATOL`` by a wide margin."""
+    want = ref_logits(ref, params, TOKENS)
+    if fault == "bf16_residual":
+        got = ref_logits(ref, params, TOKENS, stream_dtype=jnp.bfloat16)
+    else:
+        def wrong(q, k, v, mu, phi, window, chunk):
+            return _attend(ref, q, k, v,
+                           *ref.chunk_summaries(k, v, mu, phi, chunk),
+                           window, chunk, stale=fault == "stale_window",
+                           summaries=fault != "no_summaries")
+        got = ref_logits(ref, params, TOKENS, attention=wrong)
+    assert np.abs(got - want).max() > 100 * ATOL
+
+
+def _attend(ref, q, k, v, ks, vs, window, chunk, *, stale, summaries):
+    """Step 3 as one masked softmax with given summaries; ``stale`` reads a
+    query's exact set one window back (a window page not overwritten), and
+    without ``summaries`` the summary set is empty."""
+    H, S, d = q.shape
+    i = jnp.arange(S)[:, None]
+    j = jnp.arange(S)[None, :]
+    c = jnp.arange(S // chunk)[None, :]
+    w = i // window
+    back = jnp.where(stale & (w > 0), window, 0)
+    ok = jnp.concatenate([(j >= w * window - back) & (j <= i - back),
+                          (c < w * (window // chunk)) & summaries], axis=-1)
+    s = jnp.einsum("hqd,hkd->hqk", q, jnp.concatenate([k, ks], 1)) \
+        / jnp.sqrt(jnp.float32(d))
+    p = jax.nn.softmax(jnp.where(ok[None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("hqk,hkd->hqd", p, jnp.concatenate([v, vs], 1))
+
+
+# -- the Pallas kernels against their XLA forms (interpret mode) --------------
+
+def _pool_arrays(rng, L=2, P=12, H=4, page=128, Dh=32):
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)
+    return mk(L, P, H, page, Dh), mk(L, P, H, page, Dh)
+
+
+@pytest.mark.parametrize("pos", [[300, 255, 17], [511, 767, 130]])
+def test_eva_decode_kernel_matches_xla(pos):
+    """Window 256, chunk 8, page 128: two window pages and one summary page
+    a row; rows in windows 0, 1 and 2."""
+    from deepspeed_tpu.ops.pallas.decode import eva_decode_paged
+
+    rng = np.random.default_rng(0)
+    kc, vc = _pool_arrays(rng)
+    q = jnp.asarray(rng.normal(size=(3, 4, 32)), jnp.float32)
+    pt = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    kw = dict(layer=1, window=256, chunk=8)
+    np.testing.assert_allclose(
+        eva_decode_paged(q, kc, vc, pos, pt, impl="interpret", **kw),
+        eva_decode_paged(q, kc, vc, pos, pt, impl="xla", **kw), atol=1e-5)
+
+
+@pytest.mark.parametrize("pos", [[300, 255, 17], [511, 767, 130],
+                                 [40, 600, 13]])
+def test_eva_summarize_kernel_matches_xla_and_touches_only_closers(pos):
+    from deepspeed_tpu.ops.pallas.decode import eva_summarize_paged
+
+    rng = np.random.default_rng(1)
+    kc, vc = _pool_arrays(rng)
+    mu = jnp.asarray(rng.normal(size=(4, 32)), jnp.float32)
+    phi = jnp.asarray(rng.normal(size=(4, 32)), jnp.float32)
+    pt = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    kw = dict(layer=1, window=256, chunk=8)
+    ka, va = eva_summarize_paged(kc, vc, mu, phi, pos, pt, impl="xla", **kw)
+    kb, vb = eva_summarize_paged(kc, vc, mu, phi, pos, pt, impl="interpret",
+                                 **kw)
+    np.testing.assert_allclose(ka, kb, atol=1e-5)
+    np.testing.assert_allclose(va, vb, atol=1e-5)
+    closing = (np.asarray(pos) + 1) % 256 == 0
+    changed = np.abs(np.asarray(kb) - np.asarray(kc)).max(axis=(0, 2, 3, 4))
+    # only the summary pages of the rows that closed a window were written
+    assert set(np.flatnonzero(changed)) == \
+        {int(pt[b, 2]) for b in np.flatnonzero(closing)}
+
+
+# -- the pool: two page kinds, one free list ---------------------------------
+
+def test_pages_needed_follow_the_position_not_the_length():
+    pool = PagedKVPool(2, 160, page_tokens=PAGE, window_tokens=W,
+                       chunk_tokens=C)
+    assert (pool.window_pages, pool.summary_pages) == (4, 5)
+    assert pool.slot_pages == 9 and pool.cache_len == 160
+    per_window = W // C                                   # 8 summary rows
+    for tokens, want in [(1, 1), (8, 1), (9, 2), (31, 4), (32, 4 + 1),
+                         (33, 5), (63, 5), (64, 4 + 2), (159, 4 + 4),
+                         (160, 4 + 5)]:
+        assert pool.pages_for(tokens) == want, tokens
+        assert pool.pages_for(tokens) == -(-min(tokens, W) // PAGE) + \
+            -(-(tokens // W) * per_window // PAGE)
+    assert pool.ensure(0, 70) and pool.ensure(1, 20)
+    assert pool.pages_used_by_kind() == {"window": 4 + 3, "summary": 2}
+    assert pool.release(0) == 6 and pool.release(1) == 3
+    pool.check_no_leak()
+    assert pool.pages_free == pool.num_pages - 1
+
+
+def _serve(model, params, **over):
+    return deepspeed_tpu.init_serving(model, config={**ENGINE, **over},
+                                      params=params)
+
+
+def _prompts():
+    rng = np.random.default_rng(5)
+    return ([rng.integers(0, V, size=n) for n in (45, 70, 20, 33)],
+            (70, 40, 30, 64))
+
+
+def _served_tokens_are_the_references_best(ref, params, prompt, out):
+    seq = np.concatenate([prompt, out])
+    rows = list(range(len(prompt) - 1, len(seq) - 1))
+    lg = ref_logits(ref, params, seq, rows)[:, :V]
+    assert (lg.max(-1) - lg[np.arange(len(out)), out]).max() <= ATOL
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_engine_serves_across_windows_and_returns_both_page_kinds(
+        ref, model, params, fused):
+    """Four requests on three slots through ``init_serving`` and ``step()``:
+    chunked prefill, continuous batching, windows closing in prefill and in
+    decode; every served token is the reference's best of head 0, and the
+    pool is whole afterwards."""
+    serve = _serve(model, params, use_fused_decode=fused)
+    assert serve.prefix_cache is None          # switched off, not refused
+    prompts, news = _prompts()
+    reqs = [serve.submit(p, max_new_tokens=n, stream=True)
+            for p, n in zip(prompts, news)]
+    serve.run()
+    serve.pool.check_no_leak()
+    assert serve.pool.pages_used == 0
+    for p, r, n in zip(prompts, reqs, news):
+        assert len(r.output_tokens) == n and r.finish_reason == "length"
+        _served_tokens_are_the_references_best(
+            ref, params, p, np.asarray(r.output_tokens))
+    serve.close()
+
+
+def test_preempted_request_resumes_to_the_same_tokens(model, params):
+    """A pool too small for three long requests: the youngest is preempted,
+    gives back every page of both kinds, resumes by recompute and yields the
+    tokens an unpressed engine yields."""
+    prompts, news = _prompts()
+    calm = _serve(model, params)
+    want = [calm.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    calm.run()
+    calm.close()
+    # 9 pages a slot at most; 14 usable pages for three slots
+    tight = _serve(model, params, kv_pool_tokens=14 * PAGE)
+    reqs = [tight.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    tight.run()
+    assert sum(r.preemptions for r in reqs) > 0
+    tight.pool.check_no_leak()
+    assert tight.pool.pages_used == 0
+    for a, b in zip(want, reqs):
+        assert a.output_tokens == b.output_tokens
+    tight.close()
+
+
+def test_aborted_request_returns_its_pages(model, params):
+    """A request that fails mid-flight (aborted after its window pages and a
+    summary page are held) returns both kinds."""
+    serve = _serve(model, params)
+    prompts, _ = _prompts()
+    req = serve.submit(prompts[1], max_new_tokens=60)
+    other = serve.submit(prompts[0], max_new_tokens=8)
+    for _ in range(6):
+        serve.step()
+    assert serve.pool.pages_used_by_kind()["summary"] > 0
+    serve.abort(req)
+    serve.run()
+    assert req.done and other.done and len(other.output_tokens) == 8
+    serve.pool.check_no_leak()
+    assert serve.pool.pages_used == 0
+    serve.close()
+
+
+def test_eva_counters_count_attended_rows_and_closes(model, params):
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(model, config=dict(ENGINE),
+                                       params=params, registry=reg)
+    prompt = np.arange(30) % V
+    serve.submit(prompt, max_new_tokens=40)
+    serve.run()
+    snap = reg.snapshot()
+    # decode steps sit at positions 30 .. 68 (39 of them: the first token
+    # comes from the prefill chunk); windows close at 31 and 63, in decode
+    pos = np.arange(30, 69)
+    assert snap["ds_serve_eva_window_rows_total"] == int((pos % W + 1).sum())
+    assert snap["ds_serve_eva_summary_rows_total"] == \
+        int((pos // W).sum()) * (W // C)
+    assert snap["ds_serve_eva_window_closes_total"] == 2
+    serve.close()
+
+
+# -- what it refuses ----------------------------------------------------------
+
+def test_config_checks():
+    with pytest.raises(ValueError, match="multiple of"):
+        ModelConfig(**{**TINY, "eva_chunk": 5})
+    with pytest.raises(ValueError, match="attention must be"):
+        ModelConfig(**{**TINY, "attention": "linear"})
+
+
+@pytest.mark.parametrize("over,match", [
+    (dict(role="prefill"), "handoff.py"),
+    (dict(role="decode"), "handoff.py"),
+    (dict(kv_host_tier_pages=4), "host_tier.py"),
+    (dict(quantize_kv_cache=True), "decoding.py"),
+    (dict(paged_kv_cache=False), "paged_kv.py"),
+    (dict(prefill_chunk=24), "prefill_chunk"),
+    (dict(prefill_chunk=64), "prefill_chunk"),
+])
+def test_init_serving_refuses(model, params, over, match):
+    kw = {k: over.pop(k) for k in ("role",) if k in over}
+    with pytest.raises((NotImplementedError, ValueError), match=match):
+        deepspeed_tpu.init_serving(model, config={**ENGINE, **over},
+                                   params=params, **kw)
+
+
+def test_static_batch_generate_refused(model, params):
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+
+    engine = InferenceEngine(model, DeepSpeedInferenceConfig(dtype="float32"),
+                             params=params)
+    with pytest.raises(NotImplementedError, match="init_serving"):
+        engine.generate(np.arange(8)[None], max_new_tokens=4)
+
+
+def test_tp_refused_and_training_loss_refused(params):
+    from deepspeed_tpu.comm.mesh import build_mesh
+
+    if len(jax.devices()) < 2:
+        pytest.skip("needs two devices")
+    mesh = build_mesh(tp=2, devices=jax.devices()[:2])
+    tp_model = CausalLM(ModelConfig(**TINY), mesh)
+    with pytest.raises(NotImplementedError, match="tp > 1"):
+        tp_model.apply(params, jnp.asarray(TOKENS[:32])[None])
+    with pytest.raises(NotImplementedError, match="num_pred_heads"):
+        CausalLM(ModelConfig(**TINY)).apply(
+            params, jnp.asarray(TOKENS[:32])[None],
+            labels=jnp.asarray(TOKENS[:32])[None])

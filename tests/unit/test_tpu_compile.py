@@ -19,7 +19,9 @@ from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.ops.pallas import (flash_attention, fused_adam_update,
                                       layer_norm, quantize, rms_norm)
-from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
+from deepspeed_tpu.ops.pallas.decode import (eva_decode_paged,
+                                             eva_summarize_paged,
+                                             flash_decode, fused_mlp,
                                              fused_moe_mlp, fused_norm_qkv,
                                              fused_proj_norm, paged_kv_append)
 from deepspeed_tpu.ops.pallas.fused_adam8bit import fused_adam8bit_update
@@ -276,6 +278,124 @@ def _moe_mlp(w, slots=64, **_):
 def test_kernels_compile_at_the_olmoe_serve_chat_shape(v5e, kernel):
     fn, shapes, want = kernel(OLMOE, **SERVE_CHAT)
     assert _custom_calls(fn, v5e, *shapes) >= want
+
+
+# the benchmark's evabyte-L6.serve-doc cell: MHA 32 x 128, window 2,048 and
+# chunk 16 over pages of 256 (8 window + 4 summary pages a row), 32 slots,
+# the residual stream float32 between the kernels
+EVABYTE = dict(D=4096, H=32, Hkv=32, Dh=128, F=11008, V=320, glu=True,
+               kind="rmsnorm")
+EVA = dict(window=2048, chunk=16)
+SERVE_DOC = dict(slots=32, page=256, maxp=12, layers=6, pool_pages=337)
+
+
+def _eva_decode(w):
+    pool, table = _paged_pool(w, **SERVE_DOC)
+    fn = lambda q, k, v, pos, pt: eva_decode_paged(
+        q, k, v, pos, pt, layer=5, impl="pallas", **EVA)
+    return fn, [((32, w["H"], w["Dh"]), BF16), pool, pool, ((32,), I32),
+                table], 1
+
+
+def _eva_summarize(w):
+    pool, table = _paged_pool(w, **SERVE_DOC)
+    vec = ((w["H"], w["Dh"]), BF16)
+    fn = lambda k, v, mu, phi, pos, pt: eva_summarize_paged(
+        k, v, mu, phi, pos, pt, layer=5, impl="pallas", **EVA)
+    return fn, [pool, pool, vec, vec, ((32,), I32), table], 1
+
+
+def _f32_stream(kernel):
+    """A fused kernel's shapes with the residual stream (a [rows, D]
+    operand that is not matmul input) in float32."""
+    def build(w):
+        fn, shapes, want = kernel(w)
+        stream = {"_norm_qkv": 0, "_proj_norm": 1, "_mlp": 1}[kernel.__name__]
+        shapes = [(s, F32) if i == stream else (s, d)
+                  for i, (s, d) in enumerate(shapes)]
+        return fn, shapes, want
+    return build
+
+
+@pytest.mark.parametrize("kernel", [
+    _eva_decode, _eva_summarize, _f32_stream(_norm_qkv),
+    _f32_stream(_proj_norm), _f32_stream(_mlp)],
+    ids=["eva_decode_paged", "eva_summarize_paged", "fused_norm_qkv_f32",
+         "fused_proj_norm_f32", "fused_mlp_f32"])
+def test_kernels_compile_at_the_evabyte_serve_doc_shape(v5e, kernel):
+    fn, shapes, want = kernel(EVABYTE)
+    assert _custom_calls(fn, v5e, *shapes) >= want
+
+
+def test_evabyte_programs_never_copy_the_pool(v5e, monkeypatch):
+    """ISSUE 32: the chunk program and the decode block of the
+    ``evabyte-L6.serve-doc`` cell (two layers of its six and a quarter of
+    its pool, which changes no shape the copies turn on) compile for the
+    v5e with the K and V pools updated in place: no copy of a pool (a
+    ``lax.cond`` around the window close cost two) and no gather over half
+    of one (``v[:, pt_row]`` on the slot's pages did), so 4.9 GB of weights
+    and an 8.8 GB pool fit the chip."""
+    import json
+
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.models import CausalLM, ModelConfig
+    from deepspeed_tpu.models.fused_decode import inject_decode_params
+    from deepspeed_tpu.ops.pallas import common
+    from deepspeed_tpu.serving.engine import ServingEngine
+
+    # this process's devices are CPUs: take the kernels the chip would
+    monkeypatch.setattr(common, "default_impl", lambda: "pallas")
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                         "benchmarks")
+    with open(os.path.join(bench, "configs", "evabyte-L6.json")) as f:
+        fields = dict(json.load(f)["model_config"], num_layers=2)
+    with open(os.path.join(bench, "workloads",
+                           "evabyte-L6.serve-doc.json")) as f:
+        engine = dict(json.load(f)["engine"], dtype="bfloat16",
+                      kv_pool_tokens=16384)
+    (device,) = v5e.device_set
+    model = CausalLM(ModelConfig(**fields), build_mesh(devices=[device]))
+    serve = ServingEngine(model, engine)
+    assert (serve.pool.window_pages, serve.pool.summary_pages) == (8, 4)
+    pool_bytes = serve._cache["k"].nbytes
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e), tree)
+
+    shapes = jax.eval_shape(
+        lambda key: jax.tree.map(lambda x: x.astype(BF16), model.init(key)),
+        jax.random.PRNGKey(0))
+    carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=v5e)
+    bucket = serve.prefill_chunk
+    chunk = serve._prefill_fn(bucket).lower(
+        on_chip(shapes), on_chip(serve._cache), on_chip(carries),
+        i32(serve.pool.slot_pages), i32(1, bucket), i32(5),
+        on_chip(serve._rng)).compile()
+    # stands in for the injected view _block() reads off the engine
+    serve.engine._dparams = jax.eval_shape(
+        lambda p: inject_decode_params(p, model.config), shapes)
+    block = serve._block().lower(
+        on_chip(serve.engine._dparams), on_chip(serve._cache),
+        *on_chip(carries), i32(serve.num_slots), i32(serve.num_slots),
+        on_chip(serve._rng),
+        i32(serve.num_slots, serve.pool.slot_pages)).compile()
+    import math
+    import re
+
+    for program in (chunk, block):
+        for name, shape, op in re.findall(
+                r"^\s*(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
+                program.as_text(), re.M):
+            size = 2 * math.prod(int(d) for d in shape.split(","))
+            assert not (size >= pool_bytes / 2
+                        and (op == "copy" or "gather" in name)), (name, shape)
+    text = block.as_text()
+    for name in ("eva_decode_paged", "eva_summarize_paged",
+                 "paged_kv_append", "fused_norm_qkv", "fused_proj_norm",
+                 "fused_mlp"):
+        assert name in text, name
 
 
 def test_last_chunk_program_aliases_cache_and_carries_at_serve_chat(v5e):
