@@ -4,42 +4,73 @@ TPU-native replacement for the reference's fused attention path
 (``csrc/transformer/softmax_kernels.cu`` + strided-batch GEMM attention in
 ``csrc/includes/strided_batch_gemm.h``, and the inference ``softmax.cu``;
 SURVEY.md §2.2): instead of materializing the [S, S] score matrix between two
-cuBLAS GEMMs, the kernel streams KV blocks through VMEM with an online
-softmax, so memory is O(S·D) and the MXU sees back-to-back matmuls.
+cuBLAS GEMMs, the kernel walks KV tiles with an online softmax, so memory is
+O(S·D) and the MXU sees back-to-back matmuls.
 
-Layout: q, k, v are [B, H, S, D].  Causal masking supported; optional
-additive bias (e.g. ALiBi) can be folded by the caller via the bias arg of the
-jnp reference for now.  All softmax math in fp32 (matching the reference
-kernels' accumulation).
+Layout: q, k, v are [B, H, S, D].  Causal masking supported; ``alibi=True``
+adds the per-head linear position bias in-kernel.  All softmax math in fp32
+(matching the reference kernels' accumulation).
 
-The TPU grid executes sequentially with the last axis fastest, so the KV-block
-axis is the innermost grid dimension and the running (m, l, acc) state lives
-in VMEM scratch across those grid steps — the Pallas-idiomatic form of the
-flash-attention inner loop.
+The tile schedule (:func:`tile_schedule`).  A grid step holds ``hb`` heads
+(whatever divides B*H and fits the VMEM budget), one Q tile of each and the
+heads' K and V for the whole sequence (a chunk of it where that does not
+fit), and walks the KV axis inside the kernel, in strips of ``_STRIP`` rows:
+a rolled loop over the strips under the diagonal, whose bound follows the Q
+tile, then the tile the diagonal crosses, whose strips each take only the
+queries from their own first row on.  So a strip the causal mask removes
+costs no grid step, no DMA and no arithmetic; only the strips of a crossed
+tile apply the mask; and of the scores computed and thrown away a strip's
+width is left, not a tile's.  The running state lives in VMEM scratch, across
+KV chunks too.
+
+The forward and the dK/dV kernel work on the TRANSPOSED score tile ``K Qᵀ``
+(KV positions on sublanes, queries on lanes).  The softmax's max and sum over
+KV then run down the sublanes, elementwise, instead of across lanes through
+the XLU once per eight rows per tile (what bound the forward at head dim
+64), and m, l, LSE and delta are lane-dense rows: an [S, 1] column of float32
+is padded to 128 lanes in HBM and in every block.  dK/dV's four matmuls are
+plain or rhs-transposed in this form (no transpose of a score tile); the
+forward pays one transpose of its [D, bq] accumulator per Q tile.
 
 Backward follows the standard recompute scheme: saved LSE from forward;
-``delta = rowsum(dO ∘ O)``; one kernel accumulates dQ over KV blocks, another
-accumulates dK/dV over Q blocks.
+``delta = rowsum(dO ∘ O)``; one kernel accumulates dQ over KV strips, another
+accumulates dK/dV over Q strips.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+import math
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.common import interpret_flag, pick_block, resolve_impl
+from deepspeed_tpu.ops.pallas.common import (interpret_flag, pick_block,
+                                             resolve_impl, round_up)
 
-# 512-token tiles: 8× fewer grid steps than 128 and MXU-shaped [512, 512]
-# score matmuls; VMEM per step stays < 4MB at D=128. Measured 3× faster than
-# 128-tiles on v5e at S=1024 (see bench notes in git history).
-DEFAULT_BLOCK_Q = 512
-DEFAULT_BLOCK_K = 512
+# Upper limits of the Q and KV tile (the tiles are the largest divisors of S
+# and Sk within them).  On one v5e, causal [16, 25, 1024, 64] bf16, forward +
+# dQ + dK/dV of one call each (tools/flash_attention_bench.py, PR 34): 8.36 ms
+# with a grid step per 512 x 512 tile (the schedule before); 4.19 ms with
+# tiles of 1024 and strips of 256, 4.32 with strips of 128; with one block a
+# tile and no strips 5.2 (512), 7.3 (256), 16.0 (128): a loop iteration costs
+# some 500 cycles whatever the tile, so tiles are large and what the mask
+# removes is cut out of them by strips.  [8, 32, 1024, 128]: 5.28 -> 2.55;
+# [2, 32, 4096, 128]: 15.6 -> 9.2.
+DEFAULT_BLOCK_Q = 1024
+DEFAULT_BLOCK_K = 1024
 NEG_INF = -1e30
+# What a grid step's double-buffered blocks and scratch may hold; it sets the
+# heads a step takes and, for long sequences, the KV (dK/dV: Q) chunk.  The
+# score-sized temporaries come on top, inside the compiler's 16 MiB default.
+_VMEM_BLOCK_BYTES = 8 * 2**20
+_LANES = 128
+# Rows of a strip, the unit the in-kernel loops step by (see _strips).
+_STRIP = 256
 
 
 # ---------------------------------------------------------------------------
@@ -72,271 +103,636 @@ def mha_reference(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
 
 
 # ---------------------------------------------------------------------------
+# tile schedule
+# ---------------------------------------------------------------------------
+
+def _clip(x, lo, hi):
+    """min(max(x, lo), hi) on Python ints and on traced scalars alike."""
+    if all(isinstance(t, int) for t in (x, lo, hi)):
+        return min(max(x, lo), hi)
+    return jnp.minimum(jnp.maximum(x, lo), hi)
+
+
+def _kv_bounds(i, bq: int, bk: int, nk: int, causal: bool):
+    """KV tiles of Q tile ``i`` (rows ``[i bq, (i+1) bq)``; a row sees the
+    columns up to its own index): tiles ``[0, plain)`` lie wholly under the
+    diagonal, ``[plain, visit)`` are crossed by it, the rest is masked out."""
+    if not causal:
+        return nk, nk
+    return (_clip((i * bq + 1) // bk, 0, nk),
+            _clip(((i + 1) * bq + bk - 1) // bk, 0, nk))
+
+
+def _q_bounds(j, bq: int, bk: int, nq: int, causal: bool):
+    """The transpose of :func:`_kv_bounds`, for KV tile ``j``: Q tiles
+    ``[first, plain)`` are crossed by the diagonal, ``[plain, nq)`` lie
+    wholly under it, those before ``first`` see none of the tile."""
+    if not causal:
+        return 0, 0
+    return (_clip(j * bk // bq, 0, nq),
+            _clip(((j + 1) * bk + bq - 2) // bq, 0, nq))
+
+
+class _Plan(NamedTuple):
+    bq: int       # Q tile
+    bk: int       # KV tile
+    ck: int       # KV tokens a forward / dQ grid step holds (Sk if it fits)
+    cq: int       # Q tokens a dK/dV grid step holds (S if it fits)
+    hb_fwd: int   # heads a grid step, by kernel
+    hb_dq: int
+    hb_dkv: int
+
+
+def _fit(n: int, tile: int, fixed: int, per_token: int) -> int:
+    """Largest chunk of ``n`` tokens, in whole tiles and dividing ``n``,
+    whose blocks fit :data:`_VMEM_BLOCK_BYTES` beside ``fixed`` bytes."""
+    t = n // tile
+    for parts in range(1, t + 1):
+        if t % parts == 0 and fixed + (n // parts) * per_token <= _VMEM_BLOCK_BYTES:
+            return n // parts
+    return tile
+
+
+def _heads(BH: int, per_head: int) -> int:
+    """Heads a grid step: the largest divisor of ``BH`` (not of the heads
+    of the model: 25 is odd) whose blocks fit the budget."""
+    return max(d for d in range(1, BH + 1)
+               if BH % d == 0 and (d == 1 or d * per_head <= _VMEM_BLOCK_BYTES))
+
+
+def _plan(BH: int, S: int, Sk: int, D: int, itemsize: int, block_q: int,
+          block_k: int) -> _Plan:
+    """Tiles, chunks and heads a step from the shapes alone.  Bytes are what
+    the blocks take in VMEM: the head dim padded to the 128 lanes, inputs
+    and outputs double-buffered, a [1, n] row of statistics 8 sublanes."""
+    bq = pick_block(S, block_q, minimum=8)
+    bk = pick_block(Sk, block_k, minimum=8)
+    row = round_up(D, _LANES) * itemsize           # one token of q / k / v
+    acc = round_up(D, _LANES) * 4                  # ... of an f32 accumulator
+    stat = 2 * 8 * 4                               # ... of a [1, n] f32 row
+    # forward: q, o tiles + lse row; m, l rows and acc scratch; K and V chunk
+    fwd_fixed = bq * (2 * 2 * row + 2 * stat + acc)
+    # dQ: q, dO, dq tiles + lse, delta rows; dq scratch; K and V chunk
+    dq_fixed = bq * (2 * 3 * row + 2 * stat + acc)
+    ck = _fit(Sk, bk, max(fwd_fixed, dq_fixed), 2 * 2 * row)
+    # dK/dV: k, v, dk, dv tiles; dk, dv scratch; Q, dO chunk + lse, delta rows
+    dkv_fixed = bk * (2 * 4 * row + 2 * acc)
+    cq = _fit(S, bq, dkv_fixed, 2 * 2 * row + 2 * stat)
+    return _Plan(bq, bk, ck, cq,
+                 _heads(BH, fwd_fixed + ck * 2 * 2 * row),
+                 _heads(BH, dq_fixed + ck * 2 * 2 * row),
+                 _heads(BH, dkv_fixed + cq * (2 * 2 * row + 2 * stat)))
+
+
+def tile_schedule(S: int, Sk: int, block_q: int = DEFAULT_BLOCK_Q,
+                  block_k: int = DEFAULT_BLOCK_K, causal: bool = True, *,
+                  head_dim: int = 64, heads: int = 1,
+                  itemsize: int = 2) -> Dict[str, object]:
+    """What the three kernels do on ``heads`` (= B*H) heads of [S, D] queries
+    against [Sk, D] keys.  Pure arithmetic on shapes, from the same bounds
+    and sub-blocks the kernels' loops use.
+
+    ``visited`` / ``masked``: the (Q tile, KV tile) pairs of the ``tiles`` =
+    nq * nk a head computes, and those of them whose body applies the mask
+    (causal, S == Sk, square tiles: n (n + 1) / 2 and n; no visited pair lies
+    wholly above the diagonal).  ``score_elements``: the scores a head
+    computes, ``kept_elements`` those the mask keeps (what a diagonal tile's
+    strips save shows here).  ``grid_steps``: of each kernel, all heads."""
+    p = _plan(heads, S, Sk, head_dim, itemsize, block_q, block_k)
+    nq, nk = S // p.bq, Sk // p.bk
+    bounds = [_kv_bounds(i, p.bq, p.bk, nk, causal) for i in range(nq)]
+    pairs = [(i, j) for i, (_, visit) in enumerate(bounds) for j in range(visit)]
+    masked = [(i, j) for i, (plain, visit) in enumerate(bounds)
+              for j in range(plain, visit)]
+    crossed = sum(kv_n * q_n for _, kv_n, _, q_n in _strips(p.bq, p.bk, True))
+    kept = sum(min(r + 1, Sk) for r in range(S)) if causal else S * Sk
+    return {"block_q": p.bq, "block_k": p.bk, "strip": _strip(p.bk),
+            "tiles": nq * nk, "visited": len(pairs), "masked": len(masked),
+            "visited_pairs": pairs, "masked_pairs": masked,
+            "score_elements": (len(pairs) - len(masked)) * p.bq * p.bk
+            + len(masked) * crossed,
+            "kept_elements": kept,
+            "heads_per_step": {"fwd": p.hb_fwd, "bwd_dq": p.hb_dq,
+                               "bwd_dkv": p.hb_dkv},
+            "grid_steps": {"fwd": heads // p.hb_fwd * nq * (Sk // p.ck),
+                           "bwd_dq": heads // p.hb_dq * nq * (Sk // p.ck),
+                           "bwd_dkv": heads // p.hb_dkv * nk * (S // p.cq)}}
+
+
+def _folds(scale: float) -> bool:
+    """A power-of-two scale (64^-1/2 is) multiplies bf16 operands and f32
+    sums exactly, so it moves off the [bq, bk] scores onto a [tile, D]
+    operand or accumulator without changing a bit of the result."""
+    return scale > 0 and math.frexp(scale)[0] == 0.5
+
+
+def _strip(tile: int) -> int:
+    """Rows of a strip of a ``tile``-token tile, the loops' own step: the
+    largest divisor of the tile within :data:`_STRIP`, a whole number of
+    lane tiles if there is one, of sublane tiles otherwise."""
+    for unit in (_LANES, 8):
+        fits = [d for d in range(unit, min(tile, _STRIP) + 1, unit)
+                if tile % d == 0]
+        if fits:
+            return fits[-1]
+    return tile
+
+
+def _strips(bq: int, bk: int, masked: bool, by_q: bool = False):
+    """The sub-blocks ``(kv_lo, kv_n, q_lo, q_n)`` of score tile [bq, bk]
+    that one loop body computes.  Under the diagonal a body is ONE strip
+    (of KV rows against the whole Q tile; ``by_q``: of Q rows against the
+    whole KV tile, the dK/dV kernel's loop) and the loop runs over the
+    strips.  A tile the diagonal crosses is one body: its KV strips, and on
+    the diagonal of square tiles each strip against the queries from its
+    own first row on only, since every query before that row has the whole
+    strip masked out (the queries lie on lanes in two of the kernels, so
+    that row has to be a multiple of the lane tile).  What is computed and
+    thrown away of such a tile shrinks from a half to ``strip / (2 bk)`` of
+    it; the strips differ in shape, so the body is unrolled over them."""
+    sq, sk = _strip(bq), _strip(bk)
+    if not masked:
+        return [(0, bk, 0, sq)] if by_q else [(0, sk, 0, bq)]
+    trimmed = bq == bk and sk % _LANES == 0
+    return [(r, sk, r, bq - r) if trimmed else (r, sk, 0, bq)
+            for r in range(0, bk, sk)]
+
+
+def _rel(kv_n: int, q_n: int, kv_dim: int):
+    """(KV index) - (Q index) inside a sub-block whose axis ``kv_dim`` runs
+    over KV positions.  Built once a grid step, outside the loops: the mask
+    of a tile is then one compare and one select, ALiBi one multiply-add."""
+    shape = (kv_n, q_n) if kv_dim == 0 else (q_n, kv_n)
+    return lax.sub(lax.broadcasted_iota(jnp.int32, shape, kv_dim),
+                   lax.broadcasted_iota(jnp.int32, shape, 1 - kv_dim))
+
+
+def _bodies(bq: int, bk: int, causal: bool, alibi: bool, kv_dim: int,
+            by_q: bool = False):
+    """{masked: [(kv_lo, kv_n, q_lo, q_n, rel or None)]} for the two loop
+    bodies; ``rel`` only where the body masks or adds ALiBi."""
+    return {masked: [blk + ((_rel(blk[1], blk[3], kv_dim)
+                             if masked or alibi else None),)
+                     for blk in _strips(bq, bk, masked, by_q)]
+            for masked in ((False, True) if causal else (False,))}
+
+
+# The kernel bodies below call ``lax`` where ``jnp`` would read better: a
+# ``jnp`` function, or an operator on a traced array, is a jitted wrapper
+# that takes most of a millisecond to trace, every time the enclosing program
+# is lowered (nothing caches that: it is part of every process's set-up),
+# against a tenth of that for the primitive.  The rare paths (ALiBi, a scale
+# that does not fold) keep ``jnp``.
+
+_NT = ((1,), (1,))    # a @ b.T
+_NN = ((1,), (0,))    # a @ b
+_TN = ((0,), (0,))    # a.T @ b
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+def _bc(x, like):
+    """A [1, n] row or an [n, 1] column broadcast over ``like``'s shape."""
+    return lax.broadcast_in_dim(x, like.shape, (0, 1))
+
+
+def _bias_and_mask(s, rel, slope, d, masked: bool):
+    """``d`` = (first Q row) - (first KV column) of the sub-block.  ALiBi
+    adds ``slope * (column - row)``; the mask keeps ``row >= column``."""
+    if slope is not None:
+        s = s + slope * (rel - d).astype(jnp.float32)
+    if masked:
+        s = lax.select(lax.le(rel, lax.broadcast(jnp.int32(d), rel.shape)), s,
+                       lax.full_like(s, NEG_INF))
+    return s
+
+
+def _run(lo, hi, tile, masked: bool):
+    """``tile(t, masked)`` for t in [lo, hi): a rolled loop (its body is
+    traced once) whose bounds depend on the grid step."""
+    def body(t, carry):
+        tile(t, masked)
+        return carry
+
+    lax.fori_loop(lo, hi, body, 0)
+
+
+def _when(pred):
+    """``pl.when``, or a plain call where ``pred`` is known to hold."""
+    return (lambda f: f()) if pred is True else pl.when(pred)
+
+
+def _all(*preds):
+    """Conjunction of traced conditions; True when there is none."""
+    return functools.reduce(jnp.logical_and, preds) if preds else True
+
+
+def _chunk(c, tiles: int, n: int):
+    """Grid step ``c``'s chunk ``[lo, hi)`` of the ``n`` tiles its loops
+    walk, and whether it starts / ends the accumulation: Python's 0, n,
+    True, True where one chunk holds them all (the usual case, and then
+    nothing of this is traced)."""
+    if tiles == n:
+        return 0, n, True, True
+    return c * tiles, (c + 1) * tiles, c == 0, c == pl.num_programs(2) - 1
+
+
+def _diagonal_here(i, mine: int, other: int, lo, hi):
+    """Square tiles: does tile ``i`` (of ``mine``) have its diagonal tile
+    among the ``other`` axis's tiles ``[lo, hi)`` of this grid step
+    (:func:`_chunk`'s: Python ints where they are all of them)?"""
+    return _all(*([i < other] if mine > other else [])
+                + ([] if isinstance(lo, int) else [i >= lo, i < hi]))
+
+
+def _part(x, axis: int, lo: int, n: int):
+    """``x[lo:lo + n]`` along ``axis`` of a 2-D value (all of it: ``x``)."""
+    if n == x.shape[axis]:
+        return x
+    start, stop = [0, 0], list(x.shape)
+    start[axis], stop[axis] = lo, lo + n
+    return lax.slice(x, start, stop)
+
+
+def _to_col(row):
+    """[1, n] -> [n, 1] (lanes to sublanes), through a whole-tile transpose."""
+    n = row.shape[1]
+    full = lax.broadcast_in_dim(row, (_LANES, n), (0, 1))
+    return _part(lax.transpose(full, (1, 0)), 1, 0, 1)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(q_ref, k_ref, v_ref, slope_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, scale, causal, alibi, block_q,
-                block_k, nk):
-    kb = pl.program_id(2)
+def _fwd_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, scale, causal, alibi, bq, bk, nq,
+                nk, tiles, hb):
+    g, i, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    fold, square = _folds(scale), causal and bq == bk
+    # this step's chunk of KV tiles, and what of it Q tile i visits (worked
+    # out only where a loop needs it: scalar arithmetic is traced too)
+    lo, hi, first, last = _chunk(c, tiles, nk)
+    has_plain = not causal or _kv_bounds(nq - 1, bq, bk, nk, True)[0] > 0
+    if has_plain or not square:
+        plain, visit = _kv_bounds(i, bq, bk, nk, causal)
+        if tiles < nk:
+            plain, visit = _clip(plain, lo, hi), _clip(visit, lo, hi)
+    bodies, sk = _bodies(bq, bk, causal, alibi, kv_dim=0), _strip(bk)
 
-    @pl.when(kb == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def head(h, carry):
+        @_when(first)
+        def _init():
+            m_scr[h] = jnp.full(m_scr.shape[1:], NEG_INF, jnp.float32)
+            l_scr[h] = jnp.zeros(l_scr.shape[1:], jnp.float32)
+            acc_scr[h] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
 
-    qb = pl.program_id(1)
-    q_start = qb * block_q
-    k_start = kb * block_k
-
-    run = True
-    if causal:
-        # whole KV block strictly above the diagonal -> nothing to do
-        run = k_start <= q_start + block_q - 1
-
-    @pl.when(run)
-    def _compute():
         # MXU matmuls take the native (bf16) operands; only the accumulator
         # and softmax statistics are fp32 — fp32 MXU inputs would quarter
         # throughput for no accuracy gain over fp32 accumulation.
-        q = q_ref[0]  # [BQ, D]
-        k = k_ref[0]  # [BK, D]
-        v = v_ref[0]  # [BK, D]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale  # [BQ, BK]
-        if causal or alibi:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if alibi:
-            s = s + slope_ref[0, 0, 0] * (cols - rows).astype(jnp.float32)
-        if causal:
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        m_prev = m_scr[:]                              # [BQ, 1]
-        m_cur = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new)                         # [BQ, BK]
-        alpha = jnp.exp(m_prev - m_new)                # [BQ, 1]
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        q = q_ref[h]                                       # [bq, D]
+        if fold:
+            q = q * scale
+        slope = slope_ref[g * hb + h] if alibi else None
 
-    @pl.when(kb == nk - 1)
-    def _finish():
-        l = l_scr[:]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
-        # lse layout (BH, S, 1): the in-kernel block is the (bq, 1) column
-        # vector itself — no relayout needed (see module docstring).
-        lse_ref[0] = m_scr[:] + jnp.log(safe_l)
+        def tile(j, masked):
+            # a masked body is a tile, a plain one a strip of a tile; on the
+            # diagonal of square tiles the two first positions are equal
+            step = bk if masked else sk
+            ks = j * step - lo * bk
+            d = 0 if masked and square else i * bq - j * step
+            for kv_lo, kv_n, q_lo, q_n, rel in bodies[masked]:
+                rows = pl.ds(pl.multiple_of(ks + kv_lo, kv_n), kv_n)
+                lanes = slice(q_lo, q_lo + q_n)
+                k, v = k_ref[h, rows, :], v_ref[h, rows, :]    # [kv_n, D]
+                # the scores transposed (KV positions on sublanes, queries
+                # on lanes): max and sum over KV run down the sublanes,
+                # elementwise, and m, l are lane-dense rows
+                st = _dot(k, _part(q, 0, q_lo, q_n), _NT)
+                if not fold:
+                    st = st * scale
+                st = _bias_and_mask(st, rel, slope, d + q_lo - kv_lo, masked)
+                m_prev = m_scr[h, :, lanes]                    # [1, q_n]
+                m_new = lax.max(m_prev, lax.expand_dims(
+                    lax.reduce_max(st, (0,)), (0,)))
+                pt = lax.exp(lax.sub(st, _bc(m_new, st)))      # [kv_n, q_n]
+                alpha = lax.exp(lax.sub(m_prev, m_new))
+                l_scr[h, :, lanes] = lax.add(
+                    lax.mul(alpha, l_scr[h, :, lanes]),
+                    lax.expand_dims(lax.reduce_sum(pt, (0,)), (0,)))
+                acc = acc_scr[h, :, lanes]                     # [D, q_n]
+                acc_scr[h, :, lanes] = lax.add(
+                    lax.mul(acc, _bc(alpha, acc)),
+                    _dot(v, lax.convert_element_type(pt, v.dtype), _TN))
+                m_scr[h, :, lanes] = m_new
+
+        if has_plain:
+            _run(lo * (bk // sk), plain * (bk // sk), tile, False)
+        if square:
+            _when(_diagonal_here(i, nq, nk, lo, hi))(lambda: tile(i, True))
+        elif causal:
+            _run(plain, visit, tile, True)
+
+        @_when(last)
+        def _finish():
+            l = l_scr[h]
+            safe_l = jnp.where(l == 0.0, 1.0, l)
+            o_ref[h] = jnp.transpose(acc_scr[h] / safe_l).astype(o_ref.dtype)
+            lse_ref[h, 0] = m_scr[h] + jnp.log(safe_l)
+
+        return carry
+
+    lax.fori_loop(0, hb, head, 0)
 
 
 def _head_slopes(B: int, H: int, alibi: bool):
-    """[B*H, 1, 1] per-grid-row ALiBi slopes (zeros when off — the argument
-    shape must be static for the shared kernel signature).  3-D so the
-    block's LAST TWO dims are full-size: Mosaic requires partial block dims
-    in the last two positions to be (8, 128)-tile aligned."""
+    """[B*H] per-head ALiBi slopes, read from SMEM by head index (zeros when
+    off — the argument is static for the shared kernel signature)."""
     if not alibi:
-        return jnp.zeros((B * H, 1, 1), jnp.float32)
+        return jnp.zeros((B * H,), jnp.float32)
     from deepspeed_tpu.models.layers import alibi_slopes
 
-    return jnp.tile(alibi_slopes(H), B).reshape(B * H, 1, 1)
+    return jnp.tile(alibi_slopes(H), B).astype(jnp.float32)
+
+
+_SLOPES = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _chunk_map(bound, tiles: int, n_chunks: int, last: bool):
+    """Index map of the [hb, chunk, D] blocks a kernel's loops walk, for grid
+    step (heads g, tile t, chunk c): chunk c, or, where the causal mask
+    removes all of it, the nearest chunk tile t does need, so the block index
+    repeats and nothing is fetched.  ``bound(t)`` is one past the last
+    needed tile when ``last``, the first needed one otherwise."""
+    def index(g, t, c):
+        if n_chunks == 1:
+            return g, 0, 0
+        if last:
+            return g, _clip(c, 0, _clip((bound(t) - 1) // tiles, 0,
+                                        n_chunks - 1)), 0
+        return g, _clip(c, _clip(bound(t) // tiles, 0, n_chunks - 1),
+                        n_chunks - 1), 0
+
+    return index
 
 
 def _flash_fwd(q, k, v, causal, alibi, scale, block_q, block_k, interpret):
     B, H, S, D = q.shape
     Sk = k.shape[2]
-    bq = pick_block(S, block_q, minimum=8)
-    bk = pick_block(Sk, block_k, minimum=8)
-    nq, nk = S // bq, Sk // bk
     BH = B * H
-    q3 = q.reshape(BH, S, D)
-    k3 = k.reshape(BH, Sk, D)
-    v3 = v.reshape(BH, Sk, D)
+    p = _plan(BH, S, Sk, D, q.dtype.itemsize, block_q, block_k)
+    bq, bk, hb = p.bq, p.bk, p.hb_fwd
+    nq, nk, tiles, nc = S // bq, Sk // bk, p.ck // bk, Sk // p.ck
+
+    kv_chunk = _chunk_map(lambda i: _kv_bounds(i, bq, bk, nk, causal)[1],
+                          tiles, nc, last=True)
+
     kernel = functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                               alibi=alibi, block_q=bq, block_k=bk, nk=nk)
+                               alibi=alibi, bq=bq, bk=bk, nq=nq, nk=nk,
+                               tiles=tiles, hb=hb)
     o, lse = pl.pallas_call(
         kernel,
-        grid=(BH, nq, nk),
-        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-                  pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-                  pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-                  pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-                   pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))],
+        grid=(BH // hb, nq, nc),
+        in_specs=[_SLOPES,
+                  pl.BlockSpec((hb, bq, D), lambda g, i, c: (g, i, 0)),
+                  pl.BlockSpec((hb, p.ck, D), kv_chunk),
+                  pl.BlockSpec((hb, p.ck, D), kv_chunk)],
+        out_specs=[pl.BlockSpec((hb, bq, D), lambda g, i, c: (g, i, 0)),
+                   pl.BlockSpec((hb, 1, 1, bq), lambda g, i, c: (g, i, 0, 0))],
         out_shape=[jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-                   jax.ShapeDtypeStruct((BH, S, 1), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, 1), jnp.float32),
-                        pltpu.VMEM((bq, D), jnp.float32)],
+                   # lane-dense rows, a Q tile each: an [S, 1] column would
+                   # be padded to 128 lanes in HBM and in every block
+                   jax.ShapeDtypeStruct((BH, nq, 1, bq), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, 1, bq), jnp.float32),
+                        pltpu.VMEM((hb, 1, bq), jnp.float32),
+                        pltpu.VMEM((hb, D, bq), jnp.float32)],
         interpret=interpret,
         name="flash_attention_fwd",
-    )(q3, k3, v3, _head_slopes(B, H, alibi))
+    )(_head_slopes(B, H, alibi), q.reshape(BH, S, D), k.reshape(BH, Sk, D),
+      v.reshape(BH, Sk, D))
     return o.reshape(B, H, S, D), lse.reshape(B, H, S)
-
-
-def _col(x_ref):
-    """Read a (1, bq, 1) stat block as a (bq, 1) column."""
-    return x_ref[0]
 
 
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, slope_ref,
-                   dq_ref, dq_scr, *, scale, causal, alibi, block_q, block_k,
-                   nk):
-    kb = pl.program_id(2)
+def _bwd_dq_kernel(slope_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                   dq_ref, dq_scr, *, scale, causal, alibi, bq, bk, nq, nk,
+                   tiles, hb):
+    g, i, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    fold, square = _folds(scale), causal and bq == bk
+    lo, hi, first, last = _chunk(c, tiles, nk)
+    has_plain = not causal or _kv_bounds(nq - 1, bq, bk, nk, True)[0] > 0
+    if has_plain or not square:
+        plain, visit = _kv_bounds(i, bq, bk, nk, causal)
+        if tiles < nk:
+            plain, visit = _clip(plain, lo, hi), _clip(visit, lo, hi)
+    bodies, sk = _bodies(bq, bk, causal, alibi, kv_dim=1), _strip(bk)
 
-    @pl.when(kb == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
+    def head(h, carry):
+        @_when(first)
+        def _init():
+            dq_scr[h] = jnp.zeros(dq_scr.shape[1:], jnp.float32)
 
-    q_start = pl.program_id(1) * block_q
-    k_start = kb * block_k
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
+        q = q_ref[h]
+        if fold:
+            q = q * scale
+        do = do_ref[h]
+        lse = _to_col(lse_ref[h, 0])                       # [bq, 1]
+        delta = _to_col(delta_ref[h, 0])
+        slope = slope_ref[g * hb + h] if alibi else None
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal or alibi:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if alibi:
-            s = s + slope_ref[0, 0, 0] * (cols - rows).astype(jnp.float32)
-        if causal:
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(k.dtype)
-        dq_scr[:] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        def tile(j, masked):
+            step = bk if masked else sk
+            ks = j * step - lo * bk
+            d = 0 if masked and square else i * bq - j * step
+            for kv_lo, kv_n, q_lo, q_n, rel in bodies[masked]:
+                cols = pl.ds(pl.multiple_of(ks + kv_lo, kv_n), kv_n)
+                rows = slice(q_lo, q_lo + q_n)
+                k, v = k_ref[h, cols, :], v_ref[h, cols, :]
+                s = _dot(_part(q, 0, q_lo, q_n), k, _NT)       # [q_n, kv_n]
+                if not fold:
+                    s = s * scale
+                s = _bias_and_mask(s, rel, slope, d + q_lo - kv_lo, masked)
+                p = lax.exp(lax.sub(s, _bc(_part(lse, 0, q_lo, q_n), s)))
+                dp = _dot(_part(do, 0, q_lo, q_n), v, _NT)
+                ds = lax.mul(p, lax.sub(
+                    dp, _bc(_part(delta, 0, q_lo, q_n), dp)))
+                if not fold:
+                    ds = ds * scale
+                dq_scr[h, rows] = lax.add(
+                    dq_scr[h, rows],
+                    _dot(lax.convert_element_type(ds, k.dtype), k, _NN))
 
-    @pl.when(kb == nk - 1)
-    def _finish():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+        if has_plain:
+            _run(lo * (bk // sk), plain * (bk // sk), tile, False)
+        if square:
+            _when(_diagonal_here(i, nq, nk, lo, hi))(lambda: tile(i, True))
+        elif causal:
+            _run(plain, visit, tile, True)
+
+        @_when(last)
+        def _finish():
+            dq = dq_scr[h]
+            dq_ref[h] = (dq * scale if fold else dq).astype(dq_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, hb, head, 0)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    slope_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
-                    causal, alibi, block_q, block_k, nq):
-    qb = pl.program_id(2)
+def _bwd_dkv_kernel(slope_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                    dk_ref, dv_ref, dk_scr, dv_scr, *, scale, causal, alibi,
+                    bq, bk, nq, nk, tiles, hb):
+    g, j, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    fold, square = _folds(scale), causal and bq == bk
+    # this step's chunk of Q tiles, and what of it KV tile j is seen by
+    lo, hi, first, last = _chunk(c, tiles, nq)
+    has_plain = not causal or _q_bounds(0, bq, bk, nq, True)[1] < nq
+    if has_plain or not square:
+        crossed, plain = _q_bounds(j, bq, bk, nq, causal)
+        if tiles < nq:
+            crossed, plain = _clip(crossed, lo, hi), _clip(plain, lo, hi)
+    bodies = _bodies(bq, bk, causal, alibi, kv_dim=0, by_q=True)
+    sq = _strip(bq)
 
-    @pl.when(qb == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
+    def head(h, carry):
+        @_when(first)
+        def _init():
+            dk_scr[h] = jnp.zeros(dk_scr.shape[1:], jnp.float32)
+            dv_scr[h] = jnp.zeros(dv_scr.shape[1:], jnp.float32)
 
-    q_start = qb * block_q
-    k_start = pl.program_id(1) * block_k
-    run = True
-    if causal:
-        # whole Q block strictly left of the diagonal -> no grad flows here
-        run = q_start + block_q - 1 >= k_start
+        k = k_ref[h]                                       # [bk, D]
+        if fold:
+            k = k * scale
+        v = v_ref[h]
+        slope = slope_ref[g * hb + h] if alibi else None
 
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]
-        delta = delta_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        if causal or alibi:
-            rows = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            cols = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        if alibi:
-            s = s + slope_ref[0, 0, 0] * (cols - rows).astype(jnp.float32)
-        if causal:
-            s = jnp.where(rows >= cols, s, NEG_INF)
-        p = jnp.exp(s - lse)                                     # [BQ, BK]
-        dv_scr[:] += jax.lax.dot_general(p.astype(do.dtype), do,
-                                         (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta) * scale).astype(q.dtype)          # [BQ, BK]
-        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
+        def tile(i, masked):
+            # a masked body is a tile, a plain one a strip of a tile; lse
+            # and delta come as one lane-dense row a strip
+            step = bq if masked else sq
+            qs = i * step - lo * bq
+            d = 0 if masked and square else i * step - j * bk
+            strip0 = i * (step // sq) - lo * (bq // sq)
+            lse_row, delta_row = (
+                ref[h, strip0] if step == sq else lax.concatenate(
+                    [ref[h, strip0 + u] for u in range(step // sq)], 1)
+                for ref in (lse_ref, delta_ref))
+            for kv_lo, kv_n, q_lo, q_n, rel in bodies[masked]:
+                at = pl.ds(pl.multiple_of(qs + q_lo, q_n), q_n)
+                q, do = q_ref[h, at, :], do_ref[h, at, :]      # [q_n, D]
+                rows = slice(kv_lo, kv_lo + kv_n)
+                lse, delta = (_part(t, 1, q_lo, q_n)
+                              for t in (lse_row, delta_row))   # [1, q_n]
+                st = _dot(_part(k, 0, kv_lo, kv_n), q, _NT)    # [kv_n, q_n]
+                if not fold:
+                    st = st * scale
+                st = _bias_and_mask(st, rel, slope, d + q_lo - kv_lo, masked)
+                pt = lax.exp(lax.sub(st, _bc(lse, st)))
+                dv_scr[h, rows] = lax.add(
+                    dv_scr[h, rows],
+                    _dot(lax.convert_element_type(pt, do.dtype), do, _NN))
+                dpt = _dot(_part(v, 0, kv_lo, kv_n), do, _NT)
+                dst = lax.mul(pt, lax.sub(dpt, _bc(delta, dpt)))
+                if not fold:
+                    dst = dst * scale
+                dk_scr[h, rows] = lax.add(
+                    dk_scr[h, rows],
+                    _dot(lax.convert_element_type(dst, q.dtype), q, _NN))
 
-    @pl.when(qb == nq - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+        if square:
+            _when(_diagonal_here(j, nk, nq, lo, hi))(lambda: tile(j, True))
+        elif causal:
+            _run(crossed, plain, tile, True)
+        if has_plain:
+            _run(plain * (bq // sq), hi * (bq // sq), tile, False)
+
+        @_when(last)
+        def _finish():
+            dk = dk_scr[h]
+            dk_ref[h] = (dk * scale if fold else dk).astype(dk_ref.dtype)
+            dv_ref[h] = dv_scr[h].astype(dv_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, hb, head, 0)
 
 
 def _flash_bwd(res, g, causal, alibi, scale, block_q, block_k, interpret):
     q, k, v, o, lse = res
     B, H, S, D = q.shape
     Sk = k.shape[2]
-    bq = pick_block(S, block_q, minimum=8)
-    bk = pick_block(Sk, block_k, minimum=8)
-    nq, nk = S // bq, Sk // bk
     BH = B * H
+    p = _plan(BH, S, Sk, D, q.dtype.itemsize, block_q, block_k)
+    bq, bk = p.bq, p.bk
+    nq, nk = S // bq, Sk // bk
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)  # [B,H,S]
     q3, k3, v3 = (t.reshape(BH, -1, D) for t in (q, k, v))
     do3 = g.reshape(BH, S, D)
-    lse3 = lse.reshape(BH, S, 1)
-    delta3 = delta.reshape(BH, S, 1)
+    # lane-dense rows of statistics: a Q tile each for the dQ kernel, a
+    # strip each for the dK/dV kernel's loop (two views of the same bytes)
+    sq = _strip(bq)
+    lse4, delta4 = (t.reshape(BH, nq, 1, bq) for t in (lse, delta))
+    lse_s, delta_s = (t.reshape(BH, S // sq, 1, sq) for t in (lse, delta))
     slopes = _head_slopes(B, H, alibi)
-    slope_spec = pl.BlockSpec((1, 1, 1), lambda b, i, j: (b, 0, 0))
 
+    hb, tiles, nc = p.hb_dq, p.ck // bk, Sk // p.ck
+
+    kv_chunk = _chunk_map(lambda i: _kv_bounds(i, bq, bk, nk, causal)[1],
+                          tiles, nc, last=True)
+
+    q_tile = pl.BlockSpec((hb, bq, D), lambda g_, i, c: (g_, i, 0))
+    stat_tile = pl.BlockSpec((hb, 1, 1, bq), lambda g_, i, c: (g_, i, 0, 0))
     dq_kernel = functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
-                                  alibi=alibi, block_q=bq, block_k=bk, nk=nk)
+                                  alibi=alibi, bq=bq, bk=bk, nq=nq, nk=nk,
+                                  tiles=tiles, hb=hb)
     dq = pl.pallas_call(
         dq_kernel,
-        grid=(BH, nq, nk),
-        in_specs=[pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-                  pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-                  pl.BlockSpec((1, bk, D), lambda b, i, j: (b, j, 0)),
-                  pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
-                  pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-                  pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0)),
-                  slope_spec],
-        out_specs=pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0)),
+        grid=(BH // hb, nq, nc),
+        in_specs=[_SLOPES, q_tile,
+                  pl.BlockSpec((hb, p.ck, D), kv_chunk),
+                  pl.BlockSpec((hb, p.ck, D), kv_chunk),
+                  q_tile, stat_tile, stat_tile],
+        out_specs=q_tile,
         out_shape=jax.ShapeDtypeStruct((BH, S, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, bq, D), jnp.float32)],
         interpret=interpret,
         name="flash_attention_bwd_dq",
-    )(q3, k3, v3, do3, lse3, delta3, slopes)
+    )(slopes, q3, k3, v3, do3, lse4, delta4)
 
+    hb, tiles, nc = p.hb_dkv, p.cq // bq, S // p.cq
+
+    q_chunk = _chunk_map(lambda j: _q_bounds(j, bq, bk, nq, causal)[0],
+                         tiles, nc, last=False)
+
+    def stat_chunk(g_, j, c):
+        return q_chunk(g_, j, c) + (0,)
+
+    kv_tile = pl.BlockSpec((hb, bk, D), lambda g_, j, c: (g_, j, 0))
     dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
-                                   alibi=alibi, block_q=bq, block_k=bk, nq=nq)
+                                   alibi=alibi, bq=bq, bk=bk, nq=nq, nk=nk,
+                                   tiles=tiles, hb=hb)
     dk, dv = pl.pallas_call(
         dkv_kernel,
-        grid=(BH, nk, nq),
-        in_specs=[pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-                  pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                  pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                  pl.BlockSpec((1, bq, D), lambda b, j, i: (b, i, 0)),
-                  pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-                  pl.BlockSpec((1, bq, 1), lambda b, j, i: (b, i, 0)),
-                  pl.BlockSpec((1, 1, 1), lambda b, j, i: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0)),
-                   pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))],
+        grid=(BH // hb, nk, nc),
+        in_specs=[_SLOPES,
+                  pl.BlockSpec((hb, p.cq, D), q_chunk), kv_tile, kv_tile,
+                  pl.BlockSpec((hb, p.cq, D), q_chunk),
+                  pl.BlockSpec((hb, p.cq // sq, 1, sq), stat_chunk),
+                  pl.BlockSpec((hb, p.cq // sq, 1, sq), stat_chunk)],
+        out_specs=[kv_tile, kv_tile],
         out_shape=[jax.ShapeDtypeStruct((BH, Sk, D), k.dtype),
                    jax.ShapeDtypeStruct((BH, Sk, D), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, bk, D), jnp.float32),
+                        pltpu.VMEM((hb, bk, D), jnp.float32)],
         interpret=interpret,
         name="flash_attention_bwd_dkv",
-    )(q3, k3, v3, do3, lse3, delta3, slopes)
+    )(slopes, q3, k3, v3, do3, lse_s, delta_s)
     return (dq.reshape(B, H, S, D), dk.reshape(B, H, Sk, D), dv.reshape(B, H, Sk, D))
 
 

@@ -2,6 +2,9 @@
 reference (SURVEY.md §4 implication (b)), plus gradient checks via custom VJP.
 """
 
+import importlib
+import itertools
+
 import numpy as np
 import pytest
 
@@ -11,6 +14,10 @@ import jax.numpy as jnp
 from deepspeed_tpu.ops.pallas import (apply_rotary_pos_emb, bias_act, flash_attention,
                                       fused_adam_update, layer_norm, mha_reference,
                                       rms_norm, rope_angles, scaled_masked_softmax)
+
+
+# the module, not the function of the same name the package exports
+flash_module = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
 
 
 def rand(*shape, dtype=jnp.float32, seed=0):
@@ -169,6 +176,179 @@ class TestFlashAttention:
         for g, r in zip(got, ref):
             np.testing.assert_allclose(np.asarray(g), np.asarray(r),
                                        rtol=5e-3, atol=5e-4)
+
+
+# One case per branch of the tile schedule.  (B, H, S, Sk, D), the limits
+# handed to block_q / block_k, then what differs from causal / default scale.
+SCHEDULE_CASES = {
+    # four diagonal tiles of 64, three Q tiles with plain tiles before them
+    "several-diagonal-tiles": ((1, 2, 256, 256, 64), (64, 64), {}),
+    "one-tile": ((1, 2, 128, 128, 64), (1024, 1024), {}),
+    # B*H = 5: the heads a grid step takes divide B*H, not H
+    "five-heads": ((1, 5, 256, 256, 64), (64, 64), {}),
+    "head-dim-128": ((1, 2, 256, 256, 128), (64, 64), {}),
+    "non-causal": ((1, 2, 256, 256, 64), (64, 64), {"causal": False}),
+    "alibi": ((2, 3, 256, 256, 32), (64, 64), {"alibi": True}),
+    "cross-length": ((1, 2, 128, 256, 64), (64, 64), {"causal": False}),
+    # a diagonal tile of 512 cut into two strips, the second against the
+    # upper half of the queries only
+    "diagonal-strips": ((1, 2, 512, 512, 64), (512, 512), {}),
+    "diagonal-strips-alibi": ((1, 2, 512, 512, 64), (512, 512),
+                              {"alibi": True, "sm_scale": 0.2}),
+    # ... and the tile under the diagonal walked strip by strip
+    "plain-strips": ((1, 1, 1024, 1024, 64), (512, 512), {}),
+    "plain-strips-non-causal": ((1, 1, 512, 1024, 32), (512, 512),
+                                {"causal": False}),
+    # Q and KV tiles differ: the loop over the tiles the diagonal crosses
+    "wide-q-tiles": ((1, 2, 256, 256, 64), (128, 64), {}),
+    "wide-kv-tiles": ((1, 2, 256, 256, 64), (64, 128), {}),
+    # a scale that is no power of two stays on the scores
+    "scale-not-folded": ((1, 2, 256, 256, 64), (64, 64), {"sm_scale": 0.3}),
+    # K and V in chunks (a budget too small for the sequence), causal
+    # steps that skip the chunks the mask removes
+    "kv-chunks": ((1, 3, 512, 512, 64), (64, 64), {"budget": 300 * 1024}),
+    "kv-chunks-strips": ((1, 2, 1024, 1024, 64), (512, 512),
+                         {"budget": 800 * 1024}),
+}
+
+
+def assert_forward_and_gradients(kernel, reference, q, k, v):
+    np.testing.assert_allclose(np.asarray(kernel(q, k, v)),
+                               np.asarray(reference(q, k, v)),
+                               rtol=2e-4, atol=2e-4)
+    got = jax.grad(lambda *a: jnp.sum(kernel(*a) ** 2), (0, 1, 2))(q, k, v)
+    ref = jax.grad(lambda *a: jnp.sum(reference(*a) ** 2), (0, 1, 2))(q, k, v)
+    for g, r in zip(got, ref):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   rtol=5e-3, atol=5e-4)
+
+
+class TestFlashSchedule:
+    @pytest.mark.parametrize("case", sorted(SCHEDULE_CASES))
+    def test_parity_forward_and_gradients(self, case, monkeypatch):
+        (B, H, S, Sk, D), (bq, bk), opts = SCHEDULE_CASES[case]
+        causal, alibi = opts.get("causal", True), opts.get("alibi", False)
+        scale = opts.get("sm_scale")
+        if "budget" in opts:
+            monkeypatch.setattr(flash_module, "_VMEM_BLOCK_BYTES", opts["budget"])
+            plan = flash_module._plan(B * H, S, Sk, D, 4, bq, bk)
+            assert plan.ck < Sk and plan.cq < S
+        q = rand(B, H, S, D, seed=1)
+        k, v = rand(B, H, Sk, D, seed=2), rand(B, H, Sk, D, seed=3)
+        bias = None
+        if alibi:
+            from deepspeed_tpu.models.layers import alibi_bias
+
+            bias = alibi_bias(H, jnp.arange(S), jnp.arange(Sk))[None]
+
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, causal, scale, bq, bk,
+                                   "interpret", alibi)
+
+        def reference(q, k, v):
+            return mha_reference(q, k, v, causal=causal, sm_scale=scale,
+                                 bias=bias)
+
+        assert_forward_and_gradients(kernel, reference, q, k, v)
+
+    @pytest.mark.parametrize("S,Sk", [(512, 256), (256, 512)])
+    def test_causal_cross_length_keeps_row_not_before_column(self, S, Sk):
+        """Causal with S != Sk: the kernels keep ``row >= column`` counted
+        from the first row and column (as before this schedule), whichever
+        of the two is longer."""
+        q = rand(1, 2, S, 64, seed=1)
+        k, v = rand(1, 2, Sk, 64, seed=2), rand(1, 2, Sk, 64, seed=3)
+        keep = jnp.arange(S)[:, None] >= jnp.arange(Sk)[None, :]
+        bias = jnp.where(keep, 0.0, -1e30)[None, None]
+
+        def kernel(q, k, v):
+            return flash_attention(q, k, v, True, None, 128, 128, "interpret")
+
+        def reference(q, k, v):
+            return mha_reference(q, k, v, causal=False, bias=bias)
+
+        assert_forward_and_gradients(kernel, reference, q, k, v)
+
+    @pytest.mark.parametrize("S,tile", [(256, 64), (1024, 128), (1024, 512),
+                                        (1024, 1024), (4096, 1024)])
+    def test_counts_of_square_tiles(self, S, tile):
+        """n (n + 1) / 2 of the n^2 tiles visited, n of them masked, and no
+        visited tile wholly above the diagonal."""
+        sch = flash_module.tile_schedule(S, S, tile, tile, True)
+        n = S // tile
+        assert (sch["block_q"], sch["block_k"]) == (tile, tile)
+        assert sch["tiles"] == n * n
+        assert sch["visited"] == n * (n + 1) // 2 and sch["masked"] == n
+        assert sch["masked_pairs"] == [(i, i) for i in range(n)]
+        for i, j in sch["visited_pairs"]:
+            assert j * tile <= (i + 1) * tile - 1
+        # a diagonal tile's strips: what is computed beyond the kept half
+        # is one strip wide, not one tile
+        strip = sch["strip"]
+        assert tile % strip == 0 and strip <= 256
+        assert sch["kept_elements"] == S * (S + 1) // 2
+        assert sch["score_elements"] == (
+            n * (n - 1) // 2 * tile * tile
+            + n * strip * sum(tile - r for r in range(0, tile, strip)))
+        assert sch["score_elements"] - sch["kept_elements"] <= S * (strip + 1) // 2
+
+    def test_non_causal_visits_the_square(self):
+        sch = flash_module.tile_schedule(256, 512, 64, 64, False)
+        assert sch["visited"] == sch["tiles"] == 4 * 8 and sch["masked"] == 0
+        assert sch["score_elements"] == sch["kept_elements"] == 256 * 512
+
+    @pytest.mark.parametrize("bq,bk", [(64, 64), (128, 64), (64, 128),
+                                       (192, 64), (64, 192), (128, 384)])
+    @pytest.mark.parametrize("S,Sk", [(384, 384), (768, 384), (384, 768)])
+    def test_bounds_against_the_mask_itself(self, S, Sk, bq, bk):
+        """The loops' bounds, for any pair of tile sizes and lengths, are
+        what the mask ``row >= column`` gives element by element: a tile is
+        visited iff it keeps something, plain iff it keeps everything; the
+        dK/dV kernel's bounds are the same sets seen from the KV tile."""
+        keep = np.arange(S)[:, None] >= np.arange(Sk)[None, :]
+        nq, nk = S // bq, Sk // bk
+        some = np.zeros((nq, nk), bool)
+        every = np.zeros((nq, nk), bool)
+        for i, j in itertools.product(range(nq), range(nk)):
+            block = keep[i * bq:(i + 1) * bq, j * bk:(j + 1) * bk]
+            some[i, j], every[i, j] = block.any(), block.all()
+        for i in range(nq):
+            plain, visit = flash_module._kv_bounds(i, bq, bk, nk, True)
+            assert [j for j in range(nk) if every[i, j]] == list(range(plain))
+            assert [j for j in range(nk) if some[i, j]] == list(range(visit))
+        for j in range(nk):
+            first, plain = flash_module._q_bounds(j, bq, bk, nq, True)
+            assert [i for i in range(nq) if some[i, j]] == list(range(first, nq))
+            assert [i for i in range(nq) if every[i, j]] == list(range(plain, nq))
+
+    def test_heads_a_step_divide_all_heads(self):
+        """25 heads a layer is odd: the heads of a grid step divide B*H."""
+        sch = flash_module.tile_schedule(1024, 1024, head_dim=64, heads=400)
+        assert (sch["block_q"], sch["block_k"], sch["strip"]) == (1024, 1024, 256)
+        assert (sch["visited"], sch["masked"]) == (1, 1)
+        assert sch["score_elements"] == 655360      # of 1,048,576; 524,800 kept
+        for kernel, hb in sch["heads_per_step"].items():
+            assert 400 % hb == 0
+            assert sch["grid_steps"][kernel] == 400 // hb
+        odd = flash_module.tile_schedule(256, 256, 64, 64, heads=5)
+        assert set(odd["heads_per_step"].values()) <= {1, 5}
+
+    @pytest.mark.parametrize("tile,strip", [(1024, 256), (896, 128), (640, 128),
+                                            (512, 256), (128, 128), (64, 64),
+                                            (1000, 200)])
+    def test_strips_of_a_diagonal_tile(self, tile, strip):
+        """Strips divide the tile; where they are whole lane tiles, each
+        starts its queries at its own first row; together they cover every
+        kept score of the tile."""
+        blocks = flash_module._strips(tile, tile, True)
+        assert flash_module._strip(tile) == strip
+        covered = np.zeros((tile, tile), bool)          # [kv, q]
+        for kv_lo, kv_n, q_lo, q_n in blocks:
+            assert kv_n == strip
+            assert q_lo == (kv_lo if strip % 128 == 0 else 0)
+            covered[kv_lo:kv_lo + kv_n, q_lo:q_lo + q_n] = True
+        keep = np.arange(tile)[:, None] <= np.arange(tile)[None, :]
+        assert not (keep & ~covered).any()
 
 
 class TestSoftmax:

@@ -11,6 +11,7 @@ results (the interpret-mode parity tests do) or times.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -239,6 +240,48 @@ KERNELS = {
 def test_kernel_compiles_for_v5e(v5e, kernel, widths):
     fn, shapes, want = KERNELS[kernel](WIDTHS[widths])
     assert _custom_calls(fn, v5e, *shapes) >= want
+
+
+def test_flash_attention_at_the_train_cell_shape(v5e):
+    """``jax.grad`` of ``flash_attention`` at the per-chip shape of
+    ``gpt2-xl.train-zero3`` ([16, 25, 1024, 64], bf16): exactly the three
+    kernels the benchmark's reader finds by name, each reading q, k, v and
+    dO as views of what the program was handed (PR 30 found an output of
+    ``bwd_dq`` leaving fast memory through an added copy), and the softmax
+    statistics in lane-dense rows (an [S, 1] column is padded to 128 lanes
+    in HBM: 210 MB an array at this shape)."""
+    arg = jax.ShapeDtypeStruct((16, 25, 1024, 64), BF16, sharding=v5e)
+
+    def loss(q, k, v, w):
+        o = flash_attention(q, k, v, causal=True, impl="pallas")
+        return (o.astype(F32) * w.astype(F32)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        arg, arg, arg, arg).compile().as_text()
+    calls = {}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            name = re.search(r"\((flash_attention_\w+?)\)+/pallas_call", line)
+            calls[name.group(1) if name else line[:40]] = line
+    assert sorted(calls) == ["flash_attention_bwd_dkv", "flash_attention_bwd_dq",
+                             "flash_attention_fwd"]
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+    # instruction name -> (result, opcode) of everything in the module
+    made = {m.group(1): (m.group(2), m.group(3)) for m in re.finditer(
+        r"(%[\w.\-]+) = (\(?[\w\[\],{}:()\s]*?)\s([\w\-]+)\(", text)}
+    for name, line in calls.items():
+        operands = re.search(r"custom-call\(([^)]*)\)", line).group(1)
+        wide = [op for op in re.findall(r"%[\w.\-]+", operands)
+                if made[op][0].startswith("bf16[400,1024,64]")]
+        assert len(wide) == (3 if name == "flash_attention_fwd" else 4)
+        for op in wide:
+            assert made[op][1] == "bitcast", (name, op, made[op])
+    # one layout conversion per argument and per gradient, the float32
+    # cast of w, and nothing else: no copy or transpose made for a kernel
+    moved = re.findall(r" = \S+ (?:copy|transpose)\(", text)
+    assert len(moved) <= 4 + 3 + 1, moved
+    assert "f32[400,1024,1]" not in text
 
 
 # the benchmark's mistral-7b-L8.serve-chat cell: 64 slots, pages of 256, 4
