@@ -71,7 +71,12 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # kv_pool_tokens = total pool capacity in tokens (0 = num_slots *
     # per-slot budget — same HBM as the fixed layout; set it LOWER to
     # oversubscribe slots against a fixed HBM budget, backed by LIFO
-    # preempt-and-requeue when the pool runs dry).
+    # preempt-and-requeue when the pool runs dry).  For a model whose
+    # layers are of two kinds (ModelConfig.layer_types) the pool holds two
+    # page budgets (serving/paged_kv.py) and kv_pool_tokens is the FULL
+    # one: positions the global layers can hold over all slots; the
+    # sliding layers' window budget is num_slots rings of sliding_window
+    # rows, derived, so that an admitted slot can always have its ring.
     paged_kv_cache: bool = True
     kv_page_tokens: int = 0
     kv_pool_tokens: int = 0

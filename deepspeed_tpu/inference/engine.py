@@ -392,6 +392,12 @@ class InferenceEngine:
                 "attention='eva' is served through deepspeed_tpu.init_serving "
                 "(the paged pool holds its window and summary rows); "
                 "generate()'s contiguous cache has no layout for them")
+        if getattr(getattr(self.module, "config", None), "is_afmoe", False):
+            raise NotImplementedError(
+                "a layer_types model (models/afmoe.py) is served through "
+                "deepspeed_tpu.init_serving (the paged pool's two budgets "
+                "hold its rings and full pages); generate()'s contiguous "
+                "cache has no layout for them")
         with self._gen_lock:
             if self._generating:
                 raise RuntimeError(

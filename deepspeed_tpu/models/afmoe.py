@@ -1,0 +1,596 @@
+"""The AFMoE layer form (arcee-ai Trinity; ``ModelConfig.layer_types``):
+layers of two attention kinds and two MLP kinds from a static pattern,
+gated attention, a norm on both sides of each sub-block, a sigmoid
+bias-corrected router and ONE CHIP'S SHARE of the routed experts.
+
+With ``N(.)`` RMSNorm (own gain, ``norm_eps``) and ``x`` the stream:
+
+    x = E[tokens] * embed_scale
+    layer l, attention:
+        h = N_in(x); q, k, v, g = h Wq, h Wk, h Wv, h Wg
+        q, k = N_q(q), N_k(k)          per head, gains [head_dim]
+        "sliding_attention": RoPE on q and k, keys j with 0 <= i - j < W
+        "full_attention":    NO position encoding, every j <= i
+        a = (softmax(q k^T / sqrt(d)) v * sigmoid(g)) Wo
+        x = x + N_post_attn(a)
+    layer l, MLP:  h = N_pre_mlp(x)
+        l < num_dense_layers:  m = (silu(h Wgate) * (h Wup)) Wdown
+        else: s = sigmoid(h Wr) over the router's experts, float32
+              sel = top-k of (s + b)   (the bias picks, it does not weigh)
+              w = s[sel] / (sum s[sel] + 1e-20) * route_scale
+              m = shared(h) + sum over e in sel HELD HERE of w_e expert_e(h)
+        x = x + N_post_mlp(m)
+    logits = N_f(x) W_head
+
+Each unusual piece is ONE function here (:func:`head_norm_rope`,
+:func:`gated`, :func:`close`, :func:`route` / :func:`held`, :func:`mlp`,
+:func:`attend`) and the three forwards call them: ``CausalLM.apply``
+(:func:`apply_layers`, no cache), ``forward_with_cache`` (:func:`cached_layers`,
+a prefill chunk on a slot's gathered views) and ``decode_step``
+(:func:`fused_layers`, the Pallas kernels of ``ops/pallas/decode.py`` for the
+matmuls and the paged attention).  What differs between a sliding and a
+global layer is data of equal shape, so the pattern is a static Python loop
+over the layers; the parameters are two stacks because the MLP shapes differ:
+``dense_layers`` ``[num_dense_layers, ...]`` and ``layers`` ``[rest, ...]``.
+
+Cache: a sliding layer keeps a RING of exactly ``W = sliding_window`` rows,
+position ``p`` at row ``p % W`` (``serving/paged_kv.py``: window pages); a
+global layer keeps every position (full pages).  Decode appends its row and
+then attends the ring whole (query ``p`` overwrote ``p - W``, which it no
+longer sees).  A prefill chunk of ``c`` tokens would overwrite rows its own
+first queries still attend, so it ATTENDS BEFORE IT APPENDS: the ring as the
+previous chunks left it beside the chunk's own K and V, every key masked by
+its position, and only then the chunk's ``valid_len`` real rows go into the
+ring (a pad row written there would destroy a live one).
+
+The share: ``num_experts`` is the number HELD here, ``[moe_first_expert,
++ num_experts)`` of the router's ``moe_router_experts``.  A token's choice of
+an expert another chip holds is dropped, not imitated: the eight ranks'
+routed parts plus the shared expert once sum to the whole layer
+(tests/unit/test_trinity.py).
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from deepspeed_tpu.ops.pallas import rope_angles
+
+NEG_INF = -1e30
+F32 = jnp.float32
+KEY_BLOCK = 1024          # keys a step of :func:`attend`'s online softmax
+
+
+def is_sliding(cfg, l: int) -> bool:
+    return cfg.layer_types[l] == "sliding_attention"
+
+
+def kind_layers(cfg):
+    """(indices of the sliding layers, indices of the global layers)."""
+    L = range(cfg.num_layers)
+    return ([l for l in L if is_sliding(cfg, l)],
+            [l for l in L if not is_sliding(cfg, l)])
+
+
+def refuse_parallel(cfg, mesh, what: str) -> None:
+    if mesh is not None and not mesh.empty and any(
+            dict(mesh.shape).get(a, 1) > 1 for a in ("tp", "ep", "sp", "pp")):
+        raise NotImplementedError(
+            f"{what} with layer_types (models/afmoe.py) under tp, ep, sp or "
+            "pp > 1: the chip's share of the experts runs WITHOUT its "
+            "exchange, and the head norms, gate and ring cache are not split")
+
+
+# ----------------------------------------------------------------------
+# parameters
+# ----------------------------------------------------------------------
+def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
+    """Seeded weights: the repo's uniform init (not the release's
+    depth-scaled one), norm gains 1, the selection bias normal x 0.05 (the
+    release starts it at zero and trains it by its balancing rule; zeros
+    would leave the bias path untested, and at x 0.01 a bias wrongly used
+    as a weight moved the logits no further than bf16 does: PERF.md section
+    4, trinity-large-L5-ep8)."""
+    D, V = cfg.hidden_size, cfg.vocab_size
+    H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    Ld, Le = cfg.num_dense_layers, cfg.num_layers - cfg.num_dense_layers
+    E, R, F = cfg.num_experts, cfg.moe_router_experts, cfg.intermediate_size
+    keys = iter(jax.random.split(rng, 40))
+    uni = lambda shape, fan_in: jax.random.uniform(
+        next(keys), shape, dtype, -fan_in ** -0.5, fan_in ** -0.5)
+    ones = lambda *shape: {"scale": jnp.ones(shape, dtype)}
+
+    def stack(L, mlp):
+        attn = {"wq": uni((L, D, H * Dh), D), "wk": uni((L, D, Hkv * Dh), D),
+                "wv": uni((L, D, Hkv * Dh), D),
+                "wo": uni((L, H * Dh, D), H * Dh)}
+        if cfg.attn_output_gate:
+            attn["wg"] = uni((L, D, H * Dh), D)
+        if cfg.qk_norm_per_head:
+            attn.update(q_norm=ones(L, Dh), k_norm=ones(L, Dh))
+        out = {"attn_norm": ones(L, D), "mlp_norm": ones(L, D),
+               "attn": attn, "mlp": mlp}
+        if cfg.sandwich_norm:
+            out.update(attn_post_norm=ones(L, D), mlp_post_norm=ones(L, D))
+        return out
+
+    def glu(lead, width):
+        return {"w_up": uni(lead + (D, width), D),
+                "w_gate": uni(lead + (D, width), D),
+                "w_down": uni(lead + (width, D), width)}
+
+    params = {"embed": {"tok": jax.random.normal(next(keys), (V, D), dtype)
+                        * 0.02},
+              "final_norm": {"scale": jnp.ones((D,), dtype)},
+              "lm_head": jax.random.normal(next(keys), (D, V), dtype)
+              * D ** -0.5}
+    if Ld:
+        params["dense_layers"] = stack(
+            Ld, glu((Ld,), cfg.dense_intermediate_size))
+    if Le:
+        mlp = {"gate_w": uni((Le, D, R), D), **glu((Le, E), F)}
+        if cfg.moe_select_bias:
+            mlp["gate_bias"] = jax.random.normal(next(keys), (Le, R),
+                                                 dtype) * 0.05
+        if cfg.num_shared_experts:
+            mlp["shared"] = glu((Le,), F * cfg.num_shared_experts)
+        params["layers"] = stack(Le, mlp)
+    return params
+
+
+def logical_pspecs(cfg, params_like) -> Dict[str, Any]:
+    """Every leaf whole on its chip (:func:`refuse_parallel`)."""
+    return jax.tree.map(lambda a: P(*([None] * a.ndim)), params_like)
+
+
+def layer_params(cfg, params, l: int):
+    """Layer ``l``'s slice of its stack, and (expert layers) its index in
+    the stacked expert arrays, which stay whole."""
+    Ld = cfg.num_dense_layers
+    if l < Ld:
+        return jax.tree.map(lambda a: a[l], params["dense_layers"]), None
+    ly = params["layers"]
+    thin = {**ly, "mlp": {k: v for k, v in ly["mlp"].items()
+                          if k not in ("w_up", "w_gate", "w_down")}}
+    return jax.tree.map(lambda a: a[l - Ld], thin), l - Ld
+
+
+# ----------------------------------------------------------------------
+# the block forms
+# ----------------------------------------------------------------------
+def rms(x, scale, eps: float):
+    """RMSNorm over the last axis in float32, back in ``x``'s dtype."""
+    x32 = x.astype(F32)
+    var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps) * scale.astype(F32)).astype(x.dtype)
+
+
+def rope(t, cos, sin):
+    """Rotate-half RoPE over the whole head: ``t`` [..., H, Dh], ``cos`` /
+    ``sin`` broadcastable [..., 1, Dh/2]."""
+    half = t.shape[-1] // 2
+    t1, t2 = t[..., :half].astype(F32), t[..., half:].astype(F32)
+    return jnp.concatenate([t1 * cos - t2 * sin, t2 * cos + t1 * sin],
+                           axis=-1).astype(t.dtype)
+
+
+def angles(cfg, positions):
+    """cos, sin [..., 1, Dh/2] float32 for integer ``positions`` [...]."""
+    cos, sin = rope_angles(positions.reshape(-1), cfg.head_dim,
+                           theta=cfg.rope_theta)
+    shape = positions.shape + (1, cfg.head_dim // 2)
+    return cos.reshape(shape), sin.reshape(shape)
+
+
+def head_norm_rope(cfg, q_norm, k_norm, q, k, cos, sin, sliding: bool):
+    """q [..., H, Dh], k [..., Hkv, Dh]: the per-head norms, then RoPE on a
+    sliding layer and NOTHING on a global one."""
+    if cfg.qk_norm_per_head:
+        q, k = rms(q, q_norm, cfg.norm_eps), rms(k, k_norm, cfg.norm_eps)
+    if sliding:
+        q, k = rope(q, cos, sin), rope(k, cos, sin)
+    return q, k
+
+
+def gated(cfg, o, g):
+    """Attention output ``o`` times sigmoid of the gate projection."""
+    if not cfg.attn_output_gate:
+        return o
+    return (o.astype(F32) * jax.nn.sigmoid(g.astype(F32))).astype(o.dtype)
+
+
+def close(cfg, x, y, post_scale):
+    """A sub-block's output ``y`` into the stream: through its post-norm
+    where the model has one (the sandwich)."""
+    if cfg.sandwich_norm:
+        y = rms(y, post_scale, cfg.norm_eps)
+    return x + y.astype(x.dtype)
+
+
+def route(cfg, h, gate_w, gate_bias=None):
+    """Router of one layer on rows ``h`` [N, D] -> (weight [N, k] float32,
+    idx [N, k] over the ROUTER's experts).  Scores in float32 at full
+    precision; the bias joins the selection only; the kept scores are
+    normalised over the k (``moe_norm_topk_prob``) and scaled."""
+    from deepspeed_tpu.moe import sharded_moe
+
+    logits = jnp.dot(h.astype(F32), gate_w.astype(F32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = (jax.nn.sigmoid(logits) if cfg.moe_score_func == "sigmoid"
+         else jax.nn.softmax(logits, axis=-1))
+    sel = s + gate_bias.astype(F32) if cfg.moe_select_bias else s
+    # through the module, so that benchmarks/lib/serve_taps.py sees the choice
+    _, idx = sharded_moe.topk_weights(sel, cfg.num_experts_per_tok, False)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if cfg.moe_norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return w * cfg.moe_route_scale, idx
+
+
+def held(cfg, weight, idx):
+    """The assignments to experts held here: (weight, 0 elsewhere; index
+    among the held, ``num_experts`` elsewhere)."""
+    local = idx - cfg.moe_first_expert
+    here = (local >= 0) & (local < cfg.num_experts)
+    return (jnp.where(here, weight, 0.0),
+            jnp.where(here, local, cfg.num_experts))
+
+
+def glu_mlp(h, m):
+    return (jax.nn.silu(h @ m["w_gate"].astype(h.dtype))
+            * (h @ m["w_up"].astype(h.dtype))) @ m["w_down"].astype(h.dtype)
+
+
+def mlp(cfg, lp, h, experts=None, layer=None):
+    """The MLP of one layer on ``h`` [B, s, D] (normed): dense, or shared
+    experts plus this chip's share of the routed ones (``experts``: the
+    STACKED expert arrays, ``layer`` this layer's index in them)."""
+    from deepspeed_tpu.moe.sharded_moe import _moe_grouped
+
+    m = lp["mlp"]
+    if experts is None:
+        return glu_mlp(h, m)
+    B, s, D = h.shape
+    ht = h.reshape(B * s, D)
+    weight, idx = route(cfg, ht, m["gate_w"], m.get("gate_bias"))
+    weight, local = held(cfg, weight, idx)
+    y, _ = _moe_grouped(experts, ht, None, cfg, False,
+                        layer=jnp.asarray(layer, jnp.int32),
+                        assign=(weight, local))
+    if cfg.num_shared_experts:
+        y = y + glu_mlp(ht, m["shared"])
+    return y.reshape(B, s, D)
+
+
+def attend(q, segments, q_pos, *, window: int, scale: float,
+           live_keys=None):
+    """Causal (and, with ``window`` > 0, sliding-window) attention by an
+    online softmax over key blocks: q [B, H, s, Dh] at positions ``q_pos``
+    [s]; ``segments`` a list of (k, v [B, Hkv, Sk, Dh], k_pos [Sk]), each
+    key masked by ITS position (negative: an empty row), so a ring of rows
+    and a chunk's own keys sit side by side.  ``live_keys`` (traced) bounds
+    the LAST segment's loop: its keys at or past it are not visited (the
+    global layer's view of ``max_out_tokens`` rows, of which a prompt fills
+    a part).  Float32 scores, never more than [B, H, s, KEY_BLOCK] of them."""
+    B, H, s, Dh = q.shape
+    Hkv = segments[0][0].shape[1]
+    qg = q.reshape(B, Hkv, H // Hkv, s, Dh)
+    qp = q_pos[:, None]                                    # [s, 1]
+
+    def block(carry, kb, vb, kp):
+        m, l, acc = carry
+        sc = jnp.einsum("bgrqd,bgkd->bgrqk", qg, kb.astype(q.dtype),
+                        preferred_element_type=F32) * scale
+        ok = (kp[None, :] <= qp) & (kp[None, :] >= 0)
+        if window:
+            ok = ok & (qp - kp[None, :] < window)
+        sc = jnp.where(ok, sc, NEG_INF)
+        m_new = jnp.maximum(m, sc.max(-1))
+        p = jnp.where(ok, jnp.exp(sc - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgrqk,bgkd->bgrqd", p.astype(vb.dtype), vb,
+            preferred_element_type=F32)
+        return m_new, alpha * l + p.sum(-1), acc
+
+    carry = (jnp.full(qg.shape[:-1], NEG_INF, F32),
+             jnp.zeros(qg.shape[:-1], F32), jnp.zeros(qg.shape, F32))
+    for i, (k, v, k_pos) in enumerate(segments):
+        Sk = k.shape[2]
+        kb = min(KEY_BLOCK, Sk)
+        pad = (-Sk) % kb
+        if pad:
+            k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+                    for t in (k, v))
+            k_pos = jnp.pad(k_pos, (0, pad), constant_values=-1)
+        n = (Sk + pad) // kb
+        if n == 1:
+            carry = block(carry, k, v, k_pos)
+            continue
+
+        def step(j, carry, k=k, v=v, k_pos=k_pos, kb=kb):
+            take = lambda t, ax: jax.lax.dynamic_slice_in_dim(
+                t, j * kb, kb, axis=ax)
+            return block(carry, take(k, 2), take(v, 2), take(k_pos, 0))
+
+        if live_keys is not None and i == len(segments) - 1:
+            n = jnp.minimum(n, (live_keys + kb - 1) // kb)
+        carry = jax.lax.fori_loop(0, n, step, carry)
+    _, l, acc = carry
+    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return o.reshape(B, H, s, Dh).astype(q.dtype)
+
+
+def _project(cfg, lp, x, cos, sin, sliding):
+    """Norm, the four projections, head norms and RoPE: q [B, s, H, Dh],
+    k, v [B, s, Hkv, Dh], g [B, s, H * Dh] | None, all in ``x``'s dtype."""
+    B, s, _ = x.shape
+    H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    a = lp["attn"]
+    h = rms(x, lp["attn_norm"]["scale"], cfg.norm_eps)
+    w = lambda n: a[n].astype(h.dtype)
+    q = (h @ w("wq")).reshape(B, s, H, Dh)
+    k = (h @ w("wk")).reshape(B, s, Hkv, Dh)
+    v = (h @ w("wv")).reshape(B, s, Hkv, Dh)
+    g = h @ w("wg") if cfg.attn_output_gate else None
+    q, k = head_norm_rope(
+        cfg, a.get("q_norm", {}).get("scale"), a.get("k_norm", {}).get("scale"),
+        q, k, cos, sin, sliding)
+    return q, k, v, g
+
+
+def _finish_layer(cfg, lp, x, o, g, experts, layer):
+    """From the attention output o [B, s, H * Dh] to the layer's end."""
+    a = gated(cfg, o, g) @ lp["attn"]["wo"].astype(o.dtype)
+    x = close(cfg, x, a, lp.get("attn_post_norm", {}).get("scale"))
+    h = rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps)
+    return close(cfg, x, mlp(cfg, lp, h, experts, layer),
+                 lp.get("mlp_post_norm", {}).get("scale"))
+
+
+def _experts(params):
+    ly = params.get("layers")
+    return None if ly is None else {k: ly["mlp"][k]
+                                    for k in ("w_up", "w_gate", "w_down")}
+
+
+def embed(cfg, table, tokens, dtype):
+    x = jnp.take(table, tokens, axis=0).astype(F32) * cfg.embed_scale
+    return x.astype(dtype)
+
+
+# ----------------------------------------------------------------------
+# forward 1: no cache (CausalLM.apply)
+# ----------------------------------------------------------------------
+def apply_layers(cfg, params, x, mesh=None):
+    """The layer stack on ``x`` [B, S, D] (embedded, scaled), positions
+    ``0 .. S - 1``: a static loop over the pattern."""
+    refuse_parallel(cfg, mesh, "CausalLM.apply")
+    B, S, _ = x.shape
+    pos = jnp.arange(S)
+    cos, sin = angles(cfg, pos)
+    heads = lambda t: t.transpose(0, 2, 1, 3)
+    for l in range(cfg.num_layers):
+        lp, le = layer_params(cfg, params, l)
+        sliding = is_sliding(cfg, l)
+        q, k, v, g = _project(cfg, lp, x, cos, sin, sliding)
+        o = attend(heads(q), [(heads(k), heads(v), pos)], pos,
+                   window=cfg.sliding_window if sliding else 0,
+                   scale=cfg.head_dim ** -0.5)
+        o = heads(o).reshape(B, S, -1)
+        x = _finish_layer(cfg, lp, x, o, g,
+                          None if le is None else _experts(params), le)
+    return x
+
+
+# ----------------------------------------------------------------------
+# forward 2: a prefill chunk on one slot's gathered views
+# ----------------------------------------------------------------------
+def ring_positions(start, window: int):
+    """The position each ring row holds before a chunk that starts at
+    ``start``: the largest ``p < start`` with ``p % W == r`` (negative: the
+    row was never written)."""
+    r = jnp.arange(window)
+    return start - 1 - ((start - 1 - r) % window)
+
+
+def cached_layers(cfg, params, x, cache, start, valid_len):
+    """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
+    over the slot's views (``k_win`` / ``v_win`` [sliding layers, 1, Hkv, W, Dh]
+    rings, ``k_full`` / ``v_full`` [global layers, 1, Hkv, positions, Dh]:
+    what ``ServingEngine._two_budget_forward`` gathers); only the first
+    ``valid_len`` rows are real (the rest pad the bucket).  Returns
+    (x, views).  Whether the chunk lies before, across or past the window,
+    and whether the ring has wrapped, is data: one program a bucket."""
+    B, s, _ = x.shape
+    assert B == 1, "a chunk program prefills one slot"
+    W = cfg.sliding_window
+    assert s <= W or not any(is_sliding(cfg, l) for l in
+                             range(cfg.num_layers)), (s, W)
+    start = jnp.asarray(start, jnp.int32)
+    pos = start + jnp.arange(s)
+    cos, sin = angles(cfg, pos)
+    heads = lambda t: t.transpose(0, 2, 1, 3)
+    k_win, v_win = cache["k_win"], cache["v_win"]
+    k_full, v_full = cache["k_full"], cache["v_full"]
+    # ring rows of the real tokens; a pad row goes nowhere (mode="drop")
+    ring_row = jnp.where(jnp.arange(s) < valid_len, pos % max(W, 1), W)
+    held_pos = ring_positions(start, W) if W else None
+    i_win = i_full = 0
+    for l in range(cfg.num_layers):
+        lp, le = layer_params(cfg, params, l)
+        sliding = is_sliding(cfg, l)
+        q, k, v, g = _project(cfg, lp, x, cos, sin, sliding)
+        kh, vh = heads(k), heads(v)                    # [1, Hkv, s, Dh]
+        if sliding:
+            # attend BEFORE appending: the ring as the earlier chunks left
+            # it, beside this chunk's own keys
+            o = attend(heads(q),
+                       [(k_win[i_win], v_win[i_win], held_pos),
+                        (kh, vh, pos)],
+                       pos, window=W, scale=cfg.head_dim ** -0.5)
+            put = lambda buf, t: buf.at[i_win, 0, :, ring_row, :].set(
+                t[0].transpose(1, 0, 2).astype(buf.dtype), mode="drop")
+            k_win, v_win = put(k_win, kh), put(v_win, vh)
+            i_win += 1
+        else:
+            at = (i_full, 0, 0, start, 0)
+            k_full = jax.lax.dynamic_update_slice(
+                k_full, kh[None].astype(k_full.dtype), at)
+            v_full = jax.lax.dynamic_update_slice(
+                v_full, vh[None].astype(v_full.dtype), at)
+            o = attend(heads(q),
+                       [(k_full[i_full], v_full[i_full],
+                         jnp.arange(k_full.shape[3]))],
+                       pos, window=0, scale=cfg.head_dim ** -0.5,
+                       live_keys=start + s)
+            i_full += 1
+        o = heads(o).reshape(B, s, -1)
+        x = _finish_layer(cfg, lp, x, o, g,
+                          None if le is None else _experts(params), le)
+    return x, {"k_win": k_win, "v_win": v_win,
+               "k_full": k_full, "v_full": v_full}
+
+
+# ----------------------------------------------------------------------
+# forward 3: one decode step through the fused kernels and the paged pool
+# ----------------------------------------------------------------------
+def inject(cfg, params) -> Dict[str, Any]:
+    """The kernel-injected view (``fused_decode.inject_decode_params``):
+    per-layer dicts with their own buffers, q, k, v AND the gate projection
+    in one ``[D, N]`` matrix; the stacked routed experts by reference."""
+    layers = []
+    for l in range(cfg.num_layers):
+        lp, le = layer_params(cfg, params, l)
+        a, m = lp["attn"], lp["mlp"]
+        cols = [a["wq"], a["wk"], a["wv"]] + (
+            [a["wg"]] if cfg.attn_output_gate else [])
+        d = {"wqkv": jnp.concatenate(cols, axis=-1), "wo": a["wo"],
+             "n1_scale": lp["attn_norm"]["scale"],
+             "n2_scale": lp["mlp_norm"]["scale"]}
+        if cfg.sandwich_norm:
+            d["n1_post"] = lp["attn_post_norm"]["scale"]
+            d["n2_post"] = lp["mlp_post_norm"]["scale"]
+        if cfg.qk_norm_per_head:
+            d["q_norm"], d["k_norm"] = a["q_norm"]["scale"], a["k_norm"]["scale"]
+        if le is None:
+            d.update({k: m[k] for k in ("w_up", "w_gate", "w_down")})
+        else:
+            d["gate_w"] = m["gate_w"]
+            if cfg.moe_select_bias:
+                d["gate_bias"] = m["gate_bias"]
+            if cfg.num_shared_experts:
+                d["shared"] = m["shared"]
+        layers.append(d)
+    out = {"embed": params["embed"], "final_norm": params["final_norm"],
+           "lm_head": params["lm_head"], "layers": tuple(layers)}
+    if _experts(params) is not None:
+        out["experts"] = _experts(params)
+    return out
+
+
+def moe_counts_zero(cfg):
+    """Zeros of :func:`fused_layers`' routing counts: assignments per HELD
+    expert [E], (layer, expert) pairs hit, the fullest expert's rows, and
+    the assignments the live rows OFFERED (k a row and expert layer, held
+    here or not)."""
+    z = jnp.zeros((), jnp.int32)
+    return (jnp.zeros((cfg.num_experts,), jnp.int32), z, z, z)
+
+
+def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
+                 impl: Optional[str] = None):
+    """The layer stack for one token a row: ``x`` [B, D] at per-row
+    positions ``pos`` [B] over the two paged budgets (``cache``: ``k_win`` /
+    ``v_win`` [sliding layers, window pages, Hkv, page, Dh], ``k_full`` /
+    ``v_full`` [global layers, full pages, ...]; ``page_table`` [B, window
+    columns + full columns], ``serving/paged_kv.py``).  A sliding layer
+    writes row ``pos % W`` of the ring and attends rows ``<= min(pos, W - 1)``:
+    once the ring has wrapped that is all of it, which is exactly the window.
+    Returns (x, cache, routing counts | None)."""
+    from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
+                                                 fused_moe_mlp,
+                                                 fused_norm_qkv,
+                                                 fused_proj_norm,
+                                                 paged_kv_append)
+
+    B = x.shape[0]
+    H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    M, Mkv, W = H * Dh, Hkv * Dh, cfg.sliding_window
+    eps, scale = cfg.norm_eps, Dh ** -0.5
+    k_win, v_win = cache["k_win"], cache["v_win"]
+    k_full, v_full = cache["k_full"], cache["v_full"]
+    wp = W // k_win.shape[3] if k_win.shape[0] else 0   # window columns
+    win_table, full_table = page_table[:, :wp], page_table[:, wp:]
+    cos, sin = angles(cfg, pos)                       # [B, 1, Dh/2]
+    ring_row, ring_len = pos % max(W, 1), jnp.minimum(pos, W - 1)
+    zeros = jnp.zeros_like(x)
+    stats = moe_counts_zero(cfg) if moe_live is not None else None
+    i_win = i_full = 0
+    for l, lp in enumerate(dparams["layers"]):
+        sliding = is_sliding(cfg, l)
+        qkv = fused_norm_qkv(x, lp["n1_scale"], None, lp["wqkv"], None,
+                             kind="rmsnorm", eps=eps, impl=impl)
+        q = qkv[:, :M].reshape(B, H, Dh)
+        k = qkv[:, M:M + Mkv].reshape(B, Hkv, Dh)
+        v = qkv[:, M + Mkv:M + 2 * Mkv].reshape(B, Hkv, Dh)
+        g = qkv[:, M + 2 * Mkv:] if cfg.attn_output_gate else None
+        q, k = head_norm_rope(cfg, lp.get("q_norm"), lp.get("k_norm"), q, k,
+                              cos, sin, sliding)
+        if sliding:
+            k_win, v_win = paged_kv_append(k_win, v_win, k, v, ring_row,
+                                           win_table, layer=i_win, impl=impl)
+            ctx = flash_decode(q, k_win, v_win, ring_len, sm_scale=scale,
+                               layer=i_win, page_table=win_table, impl=impl)
+            i_win += 1
+        else:
+            k_full, v_full = paged_kv_append(k_full, v_full, k, v, pos,
+                                             full_table, layer=i_full,
+                                             impl=impl)
+            ctx = flash_decode(q, k_full, v_full, pos, sm_scale=scale,
+                               layer=i_full, page_table=full_table, impl=impl)
+            i_full += 1
+        ctx = gated(cfg, ctx.reshape(B, M), g)
+        if cfg.sandwich_norm:
+            # the post-norm sits between the projection and its residual
+            # add: the kernel projects onto a zero stream and norms that
+            _, a = fused_proj_norm(ctx, zeros, lp["wo"], None, lp["n1_post"],
+                                   None, kind="rmsnorm", eps=eps, impl=impl)
+            x = x + a.astype(x.dtype)
+            h = rms(x, lp["n2_scale"], eps)
+        else:
+            x, h = fused_proj_norm(ctx, x, lp["wo"], None, lp["n2_scale"],
+                                   None, kind="rmsnorm", eps=eps, impl=impl)
+        base = zeros if cfg.sandwich_norm else x
+        if "gate_w" not in lp:
+            y = fused_mlp(h, base, lp["w_up"], lp["w_down"], lp["w_gate"],
+                          act=cfg.activation, impl=impl)
+        else:
+            if cfg.num_shared_experts:
+                sh = lp["shared"]
+                base = fused_mlp(h, base, sh["w_up"], sh["w_down"],
+                                 sh["w_gate"], act=cfg.activation, impl=impl)
+            weight, idx = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
+            weight, local = held(cfg, weight, idx)
+            onehot = jax.nn.one_hot(local, cfg.num_experts, dtype=F32)
+            combine = jnp.sum(onehot * weight[..., None], axis=1)
+            ex = dparams["experts"]
+            y = fused_moe_mlp(h, base, combine, ex["w_up"], ex["w_down"],
+                              ex["w_gate"], layer=l - cfg.num_dense_layers,
+                              act=cfg.activation, impl=impl)
+            if stats is not None:
+                load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
+                               & moe_live[:, None], axis=0, dtype=jnp.int32)
+                stats = (stats[0] + load,
+                         stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
+                         stats[2] + jnp.max(load),
+                         stats[3] + jnp.sum(moe_live, dtype=jnp.int32)
+                         * cfg.num_experts_per_tok)
+        x = close(cfg, x, y, lp.get("n2_post")) if cfg.sandwich_norm else y
+    return x, {"k_win": k_win, "v_win": v_win,
+               "k_full": k_full, "v_full": v_full}, stats
+

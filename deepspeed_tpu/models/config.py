@@ -20,6 +20,20 @@ families instead; `ModelConfig` spans them with feature flags:
   ``fp32_residual``; benchmarks/configs/evabyte-L6.json).  SERVED ONLY, on
   seeded weights: no checkpoint import, no training loss over the eight
   heads, no multi-byte self-speculative decoding, no image tokenizer
+- AFMoE (Trinity): layers of more than one kind from a static pattern
+  (``layer_types``: ``sliding_attention`` layers with RoPE and a
+  ``sliding_window``, ``full_attention`` layers with no position encoding),
+  ``num_dense_layers`` leading SwiGLU layers of ``dense_intermediate_size``
+  then expert layers; per-head QK-norm, a sigmoid output gate on attention,
+  a norm before AND after each sub-block, a sigmoid router over
+  ``moe_router_experts`` whose selection bias picks and does not weigh, the
+  kept weights normalised and scaled by ``moe_route_scale``, shared experts,
+  and ONE CHIP'S SHARE of the routed experts (``num_experts`` held from
+  ``moe_first_expert``; the other ranks' assignments are not computed and
+  no exchange stands in for them), the embedding times ``embed_scale``
+  (``models/afmoe.py``; benchmarks/configs/trinity-large-L5-ep8.json).
+  SERVED ONLY, on seeded weights, one chip (tp = ep = sp = 1): no training
+  loss, no checkpoint import
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -68,6 +82,35 @@ class ModelConfig:
     # [D, num_pred_heads * vocab_size] predict token i + 1 + p (evabyte);
     # serving samples from head 0
     num_pred_heads: int = 1
+    # -- the afmoe layer form (models/afmoe.py); ``layer_types`` turns it on
+    # one entry a layer, "sliding_attention" (RoPE, keys j with
+    # 0 <= i - j < sliding_window) or "full_attention" (NO position
+    # encoding, every j <= i).  The first ``num_dense_layers`` layers carry
+    # a dense MLP of ``dense_intermediate_size`` (parameters
+    # ``dense_layers``), the rest the expert block (``layers``)
+    layer_types: Optional[tuple] = None
+    sliding_window: int = 0
+    num_dense_layers: int = 0
+    dense_intermediate_size: int = 0
+    # RMSNorm of q and k PER HEAD (gains [head_dim], shared by the heads),
+    # after the head split and before RoPE
+    qk_norm_per_head: bool = False
+    # attention output times sigmoid(h Wg) before the output projection
+    attn_output_gate: bool = False
+    # a norm AFTER each sub-block too: x + N_post(block(N_pre(x)))
+    sandwich_norm: bool = False
+    embed_scale: float = 1.0               # multiplies the token embedding
+    # router scores: "softmax" over the experts, or independent "sigmoid"s
+    moe_score_func: str = "softmax"
+    moe_route_scale: float = 1.0           # multiplies the kept weights
+    # a per-expert bias added to the scores for the top-k SELECTION only
+    moe_select_bias: bool = False
+    num_shared_experts: int = 0            # always-on experts beside the routed
+    # the router's width where this chip holds a share of the experts:
+    # ``num_experts`` are HELD here, [moe_first_expert, + num_experts) of
+    # ``moe_router_experts`` (0 = all of them are held)
+    moe_router_experts: int = 0
+    moe_first_expert: int = 0
     # RMSNorm multiplies by (1 + scale): the stored gain starts at 0
     norm_add_unit_offset: bool = False
     # the residual stream (and the logits) stay float32 whatever dtype the
@@ -159,6 +202,56 @@ class ModelConfig:
                                  "KV head a query head (evabyte)")
         if self.num_pred_heads < 1:
             raise ValueError("num_pred_heads must be >= 1")
+        if self.moe_score_func not in ("softmax", "sigmoid"):
+            raise ValueError(f"moe_score_func must be 'softmax' or "
+                             f"'sigmoid', got {self.moe_score_func!r}")
+        if self.layer_types is not None:
+            self._check_afmoe()
+        elif any(getattr(self, f.name) != f.default
+                 for f in dataclasses.fields(self)
+                 if f.name in _AFMOE_ONLY):
+            raise ValueError(
+                f"{sorted(_AFMOE_ONLY)} belong to the layer form of "
+                "models/afmoe.py, which ``layer_types`` turns on")
+
+    def _check_afmoe(self):
+        """The one combination models/afmoe.py is written for."""
+        self.layer_types = tuple(self.layer_types)
+        kinds = {"sliding_attention", "full_attention"}
+        if len(self.layer_types) != self.num_layers or \
+                not set(self.layer_types) <= kinds:
+            raise ValueError(
+                f"layer_types must name one of {sorted(kinds)} for each of "
+                f"the {self.num_layers} layers, got {self.layer_types!r}")
+        if "sliding_attention" in self.layer_types and self.sliding_window < 1:
+            raise ValueError("sliding_attention layers need sliding_window")
+        if not 0 <= self.num_dense_layers <= self.num_layers:
+            raise ValueError("num_dense_layers out of range")
+        if self.num_dense_layers and self.dense_intermediate_size < 1:
+            raise ValueError("dense layers need dense_intermediate_size")
+        if self.num_dense_layers < self.num_layers and not self.is_moe:
+            raise ValueError("the layers after num_dense_layers are expert "
+                             "layers: num_experts must be > 0")
+        if not self.moe_router_experts:
+            self.moe_router_experts = self.num_experts
+        if not (0 <= self.moe_first_expert and self.moe_first_expert
+                + self.num_experts <= self.moe_router_experts):
+            raise ValueError(
+                f"held experts [{self.moe_first_expert}, "
+                f"{self.moe_first_expert + self.num_experts}) do not lie in "
+                f"the router's {self.moe_router_experts}")
+        if (self.norm, self.position, self.glu, self.attention) != (
+                "rmsnorm", "rope", True, "full") or self.moe_drop_tokens \
+                or self.use_bias or self.qkv_bias or self.mlp_bias \
+                or self.qk_norm or self.parallel_residual \
+                or self.fp32_residual or self.norm_add_unit_offset \
+                or self.tie_embeddings or self.num_pred_heads != 1 \
+                or self.rotary_pct != 1.0 or self.dropout:
+            raise ValueError(
+                "layer_types (models/afmoe.py) is built for RMSNorm, RoPE "
+                "on the sliding layers, gated MLPs without biases, dropless "
+                "experts (moe_drop_tokens=False), an untied head and a "
+                "stream in the weights' dtype")
 
     @property
     def has_mlp_bias(self) -> bool:
@@ -171,6 +264,24 @@ class ModelConfig:
     @property
     def is_eva(self) -> bool:
         return self.attention == "eva"
+
+    @property
+    def is_afmoe(self) -> bool:
+        return self.layer_types is not None
+
+    @property
+    def num_expert_layers(self) -> int:
+        """Layers that carry the expert block (all of a MoE model's but an
+        afmoe model's leading dense ones)."""
+        return (self.num_layers - self.num_dense_layers) if self.is_moe else 0
+
+
+# fields only models/afmoe.py reads
+_AFMOE_ONLY = frozenset({
+    "sliding_window", "num_dense_layers", "dense_intermediate_size",
+    "qk_norm_per_head", "attn_output_gate", "sandwich_norm", "embed_scale",
+    "moe_score_func", "moe_route_scale", "moe_select_bias",
+    "num_shared_experts", "moe_router_experts", "moe_first_expert"})
 
 
 _PRESETS = {

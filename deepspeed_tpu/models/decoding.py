@@ -280,7 +280,7 @@ def _scatter_rows(buf, rows, start_pos):
 
 
 def forward_with_cache(model, params, tokens, cache, start_pos,
-                       page_table=None):
+                       page_table=None, valid_len=None):
     """Run the model over ``tokens`` [B, s] starting at absolute position
     ``start_pos``, reading/updating the KV cache.
 
@@ -305,6 +305,13 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     its summaries behind.  The paged form gathers each row's pages, works on
     the view and scatters them back: the reference for the fused path.
 
+    Under ``layer_types`` (``models/afmoe.py``) the cache is the two-kind
+    view ``afmoe.cached_layers`` takes (rings of ``sliding_window`` rows for the
+    sliding layers, every position for the global ones) and the call is a
+    prefill chunk of ONE slot at a scalar ``start_pos``, of which the first
+    ``valid_len`` tokens are real: a ring takes no pad row.  Decode runs on
+    the fused path only.
+
     Returns (logits [B, s, num_pred_heads * V], new_cache).  Used for prefill
     (s = prompt length, start_pos=0), decode (s = 1), and chunked per-slot
     prefill (s = chunk, scalar start_pos = chunk offset).
@@ -314,6 +321,20 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     batch_ax = ("dp", "fsdp", "ep")
     B, s = tokens.shape
     H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    if cfg.is_afmoe:
+        from deepspeed_tpu.models import afmoe
+        if page_table is not None or jnp.ndim(start_pos) != 0:
+            raise NotImplementedError(
+                "a layer_types model (models/afmoe.py) decodes through the "
+                "fused path (fused_decode.decode_step); forward_with_cache "
+                "prefills one slot's chunk at a scalar start_pos")
+        afmoe.refuse_parallel(cfg, mesh, "forward_with_cache")
+        x = afmoe.embed(cfg, params["embed"]["tok"], tokens,
+                        cache["k_full"].dtype)
+        x, new_cache = afmoe.cached_layers(
+            cfg, params, x, cache, start_pos,
+            s if valid_len is None else valid_len)
+        return output_logits(cfg, params, x), new_cache
     quant_kv = "k_scale" in cache
     start_pos = jnp.asarray(start_pos, jnp.int32)
     per_row = start_pos.ndim == 1                      # [B] vector of depths

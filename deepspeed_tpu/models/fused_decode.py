@@ -73,6 +73,9 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
     the caller's own arrays by reference and they stay resident once."""
     from deepspeed_tpu.models.quant import QTensor, is_qtensor
 
+    if cfg.is_afmoe:      # two stacks of layers, its own view
+        from deepspeed_tpu.models import afmoe
+        return afmoe.inject(cfg, params)
     ly = params["layers"]
     attn, mlp = ly["attn"], ly["mlp"]
     if is_qtensor(attn["wq"]):  # int8 serving: concat payloads AND scales
@@ -150,6 +153,9 @@ def moe_combine(h, gate_w, cfg):
 
 def moe_counts_zero(cfg):
     """Zeros of ``decode_step``'s routing counts (its ``moe_live`` result)."""
+    if cfg.is_afmoe:      # a fourth count: the assignments offered
+        from deepspeed_tpu.models import afmoe
+        return afmoe.moe_counts_zero(cfg)
     return (jnp.zeros((cfg.num_experts,), jnp.int32),
             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
 
@@ -192,6 +198,21 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             "attention='eva' decodes through the paged pool "
             "(serving/paged_kv.py); the contiguous caches hold no window "
             "and summary rows on the fused path")
+    if cfg.is_afmoe:
+        from deepspeed_tpu.models import afmoe
+        if page_table is None:
+            raise NotImplementedError(
+                "a layer_types model (models/afmoe.py) decodes through the "
+                "paged pool's two budgets (serving/paged_kv.py)")
+        x = afmoe.embed(cfg, dparams["embed"]["tok"], tokens[:, 0],
+                        cache["k_full"].dtype)
+        x, new_cache, moe_stats = afmoe.fused_layers(
+            cfg, dparams, x, cache, pos, page_table, moe_live=moe_live,
+            impl=impl)
+        logits = output_logits(cfg, dparams, x)
+        if moe_live is not None:
+            return logits, new_cache, moe_stats
+        return logits, new_cache
     x = jnp.take(dparams["embed"]["tok"], tokens[:, 0], axis=0)
     if cfg.position == "learned":
         x = x + jnp.take(dparams["embed"]["pos"],

@@ -84,6 +84,9 @@ class CausalLM:
     def init(self, rng, tokens=None, labels=None) -> Dict[str, Any]:
         cfg = self.config
         dtype = jnp.float32  # master params fp32; engine casts for compute
+        if cfg.is_afmoe:     # two stacks of layers (models/afmoe.py)
+            from deepspeed_tpu.models import afmoe
+            return afmoe.init_params(cfg, rng, dtype)
         D, F, V, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_layers
         H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         E = cfg.num_experts
@@ -176,6 +179,10 @@ class CausalLM:
         ``fsdp`` may claim it later for ZeRO-3).
         """
         cfg = self.config
+        if cfg.is_afmoe:
+            from deepspeed_tpu.models import afmoe
+            return afmoe.logical_pspecs(cfg, jax.eval_shape(
+                lambda: afmoe.init_params(cfg, jax.random.PRNGKey(0))))
         col = P(None, None, "tp")       # [L, D, H*Dh] / [L, D, F] — column split
         row = P(None, "tp", None)       # [L, F, D] / [L, H*Dh, D] — row split
         norm_spec = {"scale": P(None, None)}
@@ -347,6 +354,8 @@ class CausalLM:
         cfg = self.config
         mesh = self.mesh
         batch_ax = _BATCH_AX
+        if cfg.is_afmoe:
+            return self._apply_afmoe(params, tokens, labels)
         if cfg.param_offload:
             # ZeRO-Infinity param tiering: non-layer params come over once
             # here; scanned layer weights stream per-layer inside the scan
@@ -666,6 +675,25 @@ class CausalLM:
         loss = self._loss_tail(params["final_norm"], head, x, labels, loss_mask,
                                head_bias=params.get("lm_head_bias"))
         return loss + cfg.moe_aux_loss_coef * aux_loss if cfg.is_moe else loss
+
+    def _apply_afmoe(self, params, tokens, labels):
+        """``apply`` for ``layer_types`` (models/afmoe.py): logits only.  A
+        chip's share of the experts and of the vocabulary has no loss to
+        train on (the other ranks' logits are absent from its softmax)."""
+        from deepspeed_tpu.models import afmoe
+
+        cfg = self.config
+        if labels is not None:
+            raise NotImplementedError(
+                "the training loss of a layer_types model (models/afmoe.py) "
+                "is not built: the chip holds a share of the experts and of "
+                "the vocabulary, and CausalLM._loss_tail knows neither; such "
+                "a model is served only")
+        x = afmoe.embed(cfg, params["embed"]["tok"], tokens,
+                        params["embed"]["tok"].dtype)
+        x = afmoe.apply_layers(cfg, params, x, self.mesh)
+        x = model_norm(cfg, x, params["final_norm"], self.mesh)
+        return x @ params["lm_head"].astype(x.dtype)
 
     def _loss_tail(self, fnorm, head, x, labels, loss_mask, head_bias=None):
         """Final norm + LM cross-entropy — the single implementation behind

@@ -170,13 +170,21 @@ def topk_gating(gates, k: int, capacity: int, rng=None,
     return combine, dispatch, aux
 
 
-def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None):
+def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
+                 assign=None):
     """Dropless expert block on tokens ``xt`` [N, D] with router
     probabilities ``gates`` [N, E]: sort the N*k (token, expert) assignments
     by expert, run each projection as ONE grouped matmul over the sorted rows
     (``group_sizes`` [E] = tokens per expert; an expert nobody chose is an
     empty group), un-sort, and sum each token's k outputs under its router
     weights in float32.  Returns (y [N, D], aux).
+
+    ``assign`` = (weight [N, k] float32, e_idx [N, k]) replaces the router
+    (``gates`` is then unused and aux is 0): the caller routed already, and
+    ``e_idx`` counts the ``E`` experts HELD HERE, with ``E`` itself for an
+    assignment to an expert another chip holds (a chip's share of the
+    experts, ``models/afmoe.py``).  Those rows sort behind every group, lie
+    in none, and add nothing to their token.
 
     ``layer`` (a traced index) says the expert arrays are the model's STACKED
     [L, E, ...] ones: they go to the grouped matmul whole, as L*E groups of
@@ -187,10 +195,15 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None):
     an empty group costs the matmul next to nothing."""
     N, D = xt.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
-    weight, e_idx = topk_weights(gates, k, normalize)            # [N, k]
-    aux = load_balance_loss(gates, e_idx[:, 0])
+    if assign is None:
+        weight, e_idx = topk_weights(gates, k, normalize)        # [N, k]
+        aux = load_balance_loss(gates, e_idx[:, 0])
+    else:
+        weight, e_idx = assign
+        aux = jnp.zeros((), jnp.float32)
     flat = e_idx.reshape(-1)
     order = jnp.argsort(flat)                   # stable: token order inside
+    # (an index of E, "held elsewhere", is past ``length`` and not counted)
     sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     rows = xt[order // k]                                        # [N*k, D]
     if layer is not None:
@@ -206,7 +219,10 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None):
     act = activation_fn(cfg.activation)
     up = dot(rows, params["w_up"])
     hidden = act(dot(rows, params["w_gate"])) * up if cfg.glu else act(up)
-    out = dot(hidden, params["w_down"])[jnp.argsort(order)]      # un-sort
+    out = dot(hidden, params["w_down"])
+    if assign is not None:      # rows of no group: whatever the matmul left
+        out = jnp.where((flat[order] < E)[:, None], out, 0)
+    out = out[jnp.argsort(order)]                                # un-sort
     y = jnp.sum(out.reshape(N, k, D).astype(jnp.float32) * weight[..., None],
                 axis=1)
     return y.astype(xt.dtype), aux

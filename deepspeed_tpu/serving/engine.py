@@ -122,6 +122,9 @@ SERVE_MOE_COUNTERS = {
         "(layer, step, expert) triples run: experts x layers x decode steps",
     "ds_serve_moe_max_load_total":
         "rows of the fullest expert, summed over layers and decode steps",
+    "ds_serve_moe_local_assignments_total":
+        "of ds_serve_moe_assignments_total, those to an expert this chip "
+        "holds (all of them unless the chip holds a share: models/afmoe.py)",
 }
 
 
@@ -147,6 +150,66 @@ SERVE_EVA_COUNTERS = {
     "ds_serve_eva_summary_rows_total":
         "summary rows attended by live decode rows, summed over steps",
 }
+
+
+# Layers of two kinds over two page budgets (models/afmoe.py,
+# serving/paged_kv.py), counted on the host from the positions the engine
+# already holds, only while the registry is on.
+SERVE_WINDOW_COUNTERS = {
+    "ds_serve_attn_window_rows_total":
+        "K/V rows the live decode queries attended in ONE sliding layer "
+        "(min(pos + 1, window) a step), summed over rows and steps",
+    "ds_serve_attn_full_rows_total":
+        "K/V rows the live decode queries attended in ONE global layer "
+        "(pos + 1 a step), summed over rows and steps",
+    "ds_serve_kv_page_steps_total":
+        "page x layer x iterations the two budgets held: window pages times "
+        "the sliding layers plus full pages times the global layers, summed "
+        "over scheduler iterations (by budget at an instant: "
+        "ds_serve_kv_pages_used_by_kind)",
+    "ds_serve_kv_page_steps_one_budget_total":
+        "page x layer x iterations one budget a layer would have held for "
+        "the same positions (every layer a page per kv_page_tokens)",
+}
+
+
+def _afmoe_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
+    """What a ``layer_types`` model (models/afmoe.py) cannot be served with,
+    each named by the module that assumes ONE budget of position-pure pages
+    (``_eva_refusals``' reasoning: a sliding layer's ring page is
+    overwritten every ``sliding_window`` tokens, and a page id means a page
+    in one kind of layer only)."""
+    if not config.paged_kv_cache:
+        raise NotImplementedError(
+            "a layer_types model is served from the paged pool's two "
+            "budgets (serving/paged_kv.py); paged_kv_cache=False has no "
+            "ring for its sliding layers")
+    if role != "both":
+        raise NotImplementedError(
+            f"role={role!r} with layer_types: serving/handoff.py ships "
+            "pages as the K and V of a token prefix in every layer, which a "
+            "ring page is not")
+    if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
+        raise NotImplementedError(
+            "kv_host_tier_pages > 0 with layer_types: serving/host_tier.py "
+            "demotes and promotes pages keyed by the token prefix they hold "
+            "in every layer, which a ring page does not keep")
+    if config.quantize_kv_cache:
+        raise NotImplementedError(
+            "quantize_kv_cache with layer_types: the int8 cache of "
+            "models/decoding.py is one array a layer kind with scales, and "
+            "the fused decode path (the only one built for this model) "
+            "reads no int8 rows")
+    if config.use_fused_decode is False:
+        raise NotImplementedError(
+            "use_fused_decode=False with layer_types: the decode step over "
+            "two page budgets is built on the fused path only "
+            "(models/afmoe.py:fused_layers)")
+    if cfg.sliding_window and prefill_chunk > cfg.sliding_window:
+        raise ValueError(
+            f"prefill_chunk={prefill_chunk} exceeds sliding_window="
+            f"{cfg.sliding_window}: a chunk's real rows must be distinct "
+            f"rows of the ring (serving/paged_kv.py)")
 
 
 def _eva_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
@@ -274,6 +337,10 @@ class ServingEngine:
         self._eva = bool(getattr(cfg, "is_eva", False))
         if self._eva:
             _eva_refusals(cfg, self._config, role, self.prefill_chunk)
+        # layers of two kinds over two page budgets (models/afmoe.py)
+        self._afmoe = bool(getattr(cfg, "is_afmoe", False))
+        if self._afmoe:
+            _afmoe_refusals(cfg, self._config, role, self.prefill_chunk)
         self.paged = bool(self._config.paged_kv_cache)
         if self.paged:
             self.pool = PagedKVPool(
@@ -281,11 +348,13 @@ class ServingEngine:
                 page_tokens=self._config.kv_page_tokens,
                 pool_tokens=self._config.kv_pool_tokens,
                 window_tokens=cfg.eva_window if self._eva else 0,
-                chunk_tokens=cfg.eva_chunk if self._eva else 0)
+                chunk_tokens=cfg.eva_chunk if self._eva else 0,
+                ring_tokens=cfg.sliding_window if self._afmoe else 0)
             self._cache = init_paged_kv_cache(
                 cfg, self.pool.num_pages, self.pool.page,
                 dtype=engine.dtype,
-                quantized=self._config.quantize_kv_cache)
+                quantized=self._config.quantize_kv_cache,
+                num_window_pages=self.pool.num_window_pages)
             # per-slot LOGICAL window (page-table depth x page); the
             # PHYSICAL pool may hold fewer tokens than num_slots windows
             self.cache_len = self.pool.cache_len
@@ -308,7 +377,14 @@ class ServingEngine:
                      "serving/prefix_cache.py shares pages as the K and V of "
                      "a token prefix, and a window page is overwritten every "
                      f"{cfg.eva_window} tokens", ranks=[0])
-        if self.paged and self._config.prefix_caching and not self._eva:
+        if self._afmoe and self._config.prefix_caching:
+            log_dist("prefix caching is off for a layer_types model: "
+                     "serving/prefix_cache.py shares pages as the K and V of "
+                     "a token prefix in every layer, and a sliding layer's "
+                     f"ring page is overwritten every {cfg.sliding_window} "
+                     "tokens", ranks=[0])
+        if self.paged and self._config.prefix_caching and not (
+                self._eva or self._afmoe):
             host_pages = int(getattr(self._config, "kv_host_tier_pages", 0))
             self.host_store = (
                 HostPageStore(host_pages, registry=self._registry)
@@ -481,6 +557,8 @@ class ServingEngine:
                        for name, what in SERVE_MOE_COUNTERS.items()}
         self._m_eva = {name: reg.counter(name, what)
                        for name, what in SERVE_EVA_COUNTERS.items()}
+        self._m_win = {name: reg.counter(name, what)
+                       for name, what in SERVE_WINDOW_COUNTERS.items()}
         self._m_first_overlapped = reg.counter(
             "ds_serve_first_token_overlapped_total",
             "first tokens fetched with a decode block already enqueued "
@@ -522,9 +600,10 @@ class ServingEngine:
             kind: reg.gauge(
                 "ds_serve_kv_pages_used_by_kind",
                 "KV pool pages held by slots, by what they hold (EVA: window "
-                "rows reused in place, or chunk summaries; full attention: "
-                "all window)", labels={"kind": kind})
-            for kind in ("window", "summary")}
+                "rows reused in place, or chunk summaries; two budgets: the "
+                "sliding layers' rings, or the global layers' full pages; "
+                "full attention: all window)", labels={"kind": kind})
+            for kind in ("window", "summary", "full")}
         self._m_preempted = reg.counter(
             "ds_serve_preempted_total",
             "requests preempted (pages reclaimed, requeued at queue head)")
@@ -592,6 +671,12 @@ class ServingEngine:
             if self._eva:
                 layout += (f" ({self.pool.window_pages} window + "
                            f"{self.pool.summary_pages} summary pages a slot)")
+            if self._afmoe:
+                layout = (f"two page budgets: {self.pool.num_window_pages - 1}"
+                          f" window + {self.pool.num_pages - 1} full x "
+                          f"{self.pool.page}-token pages, {self.num_slots} "
+                          f"slots x ({self.pool.window_pages} ring pages + "
+                          f"{self.cache_len} positions)")
         else:
             layout = f"{self.num_slots} slots x {self.cache_len} tokens"
         log_dist(f"serving engine: {layout}, prefill_chunk="
@@ -626,11 +711,11 @@ class ServingEngine:
                 "engine is draining/drained: not admitting new requests "
                 "(the router should have stopped sending — /healthz is "
                 "503; resume_admission() re-opens)")
-        if prefill_only and self._eva:
+        if prefill_only and (self._eva or self._afmoe):
             raise NotImplementedError(
-                "prefill_only with attention='eva': serving/handoff.py ships "
-                "pages as the K and V of a token prefix, which a window "
-                "page is not")
+                "prefill_only with attention='eva' or layer_types: "
+                "serving/handoff.py ships pages as the K and V of a token "
+                "prefix, which a window page is not")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -728,6 +813,8 @@ class ServingEngine:
             if self.pool.pages_used:
                 self._m_kv_util.record(
                     self.pool.utilization(int(self._pos.sum())))
+            if self._afmoe and self._registry.enabled:
+                self._count_page_steps()
         elif self.scheduler.num_occupied:
             self._m_kv_util.record(
                 int(self._pos.sum()) / (self.num_slots * self.cache_len))
@@ -1730,7 +1817,7 @@ class ServingEngine:
     def _page_gauges(self) -> None:
         self._m_pages_used.set(self.pool.pages_used)
         self._m_pages_free.set(self.pool.pages_free)
-        if self._eva and self._registry.enabled:
+        if (self._eva or self._afmoe) and self._registry.enabled:
             for kind, n in self.pool.pages_used_by_kind().items():
                 self._m_pages_kind[kind].set(n)
 
@@ -1946,6 +2033,8 @@ class ServingEngine:
         eva = self._eva
         if self.paged:
             maxp, page = self.pool.slot_pages, self.pool.page
+        if self._afmoe:
+            forward = self._two_budget_forward(cb)
 
         @functools.partial(jax.jit, donate_argnums=(1, 2))
         def prefill(params, cache, carries, pt_row, chunk, meta, srng):
@@ -1980,12 +2069,17 @@ class ServingEngine:
                     return dst
                 return dst.at[:, pt_row].set(pages)
 
-            sub = {k: (view(v) if v.ndim == 5 else v)
-                   for k, v in cache.items()}
-            logits, sub = forward_with_cache(model, params, chunk, sub, start)
-            out = {k: (write_back(cache[k], sub[k])
-                       if cache[k].ndim == 5 else sub[k])
-                   for k in cache}
+            if self._afmoe:
+                logits, out = forward(params, cache, pt_row, chunk, start,
+                                      last_idx + 1)
+            else:
+                sub = {k: (view(v) if v.ndim == 5 else v)
+                       for k, v in cache.items()}
+                logits, sub = forward_with_cache(model, params, chunk, sub,
+                                                 start)
+                out = {k: (write_back(cache[k], sub[k])
+                           if cache[k].ndim == 5 else sub[k])
+                       for k in cache}
             logits = next_token_logits(model.config, jax.lax.dynamic_index_in_dim(
                 logits, last_idx, axis=1, keepdims=False))
             tok = sample_token(logits, srng, temperature=temperature,
@@ -1999,6 +2093,55 @@ class ServingEngine:
 
         self._prefill_fns[cb] = prefill
         return prefill
+
+    def _two_budget_forward(self, cb: int):
+        """The chunk program's forward over two page budgets
+        (serving/paged_kv.py): ``(params, cache, page-table row, chunk [1,
+        cb], start, real tokens) -> (logits, cache)``.  The slot's ring
+        pages and its full pages are sliced out one by one into the
+        contiguous views ``afmoe.cached_layers`` takes (a gather through the
+        table walks the whole pool); the forward attends the ring BEFORE it
+        appends, and the pages go back: all of the ring's, and of the full
+        ones only those the chunk's ``cb`` rows can have touched."""
+        model, page = self.module, self.pool.page
+        wp = self.pool.window_pages
+        fp = self.pool.slot_pages - wp
+        touched = -(-cb // page) + 1
+
+        def forward(params, cache, pt_row, chunk, start, valid_len):
+            def view(v, cols):
+                g = jnp.concatenate(
+                    [jax.lax.dynamic_slice_in_dim(v, pt_row[c], 1, axis=1)
+                     for c in cols], axis=1)       # [L, n, Hkv, page, D]
+                L, n, Hkv, pg, D = g.shape
+                return g.transpose(0, 2, 1, 3, 4).reshape(L, 1, Hkv, n * pg, D)
+
+            def write_back(dst, s, col0, firsts):
+                L, _, Hkv, S, D = s.shape
+                pages = s.reshape(L, Hkv, S // page, page, D).transpose(
+                    0, 2, 1, 3, 4)
+                for i in firsts:                   # index among the view's
+                    one = jax.lax.dynamic_slice_in_dim(pages, i, 1, axis=1)
+                    dst = jax.lax.dynamic_update_slice_in_dim(
+                        dst, one, pt_row[col0 + i], axis=1)
+                return dst
+
+            win, full = range(wp), range(wp, wp + fp)
+            sub = {"k_win": view(cache["k_win"], win),
+                   "v_win": view(cache["v_win"], win),
+                   "k_full": view(cache["k_full"], full),
+                   "v_full": view(cache["v_full"], full)}
+            logits, sub = forward_with_cache(model, params, chunk, sub, start,
+                                             valid_len=valid_len)
+            first = jnp.minimum(start // page, fp - 1)
+            spans = {"win": (0, list(range(wp))),
+                     "full": (wp, [jnp.minimum(first + i, fp - 1)
+                                   for i in range(min(touched, fp))])}
+            out = {k: write_back(cache[k], sub[k], *spans[k.split("_")[1]])
+                   for k in cache}
+            return logits, out
+
+        return forward
 
     # ------------------------------------------------------------------
     def _decode_block(self) -> None:
@@ -2058,6 +2201,8 @@ class ServingEngine:
             n = int(min(self._K, self._limit[b] - self._pos[b]))
             if self._eva and self._registry.enabled:
                 self._count_eva(int(self._pos[b]), n)
+            if self._afmoe and self._registry.enabled:
+                self._count_attended(int(self._pos[b]), n)
             self._pos[b] += n
             # one span per participating row: the block's host dispatch
             # window with this request's scheduled token count
@@ -2129,15 +2274,46 @@ class ServingEngine:
         m["ds_serve_eva_summary_rows_total"].inc(int((p // W).sum()) * per)
         m["ds_serve_eva_window_closes_total"].inc(int(((p + 1) % W == 0).sum()))
 
-    def _count_moe(self, per_expert, hits, max_load) -> None:
+    def _count_attended(self, pos: int, n: int) -> None:
+        """A row's ``n`` decode steps from position ``pos`` into
+        ``ds_serve_attn_*_rows_total``: the K/V rows each step attends in one
+        sliding layer (``min(p + 1, W)``) and in one global layer (``p +
+        1``).  Host arithmetic on positions the engine holds anyway."""
+        p = np.arange(pos, pos + n) + 1
+        W = self.module.config.sliding_window
+        self._m_win["ds_serve_attn_window_rows_total"].inc(
+            int(np.minimum(p, W).sum()))
+        self._m_win["ds_serve_attn_full_rows_total"].inc(int(p.sum()))
+
+    def _count_page_steps(self) -> None:
+        """This iteration's pages into ``ds_serve_kv_page_steps_*``: what the
+        two budgets hold, each page weighted by the layers of its kind, and
+        what ONE budget would hold for the same slots (their full pages, in
+        every layer)."""
+        from deepspeed_tpu.models.afmoe import kind_layers
+
+        n_win, n_full = (len(k) for k in kind_layers(self.module.config))
+        held = self.pool.pages_used_by_kind()
+        self._m_win["ds_serve_kv_page_steps_total"].inc(
+            held["window"] * n_win + held["full"] * n_full)
+        self._m_win["ds_serve_kv_page_steps_one_budget_total"].inc(
+            held["full"] * (n_win + n_full))
+
+    def _count_moe(self, per_expert, hits, max_load, offered=None) -> None:
         """One decode block's routing (``decode_step``'s ``moe_live``
-        result, summed over the block's steps) into ``ds_serve_moe_*``."""
+        result, summed over the block's steps) into ``ds_serve_moe_*``.
+        ``offered`` (a chip's share of the experts, models/afmoe.py): the
+        assignments the live rows made, of which ``per_expert`` holds those
+        to experts held here."""
         cfg = self.module.config
         m = self._m_moe
-        m["ds_serve_moe_assignments_total"].inc(int(per_expert.sum()))
+        local = int(per_expert.sum())
+        m["ds_serve_moe_assignments_total"].inc(
+            local if offered is None else int(offered))
+        m["ds_serve_moe_local_assignments_total"].inc(local)
         m["ds_serve_moe_expert_hits_total"].inc(int(hits))
         m["ds_serve_moe_expert_slots_total"].inc(
-            cfg.num_experts * cfg.num_layers * self._K)
+            cfg.num_experts * cfg.num_expert_layers * self._K)
         m["ds_serve_moe_max_load_total"].inc(int(max_load))
 
     def _unref(self, idx: int) -> None:

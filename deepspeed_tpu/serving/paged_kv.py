@@ -45,6 +45,37 @@ window pages, and window pages are all granted before the first summary
 page, so the table's columns still fill in order.  ``pool_tokens`` keeps
 its meaning, rows of the pool: pages x ``page_tokens``.
 
+**Two page budgets** (``ring_tokens`` below; a model whose layers are of
+two kinds, ``models/afmoe.py``): the SLIDING layers keep only the last
+``W = ring_tokens`` positions and the GLOBAL layers keep all of them, so
+one page id cannot mean "a page in every layer".  The pool then holds two
+budgets, each with its own device arrays (``init_paged_kv_cache``:
+``k_win`` / ``v_win`` ``[sliding layers, window pages, ...]``, ``k_full`` /
+``v_full`` ``[global layers, full pages, ...]``), its own free list, its own
+refcounts and its own junk page 0, and a slot's table has two column ranges:
+
+- **window pages**, columns ``[0, W / page)``, ids into the window arrays:
+  a RING of exactly ``W`` rows, position ``p`` at row ``p % W``, filled once
+  and then overwritten in place.  Exactly ``W`` rows are enough in decode
+  (position ``p`` overwrites ``p - W``, which query ``p`` no longer sees).
+  A prefill chunk would overwrite rows its own first queries still attend,
+  so THE CHUNK ATTENDS BEFORE IT APPENDS (``afmoe.cached_layers``: the ring
+  as the earlier chunks left it beside the chunk's own K and V, each key
+  masked by its position) and writes only its real rows: a pad row in a
+  ring would destroy a live one.  So ``prefill_chunk <= W``;
+- **full pages**, the columns after them, ids into the full arrays: the K
+  and V of ``page_tokens`` consecutive positions, for ever.
+
+``pages_for(tokens, kind)`` answers per kind (window: ``ceil(min(tokens, W)
+/ page)``; full: ``ceil(tokens / page)``); :meth:`ensure` grants BOTH kinds'
+pages or neither.  ``pool_tokens`` (the engine's ``kv_pool_tokens``) is the
+FULL budget, positions the global layers can hold over all slots; the
+window budget is not a knob of the engine: ``num_slots`` rings, so that a
+slot that was admitted can always have its ring (``window_pool_tokens``
+lowers it, for the allocator's own tests).  A 3 x W request holds ``W /
+page`` window pages and ``3 W / page`` full ones, where one budget a layer
+would hold ``3 W / page`` in every layer.
+
 Physical **page 0 is reserved as the junk page**: it is never allocated,
 and a released slot's table rows all point at it, so the parked row's
 junk K/V writes (inactive rows still execute in the static-shape compiled
@@ -98,11 +129,25 @@ def default_page_tokens(max_out_tokens: int) -> int:
 
 def init_paged_kv_cache(cfg, num_pages: int, page_tokens: int,
                         dtype=jnp.bfloat16,
-                        quantized: bool = False) -> Dict[str, Any]:
+                        quantized: bool = False,
+                        num_window_pages: int = 0) -> Dict[str, Any]:
     """Device arrays for the shared page pool — the paged analog of
     :func:`~deepspeed_tpu.models.decoding.init_kv_cache`, with the slot
-    dim replaced by the page dim and the sequence dim by the page depth."""
+    dim replaced by the page dim and the sequence dim by the page depth.
+    ``num_window_pages`` (two budgets, module docstring): ``k_win`` /
+    ``v_win`` of that many pages for the sliding layers beside ``k_full`` /
+    ``v_full`` of ``num_pages`` for the global ones."""
     L, Hkv, Dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    if num_window_pages:
+        from deepspeed_tpu.models.afmoe import kind_layers
+
+        ls, lf = kind_layers(cfg)
+        z = lambda n, pages: jnp.zeros((n, pages, Hkv, page_tokens, Dh),
+                                       dtype)
+        return {"k_win": z(len(ls), num_window_pages),
+                "v_win": z(len(ls), num_window_pages),
+                "k_full": z(len(lf), num_pages),
+                "v_full": z(len(lf), num_pages)}
     if quantized:
         return {
             "k": jnp.zeros((L, num_pages, Hkv, page_tokens, Dh), jnp.int8),
@@ -143,17 +188,33 @@ class PagedKVPool:
         table is then ``window_tokens / page`` window columns followed by
         the summary columns (module docstring), and ``cache_len`` stays the
         LOGICAL budget in positions, which the table no longer spans.
+    ring_tokens, window_pool_tokens:
+        Two page budgets (module docstring): the sliding layers' window
+        (0 = one budget).  The slot's table is ``ring_tokens / page`` window
+        columns, ids into the window budget, then ``cache_len / page`` full
+        columns; ``pool_tokens`` sizes the full budget and
+        ``window_pool_tokens`` the window budget (0 = ``num_slots`` rings).
     """
 
     def __init__(self, num_slots: int, max_out_tokens: int, *,
                  page_tokens: int = 0, pool_tokens: int = 0,
-                 window_tokens: int = 0, chunk_tokens: int = 0):
+                 window_tokens: int = 0, chunk_tokens: int = 0,
+                 ring_tokens: int = 0, window_pool_tokens: int = 0):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
         self.page = int(page_tokens) or default_page_tokens(max_out_tokens)
         self.window, self.chunk = int(window_tokens), int(chunk_tokens)
+        self.ring = int(ring_tokens)
         self.cache_len = -(-int(max_out_tokens) // self.page) * self.page
-        if self.window:
+        if self.ring:
+            if self.window or self.ring % self.page:
+                raise ValueError(
+                    f"a ring of {self.ring} tokens must be a whole number "
+                    f"of {self.page}-token pages (and not EVA's window)")
+            self.window_pages = self.ring // self.page
+            self.summary_pages = 0
+            self.slot_pages = self.window_pages + self.cache_len // self.page
+        elif self.window:
             if self.window % self.page:
                 raise ValueError(
                     f"EVA window of {self.window} tokens is not a whole "
@@ -165,8 +226,9 @@ class PagedKVPool:
         else:
             self.window_pages, self.summary_pages = 0, 0
             self.slot_pages = self.cache_len // self.page
-        want = int(pool_tokens) or num_slots * self.slot_pages * self.page
-        usable = max(self.slot_pages, -(-want // self.page))
+        full_pages = self.slot_pages - (self.window_pages if self.ring else 0)
+        want = int(pool_tokens) or num_slots * full_pages * self.page
+        usable = max(full_pages, -(-want // self.page))
         self.num_pages = usable + 1          # + the reserved junk page 0
         self.num_slots = num_slots
         # unallocated entries point at the junk page
@@ -182,14 +244,30 @@ class PagedKVPool:
         # LIFO free list: released pages are reused first (locality, and
         # deterministic reuse for the preempt-resume tests)
         self._free: List[int] = list(range(usable, 0, -1))
+        # the window budget of two: the same three structures again
+        self.num_window_pages = 0
+        if self.ring:
+            want_w = (int(window_pool_tokens)
+                      or num_slots * self.window_pages * self.page)
+            usable_w = max(self.window_pages, -(-want_w // self.page))
+            self.num_window_pages = usable_w + 1
+            self._owned_win: List[List[int]] = [[] for _ in range(num_slots)]
+            self._ref_win = np.zeros(self.num_window_pages, np.int32)
+            self._free_win: List[int] = list(range(usable_w, 0, -1))
 
     # -- allocation ----------------------------------------------------
-    def pages_for(self, tokens: int) -> int:
+    def pages_for(self, tokens: int, kind: Optional[str] = None) -> int:
         """Pages a slot holds once positions ``[0, tokens)`` are written.
         Full attention: one every ``page`` tokens.  EVA: the window's pages
         (at most ``W / page``, then reused in place) plus the pages of the
-        summary rows of the windows that have closed."""
+        summary rows of the windows that have closed.  Two budgets: ``kind``
+        ``"window"`` (the ring: ``ceil(min(tokens, W) / page)``) or
+        ``"full"`` (``ceil(tokens / page)``); None is their sum."""
         tokens = int(tokens)
+        if self.ring:
+            n = {"window": -(-min(tokens, self.ring) // self.page),
+                 "full": -(-tokens // self.page)}
+            return n[kind] if kind else n["window"] + n["full"]
         if not self.window:
             return -(-tokens // self.page)
         return (-(-min(tokens, self.window) // self.page)
@@ -204,6 +282,8 @@ class PagedKVPool:
         if tokens > self.cache_len:
             raise ValueError(f"slot needs {tokens} tokens > per-slot budget "
                              f"{self.cache_len}")
+        if self.ring:
+            return self._ensure_both(slot, tokens)
         owned = self._owned[slot]
         need = self.pages_for(tokens)
         while len(owned) < need:
@@ -212,6 +292,26 @@ class PagedKVPool:
             p = self._free.pop()
             self.page_table[slot, len(owned)] = p
             owned.append(p)
+            self._ref[p] += 1
+        return True
+
+    def _ensure_both(self, slot: int, tokens: int) -> bool:
+        """Two budgets: grant the window AND the full pages the slot lacks,
+        or, where either budget is short, none of them."""
+        win, full = self._owned_win[slot], self._owned[slot]
+        more_w = self.pages_for(tokens, "window") - len(win)
+        more_f = self.pages_for(tokens, "full") - len(full)
+        if more_w > len(self._free_win) or more_f > len(self._free):
+            return False
+        for _ in range(more_w):
+            p = self._free_win.pop()
+            self.page_table[slot, len(win)] = p
+            win.append(p)
+            self._ref_win[p] += 1
+        for _ in range(more_f):
+            p = self._free.pop()
+            self.page_table[slot, self.window_pages + len(full)] = p
+            full.append(p)
             self._ref[p] += 1
         return True
 
@@ -261,6 +361,12 @@ class PagedKVPool:
                 self._free.append(p)
                 freed += 1
         owned.clear()
+        if self.ring:
+            for p in self._owned_win[slot]:
+                self._ref_win[p] -= 1
+                self._free_win.append(p)
+                freed += 1
+            self._owned_win[slot].clear()
         self.page_table[slot, :] = 0
         return freed
 
@@ -289,11 +395,14 @@ class PagedKVPool:
     def pages_used(self) -> int:
         """Distinct physical pages referenced by at least one slot (a
         shared page counts once — it occupies one page of HBM)."""
-        return int((self._ref > 0).sum())
+        used = int((self._ref > 0).sum())
+        return used + int((self._ref_win > 0).sum()) if self.ring else used
 
     @property
     def pages_free(self) -> int:
-        return len(self._free)
+        """Free pages (two budgets: of both; a slot may still be refused
+        with pages free, in the other budget)."""
+        return len(self._free) + (len(self._free_win) if self.ring else 0)
 
     @property
     def pages_cached(self) -> int:
@@ -302,12 +411,17 @@ class PagedKVPool:
         return len(self._cached)
 
     def slot_pages_used(self, slot: int) -> int:
-        return len(self._owned[slot])
+        return len(self._owned[slot]) + (
+            len(self._owned_win[slot]) if self.ring else 0)
 
     def pages_used_by_kind(self) -> Dict[str, int]:
         """Pages held by slots as ``{"window": n, "summary": n}`` (EVA; a
-        slot's first ``W / page`` pages are its window's).  Under full
-        attention every page counts as ``"window"``."""
+        slot's first ``W / page`` pages are its window's), or ``{"window":
+        n, "full": n}`` (two budgets).  Under full attention every page
+        counts as ``"window"``."""
+        if self.ring:
+            return {"window": sum(len(o) for o in self._owned_win),
+                    "full": sum(len(o) for o in self._owned)}
         held = [len(o) for o in self._owned]
         cap = self.window_pages or self.slot_pages
         window = sum(min(n, cap) for n in held)
@@ -331,22 +445,33 @@ class PagedKVPool:
         """Invariant probe (tests): every non-junk page is accounted for
         exactly once across {slot-referenced, cache-pinned, free} —
         refcounts equal the number of owning slots, pages no slot or cache
-        holds are all on the free list, and nothing live is free."""
+        holds are all on the free list, and nothing live is free.  Two
+        budgets: the same of each."""
+        self._check_budget(self._owned, self._ref, self._free, self._cached,
+                           self.num_pages, "")
+        if self.ring:
+            self._check_budget(self._owned_win, self._ref_win,
+                               self._free_win, set(), self.num_window_pages,
+                               "window ")
+
+    @staticmethod
+    def _check_budget(owned, ref, free_list, cached, num_pages, what) -> None:
         counts: Dict[int, int] = {}
-        for o in self._owned:
-            assert len(o) == len(set(o)), f"slot owns a page twice: {o}"
+        for o in owned:
+            assert len(o) == len(set(o)), f"slot owns a {what}page twice: {o}"
             for p in o:
                 counts[p] = counts.get(p, 0) + 1
-        assert 0 not in counts and 0 not in self._free \
-            and 0 not in self._cached, "junk page allocated"
-        for p in range(1, self.num_pages):
-            assert self._ref[p] == counts.get(p, 0), (
-                f"page {p}: refcount {self._ref[p]} != "
+        assert 0 not in counts and 0 not in free_list \
+            and 0 not in cached, f"{what}junk page allocated"
+        for p in range(1, num_pages):
+            assert ref[p] == counts.get(p, 0), (
+                f"{what}page {p}: refcount {ref[p]} != "
                 f"{counts.get(p, 0)} owning slot(s)")
-        free = set(self._free)
-        assert len(free) == len(self._free), "page on the free list twice"
-        live = set(counts) | self._cached
-        assert not (free & live), f"live pages on the free list: {free & live}"
-        assert sorted(free | live) == list(range(1, self.num_pages)), (
-            f"leaked pages: referenced={sorted(counts)} "
-            f"cached={sorted(self._cached)} free={sorted(free)}")
+        free = set(free_list)
+        assert len(free) == len(free_list), f"{what}page on the free list twice"
+        live = set(counts) | cached
+        assert not (free & live), (
+            f"live {what}pages on the free list: {free & live}")
+        assert sorted(free | live) == list(range(1, num_pages)), (
+            f"leaked {what}pages: referenced={sorted(counts)} "
+            f"cached={sorted(cached)} free={sorted(free)}")

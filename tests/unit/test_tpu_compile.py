@@ -441,6 +441,78 @@ def test_evabyte_programs_never_copy_the_pool(v5e, monkeypatch):
         assert name in text, name
 
 
+def test_trinity_cell_programs_compile_without_copying_a_budget(
+        v5e, monkeypatch):
+    """ISSUE 36: the chunk program (bucket 1,024) and the decode block of
+    the ``trinity-large-L5-ep8.serve-mixed-16k`` cell (the published widths:
+    48 / 8 heads x 128, a GQA group of 6; the pattern cut to [s | s, f], which
+    changes no shape; the full budget cut to 65,536 positions) compile for
+    the v5e: both page budgets stay where they are (no copy or gather the
+    size of either), and the decode block carries the paged attention and
+    append kernels and the expert block."""
+    import json
+
+    from deepspeed_tpu.comm.mesh import build_mesh
+    from deepspeed_tpu.models import CausalLM, ModelConfig
+    from deepspeed_tpu.models.fused_decode import inject_decode_params
+    from deepspeed_tpu.ops.pallas import common
+    from deepspeed_tpu.serving.engine import ServingEngine
+
+    monkeypatch.setattr(common, "default_impl", lambda: "pallas")
+    bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                         "benchmarks")
+    with open(os.path.join(bench, "configs",
+                           "trinity-large-L5-ep8.json")) as f:
+        fields = dict(json.load(f)["model_config"], num_layers=3,
+                      layer_types=["sliding_attention"] * 2
+                      + ["full_attention"])
+    with open(os.path.join(
+            bench, "workloads",
+            "trinity-large-L5-ep8.serve-mixed-16k.json")) as f:
+        engine = dict(json.load(f)["engine"], dtype="bfloat16",
+                      kv_pool_tokens=65536, num_slots=8)
+    (device,) = v5e.device_set
+    model = CausalLM(ModelConfig(**fields), build_mesh(devices=[device]))
+    serve = ServingEngine(model, engine)
+    assert (serve.pool.window_pages, serve.pool.slot_pages) == (16, 80)
+    smallest = min(v.nbytes for v in serve._cache.values())
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e), tree)
+
+    shapes = jax.eval_shape(
+        lambda key: jax.tree.map(lambda x: x.astype(BF16), model.init(key)),
+        jax.random.PRNGKey(0))
+    carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=v5e)
+    bucket = serve.prefill_chunk
+    chunk = serve._prefill_fn(bucket).lower(
+        on_chip(shapes), on_chip(serve._cache), on_chip(carries),
+        i32(serve.pool.slot_pages), i32(1, bucket), i32(5),
+        on_chip(serve._rng)).compile()
+    serve.engine._dparams = jax.eval_shape(
+        lambda p: inject_decode_params(p, model.config), shapes)
+    block = serve._block().lower(
+        on_chip(serve.engine._dparams), on_chip(serve._cache),
+        *on_chip(carries), i32(serve.num_slots), i32(serve.num_slots),
+        on_chip(serve._rng),
+        i32(serve.num_slots, serve.pool.slot_pages)).compile()
+    import math
+
+    for program in (chunk, block):
+        for name, shape, op in re.findall(
+                r"^\s*(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
+                program.as_text(), re.M):
+            size = 2 * math.prod(int(d) for d in shape.split(","))
+            assert not (size >= smallest / 2
+                        and (op == "copy" or "gather" in name)), (name, shape)
+    text = block.as_text()
+    for name in ("flash_decode_paged", "paged_kv_append", "fused_norm_qkv",
+                 "fused_proj_norm", "fused_mlp", "fused_moe_mlp"):
+        assert name in text, name
+
+
 def test_last_chunk_program_aliases_cache_and_carries_at_serve_chat(v5e):
     """ISSUE 28: the chunk program of the ``mistral-7b-L8.serve-chat`` cell
     (64 slots, pages of 256, bucket 256; two layers of the cell's eight,
