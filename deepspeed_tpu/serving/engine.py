@@ -243,6 +243,35 @@ def _eva_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
             f"straddle a window boundary")
 
 
+def _slot_view(v, pt_row, cols):
+    """The pages of pool entry ``v`` ``[L, pages, Hkv, page, D]`` that a
+    slot's page-table row names at columns ``cols``, as the contiguous
+    ``[L, 1, Hkv, len(cols) * page, D]`` the prefill forward takes: one
+    slice a page (a gather through the table, ``v[:, pt_row]``, compiles on
+    the v5e to a read and a write-back of the whole donated pool)."""
+    g = jnp.concatenate(
+        [jax.lax.dynamic_slice_in_dim(v, pt_row[c], 1, axis=1)
+         for c in cols], axis=1)                   # [L, n, Hkv, page, D]
+    L, n, Hkv, page, D = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(L, 1, Hkv, n * page, D)
+
+
+def _slot_write_back(dst, s, pt_row, col0, pages):
+    """:func:`_slot_view`'s inverse, in place in the donated pool: page
+    ``i`` of the view ``s`` (a Python or a traced index, for each ``i`` of
+    ``pages``) goes to the pool page at column ``col0 + i`` of the row.
+    Columns that name one pool page (unallocated entries all name junk page
+    0) are written in turn and the last wins."""
+    L, _, Hkv, S, D = s.shape
+    page = dst.shape[3]
+    paged = s.reshape(L, Hkv, S // page, page, D).transpose(0, 2, 1, 3, 4)
+    for i in pages:
+        one = jax.lax.dynamic_slice_in_dim(paged, i, 1, axis=1)
+        dst = jax.lax.dynamic_update_slice_in_dim(
+            dst, one, pt_row[col0 + i], axis=1)
+    return dst
+
+
 class ServingEngine:
     """Continuous-batching serving over an :class:`InferenceEngine`'s
     weights (plain + kernel-injected views, dtype, mesh all reused).
@@ -2009,10 +2038,12 @@ class ServingEngine:
         (batch-1) prefill forward at the chunk's absolute offset, write the
         slot back, and sample the next token from the last real position's
         logits — the token stays a DEVICE scalar so admission never syncs
-        the host.  Paged layout: the slot's pages are GATHERED into the
-        same contiguous logical view, the identical forward runs, and the
-        pages scatter back (prefill is matmul-bound; the gather cost is
-        one slot window per chunk, and the decode hot path never pays it).
+        the host.  Paged layout: the slot's pages are sliced out of the
+        pool one by one into the same contiguous logical view
+        (``_slot_view``), the identical forward runs, and each page goes
+        back in place in the donated pool (``_slot_write_back``): a chunk
+        reads and writes ``slot_pages`` pages of K and V whatever the
+        pool's size, and the decode hot path never builds the view.
         Pad rows in [off+c, off+cb) hold junk K/V but are only ever
         attended AFTER being overwritten by the next chunk / decode step
         (queries attend key_pos <= q_pos, and every row <= q_pos has been
@@ -2030,9 +2061,6 @@ class ServingEngine:
         self._m_compiles.inc()
         model = self.module
         do_sample, temperature, top_k, top_p = self._sample
-        eva = self._eva
-        if self.paged:
-            maxp, page = self.pool.slot_pages, self.pool.page
         if self._afmoe:
             forward = self._two_budget_forward(cb)
 
@@ -2043,31 +2071,14 @@ class ServingEngine:
             def view(v):                 # the slot's rows, contiguous
                 if pt_row is None:
                     return jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-                if eva:
-                    # a dozen pages a slot whatever its length: a slice
-                    # each, where the gather below walks the whole pool
-                    g = jnp.concatenate(
-                        [jax.lax.dynamic_slice_in_dim(v, pt_row[i], 1, axis=1)
-                         for i in range(maxp)], axis=1)
-                else:
-                    g = v[:, pt_row]            # [L, maxp, Hkv, page, D]
-                L, mp, Hkv, pg, D = g.shape
-                return g.transpose(0, 2, 1, 3, 4).reshape(
-                    L, 1, Hkv, mp * pg, D)
+                return _slot_view(v, pt_row, range(pt_row.shape[0]))
 
             def write_back(dst, s):
                 if pt_row is None:
                     return jax.lax.dynamic_update_slice_in_dim(
                         dst, s, slot, axis=1)
-                L, _, Hkv, _, D = s.shape
-                pages = s.reshape(L, Hkv, maxp, page, D).transpose(
-                    0, 2, 1, 3, 4)
-                if eva:
-                    for i in range(maxp):
-                        dst = jax.lax.dynamic_update_slice_in_dim(
-                            dst, pages[:, i:i + 1], pt_row[i], axis=1)
-                    return dst
-                return dst.at[:, pt_row].set(pages)
+                return _slot_write_back(dst, s, pt_row, 0,
+                                        range(pt_row.shape[0]))
 
             if self._afmoe:
                 logits, out = forward(params, cache, pt_row, chunk, start,
@@ -2098,46 +2109,30 @@ class ServingEngine:
         """The chunk program's forward over two page budgets
         (serving/paged_kv.py): ``(params, cache, page-table row, chunk [1,
         cb], start, real tokens) -> (logits, cache)``.  The slot's ring
-        pages and its full pages are sliced out one by one into the
-        contiguous views ``afmoe.cached_layers`` takes (a gather through the
-        table walks the whole pool); the forward attends the ring BEFORE it
-        appends, and the pages go back: all of the ring's, and of the full
-        ones only those the chunk's ``cb`` rows can have touched."""
+        pages and its full pages are sliced out into the contiguous views
+        ``afmoe.cached_layers`` takes (``_slot_view``); the forward attends
+        the ring BEFORE it appends, and the pages go back
+        (``_slot_write_back``): all of the ring's, and of the full ones only
+        those the chunk's ``cb`` rows can have touched."""
         model, page = self.module, self.pool.page
         wp = self.pool.window_pages
         fp = self.pool.slot_pages - wp
         touched = -(-cb // page) + 1
 
         def forward(params, cache, pt_row, chunk, start, valid_len):
-            def view(v, cols):
-                g = jnp.concatenate(
-                    [jax.lax.dynamic_slice_in_dim(v, pt_row[c], 1, axis=1)
-                     for c in cols], axis=1)       # [L, n, Hkv, page, D]
-                L, n, Hkv, pg, D = g.shape
-                return g.transpose(0, 2, 1, 3, 4).reshape(L, 1, Hkv, n * pg, D)
-
-            def write_back(dst, s, col0, firsts):
-                L, _, Hkv, S, D = s.shape
-                pages = s.reshape(L, Hkv, S // page, page, D).transpose(
-                    0, 2, 1, 3, 4)
-                for i in firsts:                   # index among the view's
-                    one = jax.lax.dynamic_slice_in_dim(pages, i, 1, axis=1)
-                    dst = jax.lax.dynamic_update_slice_in_dim(
-                        dst, one, pt_row[col0 + i], axis=1)
-                return dst
-
             win, full = range(wp), range(wp, wp + fp)
-            sub = {"k_win": view(cache["k_win"], win),
-                   "v_win": view(cache["v_win"], win),
-                   "k_full": view(cache["k_full"], full),
-                   "v_full": view(cache["v_full"], full)}
+            sub = {"k_win": _slot_view(cache["k_win"], pt_row, win),
+                   "v_win": _slot_view(cache["v_win"], pt_row, win),
+                   "k_full": _slot_view(cache["k_full"], pt_row, full),
+                   "v_full": _slot_view(cache["v_full"], pt_row, full)}
             logits, sub = forward_with_cache(model, params, chunk, sub, start,
                                              valid_len=valid_len)
             first = jnp.minimum(start // page, fp - 1)
             spans = {"win": (0, list(range(wp))),
                      "full": (wp, [jnp.minimum(first + i, fp - 1)
                                    for i in range(min(touched, fp))])}
-            out = {k: write_back(cache[k], sub[k], *spans[k.split("_")[1]])
+            out = {k: _slot_write_back(cache[k], sub[k], pt_row,
+                                       *spans[k.split("_")[1]])
                    for k in cache}
             return logits, out
 

@@ -285,6 +285,77 @@ def test_int8_kv_paged_parity(setup, rng):
                                       err_msg=f"int8-KV paged request {i}")
 
 
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
+def test_chunk_program_through_a_scattered_table_row(setup, rng, quantized):
+    """ISSUE 37: the chunk program builds a slot's view from its pages by
+    slices (``_slot_view`` / ``_slot_write_back``).  Through a row whose
+    pages lie out of order in a pool full of other slots' rows, with its
+    unallocated tail all naming junk page 0, a 16-token chunk across a page
+    boundary and then a 5-token last chunk give the tokens and the K / V
+    rows (int8 KV: the scales too) that the fixed-slot layout gives at the
+    same positions, and no page of another slot changes by a bit."""
+    model, params, _ = setup
+    cfg = {"dtype": "bfloat16", "max_out_tokens": 64, "kv_page_tokens": 8,
+           "quantize_kv_cache": quantized}
+
+    def engine(**over):
+        s = deepspeed_tpu.init_serving(model, config={**cfg, **over},
+                                       num_slots=3, prefill_chunk=16,
+                                       decode_block_tokens=3)
+        s.set_params(params)
+        return s
+
+    paged, fixed = engine(), engine(paged_kv_cache=False)
+    slot, row = 1, np.array([5, 2, 7, 0, 0, 0, 0, 0], np.int32)
+    assert paged.pool.slot_pages == len(row) and paged.pool.page == 8
+    keys = iter(jax.random.split(rng, 8))
+
+    def junk(x):                         # finite rows of other requests
+        if x.dtype == jnp.int8:
+            return jax.random.randint(next(keys), x.shape, -127, 128,
+                                      jnp.int8)
+        return jax.random.uniform(next(keys), x.shape, jnp.float32,
+                                  0.5, 1.5).astype(x.dtype)
+
+    pool = {k: (junk(v) if v.ndim == 5 else v)
+            for k, v in paged._cache.items()}
+    before = {k: np.asarray(v) for k, v in pool.items() if v.ndim == 5}
+    prompt = np.asarray(jax.random.randint(next(keys), (21,), 0, 256),
+                        np.int32)
+
+    def run(serve, cache, pt_row):
+        toks = []
+        carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
+        for start, cb, c in ((0, 16, 16), (16, 8, 5)):
+            chunk = np.zeros((1, cb), np.int32)
+            chunk[0, :c] = prompt[start:start + c]
+            meta = jnp.asarray([slot, start, c - 1, 0, -1], jnp.int32)
+            tok, cache, carries = serve._prefill_fn(cb)(
+                serve.engine._params, cache, carries, pt_row,
+                jnp.asarray(chunk), meta, jax.random.PRNGKey(0))
+            toks.append(int(tok))
+            assert int(carries[0][slot]) == toks[-1]
+            assert int(carries[1][slot]) == start + c
+        return toks, {k: np.asarray(v) for k, v in cache.items()
+                      if v.ndim == 5}
+
+    toks_p, after = run(paged, pool, jnp.asarray(row))
+    toks_f, slots = run(fixed, fixed._cache, None)
+    assert toks_p == toks_f
+    assert set(after) == ({"k", "v", "k_scale", "v_scale"} if quantized
+                          else {"k", "v"})
+    theirs = [p for p in range(paged.pool.num_pages)
+              if p not in set(row.tolist())]
+    for name, pages in after.items():
+        L, _, Hkv, page, D = pages.shape
+        mine = pages[:, row[:3]].transpose(0, 2, 1, 3, 4).reshape(
+            L, Hkv, 3 * page, D)
+        np.testing.assert_array_equal(
+            mine[:, :, :21], slots[name][:, slot, :, :21], err_msg=name)
+        np.testing.assert_array_equal(pages[:, theirs],
+                                      before[name][:, theirs], err_msg=name)
+
+
 def test_fixed_slot_fallback_parity(setup, rng):
     """``paged_kv_cache=False`` keeps the PR 1 contiguous per-slot layout
     working (the reference the paged path is tested against)."""

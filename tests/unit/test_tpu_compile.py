@@ -370,7 +370,109 @@ def test_kernels_compile_at_the_evabyte_serve_doc_shape(v5e, kernel):
     assert _custom_calls(fn, v5e, *shapes) >= want
 
 
-def test_evabyte_programs_never_copy_the_pool(v5e, monkeypatch):
+class _ServeCell:
+    """A serve cell of the benchmark as ``benchmarks/configs`` and
+    ``benchmarks/workloads`` describe it (``fields`` / ``engine`` overridden
+    where a test cuts depth or pool, which changes no shape a copy or an
+    alias turns on), its engine built on this process's CPU devices and its
+    programs lowered from shapes for the described v5e."""
+
+    def __init__(self, v5e, config, workload, fields=(), engine=()):
+        import json
+
+        from deepspeed_tpu.comm.mesh import build_mesh
+        from deepspeed_tpu.models import CausalLM, ModelConfig
+        from deepspeed_tpu.serving.engine import ServingEngine
+
+        bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
+                             "benchmarks")
+        with open(os.path.join(bench, "configs", config + ".json")) as f:
+            fields = dict(json.load(f)["model_config"], **dict(fields))
+        with open(os.path.join(bench, "workloads", workload + ".json")) as f:
+            engine = dict(json.load(f)["engine"], dtype="bfloat16",
+                          **dict(engine))
+        (device,) = v5e.device_set
+        self.v5e = v5e
+        self.model = CausalLM(ModelConfig(**fields),
+                              build_mesh(devices=[device]))
+        self.serve = ServingEngine(self.model, engine)
+        self.params = jax.eval_shape(
+            lambda key: jax.tree.map(lambda x: x.astype(BF16),
+                                     self.model.init(key)),
+            jax.random.PRNGKey(0))
+        self.smallest_pool = min(v.nbytes for v in self.serve._cache.values())
+
+    def _on_chip(self, tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=self.v5e), tree)
+
+    def _i32(self, *shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=self.v5e)
+
+    def _carries(self):
+        s = self.serve
+        return self._on_chip((s._last_dev, s._pos_dev, s._act_dev))
+
+    def chunk(self, bucket):
+        """The chunk program of one prefill bucket, compiled."""
+        s = self.serve
+        return s._prefill_fn(bucket).lower(
+            self._on_chip(self.params), self._on_chip(s._cache),
+            self._carries(), self._i32(s.pool.slot_pages),
+            self._i32(1, bucket), self._i32(5),
+            self._on_chip(s._rng)).compile()
+
+    def block(self):
+        """The decode block, compiled."""
+        from deepspeed_tpu.models.fused_decode import inject_decode_params
+
+        s = self.serve
+        # stands in for the injected view _block() reads off the engine
+        s.engine._dparams = jax.eval_shape(
+            lambda p: inject_decode_params(p, self.model.config), self.params)
+        return s._block().lower(
+            self._on_chip(s.engine._dparams), self._on_chip(s._cache),
+            *self._carries(), self._i32(s.num_slots), self._i32(s.num_slots),
+            self._on_chip(s._rng),
+            self._i32(s.num_slots, s.pool.slot_pages)).compile()
+
+    def assert_pools_stay_in_place(self, program):
+        """No instruction of ``program`` moves half a pool's bytes or more:
+        no ``copy``, and no gather or scatter by op or by name (a gather
+        through a page-table row came out as ``mini-gather-slice``s of half
+        the pool each, the whole pool read and written back)."""
+        import math
+
+        for name, shape, op in re.findall(
+                r"^\s*(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
+                program.as_text(), re.M):
+            size = 2 * math.prod(int(d) for d in shape.split(","))
+            moved = op == "copy" or any(
+                w in part for w in ("gather", "scatter")
+                for part in (op, name))
+            assert not (size >= self.smallest_pool / 2 and moved), (
+                name, shape, op)
+
+    def assert_donations_taken(self, program, donated):
+        """The ``donated`` arguments behind the parameters' leaves come back
+        in their own buffers: results are the token, then they in order."""
+        text = program.as_text()
+        header = text[:text.index("entry_computation_layout")]
+        aliased = {int(arg): int(out) for out, arg in re.findall(
+            r"\{(\d+)\}: \((\d+), \{\}", header)}
+        first = len(jax.tree.leaves(self.params))
+        assert aliased == {first + i: 1 + i for i in range(donated)}
+
+
+@pytest.fixture
+def chip_kernels(monkeypatch):
+    """This process's devices are CPUs: take the kernels the chip would."""
+    from deepspeed_tpu.ops.pallas import common
+
+    monkeypatch.setattr(common, "default_impl", lambda: "pallas")
+
+
+def test_evabyte_programs_never_copy_the_pool(v5e, chip_kernels):
     """ISSUE 32: the chunk program and the decode block of the
     ``evabyte-L6.serve-doc`` cell (two layers of its six and a quarter of
     its pool, which changes no shape the copies turn on) compile for the
@@ -378,62 +480,14 @@ def test_evabyte_programs_never_copy_the_pool(v5e, monkeypatch):
     ``lax.cond`` around the window close cost two) and no gather over half
     of one (``v[:, pt_row]`` on the slot's pages did), so 4.9 GB of weights
     and an 8.8 GB pool fit the chip."""
-    import json
-
-    from deepspeed_tpu.comm.mesh import build_mesh
-    from deepspeed_tpu.models import CausalLM, ModelConfig
-    from deepspeed_tpu.models.fused_decode import inject_decode_params
-    from deepspeed_tpu.ops.pallas import common
-    from deepspeed_tpu.serving.engine import ServingEngine
-
-    # this process's devices are CPUs: take the kernels the chip would
-    monkeypatch.setattr(common, "default_impl", lambda: "pallas")
-    bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                         "benchmarks")
-    with open(os.path.join(bench, "configs", "evabyte-L6.json")) as f:
-        fields = dict(json.load(f)["model_config"], num_layers=2)
-    with open(os.path.join(bench, "workloads",
-                           "evabyte-L6.serve-doc.json")) as f:
-        engine = dict(json.load(f)["engine"], dtype="bfloat16",
-                      kv_pool_tokens=16384)
-    (device,) = v5e.device_set
-    model = CausalLM(ModelConfig(**fields), build_mesh(devices=[device]))
-    serve = ServingEngine(model, engine)
-    assert (serve.pool.window_pages, serve.pool.summary_pages) == (8, 4)
-    pool_bytes = serve._cache["k"].nbytes
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=v5e), tree)
-
-    shapes = jax.eval_shape(
-        lambda key: jax.tree.map(lambda x: x.astype(BF16), model.init(key)),
-        jax.random.PRNGKey(0))
-    carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=v5e)
-    bucket = serve.prefill_chunk
-    chunk = serve._prefill_fn(bucket).lower(
-        on_chip(shapes), on_chip(serve._cache), on_chip(carries),
-        i32(serve.pool.slot_pages), i32(1, bucket), i32(5),
-        on_chip(serve._rng)).compile()
-    # stands in for the injected view _block() reads off the engine
-    serve.engine._dparams = jax.eval_shape(
-        lambda p: inject_decode_params(p, model.config), shapes)
-    block = serve._block().lower(
-        on_chip(serve.engine._dparams), on_chip(serve._cache),
-        *on_chip(carries), i32(serve.num_slots), i32(serve.num_slots),
-        on_chip(serve._rng),
-        i32(serve.num_slots, serve.pool.slot_pages)).compile()
-    import math
-    import re
-
-    for program in (chunk, block):
-        for name, shape, op in re.findall(
-                r"^\s*(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
-                program.as_text(), re.M):
-            size = 2 * math.prod(int(d) for d in shape.split(","))
-            assert not (size >= pool_bytes / 2
-                        and (op == "copy" or "gather" in name)), (name, shape)
+    cell = _ServeCell(v5e, "evabyte-L6", "evabyte-L6.serve-doc",
+                      fields=dict(num_layers=2),
+                      engine=dict(kv_pool_tokens=16384))
+    pool = cell.serve.pool
+    assert (pool.window_pages, pool.summary_pages) == (8, 4)
+    cell.assert_pools_stay_in_place(cell.chunk(cell.serve.prefill_chunk))
+    block = cell.block()
+    cell.assert_pools_stay_in_place(block)
     text = block.as_text()
     for name in ("eva_decode_paged", "eva_summarize_paged",
                  "paged_kv_append", "fused_norm_qkv", "fused_proj_norm",
@@ -441,8 +495,41 @@ def test_evabyte_programs_never_copy_the_pool(v5e, monkeypatch):
         assert name in text, name
 
 
+# the power-of-two chunk buckets from 8 up to the chat cells' prefill_chunk
+CHAT_BUCKETS = [8, 16, 32, 64, 128, 256]
+
+
+@pytest.fixture(scope="module")
+def chat_cells():
+    """One engine a chat cell for all of its buckets' cases."""
+    return {}
+
+
+@pytest.mark.parametrize("bucket", CHAT_BUCKETS)
+@pytest.mark.parametrize("cell", ["mistral-7b-L8", "olmoe-1b-7b-L8"])
+def test_chat_chunk_programs_never_copy_the_pool(v5e, chip_kernels,
+                                                 chat_cells, cell, bucket):
+    """ISSUE 37: every chunk program of the ``mistral-7b-L8.serve-chat`` and
+    ``olmoe-1b-7b-L8.serve-chat`` cells (two layers of their eight) takes a
+    slot's four pages out of the pool by slices and puts them back in
+    place: compiled for the v5e, nothing the size of half a pool is copied,
+    gathered or scattered (the gather ``v[:, pt_row]`` read and rewrote the
+    541 MB / 1.08 GB pools, 3.3 / 6.6 ms of every chunk program), and the
+    donated pools and carries keep their buffers."""
+    if cell not in chat_cells:
+        chat_cells[cell] = _ServeCell(v5e, cell, cell + ".serve-chat",
+                                      fields=dict(num_layers=2))
+    built = chat_cells[cell]
+    serve = built.serve
+    assert (serve.pool.slot_pages, serve.pool.page) == (4, 256)
+    assert bucket <= serve.prefill_chunk == CHAT_BUCKETS[-1]
+    program = built.chunk(bucket)
+    built.assert_pools_stay_in_place(program)
+    built.assert_donations_taken(program, donated=5)
+
+
 def test_trinity_cell_programs_compile_without_copying_a_budget(
-        v5e, monkeypatch):
+        v5e, chip_kernels):
     """ISSUE 36: the chunk program (bucket 1,024) and the decode block of
     the ``trinity-large-L5-ep8.serve-mixed-16k`` cell (the published widths:
     48 / 8 heads x 128, a GQA group of 6; the pattern cut to [s | s, f], which
@@ -450,63 +537,16 @@ def test_trinity_cell_programs_compile_without_copying_a_budget(
     the v5e: both page budgets stay where they are (no copy or gather the
     size of either), and the decode block carries the paged attention and
     append kernels and the expert block."""
-    import json
-
-    from deepspeed_tpu.comm.mesh import build_mesh
-    from deepspeed_tpu.models import CausalLM, ModelConfig
-    from deepspeed_tpu.models.fused_decode import inject_decode_params
-    from deepspeed_tpu.ops.pallas import common
-    from deepspeed_tpu.serving.engine import ServingEngine
-
-    monkeypatch.setattr(common, "default_impl", lambda: "pallas")
-    bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                         "benchmarks")
-    with open(os.path.join(bench, "configs",
-                           "trinity-large-L5-ep8.json")) as f:
-        fields = dict(json.load(f)["model_config"], num_layers=3,
-                      layer_types=["sliding_attention"] * 2
-                      + ["full_attention"])
-    with open(os.path.join(
-            bench, "workloads",
-            "trinity-large-L5-ep8.serve-mixed-16k.json")) as f:
-        engine = dict(json.load(f)["engine"], dtype="bfloat16",
-                      kv_pool_tokens=65536, num_slots=8)
-    (device,) = v5e.device_set
-    model = CausalLM(ModelConfig(**fields), build_mesh(devices=[device]))
-    serve = ServingEngine(model, engine)
-    assert (serve.pool.window_pages, serve.pool.slot_pages) == (16, 80)
-    smallest = min(v.nbytes for v in serve._cache.values())
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=v5e), tree)
-
-    shapes = jax.eval_shape(
-        lambda key: jax.tree.map(lambda x: x.astype(BF16), model.init(key)),
-        jax.random.PRNGKey(0))
-    carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, I32, sharding=v5e)
-    bucket = serve.prefill_chunk
-    chunk = serve._prefill_fn(bucket).lower(
-        on_chip(shapes), on_chip(serve._cache), on_chip(carries),
-        i32(serve.pool.slot_pages), i32(1, bucket), i32(5),
-        on_chip(serve._rng)).compile()
-    serve.engine._dparams = jax.eval_shape(
-        lambda p: inject_decode_params(p, model.config), shapes)
-    block = serve._block().lower(
-        on_chip(serve.engine._dparams), on_chip(serve._cache),
-        *on_chip(carries), i32(serve.num_slots), i32(serve.num_slots),
-        on_chip(serve._rng),
-        i32(serve.num_slots, serve.pool.slot_pages)).compile()
-    import math
-
-    for program in (chunk, block):
-        for name, shape, op in re.findall(
-                r"^\s*(\S+) = bf16\[([\d,]+)\]\S* ([\w-]+)\(",
-                program.as_text(), re.M):
-            size = 2 * math.prod(int(d) for d in shape.split(","))
-            assert not (size >= smallest / 2
-                        and (op == "copy" or "gather" in name)), (name, shape)
+    cell = _ServeCell(
+        v5e, "trinity-large-L5-ep8", "trinity-large-L5-ep8.serve-mixed-16k",
+        fields=dict(num_layers=3, layer_types=["sliding_attention"] * 2
+                    + ["full_attention"]),
+        engine=dict(kv_pool_tokens=65536, num_slots=8))
+    pool = cell.serve.pool
+    assert (pool.window_pages, pool.slot_pages) == (16, 80)
+    cell.assert_pools_stay_in_place(cell.chunk(cell.serve.prefill_chunk))
+    block = cell.block()
+    cell.assert_pools_stay_in_place(block)
     text = block.as_text()
     for name in ("flash_decode_paged", "paged_kv_append", "fused_norm_qkv",
                  "fused_proj_norm", "fused_mlp", "fused_moe_mlp"):
@@ -520,52 +560,17 @@ def test_last_chunk_program_aliases_cache_and_carries_at_serve_chat(v5e):
     every donated argument taken: K and V pools AND the decode block's
     three carries (``last``, ``pos``, ``active``), which the program now
     updates for its slot, come back in their own buffers."""
-    import json
-    import re
     import warnings
 
-    from deepspeed_tpu.comm.mesh import build_mesh
-    from deepspeed_tpu.models import CausalLM, ModelConfig
-    from deepspeed_tpu.serving.engine import ServingEngine
-
-    bench = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir,
-                         "benchmarks")
-    with open(os.path.join(bench, "configs", "mistral-7b-L8.json")) as f:
-        fields = dict(json.load(f)["model_config"], num_layers=2)
-    with open(os.path.join(bench, "workloads",
-                           "mistral-7b-L8.serve-chat.json")) as f:
-        engine = dict(json.load(f)["engine"], dtype="bfloat16")
-    (device,) = v5e.device_set
-    model = CausalLM(ModelConfig(**fields), build_mesh(devices=[device]))
-    serve = ServingEngine(model, engine)
+    cell = _ServeCell(v5e, "mistral-7b-L8", "mistral-7b-L8.serve-chat",
+                      fields=dict(num_layers=2))
+    serve = cell.serve
     assert (serve.num_slots, serve.pool.page) == (64, 256)
-    bucket = serve.prefill_chunk
-    assert bucket == 256
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=v5e), tree)
-
-    params = on_chip(jax.eval_shape(
-        lambda key: jax.tree.map(lambda x: x.astype(BF16), model.init(key)),
-        jax.random.PRNGKey(0)))
-    carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
+    assert serve.prefill_chunk == 256
     with warnings.catch_warnings():
         warnings.simplefilter("error")      # "donated buffers not usable"
-        text = serve._prefill_fn(bucket).lower(
-            params, on_chip(serve._cache), on_chip(carries),
-            jax.ShapeDtypeStruct((serve.pool.slot_pages,), I32, sharding=v5e),
-            jax.ShapeDtypeStruct((1, bucket), I32, sharding=v5e),
-            jax.ShapeDtypeStruct((5,), I32, sharding=v5e),
-            on_chip(serve._rng)).compile().as_text()
-    header = text[:text.index("entry_computation_layout")]
-    aliased = {int(arg): int(out) for out, arg in re.findall(
-        r"\{(\d+)\}: \((\d+), \{\}", header)}
-    first = len(jax.tree.leaves(params))
-    donated = len(jax.tree.leaves((serve._cache, carries)))
-    assert donated == 5
-    # results: the token, then the pools and the carries in argument order
-    assert aliased == {first + i: 1 + i for i in range(donated)}
+        program = cell.chunk(256)
+    cell.assert_donations_taken(program, donated=5)
 
 
 def test_train_zero3_loss_tail_keeps_logits_on_their_chip(v5e):
