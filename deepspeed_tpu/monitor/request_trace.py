@@ -261,9 +261,13 @@ class RequestTracer:
         rec["edges"].append((t, "preempted_wait"))
 
     def span(self, rid: int, kind: str, t0: float, t1: float,
-             tokens: int) -> None:
-        """One measured host dispatch window (``prefill_chunk`` /
-        ``decode_block`` / ``drain_fetch``) with its token count."""
+             tokens: int, seq: Optional[int] = None) -> None:
+        """One measured host window with its token count.  A
+        ``prefill_chunk`` / ``decode_block`` span is the ENQUEUE of that
+        program (the host's dispatch, not the program's run on the chip)
+        and carries the engine's launch number ``seq``, the one the
+        ``ds_serve_*_dispatch`` range of a profiler capture carries too;
+        ``drain_fetch`` is the blocking fetch of a block's tokens."""
         if not self.enabled:
             return
         rec = self._open.get(rid)
@@ -273,7 +277,7 @@ class RequestTracer:
         if len(spans) >= self._max_spans:
             rec["spans_dropped"] += 1
             return
-        spans.append((kind, t0, t1, tokens))
+        spans.append((kind, t0, t1, tokens, seq))
 
     def finish(self, rid: int, t: float, reason: str, n_out: int) -> None:
         """Terminal edge: close the timeline, compute the phase partition,
@@ -364,7 +368,7 @@ class RequestTracer:
     def _rec_json(rec: Dict[str, Any]) -> Dict[str, Any]:
         out = dict(rec)
         out["edges"] = [[t, ph] for t, ph in rec["edges"]]
-        out["spans"] = [[k, t0, t1, n] for k, t0, t1, n in rec["spans"]]
+        out["spans"] = [list(s) for s in rec["spans"]]
         return out
 
     def snapshot(self, limit: int = 32) -> Dict[str, Any]:
@@ -423,8 +427,10 @@ class RequestTracer:
                 events.append({"ph": "M", "pid": 1, "tid": t_sp,
                                "name": "thread_name",
                                "args": {"name": f"req {rid} spans"}})
-            for kind, t0, t1, n in rec["spans"]:
+            for kind, t0, t1, n, seq in rec["spans"]:
                 args = {"request_id": rid, "tokens": n}
+                if seq is not None:
+                    args["seq"] = seq
                 if trace:
                     args["trace"] = trace
                 events.append({"ph": "X", "pid": 1, "tid": t_sp,

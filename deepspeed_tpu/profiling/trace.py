@@ -46,18 +46,22 @@ class phase:
     the registry keeps.  A parent's self time is its own seconds minus its
     children's.  How often a phase was entered is not counted here: the
     code inside counts what it does (``ds_serve_steps_total``,
-    ``ds_serve_prefill_chunks_total``).  With no profiler session and the
-    registry disabled this is one ``TraceAnnotation`` enter/exit and one
-    branch.
+    ``ds_serve_prefill_chunks_total``).  ``meta`` (numbers, strings) goes
+    to the annotation as ``step_num`` does: the profiler's host tracer
+    keeps the event's name and reads the pairs back as the event's stats,
+    which is how a dispatch range says what it enqueued (``seq``,
+    ``request_id``).  With no profiler session and the registry disabled
+    this is one ``TraceAnnotation`` enter/exit and one branch.
     """
 
     __slots__ = ("_ann", "_seconds", "_t0")
 
     def __init__(self, name: str, registry: Optional[MetricsRegistry] = None,
-                 step_num: Optional[int] = None):
-        self._ann = (jax.profiler.TraceAnnotation(name) if step_num is None
+                 step_num: Optional[int] = None, **meta):
+        self._ann = (jax.profiler.TraceAnnotation(name, **meta)
+                     if step_num is None
                      else jax.profiler.StepTraceAnnotation(
-                         name, step_num=step_num))
+                         name, step_num=step_num, **meta))
         reg = registry if registry is not None else get_registry()
         self._seconds = (reg.counter(name + "_seconds_total")
                          if reg.enabled else None)

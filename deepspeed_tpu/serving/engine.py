@@ -502,6 +502,11 @@ class ServingEngine:
         self._owed = deque()
         self._drain_lag = 1
         self._next_block = 0
+        # launch number: one for every chunk program and every decode block
+        # enqueued, in enqueue order.  Its dispatch range carries it
+        # (``seq``), so does the request tracer's span: by it a program on
+        # the chip's ``XLA Modules`` line is found from the host's side
+        self._launch_seq = 0
         self.steps = 0
         self.metrics_server = None   # attached by init_serving(metrics_port=)
         # /profilez: windowed capture over scheduler iterations (decode
@@ -593,6 +598,19 @@ class ServingEngine:
             "first tokens fetched with a decode block already enqueued "
             "behind their chunk (over finished requests: the share of first "
             "tokens that cost the chip no gap)")
+        self._m_first_tokens = reg.counter(
+            "ds_serve_first_tokens_total",
+            "first tokens fetched right behind their chunk (stream / EOS / "
+            "last-token path): what ds_serve_first_token_overlapped_total "
+            "is a share of")
+        self._m_prefill_turns = reg.counter(
+            "ds_serve_prefill_turns_total",
+            "request-iterations in PREFILLING: a request that held a slot "
+            "with prompt left to compute, once an iteration")
+        self._m_prefill_turns_missed = reg.counter(
+            "ds_serve_prefill_turns_missed_total",
+            "of ds_serve_prefill_turns_total, those given no chunk: beyond "
+            "max_prefill_chunks, or refused pages")
         self._m_prefill_chunks = reg.counter(
             "ds_serve_prefill_chunks_total", "prefill chunks dispatched")
         self._m_prefill_toks = reg.counter(
@@ -817,8 +835,12 @@ class ServingEngine:
         # 2. chunked prefill, oldest admissions first (bounded per
         #    iteration so running slots' decode latency stays bounded)
         with self._phase("ds_serve_prefill"):
-            for req in self.scheduler.prefilling()[: self.max_prefill_chunks]:
+            waiting = self.scheduler.prefilling()
+            for req in waiting[: self.max_prefill_chunks]:
                 self._prefill_one_chunk(req)
+            for req in waiting[self.max_prefill_chunks:]:
+                if req.state == PREFILLING:  # not preempted by a chunk above
+                    self._prefill_turn(missed=True)
             if not self._active.any():
                 # no block will be queued behind the chunks: fetch now
                 self._settle_first_tokens()
@@ -1896,16 +1918,27 @@ class ServingEngine:
         self._page_gauges()
 
     # ------------------------------------------------------------------
+    def _prefill_turn(self, missed: bool) -> None:
+        """One iteration a request spent in PREFILLING, with a chunk or
+        without (``missed``: passed over, or refused pages)."""
+        self._m_prefill_turns.inc()
+        if missed:
+            self._m_prefill_turns_missed.inc()
+
     def _prefill_one_chunk(self, req: Request) -> None:
         if req.state != PREFILLING:      # preempted mid-iteration
             return
         t0 = time.perf_counter()
+        if not req.t_first_chunk:
+            req.t_first_chunk = t0       # its turn came; pages not yet asked
         slot, off = req.slot, req.prefill_pos
         prefix = req.prefix              # prompt (+ outputs after a resume)
         S = req.prefix_len
         c = min(self.prefill_chunk, S - off)
         if self.paged and not self._ensure_pages(req, off + c):
+            self._prefill_turn(missed=True)
             return                       # self-preempted: resumes later
+        self._prefill_turn(missed=False)
         last_chunk = off + c == S
         wake = False
         if last_chunk and not req.prefill_only:
@@ -1924,7 +1957,10 @@ class ServingEngine:
             # is the request's last (the device adds: unless it is the EOS)
             wake = (len(req.output_tokens) + 1 < req.max_new_tokens
                     and limit > S)
-        with self._phase("ds_serve_prefill_dispatch"):
+        self._launch_seq += 1
+        seq = self._launch_seq
+        with self._phase("ds_serve_prefill_dispatch", seq=seq,
+                         request_id=req.request_id, last=int(last_chunk)):
             cb = pow2_bucket(c, lo=8, cap=self.cache_len - off)
             chunk = np.zeros((1, cb), np.int32)
             chunk[0, :c] = prefix[off:off + c]
@@ -1942,7 +1978,7 @@ class ServingEngine:
             self._last_dev, self._pos_dev, self._act_dev = carries
             req.prefill_pos += c
             self._tracer.span(req.request_id, "prefill_chunk", t0,
-                              time.perf_counter(), c)
+                              time.perf_counter(), c, seq=seq)
             self._m_prefill_chunks.inc()
             self._m_prefill_toks.inc(c)
             if self._eva and (off + c) % self.module.config.eva_window == 0:
@@ -1954,6 +1990,7 @@ class ServingEngine:
             self._pos[slot] = req.prefill_pos
         if not last_chunk:
             return
+        req.t_last_chunk = time.perf_counter()   # the dispatch range's end
         # prefix fully resident: the next token came out of the final
         # chunk's program, which also woke the slot.  Its VALUE is only
         # fetched when scheduling depends on it (EOS) or a client waits for
@@ -2005,6 +2042,7 @@ class ServingEngine:
                 first = int(tok_dev)
             self._first_token_on_host(req)
             req.output_tokens.append(first)
+            self._m_first_tokens.inc()
             if overlapped:
                 self._m_first_overlapped.inc()
             if req.eos_token_id >= 0 and first == req.eos_token_id:
@@ -2178,7 +2216,9 @@ class ServingEngine:
             if not self._active.any():
                 self._settle_first_tokens()
                 return
-        with self._phase("ds_serve_decode_dispatch"):
+        self._launch_seq += 1
+        seq = self._launch_seq
+        with self._phase("ds_serve_decode_dispatch", seq=seq):
             args = [self._loop_params(), self._cache, self._last_dev,
                     self._pos_dev, self._act_dev, jnp.asarray(self._limit),
                     jnp.asarray(self._eos), self._rng]
@@ -2201,7 +2241,8 @@ class ServingEngine:
             self._pos[b] += n
             # one span per participating row: the block's host dispatch
             # window with this request's scheduled token count
-            self._tracer.span(req.request_id, "decode_block", t0, t1, n)
+            self._tracer.span(req.request_id, "decode_block", t0, t1, n,
+                              seq=seq)
             self._m_decode_toks.inc(n)
             self._goodput.add_tokens(n)
             refs += 1

@@ -72,6 +72,14 @@ class Request:
     pending_blocks: List = field(default_factory=list)
     t_submit: float = 0.0
     t_admit: float = 0.0                # slot assignment (queue wait ends)
+    # where the time in the slot before the first token goes, both since the
+    # LAST admission (reset with t_admit): the request's turn came for its
+    # first chunk (entry of the engine's _prefill_one_chunk, before any wait
+    # for pages), and its last chunk's program was enqueued.  t_first_chunk -
+    # t_admit, t_last_chunk - t_first_chunk and t_first_token - t_last_chunk
+    # sum to t_first_token - t_admit
+    t_first_chunk: float = 0.0
+    t_last_chunk: float = 0.0
     # first output token's VALUE on the host (after the fetch returned):
     # the earliest a client can be sent it.  A non-streaming request
     # without an EOS id defers that fetch to its finish (the sync-free
@@ -137,11 +145,6 @@ class Request:
     @property
     def done(self) -> bool:
         return self.state == FINISHED
-
-    @property
-    def latency(self) -> float:
-        """Submit -> finish wall seconds (0 until finished)."""
-        return (self.t_finish - self.t_submit) if self.done else 0.0
 
 
 class IterationScheduler:
@@ -294,6 +297,7 @@ class IterationScheduler:
             req.state = PREFILLING
             req.prefill_pos = 0
             req.t_admit = time.perf_counter()
+            req.t_first_chunk = req.t_last_chunk = 0.0
             self._slots[slot] = req
             admitted.append(req)
             self._tracer.admit(req.request_id, slot, req.t_admit)

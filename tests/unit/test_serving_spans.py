@@ -23,7 +23,7 @@ from deepspeed_tpu.profiling.trace import phase
 from deepspeed_tpu.serving.engine import SERVE_PHASES
 from tests.unit.hlo_text import program_text
 
-from benchmarks.lib import host_spans
+from benchmarks.lib import host_spans, request_spans
 from benchmarks.lib import trace_reduce as tr
 from benchmarks.lib.manifest import Bench
 from benchmarks.lib.stats import median
@@ -33,18 +33,29 @@ FETCH_DELAY_S = 0.03
 
 
 @pytest.fixture(scope="module")
-def serve(devices):
+def tiny(devices):
     mesh = build_mesh(fsdp=8, devices=devices)
     set_global_mesh(mesh)
     model = causal_lm("llama-tiny", mesh=mesh, num_layers=2, hidden_size=64,
                       intermediate_size=128, num_heads=4, num_kv_heads=2,
                       vocab_size=256, remat=False)
-    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))
+    return model, model.init(jax.random.PRNGKey(0),
+                             jnp.zeros((1, 8), jnp.int32))
+
+
+def _engine(tiny, **over):
+    model, params = tiny
     engine = deepspeed_tpu.init_serving(
         model, config={"dtype": "float32", "max_out_tokens": 64,
-                       "kv_page_tokens": 16},
+                       "kv_page_tokens": 16, **over},
         num_slots=2, prefill_chunk=4, decode_block_tokens=3)
     engine.set_params(params)
+    return engine
+
+
+@pytest.fixture(scope="module")
+def serve(tiny):
+    engine = _engine(tiny)
     yield engine
     engine.close()
 
@@ -108,7 +119,7 @@ def test_first_token_is_stamped_after_the_fetch(serve, monkeypatch, path):
         # enqueued (the last chunk's span ends there; the fetch itself now
         # follows the decode block's enqueue), and no token was visible
         # outside before the stamp
-        enqueued = max(t1 for kind, _, t1, _ in by_id[r.request_id]["spans"]
+        enqueued = max(t1 for kind, _, t1, _, _ in by_id[r.request_id]["spans"]
                        if kind == "prefill_chunk")
         assert r.t_first_token - enqueued >= FETCH_DELAY_S
         assert r.t_first_token <= seen_at[r.request_id]
@@ -298,6 +309,252 @@ def test_host_shares_read_the_phase_counters(serve):
     assert end["ds_serve_decode_dispatch_seconds_total"] + \
         end["ds_serve_first_token_fetch_seconds_total"] <= \
         end["ds_serve_decode_seconds_total"]
+
+
+# ---------------------------------------------------------------------------
+# (b') ... and prefill = chunk_wait + chunks + backlog (ISSUE 38): two more
+# stamps on every request, three counters beside them
+# ---------------------------------------------------------------------------
+
+def _counting():
+    """Registry and request tracer on and empty: the chunk programs a request
+    was given are its tracer record's ``prefill_chunk`` spans."""
+    reg, tracer = get_registry(), get_request_tracer()
+    reg.enable()
+    reg.reset()
+    tracer.reset()
+    tracer.enable()
+    return reg, tracer
+
+
+def _chunk_spans(tracer, req):
+    rec = next(r for r in tracer.completed() if r["id"] == req.request_id)
+    return [s for s in rec["spans"] if s[0] == "prefill_chunk"]
+
+
+def _check_stamps(r):
+    assert r.t_admit <= r.t_first_chunk <= r.t_last_chunk <= r.t_first_token
+    # differences of neighbouring perf_counter() values are exact, and so is
+    # their sum: the three parts ARE the prefill part, to the last bit
+    assert ((r.t_first_chunk - r.t_admit) + (r.t_last_chunk - r.t_first_chunk)
+            + (r.t_first_token - r.t_last_chunk)
+            ) == r.t_first_token - r.t_admit
+
+
+@pytest.mark.parametrize("path", ["stream", "eos", "deferred"])
+def test_chunk_stamps_partition_the_time_in_the_slot(serve, path):
+    kw = {"stream": {"stream": True}, "eos": {"eos_token_id": 255},
+          "deferred": {}}[path]
+    reg, tracer = _counting()
+    prompts = _prompts(6, seed=21)
+    reqs = [serve.submit(p, max_new_tokens=5, **kw) for p in prompts]
+    serve.run()
+    for r, p in zip(reqs, prompts):
+        assert r.done and not r.preemptions
+        _check_stamps(r)
+        assert len(_chunk_spans(tracer, r)) == \
+            -(-len(p) // serve.prefill_chunk)
+    # two slots, two chunks a turn: every turn in PREFILLING got its chunk
+    assert reg.get("ds_serve_prefill_turns_missed_total").value == 0
+    assert reg.get("ds_serve_prefill_turns_total").value == \
+        reg.get("ds_serve_prefill_chunks_total").value
+
+
+def test_stamps_are_those_of_the_last_admission(tiny):
+    # five pages for two requests of three: the younger one is preempted
+    # while it decodes, and comes back through a re-prefill of its prompt
+    # and of what it had produced
+    serve = _engine(tiny, kv_pool_tokens=80)
+    try:
+        rng = np.random.default_rng(4)
+        prompts = [rng.integers(0, 256, n, dtype=np.int32) for n in (8, 9)]
+        reqs = [serve.submit(p, max_new_tokens=40, stream=True)
+                for p in prompts]
+        admits = {r.request_id: [] for r in reqs}
+        while serve.scheduler.has_work:
+            serve.step()
+            for r in reqs:
+                if r.t_admit and r.t_admit not in admits[r.request_id]:
+                    admits[r.request_id].append(r.t_admit)
+        victims = [r for r in reqs if r.preemptions]
+        assert victims and all(r.done for r in reqs)
+        for r in victims:
+            assert len(admits[r.request_id]) == r.preemptions + 1
+            assert r.t_admit == admits[r.request_id][-1]
+            # not re-stamped on resume: the first token is the first run's
+            assert r.t_first_token < r.t_admit
+            # both chunk stamps were taken again, by the re-prefill
+            assert r.t_admit <= r.t_first_chunk <= r.t_last_chunk \
+                <= r.t_finish
+        for r in reqs:
+            if not r.preemptions:
+                _check_stamps(r)
+        # a reader of the stamps leaves the preempted out, as ttft_parts does
+    finally:
+        serve.close()
+
+
+def test_turns_missed_and_first_tokens_are_counted(serve, monkeypatch):
+    monkeypatch.setattr(serve, "max_prefill_chunks", 1)
+    reg, tracer = _counting()
+    # two slots, one chunk an iteration: the younger of two prefilling
+    # requests is passed over while the older has chunks left
+    reqs = [serve.submit(p, max_new_tokens=4, stream=True)
+            for p in _prompts(6, seed=8)]
+    turns = 0
+    while serve.scheduler.has_work:
+        turns += len(serve.scheduler.prefilling()) + min(
+            serve.scheduler.num_queued, len(serve.scheduler.free_slots()))
+        serve.step()
+    assert all(r.done for r in reqs)
+    for r in reqs:
+        _check_stamps(r)
+    chunks = sum(len(_chunk_spans(tracer, r)) for r in reqs)
+    assert chunks == sum(-(-r.prompt_len // serve.prefill_chunk)
+                         for r in reqs)
+    missed = reg.get("ds_serve_prefill_turns_missed_total").value
+    assert missed > 0
+    assert reg.get("ds_serve_prefill_turns_total").value == turns
+    assert missed + chunks == turns
+    assert reg.get("ds_serve_prefill_chunks_total").value == chunks
+    assert reg.get("ds_serve_first_tokens_total").value == len(reqs)
+    assert reg.get("ds_serve_first_token_overlapped_total").value <= \
+        len(reqs)
+    # the request tracer's spans name the launch each one is the ENQUEUE of:
+    # a number a chunk, a number a block, none twice over the requests'
+    # chunks, a block's shared by its rows
+    by_id = {r["id"]: r for r in tracer.completed()}
+    chunk_seqs, block_seqs = [], set()
+    for r in reqs:
+        for kind, _, _, _, seq in by_id[r.request_id]["spans"]:
+            if kind == "prefill_chunk":
+                chunk_seqs.append(seq)
+            elif kind == "decode_block":
+                block_seqs.add(seq)
+            else:
+                assert seq is None
+    assert len(set(chunk_seqs)) == len(chunk_seqs) == chunks
+    assert not block_seqs & set(chunk_seqs)
+    assert max(block_seqs | set(chunk_seqs)) == serve._launch_seq
+
+
+def test_readers_of_the_stamps_and_counters(serve):
+    bench = Bench()
+    driver = bench.driver("serve_open_loop")
+    reg = get_registry()
+    reg.enable()
+    reg.reset()
+    snap = lambda: {k: v for k, v in reg.snapshot().items()
+                    if isinstance(v, (int, float))}
+    begin = snap()
+    rng = np.random.default_rng(13)
+    schedule = [Arrival(0.02 * (i // 2), p, int(rng.integers(2, 7)))
+                for i, p in enumerate(_prompts(10, seed=13))]
+    res = driver.drive(serve, schedule, 0.5, 60.0)
+    end = snap()
+    ctx = {"loop": {"records": res["records"], "schedule": schedule,
+                    "late_s": res["late_s"], "until_s": 0.5},
+           "trace_window": None,
+           "counters": {"begin": begin, "trace_start": end}}
+    parts = request_spans.stamp_parts(ctx)
+    whole = host_spans.ttft_parts(ctx)
+    assert len(parts) == len(whole) == len(schedule)
+    for p, w in zip(parts, whole):       # the same requests, in one order
+        assert p["prefill"] == w["prefill"]
+        assert p["chunk_wait"] + p["chunks"] + p["backlog"] == p["prefill"]
+        assert min(p["chunk_wait"], p["chunks"], p["backlog"]) >= 0
+    for name, part in (("ttft_chunk_wait_p50_ms", "chunk_wait"),
+                       ("ttft_chunks_p50_ms", "chunks"),
+                       ("ttft_backlog_p50_ms", "backlog")):
+        assert bench.reader(name).read(ctx) == pytest.approx(
+            median([p[part] for p in parts]) * 1e3)
+    d = lambda k: end[k] - begin[k]
+    assert bench.reader("prefill_turns_missed_share").read(ctx) == \
+        pytest.approx(100.0 * d("ds_serve_prefill_turns_missed_total")
+                      / d("ds_serve_prefill_turns_total"))
+    assert d("ds_serve_first_tokens_total") == len(schedule)
+    assert bench.reader("first_token_overlapped_share").read(ctx) == \
+        pytest.approx(100.0 * d("ds_serve_first_token_overlapped_total")
+                      / len(schedule))
+    # no device trace (ctx["trace"] is what run.py reduced one to): the
+    # joined metrics are left out
+    ctx["trace"] = None
+    for name in ("ttft_backlog_decode_p50_ms", "ttft_backlog_chunks_p50_ms",
+                 "ttft_own_chunk_p50_ms"):
+        assert bench.reader(name).read(ctx) is None
+    # a traced run reads requests due a second before the profiler started
+    ctx["trace_window"] = (0.5, 0.6)
+    assert request_spans.stamp_parts(ctx) == []
+    assert bench.reader("ttft_backlog_p50_ms").read(ctx) is None
+
+
+def test_new_readers_return_nothing_for_the_parent_program():
+    from types import SimpleNamespace as NS
+
+    # PR 37's program: the phases' counters and the overlapped counter, no
+    # chunk stamps, no turn counters, no count of first tokens
+    old = NS(t_submit=1.0, t_admit=1.1, t_first_token=1.2, preemptions=0)
+    counters = {"ds_serve_first_token_fetch_seconds_total": 0.1,
+                "ds_serve_first_token_overlapped_total": 3}
+    ctx = {"loop": {"records": [NS(req=old, t_first=0.3)],
+                    "schedule": [NS(due_s=0.0)], "late_s": [0.0],
+                    "until_s": 1.0},
+           "trace_window": None, "trace": None,
+           "counters": {"begin": counters, "trace_start": counters}}
+    bench = Bench()
+    assert bench.reader("ttft_prefill_p50_ms").read(ctx) is not None
+    for name in ("ttft_chunk_wait_p50_ms", "ttft_chunks_p50_ms",
+                 "ttft_backlog_p50_ms", "prefill_turns_missed_share",
+                 "first_token_overlapped_share",
+                 "ttft_backlog_decode_p50_ms", "ttft_backlog_chunks_p50_ms",
+                 "ttft_own_chunk_p50_ms"):
+        assert bench.reader(name).read(ctx) is None, name
+
+
+def test_dispatch_ranges_carry_the_launch_number(serve, tmp_path):
+    """In a profiler session the two dispatch ranges keep their bare names
+    (what ``idle_by_span`` and the driver's ``HOST_SCOPES`` match) and say
+    in their stats what they enqueued; ``seq`` rises by one from each
+    dispatch, of either kind, to the next."""
+    first = serve._launch_seq
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        reqs = [serve.submit(p, max_new_tokens=5, stream=True)
+                for p in _prompts(4, seed=17)]
+        serve.run()
+    finally:
+        jax.profiler.stop_trace()
+    trace = tr.load_xplane(tr.find_xplane(str(tmp_path)),
+                           planes=(r"^/host:CPU$",), all_stats=True)
+    chunks = tr.host_events(trace, "ds_serve_prefill_dispatch")
+    blocks = tr.host_events(trace, "ds_serve_decode_dispatch")
+    assert len(chunks) == sum(-(-r.prompt_len // serve.prefill_chunk)
+                              for r in reqs) and blocks
+    both = sorted(chunks + blocks, key=lambda e: e.start)
+    assert [e.stats["seq"] for e in both] == list(
+        range(first + 1, first + 1 + len(both)))
+    assert serve._launch_seq == first + len(both)
+    ids = {r.request_id: r for r in reqs}
+    # the stats are the three the benchmark's join reads, and no other
+    for e in chunks:
+        assert set(e.stats) == {"seq", "request_id", "last"}
+    for rid, r in ids.items():
+        mine = [e for e in chunks if e.stats["request_id"] == rid]
+        assert len(mine) == -(-r.prompt_len // serve.prefill_chunk)
+        assert [e.stats["last"] for e in mine] == [0] * (len(mine) - 1) + [1]
+    for e in blocks:
+        assert set(e.stats) == {"seq"}
+    # the other ranges say nothing of a launch
+    assert all("seq" not in e.stats
+               for n in SERVE_PHASES if not n.endswith("_dispatch")
+               for e in tr.host_events(trace, n))
+    assert tr.host_events(trace, "ds_serve_step")
+    # the benchmark's join reads them in launch order; a CPU trace has no
+    # chip to join them to
+    assert [e.stats["seq"] for e in request_spans.dispatches(trace)] == \
+        [e.stats["seq"] for e in both]
+    with pytest.raises(tr.NoDeviceTrace):
+        request_spans.join(trace)
 
 
 # ---------------------------------------------------------------------------
