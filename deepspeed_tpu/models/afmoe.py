@@ -511,6 +511,8 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     columns + full columns], ``serving/paged_kv.py``).  A sliding layer
     writes row ``pos % W`` of the ring and attends rows ``<= min(pos, W - 1)``:
     once the ring has wrapped that is all of it, which is exactly the window.
+    ``moe_live`` [B] bool: the rows that decode, which the attention kernels
+    visit and the routing counts cover (``fused_decode.decode_step``).
     Returns (x, cache, routing counts | None)."""
     from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
                                                  fused_moe_mlp,
@@ -545,14 +547,16 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
             k_win, v_win = paged_kv_append(k_win, v_win, k, v, ring_row,
                                            win_table, layer=i_win, impl=impl)
             ctx = flash_decode(q, k_win, v_win, ring_len, sm_scale=scale,
-                               layer=i_win, page_table=win_table, impl=impl)
+                               layer=i_win, page_table=win_table,
+                               live=moe_live, impl=impl)
             i_win += 1
         else:
             k_full, v_full = paged_kv_append(k_full, v_full, k, v, pos,
                                              full_table, layer=i_full,
                                              impl=impl)
             ctx = flash_decode(q, k_full, v_full, pos, sm_scale=scale,
-                               layer=i_full, page_table=full_table, impl=impl)
+                               layer=i_full, page_table=full_table,
+                               live=moe_live, impl=impl)
             i_full += 1
         ctx = gated(cfg, ctx.reshape(B, M), g)
         if cfg.sandwich_norm:

@@ -175,12 +175,15 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
     scatter through the table and the flash-decode kernel indirects its
     DMA index map through it (per-row positions required).
 
-    ``moe_live`` [B] bool (the rows that are really decoding) adds a third
-    result: a mixture-of-experts model's routing of this step over those
-    rows, summed over layers — (assignments per expert [E], (layer, expert)
-    pairs with at least one row, the fullest expert's rows summed over
-    layers), int32, what ``ds_serve_moe_*`` count — and None for a dense
-    model.
+    ``moe_live`` [B] bool (the rows that are really decoding; one mask, two
+    uses) is handed to the attention kernels, whose grid then visits those
+    rows only (a row that does not decode gets its ``q`` back and costs no
+    page fetch: ``ops/pallas/decode.py:_decode_attention``), and adds a
+    third result: a mixture-of-experts model's routing of this step over
+    those rows, summed over layers — (assignments per expert [E], (layer,
+    expert) pairs with at least one row, the fullest expert's rows summed
+    over layers), int32, what ``ds_serve_moe_*`` count — and None for a
+    dense model.  Without it every row is visited.
 
     Four kernel launches per layer: norm+QKV, flash-decode attention,
     out-proj+residual+norm, MLP+residual (ops/pallas/decode.py); the cache
@@ -315,7 +318,7 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
         if cfg.is_eva:
             ctx = eva_decode_paged(q, kc_all, vc_all, pos, page_table,
                                    layer=l, window=W, chunk=C,
-                                   sm_scale=scale, impl=impl)
+                                   sm_scale=scale, live=moe_live, impl=impl)
             # rows whose step filled their window leave its summaries behind
             kc_all, vc_all = eva_summarize_paged(
                 kc_all, vc_all, lp["eva_mu"], lp["eva_phi"], pos, page_table,
@@ -323,7 +326,8 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
         else:
             ctx = flash_decode(q, kc_all, vc_all, pos, sm_scale=scale,
                                layer=l, alibi=cfg.position == "alibi",
-                               page_table=page_table, impl=impl)
+                               page_table=page_table, live=moe_live,
+                               impl=impl)
         wo, s_wo = wq_pair(lp["wo"])
         r, h = fused_proj_norm(ctx.reshape(B, M), x, wo, lp.get("bo"),
                                lp["n2_scale"], lp.get("n2_bias"), kind=kind,

@@ -209,21 +209,21 @@ def _flash_decode_ref(q, kcache, vcache, pos, *, scale, alibi=False):
     return o.reshape(B, H, Dh).astype(q.dtype)
 
 
-def _flash_decode_kernel(*refs, scale, block, nb, alibi):
+def _flash_decode_kernel(*refs, scale, block, alibi):
     """One grid step = one batch row x ``hb`` KV heads x one key block (a
     page of the paged pool): online softmax with a leading head axis.  The
-    scalar-prefetched refs lead (``pos`` first; the paged layout adds its
-    page table, which only the index maps read — it picks WHICH physical
-    page the step's K and V blocks DMA; the math here is
-    position-logical)."""
-    pos_ref = refs[0]
+    scalar-prefetched refs lead (``rows``, the batch row of each step of
+    the grid's first axis, then ``pos``; the paged layout adds its page
+    table, which only the index maps read — it picks WHICH physical page
+    the step's K and V blocks DMA; the math here is position-logical)."""
+    rows_ref, pos_ref = refs[:2]
     q_ref, k_ref, v_ref, slope_ref, o_ref, m_scr, l_scr, acc_scr = refs[-8:]
     j = pl.program_id(2)
 
     pl.when(j == 0)(functools.partial(_softmax_init, m_scr, l_scr, acc_scr))
 
     # each batch row has its own position (continuous batching)
-    pos = pos_ref[pl.program_id(0)]
+    pos = pos_ref[rows_ref[pl.program_id(0)]]
 
     @pl.when(j * block <= pos)
     def _compute():
@@ -239,7 +239,7 @@ def _flash_decode_kernel(*refs, scale, block, nb, alibi):
         s = jnp.where(key_pos <= pos, s, NEG_INF)   # [hb, rep, block]
         _softmax_block(s, v_ref, m_scr, l_scr, acc_scr)
 
-    pl.when(j == nb - 1)(
+    pl.when(j == pl.num_programs(2) - 1)(
         functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
 
 
@@ -285,14 +285,25 @@ def _kv_heads_per_step(hkv: int, block: int, dh: int, itemsize: int) -> int:
                default=1)
 
 
-def _decode_attention(q, kcache, vcache, prefetch, kv_map, *, block, nb,
-                      scale, alibi, impl, name, kernel=None):
+def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
+                      nb, scale, alibi, impl, name, kernel=None):
     """The one ``pallas_call`` behind every cache layout.  The caches are
     taken as ``[N, Hkv, S, Dh]`` views (N = stacked layers x batch rows, or
-    x physical pages: a free reshape); ``kv_map(b, g, j, *prefetch_refs)``
-    places the ``(1, hb, block, Dh)`` K and V blocks of grid step
-    ``(b, g, j)`` of ``grid=(B, Hkv // hb, nb)``.  ``kernel`` replaces the
-    body (same refs; ``_eva_decode_kernel`` masks by two lengths a row)."""
+    x physical pages: a free reshape); ``kv_map(b, g, j, pos_ref,
+    *table_refs)`` places the ``(1, hb, block, Dh)`` K and V blocks of batch
+    row ``b``, head group ``g``, key block ``j``.  ``kernel`` replaces the
+    body (same refs; ``_eva_decode_kernel`` masks by two lengths a row).
+
+    The grid follows the batch, not the slot count: ``live`` [B] bool names
+    the rows that decode (None: all of them), and the grid is ``(live rows,
+    Hkv // hb, nb)`` with the first extent read at run time.  Step ``i`` of
+    it works on row ``rows[i]``, the live rows' indices in order
+    (scalar-prefetched ahead of ``pos``), so a row that does not decode costs
+    no grid step and no page fetch; its output is its ``q``, which the
+    output is aliased onto.  No live row at all is a grid of no steps.
+    ``nb`` is a row's key blocks; under the default body, whose blocks are
+    one run up to ``pos``, the third extent stops at the deepest live row's
+    last one, ``max(pos // block + 1)`` over them."""
     B, H, Dh = q.shape
     view = (-1,) + kcache.shape[-3:]
     hkv = view[1]
@@ -304,18 +315,30 @@ def _decode_attention(q, kcache, vcache, prefetch, kv_map, *, block, nb,
         slopes = alibi_slopes(H).reshape(hkv, rep, 1)
     else:
         slopes = jnp.zeros((hkv, rep, 1), jnp.float32)
+    depth = pos // block + 1
+    if live is None:
+        rows, n_live = jnp.arange(B, dtype=jnp.int32), B
+    else:
+        rows = jnp.argsort(~live, stable=True).astype(jnp.int32)
+        n_live = jnp.sum(live, dtype=jnp.int32)
+        depth = jnp.where(live, depth, 0)
     if kernel is None:
+        nb = jnp.minimum(nb, jnp.max(depth))
         kernel = functools.partial(_flash_decode_kernel, scale=scale,
-                                   block=block, nb=nb, alibi=alibi)
+                                   block=block, alibi=alibi)
+    prefetch = (rows, pos) + tuple(tables)
     # index maps see the scalar-prefetch refs AFTER the grid indices (the
     # kernel body sees them first)
-    heads = pl.BlockSpec((1, hb, rep, Dh), lambda b, g, j, *_: (b, g, 0, 0))
-    kv = pl.BlockSpec((1, hb, block, Dh), kv_map)
+    heads = pl.BlockSpec((1, hb, rep, Dh),
+                         lambda i, g, j, rows_ref, *_: (rows_ref[i], g, 0, 0))
+    kv = pl.BlockSpec((1, hb, block, Dh),
+                      lambda i, g, j, rows_ref, *refs:
+                      kv_map(rows_ref[i], g, j, *refs))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(B, hkv // hb, nb),
+        grid=(n_live, hkv // hb, nb),
         in_specs=[heads, kv, kv,
-                  pl.BlockSpec((hb, rep, 1), lambda b, g, j, *_: (g, 0, 0))],
+                  pl.BlockSpec((hb, rep, 1), lambda i, g, j, *_: (g, 0, 0))],
         out_specs=heads,
         scratch_shapes=[pltpu.VMEM((hb, rep, 1), jnp.float32),
                         pltpu.VMEM((hb, rep, 1), jnp.float32),
@@ -324,6 +347,8 @@ def _decode_attention(q, kcache, vcache, prefetch, kv_map, *, block, nb,
     o = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hkv, rep, Dh), q.dtype),
+        # operands count the scalar-prefetch arrays: q follows them
+        input_output_aliases={len(prefetch): 0},
         interpret=interpret_flag(impl),
         name=name,
     )(*prefetch, q.reshape(B, hkv, rep, Dh), kcache.reshape(view),
@@ -407,22 +432,25 @@ def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
 
 
 def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
-                        layer: Optional[int], alibi: bool, impl: str):
+                        layer: Optional[int], alibi: bool, live, impl: str):
     """Decode attention over the PAGED pool (``serving/paged_kv.py``):
     caches [P, Hkv, page, Dh] (or stacked [L, P, Hkv, page, Dh] with
     ``layer=l``), ``page_table`` [B, maxp] int32 naming each row's
-    physical page per logical block.  One grid step is one batch row x
-    ``hb`` KV heads x one logical page (``grid=(B, Hkv // hb, maxp)``;
+    physical page per logical block.  One grid step is one LIVE batch row
+    x ``hb`` KV heads x one logical page (``grid=(live rows, Hkv // hb,
+    the deepest live row's pages)``, :func:`_decode_attention`;
     :func:`_kv_heads_per_step` sizes ``hb`` from the shapes, all of ``Hkv``
     at GQA widths): a physical page holds its KV heads contiguously, so
     the step's K and V blocks are ``[hb, page, Dh]`` slabs of one page.
     The block index map indirects through the scalar-prefetched table
     (``pt_ref[row, min(j, pos // page)]``), so each fetch lands on the
     right physical page and — exactly as in the contiguous kernel —
-    pages past each row's ``pos`` are neither fetched nor computed (they
-    still cost their grid step).  The XLA path gathers the logical
-    per-slot view and runs the dense reference (CPU tests, and the page
-    sizes :func:`paged_decode_reference_reason` names)."""
+    pages past each row's ``pos`` are neither fetched nor computed: a
+    shallower row's steps up to the deepest live row's last page still
+    cost their grid step, a row that does not decode costs nothing and
+    pages past every live row are no grid steps.  The XLA path gathers
+    the logical per-slot view and runs the dense reference (CPU tests, and
+    the page sizes :func:`paged_decode_reference_reason` names)."""
     kc = kcache if layer is None else kcache[layer]
     vc = vcache if layer is None else vcache[layer]
     page = kc.shape[2]
@@ -441,15 +469,15 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
         return base + pt_ref[b, jl], g, 0, 0
 
     return _decode_attention(
-        q, kcache, vcache, (pos, page_table.astype(jnp.int32)), page_map,
-        block=page, nb=page_table.shape[1], scale=scale, alibi=alibi,
-        impl=impl, name="flash_decode_paged")
+        q, kcache, vcache, pos, (page_table.astype(jnp.int32),), page_map,
+        live=live, block=page, nb=page_table.shape[1], scale=scale,
+        alibi=alibi, impl=impl, name="flash_decode_paged")
 
 
 def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
                  block: int = 256, layer: Optional[int] = None,
                  alibi: bool = False, impl: Optional[str] = None,
-                 page_table=None):
+                 page_table=None, live=None):
     """Single-launch decode attention.  q: [B, H, Dh]; caches:
     [B, Hkv, Smax, Dh] — or, with ``layer=l``, stacked [L, B, Hkv, Smax, Dh]
     read at static layer offset ``l`` through the index map (no cache slice
@@ -458,6 +486,10 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
     (continuous batching: each slot masks and clamps independently).
     ``page_table`` [B, maxp] switches to the paged pool layout
     ([P, Hkv, page, Dh] physical pages; see :func:`_flash_decode_paged`).
+
+    ``live`` [B] bool names the rows that really decode (None: all): the
+    kernel's grid visits those rows only and stops at the deepest one's
+    last block (:func:`_decode_attention`); the others get their ``q`` back.
 
     The block index map clamps to the position's block PER ROW, so cache
     blocks past each row's ``pos`` are neither fetched nor computed — the
@@ -470,7 +502,7 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
     if page_table is not None:
         return _flash_decode_paged(q, kcache, vcache, pos, page_table,
                                    scale=scale, layer=layer, alibi=alibi,
-                                   impl=impl)
+                                   live=live, impl=impl)
     # the xla path slices the stacked cache; the pallas path offsets the map
     kc = kcache if layer is None else kcache[layer]
     vc = vcache if layer is None else vcache[layer]
@@ -488,8 +520,9 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
         return base + b, g, jnp.minimum(j, pos_ref[b] // block), 0
 
     return _decode_attention(
-        q, kcache, vcache, (pos,), clamp, block=block, nb=Smax // block,
-        scale=scale, alibi=alibi, impl=impl, name="flash_decode")
+        q, kcache, vcache, pos, (), clamp, live=live, block=block,
+        nb=Smax // block, scale=scale, alibi=alibi, impl=impl,
+        name="flash_decode")
 
 
 # ---------------------------------------------------------------------------
@@ -497,18 +530,18 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
 # and the pooling of a filled window into its summary rows
 # ---------------------------------------------------------------------------
 
-def _eva_decode_kernel(*refs, scale, block, nb, wp, window, per):
+def _eva_decode_kernel(*refs, scale, block, wp, window, per):
     """``_flash_decode_kernel`` with two valid lengths a row: grid steps
     ``j < wp`` walk the row's window pages, of which rows ``[0, pos % W]``
     count; steps ``j >= wp`` its summary pages, of which the first
     ``(pos // W) * W/C`` rows count.  One online softmax over both."""
-    pos_ref = refs[0]
+    rows_ref, pos_ref = refs[:2]
     q_ref, k_ref, v_ref, _, o_ref, m_scr, l_scr, acc_scr = refs[-8:]
     j = pl.program_id(2)
 
     pl.when(j == 0)(functools.partial(_softmax_init, m_scr, l_scr, acc_scr))
 
-    pos = pos_ref[pl.program_id(0)]
+    pos = pos_ref[rows_ref[pl.program_id(0)]]
     # rows of this page that count
     n_valid = jnp.where(j < wp, pos % window + 1 - j * block,
                         (pos // window) * per - (j - wp) * block)
@@ -522,7 +555,7 @@ def _eva_decode_kernel(*refs, scale, block, nb, wp, window, per):
         _softmax_block(jnp.where(row < n_valid, s, NEG_INF), v_ref, m_scr,
                        l_scr, acc_scr)
 
-    pl.when(j == nb - 1)(
+    pl.when(j == pl.num_programs(2) - 1)(
         functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
 
 
@@ -541,15 +574,19 @@ def eva_reference_reason(page: int, window: int, chunk: int) -> Optional[str]:
 
 def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer: int,
                      window: int, chunk: int,
-                     sm_scale: Optional[float] = None,
+                     sm_scale: Optional[float] = None, live=None,
                      impl: Optional[str] = None):
     """EVA decode attention over the paged pool.  q [B, H, Dh] at absolute
     positions ``pos`` [B]; caches stacked [L, P, H, page, Dh] read at static
     layer ``layer``; ``page_table`` [B, wp + sp]: a row's ``wp = W / page``
     window pages, then its summary pages (``serving/paged_kv.py``).  One
-    grid step is one row x ``hb`` heads x one page, window pages first; the
-    index map clamps past the last page with a row that counts, so pages a
-    row does not attend are neither fetched nor computed."""
+    grid step is one LIVE row (``live`` [B] bool, None: all;
+    :func:`_decode_attention`) x ``hb`` heads x one page, window pages first;
+    the index map clamps past the last page with a row that counts, so pages
+    a row does not attend are neither fetched nor computed.  The page axis
+    is two segments, so its extent stays the table's width: a live row still
+    pays a grid step for each page it does not attend, a row that does not
+    decode pays nothing."""
     impl = resolve_impl(impl)
     B, H, Dh = q.shape
     L, P, _, page, _ = kcache.shape
@@ -566,7 +603,6 @@ def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer: int,
             paged_logical_view(vcache[layer], page_table), pos[:, None],
             window=window, chunk=chunk, scale=scale)[:, :, 0]
     wp, per = window // page, window // chunk
-    nb = page_table.shape[1]
     base = layer * P
 
     def page_map(b, g, j, pos_ref, pt_ref):
@@ -580,11 +616,11 @@ def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer: int,
         return base + pt_ref[b, col], g, 0, 0
 
     kernel = functools.partial(_eva_decode_kernel, scale=scale, block=page,
-                               nb=nb, wp=wp, window=window, per=per)
+                               wp=wp, window=window, per=per)
     return _decode_attention(
-        q, kcache, vcache, (pos, page_table.astype(jnp.int32)), page_map,
-        block=page, nb=nb, scale=scale, alibi=False, impl=impl,
-        name="eva_decode_paged", kernel=kernel)
+        q, kcache, vcache, pos, (page_table.astype(jnp.int32),), page_map,
+        live=live, block=page, nb=page_table.shape[1], scale=scale,
+        alibi=False, impl=impl, name="eva_decode_paged", kernel=kernel)
 
 
 def _eva_summarize_kernel(pp_ref, sp_ref, k_ref, v_ref, mu_ref, phi_ref,

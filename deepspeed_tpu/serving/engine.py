@@ -617,6 +617,12 @@ class ServingEngine:
             "ds_serve_prefill_tokens_total", "prompt tokens prefilled")
         self._m_decode_toks = reg.counter(
             "ds_serve_decode_tokens_total", "decode tokens scheduled")
+        self._m_row_slots = reg.counter(
+            "ds_serve_decode_row_slots_total",
+            "rows x steps of the decode blocks' batch: num_slots x "
+            "decode_block_tokens a block.  ds_serve_decode_tokens_total over "
+            "it is the share of them in which the row decoded, the rows "
+            "the decode attention kernels visit (ops/pallas/decode.py)")
         self._m_steps = reg.counter(
             "ds_serve_steps_total", "scheduler iterations")
         self._m_compiles = reg.counter(
@@ -2229,6 +2235,7 @@ class ServingEngine:
         t1 = time.perf_counter()
         idx = self._next_block
         self._next_block += 1
+        self._m_row_slots.inc(self.num_slots * self._K)
         refs = 0
         drainers: List[Request] = []
         for req in running:
@@ -2461,7 +2468,8 @@ class ServingEngine:
         """One decode micro-step at per-row positions: (params, tokens
         [B, 1], cache, pos [B], page_table|None, live [B]) -> (logits
         [B, V], cache, routing counts of the live rows | None: a
-        mixture-of-experts model on the fused path, ``decode_step``)."""
+        mixture-of-experts model on the fused path, ``decode_step``, whose
+        attention kernels also visit the live rows only)."""
         model = self.module
         if self.engine._dparams is not None:
             from deepspeed_tpu.models.fused_decode import decode_step

@@ -242,6 +242,40 @@ def test_eva_decode_kernel_matches_xla(pos):
         eva_decode_paged(q, kc, vc, pos, pt, impl="xla", **kw), atol=1e-5)
 
 
+@pytest.mark.parametrize("live,pos", [
+    # a row before its first closed window beside one after it, parked rows
+    # between them whose stale pos is deeper than either
+    ([False, True, False, True], [767, 130, 700, 300]),
+    ([True, True, True, False], [17, 255, 511, 767]),      # all but one
+    ([False] * 4, [300, 255, 17, 600]),                    # no live row
+    ([True] * 4, [300, 255, 17, 600]),                     # every row
+    (None, [300, 255, 17, 600]),                           # no mask given
+], ids=["interleaved", "one_parked", "none_live", "all_live", "no_mask"])
+def test_eva_decode_kernel_visits_live_rows(live, pos):
+    """The grid follows ``live``: live rows equal the XLA form whichever
+    side of their first window close they stand; a row that does not decode
+    is never visited (every page its table names is NaN) and gets its ``q``
+    back; no live row at all is a kernel of no steps."""
+    from deepspeed_tpu.ops.pallas.decode import eva_decode_paged
+
+    rng = np.random.default_rng(2)
+    kc, vc = _pool_arrays(rng, P=13)
+    q = jnp.asarray(rng.normal(size=(4, 4, 32)), jnp.float32)
+    pt = jnp.asarray(np.arange(1, 13).reshape(4, 3), jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    rows = np.flatnonzero(np.ones(4) if live is None else live)
+    parked = np.setdiff1d(np.arange(4), rows)
+    poison = np.asarray(pt)[parked].reshape(-1)
+    kx, vx = kc.at[:, poison].set(jnp.nan), vc.at[:, poison].set(jnp.nan)
+    kw = dict(layer=1, window=256, chunk=8)
+    got = eva_decode_paged(q, kx, vx, pos, pt, impl="interpret",
+                           live=None if live is None else jnp.asarray(live),
+                           **kw)
+    want = eva_decode_paged(q, kc, vc, pos, pt, impl="xla", **kw)
+    np.testing.assert_allclose(got[rows], want[rows], atol=1e-5)
+    np.testing.assert_array_equal(got[parked], q[parked])
+
+
 @pytest.mark.parametrize("pos", [[300, 255, 17], [511, 767, 130],
                                  [40, 600, 13]])
 def test_eva_summarize_kernel_matches_xla_and_touches_only_closers(pos):

@@ -505,3 +505,45 @@ def test_mlp_parity(glu, with_bias):
                     act=act, impl="interpret")
     want = _mlp_ref(h, r, w_up, w_gate, w_down, b_up, b_gate, b_down, act=act)
     np.testing.assert_allclose(got, want, rtol=3e-5, atol=3e-5)
+
+
+# which rows decode, and where each row's ``pos`` stands (page 256, 3 pages
+# a row): the batches a decode block hands the attention kernels
+LIVE_BATCHES = {
+    # parked rows between the live ones, their stale pos deeper than any
+    "interleaved": ([False, True, False, True, False],
+                    [700, 5, 767, 300, 600]),
+    "none_live": ([False] * 4, [5, 300, 0, 767]),
+    "all_live": ([True] * 4, [5, 300, 0, 767]),
+    "one_and_all_pages": ([True, True, False], [3, 767, 400]),
+    "no_mask": (None, [5, 300, 0, 767]),
+}
+
+
+@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+@pytest.mark.parametrize("case", sorted(LIVE_BATCHES))
+def test_flash_decode_visits_live_rows(case, layout):
+    """The grid follows ``live``: the rows that decode equal the dense
+    reference, the others come back finite (their ``q``: they were never
+    visited, so a NaN in every page a PARKED row's table and ``pos`` name
+    is never read), and no live row at all is a kernel of no steps."""
+    mask, pos = LIVE_BATCHES[case]
+    B, Hkv, rep, Dh, page, maxp = len(pos), 2, 2, 64, 256, 3
+    q = _rand(0, B, Hkv * rep, Dh)
+    k = _rand(1, B, Hkv, maxp * page, Dh)
+    v = _rand(2, B, Hkv, maxp * page, Dh)
+    live = None if mask is None else jnp.asarray(mask)
+    rows = np.flatnonzero(np.ones(B) if mask is None else mask)
+    parked = np.setdiff1d(np.arange(B), rows)
+    # whatever a parked row could reach is poison
+    kx, vx = k.at[parked].set(jnp.nan), v.at[parked].set(jnp.nan)
+    posv = jnp.asarray(pos, jnp.int32)
+    if layout == "paged":
+        kp, vp, pt = _paged_from_logical(kx, vx, maxp, page)
+        got = flash_decode(q, kp, vp, posv, page_table=pt, live=live,
+                           impl="interpret")
+    else:
+        got = flash_decode(q, kx, vx, posv, live=live, impl="interpret")
+    want = _flash_decode_ref(q, k, v, posv, scale=Dh ** -0.5)
+    np.testing.assert_allclose(got[rows], want[rows], rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(got[parked], q[parked])

@@ -592,3 +592,70 @@ def test_pool_pressure_meets_an_owed_first_token(setup, monkeypatch,
             assert toks == _ref_out(ref, p, kw["max_new_tokens"]).tolist()
             assert reason == "length"
     serve.close()
+
+
+# ---------------------------------------------------------------------------
+# the decode attention kernels visit the rows that decode (ISSUE 39): the
+# block hands its live mask down to them.  What the benchmark's cells check
+# on the chip: the served tokens are what they are without the mask.
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("layout", ["paged", "fixed"])
+def test_served_tokens_do_not_depend_on_the_live_mask(setup, rng, layout,
+                                                      monkeypatch):
+    """Three slots through the Pallas kernels (interpret mode; pages of 128,
+    the contiguous block of 256): two requests of unequal length, one across
+    a page, a third that arrives once the first has left, so rows park, wake
+    and prefill while others decode and one block runs with no live row's
+    neighbour.  Served once with the block's mask handed to ``decode_step``
+    and once with it withheld (every row visited, as before ISSUE 39): the
+    same tokens, and ``generate()``'s."""
+    from deepspeed_tpu.models import fused_decode
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+    from deepspeed_tpu.ops.pallas import common
+
+    model, params, _ = setup
+    monkeypatch.setattr(common, "default_impl", lambda: "interpret")
+    prompts = [np.asarray(jax.random.randint(k, (n,), 0, 256))
+               for k, n in zip(jax.random.split(rng, 3), (9, 125, 30))]
+    news = [4, 8, 6]
+
+    def served(registry):
+        serve = deepspeed_tpu.init_serving(
+            model, config={"dtype": "float32", "max_out_tokens": 256,
+                           "kv_page_tokens": 128,
+                           "paged_kv_cache": layout == "paged"},
+            num_slots=3, prefill_chunk=64, decode_block_tokens=3,
+            registry=registry)
+        serve.set_params(params)
+        assert serve.engine._dparams is not None          # the fused path
+        reqs = [serve.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts[:2], news)]
+        while not reqs[0].done:
+            serve.step()
+        reqs.append(serve.submit(prompts[2], max_new_tokens=news[2]))
+        serve.run()
+        serve.close()
+        return [list(r.output_tokens) for r in reqs]
+
+    registry = MetricsRegistry().enable()
+    masked = served(registry)
+    # the counter behind decode_rows_live_share: rows x steps of the blocks'
+    # batch, of which the scheduled tokens are the rows that decoded
+    snap = registry.snapshot()
+    slots = snap["ds_serve_decode_row_slots_total"]
+    assert slots % (3 * 3) == 0
+    assert 0 < snap["ds_serve_decode_tokens_total"] < slots
+    step = fused_decode.decode_step
+
+    def unmasked(*args, moe_live=None, **kwargs):
+        return (*step(*args, **kwargs), None)
+
+    with monkeypatch.context() as m:
+        m.setattr(fused_decode, "decode_step", unmasked)
+        assert served(MetricsRegistry()) == masked
+    long_ref = deepspeed_tpu.init_inference(
+        model, config={"dtype": "float32", "max_out_tokens": 256})
+    long_ref.set_params(params)
+    for got, prompt, n in zip(masked, prompts, news):
+        np.testing.assert_array_equal(got, _ref_out(long_ref, prompt, n))

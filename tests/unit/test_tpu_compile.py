@@ -528,6 +528,40 @@ def test_chat_chunk_programs_never_copy_the_pool(v5e, chip_kernels,
     built.assert_donations_taken(program, donated=5)
 
 
+@pytest.mark.parametrize("config,in_place", [
+    ("mistral-7b-L8", True), ("olmoe-1b-7b-L8", True), ("gpt2-xl", False)])
+def test_decode_blocks_visit_the_live_rows(v5e, chip_kernels, config,
+                                           in_place):
+    """ISSUE 39: the decode block of the two chat cells, and of ``gpt2-xl``
+    served in the Mistral cell's engine (head dim 64, 25 KV heads; not a
+    cell), two layers each, compiles for the v5e with the attention kernel's
+    grid read at run time: five kernels a layer and the final norm as
+    before, the live rows sorted ONCE a step for all layers' calls, each
+    call's output in its ``q``'s buffer, and no pool copied for it (at head
+    dim 64 the block converts the pool's layout on its way in and out, four
+    copies before this change and after)."""
+    cell = _ServeCell(v5e, config, "mistral-7b-L8.serve-chat",
+                      fields=dict(num_layers=2))
+    block = cell.block()
+    text = block.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 11
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "flash_decode_paged" in line]
+    assert len(calls) == 2
+    # operands: the grid's two run-time extents, rows, pos, the table, q
+    rows = {re.search(r"custom-call\(([^)]*)\)", line).group(1).split(", ")[2]
+            for line in calls}
+    assert len(rows) == 1 and rows.pop().startswith("%sort")
+    assert len(re.findall(r" sort\(%.*argsort", text)) == 1
+    assert all("output_to_operand_aliasing={{}: (5, {})}" in line
+               for line in calls)
+    if in_place:
+        cell.assert_pools_stay_in_place(block)
+    else:
+        pool = ",".join(str(d) for d in cell.serve._cache["k"].shape)
+        assert len(re.findall(rf"bf16\[{pool}\]\S* copy\(", text)) == 4
+
+
 def test_trinity_cell_programs_compile_without_copying_a_budget(
         v5e, chip_kernels):
     """ISSUE 36: the chunk program (bucket 1,024) and the decode block of

@@ -339,11 +339,18 @@ def test_counters_count_attended_rows_pages_and_local_choices(model):
 
 
 # ------------------------------------------- the kernels, interpret mode
-def test_fused_layers_through_the_kernels_match_their_references():
+@pytest.mark.parametrize("live", [
+    [True, True, True], [True, False, True], [False, True, False],
+    [False, False, False]], ids=["all", "ring_and_short", "parked_between",
+                                 "none"])
+def test_fused_layers_through_the_kernels_match_their_references(live):
     """The decode step over two page budgets with every Pallas kernel in
     interpret mode against the same step on the kernels' jnp references: a
     GQA group of 3 (not a multiple of the 8 sublanes), pages of 128 (the
-    lane tile), a ring that has wrapped for one row and not for the other."""
+    lane tile), a ring that has wrapped for two rows and not for the third.
+    The attention kernels visit the ``live`` rows only (a wrapped ring row
+    beside a row of one full page; a deeper row that does not decode between
+    them): those equal the references, the others stay finite."""
     cfg = ModelConfig(**dict(
         FIELDS, hidden_size=128, num_heads=6, num_kv_heads=2, head_dim=128,
         num_layers=3, layer_types=("sliding_attention",) * 2
@@ -352,9 +359,10 @@ def test_fused_layers_through_the_kernels_match_their_references():
     params = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
                           afmoe.init_params(cfg, jax.random.PRNGKey(9)))
     dparams = afmoe.inject(cfg, params)
-    pool = PagedKVPool(2, 512, page_tokens=128, ring_tokens=256)
-    pos = jnp.asarray([300, 40], jnp.int32)
-    for b, p in enumerate((300, 40)):
+    depths = (300, 480, 40)
+    pool = PagedKVPool(3, 512, page_tokens=128, ring_tokens=256)
+    pos = jnp.asarray(depths, jnp.int32)
+    for b, p in enumerate(depths):
         assert pool.ensure(b, p + 1)
     fill = lambda key, L, P: jax.random.normal(
         key, (L, P, 2, 128, 128), jnp.bfloat16)
@@ -363,15 +371,23 @@ def test_fused_layers_through_the_kernels_match_their_references():
              "v_win": fill(ks[1], 2, pool.num_window_pages),
              "k_full": fill(ks[2], 1, pool.num_pages),
              "v_full": fill(ks[3], 1, pool.num_pages)}
-    x = jax.random.normal(ks[4], (2, 128), jnp.bfloat16)
+    x = jax.random.normal(ks[4], (3, 128), jnp.bfloat16)
     table = jnp.asarray(pool.page_table)
-    live = jnp.asarray([True, True])
     run = lambda impl: afmoe.fused_layers(cfg, dparams, x, cache, pos, table,
-                                          moe_live=live, impl=impl)
+                                          moe_live=jnp.asarray(live),
+                                          impl=impl)
     (xa, ca, sa), (xb, cb, sb) = run("interpret"), run("xla")
-    np.testing.assert_allclose(xa.astype(np.float32), xb.astype(np.float32),
+    xa, xb = xa.astype(np.float32), xb.astype(np.float32)
+    np.testing.assert_allclose(xa[np.asarray(live)], xb[np.asarray(live)],
                                rtol=2e-2, atol=2e-2)
+    assert np.isfinite(xa).all()
+    # what a row that does not decode appends past the first layer is
+    # computed from its unvisited attention output: junk in both, not equal
+    wp = pool.window_pages
+    parked = np.asarray(table)[~np.asarray(live)]
     for k in ca:
-        np.testing.assert_array_equal(np.asarray(ca[k], np.float32),
-                                      np.asarray(cb[k], np.float32))
+        keep = np.setdiff1d(np.arange(ca[k].shape[1]), parked[:, :wp]
+                            if k.endswith("win") else parked[:, wp:])
+        np.testing.assert_array_equal(np.asarray(ca[k], np.float32)[:, keep],
+                                      np.asarray(cb[k], np.float32)[:, keep])
     assert all((np.asarray(a) == np.asarray(b)).all() for a, b in zip(sa, sb))

@@ -9,12 +9,15 @@ JSON per mix, appended to ``chiprun_out/paged_decode_bench.jsonl``.
 
     python3 tools/paged_decode_bench.py [--tree <checkout>] [--label parent]
         [--heads 32 --kv-heads 8 --head-dim 128 --slots 64 --page 256
-         --maxp 4 --layers 8] [--mixes 0,255,300,1023,cell]
+         --maxp 4 --layers 8] [--mixes 0,255,300,1023,cell,cell16]
 
 ``--tree`` imports ``deepspeed_tpu`` from another checkout (the parent
 commit, unpacked beside this one), so one call times both on one chip.  A
 mix is a position shared by every row, or ``cell``: 33 rows at 150-500 and
-31 parked (position 0 on the junk page 0), the cell's mean occupancy.  The
+31 parked (position 0 on the junk page 0), the cell's mean occupancy when
+PR 26 took it; ``cell<n>`` has ``n`` such rows.  A ``cell`` mix hands the
+kernel its live mask where the tree's kernel takes one (its grid then
+visits the live rows only, ISSUE 39; ``masked`` in the row says so).  The
 time of a call is the host clock around ``--reps`` programs of ``layers``
 x ``--rounds`` kernel calls each, ending in ``block_until_ready``.  TPU only.
 """
@@ -55,7 +58,11 @@ def main() -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    import inspect
+
     from deepspeed_tpu.ops.pallas.decode import flash_decode, paged_kv_append
+
+    masked = "live" in inspect.signature(flash_decode).parameters
 
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.allow_cpu:
@@ -76,12 +83,12 @@ def main() -> int:
     vn = jax.random.normal(keys[4], (B, Hkv, Dh), jnp.bfloat16)
 
     def mix(name):
-        """(pos [B], page_table [B, maxp]) of one mix; live rows own
-        distinct shuffled pages as far as the pool goes, parked rows sit on
-        the junk page."""
-        if name == "cell":
+        """(pos [B], page_table [B, maxp], live [B]) of one mix; live rows
+        own distinct shuffled pages as far as the pool goes, parked rows sit
+        on the junk page."""
+        if name.startswith("cell"):
             live = np.zeros(B, bool)
-            live[rng.permutation(B)[:B * 33 // 64]] = True
+            live[rng.permutation(B)[:int(name[4:] or B * 33 // 64)]] = True
             pos = np.where(live, rng.randint(150, 501, B), 0)
             pos = np.minimum(pos, maxp * page - 1)
         else:
@@ -92,15 +99,19 @@ def main() -> int:
         for b in np.flatnonzero(live):
             for j in range(pos[b] // page + 1):
                 pt[b, j] = free.pop() if free else 1 + (b * maxp + j) % (P - 1)
-        return jnp.asarray(pos, jnp.int32), jnp.asarray(pt)
+        return jnp.asarray(pos, jnp.int32), jnp.asarray(pt), jnp.asarray(live)
 
     layers = list(range(L)) * args.rounds
 
+    def attention(q, kc, vc, pos, pt, live, layer, impl):
+        mask = {"live": live} if masked else {}
+        return flash_decode(q, kc, vc, pos, layer=layer, page_table=pt,
+                            impl=impl, **mask)
+
     @jax.jit
-    def attend(q, kc, vc, pos, pt):
+    def attend(q, kc, vc, pos, pt, live):
         for l in layers:              # each call feeds the next: in order
-            q = flash_decode(q, kc, vc, pos, layer=l, page_table=pt,
-                             impl=impl)
+            q = attention(q, kc, vc, pos, pt, live, l, impl)
         return q
 
     @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -110,16 +121,15 @@ def main() -> int:
                                      impl=impl)
         return kc, vc
 
-    def worst_error(pos, pt):
-        """Largest |kernel - jnp reference| of one layer's attention, and
-        whether the append wrote what the scatter writes off the junk page
-        (parked rows race there)."""
+    def worst_error(pos, pt, live):
+        """Largest |kernel - jnp reference| of one layer's attention over
+        the live rows, and whether the append wrote what the scatter writes
+        off the junk page (parked rows race there)."""
         l = L - 1
-        got = flash_decode(q, kc, vc, pos, layer=l, page_table=pt, impl=impl)
-        want = flash_decode(q, kc, vc, pos, layer=l, page_table=pt,
-                            impl="xla")
+        got = attention(q, kc, vc, pos, pt, live, l, impl)
+        want = attention(q, kc, vc, pos, pt, live, l, "xla")
         err = jnp.max(jnp.abs(got.astype(jnp.float32)
-                              - want.astype(jnp.float32)))
+                              - want.astype(jnp.float32))[live])
         ka, _ = paged_kv_append(kc, vc, kn, vn, pos, pt, layer=l, impl=impl)
         kx, _ = paged_kv_append(kc, vc, kn, vn, pos, pt, layer=l, impl="xla")
         return float(err), bool(jnp.array_equal(ka[:, 1:], kx[:, 1:]))
@@ -138,18 +148,20 @@ def main() -> int:
                  maxp=maxp, layers=L, pool_pages=P)
     reps = args.reps if dev.platform == "tpu" else 1
     for name in args.mixes.split(","):
-        pos, pt = mix(name)
-        err, same = worst_error(pos, pt)
-        attn_us = timed(lambda: attend(q, kc, vc, pos, pt), reps)
+        pos, pt, live = mix(name)
+        err, same = worst_error(pos, pt, live)
+        attn_us = timed(lambda: attend(q, kc, vc, pos, pt, live), reps)
 
         def step():
             nonlocal kc, vc
             kc, vc = append(kc, vc, kn, vn, pos, pt)
             return kc
         app_us = timed(step, reps)
-        pages = int(jnp.sum(pos // page + 1))
+        pages = int(jnp.sum(jnp.where(live, pos // page + 1, 0)))
         row = {"label": args.label, "device": dev.device_kind, "mix": name,
-               "live_pages": pages, "context_tokens": int(jnp.sum(pos + 1)),
+               "masked": masked and name.startswith("cell"),
+               "live_rows": int(jnp.sum(live)), "live_pages": pages,
+               "context_tokens": int(jnp.sum(jnp.where(live, pos + 1, 0))),
                "flash_decode_paged_us_per_call": attn_us,
                "paged_kv_append_us_per_call": app_us,
                "attention_max_abs_err": err, "append_matches_scatter": same,
