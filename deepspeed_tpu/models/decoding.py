@@ -23,11 +23,13 @@ from typing import Any, Dict, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.comm.mesh import axis_size
 from deepspeed_tpu.models import eva
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
                                          constrain, model_norm, norm, qk_norm,
                                          _repeat_kv, rope_dim)
 from deepspeed_tpu.ops.pallas import rope_angles
+from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_attention
 
 NEG_INF = -1e30
 
@@ -395,6 +397,11 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         cos = sin = jnp.zeros((), x.dtype)
     scale = 1.0 / (Dh ** 0.5)
     W = cfg.eva_window
+    # EVA: several queries at shared positions whose heads all sit on this
+    # chip (GSPMD cannot split a Pallas kernel over tp or sp)
+    flash_chunk = s > 1 and not per_row and (
+        mesh is None or mesh.empty
+        or axis_size(mesh, "tp") == axis_size(mesh, "sp") == 1)
 
     # A dropless MoE block takes the STACKED expert arrays whole and its own
     # layer's index (moe/sharded_moe.py:_moe_grouped): scanned like the other
@@ -450,8 +457,15 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
                     kv_view, k.astype(kc.dtype), (0, 0, win_pos, 0))
                 vv_view = jax.lax.dynamic_update_slice(
                     vv_view, v.astype(vc.dtype), (0, 0, win_pos, 0))
-            o = eva.cached_attention(q, kv_view, vv_view, q_pos, window=W,
-                                     chunk=cfg.eva_chunk, scale=scale)
+            if flash_chunk:
+                # a chunk at shared positions: the flash form where its
+                # sizes allow (the scores stay in VMEM), else the dense one
+                o = eva_chunk_attention(q, kv_view, vv_view, start_pos,
+                                        window=W, chunk=cfg.eva_chunk,
+                                        sm_scale=scale)
+            else:
+                o = eva.cached_attention(q, kv_view, vv_view, q_pos, window=W,
+                                         chunk=cfg.eva_chunk, scale=scale)
             kv_view, vv_view = eva.write_window_summaries(
                 kv_view, vv_view, a["eva_mu"], a["eva_phi"], start_pos // W,
                 window=W, chunk=cfg.eva_chunk)

@@ -35,6 +35,11 @@ forward pays one transpose of its [D, bq] accumulator per Q tile.
 Backward follows the standard recompute scheme: saved LSE from forward;
 ``delta = rowsum(dO ∘ O)``; one kernel accumulates dQ over KV strips, another
 accumulates dK/dV over Q strips.
+
+:func:`eva_chunk_attention` (forward only) is the same schedule for a serving
+prefill chunk of an EVA model (``models/eva.py``): the chunk's queries over
+the slot's window rows up to each query and the summary rows of the windows
+before, one online softmax over both runs of strips.
 """
 
 from __future__ import annotations
@@ -49,7 +54,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.pallas.common import (interpret_flag, pick_block,
+from deepspeed_tpu.ops.pallas.common import (interpret_flag,
+                                             kernel_or_reference, pick_block,
                                              resolve_impl, round_up)
 
 # Upper limits of the Q and KV tile (the tiles are the largest divisors of S
@@ -160,6 +166,12 @@ def _heads(BH: int, per_head: int) -> int:
                if BH % d == 0 and (d == 1 or d * per_head <= _VMEM_BLOCK_BYTES))
 
 
+def _token_bytes(D: int, itemsize: int):
+    """VMEM bytes one token takes in a q / k / v block, in an f32
+    accumulator and in a double-buffered [1, n] f32 row of statistics."""
+    return round_up(D, _LANES) * itemsize, round_up(D, _LANES) * 4, 2 * 8 * 4
+
+
 def _plan(BH: int, S: int, Sk: int, D: int, itemsize: int, block_q: int,
           block_k: int) -> _Plan:
     """Tiles, chunks and heads a step from the shapes alone.  Bytes are what
@@ -167,9 +179,7 @@ def _plan(BH: int, S: int, Sk: int, D: int, itemsize: int, block_q: int,
     and outputs double-buffered, a [1, n] row of statistics 8 sublanes."""
     bq = pick_block(S, block_q, minimum=8)
     bk = pick_block(Sk, block_k, minimum=8)
-    row = round_up(D, _LANES) * itemsize           # one token of q / k / v
-    acc = round_up(D, _LANES) * 4                  # ... of an f32 accumulator
-    stat = 2 * 8 * 4                               # ... of a [1, n] f32 row
+    row, acc, stat = _token_bytes(D, itemsize)
     # forward: q, o tiles + lse row; m, l rows and acc scratch; K and V chunk
     fwd_fixed = bq * (2 * 2 * row + 2 * stat + acc)
     # dQ: q, dO, dq tiles + lse, delta rows; dq scratch; K and V chunk
@@ -368,6 +378,32 @@ def _to_col(row):
 # forward
 # ---------------------------------------------------------------------------
 
+def _softmax_init(h, m_scr, l_scr, acc_scr):
+    m_scr[h] = jnp.full(m_scr.shape[1:], NEG_INF, jnp.float32)
+    l_scr[h] = jnp.zeros(l_scr.shape[1:], jnp.float32)
+    acc_scr[h] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
+
+
+def _softmax_step(st, v, h, lanes, m_scr, l_scr, acc_scr):
+    """One sub-block of the online softmax on transposed scores ``st``
+    [kv_n, q_n] (masked already) and the strip's values ``v`` [kv_n, D]:
+    head ``h``'s running max and sum (rows [1, bq]) and accumulator
+    ([D, bq]) move on for the queries at ``lanes``."""
+    m_prev = m_scr[h, :, lanes]                                # [1, q_n]
+    m_new = lax.max(m_prev, lax.expand_dims(
+        lax.reduce_max(st, (0,)), (0,)))
+    pt = lax.exp(lax.sub(st, _bc(m_new, st)))                  # [kv_n, q_n]
+    alpha = lax.exp(lax.sub(m_prev, m_new))
+    l_scr[h, :, lanes] = lax.add(
+        lax.mul(alpha, l_scr[h, :, lanes]),
+        lax.expand_dims(lax.reduce_sum(pt, (0,)), (0,)))
+    acc = acc_scr[h, :, lanes]                                 # [D, q_n]
+    acc_scr[h, :, lanes] = lax.add(
+        lax.mul(acc, _bc(alpha, acc)),
+        _dot(v, lax.convert_element_type(pt, v.dtype), _TN))
+    m_scr[h, :, lanes] = m_new
+
+
 def _fwd_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, scale, causal, alibi, bq, bk, nq,
                 nk, tiles, hb):
@@ -384,11 +420,7 @@ def _fwd_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     bodies, sk = _bodies(bq, bk, causal, alibi, kv_dim=0), _strip(bk)
 
     def head(h, carry):
-        @_when(first)
-        def _init():
-            m_scr[h] = jnp.full(m_scr.shape[1:], NEG_INF, jnp.float32)
-            l_scr[h] = jnp.zeros(l_scr.shape[1:], jnp.float32)
-            acc_scr[h] = jnp.zeros(acc_scr.shape[1:], jnp.float32)
+        _when(first)(lambda: _softmax_init(h, m_scr, l_scr, acc_scr))
 
         # MXU matmuls take the native (bf16) operands; only the accumulator
         # and softmax statistics are fp32 — fp32 MXU inputs would quarter
@@ -415,19 +447,7 @@ def _fwd_kernel(slope_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 if not fold:
                     st = st * scale
                 st = _bias_and_mask(st, rel, slope, d + q_lo - kv_lo, masked)
-                m_prev = m_scr[h, :, lanes]                    # [1, q_n]
-                m_new = lax.max(m_prev, lax.expand_dims(
-                    lax.reduce_max(st, (0,)), (0,)))
-                pt = lax.exp(lax.sub(st, _bc(m_new, st)))      # [kv_n, q_n]
-                alpha = lax.exp(lax.sub(m_prev, m_new))
-                l_scr[h, :, lanes] = lax.add(
-                    lax.mul(alpha, l_scr[h, :, lanes]),
-                    lax.expand_dims(lax.reduce_sum(pt, (0,)), (0,)))
-                acc = acc_scr[h, :, lanes]                     # [D, q_n]
-                acc_scr[h, :, lanes] = lax.add(
-                    lax.mul(acc, _bc(alpha, acc)),
-                    _dot(v, lax.convert_element_type(pt, v.dtype), _TN))
-                m_scr[h, :, lanes] = m_new
+                _softmax_step(st, v, h, lanes, m_scr, l_scr, acc_scr)
 
         if has_plain:
             _run(lo * (bk // sk), plain * (bk // sk), tile, False)
@@ -792,3 +812,194 @@ def _fa_bwd(causal, sm_scale, block_q, block_k, impl, alibi, res, g):
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
+
+
+# ---------------------------------------------------------------------------
+# EVA prefill-chunk attention (models/eva.py:cached_attention), forward only
+# ---------------------------------------------------------------------------
+
+class _ChunkPlan(NamedTuple):
+    bq: int       # Q tile (the whole chunk up to DEFAULT_BLOCK_Q)
+    sk: int       # rows of a window strip
+    ss: int       # rows of a summary strip
+    per_head: int  # VMEM bytes of one head's blocks and scratch
+
+
+def _chunk_plan(s: int, rows: int, window: int, D: int,
+                itemsize: int) -> _ChunkPlan:
+    bq = pick_block(s, DEFAULT_BLOCK_Q)
+    row, acc, stat = _token_bytes(D, itemsize)
+    # q, o tiles; m, l rows and the acc scratch; the whole K and V view
+    per_head = bq * (2 * 2 * row + 2 * stat + acc) + rows * 2 * 2 * row
+    return _ChunkPlan(bq, _strip(bq), _strip(max(rows - window, 1)), per_head)
+
+
+def eva_chunk_reference_reason(s: int, window: int, rows: int, head_dim: int,
+                               itemsize: int = 2) -> Optional[str]:
+    """Why :func:`eva_chunk_attention` cannot take these sizes (None = it
+    can): ``s`` queries over a view of ``rows`` = ``window`` + summary rows.
+    Its strips are whole lane tiles of the chunk, of the window and of the
+    summary rows, and a head's whole view waits in VMEM."""
+    for what, n in (("chunk", s), ("window", window),
+                    ("summary rows", rows - window), ("head dim", head_dim)):
+        if n <= 0 or n % _LANES:
+            return f"{what} of {n} is not a multiple of the 128-lane tile"
+    p = _chunk_plan(s, rows, window, head_dim, itemsize)
+    if window % p.sk:
+        return f"window {window} is not whole strips of {p.sk} rows"
+    if p.per_head > _VMEM_BLOCK_BYTES:
+        return (f"a head's view of {rows} rows takes {p.per_head} bytes of "
+                f"VMEM, over the {_VMEM_BLOCK_BYTES} of a grid step")
+    return None
+
+
+def _chunk_bounds(w0, n_sum, p: _ChunkPlan, window: int):
+    """What a Q tile whose first query sits at window row ``w0``, with
+    ``n_sum`` summary rows to attend, walks (Python ints or traced scalars):
+
+    - window strips ``[0, plain)`` lie wholly under its first query;
+    - ``aligned``: ``w0`` is a strip's first row, so the tile the diagonal
+      crosses, rows ``[w0, w0 + bq)``, takes :func:`_strips`' trimmed form;
+      otherwise strips ``[plain, crossed)`` each take every query, masked;
+    - summary strips ``[0, full)`` whole, then ``part`` rows of one more."""
+    plain = w0 // p.sk
+    return (plain, w0 % p.sk == 0,
+            _clip((w0 + p.bq + p.sk - 1) // p.sk, 0, window // p.sk),
+            n_sum // p.ss, n_sum % p.ss)
+
+
+def eva_chunk_schedule(start: int, s: int, *, window: int, chunk: int,
+                       rows: int, head_dim: int = 128, itemsize: int = 2,
+                       real: Optional[int] = None,
+                       impl: Optional[str] = None) -> Dict[str, object]:
+    """What one head of one layer computes for the ``s`` queries at
+    positions ``start ..`` (all in one window) over a view of ``rows`` rows,
+    from the bounds the kernel's loops take (:func:`tile_schedule`'s way of
+    counting).  ``kept``: the scores the two masks keep for the first
+    ``real`` queries (all ``s`` by default), in closed form; ``dense``:
+    ``s * rows``, what :func:`eva.cached_attention` computes; ``visited``:
+    what the kernel does, or ``dense`` where ``reason`` says why it does not
+    run (its sizes, or ``impl``, resolved as the call resolves it)."""
+    n = s if real is None else real
+    per, w0 = window // chunk, start % window
+    n_sum = min((start // window) * per, rows - window)
+    reason = eva_chunk_reference_reason(s, window, rows, head_dim, itemsize)
+    if reason is None and resolve_impl(impl) == "xla":
+        reason = "impl is xla"
+    out = {"kept": n * w0 + n * (n + 1) // 2 + n * n_sum, "dense": s * rows,
+           "reason": reason}
+    if reason is not None:
+        return dict(out, visited=out["dense"])
+    p = _chunk_plan(s, rows, window, head_dim, itemsize)
+    trimmed = sum(kv_n * q_n for _, kv_n, _, q_n in _strips(p.bq, p.bq, True))
+    visited = 0
+    for i in range(s // p.bq):
+        plain, aligned, crossed, full, part = _chunk_bounds(
+            w0 + i * p.bq, n_sum, p, window)
+        visited += (plain * p.sk * p.bq
+                    + (trimmed if aligned else (crossed - plain) * p.sk * p.bq)
+                    + (full + (part > 0)) * p.ss * p.bq)
+    return dict(out, visited=visited, block_q=p.bq, strip=p.sk,
+                summary_strip=p.ss)
+
+
+def _eva_chunk_kernel(start_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                      acc_scr, *, scale, window, per, p, hb):
+    bq, sk, ss = p.bq, p.sk, p.ss
+    fold = _folds(scale)
+    start = start_ref[0]
+    w0 = start % window + pl.program_id(1) * bq
+    n_sum = _clip((start // window) * per, 0, k_ref.shape[1] - window)
+    plain, aligned, crossed, full, part = _chunk_bounds(w0, n_sum, p, window)
+    diagonal = _bodies(bq, bq, True, False, kv_dim=0)[True]
+    rel = _rel(sk, bq, 0)                       # (KV row) - (query), a strip
+    kv_row = lax.broadcasted_iota(jnp.int32, (ss, bq), 0)
+
+    def head(h, carry):
+        _softmax_init(h, m_scr, l_scr, acc_scr)
+        q = q_ref[h]                                       # [bq, D]
+        if fold:
+            q = q * scale
+
+        def block(row0, kv_n, q_lo=0, q_n=bq, rel=None, d=0):
+            """View rows ``[row0, row0 + kv_n)`` against the tile's queries
+            ``[q_lo, q_lo + q_n)``, keeping ``rel <= d`` where masked."""
+            rows = pl.ds(pl.multiple_of(row0, _LANES), kv_n)
+            st = _dot(k_ref[h, rows, :], _part(q, 0, q_lo, q_n), _NT)
+            if not fold:
+                st = st * scale
+            st = _bias_and_mask(st, rel, None, d, rel is not None)
+            _softmax_step(st, v_ref[h, rows, :], h, slice(q_lo, q_lo + q_n),
+                          m_scr, l_scr, acc_scr)
+
+        # 1. the window's rows up to the tile's last query
+        _run(0, plain, lambda t, _: block(t * sk, sk), False)
+
+        @pl.when(aligned)
+        def _trimmed():
+            for kv_lo, kv_n, q_lo, q_n, tri in diagonal:
+                block(w0 + kv_lo, kv_n, q_lo, q_n, tri, q_lo - kv_lo)
+
+        @pl.when(jnp.logical_not(aligned))
+        def _crossed():
+            _run(plain, crossed,
+                 lambda t, _: block(t * sk, sk, rel=rel, d=w0 - t * sk), True)
+
+        # 2. the summaries of the windows before, the same for every query
+        _run(0, full, lambda t, _: block(window + t * ss, ss), False)
+        pl.when(part > 0)(lambda: block(window + full * ss, ss, rel=kv_row,
+                                        d=part - 1))
+
+        o_ref[h] = jnp.transpose(acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
+        return carry
+
+    lax.fori_loop(0, hb, head, 0)
+
+
+def eva_chunk_attention(q, kview, vview, start, *, window: int, chunk: int,
+                        sm_scale: Optional[float] = None,
+                        impl: Optional[str] = None):
+    """EVA attention of one prefill chunk (``models/eva.py``), the scores
+    never leaving VMEM.  q [B, H, s, Dh] at the shared absolute positions
+    ``start .. start + s - 1``, all in ONE window (``start`` a traced
+    scalar); the logical views [B, H, W + summary rows, Dh] with the chunk's
+    own rows already written.  One softmax over the window rows up to each
+    query and the ``(start // W) * W/C`` summary rows of the windows before.
+
+    A grid step holds ``hb`` heads' whole views and one Q tile and walks two
+    runs of key strips (:func:`_chunk_bounds`), so a strip past either bound
+    costs no arithmetic.  Sizes the kernel cannot take
+    (:func:`eva_chunk_reference_reason`) run :func:`eva.cached_attention`."""
+    from deepspeed_tpu.models import eva
+
+    impl = resolve_impl(impl)
+    B, H, s, D = q.shape
+    rows = kview.shape[2]
+    scale = sm_scale if sm_scale is not None else 1.0 / (D ** 0.5)
+    start = jnp.asarray(start, jnp.int32)
+    impl = kernel_or_reference(
+        "eva_chunk_attention", impl, eva_chunk_reference_reason(
+            s, window, rows, D, kview.dtype.itemsize))
+    if impl == "xla":
+        return eva.cached_attention(q, kview, vview, start + jnp.arange(s),
+                                    window=window, chunk=chunk, scale=scale)
+    BH = B * H
+    p = _chunk_plan(s, rows, window, D, kview.dtype.itemsize)
+    hb = _heads(BH, p.per_head)
+    q_tile = pl.BlockSpec((hb, p.bq, D), lambda g, i, start_ref: (g, i, 0))
+    view = pl.BlockSpec((hb, rows, D), lambda g, i, start_ref: (g, 0, 0))
+    o = pl.pallas_call(
+        functools.partial(_eva_chunk_kernel, scale=scale, window=window,
+                          per=window // chunk, p=p, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(BH // hb, s // p.bq),
+            in_specs=[q_tile, view, view], out_specs=q_tile,
+            scratch_shapes=[pltpu.VMEM((hb, 1, p.bq), jnp.float32),
+                            pltpu.VMEM((hb, 1, p.bq), jnp.float32),
+                            pltpu.VMEM((hb, D, p.bq), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((BH, s, D), q.dtype),
+        interpret=interpret_flag(impl),
+        name="eva_chunk_attention",
+    )(start.reshape(1), q.astype(kview.dtype).reshape(BH, s, D),
+      kview.reshape(BH, rows, D), vview.reshape(BH, rows, D))
+    return o.reshape(B, H, s, D)

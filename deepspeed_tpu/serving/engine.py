@@ -78,6 +78,7 @@ from deepspeed_tpu.monitor.goodput import get_goodput_ledger
 from deepspeed_tpu.monitor.health import get_health
 from deepspeed_tpu.monitor.metrics import get_registry
 from deepspeed_tpu.monitor.request_trace import get_request_tracer
+from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_schedule
 from deepspeed_tpu.profiling.trace import phase
 from deepspeed_tpu.serving.host_tier import HostPageStore
 from deepspeed_tpu.serving.paged_kv import PagedKVPool, init_paged_kv_cache
@@ -149,6 +150,13 @@ SERVE_EVA_COUNTERS = {
         "window rows attended by live decode rows, summed over steps",
     "ds_serve_eva_summary_rows_total":
         "summary rows attended by live decode rows, summed over steps",
+    "ds_serve_eva_prefill_scores_total":
+        "scores (query, key row) the two masks keep for the real tokens of "
+        "the prefill chunks, one head of one layer",
+    "ds_serve_eva_prefill_scores_visited_total":
+        "scores the chunk programs' attention computes for those chunks "
+        "(eva_chunk_schedule: the kernel's strips, or the whole bucket x "
+        "view where the dense form runs), one head of one layer",
 }
 
 
@@ -1989,6 +1997,8 @@ class ServingEngine:
             self._m_prefill_toks.inc(c)
             if self._eva and (off + c) % self.module.config.eva_window == 0:
                 self._m_eva["ds_serve_eva_window_closes_total"].inc()
+            if self._eva and self._registry.enabled:
+                self._count_eva_chunk(off, c, cb)
             # parked rows write junk at their own pos; keeping pos =
             # prefill progress (host view here, device carry inside the
             # chunk's program) means the NEXT chunk overwrites that row
@@ -2316,6 +2326,20 @@ class ServingEngine:
         m["ds_serve_eva_window_rows_total"].inc(int((p % W + 1).sum()))
         m["ds_serve_eva_summary_rows_total"].inc(int((p // W).sum()) * per)
         m["ds_serve_eva_window_closes_total"].inc(int(((p + 1) % W == 0).sum()))
+
+    def _count_eva_chunk(self, off: int, c: int, cb: int) -> None:
+        """A prefill chunk of ``c`` real tokens at position ``off`` in a
+        bucket of ``cb`` into ``ds_serve_eva_prefill_scores*``: what the
+        masks keep of its scores and what its program's attention computes,
+        from the schedule the kernel takes its bounds from."""
+        cfg = self.module.config
+        sch = eva_chunk_schedule(
+            off, cb, real=c, window=cfg.eva_window, chunk=cfg.eva_chunk,
+            rows=self.pool.slot_pages * self.pool.page, head_dim=cfg.head_dim,
+            itemsize=self._cache["k"].dtype.itemsize)
+        m = self._m_eva
+        m["ds_serve_eva_prefill_scores_total"].inc(sch["kept"])
+        m["ds_serve_eva_prefill_scores_visited_total"].inc(sch["visited"])
 
     def _count_attended(self, pos: int, n: int) -> None:
         """A row's ``n`` decode steps from position ``pos`` into

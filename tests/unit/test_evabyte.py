@@ -15,6 +15,7 @@ magnitude (``test_the_tolerance_catches_*``): the residual carried in bf16
 window stale (about 1e-1).
 """
 
+import importlib
 import importlib.util
 import os
 
@@ -300,6 +301,182 @@ def test_eva_summarize_kernel_matches_xla_and_touches_only_closers(pos):
         {int(pt[b, 2]) for b in np.flatnonzero(closing)}
 
 
+# -- the prefill chunk's attention: the flash kernel against eva.cached_attention
+
+def _chunk_inputs(s, window, chunk, windows, heads=2, batch=1, Dh=128,
+                  dtype=jnp.float32, seed=3):
+    """q of one chunk and the slot's logical views, sized for ``windows``
+    windows' summaries."""
+    rng = np.random.default_rng(seed)
+    rows = window + windows * (window // chunk)
+    mk = lambda n: jnp.asarray(rng.normal(size=(batch, heads, n, Dh)), dtype)
+    return mk(s), mk(rows), mk(rows)
+
+
+def _chunk_both(q, k, v, start, window, chunk):
+    from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_attention
+
+    got = jax.jit(lambda q, k, v, at: eva_chunk_attention(
+        q, k, v, at, window=window, chunk=chunk, impl="interpret"))(
+            q, k, v, jnp.int32(start))
+    want = eva.cached_attention(q, k, v, start + jnp.arange(q.shape[2]),
+                                window=window, chunk=chunk,
+                                scale=q.shape[-1] ** -0.5)
+    return got, want
+
+
+# (start, s, window, eva chunk, windows the view holds summaries of): a case
+# a branch of the schedule
+CHUNK_CASES = {
+    # the first window: the diagonal tile alone, no summary row
+    "offset_0": (0, 128, 256, 16, 8),
+    # strips wholly under the chunk's first query, then the diagonal tile
+    "half_window": (128, 128, 256, 16, 8),
+    # 16 and 48 summary rows: one partial summary strip, masked by row
+    "window_1": (256, 128, 256, 16, 8),
+    "window_3_half": (3 * 256 + 128, 128, 256, 16, 8),
+    # 160 summary rows in strips of 128: one whole strip and a partial one
+    "window_5_whole_summary_strip": (5 * 256, 128, 256, 8, 12),
+    # 256 of them: two whole strips and no partial one
+    "window_8_no_partial_strip": (8 * 256 + 128, 128, 256, 8, 12),
+    # the chunk bucket up to half a window, at both window offsets
+    "bucket_256_offset_0": (2 * 512, 256, 512, 16, 4),
+    "bucket_256_half_window": (2 * 512 + 256, 256, 512, 16, 4),
+    # a first query that is not a strip's first row (no engine chunk starts
+    # so; forward_with_cache's other callers may): every strip from the
+    # first one the diagonal touches is masked whole
+    "unaligned_start": (256 + 64, 128, 256, 16, 8),
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_CASES)
+def test_eva_chunk_kernel_matches_xla(case):
+    start, s, window, chunk, windows = CHUNK_CASES[case]
+    got, want = _chunk_both(*_chunk_inputs(s, window, chunk, windows), start,
+                            window, chunk)
+    np.testing.assert_allclose(got, want, atol=ATOL)
+
+
+@pytest.mark.parametrize("budget,heads_a_step", [(None, 4), ("one_head", 1)])
+def test_eva_chunk_kernel_heads_a_grid_step(monkeypatch, budget,
+                                            heads_a_step):
+    """Two rows of two heads: all four in one grid step where they fit the
+    VMEM budget, one a step where the budget holds one head's blocks."""
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    q, k, v = _chunk_inputs(128, 256, 16, 8, batch=2)
+    plan = fa._chunk_plan(128, k.shape[2], 256, 128, 4)
+    if budget:
+        monkeypatch.setattr(fa, "_VMEM_BLOCK_BYTES", plan.per_head)
+    assert fa._heads(4, plan.per_head) == heads_a_step
+    got, want = _chunk_both(q, k, v, 256 + 128, 256, 16)
+    np.testing.assert_allclose(got, want, atol=ATOL)
+
+
+def test_eva_chunk_kernel_two_query_tiles(monkeypatch):
+    """A chunk longer than the Q tile: the second tile's first query sits a
+    tile further into the window."""
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    monkeypatch.setattr(fa, "DEFAULT_BLOCK_Q", 128)
+    got, want = _chunk_both(*_chunk_inputs(256, 512, 16, 4), 512 + 256, 512,
+                            16)
+    np.testing.assert_allclose(got, want, atol=ATOL)
+
+
+def test_eva_chunk_kernel_bf16_operands():
+    """The chip's arithmetic: bf16 operands on both matmuls (the
+    probabilities rounded to bf16 ahead of ``PV``), float32 sums."""
+    got, want = _chunk_both(
+        *_chunk_inputs(128, 256, 16, 8, dtype=jnp.bfloat16), 3 * 256 + 128,
+        256, 16)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(got.astype(np.float32),
+                               want.astype(np.float32), atol=2e-2)
+
+
+def test_eva_chunk_kernel_padded_last_chunk():
+    """A bucket of 128 holding 37 real tokens: whatever the pad rows hold
+    (their queries, and the K and V rows the chunk wrote for them) stays out
+    of the real queries' outputs, and every output is finite."""
+    start, real, window = 256 + 128, 37, 256
+    q, k, v = _chunk_inputs(128, window, 16, 8)
+    w0 = start % window
+    outs = []
+    for junk in (1e3, -7.0):
+        pad = lambda t, lo: t.at[:, :, lo + real:lo + 128].set(junk)
+        got, want = _chunk_both(pad(q, 0), pad(k, w0), pad(v, w0), start,
+                                window, 16)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(got[:, :, :real], want[:, :, :real],
+                                   atol=ATOL)
+        outs.append(np.asarray(got[:, :, :real]))
+    np.testing.assert_array_equal(*outs)
+
+
+# (s, window, eva chunk, windows, Dh, what the reason names)
+CHUNK_REFUSALS = {
+    "chunk_under_the_tile": (64, 256, 16, 8, 128, "chunk of 64"),
+    "window_off_the_tile": (128, 192, 16, 8, 128, "window of 192"),
+    "summary_rows_off_the_tile": (128, 256, 16, 4, 128,
+                                  "summary rows of 64"),
+    "head_dim_off_the_tile": (128, 256, 16, 8, 64, "head dim of 64"),
+    "view_over_the_vmem_budget": (128, 4096, 16, 8, 128, "VMEM"),
+}
+
+
+@pytest.mark.parametrize("case", CHUNK_REFUSALS)
+def test_eva_chunk_refusals_run_the_xla_form(case):
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        eva_chunk_reference_reason, eva_chunk_schedule)
+
+    s, window, chunk, windows, Dh, names = CHUNK_REFUSALS[case]
+    q, k, v = _chunk_inputs(s, window, chunk, windows, Dh=Dh)
+    reason = eva_chunk_reference_reason(s, window, k.shape[2], Dh,
+                                        itemsize=4)
+    assert names in reason
+    sch = eva_chunk_schedule(window, s, window=window, chunk=chunk,
+                             rows=k.shape[2], head_dim=Dh, itemsize=4,
+                             impl="interpret")
+    assert sch["reason"] == reason and sch["visited"] == s * k.shape[2]
+    got, want = _chunk_both(q, k, v, window, window, chunk)
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("case,visited", [
+    ("offset_0", 128 * 128),                  # the diagonal strip
+    ("half_window", 2 * 128 * 128),           # + one strip under it
+    ("window_1", (1 + 1) * 128 * 128),        # diagonal + a summary strip
+    ("window_3_half", (2 + 1) * 128 * 128),
+    ("window_5_whole_summary_strip", (1 + 2) * 128 * 128),
+    ("window_8_no_partial_strip", (2 + 2) * 128 * 128),
+    # strips of 256 rows: the diagonal tile alone is one strip
+    ("bucket_256_offset_0", 256 * 256 + 128 * 256),
+    ("bucket_256_half_window", 2 * 256 * 256 + 128 * 256),
+    ("unaligned_start", (2 + 1) * 128 * 128),  # two strips masked whole
+])
+def test_eva_chunk_schedule_counts_against_the_mask(case, visited):
+    """``kept`` is the mask of ``eva.cached_attention`` summed (over the
+    real queries where the bucket is padded); ``visited`` the strips the
+    kernel's loops walk, counted by hand."""
+    from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_schedule
+
+    start, s, window, chunk, windows = CHUNK_CASES[case]
+    rows = window + windows * (window // chunk)
+    pos = start + np.arange(s)[:, None]
+    r = np.arange(rows)[None]
+    ok = np.where(r < window, r < pos % window + 1,
+                  r - window < (pos // window) * (window // chunk))
+    kw = dict(window=window, chunk=chunk, rows=rows)
+    for real in (s, 37):
+        sch = eva_chunk_schedule(start, s, real=real, impl="interpret", **kw)
+        assert sch["reason"] is None
+        assert sch["kept"] == ok[:real].sum()
+        assert sch["visited"] == visited and sch["dense"] == s * rows
+        assert sch["kept"] <= sch["visited"] <= sch["dense"]
+    # where the dense form runs it computes the whole bucket x view
+    assert eva_chunk_schedule(start, s, impl="xla", **kw)["visited"] == \
+        s * rows
+
+
 # -- the pool: two page kinds, one free list ---------------------------------
 
 def test_pages_needed_follow_the_position_not_the_length():
@@ -418,6 +595,88 @@ def test_eva_counters_count_attended_rows_and_closes(model, params):
         int((pos // W).sum()) * (W // C)
     assert snap["ds_serve_eva_window_closes_total"] == 2
     serve.close()
+
+
+def _kept_by_the_mask(chunks, window, per):
+    """Scores ``eva.cached_attention``'s mask keeps for the real tokens of
+    ``chunks`` [(offset, real tokens)], counted position by position."""
+    return sum(p % window + 1 + (p // window) * per
+               for off, c in chunks for p in range(off, off + c))
+
+
+def test_eva_prefill_score_counters_against_a_brute_force_count(model,
+                                                                params):
+    """The tiny model's sizes are under the kernel's tiles, so the dense
+    form runs and computes every bucket x the whole view."""
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(model, config=dict(ENGINE),
+                                       params=params, registry=reg)
+    serve.submit(np.arange(45) % V, max_new_tokens=2)
+    serve.run()
+    snap = reg.snapshot()
+    # chunks of 16, 16 and 13 tokens (the last in a bucket of 16)
+    assert snap["ds_serve_eva_prefill_scores_total"] == _kept_by_the_mask(
+        [(0, 16), (16, 16), (32, 13)], W, W // C)
+    rows = serve.pool.slot_pages * PAGE
+    assert rows == W + 5 * (W // C)          # five windows in 160 tokens
+    assert snap["ds_serve_eva_prefill_scores_visited_total"] == 3 * 16 * rows
+    serve.close()
+
+
+def test_engine_serves_the_same_tokens_through_the_chunk_kernel(monkeypatch):
+    """A model at the kernel's smallest tiles (window 256, chunk 16, head
+    dim 128, prefill chunks of 128; a view of 2 window pages and one summary
+    page) served with every kernel in interpret mode and with the XLA forms:
+    the same tokens.  The prompts' chunks sit at both window offsets of
+    windows 0 and 1; one last chunk is a padded bucket of 128 (the kernel),
+    the other a bucket of 64 (under the tile: the dense form)."""
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+    from deepspeed_tpu.ops.pallas import common
+
+    fa = importlib.import_module("deepspeed_tpu.ops.pallas.flash_attention")
+    model = CausalLM(ModelConfig(**dict(
+        TINY, hidden_size=256, num_heads=2, head_dim=128, num_layers=1,
+        max_seq_len=2048, eva_window=256, eva_chunk=16)))
+    params = model.init(jax.random.PRNGKey(2))
+    for name in ("eva_mu", "eva_phi"):
+        params["layers"]["attn"][name] = params["layers"]["attn"][name] * 40.0
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(0, V, size=n) for n in (484, 300)]
+
+    def served(registry):
+        serve = deepspeed_tpu.init_serving(
+            model, config=dict(ENGINE, num_slots=2, prefill_chunk=128,
+                               kv_page_tokens=128, max_out_tokens=2048),
+            params=params, registry=registry)
+        reqs = [serve.submit(p, max_new_tokens=6) for p in prompts]
+        serve.run()
+        serve.close()
+        return [list(r.output_tokens) for r in reqs]
+
+    want = served(MetricsRegistry())
+    buckets = []
+    kernel = fa._eva_chunk_kernel
+
+    def spy(*refs, p, **kw):
+        buckets.append(p.bq)
+        return kernel(*refs, p=p, **kw)
+
+    monkeypatch.setattr(common, "default_impl", lambda: "interpret")
+    monkeypatch.setattr(fa, "_eva_chunk_kernel", spy)
+    reg = MetricsRegistry().enable()
+    assert served(reg) == want
+    assert set(buckets) == {128}     # the bucket of 64 runs the XLA form
+    # 484 = 3 x 128 + 100 in a bucket of 128; 300 = 2 x 128 + 44 in one of 64
+    chunks = [(0, 128), (128, 128), (256, 128), (384, 100),
+              (0, 128), (128, 128), (256, 44)]
+    snap = reg.snapshot()
+    assert snap["ds_serve_eva_prefill_scores_total"] == _kept_by_the_mask(
+        chunks, 256, 16)
+    strip = 128 * 128    # the diagonal alone; + one under it; + summaries
+    assert snap["ds_serve_eva_prefill_scores_visited_total"] == (
+        (1 + 2 + 2 + 3) * strip + (1 + 2) * strip + 64 * 384)
 
 
 # -- what it refuses ----------------------------------------------------------

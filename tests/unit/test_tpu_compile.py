@@ -348,6 +348,22 @@ def _eva_summarize(w):
     return fn, [pool, pool, vec, vec, ((32,), I32), table], 1
 
 
+def _eva_chunk(bucket):
+    """The chunk program's attention at one prefill bucket: q [1, 32,
+    bucket, 128] over the slot's 12 pages as one view."""
+    def build(w):
+        from deepspeed_tpu.ops.pallas.flash_attention import \
+            eva_chunk_attention
+
+        view = ((1, w["H"], SERVE_DOC["maxp"] * SERVE_DOC["page"], w["Dh"]),
+                BF16)
+        fn = lambda q, k, v, start: eva_chunk_attention(
+            q, k, v, start, impl="pallas", **EVA)
+        return fn, [((1, w["H"], bucket, w["Dh"]), BF16), view, view,
+                    ((), I32)], 1
+    return build
+
+
 def _f32_stream(kernel):
     """A fused kernel's shapes with the residual stream (a [rows, D]
     operand that is not matmul input) in float32."""
@@ -362,9 +378,12 @@ def _f32_stream(kernel):
 
 @pytest.mark.parametrize("kernel", [
     _eva_decode, _eva_summarize, _f32_stream(_norm_qkv),
-    _f32_stream(_proj_norm), _f32_stream(_mlp)],
+    _f32_stream(_proj_norm), _f32_stream(_mlp), _eva_chunk(1024),
+    _eva_chunk(512), _eva_chunk(256), _eva_chunk(128)],
     ids=["eva_decode_paged", "eva_summarize_paged", "fused_norm_qkv_f32",
-         "fused_proj_norm_f32", "fused_mlp_f32"])
+         "fused_proj_norm_f32", "fused_mlp_f32", "eva_chunk_attention_1024",
+         "eva_chunk_attention_512", "eva_chunk_attention_256",
+         "eva_chunk_attention_128"])
 def test_kernels_compile_at_the_evabyte_serve_doc_shape(v5e, kernel):
     fn, shapes, want = kernel(EVABYTE)
     assert _custom_calls(fn, v5e, *shapes) >= want
@@ -493,6 +512,36 @@ def test_evabyte_programs_never_copy_the_pool(v5e, chip_kernels):
                  "paged_kv_append", "fused_norm_qkv", "fused_proj_norm",
                  "fused_mlp"):
         assert name in text, name
+
+
+@pytest.fixture(scope="module")
+def evabyte_cell():
+    """One engine of the EvaByte cell for its buckets' cases."""
+    return {}
+
+
+@pytest.mark.parametrize("bucket", [1024, 128, 64])
+def test_evabyte_chunk_programs_keep_their_scores_in_vmem(
+        v5e, chip_kernels, evabyte_cell, bucket):
+    """ISSUE 42: the cell's chunk programs (two layers of its six, scanned:
+    one attention call in the loop's body) hold the flash kernel and no
+    float32 score array ``[(1,) 32, bucket, 3072]`` at every bucket of whole
+    lane tiles; a bucket under the tile runs the dense form, scores and
+    all."""
+    if not evabyte_cell:
+        evabyte_cell["cell"] = _ServeCell(
+            v5e, "evabyte-L6", "evabyte-L6.serve-doc",
+            fields=dict(num_layers=2), engine=dict(kv_pool_tokens=16384))
+    text = evabyte_cell["cell"].chunk(bucket).as_text()
+    # the Pallas kernels by their instructions' names, as the readers of the
+    # EVA decode metrics find theirs over the whole window
+    kernels = re.findall(r"^\s*%([a-z_]+)[.\d]* = \S+ custom-call\(.*"
+                         r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    # (XLA drops the batch of one from the dense form's arrays)
+    scores = bool(re.search(rf"f32\[(1,)?32,{bucket},3072\]", text))
+    assert (kernels.count("eva_chunk_attention"), scores) == (
+        (1, False) if bucket >= 128 else (0, True))
+    assert not {"eva_decode_paged", "eva_summarize_paged"} & set(kernels)
 
 
 # the power-of-two chunk buckets from 8 up to the chat cells' prefill_chunk
