@@ -394,10 +394,11 @@ class InferenceEngine:
                 "generate()'s contiguous cache has no layout for them")
         if getattr(getattr(self.module, "config", None), "is_afmoe", False):
             raise NotImplementedError(
-                "a layer_types model (models/afmoe.py) is served through "
-                "deepspeed_tpu.init_serving (the paged pool's two budgets "
-                "hold its rings and full pages); generate()'s contiguous "
-                "cache has no layout for them")
+                "a layer_types model (models/afmoe.py, models/kda_mla.py) "
+                "is served through deepspeed_tpu.init_serving (the paged "
+                "pool holds its rings and full pages, or its latent pages "
+                "and slot state); generate()'s contiguous cache "
+                "(models/decoding.py:init_kv_cache) has no layout for them")
         with self._gen_lock:
             if self._generating:
                 raise RuntimeError(

@@ -65,6 +65,20 @@ F32 = jnp.float32
 KEY_BLOCK = 1024          # keys a step of :func:`attend`'s online softmax
 
 
+def form(cfg):
+    """The module that holds a ``layer_types`` model's three forwards: this
+    one (sliding and global layers), or its sibling for linear-attention and
+    latent-attention layers, which calls this one's pieces."""
+    if cfg.is_kda_mla:
+        from deepspeed_tpu.models import kda_mla
+        return kda_mla
+    import sys
+    return sys.modules[__name__]
+
+
+CACHE_KEY = "k_full"      # the cache entry whose dtype the stream takes
+
+
 def is_sliding(cfg, l: int) -> bool:
     return cfg.layer_types[l] == "sliding_attention"
 
@@ -88,8 +102,10 @@ def refuse_parallel(cfg, mesh, what: str) -> None:
 # ----------------------------------------------------------------------
 # parameters
 # ----------------------------------------------------------------------
-def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
-    """Seeded weights: the repo's uniform init (not the release's
+def init_params(cfg, rng, dtype=F32, attn: bool = True) -> Dict[str, Any]:
+    """Seeded weights (``attn=False``: without the attention projections,
+    for a form whose attention kinds bring their own stacks,
+    ``models/kda_mla.py``): the repo's uniform init (not the release's
     depth-scaled one), norm gains 1, the selection bias normal x 0.05 (the
     release starts it at zero and trains it by its balancing rule; zeros
     would leave the bias path untested, and at x 0.01 a bias wrongly used
@@ -105,15 +121,16 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     ones = lambda *shape: {"scale": jnp.ones(shape, dtype)}
 
     def stack(L, mlp):
-        attn = {"wq": uni((L, D, H * Dh), D), "wk": uni((L, D, Hkv * Dh), D),
-                "wv": uni((L, D, Hkv * Dh), D),
-                "wo": uni((L, H * Dh, D), H * Dh)}
-        if cfg.attn_output_gate:
-            attn["wg"] = uni((L, D, H * Dh), D)
-        if cfg.qk_norm_per_head:
-            attn.update(q_norm=ones(L, Dh), k_norm=ones(L, Dh))
-        out = {"attn_norm": ones(L, D), "mlp_norm": ones(L, D),
-               "attn": attn, "mlp": mlp}
+        out = {"attn_norm": ones(L, D), "mlp_norm": ones(L, D), "mlp": mlp}
+        if attn:
+            a = {"wq": uni((L, D, H * Dh), D), "wk": uni((L, D, Hkv * Dh), D),
+                 "wv": uni((L, D, Hkv * Dh), D),
+                 "wo": uni((L, H * Dh, D), H * Dh)}
+            if cfg.attn_output_gate:
+                a["wg"] = uni((L, D, H * Dh), D)
+            if cfg.qk_norm_per_head:
+                a.update(q_norm=ones(L, Dh), k_norm=ones(L, Dh))
+            out["attn"] = a
         if cfg.sandwich_norm:
             out.update(attn_post_norm=ones(L, D), mlp_post_norm=ones(L, D))
         return out
@@ -267,7 +284,7 @@ def mlp(cfg, lp, h, experts=None, layer=None):
 
 
 def attend(q, segments, q_pos, *, window: int, scale: float,
-           live_keys=None):
+           live_keys=None, expand=None):
     """Causal (and, with ``window`` > 0, sliding-window) attention by an
     online softmax over key blocks: q [B, H, s, Dh] at positions ``q_pos``
     [s]; ``segments`` a list of (k, v [B, Hkv, Sk, Dh], k_pos [Sk]), each
@@ -275,14 +292,24 @@ def attend(q, segments, q_pos, *, window: int, scale: float,
     and a chunk's own keys sit side by side.  ``live_keys`` (traced) bounds
     the LAST segment's loop: its keys at or past it are not visited (the
     global layer's view of ``max_out_tokens`` rows, of which a prompt fills
-    a part).  Float32 scores, never more than [B, H, s, KEY_BLOCK] of them."""
+    a part).  Float32 scores, never more than [B, H, s, KEY_BLOCK] of them.
+    The values' width is their own.  ``expand`` (a latent layer,
+    ``models/kda_mla.py``): a segment is (rows [B, 1, Sk, W], None, k_pos),
+    and a key block's per-head keys and values ``expand(rows of the block)``
+    [B, Hkv, kb, .] are made when the block is visited."""
     B, H, s, Dh = q.shape
-    Hkv = segments[0][0].shape[1]
+    if expand is None:
+        Hkv, Dv = segments[0][0].shape[1], segments[0][1].shape[-1]
+    else:
+        ks, vs = jax.eval_shape(expand, segments[0][0][:, :, :1])
+        Hkv, Dv = ks.shape[1], vs.shape[-1]
     qg = q.reshape(B, Hkv, H // Hkv, s, Dh)
     qp = q_pos[:, None]                                    # [s, 1]
 
     def block(carry, kb, vb, kp):
         m, l, acc = carry
+        if expand is not None:
+            kb, vb = expand(kb)
         sc = jnp.einsum("bgrqd,bgkd->bgrqk", qg, kb.astype(q.dtype),
                         preferred_element_type=F32) * scale
         ok = (kp[None, :] <= qp) & (kp[None, :] >= 0)
@@ -298,13 +325,15 @@ def attend(q, segments, q_pos, *, window: int, scale: float,
         return m_new, alpha * l + p.sum(-1), acc
 
     carry = (jnp.full(qg.shape[:-1], NEG_INF, F32),
-             jnp.zeros(qg.shape[:-1], F32), jnp.zeros(qg.shape, F32))
+             jnp.zeros(qg.shape[:-1], F32),
+             jnp.zeros(qg.shape[:-1] + (Dv,), F32))
     for i, (k, v, k_pos) in enumerate(segments):
         Sk = k.shape[2]
         kb = min(KEY_BLOCK, Sk)
         pad = (-Sk) % kb
         if pad:
-            k, v = (jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
+            k, v = (t if t is None else
+                    jnp.pad(t, ((0, 0), (0, 0), (0, pad), (0, 0)))
                     for t in (k, v))
             k_pos = jnp.pad(k_pos, (0, pad), constant_values=-1)
         n = (Sk + pad) // kb
@@ -313,8 +342,8 @@ def attend(q, segments, q_pos, *, window: int, scale: float,
             continue
 
         def step(j, carry, k=k, v=v, k_pos=k_pos, kb=kb):
-            take = lambda t, ax: jax.lax.dynamic_slice_in_dim(
-                t, j * kb, kb, axis=ax)
+            take = lambda t, ax: t if t is None else \
+                jax.lax.dynamic_slice_in_dim(t, j * kb, kb, axis=ax)
             return block(carry, take(k, 2), take(v, 2), take(k_pos, 0))
 
         if live_keys is not None and i == len(segments) - 1:
@@ -322,7 +351,7 @@ def attend(q, segments, q_pos, *, window: int, scale: float,
         carry = jax.lax.fori_loop(0, n, step, carry)
     _, l, acc = carry
     o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
-    return o.reshape(B, H, s, Dh).astype(q.dtype)
+    return o.reshape(B, H, s, Dv).astype(q.dtype)
 
 
 def _project(cfg, lp, x, cos, sin, sliding):
@@ -343,13 +372,19 @@ def _project(cfg, lp, x, cos, sin, sliding):
     return q, k, v, g
 
 
-def _finish_layer(cfg, lp, x, o, g, experts, layer):
-    """From the attention output o [B, s, H * Dh] to the layer's end."""
-    a = gated(cfg, o, g) @ lp["attn"]["wo"].astype(o.dtype)
+def mlp_block(cfg, lp, x, a, experts, layer):
+    """The attention sub-block's output ``a`` [B, s, D] into the stream,
+    then the MLP sub-block: the layer's end, whatever kind attended."""
     x = close(cfg, x, a, lp.get("attn_post_norm", {}).get("scale"))
     h = rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps)
     return close(cfg, x, mlp(cfg, lp, h, experts, layer),
                  lp.get("mlp_post_norm", {}).get("scale"))
+
+
+def _finish_layer(cfg, lp, x, o, g, experts, layer):
+    """From the attention output o [B, s, H * Dh] to the layer's end."""
+    a = gated(cfg, o, g) @ lp["attn"]["wo"].astype(o.dtype)
+    return mlp_block(cfg, lp, x, a, experts, layer)
 
 
 def _experts(params):
@@ -466,26 +501,38 @@ def inject(cfg, params) -> Dict[str, Any]:
     layers = []
     for l in range(cfg.num_layers):
         lp, le = layer_params(cfg, params, l)
-        a, m = lp["attn"], lp["mlp"]
+        a = lp["attn"]
         cols = [a["wq"], a["wk"], a["wv"]] + (
             [a["wg"]] if cfg.attn_output_gate else [])
         d = {"wqkv": jnp.concatenate(cols, axis=-1), "wo": a["wo"],
-             "n1_scale": lp["attn_norm"]["scale"],
-             "n2_scale": lp["mlp_norm"]["scale"]}
-        if cfg.sandwich_norm:
-            d["n1_post"] = lp["attn_post_norm"]["scale"]
-            d["n2_post"] = lp["mlp_post_norm"]["scale"]
+             **inject_rest(cfg, lp, le)}
         if cfg.qk_norm_per_head:
             d["q_norm"], d["k_norm"] = a["q_norm"]["scale"], a["k_norm"]["scale"]
-        if le is None:
-            d.update({k: m[k] for k in ("w_up", "w_gate", "w_down")})
-        else:
-            d["gate_w"] = m["gate_w"]
-            if cfg.moe_select_bias:
-                d["gate_bias"] = m["gate_bias"]
-            if cfg.num_shared_experts:
-                d["shared"] = m["shared"]
         layers.append(d)
+    return inject_outer(params, layers)
+
+
+def inject_rest(cfg, lp, le) -> Dict[str, Any]:
+    """A layer's injected entries beside its attention's: the norms and the
+    MLP (dense), or the router and the shared expert (expert layer)."""
+    m = lp["mlp"]
+    d = {"n1_scale": lp["attn_norm"]["scale"],
+         "n2_scale": lp["mlp_norm"]["scale"]}
+    if cfg.sandwich_norm:
+        d["n1_post"] = lp["attn_post_norm"]["scale"]
+        d["n2_post"] = lp["mlp_post_norm"]["scale"]
+    if le is None:
+        d.update({k: m[k] for k in ("w_up", "w_gate", "w_down")})
+    else:
+        d["gate_w"] = m["gate_w"]
+        if cfg.moe_select_bias:
+            d["gate_bias"] = m["gate_bias"]
+        if cfg.num_shared_experts:
+            d["shared"] = m["shared"]
+    return d
+
+
+def inject_outer(params, layers) -> Dict[str, Any]:
     out = {"embed": params["embed"], "final_norm": params["final_norm"],
            "lm_head": params["lm_head"], "layers": tuple(layers)}
     if _experts(params) is not None:
@@ -502,6 +549,55 @@ def moe_counts_zero(cfg):
     return (jnp.zeros((cfg.num_experts,), jnp.int32), z, z, z)
 
 
+def fused_close(cfg, dparams, lp, l: int, ctx, x, stats, moe_live, impl):
+    """Layer ``l`` of the fused path from its attention's output ``ctx``
+    [B, M] (gated, before ``wo``) to its end, whatever kind attended: the
+    output projection, the norms, the dense MLP or the expert block, and the
+    routing counts.  Returns (x, stats)."""
+    from deepspeed_tpu.ops.pallas.decode import (fused_mlp, fused_moe_mlp,
+                                                 fused_proj_norm)
+
+    eps = cfg.norm_eps
+    zeros = jnp.zeros_like(x)
+    if cfg.sandwich_norm:
+        # the post-norm sits between the projection and its residual
+        # add: the kernel projects onto a zero stream and norms that
+        _, a = fused_proj_norm(ctx, zeros, lp["wo"], None, lp["n1_post"],
+                               None, kind="rmsnorm", eps=eps, impl=impl)
+        x = x + a.astype(x.dtype)
+        h = rms(x, lp["n2_scale"], eps)
+    else:
+        x, h = fused_proj_norm(ctx, x, lp["wo"], None, lp["n2_scale"],
+                               None, kind="rmsnorm", eps=eps, impl=impl)
+    base = zeros if cfg.sandwich_norm else x
+    if "gate_w" not in lp:
+        y = fused_mlp(h, base, lp["w_up"], lp["w_down"], lp["w_gate"],
+                      act=cfg.activation, impl=impl)
+    else:
+        if cfg.num_shared_experts:
+            sh = lp["shared"]
+            base = fused_mlp(h, base, sh["w_up"], sh["w_down"],
+                             sh["w_gate"], act=cfg.activation, impl=impl)
+        weight, idx = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
+        weight, local = held(cfg, weight, idx)
+        onehot = jax.nn.one_hot(local, cfg.num_experts, dtype=F32)
+        combine = jnp.sum(onehot * weight[..., None], axis=1)
+        ex = dparams["experts"]
+        y = fused_moe_mlp(h, base, combine, ex["w_up"], ex["w_down"],
+                          ex["w_gate"], layer=l - cfg.num_dense_layers,
+                          act=cfg.activation, impl=impl)
+        if stats is not None:
+            load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
+                           & moe_live[:, None], axis=0, dtype=jnp.int32)
+            stats = (stats[0] + load,
+                     stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
+                     stats[2] + jnp.max(load),
+                     stats[3] + jnp.sum(moe_live, dtype=jnp.int32)
+                     * cfg.num_experts_per_tok) + stats[4:]
+    x = close(cfg, x, y, lp.get("n2_post")) if cfg.sandwich_norm else y
+    return x, stats
+
+
 def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                  impl: Optional[str] = None):
     """The layer stack for one token a row: ``x`` [B, D] at per-row
@@ -514,10 +610,7 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     ``moe_live`` [B] bool: the rows that decode, which the attention kernels
     visit and the routing counts cover (``fused_decode.decode_step``).
     Returns (x, cache, routing counts | None)."""
-    from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_mlp,
-                                                 fused_moe_mlp,
-                                                 fused_norm_qkv,
-                                                 fused_proj_norm,
+    from deepspeed_tpu.ops.pallas.decode import (flash_decode, fused_norm_qkv,
                                                  paged_kv_append)
 
     B = x.shape[0]
@@ -530,7 +623,6 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     win_table, full_table = page_table[:, :wp], page_table[:, wp:]
     cos, sin = angles(cfg, pos)                       # [B, 1, Dh/2]
     ring_row, ring_len = pos % max(W, 1), jnp.minimum(pos, W - 1)
-    zeros = jnp.zeros_like(x)
     stats = moe_counts_zero(cfg) if moe_live is not None else None
     i_win = i_full = 0
     for l, lp in enumerate(dparams["layers"]):
@@ -558,43 +650,9 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                                layer=i_full, page_table=full_table,
                                live=moe_live, impl=impl)
             i_full += 1
-        ctx = gated(cfg, ctx.reshape(B, M), g)
-        if cfg.sandwich_norm:
-            # the post-norm sits between the projection and its residual
-            # add: the kernel projects onto a zero stream and norms that
-            _, a = fused_proj_norm(ctx, zeros, lp["wo"], None, lp["n1_post"],
-                                   None, kind="rmsnorm", eps=eps, impl=impl)
-            x = x + a.astype(x.dtype)
-            h = rms(x, lp["n2_scale"], eps)
-        else:
-            x, h = fused_proj_norm(ctx, x, lp["wo"], None, lp["n2_scale"],
-                                   None, kind="rmsnorm", eps=eps, impl=impl)
-        base = zeros if cfg.sandwich_norm else x
-        if "gate_w" not in lp:
-            y = fused_mlp(h, base, lp["w_up"], lp["w_down"], lp["w_gate"],
-                          act=cfg.activation, impl=impl)
-        else:
-            if cfg.num_shared_experts:
-                sh = lp["shared"]
-                base = fused_mlp(h, base, sh["w_up"], sh["w_down"],
-                                 sh["w_gate"], act=cfg.activation, impl=impl)
-            weight, idx = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
-            weight, local = held(cfg, weight, idx)
-            onehot = jax.nn.one_hot(local, cfg.num_experts, dtype=F32)
-            combine = jnp.sum(onehot * weight[..., None], axis=1)
-            ex = dparams["experts"]
-            y = fused_moe_mlp(h, base, combine, ex["w_up"], ex["w_down"],
-                              ex["w_gate"], layer=l - cfg.num_dense_layers,
-                              act=cfg.activation, impl=impl)
-            if stats is not None:
-                load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
-                               & moe_live[:, None], axis=0, dtype=jnp.int32)
-                stats = (stats[0] + load,
-                         stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
-                         stats[2] + jnp.max(load),
-                         stats[3] + jnp.sum(moe_live, dtype=jnp.int32)
-                         * cfg.num_experts_per_tok)
-        x = close(cfg, x, y, lp.get("n2_post")) if cfg.sandwich_norm else y
+        x, stats = fused_close(cfg, dparams, lp, l,
+                               gated(cfg, ctx.reshape(B, M), g), x, stats,
+                               moe_live, impl)
     return x, {"k_win": k_win, "v_win": v_win,
                "k_full": k_full, "v_full": v_full}, stats
 

@@ -34,6 +34,17 @@ families instead; `ModelConfig` spans them with feature flags:
   (``models/afmoe.py``; benchmarks/configs/trinity-large-L5-ep8.json).
   SERVED ONLY, on seeded weights, one chip (tp = ep = sp = 1): no training
   loss, no checkpoint import
+- Linear-attention layers beside latent-attention layers (Kimi-Linear): the
+  same layer form (static pattern, leading dense layers, the held-share
+  expert block) over two further attention kinds, plain pre-norm
+  (``layer_types``: ``linear_attention``, a gated delta rule whose state is
+  ``kda_num_heads`` matrices of ``kda_head_dim`` squared in float32 a slot,
+  fed through a short causal convolution of ``kda_conv_kernel`` taps with a
+  channel-wise decay and a gated output norm; ``latent_attention``, keys
+  and values decompressed from ONE row of ``mla_kv_rank + mla_rot_dim``
+  values a token shared by all heads, no position encoding;
+  ``models/kda_mla.py``; benchmarks/configs/kimi-linear-L5-ep8.json).
+  SERVED ONLY, one chip's share, seeded weights
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -111,6 +122,23 @@ class ModelConfig:
     # ``moe_router_experts`` (0 = all of them are held)
     moe_router_experts: int = 0
     moe_first_expert: int = 0
+    # -- ``layer_types`` kinds "linear_attention" and "latent_attention"
+    # (models/kda_mla.py).  Linear: heads, the size of a head's key and
+    # value (its state is [size, size] float32), taps of the short causal
+    # convolution on q, k and v, rank of the decay gate's and the output
+    # gate's two-matrix projections
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_conv_kernel: int = 0
+    kda_gate_rank: int = 0
+    # latent: the cache row is ``mla_kv_rank`` normed values plus
+    # ``mla_rot_dim`` shared key values (used UNROTATED: no position
+    # encoding); a query head is ``mla_nope_dim + mla_rot_dim`` wide, a
+    # value head ``mla_v_dim``; ``num_heads`` query heads
+    mla_kv_rank: int = 0
+    mla_nope_dim: int = 0
+    mla_rot_dim: int = 0
+    mla_v_dim: int = 0
     # RMSNorm multiplies by (1 + scale): the stored gain starts at 0
     norm_add_unit_offset: bool = False
     # the residual stream (and the logits) stay float32 whatever dtype the
@@ -211,18 +239,25 @@ class ModelConfig:
                  for f in dataclasses.fields(self)
                  if f.name in _AFMOE_ONLY):
             raise ValueError(
-                f"{sorted(_AFMOE_ONLY)} belong to the layer form of "
-                "models/afmoe.py, which ``layer_types`` turns on")
+                f"{sorted(_AFMOE_ONLY)} belong to the layer form that "
+                "``layer_types`` turns on (models/afmoe.py: sliding_attention "
+                "and full_attention layers; models/kda_mla.py: "
+                "linear_attention and latent_attention layers)")
 
     def _check_afmoe(self):
-        """The one combination models/afmoe.py is written for."""
+        """The combinations the layer form is written for: sliding and
+        global layers (models/afmoe.py), or linear-attention and
+        latent-attention layers (models/kda_mla.py), not a mix of the two."""
         self.layer_types = tuple(self.layer_types)
-        kinds = {"sliding_attention", "full_attention"}
-        if len(self.layer_types) != self.num_layers or \
-                not set(self.layer_types) <= kinds:
+        got = set(self.layer_types)
+        if len(self.layer_types) != self.num_layers or not (
+                got <= _WINDOW_KINDS or got <= _STATE_KINDS):
             raise ValueError(
-                f"layer_types must name one of {sorted(kinds)} for each of "
-                f"the {self.num_layers} layers, got {self.layer_types!r}")
+                f"layer_types must name, for each of the {self.num_layers} "
+                f"layers, one of {sorted(_WINDOW_KINDS)} (models/afmoe.py) or "
+                f"one of {sorted(_STATE_KINDS)} (models/kda_mla.py), got "
+                f"{self.layer_types!r}")
+        self._check_kda_mla()
         if "sliding_attention" in self.layer_types and self.sliding_window < 1:
             raise ValueError("sliding_attention layers need sliding_window")
         if not 0 <= self.num_dense_layers <= self.num_layers:
@@ -248,10 +283,37 @@ class ModelConfig:
                 or self.tie_embeddings or self.num_pred_heads != 1 \
                 or self.rotary_pct != 1.0 or self.dropout:
             raise ValueError(
-                "layer_types (models/afmoe.py) is built for RMSNorm, RoPE "
-                "on the sliding layers, gated MLPs without biases, dropless "
+                "layer_types (models/afmoe.py, models/kda_mla.py) is built "
+                "for RMSNorm, RoPE on the sliding layers and no position "
+                "encoding elsewhere, gated MLPs without biases, dropless "
                 "experts (moe_drop_tokens=False), an untied head and a "
                 "stream in the weights' dtype")
+
+    def _check_kda_mla(self):
+        """The sizes the two kinds of models/kda_mla.py need, and what that
+        module does not build."""
+        sizes = {k: getattr(self, k) for k in _KDA_MLA_ONLY}
+        if not self.is_kda_mla:
+            if any(sizes.values()):
+                raise ValueError(
+                    f"{sorted(_KDA_MLA_ONLY)} belong to linear_attention and "
+                    "latent_attention layers (models/kda_mla.py)")
+            return
+        used = {"linear_attention": "kda_", "latent_attention": "mla_"}
+        need = [k for k in _KDA_MLA_ONLY if sizes[k] < 1 and any(
+            k.startswith(used[t]) for t in set(self.layer_types))]
+        if need:
+            raise ValueError(
+                f"layer_types {self.layer_types!r} (models/kda_mla.py) "
+                f"needs {need}")
+        if self.sandwich_norm or self.qk_norm_per_head \
+                or self.attn_output_gate or self.embed_scale != 1.0 \
+                or self.sliding_window:
+            raise ValueError(
+                "linear_attention and latent_attention layers "
+                "(models/kda_mla.py) are plain pre-norm: sandwich_norm, "
+                "qk_norm_per_head, attn_output_gate, embed_scale and "
+                "sliding_window belong to models/afmoe.py's two kinds")
 
     @property
     def has_mlp_bias(self) -> bool:
@@ -270,18 +332,32 @@ class ModelConfig:
         return self.layer_types is not None
 
     @property
+    def is_kda_mla(self) -> bool:
+        """A ``layer_types`` model of linear-attention and latent-attention
+        layers (models/kda_mla.py)."""
+        return self.layer_types is not None and \
+            set(self.layer_types) <= _STATE_KINDS
+
+    @property
     def num_expert_layers(self) -> int:
         """Layers that carry the expert block (all of a MoE model's but an
         afmoe model's leading dense ones)."""
         return (self.num_layers - self.num_dense_layers) if self.is_moe else 0
 
 
-# fields only models/afmoe.py reads
+_WINDOW_KINDS = frozenset({"sliding_attention", "full_attention"})
+_STATE_KINDS = frozenset({"linear_attention", "latent_attention"})
+# fields only models/kda_mla.py reads
+_KDA_MLA_ONLY = ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
+                 "kda_gate_rank", "mla_kv_rank", "mla_nope_dim",
+                 "mla_rot_dim", "mla_v_dim")
+# fields only the layer form (models/afmoe.py, models/kda_mla.py) reads
 _AFMOE_ONLY = frozenset({
     "sliding_window", "num_dense_layers", "dense_intermediate_size",
     "qk_norm_per_head", "attn_output_gate", "sandwich_norm", "embed_scale",
     "moe_score_func", "moe_route_scale", "moe_select_bias",
-    "num_shared_experts", "moe_router_experts", "moe_first_expert"})
+    "num_shared_experts", "moe_router_experts", "moe_first_expert",
+    *_KDA_MLA_ONLY})
 
 
 _PRESETS = {

@@ -312,7 +312,10 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     sliding layers, every position for the global ones) and the call is a
     prefill chunk of ONE slot at a scalar ``start_pos``, of which the first
     ``valid_len`` tokens are real: a ring takes no pad row.  Decode runs on
-    the fused path only.
+    the fused path only.  Where the kinds are linear and latent attention
+    (``models/kda_mla.py``) the cache is that module's view of one slot:
+    latent rows of every position, and the recurrent state and convolution
+    tail the chunk carries in and out as of its last real row.
 
     Returns (logits [B, s, num_pred_heads * V], new_cache).  Used for prefill
     (s = prompt length, start_pos=0), decode (s = 1), and chunked per-slot
@@ -331,9 +334,10 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
                 "fused path (fused_decode.decode_step); forward_with_cache "
                 "prefills one slot's chunk at a scalar start_pos")
         afmoe.refuse_parallel(cfg, mesh, "forward_with_cache")
+        form = afmoe.form(cfg)      # afmoe itself, or models/kda_mla.py
         x = afmoe.embed(cfg, params["embed"]["tok"], tokens,
-                        cache["k_full"].dtype)
-        x, new_cache = afmoe.cached_layers(
+                        cache[form.CACHE_KEY].dtype)
+        x, new_cache = form.cached_layers(
             cfg, params, x, cache, start_pos,
             s if valid_len is None else valid_len)
         return output_logits(cfg, params, x), new_cache

@@ -75,7 +75,7 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
 
     if cfg.is_afmoe:      # two stacks of layers, its own view
         from deepspeed_tpu.models import afmoe
-        return afmoe.inject(cfg, params)
+        return afmoe.form(cfg).inject(cfg, params)
     ly = params["layers"]
     attn, mlp = ly["attn"], ly["mlp"]
     if is_qtensor(attn["wq"]):  # int8 serving: concat payloads AND scales
@@ -155,7 +155,7 @@ def moe_counts_zero(cfg):
     """Zeros of ``decode_step``'s routing counts (its ``moe_live`` result)."""
     if cfg.is_afmoe:      # a fourth count: the assignments offered
         from deepspeed_tpu.models import afmoe
-        return afmoe.moe_counts_zero(cfg)
+        return afmoe.form(cfg).moe_counts_zero(cfg)
     return (jnp.zeros((cfg.num_experts,), jnp.int32),
             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
 
@@ -207,9 +207,10 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             raise NotImplementedError(
                 "a layer_types model (models/afmoe.py) decodes through the "
                 "paged pool's two budgets (serving/paged_kv.py)")
+        form = afmoe.form(cfg)      # afmoe itself, or models/kda_mla.py
         x = afmoe.embed(cfg, dparams["embed"]["tok"], tokens[:, 0],
-                        cache["k_full"].dtype)
-        x, new_cache, moe_stats = afmoe.fused_layers(
+                        cache[form.CACHE_KEY].dtype)
+        x, new_cache, moe_stats = form.fused_layers(
             cfg, dparams, x, cache, pos, page_table, moe_live=moe_live,
             impl=impl)
         logits = output_logits(cfg, dparams, x)

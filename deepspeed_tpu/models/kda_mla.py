@@ -1,0 +1,525 @@
+"""Linear-attention layers beside latent-attention layers
+(moonshotai Kimi-Linear; ``ModelConfig.layer_types``): the layer form of
+``models/afmoe.py`` (a static pattern, leading dense layers, the sigmoid
+bias-corrected router over ONE CHIP'S SHARE of the experts) over two further
+attention kinds, plain pre-norm.
+
+With ``N(.)`` RMSNorm (own gain, ``norm_eps``), ``x`` the stream, ``h =
+N_in(x)``:
+
+    layer:  x = x + attn(N_in(x));  x = x + mlp(N_post(x))   (afmoe.mlp_block)
+
+    "linear_attention" (KDA: H = kda_num_heads heads of d = kda_head_dim):
+        u = h [Wq | Wk | Wv]                                   [3 H d]
+        c[t] = silu(sum_i conv[:, i] * u[t - K + 1 + i])       depthwise causal
+            convolution of K = kda_conv_kernel taps, zeros before t = 0
+        q = l2norm(c_q) * d^-0.5;  k = l2norm(c_k);  v = c_v   per head
+        g = -exp(a_log[head]) * softplus((h Wf_down) Wf_up + dt_bias)   <= 0
+        beta = sigmoid(h Wb)                                   [H]
+        per head, S [d, d] float32, S_0 = 0:
+            S' = diag(exp(g_t)) S_{t-1}
+            S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+            o_t = S_t^T q_t
+        a = (N_o(o_t) * sigmoid((h Wg_down) Wg_up + b_g)) Wo   N_o over d
+
+    "latent_attention" (MLA: H = num_heads query heads of n + r = mla_nope_dim
+    + mla_rot_dim, values of mla_v_dim, latent mla_kv_rank), NO position
+    encoding (the r values are used UNROTATED):
+        q = h Wq;  [c_raw | k_r] = h Wkva;  c = N_kv(c_raw)
+        [k_n,h | v_h] = c Wkvb        per head
+        score_h(t, j) = (q_n,h(t) . k_n,h(j) + q_r,h(t) . k_r(j)) / sqrt(n + r)
+        a = concat_h(softmax(score_h) v_h) Wo
+        cache row of token j: [c(j) | k_r(j)], shared by all heads
+
+Each piece is ONE function here (:func:`short_conv`, :func:`kda_activate`,
+:func:`kda_step` / :func:`kda_chunk`, :func:`kda_out`; :func:`mla_project`,
+:func:`mla_decompress`, :func:`mla_absorb` / :func:`mla_unabsorb`) and the
+three forwards call them, as ``afmoe.py``'s do; the expert block, the pattern
+loop's parameter stacks and the fused path's layer end are ``afmoe``'s own
+functions.  The attention parameters are two stacks by KIND (``params["kda"]``
+``[linear layers, ...]``, ``params["mla"]`` ``[latent layers, ...]``): the
+kinds interleave inside ``afmoe``'s two MLP stacks.
+
+Cache (``serving/paged_kv.py``): a linear layer keeps, for each SLOT, its
+state ``[H, d, d]`` float32 and the convolution's tail, the last ``K - 1``
+rows of ``u``: fixed, never paged, zeroed when a request starts (a chunk at
+position 0 reads zeros whatever the slot held).  A latent layer keeps one
+row a position in LATENT PAGES ``[pages, 1, page, row_width]``: no head axis
+to speak of, no V array, keys and values read from the same row (padded from
+``mla_kv_rank + mla_rot_dim`` to whole 128-lane tiles, zeros).  A prefill
+chunk carries the state: pad rows of its bucket get ``beta = 0`` and ``g =
+0`` and leave state and tail as of the last REAL row.  It runs the
+recurrence in its chunkwise (UT transform) form over sub-chunks of ``SUB``
+tokens; decays are differences of cumulative log-decays inside a sub-chunk,
+masked before the exponential, so that no quotient of decays is formed.  A
+decode step attends in the absorbed form: ``q'_h = [q_n,h Wkvb_k,h^T |
+q_r,h]`` against the rows, the context ``sum_j p_j c(j)`` through
+``Wkvb_v,h``.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models import afmoe
+from deepspeed_tpu.models.afmoe import F32, refuse_parallel, rms
+
+SUB = 64                  # tokens a sub-chunk of the chunkwise delta rule
+HI = jax.lax.Precision.HIGHEST
+L2_EPS = 1e-6
+CACHE_KEY = "latent"      # the cache entry whose dtype the stream takes
+
+
+def is_linear(cfg, l: int) -> bool:
+    return cfg.layer_types[l] == "linear_attention"
+
+
+def kind_layers(cfg):
+    """(indices of the linear layers, indices of the latent layers)."""
+    L = range(cfg.num_layers)
+    return ([l for l in L if is_linear(cfg, l)],
+            [l for l in L if not is_linear(cfg, l)])
+
+
+def row_width(cfg) -> int:
+    """A latent page's row: ``mla_kv_rank + mla_rot_dim`` values padded to
+    whole 128-lane tiles."""
+    return -(-(cfg.mla_kv_rank + cfg.mla_rot_dim) // 128) * 128
+
+
+def state_shapes(cfg, num_slots: int):
+    """Per-slot state of the linear layers: ``state`` float32 and ``tail``
+    (the stream's dtype) shapes."""
+    n = len(kind_layers(cfg)[0])
+    H, d = cfg.kda_num_heads, cfg.kda_head_dim
+    return ((n, num_slots, H, d, d),
+            (n, num_slots, cfg.kda_conv_kernel - 1, 3 * H * d))
+
+
+def slot_state_bytes(cfg, dtype) -> int:
+    """Bytes of :func:`state_shapes` for ONE slot, the tail in ``dtype``."""
+    state, tail = state_shapes(cfg, 1)
+    return math.prod(state) * 4 + math.prod(tail) * jnp.dtype(dtype).itemsize
+
+
+# ----------------------------------------------------------------------
+# parameters
+# ----------------------------------------------------------------------
+def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
+    """``afmoe.init_params``' two MLP stacks and the two attention stacks.
+    Seeded so that the mechanism is alive: ``a_log`` = log U(1, 16) and
+    ``dt_bias`` with ``softplus(dt_bias)`` log-uniform in [1e-3, 1e-1], so a
+    step's decay runs from 0.999 (a channel that remembers thousands of
+    tokens) to about 0.2 (one that forgets in three); gate bias normal x
+    0.1; norm gains 1; the token embedding normal x 1, not ``afmoe``'s x
+    0.02: this form has no ``embed_scale``, and under an embedding of 0.02
+    the stream IS the first sub-blocks' outputs, whose bf16 rounding nothing
+    then dilutes (0.64% of relative error after one layer and 3.1% after
+    five at the published widths, a sixth of all top-8 sets flipped against
+    the float32 forward: PERF.md section 6, PR 44)."""
+    params = afmoe.init_params(cfg, rng, dtype, attn=False)
+    D, H = cfg.hidden_size, cfg.num_heads
+    lin, lat = kind_layers(cfg)
+    keys = iter(jax.random.split(jax.random.fold_in(rng, 0x4B4441), 24))
+    params["embed"] = {"tok": jax.random.normal(
+        next(keys), (cfg.vocab_size, D), dtype)}
+    uni = lambda shape, fan_in: jax.random.uniform(
+        next(keys), shape, dtype, -fan_in ** -0.5, fan_in ** -0.5)
+    if lin:
+        L, Hk, d = len(lin), cfg.kda_num_heads, cfg.kda_head_dim
+        K, r, C = cfg.kda_conv_kernel, cfg.kda_gate_rank, Hk * d
+        dt = jnp.exp(jax.random.uniform(next(keys), (L, C), F32,
+                                        jnp.log(1e-3), jnp.log(1e-1)))
+        params["kda"] = {
+            "wq": uni((L, D, C), D), "wk": uni((L, D, C), D),
+            "wv": uni((L, D, C), D), "conv": uni((L, 3 * C, K), K),
+            "wf_down": uni((L, D, r), D), "wf_up": uni((L, r, C), r),
+            "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+            "a_log": jnp.log(jax.random.uniform(
+                next(keys), (L, Hk), F32, 1.0, 16.0)).astype(dtype),
+            "wb": uni((L, D, Hk), D),
+            "wg_down": uni((L, D, r), D), "wg_up": uni((L, r, C), r),
+            "b_g": jax.random.normal(next(keys), (L, C), dtype) * 0.1,
+            "o_norm": jnp.ones((L, d), dtype), "wo": uni((L, C, D), C)}
+    if lat:
+        L, n, r = len(lat), cfg.mla_nope_dim, cfg.mla_rot_dim
+        kv, v = cfg.mla_kv_rank, cfg.mla_v_dim
+        params["mla"] = {
+            "wq": uni((L, D, H * (n + r)), D), "wkva": uni((L, D, kv + r), D),
+            "kv_norm": jnp.ones((L, kv), dtype),
+            "wkvb": uni((L, kv, H * (n + v)), kv),
+            "wo": uni((L, H * v, D), H * v)}
+    return params
+
+
+def layer_params(cfg, params, l: int):
+    """``afmoe.layer_params`` with layer ``l``'s slice of its KIND's
+    attention stack as ``attn``."""
+    lp, le = afmoe.layer_params(cfg, params, l)
+    kind = "kda" if is_linear(cfg, l) else "mla"
+    i = sum(is_linear(cfg, j) == is_linear(cfg, l) for j in range(l))
+    return {**lp, "attn": jax.tree.map(lambda a: a[i], params[kind])}, le
+
+
+# ----------------------------------------------------------------------
+# the linear-attention (KDA) pieces
+# ----------------------------------------------------------------------
+def kda_project(a, h):
+    """The projections of ``h`` [..., D]: (u [..., 3 H d] = q | k | v before
+    the convolution, the two gates' low-rank rows, the beta logits)."""
+    w = lambda n: a[n].astype(h.dtype)
+    u = jnp.concatenate([h @ w("wq"), h @ w("wk"), h @ w("wv")], axis=-1)
+    return u, h @ w("wf_down"), h @ w("wg_down"), h @ w("wb")
+
+
+def short_conv(u, tail, w, valid_len=None):
+    """The depthwise causal convolution and its SiLU: ``u`` [B, s, C] after
+    ``tail`` [B, K - 1, C] (the K - 1 rows before them; zeros at a
+    sequence's start), ``w`` [C, K].  Returns (float32 [B, s, C], the tail
+    after the first ``valid_len`` rows (None: all ``s``): the last K - 1 REAL
+    rows, so pad rows do not move it)."""
+    K, s = w.shape[-1], u.shape[1]
+    full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
+    w32 = w.astype(F32)
+    y = sum(full[:, i:i + s].astype(F32) * w32[:, i] for i in range(K))
+    new_tail = jax.lax.dynamic_slice_in_dim(
+        full, s if valid_len is None else valid_len, K - 1, axis=1)
+    return jax.nn.silu(y), new_tail.astype(tail.dtype)
+
+
+def kda_activate(cfg, a, c, f_low, g_low, b_raw):
+    """From the convolved rows ``c`` [..., 3 H d] float32 and the gates'
+    low-rank rows to the recurrence's inputs, float32: q, k, v [..., H, d]
+    (q and k l2-normed per head, q scaled), the log-decay g [..., H, d] <=
+    0, beta [..., H], and the output gate before its sigmoid [..., H d]."""
+    H, d = cfg.kda_num_heads, cfg.kda_head_dim
+    lead = c.shape[:-1]
+    q, k, v = (t.reshape(lead + (H, d)) for t in jnp.split(c, 3, axis=-1))
+    l2 = lambda t: t * jax.lax.rsqrt((t * t).sum(-1, keepdims=True) + L2_EPS)
+    up = lambda low, w, b: jnp.dot(
+        low, a[w].astype(low.dtype), preferred_element_type=F32) \
+        + a[b].astype(F32)
+    f = up(f_low, "wf_up", "dt_bias").reshape(lead + (H, d))
+    g = -jnp.exp(a["a_log"].astype(F32))[:, None] * jax.nn.softplus(f)
+    return (l2(q) * d ** -0.5, l2(k), v, g,
+            jax.nn.sigmoid(b_raw[..., :H].astype(F32)),
+            up(g_low, "wg_up", "b_g"))
+
+
+def kda_step(S, q, k, v, g, beta):
+    """The delta rule for ONE token: state ``S`` [..., H, d, d] float32 (key
+    axis, value axis), q, k, v, g [..., H, d], beta [..., H].  Returns (o
+    [..., H, d], S).  Elementwise float32: the same on every backend."""
+    S = S * jnp.exp(g)[..., None]
+    u = beta[..., None] * (v - (S * k[..., None]).sum(-2))
+    S = S + k[..., None] * u[..., None, :]
+    return (S * q[..., None]).sum(-2), S
+
+
+def kda_chunk(S, q, k, v, g, beta):
+    """The delta rule for ``s`` tokens of one sequence, chunkwise: ``S``
+    [H, d, d]; q, k, v, g [s, H, d]; beta [s, H]; ``s`` a multiple of
+    :data:`SUB` or less than it.  Returns (S, o [s, H, d]).
+
+    Inside a sub-chunk, with ``G_t`` the cumulative log-decay and ``S_0``
+    the state before it,
+
+        S_t = diag(e^{G_t}) S_0 + sum_{i <= t} diag(e^{G_t - G_i}) k_i u_i^T
+        (I + A) u = beta (v - (k e^G) S_0),
+            A[t, i] = beta_t sum_c k_t[c] k_i[c] e^{G_t[c] - G_i[c]}, i < t
+        o = (q e^G) S_0 + B u,  B[t, i] = sum_c q_t[c] k_i[c] e^{...}, i <= t
+
+    ``T = (I + A)^-1`` (the UT transform) is made by forward substitution
+    for all sub-chunks at once, the states then follow one sub-chunk after
+    another.  Every exponent is a difference ``G_t - G_i`` with ``i <= t``,
+    masked BEFORE the exponential: at most 0, so a channel that decays by
+    e^-100 over a sub-chunk neither overflows nor flushes its row."""
+    s, H, d = q.shape
+    C = min(SUB, s)
+    n = s // C
+    assert s == n * C, (s, C)
+    sub = lambda t: t.reshape((n, C) + t.shape[1:]).swapaxes(1, 2)
+    q, k, v, g, beta = (sub(t) for t in (q, k, v, g, beta))   # [n, H, C, .]
+    G = jnp.cumsum(g, axis=2)
+    t_i = jnp.arange(C)
+    low = t_i[:, None] >= t_i[None, :]                        # i <= t
+
+    def pairs(xs):
+        q, k, G, beta = xs                                    # one sub-chunk
+        E = jnp.exp(jnp.where(low[None, :, :, None],
+                              G[:, :, None, :] - G[:, None, :, :], -jnp.inf))
+        kE = k[:, None, :, :] * E                             # [H, t, i, d]
+        A = (k[:, :, None, :] * kE).sum(-1) * beta[:, :, None]
+        return (jnp.where(low & ~jnp.eye(C, dtype=bool), A, 0.0),
+                (q[:, :, None, :] * kE).sum(-1))
+
+    A, B = jax.lax.map(pairs, (q, k, G, beta))                # [n, H, C, C]
+
+    def row(t, T):
+        # row t of (I + A)^-1: e_t - sum_{i < t} A[t, i] T[i, :]
+        new = (t_i == t).astype(F32) - (A[:, :, t, :, None] * T).sum(-2)
+        return jax.lax.dynamic_update_index_in_dim(T, new, t, axis=2)
+
+    T = jax.lax.fori_loop(0, C, row, jnp.zeros_like(A))
+    dot = lambda spec, x, y: jnp.einsum(spec, x, y, precision=HI)
+
+    def step(S, xs):
+        q, k, v, G, beta, T, B = xs
+        eG = jnp.exp(G)
+        u = dot("hti,hid->htd", T,
+                beta[..., None] * (v - dot("htc,hcd->htd", k * eG, S)))
+        o = dot("htc,hcd->htd", q * eG, S) + dot("hti,hid->htd", B, u)
+        Gc = G[:, -1:, :]
+        S = S * jnp.exp(Gc).swapaxes(1, 2) \
+            + dot("hic,hid->hcd", k * jnp.exp(Gc - G), u)
+        return S, o
+
+    S, o = jax.lax.scan(step, S, (q, k, v, G, beta, T, B))
+    return S, o.swapaxes(1, 2).reshape(s, H, d)
+
+
+def kda_out(cfg, a, o, gate):
+    """The gated output norm: ``N_o(o)`` over each head's values times the
+    sigmoid of ``gate``; o [..., H, d] float32 -> [..., H d] in the weights'
+    dtype (the input of ``wo``)."""
+    y = rms(o, a["o_norm"], cfg.norm_eps).reshape(gate.shape)
+    return (y * jax.nn.sigmoid(gate)).astype(a["wo"].dtype)
+
+
+# ----------------------------------------------------------------------
+# the latent-attention (MLA) pieces
+# ----------------------------------------------------------------------
+def mla_row(cfg, a, cr):
+    """The cache row from ``[c_raw | k_r]`` [..., kv + r]: the latent
+    normed, the shared key values as they are, zeros up to the row width."""
+    kv = cfg.mla_kv_rank
+    c = rms(cr[..., :kv], a["kv_norm"], cfg.norm_eps)
+    pad = jnp.zeros(cr.shape[:-1] + (row_width(cfg) - cr.shape[-1],),
+                    cr.dtype)
+    return jnp.concatenate([c, cr[..., kv:], pad], axis=-1)
+
+
+def mla_project(cfg, a, h):
+    """(q [..., H, n + r], the cache row [..., row_width]) of ``h``."""
+    q = h @ a["wq"].astype(h.dtype)
+    return (q.reshape(q.shape[:-1] + (cfg.num_heads, -1)),
+            mla_row(cfg, a, h @ a["wkva"].astype(h.dtype)))
+
+
+def _wkvb(cfg, a):
+    """``Wkvb`` as (keys [kv, H, n], values [kv, H, v])."""
+    w = a["wkvb"].reshape(cfg.mla_kv_rank, cfg.num_heads, -1)
+    return w[..., :cfg.mla_nope_dim], w[..., cfg.mla_nope_dim:]
+
+
+def mla_decompress(cfg, a, rows):
+    """Per-head keys [..., H, n + r] and values [..., H, v] of cache rows
+    [..., row_width]."""
+    kv, r = cfg.mla_kv_rank, cfg.mla_rot_dim
+    wk, wv = _wkvb(cfg, a)
+    c = rows[..., :kv]
+    k_n = jnp.einsum("...c,chn->...hn", c, wk.astype(c.dtype))
+    k_r = jnp.broadcast_to(rows[..., None, kv:kv + r],
+                           k_n.shape[:-1] + (r,))
+    return (jnp.concatenate([k_n, k_r], axis=-1),
+            jnp.einsum("...c,chv->...hv", c, wv.astype(c.dtype)))
+
+
+def mla_absorb(cfg, a, q):
+    """A decode step's queries against the ROWS: q [B, H, n + r] ->
+    ``[q_n Wkvb_k^T | q_r | 0]`` [B, H, row_width]."""
+    n = cfg.mla_nope_dim
+    wk, _ = _wkvb(cfg, a)
+    qc = jnp.einsum("bhn,chn->bhc", q[..., :n], wk.astype(q.dtype))
+    pad = jnp.zeros(q.shape[:-1] + (
+        row_width(cfg) - cfg.mla_kv_rank - cfg.mla_rot_dim,), q.dtype)
+    return jnp.concatenate([qc, q[..., n:], pad], axis=-1)
+
+
+def mla_unabsorb(cfg, a, o):
+    """The rows' weighted sum o [B, H, row_width] (its first ``mla_kv_rank``
+    values: ``sum_j p_j c(j)``) through ``Wkvb_v``: [B, H v]."""
+    _, wv = _wkvb(cfg, a)
+    y = jnp.einsum("bhc,chv->bhv", o[..., :cfg.mla_kv_rank],
+                   wv.astype(o.dtype))
+    return y.reshape(o.shape[0], -1)
+
+
+def _mla_scale(cfg) -> float:
+    return (cfg.mla_nope_dim + cfg.mla_rot_dim) ** -0.5
+
+
+# ----------------------------------------------------------------------
+# forwards 1 and 2: no cache (CausalLM.apply), and a prefill chunk on one
+# slot's views (state carried in and out)
+# ----------------------------------------------------------------------
+def apply_layers(cfg, params, x, mesh=None):
+    """The layer stack on ``x`` [B, S, D], positions ``0 .. S - 1``: every
+    sequence a chunk at position 0 on an empty cache of its own."""
+    refuse_parallel(cfg, mesh, "CausalLM.apply")
+    B, S, _ = x.shape
+    pad = -S % SUB if S > SUB else 0       # whole sub-chunks (pad rows idle)
+    (ns, _, H, d, _), (_, _, K1, C3) = state_shapes(cfg, 1)
+    nl = cfg.num_layers - ns
+
+    def one(xb):
+        xb = jnp.pad(xb, ((0, pad), (0, 0)))[None]
+        cache = {"latent": jnp.zeros((nl, 1, 1, S + pad, row_width(cfg)),
+                                     x.dtype),
+                 "state": jnp.zeros((ns, 1, H, d, d), F32),
+                 "tail": jnp.zeros((ns, 1, K1, C3), x.dtype)}
+        return cached_layers(cfg, params, xb, cache, 0, S)[0][0, :S]
+
+    return jax.lax.map(one, x)
+
+
+def cached_layers(cfg, params, x, cache, start, valid_len):
+    """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
+    over ONE slot's views (``latent`` [latent layers, 1, 1, positions,
+    row_width]; ``state`` [linear layers, 1, H, d, d] float32; ``tail``
+    [linear layers, 1, K - 1, 3 H d]: what ``ServingEngine._state_forward``
+    slices out); only the first ``valid_len`` rows are real.  A chunk at
+    position 0 starts from a zero state whatever the slot held (the state's
+    reset at admission).  Returns (x, views)."""
+    B, s, _ = x.shape
+    assert B == 1, "a chunk program prefills one slot"
+    start = jnp.asarray(start, jnp.int32)
+    pos = start + jnp.arange(s)
+    real = jnp.arange(s) < valid_len
+    latent, state, tail = cache["latent"], cache["state"], cache["tail"]
+    kept = (start != 0)
+    experts = afmoe._experts(params)
+    i_lin = i_lat = 0
+    for l in range(cfg.num_layers):
+        lp, le = layer_params(cfg, params, l)
+        a = lp["attn"]
+        h = rms(x, lp["attn_norm"]["scale"], cfg.norm_eps)
+        if is_linear(cfg, l):
+            u, f_low, g_low, b_raw = kda_project(a, h)
+            c, t1 = short_conv(u, jnp.where(kept, tail[i_lin], 0),
+                               a["conv"], valid_len)
+            q, k, v, g, beta, gate = kda_activate(cfg, a, c, f_low, g_low,
+                                                  b_raw)
+            # a pad row: beta = 0 and no decay, so the state does not move
+            g = jnp.where(real[None, :, None, None], g, 0.0)
+            beta = jnp.where(real[None, :, None], beta, 0.0)
+            S1, o = kda_chunk(jnp.where(kept, state[i_lin, 0], 0.0), q[0],
+                              k[0], v[0], g[0], beta[0])
+            state = state.at[i_lin, 0].set(S1)
+            tail = tail.at[i_lin].set(t1)
+            ctx = kda_out(cfg, a, o[None], gate)
+            i_lin += 1
+        else:
+            q, row = mla_project(cfg, a, h)
+            latent = jax.lax.dynamic_update_slice(
+                latent, row[None, :, None].astype(latent.dtype),
+                (i_lat, 0, 0, start, 0))
+            rows = latent[i_lat]                       # [1, 1, positions, W]
+            o = afmoe.attend(
+                q.transpose(0, 2, 1, 3),
+                [(rows, None, jnp.arange(rows.shape[2]))], pos, window=0,
+                scale=_mla_scale(cfg), live_keys=start + s,
+                expand=lambda rb, a=a: tuple(
+                    t[:, 0].transpose(0, 2, 1, 3)
+                    for t in mla_decompress(cfg, a, rb)))
+            ctx = o.transpose(0, 2, 1, 3).reshape(B, s, -1)
+            i_lat += 1
+        x = afmoe.mlp_block(cfg, lp, x, ctx @ a["wo"].astype(ctx.dtype),
+                            None if le is None else experts, le)
+    return x, {"latent": latent, "state": state, "tail": tail}
+
+
+# ----------------------------------------------------------------------
+# forward 3: one decode step through the fused kernels
+# ----------------------------------------------------------------------
+def _pad_cols(w, multiple: int = 512):
+    """``fused_norm_qkv`` tiles its weight's columns in 128-lane blocks
+    that divide their number: pad with zero columns to a count that has
+    such divisors."""
+    return jnp.pad(w, ((0, 0), (0, -w.shape[1] % multiple)))
+
+
+def inject(cfg, params) -> Dict[str, Any]:
+    """The kernel-injected view (``afmoe.inject``'s shape): per-layer dicts,
+    every projection of ``h`` in one ``[D, N]`` matrix ``w_in`` (linear: q |
+    k | v | decay gate down | output gate down | beta; latent: q | [c_raw |
+    k_r]), the small per-kind arrays under their own names, the stacked
+    routed experts by reference."""
+    layers = []
+    for l in range(cfg.num_layers):
+        lp, le = layer_params(cfg, params, l)
+        a = lp["attn"]
+        names = (("wq", "wk", "wv", "wf_down", "wg_down", "wb")
+                 if is_linear(cfg, l) else ("wq", "wkva"))
+        d = {k: v for k, v in a.items() if k not in names}
+        d["w_in"] = _pad_cols(jnp.concatenate([a[k] for k in names], axis=-1))
+        layers.append({**d, **afmoe.inject_rest(cfg, lp, le)})
+    return afmoe.inject_outer(params, layers)
+
+
+def moe_counts_zero(cfg):
+    """``afmoe.moe_counts_zero`` and a fifth entry: (row, linear layer)
+    pairs that were LIVE, and pairs whose state the decode kernel
+    VISITED."""
+    return afmoe.moe_counts_zero(cfg) + (jnp.zeros((2,), jnp.int32),)
+
+
+def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
+                 impl: Optional[str] = None):
+    """The layer stack for one token a row: ``x`` [B, D] at per-row
+    positions ``pos`` [B]; ``cache``: ``latent`` [latent layers, pages, 1,
+    page, row_width] through ``page_table`` [B, columns], ``state`` [linear
+    layers, B, H, d, d] and ``tail`` [linear layers, B, K - 1, 3 H d] by
+    row (a row of the batch is a slot).  ``moe_live`` [B] bool: the rows that
+    decode; only their state, tail and latent pages move, and the kernels
+    visit only them.  Returns (x, cache, counts | None)."""
+    from deepspeed_tpu.ops.pallas.decode import (fused_norm_qkv,
+                                                 kda_decode_step,
+                                                 mla_decode_paged,
+                                                 paged_row_append)
+
+    B = x.shape[0]
+    H, Hk, d = cfg.num_heads, cfg.kda_num_heads, cfg.kda_head_dim
+    C3, r = 3 * Hk * d, cfg.kda_gate_rank
+    M = H * (cfg.mla_nope_dim + cfg.mla_rot_dim)
+    latent, state, tail = cache["latent"], cache["state"], cache["tail"]
+    stats = moe_counts_zero(cfg) if moe_live is not None else None
+    i_lin = i_lat = 0
+    for l, lp in enumerate(dparams["layers"]):
+        y = fused_norm_qkv(x, lp["n1_scale"], None, lp["w_in"], None,
+                           kind="rmsnorm", eps=cfg.norm_eps, impl=impl)
+        if is_linear(cfg, l):
+            c, t1 = short_conv(y[:, None, :C3], tail[i_lin], lp["conv"])
+            if moe_live is not None:
+                t1 = jnp.where(moe_live[:, None, None], t1, tail[i_lin])
+            tail = tail.at[i_lin].set(t1)
+            q, k, v, g, beta, gate = kda_activate(
+                cfg, lp, c[:, 0], y[:, C3:C3 + r], y[:, C3 + r:C3 + 2 * r],
+                y[:, C3 + 2 * r:])
+            o, state, visited = kda_decode_step(
+                state, q, k, v, g, beta, layer=i_lin, live=moe_live,
+                impl=impl)
+            if stats is not None:
+                stats = stats[:4] + (stats[4] + jnp.stack(
+                    [jnp.sum(moe_live, dtype=jnp.int32), visited]),)
+            ctx = kda_out(cfg, lp, o, gate)
+            i_lin += 1
+        else:
+            q = y[:, :M].reshape(B, H, -1)
+            row = mla_row(cfg, lp, y[:, M:M + cfg.mla_kv_rank
+                                     + cfg.mla_rot_dim])
+            latent = paged_row_append(latent, row, pos, page_table,
+                                      layer=i_lat, impl=impl)
+            o = mla_decode_paged(mla_absorb(cfg, lp, q), latent, pos,
+                                 page_table, layer=i_lat,
+                                 sm_scale=_mla_scale(cfg), live=moe_live,
+                                 impl=impl)
+            ctx = mla_unabsorb(cfg, lp, o)
+            i_lat += 1
+        x, stats = afmoe.fused_close(cfg, dparams, lp, l, ctx, x, stats,
+                                     moe_live, impl)
+    return x, {"latent": latent, "state": state, "tail": tail}, stats

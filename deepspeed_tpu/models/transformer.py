@@ -86,7 +86,7 @@ class CausalLM:
         dtype = jnp.float32  # master params fp32; engine casts for compute
         if cfg.is_afmoe:     # two stacks of layers (models/afmoe.py)
             from deepspeed_tpu.models import afmoe
-            return afmoe.init_params(cfg, rng, dtype)
+            return afmoe.form(cfg).init_params(cfg, rng, dtype)
         D, F, V, L = cfg.hidden_size, cfg.intermediate_size, cfg.vocab_size, cfg.num_layers
         H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
         E = cfg.num_experts
@@ -182,7 +182,8 @@ class CausalLM:
         if cfg.is_afmoe:
             from deepspeed_tpu.models import afmoe
             return afmoe.logical_pspecs(cfg, jax.eval_shape(
-                lambda: afmoe.init_params(cfg, jax.random.PRNGKey(0))))
+                lambda: afmoe.form(cfg).init_params(cfg,
+                                                    jax.random.PRNGKey(0))))
         col = P(None, None, "tp")       # [L, D, H*Dh] / [L, D, F] — column split
         row = P(None, "tp", None)       # [L, F, D] / [L, H*Dh, D] — row split
         norm_spec = {"scale": P(None, None)}
@@ -677,21 +678,22 @@ class CausalLM:
         return loss + cfg.moe_aux_loss_coef * aux_loss if cfg.is_moe else loss
 
     def _apply_afmoe(self, params, tokens, labels):
-        """``apply`` for ``layer_types`` (models/afmoe.py): logits only.  A
-        chip's share of the experts and of the vocabulary has no loss to
-        train on (the other ranks' logits are absent from its softmax)."""
+        """``apply`` for ``layer_types`` (models/afmoe.py, or its sibling
+        models/kda_mla.py): logits only.  A chip's share of the experts and
+        of the vocabulary has no loss to train on (the other ranks' logits
+        are absent from its softmax)."""
         from deepspeed_tpu.models import afmoe
 
         cfg = self.config
         if labels is not None:
             raise NotImplementedError(
-                "the training loss of a layer_types model (models/afmoe.py) "
-                "is not built: the chip holds a share of the experts and of "
-                "the vocabulary, and CausalLM._loss_tail knows neither; such "
-                "a model is served only")
+                "the training loss of a layer_types model (models/afmoe.py, "
+                "models/kda_mla.py) is not built: the chip holds a share of "
+                "the experts and of the vocabulary, and CausalLM._loss_tail "
+                "knows neither; such a model is served only")
         x = afmoe.embed(cfg, params["embed"]["tok"], tokens,
                         params["embed"]["tok"].dtype)
-        x = afmoe.apply_layers(cfg, params, x, self.mesh)
+        x = afmoe.form(cfg).apply_layers(cfg, params, x, self.mesh)
         x = model_norm(cfg, x, params["final_norm"], self.mesh)
         return x @ params["lm_head"].astype(x.dtype)
 

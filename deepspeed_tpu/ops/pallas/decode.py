@@ -18,6 +18,11 @@ these kernels collapse each layer to four launches:
   pages and its summary pages, two valid lengths a row (``models/eva.py``)
 - :func:`eva_summarize_paged` — the rows whose step filled a window pool it
   into that window's summary rows, in place
+- :func:`mla_decode_paged` — the same online softmax over LATENT pages
+  (``models/kda_mla.py``): one row a position shared by all query heads, a
+  page fetched once and used as keys and as values
+- :func:`kda_decode_step`  — a linear-attention layer's delta-rule update of
+  each live row's recurrent state, in place, and its read-out
 - :func:`fused_proj_norm`  — attention out-projection → residual add → norm
 - :func:`fused_mlp`        — (gated) MLP → residual add, blocked over the
   FFN dim so VMEM holds one weight tile at a time
@@ -285,6 +290,16 @@ def _kv_heads_per_step(hkv: int, block: int, dh: int, itemsize: int) -> int:
                default=1)
 
 
+def _live_rows(live, B: int):
+    """(the batch rows that decode, in order, then the others; how many
+    decode): the first axis of a grid that follows the live mask (None:
+    every row)."""
+    if live is None:
+        return jnp.arange(B, dtype=jnp.int32), B
+    return (jnp.argsort(~live, stable=True).astype(jnp.int32),
+            jnp.sum(live, dtype=jnp.int32))
+
+
 def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
                       nb, scale, alibi, impl, name, kernel=None):
     """The one ``pallas_call`` behind every cache layout.  The caches are
@@ -303,7 +318,11 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
     output is aliased onto.  No live row at all is a grid of no steps.
     ``nb`` is a row's key blocks; under the default body, whose blocks are
     one run up to ``pos``, the third extent stops at the deepest live row's
-    last one, ``max(pos // block + 1)`` over them."""
+    last one, ``max(pos // block + 1)`` over them.
+
+    ``vcache`` None (latent pages, :func:`mla_decode_paged`): the values
+    are the key rows themselves, and the block is fetched ONCE: the body
+    gets the one ref as its K and as its V."""
     B, H, Dh = q.shape
     view = (-1,) + kcache.shape[-3:]
     hkv = view[1]
@@ -316,16 +335,19 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
     else:
         slopes = jnp.zeros((hkv, rep, 1), jnp.float32)
     depth = pos // block + 1
-    if live is None:
-        rows, n_live = jnp.arange(B, dtype=jnp.int32), B
-    else:
-        rows = jnp.argsort(~live, stable=True).astype(jnp.int32)
-        n_live = jnp.sum(live, dtype=jnp.int32)
+    rows, n_live = _live_rows(live, B)
+    if live is not None:
         depth = jnp.where(live, depth, 0)
     if kernel is None:
         nb = jnp.minimum(nb, jnp.max(depth))
         kernel = functools.partial(_flash_decode_kernel, scale=scale,
                                    block=block, alibi=alibi)
+    caches = [kcache.reshape(view)]
+    if vcache is None:
+        body = kernel      # refs end q, k, slopes, o, m, l, acc: k again as v
+        kernel = lambda *refs: body(*refs[:-5], refs[-6], *refs[-5:])
+    else:
+        caches.append(vcache.reshape(view))
     prefetch = (rows, pos) + tuple(tables)
     # index maps see the scalar-prefetch refs AFTER the grid indices (the
     # kernel body sees them first)
@@ -337,8 +359,8 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(n_live, hkv // hb, nb),
-        in_specs=[heads, kv, kv,
-                  pl.BlockSpec((hb, rep, 1), lambda i, g, j, *_: (g, 0, 0))],
+        in_specs=[heads] + [kv] * len(caches) + [
+            pl.BlockSpec((hb, rep, 1), lambda i, g, j, *_: (g, 0, 0))],
         out_specs=heads,
         scratch_shapes=[pltpu.VMEM((hb, rep, 1), jnp.float32),
                         pltpu.VMEM((hb, rep, 1), jnp.float32),
@@ -351,8 +373,7 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
         input_output_aliases={len(prefetch): 0},
         interpret=interpret_flag(impl),
         name=name,
-    )(*prefetch, q.reshape(B, hkv, rep, Dh), kcache.reshape(view),
-      vcache.reshape(view), slopes)
+    )(*prefetch, q.reshape(B, hkv, rep, Dh), *caches, slopes)
     return o.reshape(B, H, Dh)
 
 
@@ -373,13 +394,15 @@ def decode_reference_reason(cache_len: int, block: int) -> Optional[str]:
     return None
 
 
-def _kv_append_kernel(pp_ref, po_ref, kn_ref, vn_ref, ko_ref, vo_ref,
-                      k_out, v_out, *, rows):
+def _kv_append_kernel(pp_ref, po_ref, *refs, rows):
+    """``refs``: the new rows, the old row groups and the groups out, one of
+    each a pool."""
     del pp_ref                    # consumed by the index maps
+    n = len(refs) // 3
     r = po_ref[pl.program_id(0)] % rows
     hit = jax.lax.broadcasted_iota(jnp.int32, (1, rows, 1), 1) == r
-    k_out[0] = jnp.where(hit, kn_ref[0], ko_ref[0])
-    v_out[0] = jnp.where(hit, vn_ref[0], vo_ref[0])
+    for new, old, out in zip(refs[:n], refs[n:2 * n], refs[2 * n:]):
+        out[0] = jnp.where(hit, new[0], old[0])
 
 
 def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
@@ -395,40 +418,58 @@ def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
     the chip has).  The kernel form rewrites only the tile-aligned group of
     rows holding the new one, aliased onto the pool, so the pool keeps one
     layout from the first layer to the last."""
+    return _paged_append((kcache, vcache), (k, v), pos, page_table, layer,
+                         impl)
+
+
+def paged_row_append(cache, row, pos, page_table, *, layer: int,
+                     impl: Optional[str] = None):
+    """:func:`paged_kv_append` for a pool of ONE array whose rows all heads
+    share (latent pages ``[L, P, 1, page, W]``, ``models/kda_mla.py``): row
+    b of ``row`` [B, W] lands at row ``pos[b] % page`` of its page."""
+    return _paged_append((cache,), (row[:, None, :],), pos, page_table,
+                         layer, impl)[0]
+
+
+def _paged_append(pools, new_rows, pos, page_table, layer: int, impl):
+    """The one scatter and the one ``pallas_call`` behind the appends: each
+    of ``pools`` [L, P, Hkv, page, Dh] takes its rows [B, Hkv, Dh]."""
     impl = resolve_impl(impl)
-    L, P, Hkv, page, Dh = kcache.shape
-    B = k.shape[0]
+    L, P, Hkv, page, Dh = pools[0].shape
+    B = new_rows[0].shape[0]
     pp = page_table[jnp.arange(B), pos // page]
     po = pos % page
     impl = kernel_or_reference("paged_kv_append", impl,
                                paged_decode_reference_reason(page))
     if impl == "xla":
-        return (kcache.at[layer, pp, :, po, :].set(k.astype(kcache.dtype)),
-                vcache.at[layer, pp, :, po, :].set(v.astype(vcache.dtype)))
-    rows = 32 // kcache.dtype.itemsize      # one (sublane x lane) tile
+        return tuple(c.at[layer, pp, :, po, :].set(r.astype(c.dtype))
+                     for c, r in zip(pools, new_rows))
+    rows = 32 // pools[0].dtype.itemsize      # one (sublane x lane) tile
     kernel = functools.partial(_kv_append_kernel, rows=rows)
+    n = len(pools)
 
     def group(b, pp_ref, po_ref):
         return layer * P + pp_ref[b], 0, po_ref[b] // rows, 0
 
     new = pl.BlockSpec((1, Hkv, 1, Dh), lambda b, pp_ref, po_ref: (b, 0, 0, 0))
     old = pl.BlockSpec((1, Hkv, rows, Dh), group)
-    pool = jax.ShapeDtypeStruct((L * P, Hkv, page, Dh), kcache.dtype)
-    k4, v4 = pl.pallas_call(
+    pool = jax.ShapeDtypeStruct((L * P, Hkv, page, Dh), pools[0].dtype)
+    out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(B,),
-            in_specs=[new, new, old, old], out_specs=[old, old]),
-        out_shape=[pool, pool],
-        # operands count the two scalar-prefetch arrays: the pools are 4, 5
-        input_output_aliases={4: 0, 5: 1},
+            in_specs=[new] * n + [old] * n, out_specs=[old] * n),
+        out_shape=[pool] * n,
+        # operands count the two scalar-prefetch arrays and the new rows:
+        # the pools follow them
+        input_output_aliases={2 + n + i: i for i in range(n)},
         interpret=interpret_flag(impl),
         name="paged_kv_append",
     )(pp.astype(jnp.int32), po.astype(jnp.int32),
-      k.astype(kcache.dtype).reshape(B, Hkv, 1, Dh),
-      v.astype(vcache.dtype).reshape(B, Hkv, 1, Dh),
-      kcache.reshape(pool.shape), vcache.reshape(pool.shape))
-    return k4.reshape(kcache.shape), v4.reshape(vcache.shape)
+      *(r.astype(c.dtype).reshape(B, Hkv, 1, Dh)
+        for c, r in zip(pools, new_rows)),
+      *(c.reshape(pool.shape) for c in pools))
+    return tuple(o.reshape(c.shape) for o, c in zip(out, pools))
 
 
 def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
@@ -523,6 +564,138 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
         q, kcache, vcache, pos, (), clamp, live=live, block=block,
         nb=Smax // block, scale=scale, alibi=alibi, impl=impl,
         name="flash_decode")
+
+
+# ---------------------------------------------------------------------------
+# Latent pages and recurrent state (models/kda_mla.py)
+# ---------------------------------------------------------------------------
+
+def mla_decode_paged(q, cache, pos, page_table, *, layer: int,
+                     sm_scale: float, live=None, impl: Optional[str] = None):
+    """Decode attention of a latent-attention layer in its ABSORBED form
+    over LATENT PAGES: ``q`` [B, H, W] (``kda_mla.mla_absorb``: each head's
+    query against the rows), ``cache`` [L, P, 1, page, W] (one row a
+    position, shared by all heads: the normed latent, the shared key values,
+    zeros to the lane tile).  :func:`_flash_decode_paged`'s schedule with
+    one "KV head" of ``H`` query rows: a grid step is one LIVE batch row x
+    one logical page, the page is fetched ONCE and serves as keys (the whole
+    row) and as values, so the step's scores are one ``[H, W] x [W, page]``
+    matmul.  Returns [B, H, W]: the rows' weighted sum, whose first
+    ``mla_kv_rank`` values are the latent context (``kda_mla.mla_unabsorb``
+    takes them through the value half of ``Wkvb``); a row that does not
+    decode gets its ``q`` back."""
+    impl = resolve_impl(impl)
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1),
+                           (q.shape[0],))
+    P, page = cache.shape[1], cache.shape[3]
+    impl = kernel_or_reference("mla_decode_paged", impl,
+                               paged_decode_reference_reason(page))
+    if impl == "xla":
+        from deepspeed_tpu.models.decoding import paged_logical_view
+
+        view = paged_logical_view(cache[layer], page_table)
+        return _flash_decode_ref(q, view, view, pos, scale=sm_scale)
+
+    def page_map(b, g, j, pos_ref, pt_ref):
+        jl = jnp.minimum(j, pos_ref[b] // page)     # per-row DMA clamp
+        return layer * P + pt_ref[b, jl], g, 0, 0
+
+    return _decode_attention(
+        q, cache, None, pos, (page_table.astype(jnp.int32),), page_map,
+        live=live, block=page, nb=page_table.shape[1], scale=sm_scale,
+        alibi=False, impl=impl, name="mla_decode_paged")
+
+
+# heads of one grid step of :func:`kda_decode_step`: their k, decay and q
+# columns (3 x this many) share one 128-lane tile
+_KDA_HEADS_PER_STEP = 16
+
+
+def kda_reference_reason(heads: int, d: int) -> Optional[str]:
+    """Why the state kernel cannot take these sizes (None = it can)."""
+    if d % 128:
+        return f"a head of {d} values is not a multiple of the 128-lane tile"
+    if heads % min(heads, _KDA_HEADS_PER_STEP):
+        return f"{heads} heads are not whole steps of {_KDA_HEADS_PER_STEP}"
+    return None
+
+
+def _kda_step_kernel(rows_ref, cols_ref, v_ref, beta_ref, s_ref, o_ref,
+                     s_out, *, hb):
+    """One grid step = one LIVE batch row x ``hb`` heads.  ``cols`` [d, 128]
+    holds, as COLUMNS over the key axis, the heads' k (lanes [0, hb)), decay
+    e^g ([hb, 2 hb)) and q ([2 hb, 3 hb)); v, beta (broadcast over the
+    lanes) and o are rows over the value axis.  A head's state [d, d] comes
+    into VMEM once, is decayed, corrected and read out, and goes back
+    through the alias."""
+    del rows_ref                  # consumed by the index maps
+    cols = cols_ref[0, 0]
+    for h in range(hb):
+        col = lambda j: cols[:, j * hb + h:j * hb + h + 1]      # [d, 1]
+        k = col(0)
+        S = s_ref[0, h] * col(1)
+        u = beta_ref[0, h:h + 1, :] * (
+            v_ref[0, h:h + 1, :] - jnp.sum(S * k, axis=0, keepdims=True))
+        S = S + k * u
+        s_out[0, h] = S
+        o_ref[0, h:h + 1, :] = jnp.sum(S * col(2), axis=0, keepdims=True)
+
+
+def kda_decode_step(state, q, k, v, g, beta, *, layer: int, live=None,
+                    impl: Optional[str] = None):
+    """The delta rule of a linear-attention layer for one token a row
+    (``kda_mla.kda_step``), on the stacked per-slot state in place:
+    ``state`` [L, B, H, d, d] float32 (key axis, value axis), q, k, v, g
+    [B, H, d] float32 (g the log-decay), beta [B, H].  Returns (o [B, H, d]
+    float32, state, rows visited).
+
+    The grid follows the batch as :func:`_decode_attention`'s does: ``live``
+    [B] bool names the rows that decode (None: all), step ``i`` of the
+    first axis works on row ``rows[i]`` of the live rows in order, so a
+    parked row costs no grid step, its state is neither read nor written
+    (it keeps it), and its ``o`` is its ``v`` (the output is aliased onto
+    it).  The XLA form updates every row and keeps the old state where a
+    row is not live: it visits all ``B``."""
+    impl = resolve_impl(impl)
+    L, B, H, d, _ = state.shape
+    impl = kernel_or_reference("kda_decode_step", impl,
+                               kda_reference_reason(H, d))
+    if impl == "xla":
+        from deepspeed_tpu.models.kda_mla import kda_step
+
+        o, new = kda_step(state[layer], q, k, v, g, beta)
+        if live is not None:
+            new = jnp.where(live[:, None, None, None], new, state[layer])
+        return o, state.at[layer].set(new), jnp.asarray(B, jnp.int32)
+    hb = min(H, _KDA_HEADS_PER_STEP)
+    rows, n_live = _live_rows(live, B)
+    # k, decay and q as columns: [B, H / hb, d, 3 hb] padded to the tile
+    cols = jnp.stack([k, jnp.exp(g), q], axis=1).reshape(B, 3, H // hb, hb, d)
+    cols = cols.transpose(0, 2, 4, 1, 3).reshape(B, H // hb, d, 3 * hb)
+    cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, 128 - 3 * hb),))
+    row_of = lambda i, j, rows_ref: (rows_ref[i], j, 0)
+    vec = pl.BlockSpec((1, hb, d), row_of)
+    mat = pl.BlockSpec((1, hb, d, d),
+                       lambda i, j, rows_ref: (layer * B + rows_ref[i], j,
+                                               0, 0))
+    o, new = pl.pallas_call(
+        functools.partial(_kda_step_kernel, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_live, H // hb),
+            in_specs=[pl.BlockSpec((1, 1, d, 128),
+                                   lambda i, j, rows_ref: (rows_ref[i], j,
+                                                           0, 0)),
+                      vec, vec, mat],
+            out_specs=[vec, mat]),
+        out_shape=[jax.ShapeDtypeStruct((B, H, d), jnp.float32),
+                   jax.ShapeDtypeStruct((L * B, H, d, d), jnp.float32)],
+        # operands count the scalar-prefetch array: v is 2, the state 4
+        input_output_aliases={2: 0, 4: 1},
+        interpret=interpret_flag(impl),
+        name="kda_decode_step",
+    )(rows, cols, v, jnp.broadcast_to(beta[..., None], (B, H, d)),
+      state.reshape(L * B, H, d, d))
+    return o, new.reshape(state.shape), jnp.asarray(n_live, jnp.int32)
 
 
 # ---------------------------------------------------------------------------

@@ -81,7 +81,9 @@ from deepspeed_tpu.monitor.request_trace import get_request_tracer
 from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_schedule
 from deepspeed_tpu.profiling.trace import phase
 from deepspeed_tpu.serving.host_tier import HostPageStore
-from deepspeed_tpu.serving.paged_kv import PagedKVPool, init_paged_kv_cache
+from deepspeed_tpu.serving.paged_kv import (PagedKVPool,
+                                            init_paged_kv_cache,
+                                            init_state_cache)
 from deepspeed_tpu.serving.prefix_cache import PrefixCache
 from deepspeed_tpu.serving.scheduler import (PREFILLING, QUEUED, RUNNING,
                                              IterationScheduler, QueueFull,
@@ -179,6 +181,60 @@ SERVE_WINDOW_COUNTERS = {
         "page x layer x iterations one budget a layer would have held for "
         "the same positions (every layer a page per kv_page_tokens)",
 }
+
+
+# Latent pages and per-slot recurrent state (models/kda_mla.py,
+# serving/paged_kv.py), only while the registry is on.  The row steps, live
+# and visited, are counted by the decode-block program itself (the live mask
+# and the state kernel's grid) and fetched with a block's tokens, as the
+# routing counts are.
+SERVE_STATE_COUNTERS = {
+    "ds_serve_state_row_steps_total":
+        "(live row, linear-attention layer, decode step) triples: the state "
+        "updates the decode steps really made",
+    "ds_serve_state_row_steps_visited_total":
+        "(row, linear-attention layer, decode step) triples whose state the "
+        "decode kernel read and wrote, live or parked "
+        "(ops/pallas/decode.py:kda_decode_step)",
+    "ds_serve_state_resets_total":
+        "slot states reset: a request (or a preempted one's resume) took a "
+        "slot and its first chunk starts from a zero state",
+}
+
+
+def _state_refusals(cfg, config, role: str) -> None:
+    """What a model of latent-attention and linear-attention layers
+    (models/kda_mla.py) cannot be served with, each named by the module
+    that assumes a slot's cache is pages of per-head K and V rows and
+    nothing else."""
+    if not config.paged_kv_cache:
+        raise NotImplementedError(
+            "linear_attention / latent_attention layers are served from the "
+            "paged pool's latent pages and its slot-state budget "
+            "(serving/paged_kv.py); paged_kv_cache=False "
+            "(models/decoding.py:init_kv_cache) has per-head K and V rows "
+            "only")
+    if role != "both":
+        raise NotImplementedError(
+            f"role={role!r} with linear_attention layers: serving/handoff.py "
+            "ships pages as the K and V of a token prefix, and a recurrent "
+            "state is not a page")
+    if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
+        raise NotImplementedError(
+            "kv_host_tier_pages > 0 with linear_attention layers: "
+            "serving/host_tier.py demotes and promotes pages for the prefix "
+            "cache, which is off for this model (a state is not "
+            "position-pure)")
+    if config.quantize_kv_cache:
+        raise NotImplementedError(
+            "quantize_kv_cache with linear_attention / latent_attention "
+            "layers: the int8 cache of models/decoding.py scales per-head K "
+            "and V rows; a latent row and a float32 state have no int8 form")
+    if config.use_fused_decode is False:
+        raise NotImplementedError(
+            "use_fused_decode=False with linear_attention / latent_attention "
+            "layers: the decode step over latent pages and slot state is "
+            "built on the fused path only (models/kda_mla.py:fused_layers)")
 
 
 def _afmoe_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
@@ -374,24 +430,39 @@ class ServingEngine:
         self._eva = bool(getattr(cfg, "is_eva", False))
         if self._eva:
             _eva_refusals(cfg, self._config, role, self.prefill_chunk)
+        # latent pages and per-slot recurrent state (models/kda_mla.py)
+        self._state = bool(getattr(cfg, "is_kda_mla", False))
+        if self._state:
+            _state_refusals(cfg, self._config, role)
         # layers of two kinds over two page budgets (models/afmoe.py)
-        self._afmoe = bool(getattr(cfg, "is_afmoe", False))
+        self._afmoe = bool(getattr(cfg, "is_afmoe", False)) \
+            and not self._state
         if self._afmoe:
             _afmoe_refusals(cfg, self._config, role, self.prefill_chunk)
         self.paged = bool(self._config.paged_kv_cache)
         if self.paged:
+            slot_state = 0
+            if self._state:
+                from deepspeed_tpu.models.kda_mla import slot_state_bytes
+                slot_state = slot_state_bytes(cfg, engine.dtype)
             self.pool = PagedKVPool(
                 self.num_slots, self._config.max_out_tokens,
                 page_tokens=self._config.kv_page_tokens,
                 pool_tokens=self._config.kv_pool_tokens,
                 window_tokens=cfg.eva_window if self._eva else 0,
                 chunk_tokens=cfg.eva_chunk if self._eva else 0,
-                ring_tokens=cfg.sliding_window if self._afmoe else 0)
-            self._cache = init_paged_kv_cache(
-                cfg, self.pool.num_pages, self.pool.page,
-                dtype=engine.dtype,
-                quantized=self._config.quantize_kv_cache,
-                num_window_pages=self.pool.num_window_pages)
+                ring_tokens=cfg.sliding_window if self._afmoe else 0,
+                slot_state_bytes=slot_state)
+            if self._state:
+                self._cache = init_state_cache(
+                    cfg, self.pool.num_pages, self.pool.page, self.num_slots,
+                    dtype=engine.dtype)
+            else:
+                self._cache = init_paged_kv_cache(
+                    cfg, self.pool.num_pages, self.pool.page,
+                    dtype=engine.dtype,
+                    quantized=self._config.quantize_kv_cache,
+                    num_window_pages=self.pool.num_window_pages)
             # per-slot LOGICAL window (page-table depth x page); the
             # PHYSICAL pool may hold fewer tokens than num_slots windows
             self.cache_len = self.pool.cache_len
@@ -420,8 +491,14 @@ class ServingEngine:
                      "a token prefix in every layer, and a sliding layer's "
                      f"ring page is overwritten every {cfg.sliding_window} "
                      "tokens", ranks=[0])
+        if self._state and self._config.prefix_caching:
+            log_dist("prefix caching is off for linear_attention layers: "
+                     "serving/prefix_cache.py shares pages as a function of "
+                     "the token prefix, and a recurrent state is a slot's, "
+                     "not a page's (the latent pages alone are "
+                     "position-pure)", ranks=[0])
         if self.paged and self._config.prefix_caching and not (
-                self._eva or self._afmoe):
+                self._eva or self._afmoe or self._state):
             host_pages = int(getattr(self._config, "kv_host_tier_pages", 0))
             self.host_store = (
                 HostPageStore(host_pages, registry=self._registry)
@@ -601,6 +678,14 @@ class ServingEngine:
                        for name, what in SERVE_EVA_COUNTERS.items()}
         self._m_win = {name: reg.counter(name, what)
                        for name, what in SERVE_WINDOW_COUNTERS.items()}
+        self._m_state = {name: reg.counter(name, what)
+                         for name, what in SERVE_STATE_COUNTERS.items()}
+        self._m_state_bytes = reg.gauge(
+            "ds_serve_state_bytes",
+            "bytes of per-slot recurrent state and convolution tails "
+            "resident on the device: num_slots times a slot's, fixed")
+        if self._state:
+            self._m_state_bytes.set(self.pool.state_bytes)
         self._m_first_overlapped = reg.counter(
             "ds_serve_first_token_overlapped_total",
             "first tokens fetched with a decode block already enqueued "
@@ -732,6 +817,9 @@ class ServingEngine:
             if self._eva:
                 layout += (f" ({self.pool.window_pages} window + "
                            f"{self.pool.summary_pages} summary pages a slot)")
+            if self._state:
+                layout += (f" of latent rows, and {self.pool.state_bytes} "
+                           "bytes of slot state")
             if self._afmoe:
                 layout = (f"two page budgets: {self.pool.num_window_pages - 1}"
                           f" window + {self.pool.num_pages - 1} full x "
@@ -772,11 +860,11 @@ class ServingEngine:
                 "engine is draining/drained: not admitting new requests "
                 "(the router should have stopped sending — /healthz is "
                 "503; resume_admission() re-opens)")
-        if prefill_only and (self._eva or self._afmoe):
+        if prefill_only and (self._eva or self._afmoe or self._state):
             raise NotImplementedError(
                 "prefill_only with attention='eva' or layer_types: "
                 "serving/handoff.py ships pages as the K and V of a token "
-                "prefix, which a window page is not")
+                "prefix, which a window page or a recurrent state is not")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -844,6 +932,8 @@ class ServingEngine:
                 self._pos[req.slot] = 0
                 self._active[req.slot] = False
                 self._limit[req.slot] = 0
+                if self._state:    # its first chunk program reads zeros
+                    self._m_state["ds_serve_state_resets_total"].inc()
                 if self.prefix_cache is not None:
                     self._admit_prefix(req)
         # 2. chunked prefill, oldest admissions first (bounded per
@@ -2117,6 +2207,8 @@ class ServingEngine:
         do_sample, temperature, top_k, top_p = self._sample
         if self._afmoe:
             forward = self._two_budget_forward(cb)
+        elif self._state:
+            forward = self._state_forward(cb)
 
         @functools.partial(jax.jit, donate_argnums=(1, 2))
         def prefill(params, cache, carries, pt_row, chunk, meta, srng):
@@ -2137,6 +2229,9 @@ class ServingEngine:
             if self._afmoe:
                 logits, out = forward(params, cache, pt_row, chunk, start,
                                       last_idx + 1)
+            elif self._state:
+                logits, out = forward(params, cache, pt_row, chunk, start,
+                                      last_idx + 1, slot)
             else:
                 sub = {k: (view(v) if v.ndim == 5 else v)
                        for k, v in cache.items()}
@@ -2189,6 +2284,38 @@ class ServingEngine:
                                        *spans[k.split("_")[1]])
                    for k in cache}
             return logits, out
+
+        return forward
+
+    def _state_forward(self, cb: int):
+        """The chunk program's forward over latent pages and slot state
+        (serving/paged_kv.py): ``(params, cache, page-table row, chunk [1,
+        cb], start, real tokens, slot) -> (logits, cache)``.  The slot's
+        latent pages are sliced out into the contiguous view
+        ``kda_mla.cached_layers`` takes (``_slot_view``) and of them only
+        those the chunk's ``cb`` rows can have touched go back
+        (``_slot_write_back``); the slot's state and tail are sliced out by
+        the slot's index, carried through the chunk (which leaves them as of
+        its last real row, and starts from zeros at position 0) and written
+        back in place."""
+        model, page = self.module, self.pool.page
+        fp = self.pool.slot_pages
+        touched = min(-(-cb // page) + 1, fp)
+
+        def forward(params, cache, pt_row, chunk, start, valid_len, slot):
+            own = lambda v: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
+            sub = {"latent": _slot_view(cache["latent"], pt_row, range(fp)),
+                   "state": own(cache["state"]), "tail": own(cache["tail"])}
+            logits, sub = forward_with_cache(model, params, chunk, sub, start,
+                                             valid_len=valid_len)
+            first = jnp.minimum(start // page, fp - 1)
+            put = lambda k: jax.lax.dynamic_update_slice_in_dim(
+                cache[k], sub[k], slot, axis=1)
+            return logits, {
+                "latent": _slot_write_back(
+                    cache["latent"], sub["latent"], pt_row, 0,
+                    [jnp.minimum(first + i, fp - 1) for i in range(touched)]),
+                "state": put("state"), "tail": put("tail")}
 
         return forward
 
@@ -2309,7 +2436,10 @@ class ServingEngine:
                          if idx in self._block_valid else None)
                 moe = self._block_moe.pop(idx, None)
                 if moe is not None:
-                    self._count_moe(*(np.asarray(a) for a in moe))  # dslint: disable=DSL002 -- rides the block's own deferred fetch, registry on only
+                    moe = [np.asarray(a) for a in moe]  # dslint: disable=DSL002 -- rides the block's own deferred fetch, registry on only
+                    if self._state:
+                        self._count_state_steps(moe.pop())
+                    self._count_moe(*moe)
             entry = self._block_np[idx] = (toks, valid)
         return entry
 
@@ -2365,6 +2495,14 @@ class ServingEngine:
             held["window"] * n_win + held["full"] * n_full)
         self._m_win["ds_serve_kv_page_steps_one_budget_total"].inc(
             held["full"] * (n_win + n_full))
+
+    def _count_state_steps(self, steps) -> None:
+        """One decode block's (row, linear layer) pairs, live and visited by
+        the state kernel (models/kda_mla.py: the fifth of its counts), into
+        ``ds_serve_state_row_steps_*``."""
+        self._m_state["ds_serve_state_row_steps_total"].inc(int(steps[0]))
+        self._m_state["ds_serve_state_row_steps_visited_total"].inc(
+            int(steps[1]))
 
     def _count_moe(self, per_expert, hits, max_load, offered=None) -> None:
         """One decode block's routing (``decode_step``'s ``moe_live``

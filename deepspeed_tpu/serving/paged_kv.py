@@ -76,6 +76,22 @@ lowers it, for the allocator's own tests).  A 3 x W request holds ``W /
 page`` window pages and ``3 W / page`` full ones, where one budget a layer
 would hold ``3 W / page`` in every layer.
 
+**Latent pages and a slot-state budget that is not pages** (a model of
+latent-attention and linear-attention layers, ``models/kda_mla.py``;
+``slot_state_bytes`` below).  A latent layer's page holds ONE row a position
+that all heads share (``init_state_cache``: ``latent`` ``[latent layers,
+pages, 1, page, row width]``, no V array: keys and values are read from the
+same row); it is position-pure and allocated like a full-attention page, one
+budget, one table column a page.  A linear layer keeps no rows at all but a
+recurrent STATE of fixed size a slot (``state`` ``[linear layers, slots,
+heads, d, d]`` float32 and ``tail`` ``[linear layers, slots, taps - 1,
+channels]``, the short convolution's last inputs): it belongs to the slot,
+not to a page, is never allocated or freed, and is RESET when a request
+takes the slot (the engine's first chunk program of a request reads zeros
+whatever the slot held, and counts ``ds_serve_state_resets_total``).
+``ensure`` /
+``release`` / ``check_no_leak`` keep their meaning, for pages.
+
 Physical **page 0 is reserved as the junk page**: it is never allocated,
 and a released slot's table rows all point at it, so the parked row's
 junk K/V writes (inactive rows still execute in the static-shape compiled
@@ -164,6 +180,21 @@ def init_paged_kv_cache(cfg, num_pages: int, page_tokens: int,
     }
 
 
+def init_state_cache(cfg, num_pages: int, page_tokens: int, num_slots: int,
+                     dtype=jnp.bfloat16) -> Dict[str, Any]:
+    """Device arrays of a model of latent-attention and linear-attention
+    layers (module docstring): ``latent`` pages, and the per-slot ``state``
+    (float32 whatever ``dtype``) and ``tail``."""
+    from deepspeed_tpu.models.kda_mla import (kind_layers, row_width,
+                                              state_shapes)
+
+    state, tail = state_shapes(cfg, num_slots)
+    return {"latent": jnp.zeros((len(kind_layers(cfg)[1]), num_pages, 1,
+                                 page_tokens, row_width(cfg)), dtype),
+            "state": jnp.zeros(state, jnp.float32),
+            "tail": jnp.zeros(tail, dtype)}
+
+
 class PagedKVPool:
     """Host-side free-list allocator for the page pool.
 
@@ -194,14 +225,20 @@ class PagedKVPool:
         columns, ids into the window budget, then ``cache_len / page`` full
         columns; ``pool_tokens`` sizes the full budget and
         ``window_pool_tokens`` the window budget (0 = ``num_slots`` rings).
+    slot_state_bytes:
+        Bytes of fixed per-slot state a slot carries beside its pages (module
+        docstring; 0 = none): a budget of ``num_slots`` times it that is
+        never allocated or freed, only reset by the engine's chunk program.
     """
 
     def __init__(self, num_slots: int, max_out_tokens: int, *,
                  page_tokens: int = 0, pool_tokens: int = 0,
                  window_tokens: int = 0, chunk_tokens: int = 0,
-                 ring_tokens: int = 0, window_pool_tokens: int = 0):
+                 ring_tokens: int = 0, window_pool_tokens: int = 0,
+                 slot_state_bytes: int = 0):
         if num_slots < 1:
             raise ValueError(f"num_slots must be >= 1, got {num_slots}")
+        self.state_bytes = num_slots * int(slot_state_bytes)
         self.page = int(page_tokens) or default_page_tokens(max_out_tokens)
         self.window, self.chunk = int(window_tokens), int(chunk_tokens)
         self.ring = int(ring_tokens)

@@ -708,3 +708,37 @@ def test_train_zero3_loss_tail_keeps_logits_on_their_chip(v5e):
                            ("all-reduce", "bf16", (D, V), True)] or \
         sorted(big) == [("all-gather", "bf16", (V, D), True),
                         ("reduce-scatter", "bf16", (D // 4, V), True)], big
+
+
+def test_kimi_linear_cell_programs_compile_with_state_and_pool_in_place(
+        v5e, chip_kernels):
+    """ISSUE 44: the chunk programs (buckets 1,024 and 64) and the decode
+    block of the ``kimi-linear-L5-ep8.serve-reason-doc-tail`` cell (the
+    published widths; the pattern cut to [linear | latent], which changes no
+    shape; the pool cut to 16 slots' worth) compile for the v5e: the latent
+    pool, the recurrent state and the convolution tails stay where they are
+    (no copy or gather the size of any of them: the state is float32, which
+    ``assert_pools_stay_in_place`` does not read, so its copies are looked
+    for by shape), and the decode block carries the two named kernels beside
+    the shared ones."""
+    cell = _ServeCell(
+        v5e, "kimi-linear-L5-ep8", "kimi-linear-L5-ep8.serve-reason-doc-tail",
+        fields=dict(num_layers=2, layer_types=["linear_attention",
+                                               "latent_attention"]),
+        engine=dict(kv_pool_tokens=16 * 13312, num_slots=16))
+    cache = cell.serve._cache
+    cell.smallest_pool = cache["latent"].nbytes    # the tails are smaller
+    shape = lambda k: ",".join(str(d) for d in cache[k].shape)
+    for program in (cell.chunk(1024), cell.chunk(64), cell.block()):
+        cell.assert_pools_stay_in_place(program)
+        for kind, key in (("f32", "state"), ("bf16", "tail")):
+            assert not re.findall(rf"{kind}\[{shape(key)}\]\S* copy\(",
+                                  program.as_text()), key
+        mem = program.memory_analysis()
+        print("memory", mem.temp_size_in_bytes, mem.argument_size_in_bytes,
+              mem.output_size_in_bytes, mem.alias_size_in_bytes)
+    text = program.as_text()
+    for name in ("kda_decode_step", "mla_decode_paged", "paged_kv_append",
+                 "fused_norm_qkv", "fused_proj_norm", "fused_mlp",
+                 "fused_moe_mlp"):
+        assert name in text, name
