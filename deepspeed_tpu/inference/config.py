@@ -57,7 +57,9 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # a long prompt causes); decode_block_tokens = decode steps per
     # compiled block per host sync (0 = follow decode_unroll);
     # max_prefill_chunks = prefill chunks advanced per iteration across
-    # slots (decode-latency vs admission-latency trade).
+    # slots (decode-latency vs admission-latency trade): one a prefilling
+    # request by admission order, the rest round by round over those with
+    # prompt left, so a prompt that prefills alone takes them all.
     num_slots: int = 8
     prefill_chunk: int = 64
     decode_block_tokens: int = 0

@@ -20,8 +20,11 @@ Pallas decode stack:
 - iteration-level scheduling: each :meth:`step` admits queued requests
   into freed slots, advances at most ``max_prefill_chunks`` prompt chunks
   (chunked per-slot prefill, interleaved with decode so decode latency
-  stays bounded), then decodes ``decode_block_tokens`` tokens for every
-  active slot in one compiled program;
+  stays bounded: one chunk a prefilling request in admission order, and
+  the places they leave go round again over those with prompt left, so a
+  prompt that prefills alone takes the whole budget), then decodes
+  ``decode_block_tokens`` tokens for every active slot in one compiled
+  program;
 - a traced active-slot mask: compiled shapes stay static while occupancy
   varies, so there is exactly ONE decode program regardless of how many
   slots are live.
@@ -706,6 +709,11 @@ class ServingEngine:
             "max_prefill_chunks, or refused pages")
         self._m_prefill_chunks = reg.counter(
             "ds_serve_prefill_chunks_total", "prefill chunks dispatched")
+        self._m_prefill_chunks_extra = reg.counter(
+            "ds_serve_prefill_chunks_extra_total",
+            "of ds_serve_prefill_chunks_total, those given to a request "
+            "that already had a chunk in the same iteration: places of "
+            "max_prefill_chunks the other requests left")
         self._m_prefill_toks = reg.counter(
             "ds_serve_prefill_tokens_total", "prompt tokens prefilled")
         self._m_decode_toks = reg.counter(
@@ -936,12 +944,31 @@ class ServingEngine:
                     self._m_state["ds_serve_state_resets_total"].inc()
                 if self.prefix_cache is not None:
                     self._admit_prefix(req)
-        # 2. chunked prefill, oldest admissions first (bounded per
-        #    iteration so running slots' decode latency stays bounded)
+        # 2. chunked prefill: max_prefill_chunks PLACES an iteration, one
+        #    chunk program each (bounded so running slots' decode latency
+        #    stays bounded).  Oldest admissions first, one chunk a request;
+        #    the places they leave go round again, in the same order, over
+        #    those that were given a chunk and have prompt left
         with self._phase("ds_serve_prefill"):
             waiting = self.scheduler.prefilling()
-            for req in waiting[: self.max_prefill_chunks]:
+            places = self.max_prefill_chunks
+            going = deque((req, True) for req in waiting[:places])
+            while going and places:
+                req, first = going.popleft()
+                if req.state != PREFILLING:      # preempted by a chunk above
+                    continue
+                before = req.prefill_pos
                 self._prefill_one_chunk(req)
+                given = req.prefill_pos > before     # else: refused pages
+                if first:                # a turn is counted by its first try
+                    self._prefill_turn(missed=not given)
+                elif given:
+                    self._m_prefill_chunks_extra.inc()
+                if given:
+                    places -= 1
+                    if (req.state == PREFILLING
+                            and req.prefill_pos < req.prefix_len):
+                        going.append((req, False))
             for req in waiting[self.max_prefill_chunks:]:
                 if req.state == PREFILLING:  # not preempted by a chunk above
                     self._prefill_turn(missed=True)
@@ -2030,8 +2057,11 @@ class ServingEngine:
             self._m_prefill_turns_missed.inc()
 
     def _prefill_one_chunk(self, req: Request) -> None:
-        if req.state != PREFILLING:      # preempted mid-iteration
-            return
+        """Enqueue ``req``'s next chunk program, unless it is refused pages
+        (``prefill_pos`` then does not advance: ``_step_inner`` reads that,
+        not a return value, which the benchmark's wrapper of this method,
+        ``benchmarks/lib/serve_taps.py``, would drop, and keeps the turns'
+        count)."""
         t0 = time.perf_counter()
         if not req.t_first_chunk:
             req.t_first_chunk = t0       # its turn came; pages not yet asked
@@ -2040,9 +2070,7 @@ class ServingEngine:
         S = req.prefix_len
         c = min(self.prefill_chunk, S - off)
         if self.paged and not self._ensure_pages(req, off + c):
-            self._prefill_turn(missed=True)
             return                       # self-preempted: resumes later
-        self._prefill_turn(missed=False)
         last_chunk = off + c == S
         wake = False
         if last_chunk and not req.prefill_only:

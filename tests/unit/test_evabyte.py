@@ -538,6 +538,39 @@ def test_engine_serves_across_windows_and_returns_both_page_kinds(
     serve.close()
 
 
+@pytest.mark.parametrize("places", [1, 2, 4])
+def test_chunks_of_one_iteration_close_windows_for_each_other(ref, model,
+                                                              params, places):
+    """``max_prefill_chunks`` places an iteration: a prompt that prefills
+    alone takes them all, so a chunk closes a window (16-token chunks, one of
+    32) and the next chunk PROGRAM reads the summaries it wrote with no
+    decode block between, twice over under four places; then three requests
+    share the places.  Every served token is the reference's best."""
+    from deepspeed_tpu.monitor.metrics import get_registry
+
+    reg = get_registry()
+    reg.enable()
+    reg.reset()
+    serve = _serve(model, params, max_prefill_chunks=places)
+    prompts, news = _prompts()
+    order = [1, 0, 2, 3]                   # the 70-token prompt goes alone
+    reqs = [serve.submit(prompts[1], max_new_tokens=news[1], stream=True)]
+    serve.run()
+    chunks = -(-len(prompts[1]) // ENGINE["prefill_chunk"])
+    assert reg.get("ds_serve_prefill_chunks_extra_total").value == \
+        chunks - -(-chunks // places)
+    reqs += [serve.submit(prompts[i], max_new_tokens=news[i], stream=True)
+             for i in order[1:]]
+    serve.run()
+    serve.pool.check_no_leak()
+    for i, r in zip(order, reqs):
+        assert len(r.output_tokens) == news[i]
+        _served_tokens_are_the_references_best(
+            ref, params, prompts[i], np.asarray(r.output_tokens))
+    serve.close()
+    reg.disable()
+
+
 def test_preempted_request_resumes_to_the_same_tokens(model, params):
     """A pool too small for three long requests: the youngest is preempted,
     gives back every page of both kinds, resumes by recompute and yields the

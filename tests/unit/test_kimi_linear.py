@@ -117,6 +117,31 @@ def test_served_tokens_are_the_references_argmax(ref, model, prompt):
     serve.close()
 
 
+@pytest.mark.parametrize("places", [1, 2, 4])
+def test_chunks_of_one_iteration_hand_the_state_on(ref, model, places):
+    """``max_prefill_chunks`` places an iteration: a prompt that prefills
+    alone takes them all, so a chunk program reads the KDA state and the
+    convolution tails the chunk program just before it left, with no decode
+    block between (five chunks of 16: all of one iteration's under four
+    places but the last); then three requests share the places.  The same
+    argmax of the reference, whatever the places."""
+    serve = serve_of(model, max_prefill_chunks=places)
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, 96, n) for n in (70, 37, 16, 45)]
+    reqs = [serve.submit(prompts[0], max_new_tokens=21)]
+    serve.run()
+    reqs += [serve.submit(p, max_new_tokens=12) for p in prompts[1:]]
+    serve.run()
+    serve.pool.check_no_leak()
+    for r, p in zip(reqs, prompts):
+        assert not r.preemptions
+        seq = np.concatenate([p, r.output_tokens])
+        want = ref_logits(ref, model[1], seq,
+                          list(range(len(p) - 1, len(seq) - 1)))
+        assert list(want.argmax(-1)) == list(r.output_tokens)
+    serve.close()
+
+
 def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
     """Five requests over three slots: the fourth and fifth take slots whose
     state a finished request left behind, and are served what an engine of

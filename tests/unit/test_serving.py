@@ -173,6 +173,31 @@ def test_continuous_batching_greedy_parity(served, rng):
                     f"max_new {news[i]}) diverged from generate()")
 
 
+@pytest.mark.parametrize("places", [1, 4])
+def test_greedy_parity_whatever_the_chunk_places(served, rng, monkeypatch,
+                                                 places):
+    """``max_prefill_chunks`` places an iteration, one (a prompt never has
+    two chunks in an iteration) or four (a prompt that prefills alone takes
+    all four, back to back with no decode block between): the same tokens
+    as generate(), for prompts of one to nine chunks."""
+    _, _, ref, serve = served
+    monkeypatch.setattr(serve, "max_prefill_chunks", places)
+    lens, news = [33, 3, 14, 26, 5, 19], [5, 7, 3, 6, 8, 2]
+    prompts = [np.asarray(jax.random.randint(k, (n,), 0, 256))
+               for k, n in zip(jax.random.split(rng, len(lens)), lens)]
+    lone = serve.submit(prompts[0], max_new_tokens=news[0])
+    serve.run()                      # alone in PREFILLING: every place its own
+    reqs = [lone] + [serve.submit(p, max_new_tokens=n)
+                     for p, n in zip(prompts[1:], news[1:])]
+    serve.run()
+    for p, n, req in zip(prompts, news, reqs):
+        want = np.asarray(ref.generate(p[None], max_new_tokens=n,
+                                       do_sample=False))[0, len(p):]
+        np.testing.assert_array_equal(
+            np.asarray(req.output_tokens), want,
+            err_msg=f"prompt {len(p)}, max_new {n} diverged from generate()")
+
+
 def test_serving_early_eos_frees_slot_and_admits_queue(served, rng):
     """A request whose greedy continuation hits EOS early must free its
     slot mid-flight so a queued request is admitted and completes."""

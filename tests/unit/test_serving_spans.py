@@ -43,12 +43,12 @@ def tiny(devices):
                              jnp.zeros((1, 8), jnp.int32))
 
 
-def _engine(tiny, **over):
+def _engine(tiny, slots=2, **over):
     model, params = tiny
     engine = deepspeed_tpu.init_serving(
         model, config={"dtype": "float32", "max_out_tokens": 64,
                        "kv_page_tokens": 16, **over},
-        num_slots=2, prefill_chunk=4, decode_block_tokens=3)
+        num_slots=slots, prefill_chunk=4, decode_block_tokens=3)
     engine.set_params(params)
     return engine
 
@@ -327,6 +327,13 @@ def _counting():
     return reg, tracer
 
 
+def _turns_due(serve):
+    """The turns the coming iteration will count: the requests that hold a
+    slot with prompt left, and those it is about to admit."""
+    return len(serve.scheduler.prefilling()) + min(
+        serve.scheduler.num_queued, len(serve.scheduler.free_slots()))
+
+
 def _chunk_spans(tracer, req):
     rec = next(r for r in tracer.completed() if r["id"] == req.request_id)
     return [s for s in rec["spans"] if s[0] == "prefill_chunk"]
@@ -354,10 +361,12 @@ def test_chunk_stamps_partition_the_time_in_the_slot(serve, path):
         _check_stamps(r)
         assert len(_chunk_spans(tracer, r)) == \
             -(-len(p) // serve.prefill_chunk)
-    # two slots, two chunks a turn: every turn in PREFILLING got its chunk
+    # two slots, two places an iteration: every turn in PREFILLING got its
+    # chunk, and a request that prefilled alone the other place too
     assert reg.get("ds_serve_prefill_turns_missed_total").value == 0
     assert reg.get("ds_serve_prefill_turns_total").value == \
-        reg.get("ds_serve_prefill_chunks_total").value
+        reg.get("ds_serve_prefill_chunks_total").value \
+        - reg.get("ds_serve_prefill_chunks_extra_total").value
 
 
 def test_stamps_are_those_of_the_last_admission(tiny):
@@ -403,8 +412,7 @@ def test_turns_missed_and_first_tokens_are_counted(serve, monkeypatch):
             for p in _prompts(6, seed=8)]
     turns = 0
     while serve.scheduler.has_work:
-        turns += len(serve.scheduler.prefilling()) + min(
-            serve.scheduler.num_queued, len(serve.scheduler.free_slots()))
+        turns += _turns_due(serve)
         serve.step()
     assert all(r.done for r in reqs)
     for r in reqs:
@@ -417,6 +425,7 @@ def test_turns_missed_and_first_tokens_are_counted(serve, monkeypatch):
     assert reg.get("ds_serve_prefill_turns_total").value == turns
     assert missed + chunks == turns
     assert reg.get("ds_serve_prefill_chunks_total").value == chunks
+    assert reg.get("ds_serve_prefill_chunks_extra_total").value == 0
     assert reg.get("ds_serve_first_tokens_total").value == len(reqs)
     assert reg.get("ds_serve_first_token_overlapped_total").value <= \
         len(reqs)
@@ -436,6 +445,165 @@ def test_turns_missed_and_first_tokens_are_counted(serve, monkeypatch):
     assert len(set(chunk_seqs)) == len(chunk_seqs) == chunks
     assert not block_seqs & set(chunk_seqs)
     assert max(block_seqs | set(chunk_seqs)) == serve._launch_seq
+
+
+# ---------------------------------------------------------------------------
+# (b'') ... and an iteration's max_prefill_chunks are PLACES (ISSUE 45): one a
+# prefilling request by admission, the rest round by round over those with
+# prompt left
+# ---------------------------------------------------------------------------
+
+def _schedule(serve, reqs, reg):
+    """Serve to empty; a row an iteration in which a chunk program ran: how
+    many each of ``reqs`` was given.  No iteration runs more of them than it
+    has places."""
+    rows, per = [], serve.prefill_chunk
+    chunks = reg.get("ds_serve_prefill_chunks_total")
+    while serve.scheduler.has_work:
+        at, n = [r.prefill_pos for r in reqs], chunks.value
+        serve.step()
+        row = tuple(-(-(r.prefill_pos - a) // per) for r, a in zip(reqs, at))
+        assert sum(row) == chunks.value - n <= serve.max_prefill_chunks
+        if any(row):
+            rows.append(row)
+    assert all(r.done and not r.preemptions for r in reqs)
+    return rows
+
+
+def _counts(reg):
+    return {k: reg.get(f"ds_serve_prefill_{k}_total").value
+            for k in ("turns", "turns_missed", "chunks", "chunks_extra")}
+
+
+def _prompt(chunks, seed, per=4):
+    """A prompt of ``chunks`` chunks, the last one ragged; a seed of its own
+    wherever an engine is shared, or the prefix cache prefills it."""
+    return np.random.default_rng(seed).integers(
+        0, 256, per * chunks - 1, dtype=np.int32)
+
+
+@pytest.mark.parametrize("places,n", [(1, 3), (2, 1), (2, 5), (2, 6),
+                                      (4, 3), (4, 6), (4, 9)])
+def test_a_lone_prompt_takes_every_place(serve, monkeypatch, places, n):
+    monkeypatch.setattr(serve, "max_prefill_chunks", places)
+    reg, _ = _counting()
+    req = serve.submit(_prompt(n, seed=100 * places + n), max_new_tokens=3,
+                       stream=True)
+    rows = _schedule(serve, [req], reg)
+    turns = -(-n // places)
+    assert rows == [(places,)] * (n // places) + [(n % places,)] * (
+        turns - n // places)
+    assert _counts(reg) == {"turns": turns, "turns_missed": 0, "chunks": n,
+                            "chunks_extra": n - turns}
+    _check_stamps(req)
+
+
+@pytest.mark.parametrize("places,rows", [
+    (2, [(1, 1)] * 5),                              # a place each: none left
+    (3, [(2, 1), (2, 1), (1, 2), (0, 1)]),          # the odd one to the older
+    (4, [(2, 2), (2, 2), (1, 1)])])
+def test_leftover_places_go_round_by_age(serve, monkeypatch, places, rows):
+    monkeypatch.setattr(serve, "max_prefill_chunks", places)
+    reg, _ = _counting()
+    reqs = [serve.submit(_prompt(5, seed=10 * places + s), max_new_tokens=3,
+                         stream=True) for s in (1, 2)]
+    assert _schedule(serve, reqs, reg) == rows
+    turns = sum(map(bool, np.ravel(rows)))
+    assert _counts(reg) == {"turns": turns, "turns_missed": 0, "chunks": 10,
+                            "chunks_extra": 10 - turns}
+
+
+def test_a_request_beyond_the_places_is_passed_over(tiny):
+    serve = _engine(tiny, slots=3)        # max_prefill_chunks 2, the default
+    try:
+        reg, _ = _counting()
+        reqs = [serve.submit(_prompt(2, seed=s), max_new_tokens=3,
+                             stream=True) for s in (1, 2, 3)]
+        # round 1 spends both places on the two oldest; the third waits for
+        # them as it always did, and then prefills alone
+        assert _schedule(serve, reqs, reg) == [(1, 1, 0), (1, 1, 0),
+                                               (0, 0, 2)]
+        assert _counts(reg) == {"turns": 7, "turns_missed": 2, "chunks": 6,
+                                "chunks_extra": 1}
+        assert reqs[2].t_first_chunk > reqs[1].t_last_chunk
+    finally:
+        serve.close()
+
+
+def test_one_place_is_the_older_schedule(serve, monkeypatch):
+    monkeypatch.setattr(serve, "max_prefill_chunks", 1)
+    reg, _ = _counting()
+    reqs = [serve.submit(_prompt(n, seed=50 + n), max_new_tokens=3,
+                         stream=True) for n in (3, 2, 4, 1)]
+    # one chunk an iteration, the oldest prefilling request's; two slots, so
+    # the third is admitted when the first leaves
+    for row in _schedule(serve, reqs, reg):
+        assert sum(row) == 1
+        assert all(r.prefill_pos == r.prompt_len
+                   for r in reqs[:row.index(1)])
+    assert _counts(reg)["chunks_extra"] == 0
+
+
+@pytest.mark.parametrize("places", [2, 3, 4])
+def test_turns_are_chunks_less_extra_plus_missed(tiny, places):
+    serve = _engine(tiny, slots=3)
+    serve.max_prefill_chunks = places
+    try:
+        reg, tracer = _counting()
+        reqs = [serve.submit(_prompt(n, seed=n), max_new_tokens=4,
+                             stream=True) for n in (5, 1, 3, 7, 2, 4, 6)]
+        turns = 0
+        while serve.scheduler.has_work:
+            turns += _turns_due(serve)
+            serve.step()
+        got = _counts(reg)
+        assert got["turns"] == turns
+        assert got["chunks"] == sum(len(_chunk_spans(tracer, r))
+                                    for r in reqs) == 28
+        assert got["turns_missed"] + got["chunks"] - got["chunks_extra"] \
+            == turns
+        assert got["chunks_extra"] > 0
+        assert (got["turns_missed"] > 0) == (places == 2)
+        for r in reqs:
+            _check_stamps(r)
+    finally:
+        serve.close()
+
+
+@pytest.mark.parametrize("places,refused", [
+    # its fifth chunk is the one that needs a page: the first try of an
+    # iteration under two places, a later round's under three
+    (2, {"turns": 1, "turns_missed": 1, "chunks": 0, "chunks_extra": 0}),
+    (3, {"turns": 1, "turns_missed": 0, "chunks": 1, "chunks_extra": 0})])
+def test_a_request_refused_pages_gets_no_later_round(tiny, places, refused):
+    # five pages: the older request decodes into four of them, the younger
+    # one's prompt needs two, so it preempts ITSELF until the older is done
+    serve = _engine(tiny, kv_pool_tokens=80)
+    serve.max_prefill_chunks = places
+    try:
+        reg, _ = _counting()
+        old = serve.submit(_prompt(2, seed=7), max_new_tokens=56, stream=True)
+        while serve.pool.pages_free > 1:
+            serve.step()
+        young = serve.submit(_prompt(5, seed=8), max_new_tokens=3,
+                             stream=True)
+        turns, seen = reg.get("ds_serve_prefill_turns_total").value, False
+        while serve.scheduler.has_work:
+            turns += _turns_due(serve)
+            at, was = _counts(reg), young.preemptions
+            serve.step()
+            if young.preemptions > was and not seen:
+                seen = True
+                assert {k: v - at[k] for k, v in _counts(reg).items()} \
+                    == refused
+                assert young.prefill_pos == 0 and young.slot < 0
+        assert seen and old.done and young.done and not old.preemptions
+        got = _counts(reg)
+        assert got["turns"] == turns
+        assert got["turns_missed"] + got["chunks"] - got["chunks_extra"] \
+            == turns
+    finally:
+        serve.close()
 
 
 def test_readers_of_the_stamps_and_counters(serve):

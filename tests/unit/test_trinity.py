@@ -106,6 +106,33 @@ def test_served_tokens_are_the_references_argmax(ref, model, pool_tokens,
     serve.close()
 
 
+@pytest.mark.parametrize("places", [1, 2, 4])
+def test_chunks_of_one_iteration_cross_the_ring(ref, model, places):
+    """``max_prefill_chunks`` places an iteration: a prompt that prefills
+    alone takes them all, so the chunk that wraps the ring (chunks of 8, a
+    window of 16) reads what the chunk program just before it wrote, with no
+    decode block between; then three requests share the places.  The same
+    argmax of the reference, whatever the places."""
+    m, params = model
+    serve = deepspeed_tpu.init_serving(
+        m, config=dict(ENGINE, max_prefill_chunks=places), params=params,
+        mesh=m.mesh)
+    rng = np.random.default_rng(4)
+    prompts = [rng.integers(0, 96, n) for n in (50, 37, 13, 29)]
+    reqs = [serve.submit(prompts[0], max_new_tokens=20)]
+    serve.run()
+    reqs += [serve.submit(p, max_new_tokens=12) for p in prompts[1:]]
+    serve.run()
+    serve.pool.check_no_leak()
+    for r, p in zip(reqs, prompts):
+        assert not r.preemptions
+        seq = np.concatenate([p, r.output_tokens])
+        want = ref_logits(ref, params, seq,
+                          list(range(len(p) - 1, len(seq) - 1)))
+        assert list(want.argmax(-1)) == list(r.output_tokens)
+    serve.close()
+
+
 def test_bf16_serving_stays_within_the_drivers_bound(ref, model):
     """What the benchmark's ``verify`` checks, at the serving dtype."""
     m, params = model
