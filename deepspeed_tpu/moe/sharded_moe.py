@@ -170,6 +170,13 @@ def topk_gating(gates, k: int, capacity: int, rng=None,
     return combine, dispatch, aux
 
 
+# The smallest row tile the chip's grouped matmul works a group in, and the
+# one it takes where its ``lhs`` is an odd number of them long
+# (:func:`_moe_grouped`; read from timings on a v5e, libtpu 0.0.34:
+# tools/moe_grouped_bench.py --held-share 8 --stacked, PERF.md Findings PR 46).
+ROW_TILE = 128
+
+
 def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
                  assign=None):
     """Dropless expert block on tokens ``xt`` [N, D] with router
@@ -184,7 +191,13 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
     ``e_idx`` counts the ``E`` experts HELD HERE, with ``E`` itself for an
     assignment to an expert another chip holds (a chip's share of the
     experts, ``models/afmoe.py``).  Those rows sort behind every group, lie
-    in none, and add nothing to their token.
+    in none, and add nothing to their token.  The sorted rows then go to the
+    grouped matmuls as an ODD number of 128-row tiles, one tile of pad rows
+    (of no group either) behind them where N*k is an even number: the chip's
+    grouped matmul works a group in row tiles of the largest of 128, 256,
+    512 that divides its ``lhs`` length, a whole tile for a group of sixteen
+    rows too (``ROW_TILE``; where the groups hold 128-256 rows the small
+    tile can cost, ROADMAP R1).
 
     ``layer`` (a traced index) says the expert arrays are the model's STACKED
     [L, E, ...] ones: they go to the grouped matmul whole, as L*E groups of
@@ -205,7 +218,13 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
     order = jnp.argsort(flat)                   # stable: token order inside
     # (an index of E, "held elsewhere", is past ``length`` and not counted)
     sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
-    rows = xt[order // k]                                        # [N*k, D]
+    gather = order
+    if assign is not None:
+        inside = flat[order] < E                # the row lies in a group
+        if N * k % (2 * ROW_TILE) == 0:         # a tile of rows of no group
+            gather, inside = (jnp.pad(a, (0, ROW_TILE))
+                              for a in (order, inside))
+    rows = xt[gather // k]                      # [N*k (+ a tile), D]
     if layer is not None:
         groups = params["w_up"].shape[0] * E
         sizes = jax.lax.dynamic_update_slice(
@@ -221,8 +240,8 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
     hidden = act(dot(rows, params["w_gate"])) * up if cfg.glu else act(up)
     out = dot(hidden, params["w_down"])
     if assign is not None:      # rows of no group: whatever the matmul left
-        out = jnp.where((flat[order] < E)[:, None], out, 0)
-    out = out[jnp.argsort(order)]                                # un-sort
+        out = jnp.where(inside[:, None], out, 0)
+    out = out[jnp.argsort(order)]               # un-sort (leaves the pad)
     y = jnp.sum(out.reshape(N, k, D).astype(jnp.float32) * weight[..., None],
                 axis=1)
     return y.astype(xt.dtype), aux

@@ -486,3 +486,29 @@ def test_counters_count_state_steps_and_resets(model):
     assert offered == 20 * 8 * 4         # steps x choices x expert layers
     assert 0 < snap["ds_serve_moe_local_assignments_total"] < offered
     serve.close()
+
+
+@pytest.mark.parametrize("routing", ["the_routers", "every_choice_held"])
+def test_chunk_programs_serve_the_same_tokens_under_a_tile_of_pad(
+        model, monkeypatch, routing):
+    """``test_trinity.py``'s case of the same name through this form's chunk
+    programs (``afmoe.mlp_block`` from ``kda_mla.cached_layers``): buckets
+    of 16 and 8 tokens x 8 choices, a tile of 16 rows."""
+    from deepspeed_tpu.moe import sharded_moe
+
+    m, params = model
+    if routing == "every_choice_held":
+        monkeypatch.setattr(afmoe, "held", lambda cfg, weight, idx: (
+            weight, idx % cfg.num_experts))
+    prompt = np.random.default_rng(8).integers(0, 96, 37)
+
+    def served(tile):
+        monkeypatch.setattr(sharded_moe, "ROW_TILE", tile)
+        serve = deepspeed_tpu.init_serving(m, config=ENGINE, params=params,
+                                           mesh=m.mesh)
+        serve.submit(prompt, max_new_tokens=6)
+        (req,) = serve.run()
+        serve.close()
+        return req.output_tokens
+
+    assert served(16) == served(1 << 30)

@@ -8,6 +8,8 @@ import pytest
 from deepspeed_tpu.comm.mesh import build_mesh, set_global_mesh
 from deepspeed_tpu.models import causal_lm
 from deepspeed_tpu.moe import MoE, compute_capacity, moe_mlp, topk_gating
+from deepspeed_tpu.moe import sharded_moe
+from deepspeed_tpu.moe.sharded_moe import ROW_TILE, _moe_grouped
 
 
 def test_topk_gating_properties(rng):
@@ -266,3 +268,117 @@ def test_rts_randomizes_overflow_victims(rng):
     # model-level: rng=None still works (content-derived key)
     y, _ = moe_mlp(params, x, cfg)
     assert np.isfinite(np.asarray(y)).all()
+
+
+# ----------------------------------------------------------------------
+# a chip's share of the experts: _moe_grouped(assign=) hands the grouped
+# matmuls an odd number of ROW_TILE-row tiles
+# ----------------------------------------------------------------------
+def _held_share_case(n_held, N=256, k=4, E=4, D=32, F=16, L=3, seed=0):
+    """Stacked experts and an assignment of which ``n_held`` of the N*k
+    choices, scattered over the tokens, are of experts held here."""
+    from types import SimpleNamespace
+
+    cfg = SimpleNamespace(num_experts=E, num_experts_per_tok=k,
+                          activation="silu", glu=True)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 5)
+    stack = {n: jax.random.normal(key, (L, E) + shape) * 0.2
+             for n, key, shape in (("w_up", keys[0], (D, F)),
+                                   ("w_gate", keys[1], (D, F)),
+                                   ("w_down", keys[2], (F, D)))}
+    x = jax.random.normal(keys[3], (N, D))
+    draw = np.random.RandomState(seed + n_held)
+    flat = np.full(N * k, E, np.int32)
+    flat[draw.permutation(N * k)[:n_held]] = draw.randint(0, E, n_held)
+    local = jnp.asarray(flat.reshape(N, k))
+    weight = jnp.where(local < E, jax.random.uniform(keys[4], (N, k)), 0.0)
+    return cfg, stack, x, (weight, local)
+
+
+def _lhs_rows(fn, *args):
+    """The ``lhs`` lengths of the grouped matmuls ``fn`` traces."""
+    return [e.invars[0].aval.shape[0] for e in jax.make_jaxpr(fn)(
+        *args).jaxpr.eqns if e.primitive.name == "ragged_dot_general"]
+
+
+# (held rows, tokens, layer of the stack | None, lhs rows of the matmuls)
+HELD_SHARE_CASES = {
+    "no_row_held": (0, 256, None, 1024 + ROW_TILE),
+    "one_row_held": (1, 256, None, 1024 + ROW_TILE),
+    "the_mean_share": (128, 256, None, 1024 + ROW_TILE),
+    "all_but_one_row_held": (1023, 256, None, 1024 + ROW_TILE),
+    "every_row_held": (1024, 256, None, 1024 + ROW_TILE),
+    "stacked_layer_2": (150, 256, 2, 1024 + ROW_TILE),
+    "stacked_layer_1_every_row": (1024, 256, 1, 1024 + ROW_TILE),
+    "two_tiles_become_three": (30, 64, 0, 3 * ROW_TILE),
+    "an_odd_number_of_tiles_as_it_is": (200, 96, 2, 3 * ROW_TILE),
+    "rows_no_multiple_of_a_tile": (500, 250, 2, 1000),
+    "bucket_under_a_tile": (10, 8, 1, 32)}
+
+
+@pytest.mark.parametrize("n_held,N,layer,lhs", HELD_SHARE_CASES.values(),
+                         ids=HELD_SHARE_CASES.keys())
+def test_held_share_pad_is_the_parents_form(n_held, N, layer, lhs,
+                                            monkeypatch):
+    """A tile of pad rows behind the sorted rows against the parent's form
+    (the N*k sorted rows and no more; a tile no row count is a multiple of
+    gives it): equal to 0.0 for every count of held rows, and the matmuls'
+    ``lhs`` is an odd number of tiles wherever N*k is a whole number."""
+    cfg, stack, x, assign = _held_share_case(n_held, N=N)
+    p = stack if layer is not None else {n: a[1] for n, a in stack.items()}
+    ly = None if layer is None else jnp.asarray(layer, jnp.int32)
+
+    def block():                # a new function a call: traced anew
+        return lambda p, x, a: _moe_grouped(
+            p, x, None, cfg, False, layer=ly, assign=a)[0]
+
+    got = jax.jit(block())(p, x, assign)
+    assert _lhs_rows(block(), p, x, assign) == [lhs] * 3
+    assert lhs % ROW_TILE or lhs // ROW_TILE % 2
+    monkeypatch.setattr(sharded_moe, "ROW_TILE", 1 << 30)
+    n_rows = N * cfg.num_experts_per_tok
+    assert _lhs_rows(block(), p, x, assign) == [n_rows] * 3
+    want = jax.jit(block())(p, x, assign)
+    assert float(jnp.abs(got - want).max()) == 0.0
+    if n_held:
+        assert float(jnp.abs(want).max()) > 0.0
+
+
+@pytest.mark.parametrize("n_held", [0, 100, 385, 1024])
+def test_held_share_against_a_loop_over_experts(n_held):
+    """Independent of ``ragged_dot``: each held expert applied densely to
+    every token, weighted by the token's assignments to it."""
+    cfg, stack, x, (weight, local) = _held_share_case(n_held, seed=3)
+    got, _ = _moe_grouped(stack, x, None, cfg, False,
+                          layer=jnp.asarray(2, jnp.int32),
+                          assign=(weight, local))
+    want = jnp.zeros_like(x)
+    for e in range(cfg.num_experts):
+        w = {n: a[2, e] for n, a in stack.items()}
+        y = (jax.nn.silu(x @ w["w_gate"]) * (x @ w["w_up"])) @ w["w_down"]
+        want = want + y * jnp.sum(jnp.where(local == e, weight, 0.0), -1,
+                                  keepdims=True)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("stacked", [False, True])
+def test_held_share_pad_leaves_the_routers_own_path_alone(stacked):
+    """Without ``assign`` (OLMoE's chunk programs, ``moe_mlp``'s dropless
+    training path) the N*k sorted rows go through the three grouped matmuls
+    as they are, and nothing is padded: the parent's program (its jaxpr and
+    the lowered text of the OLMoE cell's chunk programs are the parent's to
+    the byte, PERF.md Findings PR 46)."""
+    cfg, stack, x, assign = _held_share_case(100)
+    n_rows = x.shape[0] * cfg.num_experts_per_tok
+    assert n_rows % (2 * ROW_TILE) == 0
+    p = stack if stacked else {n: a[1] for n, a in stack.items()}
+    ly = jnp.asarray(1, jnp.int32) if stacked else None
+    gates = jax.nn.softmax(x[:, :cfg.num_experts], axis=-1)
+    routed = jax.make_jaxpr(lambda p, x, g: _moe_grouped(
+        p, x, g, cfg, False, layer=ly))(p, x, gates)
+    assert _lhs_rows(lambda p, x, g: _moe_grouped(
+        p, x, g, cfg, False, layer=ly), p, x, gates) == [n_rows] * 3
+    assert "pad" not in {e.primitive.name for e in routed.jaxpr.eqns}
+    assert _lhs_rows(lambda p, x, a: _moe_grouped(
+        p, x, None, cfg, False, layer=ly, assign=a), p, x, assign) == [
+            n_rows + ROW_TILE] * 3

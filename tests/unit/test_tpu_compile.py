@@ -455,6 +455,18 @@ class _ServeCell:
             self._on_chip(s._rng),
             self._i32(s.num_slots, s.pool.slot_pages)).compile()
 
+    def assert_expert_rows_are_an_odd_number_of_tiles(self, program, rows):
+        """ISSUE 46: where the chip holds a share of the experts, every
+        grouped matmul of a chunk program takes its bucket's ``rows`` x k
+        sorted rows and one ``sharded_moe.ROW_TILE`` of pad, an odd number
+        of 128-row tiles (the tile the chip's ``ragged-dot`` then works a
+        group in)."""
+        from deepspeed_tpu.moe.sharded_moe import ROW_TILE
+
+        lhs = {int(r) for r in re.findall(
+            r"%ragged-dot-none\S* = bf16\[(\d+),", program.as_text())}
+        assert lhs == {rows + ROW_TILE} and rows % (2 * ROW_TILE) == 0, lhs
+
     def assert_pools_stay_in_place(self, program):
         """No instruction of ``program`` moves half a pool's bytes or more:
         no ``copy``, and no gather or scatter by op or by name (a gather
@@ -627,7 +639,10 @@ def test_trinity_cell_programs_compile_without_copying_a_budget(
         engine=dict(kv_pool_tokens=65536, num_slots=8))
     pool = cell.serve.pool
     assert (pool.window_pages, pool.slot_pages) == (16, 80)
-    cell.assert_pools_stay_in_place(cell.chunk(cell.serve.prefill_chunk))
+    chunk = cell.chunk(cell.serve.prefill_chunk)
+    cell.assert_pools_stay_in_place(chunk)
+    cell.assert_expert_rows_are_an_odd_number_of_tiles(
+        chunk, cell.serve.prefill_chunk * 4)
     block = cell.block()
     cell.assert_pools_stay_in_place(block)
     text = block.as_text()
@@ -729,7 +744,11 @@ def test_kimi_linear_cell_programs_compile_with_state_and_pool_in_place(
     cache = cell.serve._cache
     cell.smallest_pool = cache["latent"].nbytes    # the tails are smaller
     shape = lambda k: ",".join(str(d) for d in cache[k].shape)
-    for program in (cell.chunk(1024), cell.chunk(64), cell.block()):
+    chunks = (cell.chunk(1024), cell.chunk(64))
+    for program, tokens in zip(chunks, (1024, 64)):
+        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+            program, tokens * 8)
+    for program in chunks + (cell.block(),):
         cell.assert_pools_stay_in_place(program)
         for kind, key in (("f32", "state"), ("bf16", "tail")):
             assert not re.findall(rf"{kind}\[{shape(key)}\]\S* copy\(",

@@ -365,6 +365,36 @@ def test_counters_count_attended_rows_pages_and_local_choices(model):
     serve.close()
 
 
+@pytest.mark.parametrize("routing", ["the_routers", "every_choice_held"])
+def test_chunk_programs_serve_the_same_tokens_under_a_tile_of_pad(
+        model, monkeypatch, routing):
+    """A chunk program's expert blocks put a tile of pad rows behind their
+    sorted rows (``sharded_moe.ROW_TILE``, cut to 16 here so that a bucket
+    of 8 tokens x 4 choices is two tiles and gets a third): the served
+    tokens are those of the N*k rows alone, the parent's form."""
+    from deepspeed_tpu.moe import sharded_moe
+
+    m, params = model
+    if routing == "every_choice_held":
+        monkeypatch.setattr(afmoe, "held", lambda cfg, weight, idx: (
+            weight, idx % cfg.num_experts))
+    prompts = [np.random.default_rng(s).integers(0, 96, n)
+               for s, n in ((8, 10), (9, 37))]
+
+    def served(tile):
+        monkeypatch.setattr(sharded_moe, "ROW_TILE", tile)
+        serve = deepspeed_tpu.init_serving(m, config=ENGINE, params=params,
+                                           mesh=m.mesh)
+        for p in prompts:
+            serve.submit(p, max_new_tokens=6)
+        tokens = [r.output_tokens for r in sorted(
+            serve.run(), key=lambda r: r.request_id)]
+        serve.close()
+        return tokens
+
+    assert served(16) == served(1 << 30)
+
+
 # ------------------------------------------- the kernels, interpret mode
 @pytest.mark.parametrize("live", [
     [True, True, True], [True, False, True], [False, True, False],

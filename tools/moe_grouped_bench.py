@@ -8,6 +8,30 @@ and as the Pallas grouped matmul JAX ships
 between the two is made by this measurement (PERF.md, Findings, PR 27).
 
     python3 tools/moe_grouped_bench.py [--tokens 256,1024]
+
+``--held-share 8 --stacked`` times the block as the held-share cells' chunk
+programs run it (``models/afmoe.py:mlp``; PERF.md, Findings, PR 46): the
+``--experts`` held here are one in ``--held-share`` of those the router
+chooses among, the arrays are the model's stacked ``[L, E, ...]`` ones and
+every expert layer's block runs inside ONE jitted function, one after the
+other, as a chunk program's do.  A row gives, per expert layer: the three
+grouped matmuls alone over the ``N*k`` sorted rows and over those and one
+``sharded_moe.ROW_TILE`` of pad (``matmuls_all_rows_us``,
+``matmuls_padded_rows_us``: a third of each is the ``us a call`` that decided
+PR 46), the whole block over ``N*k`` rows (``block_one_call_us``: what the
+parent ran) and as the program runs it, padded (``block_padded_us``), with
+the worst difference between the two; ``--lhs-rows`` gives the matmuls alone
+over other lengths of the sorted rows' prefix (``matmuls_<n>_rows_us``: the
+chip's row-tile rule is read from these, 384 against 512).  ``--all-held``
+routes every choice to a held expert, as a rank that received its peers' rows
+would find them, and ``--held-share 1`` holds every expert.  These readings
+ARE what the cells' programs pay, a traced window's ``ragged-dot`` time over
+its calls says the same; the stand-alone readings above (one un-stacked call
+a jit) read about twice the cell's per-call time and price nothing in a cell
+(ROADMAP D8).
+
+    python3 tools/moe_grouped_bench.py --held-share 8 --stacked \
+        --experts 32 --top-k 8 --hidden 2304 --width 1024 --tokens 1024
 """
 
 from __future__ import annotations
@@ -33,6 +57,93 @@ def timed(fn, *args, calls=20):
     return (time.perf_counter() - t0) / calls * 1e3
 
 
+def held_share(args) -> int:
+    """``--held-share``: one JSON row a token count (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    from types import SimpleNamespace
+
+    from deepspeed_tpu.moe import sharded_moe
+
+    dev = jax.devices()[0]
+    E, k, D, F = args.experts, args.top_k, args.hidden, args.width
+    ER, L = E * args.held_share, args.stacked_layers or 4
+    cfg = SimpleNamespace(num_experts=E, num_experts_per_tok=k,
+                          activation="silu", glu=True)
+    keys = jax.random.split(jax.random.PRNGKey(args.seed), 6)
+    bf = jnp.bfloat16
+    stack = {n: jax.random.normal(key, (L, E) + shape, bf) * shape[0] ** -0.5
+             for n, key, shape in (("w_up", keys[0], (D, F)),
+                                   ("w_gate", keys[1], (D, F)),
+                                   ("w_down", keys[2], (F, D)))}
+    layers = jnp.arange(L, dtype=jnp.int32)
+    tile = sharded_moe.ROW_TILE
+
+    def matmuls(rows, w, sizes, layers):
+        """The three projections of every expert layer over ``rows``."""
+        flat = {n: a.reshape((L * E,) + a.shape[2:]) for n, a in w.items()}
+        out = rows
+        for l in range(L):
+            s = jax.lax.dynamic_update_slice(
+                jnp.zeros((L * E,), jnp.int32), sizes, (layers[l] * E,))
+            dot = lambda a, n: jax.lax.ragged_dot(a, flat[n], s)
+            h = jax.nn.silu(dot(out, "w_gate")) * dot(out, "w_up")
+            out = out + dot(h, "w_down")
+        return out
+
+    def blocks(row_tile, *args):
+        """Every expert layer's whole block, traced under ``row_tile``."""
+        def f(x, w, weight, local, layers):
+            for l in range(L):
+                y, _ = sharded_moe._moe_grouped(
+                    w, x, None, cfg, False, layer=layers[l],
+                    assign=(weight, local))
+                x = x + y
+            return x
+        sharded_moe.ROW_TILE = row_tile
+        try:
+            return jax.jit(f).lower(*args).compile()
+        finally:
+            sharded_moe.ROW_TILE = tile
+
+    for N in (int(n) for n in args.tokens.split(",")):
+        x = jax.random.normal(keys[3], (N, D), bf)
+        # k distinct experts a token, even over the router's: the first E
+        # are held here
+        _, idx = jax.lax.top_k(jax.random.uniform(
+            keys[4], (N, E if args.all_held else ER)), k)
+        weight = jnp.where(idx < E, 1.0 / k, 0.0)
+        local = jnp.where(idx < E, idx, E).astype(jnp.int32)
+        sizes = jnp.bincount(local.reshape(-1), length=E).astype(jnp.int32)
+        rows = jax.random.normal(keys[5], (N * k, D), bf)
+        held = int(sizes.sum())
+        per_layer_us = lambda fn, *a: timed(fn, *a) / L * 1e3
+        mm = jax.jit(matmuls)
+        args_ = (x, stack, weight, local, layers)
+        # a tile no row count is a multiple of: the parent's form
+        one_call, padded = blocks(1 << 30, *args_), blocks(tile, *args_)
+        row = {"tokens": N, "rows": N * k, "held_rows": held, "tile": tile,
+               "layers": L, "device": dev.device_kind,
+               "weight_bytes_us": 3 * E * D * F * 2 / 819e9 * 1e6,
+               "held_flops_us": 2 * held * 3 * D * F / 197e12 * 1e6,
+               "matmuls_all_rows_us": per_layer_us(mm, rows, stack, sizes,
+                                                   layers),
+               "matmuls_padded_rows_us": per_layer_us(
+                   mm, jnp.pad(rows, ((0, tile), (0, 0))), stack, sizes,
+                   layers),
+               "block_one_call_us": per_layer_us(one_call, *args_),
+               "block_padded_us": per_layer_us(padded, *args_),
+               "padded_max_abs_diff": float(jnp.abs(
+                   one_call(*args_).astype(jnp.float32)
+                   - padded(*args_)).max())}
+        for n in (int(n) for n in args.lhs_rows.split(",") if n):
+            if held <= n <= N * k:      # the prefix holds every held row
+                row[f"matmuls_{n}_rows_us"] = per_layer_us(
+                    mm, rows[:n], stack, sizes, layers)
+        print(json.dumps(row), flush=True)
+    return 0
+
+
 def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", default="256,1024")
@@ -41,6 +152,19 @@ def main() -> int:
     ap.add_argument("--hidden", type=int, default=2048)
     ap.add_argument("--width", type=int, default=1024)
     ap.add_argument("--stacked-layers", type=int, default=0)
+    ap.add_argument("--held-share", type=int, default=0,
+                    help="the --experts held here are one in this many of "
+                         "the router's (with --stacked)")
+    ap.add_argument("--stacked", action="store_true")
+    ap.add_argument("--lhs-rows", default="",
+                    help="--held-share: other lengths of the sorted rows' "
+                         "prefix to run the matmuls alone over")
+    ap.add_argument("--all-held", action="store_true",
+                    help="--held-share: the router chooses held experts "
+                         "only (a rank that received its peers' rows)")
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--allow-cpu", action="store_true",
+                    help="rehearse the control flow (tiny shapes only)")
     args = ap.parse_args()
     import jax
     import jax.numpy as jnp
@@ -49,9 +173,13 @@ def main() -> int:
     from deepspeed_tpu.moe.sharded_moe import moe_mlp
 
     dev = jax.devices()[0]
-    if dev.platform != "tpu":
+    if dev.platform != "tpu" and not args.allow_cpu:
         print("tools/moe_grouped_bench.py needs a TPU", file=sys.stderr)
         return 1
+    if args.held_share:
+        if not args.stacked:
+            ap.error("--held-share times the cells' form: add --stacked")
+        return held_share(args)
     E, k, D, F = args.experts, args.top_k, args.hidden, args.width
     cfg = SimpleNamespace(num_experts=E, num_experts_per_tok=k,
                           activation="silu", glu=True, moe_drop_tokens=False,
