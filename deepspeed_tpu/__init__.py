@@ -74,8 +74,7 @@ def _merge_inference_config(config, kwargs, cls):
 def init_serving(model=None, config=None, **kwargs):
     """Create a continuous-batching :class:`~deepspeed_tpu.serving.engine.
     ServingEngine` (the MII / DeepSpeed-FastGen dynamic-batching role):
-    paged KV cache (slots draw token pages from one shared pool;
-    ``paged_kv_cache=False`` for the contiguous per-slot layout),
+    paged KV cache (slots draw token pages from one shared pool),
     iteration-level scheduling, chunked prefill interleaved with
     per-row-position decode, and sync-free (device-resident) EOS
     termination with deferred finish-event drains.

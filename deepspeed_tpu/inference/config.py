@@ -71,15 +71,14 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # worst-case request.  kv_page_tokens = page granularity (0 = auto:
     # the flash-decode block, capped at the per-slot budget);
     # kv_pool_tokens = total pool capacity in tokens (0 = num_slots *
-    # per-slot budget — same HBM as the fixed layout; set it LOWER to
-    # oversubscribe slots against a fixed HBM budget, backed by LIFO
-    # preempt-and-requeue when the pool runs dry).  For a model whose
-    # layers are of two kinds (ModelConfig.layer_types) the pool holds two
-    # page budgets (serving/paged_kv.py) and kv_pool_tokens is the FULL
-    # one: positions the global layers can hold over all slots; the
-    # sliding layers' window budget is num_slots rings of sliding_window
-    # rows, derived, so that an admitted slot can always have its ring.
-    paged_kv_cache: bool = True
+    # per-slot budget; set it LOWER to oversubscribe slots against a fixed
+    # HBM budget, backed by LIFO preempt-and-requeue when the pool runs
+    # dry).  For a model whose layers are of two kinds
+    # (ModelConfig.layer_types) the pool holds two page budgets
+    # (serving/paged_kv.py) and kv_pool_tokens is the FULL one: positions
+    # the global layers can hold over all slots; the sliding layers'
+    # window budget is num_slots rings of sliding_window rows, derived, so
+    # that an admitted slot can always have its ring.
     kv_page_tokens: int = 0
     kv_pool_tokens: int = 0
     # Copy-on-write prefix caching (serving/prefix_cache.py — the
@@ -89,8 +88,8 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # and prefill starts at the match frontier, with one device-side
     # page copy when the boundary page is only partially matched
     # (copy-on-write).  Greedy outputs are token-identical with the
-    # cache on or off.  Paged engines only (ignored on the fixed-slot
-    # layout).
+    # cache on or off.  Off, with the reason logged, for a model whose
+    # pages are not a function of the token prefix (serving/cache_kind.py).
     prefix_caching: bool = True
     # KV host tier (serving/host_tier.py — the ZeRO-Infinity move applied
     # to serving): > 0 bounds an LRU host-RAM store of that many pages;
@@ -99,8 +98,8 @@ class DeepSpeedInferenceConfig(DeepSpeedConfigModel):
     # demoted chunk PROMOTES it back (host->device, byte-identical — greedy
     # outputs cannot change), so the effective prefix cache is host-RAM
     # sized and a preempt-resume re-adopts instead of re-prefilling.
-    # 0 (default) = off: eviction drops, the PR 9 semantics.  Paged +
-    # prefix_caching only.
+    # 0 (default) = off: eviction drops, the PR 9 semantics.  Needs
+    # prefix_caching.
     kv_host_tier_pages: int = 0
     # Overload protection (serving/scheduler.py, docs/RESILIENCE.md
     # "Serving fleet"): max_queue_depth bounds the admission queue — a

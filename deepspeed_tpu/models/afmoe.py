@@ -437,7 +437,7 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
     """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
     over the slot's views (``k_win`` / ``v_win`` [sliding layers, 1, Hkv, W, Dh]
     rings, ``k_full`` / ``v_full`` [global layers, 1, Hkv, positions, Dh]:
-    what ``ServingEngine._two_budget_forward`` gathers); only the first
+    what ``cache_kind.TwoBudgets.view`` gathers); only the first
     ``valid_len`` rows are real (the rest pad the bucket).  Returns
     (x, views).  Whether the chunk lies before, across or past the window,
     and whether the ring has wrapped, is data: one program a bucket."""

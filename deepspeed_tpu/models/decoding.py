@@ -288,7 +288,8 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
 
     ``start_pos`` is a scalar (the whole batch at one depth — static-batch
     prefill/decode) or an int32 [B] vector of per-row positions (the
-    continuous-batching decode, where every slot sits at its own depth).
+    continuous-batching decode, where every slot sits at its own depth: the
+    paged layout's, so it comes with a ``page_table``).
 
     ``page_table`` [B, maxp] switches the cache to the PAGED layout
     (``serving/paged_kv.py``: [L, num_pages, Hkv, page, Dh] pools shared
@@ -345,9 +346,10 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
     start_pos = jnp.asarray(start_pos, jnp.int32)
     per_row = start_pos.ndim == 1                      # [B] vector of depths
     paged = page_table is not None
-    if paged and (not per_row or s != 1):
-        raise ValueError("paged KV decode requires per-row positions and "
-                         "s == 1 (prefill runs on a gathered slot view)")
+    if paged != per_row or (paged and s != 1):
+        raise ValueError("per-row positions are the paged KV decode's, with "
+                         "a page_table and s == 1 (prefill runs on a "
+                         "gathered slot view at a scalar start_pos)")
     if cfg.is_eva and quant_kv:
         raise NotImplementedError(
             "attention='eva' with an int8 KV cache: the summary rows are "
@@ -486,11 +488,6 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
                 vc = _scatter_paged_rows(vc, vq, start_pos, page_table)
                 ksc = _scatter_paged_rows(ksc, ks, start_pos, page_table)
                 vsc = _scatter_paged_rows(vsc, vs, start_pos, page_table)
-            elif per_row:
-                kc = _scatter_rows(kc, kq, start_pos)
-                vc = _scatter_rows(vc, vq, start_pos)
-                ksc = _scatter_rows(ksc, ks, start_pos)
-                vsc = _scatter_rows(vsc, vs, start_pos)
             else:
                 kc = jax.lax.dynamic_update_slice(kc, kq, (0, 0, start_pos, 0))
                 vc = jax.lax.dynamic_update_slice(vc, vq, (0, 0, start_pos, 0))
@@ -501,9 +498,6 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         elif paged:
             kc = _scatter_paged_rows(kc, k, start_pos, page_table)
             vc = _scatter_paged_rows(vc, v, start_pos, page_table)
-        elif per_row:
-            kc = _scatter_rows(kc, k, start_pos)
-            vc = _scatter_rows(vc, v, start_pos)
         else:
             kc = jax.lax.dynamic_update_slice(kc, k.astype(kc.dtype),
                                               (0, 0, start_pos, 0))

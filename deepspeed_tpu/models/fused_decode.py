@@ -167,13 +167,13 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
 
     ``pos`` is a traced scalar (static batch: every row at the same depth)
     or an int32 [B] vector of per-row positions (continuous batching: each
-    slot sits at its own depth; cache appends scatter per row and the
-    flash-decode kernel masks per row).
+    slot sits at its own depth in the paged pool, so ``page_table`` comes
+    with it; the flash-decode kernel masks per row).
 
     ``page_table`` [B, maxp] switches the cache to the paged pool layout
     ([L, num_pages, Hkv, page, Dh], ``serving/paged_kv.py``): appends
     scatter through the table and the flash-decode kernel indirects its
-    DMA index map through it (per-row positions required).
+    DMA index map through it.
 
     ``moe_live`` [B] bool (the rows that are really decoding; one mask, two
     uses) is handed to the attention kernels, whose grid then visits those
@@ -194,8 +194,9 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
     kind, eps = cfg.norm, cfg.norm_eps
     pos = jnp.asarray(pos, jnp.int32)
     per_row = pos.ndim == 1                  # [B] per-slot depths
-    if page_table is not None and not per_row:
-        raise ValueError("paged KV decode requires per-row positions")
+    if (page_table is not None) != per_row:
+        raise ValueError("per-row positions are the paged KV decode's: pass "
+                         "both pos [B] and page_table, or neither")
     if cfg.is_eva and page_table is None:
         raise NotImplementedError(
             "attention='eva' decodes through the paged pool "
@@ -298,17 +299,6 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             # slot reads)
             kc_all, vc_all = paged_kv_append(kc_all, vc_all, k, v, pos,
                                              page_table, layer=l, impl=impl)
-        elif per_row:
-            # per-slot append: row b writes at its own depth pos[b], as ONE
-            # batched scatter.  Measured (CPU, 16-step scan, donated
-            # cache): scatter 37ms vs a per-row dynamic_update_slice loop
-            # 432ms — the per-row-index DUS defeats XLA's in-place
-            # aliasing and copies the cache per write.
-            bidx = jnp.arange(B)
-            kc_all = kc_all.at[l, bidx, :, pos, :].set(
-                k.astype(kc_all.dtype))
-            vc_all = vc_all.at[l, bidx, :, pos, :].set(
-                v.astype(vc_all.dtype))
         else:
             kc_all = jax.lax.dynamic_update_slice(
                 kc_all, k[None, :, :, None, :].astype(kc_all.dtype),

@@ -381,8 +381,8 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
     """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
     over ONE slot's views (``latent`` [latent layers, 1, 1, positions,
     row_width]; ``state`` [linear layers, 1, H, d, d] float32; ``tail``
-    [linear layers, 1, K - 1, 3 H d]: what ``ServingEngine._state_forward``
-    slices out); only the first ``valid_len`` rows are real.  A chunk at
+    [linear layers, 1, K - 1, 3 H d]: what
+    ``cache_kind.LatentPagesAndState.view`` slices out); only the first ``valid_len`` rows are real.  A chunk at
     position 0 starts from a zero state whatever the slot held (the state's
     reset at admission).  Returns (x, views)."""
     B, s, _ = x.shape

@@ -9,7 +9,10 @@ inference engine).
 - :mod:`deepspeed_tpu.serving.paged_kv` — :class:`PagedKVPool`: block
   allocator over one shared pool of fixed-size KV token pages (per-slot
   page tables, alloc-on-append, free-on-finish, LIFO preempt-and-requeue
-  under pool pressure) — the vLLM/PagedAttention role, on by default.
+  under pool pressure) — the vLLM/PagedAttention role.
+- :mod:`deepspeed_tpu.serving.cache_kind` — what a slot's cache is made of
+  (full pages, window + summary pages, two budgets, latent pages + state):
+  the one place that decides it from the model's configuration.
 - :mod:`deepspeed_tpu.serving.engine` — :class:`ServingEngine`: KV-cache
   slots decoding in lock-step with PER-ROW positions (every slot at its
   own depth), chunked per-slot prefill interleaved with decode so decode

@@ -1,0 +1,508 @@
+"""What a slot's cache is made of, behind one interface.
+
+``serving/paged_kv.py`` allocates pages and ``serving/engine.py`` schedules
+requests; neither knows what a page holds.  That depends on the model's
+attention form, and :func:`cache_kind` is the one place that decides it
+(the layouts themselves are set out in ``serving/paged_kv.py``'s docstring):
+
+- :class:`FullPages` (GPT-2, Mistral, OLMoE): per-head K and V rows of
+  ``page`` consecutive positions in every layer, for ever.  Position-pure,
+  so prefix caching, the prefill -> decode hand-off, the host tier and the
+  int8 cache all work on it: it refuses nothing;
+- :class:`WindowSummaryPages` (``attention="eva"``, ``models/eva.py``);
+- :class:`TwoBudgets` (sliding and global layers, ``models/afmoe.py``);
+- :class:`LatentPagesAndState` (linear-attention and latent-attention
+  layers, ``models/kda_mla.py``).
+
+A kind answers what the engine asks and nothing else: the pool's arguments
+and the device arrays; what it cannot be served with (``cannot``: one table
+of option and reason); the chunk program's ``view`` of one slot and its
+``write_back``; its counters, moved on the host from positions the engine
+holds anyway.  Every engine registers every kind's series (``attach``), so
+what a replica exports does not depend on the model it serves.  A new layout
+is one class here beside its model module.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deepspeed_tpu.models import afmoe, kda_mla
+from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_schedule
+from deepspeed_tpu.serving.paged_kv import init_paged_kv_cache
+
+
+def _slot_view(v, pt_row, cols):
+    """The pages of pool entry ``v`` ``[L, pages, Hkv, page, D]`` that a
+    slot's page-table row names at columns ``cols``, as the contiguous
+    ``[L, 1, Hkv, len(cols) * page, D]`` the prefill forward takes: one
+    slice a page (a gather through the table, ``v[:, pt_row]``, compiles on
+    the v5e to a read and a write-back of the whole donated pool)."""
+    g = jnp.concatenate(
+        [jax.lax.dynamic_slice_in_dim(v, pt_row[c], 1, axis=1)
+         for c in cols], axis=1)                   # [L, n, Hkv, page, D]
+    L, n, Hkv, page, D = g.shape
+    return g.transpose(0, 2, 1, 3, 4).reshape(L, 1, Hkv, n * page, D)
+
+
+def _slot_write_back(dst, s, pt_row, col0, pages):
+    """:func:`_slot_view`'s inverse, in place in the donated pool: page
+    ``i`` of the view ``s`` (a Python or a traced index, for each ``i`` of
+    ``pages``) goes to the pool page at column ``col0 + i`` of the row.
+    Columns that name one pool page (unallocated entries all name junk page
+    0) are written in turn and the last wins."""
+    L, _, Hkv, S, D = s.shape
+    page = dst.shape[3]
+    paged = s.reshape(L, Hkv, S // page, page, D).transpose(0, 2, 1, 3, 4)
+    for i in pages:
+        one = jax.lax.dynamic_slice_in_dim(paged, i, 1, axis=1)
+        dst = jax.lax.dynamic_update_slice_in_dim(
+            dst, one, pt_row[col0 + i], axis=1)
+    return dst
+
+
+def _touched(start, cb: int, page: int, fp: int) -> List[Any]:
+    """The pages of a slot's ``fp`` position-pure ones that a chunk of
+    ``cb`` rows at ``start`` can have written, as indices into its view."""
+    first = jnp.minimum(start // page, fp - 1)
+    return [jnp.minimum(first + i, fp - 1)
+            for i in range(min(-(-cb // page) + 1, fp))]
+
+
+class FullPages:
+    """Pages of per-head K and V rows of consecutive positions, the same in
+    every layer (module docstring); the base of the other kinds."""
+
+    what = "attention='full'"       # how a refusal names the model
+    # option -> why this kind cannot be served with it: ``handoff`` (a role
+    # other than "both", a ``prefill_only`` request), ``kv_host_tier_pages``,
+    # ``quantize_kv_cache``, ``use_fused_decode`` (False), all refused by
+    # name; ``prefix_caching``, which is turned off with the reason logged
+    cannot: Dict[str, str] = {}
+    counters: Dict[str, str] = {}   # the series this kind moves
+    takes_valid_len = False         # the chunk's forward is told its real rows
+    pages_by_kind = False           # ds_serve_kv_pages_used_by_kind moves
+
+    def __init__(self, cfg):
+        self.cfg = cfg
+
+    # -- the pool and its arrays ---------------------------------------
+    def pool_args(self, dtype) -> Dict[str, int]:
+        """What ``PagedKVPool`` is told beside slots, budget and page."""
+        return {}
+
+    def init_cache(self, pool, num_slots: int, dtype, quantized: bool):
+        return init_paged_kv_cache(self.cfg, pool.num_pages, pool.page,
+                                   dtype=dtype, quantized=quantized)
+
+    def layout(self, pool, num_slots: int) -> str:
+        """The start-up log's account of the pool."""
+        return (f"paged pool: {pool.num_pages - 1} x {pool.page}-token "
+                f"pages, {num_slots} slots x {pool.cache_len} window")
+
+    # -- what it cannot be served with ---------------------------------
+    def refuse(self, option: str, asked: str) -> None:
+        """Raise where the table lists ``option``; ``asked`` is how the
+        caller spelled it."""
+        if option in self.cannot:
+            raise NotImplementedError(
+                f"{asked} with {self.what}: {self.cannot[option]}")
+
+    def check(self, config, role: str, prefill_chunk: int) -> None:
+        """An engine's construction against the table, then the kind's own
+        bound on ``prefill_chunk``."""
+        if role != "both":
+            self.refuse("handoff", f"role={role!r}")
+        if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
+            self.refuse("kv_host_tier_pages", "kv_host_tier_pages > 0")
+        if config.quantize_kv_cache:
+            self.refuse("quantize_kv_cache", "quantize_kv_cache")
+        if config.use_fused_decode is False:
+            self.refuse("use_fused_decode", "use_fused_decode=False")
+        self.check_prefill_chunk(int(prefill_chunk))
+
+    def check_prefill_chunk(self, prefill_chunk: int) -> None:
+        pass
+
+    # -- the chunk program's view of one slot --------------------------
+    def view(self, cache, pt_row, slot, start, cb: int):
+        """The slot's rows as the contiguous cache ``forward_with_cache``
+        takes for a chunk of ``cb`` rows at ``start``: its pages sliced out
+        of the pool one by one (:func:`_slot_view`); scalars pass."""
+        cols = range(pt_row.shape[0])
+        return {k: (_slot_view(v, pt_row, cols) if v.ndim == 5 else v)
+                for k, v in cache.items()}
+
+    def write_back(self, cache, sub, pt_row, slot, start, cb: int):
+        """``sub``, the view as the forward left it, back in place in the
+        donated pool (:func:`_slot_write_back`).  A chunk reads and writes
+        ``slot_pages`` pages whatever the pool's size.  Pad rows hold junk
+        but are only ever attended after the next chunk or decode step has
+        overwritten them; junk past the allocated pages lands on the junk
+        page."""
+        cols = range(pt_row.shape[0])
+        return {k: (_slot_write_back(cache[k], sub[k], pt_row, 0, cols)
+                    if cache[k].ndim == 5 else sub[k]) for k in cache}
+
+    # -- counters ------------------------------------------------------
+    def attach(self, registry, pool) -> None:
+        """Register every kind's series and keep the registry: a kind moves
+        its own, and does the arithmetic for them only while
+        ``registry.enabled``."""
+        for kind in KINDS:
+            for name, what in kind.counters.items():
+                registry.counter(name, what)
+        registry.gauge(
+            "ds_serve_state_bytes",
+            "bytes of per-slot recurrent state and convolution tails "
+            "resident on the device: num_slots times a slot's, fixed")
+        self._pages_kind = {
+            kind: registry.gauge(
+                "ds_serve_kv_pages_used_by_kind",
+                "KV pool pages held by slots, by what they hold (EVA: window "
+                "rows reused in place, or chunk summaries; two budgets: the "
+                "sliding layers' rings, or the global layers' full pages; "
+                "full attention: all window)", labels={"kind": kind})
+            for kind in ("window", "summary", "full")}
+        self._reg = registry
+        self._m = {name: registry.counter(name) for name in self.counters}
+
+    def count_admit(self) -> None:
+        """A request took a slot."""
+
+    def count_chunk(self, pool, cache, off: int, c: int, cb: int) -> None:
+        """A chunk of ``c`` real tokens at ``off``, in a bucket of ``cb``,
+        was enqueued."""
+
+    def count_rows(self, pos: int, n: int) -> None:
+        """A row of a decode block was scheduled ``n`` steps from ``pos``."""
+
+    def count_iteration(self, pool) -> None:
+        """A scheduler iteration ended."""
+
+    def count_block(self, counts: List[np.ndarray]) -> List[np.ndarray]:
+        """The counts a decode block's program returned reached the host:
+        take this kind's off the end, return the routing's."""
+        return counts
+
+    def page_gauges(self, pool) -> None:
+        if self.pages_by_kind and self._reg.enabled:
+            for kind, n in pool.pages_used_by_kind().items():
+                self._pages_kind[kind].set(n)
+
+
+class WindowSummaryPages(FullPages):
+    """EVA attention: window pages reused in place, then the pages of the
+    chunk summaries, both out of one pool and in the arrays ``k`` and ``v``;
+    the chunk program's view is all of them, as for full pages."""
+
+    what = "attention='eva'"
+    _pages = ("pages as the K and V of a token prefix, which a window page "
+              "is not (it is overwritten every eva_window tokens)")
+    cannot = {
+        "handoff": "serving/handoff.py ships " + _pages,
+        "kv_host_tier_pages": "serving/host_tier.py demotes and promotes "
+                              + _pages,
+        "prefix_caching": "serving/prefix_cache.py shares " + _pages,
+        "quantize_kv_cache":
+            "models/decoding.py scales int8 rows a position at a time, and "
+            "summary rows are pooled from window rows with no scales of "
+            "their own",
+    }
+    counters = {
+        "ds_serve_eva_window_closes_total":
+            "windows closed (pooled into summary rows), by prefill chunks and "
+            "decode steps",
+        "ds_serve_eva_window_rows_total":
+            "window rows attended by live decode rows, summed over steps",
+        "ds_serve_eva_summary_rows_total":
+            "summary rows attended by live decode rows, summed over steps",
+        "ds_serve_eva_prefill_scores_total":
+            "scores (query, key row) the two masks keep for the real tokens "
+            "of the prefill chunks, one head of one layer",
+        "ds_serve_eva_prefill_scores_visited_total":
+            "scores the chunk programs' attention computes for those chunks "
+            "(eva_chunk_schedule: the kernel's strips, or the whole bucket x "
+            "view where the dense form runs), one head of one layer",
+    }
+    pages_by_kind = True
+
+    def pool_args(self, dtype):
+        return {"window_tokens": self.cfg.eva_window,
+                "chunk_tokens": self.cfg.eva_chunk}
+
+    def layout(self, pool, num_slots):
+        return (super().layout(pool, num_slots)
+                + f" ({pool.window_pages} window + {pool.summary_pages} "
+                  "summary pages a slot)")
+
+    def check_prefill_chunk(self, prefill_chunk):
+        W = self.cfg.eva_window
+        if W % prefill_chunk or prefill_chunk & (prefill_chunk - 1):
+            raise ValueError(
+                f"prefill_chunk={prefill_chunk} must be a power of two that "
+                f"divides eva_window={W}: a prefill chunk may not straddle a "
+                f"window boundary")
+
+    def count_chunk(self, pool, cache, off, c, cb):
+        """``ds_serve_eva_prefill_scores*``: what the masks keep of the
+        chunk's scores and what its program's attention computes, from the
+        schedule the kernel takes its bounds from; and the window a chunk
+        closes."""
+        cfg = self.cfg
+        if (off + c) % cfg.eva_window == 0:
+            self._m["ds_serve_eva_window_closes_total"].inc()
+        if not self._reg.enabled:
+            return
+        sch = eva_chunk_schedule(
+            off, cb, real=c, window=cfg.eva_window, chunk=cfg.eva_chunk,
+            rows=pool.slot_pages * pool.page, head_dim=cfg.head_dim,
+            itemsize=cache["k"].dtype.itemsize)
+        self._m["ds_serve_eva_prefill_scores_total"].inc(sch["kept"])
+        self._m["ds_serve_eva_prefill_scores_visited_total"].inc(
+            sch["visited"])
+
+    def count_rows(self, pos, n):
+        """``ds_serve_eva_*``: the rows each step attends (``pos % W + 1``
+        window rows and the ``W/C`` summaries of each closed window) and the
+        windows the steps close; an EOS row that stops early is counted to
+        its bound."""
+        if not self._reg.enabled:
+            return
+        W, per = self.cfg.eva_window, self.cfg.eva_window // self.cfg.eva_chunk
+        p = np.arange(pos, pos + n)
+        m = self._m
+        m["ds_serve_eva_window_rows_total"].inc(int((p % W + 1).sum()))
+        m["ds_serve_eva_summary_rows_total"].inc(int((p // W).sum()) * per)
+        m["ds_serve_eva_window_closes_total"].inc(
+            int(((p + 1) % W == 0).sum()))
+
+
+class TwoBudgets(FullPages):
+    """Sliding and global layers: the table's first ``sliding_window /
+    page`` columns name a ring in the window budget's arrays ``k_win`` /
+    ``v_win``, the rest full pages in ``k_full`` / ``v_full``."""
+
+    what = "layer_types"
+    _pages = ("pages as the K and V of a token prefix in every layer, which "
+              "a sliding layer's ring page is not (it is overwritten every "
+              "sliding_window tokens)")
+    cannot = {
+        "handoff": "serving/handoff.py ships " + _pages,
+        "kv_host_tier_pages": "serving/host_tier.py demotes and promotes "
+                              + _pages,
+        "prefix_caching": "serving/prefix_cache.py shares " + _pages,
+        "quantize_kv_cache":
+            "the int8 cache of models/decoding.py is one array a layer kind "
+            "with scales, and the fused decode path (the only one built for "
+            "this model) reads no int8 rows",
+        "use_fused_decode":
+            "the decode step over two page budgets is built on the fused "
+            "path only (models/afmoe.py:fused_layers)",
+    }
+    counters = {
+        "ds_serve_attn_window_rows_total":
+            "K/V rows the live decode queries attended in ONE sliding layer "
+            "(min(pos + 1, window) a step), summed over rows and steps",
+        "ds_serve_attn_full_rows_total":
+            "K/V rows the live decode queries attended in ONE global layer "
+            "(pos + 1 a step), summed over rows and steps",
+        "ds_serve_kv_page_steps_total":
+            "page x layer x iterations the two budgets held: window pages "
+            "times the sliding layers plus full pages times the global "
+            "layers, summed over scheduler iterations (by budget at an "
+            "instant: "
+            "ds_serve_kv_pages_used_by_kind)",
+        "ds_serve_kv_page_steps_one_budget_total":
+            "page x layer x iterations one budget a layer would have held for "
+            "the same positions (every layer a page per kv_page_tokens)",
+    }
+    takes_valid_len = True          # a ring takes no pad row
+    pages_by_kind = True
+
+    def pool_args(self, dtype):
+        return {"ring_tokens": self.cfg.sliding_window}
+
+    def init_cache(self, pool, num_slots, dtype, quantized):
+        cfg = self.cfg
+        ls, lf = afmoe.kind_layers(cfg)
+        z = lambda n, pages: jnp.zeros(
+            (n, pages, cfg.num_kv_heads, pool.page, cfg.head_dim), dtype)
+        return {"k_win": z(len(ls), pool.num_window_pages),
+                "v_win": z(len(ls), pool.num_window_pages),
+                "k_full": z(len(lf), pool.num_pages),
+                "v_full": z(len(lf), pool.num_pages)}
+
+    def layout(self, pool, num_slots):
+        return (f"two page budgets: {pool.num_window_pages - 1} window + "
+                f"{pool.num_pages - 1} full x {pool.page}-token pages, "
+                f"{num_slots} slots x ({pool.window_pages} ring pages + "
+                f"{pool.cache_len} positions)")
+
+    def check_prefill_chunk(self, prefill_chunk):
+        W = self.cfg.sliding_window
+        if W and prefill_chunk > W:
+            raise ValueError(
+                f"prefill_chunk={prefill_chunk} exceeds sliding_window={W}: "
+                f"a chunk's real rows must be distinct rows of the ring "
+                f"(serving/paged_kv.py)")
+
+    def _columns(self, cache, pt_row):
+        """(ring pages, full pages) of a slot's table row."""
+        wp = self.cfg.sliding_window // cache["k_win"].shape[3]
+        return wp, pt_row.shape[0] - wp
+
+    def view(self, cache, pt_row, slot, start, cb):
+        """The views ``afmoe.cached_layers`` takes: the ring as the earlier
+        chunks left it (the forward attends it BEFORE it appends) and every
+        full page."""
+        wp, fp = self._columns(cache, pt_row)
+        cols = {"win": range(wp), "full": range(wp, wp + fp)}
+        return {k: _slot_view(cache[k], pt_row, cols[k[2:]])
+                for k in ("k_win", "v_win", "k_full", "v_full")}
+
+    def write_back(self, cache, sub, pt_row, slot, start, cb):
+        """All of the ring's pages go back, and of the full ones only those
+        the chunk's ``cb`` rows can have touched."""
+        wp, fp = self._columns(cache, pt_row)
+        spans = {"win": (0, list(range(wp))),
+                 "full": (wp, _touched(start, cb, cache["k_full"].shape[3],
+                                       fp))}
+        return {k: _slot_write_back(cache[k], sub[k], pt_row, *spans[k[2:]])
+                for k in cache}
+
+    def count_rows(self, pos, n):
+        """``ds_serve_attn_*_rows_total``: the K/V rows each step attends in
+        one sliding layer (``min(p + 1, W)``) and in one global layer (``p +
+        1``)."""
+        if not self._reg.enabled:
+            return
+        p = np.arange(pos, pos + n) + 1
+        self._m["ds_serve_attn_window_rows_total"].inc(
+            int(np.minimum(p, self.cfg.sliding_window).sum()))
+        self._m["ds_serve_attn_full_rows_total"].inc(int(p.sum()))
+
+    def count_iteration(self, pool):
+        """``ds_serve_kv_page_steps_*``: what the two budgets hold, each
+        page weighted by the layers of its kind, and what ONE budget would
+        hold for the same slots (their full pages, in every layer)."""
+        if not self._reg.enabled:
+            return
+        n_win, n_full = (len(k) for k in afmoe.kind_layers(self.cfg))
+        held = pool.pages_used_by_kind()
+        self._m["ds_serve_kv_page_steps_total"].inc(
+            held["window"] * n_win + held["full"] * n_full)
+        self._m["ds_serve_kv_page_steps_one_budget_total"].inc(
+            held["full"] * (n_win + n_full))
+
+
+class LatentPagesAndState(FullPages):
+    """Linear-attention and latent-attention layers: ``latent`` pages of one
+    row a position that all heads share, allocated like full pages, and per
+    SLOT a float32 recurrent ``state`` and a convolution ``tail``, never
+    allocated or freed (a request's first chunk program reads zeros)."""
+
+    what = "linear_attention / latent_attention layers"
+    cannot = {
+        "handoff": "serving/handoff.py ships pages as the K and V of a token "
+                   "prefix, and a recurrent state is not a page",
+        "kv_host_tier_pages":
+            "serving/host_tier.py demotes and promotes pages for the prefix "
+            "cache, which is off for this model (a state is not "
+            "position-pure)",
+        "prefix_caching":
+            "serving/prefix_cache.py shares pages as a function of the token "
+            "prefix, and a recurrent state is a slot's, not a page's (the "
+            "latent pages alone are position-pure)",
+        "quantize_kv_cache":
+            "the int8 cache of models/decoding.py scales per-head K and V "
+            "rows; a latent row and a float32 state have no int8 form",
+        "use_fused_decode":
+            "the decode step over latent pages and slot state is built on "
+            "the fused path only (models/kda_mla.py:fused_layers)",
+    }
+    # the row steps are counted by the decode-block program itself (the
+    # live mask and the state kernel's grid) and fetched with its tokens
+    counters = {
+        "ds_serve_state_row_steps_total":
+            "(live row, linear-attention layer, decode step) triples: the "
+            "state updates the decode steps really made",
+        "ds_serve_state_row_steps_visited_total":
+            "(row, linear-attention layer, decode step) triples whose state "
+            "the decode kernel read and wrote, live or parked "
+            "(ops/pallas/decode.py:kda_decode_step)",
+        "ds_serve_state_resets_total":
+            "slot states reset: a request (or a preempted one's resume) took "
+            "a slot and its first chunk starts from a zero state",
+    }
+    takes_valid_len = True          # the state is left as of the last real row
+
+    def pool_args(self, dtype):
+        return {"slot_state_bytes": kda_mla.slot_state_bytes(self.cfg, dtype)}
+
+    def init_cache(self, pool, num_slots, dtype, quantized):
+        cfg = self.cfg
+        state, tail = kda_mla.state_shapes(cfg, num_slots)
+        return {"latent": jnp.zeros(
+                    (len(kda_mla.kind_layers(cfg)[1]), pool.num_pages, 1,
+                     pool.page, kda_mla.row_width(cfg)), dtype),
+                "state": jnp.zeros(state, jnp.float32),
+                "tail": jnp.zeros(tail, dtype)}
+
+    def layout(self, pool, num_slots):
+        return (super().layout(pool, num_slots)
+                + f" of latent rows, and {pool.state_bytes} bytes of slot "
+                  "state")
+
+    def view(self, cache, pt_row, slot, start, cb):
+        """The view ``kda_mla.cached_layers`` takes: every latent page of
+        the slot, and its state and tail sliced out by the slot's index (the
+        chunk carries them through and starts from zeros at position 0)."""
+        own = lambda v: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
+        return {"latent": _slot_view(cache["latent"], pt_row,
+                                     range(pt_row.shape[0])),
+                "state": own(cache["state"]), "tail": own(cache["tail"])}
+
+    def write_back(self, cache, sub, pt_row, slot, start, cb):
+        """Of the latent pages only those the chunk's ``cb`` rows can have
+        touched go back; state and tail in place at the slot's index."""
+        latent = _slot_write_back(
+            cache["latent"], sub["latent"], pt_row, 0,
+            _touched(start, cb, cache["latent"].shape[3], pt_row.shape[0]))
+        put = lambda k: jax.lax.dynamic_update_slice_in_dim(
+            cache[k], sub[k], slot, axis=1)
+        return {"latent": latent, "state": put("state"), "tail": put("tail")}
+
+    def attach(self, registry, pool):
+        super().attach(registry, pool)
+        registry.gauge("ds_serve_state_bytes").set(pool.state_bytes)
+
+    def count_admit(self):
+        self._m["ds_serve_state_resets_total"].inc()
+
+    def count_block(self, counts):
+        """``ds_serve_state_row_steps_*``: the block's (row, linear layer)
+        pairs, live and visited by the state kernel (the fifth of
+        ``kda_mla.fused_layers``' counts)."""
+        steps = counts.pop()
+        self._m["ds_serve_state_row_steps_total"].inc(int(steps[0]))
+        self._m["ds_serve_state_row_steps_visited_total"].inc(int(steps[1]))
+        return counts
+
+
+KINDS = (FullPages, WindowSummaryPages, TwoBudgets, LatentPagesAndState)
+
+
+def cache_kind(cfg) -> FullPages:
+    """The kind of cache a slot of a model of configuration ``cfg`` has."""
+    if getattr(cfg, "is_eva", False):
+        return WindowSummaryPages(cfg)
+    if getattr(cfg, "is_kda_mla", False):
+        return LatentPagesAndState(cfg)
+    if getattr(cfg, "is_afmoe", False):
+        return TwoBudgets(cfg)
+    return FullPages(cfg)

@@ -1,4 +1,4 @@
-"""Continuous-batching serving engine over a paged (or fixed-slot) KV cache.
+"""Continuous-batching serving engine over a paged KV cache.
 
 The static-batch :class:`~deepspeed_tpu.inference.engine.InferenceEngine`
 decodes the whole batch in lock-step on one scalar position: no request can
@@ -7,16 +7,16 @@ burns most of the batch on padding and head-of-line blocking.  This engine
 is the Orca / DeepSpeed-FastGen answer, mapped onto the existing fused
 Pallas decode stack:
 
-- a KV cache shared by ``num_slots`` slots — by default a PAGED pool
+- a KV cache shared by ``num_slots`` slots: a PAGED pool
   (``serving/paged_kv.py``: fixed-size token pages, per-slot page tables,
   alloc-on-append, free-on-finish, LIFO preempt-and-requeue under pool
   pressure), so HBM tracks the tokens actually live instead of reserving
-  ``max_out_tokens`` per slot; ``paged_kv_cache=False`` keeps the PR 1
-  contiguous per-slot layout ([L, num_slots, Hkv, Smax, Dh]);
+  ``max_out_tokens`` per slot.  What a page holds, and what else a slot
+  carries, is the model's cache kind's business
+  (``serving/cache_kind.py``): this module asks it and tests no model flag;
 - PER-ROW decode positions: every slot sits at its own depth, threaded
   through ``forward_with_cache`` / ``decode_step`` / the flash-decode
-  kernel (which masks, DMA-clamps, and — paged — page-table-indirects per
-  row);
+  kernel (which masks, DMA-clamps and page-table-indirects per row);
 - iteration-level scheduling: each :meth:`step` admits queued requests
   into freed slots, advances at most ``max_prefill_chunks`` prompt chunks
   (chunked per-slot prefill, interleaved with decode so decode latency
@@ -55,8 +55,8 @@ written by the CURRENT occupant before it is first attended — prefill
 writes [0, S) before the first decode, and each decode step writes its own
 row before attending it.  Inactive slots are "parked": they still run in
 the compiled step (static shapes) but write their junk K/V at their own
-frozen position — their own rows in the fixed layout, the reserved junk
-page 0 in the paged layout (a released slot's page table points there).
+frozen position, which is on the reserved junk page 0 (a released slot's
+page table points there).
 """
 
 from __future__ import annotations
@@ -74,19 +74,17 @@ import numpy as np
 
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.engine import InferenceEngine, pow2_bucket
-from deepspeed_tpu.models.decoding import (forward_with_cache, init_kv_cache,
+from deepspeed_tpu.models.decoding import (forward_with_cache,
                                            next_token_logits, sample_token)
 from deepspeed_tpu.monitor.flight_recorder import get_flight_recorder
 from deepspeed_tpu.monitor.goodput import get_goodput_ledger
 from deepspeed_tpu.monitor.health import get_health
 from deepspeed_tpu.monitor.metrics import get_registry
 from deepspeed_tpu.monitor.request_trace import get_request_tracer
-from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_schedule
 from deepspeed_tpu.profiling.trace import phase
+from deepspeed_tpu.serving.cache_kind import cache_kind
 from deepspeed_tpu.serving.host_tier import HostPageStore
-from deepspeed_tpu.serving.paged_kv import (PagedKVPool,
-                                            init_paged_kv_cache,
-                                            init_state_cache)
+from deepspeed_tpu.serving.paged_kv import PagedKVPool
 from deepspeed_tpu.serving.prefix_cache import PrefixCache
 from deepspeed_tpu.serving.scheduler import (PREFILLING, QUEUED, RUNNING,
                                              IterationScheduler, QueueFull,
@@ -145,200 +143,6 @@ def _in_phase(name: str):
     return wrap
 
 
-# EVA attention (models/eva.py), counted on the host from the positions the
-# engine already holds, only while the registry is on.
-SERVE_EVA_COUNTERS = {
-    "ds_serve_eva_window_closes_total":
-        "windows closed (pooled into summary rows), by prefill chunks and "
-        "decode steps",
-    "ds_serve_eva_window_rows_total":
-        "window rows attended by live decode rows, summed over steps",
-    "ds_serve_eva_summary_rows_total":
-        "summary rows attended by live decode rows, summed over steps",
-    "ds_serve_eva_prefill_scores_total":
-        "scores (query, key row) the two masks keep for the real tokens of "
-        "the prefill chunks, one head of one layer",
-    "ds_serve_eva_prefill_scores_visited_total":
-        "scores the chunk programs' attention computes for those chunks "
-        "(eva_chunk_schedule: the kernel's strips, or the whole bucket x "
-        "view where the dense form runs), one head of one layer",
-}
-
-
-# Layers of two kinds over two page budgets (models/afmoe.py,
-# serving/paged_kv.py), counted on the host from the positions the engine
-# already holds, only while the registry is on.
-SERVE_WINDOW_COUNTERS = {
-    "ds_serve_attn_window_rows_total":
-        "K/V rows the live decode queries attended in ONE sliding layer "
-        "(min(pos + 1, window) a step), summed over rows and steps",
-    "ds_serve_attn_full_rows_total":
-        "K/V rows the live decode queries attended in ONE global layer "
-        "(pos + 1 a step), summed over rows and steps",
-    "ds_serve_kv_page_steps_total":
-        "page x layer x iterations the two budgets held: window pages times "
-        "the sliding layers plus full pages times the global layers, summed "
-        "over scheduler iterations (by budget at an instant: "
-        "ds_serve_kv_pages_used_by_kind)",
-    "ds_serve_kv_page_steps_one_budget_total":
-        "page x layer x iterations one budget a layer would have held for "
-        "the same positions (every layer a page per kv_page_tokens)",
-}
-
-
-# Latent pages and per-slot recurrent state (models/kda_mla.py,
-# serving/paged_kv.py), only while the registry is on.  The row steps, live
-# and visited, are counted by the decode-block program itself (the live mask
-# and the state kernel's grid) and fetched with a block's tokens, as the
-# routing counts are.
-SERVE_STATE_COUNTERS = {
-    "ds_serve_state_row_steps_total":
-        "(live row, linear-attention layer, decode step) triples: the state "
-        "updates the decode steps really made",
-    "ds_serve_state_row_steps_visited_total":
-        "(row, linear-attention layer, decode step) triples whose state the "
-        "decode kernel read and wrote, live or parked "
-        "(ops/pallas/decode.py:kda_decode_step)",
-    "ds_serve_state_resets_total":
-        "slot states reset: a request (or a preempted one's resume) took a "
-        "slot and its first chunk starts from a zero state",
-}
-
-
-def _state_refusals(cfg, config, role: str) -> None:
-    """What a model of latent-attention and linear-attention layers
-    (models/kda_mla.py) cannot be served with, each named by the module
-    that assumes a slot's cache is pages of per-head K and V rows and
-    nothing else."""
-    if not config.paged_kv_cache:
-        raise NotImplementedError(
-            "linear_attention / latent_attention layers are served from the "
-            "paged pool's latent pages and its slot-state budget "
-            "(serving/paged_kv.py); paged_kv_cache=False "
-            "(models/decoding.py:init_kv_cache) has per-head K and V rows "
-            "only")
-    if role != "both":
-        raise NotImplementedError(
-            f"role={role!r} with linear_attention layers: serving/handoff.py "
-            "ships pages as the K and V of a token prefix, and a recurrent "
-            "state is not a page")
-    if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
-        raise NotImplementedError(
-            "kv_host_tier_pages > 0 with linear_attention layers: "
-            "serving/host_tier.py demotes and promotes pages for the prefix "
-            "cache, which is off for this model (a state is not "
-            "position-pure)")
-    if config.quantize_kv_cache:
-        raise NotImplementedError(
-            "quantize_kv_cache with linear_attention / latent_attention "
-            "layers: the int8 cache of models/decoding.py scales per-head K "
-            "and V rows; a latent row and a float32 state have no int8 form")
-    if config.use_fused_decode is False:
-        raise NotImplementedError(
-            "use_fused_decode=False with linear_attention / latent_attention "
-            "layers: the decode step over latent pages and slot state is "
-            "built on the fused path only (models/kda_mla.py:fused_layers)")
-
-
-def _afmoe_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
-    """What a ``layer_types`` model (models/afmoe.py) cannot be served with,
-    each named by the module that assumes ONE budget of position-pure pages
-    (``_eva_refusals``' reasoning: a sliding layer's ring page is
-    overwritten every ``sliding_window`` tokens, and a page id means a page
-    in one kind of layer only)."""
-    if not config.paged_kv_cache:
-        raise NotImplementedError(
-            "a layer_types model is served from the paged pool's two "
-            "budgets (serving/paged_kv.py); paged_kv_cache=False has no "
-            "ring for its sliding layers")
-    if role != "both":
-        raise NotImplementedError(
-            f"role={role!r} with layer_types: serving/handoff.py ships "
-            "pages as the K and V of a token prefix in every layer, which a "
-            "ring page is not")
-    if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
-        raise NotImplementedError(
-            "kv_host_tier_pages > 0 with layer_types: serving/host_tier.py "
-            "demotes and promotes pages keyed by the token prefix they hold "
-            "in every layer, which a ring page does not keep")
-    if config.quantize_kv_cache:
-        raise NotImplementedError(
-            "quantize_kv_cache with layer_types: the int8 cache of "
-            "models/decoding.py is one array a layer kind with scales, and "
-            "the fused decode path (the only one built for this model) "
-            "reads no int8 rows")
-    if config.use_fused_decode is False:
-        raise NotImplementedError(
-            "use_fused_decode=False with layer_types: the decode step over "
-            "two page budgets is built on the fused path only "
-            "(models/afmoe.py:fused_layers)")
-    if cfg.sliding_window and prefill_chunk > cfg.sliding_window:
-        raise ValueError(
-            f"prefill_chunk={prefill_chunk} exceeds sliding_window="
-            f"{cfg.sliding_window}: a chunk's real rows must be distinct "
-            f"rows of the ring (serving/paged_kv.py)")
-
-
-def _eva_refusals(cfg, config, role: str, prefill_chunk: int) -> None:
-    """What an ``attention="eva"`` model cannot be served with, each named
-    by the module that assumes a page holds the K and V of fixed positions
-    for ever (a window page is overwritten every ``eva_window`` tokens)."""
-    if not config.paged_kv_cache:
-        raise NotImplementedError(
-            "attention='eva' is served from the paged pool "
-            "(serving/paged_kv.py holds its window and summary pages); "
-            "paged_kv_cache=False has no layout for them")
-    if role != "both":
-        raise NotImplementedError(
-            f"role={role!r} with attention='eva': serving/handoff.py ships "
-            "pages as the K and V of a token prefix, which a window page "
-            "is not")
-    if int(getattr(config, "kv_host_tier_pages", 0)) > 0:
-        raise NotImplementedError(
-            "kv_host_tier_pages > 0 with attention='eva': "
-            "serving/host_tier.py demotes and promotes pages keyed by the "
-            "token prefix they hold, which a window page does not keep")
-    if config.quantize_kv_cache:
-        raise NotImplementedError(
-            "quantize_kv_cache with attention='eva': models/decoding.py "
-            "scales int8 rows a position at a time, and summary rows are "
-            "pooled from window rows with no scales of their own")
-    if cfg.eva_window % prefill_chunk or prefill_chunk & (prefill_chunk - 1):
-        raise ValueError(
-            f"prefill_chunk={prefill_chunk} must be a power of two that "
-            f"divides eva_window={cfg.eva_window}: a prefill chunk may not "
-            f"straddle a window boundary")
-
-
-def _slot_view(v, pt_row, cols):
-    """The pages of pool entry ``v`` ``[L, pages, Hkv, page, D]`` that a
-    slot's page-table row names at columns ``cols``, as the contiguous
-    ``[L, 1, Hkv, len(cols) * page, D]`` the prefill forward takes: one
-    slice a page (a gather through the table, ``v[:, pt_row]``, compiles on
-    the v5e to a read and a write-back of the whole donated pool)."""
-    g = jnp.concatenate(
-        [jax.lax.dynamic_slice_in_dim(v, pt_row[c], 1, axis=1)
-         for c in cols], axis=1)                   # [L, n, Hkv, page, D]
-    L, n, Hkv, page, D = g.shape
-    return g.transpose(0, 2, 1, 3, 4).reshape(L, 1, Hkv, n * page, D)
-
-
-def _slot_write_back(dst, s, pt_row, col0, pages):
-    """:func:`_slot_view`'s inverse, in place in the donated pool: page
-    ``i`` of the view ``s`` (a Python or a traced index, for each ``i`` of
-    ``pages``) goes to the pool page at column ``col0 + i`` of the row.
-    Columns that name one pool page (unallocated entries all name junk page
-    0) are written in turn and the last wins."""
-    L, _, Hkv, S, D = s.shape
-    page = dst.shape[3]
-    paged = s.reshape(L, Hkv, S // page, page, D).transpose(0, 2, 1, 3, 4)
-    for i in pages:
-        one = jax.lax.dynamic_slice_in_dim(paged, i, 1, axis=1)
-        dst = jax.lax.dynamic_update_slice_in_dim(
-            dst, one, pt_row[col0 + i], axis=1)
-    return dst
-
-
 class ServingEngine:
     """Continuous-batching serving over an :class:`InferenceEngine`'s
     weights (plain + kernel-injected views, dtype, mesh all reused).
@@ -358,11 +162,11 @@ class ServingEngine:
         Decode steps per compiled block (per host dispatch) — the serving
         analog of ``decode_unroll``.
 
-    Paged-KV knobs ride on the config: ``paged_kv_cache`` (default on),
-    ``kv_page_tokens`` (page granularity), ``kv_pool_tokens`` (total pool
-    capacity — set it below ``num_slots * max_out_tokens`` to oversubscribe
-    slots against a fixed HBM budget; pool pressure preempts the
-    youngest-admitted slot LIFO and requeues it at the queue head).
+    Paged-KV knobs ride on the config: ``kv_page_tokens`` (page
+    granularity), ``kv_pool_tokens`` (total pool capacity — set it below
+    ``num_slots * max_out_tokens`` to oversubscribe slots against a fixed
+    HBM budget; pool pressure preempts the youngest-admitted slot LIFO and
+    requeues it at the queue head).
     """
 
     # HTTP /generate worker threads share the idempotent-dispatch map
@@ -429,91 +233,53 @@ class ServingEngine:
             max_queue_depth=int(self._config.max_queue_depth),
             shed_retry_after_s=float(self._config.shed_retry_after_s))
 
+        if getattr(self._config, "paged_kv_cache", True) is False:
+            # an unknown key only warns (runtime/config_utils.py): this
+            # value would silently be served from the pool
+            raise ValueError(
+                "paged_kv_cache=False: the option and the contiguous "
+                "per-slot layout it selected were removed; every engine "
+                "serves from the paged pool (serving/paged_kv.py)")
         cfg = self.module.config
-        self._eva = bool(getattr(cfg, "is_eva", False))
-        if self._eva:
-            _eva_refusals(cfg, self._config, role, self.prefill_chunk)
-        # latent pages and per-slot recurrent state (models/kda_mla.py)
-        self._state = bool(getattr(cfg, "is_kda_mla", False))
-        if self._state:
-            _state_refusals(cfg, self._config, role)
-        # layers of two kinds over two page budgets (models/afmoe.py)
-        self._afmoe = bool(getattr(cfg, "is_afmoe", False)) \
-            and not self._state
-        if self._afmoe:
-            _afmoe_refusals(cfg, self._config, role, self.prefill_chunk)
-        self.paged = bool(self._config.paged_kv_cache)
-        if self.paged:
-            slot_state = 0
-            if self._state:
-                from deepspeed_tpu.models.kda_mla import slot_state_bytes
-                slot_state = slot_state_bytes(cfg, engine.dtype)
-            self.pool = PagedKVPool(
-                self.num_slots, self._config.max_out_tokens,
-                page_tokens=self._config.kv_page_tokens,
-                pool_tokens=self._config.kv_pool_tokens,
-                window_tokens=cfg.eva_window if self._eva else 0,
-                chunk_tokens=cfg.eva_chunk if self._eva else 0,
-                ring_tokens=cfg.sliding_window if self._afmoe else 0,
-                slot_state_bytes=slot_state)
-            if self._state:
-                self._cache = init_state_cache(
-                    cfg, self.pool.num_pages, self.pool.page, self.num_slots,
-                    dtype=engine.dtype)
+        # what a slot's cache is made of: pages of a kind, and whatever else
+        # the slot carries (serving/cache_kind.py)
+        self.kind = cache_kind(cfg)
+        self.kind.check(self._config, role, self.prefill_chunk)
+        self.pool = PagedKVPool(
+            self.num_slots, self._config.max_out_tokens,
+            page_tokens=self._config.kv_page_tokens,
+            pool_tokens=self._config.kv_pool_tokens,
+            **self.kind.pool_args(engine.dtype))
+        self._cache = self.kind.init_cache(
+            self.pool, self.num_slots, engine.dtype,
+            self._config.quantize_kv_cache)
+        # per-slot LOGICAL window (page-table depth x page); the PHYSICAL
+        # pool may hold fewer tokens than num_slots windows
+        self.cache_len = self.pool.cache_len
+        # copy-on-write prefix caching over the page pool, for a kind whose
+        # pages are a function of the token prefix alone, with an optional
+        # HOST TIER: kv_host_tier_pages > 0 bounds an LRU host store that
+        # eviction victims demote into (instead of dropping) and admissions
+        # promote back out of — the effective prefix cache becomes
+        # host-RAM-sized (docs/OBSERVABILITY.md "KV host tier")
+        self.host_store = None
+        self.prefix_cache = None
+        if self._config.prefix_caching:
+            why_not = self.kind.cannot.get("prefix_caching")
+            if why_not:
+                log_dist(f"prefix caching is off for {self.kind.what}: "
+                         f"{why_not}", ranks=[0])
             else:
-                self._cache = init_paged_kv_cache(
-                    cfg, self.pool.num_pages, self.pool.page,
-                    dtype=engine.dtype,
-                    quantized=self._config.quantize_kv_cache,
-                    num_window_pages=self.pool.num_window_pages)
-            # per-slot LOGICAL window (page-table depth x page); the
-            # PHYSICAL pool may hold fewer tokens than num_slots windows
-            self.cache_len = self.pool.cache_len
-        else:
-            self.pool = None
-            self._cache = init_kv_cache(
-                cfg, self.num_slots, self._config.max_out_tokens,
-                dtype=engine.dtype, quantized=self._config.quantize_kv_cache)
-            # cache_len is the PHYSICAL depth (init_kv_cache rounds up to a
-            # flash-decode block multiple)
-            self.cache_len = int(self._cache["k"].shape[-2])
-        # copy-on-write prefix caching over the page pool (a fixed-slot
-        # engine has no pages to share — the knob is paged-only), with an
-        # optional HOST TIER: kv_host_tier_pages > 0 bounds an LRU host
-        # store that eviction victims demote into (instead of dropping)
-        # and admissions promote back out of — the effective prefix cache
-        # becomes host-RAM-sized (docs/OBSERVABILITY.md "KV host tier")
-        if self._eva and self._config.prefix_caching:
-            log_dist("prefix caching is off for attention='eva': "
-                     "serving/prefix_cache.py shares pages as the K and V of "
-                     "a token prefix, and a window page is overwritten every "
-                     f"{cfg.eva_window} tokens", ranks=[0])
-        if self._afmoe and self._config.prefix_caching:
-            log_dist("prefix caching is off for a layer_types model: "
-                     "serving/prefix_cache.py shares pages as the K and V of "
-                     "a token prefix in every layer, and a sliding layer's "
-                     f"ring page is overwritten every {cfg.sliding_window} "
-                     "tokens", ranks=[0])
-        if self._state and self._config.prefix_caching:
-            log_dist("prefix caching is off for linear_attention layers: "
-                     "serving/prefix_cache.py shares pages as a function of "
-                     "the token prefix, and a recurrent state is a slot's, "
-                     "not a page's (the latent pages alone are "
-                     "position-pure)", ranks=[0])
-        if self.paged and self._config.prefix_caching and not (
-                self._eva or self._afmoe or self._state):
-            host_pages = int(getattr(self._config, "kv_host_tier_pages", 0))
-            self.host_store = (
-                HostPageStore(host_pages, registry=self._registry)
-                if host_pages > 0 else None)
-            self.prefix_cache = PrefixCache(
-                self.pool, registry=self._registry,
-                host_store=self.host_store,
-                fetch_page=(self._fetch_page_host
-                            if self.host_store is not None else None))
-        else:
-            self.host_store = None
-            self.prefix_cache = None
+                host_pages = int(getattr(self._config,
+                                         "kv_host_tier_pages", 0))
+                if host_pages > 0:
+                    self.host_store = HostPageStore(host_pages,
+                                                    registry=self._registry)
+                self.prefix_cache = PrefixCache(
+                    self.pool, registry=self._registry,
+                    host_store=self.host_store,
+                    fetch_page=(self._fetch_page_host
+                                if self.host_store is not None else None))
         # max_out is the configured LOGICAL budget — generation bounds use
         # max_out so serving stays token-identical to generate(), which
         # never sees the physical rounding
@@ -677,18 +443,8 @@ class ServingEngine:
                         f"host seconds inside {name}: {what}")
         self._m_moe = {name: reg.counter(name, what)
                        for name, what in SERVE_MOE_COUNTERS.items()}
-        self._m_eva = {name: reg.counter(name, what)
-                       for name, what in SERVE_EVA_COUNTERS.items()}
-        self._m_win = {name: reg.counter(name, what)
-                       for name, what in SERVE_WINDOW_COUNTERS.items()}
-        self._m_state = {name: reg.counter(name, what)
-                         for name, what in SERVE_STATE_COUNTERS.items()}
-        self._m_state_bytes = reg.gauge(
-            "ds_serve_state_bytes",
-            "bytes of per-slot recurrent state and convolution tails "
-            "resident on the device: num_slots times a slot's, fixed")
-        if self._state:
-            self._m_state_bytes.set(self.pool.state_bytes)
+        # page and state series: every kind's registered, this kind's moved
+        self.kind.attach(reg, self.pool)
         self._m_first_overlapped = reg.counter(
             "ds_serve_first_token_overlapped_total",
             "first tokens fetched with a decode block already enqueued "
@@ -744,20 +500,11 @@ class ServingEngine:
             "ds_serve_draining",
             "1 while drain() runs (admission stopped, in-flight requests "
             "finishing); 0 otherwise")
-        # paged-KV pool health (registered unconditionally so the metrics
-        # namespace guard covers them; zero-valued on fixed-slot engines)
+        # paged-KV pool health
         self._m_pages_used = reg.gauge(
             "ds_serve_kv_pages_used", "KV pool pages allocated to slots")
         self._m_pages_free = reg.gauge(
             "ds_serve_kv_pages_free", "KV pool pages on the free list")
-        self._m_pages_kind = {
-            kind: reg.gauge(
-                "ds_serve_kv_pages_used_by_kind",
-                "KV pool pages held by slots, by what they hold (EVA: window "
-                "rows reused in place, or chunk summaries; two budgets: the "
-                "sliding layers' rings, or the global layers' full pages; "
-                "full attention: all window)", labels={"kind": kind})
-            for kind in ("window", "summary", "full")}
         self._m_preempted = reg.counter(
             "ds_serve_preempted_total",
             "requests preempted (pages reclaimed, requeued at queue head)")
@@ -818,26 +565,10 @@ class ServingEngine:
                     and supports_fused_decode(
                         cfg, quantized_kv=self._config.quantize_kv_cache,
                         tp=engine.mesh.shape.get("tp", 1)))
-        if self.paged:
-            layout = (f"paged pool: {self.pool.num_pages - 1} x "
-                      f"{self.pool.page}-token pages, "
-                      f"{self.num_slots} slots x {self.cache_len} window")
-            if self._eva:
-                layout += (f" ({self.pool.window_pages} window + "
-                           f"{self.pool.summary_pages} summary pages a slot)")
-            if self._state:
-                layout += (f" of latent rows, and {self.pool.state_bytes} "
-                           "bytes of slot state")
-            if self._afmoe:
-                layout = (f"two page budgets: {self.pool.num_window_pages - 1}"
-                          f" window + {self.pool.num_pages - 1} full x "
-                          f"{self.pool.page}-token pages, {self.num_slots} "
-                          f"slots x ({self.pool.window_pages} ring pages + "
-                          f"{self.cache_len} positions)")
-        else:
-            layout = f"{self.num_slots} slots x {self.cache_len} tokens"
-        log_dist(f"serving engine: {layout}, prefill_chunk="
-                 f"{self.prefill_chunk}, decode_block={self._K}, "
+        log_dist("serving engine: "
+                 f"{self.kind.layout(self.pool, self.num_slots)}, "
+                 f"prefill_chunk={self.prefill_chunk}, "
+                 f"decode_block={self._K}, "
                  f"{'fused' if fused_ok else 'unfused'} decode", ranks=[0])
 
     # ------------------------------------------------------------------
@@ -868,11 +599,8 @@ class ServingEngine:
                 "engine is draining/drained: not admitting new requests "
                 "(the router should have stopped sending — /healthz is "
                 "503; resume_admission() re-opens)")
-        if prefill_only and (self._eva or self._afmoe or self._state):
-            raise NotImplementedError(
-                "prefill_only with attention='eva' or layer_types: "
-                "serving/handoff.py ships pages as the K and V of a token "
-                "prefix, which a window page or a recurrent state is not")
+        if prefill_only:
+            self.kind.refuse("handoff", "prefill_only")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prompt.size == 0:
             raise ValueError("empty prompt")
@@ -940,8 +668,7 @@ class ServingEngine:
                 self._pos[req.slot] = 0
                 self._active[req.slot] = False
                 self._limit[req.slot] = 0
-                if self._state:    # its first chunk program reads zeros
-                    self._m_state["ds_serve_state_resets_total"].inc()
+                self.kind.count_admit()
                 if self.prefix_cache is not None:
                     self._admit_prefix(req)
         # 2. chunked prefill: max_prefill_chunks PLACES an iteration, one
@@ -988,18 +715,11 @@ class ServingEngine:
         self._m_steps.inc()
         self._m_active.set(int(self._active.sum()))
         self._m_occupancy.record(self.scheduler.num_occupied / self.num_slots)
-        # cache utilization = live tokens / ALLOCATED tokens: pages actually
-        # granted on the paged pool, the full per-slot reservation on the
-        # fixed layout — the bench's paged-vs-fixed attribution series
-        if self.paged:
-            if self.pool.pages_used:
-                self._m_kv_util.record(
-                    self.pool.utilization(int(self._pos.sum())))
-            if self._afmoe and self._registry.enabled:
-                self._count_page_steps()
-        elif self.scheduler.num_occupied:
+        # cache utilization = live tokens / tokens of the pages granted
+        if self.pool.pages_used:
             self._m_kv_util.record(
-                int(self._pos.sum()) / (self.num_slots * self.cache_len))
+                self.pool.utilization(int(self._pos.sum())))
+        self.kind.count_iteration(self.pool)
         finished = self.scheduler.finished[done_before:]
         self._m_step_finished.set(len(finished))
         self._profilez_end()
@@ -1567,11 +1287,8 @@ class ServingEngine:
         request's FULL prompt pages device->host and stash (chunk tokens,
         page payload) pairs on the request — BEFORE release returns the
         pages to the pool (the payloads are host copies, so the release
-        is safe).  Fixed-slot engines have no pages to ship; the decode
-        side simply re-prefills (degraded mode)."""
+        is safe)."""
         req.handoff = []
-        if not self.paged:
-            return
         page = self.pool.page
         resident = min(req.prefill_pos, req.prompt_len)
         full = resident // page
@@ -1717,8 +1434,7 @@ class ServingEngine:
                 chunks, payloads, alloc, self._write_page)
             if adopted:
                 self._m_adopted_pages.inc(adopted)
-                self._m_pages_used.set(self.pool.pages_used)
-                self._m_pages_free.set(self.pool.pages_free)
+                self._page_gauges()
             return {"adopted": adopted}
         finally:
             self._goodput.pop()
@@ -1880,8 +1596,7 @@ class ServingEngine:
         self._pos_dev = self._setpos_fn(
             self._pos_dev, jnp.asarray(req.slot, jnp.int32),
             jnp.asarray(matched, jnp.int32))
-        self._m_pages_used.set(self.pool.pages_used)
-        self._m_pages_free.set(self.pool.pages_free)
+        self._page_gauges()
         self._tracer.span(req.request_id, "prefix_hit", req.t_admit,
                           req.t_admit, matched)
 
@@ -1999,9 +1714,7 @@ class ServingEngine:
     def _page_gauges(self) -> None:
         self._m_pages_used.set(self.pool.pages_used)
         self._m_pages_free.set(self.pool.pages_free)
-        if (self._eva or self._afmoe) and self._registry.enabled:
-            for kind, n in self.pool.pages_used_by_kind().items():
-                self._m_pages_kind[kind].set(n)
+        self.kind.page_gauges(self.pool)
 
     def _youngest_victim(self) -> Optional[Request]:
         cands = self.scheduler.running() + self.scheduler.prefilling()
@@ -2023,20 +1736,13 @@ class ServingEngine:
         self._eos[b] = -1
         self._pos_dev, self._act_dev = self._park_fn(
             self._pos_dev, self._act_dev, jnp.asarray(b, jnp.int32))
-        if self.prefix_cache is not None:
-            # the victim's already-computed prompt pages go into the cache
-            # BEFORE release reclaims them: its requeue-front resume (and
-            # anyone sharing the prompt) re-prefills through the cache, so
-            # LIFO preemption costs the boundary/output tokens, not the
-            # whole prompt.  Under the very pressure that triggered this
-            # preempt these pages are the NEWEST LRU entries — the
-            # requester evicts older history first and takes these only
-            # as a last resort.
-            resident = min(victim.prefill_pos, victim.prompt_len)
-            full = resident // self.pool.page
-            if full:
-                self.prefix_cache.insert(victim.prompt,
-                                         self.pool.owned(b)[:full])
+        # the victim's requeue-front resume (and anyone sharing the prompt)
+        # re-prefills through the cache, so LIFO preemption costs the
+        # boundary/output tokens, not the whole prompt.  Under the very
+        # pressure that triggered this preempt these pages are the NEWEST
+        # LRU entries — the requester evicts older history first and takes
+        # these only as a last resort.
+        self._cache_prompt_pages(victim)
         freed = self.pool.release(b)
         victim.preemptions += 1
         self.scheduler.requeue_front(victim)   # records the preempt edge
@@ -2069,7 +1775,7 @@ class ServingEngine:
         prefix = req.prefix              # prompt (+ outputs after a resume)
         S = req.prefix_len
         c = min(self.prefill_chunk, S - off)
-        if self.paged and not self._ensure_pages(req, off + c):
+        if not self._ensure_pages(req, off + c):
             return                       # self-preempted: resumes later
         last_chunk = off + c == S
         wake = False
@@ -2104,8 +1810,7 @@ class ServingEngine:
             tok_dev, self._cache, carries = self._prefill_fn(cb)(
                 self.engine._params, self._cache,
                 (self._last_dev, self._pos_dev, self._act_dev),
-                (jnp.asarray(self.pool.page_table[slot]) if self.paged
-                 else None),
+                jnp.asarray(self.pool.page_table[slot]),
                 jnp.asarray(chunk), meta, srng)
             self._last_dev, self._pos_dev, self._act_dev = carries
             req.prefill_pos += c
@@ -2113,10 +1818,7 @@ class ServingEngine:
                               time.perf_counter(), c, seq=seq)
             self._m_prefill_chunks.inc()
             self._m_prefill_toks.inc(c)
-            if self._eva and (off + c) % self.module.config.eva_window == 0:
-                self._m_eva["ds_serve_eva_window_closes_total"].inc()
-            if self._eva and self._registry.enabled:
-                self._count_eva_chunk(off, c, cb)
+            self.kind.count_chunk(self.pool, self._cache, off, c, cb)
             # parked rows write junk at their own pos; keeping pos =
             # prefill progress (host view here, device carry inside the
             # chunk's program) means the NEXT chunk overwrites that row
@@ -2202,25 +1904,18 @@ class ServingEngine:
 
     def _prefill_fn(self, cb: int):
         """Per-slot chunked prefill, compiled once per pow2 chunk bucket:
-        ``(params, cache, (last, pos, active), page-table row | None, chunk
-        [1, cb], meta, rng) -> (token, cache, (last, pos, active))``, cache
-        and carries donated.
+        ``(params, cache, (last, pos, active), page-table row, chunk [1, cb],
+        meta, rng) -> (token, cache, (last, pos, active))``, cache and
+        carries donated.
 
-        Fixed layout: slice the slot's cache rows out, run the standard
-        (batch-1) prefill forward at the chunk's absolute offset, write the
-        slot back, and sample the next token from the last real position's
-        logits — the token stays a DEVICE scalar so admission never syncs
-        the host.  Paged layout: the slot's pages are sliced out of the
-        pool one by one into the same contiguous logical view
-        (``_slot_view``), the identical forward runs, and each page goes
-        back in place in the donated pool (``_slot_write_back``): a chunk
-        reads and writes ``slot_pages`` pages of K and V whatever the
-        pool's size, and the decode hot path never builds the view.
-        Pad rows in [off+c, off+cb) hold junk K/V but are only ever
-        attended AFTER being overwritten by the next chunk / decode step
-        (queries attend key_pos <= q_pos, and every row <= q_pos has been
-        rewritten by then); junk landing past the allocated pages goes to
-        the junk page.
+        The slot's rows come out of the pool as the contiguous view the
+        model's forward takes (``self.kind.view``: its pages sliced out one
+        by one, and whatever else the slot carries), the standard (batch-1)
+        prefill forward runs at the chunk's absolute offset, the view goes
+        back in place in the donated pool (``self.kind.write_back``), and
+        the next token is sampled from the last real position's logits — it
+        stays a DEVICE scalar so admission never syncs the host, and the
+        decode hot path never builds the view.
 
         The program also updates the decode block's carries for its slot:
         ``last`` takes the sampled token, ``pos`` the prefill frontier (so
@@ -2231,43 +1926,18 @@ class ServingEngine:
         if cb in self._prefill_fns:
             return self._prefill_fns[cb]
         self._m_compiles.inc()
-        model = self.module
+        model, kind = self.module, self.kind
         do_sample, temperature, top_k, top_p = self._sample
-        if self._afmoe:
-            forward = self._two_budget_forward(cb)
-        elif self._state:
-            forward = self._state_forward(cb)
 
         @functools.partial(jax.jit, donate_argnums=(1, 2))
         def prefill(params, cache, carries, pt_row, chunk, meta, srng):
             slot, start, last_idx, wake, eos = meta
-
-            def view(v):                 # the slot's rows, contiguous
-                if pt_row is None:
-                    return jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-                return _slot_view(v, pt_row, range(pt_row.shape[0]))
-
-            def write_back(dst, s):
-                if pt_row is None:
-                    return jax.lax.dynamic_update_slice_in_dim(
-                        dst, s, slot, axis=1)
-                return _slot_write_back(dst, s, pt_row, 0,
-                                        range(pt_row.shape[0]))
-
-            if self._afmoe:
-                logits, out = forward(params, cache, pt_row, chunk, start,
-                                      last_idx + 1)
-            elif self._state:
-                logits, out = forward(params, cache, pt_row, chunk, start,
-                                      last_idx + 1, slot)
-            else:
-                sub = {k: (view(v) if v.ndim == 5 else v)
-                       for k, v in cache.items()}
-                logits, sub = forward_with_cache(model, params, chunk, sub,
-                                                 start)
-                out = {k: (write_back(cache[k], sub[k])
-                           if cache[k].ndim == 5 else sub[k])
-                       for k in cache}
+            real = ({"valid_len": last_idx + 1} if kind.takes_valid_len
+                    else {})
+            sub = kind.view(cache, pt_row, slot, start, cb)
+            logits, sub = forward_with_cache(model, params, chunk, sub, start,
+                                             **real)
+            out = kind.write_back(cache, sub, pt_row, slot, start, cb)
             logits = next_token_logits(model.config, jax.lax.dynamic_index_in_dim(
                 logits, last_idx, axis=1, keepdims=False))
             tok = sample_token(logits, srng, temperature=temperature,
@@ -2281,71 +1951,6 @@ class ServingEngine:
 
         self._prefill_fns[cb] = prefill
         return prefill
-
-    def _two_budget_forward(self, cb: int):
-        """The chunk program's forward over two page budgets
-        (serving/paged_kv.py): ``(params, cache, page-table row, chunk [1,
-        cb], start, real tokens) -> (logits, cache)``.  The slot's ring
-        pages and its full pages are sliced out into the contiguous views
-        ``afmoe.cached_layers`` takes (``_slot_view``); the forward attends
-        the ring BEFORE it appends, and the pages go back
-        (``_slot_write_back``): all of the ring's, and of the full ones only
-        those the chunk's ``cb`` rows can have touched."""
-        model, page = self.module, self.pool.page
-        wp = self.pool.window_pages
-        fp = self.pool.slot_pages - wp
-        touched = -(-cb // page) + 1
-
-        def forward(params, cache, pt_row, chunk, start, valid_len):
-            win, full = range(wp), range(wp, wp + fp)
-            sub = {"k_win": _slot_view(cache["k_win"], pt_row, win),
-                   "v_win": _slot_view(cache["v_win"], pt_row, win),
-                   "k_full": _slot_view(cache["k_full"], pt_row, full),
-                   "v_full": _slot_view(cache["v_full"], pt_row, full)}
-            logits, sub = forward_with_cache(model, params, chunk, sub, start,
-                                             valid_len=valid_len)
-            first = jnp.minimum(start // page, fp - 1)
-            spans = {"win": (0, list(range(wp))),
-                     "full": (wp, [jnp.minimum(first + i, fp - 1)
-                                   for i in range(min(touched, fp))])}
-            out = {k: _slot_write_back(cache[k], sub[k], pt_row,
-                                       *spans[k.split("_")[1]])
-                   for k in cache}
-            return logits, out
-
-        return forward
-
-    def _state_forward(self, cb: int):
-        """The chunk program's forward over latent pages and slot state
-        (serving/paged_kv.py): ``(params, cache, page-table row, chunk [1,
-        cb], start, real tokens, slot) -> (logits, cache)``.  The slot's
-        latent pages are sliced out into the contiguous view
-        ``kda_mla.cached_layers`` takes (``_slot_view``) and of them only
-        those the chunk's ``cb`` rows can have touched go back
-        (``_slot_write_back``); the slot's state and tail are sliced out by
-        the slot's index, carried through the chunk (which leaves them as of
-        its last real row, and starts from zeros at position 0) and written
-        back in place."""
-        model, page = self.module, self.pool.page
-        fp = self.pool.slot_pages
-        touched = min(-(-cb // page) + 1, fp)
-
-        def forward(params, cache, pt_row, chunk, start, valid_len, slot):
-            own = lambda v: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-            sub = {"latent": _slot_view(cache["latent"], pt_row, range(fp)),
-                   "state": own(cache["state"]), "tail": own(cache["tail"])}
-            logits, sub = forward_with_cache(model, params, chunk, sub, start,
-                                             valid_len=valid_len)
-            first = jnp.minimum(start // page, fp - 1)
-            put = lambda k: jax.lax.dynamic_update_slice_in_dim(
-                cache[k], sub[k], slot, axis=1)
-            return logits, {
-                "latent": _slot_write_back(
-                    cache["latent"], sub["latent"], pt_row, 0,
-                    [jnp.minimum(first + i, fp - 1) for i in range(touched)]),
-                "state": put("state"), "tail": put("tail")}
-
-        return forward
 
     # ------------------------------------------------------------------
     def _decode_block(self) -> None:
@@ -2370,33 +1975,31 @@ class ServingEngine:
         values is covered by the block."""
         t0 = time.perf_counter()
         running = self.scheduler.running()
-        if self.paged:
-            for req in running:
-                if req.state != RUNNING:     # preempted by an earlier ensure
-                    continue
-                b = req.slot
-                n = int(min(self._K, self._limit[b] - self._pos[b]))
-                if n > 0:
-                    # the block writes rows [pos, pos+n); EOS rows may stop
-                    # early on device — the host view only over-allocates.
-                    # A False return = req itself was the youngest and
-                    # self-preempted; the filter below drops it.
-                    self._ensure_pages(req, int(self._pos[b]) + n)
-            # a preemption above may have demoted someone mid-list
-            running = [r for r in running if r.state == RUNNING]
-            if not self._active.any():
-                self._settle_first_tokens()
-                return
+        for req in running:
+            if req.state != RUNNING:     # preempted by an earlier ensure
+                continue
+            b = req.slot
+            n = int(min(self._K, self._limit[b] - self._pos[b]))
+            if n > 0:
+                # the block writes rows [pos, pos+n); EOS rows may stop
+                # early on device — the host view only over-allocates.
+                # A False return = req itself was the youngest and
+                # self-preempted; the filter below drops it.
+                self._ensure_pages(req, int(self._pos[b]) + n)
+        # a preemption above may have demoted someone mid-list
+        running = [r for r in running if r.state == RUNNING]
+        if not self._active.any():
+            self._settle_first_tokens()
+            return
         self._launch_seq += 1
         seq = self._launch_seq
         with self._phase("ds_serve_decode_dispatch", seq=seq):
-            args = [self._loop_params(), self._cache, self._last_dev,
-                    self._pos_dev, self._act_dev, jnp.asarray(self._limit),
-                    jnp.asarray(self._eos), self._rng]
-            if self.paged:
-                args.append(jnp.asarray(self.pool.page_table))
             (toks, valid, self._last_dev, self._pos_dev, self._act_dev,
-             self._cache, self._rng, moe) = self._block()(*args)
+             self._cache, self._rng, moe) = self._block()(
+                self._loop_params(), self._cache, self._last_dev,
+                self._pos_dev, self._act_dev, jnp.asarray(self._limit),
+                jnp.asarray(self._eos), self._rng,
+                jnp.asarray(self.pool.page_table))
         t1 = time.perf_counter()
         idx = self._next_block
         self._next_block += 1
@@ -2406,10 +2009,7 @@ class ServingEngine:
         for req in running:
             b = req.slot
             n = int(min(self._K, self._limit[b] - self._pos[b]))
-            if self._eva and self._registry.enabled:
-                self._count_eva(int(self._pos[b]), n)
-            if self._afmoe and self._registry.enabled:
-                self._count_attended(int(self._pos[b]), n)
+            self.kind.count_rows(int(self._pos[b]), n)
             self._pos[b] += n
             # one span per participating row: the block's host dispatch
             # window with this request's scheduled token count
@@ -2465,72 +2065,9 @@ class ServingEngine:
                 moe = self._block_moe.pop(idx, None)
                 if moe is not None:
                     moe = [np.asarray(a) for a in moe]  # dslint: disable=DSL002 -- rides the block's own deferred fetch, registry on only
-                    if self._state:
-                        self._count_state_steps(moe.pop())
-                    self._count_moe(*moe)
+                    self._count_moe(*self.kind.count_block(moe))
             entry = self._block_np[idx] = (toks, valid)
         return entry
-
-    def _count_eva(self, pos: int, n: int) -> None:
-        """A row's ``n`` decode steps from position ``pos`` into
-        ``ds_serve_eva_*``: the rows each step attends (``pos % W + 1``
-        window rows and the ``W/C`` summaries of each closed window) and the
-        windows the steps close.  Host arithmetic on positions the engine
-        holds anyway; an EOS row that stops early is counted to its bound."""
-        cfg = self.module.config
-        W, per = cfg.eva_window, cfg.eva_window // cfg.eva_chunk
-        p = np.arange(pos, pos + n)
-        m = self._m_eva
-        m["ds_serve_eva_window_rows_total"].inc(int((p % W + 1).sum()))
-        m["ds_serve_eva_summary_rows_total"].inc(int((p // W).sum()) * per)
-        m["ds_serve_eva_window_closes_total"].inc(int(((p + 1) % W == 0).sum()))
-
-    def _count_eva_chunk(self, off: int, c: int, cb: int) -> None:
-        """A prefill chunk of ``c`` real tokens at position ``off`` in a
-        bucket of ``cb`` into ``ds_serve_eva_prefill_scores*``: what the
-        masks keep of its scores and what its program's attention computes,
-        from the schedule the kernel takes its bounds from."""
-        cfg = self.module.config
-        sch = eva_chunk_schedule(
-            off, cb, real=c, window=cfg.eva_window, chunk=cfg.eva_chunk,
-            rows=self.pool.slot_pages * self.pool.page, head_dim=cfg.head_dim,
-            itemsize=self._cache["k"].dtype.itemsize)
-        m = self._m_eva
-        m["ds_serve_eva_prefill_scores_total"].inc(sch["kept"])
-        m["ds_serve_eva_prefill_scores_visited_total"].inc(sch["visited"])
-
-    def _count_attended(self, pos: int, n: int) -> None:
-        """A row's ``n`` decode steps from position ``pos`` into
-        ``ds_serve_attn_*_rows_total``: the K/V rows each step attends in one
-        sliding layer (``min(p + 1, W)``) and in one global layer (``p +
-        1``).  Host arithmetic on positions the engine holds anyway."""
-        p = np.arange(pos, pos + n) + 1
-        W = self.module.config.sliding_window
-        self._m_win["ds_serve_attn_window_rows_total"].inc(
-            int(np.minimum(p, W).sum()))
-        self._m_win["ds_serve_attn_full_rows_total"].inc(int(p.sum()))
-
-    def _count_page_steps(self) -> None:
-        """This iteration's pages into ``ds_serve_kv_page_steps_*``: what the
-        two budgets hold, each page weighted by the layers of its kind, and
-        what ONE budget would hold for the same slots (their full pages, in
-        every layer)."""
-        from deepspeed_tpu.models.afmoe import kind_layers
-
-        n_win, n_full = (len(k) for k in kind_layers(self.module.config))
-        held = self.pool.pages_used_by_kind()
-        self._m_win["ds_serve_kv_page_steps_total"].inc(
-            held["window"] * n_win + held["full"] * n_full)
-        self._m_win["ds_serve_kv_page_steps_one_budget_total"].inc(
-            held["full"] * (n_win + n_full))
-
-    def _count_state_steps(self, steps) -> None:
-        """One decode block's (row, linear layer) pairs, live and visited by
-        the state kernel (models/kda_mla.py: the fifth of its counts), into
-        ``ds_serve_state_row_steps_*``."""
-        self._m_state["ds_serve_state_row_steps_total"].inc(int(steps[0]))
-        self._m_state["ds_serve_state_row_steps_visited_total"].inc(
-            int(steps[1]))
 
     def _count_moe(self, per_expert, hits, max_load, offered=None) -> None:
         """One decode block's routing (``decode_step``'s ``moe_live``
@@ -2593,30 +2130,16 @@ class ServingEngine:
         """Finish the request, park its slot at depth 0 (the parked row's
         junk writes land on row 0 / the junk page, overwritten or never
         read before any query can see them, and the slot's stale depth no
-        longer inflates the flash-decode loop bound), and — paged — return
-        its pages to the pool."""
+        longer inflates the flash-decode loop bound), and return its pages
+        to the pool."""
         b = req.slot
         self._active[b] = False
         self._pos[b] = 0
         self._pos_dev, self._act_dev = self._park_fn(
             self._pos_dev, self._act_dev, jnp.asarray(b, jnp.int32))
-        if self.paged:
-            if self.prefix_cache is not None:
-                # insert the request's FULL prompt pages (the pages whose
-                # every row holds a prompt token — the boundary page mixes
-                # in generated tokens and is not cacheable) before release
-                # decrefs them; newly-inserted pages are pinned and
-                # survive, already-cached chunks keep their existing page.
-                # Bounded by the prefill frontier: an ABORTED mid-prefill
-                # request must not cache pages it never computed (every
-                # natural finish path has the whole prompt resident)
-                resident = min(req.prefill_pos, req.prompt_len)
-                full = resident // self.pool.page
-                if full:
-                    self.prefix_cache.insert(
-                        req.prompt, self.pool.owned(b)[:full])
-            self.pool.release(b)
-            self._page_gauges()
+        self._cache_prompt_pages(req)
+        self.pool.release(b)
+        self._page_gauges()
         req.finish_reason = reason
         n = len(req.output_tokens)
         # a pace only where tokens reached the host as they were made: a
@@ -2627,6 +2150,20 @@ class ServingEngine:
             self._m_tpot.record((time.perf_counter() - req.t_first_token)
                                 / (n - 1))
         self.scheduler.finish(req)
+
+    def _cache_prompt_pages(self, req: Request) -> None:
+        """Before a release decrefs them, the request's FULL prompt pages
+        (every row a prompt token: the boundary page mixes in generated
+        tokens and is not cacheable) go into the prefix cache; new pages are
+        pinned and survive, already-cached chunks keep their existing page.
+        Bounded by the prefill frontier: an ABORTED or preempted mid-prefill
+        request must not cache pages it never computed."""
+        if self.prefix_cache is None:
+            return
+        full = min(req.prefill_pos, req.prompt_len) // self.pool.page
+        if full:
+            self.prefix_cache.insert(req.prompt,
+                                     self.pool.owned(req.slot)[:full])
 
     def _materialize(self, req: Request) -> None:
         """Fetch this request's deferred tokens (the prefill-sampled first
@@ -2656,7 +2193,7 @@ class ServingEngine:
     # ------------------------------------------------------------------
     def _step_fn(self):
         """One decode micro-step at per-row positions: (params, tokens
-        [B, 1], cache, pos [B], page_table|None, live [B]) -> (logits
+        [B, 1], cache, pos [B], page_table, live [B]) -> (logits
         [B, V], cache, routing counts of the live rows | None: a
         mixture-of-experts model on the fused path, ``decode_step``, whose
         attention kernels also visit the live rows only)."""
@@ -2718,13 +2255,8 @@ class ServingEngine:
                 sub, (cache, last, pos, active, rng, moe0), None, length=K)
             return toks, valid, last, pos, act, cache, rng, moe
 
-        if self.paged:
-            block = jax.jit(body, donate_argnums=(1, 2, 3, 4))
-        else:
-            block = jax.jit(functools.partial(body, page_table=None),
-                            donate_argnums=(1, 2, 3, 4))
-        self._block_fn = block
-        return block
+        self._block_fn = jax.jit(body, donate_argnums=(1, 2, 3, 4))
+        return self._block_fn
 
     # ------------------------------------------------------------------
     def close(self) -> None:

@@ -1,13 +1,11 @@
 """Paged KV cache: a block allocator over one shared pool of token pages.
 
-The fixed-slot serving cache (PR 1) reserves ``max_out_tokens`` of KV per
-slot, so a slot holding a 30-token chat reply pins the same HBM as one
-decoding 2k tokens — with bimodal chat-like lengths most of the
-reservation is dead weight and the slot count (goodput) is bounded by the
-worst-case request.  This module is the vLLM/PagedAttention answer mapped
-onto the existing flash-decode stack, and the serving-time counterpart of
-the ZeRO-Infinity argument (arXiv:2104.07857): treat KV memory as a
-managed pool, not a static reservation.
+A cache that reserves ``max_out_tokens`` of KV per slot pins the same HBM
+for a 30-token chat reply as for a 2k-token one, and bounds the slot count
+by the worst-case request.  This module is the vLLM/PagedAttention answer
+mapped onto the flash-decode stack, and the serving-time counterpart of the
+ZeRO-Infinity argument (arXiv:2104.07857): treat KV memory as a managed
+pool, not a static reservation.
 
 Layout: the physical cache is ``[L, num_pages, Hkv, page_tokens, Dh]``
 (one pool shared by every slot) and each slot owns an ordered list of
@@ -19,7 +17,9 @@ state, shipped into every compiled program; reads indirect through it
 block; the XLA fallback gathers a logical view) and per-row appends
 scatter through it.
 
-What a page holds depends on the model's attention form.  Under
+What a page holds depends on the model's attention form
+(``serving/cache_kind.py`` decides, and owns the device arrays of the forms
+below; this module counts pages).  Under
 ``attention="full"`` (every model but one) a page holds the K and V rows of
 ``page_tokens`` consecutive positions, for ever: a slot's pages grow by one
 every ``page_tokens`` tokens, and prefix caching, the prefill->decode
@@ -49,7 +49,7 @@ its meaning, rows of the pool: pages x ``page_tokens``.
 two kinds, ``models/afmoe.py``): the SLIDING layers keep only the last
 ``W = ring_tokens`` positions and the GLOBAL layers keep all of them, so
 one page id cannot mean "a page in every layer".  The pool then holds two
-budgets, each with its own device arrays (``init_paged_kv_cache``:
+budgets, each with its own device arrays (``cache_kind.TwoBudgets``:
 ``k_win`` / ``v_win`` ``[sliding layers, window pages, ...]``, ``k_full`` /
 ``v_full`` ``[global layers, full pages, ...]``), its own free list, its own
 refcounts and its own junk page 0, and a slot's table has two column ranges:
@@ -79,8 +79,8 @@ would hold ``3 W / page`` in every layer.
 **Latent pages and a slot-state budget that is not pages** (a model of
 latent-attention and linear-attention layers, ``models/kda_mla.py``;
 ``slot_state_bytes`` below).  A latent layer's page holds ONE row a position
-that all heads share (``init_state_cache``: ``latent`` ``[latent layers,
-pages, 1, page, row width]``, no V array: keys and values are read from the
+that all heads share (``cache_kind.LatentPagesAndState``: ``latent`` ``[latent
+layers, pages, 1, page, row width]``, no V array: keys and values are read from the
 same row); it is position-pure and allocated like a full-attention page, one
 budget, one table column a page.  A linear layer keeps no rows at all but a
 recurrent STATE of fixed size a slot (``state`` ``[linear layers, slots,
@@ -145,25 +145,13 @@ def default_page_tokens(max_out_tokens: int) -> int:
 
 def init_paged_kv_cache(cfg, num_pages: int, page_tokens: int,
                         dtype=jnp.bfloat16,
-                        quantized: bool = False,
-                        num_window_pages: int = 0) -> Dict[str, Any]:
+                        quantized: bool = False) -> Dict[str, Any]:
     """Device arrays for the shared page pool — the paged analog of
     :func:`~deepspeed_tpu.models.decoding.init_kv_cache`, with the slot
-    dim replaced by the page dim and the sequence dim by the page depth.
-    ``num_window_pages`` (two budgets, module docstring): ``k_win`` /
-    ``v_win`` of that many pages for the sliding layers beside ``k_full`` /
-    ``v_full`` of ``num_pages`` for the global ones."""
+    dim replaced by the page dim and the sequence dim by the page depth:
+    one budget of per-head K and V pages in every layer.  The arrays of the
+    other kinds of cache are their kinds' (``serving/cache_kind.py``)."""
     L, Hkv, Dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
-    if num_window_pages:
-        from deepspeed_tpu.models.afmoe import kind_layers
-
-        ls, lf = kind_layers(cfg)
-        z = lambda n, pages: jnp.zeros((n, pages, Hkv, page_tokens, Dh),
-                                       dtype)
-        return {"k_win": z(len(ls), num_window_pages),
-                "v_win": z(len(ls), num_window_pages),
-                "k_full": z(len(lf), num_pages),
-                "v_full": z(len(lf), num_pages)}
     if quantized:
         return {
             "k": jnp.zeros((L, num_pages, Hkv, page_tokens, Dh), jnp.int8),
@@ -180,21 +168,6 @@ def init_paged_kv_cache(cfg, num_pages: int, page_tokens: int,
     }
 
 
-def init_state_cache(cfg, num_pages: int, page_tokens: int, num_slots: int,
-                     dtype=jnp.bfloat16) -> Dict[str, Any]:
-    """Device arrays of a model of latent-attention and linear-attention
-    layers (module docstring): ``latent`` pages, and the per-slot ``state``
-    (float32 whatever ``dtype``) and ``tail``."""
-    from deepspeed_tpu.models.kda_mla import (kind_layers, row_width,
-                                              state_shapes)
-
-    state, tail = state_shapes(cfg, num_slots)
-    return {"latent": jnp.zeros((len(kind_layers(cfg)[1]), num_pages, 1,
-                                 page_tokens, row_width(cfg)), dtype),
-            "state": jnp.zeros(state, jnp.float32),
-            "tail": jnp.zeros(tail, dtype)}
-
-
 class PagedKVPool:
     """Host-side free-list allocator for the page pool.
 
@@ -203,15 +176,14 @@ class PagedKVPool:
     num_slots:
         Slots (page-table rows) sharing the pool.
     max_out_tokens:
-        Per-slot LOGICAL budget (prompt + generation), same meaning as the
-        fixed-slot cache; rounded up to a page multiple for the physical
-        table depth (``cache_len``).
+        Per-slot LOGICAL budget (prompt + generation), rounded up to a
+        page multiple for the physical table depth (``cache_len``).
     page_tokens:
         Tokens per page (0 = :func:`default_page_tokens`).
     pool_tokens:
-        Total pool capacity in tokens (0 = ``num_slots * cache_len`` — the
-        same HBM as the fixed layout, but allocated on demand).  Setting
-        it lower oversubscribes slots against a fixed HBM budget; the pool
+        Total pool capacity in tokens (0 = ``num_slots * cache_len``: a
+        full budget for every slot, allocated on demand).  Setting it
+        lower oversubscribes slots against a fixed HBM budget; the pool
         always holds at least one slot's full budget so a lone request can
         never deadlock.
     window_tokens, chunk_tokens:
@@ -471,8 +443,7 @@ class PagedKVPool:
 
     def utilization(self, live_tokens: int) -> float:
         """live-tokens / allocated-page-tokens (1.0 = every allocated page
-        row holds a live token; the fixed-slot layout's equivalent is
-        live / (num_slots * cache_len)).  With prefix sharing the ratio
+        row holds a live token).  With prefix sharing the ratio
         can exceed 1 — several slots' live tokens backed by one physical
         page is precisely the memory the cache saves."""
         alloc = self.pages_used * self.page

@@ -1,0 +1,264 @@
+"""The contract of ``serving/cache_kind.py``, once over the four kinds of
+slot cache at the tiny sizes their model tests build: full pages (a tiny
+Llama), window + summary pages (``test_evabyte``), two page budgets
+(``test_trinity``), latent pages + slot state (``test_kimi_linear``).
+
+What every kind owes the engine: a slot's view written back unchanged leaves
+the pool as it was, and a changed one touches nobody else's pages; an
+aborted request returns every page; a reused slot serves what a fresh engine
+serves; what a kind cannot be served with is refused by its reason, and the
+position-pure kind refuses nothing."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import deepspeed_tpu
+from deepspeed_tpu.comm.mesh import build_mesh
+from deepspeed_tpu.models import CausalLM, ModelConfig, causal_lm
+from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages,
+                                              LatentPagesAndState, TwoBudgets,
+                                              WindowSummaryPages, cache_kind)
+from deepspeed_tpu.serving.paged_kv import PagedKVPool
+
+from . import test_evabyte, test_kimi_linear, test_trinity
+
+ENGINE = dict(num_slots=3, prefill_chunk=16, max_prefill_chunks=2,
+              decode_block_tokens=4, max_out_tokens=96, kv_page_tokens=8,
+              dtype="float32")
+# kind -> (its class, its model's fields | None for the tiny Llama, engine)
+CASES = {
+    "full": (FullPages, None, ENGINE),
+    "eva": (WindowSummaryPages, test_evabyte.TINY, test_evabyte.ENGINE),
+    "two_budgets": (TwoBudgets, test_trinity.FIELDS, test_trinity.ENGINE),
+    "state": (LatentPagesAndState, test_kimi_linear.FIELDS,
+              test_kimi_linear.ENGINE),
+}
+NAMES = list(CASES)
+
+
+@pytest.fixture(scope="module")
+def built():
+    """name -> (model, params), built once a module and on demand."""
+    mesh = build_mesh(devices=jax.devices()[:1])
+    made = {}
+
+    def get(name):
+        if name not in made:
+            fields = CASES[name][1]
+            if fields is None:
+                model = causal_lm("llama-tiny", mesh=mesh, num_layers=2,
+                                  hidden_size=64, intermediate_size=128,
+                                  num_heads=4, num_kv_heads=2, vocab_size=96,
+                                  remat=False)
+            else:
+                model = CausalLM(ModelConfig(**fields), mesh)
+            params = model.init(jax.random.PRNGKey(0))
+            noise = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+            made[name] = model, jax.tree.map(      # no gain of exactly 1
+                lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape),
+                params)
+        return made[name]
+
+    return get
+
+
+def serve_of(built, name, **kw):
+    model, params = built(name)
+    role = {k: kw.pop(k) for k in ("role",) if k in kw}
+    return deepspeed_tpu.init_serving(
+        model, config=dict(CASES[name][2], **kw), params=params,
+        mesh=model.mesh, **role)
+
+
+def prompts_of(built, name, lengths, seed):
+    vocab = built(name)[0].config.vocab_size
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, n) for n in lengths]
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_the_chooser_picks_the_kind(built, name):
+    kind = cache_kind(built(name)[0].config)
+    assert type(kind) is CASES[name][0] and type(kind) in KINDS
+    assert bool(kind.cannot) == (name != "full")
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_a_view_written_back_leaves_the_pool_and_a_changed_one_its_neighbours(
+        built, name):
+    """Slot 1's pages lie out of order between two other slots' in a pool of
+    random rows, its unallocated columns all naming junk page 0.  Its view
+    written back as it came leaves every array bit-identical; with every
+    value of the view changed, the page under the chunk's start changes and
+    no page (and no state) of another slot does."""
+    cfg = built(name)[0].config
+    engine = CASES[name][2]
+    kind = cache_kind(cfg)
+    pool = PagedKVPool(3, engine["max_out_tokens"], page_tokens=8,
+                       **kind.pool_args(jnp.float32))
+    for slot, tokens in ((1, 8), (0, 16), (1, 16), (2, 8), (1, 24)):
+        assert pool.ensure(slot, tokens)
+    keys = iter(jax.random.split(jax.random.PRNGKey(2), 8))
+    cache = {k: jax.random.uniform(next(keys), v.shape, jnp.float32, 0.5, 1.5)
+             for k, v in kind.init_cache(pool, 3, jnp.float32, False).items()}
+    row, slot, start, cb = jnp.asarray(pool.page_table[1]), 1, 8, 16
+
+    @jax.jit
+    def there_and_back(cache, add):
+        sub = kind.view(cache, row, slot, start, cb)
+        sub = {k: v + add for k, v in sub.items()}
+        return kind.write_back(cache, sub, row, slot, start, cb)
+
+    same = there_and_back(cache, 0.0)
+    assert set(same) == set(cache)
+    for k in cache:
+        np.testing.assert_array_equal(np.asarray(same[k]),
+                                      np.asarray(cache[k]), err_msg=k)
+    changed = there_and_back(cache, 1.0)
+    for k, before in cache.items():
+        before, after = np.asarray(before), np.asarray(changed[k])
+        if k in ("state", "tail"):              # [layers, slots, ...]
+            mine = [slot]
+        elif k.endswith("_win"):
+            mine = pool._owned_win[slot]
+        else:
+            mine = pool._owned[slot]
+        others = [i for i in range(before.shape[1]) if i not in mine
+                  and (i != 0 or k in ("state", "tail"))]
+        np.testing.assert_array_equal(after[:, others], before[:, others],
+                                      err_msg=k)
+        # the slot's own state; a ring page; the page of position ``start``
+        under_start = (slot if k in ("state", "tail") else
+                       mine[0] if k.endswith("_win") else mine[start // 8])
+        assert (after[:, under_start] == before[:, under_start] + 1.0).all()
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_an_aborted_request_returns_every_page(built, name):
+    """A request aborted mid-flight, after chunks and decode blocks have
+    given it pages of every kind the slot holds, returns them all."""
+    serve = serve_of(built, name)
+    long, short = prompts_of(built, name, (70 if name == "eva" else 40, 12),
+                             seed=5)
+    req = serve.submit(long, max_new_tokens=50)
+    other = serve.submit(short, max_new_tokens=8)
+    for _ in range(6):
+        serve.step()
+    held = serve.pool.pages_used_by_kind()
+    assert serve.pool.slot_pages_used(req.slot) > 1 and not req.done
+    if name == "eva":
+        assert held["summary"] > 0
+    if name == "two_budgets":
+        assert held["window"] > 0 and held["full"] > 0
+    serve.abort(req)
+    serve.run()
+    assert req.done and req.finish_reason == "cancelled"
+    assert other.done and len(other.output_tokens) == 8
+    serve.pool.check_no_leak()
+    assert serve.pool.pages_used == 0
+    serve.close()
+
+
+@pytest.mark.parametrize("name", NAMES)
+def test_a_reused_slot_serves_what_a_fresh_engine_serves(built, name):
+    """Five requests over three slots: the fourth and fifth take slots a
+    finished request left its rows (and its state) in, and are served what
+    an engine of their own serves them."""
+    prompts = prompts_of(built, name, (20, 33, 9, 25, 18), seed=2)
+    serve = serve_of(built, name)
+    reqs = [serve.submit(p, max_new_tokens=12) for p in prompts]
+    serve.run()
+    serve.pool.check_no_leak()
+    serve.close()
+    for p, r in zip(prompts[3:], reqs[3:]):
+        alone = serve_of(built, name)
+        want = alone.submit(p, max_new_tokens=12)
+        alone.run()
+        assert list(r.output_tokens) == list(want.output_tokens)
+        alone.close()
+
+
+def test_two_budgets_preempt_and_resume_are_token_identical(built):
+    """A full budget of 13 pages for three slots: the youngest is preempted,
+    gives back its ring and its full pages, re-prefills prompt + outputs
+    through both budgets, and every request still gets the tokens an
+    unpressed engine gives it."""
+    prompts = prompts_of(built, "two_budgets", (30, 41, 22), seed=3)
+    news = (40, 30, 50)
+    easy = serve_of(built, "two_budgets")
+    tight = serve_of(built, "two_budgets", kv_pool_tokens=104)
+    want = [easy.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    got = [tight.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    easy.run()
+    tight.run()
+    tight.pool.check_no_leak()
+    assert tight.pool.pages_used == 0
+    assert sum(r.preemptions for r in got) > 0
+    for w, g in zip(want, got):
+        assert list(g.output_tokens) == list(w.output_tokens)
+    easy.close()
+    tight.close()
+
+
+# -- the refusal table --------------------------------------------------------
+# option of ``cannot`` -> how an engine is asked for it.  The per-model tests
+# hold the rows their model lists, by the substring each names; here the rows
+# they leave out, and the kind that lists none.
+ASKED = {
+    "handoff": dict(role="decode"),
+    "kv_host_tier_pages": dict(kv_host_tier_pages=4),
+    "quantize_kv_cache": dict(quantize_kv_cache=True),
+    "use_fused_decode": dict(use_fused_decode=False),
+}
+
+
+@pytest.mark.parametrize("name", NAMES[1:])
+def test_prefill_only_is_refused_with_the_kinds_reason(built, name):
+    serve = serve_of(built, name)
+    kind = serve.kind
+    with pytest.raises(NotImplementedError) as err:
+        serve.submit([1, 2, 3], prefill_only=True)
+    assert str(err.value) == (f"prefill_only with {kind.what}: "
+                              f"{kind.cannot['handoff']}")
+    assert "handoff.py" in str(err.value)
+    serve.close()
+
+
+@pytest.mark.parametrize("name", ["two_budgets", "state"])
+def test_the_decode_role_is_refused_with_the_kinds_reason(built, name):
+    with pytest.raises(NotImplementedError) as err:
+        serve_of(built, name, **ASKED["handoff"])
+    kind = cache_kind(built(name)[0].config)
+    assert str(err.value) == f"role='decode' with {kind.what}: " \
+                             f"{kind.cannot['handoff']}"
+
+
+@pytest.mark.parametrize("option", [*ASKED, "prefill_only"])
+def test_full_pages_refuse_nothing(built, option):
+    """Every option another kind's table lists builds (and ``prefill_only``
+    submits) on position-pure pages; prefix caching stays on."""
+    serve = serve_of(built, "full", **ASKED.get(option, {}))
+    assert serve.kind.cannot == {} and serve.prefix_cache is not None
+    assert (serve.host_store is not None) == (option == "kv_host_tier_pages")
+    if option == "prefill_only":
+        req = serve.submit(np.arange(20), prefill_only=True)
+        serve.run()
+        assert req.finish_reason == "prefill_done"
+        assert len(req.handoff) == 20 // serve.pool.page
+    serve.close()
+
+
+@pytest.mark.parametrize("value", [False, True])
+def test_the_removed_paged_kv_cache_option(built, value):
+    """``paged_kv_cache`` is no field any more, so the config would let it
+    pass as an unknown key: ``False`` is refused by name (it would be served
+    from the pool unasked), ``True`` asks for what every engine does."""
+    if value:
+        serve = serve_of(built, "full", paged_kv_cache=True)
+        assert serve.pool is not None
+        serve.close()
+    else:
+        with pytest.raises(ValueError, match="paged_kv_cache=False.*removed"):
+            serve_of(built, "full", paged_kv_cache=False)

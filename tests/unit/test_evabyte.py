@@ -592,24 +592,6 @@ def test_preempted_request_resumes_to_the_same_tokens(model, params):
     tight.close()
 
 
-def test_aborted_request_returns_its_pages(model, params):
-    """A request that fails mid-flight (aborted after its window pages and a
-    summary page are held) returns both kinds."""
-    serve = _serve(model, params)
-    prompts, _ = _prompts()
-    req = serve.submit(prompts[1], max_new_tokens=60)
-    other = serve.submit(prompts[0], max_new_tokens=8)
-    for _ in range(6):
-        serve.step()
-    assert serve.pool.pages_used_by_kind()["summary"] > 0
-    serve.abort(req)
-    serve.run()
-    assert req.done and other.done and len(other.output_tokens) == 8
-    serve.pool.check_no_leak()
-    assert serve.pool.pages_used == 0
-    serve.close()
-
-
 def test_eva_counters_count_attended_rows_and_closes(model, params):
     from deepspeed_tpu.monitor.metrics import MetricsRegistry
 
@@ -726,7 +708,6 @@ def test_config_checks():
     (dict(role="decode"), "handoff.py"),
     (dict(kv_host_tier_pages=4), "host_tier.py"),
     (dict(quantize_kv_cache=True), "decoding.py"),
-    (dict(paged_kv_cache=False), "paged_kv.py"),
     (dict(prefill_chunk=24), "prefill_chunk"),
     (dict(prefill_chunk=64), "prefill_chunk"),
 ])

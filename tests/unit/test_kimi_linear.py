@@ -142,25 +142,6 @@ def test_chunks_of_one_iteration_hand_the_state_on(ref, model, places):
     serve.close()
 
 
-def test_a_reused_slot_serves_what_a_fresh_engine_serves(model):
-    """Five requests over three slots: the fourth and fifth take slots whose
-    state a finished request left behind, and are served what an engine of
-    their own serves them (the state is zeroed at admission)."""
-    rng = np.random.default_rng(2)
-    prompts = [rng.integers(0, 96, n) for n in (20, 33, 9, 25, 18)]
-    serve = serve_of(model)
-    reqs = [serve.submit(p, max_new_tokens=12) for p in prompts]
-    serve.run()
-    serve.pool.check_no_leak()
-    serve.close()
-    for p, r in zip(prompts[3:], reqs[3:]):
-        alone = serve_of(model)
-        want = alone.submit(p, max_new_tokens=12)
-        alone.run()
-        assert list(r.output_tokens) == list(want.output_tokens)
-        alone.close()
-
-
 def test_preempt_and_resume_are_token_identical(model):
     """A pool of nine pages for three slots: the youngest is preempted,
     re-prefills prompt + outputs onto a zeroed state, and every request
@@ -393,7 +374,6 @@ def test_parked_rows_keep_their_state_across_a_decode_block(model):
 
 # ------------------------------------------------------------ the refusals
 @pytest.mark.parametrize("kw,match", [
-    (dict(config=dict(paged_kv_cache=False)), "init_kv_cache"),
     (dict(config={}, role="prefill"), "handoff"),
     (dict(config=dict(kv_host_tier_pages=4)), "host_tier"),
     (dict(config=dict(quantize_kv_cache=True)), "models/decoding.py"),

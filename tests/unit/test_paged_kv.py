@@ -1,7 +1,7 @@
 """Paged KV cache (serving/paged_kv.py + the paged serving engine):
 allocator unit behavior (alloc/free/LIFO reuse, exhaustion, leak probe),
 pool-pressure preempt-and-resume staying token-identical to sequential
-``generate()``, the fixed-slot fallback layout, and the sync-free EOS
+``generate()``, the chunk program's view of a slot, and the sync-free EOS
 decode (finish events drained one block BEHIND dispatch — no per-step
 host-device sync).  Engines are module-scoped where possible: compiles
 dominate tier-1 wall time."""
@@ -288,24 +288,23 @@ def test_int8_kv_paged_parity(setup, rng):
 @pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8kv"])
 def test_chunk_program_through_a_scattered_table_row(setup, rng, quantized):
     """ISSUE 37: the chunk program builds a slot's view from its pages by
-    slices (``_slot_view`` / ``_slot_write_back``).  Through a row whose
-    pages lie out of order in a pool full of other slots' rows, with its
-    unallocated tail all naming junk page 0, a 16-token chunk across a page
-    boundary and then a 5-token last chunk give the tokens and the K / V
-    rows (int8 KV: the scales too) that the fixed-slot layout gives at the
-    same positions, and no page of another slot changes by a bit."""
+    slices (``cache_kind._slot_view`` / ``_slot_write_back``).  Through a
+    row whose pages lie out of order in a pool full of other slots' rows,
+    with its unallocated tail all naming junk page 0, a 16-token chunk across
+    a page boundary and then a 5-token last chunk give the tokens and the K /
+    V rows (int8 KV: the scales too) that ``forward_with_cache`` gives on a
+    contiguous ``init_kv_cache`` cache at the same positions, and no page of
+    another slot changes by a bit."""
+    from deepspeed_tpu.models.decoding import (forward_with_cache,
+                                               init_kv_cache,
+                                               next_token_logits)
+
     model, params, _ = setup
-    cfg = {"dtype": "bfloat16", "max_out_tokens": 64, "kv_page_tokens": 8,
-           "quantize_kv_cache": quantized}
-
-    def engine(**over):
-        s = deepspeed_tpu.init_serving(model, config={**cfg, **over},
-                                       num_slots=3, prefill_chunk=16,
-                                       decode_block_tokens=3)
-        s.set_params(params)
-        return s
-
-    paged, fixed = engine(), engine(paged_kv_cache=False)
+    paged = deepspeed_tpu.init_serving(
+        model, config={"dtype": "bfloat16", "max_out_tokens": 64,
+                       "kv_page_tokens": 8, "quantize_kv_cache": quantized},
+        num_slots=3, prefill_chunk=16, decode_block_tokens=3)
+    paged.set_params(params)
     slot, row = 1, np.array([5, 2, 7, 0, 0, 0, 0, 0], np.int32)
     assert paged.pool.slot_pages == len(row) and paged.pool.page == 8
     keys = iter(jax.random.split(rng, 8))
@@ -322,26 +321,33 @@ def test_chunk_program_through_a_scattered_table_row(setup, rng, quantized):
     before = {k: np.asarray(v) for k, v in pool.items() if v.ndim == 5}
     prompt = np.asarray(jax.random.randint(next(keys), (21,), 0, 256),
                         np.int32)
+    chunks = []
+    for start, cb, c in ((0, 16, 16), (16, 8, 5)):
+        chunk = np.zeros((1, cb), np.int32)
+        chunk[0, :c] = prompt[start:start + c]
+        chunks.append((start, c, jnp.asarray(chunk)))
 
-    def run(serve, cache, pt_row):
-        toks = []
-        carries = (serve._last_dev, serve._pos_dev, serve._act_dev)
-        for start, cb, c in ((0, 16, 16), (16, 8, 5)):
-            chunk = np.zeros((1, cb), np.int32)
-            chunk[0, :c] = prompt[start:start + c]
-            meta = jnp.asarray([slot, start, c - 1, 0, -1], jnp.int32)
-            tok, cache, carries = serve._prefill_fn(cb)(
-                serve.engine._params, cache, carries, pt_row,
-                jnp.asarray(chunk), meta, jax.random.PRNGKey(0))
-            toks.append(int(tok))
-            assert int(carries[0][slot]) == toks[-1]
-            assert int(carries[1][slot]) == start + c
-        return toks, {k: np.asarray(v) for k, v in cache.items()
-                      if v.ndim == 5}
+    toks_p, cache = [], pool
+    carries = (paged._last_dev, paged._pos_dev, paged._act_dev)
+    for start, c, chunk in chunks:
+        meta = jnp.asarray([slot, start, c - 1, 0, -1], jnp.int32)
+        tok, cache, carries = paged._prefill_fn(chunk.shape[1])(
+            paged.engine._params, cache, carries, jnp.asarray(row), chunk,
+            meta, jax.random.PRNGKey(0))
+        toks_p.append(int(tok))
+        assert int(carries[0][slot]) == toks_p[-1]
+        assert int(carries[1][slot]) == start + c
+    after = {k: np.asarray(v) for k, v in cache.items() if v.ndim == 5}
 
-    toks_p, after = run(paged, pool, jnp.asarray(row))
-    toks_f, slots = run(fixed, fixed._cache, None)
-    assert toks_p == toks_f
+    toks_c = []
+    flat = init_kv_cache(model.config, 1, 64, dtype=paged.engine.dtype,
+                         quantized=quantized)
+    step = jax.jit(lambda p, t, c, s: forward_with_cache(model, p, t, c, s))
+    for start, c, chunk in chunks:
+        logits, flat = step(paged.engine._params, chunk, flat, start)
+        toks_c.append(int(jnp.argmax(
+            next_token_logits(model.config, logits[:, c - 1]), -1)[0]))
+    assert toks_p == toks_c
     assert set(after) == ({"k", "v", "k_scale", "v_scale"} if quantized
                           else {"k", "v"})
     theirs = [p for p in range(paged.pool.num_pages)
@@ -351,26 +357,11 @@ def test_chunk_program_through_a_scattered_table_row(setup, rng, quantized):
         mine = pages[:, row[:3]].transpose(0, 2, 1, 3, 4).reshape(
             L, Hkv, 3 * page, D)
         np.testing.assert_array_equal(
-            mine[:, :, :21], slots[name][:, slot, :, :21], err_msg=name)
+            mine[:, :, :21], np.asarray(flat[name])[:, 0, :, :21],
+            err_msg=name)
         np.testing.assert_array_equal(pages[:, theirs],
                                       before[name][:, theirs], err_msg=name)
-
-
-def test_fixed_slot_fallback_parity(setup, rng):
-    """``paged_kv_cache=False`` keeps the PR 1 contiguous per-slot layout
-    working (the reference the paged path is tested against)."""
-    model, params, ref = setup
-    serve = _serve(model, params, paged_kv_cache=False)
-    assert serve.pool is None
-    prompts = [np.asarray(jax.random.randint(k, (n,), 0, 256))
-               for k, n in zip(jax.random.split(rng, 3), (3, 6, 11))]
-    news = [5, 7, 4]
-    want = [_ref_out(ref, p, n) for p, n in zip(prompts, news)]
-    reqs = [serve.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    serve.run()
-    for i, (req, w) in enumerate(zip(reqs, want)):
-        np.testing.assert_array_equal(np.asarray(req.output_tokens), w,
-                                      err_msg=f"fixed-slot request {i}")
+    paged.close()
 
 
 # ---------------------------------------------------------------------------
@@ -387,14 +378,15 @@ OVERLAP_PATHS = ["stream", "eos_later", "eos_first", "max_new_1",
                  "abort_owed"]
 
 
-@pytest.fixture(scope="module", params=["paged", "fixed"])
+@pytest.fixture(scope="module", params=[16, 8], ids=["page16", "page8"])
 def overlap(request, setup):
-    """One greedy and one sampling engine per layout, two slots, ample
-    pool; a reference with room for one token past the serving window."""
+    """One greedy and one sampling engine per page size, two slots, ample
+    pool; a reference with room for one token past the serving window.
+    Pages of 8: the 9-token prompt's last chunk and every request's decode
+    blocks cross a page between a first token and its fetch."""
     model, params, _ = setup
-    over = {} if request.param == "paged" else {"paged_kv_cache": False}
-    cfg = {"dtype": "float32", "max_out_tokens": 64, "kv_page_tokens": 16,
-           **over}
+    cfg = {"dtype": "float32", "max_out_tokens": 64,
+           "kv_page_tokens": request.param}
     engines = {}
     for name, kw in (("greedy", {}),
                      ("sampled", {"do_sample": True, "temperature": 0.8,
@@ -434,9 +426,8 @@ def _serve_from_key(serve, submits, hook=None):
         hook(reqs)
     serve.run()
     assert not serve._owed and not serve._outstanding
-    if serve.pool is not None:
-        assert serve.pool.pages_used == 0
-        serve.pool.check_no_leak()
+    assert serve.pool.pages_used == 0
+    serve.pool.check_no_leak()
     assert all(r.done for r in reqs)
     return [(list(r.output_tokens), r.finish_reason) for r in reqs]
 
@@ -600,11 +591,10 @@ def test_pool_pressure_meets_an_owed_first_token(setup, monkeypatch,
 # on the chip: the served tokens are what they are without the mask.
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("layout", ["paged", "fixed"])
-def test_served_tokens_do_not_depend_on_the_live_mask(setup, rng, layout,
+def test_served_tokens_do_not_depend_on_the_live_mask(setup, rng,
                                                       monkeypatch):
-    """Three slots through the Pallas kernels (interpret mode; pages of 128,
-    the contiguous block of 256): two requests of unequal length, one across
+    """Three slots through the Pallas kernels (interpret mode; pages of
+    128): two requests of unequal length, one across
     a page, a third that arrives once the first has left, so rows park, wake
     and prefill while others decode and one block runs with no live row's
     neighbour.  Served once with the block's mask handed to ``decode_step``
@@ -623,8 +613,7 @@ def test_served_tokens_do_not_depend_on_the_live_mask(setup, rng, layout,
     def served(registry):
         serve = deepspeed_tpu.init_serving(
             model, config={"dtype": "float32", "max_out_tokens": 256,
-                           "kv_page_tokens": 128,
-                           "paged_kv_cache": layout == "paged"},
+                           "kv_page_tokens": 128},
             num_slots=3, prefill_chunk=64, decode_block_tokens=3,
             registry=registry)
         serve.set_params(params)

@@ -133,8 +133,7 @@ def served(devices):
     ref.set_params(params)
     # kv_page_tokens=16 -> 4 pages per 64-token slot window: every e2e
     # test in this module runs the PAGED cache with real multi-page
-    # tables (paged_kv_cache defaults on; page indirection is trivial at
-    # one page per slot)
+    # tables (page indirection is trivial at one page per slot)
     serve = deepspeed_tpu.init_serving(
         model, config={"dtype": "float32", "max_out_tokens": 64,
                        "kv_page_tokens": 16},

@@ -284,7 +284,6 @@ def test_one_budget_pools_are_as_they_were():
 
 # ------------------------------------------------------------ the refusals
 @pytest.mark.parametrize("kw,match", [
-    (dict(config=dict(paged_kv_cache=False)), "paged_kv_cache=False"),
     (dict(config={}, role="prefill"), "handoff"),
     (dict(config=dict(kv_host_tier_pages=4)), "host_tier"),
     (dict(config=dict(quantize_kv_cache=True)), "quantize_kv_cache"),
