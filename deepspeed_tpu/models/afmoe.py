@@ -16,7 +16,9 @@ With ``N(.)`` RMSNorm (own gain, ``norm_eps``) and ``x`` the stream:
     layer l, MLP:  h = N_pre_mlp(x)
         l < num_dense_layers:  m = (silu(h Wgate) * (h Wup)) Wdown
         else: s = sigmoid(h Wr) over the router's experts, float32
-              sel = top-k of (s + b)   (the bias picks, it does not weigh)
+              sel = top-k of (s + b)   (the bias picks, it does not weigh),
+                    inside the moe_topk_group best of moe_n_group groups of
+                    consecutive experts where the model has more than one
               w = s[sel] / (sum s[sel] + 1e-20) * route_scale
               m = shared(h) + sum over e in sel HELD HERE of w_e expert_e(h)
         x = x + N_post_mlp(m)
@@ -228,11 +230,25 @@ def close(cfg, x, y, post_scale):
     return x + y.astype(x.dtype)
 
 
+def kept_groups(cfg, sel):
+    """The group limit on selection scores ``sel`` [N, R] >= 0: a group of
+    ``R / moe_n_group`` consecutive experts scores the sum of its two best,
+    and the ``moe_topk_group`` best groups are kept.  Returns kept [N, G]
+    bool."""
+    N, R = sel.shape
+    G = cfg.moe_n_group
+    score = jax.lax.top_k(sel.reshape(N, G, R // G), 2)[0].sum(-1)
+    _, best = jax.lax.top_k(score, cfg.moe_topk_group)
+    return jnp.zeros((N, G), bool).at[jnp.arange(N)[:, None], best].set(True)
+
+
 def route(cfg, h, gate_w, gate_bias=None):
     """Router of one layer on rows ``h`` [N, D] -> (weight [N, k] float32,
-    idx [N, k] over the ROUTER's experts).  Scores in float32 at full
-    precision; the bias joins the selection only; the kept scores are
-    normalised over the k (``moe_norm_topk_prob``) and scaled."""
+    idx [N, k] over the ROUTER's experts, the groups kept [N, G] bool | None
+    with one group).  Scores in float32 at full precision; the bias joins
+    the selection only; with more than one group the selection is limited
+    to the kept groups (:func:`kept_groups`); the kept scores are normalised
+    over the k (``moe_norm_topk_prob``) and scaled."""
     from deepspeed_tpu.moe import sharded_moe
 
     logits = jnp.dot(h.astype(F32), gate_w.astype(F32),
@@ -240,12 +256,26 @@ def route(cfg, h, gate_w, gate_bias=None):
     s = (jax.nn.sigmoid(logits) if cfg.moe_score_func == "sigmoid"
          else jax.nn.softmax(logits, axis=-1))
     sel = s + gate_bias.astype(F32) if cfg.moe_select_bias else s
+    kept = None
+    if cfg.moe_n_group > 1:
+        kept = kept_groups(cfg, sel)
+        sel = jnp.where(jnp.repeat(kept, sel.shape[1] // cfg.moe_n_group,
+                                   axis=1), sel, 0.0)
     # through the module, so that benchmarks/lib/serve_taps.py sees the choice
     _, idx = sharded_moe.topk_weights(sel, cfg.num_experts_per_tok, False)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if cfg.moe_norm_topk_prob:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
-    return w * cfg.moe_route_scale, idx
+    return w * cfg.moe_route_scale, idx, kept
+
+
+def held_group_kept(cfg, kept):
+    """Rows [N] bool whose kept groups include a group with an expert held
+    here."""
+    size = cfg.moe_router_experts // cfg.moe_n_group
+    first = cfg.moe_first_expert // size
+    last = (cfg.moe_first_expert + cfg.num_experts - 1) // size
+    return kept[:, first:last + 1].any(-1)
 
 
 def held(cfg, weight, idx):
@@ -273,7 +303,7 @@ def mlp(cfg, lp, h, experts=None, layer=None):
         return glu_mlp(h, m)
     B, s, D = h.shape
     ht = h.reshape(B * s, D)
-    weight, idx = route(cfg, ht, m["gate_w"], m.get("gate_bias"))
+    weight, idx, _ = route(cfg, ht, m["gate_w"], m.get("gate_bias"))
     weight, local = held(cfg, weight, idx)
     y, _ = _moe_grouped(experts, ht, None, cfg, False,
                         layer=jnp.asarray(layer, jnp.int32),
@@ -352,6 +382,15 @@ def attend(q, segments, q_pos, *, window: int, scale: float,
     _, l, acc = carry
     o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
     return o.reshape(B, H, s, Dv).astype(q.dtype)
+
+
+def keys_visited(rows: int, live_keys: int) -> int:
+    """Keys of a LAST segment of ``rows`` keys that :func:`attend` visits
+    under ``live_keys``: whole key blocks up to the one that holds key
+    ``live_keys - 1`` (the host's copy of the loop bound above, for the
+    counters of ``serving/cache_kind.py``)."""
+    kb = min(KEY_BLOCK, rows)
+    return min(-(-rows // kb), -(-live_keys // kb)) * kb
 
 
 def _project(cfg, lp, x, cos, sin, sliding):
@@ -544,9 +583,11 @@ def moe_counts_zero(cfg):
     """Zeros of :func:`fused_layers`' routing counts: assignments per HELD
     expert [E], (layer, expert) pairs hit, the fullest expert's rows, and
     the assignments the live rows OFFERED (k a row and expert layer, held
-    here or not)."""
+    here or not); under a group limit a fifth, the (live row, expert layer)
+    pairs whose kept groups include one with an expert held here."""
     z = jnp.zeros((), jnp.int32)
-    return (jnp.zeros((cfg.num_experts,), jnp.int32), z, z, z)
+    return (jnp.zeros((cfg.num_experts,), jnp.int32), z, z, z) \
+        + ((z,) if cfg.moe_n_group > 1 else ())
 
 
 def fused_close(cfg, dparams, lp, l: int, ctx, x, stats, moe_live, impl):
@@ -578,7 +619,7 @@ def fused_close(cfg, dparams, lp, l: int, ctx, x, stats, moe_live, impl):
             sh = lp["shared"]
             base = fused_mlp(h, base, sh["w_up"], sh["w_down"],
                              sh["w_gate"], act=cfg.activation, impl=impl)
-        weight, idx = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
+        weight, idx, kept = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
         weight, local = held(cfg, weight, idx)
         onehot = jax.nn.one_hot(local, cfg.num_experts, dtype=F32)
         combine = jnp.sum(onehot * weight[..., None], axis=1)
@@ -589,11 +630,16 @@ def fused_close(cfg, dparams, lp, l: int, ctx, x, stats, moe_live, impl):
         if stats is not None:
             load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
                            & moe_live[:, None], axis=0, dtype=jnp.int32)
+            rest = stats[4:]
+            if kept is not None:      # the group limit's count leads them
+                rest = (rest[0] + jnp.sum(
+                    held_group_kept(cfg, kept) & moe_live,
+                    dtype=jnp.int32),) + rest[1:]
             stats = (stats[0] + load,
                      stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
                      stats[2] + jnp.max(load),
                      stats[3] + jnp.sum(moe_live, dtype=jnp.int32)
-                     * cfg.num_experts_per_tok) + stats[4:]
+                     * cfg.num_experts_per_tok) + rest
     x = close(cfg, x, y, lp.get("n2_post")) if cfg.sandwich_norm else y
     return x, stats
 

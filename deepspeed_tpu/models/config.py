@@ -42,8 +42,13 @@ families instead; `ModelConfig` spans them with feature flags:
   fed through a short causal convolution of ``kda_conv_kernel`` taps with a
   channel-wise decay and a gated output norm; ``latent_attention``, keys
   and values decompressed from ONE row of ``mla_kv_rank + mla_rot_dim``
-  values a token shared by all heads, no position encoding;
-  ``models/kda_mla.py``; benchmarks/configs/kimi-linear-L5-ep8.json).
+  values a token shared by all heads, the ``mla_rot_dim`` values unrotated
+  (Kimi-Linear: no position encoding) or rotated by position with YaRN
+  frequencies (``mla_rope``), the query full-rank or through a normed
+  low-rank bottleneck (``mla_q_rank``); a model may be latent layers only
+  (A.X-K1, whose router also keeps ``moe_topk_group`` of ``moe_n_group``
+  groups of experts before its top-k); ``models/kda_mla.py``;
+  benchmarks/configs/kimi-linear-L5-ep8.json, axk1-L5-ep16.json).
   SERVED ONLY, one chip's share, seeded weights
 
 All presets follow the public architecture descriptions of those model
@@ -122,6 +127,12 @@ class ModelConfig:
     # ``moe_router_experts`` (0 = all of them are held)
     moe_router_experts: int = 0
     moe_first_expert: int = 0
+    # group-limited selection: the router's experts lie in ``moe_n_group``
+    # groups of consecutive experts, a group scores the sum of its two best
+    # selection scores, and the top-k is taken inside the ``moe_topk_group``
+    # best groups (1 group = the plain top-k)
+    moe_n_group: int = 1
+    moe_topk_group: int = 1
     # -- ``layer_types`` kinds "linear_attention" and "latent_attention"
     # (models/kda_mla.py).  Linear: heads, the size of a head's key and
     # value (its state is [size, size] float32), taps of the short causal
@@ -132,13 +143,24 @@ class ModelConfig:
     kda_conv_kernel: int = 0
     kda_gate_rank: int = 0
     # latent: the cache row is ``mla_kv_rank`` normed values plus
-    # ``mla_rot_dim`` shared key values (used UNROTATED: no position
-    # encoding); a query head is ``mla_nope_dim + mla_rot_dim`` wide, a
-    # value head ``mla_v_dim``; ``num_heads`` query heads
+    # ``mla_rot_dim`` shared key values; a query head is ``mla_nope_dim +
+    # mla_rot_dim`` wide, a value head ``mla_v_dim``; ``num_heads`` query
+    # heads
     mla_kv_rank: int = 0
     mla_nope_dim: int = 0
     mla_rot_dim: int = 0
     mla_v_dim: int = 0
+    # the query through a bottleneck of this rank with a norm of its own,
+    # ``N_q(h W_qa) W_qb`` (0: one full-rank ``W_q``)
+    mla_q_rank: int = 0
+    # the position encoding of the ``mla_rot_dim`` values of the query heads
+    # and of the shared key.  None: they are used UNROTATED (no position
+    # encoding at all).  A group ``{"theta", "factor",
+    # "original_max_position_embeddings", "beta_fast", "beta_slow",
+    # "mscale", "mscale_all_dim"}``: rotated at the token's position, pairs
+    # (2i, 2i + 1), YaRN frequencies (``factor`` 1: plain RoPE), and the
+    # softmax scale times YaRN's factor squared (kda_mla.yarn)
+    mla_rope: Optional[dict] = None
     # RMSNorm multiplies by (1 + scale): the stored gain starts at 0
     norm_add_unit_offset: bool = False
     # the residual stream (and the logits) stay float32 whatever dtype the
@@ -275,6 +297,14 @@ class ModelConfig:
                 f"held experts [{self.moe_first_expert}, "
                 f"{self.moe_first_expert + self.num_experts}) do not lie in "
                 f"the router's {self.moe_router_experts}")
+        G, Gk = self.moe_n_group, self.moe_topk_group
+        if not (1 <= Gk <= G and self.moe_router_experts % G == 0
+                and (G == 1 or self.num_experts_per_tok
+                     <= Gk * (self.moe_router_experts // G))):
+            raise ValueError(
+                f"moe_topk_group={Gk} of moe_n_group={G} equal groups of the "
+                f"router's {self.moe_router_experts} experts must hold the "
+                f"top-{self.num_experts_per_tok}")
         if (self.norm, self.position, self.glu, self.attention) != (
                 "rmsnorm", "rope", True, "full") or self.moe_drop_tokens \
                 or self.use_bias or self.qkv_bias or self.mlp_bias \
@@ -294,10 +324,11 @@ class ModelConfig:
         module does not build."""
         sizes = {k: getattr(self, k) for k in _KDA_MLA_ONLY}
         if not self.is_kda_mla:
-            if any(sizes.values()):
+            if any(sizes.values()) or self.mla_q_rank or self.mla_rope:
                 raise ValueError(
-                    f"{sorted(_KDA_MLA_ONLY)} belong to linear_attention and "
-                    "latent_attention layers (models/kda_mla.py)")
+                    f"{sorted(_KDA_MLA_ONLY + _MLA_FORMS)} belong to "
+                    "linear_attention and latent_attention layers "
+                    "(models/kda_mla.py)")
             return
         used = {"linear_attention": "kda_", "latent_attention": "mla_"}
         need = [k for k in _KDA_MLA_ONLY if sizes[k] < 1 and any(
@@ -306,6 +337,13 @@ class ModelConfig:
             raise ValueError(
                 f"layer_types {self.layer_types!r} (models/kda_mla.py) "
                 f"needs {need}")
+        if self.mla_rope is not None:
+            self.mla_rope = dict(self.mla_rope)
+            if set(self.mla_rope) != _MLA_ROPE_KEYS or self.mla_rot_dim % 2:
+                raise ValueError(
+                    f"mla_rope names {sorted(_MLA_ROPE_KEYS)} and rotates "
+                    f"pairs of an even mla_rot_dim, got "
+                    f"{sorted(self.mla_rope)}, mla_rot_dim={self.mla_rot_dim}")
         if self.sandwich_norm or self.qk_norm_per_head \
                 or self.attn_output_gate or self.embed_scale != 1.0 \
                 or self.sliding_window:
@@ -351,13 +389,18 @@ _STATE_KINDS = frozenset({"linear_attention", "latent_attention"})
 _KDA_MLA_ONLY = ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
                  "kda_gate_rank", "mla_kv_rank", "mla_nope_dim",
                  "mla_rot_dim", "mla_v_dim")
+# ... and the forms of a latent layer that a model may leave at their zero
+_MLA_FORMS = ("mla_q_rank", "mla_rope")
+_MLA_ROPE_KEYS = frozenset({
+    "theta", "factor", "original_max_position_embeddings", "beta_fast",
+    "beta_slow", "mscale", "mscale_all_dim"})
 # fields only the layer form (models/afmoe.py, models/kda_mla.py) reads
 _AFMOE_ONLY = frozenset({
     "sliding_window", "num_dense_layers", "dense_intermediate_size",
     "qk_norm_per_head", "attn_output_gate", "sandwich_norm", "embed_scale",
     "moe_score_func", "moe_route_scale", "moe_select_bias",
     "num_shared_experts", "moe_router_experts", "moe_first_expert",
-    *_KDA_MLA_ONLY})
+    "moe_n_group", "moe_topk_group", *_KDA_MLA_ONLY, *_MLA_FORMS})
 
 
 _PRESETS = {

@@ -1,8 +1,9 @@
-"""Linear-attention layers beside latent-attention layers
-(moonshotai Kimi-Linear; ``ModelConfig.layer_types``): the layer form of
-``models/afmoe.py`` (a static pattern, leading dense layers, the sigmoid
-bias-corrected router over ONE CHIP'S SHARE of the experts) over two further
-attention kinds, plain pre-norm.
+"""Linear-attention and latent-attention layers (moonshotai Kimi-Linear:
+both kinds, the latent one without a position encoding; skt A.X-K1: latent
+layers only, rotated, with a low-rank query; ``ModelConfig.layer_types``):
+the layer form of ``models/afmoe.py`` (a static pattern, leading dense
+layers, the sigmoid router over ONE CHIP'S SHARE of the experts) over two
+further attention kinds, plain pre-norm.
 
 With ``N(.)`` RMSNorm (own gain, ``norm_eps``), ``x`` the stream, ``h =
 N_in(x)``:
@@ -23,22 +24,31 @@ N_in(x)``:
         a = (N_o(o_t) * sigmoid((h Wg_down) Wg_up + b_g)) Wo   N_o over d
 
     "latent_attention" (MLA: H = num_heads query heads of n + r = mla_nope_dim
-    + mla_rot_dim, values of mla_v_dim, latent mla_kv_rank), NO position
-    encoding (the r values are used UNROTATED):
-        q = h Wq;  [c_raw | k_r] = h Wkva;  c = N_kv(c_raw)
+    + mla_rot_dim, values of mla_v_dim, latent mla_kv_rank):
+        q = h Wq   or, with mla_q_rank,   q = N_q(h Wqa) Wqb
+        [c_raw | k_r] = h Wkva;  c = N_kv(c_raw)
+        mla_rope None: the r values of q_h and k_r are used UNROTATED (no
+            position encoding), m = 1
+        mla_rope set:  q_r,h <- R_t q_r,h;  k_r <- R_t k_r   at the token's
+            position t, pairs (2i, 2i + 1), YaRN frequencies; m = YaRN's
+            factor on the softmax scale (:func:`yarn`)
         [k_n,h | v_h] = c Wkvb        per head
-        score_h(t, j) = (q_n,h(t) . k_n,h(j) + q_r,h(t) . k_r(j)) / sqrt(n + r)
+        score_h(t, j) = (q_n,h(t) . k_n,h(j) + q_r,h(t) . k_r(j))
+                        * m^2 / sqrt(n + r)
         a = concat_h(softmax(score_h) v_h) Wo
-        cache row of token j: [c(j) | k_r(j)], shared by all heads
+        cache row of token j: [c(j) | k_r(j)] (k_r as the scores use it:
+            rotated where the model rotates), shared by all heads
 
 Each piece is ONE function here (:func:`short_conv`, :func:`kda_activate`,
 :func:`kda_step` / :func:`kda_chunk`, :func:`kda_out`; :func:`mla_project`,
-:func:`mla_decompress`, :func:`mla_absorb` / :func:`mla_unabsorb`) and the
+:func:`mla_query`, :func:`mla_row`, :func:`rotate`, :func:`mla_decompress`,
+:func:`mla_absorb` / :func:`mla_unabsorb`) and the
 three forwards call them, as ``afmoe.py``'s do; the expert block, the pattern
 loop's parameter stacks and the fused path's layer end are ``afmoe``'s own
 functions.  The attention parameters are two stacks by KIND (``params["kda"]``
 ``[linear layers, ...]``, ``params["mla"]`` ``[latent layers, ...]``): the
-kinds interleave inside ``afmoe``'s two MLP stacks.
+kinds interleave inside ``afmoe``'s two MLP stacks.  A model without linear
+layers has no ``kda`` stack, and its cache no ``state`` and no ``tail``.
 
 Cache (``serving/paged_kv.py``): a linear layer keeps, for each SLOT, its
 state ``[H, d, d]`` float32 and the convolution's tail, the last ``K - 1``
@@ -64,6 +74,7 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from deepspeed_tpu.models import afmoe
 from deepspeed_tpu.models.afmoe import F32, refuse_parallel, rms
@@ -148,8 +159,12 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     if lat:
         L, n, r = len(lat), cfg.mla_nope_dim, cfg.mla_rot_dim
         kv, v = cfg.mla_kv_rank, cfg.mla_v_dim
+        rq = cfg.mla_q_rank
+        wq = {"wq": uni((L, D, H * (n + r)), D)} if not rq else {
+            "wqa": uni((L, D, rq), D), "q_norm": jnp.ones((L, rq), dtype),
+            "wqb": uni((L, rq, H * (n + r)), rq)}
         params["mla"] = {
-            "wq": uni((L, D, H * (n + r)), D), "wkva": uni((L, D, kv + r), D),
+            **wq, "wkva": uni((L, D, kv + r), D),
             "kv_norm": jnp.ones((L, kv), dtype),
             "wkvb": uni((L, kv, H * (n + v)), kv),
             "wo": uni((L, H * v, D), H * v)}
@@ -293,21 +308,87 @@ def kda_out(cfg, a, o, gate):
 # ----------------------------------------------------------------------
 # the latent-attention (MLA) pieces
 # ----------------------------------------------------------------------
-def mla_row(cfg, a, cr):
-    """The cache row from ``[c_raw | k_r]`` [..., kv + r]: the latent
-    normed, the shared key values as they are, zeros up to the row width."""
+def yarn(rope, dim: int):
+    """(inv_freq [dim / 2] float32, the factor on cos and sin, the factor
+    ``m`` whose SQUARE multiplies the softmax scale) of a ``mla_rope`` group:
+    with ``f_i = theta^(-2i / dim)``, ``s = factor`` and ``L0`` the original
+    length, ``lo = floor(dim ln(L0 / (2 pi beta_fast)) / (2 ln theta))``,
+    ``hi = ceil(the same with beta_slow)`` (both inside [0, dim - 1]), the
+    ramp ``r_i = clip((i - lo) / (hi - lo), 0, 1)`` and
+
+        inv_freq_i = f_i (1 - r_i) + (f_i / s) r_i
+
+    (pairs that turn more than ``beta_fast`` times in ``L0`` keep their
+    frequency, pairs that turn less than ``beta_slow`` times are slowed
+    ``s``-fold).  With ``y(a) = 0.1 a ln s + 1`` (1 for ``s <= 1``), cos and
+    sin are scaled by ``y(mscale) / y(mscale_all_dim)`` and ``m =
+    y(mscale_all_dim)`` where ``mscale_all_dim`` is set (else 1).  Computed
+    once, on the host, from the configuration."""
+    s, theta = float(rope["factor"]), float(rope["theta"])
+    L0 = float(rope["original_max_position_embeddings"])
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    turns = lambda b: dim * math.log(L0 / (b * 2 * math.pi)) \
+        / (2 * math.log(theta))
+    lo = max(math.floor(turns(rope["beta_fast"])), 0)
+    hi = min(math.ceil(turns(rope["beta_slow"])), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    y = lambda a: 0.1 * a * math.log(s) + 1.0 if s > 1 else 1.0
+    all_dim = float(rope["mscale_all_dim"])
+    return ((f * (1 - ramp) + f / s * ramp).astype(np.float32),
+            y(float(rope["mscale"])) / y(all_dim),
+            y(all_dim) if all_dim else 1.0)
+
+
+def rotate(cfg, t, pos):
+    """``t`` [..., r] (the rotary values of the query heads or of the shared
+    key) at integer positions ``pos``, broadcastable to ``t``'s leading
+    axes: pairs (2i, 2i + 1) turned by ``pos * inv_freq_i``, float32 angles.
+    Without ``mla_rope`` the values pass unrotated."""
+    if cfg.mla_rope is None:
+        return t
+    inv_freq, on_cos_sin, _ = yarn(cfg.mla_rope, t.shape[-1])
+    ang = jnp.asarray(pos, F32)[..., None] * jnp.repeat(inv_freq, 2)
+    cos, sin = jnp.cos(ang) * on_cos_sin, jnp.sin(ang) * on_cos_sin
+    t32 = t.astype(F32)
+    # the pair's other value: -t[2i + 1] at 2i, t[2i] at 2i + 1
+    other = jnp.where(jnp.arange(t.shape[-1]) % 2 == 0,
+                      -jnp.roll(t32, -1, axis=-1), jnp.roll(t32, 1, axis=-1))
+    return (t32 * cos + other * sin).astype(t.dtype)
+
+
+def mla_row(cfg, a, cr, pos):
+    """The cache row from ``[c_raw | k_r]`` [..., kv + r] at positions
+    ``pos`` [...]: the latent normed, the shared key values (rotated where
+    the model rotates), zeros up to the row width."""
     kv = cfg.mla_kv_rank
     c = rms(cr[..., :kv], a["kv_norm"], cfg.norm_eps)
     pad = jnp.zeros(cr.shape[:-1] + (row_width(cfg) - cr.shape[-1],),
                     cr.dtype)
-    return jnp.concatenate([c, cr[..., kv:], pad], axis=-1)
+    return jnp.concatenate([c, rotate(cfg, cr[..., kv:], pos), pad], axis=-1)
 
 
-def mla_project(cfg, a, h):
-    """(q [..., H, n + r], the cache row [..., row_width]) of ``h``."""
-    q = h @ a["wq"].astype(h.dtype)
-    return (q.reshape(q.shape[:-1] + (cfg.num_heads, -1)),
-            mla_row(cfg, a, h @ a["wkva"].astype(h.dtype)))
+def mla_query(cfg, q, pos):
+    """The projected queries ``q`` [..., H (n + r)] at positions ``pos``
+    [...] as heads [..., H, n + r], each head's rotary part rotated."""
+    n = cfg.mla_nope_dim
+    q = q.reshape(q.shape[:-1] + (cfg.num_heads, -1))
+    if cfg.mla_rope is None:
+        return q
+    return jnp.concatenate(
+        [q[..., :n], rotate(cfg, q[..., n:], jnp.asarray(pos)[..., None])],
+        axis=-1)
+
+
+def mla_project(cfg, a, h, pos):
+    """(q [..., H, n + r], the cache row [..., row_width]) of ``h`` [..., D]
+    at positions ``pos`` [...]; the query full-rank, or ``N_q(h Wqa) Wqb``
+    where the model has ``mla_q_rank``."""
+    w = lambda n: a[n].astype(h.dtype)
+    if cfg.mla_q_rank:
+        q = rms(h @ w("wqa"), a["q_norm"], cfg.norm_eps) @ w("wqb")
+    else:
+        q = h @ w("wq")
+    return mla_query(cfg, q, pos), mla_row(cfg, a, h @ w("wkva"), pos)
 
 
 def _wkvb(cfg, a):
@@ -350,7 +431,9 @@ def mla_unabsorb(cfg, a, o):
 
 
 def _mla_scale(cfg) -> float:
-    return (cfg.mla_nope_dim + cfg.mla_rot_dim) ** -0.5
+    m = 1.0 if cfg.mla_rope is None else yarn(cfg.mla_rope,
+                                              cfg.mla_rot_dim)[2]
+    return (cfg.mla_nope_dim + cfg.mla_rot_dim) ** -0.5 * m * m
 
 
 # ----------------------------------------------------------------------
@@ -363,15 +446,16 @@ def apply_layers(cfg, params, x, mesh=None):
     refuse_parallel(cfg, mesh, "CausalLM.apply")
     B, S, _ = x.shape
     pad = -S % SUB if S > SUB else 0       # whole sub-chunks (pad rows idle)
-    (ns, _, H, d, _), (_, _, K1, C3) = state_shapes(cfg, 1)
-    nl = cfg.num_layers - ns
+    lin, lat = kind_layers(cfg)
+    state, tail = state_shapes(cfg, 1)
 
     def one(xb):
         xb = jnp.pad(xb, ((0, pad), (0, 0)))[None]
-        cache = {"latent": jnp.zeros((nl, 1, 1, S + pad, row_width(cfg)),
-                                     x.dtype),
-                 "state": jnp.zeros((ns, 1, H, d, d), F32),
-                 "tail": jnp.zeros((ns, 1, K1, C3), x.dtype)}
+        cache = {"latent": jnp.zeros(
+            (len(lat), 1, 1, S + pad, row_width(cfg)), x.dtype)}
+        if lin:
+            cache.update(state=jnp.zeros(state, F32),
+                         tail=jnp.zeros(tail, x.dtype))
         return cached_layers(cfg, params, xb, cache, 0, S)[0][0, :S]
 
     return jax.lax.map(one, x)
@@ -380,17 +464,18 @@ def apply_layers(cfg, params, x, mesh=None):
 def cached_layers(cfg, params, x, cache, start, valid_len):
     """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
     over ONE slot's views (``latent`` [latent layers, 1, 1, positions,
-    row_width]; ``state`` [linear layers, 1, H, d, d] float32; ``tail``
-    [linear layers, 1, K - 1, 3 H d]: what
-    ``cache_kind.LatentPagesAndState.view`` slices out); only the first ``valid_len`` rows are real.  A chunk at
-    position 0 starts from a zero state whatever the slot held (the state's
-    reset at admission).  Returns (x, views)."""
+    row_width]; with linear layers also ``state`` [linear layers, 1, H, d, d]
+    float32 and ``tail`` [linear layers, 1, K - 1, 3 H d]: what
+    ``cache_kind.LatentPages.view`` / ``LatentPagesAndState.view`` slice
+    out); only the first ``valid_len`` rows are real.  A chunk at position 0
+    starts from a zero state whatever the slot held (the state's reset at
+    admission).  Returns (x, views)."""
     B, s, _ = x.shape
     assert B == 1, "a chunk program prefills one slot"
     start = jnp.asarray(start, jnp.int32)
     pos = start + jnp.arange(s)
     real = jnp.arange(s) < valid_len
-    latent, state, tail = cache["latent"], cache["state"], cache["tail"]
+    latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
     kept = (start != 0)
     experts = afmoe._experts(params)
     i_lin = i_lat = 0
@@ -414,7 +499,7 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
             ctx = kda_out(cfg, a, o[None], gate)
             i_lin += 1
         else:
-            q, row = mla_project(cfg, a, h)
+            q, row = mla_project(cfg, a, h, pos[None])
             latent = jax.lax.dynamic_update_slice(
                 latent, row[None, :, None].astype(latent.dtype),
                 (i_lat, 0, 0, start, 0))
@@ -430,7 +515,14 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
             i_lat += 1
         x = afmoe.mlp_block(cfg, lp, x, ctx @ a["wo"].astype(ctx.dtype),
                             None if le is None else experts, le)
-    return x, {"latent": latent, "state": state, "tail": tail}
+    return x, _views(latent, state, tail)
+
+
+def _views(latent, state, tail):
+    """The cache a forward hands back: what it was given."""
+    if state is None:
+        return {"latent": latent}
+    return {"latent": latent, "state": state, "tail": tail}
 
 
 # ----------------------------------------------------------------------
@@ -447,14 +539,16 @@ def inject(cfg, params) -> Dict[str, Any]:
     """The kernel-injected view (``afmoe.inject``'s shape): per-layer dicts,
     every projection of ``h`` in one ``[D, N]`` matrix ``w_in`` (linear: q |
     k | v | decay gate down | output gate down | beta; latent: q | [c_raw |
-    k_r]), the small per-kind arrays under their own names, the stacked
-    routed experts by reference."""
+    k_r], or, with a low-rank query, Wqa | [c_raw | k_r] with ``wqb`` and
+    ``q_norm`` beside it), the small per-kind arrays under their own names,
+    the stacked routed experts by reference."""
     layers = []
     for l in range(cfg.num_layers):
         lp, le = layer_params(cfg, params, l)
         a = lp["attn"]
         names = (("wq", "wk", "wv", "wf_down", "wg_down", "wb")
-                 if is_linear(cfg, l) else ("wq", "wkva"))
+                 if is_linear(cfg, l) else
+                 ("wqa" if cfg.mla_q_rank else "wq", "wkva"))
         d = {k: v for k, v in a.items() if k not in names}
         d["w_in"] = _pad_cols(jnp.concatenate([a[k] for k in names], axis=-1))
         layers.append({**d, **afmoe.inject_rest(cfg, lp, le)})
@@ -462,21 +556,23 @@ def inject(cfg, params) -> Dict[str, Any]:
 
 
 def moe_counts_zero(cfg):
-    """``afmoe.moe_counts_zero`` and a fifth entry: (row, linear layer)
-    pairs that were LIVE, and pairs whose state the decode kernel
-    VISITED."""
-    return afmoe.moe_counts_zero(cfg) + (jnp.zeros((2,), jnp.int32),)
+    """``afmoe.moe_counts_zero`` and, for a model with linear layers, a last
+    entry: (row, linear layer) pairs that were LIVE, and pairs whose state
+    the decode kernel VISITED."""
+    return afmoe.moe_counts_zero(cfg) + (
+        (jnp.zeros((2,), jnp.int32),) if kind_layers(cfg)[0] else ())
 
 
 def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                  impl: Optional[str] = None):
     """The layer stack for one token a row: ``x`` [B, D] at per-row
     positions ``pos`` [B]; ``cache``: ``latent`` [latent layers, pages, 1,
-    page, row_width] through ``page_table`` [B, columns], ``state`` [linear
-    layers, B, H, d, d] and ``tail`` [linear layers, B, K - 1, 3 H d] by
-    row (a row of the batch is a slot).  ``moe_live`` [B] bool: the rows that
-    decode; only their state, tail and latent pages move, and the kernels
-    visit only them.  Returns (x, cache, counts | None)."""
+    page, row_width] through ``page_table`` [B, columns] and, with linear
+    layers, ``state`` [linear layers, B, H, d, d] and ``tail`` [linear
+    layers, B, K - 1, 3 H d] by row (a row of the batch is a slot).
+    ``moe_live`` [B] bool: the rows that decode; only their state, tail and
+    latent pages move, and the kernels visit only them.  Returns (x, cache,
+    counts | None)."""
     from deepspeed_tpu.ops.pallas.decode import (fused_norm_qkv,
                                                  kda_decode_step,
                                                  mla_decode_paged,
@@ -485,8 +581,9 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     B = x.shape[0]
     H, Hk, d = cfg.num_heads, cfg.kda_num_heads, cfg.kda_head_dim
     C3, r = 3 * Hk * d, cfg.kda_gate_rank
-    M = H * (cfg.mla_nope_dim + cfg.mla_rot_dim)
-    latent, state, tail = cache["latent"], cache["state"], cache["tail"]
+    # the query's columns of ``w_in``: the heads, or the bottleneck
+    M = cfg.mla_q_rank or H * (cfg.mla_nope_dim + cfg.mla_rot_dim)
+    latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
     stats = moe_counts_zero(cfg) if moe_live is not None else None
     i_lin = i_lat = 0
     for l, lp in enumerate(dparams["layers"]):
@@ -504,14 +601,19 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                 state, q, k, v, g, beta, layer=i_lin, live=moe_live,
                 impl=impl)
             if stats is not None:
-                stats = stats[:4] + (stats[4] + jnp.stack(
+                stats = stats[:-1] + (stats[-1] + jnp.stack(
                     [jnp.sum(moe_live, dtype=jnp.int32), visited]),)
             ctx = kda_out(cfg, lp, o, gate)
             i_lin += 1
         else:
-            q = y[:, :M].reshape(B, H, -1)
+            q = y[:, :M]
+            if cfg.mla_q_rank:          # N_q and Wqb: the kernel once more
+                q = fused_norm_qkv(q, lp["q_norm"], None, lp["wqb"], None,
+                                   kind="rmsnorm", eps=cfg.norm_eps,
+                                   impl=impl)
+            q = mla_query(cfg, q, pos)
             row = mla_row(cfg, lp, y[:, M:M + cfg.mla_kv_rank
-                                     + cfg.mla_rot_dim])
+                                     + cfg.mla_rot_dim], pos)
             latent = paged_row_append(latent, row, pos, page_table,
                                       layer=i_lat, impl=impl)
             o = mla_decode_paged(mla_absorb(cfg, lp, q), latent, pos,
@@ -522,4 +624,4 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
             i_lat += 1
         x, stats = afmoe.fused_close(cfg, dparams, lp, l, ctx, x, stats,
                                      moe_live, impl)
-    return x, {"latent": latent, "state": state, "tail": tail}, stats
+    return x, _views(latent, state, tail), stats

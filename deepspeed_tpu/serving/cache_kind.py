@@ -11,8 +11,10 @@ attention form, and :func:`cache_kind` is the one place that decides it
   int8 cache all work on it: it refuses nothing;
 - :class:`WindowSummaryPages` (``attention="eva"``, ``models/eva.py``);
 - :class:`TwoBudgets` (sliding and global layers, ``models/afmoe.py``);
-- :class:`LatentPagesAndState` (linear-attention and latent-attention
-  layers, ``models/kda_mla.py``).
+- :class:`LatentPages` (latent-attention layers only,
+  ``models/kda_mla.py``): one row a position that all heads share;
+- :class:`LatentPagesAndState` (linear-attention layers beside them): the
+  same pages and a recurrent state a slot.
 
 A kind answers what the engine asks and nothing else: the pool's arguments
 and the device arrays; what it cannot be served with (``cannot``: one table
@@ -400,11 +402,79 @@ class TwoBudgets(FullPages):
             held["full"] * (n_win + n_full))
 
 
-class LatentPagesAndState(FullPages):
-    """Linear-attention and latent-attention layers: ``latent`` pages of one
-    row a position that all heads share, allocated like full pages, and per
-    SLOT a float32 recurrent ``state`` and a convolution ``tail``, never
-    allocated or freed (a request's first chunk program reads zeros)."""
+class LatentPages(FullPages):
+    """Latent-attention layers: ``latent`` pages of one row a position that
+    all heads share (the normed latent and the shared key values),
+    allocated like full pages and position-pure like them, but no per-head
+    K and V arrays."""
+
+    what = "latent_attention layers"
+    _rows = ("per-head K and V arrays, and a latent page is one row a "
+             "position shared by all heads (ROADMAP R2)")
+    cannot = {
+        "handoff": "serving/handoff.py ships pages as " + _rows,
+        "kv_host_tier_pages": "serving/host_tier.py demotes and promotes "
+                              "pages as " + _rows,
+        "prefix_caching":
+            "serving/prefix_cache.py and the engine's boundary-page copy "
+            "share pages as " + _rows,
+        "quantize_kv_cache":
+            "the int8 cache of models/decoding.py scales per-head K and V "
+            "rows; a latent row has no int8 form",
+        "use_fused_decode":
+            "the decode step over latent pages is built on the fused path "
+            "only (models/kda_mla.py:fused_layers)",
+    }
+    counters = {
+        "ds_serve_mla_rows_expanded_total":
+            "(latent row, latent layer) pairs the prefill chunk programs "
+            "decompressed to per-head keys and values: a chunk expands every "
+            "earlier row of its request again, a key block at a time "
+            "(models/afmoe.py:attend(expand=))",
+        "ds_serve_mla_rows_written_total":
+            "(latent row, latent layer) pairs the prefill chunk programs "
+            "wrote for the real tokens of their chunks",
+    }
+
+    def init_cache(self, pool, num_slots, dtype, quantized):
+        cfg = self.cfg
+        return {"latent": jnp.zeros(
+            (len(kda_mla.kind_layers(cfg)[1]), pool.num_pages, 1, pool.page,
+             kda_mla.row_width(cfg)), dtype)}
+
+    def layout(self, pool, num_slots):
+        return super().layout(pool, num_slots) + " of latent rows"
+
+    def view(self, cache, pt_row, slot, start, cb):
+        """The view ``kda_mla.cached_layers`` takes: every latent page of
+        the slot."""
+        return {"latent": _slot_view(cache["latent"], pt_row,
+                                     range(pt_row.shape[0]))}
+
+    def write_back(self, cache, sub, pt_row, slot, start, cb):
+        """Of the latent pages only those the chunk's ``cb`` rows can have
+        touched go back."""
+        return {"latent": _slot_write_back(
+            cache["latent"], sub["latent"], pt_row, 0,
+            _touched(start, cb, cache["latent"].shape[3], pt_row.shape[0]))}
+
+    def count_chunk(self, pool, cache, off, c, cb):
+        """``ds_serve_mla_rows_*``: the rows the chunk's attention visits
+        (whole key blocks up to the bucket's end, ``afmoe.keys_visited``),
+        each decompressed once a latent layer, and the real rows it adds."""
+        if not self._reg.enabled:
+            return
+        layers = cache["latent"].shape[0]
+        self._m["ds_serve_mla_rows_expanded_total"].inc(layers * int(
+            afmoe.keys_visited(pool.slot_pages * pool.page, off + cb)))
+        self._m["ds_serve_mla_rows_written_total"].inc(layers * c)
+
+
+class LatentPagesAndState(LatentPages):
+    """Linear-attention layers beside latent-attention layers: the latent
+    pages, and per SLOT a float32 recurrent ``state`` and a convolution
+    ``tail``, never allocated or freed (a request's first chunk program
+    reads zeros)."""
 
     what = "linear_attention / latent_attention layers"
     cannot = {
@@ -417,7 +487,8 @@ class LatentPagesAndState(FullPages):
         "prefix_caching":
             "serving/prefix_cache.py shares pages as a function of the token "
             "prefix, and a recurrent state is a slot's, not a page's (the "
-            "latent pages alone are position-pure)",
+            "latent pages alone are position-pure, and shared they would "
+            "still not be per-head K and V arrays: LatentPages)",
         "quantize_kv_cache":
             "the int8 cache of models/decoding.py scales per-head K and V "
             "rows; a latent row and a float32 state have no int8 form",
@@ -428,6 +499,7 @@ class LatentPagesAndState(FullPages):
     # the row steps are counted by the decode-block program itself (the
     # live mask and the state kernel's grid) and fetched with its tokens
     counters = {
+        **LatentPages.counters,
         "ds_serve_state_row_steps_total":
             "(live row, linear-attention layer, decode step) triples: the "
             "state updates the decode steps really made",
@@ -445,37 +517,30 @@ class LatentPagesAndState(FullPages):
         return {"slot_state_bytes": kda_mla.slot_state_bytes(self.cfg, dtype)}
 
     def init_cache(self, pool, num_slots, dtype, quantized):
-        cfg = self.cfg
-        state, tail = kda_mla.state_shapes(cfg, num_slots)
-        return {"latent": jnp.zeros(
-                    (len(kda_mla.kind_layers(cfg)[1]), pool.num_pages, 1,
-                     pool.page, kda_mla.row_width(cfg)), dtype),
+        state, tail = kda_mla.state_shapes(self.cfg, num_slots)
+        return {**super().init_cache(pool, num_slots, dtype, quantized),
                 "state": jnp.zeros(state, jnp.float32),
                 "tail": jnp.zeros(tail, dtype)}
 
     def layout(self, pool, num_slots):
         return (super().layout(pool, num_slots)
-                + f" of latent rows, and {pool.state_bytes} bytes of slot "
-                  "state")
+                + f", and {pool.state_bytes} bytes of slot state")
 
     def view(self, cache, pt_row, slot, start, cb):
-        """The view ``kda_mla.cached_layers`` takes: every latent page of
-        the slot, and its state and tail sliced out by the slot's index (the
-        chunk carries them through and starts from zeros at position 0)."""
+        """The latent pages, and the slot's state and tail sliced out by its
+        index (the chunk carries them through and starts from zeros at
+        position 0)."""
         own = lambda v: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
-        return {"latent": _slot_view(cache["latent"], pt_row,
-                                     range(pt_row.shape[0])),
+        return {**super().view(cache, pt_row, slot, start, cb),
                 "state": own(cache["state"]), "tail": own(cache["tail"])}
 
     def write_back(self, cache, sub, pt_row, slot, start, cb):
-        """Of the latent pages only those the chunk's ``cb`` rows can have
-        touched go back; state and tail in place at the slot's index."""
-        latent = _slot_write_back(
-            cache["latent"], sub["latent"], pt_row, 0,
-            _touched(start, cb, cache["latent"].shape[3], pt_row.shape[0]))
+        """The latent pages as :class:`LatentPages` puts them back; state
+        and tail in place at the slot's index."""
         put = lambda k: jax.lax.dynamic_update_slice_in_dim(
             cache[k], sub[k], slot, axis=1)
-        return {"latent": latent, "state": put("state"), "tail": put("tail")}
+        return {**super().write_back(cache, sub, pt_row, slot, start, cb),
+                "state": put("state"), "tail": put("tail")}
 
     def attach(self, registry, pool):
         super().attach(registry, pool)
@@ -486,7 +551,7 @@ class LatentPagesAndState(FullPages):
 
     def count_block(self, counts):
         """``ds_serve_state_row_steps_*``: the block's (row, linear layer)
-        pairs, live and visited by the state kernel (the fifth of
+        pairs, live and visited by the state kernel (the last of
         ``kda_mla.fused_layers``' counts)."""
         steps = counts.pop()
         self._m["ds_serve_state_row_steps_total"].inc(int(steps[0]))
@@ -494,7 +559,8 @@ class LatentPagesAndState(FullPages):
         return counts
 
 
-KINDS = (FullPages, WindowSummaryPages, TwoBudgets, LatentPagesAndState)
+KINDS = (FullPages, WindowSummaryPages, TwoBudgets, LatentPages,
+         LatentPagesAndState)
 
 
 def cache_kind(cfg) -> FullPages:
@@ -502,7 +568,8 @@ def cache_kind(cfg) -> FullPages:
     if getattr(cfg, "is_eva", False):
         return WindowSummaryPages(cfg)
     if getattr(cfg, "is_kda_mla", False):
-        return LatentPagesAndState(cfg)
+        return (LatentPagesAndState if kda_mla.kind_layers(cfg)[0]
+                else LatentPages)(cfg)
     if getattr(cfg, "is_afmoe", False):
         return TwoBudgets(cfg)
     return FullPages(cfg)

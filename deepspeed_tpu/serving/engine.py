@@ -129,6 +129,12 @@ SERVE_MOE_COUNTERS = {
     "ds_serve_moe_local_assignments_total":
         "of ds_serve_moe_assignments_total, those to an expert this chip "
         "holds (all of them unless the chip holds a share: models/afmoe.py)",
+    "ds_serve_moe_group_kept_total":
+        "(live row, expert layer, decode step) triples whose kept groups of "
+        "experts include a group with an expert this chip holds; moves only "
+        "under a group-limited router (moe_n_group > 1: models/afmoe.py), "
+        "over assignments / num_experts_per_tok it is how often the limit "
+        "leaves this chip's experts in reach of a row",
 }
 
 
@@ -2069,12 +2075,14 @@ class ServingEngine:
             entry = self._block_np[idx] = (toks, valid)
         return entry
 
-    def _count_moe(self, per_expert, hits, max_load, offered=None) -> None:
+    def _count_moe(self, per_expert, hits, max_load, offered=None,
+                   group_kept=0) -> None:
         """One decode block's routing (``decode_step``'s ``moe_live``
         result, summed over the block's steps) into ``ds_serve_moe_*``.
         ``offered`` (a chip's share of the experts, models/afmoe.py): the
         assignments the live rows made, of which ``per_expert`` holds those
-        to experts held here."""
+        to experts held here.  ``group_kept``: a group-limited router's
+        fifth count."""
         cfg = self.module.config
         m = self._m_moe
         local = int(per_expert.sum())
@@ -2085,6 +2093,7 @@ class ServingEngine:
         m["ds_serve_moe_expert_slots_total"].inc(
             cfg.num_experts * cfg.num_expert_layers * self._K)
         m["ds_serve_moe_max_load_total"].inc(int(max_load))
+        m["ds_serve_moe_group_kept_total"].inc(int(group_kept))
 
     def _unref(self, idx: int) -> None:
         self._block_refs[idx] -= 1

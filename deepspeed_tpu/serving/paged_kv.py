@@ -79,8 +79,9 @@ would hold ``3 W / page`` in every layer.
 **Latent pages and a slot-state budget that is not pages** (a model of
 latent-attention and linear-attention layers, ``models/kda_mla.py``;
 ``slot_state_bytes`` below).  A latent layer's page holds ONE row a position
-that all heads share (``cache_kind.LatentPagesAndState``: ``latent`` ``[latent
-layers, pages, 1, page, row width]``, no V array: keys and values are read from the
+that all heads share (``cache_kind.LatentPages``, alone where every layer is
+a latent one, and ``LatentPagesAndState``: ``latent`` ``[latent layers,
+pages, 1, page, row width]``, no V array: keys and values are read from the
 same row); it is position-pure and allocated like a full-attention page, one
 budget, one table column a page.  A linear layer keeps no rows at all but a
 recurrent STATE of fixed size a slot (``state`` ``[linear layers, slots,

@@ -1,7 +1,8 @@
-"""The contract of ``serving/cache_kind.py``, once over the four kinds of
+"""The contract of ``serving/cache_kind.py``, once over the five kinds of
 slot cache at the tiny sizes their model tests build: full pages (a tiny
 Llama), window + summary pages (``test_evabyte``), two page budgets
-(``test_trinity``), latent pages + slot state (``test_kimi_linear``).
+(``test_trinity``), latent pages alone (``test_axk1``), latent pages + slot
+state (``test_kimi_linear``).
 
 What every kind owes the engine: a slot's view written back unchanged leaves
 the pool as it was, and a changed one touches nobody else's pages; an
@@ -17,12 +18,12 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, causal_lm
-from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages,
+from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages, LatentPages,
                                               LatentPagesAndState, TwoBudgets,
                                               WindowSummaryPages, cache_kind)
 from deepspeed_tpu.serving.paged_kv import PagedKVPool
 
-from . import test_evabyte, test_kimi_linear, test_trinity
+from . import test_axk1, test_evabyte, test_kimi_linear, test_trinity
 
 ENGINE = dict(num_slots=3, prefill_chunk=16, max_prefill_chunks=2,
               decode_block_tokens=4, max_out_tokens=96, kv_page_tokens=8,
@@ -32,6 +33,7 @@ CASES = {
     "full": (FullPages, None, ENGINE),
     "eva": (WindowSummaryPages, test_evabyte.TINY, test_evabyte.ENGINE),
     "two_budgets": (TwoBudgets, test_trinity.FIELDS, test_trinity.ENGINE),
+    "latent": (LatentPages, test_axk1.FIELDS, test_axk1.ENGINE),
     "state": (LatentPagesAndState, test_kimi_linear.FIELDS,
               test_kimi_linear.ENGINE),
 }
@@ -226,7 +228,7 @@ def test_prefill_only_is_refused_with_the_kinds_reason(built, name):
     serve.close()
 
 
-@pytest.mark.parametrize("name", ["two_budgets", "state"])
+@pytest.mark.parametrize("name", ["two_budgets", "latent", "state"])
 def test_the_decode_role_is_refused_with_the_kinds_reason(built, name):
     with pytest.raises(NotImplementedError) as err:
         serve_of(built, name, **ASKED["handoff"])

@@ -248,8 +248,8 @@ def test_absorbed_latent_attention_is_the_decompressed(model):
     ks = jax.random.split(jax.random.PRNGKey(5), 2)
     h = jax.random.normal(ks[0], (3, 64))
     hist = jax.random.normal(ks[1], (3, 40, 64))
-    q, row = kda_mla.mla_project(cfg, a, h)                    # [3, H, 24]
-    _, rows = kda_mla.mla_project(cfg, a, hist)                # [3, 40, W]
+    q, row = kda_mla.mla_project(cfg, a, h, None)                # [3, H, 24]
+    _, rows = kda_mla.mla_project(cfg, a, hist, None)           # [3, 40, W]
     assert rows.shape[-1] == kda_mla.row_width(cfg) == 128
     assert not np.asarray(rows[..., 40:]).any()                # the padding
     rows = jnp.concatenate([rows, row[:, None]], axis=1)       # own row last

@@ -761,3 +761,37 @@ def test_kimi_linear_cell_programs_compile_with_state_and_pool_in_place(
                  "fused_norm_qkv", "fused_proj_norm", "fused_mlp",
                  "fused_moe_mlp"):
         assert name in text, name
+
+
+def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
+        v5e, chip_kernels):
+    """ISSUE 48: the chunk programs (buckets 1,024 and 64) and the decode
+    block of the ``axk1-L5-ep16.serve-mixed-16k`` cell (the published
+    widths: 64 heads against one 640-value row, a query bottleneck of 1,536,
+    experts of 88 MB; depth cut to the dense layer and one expert layer,
+    the pool to 8 slots' worth, which change no shape) compile for the v5e:
+    the latent pool stays where it is, a chunk's grouped matmuls take their
+    odd number of tiles, and the decode block carries ``fused_norm_qkv``
+    twice a layer (the projections, then ``N_q`` and ``W_qb``), the latent
+    kernels and no state kernel."""
+    cell = _ServeCell(
+        v5e, "axk1-L5-ep16", "axk1-L5-ep16.serve-mixed-16k",
+        fields=dict(num_layers=2, layer_types=["latent_attention"] * 2),
+        engine=dict(kv_pool_tokens=8 * 16384, num_slots=8))
+    assert set(cell.serve._cache) == {"latent"}
+    assert cell.serve._cache["latent"].shape[-1] == 640
+    chunks = (cell.chunk(1024), cell.chunk(64))
+    for program, tokens in zip(chunks, (1024, 64)):
+        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+            program, tokens * 8)
+    block = cell.block()
+    for program in chunks + (block,):
+        cell.assert_pools_stay_in_place(program)
+    text = block.as_text()
+    for name in ("mla_decode_paged", "paged_kv_append", "fused_norm_qkv",
+                 "fused_proj_norm", "fused_mlp", "fused_moe_mlp"):
+        assert name in text, name
+    assert "kda_decode_step" not in text
+    calls = lambda name: len(re.findall(
+        rf"custom-call\([^\n]*{name}", text))
+    assert calls("fused_norm_qkv") == 2 * calls("mla_decode_paged") > 0

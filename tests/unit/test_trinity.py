@@ -186,9 +186,9 @@ def test_the_bias_changes_the_selection_and_not_the_weights(model):
     h = jax.random.normal(jax.random.PRNGKey(6), (11, 64))
     gate_w = params["layers"]["mlp"]["gate_w"][0]
     zero = jnp.zeros((8,))
-    w0, i0 = afmoe.route(cfg, h, gate_w, zero)
+    w0, i0, _ = afmoe.route(cfg, h, gate_w, zero)
     push = zero.at[5].set(10.0).at[2].set(-10.0)
-    w1, i1 = afmoe.route(cfg, h, gate_w, push)
+    w1, i1, _ = afmoe.route(cfg, h, gate_w, push)
     assert (np.asarray(i1) == 5).any(-1).all() and not (np.asarray(i1) == 2).any()
     assert not (np.asarray(i0) == 5).any(-1).all()
     # the weights are the sigmoid scores of the chosen, normalised, scaled
