@@ -62,9 +62,13 @@ chunk carries the state: pad rows of its bucket get ``beta = 0`` and ``g =
 recurrence in its chunkwise (UT transform) form over sub-chunks of ``SUB``
 tokens; decays are differences of cumulative log-decays inside a sub-chunk,
 masked before the exponential, so that no quotient of decays is formed.  A
-decode step attends in the absorbed form: ``q'_h = [q_n,h Wkvb_k,h^T |
-q_r,h]`` against the rows, the context ``sum_j p_j c(j)`` through
-``Wkvb_v,h``.
+chunk's latent layers attend DECOMPRESSED keys and values, a strip of rows
+at a time inside ``ops/pallas/flash_attention.py:mla_chunk_attention`` (the
+per-head keys, values and scores stay in VMEM; ``afmoe.attend(expand=)``,
+its reference, where the kernel does not run: the CPU, widths that are not
+lane tiles).  A decode step attends in the absorbed form: ``q'_h = [q_n,h
+Wkvb_k,h^T | q_r,h]`` against the rows, the context ``sum_j p_j c(j)``
+through ``Wkvb_v,h``.
 """
 
 from __future__ import annotations
@@ -78,6 +82,7 @@ import numpy as np
 
 from deepspeed_tpu.models import afmoe
 from deepspeed_tpu.models.afmoe import F32, refuse_parallel, rms
+from deepspeed_tpu.ops.pallas.flash_attention import mla_chunk_attention
 
 SUB = 64                  # tokens a sub-chunk of the chunkwise delta rule
 HI = jax.lax.Precision.HIGHEST
@@ -397,11 +402,12 @@ def _wkvb(cfg, a):
     return w[..., :cfg.mla_nope_dim], w[..., cfg.mla_nope_dim:]
 
 
-def mla_decompress(cfg, a, rows):
+def mla_decompress(rows, wk, wv, r: int):
     """Per-head keys [..., H, n + r] and values [..., H, v] of cache rows
-    [..., row_width]."""
-    kv, r = cfg.mla_kv_rank, cfg.mla_rot_dim
-    wk, wv = _wkvb(cfg, a)
+    [..., row_width] through ``Wkvb`` as :func:`_wkvb` splits it: float32
+    sums rounded to the rows' dtype, the shared ``k_r`` (``r`` values) as
+    the row has it."""
+    kv = wk.shape[0]
     c = rows[..., :kv]
     k_n = jnp.einsum("...c,chn->...hn", c, wk.astype(c.dtype))
     k_r = jnp.broadcast_to(rows[..., None, kv:kv + r],
@@ -461,7 +467,8 @@ def apply_layers(cfg, params, x, mesh=None):
     return jax.lax.map(one, x)
 
 
-def cached_layers(cfg, params, x, cache, start, valid_len):
+def cached_layers(cfg, params, x, cache, start, valid_len,
+                  impl: Optional[str] = None):
     """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
     over ONE slot's views (``latent`` [latent layers, 1, 1, positions,
     row_width]; with linear layers also ``state`` [linear layers, 1, H, d, d]
@@ -469,7 +476,9 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
     ``cache_kind.LatentPages.view`` / ``LatentPagesAndState.view`` slice
     out); only the first ``valid_len`` rows are real.  A chunk at position 0
     starts from a zero state whatever the slot held (the state's reset at
-    admission).  Returns (x, views)."""
+    admission).  ``impl``: the latent layers' chunk attention
+    (``ops/pallas/common.py``'s three names; None: by the device).  Returns
+    (x, views)."""
     B, s, _ = x.shape
     assert B == 1, "a chunk program prefills one slot"
     start = jnp.asarray(start, jnp.int32)
@@ -503,15 +512,15 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
             latent = jax.lax.dynamic_update_slice(
                 latent, row[None, :, None].astype(latent.dtype),
                 (i_lat, 0, 0, start, 0))
-            rows = latent[i_lat]                       # [1, 1, positions, W]
-            o = afmoe.attend(
-                q.transpose(0, 2, 1, 3),
-                [(rows, None, jnp.arange(rows.shape[2]))], pos, window=0,
-                scale=_mla_scale(cfg), live_keys=start + s,
-                expand=lambda rb, a=a: tuple(
-                    t[:, 0].transpose(0, 2, 1, 3)
-                    for t in mla_decompress(cfg, a, rb)))
-            ctx = o.transpose(0, 2, 1, 3).reshape(B, s, -1)
+            # this layer's cache is latent rows: the flash kernel where its
+            # sizes allow (keys, values and scores stay in VMEM), else
+            # afmoe.attend(expand=mla_decompress), its reference
+            o = mla_chunk_attention(
+                q[0], latent[:, 0, 0],
+                a["wkvb"].reshape(cfg.mla_kv_rank, cfg.num_heads, -1), start,
+                nope=cfg.mla_nope_dim, scale=_mla_scale(cfg), layer=i_lat,
+                impl=impl)
+            ctx = o.reshape(B, s, -1)
             i_lat += 1
         x = afmoe.mlp_block(cfg, lp, x, ctx @ a["wo"].astype(ctx.dtype),
                             None if le is None else experts, le)

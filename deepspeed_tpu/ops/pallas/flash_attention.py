@@ -40,6 +40,13 @@ accumulates dK/dV over Q strips.
 prefill chunk of an EVA model (``models/eva.py``): the chunk's queries over
 the slot's window rows up to each query and the summary rows of the windows
 before, one online softmax over both runs of strips.
+
+:func:`mla_chunk_attention` (forward only) is the schedule again for a
+prefill chunk of a latent-attention layer (``models/kda_mla.py``): the cache
+holds one compressed row a position, and each strip of rows is decompressed
+to a head's keys and values on chip, right before its scores; the grid walks
+blocks of rows only up to the chunk's last query (its extent is read at run
+time).  Its jnp reference is ``models/afmoe.py:attend(expand=)``.
 """
 
 from __future__ import annotations
@@ -1003,3 +1010,267 @@ def eva_chunk_attention(q, kview, vview, start, *, window: int, chunk: int,
     )(start.reshape(1), q.astype(kview.dtype).reshape(BH, s, D),
       kview.reshape(BH, rows, D), vview.reshape(BH, rows, D))
     return o.reshape(B, H, s, D)
+
+
+# ---------------------------------------------------------------------------
+# latent-attention (MLA) prefill-chunk attention (models/kda_mla.py), forward
+# only: afmoe.attend(expand=) with the scores and the decompressed keys and
+# values never leaving VMEM
+# ---------------------------------------------------------------------------
+
+# float32 scores of ONE strip a body of the latent chunk kernel may make
+# (2 MiB): it sets the rows of a strip under the chunk, which take no mask
+# and are the longer the fewer the chunk's queries
+_MLA_SCORES = 512 * 1024
+
+
+class _MlaPlan(NamedTuple):
+    bq: int       # the chunk's queries, padded to whole lane tiles: ONE tile
+    sk: int       # rows of a strip of the chunk's own rows (the masked ones)
+    sp: int       # rows of a strip under the chunk (whole ``sk`` strips)
+    kb: int       # cache rows a grid step holds (whole ``sp`` strips)
+    hb: int       # heads a grid step (0: not even one fits)
+
+
+def _mla_plan(s: int, rows: int, H: int, kv: int, n: int, r: int, v: int,
+              row_width: int, itemsize: int) -> _MlaPlan:
+    bq = round_up(s, _LANES)
+    sk = sp = _strip(bq)
+    kb = pick_block(rows, max(DEFAULT_BLOCK_K, sk), minimum=sk)
+    while kb % (2 * sp) == 0 and 2 * sp * bq <= _MLA_SCORES:
+        sp *= 2
+    # a head's q and o tiles and W_kvb columns (double-buffered), its m, l
+    # rows and acc scratch; beside them the block of cache rows
+    per_head = (bq * 2 * (round_up(n + r, 16) + v) * itemsize
+                + bq * (v * 4 + 2 * 8 * 4) + 2 * kv * (n + v) * itemsize)
+    room = _VMEM_BLOCK_BYTES - 2 * kb * row_width * itemsize
+    return _MlaPlan(bq, sk, sp, kb,
+                    max([d for d in range(1, H + 1)
+                         if H % d == 0 and d * per_head <= room], default=0))
+
+
+def mla_chunk_reference_reason(s: int, rows: int, H: int, kv: int, n: int,
+                               r: int, v: int, row_width: int,
+                               itemsize: int = 2) -> Optional[str]:
+    """Why :func:`mla_chunk_attention` cannot take these sizes (None = it
+    can): ``s`` queries of ``H`` heads of ``n + r`` over a view of ``rows``
+    cache rows ``row_width`` wide, latent ``kv``, values of ``v``.  The
+    latent, the heads' two parts of ``W_kvb``, the row and the view are
+    whole 128-lane tiles (the rotary part is the tail of a tile), the chunk
+    is one Q tile, and a head's blocks wait in VMEM beside a block of
+    rows."""
+    for what, d in (("latent rank", kv), ("nope dim", n), ("value dim", v),
+                    ("row width", row_width), ("view", rows)):
+        if d <= 0 or d % _LANES:
+            return f"{what} of {d} is not a multiple of the 128-lane tile"
+    if not 0 < r <= row_width - kv:
+        return (f"rotary part of {r} does not fit the {row_width - kv} values "
+                f"a row of {row_width} leaves beside the latent's {kv}")
+    if not 0 < s <= DEFAULT_BLOCK_Q:
+        return f"chunk of {s} queries is not one Q tile of {DEFAULT_BLOCK_Q}"
+    if not _mla_plan(s, rows, H, kv, n, r, v, row_width, itemsize).hb:
+        return (f"one head's blocks and a block of {row_width}-wide rows take "
+                f"over the {_VMEM_BLOCK_BYTES} bytes of VMEM of a grid step")
+    return None
+
+
+def _mla_bounds(start, p: _MlaPlan, rows: int):
+    """What the queries at positions ``start .. start + p.bq - 1`` walk of a
+    view of ``rows`` cache rows (Python ints or traced scalars): ``long``
+    strips of ``p.sp`` rows, then strips of ``p.sk`` rows up to strip
+    ``plain``, lie wholly under the first query and take no mask;
+    ``aligned``: ``start`` is a strip's first row, so the chunk's own rows
+    are :func:`_strips`' trimmed tile (each ``p.sk`` strip against the
+    queries from its own first row on); otherwise strips ``[plain, visit)``
+    each take every query, masked.  No row at or past ``visit * p.sk`` is
+    fetched or computed: the grid walks the first ``blocks`` blocks of
+    ``p.kb`` rows."""
+    visit = _clip((start + p.bq + p.sk - 1) // p.sk, 0, rows // p.sk)
+    return (start // p.sp, start // p.sk, start % p.sk == 0, visit,
+            (visit * p.sk + p.kb - 1) // p.kb)
+
+
+def mla_chunk_schedule(start: int, s: int, rows: int, *, heads: int, kv: int,
+                       nope: int, rot: int, v_dim: int, row_width: int,
+                       itemsize: int = 2,
+                       impl: Optional[str] = None) -> Dict[str, object]:
+    """What one latent layer's chunk attention walks for the ``s`` queries
+    at positions ``start ..`` over a view of ``rows`` cache rows.
+    ``visited``: the rows decompressed to per-head keys and values, from the
+    bounds the kernel's grid and loops take (whole strips up to the padded
+    chunk's last query), or, where ``reason`` says why the kernel does not
+    run (its sizes, or ``impl``, resolved as the call resolves it), what
+    :func:`afmoe.attend` visits: whole key blocks (``afmoe.keys_visited``)."""
+    from deepspeed_tpu.models import afmoe
+
+    reason = mla_chunk_reference_reason(s, rows, heads, kv, nope, rot, v_dim,
+                                        row_width, itemsize)
+    if reason is None and resolve_impl(impl) == "xla":
+        reason = "impl is xla"
+    if reason is not None:
+        return {"visited": afmoe.keys_visited(rows, start + s),
+                "reason": reason}
+    p = _mla_plan(s, rows, heads, kv, nope, rot, v_dim, row_width, itemsize)
+    *_, visit, blocks = _mla_bounds(start, p, rows)
+    return {"visited": visit * p.sk, "reason": None, "block_q": p.bq,
+            "strip": p.sk, "strip_under_the_chunk": p.sp,
+            "rows_per_step": p.kb, "heads_per_step": p.hb,
+            "grid_steps": heads // p.hb * blocks}
+
+
+def _mla_chunk_kernel(at_ref, q_ref, rows_ref, w_ref, o_ref, m_scr, l_scr,
+                      acc_scr, *, scale, p, rows, kv, n, r, v):
+    bq, sk, sp, kb, hb = p
+    c, start = pl.program_id(1), at_ref[0]
+    long, plain, aligned, visit, blocks = _mla_bounds(start, p, rows)
+    lo, hi = c * (kb // sk), (c + 1) * (kb // sk)    # this step's strips
+    diagonal = _bodies(bq, bq, True, False, kv_dim=0)[True]
+    rel = _rel(sk, bq, 0)                        # (cache row) - (query)
+
+    def head(h, carry):
+        pl.when(c == 0)(lambda: _softmax_init(h, m_scr, l_scr, acc_scr))
+        qt = q_ref[h]                                          # [n + r, bq]
+        cols = pl.ds(pl.multiple_of(h * (n + v), _LANES), n + v)
+
+        def block(row0, kv_n=sk, q_lo=0, q_n=bq, rel=None, d=0):
+            """The ``kv_n`` rows from this step's row ``row0`` against the
+            tile's queries ``[q_lo, q_lo + q_n)``, keeping ``rel <= d``
+            where masked: decompressed as ``mla_decompress`` does it
+            (float32 sums rounded to the cache's dtype), then ``attend``'s
+            block."""
+            at = pl.ds(pl.multiple_of(row0, _LANES), kv_n)
+            kn_v = lax.convert_element_type(
+                _dot(rows_ref[at, :kv], w_ref[:, cols], _NN), qt.dtype)
+            k = lax.concatenate([_part(kn_v, 1, 0, n),
+                                 rows_ref[at, kv:kv + r]], 1)  # [kv_n, n + r]
+            st = _dot(k, _part(qt, 1, q_lo, q_n), _NN) * scale
+            st = _bias_and_mask(st, rel, None, d, rel is not None)
+            _softmax_step(st, _part(kn_v, 1, n, v), h,
+                          slice(q_lo, q_lo + q_n), m_scr, l_scr, acc_scr)
+
+        # 1. the rows under the chunk: long strips, then (where the chunk
+        #    does not start at a long strip's first row) the rest by sk
+        _run(c * (kb // sp), _clip(long, c * (kb // sp), (c + 1) * (kb // sp)),
+             lambda u, _: block(u * sp - c * kb, sp), False)
+        if sp != sk:
+            _run(_clip(long * (sp // sk), lo, hi), _clip(plain, lo, hi),
+                 lambda t, _: block((t - lo) * sk), False)
+        # 2. the chunk's own rows: the trimmed tile, or masked strips
+        for kv_lo, _, q_lo, q_n, tri in diagonal:
+            row = start + kv_lo
+            pl.when(aligned & (row // kb == c))(
+                lambda row=row, q_lo=q_lo, q_n=q_n, tri=tri: block(
+                    row - c * kb, sk, q_lo, q_n, tri, 0))
+        _run(_clip(plain, lo, hi),
+             _clip(jnp.where(aligned, plain, visit), lo, hi),
+             lambda t, _: block((t - lo) * sk, rel=rel, d=start - t * sk),
+             True)
+
+        @pl.when(c == blocks - 1)
+        def _finish():
+            o_ref[:, pl.ds(pl.multiple_of(h * v, _LANES), v)] = jnp.transpose(
+                acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, hb, head, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("nope", "scale", "interpret"))
+def _mla_chunk_call(q, rows, wkvb, at, *, nope: int, scale: float,
+                    interpret: bool):
+    """The kernel on ``at`` = (start, layer), both traced: jitted of its own
+    so that a chunk program's latent layers, which differ in ``layer``
+    alone, are ONE traced and lowered function called five times (a call
+    site costs 0.1-0.2 s of tracing and lowering that no cache keeps, and a
+    serve process lowers every layer of every bucket at set-up)."""
+    s, H, D = q.shape
+    L, P, W = rows.shape
+    kv, n, v = wkvb.shape[0], nope, wkvb.shape[2] - nope
+    p = _mla_plan(s, P, H, kv, n, D - n, v, W, rows.dtype.itemsize)
+    qt = q.astype(rows.dtype).transpose(1, 2, 0)           # [H, n + r, s]
+    if p.bq != s:
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, p.bq - s)))
+    by_head = lambda g, c, at_ref: (g, 0, 0)
+    head_cols = lambda g, c, at_ref: (0, g)
+    o = pl.pallas_call(
+        functools.partial(_mla_chunk_kernel, scale=scale, p=p, rows=P, kv=kv,
+                          n=n, r=D - n, v=v),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H // p.hb, _mla_bounds(at[0], p, P)[4]),
+            in_specs=[pl.BlockSpec((p.hb, D, p.bq), by_head),
+                      pl.BlockSpec((p.kb, W), lambda g, c, at_ref: (
+                          at_ref[1] * (P // p.kb) + c, 0)),
+                      pl.BlockSpec((kv, p.hb * (n + v)), head_cols)],
+            out_specs=pl.BlockSpec((p.bq, p.hb * v), head_cols),
+            scratch_shapes=[pltpu.VMEM((p.hb, 1, p.bq), jnp.float32),
+                            pltpu.VMEM((p.hb, 1, p.bq), jnp.float32),
+                            pltpu.VMEM((p.hb, v, p.bq), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((p.bq, H * v), qt.dtype),
+        interpret=interpret,
+        name="mla_chunk_attention",
+    )(at, qt, rows.reshape(L * P, W),
+      wkvb.astype(rows.dtype).reshape(kv, -1))
+    return o[:s].reshape(s, H, v)
+
+
+def mla_chunk_attention(q, rows, wkvb, start, *, nope: int, scale: float,
+                        layer: int = 0, impl: Optional[str] = None):
+    """Latent attention of one prefill chunk (``models/kda_mla.py``): what
+    ``afmoe.attend(expand=mla_decompress)`` computes, in one kernel that
+    decompresses each strip of cache rows on chip, so that neither the
+    per-head keys and values nor the scores leave VMEM.  q [s, H, n + r]
+    (as ``mla_project`` returns it, rotated where the model rotates) at
+    positions ``start .. start + s - 1`` (``start`` a traced scalar);
+    ``rows`` [layers, positions, row_width] ONE slot's cache rows ``[c | k_r
+    | 0]``, of which layer ``layer``'s are attended, with the chunk's own
+    rows written (the whole view goes in and the index map picks the layer:
+    a slice of it would be copied first, 21 MB a layer a chunk in A.X-K1's
+    cell); ``wkvb`` [kv, H, n + v], a head's first ``nope`` = n columns its
+    keys, the rest its values (``kda_mla._wkvb``'s split).  Returns [s, H,
+    v], what ``W_o`` takes.
+
+    A grid step holds ``hb`` heads' queries (the chunk padded to whole lane
+    tiles: one Q tile; TRANSPOSED, ``[n + r, s]`` a head, which is how XLA
+    lays the query projection out for the reference's own matmul, so the
+    transposition costs nothing and the scores are a plain ``k q^T``), their
+    columns of ``W_kvb`` and of the output, and one block of rows, and walks
+    the block's strips (:func:`_mla_bounds`); the grid's second extent is
+    read at run time, the blocks up to the one the chunk's last query sees,
+    so rows past the prompt cost no grid step, no fetch and no arithmetic.
+    Per head and strip, in ``attend``'s and ``mla_decompress``'s precisions
+    and order of roundings: ``[k_n | v] = c W_kvb[h]`` summed in float32 and
+    ROUNDED TO THE CACHE'S DTYPE; the scores ``[k_n | k_r] q^T`` in float32,
+    times ``scale``, masked by cache row against query position in the
+    strips that hold the chunk's own rows only; the online softmax in
+    float32; ``p`` ROUNDED TO THE CACHE'S DTYPE for ``p v``, summed in
+    float32; the quotient rounded once, to ``q``'s dtype.  What differs from
+    ``attend`` is the step of the online softmax (a strip, not
+    ``KEY_BLOCK``), so the running maximum at which ``p`` is rounded.
+
+    ``afmoe.attend`` is the reference: it runs for ``impl="xla"`` (the CPU)
+    and for the sizes :func:`mla_chunk_reference_reason` names."""
+    from deepspeed_tpu.models import afmoe, kda_mla
+
+    impl = resolve_impl(impl)
+    s, H, D = q.shape
+    _, P, W = rows.shape
+    kv, n, v = wkvb.shape[0], nope, wkvb.shape[2] - nope
+    start = jnp.asarray(start, jnp.int32)
+    impl = kernel_or_reference(
+        "mla_chunk_attention", impl, mla_chunk_reference_reason(
+            s, P, H, kv, n, D - n, v, W, rows.dtype.itemsize))
+    if impl == "xla":
+        wk, wv = wkvb[..., :n], wkvb[..., n:]
+        return afmoe.attend(
+            q.transpose(1, 0, 2)[None],
+            [(rows[layer][None, None], None, jnp.arange(P))],
+            start + jnp.arange(s), window=0, scale=scale, live_keys=start + s,
+            expand=lambda rb: tuple(
+                t[:, 0].transpose(0, 2, 1, 3)
+                for t in kda_mla.mla_decompress(rb, wk, wv, D - n))
+        )[0].transpose(1, 0, 2)
+    return _mla_chunk_call(q, rows, wkvb, jnp.stack([start, jnp.int32(layer)]),
+                           nope=nope, scale=scale,
+                           interpret=interpret_flag(impl))

@@ -34,7 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models import afmoe, kda_mla
-from deepspeed_tpu.ops.pallas.flash_attention import eva_chunk_schedule
+from deepspeed_tpu.ops.pallas.flash_attention import (eva_chunk_schedule,
+                                                      mla_chunk_schedule)
 from deepspeed_tpu.serving.paged_kv import init_paged_kv_cache
 
 
@@ -429,8 +430,9 @@ class LatentPages(FullPages):
         "ds_serve_mla_rows_expanded_total":
             "(latent row, latent layer) pairs the prefill chunk programs "
             "decompressed to per-head keys and values: a chunk expands every "
-            "earlier row of its request again, a key block at a time "
-            "(models/afmoe.py:attend(expand=))",
+            "earlier row of its request again, a strip of rows at a time "
+            "(ops/pallas/flash_attention.py:mla_chunk_attention; a key block "
+            "where models/afmoe.py:attend(expand=) runs in its place)",
         "ds_serve_mla_rows_written_total":
             "(latent row, latent layer) pairs the prefill chunk programs "
             "wrote for the real tokens of their chunks",
@@ -460,13 +462,18 @@ class LatentPages(FullPages):
 
     def count_chunk(self, pool, cache, off, c, cb):
         """``ds_serve_mla_rows_*``: the rows the chunk's attention visits
-        (whole key blocks up to the bucket's end, ``afmoe.keys_visited``),
-        each decompressed once a latent layer, and the real rows it adds."""
+        (``mla_chunk_schedule``: the kernel's strips up to the bucket's end,
+        or whole key blocks where ``afmoe.attend`` runs), each decompressed
+        once a latent layer, and the real rows it adds."""
         if not self._reg.enabled:
             return
-        layers = cache["latent"].shape[0]
-        self._m["ds_serve_mla_rows_expanded_total"].inc(layers * int(
-            afmoe.keys_visited(pool.slot_pages * pool.page, off + cb)))
+        cfg, (layers, *_, width) = self.cfg, cache["latent"].shape
+        self._m["ds_serve_mla_rows_expanded_total"].inc(
+            layers * mla_chunk_schedule(
+                off, cb, pool.slot_pages * pool.page, heads=cfg.num_heads,
+                kv=cfg.mla_kv_rank, nope=cfg.mla_nope_dim,
+                rot=cfg.mla_rot_dim, v_dim=cfg.mla_v_dim, row_width=width,
+                itemsize=cache["latent"].dtype.itemsize)["visited"])
         self._m["ds_serve_mla_rows_written_total"].inc(layers * c)
 
 

@@ -24,6 +24,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, afmoe, kda_mla
+from deepspeed_tpu.ops.pallas.flash_attention import mla_chunk_schedule
 from deepspeed_tpu.serving import cache_kind
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
@@ -251,7 +252,8 @@ def test_absorbed_latent_attention_is_the_decompressed(model):
     scale = kda_mla._mla_scale(cfg)
     assert scale == pytest.approx(1.81326 / math.sqrt(24), rel=1e-5)
     with jax.default_matmul_precision("highest"):
-        k, v = kda_mla.mla_decompress(cfg, a, rows)            # [3, 41, H, .]
+        k, v = kda_mla.mla_decompress(rows, *kda_mla._wkvb(cfg, a),
+                                      cfg.mla_rot_dim)         # [3, 41, H, .]
         s = jnp.einsum("bhd,bjhd->bhj", q, k) * scale
         want = jnp.einsum("bhj,bjhv->bhv", jax.nn.softmax(s, -1), v)
         qa = kda_mla.mla_absorb(cfg, a, q)
@@ -438,6 +440,38 @@ def test_fused_layers_through_the_kernels_match_their_references(live):
                 != np.asarray(cache["latent"][:, 5, 0, 2], np.float32)).any()
 
 
+@pytest.mark.parametrize("start,valid", [(0, 16), (48, 11), (128, 16)],
+                         ids=["at_zero", "inside_a_strip", "at_a_strip"])
+def test_cached_layers_through_the_chunk_kernel_match_the_jnp_path(start,
+                                                                   valid):
+    """A chunk program's layer stack with ``mla_chunk_attention`` in
+    interpret mode against the same stack on ``afmoe.attend(expand=)``, its
+    jnp reference, at tile widths (a latent row of 128 + 64 padded to 256,
+    heads of 128 + 64 with values of 128, a view of 256 rows with earlier
+    rows in it): the stream and the rows written agree."""
+    from deepspeed_tpu.ops.pallas.common import reference_selections
+
+    cfg = ModelConfig(**dict(
+        FIELDS, hidden_size=128, num_layers=2, num_dense_layers=1,
+        layer_types=("latent_attention",) * 2, mla_kv_rank=128,
+        mla_nope_dim=128, mla_rot_dim=64, mla_v_dim=128, mla_q_rank=256,
+        intermediate_size=128, dense_intermediate_size=256))
+    params = kda_mla.init_params(cfg, jax.random.PRNGKey(9))
+    ks = jax.random.split(jax.random.PRNGKey(11), 2)
+    cache = {"latent": jax.random.normal(ks[0], (2, 1, 1, 256, 256))}
+    x = jax.random.normal(ks[1], (1, 16, 128))
+    before = len(reference_selections())
+    run = lambda impl: kda_mla.cached_layers(cfg, params, x, cache, start,
+                                             valid, impl=impl)
+    (x_ref, c_ref), (x_k, c_k) = run("xla"), run("interpret")
+    assert len(reference_selections()) == before     # the kernel itself ran
+    np.testing.assert_allclose(x_k, x_ref, rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(c_k["latent"], c_ref["latent"], rtol=2e-4,
+                               atol=2e-4)
+    assert (np.asarray(c_k["latent"][:, 0, 0, start:start + 16])
+            != np.asarray(cache["latent"][:, 0, 0, start:start + 16])).any()
+
+
 # --------------------------------------------------------- the cache kind
 def test_a_latent_only_model_has_pages_and_no_slot_state(model):
     m, params = model
@@ -598,9 +632,17 @@ def test_counters_count_expanded_rows_and_kept_groups(model):
     snap = {k: v for k, v in reg.snapshot().items()
             if isinstance(v, (int, float))}
     # five latent layers; chunks of 16, 16 and 8 real rows; the slot's view
-    # is 96 rows, one key block, which every chunk expands whole
+    # is 96 rows, one key block, which every chunk expands whole: the counter
+    # reads mla_chunk_schedule, and on the CPU (and at these widths on any
+    # device) the schedule is that of afmoe.attend, the loop that runs
     assert snap["ds_serve_mla_rows_written_total"] == 5 * 40
     assert snap["ds_serve_mla_rows_expanded_total"] == 5 * 3 * 96
+    sch = lambda off, cb, **kw: mla_chunk_schedule(
+        off, cb, 96, heads=4, nope=16, rot=8, v_dim=16, row_width=128,
+        itemsize=4, kv=32, **kw)
+    assert [sch(off, cb)["visited"] for off, cb in
+            ((0, 16), (16, 16), (32, 8))] == [96] * 3
+    assert "latent rank of 32" in sch(0, 16, impl="pallas")["reason"]
     # 20 decode steps x 4 expert layers x 4 choices offered
     offered = snap["ds_serve_moe_assignments_total"]
     assert offered == 20 * 4 * 4
@@ -611,6 +653,13 @@ def test_counters_count_expanded_rows_and_kept_groups(model):
     assert "ds_serve_state_row_steps_total" in snap       # every kind's series
     assert snap["ds_serve_state_row_steps_total"] == 0
     serve.close()
+    # at the cell's widths the kernel's strips: a last chunk's bucket of 256
+    # at 7,168 stops at 7,424 where attend's key block ends at 8,192
+    cell = lambda off, cb, impl: mla_chunk_schedule(
+        off, cb, 16384, heads=64, kv=512, nope=128, rot=64, v_dim=128,
+        row_width=640, impl=impl)["visited"]
+    assert (cell(7168, 256, "pallas"), cell(7168, 256, "xla")) == (7424, 8192)
+    assert cell(7168, 1024, "pallas") == cell(7168, 1024, "xla") == 8192
     # the host's copy of attend's loop bound, by hand: key blocks of 1,024
     assert afmoe.keys_visited(16384, 1024) == 1024
     assert afmoe.keys_visited(16384, 1025) == 2048

@@ -254,7 +254,8 @@ def test_absorbed_latent_attention_is_the_decompressed(model):
     assert not np.asarray(rows[..., 40:]).any()                # the padding
     rows = jnp.concatenate([rows, row[:, None]], axis=1)       # own row last
     with jax.default_matmul_precision("highest"):
-        k, v = kda_mla.mla_decompress(cfg, a, rows)            # [3, 41, H, .]
+        k, v = kda_mla.mla_decompress(rows, *kda_mla._wkvb(cfg, a),
+                                      cfg.mla_rot_dim)         # [3, 41, H, .]
         s = jnp.einsum("bhd,bjhd->bhj", q, k) * 24 ** -0.5
         want = jnp.einsum("bhj,bjhv->bhv", jax.nn.softmax(s, -1), v)
         qa = kda_mla.mla_absorb(cfg, a, q)
@@ -345,6 +346,35 @@ def test_fused_layers_through_the_kernels_match_their_references(live):
     # updates every slot and keeps the old state where a row is parked
     assert list(s_k[4]) == [len(rows)] * 2
     assert list(s_ref[4]) == [len(rows), 3]
+
+
+@pytest.mark.parametrize("start,valid", [(0, 16), (48, 11)],
+                         ids=["at_zero", "inside_a_strip"])
+def test_cached_layers_through_the_chunk_kernel_match_the_jnp_path(start,
+                                                                   valid):
+    """A chunk program's layer stack (a linear layer, then a latent one,
+    unrotated) with ``mla_chunk_attention`` in interpret mode against the
+    same stack on ``afmoe.attend(expand=)``, at tile widths: the stream, the
+    rows written and the carried state agree."""
+    cfg = ModelConfig(**dict(
+        FIELDS, hidden_size=128, num_layers=2, num_dense_layers=1,
+        layer_types=("linear_attention", "latent_attention"),
+        mla_kv_rank=128, mla_nope_dim=128, mla_rot_dim=64, mla_v_dim=128,
+        intermediate_size=128, dense_intermediate_size=256))
+    params = kda_mla.init_params(cfg, jax.random.PRNGKey(9))
+    ks = jax.random.split(jax.random.PRNGKey(11), 4)
+    state, tail = kda_mla.state_shapes(cfg, 1)
+    cache = {"latent": jax.random.normal(ks[0], (1, 1, 1, 256, 256)),
+             "state": jax.random.normal(ks[1], state),
+             "tail": jax.random.normal(ks[2], tail)}
+    x = jax.random.normal(ks[3], (1, 16, 128))
+    run = lambda impl: kda_mla.cached_layers(cfg, params, x, cache, start,
+                                             valid, impl=impl)
+    (x_ref, c_ref), (x_k, c_k) = run("xla"), run("interpret")
+    np.testing.assert_allclose(x_k, x_ref, rtol=2e-4, atol=2e-4)
+    for key in ("latent", "state", "tail"):
+        np.testing.assert_allclose(c_k[key], c_ref[key], rtol=2e-4,
+                                   atol=2e-4)
 
 
 def test_parked_rows_keep_their_state_across_a_decode_block(model):

@@ -389,6 +389,48 @@ def test_kernels_compile_at_the_evabyte_serve_doc_shape(v5e, kernel):
     assert _custom_calls(fn, v5e, *shapes) >= want
 
 
+# the two cells with latent layers: A.X-K1's 64 heads over a slot's view of
+# 16,384 rows of 640 (five latent layers), Kimi-Linear's 32 over 13,312 (one)
+LATENT_CELLS = {"axk1": dict(H=64, rows=16384, layers=5),
+                "kimi": dict(H=32, rows=13312, layers=1)}
+
+
+@pytest.mark.parametrize("bucket", [1024, 512, 256, 128, 8])
+@pytest.mark.parametrize("cell", sorted(LATENT_CELLS))
+def test_mla_chunk_attention_compiles_at_the_latent_cells_shapes(
+        v5e, cell, bucket):
+    """ISSUE 49: the latent layers' chunk attention at every kind of bucket
+    of the two cells (heads of 128 + 64 against one 640-value row, ``W_kvb``
+    ``[512, H, 256]``, the LAST layer of the slot's view; a bucket under the
+    lane tile pads its queries inside the call) is one Mosaic kernel: no
+    size runs the reference."""
+    from deepspeed_tpu.ops.pallas.common import reference_selections
+    from deepspeed_tpu.ops.pallas.flash_attention import mla_chunk_attention
+
+    w = LATENT_CELLS[cell]
+    before = len(reference_selections())
+    fn = lambda q, rows, wkvb, start: mla_chunk_attention(
+        q, rows, wkvb, start, nope=128, scale=192 ** -0.5,
+        layer=w["layers"] - 1, impl="pallas")
+    assert _custom_calls(
+        fn, v5e, ((bucket, w["H"], 192), BF16),
+        ((w["layers"], w["rows"], 640), BF16), ((512, w["H"], 256), BF16),
+        ((), I32)) == 1
+    assert len(reference_selections()) == before
+
+
+def _latent_chunk_kernels(program, heads, bucket):
+    """ISSUE 49: (calls of ``mla_chunk_attention`` in a chunk program, is
+    there a float32 score array ``[heads, bucket, KEY_BLOCK]`` that
+    ``afmoe.attend`` would have made, does any instruction copy a layer's
+    ``[rows, 640]`` out of the slot's view for it)."""
+    text = program.as_text()
+    return (len(re.findall(r"custom-call\([^\n]*mla_chunk_attention", text)),
+            bool(re.search(rf"f32\[(1,)?{heads},(1,)?{bucket},1024\]", text)),
+            bool(re.search(r"= bf16\[(16384|13312),640\]\S* (fusion|copy)\(",
+                           text)))
+
+
 class _ServeCell:
     """A serve cell of the benchmark as ``benchmarks/configs`` and
     ``benchmarks/workloads`` describe it (``fields`` / ``engine`` overridden
@@ -748,6 +790,8 @@ def test_kimi_linear_cell_programs_compile_with_state_and_pool_in_place(
     for program, tokens in zip(chunks, (1024, 64)):
         cell.assert_expert_rows_are_an_odd_number_of_tiles(
             program, tokens * 8)
+        # ISSUE 49: the one latent layer's chunk attention is the kernel
+        assert _latent_chunk_kernels(program, 32, tokens) == (1, False, False)
     for program in chunks + (cell.block(),):
         cell.assert_pools_stay_in_place(program)
         for kind, key in (("f32", "state"), ("bf16", "tail")):
@@ -784,6 +828,8 @@ def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
     for program, tokens in zip(chunks, (1024, 64)):
         cell.assert_expert_rows_are_an_odd_number_of_tiles(
             program, tokens * 8)
+        # ISSUE 49: a kernel a latent layer, no score array, no copied view
+        assert _latent_chunk_kernels(program, 64, tokens) == (2, False, False)
     block = cell.block()
     for program in chunks + (block,):
         cell.assert_pools_stay_in_place(program)
