@@ -50,6 +50,15 @@ families instead; `ModelConfig` spans them with feature flags:
   groups of experts before its top-k); ``models/kda_mla.py``;
   benchmarks/configs/kimi-linear-L5-ep8.json, axk1-L5-ep16.json).
   SERVED ONLY, one chip's share, seeded weights
+- Latent layers of TWO kinds (dots3-note-prev): sizes, head count and base
+  are a property of the layer kind (:meth:`ModelConfig.mla_kind`):
+  ``latent_attention`` layers that attend the ``mla_index_topk`` keys a
+  learned indexer selects (``mla_index_heads`` index heads of
+  ``mla_index_dim`` over a cache of index keys), ``latent_sliding_attention``
+  layers with sizes of their own (``mla_sliding``) over the last
+  ``sliding_window`` positions, a sigmoid gate a head (``mla_head_gate``)
+  and the low-rank rescale (``mla_lora_rescale``); the same module;
+  benchmarks/configs/dots3-note-L5-ep16.json.  SERVED ONLY likewise
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -161,6 +170,28 @@ class ModelConfig:
     # (2i, 2i + 1), YaRN frequencies (``factor`` 1: plain RoPE), and the
     # softmax scale times YaRN's factor squared (kda_mla.yarn)
     mla_rope: Optional[dict] = None
+    # a SECOND latent kind with sizes, head count and base of its own
+    # (``layer_types`` kind "latent_sliding_attention": keys j with 0 <= t -
+    # j < ``sliding_window``, its cache a per-slot RING of rows): a group
+    # ``{"num_heads", "kv_rank", "nope_dim", "rot_dim", "v_dim", "q_rank",
+    # "rope"}`` (``rope`` a group as ``mla_rope``, or None).  The fields
+    # above are the "latent_attention" kind's (:meth:`mla_kind`)
+    mla_sliding: Optional[dict] = None
+    # a learned selection of the "latent_attention" layers' keys (DeepSeek
+    # sparse attention): ``mla_index_heads`` index heads of ``mla_index_dim``
+    # score every earlier key from a cache of index keys of its own, and the
+    # layer attends the ``mla_index_topk`` best (all of them while there are
+    # no more); needs ``mla_q_rank`` (the index queries come from the
+    # query's bottleneck) and ``mla_rope``.  0: every earlier key
+    mla_index_heads: int = 0
+    mla_index_dim: int = 0
+    mla_index_topk: int = 0
+    # one sigmoid gate a HEAD on the head's output before ``W_o``, from the
+    # normed input (``afmoe``'s ``attn_output_gate`` is the elementwise one)
+    mla_head_gate: bool = False
+    # the normed bottlenecks times sqrt(hidden_size / rank): ``c_q`` and the
+    # latent ``c`` (the shared key values unscaled)
+    mla_lora_rescale: bool = False
     # RMSNorm multiplies by (1 + scale): the stored gain starts at 0
     norm_add_unit_offset: bool = False
     # the residual stream (and the logits) stay float32 whatever dtype the
@@ -324,13 +355,15 @@ class ModelConfig:
         module does not build."""
         sizes = {k: getattr(self, k) for k in _KDA_MLA_ONLY}
         if not self.is_kda_mla:
-            if any(sizes.values()) or self.mla_q_rank or self.mla_rope:
+            if any(sizes.values()) or any(
+                    getattr(self, k) for k in _MLA_FORMS):
                 raise ValueError(
                     f"{sorted(_KDA_MLA_ONLY + _MLA_FORMS)} belong to "
                     "linear_attention and latent_attention layers "
                     "(models/kda_mla.py)")
             return
-        used = {"linear_attention": "kda_", "latent_attention": "mla_"}
+        used = {"linear_attention": "kda_", "latent_attention": "mla_",
+                "latent_sliding_attention": "mla_sliding"}
         need = [k for k in _KDA_MLA_ONLY if sizes[k] < 1 and any(
             k.startswith(used[t]) for t in set(self.layer_types))]
         if need:
@@ -344,14 +377,86 @@ class ModelConfig:
                     f"mla_rope names {sorted(_MLA_ROPE_KEYS)} and rotates "
                     f"pairs of an even mla_rot_dim, got "
                     f"{sorted(self.mla_rope)}, mla_rot_dim={self.mla_rot_dim}")
+        self._check_mla_kinds()
         if self.sandwich_norm or self.qk_norm_per_head \
                 or self.attn_output_gate or self.embed_scale != 1.0 \
-                or self.sliding_window:
+                or (self.sliding_window and self.mla_sliding is None):
             raise ValueError(
                 "linear_attention and latent_attention layers "
                 "(models/kda_mla.py) are plain pre-norm: sandwich_norm, "
                 "qk_norm_per_head, attn_output_gate, embed_scale and "
                 "sliding_window belong to models/afmoe.py's two kinds")
+
+    def _check_mla_kinds(self):
+        """The second latent kind's group and the indexer's sizes."""
+        sliding = "latent_sliding_attention" in self.layer_types
+        if sliding != (self.mla_sliding is not None):
+            raise ValueError(
+                "latent_sliding_attention layers and the group mla_sliding "
+                f"({sorted(_MLA_SLIDING_KEYS)}) come together: a sliding "
+                "latent layer has sizes of its own")
+        if sliding:
+            self.mla_sliding = dict(self.mla_sliding)
+            extra = set(self.mla_sliding) - _MLA_SLIDING_KEYS
+            if any(k.startswith("index") for k in extra):
+                raise ValueError(
+                    f"mla_sliding names {sorted(extra)}: an indexer on a "
+                    "sliding layer is not built (a window attends every key "
+                    "it holds; mla_index_* select among a latent_attention "
+                    "layer's keys)")
+            if set(self.mla_sliding) != _MLA_SLIDING_KEYS:
+                raise ValueError(
+                    f"mla_sliding names {sorted(_MLA_SLIDING_KEYS)}, got "
+                    f"{sorted(self.mla_sliding)}")
+            need = [k for k in ("num_heads", "kv_rank", "nope_dim",
+                                "rot_dim", "v_dim")
+                    if int(self.mla_sliding[k]) < 1]
+            if need or self.sliding_window < 1:
+                raise ValueError(
+                    "latent_sliding_attention layers need sliding_window and "
+                    f"mla_sliding's sizes, missing: "
+                    f"{need or ['sliding_window']}")
+            if "linear_attention" in self.layer_types:
+                raise ValueError(
+                    "latent_sliding_attention beside linear_attention layers "
+                    "is not built (one per-slot array a model: a ring or a "
+                    "state)")
+        index = (self.mla_index_heads, self.mla_index_dim,
+                 self.mla_index_topk)
+        if any(index) and not (all(v > 0 for v in index) and self.mla_q_rank
+                               and self.mla_rope is not None
+                               and "latent_attention" in self.layer_types
+                               and self.mla_index_dim >= self.mla_rot_dim):
+            raise ValueError(
+                "mla_index_heads, mla_index_dim and mla_index_topk come "
+                "together, on latent_attention layers with mla_q_rank (the "
+                "index queries are made from the query's bottleneck) and "
+                "mla_rope (their first mla_rot_dim values are rotated), got "
+                f"{index}")
+
+    def mla_kind(self, layer_type: str) -> "MlaKind":
+        """The sizes, head count, base and forms of one KIND of latent
+        layer: what ``models/kda_mla.py``'s pieces read."""
+        D, eps = self.hidden_size, self.norm_eps
+        scale = lambda rank: (D / rank) ** 0.5 \
+            if self.mla_lora_rescale and rank else 1.0
+        if layer_type == "latent_attention":
+            index = (self.mla_index_heads, self.mla_index_dim,
+                     self.mla_index_topk) if self.mla_index_topk else None
+            return MlaKind(
+                "mla", self.num_heads, self.mla_kv_rank, self.mla_nope_dim,
+                self.mla_rot_dim, self.mla_v_dim, self.mla_q_rank,
+                self.mla_rope, 0, index, self.mla_head_gate,
+                scale(self.mla_q_rank), scale(self.mla_kv_rank), eps)
+        if layer_type == "latent_sliding_attention":
+            g = self.mla_sliding
+            return MlaKind(
+                "mla_sw", int(g["num_heads"]), int(g["kv_rank"]),
+                int(g["nope_dim"]), int(g["rot_dim"]), int(g["v_dim"]),
+                int(g["q_rank"]), g["rope"], self.sliding_window, None,
+                self.mla_head_gate, scale(int(g["q_rank"])),
+                scale(int(g["kv_rank"])), eps)
+        raise ValueError(f"{layer_type!r} is not a latent kind")
 
     @property
     def has_mlp_bias(self) -> bool:
@@ -383,14 +488,44 @@ class ModelConfig:
         return (self.num_layers - self.num_dense_layers) if self.is_moe else 0
 
 
+@dataclasses.dataclass(frozen=True)
+class MlaKind:
+    """One kind of latent layer (:meth:`ModelConfig.mla_kind`)."""
+    stack: str               # its parameter stack: params[stack]
+    heads: int
+    kv: int                  # the latent's rank
+    nope: int
+    rot: int
+    v: int
+    q_rank: int              # 0: a full-rank query
+    rope: Optional[dict]     # None: the rotary values pass unrotated
+    window: int              # 0: every earlier key
+    index: Optional[tuple]   # (index heads, their size, keys selected)
+    gate: bool               # a sigmoid a head on the head's output
+    q_scale: float           # on the normed query bottleneck
+    kv_scale: float          # on the normed latent
+    eps: float
+
+    @property
+    def row_width(self) -> int:
+        """A cache row: ``kv + rot`` values padded to whole 128-lane
+        tiles."""
+        return -(-(self.kv + self.rot) // 128) * 128
+
+
 _WINDOW_KINDS = frozenset({"sliding_attention", "full_attention"})
-_STATE_KINDS = frozenset({"linear_attention", "latent_attention"})
+_STATE_KINDS = frozenset({"linear_attention", "latent_attention",
+                          "latent_sliding_attention"})
+_MLA_SLIDING_KEYS = frozenset({"num_heads", "kv_rank", "nope_dim", "rot_dim",
+                               "v_dim", "q_rank", "rope"})
 # fields only models/kda_mla.py reads
 _KDA_MLA_ONLY = ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
                  "kda_gate_rank", "mla_kv_rank", "mla_nope_dim",
                  "mla_rot_dim", "mla_v_dim")
 # ... and the forms of a latent layer that a model may leave at their zero
-_MLA_FORMS = ("mla_q_rank", "mla_rope")
+_MLA_FORMS = ("mla_q_rank", "mla_rope", "mla_sliding", "mla_index_heads",
+              "mla_index_dim", "mla_index_topk", "mla_head_gate",
+              "mla_lora_rescale")
 _MLA_ROPE_KEYS = frozenset({
     "theta", "factor", "original_max_position_embeddings", "beta_fast",
     "beta_slow", "mscale", "mscale_all_dim"})

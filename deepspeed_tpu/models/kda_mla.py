@@ -1,9 +1,11 @@
 """Linear-attention and latent-attention layers (moonshotai Kimi-Linear:
 both kinds, the latent one without a position encoding; skt A.X-K1: latent
-layers only, rotated, with a low-rank query; ``ModelConfig.layer_types``):
-the layer form of ``models/afmoe.py`` (a static pattern, leading dense
-layers, the sigmoid router over ONE CHIP'S SHARE of the experts) over two
-further attention kinds, plain pre-norm.
+layers only, rotated, with a low-rank query; dots-studio dots3-note-prev:
+latent layers of TWO kinds, one under a learned selection of its keys, one
+under a window; ``ModelConfig.layer_types``): the layer form of
+``models/afmoe.py`` (a static pattern, leading dense layers, the sigmoid
+router over ONE CHIP'S SHARE of the experts) over further attention kinds,
+plain pre-norm.
 
 With ``N(.)`` RMSNorm (own gain, ``norm_eps``), ``x`` the stream, ``h =
 N_in(x)``:
@@ -39,10 +41,26 @@ N_in(x)``:
         cache row of token j: [c(j) | k_r(j)] (k_r as the scores use it:
             rotated where the model rotates), shared by all heads
 
+    A latent layer's sizes, head count and base are its KIND's
+    (``ModelConfig.mla_kind(layer type)``, an ``MlaKind``), and a kind may add:
+        rescale:  c_q and c times sqrt(hidden / rank) after their norms
+        a gate:   a = concat_h(sigmoid(h Wg)_h o_h) Wo,  Wg [D, H]
+        an indexer ("latent_attention" with ``mla_index_*``; G index heads
+        of d, their first ``rot`` values rotated as k_r is):
+            qI_g = c_q W_Iq;  kI = LN(h W_Ik) (gain and bias);
+            w = h W_Iw G^-0.5 d^-0.5
+            I(t, j) = sum_g w_g(t) relu(qI_g(t) . kI(j)),  j <= t
+            the softmax runs over the ``topk`` keys of largest I(t, j) only
+            (all of them while t + 1 <= topk); kI(j) is cached beside the row
+        a window ("latent_sliding_attention"): keys 0 <= t - j < window
+
 Each piece is ONE function here (:func:`short_conv`, :func:`kda_activate`,
 :func:`kda_step` / :func:`kda_chunk`, :func:`kda_out`; :func:`mla_project`,
 :func:`mla_query`, :func:`mla_row`, :func:`rotate`, :func:`mla_decompress`,
-:func:`mla_absorb` / :func:`mla_unabsorb`) and the
+:func:`mla_absorb` / :func:`mla_unabsorb`,
+:func:`head_gate`; :func:`index_key`, :func:`index_query`,
+:func:`select_keys` / :func:`select_positions`; :func:`ring_before`,
+:func:`ring_after`, :func:`window_attention`, :func:`ring_decode`) and the
 three forwards call them, as ``afmoe.py``'s do; the expert block, the pattern
 loop's parameter stacks and the fused path's layer end are ``afmoe``'s own
 functions.  The attention parameters are two stacks by KIND (``params["kda"]``
@@ -68,7 +86,21 @@ per-head keys, values and scores stay in VMEM; ``afmoe.attend(expand=)``,
 its reference, where the kernel does not run: the CPU, widths that are not
 lane tiles).  A decode step attends in the absorbed form: ``q'_h = [q_n,h
 Wkvb_k,h^T | q_r,h]`` against the rows, the context ``sum_j p_j c(j)``
-through ``Wkvb_v,h``.
+through ``Wkvb_v,h``.  Under an indexer the latent pages carry ``index``
+pages of index keys under the same table: a chunk scores every key up to
+each of its queries (``dsa_index_scores_chunk``), finds each query's
+``topk``-th score by bisection and attends under the mask
+(``dsa_chunk_attention``: every row up to the chunk's last query is
+decompressed and scored, because the selection differs by query); a decode
+step scores its row's pages (``dsa_index_scores_paged``), takes a top-k and
+attends the rows at those POSITIONS, gathered out of the pool
+(``dsa_decode_selected``).  A sliding latent layer keeps a per-slot ``ring``
+``[sliding layers, slots, ring rows, row width]``: the row of position p at
+``p % ring rows``, attended where the position a ring row holds lies in the
+window; a chunk attends the ring's rows before it and its own, then writes
+its last real rows.  An exact tie at the edge of a top-k is kept by a chunk
+and cut by index order in a decode step; neither happens with 64 index heads
+of float32 sums.
 """
 
 from __future__ import annotations
@@ -82,7 +114,8 @@ import numpy as np
 
 from deepspeed_tpu.models import afmoe
 from deepspeed_tpu.models.afmoe import F32, refuse_parallel, rms
-from deepspeed_tpu.ops.pallas.flash_attention import mla_chunk_attention
+from deepspeed_tpu.ops.pallas.flash_attention import (
+    dsa_chunk_attention, dsa_index_scores_chunk, mla_chunk_attention)
 
 SUB = 64                  # tokens a sub-chunk of the chunkwise delta rule
 HI = jax.lax.Precision.HIGHEST
@@ -95,16 +128,29 @@ def is_linear(cfg, l: int) -> bool:
 
 
 def kind_layers(cfg):
-    """(indices of the linear layers, indices of the latent layers)."""
+    """(indices of the linear layers, indices of the latent layers whose
+    rows live in pages: "latent_attention")."""
     L = range(cfg.num_layers)
     return ([l for l in L if is_linear(cfg, l)],
-            [l for l in L if not is_linear(cfg, l)])
+            [l for l in L if cfg.layer_types[l] == "latent_attention"])
+
+
+def sliding_layers(cfg):
+    """Indices of the "latent_sliding_attention" layers (rows in a ring)."""
+    return [l for l in range(cfg.num_layers)
+            if cfg.layer_types[l] == "latent_sliding_attention"]
 
 
 def row_width(cfg) -> int:
-    """A latent page's row: ``mla_kv_rank + mla_rot_dim`` values padded to
-    whole 128-lane tiles."""
-    return -(-(cfg.mla_kv_rank + cfg.mla_rot_dim) // 128) * 128
+    """A latent page's row (``MlaKind.row_width`` of the paged kind)."""
+    return cfg.mla_kind("latent_attention").row_width
+
+
+def ring_rows(cfg, page: int) -> int:
+    """Rows of a sliding latent layer's ring: the whole pages that hold a
+    window (a row lands at ``position % rows`` and is masked by the position
+    it holds: 513 is no multiple of a page)."""
+    return -(-cfg.sliding_window // page) * page
 
 
 def state_shapes(cfg, num_slots: int):
@@ -138,7 +184,7 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     five at the published widths, a sixth of all top-8 sets flipped against
     the float32 forward: PERF.md section 6, PR 44)."""
     params = afmoe.init_params(cfg, rng, dtype, attn=False)
-    D, H = cfg.hidden_size, cfg.num_heads
+    D = cfg.hidden_size
     lin, lat = kind_layers(cfg)
     keys = iter(jax.random.split(jax.random.fold_in(rng, 0x4B4441), 24))
     params["embed"] = {"tok": jax.random.normal(
@@ -161,18 +207,36 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
             "wg_down": uni((L, D, r), D), "wg_up": uni((L, r, C), r),
             "b_g": jax.random.normal(next(keys), (L, C), dtype) * 0.1,
             "o_norm": jnp.ones((L, d), dtype), "wo": uni((L, C, D), C)}
-    if lat:
-        L, n, r = len(lat), cfg.mla_nope_dim, cfg.mla_rot_dim
-        kv, v = cfg.mla_kv_rank, cfg.mla_v_dim
-        rq = cfg.mla_q_rank
+    def latent_stack(kd, L):
+        H, n, r, kv, v, rq = kd.heads, kd.nope, kd.rot, kd.kv, kd.v, kd.q_rank
         wq = {"wq": uni((L, D, H * (n + r)), D)} if not rq else {
             "wqa": uni((L, D, rq), D), "q_norm": jnp.ones((L, rq), dtype),
             "wqb": uni((L, rq, H * (n + r)), rq)}
-        params["mla"] = {
-            **wq, "wkva": uni((L, D, kv + r), D),
-            "kv_norm": jnp.ones((L, kv), dtype),
-            "wkvb": uni((L, kv, H * (n + v)), kv),
-            "wo": uni((L, H * v, D), H * v)}
+        return {**wq, "wkva": uni((L, D, kv + r), D),
+                "kv_norm": jnp.ones((L, kv), dtype),
+                "wkvb": uni((L, kv, H * (n + v)), kv),
+                "wo": uni((L, H * v, D), H * v)}
+
+    def more(kd, L):
+        """The gate and the indexer, drawn after every kind's stack so that
+        a model without them keeps its weights."""
+        a = {"wg": uni((L, D, kd.heads), D)} if kd.gate else {}
+        if kd.index:
+            Hi, di, _ = kd.index
+            a.update(wiq=uni((L, kd.q_rank, Hi * di), kd.q_rank),
+                     wik=uni((L, D, di), D), wiw=uni((L, D, Hi), D),
+                     ik_norm=jnp.ones((L, di), dtype),
+                     ik_bias=jax.random.normal(next(keys), (L, di), dtype)
+                     * 0.1)
+        return a
+
+    kinds = [(cfg.mla_kind(t), len(ls)) for t, ls in (
+        ("latent_attention", lat),
+        ("latent_sliding_attention", sliding_layers(cfg))) if ls]
+    for kd, L in kinds:
+        params[kd.stack] = latent_stack(kd, L)
+    for kd, L in kinds:
+        params[kd.stack].update(more(kd, L))
     return params
 
 
@@ -180,8 +244,9 @@ def layer_params(cfg, params, l: int):
     """``afmoe.layer_params`` with layer ``l``'s slice of its KIND's
     attention stack as ``attn``."""
     lp, le = afmoe.layer_params(cfg, params, l)
-    kind = "kda" if is_linear(cfg, l) else "mla"
-    i = sum(is_linear(cfg, j) == is_linear(cfg, l) for j in range(l))
+    t = cfg.layer_types[l]
+    kind = "kda" if is_linear(cfg, l) else cfg.mla_kind(t).stack
+    i = sum(cfg.layer_types[j] == t for j in range(l))
     return {**lp, "attn": jax.tree.map(lambda a: a[i], params[kind])}, le
 
 
@@ -344,14 +409,15 @@ def yarn(rope, dim: int):
             y(all_dim) if all_dim else 1.0)
 
 
-def rotate(cfg, t, pos):
-    """``t`` [..., r] (the rotary values of the query heads or of the shared
-    key) at integer positions ``pos``, broadcastable to ``t``'s leading
-    axes: pairs (2i, 2i + 1) turned by ``pos * inv_freq_i``, float32 angles.
-    Without ``mla_rope`` the values pass unrotated."""
-    if cfg.mla_rope is None:
+def rotate(kd, t, pos):
+    """``t`` [..., r] (the rotary values of the query heads, of the shared
+    key or of the index heads) at integer positions ``pos``, broadcastable
+    to ``t``'s leading axes: pairs (2i, 2i + 1) turned by ``pos *
+    inv_freq_i``, float32 angles.  Without a ``rope`` group the values pass
+    unrotated."""
+    if kd.rope is None:
         return t
-    inv_freq, on_cos_sin, _ = yarn(cfg.mla_rope, t.shape[-1])
+    inv_freq, on_cos_sin, _ = yarn(kd.rope, t.shape[-1])
     ang = jnp.asarray(pos, F32)[..., None] * jnp.repeat(inv_freq, 2)
     cos, sin = jnp.cos(ang) * on_cos_sin, jnp.sin(ang) * on_cos_sin
     t32 = t.astype(F32)
@@ -361,45 +427,60 @@ def rotate(cfg, t, pos):
     return (t32 * cos + other * sin).astype(t.dtype)
 
 
-def mla_row(cfg, a, cr, pos):
+def _gain(scale, by: float):
+    """A norm's gain times a kind's rescale factor (1: the gain itself)."""
+    return scale if by == 1.0 else scale.astype(F32) * by
+
+
+def mla_row(kd, a, cr, pos):
     """The cache row from ``[c_raw | k_r]`` [..., kv + r] at positions
-    ``pos`` [...]: the latent normed, the shared key values (rotated where
-    the model rotates), zeros up to the row width."""
-    kv = cfg.mla_kv_rank
-    c = rms(cr[..., :kv], a["kv_norm"], cfg.norm_eps)
-    pad = jnp.zeros(cr.shape[:-1] + (row_width(cfg) - cr.shape[-1],),
+    ``pos`` [...]: the latent normed (times the kind's ``kv_scale``), the
+    shared key values (rotated where the model rotates), zeros up to the row
+    width."""
+    kv = kd.kv
+    c = rms(cr[..., :kv], _gain(a["kv_norm"], kd.kv_scale), kd.eps)
+    pad = jnp.zeros(cr.shape[:-1] + (kd.row_width - cr.shape[-1],),
                     cr.dtype)
-    return jnp.concatenate([c, rotate(cfg, cr[..., kv:], pos), pad], axis=-1)
+    return jnp.concatenate([c, rotate(kd, cr[..., kv:], pos), pad], axis=-1)
 
 
-def mla_query(cfg, q, pos):
+def mla_query(kd, q, pos):
     """The projected queries ``q`` [..., H (n + r)] at positions ``pos``
     [...] as heads [..., H, n + r], each head's rotary part rotated."""
-    n = cfg.mla_nope_dim
-    q = q.reshape(q.shape[:-1] + (cfg.num_heads, -1))
-    if cfg.mla_rope is None:
+    n = kd.nope
+    q = q.reshape(q.shape[:-1] + (kd.heads, -1))
+    if kd.rope is None:
         return q
     return jnp.concatenate(
-        [q[..., :n], rotate(cfg, q[..., n:], jnp.asarray(pos)[..., None])],
+        [q[..., :n], rotate(kd, q[..., n:], jnp.asarray(pos)[..., None])],
         axis=-1)
 
 
-def mla_project(cfg, a, h, pos):
-    """(q [..., H, n + r], the cache row [..., row_width]) of ``h`` [..., D]
-    at positions ``pos`` [...]; the query full-rank, or ``N_q(h Wqa) Wqb``
-    where the model has ``mla_q_rank``."""
+def mla_cq(kd, a, cq_raw):
+    """The query's bottleneck ``N_q(h W_qa)`` (times the kind's
+    ``q_scale``)."""
+    return rms(cq_raw, _gain(a["q_norm"], kd.q_scale), kd.eps)
+
+
+def mla_project(kd, a, h, pos):
+    """(q [..., H, n + r], the cache row [..., row_width], the query's
+    bottleneck ``c_q`` | None) of ``h`` [..., D] at positions ``pos`` [...];
+    the query full-rank, or ``N_q(h Wqa) Wqb`` where the kind has
+    ``q_rank``."""
     w = lambda n: a[n].astype(h.dtype)
-    if cfg.mla_q_rank:
-        q = rms(h @ w("wqa"), a["q_norm"], cfg.norm_eps) @ w("wqb")
+    cq = None
+    if kd.q_rank:
+        cq = mla_cq(kd, a, h @ w("wqa"))
+        q = cq @ w("wqb")
     else:
         q = h @ w("wq")
-    return mla_query(cfg, q, pos), mla_row(cfg, a, h @ w("wkva"), pos)
+    return mla_query(kd, q, pos), mla_row(kd, a, h @ w("wkva"), pos), cq
 
 
-def _wkvb(cfg, a):
+def _wkvb(kd, a):
     """``Wkvb`` as (keys [kv, H, n], values [kv, H, v])."""
-    w = a["wkvb"].reshape(cfg.mla_kv_rank, cfg.num_heads, -1)
-    return w[..., :cfg.mla_nope_dim], w[..., cfg.mla_nope_dim:]
+    w = a["wkvb"].reshape(kd.kv, kd.heads, -1)
+    return w[..., :kd.nope], w[..., kd.nope:]
 
 
 def mla_decompress(rows, wk, wv, r: int):
@@ -416,30 +497,183 @@ def mla_decompress(rows, wk, wv, r: int):
             jnp.einsum("...c,chv->...hv", c, wv.astype(c.dtype)))
 
 
-def mla_absorb(cfg, a, q):
+def mla_absorb(kd, a, q):
     """A decode step's queries against the ROWS: q [B, H, n + r] ->
     ``[q_n Wkvb_k^T | q_r | 0]`` [B, H, row_width]."""
-    n = cfg.mla_nope_dim
-    wk, _ = _wkvb(cfg, a)
+    n = kd.nope
+    wk, _ = _wkvb(kd, a)
     qc = jnp.einsum("bhn,chn->bhc", q[..., :n], wk.astype(q.dtype))
-    pad = jnp.zeros(q.shape[:-1] + (
-        row_width(cfg) - cfg.mla_kv_rank - cfg.mla_rot_dim,), q.dtype)
+    pad = jnp.zeros(q.shape[:-1] + (kd.row_width - kd.kv - kd.rot,), q.dtype)
     return jnp.concatenate([qc, q[..., n:], pad], axis=-1)
 
 
-def mla_unabsorb(cfg, a, o):
-    """The rows' weighted sum o [B, H, row_width] (its first ``mla_kv_rank``
-    values: ``sum_j p_j c(j)``) through ``Wkvb_v``: [B, H v]."""
-    _, wv = _wkvb(cfg, a)
-    y = jnp.einsum("bhc,chv->bhv", o[..., :cfg.mla_kv_rank],
-                   wv.astype(o.dtype))
-    return y.reshape(o.shape[0], -1)
+def mla_unabsorb(kd, a, o):
+    """The rows' weighted sum o [B, H, row_width] (its first ``kv`` values:
+    ``sum_j p_j c(j)``) through ``Wkvb_v``: [B, H, v]."""
+    _, wv = _wkvb(kd, a)
+    return jnp.einsum("bhc,chv->bhv", o[..., :kd.kv], wv.astype(o.dtype))
 
 
-def _mla_scale(cfg) -> float:
-    m = 1.0 if cfg.mla_rope is None else yarn(cfg.mla_rope,
-                                              cfg.mla_rot_dim)[2]
-    return (cfg.mla_nope_dim + cfg.mla_rot_dim) ** -0.5 * m * m
+def _mla_scale(kd) -> float:
+    m = 1.0 if kd.rope is None else yarn(kd.rope, kd.rot)[2]
+    return (kd.nope + kd.rot) ** -0.5 * m * m
+
+
+def head_gate(kd, o, g, lead):
+    """The headwise gate: head outputs ``o`` [..., H, v] times the sigmoid
+    of the head's gate logit ``g`` [..., H] (shaped alike); returns ``lead +
+    (H v,)``, what ``W_o`` takes, gated or not."""
+    if kd.gate:
+        g = jax.nn.sigmoid(g.astype(F32)).reshape(o.shape[:-1] + (1,))
+        o = (o.astype(F32) * g).astype(o.dtype)
+    return o.reshape(lead + (-1,))
+
+
+# ----------------------------------------------------------------------
+# the learned selection of a latent layer's keys (the indexer)
+# ----------------------------------------------------------------------
+def index_key(kd, a, ik_raw, pos):
+    """The index key of a token from ``h W_Ik`` [..., di] at positions
+    ``pos`` [...]: LayerNorm (gain and bias), then its first ``rot`` values
+    rotated as the shared key's are.  What the index cache holds."""
+    x = ik_raw.astype(F32)
+    mu = x.mean(-1, keepdims=True)
+    var = ((x - mu) ** 2).mean(-1, keepdims=True)
+    k = ((x - mu) * jax.lax.rsqrt(var + kd.eps) * a["ik_norm"].astype(F32)
+         + a["ik_bias"].astype(F32)).astype(ik_raw.dtype)
+    r = kd.rot
+    return jnp.concatenate([rotate(kd, k[..., :r], pos), k[..., r:]], -1)
+
+
+def index_query(kd, qi, w_raw, pos):
+    """(index queries [..., Hi, di], their first ``rot`` values rotated;
+    head weights [..., Hi] float32 = ``h W_Iw`` times ``Hi^-0.5 di^-0.5``)
+    from ``c_q W_Iq`` [..., Hi di] and ``h W_Iw`` [..., Hi]."""
+    Hi, di, _ = kd.index
+    r = kd.rot
+    q = qi.reshape(qi.shape[:-1] + (Hi, di))
+    q = jnp.concatenate(
+        [rotate(kd, q[..., :r], jnp.asarray(pos)[..., None]), q[..., r:]], -1)
+    return q, w_raw.astype(F32) * (Hi ** -0.5 * di ** -0.5)
+
+
+def _sortable(x):
+    """float32 -> uint32 that orders as the floats do."""
+    b = jax.lax.bitcast_convert_type(x.astype(F32), jnp.int32)
+    b = b ^ ((b >> 31) & jnp.int32(0x7FFFFFFF))
+    return jax.lax.bitcast_convert_type(b, jnp.uint32) ^ jnp.uint32(1 << 31)
+
+
+def select_keys(scores_t, q_pos, k: int):
+    """A chunk's selection as an additive bias: ``scores_t`` [keys, s]
+    float32 (TRANSPOSED: a key a row; ``NEG_INF`` where the key is past the
+    query) -> [keys, s] bfloat16, 0 where key j is among the ``k`` largest
+    of query t's scores (all of ``j <= t`` while ``t + 1 <= k``; exact ties
+    at the k-th all kept) and ``NEG_INF`` elsewhere.  The k-th largest is
+    found by bisection on the scores' bits, 32 counting passes and no sort,
+    over the shortest of eight prefixes of the keys that holds the chunk."""
+    P, s = scores_t.shape
+    u = _sortable(scores_t)
+    causal = jnp.arange(P)[:, None] <= q_pos[None, :]
+
+    def kth(n):
+        def run(u):
+            un = u[:n]
+
+            def bit(i, t):
+                cand = t | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+                cnt = jnp.sum(un >= cand[None, :], axis=0, dtype=jnp.int32)
+                return jnp.where(cnt >= k, cand, t)
+
+            return jax.lax.fori_loop(0, 32, bit, jnp.zeros((s,), jnp.uint32))
+        return run
+
+    if P <= k:
+        keep = causal
+    else:
+        step = -(-P // 8 // 128) * 128 if P >= 8 * 1024 else P
+        cuts = list(range(step, P, step)) + [P]
+        last = q_pos[-1] + 1                       # keys the chunk can see
+        branch = jnp.sum(jnp.asarray(cuts) < last)
+        thr = jax.lax.switch(branch, [kth(n) for n in cuts], u)
+        keep = causal & (u >= thr[None, :])
+    return jnp.where(keep, 0.0, afmoe.NEG_INF).astype(jnp.bfloat16)
+
+
+def select_positions(scores, pos, k: int):
+    """A decode step's selection as positions: ``scores`` [B, keys] float32
+    (``NEG_INF`` past ``pos``) -> (positions [B, k'] int32 of the ``k' =
+    min(k, keys)`` largest, best first; how many of them are real,
+    ``min(pos + 1, k')`` [B])."""
+    k = min(k, scores.shape[1])
+    _, idx = jax.lax.top_k(scores, k)
+    return idx.astype(jnp.int32), jnp.minimum(pos + 1, k).astype(jnp.int32)
+
+
+# ----------------------------------------------------------------------
+# the window over latent rows (a per-slot ring)
+# ----------------------------------------------------------------------
+def ring_before(ring, start, window: int):
+    """The ``window - 1`` rows before position ``start`` out of a ring
+    [R, W] (row of position p at ``p % R``), oldest first, and their
+    positions (negative: before the sequence's start, to be masked)."""
+    R = ring.shape[0]
+    p = start - (window - 1) + jnp.arange(window - 1)
+    return jnp.take(ring, p % R, axis=0), p
+
+
+def ring_after(ring, rows, start, valid_len):
+    """The ring after a chunk's first ``valid_len`` rows ``rows`` [s, W] at
+    positions ``start ..``: each ring row takes the LAST real position that
+    lands on it, the others stay."""
+    R, s = ring.shape[0], rows.shape[0]
+    last = start + valid_len - 1
+    i = jnp.arange(R)
+    p = last - (last - i) % R                 # the last position on row i
+    new = jnp.take(rows, jnp.clip(p - start, 0, s - 1), axis=0)
+    return jnp.where((p >= start)[:, None], new.astype(ring.dtype), ring)
+
+
+def window_attention(kd, a, q, rows, q_pos, k_pos):
+    """A chunk's attention over a window of latent rows, decompressed: q
+    [s, H, n + r] at positions ``q_pos`` [s]; ``rows`` [S, W] at positions
+    ``k_pos`` [S] (the ring's rows before the chunk, then the chunk's own);
+    keys ``0 <= t - j < window``, ``j >= 0``.  Query blocks of 256 against
+    the keys their windows can hold.  Returns [s, H, v]."""
+    s, S = q.shape[0], rows.shape[0]
+    wk, wv = _wkvb(kd, a)
+    k, v = mla_decompress(rows, wk, wv, kd.rot)          # [S, H, .]
+    scale, W = _mla_scale(kd), kd.window
+    bq = min(s, 256)
+    span = min(S, bq + W - 1)
+    out = []
+    for q0 in range(0, s, bq):
+        # the block's keys: from its first query's window to its last query
+        k0 = q0                                # rows[q0 + W - 1] is query q0
+        kb, vb = k[k0:k0 + span], v[k0:k0 + span]
+        d = q_pos[q0:q0 + bq, None] - k_pos[None, k0:k0 + span]
+        ok = (d >= 0) & (d < W) & (k_pos[None, k0:k0 + span] >= 0)
+        sc = jnp.einsum("qhd,khd->hqk", q[q0:q0 + bq], kb,
+                        preferred_element_type=F32) * scale
+        p = jax.nn.softmax(jnp.where(ok[None], sc, afmoe.NEG_INF), axis=-1)
+        out.append(jnp.einsum("hqk,khv->qhv", p.astype(vb.dtype), vb,
+                              preferred_element_type=F32).astype(q.dtype))
+    return jnp.concatenate(out, axis=0)
+
+
+def ring_decode(kd, q, ring, pos):
+    """A decode step's absorbed attention over the rings: q [B, H, W]
+    (:func:`mla_absorb`), ``ring`` [B, R, W] with this step's row written at
+    ``pos % R``; ring row i holds position ``pos - (pos - i) % R``, attended
+    where it is not negative and within the window.  Returns [B, H, W]."""
+    R = ring.shape[1]
+    back = (pos[:, None] - jnp.arange(R)[None, :]) % R        # t - j
+    ok = (back < kd.window) & (back <= pos[:, None])
+    sc = jnp.einsum("bhw,brw->bhr", q, ring,
+                    preferred_element_type=F32) * _mla_scale(kd)
+    p = jax.nn.softmax(jnp.where(ok[:, None], sc, afmoe.NEG_INF), axis=-1)
+    return jnp.einsum("bhr,brw->bhw", p.astype(ring.dtype), ring,
+                      preferred_element_type=F32).astype(q.dtype)
 
 
 # ----------------------------------------------------------------------
@@ -462,9 +696,26 @@ def apply_layers(cfg, params, x, mesh=None):
         if lin:
             cache.update(state=jnp.zeros(state, F32),
                          tail=jnp.zeros(tail, x.dtype))
+        cache.update(_empty_extras(cfg, S + pad, x.dtype))
         return cached_layers(cfg, params, xb, cache, 0, S)[0][0, :S]
 
     return jax.lax.map(one, x)
+
+
+def _empty_extras(cfg, positions: int, dtype):
+    """The cache entries beside ``latent`` that one sequence of
+    ``positions`` rows needs where the model has an indexer (``index``) or
+    sliding latent layers (``ring``): zeros."""
+    out = {}
+    kd = cfg.mla_kind("latent_attention") if kind_layers(cfg)[1] else None
+    if kd is not None and kd.index:
+        out["index"] = jnp.zeros((len(kind_layers(cfg)[1]), 1, 1, positions,
+                                  kd.index[1]), dtype)
+    sl = sliding_layers(cfg)
+    if sl:
+        kd = cfg.mla_kind("latent_sliding_attention")
+        out["ring"] = jnp.zeros((len(sl), 1, kd.window, kd.row_width), dtype)
+    return out
 
 
 def cached_layers(cfg, params, x, cache, start, valid_len,
@@ -485,9 +736,10 @@ def cached_layers(cfg, params, x, cache, start, valid_len,
     pos = start + jnp.arange(s)
     real = jnp.arange(s) < valid_len
     latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
+    index, ring = cache.get("index"), cache.get("ring")
     kept = (start != 0)
     experts = afmoe._experts(params)
-    i_lin = i_lat = 0
+    i_lin = i_lat = i_sw = 0
     for l in range(cfg.num_layers):
         lp, le = layer_params(cfg, params, l)
         a = lp["attn"]
@@ -508,30 +760,72 @@ def cached_layers(cfg, params, x, cache, start, valid_len,
             ctx = kda_out(cfg, a, o[None], gate)
             i_lin += 1
         else:
-            q, row = mla_project(cfg, a, h, pos[None])
-            latent = jax.lax.dynamic_update_slice(
-                latent, row[None, :, None].astype(latent.dtype),
-                (i_lat, 0, 0, start, 0))
-            # this layer's cache is latent rows: the flash kernel where its
-            # sizes allow (keys, values and scores stay in VMEM), else
-            # afmoe.attend(expand=mla_decompress), its reference
-            o = mla_chunk_attention(
-                q[0], latent[:, 0, 0],
-                a["wkvb"].reshape(cfg.mla_kv_rank, cfg.num_heads, -1), start,
-                nope=cfg.mla_nope_dim, scale=_mla_scale(cfg), layer=i_lat,
-                impl=impl)
-            ctx = o.reshape(B, s, -1)
-            i_lat += 1
+            kd = cfg.mla_kind(cfg.layer_types[l])
+            q, row, cq = mla_project(kd, a, h, pos[None])
+            if kd.window:
+                # a window over latent rows: the ring's rows before the
+                # chunk and the chunk's own, then the ring moves on
+                before, p_before = ring_before(ring[i_sw, 0], start,
+                                               kd.window)
+                o = window_attention(
+                    kd, a, q[0], jnp.concatenate(
+                        [before, row[0].astype(ring.dtype)], axis=0),
+                    pos, jnp.concatenate([p_before, pos]))
+                ring = ring.at[i_sw, 0].set(
+                    ring_after(ring[i_sw, 0], row[0], start, valid_len))
+                i_sw += 1
+            else:
+                latent = jax.lax.dynamic_update_slice(
+                    latent, row[None, :, None].astype(latent.dtype),
+                    (i_lat, 0, 0, start, 0))
+                if kd.index:
+                    # the chunk's index keys join the cache, every query
+                    # scores the keys up to itself, and the layer attends
+                    # the selected ones only
+                    w = lambda n: a[n].astype(h.dtype)
+                    ik = index_key(kd, a, h @ w("wik"), pos[None])
+                    index = jax.lax.dynamic_update_slice(
+                        index, ik[None, :, None].astype(index.dtype),
+                        (i_lat, 0, 0, start, 0))
+                    qi, wi = index_query(kd, cq @ w("wiq"), h @ w("wiw"),
+                                         pos[None])
+                    scores_t = dsa_index_scores_chunk(
+                        qi[0], wi[0], index[:, 0, 0], start, layer=i_lat,
+                        impl=impl)
+                    o = dsa_chunk_attention(
+                        q[0], latent[:, 0, 0],
+                        a["wkvb"].reshape(kd.kv, kd.heads, -1),
+                        select_keys(scores_t, pos, kd.index[2]), start,
+                        nope=kd.nope, scale=_mla_scale(kd), layer=i_lat,
+                        impl=impl)
+                else:
+                    # this layer's cache is latent rows: the flash kernel
+                    # where its sizes allow (keys, values and scores stay in
+                    # VMEM), else afmoe.attend(expand=mla_decompress), its
+                    # reference
+                    o = mla_chunk_attention(
+                        q[0], latent[:, 0, 0],
+                        a["wkvb"].reshape(kd.kv, kd.heads, -1), start,
+                        nope=kd.nope, scale=_mla_scale(kd), layer=i_lat,
+                        impl=impl)
+                i_lat += 1
+            ctx = head_gate(kd, o, h @ a["wg"].astype(h.dtype)
+                            if kd.gate else None, (B, s))
         x = afmoe.mlp_block(cfg, lp, x, ctx @ a["wo"].astype(ctx.dtype),
                             None if le is None else experts, le)
-    return x, _views(latent, state, tail)
+    return x, _views(latent, state, tail, index, ring)
 
 
-def _views(latent, state, tail):
+def _views(latent, state, tail, index=None, ring=None):
     """The cache a forward hands back: what it was given."""
-    if state is None:
-        return {"latent": latent}
-    return {"latent": latent, "state": state, "tail": tail}
+    out = {"latent": latent}
+    if state is not None:
+        out.update(state=state, tail=tail)
+    if index is not None:
+        out["index"] = index
+    if ring is not None:
+        out["ring"] = ring
+    return out
 
 
 # ----------------------------------------------------------------------
@@ -544,12 +838,21 @@ def _pad_cols(w, multiple: int = 512):
     return jnp.pad(w, ((0, 0), (0, -w.shape[1] % multiple)))
 
 
+def _w_in_names(kd):
+    """A latent layer's projections of ``h``, in ``w_in``'s column order."""
+    return (("wqa" if kd.q_rank else "wq", "wkva")
+            + (("wg",) if kd.gate else ())
+            + (("wik", "wiw") if kd.index else ()))
+
+
 def inject(cfg, params) -> Dict[str, Any]:
     """The kernel-injected view (``afmoe.inject``'s shape): per-layer dicts,
     every projection of ``h`` in one ``[D, N]`` matrix ``w_in`` (linear: q |
     k | v | decay gate down | output gate down | beta; latent: q | [c_raw |
     k_r], or, with a low-rank query, Wqa | [c_raw | k_r] with ``wqb`` and
-    ``q_norm`` beside it), the small per-kind arrays under their own names,
+    ``q_norm`` beside it; then the head gate's and the indexer's key and
+    head-weight columns where the kind has them), the small per-kind arrays
+    under their own names,
     the stacked routed experts by reference."""
     layers = []
     for l in range(cfg.num_layers):
@@ -557,7 +860,7 @@ def inject(cfg, params) -> Dict[str, Any]:
         a = lp["attn"]
         names = (("wq", "wk", "wv", "wf_down", "wg_down", "wb")
                  if is_linear(cfg, l) else
-                 ("wqa" if cfg.mla_q_rank else "wq", "wkva"))
+                 _w_in_names(cfg.mla_kind(cfg.layer_types[l])))
         d = {k: v for k, v in a.items() if k not in names}
         d["w_in"] = _pad_cols(jnp.concatenate([a[k] for k in names], axis=-1))
         layers.append({**d, **afmoe.inject_rest(cfg, lp, le)})
@@ -582,19 +885,20 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     ``moe_live`` [B] bool: the rows that decode; only their state, tail and
     latent pages move, and the kernels visit only them.  Returns (x, cache,
     counts | None)."""
-    from deepspeed_tpu.ops.pallas.decode import (fused_norm_qkv,
+    from deepspeed_tpu.ops.pallas.decode import (dsa_decode_selected,
+                                                 dsa_index_scores_paged,
+                                                 fused_norm_qkv,
                                                  kda_decode_step,
                                                  mla_decode_paged,
                                                  paged_row_append)
 
     B = x.shape[0]
-    H, Hk, d = cfg.num_heads, cfg.kda_num_heads, cfg.kda_head_dim
+    Hk, d = cfg.kda_num_heads, cfg.kda_head_dim
     C3, r = 3 * Hk * d, cfg.kda_gate_rank
-    # the query's columns of ``w_in``: the heads, or the bottleneck
-    M = cfg.mla_q_rank or H * (cfg.mla_nope_dim + cfg.mla_rot_dim)
     latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
+    index, ring = cache.get("index"), cache.get("ring")
     stats = moe_counts_zero(cfg) if moe_live is not None else None
-    i_lin = i_lat = 0
+    i_lin = i_lat = i_sw = 0
     for l, lp in enumerate(dparams["layers"]):
         y = fused_norm_qkv(x, lp["n1_scale"], None, lp["w_in"], None,
                            kind="rmsnorm", eps=cfg.norm_eps, impl=impl)
@@ -615,22 +919,62 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
             ctx = kda_out(cfg, lp, o, gate)
             i_lin += 1
         else:
-            q = y[:, :M]
-            if cfg.mla_q_rank:          # N_q and Wqb: the kernel once more
+            kd = cfg.mla_kind(cfg.layer_types[l])
+            # the query's columns of ``w_in``: the heads, or the bottleneck
+            M = kd.q_rank or kd.heads * (kd.nope + kd.rot)
+            R = M + kd.kv + kd.rot               # ... then [c_raw | k_r]
+            q = cq = y[:, :M]
+            if kd.q_rank and kd.q_scale == 1.0 and not kd.index:
+                # N_q and Wqb: the kernel once more
                 q = fused_norm_qkv(q, lp["q_norm"], None, lp["wqb"], None,
                                    kind="rmsnorm", eps=cfg.norm_eps,
                                    impl=impl)
-            q = mla_query(cfg, q, pos)
-            row = mla_row(cfg, lp, y[:, M:M + cfg.mla_kv_rank
-                                     + cfg.mla_rot_dim], pos)
-            latent = paged_row_append(latent, row, pos, page_table,
-                                      layer=i_lat, impl=impl)
-            o = mla_decode_paged(mla_absorb(cfg, lp, q), latent, pos,
-                                 page_table, layer=i_lat,
-                                 sm_scale=_mla_scale(cfg), live=moe_live,
-                                 impl=impl)
-            ctx = mla_unabsorb(cfg, lp, o)
-            i_lat += 1
+            elif kd.q_rank:       # the bottleneck is the indexer's input too
+                cq = mla_cq(kd, lp, q)
+                q = cq @ lp["wqb"].astype(cq.dtype)
+            q = mla_query(kd, q, pos)
+            row = mla_row(kd, lp, y[:, M:R], pos)
+            gate = y[:, R:R + kd.heads] if kd.gate else None
+            if kd.window:
+                # the ring: a live row's position lands at ``pos % rows``
+                at = jax.nn.one_hot(pos % ring.shape[2], ring.shape[2],
+                                    dtype=bool)
+                if moe_live is not None:
+                    at = at & moe_live[:, None]
+                ring = ring.at[i_sw].set(jnp.where(
+                    at[:, :, None], row[:, None].astype(ring.dtype),
+                    ring[i_sw]))
+                o = ring_decode(kd, mla_absorb(kd, lp, q), ring[i_sw], pos)
+                i_sw += 1
+            else:
+                latent = paged_row_append(latent, row, pos, page_table,
+                                          layer=i_lat, impl=impl)
+                if kd.index:
+                    # score the row's index keys, take the best, attend
+                    # their rows only
+                    G = R + kd.heads * kd.gate
+                    Hi, di, topk = kd.index
+                    index = paged_row_append(
+                        index, index_key(kd, lp, y[:, G:G + di], pos), pos,
+                        page_table, layer=i_lat, impl=impl)
+                    qi, wi = index_query(
+                        kd, cq @ lp["wiq"].astype(cq.dtype),
+                        y[:, G + di:G + di + Hi], pos)
+                    scores = dsa_index_scores_paged(
+                        qi, wi, index, pos, page_table, layer=i_lat,
+                        live=moe_live, impl=impl)
+                    sel, n_sel = select_positions(scores, pos, topk)
+                    o = dsa_decode_selected(
+                        mla_absorb(kd, lp, q), latent, sel, n_sel,
+                        page_table, layer=i_lat, sm_scale=_mla_scale(kd),
+                        impl=impl)
+                else:
+                    o = mla_decode_paged(mla_absorb(kd, lp, q), latent, pos,
+                                         page_table, layer=i_lat,
+                                         sm_scale=_mla_scale(kd),
+                                         live=moe_live, impl=impl)
+                i_lat += 1
+            ctx = head_gate(kd, mla_unabsorb(kd, lp, o), gate, (B,))
         x, stats = afmoe.fused_close(cfg, dparams, lp, l, ctx, x, stats,
                                      moe_live, impl)
-    return x, _views(latent, state, tail), stats
+    return x, _views(latent, state, tail, index, ring), stats

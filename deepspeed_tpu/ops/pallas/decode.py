@@ -606,6 +606,181 @@ def mla_decode_paged(q, cache, pos, page_table, *, layer: int,
         alibi=False, impl=impl, name="mla_decode_paged")
 
 
+# ---------------------------------------------------------------------------
+# A learned selection of keys (models/kda_mla.py: the indexer)
+# ---------------------------------------------------------------------------
+
+def _index_scores_ref(q, w, keys, pos):
+    """``I(b, j) = sum_g w[b, g] relu(q[b, g] . keys[b, j])`` [B, S] float32
+    for ``j <= pos[b]``, ``NEG_INF`` past it; q [B, G, d], w [B, G] float32,
+    keys [B, S, d]."""
+    s = jnp.einsum("bgd,bkd->bgk", q, keys.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    i = jnp.sum(jax.nn.relu(s) * w[:, :, None], axis=1)
+    return jnp.where(jnp.arange(keys.shape[1])[None, :] <= pos[:, None], i,
+                     NEG_INF)
+
+
+def _index_scores_kernel(rows_ref, pos_ref, pt_ref, q_ref, w_ref, k_ref,
+                         old_ref, o_ref, *, page):
+    """One grid step = one LIVE batch row x one logical page of index keys:
+    the page's keys against the row's ``G`` index queries, ReLU, the heads'
+    weighted sum."""
+    del pt_ref, old_ref           # the index maps'; the aliased output's
+    j = pl.program_id(1)
+    pos = pos_ref[rows_ref[pl.program_id(0)]]
+
+    @pl.when(j * page <= pos)
+    def _compute():
+        s = jax.lax.dot_general(q_ref[0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        i = jnp.sum(jnp.maximum(s, 0.0) * w_ref[0], axis=0, keepdims=True)
+        key_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, i.shape, 1)
+        o_ref[0, 0] = jnp.where(key_pos <= pos, i, NEG_INF)
+
+    @pl.when(j * page > pos)
+    def _past():
+        o_ref[0, 0] = jnp.full(o_ref.shape[2:], NEG_INF, jnp.float32)
+
+
+def dsa_index_scores_paged(q, w, cache, pos, page_table, *, layer: int,
+                           live=None, impl: Optional[str] = None):
+    """Index scores of a decode step over the pages of INDEX KEYS: ``q`` [B,
+    G, d] (the row's index queries, rotated), ``w`` [B, G] float32 (its head
+    weights), ``cache`` [L, P, 1, page, d] (one index key a position, under
+    the latent pages' table).  Returns [B, columns * page] float32: ``sum_g
+    w_g relu(q_g . k(j))`` for ``j <= pos``, ``NEG_INF`` past it and in the
+    rows that do not decode.  :func:`mla_decode_paged`'s schedule: a grid
+    step is one LIVE row x one logical page, up to the deepest live row's
+    last page; what the grid does not visit keeps the ``NEG_INF`` it is
+    aliased onto."""
+    impl = resolve_impl(impl)
+    B, G, d = q.shape
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (B,))
+    P, page = cache.shape[1], cache.shape[3]
+    cols = page_table.shape[1]
+    impl = kernel_or_reference("dsa_index_scores_paged", impl,
+                               paged_decode_reference_reason(page))
+    if impl == "xla":
+        from deepspeed_tpu.models.decoding import paged_logical_view
+
+        view = paged_logical_view(cache[layer], page_table)[:, 0]
+        out = _index_scores_ref(q.astype(cache.dtype), w, view, pos)
+        return out if live is None else jnp.where(live[:, None], out, NEG_INF)
+    rows, n_live = _live_rows(live, B)
+    depth = pos // page + 1
+    if live is not None:
+        depth = jnp.where(live, depth, 0)
+    nb = jnp.minimum(cols, jnp.max(depth))
+
+    def page_map(i, j, rows_ref, pos_ref, pt_ref):
+        b = rows_ref[i]
+        return layer * P + pt_ref[b, jnp.minimum(j, pos_ref[b] // page)], 0, 0, 0
+
+    by_row = lambda i, j, rows_ref, *_: (rows_ref[i], 0, 0)
+    out = pl.BlockSpec((1, 1, 1, page),
+                       lambda i, j, rows_ref, *_: (rows_ref[i], j, 0, 0))
+    o = pl.pallas_call(
+        functools.partial(_index_scores_kernel, page=page),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(n_live, nb),
+            in_specs=[pl.BlockSpec((1, G, d), by_row),
+                      pl.BlockSpec((1, G, 1), by_row),
+                      pl.BlockSpec((1, 1, page, d), page_map), out],
+            out_specs=out),
+        out_shape=jax.ShapeDtypeStruct((B, cols, 1, page), jnp.float32),
+        input_output_aliases={6: 0},
+        interpret=interpret_flag(impl),
+        name="dsa_index_scores_paged",
+    )(rows, pos, page_table.astype(jnp.int32), q.astype(cache.dtype),
+      w.astype(jnp.float32)[:, :, None],
+      cache.reshape((-1,) + cache.shape[2:]),
+      jnp.full((B, cols, 1, page), NEG_INF, jnp.float32))
+    return o.reshape(B, cols * page)
+
+
+def _selected_ref(q, rows, n, *, scale):
+    """Attention of q [B, H, W] over its own gathered rows [B, K, W], the
+    first ``n[b]`` of them real; keys and values are the rows."""
+    s = jnp.einsum("bhw,bkw->bhk", q, rows,
+                   preferred_element_type=jnp.float32) * scale
+    ok = jnp.arange(rows.shape[1])[None, :] < n[:, None]
+    p = jax.nn.softmax(jnp.where(ok[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhk,bkw->bhw", p.astype(rows.dtype), rows,
+                      preferred_element_type=jnp.float32).astype(q.dtype)
+
+
+def _selected_kernel(n_ref, q_ref, rows_ref, o_ref, *, scale):
+    """One grid step = one batch row: its ``H`` absorbed queries against its
+    ``K`` selected rows, one softmax (no running maximum: the scores of one
+    row fit VMEM whole)."""
+    rows = rows_ref[0]                                          # [K, W]
+    s = jax.lax.dot_general(q_ref[0], rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    ok = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
+        < n_ref[pl.program_id(0)]
+    s = jnp.where(ok, s, NEG_INF)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.where(ok, p, 0.0)
+    o = jax.lax.dot_general(p.astype(rows.dtype), rows,
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    o_ref[0] = (o / jnp.sum(p, axis=-1, keepdims=True)).astype(o_ref.dtype)
+
+
+def selected_reference_reason(K: int, W: int) -> Optional[str]:
+    """Why :func:`dsa_decode_selected`'s kernel cannot take these sizes."""
+    if K % 128 or W % 128:
+        return (f"{K} selected rows of {W} values are not whole 128-lane "
+                "tiles")
+    return None
+
+
+def dsa_decode_selected(q, cache, sel, n_sel, page_table, *, layer: int,
+                        sm_scale: float, impl: Optional[str] = None):
+    """Decode attention of a latent layer in its absorbed form over the
+    SELECTED rows: ``q`` [B, H, W] (``kda_mla.mla_absorb``), ``cache`` [L,
+    P, 1, page, W] latent pages, ``sel`` [B, K] int32 the positions each row
+    attends (best first; the first ``n_sel[b]`` are real), through
+    ``page_table``.  The rows are gathered out of the pool ([B, K, W], one
+    gather over the pool as a flat array of rows), then one grid step a
+    batch row scores them, takes the softmax and sums them.  Returns [B, H,
+    W] as :func:`mla_decode_paged` does."""
+    impl = resolve_impl(impl)
+    B, H, W = q.shape
+    L, P, _, page, _ = cache.shape
+    K = sel.shape[1]
+    # the page each position lies on, as a one-hot product with the row's
+    # table (exact in float32; a gather of B x K scalars through the table
+    # took 0.33 ms a layer a step on the v5e, as long as half the sort that
+    # made the positions: my chip run, PR 52)
+    phys = jnp.einsum(
+        "bkp,bp->bk", jax.nn.one_hot(sel // page, page_table.shape[1],
+                                     dtype=jnp.float32),
+        page_table.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
+    flat = (layer * P + phys) * page + sel % page
+    rows = cache.reshape(L * P * page, W).at[flat].get(
+        mode="promise_in_bounds")                                  # [B, K, W]
+    impl = kernel_or_reference("dsa_decode_selected", impl,
+                               selected_reference_reason(K, W))
+    if impl == "xla":
+        return _selected_ref(q.astype(rows.dtype), rows, n_sel,
+                             scale=sm_scale)
+    by_row = lambda b, n_ref: (b, 0, 0)
+    return pl.pallas_call(
+        functools.partial(_selected_kernel, scale=sm_scale),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B,),
+            in_specs=[pl.BlockSpec((1, H, W), by_row),
+                      pl.BlockSpec((1, K, W), by_row)],
+            out_specs=pl.BlockSpec((1, H, W), by_row)),
+        out_shape=jax.ShapeDtypeStruct((B, H, W), q.dtype),
+        interpret=interpret_flag(impl),
+        name="dsa_decode_selected",
+    )(n_sel.astype(jnp.int32), q.astype(rows.dtype), rows)
+
+
 # heads of one grid step of :func:`kda_decode_step`: their k, decay and q
 # columns (3 x this many) share one 128-lane tile
 _KDA_HEADS_PER_STEP = 16

@@ -1274,3 +1274,271 @@ def mla_chunk_attention(q, rows, wkvb, start, *, nope: int, scale: float,
     return _mla_chunk_call(q, rows, wkvb, jnp.stack([start, jnp.int32(layer)]),
                            nope=nope, scale=scale,
                            interpret=interpret_flag(impl))
+
+
+# ---------------------------------------------------------------------------
+# a learned selection of a latent layer's keys (models/kda_mla.py's indexer),
+# forward only: a chunk's index scores, and its attention under the selection
+# ---------------------------------------------------------------------------
+
+_DSA_BLOCK_K = 512        # index keys / cache rows a grid step
+_DSA_BLOCK_Q = 128        # queries a grid step of the index scores
+
+
+def dsa_chunk_reference_reason(s: int, rows: int, *widths: int
+                               ) -> Optional[str]:
+    """Why the two kernels of a chunk under a selection cannot take these
+    sizes (None = they can): every width whole 128-lane tiles, the view
+    whole blocks of rows and no shorter than the chunk padded to a lane
+    tile (the calls pad a shorter bucket's queries)."""
+    for what, d in (("width", w) for w in widths):
+        if d <= 0 or d % _LANES:
+            return f"{what} of {d} is not a multiple of the 128-lane tile"
+    if rows % _DSA_BLOCK_K or rows < round_up(s, _LANES):
+        return (f"view of {rows} rows is not whole blocks of {_DSA_BLOCK_K} "
+                f"that hold a chunk of {s}")
+    return None
+
+
+def _index_scores_chunk_ref(q, w, keys, start):
+    """[P, s] float32: ``sum_g w[t, g] relu(q[t, g] . keys[j])`` at [j, t]
+    for ``j <= start + t``, ``NEG_INF`` elsewhere; a block of keys at a
+    time (the per-head products of ALL keys would be [G, s, P])."""
+    s, G, d = q.shape
+    P = keys.shape[0]
+    kb = min(P, 1024)
+    pad = -P % kb
+    keys = jnp.pad(keys, ((0, pad), (0, 0)))
+
+    def block(kblk):
+        sc = jnp.einsum("kd,tgd->kgt", kblk, q.astype(kblk.dtype),
+                        preferred_element_type=jnp.float32)
+        return jnp.sum(jax.nn.relu(sc) * w.T[None], axis=1)      # [kb, s]
+
+    out = lax.map(block, keys.reshape(-1, kb, d)).reshape(-1, s)[:P]
+    ok = jnp.arange(P)[:, None] <= start + jnp.arange(s)[None, :]
+    return jnp.where(ok, out, NEG_INF)
+
+
+def _dsa_index_kernel(at_ref, q_ref, w_ref, k_ref, old_ref, o_ref, *, G, bk,
+                      bq):
+    del old_ref                   # what the grid does not visit keeps it
+    i, c = pl.program_id(0), pl.program_id(1)
+    keys = k_ref[...]                                          # [bk, d]
+
+    def head(g, acc):
+        st = _dot(keys, q_ref[g], _NN)                         # [bk, bq]
+        return acc + jnp.maximum(st, 0.0) * w_ref[g]
+
+    acc = lax.fori_loop(0, G, head, jnp.zeros((bk, bq), jnp.float32))
+    key = c * bk + lax.broadcasted_iota(jnp.int32, (bk, bq), 0)
+    qry = at_ref[0] + i * bq + lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+    o_ref[...] = jnp.where(key <= qry, acc, NEG_INF)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dsa_index_call(q, w, keys, at, *, interpret: bool):
+    s0, G, d = q.shape
+    L, P, _ = keys.shape
+    s = round_up(s0, _LANES)              # a short bucket: idle queries
+    bk, bq = _DSA_BLOCK_K, _DSA_BLOCK_Q
+    qt = jnp.pad(q.astype(keys.dtype).transpose(1, 2, 0),      # [G, d, s]
+                 ((0, 0), (0, 0), (0, s - s0)))
+    wt = jnp.pad(w.astype(jnp.float32).T[:, None, :],          # [G, 1, s]
+                 ((0, 0), (0, 0), (0, s - s0)))
+    blocks = jnp.minimum((at[0] + s + bk - 1) // bk, P // bk)
+    out = pl.BlockSpec((bk, bq), lambda i, c, at_ref: (c, i))
+    return pl.pallas_call(
+        functools.partial(_dsa_index_kernel, G=G, bk=bk, bq=bq),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(s // bq, blocks),
+            in_specs=[pl.BlockSpec((G, d, bq), lambda i, c, at_ref: (0, 0, i)),
+                      pl.BlockSpec((G, 1, bq), lambda i, c, at_ref: (0, 0, i)),
+                      pl.BlockSpec((bk, d), lambda i, c, at_ref: (
+                          at_ref[1] * (P // bk) + c, 0)),
+                      out],
+            out_specs=out),
+        out_shape=jax.ShapeDtypeStruct((P, s), jnp.float32),
+        input_output_aliases={4: 0},
+        interpret=interpret,
+        name="dsa_index_scores_chunk",
+    )(at, qt, wt, keys.reshape(L * P, d),
+      jnp.full((P, s), NEG_INF, jnp.float32))[:, :s0]
+
+
+def dsa_index_scores_chunk(q, w, keys, start, *, layer: int = 0,
+                           impl: Optional[str] = None):
+    """The index scores of one prefill chunk, TRANSPOSED (a key a row, as
+    the chunk kernels lay their scores): ``q`` [s, G, d] the chunk's index
+    queries (rotated) at positions ``start ..``, ``w`` [s, G] float32 their
+    head weights, ``keys`` [layers, positions, d] ONE slot's index keys with
+    the chunk's own written, of which layer ``layer``'s are scored.  Returns
+    [positions, s] float32: ``sum_g w[t, g] relu(q[t, g] . k(j))`` at [j,
+    t] where ``j <= start + t``, ``NEG_INF`` elsewhere.  The per-head
+    products ([G, s, positions]: 12.9 GB at 64 heads, a chunk of 1,024 and
+    48 k positions) never exist: a grid step holds a block of keys and a
+    block of queries and sums the heads in VMEM; the grid's second extent is
+    read at run time, the blocks up to the chunk's last query, and what it
+    does not visit keeps the ``NEG_INF`` the output is aliased onto."""
+    impl = resolve_impl(impl)
+    s, G, d = q.shape
+    start = jnp.asarray(start, jnp.int32)
+    impl = kernel_or_reference(
+        "dsa_index_scores_chunk", impl,
+        dsa_chunk_reference_reason(s, keys.shape[1], d))
+    if impl == "xla":
+        return _index_scores_chunk_ref(q, w, keys[layer], start)
+    return _dsa_index_call(q, w, keys, jnp.stack([start, jnp.int32(layer)]),
+                           interpret=interpret_flag(impl))
+
+
+def _dsa_attention_ref(q, rows, wkvb, keep, *, nope: int, scale: float):
+    """``afmoe.attend(expand=mla_decompress)``'s roundings under an additive
+    bias ``keep`` [P, s]: a block of rows at a time, online softmax."""
+    from deepspeed_tpu.models import kda_mla
+
+    s, H, D = q.shape
+    P = rows.shape[0]
+    wk, wv = wkvb[..., :nope], wkvb[..., nope:]
+    kb = min(P, 1024)
+    pad = -P % kb
+    rows = jnp.pad(rows, ((0, pad), (0, 0)))
+    keep = jnp.pad(keep.astype(jnp.float32), ((0, pad), (0, 0)),
+                   constant_values=NEG_INF)
+    v_dim = wv.shape[-1]
+
+    def block(carry, xs):
+        m, l, acc = carry
+        rb, bias = xs
+        k, v = kda_mla.mla_decompress(rb, wk, wv, D - nope)    # [kb, H, .]
+        sc = jnp.einsum("qhd,khd->hqk", q.astype(k.dtype), k,
+                        preferred_element_type=jnp.float32) * scale
+        sc = jnp.maximum(sc + bias.T[None], NEG_INF)
+        ok = (bias.T > NEG_INF / 2)[None]
+        m_new = jnp.maximum(m, sc.max(-1))
+        p = jnp.where(ok, jnp.exp(sc - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "hqk,khv->hqv", p.astype(v.dtype), v,
+            preferred_element_type=jnp.float32)
+        return (m_new, alpha * l + p.sum(-1), acc), None
+
+    init = (jnp.full((H, s), NEG_INF, jnp.float32),
+            jnp.zeros((H, s), jnp.float32),
+            jnp.zeros((H, s, v_dim), jnp.float32))
+    (_, l, acc), _ = lax.scan(
+        block, init, (rows.reshape(-1, kb, rows.shape[-1]),
+                      keep.reshape(-1, kb, s)))
+    o = acc / jnp.where(l == 0.0, 1.0, l)[..., None]
+    return o.transpose(1, 0, 2).astype(q.dtype)
+
+
+def _dsa_heads_per_step(s: int, H: int, kv: int, n: int, r: int, v: int,
+                        W: int, itemsize: int) -> int:
+    per_head = (s * 2 * (round_up(n + r, 16) + v) * itemsize
+                + s * (v * 4 + 2 * 8 * 4) + 2 * kv * (n + v) * itemsize)
+    room = _VMEM_BLOCK_BYTES - 2 * _DSA_BLOCK_K * (W + s) * itemsize \
+        - _DSA_BLOCK_K * s * 8
+    return max([d for d in range(1, H + 1)
+                if H % d == 0 and d * per_head <= room], default=1)
+
+
+def _dsa_chunk_kernel(at_ref, q_ref, rows_ref, w_ref, keep_ref, o_ref, m_scr,
+                      l_scr, acc_scr, *, scale, hb, kv, n, r, v, blocks_max):
+    c = pl.program_id(1)
+    bias = keep_ref[...].astype(jnp.float32)                   # [bk, s]
+    bk, s = bias.shape
+
+    def head(h, carry):
+        pl.when(c == 0)(lambda: _softmax_init(h, m_scr, l_scr, acc_scr))
+        qt = q_ref[h]                                          # [n + r, s]
+        cols = pl.ds(pl.multiple_of(h * (n + v), _LANES), n + v)
+        # decompressed as ``mla_decompress`` does it: float32 sums rounded
+        # to the cache's dtype
+        kn_v = lax.convert_element_type(
+            _dot(rows_ref[:, :kv], w_ref[:, cols], _NN), qt.dtype)
+        k = lax.concatenate([_part(kn_v, 1, 0, n), rows_ref[:, kv:kv + r]], 1)
+        st = _dot(k, qt, _NN) * scale + bias                   # [bk, s]
+        st = lax.max(st, lax.full_like(st, NEG_INF))
+        _softmax_step(st, _part(kn_v, 1, n, v), h, slice(0, s), m_scr, l_scr,
+                      acc_scr)
+
+        # the grid's last block (its extent, as the call reads it)
+        @pl.when(c == jnp.minimum((at_ref[0] + s + bk - 1) // bk,
+                                  blocks_max) - 1)
+        def _finish():
+            o_ref[:, pl.ds(pl.multiple_of(h * v, _LANES), v)] = jnp.transpose(
+                acc_scr[h] / l_scr[h]).astype(o_ref.dtype)
+
+        return carry
+
+    lax.fori_loop(0, hb, head, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("nope", "scale", "interpret"))
+def _dsa_chunk_call(q, rows, wkvb, keep, at, *, nope: int, scale: float,
+                    interpret: bool):
+    s0, H, D = q.shape
+    L, P, W = rows.shape
+    kv, n, v = wkvb.shape[0], nope, wkvb.shape[2] - nope
+    bk = _DSA_BLOCK_K
+    s = round_up(s0, _LANES)      # a short bucket: idle queries, every key
+    hb = _dsa_heads_per_step(s, H, kv, n, D - n, v, W, rows.dtype.itemsize)
+    qt = jnp.pad(q.astype(rows.dtype).transpose(1, 2, 0),      # [H, n + r, s]
+                 ((0, 0), (0, 0), (0, s - s0)))
+    keep = jnp.pad(keep, ((0, 0), (0, s - s0)))
+    by_head = lambda g, c, at_ref: (g, 0, 0)
+    head_cols = lambda g, c, at_ref: (0, g)
+    o = pl.pallas_call(
+        functools.partial(_dsa_chunk_kernel, scale=scale, hb=hb, kv=kv, n=n,
+                          r=D - n, v=v, blocks_max=P // bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H // hb, jnp.minimum((at[0] + s + bk - 1) // bk, P // bk)),
+            in_specs=[pl.BlockSpec((hb, D, s), by_head),
+                      pl.BlockSpec((bk, W), lambda g, c, at_ref: (
+                          at_ref[1] * (P // bk) + c, 0)),
+                      pl.BlockSpec((kv, hb * (n + v)), head_cols),
+                      pl.BlockSpec((bk, s), lambda g, c, at_ref: (c, 0))],
+            out_specs=pl.BlockSpec((s, hb * v), head_cols),
+            scratch_shapes=[pltpu.VMEM((hb, 1, s), jnp.float32),
+                            pltpu.VMEM((hb, 1, s), jnp.float32),
+                            pltpu.VMEM((hb, v, s), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((s, H * v), qt.dtype),
+        interpret=interpret,
+        name="dsa_chunk_attention",
+    )(at, qt, rows.reshape(L * P, W),
+      wkvb.astype(rows.dtype).reshape(kv, -1), keep)
+    return o[:s0].reshape(s0, H, v)
+
+
+def dsa_chunk_attention(q, rows, wkvb, keep, start, *, nope: int,
+                        scale: float, layer: int = 0,
+                        impl: Optional[str] = None):
+    """Latent attention of one prefill chunk over the SELECTED keys:
+    :func:`mla_chunk_attention`'s operands and roundings (each block of
+    cache rows decompressed on chip to per-head keys and values, the scores
+    ``[k_n | k_r] q^T`` in float32, the online softmax, ``p`` rounded to the
+    cache's dtype for ``p v``) under ``keep`` [positions, s] bfloat16, an
+    additive bias a (key, query) pair: 0 where query t attends key j,
+    ``NEG_INF`` where it does not (``kda_mla.select_keys``; it carries the
+    causal mask, so every block is a masked one and the chunk's own rows
+    take no path of their own).  The selection differs by query, so no key
+    is skipped for the chunk as a whole: every row up to the chunk's last
+    query is decompressed and scored, a block of ``_DSA_BLOCK_K`` rows a
+    grid step, the second extent of the grid read at run time.  Returns [s,
+    H, v].  Every query attends at least one key (itself, or 2,048)."""
+    impl = resolve_impl(impl)
+    s, H, D = q.shape
+    _, P, W = rows.shape
+    kv, n, v = wkvb.shape[0], nope, wkvb.shape[2] - nope
+    start = jnp.asarray(start, jnp.int32)
+    impl = kernel_or_reference(
+        "dsa_chunk_attention", impl,
+        dsa_chunk_reference_reason(s, P, kv, n, v, W))
+    if impl == "xla":
+        return _dsa_attention_ref(q, rows[layer], wkvb, keep, nope=nope,
+                                  scale=scale)
+    return _dsa_chunk_call(q, rows, wkvb, keep,
+                           jnp.stack([start, jnp.int32(layer)]), nope=nope,
+                           scale=scale, interpret=interpret_flag(impl))

@@ -14,7 +14,11 @@ attention form, and :func:`cache_kind` is the one place that decides it
 - :class:`LatentPages` (latent-attention layers only,
   ``models/kda_mla.py``): one row a position that all heads share;
 - :class:`LatentPagesAndState` (linear-attention layers beside them): the
-  same pages and a recurrent state a slot.
+  same pages and a recurrent state a slot;
+- :class:`IndexedLatentPagesAndRing` (latent layers that attend a learned
+  selection of their keys, beside sliding latent layers of other sizes): a
+  latent row AND an index key a position under one page table, and a ring of
+  the sliding layers' rows a slot.
 
 A kind answers what the engine asks and nothing else: the pool's arguments
 and the device arrays; what it cannot be served with (``cannot``: one table
@@ -169,8 +173,10 @@ class FullPages:
                 "KV pool pages held by slots, by what they hold (EVA: window "
                 "rows reused in place, or chunk summaries; two budgets: the "
                 "sliding layers' rings, or the global layers' full pages; "
-                "full attention: all window)", labels={"kind": kind})
-            for kind in ("window", "summary", "full")}
+                "full attention: all window; a learned selection: the "
+                "sliding layers' rings, the latent pages, and the same "
+                "pages' index keys)", labels={"kind": kind})
+            for kind in ("window", "summary", "full", "index")}
         self._reg = registry
         self._m = {name: registry.counter(name) for name in self.counters}
 
@@ -566,8 +572,176 @@ class LatentPagesAndState(LatentPages):
         return counts
 
 
+class IndexedLatentPagesAndRing(LatentPages):
+    """Latent layers under a learned selection of their keys beside sliding
+    latent layers (``models/kda_mla.py``): ``latent`` pages and, under the
+    same table, ``index`` pages of one index key a position ([latent layers,
+    pages, 1, page, index size]); per SLOT a ``ring`` of the sliding layers'
+    rows ([sliding layers, slots, ring rows, their row width], a row of
+    position p at ``p % ring rows``, masked by the position it holds), never
+    allocated or freed: a request reads no row of it that it has not written
+    (a ring row before the sequence's start, or older than the window, is
+    masked by position)."""
+
+    what = "latent_attention layers under an indexer / " \
+           "latent_sliding_attention layers"
+    _not = ("a position here is a latent row AND an index key, and the "
+            "sliding layers' ring is a slot's, not a page's (ROADMAP R10)")
+    cannot = {
+        "handoff": "serving/handoff.py ships pages as per-head K and V; "
+                   + _not,
+        "kv_host_tier_pages": "serving/host_tier.py demotes and promotes "
+                              "pages as per-head K and V; " + _not,
+        "prefix_caching": "serving/prefix_cache.py shares pages as per-head "
+                          "K and V of a token prefix; " + _not,
+        "quantize_kv_cache":
+            "the int8 cache of models/decoding.py scales per-head K and V "
+            "rows; a latent row and an index key have no int8 form (the "
+            "release's FP8 index keys are left out)",
+        "use_fused_decode":
+            "the decode step over index keys, selected rows and rings is "
+            "built on the fused path only (models/kda_mla.py:fused_layers)",
+    }
+    counters = {
+        **LatentPages.counters,
+        "ds_serve_dsa_keys_scored_total":
+            "index keys the live decode rows scored in ONE indexed layer "
+            "(pos + 1 a step), summed over rows and steps",
+        "ds_serve_dsa_keys_attended_total":
+            "keys the live decode rows attended in ONE indexed layer "
+            "(min(pos + 1, mla_index_topk) a step), summed over rows and "
+            "steps",
+        "ds_serve_dsa_chunk_keys_scored_total":
+            "index keys the real tokens of the prefill chunks scored in ONE "
+            "indexed layer (t + 1 for the token at t)",
+        "ds_serve_dsa_chunk_keys_attended_total":
+            "keys the real tokens of the prefill chunks attended in ONE "
+            "indexed layer (min(t + 1, mla_index_topk))",
+        "ds_serve_attn_window_rows_total":
+            TwoBudgets.counters["ds_serve_attn_window_rows_total"],
+    }
+    takes_valid_len = True          # a ring takes no pad row
+    pages_by_kind = True
+
+    def _ring(self, pool):
+        """(sliding layers, ring rows, a ring row's width)."""
+        cfg = self.cfg
+        kd = cfg.mla_kind("latent_sliding_attention")
+        return (len(kda_mla.sliding_layers(cfg)),
+                kda_mla.ring_rows(cfg, pool.page), kd.row_width)
+
+    def pool_args(self, dtype):
+        cfg = self.cfg
+        if not kda_mla.sliding_layers(cfg):
+            return {}
+        # the pool's page is not known yet: the ring's bytes are reported
+        # for rows of the window itself
+        kd = cfg.mla_kind("latent_sliding_attention")
+        return {"slot_state_bytes": len(kda_mla.sliding_layers(cfg))
+                * cfg.sliding_window * kd.row_width
+                * jnp.dtype(dtype).itemsize}
+
+    def init_cache(self, pool, num_slots, dtype, quantized):
+        cfg = self.cfg
+        out = super().init_cache(pool, num_slots, dtype, quantized)
+        kd = cfg.mla_kind("latent_attention")
+        if kd.index:
+            out["index"] = jnp.zeros(out["latent"].shape[:-1]
+                                     + (kd.index[1],), dtype)
+        if kda_mla.sliding_layers(cfg):
+            n, rows, width = self._ring(pool)
+            out["ring"] = jnp.zeros((n, num_slots, rows, width), dtype)
+        return out
+
+    def layout(self, pool, num_slots):
+        return (super().layout(pool, num_slots) + " and index keys, and "
+                f"rings of {self._ring(pool)[1]} rows a slot in "
+                f"{self._ring(pool)[0]} sliding layers")
+
+    def check_prefill_chunk(self, prefill_chunk):
+        pass        # a chunk longer than the ring leaves its last rows there
+
+    def view(self, cache, pt_row, slot, start, cb):
+        """Every latent and index page of the slot, and the slot's rings
+        sliced out by its index."""
+        cols = range(pt_row.shape[0])
+        out = {k: _slot_view(cache[k], pt_row, cols)
+               for k in ("latent", "index") if k in cache}
+        if "ring" in cache:
+            out["ring"] = jax.lax.dynamic_slice_in_dim(cache["ring"], slot, 1,
+                                                       axis=1)
+        return out
+
+    def write_back(self, cache, sub, pt_row, slot, start, cb):
+        """Of the pages only those the chunk's ``cb`` rows can have touched
+        go back; the rings in place at the slot's index."""
+        page = cache["latent"].shape[3]
+        pages = _touched(start, cb, page, pt_row.shape[0])
+        out = {k: _slot_write_back(cache[k], sub[k], pt_row, 0, pages)
+               for k in ("latent", "index") if k in cache}
+        if "ring" in cache:
+            out["ring"] = jax.lax.dynamic_update_slice_in_dim(
+                cache["ring"], sub["ring"], slot, axis=1)
+        return out
+
+    def attach(self, registry, pool):
+        super().attach(registry, pool)
+        registry.gauge("ds_serve_state_bytes").set(pool.state_bytes)
+
+    def count_chunk(self, pool, cache, off, c, cb):
+        """The rows the chunk's attention decompresses (an indexed layer:
+        every row up to the bucket's end, whole blocks; a sliding layer: the
+        window before the chunk and the bucket), the rows it writes, and
+        the keys its real tokens score and attend in one indexed layer."""
+        if not self._reg.enabled:
+            return
+        from deepspeed_tpu.ops.pallas.flash_attention import _DSA_BLOCK_K
+
+        cfg = self.cfg
+        n_lat = cache["latent"].shape[0]
+        n_sw = len(kda_mla.sliding_layers(cfg))
+        m = self._m
+        m["ds_serve_mla_rows_expanded_total"].inc(
+            n_lat * -(-(off + cb) // _DSA_BLOCK_K) * _DSA_BLOCK_K
+            + n_sw * (cb + max(cfg.sliding_window - 1, 0)))
+        m["ds_serve_mla_rows_written_total"].inc((n_lat + n_sw) * c)
+        t = np.arange(off, off + c) + 1
+        m["ds_serve_dsa_chunk_keys_scored_total"].inc(int(t.sum()))
+        m["ds_serve_dsa_chunk_keys_attended_total"].inc(
+            int(np.minimum(t, cfg.mla_index_topk or t.max()).sum()))
+
+    def count_rows(self, pos, n):
+        """``ds_serve_dsa_keys_*`` and ``ds_serve_attn_window_rows_total``:
+        what each step scores and attends in one indexed layer, and attends
+        in one sliding layer."""
+        if not self._reg.enabled:
+            return
+        cfg = self.cfg
+        p = np.arange(pos, pos + n) + 1
+        m = self._m
+        m["ds_serve_dsa_keys_scored_total"].inc(int(p.sum()))
+        m["ds_serve_dsa_keys_attended_total"].inc(
+            int(np.minimum(p, cfg.mla_index_topk or p.max()).sum()))
+        if cfg.sliding_window:
+            m["ds_serve_attn_window_rows_total"].inc(
+                int(np.minimum(p, cfg.sliding_window).sum()))
+
+    def page_gauges(self, pool):
+        """The three budgets: the rings of the slots that hold pages (as
+        pages), the latent pages, and the same pages' index keys."""
+        if not self._reg.enabled:
+            return
+        held = [pool.slot_pages_used(s) for s in range(pool.num_slots)]
+        ring = self._ring(pool)[1] // pool.page \
+            if kda_mla.sliding_layers(self.cfg) else 0
+        self._pages_kind["window"].set(ring * sum(n > 0 for n in held))
+        self._pages_kind["full"].set(sum(held))
+        self._pages_kind["index"].set(
+            sum(held) if self.cfg.mla_index_topk else 0)
+
+
 KINDS = (FullPages, WindowSummaryPages, TwoBudgets, LatentPages,
-         LatentPagesAndState)
+         LatentPagesAndState, IndexedLatentPagesAndRing)
 
 
 def cache_kind(cfg) -> FullPages:
@@ -575,6 +749,8 @@ def cache_kind(cfg) -> FullPages:
     if getattr(cfg, "is_eva", False):
         return WindowSummaryPages(cfg)
     if getattr(cfg, "is_kda_mla", False):
+        if cfg.mla_index_topk or kda_mla.sliding_layers(cfg):
+            return IndexedLatentPagesAndRing(cfg)
         return (LatentPagesAndState if kda_mla.kind_layers(cfg)[0]
                 else LatentPages)(cfg)
     if getattr(cfg, "is_afmoe", False):

@@ -90,7 +90,15 @@ channels]``, the short convolution's last inputs): it belongs to the slot,
 not to a page, is never allocated or freed, and is RESET when a request
 takes the slot (the engine's first chunk program of a request reads zeros
 whatever the slot held, and counts ``ds_serve_state_resets_total``).
-``ensure`` /
+Under a learned selection of keys (``cache_kind.IndexedLatentPagesAndRing``)
+a position of a latent layer is a latent row AND an index key (``index``
+``[latent layers, pages, 1, page, index size]``, the same pages under the
+same table), and a sliding latent layer keeps a RING a slot in the same
+kind of budget as a state (``ring`` ``[sliding layers, slots, ring rows, the
+kind's row width]``, ``ring rows`` the whole pages that hold a window: the
+row of position p at ``p % ring rows``, attended where the position it
+holds lies in the window; never reset, because a row is read only under the
+position it was written for).  ``ensure`` /
 ``release`` / ``check_no_leak`` keep their meaning, for pages.
 
 Physical **page 0 is reserved as the junk page**: it is never allocated,

@@ -194,8 +194,8 @@ def test_yarn_at_the_published_numbers_by_hand():
     assert m * m == pytest.approx(1.81326, rel=1e-5)
     cfg = ModelConfig(**dict(FIELDS, mla_rope=PUBLISHED_ROPE,
                              mla_nope_dim=128, mla_rot_dim=64))
-    assert kda_mla._mla_scale(cfg) == pytest.approx(1.81326 / math.sqrt(192),
-                                                    rel=1e-5)
+    assert kda_mla._mla_scale(cfg.mla_kind("latent_attention")) == \
+        pytest.approx(1.81326 / math.sqrt(192), rel=1e-5)
     # the factors: cos and sin are scaled where mscale != mscale_all_dim,
     # and the softmax scale only where mscale_all_dim is set
     _, ratio, m0 = kda_mla.yarn(dict(PUBLISHED_ROPE, mscale_all_dim=0), 64)
@@ -207,7 +207,7 @@ def test_yarn_at_the_published_numbers_by_hand():
 
 
 def test_rotation_turns_pairs_and_scores_see_only_the_distance():
-    cfg = ModelConfig(**FIELDS)
+    cfg = ModelConfig(**FIELDS).mla_kind("latent_attention")
     t = jax.random.normal(jax.random.PRNGKey(0), (5, 3, 8))
     pos = jnp.asarray([0, 1, 7, 40, 200])
     got = np.asarray(kda_mla.rotate(cfg, t, pos[:, None]))
@@ -223,7 +223,8 @@ def test_rotation_turns_pairs_and_scores_see_only_the_distance():
     assert dot(50, 43) == pytest.approx(dot(7, 0), rel=1e-4)
     assert abs(dot(50, 43) - dot(50, 40)) > 1e-3
     # no position encoding: the values pass as they are
-    plain = ModelConfig(**dict(FIELDS, mla_rope=None))
+    plain = ModelConfig(**dict(FIELDS, mla_rope=None)).mla_kind(
+        "latent_attention")
     assert kda_mla.rotate(plain, t, pos[:, None]) is t
     assert kda_mla._mla_scale(plain) == pytest.approx(24 ** -0.5)
 
@@ -239,8 +240,9 @@ def test_absorbed_latent_attention_is_the_decompressed(model):
     ks = jax.random.split(jax.random.PRNGKey(5), 2)
     h = jax.random.normal(ks[0], (3, 64))
     hist = jax.random.normal(ks[1], (3, 40, 64))
-    q, row = kda_mla.mla_project(cfg, a, h, jnp.full((3,), 40))
-    _, rows = kda_mla.mla_project(cfg, a, hist, jnp.arange(40)[None])
+    kd = cfg.mla_kind("latent_attention")     # the sizes are the KIND's
+    q, row, _ = kda_mla.mla_project(kd, a, h, jnp.full((3,), 40))
+    _, rows, _ = kda_mla.mla_project(kd, a, hist, jnp.arange(40)[None])
     assert q.shape == (3, 4, 24)
     assert rows.shape[-1] == kda_mla.row_width(cfg) == 128
     assert not np.asarray(rows[..., 40:]).any()                # the padding
@@ -249,18 +251,18 @@ def test_absorbed_latent_attention_is_the_decompressed(model):
     np.testing.assert_array_equal(rows[:, 0, 32:40], raw[:, 0])
     assert np.abs(np.asarray(rows[:, 5:, 32:40] - raw[:, 5:])).max() > 1e-2
     rows = jnp.concatenate([rows, row[:, None]], axis=1)       # own row last
-    scale = kda_mla._mla_scale(cfg)
+    scale = kda_mla._mla_scale(kd)
     assert scale == pytest.approx(1.81326 / math.sqrt(24), rel=1e-5)
     with jax.default_matmul_precision("highest"):
-        k, v = kda_mla.mla_decompress(rows, *kda_mla._wkvb(cfg, a),
+        k, v = kda_mla.mla_decompress(rows, *kda_mla._wkvb(kd, a),
                                       cfg.mla_rot_dim)         # [3, 41, H, .]
         s = jnp.einsum("bhd,bjhd->bhj", q, k) * scale
         want = jnp.einsum("bhj,bjhv->bhv", jax.nn.softmax(s, -1), v)
-        qa = kda_mla.mla_absorb(cfg, a, q)
+        qa = kda_mla.mla_absorb(kd, a, q)
         p = jax.nn.softmax(jnp.einsum("bhw,bjw->bhj", qa, rows) * scale, -1)
-        got = kda_mla.mla_unabsorb(cfg, a, jnp.einsum("bhj,bjw->bhw", p,
-                                                      rows))
-    np.testing.assert_allclose(got, want.reshape(3, -1), rtol=1e-4, atol=1e-5)
+        got = kda_mla.mla_unabsorb(kd, a, jnp.einsum("bhj,bjw->bhw", p,
+                                                     rows))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
 # ------------------------------------------------------------ the router

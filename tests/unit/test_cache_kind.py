@@ -1,8 +1,9 @@
-"""The contract of ``serving/cache_kind.py``, once over the five kinds of
+"""The contract of ``serving/cache_kind.py``, once over the six kinds of
 slot cache at the tiny sizes their model tests build: full pages (a tiny
 Llama), window + summary pages (``test_evabyte``), two page budgets
 (``test_trinity``), latent pages alone (``test_axk1``), latent pages + slot
-state (``test_kimi_linear``).
+state (``test_kimi_linear``), latent pages + index keys + slot rings
+(``test_dots3_note``).
 
 What every kind owes the engine: a slot's view written back unchanged leaves
 the pool as it was, and a changed one touches nobody else's pages; an
@@ -18,12 +19,15 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, causal_lm
-from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages, LatentPages,
+from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages,
+                                              IndexedLatentPagesAndRing,
+                                              LatentPages,
                                               LatentPagesAndState, TwoBudgets,
                                               WindowSummaryPages, cache_kind)
 from deepspeed_tpu.serving.paged_kv import PagedKVPool
 
-from . import test_axk1, test_evabyte, test_kimi_linear, test_trinity
+from . import (test_axk1, test_dots3_note, test_evabyte, test_kimi_linear,
+               test_trinity)
 
 ENGINE = dict(num_slots=3, prefill_chunk=16, max_prefill_chunks=2,
               decode_block_tokens=4, max_out_tokens=96, kv_page_tokens=8,
@@ -36,7 +40,10 @@ CASES = {
     "latent": (LatentPages, test_axk1.FIELDS, test_axk1.ENGINE),
     "state": (LatentPagesAndState, test_kimi_linear.FIELDS,
               test_kimi_linear.ENGINE),
+    "indexed": (IndexedLatentPagesAndRing, test_dots3_note.FIELDS,
+                test_dots3_note.ENGINE),
 }
+BY_SLOT = ("state", "tail", "ring")     # entries [layers, slots, ...]
 NAMES = list(CASES)
 
 
@@ -121,18 +128,18 @@ def test_a_view_written_back_leaves_the_pool_and_a_changed_one_its_neighbours(
     changed = there_and_back(cache, 1.0)
     for k, before in cache.items():
         before, after = np.asarray(before), np.asarray(changed[k])
-        if k in ("state", "tail"):              # [layers, slots, ...]
+        if k in BY_SLOT:                        # [layers, slots, ...]
             mine = [slot]
         elif k.endswith("_win"):
             mine = pool._owned_win[slot]
         else:
             mine = pool._owned[slot]
         others = [i for i in range(before.shape[1]) if i not in mine
-                  and (i != 0 or k in ("state", "tail"))]
+                  and (i != 0 or k in BY_SLOT)]
         np.testing.assert_array_equal(after[:, others], before[:, others],
                                       err_msg=k)
         # the slot's own state; a ring page; the page of position ``start``
-        under_start = (slot if k in ("state", "tail") else
+        under_start = (slot if k in BY_SLOT else
                        mine[0] if k.endswith("_win") else mine[start // 8])
         assert (after[:, under_start] == before[:, under_start] + 1.0).all()
 
@@ -228,7 +235,8 @@ def test_prefill_only_is_refused_with_the_kinds_reason(built, name):
     serve.close()
 
 
-@pytest.mark.parametrize("name", ["two_budgets", "latent", "state"])
+@pytest.mark.parametrize("name", ["two_budgets", "latent", "state",
+                                  "indexed"])
 def test_the_decode_role_is_refused_with_the_kinds_reason(built, name):
     with pytest.raises(NotImplementedError) as err:
         serve_of(built, name, **ASKED["handoff"])
@@ -264,3 +272,87 @@ def test_the_removed_paged_kv_cache_option(built, value):
     else:
         with pytest.raises(ValueError, match="paged_kv_cache=False.*removed"):
             serve_of(built, "full", paged_kv_cache=False)
+
+
+# -- the sixth kind: index keys under the latent pages' table, rings by slot --
+def test_the_indexed_kinds_arrays_and_pool_arguments(built):
+    cfg = built("indexed")[0].config
+    kind = cache_kind(cfg)
+    # three sliding layers' windows of 13 rows of 128 values, float32
+    assert kind.pool_args(jnp.float32) == {
+        "slot_state_bytes": 3 * 13 * 128 * 4}
+    pool = PagedKVPool(3, 96, page_tokens=8, **kind.pool_args(jnp.float32))
+    cache = kind.init_cache(pool, 3, jnp.float32, False)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "latent": (2, pool.num_pages, 1, 8, 128),
+        "index": (2, pool.num_pages, 1, 8, 16),
+        "ring": (3, 3, 16, 128)}            # 13 rows in whole pages of 8
+    assert kind.takes_valid_len and kind.pages_by_kind
+    assert "rings of 16 rows a slot in 3 sliding layers" in kind.layout(pool,
+                                                                         3)
+
+
+@pytest.mark.parametrize("option", [*ASKED, "prefix_caching"])
+def test_the_indexed_kind_refuses_by_its_own_reasons(built, option):
+    kind = cache_kind(built("indexed")[0].config)
+    assert set(kind.cannot) == {*ASKED, "prefix_caching"}
+    if option == "prefix_caching":          # turned off, with the reason
+        serve = serve_of(built, "indexed")
+        assert serve.prefix_cache is None and "index key" in kind.cannot[option]
+        serve.close()
+        return
+    with pytest.raises(NotImplementedError) as err:
+        serve_of(built, "indexed", **ASKED[option])
+    assert kind.cannot[option] in str(err.value)
+
+
+def test_the_indexed_kinds_counters_follow_the_positions(built):
+    """Served with the registry on: the decode rows' keys scored and attended
+    (``pos + 1`` and ``min(pos + 1, 16)`` a step), the chunks' pair of the
+    same, the window rows of one sliding layer, and the three budgets."""
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+
+    model, params = built("indexed")
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(
+        model, config=dict(CASES["indexed"][2]), params=params,
+        mesh=model.mesh, registry=reg)
+    prompt, n_out = 37, 12
+    serve.submit(np.arange(prompt) % 96, max_new_tokens=n_out)
+    for _ in range(2):
+        serve.step()
+    serve.kind.page_gauges(serve.pool)
+    held = {k: reg.get("ds_serve_kv_pages_used_by_kind", {"kind": k}).value
+            for k in ("window", "full", "index")}
+    serve.run()
+    value = lambda name: reg.get(name).value
+    t = np.arange(prompt) + 1
+    assert value("ds_serve_dsa_chunk_keys_scored_total") == t.sum()
+    assert value("ds_serve_dsa_chunk_keys_attended_total") == \
+        np.minimum(t, 16).sum()
+    # the first token comes from the last chunk; the steps run from there
+    p = np.arange(prompt, prompt + n_out - 1) + 1
+    assert value("ds_serve_dsa_keys_scored_total") == p.sum()
+    assert value("ds_serve_dsa_keys_attended_total") == 16 * len(p)
+    assert value("ds_serve_attn_window_rows_total") == 13 * len(p)
+    assert value("ds_serve_mla_rows_written_total") == 5 * prompt
+    # one busy slot: its rings as two pages, its latent pages, their keys
+    assert held["window"] == 2 and held["full"] == held["index"] >= 5
+    serve.close()
+
+
+@pytest.mark.parametrize("fields,words", [
+    (dict(mla_sliding=None), "latent_sliding_attention layers and the group"),
+    (dict(mla_sliding=dict(test_dots3_note.SLIDING, kv_rank=0)),
+     "missing: ['kv_rank']"),
+    (dict(sliding_window=0), "missing: ['sliding_window']"),
+    (dict(mla_sliding=dict(test_dots3_note.SLIDING, index_topk=16)),
+     "an indexer on a sliding layer"),
+    (dict(mla_index_heads=0), "come together"),
+    (dict(mla_q_rank=0), "the index queries are made from the query's"),
+], ids=["no_sizes", "a_size_of_zero", "no_window", "an_indexer", "half_an_indexer",
+        "an_indexer_without_its_bottleneck"])
+def test_model_config_refuses_by_name(fields, words):
+    with pytest.raises(ValueError) as err:
+        ModelConfig(**dict(test_dots3_note.FIELDS, **fields))
+    assert words in str(err.value)

@@ -248,22 +248,23 @@ def test_absorbed_latent_attention_is_the_decompressed(model):
     ks = jax.random.split(jax.random.PRNGKey(5), 2)
     h = jax.random.normal(ks[0], (3, 64))
     hist = jax.random.normal(ks[1], (3, 40, 64))
-    q, row = kda_mla.mla_project(cfg, a, h, None)                # [3, H, 24]
-    _, rows = kda_mla.mla_project(cfg, a, hist, None)           # [3, 40, W]
+    kd = cfg.mla_kind("latent_attention")     # the sizes are the KIND's
+    q, row, _ = kda_mla.mla_project(kd, a, h, None)              # [3, H, 24]
+    _, rows, _ = kda_mla.mla_project(kd, a, hist, None)         # [3, 40, W]
     assert rows.shape[-1] == kda_mla.row_width(cfg) == 128
     assert not np.asarray(rows[..., 40:]).any()                # the padding
     rows = jnp.concatenate([rows, row[:, None]], axis=1)       # own row last
     with jax.default_matmul_precision("highest"):
-        k, v = kda_mla.mla_decompress(rows, *kda_mla._wkvb(cfg, a),
+        k, v = kda_mla.mla_decompress(rows, *kda_mla._wkvb(kd, a),
                                       cfg.mla_rot_dim)         # [3, 41, H, .]
         s = jnp.einsum("bhd,bjhd->bhj", q, k) * 24 ** -0.5
         want = jnp.einsum("bhj,bjhv->bhv", jax.nn.softmax(s, -1), v)
-        qa = kda_mla.mla_absorb(cfg, a, q)
+        qa = kda_mla.mla_absorb(kd, a, q)
         p = jax.nn.softmax(jnp.einsum("bhw,bjw->bhj", qa, rows)
                            * 24 ** -0.5, -1)
-        got = kda_mla.mla_unabsorb(cfg, a, jnp.einsum("bhj,bjw->bhw", p,
-                                                      rows))
-    np.testing.assert_allclose(got, want.reshape(3, -1), rtol=1e-4, atol=1e-5)
+        got = kda_mla.mla_unabsorb(kd, a, jnp.einsum("bhj,bjw->bhw", p,
+                                                     rows))
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
 def test_sum_of_the_eight_shares_is_the_whole_layer(ref, model):

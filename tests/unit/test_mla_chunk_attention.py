@@ -23,19 +23,20 @@ ROPE = {"theta": 10000, "factor": 32, "original_max_position_embeddings": 64,
 
 
 def _cfg(heads, rope):
+    """The latent KIND (``MlaKind``) whose sizes ``kda_mla``'s pieces read."""
     return ModelConfig(
         vocab_size=96, hidden_size=64, num_layers=1, num_heads=heads,
         max_seq_len=4096, layer_types=("latent_attention",),
         num_dense_layers=1, dense_intermediate_size=64,
         moe_drop_tokens=False, mla_kv_rank=KV, mla_nope_dim=N, mla_rot_dim=R,
-        mla_v_dim=V, mla_rope=rope)
+        mla_v_dim=V, mla_rope=rope).mla_kind("latent_attention")
 
 
 def _inputs(cfg, s, rows, start, dtype, seed):
     """q [s, H, n + r], the slot's view [rows, W] and the layer's weights:
     queries and the shared key part rotated where the model rotates, zeros
     in a row's pad, and GARBAGE at and past ``start + s``."""
-    H = cfg.num_heads
+    H = cfg.heads
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     a = {"wkvb": (jax.random.normal(ks[0], (KV, H * (N + V))) * KV ** -0.5
                   ).astype(dtype)}
@@ -60,7 +61,7 @@ def _attend(cfg, a, q, view, start):
         live_keys=start + s, expand=lambda rb: tuple(
             t[:, 0].transpose(0, 2, 1, 3)
             for t in kda_mla.mla_decompress(rb, *kda_mla._wkvb(cfg, a),
-                                            cfg.mla_rot_dim))
+                                            cfg.rot))
     )[0].transpose(1, 0, 2)
 
 
@@ -190,7 +191,8 @@ def test_refused_sizes_run_attend_and_say_so_once():
         vocab_size=96, hidden_size=64, num_layers=1, num_heads=2,
         layer_types=("latent_attention",), num_dense_layers=1,
         dense_intermediate_size=64, moe_drop_tokens=False, mla_kv_rank=32,
-        mla_nope_dim=16, mla_rot_dim=8, mla_v_dim=16)
+        mla_nope_dim=16, mla_rot_dim=8, mla_v_dim=16).mla_kind(
+            "latent_attention")
     ks = jax.random.split(jax.random.PRNGKey(0), 3)
     q = jax.random.normal(ks[0], (8, 2, 24))
     view = jax.random.normal(ks[1], (96, 128))

@@ -841,3 +841,64 @@ def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
     calls = lambda name: len(re.findall(
         rf"custom-call\([^\n]*{name}", text))
     assert calls("fused_norm_qkv") == 2 * calls("mla_decode_paged") > 0
+
+
+def test_dots3_note_cell_programs_compile_with_pages_and_rings_in_place(
+        v5e, chip_kernels):
+    """ISSUE 52: the chunk programs (buckets 1,024 and 64: a short bucket's
+    queries are padded to a lane tile) and the decode block of the
+    ``dots3-note-L5-ep16.serve-doc-48k`` cell (the published widths: 128
+    heads against a 640-value row under 64 index heads of 128, 64 heads
+    against a 1,152-value ring row; depth cut to one full and one sliding
+    expert layer, the pool to 6 slots' worth of 32,768 positions, which
+    change no shape: half of the latent pages stays larger than a chunk's
+    sorted expert rows, the one gather a chunk program may hold) compile for the v5e: the latent pages and the index
+    keys stay where they are, a chunk's grouped matmuls take their odd
+    number of tiles, a chunk program carries the two selection kernels a
+    full layer and no ``mla_chunk_attention``, the decode block the two
+    decode kernels a full layer and no ``mla_decode_paged``."""
+    cell = _ServeCell(
+        v5e, "dots3-note-L5-ep16", "dots3-note-L5-ep16.serve-doc-48k",
+        fields=dict(num_layers=2, num_dense_layers=0,
+                    layer_types=["latent_attention",
+                                 "latent_sliding_attention"]),
+        engine=dict(kv_pool_tokens=6 * 32768))
+    cache = cell.serve._cache
+    assert {k: v.shape[2:] for k, v in cache.items()} == {
+        "latent": (1, 256, 640), "index": (1, 256, 128), "ring": (768, 1152)}
+    # the index keys and the rings are smaller than a chunk's sorted expert
+    # rows: their copies are looked for by shape
+    cell.smallest_pool = cache["latent"].nbytes
+    shape = lambda k: ",".join(str(d) for d in cache[k].shape)
+    calls = lambda text, name: len(re.findall(
+        rf"custom-call\([^\n]*{name}", text))
+    chunks = (cell.chunk(1024), cell.chunk(64))
+    for program, tokens in zip(chunks, (1024, 64)):
+        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+            program, tokens * 8)
+        text = program.as_text()
+        assert (calls(text, "dsa_index_scores_chunk"),
+                calls(text, "dsa_chunk_attention"),
+                calls(text, "mla_chunk_attention")) == (1, 1, 0)
+        # the per-head index products [64, bucket, keys] never exist
+        assert not re.search(rf"f32\[(1,)?64,{tokens},\d{{4,}}\]", text)
+    block = cell.block()
+    for program in chunks + (block,):
+        cell.assert_pools_stay_in_place(program)
+        for key in ("index", "ring"):
+            assert not re.findall(rf"bf16\[{shape(key)}\]\S* copy\(",
+                                  program.as_text()), key
+        mem = program.memory_analysis()
+        print("memory", mem.temp_size_in_bytes, mem.argument_size_in_bytes,
+              mem.output_size_in_bytes, mem.alias_size_in_bytes)
+    text = block.as_text()
+    for name in ("dsa_index_scores_paged", "dsa_decode_selected",
+                 "paged_kv_append", "fused_norm_qkv", "fused_proj_norm",
+                 "fused_moe_mlp"):
+        assert name in text, name
+    # by call, not by name: the text's source table may hold the name of an
+    # older model's kernel whose cached helper (``_live_rows``) this one shares
+    assert calls(text, "mla_decode_paged") == \
+        calls(text, "kda_decode_step") == 0
+    assert calls(text, "dsa_index_scores_paged") == \
+        calls(text, "dsa_decode_selected") > 0
