@@ -36,8 +36,9 @@ losses agree step by step, params and optimizer state are spread a quarter
 per device, and the stage-3 step holds all-gather and reduce-scatter.
 
 Each phase prints one JSON line.  Its ``wall_s`` are SMOKE TIMINGS on a
-host clock (``compile`` is what jax.monitoring reports for backend compiles
-and reads of the persistent cache inside the phase; ``steady`` is the rest)
+host clock (``trace``, ``lower`` and ``compile`` are what the package's
+compile ledger counted inside the phase, ``compile`` being backend compiles
+and reads of the persistent cache; ``steady`` is the rest)
 — they say whether the compile cache hit, and are not metrics.  Any failed check or
 exception ends the run with a non-zero exit code; nothing is caught and
 reported as a status.  Without a TPU the script exits non-zero before it
@@ -129,58 +130,58 @@ def collectives_in(compiled_text: str) -> List[str]:
     return sorted(found)
 
 
-class CompileClock:
-    """Seconds jax spent in backend compiles (or reading the persistent
-    cache in their place) while the context was open, and how often that
-    cache hit, from jax.monitoring's own events.  Tracing and lowering are
-    left out: their events nest and would count twice."""
+class JitClock:
+    """What the package's compile ledger (``profiling/trace.py``) counted
+    while the context was open: seconds jax spent tracing, lowering and in
+    backend compiles (or reads of the persistent cache in their place), self
+    time so that the three never hold a second twice, and how often that
+    cache hit.  The ledger listens while the process's registry is enabled,
+    so the context enables it, and puts it back as it was."""
 
-    _DURATIONS = ("/jax/core/compile/backend_compile_duration",)
+    _NAMES = {"trace": "ds_jit_trace_seconds_total",
+              "lower": "ds_jit_lower_seconds_total",
+              "compile": "ds_jit_compile_seconds_total",
+              "cache_hits": "ds_jit_cache_hits_total",
+              "cache_misses": "ds_jit_cache_misses_total"}
 
-    def __init__(self):
-        self.seconds = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-
-    def _on_duration(self, event: str, secs: float, **_) -> None:
-        if event in self._DURATIONS:
-            self.seconds += secs
-
-    def _on_event(self, event: str, **_) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
+    def _read(self) -> Dict[str, float]:
+        return {k: self._registry.get(name).value
+                for k, name in self._NAMES.items()}
 
     def __enter__(self):
-        from jax import monitoring
+        from deepspeed_tpu.monitor.metrics import get_registry
 
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
+        self._registry = get_registry()
+        self._was_enabled = self._registry.enabled
+        self._registry.enable()
+        self._before = self._read()
         return self
 
     def __exit__(self, *exc):
-        from jax import monitoring
-
-        monitoring.unregister_event_duration_listener(self._on_duration)
-        monitoring.unregister_event_listener(self._on_event)
+        for k, v in self._read().items():
+            setattr(self, k, v - self._before[k])
+        if not self._was_enabled:
+            self._registry.disable()
 
 
 def _phase_record(phase: str, model, n_params: int, t0: float,
-                  clock: CompileClock, device, **rest) -> Dict[str, Any]:
+                  clock: JitClock, device, **rest) -> Dict[str, Any]:
     from deepspeed_tpu.ops.pallas.common import reference_selections
 
     total = time.perf_counter() - t0
+    jit = clock.trace + clock.lower + clock.compile
     stats = device.memory_stats() or {}
     return {"phase": phase, "model": model, "params": int(n_params),
             "wall_s": {"total": round(total, 2),
-                       "compile": round(clock.seconds, 2),
-                       "steady": round(total - clock.seconds, 2),
-                       "note": "smoke timing, not a metric; compile = XLA "
-                               "backend compiles and cache reads, steady = "
-                               "the rest, tracing and lowering included"},
-            "compile_cache": {"hits": clock.cache_hits,
-                              "misses": clock.cache_misses},
+                       "trace": round(clock.trace, 2),
+                       "lower": round(clock.lower, 2),
+                       "compile": round(clock.compile, 2),
+                       "steady": round(total - jit, 2),
+                       "note": "smoke timing, not a metric; trace, lower = "
+                               "jax's own stages, compile = XLA backend "
+                               "compiles and cache reads, steady = the rest"},
+            "compile_cache": {"hits": int(clock.cache_hits),
+                              "misses": int(clock.cache_misses)},
             "peak_bytes": stats.get("peak_bytes_in_use"),
             **rest,
             "reference_in_place_of_kernel": [
@@ -276,7 +277,7 @@ def train_phase(devices, *, preset: str = "gpt2-small",
     from deepspeed_tpu.models import causal_lm
 
     t0 = time.perf_counter()
-    with CompileClock() as clock:
+    with JitClock() as clock:
         mesh = build_mesh(devices=list(devices[:1]))
         model = causal_lm(preset, mesh=mesh, **(overrides or {}))
         engine, _, _, _ = deepspeed_tpu.initialize(
@@ -316,7 +317,7 @@ def serve_phase(devices, *, preset: str = "gpt2-xl",
     from deepspeed_tpu.models import causal_lm
 
     t0 = time.perf_counter()
-    with CompileClock() as clock:
+    with JitClock() as clock:
         mesh = build_mesh(devices=list(devices[:1]))
         model = causal_lm(preset, mesh=mesh, **(overrides or {}))
         # seeded weights, cast inside the init program: the fp32 tree (twice
@@ -439,7 +440,7 @@ def sharded_phase(devices, *, preset: str = "gpt2-small",
 
     n = len(devices)
     t0 = time.perf_counter()
-    with CompileClock() as clock:
+    with JitClock() as clock:
         probe = causal_lm(preset, **(overrides or {}))
         weights = jax.device_get(
             jax.jit(probe.init)(jax.random.PRNGKey(SEED)))

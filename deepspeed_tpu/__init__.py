@@ -32,16 +32,18 @@ def initialize(args=None, model=None, optimizer=None, model_parameters=None,
     ``deepspeed_tpu/runtime/engine.py`` for the engine design (functional
     jitted train step under an imperative forward/backward/step façade).
     """
+    from deepspeed_tpu.profiling.trace import phase
     from deepspeed_tpu.runtime.engine import DeepSpeedEngine
 
     cfg = config if config is not None else config_params
     if cfg is None and args is not None and hasattr(args, "deepspeed_config"):
         cfg = args.deepspeed_config
-    engine = DeepSpeedEngine(args=args, model=model, optimizer=optimizer,
-                             model_parameters=model_parameters, training_data=training_data,
-                             lr_scheduler=lr_scheduler, mpu=mpu,
-                             dist_init_required=dist_init_required, collate_fn=collate_fn,
-                             config=cfg, mesh=mesh, rng=rng, loss_fn=loss_fn)
+    with phase("ds_setup_initialize"):
+        engine = DeepSpeedEngine(args=args, model=model, optimizer=optimizer,
+                                 model_parameters=model_parameters, training_data=training_data,
+                                 lr_scheduler=lr_scheduler, mpu=mpu,
+                                 dist_init_required=dist_init_required, collate_fn=collate_fn,
+                                 config=cfg, mesh=mesh, rng=rng, loss_fn=loss_fn)
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
 
 
@@ -106,6 +108,19 @@ def init_serving(model=None, config=None, **kwargs):
     docs/RESILIENCE.md "Disaggregated serving".
     See docs/OBSERVABILITY.md.
     """
+    from deepspeed_tpu.monitor.metrics import get_registry
+    from deepspeed_tpu.profiling.trace import phase
+
+    reg = kwargs.get("registry")
+    if reg is None:
+        reg = get_registry()
+    if kwargs.get("metrics_port") is not None:
+        reg.enable()       # before anything is built: set-up is measured too
+    with phase("ds_setup_serving", registry=reg):
+        return _init_serving(model, config, kwargs)
+
+
+def _init_serving(model, config, kwargs):
     from deepspeed_tpu.serving.engine import ServingEngine
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 
@@ -147,7 +162,6 @@ def init_serving(model=None, config=None, **kwargs):
         from deepspeed_tpu.monitor.server import MetricsServer
 
         reg = registry if registry is not None else get_registry()
-        reg.enable()
         server = MetricsServer(reg, port=int(metrics_port),
                                health=serve.health)
         server.set_generate_handler(serve._http_generate)
@@ -222,3 +236,10 @@ def argparse_suppress():
     import argparse
 
     return argparse.SUPPRESS
+
+
+# the process's age now is the `import` part of set-up (the gauge
+# ds_setup_import_seconds once the registry is enabled)
+from deepspeed_tpu.profiling.trace import stamp_import_age as _stamp  # noqa: E402
+
+_stamp()

@@ -31,6 +31,7 @@ from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.models.decoding import (forward_with_cache, init_kv_cache,
                                            sample_token)
 from deepspeed_tpu.monitor.metrics import get_registry
+from deepspeed_tpu.profiling.trace import phase
 from deepspeed_tpu.runtime.zero.partition import params_pspecs, shardings_from_pspecs
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -49,6 +50,11 @@ def pow2_bucket(n: int, lo: int = 1, cap: Optional[int] = None) -> int:
 class InferenceEngine:
     def __init__(self, model, config: DeepSpeedInferenceConfig, params: Any = None,
                  mesh=None):
+        # everything the constructor builds is the `engine` part of set-up
+        with phase("ds_setup_engine"):
+            self._build(model, config, params, mesh)
+
+    def _build(self, model, config, params, mesh) -> None:
         self.module = model                      # reference attr name
         self._config = config
         tp = config.tensor_parallel.tp_size if config.tensor_parallel else 1
@@ -138,7 +144,8 @@ class InferenceEngine:
             specs = jax.tree.map(qspec, cast, specs, is_leaf=is_qtensor)
             shardings = shardings_from_pspecs(specs, self.mesh)
         self._params = jax.device_put(cast, shardings)
-        self._build_injected_view()
+        with phase("ds_setup_inject"):
+            self._build_injected_view()
         self._gen_fns = {}
         self._prefill_fns = {}
         n = sum(x.size for x in jax.tree.leaves(self._params))

@@ -36,7 +36,7 @@ from __future__ import annotations
 import json
 import math
 import threading
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "get_registry", "window_delta", "DEFAULT_BUCKETS"]
@@ -289,7 +289,10 @@ class MetricsRegistry:
 
     Registration takes a lock (cold path); recording does not (see module
     docstring).  ``enable()``/``disable()`` flip the one flag every record
-    checks.
+    checks, and tell whoever asked with :meth:`on_switch`: the compile
+    ledger (``profiling/trace.py``) hangs its ``jax.monitoring`` listeners
+    on the process-global registry that way, so a disabled registry leaves
+    none behind and a private per-replica registry never has any.
     """
 
     def __init__(self):
@@ -297,6 +300,8 @@ class MetricsRegistry:
         self._lock = threading.Lock()
         self._metrics: Dict[Tuple[str, Tuple[Tuple[str, str], ...]],
                             _Instrument] = {}
+        self._switch_hooks: List[Callable[[bool], None]] = []
+        self._statz_extras: Dict[str, Callable[[], object]] = {}
 
     # -- switch --------------------------------------------------------
     @property
@@ -305,11 +310,28 @@ class MetricsRegistry:
 
     def enable(self) -> "MetricsRegistry":
         self._enabled = True
+        for hook in self._switch_hooks:
+            hook(True)
         return self
 
     def disable(self) -> "MetricsRegistry":
         self._enabled = False
+        for hook in self._switch_hooks:
+            hook(False)
         return self
+
+    def on_switch(self, hook: Callable[[bool], None]) -> None:
+        """Call ``hook(enabled)`` at every ``enable()`` / ``disable()`` from
+        now on, and once now if the registry is already on."""
+        self._switch_hooks.append(hook)
+        if self._enabled:
+            hook(True)
+
+    def add_statz(self, key: str, render: Callable[[], object]) -> None:
+        """``/statz`` carries ``render()`` under ``key`` beside
+        ``"metrics"``: what a module keeps that is no instrument (the
+        compile ledger's rows under ``"jit"``)."""
+        self._statz_extras[key] = render
 
     # -- registration --------------------------------------------------
     def _register(self, cls, name, help, labels, **kw):
@@ -403,9 +425,10 @@ class MetricsRegistry:
                 for (name, labels), m in items}
 
     def statz_json(self) -> str:
-        return json.dumps({"enabled": self._enabled,
-                           "metrics": self.snapshot()},
-                          sort_keys=True)
+        doc = {"enabled": self._enabled, "metrics": self.snapshot()}
+        for key, render in self._statz_extras.items():
+            doc[key] = render()
+        return json.dumps(doc, sort_keys=True)
 
     def prometheus_text(self) -> str:
         """Prometheus/OpenMetrics text exposition (one HELP/TYPE block per

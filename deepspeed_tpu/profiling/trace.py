@@ -14,8 +14,10 @@ device ops carry the ``ds_fwd_bwd`` / ``ds_optimizer_step``
 from __future__ import annotations
 
 import os
+import re
+import threading
 import time
-from typing import Optional
+from typing import Any, Dict, List, Optional
 
 import jax
 
@@ -52,9 +54,15 @@ class phase:
     which is how a dispatch range says what it enqueued (``seq``,
     ``request_id``).  With no profiler session and the registry disabled
     this is one ``TraceAnnotation`` enter/exit and one branch.
+
+    While the registry is enabled, and only then, an open phase's name is
+    on its thread's stack of open phases: the compile ledger below reads it
+    to say which ``ds_setup_*`` range a program began in, and the outermost
+    ``ds_setup_*`` range adds its seconds to ``ds_setup_seconds_total`` too,
+    so that nested ranges of set-up add up without a second counted twice.
     """
 
-    __slots__ = ("_ann", "_seconds", "_t0")
+    __slots__ = ("_ann", "_seconds", "_t0", "_name")
 
     def __init__(self, name: str, registry: Optional[MetricsRegistry] = None,
                  step_num: Optional[int] = None, **meta):
@@ -65,17 +73,339 @@ class phase:
         reg = registry if registry is not None else get_registry()
         self._seconds = (reg.counter(name + "_seconds_total")
                          if reg.enabled else None)
+        self._name = name
 
     def __enter__(self) -> "phase":
         self._ann.__enter__()
         if self._seconds is not None:
+            _open.names.append(self._name)
             self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         if self._seconds is not None:
-            self._seconds.inc(time.perf_counter() - self._t0)
+            elapsed = time.perf_counter() - self._t0
+            self._seconds.inc(elapsed)
+            open_phases = _open.names
+            if open_phases:        # entered on this thread, as `with` does
+                open_phases.pop()
+            if self._name.startswith(_SETUP) and \
+                    _setup_phase(open_phases) is None:
+                self._seconds._registry.counter(
+                    "ds_setup_seconds_total", _SETUP_HELP).inc(elapsed)
         self._ann.__exit__(*exc)
+
+
+_SETUP = "ds_setup_"
+_SETUP_HELP = ("seconds inside ds_setup_* ranges, each second once (the "
+               "outermost range's)")
+
+
+class _OpenPhases(threading.local):
+    """Names of the phases open on this thread, outermost first (kept only
+    while the registry is enabled)."""
+
+    def __init__(self):
+        self.names: List[str] = []
+
+
+_open = _OpenPhases()
+
+
+def _setup_phase(open_phases: List[str]) -> Optional[str]:
+    """The innermost ``ds_setup_*`` phase among ``open_phases``."""
+    for name in reversed(open_phases):
+        if name.startswith(_SETUP):
+            return name
+    return None
+
+
+# -- the compile ledger ----------------------------------------------------
+# Where set-up goes is mostly first calls: jax traces a function, lowers the
+# jaxpr to a module, and hands the module to XLA (or reads the executable
+# from the persistent cache in XLA's place).  jax times all three itself and
+# tells ``jax.monitoring``'s listeners, with the function's name; this is
+# the listener.  It lives here beside ``phase`` because the two are read
+# together: a program's row names the ``ds_setup_*`` phase it began in.
+
+_STAGE_OF = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    # wraps compile_or_get_cached: a cache read is inside it
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+# what the persistent cache says inside a backend compile, by a row's key
+_CACHE_SECONDS = {
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_read_s",
+    "/jax/compilation_cache/compile_time_saved_sec": "saved_s"}
+_CACHE_COUNT = {"/jax/compilation_cache/cache_hits": "hits",
+                "/jax/compilation_cache/cache_misses": "misses"}
+_NOT_IN_A_MODULE_NAME = re.compile(r"[^\w.-]")
+_JIT_HELP = {
+    "ds_jit_trace_seconds_total":
+        "tracing functions to jaxprs, self time (no cache keeps it)",
+    "ds_jit_lower_seconds_total":
+        "lowering jaxprs to modules, self time (no cache keeps it)",
+    "ds_jit_compile_seconds_total":
+        "XLA backend compiles, or the persistent cache's reads in their "
+        "place, self time",
+    "ds_jit_cache_read_seconds_total":
+        "reading executables from the persistent compilation cache (part "
+        "of ds_jit_compile_seconds_total)",
+    "ds_jit_in_setup_seconds_total":
+        "the part of trace + lower + compile self time that began inside "
+        "a ds_setup_* range",
+    "ds_jit_programs_total": "programs compiled or read from the cache",
+    "ds_jit_cache_hits_total": "persistent compilation cache hits",
+    "ds_jit_cache_misses_total":
+        "persistent compilation cache misses (entries written)",
+}
+_STAGE_HELP = ("seconds by stage (trace, lower, compile, cache_read) and "
+               "program, INCLUSIVE of what nests inside")
+_IMPORT_HELP = ("age of the process when the package's import ended: "
+                "interpreter start, jax, the package's own modules")
+_import_age_s: Optional[float] = None
+
+
+def _process_age_s() -> Optional[float]:
+    """Seconds since the kernel started this process (None off Linux)."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def stamp_import_age() -> None:
+    """Called once, at the end of ``deepspeed_tpu/__init__.py``."""
+    global _import_age_s
+    if _import_age_s is None:
+        _import_age_s = _process_age_s()
+
+
+def _program_name(fun_name: str) -> str:
+    """One name a program: tracing reports ``step``, lowering and compiling
+    ``jit(step)``; a profile and an HLO dump say ``jit_step``."""
+    api, paren, rest = fun_name.partition("(")
+    if paren and rest.endswith(")"):
+        fun = rest[:-1]
+    else:
+        api, fun = "jit", fun_name
+    return api + "_" + _NOT_IN_A_MODULE_NAME.sub("_", fun)
+
+
+class _ThreadSpans(threading.local):
+    """What one thread's listeners keep between events."""
+
+    def __init__(self):
+        self.pending: List[tuple] = []   # (start, seconds) of finished spans
+        self.open = 0                    # spans begun and not yet ended
+        self.new_cache()
+
+    def new_cache(self) -> Dict[str, float]:
+        """Hand over what the cache said since the last backend compile
+        ended (its events carry no name: they fire inside that compile's
+        span, on its thread) and start again."""
+        said = getattr(self, "cache", None)
+        self.cache = {"cache_read_s": 0.0, "saved_s": 0.0, "hits": 0,
+                      "misses": 0}
+        return said
+
+
+class CompileLedger:
+    """What jax traced, lowered and compiled, by program name.
+
+    Totals go to plain counters of the registry (``ds_jit_*``, the form a
+    benchmark's snapshot keeps) and hold SELF time: a span of any stage
+    that lies inside another on the same thread (an inner jit traced inside
+    an outer one, an eager op compiled while a function is traced) is taken
+    from the span around it, so over any interval trace + lower + compile
+    is at most the wall time of the thread that compiled.  Rows, one a
+    program name and at most ``MAX_PROGRAMS`` of them (the rest under
+    ``"other"``), hold INCLUSIVE seconds a stage plus the row's ``self_s``,
+    and the ``ds_setup_*`` phase that was open when the program first began.
+    Spans arrive on ``time.time()``; rows are on ``perf_counter()``, the
+    clock of ``phase`` and of ``Request.t_*``, by one offset taken at
+    :meth:`install`.
+
+    What a span holds is known from the spans alone (they end innermost
+    first); the begins jax also sends say when a thread has none open, so
+    that nothing is kept longer, however many spans one trace holds.
+
+    Listeners exist only between :meth:`install` and :meth:`uninstall`,
+    which the process-global registry's ``enable()`` / ``disable()`` call.
+    They run when something traces, lowers or compiles: never in a warm
+    window.
+    """
+
+    MAX_PROGRAMS = 512
+    # finished spans a thread keeps for a parent where jax sent no begins
+    _PENDING_MAX = 4096
+
+    def __init__(self, registry: MetricsRegistry):
+        self._registry = registry
+        self._lock = threading.Lock()
+        self._tls = _ThreadSpans()
+        self.installed = False
+        self._offset = 0.0
+        self._rows: Dict[str, Dict[str, Any]] = {}
+        self._c = {name: registry.counter(name, help)
+                   for name, help in _JIT_HELP.items()}
+
+    # -- switch ----------------------------------------------------------
+    def install(self) -> None:
+        if self.installed:
+            return
+        from jax import monitoring
+
+        if _import_age_s is not None:
+            self._registry.gauge("ds_setup_import_seconds",
+                                 _IMPORT_HELP).set(_import_age_s)
+        self._offset = time.perf_counter() - time.time()
+        self._tls = _ThreadSpans()      # no thread keeps an earlier life's
+        monitoring.register_scalar_listener(self._on_begin)
+        monitoring.register_event_time_span_listener(self._on_span)
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+        self.installed = True
+
+    def uninstall(self) -> None:
+        if not self.installed:
+            return
+        from jax import monitoring
+
+        self.installed = False
+        monitoring.unregister_scalar_listener(self._on_begin)
+        monitoring.unregister_event_time_span_listener(self._on_span)
+        monitoring.unregister_event_duration_listener(self._on_duration)
+        monitoring.unregister_event_listener(self._on_event)
+
+    def clear(self) -> None:
+        """Forget the rows (the registry's ``reset()`` zeroes the totals)."""
+        with self._lock:
+            self._rows = {}
+
+    # -- listeners -------------------------------------------------------
+    def _on_begin(self, event: str, start: float, **_) -> None:
+        # jax records a stage's start time as a scalar when it begins
+        if event in _STAGE_OF and self._registry._enabled:
+            self._tls.open += 1
+
+    def _on_event(self, event: str, **_) -> None:
+        key = _CACHE_COUNT.get(event)
+        if key is not None and self._registry._enabled:
+            self._tls.cache[key] += 1
+
+    def _on_duration(self, event: str, seconds: float, **_) -> None:
+        key = _CACHE_SECONDS.get(event)
+        if key is not None and self._registry._enabled:
+            self._tls.cache[key] += seconds
+
+    def _on_span(self, event: str, start: float, end: float,
+                 fun_name: str = "", **_) -> None:
+        stage = _STAGE_OF.get(event)
+        if stage is None:
+            return
+        tls = self._tls
+        if not self._registry._enabled:
+            # the flag went off under a span: its begin may have counted
+            tls.open = 0
+            tls.pending.clear()
+            return
+        seconds = end - start
+        # spans end innermost first: what this one holds is the tail of the
+        # thread's finished spans that began after it did
+        pending, inside = tls.pending, 0.0
+        while pending and pending[-1][0] >= start:
+            inside += pending.pop()[1]
+        pending.append((start, seconds))
+        if tls.open:
+            tls.open -= 1
+            if not tls.open:        # nothing open on this thread: no span
+                pending.clear()     # is left that could hold these
+        elif len(pending) > self._PENDING_MAX:
+            # no begin was seen (installed inside a span; a jax that sends
+            # none): bounded, and exact while a span has fewer children
+            del pending[:self._PENDING_MAX // 2]
+        self_s = max(seconds - inside, 0.0)
+        cache = tls.new_cache() if stage == "compile" else None
+        setup = _setup_phase(_open.names)
+        with self._lock:
+            self._add(_program_name(fun_name), stage, start + self._offset,
+                      seconds, self_s, setup, cache)
+
+    def _add(self, name, stage, t0, seconds, self_s, setup, cache) -> None:
+        c = self._c
+        c[f"ds_jit_{stage}_seconds_total"].inc(self_s)
+        if setup is not None:
+            c["ds_jit_in_setup_seconds_total"].inc(self_s)
+        row = self._rows.get(name)
+        if row is None:
+            if len(self._rows) >= self.MAX_PROGRAMS:
+                name = "other"
+                row = self._rows.get(name)
+            if row is None:
+                row = self._rows[name] = {
+                    "program": name, "phase": setup, "trace": 0, "lower": 0,
+                    "compile": 0, "trace_s": 0.0, "lower_s": 0.0,
+                    "compile_s": 0.0, "cache_read_s": 0.0, "saved_s": 0.0,
+                    "self_s": 0.0, "hits": 0, "misses": 0,
+                    "first_start": t0, "last_end": t0 + seconds}
+        row[stage] += 1
+        row[stage + "_s"] += seconds
+        row["self_s"] += self_s
+        row["last_end"] = max(row["last_end"], t0 + seconds)
+        self._stage_series(stage, name).inc(seconds)
+        if cache is not None:
+            c["ds_jit_programs_total"].inc()
+            c["ds_jit_cache_read_seconds_total"].inc(cache["cache_read_s"])
+            c["ds_jit_cache_hits_total"].inc(cache["hits"])
+            c["ds_jit_cache_misses_total"].inc(cache["misses"])
+            for key, said in cache.items():
+                row[key] += said
+            if cache["cache_read_s"]:
+                self._stage_series("cache_read", name).inc(
+                    cache["cache_read_s"])
+
+    def _stage_series(self, stage: str, name: str):
+        return self._registry.counter(
+            "ds_jit_stage_seconds_total", _STAGE_HELP,
+            labels={"stage": stage, "program": name})
+
+    # -- reads -----------------------------------------------------------
+    def rows(self) -> List[Dict[str, Any]]:
+        """A copy of the rows in the order the programs first began.
+        ``trace`` / ``lower`` / ``compile`` count events, ``calls`` is the
+        most of the three, ``*_s`` are inclusive seconds (``self_s``
+        excepted), ``first_start`` / ``last_end`` are ``perf_counter()``
+        readings."""
+        with self._lock:
+            rows = [dict(r) for r in self._rows.values()]
+        for r in rows:
+            r["calls"] = max(r["trace"], r["lower"], r["compile"])
+        return sorted(rows, key=lambda r: r["first_start"])
+
+
+_LEDGER = CompileLedger(get_registry())
+
+
+def compile_ledger() -> CompileLedger:
+    """The process's compile ledger (it follows ``get_registry()``)."""
+    return _LEDGER
+
+
+def _follow_registry(enabled: bool) -> None:
+    if enabled:
+        _LEDGER.install()
+    else:
+        _LEDGER.uninstall()
+
+
+get_registry().on_switch(_follow_registry)
+get_registry().add_statz("jit", _LEDGER.rows)
 
 
 def scope(name: str):

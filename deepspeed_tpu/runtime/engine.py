@@ -50,7 +50,7 @@ from deepspeed_tpu.monitor.metrics import get_registry
 from deepspeed_tpu.monitor.monitor import MonitorMaster
 from deepspeed_tpu.monitor.request_trace import get_step_timeline
 from deepspeed_tpu.profiling.flops import TrainFlopsMeter, lm_flops_per_token
-from deepspeed_tpu.profiling.trace import annotate
+from deepspeed_tpu.profiling.trace import annotate, phase
 from deepspeed_tpu.runtime import optimizer as opt_builder
 from deepspeed_tpu.runtime.checkpoint_engine import (MsgpackCheckpointEngine,
                                                      ShardedCheckpointEngine)
@@ -786,7 +786,8 @@ class DeepSpeedEngine:
         # Params supplied eagerly -> materialize state now; else lazy-init on
         # the first batch (zero.Init-equivalent abstract init, SURVEY.md §7.4).
         if model_parameters is not None:
-            self._init_state(model_parameters)
+            with phase("ds_setup_params"):
+                self._init_state(model_parameters)
 
     # ------------------------------------------------------------------
     # construction helpers
@@ -1527,15 +1528,16 @@ class DeepSpeedEngine:
                     if jnp.issubdtype(x.dtype, jnp.floating) else x,
                     init_fn(rng, b))
 
-        abstract = jax.eval_shape(build_fn, init_rng, batch)
-        zcfg = self.config.zero_config
-        persist = zcfg.stage3_param_persistence_threshold if self.zero_stage == 3 else 0
-        specs = params_pspecs(abstract, self.mesh, shard=self.zero_stage == 3,
-                              persistence_threshold=persist,
-                              logical_specs=self._client_param_pspecs)
-        shardings = shardings_from_pspecs(specs, self.mesh)
-        params = jax.jit(build_fn, out_shardings=shardings)(init_rng, batch)
-        self._init_state(params)
+        with phase("ds_setup_params"):
+            abstract = jax.eval_shape(build_fn, init_rng, batch)
+            zcfg = self.config.zero_config
+            persist = zcfg.stage3_param_persistence_threshold if self.zero_stage == 3 else 0
+            specs = params_pspecs(abstract, self.mesh, shard=self.zero_stage == 3,
+                                  persistence_threshold=persist,
+                                  logical_specs=self._client_param_pspecs)
+            shardings = shardings_from_pspecs(specs, self.mesh)
+            params = jax.jit(build_fn, out_shardings=shardings)(init_rng, batch)
+            self._init_state(params)
 
     # ------------------------------------------------------------------
     # jitted step functions
@@ -1547,7 +1549,8 @@ class DeepSpeedEngine:
         # nested pushes (an elastic rescale recompiling mid-run) stack
         self._goodput.push("recompile")
         try:
-            self._compile_steps_inner()
+            with phase("ds_setup_compile_steps"):
+                self._compile_steps_inner()
         finally:
             self._goodput.pop()
 

@@ -251,41 +251,8 @@ class ServingEngine:
         # the slot carries (serving/cache_kind.py)
         self.kind = cache_kind(cfg)
         self.kind.check(self._config, role, self.prefill_chunk)
-        self.pool = PagedKVPool(
-            self.num_slots, self._config.max_out_tokens,
-            page_tokens=self._config.kv_page_tokens,
-            pool_tokens=self._config.kv_pool_tokens,
-            **self.kind.pool_args(engine.dtype))
-        self._cache = self.kind.init_cache(
-            self.pool, self.num_slots, engine.dtype,
-            self._config.quantize_kv_cache)
-        # per-slot LOGICAL window (page-table depth x page); the PHYSICAL
-        # pool may hold fewer tokens than num_slots windows
-        self.cache_len = self.pool.cache_len
-        # copy-on-write prefix caching over the page pool, for a kind whose
-        # pages are a function of the token prefix alone, with an optional
-        # HOST TIER: kv_host_tier_pages > 0 bounds an LRU host store that
-        # eviction victims demote into (instead of dropping) and admissions
-        # promote back out of — the effective prefix cache becomes
-        # host-RAM-sized (docs/OBSERVABILITY.md "KV host tier")
-        self.host_store = None
-        self.prefix_cache = None
-        if self._config.prefix_caching:
-            why_not = self.kind.cannot.get("prefix_caching")
-            if why_not:
-                log_dist(f"prefix caching is off for {self.kind.what}: "
-                         f"{why_not}", ranks=[0])
-            else:
-                host_pages = int(getattr(self._config,
-                                         "kv_host_tier_pages", 0))
-                if host_pages > 0:
-                    self.host_store = HostPageStore(host_pages,
-                                                    registry=self._registry)
-                self.prefix_cache = PrefixCache(
-                    self.pool, registry=self._registry,
-                    host_store=self.host_store,
-                    fetch_page=(self._fetch_page_host
-                                if self.host_store is not None else None))
+        with self._phase("ds_setup_pool"):
+            self._build_pool(engine.dtype)
         # max_out is the configured LOGICAL budget — generation bounds use
         # max_out so serving stays token-identical to generate(), which
         # never sees the physical rounding
@@ -576,6 +543,45 @@ class ServingEngine:
                  f"prefill_chunk={self.prefill_chunk}, "
                  f"decode_block={self._K}, "
                  f"{'fused' if fused_ok else 'unfused'} decode", ranks=[0])
+
+    def _build_pool(self, dtype) -> None:
+        """The page pool, the cache kind's tables over it and the prefix
+        cache: the ``pool`` part of set-up."""
+        self.pool = PagedKVPool(
+            self.num_slots, self._config.max_out_tokens,
+            page_tokens=self._config.kv_page_tokens,
+            pool_tokens=self._config.kv_pool_tokens,
+            **self.kind.pool_args(dtype))
+        self._cache = self.kind.init_cache(
+            self.pool, self.num_slots, dtype,
+            self._config.quantize_kv_cache)
+        # per-slot LOGICAL window (page-table depth x page); the PHYSICAL
+        # pool may hold fewer tokens than num_slots windows
+        self.cache_len = self.pool.cache_len
+        # copy-on-write prefix caching over the page pool, for a kind whose
+        # pages are a function of the token prefix alone, with an optional
+        # HOST TIER: kv_host_tier_pages > 0 bounds an LRU host store that
+        # eviction victims demote into (instead of dropping) and admissions
+        # promote back out of — the effective prefix cache becomes
+        # host-RAM-sized (docs/OBSERVABILITY.md "KV host tier")
+        self.host_store = None
+        self.prefix_cache = None
+        if self._config.prefix_caching:
+            why_not = self.kind.cannot.get("prefix_caching")
+            if why_not:
+                log_dist(f"prefix caching is off for {self.kind.what}: "
+                         f"{why_not}", ranks=[0])
+            else:
+                host_pages = int(getattr(self._config,
+                                         "kv_host_tier_pages", 0))
+                if host_pages > 0:
+                    self.host_store = HostPageStore(host_pages,
+                                                    registry=self._registry)
+                self.prefix_cache = PrefixCache(
+                    self.pool, registry=self._registry,
+                    host_store=self.host_store,
+                    fetch_page=(self._fetch_page_host
+                                if self.host_store is not None else None))
 
     # ------------------------------------------------------------------
     def set_params(self, params: Any) -> None:

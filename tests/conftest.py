@@ -68,7 +68,9 @@ def _restore_metrics_registry_enabled():
     prev_reg, prev_comms = reg.enabled, comm_metrics.enabled
     prev_tracer = tracer.enabled
     yield
-    reg._enabled = prev_reg
+    # through the switch, not the flag: it takes the compile ledger's
+    # jax.monitoring listeners off again (profiling/trace.py)
+    (reg.enable if prev_reg else reg.disable)()
     comm_metrics.enabled = prev_comms
     tracer.enabled = prev_tracer
 

@@ -41,7 +41,8 @@ def test_train_phase_tiny(devices, interpret_kernels):
     assert rec["losses"][-1] < rec["losses"][0]
     # seq 128 takes the flash kernel: no shape rule put a reference in
     assert rec["reference_in_place_of_kernel"] == []
-    assert set(rec["wall_s"]) == {"total", "compile", "steady", "note"}
+    assert set(rec["wall_s"]) == {"total", "trace", "lower", "compile",
+                                  "steady", "note"}
     json.dumps(rec)
 
 
