@@ -23,7 +23,8 @@ attention form, and :func:`cache_kind` is the one place that decides it
 A kind answers what the engine asks and nothing else: the pool's arguments
 and the device arrays; what it cannot be served with (``cannot``: one table
 of option and reason); the chunk program's ``view`` of one slot and its
-``write_back``; its counters, moved on the host from positions the engine
+``write_back``; the smallest bucket a chunk program is worth building for
+(``chunk_rows``); its counters, moved on the host from positions the engine
 holds anyway.  Every engine registers every kind's series (``attach``), so
 what a replica exports does not depend on the model it serves.  A new layout
 is one class here beside its model module.
@@ -38,7 +39,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models import afmoe, kda_mla
-from deepspeed_tpu.ops.pallas.flash_attention import (eva_chunk_schedule,
+from deepspeed_tpu.ops.pallas.flash_attention import (_LANES,
+                                                      eva_chunk_schedule,
                                                       mla_chunk_schedule)
 from deepspeed_tpu.serving.paged_kv import init_paged_kv_cache
 
@@ -93,6 +95,11 @@ class FullPages:
     counters: Dict[str, str] = {}   # the series this kind moves
     takes_valid_len = False         # the chunk's forward is told its real rows
     pages_by_kind = False           # ds_serve_kv_pages_used_by_kind moves
+    # the query rows the chunk's attention pads a bucket to anyway: the
+    # floor of the engine's chunk buckets (``ServingEngine.chunk_bucket``),
+    # since a program under it saves no work and costs a compile.  Here the
+    # attention pads nothing and a chat prompt does end in a short chunk
+    chunk_rows = 8
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -443,6 +450,9 @@ class LatentPages(FullPages):
             "(latent row, latent layer) pairs the prefill chunk programs "
             "wrote for the real tokens of their chunks",
     }
+    # mla_chunk_attention, dsa_index_scores_chunk and dsa_chunk_attention
+    # pad a chunk's queries to one lane tile inside the call
+    chunk_rows = _LANES
 
     def init_cache(self, pool, num_slots, dtype, quantized):
         cfg = self.cfg

@@ -72,6 +72,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deepspeed_tpu.comm.mesh import replicated
 from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
 from deepspeed_tpu.inference.engine import InferenceEngine, pow2_bucket
 from deepspeed_tpu.models.decoding import (forward_with_cache,
@@ -82,7 +83,7 @@ from deepspeed_tpu.monitor.health import get_health
 from deepspeed_tpu.monitor.metrics import get_registry
 from deepspeed_tpu.monitor.request_trace import get_request_tracer
 from deepspeed_tpu.profiling.trace import phase
-from deepspeed_tpu.serving.cache_kind import cache_kind
+from deepspeed_tpu.serving.cache_kind import FullPages, cache_kind
 from deepspeed_tpu.serving.host_tier import HostPageStore
 from deepspeed_tpu.serving.paged_kv import PagedKVPool
 from deepspeed_tpu.serving.prefix_cache import PrefixCache
@@ -164,6 +165,10 @@ class ServingEngine:
     prefill_chunk:
         Max prompt tokens prefilled per scheduler iteration per slot
         (chunked prefill; bounds the decode stall a long prompt causes).
+        A chunk runs in a program of its bucket (:meth:`chunk_bucket`),
+        compiled once per bucket from the cache kind's floor up: a warm-up
+        needs one prompt a power of two from that floor to ``prefill_chunk``
+        (shorter ones land in the floor's program).
     decode_block_tokens:
         Decode steps per compiled block (per host dispatch) — the serving
         analog of ``decode_unroll``.
@@ -251,6 +256,13 @@ class ServingEngine:
         # the slot carries (serving/cache_kind.py)
         self.kind = cache_kind(cfg)
         self.kind.check(self._config, role, self.prefill_chunk)
+        # ONE placement, from construction on, for what the programs carry
+        # from call to call (the pool's arrays, the three carries, the key):
+        # over the engine's mesh, as every program hands them back.  jit keys
+        # a program on its arguments' mesh as well as on their shapes, so an
+        # array fresh from ``jnp.zeros`` (no mesh) made the first program
+        # that took it trace, lower and compile a second time
+        self._placement = replicated(engine.mesh)
         with self._phase("ds_setup_pool"):
             self._build_pool(engine.dtype)
         # max_out is the configured LOGICAL budget — generation bounds use
@@ -270,16 +282,18 @@ class ServingEngine:
         # position, per-row active mask — carried (donated) through every
         # chunk program (which wakes the slot it prefilled) and block to
         # block, so neither no-EOS nor EOS scheduling ever syncs per step
-        self._last_dev = jnp.zeros(self.num_slots, jnp.int32)
-        self._pos_dev = jnp.zeros(self.num_slots, jnp.int32)
-        self._act_dev = jnp.zeros(self.num_slots, bool)
+        self._last_dev, self._pos_dev, self._act_dev = jax.device_put(
+            (jnp.zeros(self.num_slots, jnp.int32),
+             jnp.zeros(self.num_slots, jnp.int32),
+             jnp.zeros(self.num_slots, bool)), self._placement)
         self._park_fn = jax.jit(
             lambda pos, act, slot: (pos.at[slot].set(0),
                                     act.at[slot].set(False)),
             donate_argnums=(0, 1))
         self._setpos_fn = jax.jit(lambda pos, slot, s: pos.at[slot].set(s),
                                   donate_argnums=(0,))
-        self._rng = jax.random.PRNGKey(self._config.seed + 1)
+        self._rng = jax.device_put(
+            jax.random.PRNGKey(self._config.seed + 1), self._placement)
         self._block_fn = None
         self._prefill_fns = {}
         self._cow_copy = None    # compiled COW page copy (prefix cache)
@@ -445,6 +459,11 @@ class ServingEngine:
             "max_prefill_chunks the other requests left")
         self._m_prefill_toks = reg.counter(
             "ds_serve_prefill_tokens_total", "prompt tokens prefilled")
+        self._m_prefill_pad_rows = reg.counter(
+            "ds_serve_prefill_pad_rows_total",
+            "rows of the prefill chunks' buckets past their real tokens "
+            "(bucket - tokens a chunk): over ds_serve_prefill_tokens_total, "
+            "the work the buckets' floor adds")
         self._m_decode_toks = reg.counter(
             "ds_serve_decode_tokens_total", "decode tokens scheduled")
         self._m_row_slots = reg.counter(
@@ -552,9 +571,10 @@ class ServingEngine:
             page_tokens=self._config.kv_page_tokens,
             pool_tokens=self._config.kv_pool_tokens,
             **self.kind.pool_args(dtype))
-        self._cache = self.kind.init_cache(
-            self.pool, self.num_slots, dtype,
-            self._config.quantize_kv_cache)
+        self._cache = jax.device_put(
+            self.kind.init_cache(self.pool, self.num_slots, dtype,
+                                 self._config.quantize_kv_cache),
+            self._placement)
         # per-slot LOGICAL window (page-table depth x page); the PHYSICAL
         # pool may hold fewer tokens than num_slots windows
         self.cache_len = self.pool.cache_len
@@ -1811,7 +1831,7 @@ class ServingEngine:
         seq = self._launch_seq
         with self._phase("ds_serve_prefill_dispatch", seq=seq,
                          request_id=req.request_id, last=int(last_chunk)):
-            cb = pow2_bucket(c, lo=8, cap=self.cache_len - off)
+            cb = self.chunk_bucket(c, off)
             chunk = np.zeros((1, cb), np.int32)
             chunk[0, :c] = prefix[off:off + c]
             self._rng, srng = jax.random.split(self._rng)
@@ -1830,6 +1850,7 @@ class ServingEngine:
                               time.perf_counter(), c, seq=seq)
             self._m_prefill_chunks.inc()
             self._m_prefill_toks.inc(c)
+            self._m_prefill_pad_rows.inc(cb - c)
             self.kind.count_chunk(self.pool, self._cache, off, c, cb)
             # parked rows write junk at their own pos; keeping pos =
             # prefill progress (host view here, device carry inside the
@@ -1914,8 +1935,21 @@ class ServingEngine:
             self._m_ttft.record(t - req.t_submit)
         self._tracer.decode_start(req.request_id, t)
 
+    def chunk_bucket(self, c: int, off: int) -> int:
+        """The bucket a chunk of ``c`` tokens at ``off`` runs in, the one
+        place the serve engine's chunk programs are sized: the next power of
+        two from the cache kind's floor up (``kind.chunk_rows``: the rows
+        its chunk attention pads a bucket to anyway; never more than
+        ``prefill_chunk``, and never under the plain kind's 8, the fewest
+        rows a chunk program was ever built for), capped at what is left of
+        the slot's window."""
+        lo = max(FullPages.chunk_rows,
+                 min(self.kind.chunk_rows, self.prefill_chunk))
+        return pow2_bucket(c, lo=lo, cap=self.cache_len - off)
+
     def _prefill_fn(self, cb: int):
-        """Per-slot chunked prefill, compiled once per pow2 chunk bucket:
+        """Per-slot chunked prefill, compiled once per bucket from the
+        kind's floor up (:meth:`chunk_bucket`):
         ``(params, cache, (last, pos, active), page-table row, chunk [1, cb],
         meta, rng) -> (token, cache, (last, pos, active))``, cache and
         carries donated.

@@ -633,7 +633,9 @@ def test_counters_count_expanded_rows_and_kept_groups(model):
     serve.run()
     snap = {k: v for k, v in reg.snapshot().items()
             if isinstance(v, (int, float))}
-    # five latent layers; chunks of 16, 16 and 8 real rows; the slot's view
+    # five latent layers; chunks of 16, 16 and 8 real rows, each in the
+    # bucket of 16 (the floor of a latent kind's buckets is its kernels' 128
+    # rows, and never more than ``prefill_chunk``); the slot's view
     # is 96 rows, one key block, which every chunk expands whole: the counter
     # reads mla_chunk_schedule, and on the CPU (and at these widths on any
     # device) the schedule is that of afmoe.attend, the loop that runs
@@ -643,7 +645,8 @@ def test_counters_count_expanded_rows_and_kept_groups(model):
         off, cb, 96, heads=4, nope=16, rot=8, v_dim=16, row_width=128,
         itemsize=4, kv=32, **kw)
     assert [sch(off, cb)["visited"] for off, cb in
-            ((0, 16), (16, 16), (32, 8))] == [96] * 3
+            ((0, 16), (16, 16), (32, 16))] == [96] * 3
+    assert snap["ds_serve_prefill_pad_rows_total"] == 16 - 8
     assert "latent rank of 32" in sch(0, 16, impl="pallas")["reason"]
     # 20 decode steps x 4 expert layers x 4 choices offered
     offered = snap["ds_serve_moe_assignments_total"]
