@@ -59,6 +59,15 @@ families instead; `ModelConfig` spans them with feature flags:
   ``sliding_window`` positions, a sigmoid gate a head (``mla_head_gate``)
   and the low-rank rescale (``mla_lora_rescale``); the same module;
   benchmarks/configs/dots3-note-L5-ep16.json.  SERVED ONLY likewise
+- A looped stack (Ouro): the Llama backbone's ``num_layers`` layers run
+  ``total_ut_steps`` times with the SAME weights, the final norm closing
+  every pass and feeding the next, a norm on both sides of each sub-block
+  (``sandwich_norm``), an exit gate on the normed stream
+  (``loop_exit_gate``).  Pass ``t``, layer ``l`` reads and writes cache
+  layer ``t * num_layers + l`` (:attr:`ModelConfig.cache_layers` of them
+  under one page table), at the same rotary positions in every pass;
+  benchmarks/configs/ouro-2.6b-L12.json.  SERVED ONLY, every token through
+  every pass (``early_exit_threshold`` 1): no training loss, no early exit
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -107,6 +116,17 @@ class ModelConfig:
     # [D, num_pred_heads * vocab_size] predict token i + 1 + p (evabyte);
     # serving samples from head 0
     num_pred_heads: int = 1
+    # the layer stack runs this many times over the same weights (a looped
+    # model, Ouro), each pass closed by the final norm; pass t, layer l
+    # keeps its keys and values in cache layer ``t * num_layers + l``
+    total_ut_steps: int = 1
+    # a Linear with bias on each pass's normed stream, ``exit_gate`` {"w"
+    # [D, 1], "b" [1]}: ``CausalLM.apply(exit_distribution=True)`` returns the
+    # distribution over passes it defines.  The serve programs do not
+    # compute it: at ``early_exit_threshold`` 1, the only value built, every
+    # token leaves after the last pass and the gate cannot move a logit
+    loop_exit_gate: bool = False
+    early_exit_threshold: float = 1.0
     # -- the afmoe layer form (models/afmoe.py); ``layer_types`` turns it on
     # one entry a layer, "sliding_attention" (RoPE, keys j with
     # 0 <= i - j < sliding_window) or "full_attention" (NO position
@@ -122,7 +142,9 @@ class ModelConfig:
     qk_norm_per_head: bool = False
     # attention output times sigmoid(h Wg) before the output projection
     attn_output_gate: bool = False
-    # a norm AFTER each sub-block too: x + N_post(block(N_pre(x)))
+    # a norm AFTER each sub-block too: x + N_post(block(N_pre(x))).  Read by
+    # the layer form and by the Llama backbone (parameters
+    # ``layers.attn_post_norm`` / ``layers.mlp_post_norm``; afmoe.close)
     sandwich_norm: bool = False
     embed_scale: float = 1.0               # multiplies the token embedding
     # router scores: "softmax" over the experts, or independent "sigmoid"s
@@ -296,6 +318,38 @@ class ModelConfig:
                 "``layer_types`` turns on (models/afmoe.py: sliding_attention "
                 "and full_attention layers; models/kda_mla.py: "
                 "linear_attention and latent_attention layers)")
+        self._check_loop()
+
+    def _check_loop(self):
+        """What a looped stack and the Llama backbone's post-norms are
+        written for."""
+        if self.total_ut_steps < 1:
+            raise ValueError("total_ut_steps must be >= 1")
+        if self.early_exit_threshold != 1.0:
+            raise NotImplementedError(
+                f"early_exit_threshold={self.early_exit_threshold}: a row that "
+                "leaves the pass loop early is not built (serving/scheduler.py "
+                "and the page pool assume every row runs every cache layer); "
+                "at 1 every token takes the last pass's logits")
+        if self.loop_exit_gate and self.total_ut_steps == 1:
+            raise ValueError("loop_exit_gate is the gate of a looped stack "
+                             "(total_ut_steps > 1)")
+        if self.total_ut_steps > 1 and (
+                self.layer_types is not None or self.is_eva or self.is_moe
+                or self.parallel_residual or self.dropout):
+            raise NotImplementedError(
+                "total_ut_steps > 1 is built for the dense Llama / GPT-2 "
+                "backbone: a looped stack beside layer_types kinds "
+                "(models/afmoe.py, models/kda_mla.py), attention='eva', "
+                "experts (their stacked arrays are indexed by layer, their "
+                "counts by step), parallel_residual or dropout is not")
+        if self.sandwich_norm and self.layer_types is None and (
+                self.norm != "rmsnorm" or self.parallel_residual
+                or self.is_moe or self.norm_add_unit_offset):
+            raise NotImplementedError(
+                "sandwich_norm outside layer_types is built for the dense "
+                "Llama backbone: RMSNorm post-norms (afmoe.close) on "
+                "sequential sub-blocks, no experts, no unit-offset gains")
 
     def _check_afmoe(self):
         """The combinations the layer form is written for: sliding and
@@ -471,6 +525,15 @@ class ModelConfig:
         return self.attention == "eva"
 
     @property
+    def is_looped(self) -> bool:
+        return self.total_ut_steps > 1
+
+    @property
+    def cache_layers(self) -> int:
+        """Layers of a K/V cache: one a (pass, layer) pair."""
+        return self.total_ut_steps * self.num_layers
+
+    @property
     def is_afmoe(self) -> bool:
         return self.layer_types is not None
 
@@ -529,10 +592,13 @@ _MLA_FORMS = ("mla_q_rank", "mla_rope", "mla_sliding", "mla_index_heads",
 _MLA_ROPE_KEYS = frozenset({
     "theta", "factor", "original_max_position_embeddings", "beta_fast",
     "beta_slow", "mscale", "mscale_all_dim"})
-# fields only the layer form (models/afmoe.py, models/kda_mla.py) reads
+# fields only the layer form (models/afmoe.py, models/kda_mla.py) reads.
+# ``sandwich_norm`` is not among them: the Llama backbone reads it too
+# (models/transformer.py, models/decoding.py, models/fused_decode.py), as it
+# reads ``total_ut_steps`` and ``loop_exit_gate``, which the layer form refuses
 _AFMOE_ONLY = frozenset({
     "sliding_window", "num_dense_layers", "dense_intermediate_size",
-    "qk_norm_per_head", "attn_output_gate", "sandwich_norm", "embed_scale",
+    "qk_norm_per_head", "attn_output_gate", "embed_scale",
     "moe_score_func", "moe_route_scale", "moe_select_bias",
     "num_shared_experts", "moe_router_experts", "moe_first_expert",
     "moe_n_group", "moe_topk_group", *_KDA_MLA_ONLY, *_MLA_FORMS})

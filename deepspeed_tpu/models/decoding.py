@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.comm.mesh import axis_size
 from deepspeed_tpu.models import eva
+from deepspeed_tpu.models.afmoe import close
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
                                          constrain, model_norm, norm, qk_norm,
                                          _repeat_kv, rope_dim)
@@ -42,8 +43,11 @@ def init_kv_cache(cfg, batch: int, max_len: int, dtype=jnp.bfloat16,
 
     Caches longer than one decode block are rounded UP to a block multiple
     so the length-aware flash-decode path always applies (the padding rows
-    cost memory only; they are never visited)."""
-    L, Hkv, Dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    cost memory only; they are never visited).
+
+    One cache layer a (pass, layer) pair: ``cfg.cache_layers`` of them, the
+    model's ``num_layers`` unless its stack is looped."""
+    L, Hkv, Dh = cfg.cache_layers, cfg.num_kv_heads, cfg.head_dim
     if max_len > DECODE_BLOCK and max_len % DECODE_BLOCK:
         rounded = -(-max_len // DECODE_BLOCK) * DECODE_BLOCK
         # callers sizing masks/position buffers must read cache['k'].shape[-2]
@@ -526,7 +530,8 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             # gpt-neox: MLP reads the LAYER INPUT; both branches add at once
             mlp_src = x0
         else:
-            h_in = h_in + o
+            h_in = close(cfg, h_in, o,
+                         lp.get("attn_post_norm", {}).get("scale"))
             mlp_src = h_in
 
         h = model_norm(cfg, mlp_src, lp["mlp_norm"]).astype(cdt)
@@ -553,34 +558,52 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
             if cfg.has_mlp_bias:
                 mlp_out = mlp_out + m["b_down"].astype(h.dtype)
         mlp_out = mlp_out.astype(h_in.dtype)
-        h_in = (x0 + o + mlp_out) if cfg.parallel_residual else (h_in + mlp_out)
+        h_in = (x0 + o + mlp_out) if cfg.parallel_residual else close(
+            cfg, h_in, mlp_out, lp.get("mlp_post_norm", {}).get("scale"))
         if quant_kv:
             return h_in, (kc, vc, ksc, vsc)
         return h_in, (kc, vc)
 
-    if quant_kv:
-        x, (kc_new, vc_new, ks_new, vs_new) = jax.lax.scan(
-            layer_step, x, (layers, layer_ids, cache["k"], cache["v"],
-                            cache["k_scale"], cache["v_scale"]))
-        new_cache = {"k": kc_new, "v": vc_new, "k_scale": ks_new,
-                     "v_scale": vs_new, "x_dtype": cache["x_dtype"]}
+    names = ("k", "v", "k_scale", "v_scale") if quant_kv else ("k", "v")
+    arrays = tuple(cache[n] for n in names)
+    if cfg.is_looped:
+        # the passes scan AROUND the scan over layers, the same stacked
+        # weights in each; the cache's ``cache_layers`` leading entries are
+        # [pass, layer] (a free reshape), so pass t's layer l reads and
+        # writes entry t * L + l; the final norm closes every pass
+        T, L = cfg.total_ut_steps, cfg.num_layers
+
+        def one_pass(x, arrays_t):
+            with jax.named_scope("ds_loop_pass"):
+                x, out = jax.lax.scan(layer_step, x,
+                                      (layers, layer_ids) + arrays_t)
+                return model_norm(cfg, x, params["final_norm"]), out
+
+        x, arrays = jax.lax.scan(
+            one_pass, x,
+            tuple(a.reshape((T, L) + a.shape[1:]) for a in arrays))
+        arrays = tuple(a.reshape((T * L,) + a.shape[2:]) for a in arrays)
     else:
-        x, (kc_new, vc_new) = jax.lax.scan(
-            layer_step, x, (layers, layer_ids, cache["k"], cache["v"]))
-        new_cache = {"k": kc_new, "v": vc_new}
-    logits = output_logits(cfg, params, x)
+        x, arrays = jax.lax.scan(layer_step, x, (layers, layer_ids) + arrays)
+    new_cache = dict(zip(names, arrays))
+    if quant_kv:
+        new_cache["x_dtype"] = cache["x_dtype"]
+    logits = output_logits(cfg, params, x, normed=cfg.is_looped)
     if cfg.lm_head_bias:
         logits = logits + params["lm_head_bias"].astype(jnp.float32)
     return logits, new_cache
 
 
-def output_logits(cfg, params, x):
+def output_logits(cfg, params, x, normed: bool = False):
     """Final norm and output head of the cached forwards (``params`` the
     plain or the kernel-injected tree): float32 logits [..., num_pred_heads
     * V].  A float32 residual stream (evabyte's fp32_logits) is normed in
     float32, meets the head in the head's dtype and is accumulated in
-    float32; otherwise the product is rounded to ``x``'s dtype first."""
-    x = model_norm(cfg, x, params["final_norm"])
+    float32; otherwise the product is rounded to ``x``'s dtype first.
+    ``normed``: the final norm has closed the last pass of a looped stack
+    already."""
+    if not normed:
+        x = model_norm(cfg, x, params["final_norm"])
     head = (params["embed"]["tok"].T if cfg.tie_embeddings
             else params["lm_head"])
     if cfg.fp32_residual:

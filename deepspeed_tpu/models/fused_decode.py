@@ -26,8 +26,9 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.models import afmoe
 from deepspeed_tpu.models.decoding import output_logits
-from deepspeed_tpu.models.layers import norm, qk_norm, rope_dim
+from deepspeed_tpu.models.layers import model_norm, norm, qk_norm, rope_dim
 from deepspeed_tpu.ops.pallas import rope_angles
 from deepspeed_tpu.ops.pallas.decode import (eva_decode_paged,
                                              eva_summarize_paged,
@@ -60,8 +61,9 @@ def supports_fused_decode(cfg, *, quantized_kv: bool = False,
 def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
     """Build the kernel-injected weight view from a model param tree.
 
-    Layers are UNSTACKED into a tuple of per-layer dicts with their own
-    device buffers: the decode step's static layer loop then feeds each
+    Layers are UNSTACKED into a tuple of per-layer dicts (``num_layers`` of
+    them: a looped stack's passes share them) with their own device
+    buffers: the decode step's static layer loop then feeds each
     Pallas kernel a whole array — profiling showed that slicing a stacked
     [L, ...] weight per layer inside the program re-materializes the
     slice (a full per-layer weight copy per token).  The QKV concat is the
@@ -74,10 +76,13 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
     from deepspeed_tpu.models.quant import QTensor, is_qtensor
 
     if cfg.is_afmoe:      # two stacks of layers, its own view
-        from deepspeed_tpu.models import afmoe
         return afmoe.form(cfg).inject(cfg, params)
     ly = params["layers"]
     attn, mlp = ly["attn"], ly["mlp"]
+    if cfg.sandwich_norm and is_qtensor(attn["wq"]):
+        raise NotImplementedError(
+            "int8 weights with sandwich_norm: the post-norm close of the "
+            "fused path (afmoe.fused_close) hands its kernels no scales")
     if is_qtensor(attn["wq"]):  # int8 serving: concat payloads AND scales
         wqkv = QTensor(
             jnp.concatenate([attn["wq"].q, attn["wk"].q, attn["wv"].q], -1),
@@ -94,6 +99,9 @@ def inject_decode_params(params: Any, cfg) -> Dict[str, Any]:
         "n1_scale": gain(ly["attn_norm"]["scale"]),
         "n2_scale": gain(ly["mlp_norm"]["scale"]),
     }
+    if cfg.sandwich_norm:
+        stacked["n1_post"] = ly["attn_post_norm"]["scale"]
+        stacked["n2_post"] = ly["mlp_post_norm"]["scale"]
     if cfg.is_eva:
         stacked["eva_mu"] = attn["eva_mu"]
         stacked["eva_phi"] = attn["eva_phi"]
@@ -154,7 +162,6 @@ def moe_combine(h, gate_w, cfg):
 def moe_counts_zero(cfg):
     """Zeros of ``decode_step``'s routing counts (its ``moe_live`` result)."""
     if cfg.is_afmoe:      # a fourth count: the assignments offered
-        from deepspeed_tpu.models import afmoe
         return afmoe.form(cfg).moe_counts_zero(cfg)
     return (jnp.zeros((cfg.num_experts,), jnp.int32),
             jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
@@ -203,7 +210,6 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
             "(serving/paged_kv.py); the contiguous caches hold no window "
             "and summary rows on the fused path")
     if cfg.is_afmoe:
-        from deepspeed_tpu.models import afmoe
         if page_table is None:
             raise NotImplementedError(
                 "a layer_types model (models/afmoe.py) decodes through the "
@@ -275,78 +281,111 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
 
     count_moe = cfg.is_moe and moe_live is not None
     moe_stats = moe_counts_zero(cfg) if count_moe else None
-    for l, lp in enumerate(dparams["layers"]):
-        wqkv, s_qkv = wq_pair(lp["wqkv"])
-        qkv = fused_norm_qkv(x, lp["n1_scale"], lp.get("n1_bias"),
-                             wqkv, lp.get("bqkv"), kind=kind, eps=eps,
-                             wscale=s_qkv, impl=impl)
-        q, k = qkv[:, :M], qkv[:, M:M + Mkv]
-        if cfg.qk_norm:
-            q, k = qk_norm(q, k, lp["q_norm"], lp["k_norm"], eps)
-        q = rope_rows(q.reshape(B, H, Dh))
-        k = rope_rows(k.reshape(B, Hkv, Dh))
-        v = qkv[:, M + Mkv:].reshape(B, Hkv, Dh)
-        if cfg.is_eva:
-            # window rows are reused in place: position p lives at row
-            # p % W of the row's window pages (the table's first W / page)
-            W, C = cfg.eva_window, cfg.eva_chunk
-            kc_all, vc_all = paged_kv_append(kc_all, vc_all, k, v, pos % W,
-                                             page_table, layer=l, impl=impl)
-        elif page_table is not None:
-            # paged append: row b writes at row pos[b] % page of physical
-            # page page_table[b, pos[b] // page] (parked rows' tables
-            # point at the junk page 0 — their writes land where no live
-            # slot reads)
-            kc_all, vc_all = paged_kv_append(kc_all, vc_all, k, v, pos,
-                                             page_table, layer=l, impl=impl)
-        else:
-            kc_all = jax.lax.dynamic_update_slice(
-                kc_all, k[None, :, :, None, :].astype(kc_all.dtype),
-                (l, pos0, pos0, pos, pos0))
-            vc_all = jax.lax.dynamic_update_slice(
-                vc_all, v[None, :, :, None, :].astype(vc_all.dtype),
-                (l, pos0, pos0, pos, pos0))
-        if cfg.is_eva:
-            ctx = eva_decode_paged(q, kc_all, vc_all, pos, page_table,
-                                   layer=l, window=W, chunk=C,
-                                   sm_scale=scale, live=moe_live, impl=impl)
-            # rows whose step filled their window leave its summaries behind
-            kc_all, vc_all = eva_summarize_paged(
-                kc_all, vc_all, lp["eva_mu"], lp["eva_phi"], pos, page_table,
-                layer=l, window=W, chunk=C, impl=impl)
-        else:
-            ctx = flash_decode(q, kc_all, vc_all, pos, sm_scale=scale,
-                               layer=l, alibi=cfg.position == "alibi",
-                               page_table=page_table, live=moe_live,
-                               impl=impl)
-        wo, s_wo = wq_pair(lp["wo"])
-        r, h = fused_proj_norm(ctx.reshape(B, M), x, wo, lp.get("bo"),
-                               lp["n2_scale"], lp.get("n2_bias"), kind=kind,
-                               eps=eps, parallel=cfg.parallel_residual,
-                               wscale=s_wo, impl=impl)
-        if cfg.is_moe:
-            combine, chosen = moe_combine(h, lp["gate_w"], cfg)
-            ex = dparams["experts"]
-            x = fused_moe_mlp(h, r, combine, ex["w_up"], ex["w_down"],
-                              ex.get("w_gate"), layer=l, act=cfg.activation,
-                              impl=impl)
-            if count_moe:
-                load = jnp.sum(chosen & moe_live[:, None], axis=0,
-                               dtype=jnp.int32)
-                moe_stats = (moe_stats[0] + load,
-                             moe_stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
-                             moe_stats[2] + jnp.max(load))
-        else:
-            wu, su = wq_pair(lp["w_up"])
-            wd, sd = wq_pair(lp["w_down"])
-            wg, sg = (wq_pair(lp["w_gate"]) if "w_gate" in lp
-                      else (None, None))
-            wscales = (su, sg, sd) if su is not None else None
-            x = fused_mlp(h, r, wu, wd, wg,
-                          lp.get("b_up"), lp.get("b_gate"), lp.get("b_down"),
-                          act=cfg.activation, wscales=wscales, impl=impl)
+
+    def one_pass(x, kc_all, vc_all, moe_stats, first):
+        """The layer bodies once, layer ``l``'s weights over cache layer
+        ``first + l``: ``first`` is 0, or a looped stack's traced ``pass *
+        num_layers``."""
+        for l, lp in enumerate(dparams["layers"]):
+            cl = first + l
+            wqkv, s_qkv = wq_pair(lp["wqkv"])
+            qkv = fused_norm_qkv(x, lp["n1_scale"], lp.get("n1_bias"),
+                                 wqkv, lp.get("bqkv"), kind=kind, eps=eps,
+                                 wscale=s_qkv, impl=impl)
+            q, k = qkv[:, :M], qkv[:, M:M + Mkv]
+            if cfg.qk_norm:
+                q, k = qk_norm(q, k, lp["q_norm"], lp["k_norm"], eps)
+            q = rope_rows(q.reshape(B, H, Dh))
+            k = rope_rows(k.reshape(B, Hkv, Dh))
+            v = qkv[:, M + Mkv:].reshape(B, Hkv, Dh)
+            if cfg.is_eva:
+                # window rows are reused in place: position p lives at row
+                # p % W of the row's window pages (the table's first W / page)
+                W, C = cfg.eva_window, cfg.eva_chunk
+                kc_all, vc_all = paged_kv_append(
+                    kc_all, vc_all, k, v, pos % W, page_table, layer=cl,
+                    impl=impl)
+            elif page_table is not None:
+                # paged append: row b writes at row pos[b] % page of physical
+                # page page_table[b, pos[b] // page] (parked rows' tables
+                # point at the junk page 0 — their writes land where no live
+                # slot reads)
+                kc_all, vc_all = paged_kv_append(
+                    kc_all, vc_all, k, v, pos, page_table, layer=cl,
+                    impl=impl)
+            else:
+                kc_all = jax.lax.dynamic_update_slice(
+                    kc_all, k[None, :, :, None, :].astype(kc_all.dtype),
+                    (cl, pos0, pos0, pos, pos0))
+                vc_all = jax.lax.dynamic_update_slice(
+                    vc_all, v[None, :, :, None, :].astype(vc_all.dtype),
+                    (cl, pos0, pos0, pos, pos0))
+            if cfg.is_eva:
+                ctx = eva_decode_paged(
+                    q, kc_all, vc_all, pos, page_table, layer=cl, window=W,
+                    chunk=C, sm_scale=scale, live=moe_live, impl=impl)
+                # rows whose step filled their window leave its summaries
+                kc_all, vc_all = eva_summarize_paged(
+                    kc_all, vc_all, lp["eva_mu"], lp["eva_phi"], pos,
+                    page_table, layer=cl, window=W, chunk=C, impl=impl)
+            else:
+                ctx = flash_decode(q, kc_all, vc_all, pos, sm_scale=scale,
+                                   layer=cl, alibi=cfg.position == "alibi",
+                                   page_table=page_table, live=moe_live,
+                                   impl=impl)
+            if cfg.sandwich_norm:
+                # a norm between each sub-block and its residual add: the
+                # layer form's close, the dense branch of it
+                x, _ = afmoe.fused_close(cfg, dparams, lp, l,
+                                         ctx.reshape(B, M), x, None, None,
+                                         impl)
+                continue
+            wo, s_wo = wq_pair(lp["wo"])
+            r, h = fused_proj_norm(
+                ctx.reshape(B, M), x, wo, lp.get("bo"), lp["n2_scale"],
+                lp.get("n2_bias"), kind=kind, eps=eps,
+                parallel=cfg.parallel_residual, wscale=s_wo, impl=impl)
+            if cfg.is_moe:
+                combine, chosen = moe_combine(h, lp["gate_w"], cfg)
+                ex = dparams["experts"]
+                x = fused_moe_mlp(h, r, combine, ex["w_up"], ex["w_down"],
+                                  ex.get("w_gate"), layer=l,
+                                  act=cfg.activation, impl=impl)
+                if count_moe:
+                    load = jnp.sum(chosen & moe_live[:, None], axis=0,
+                                   dtype=jnp.int32)
+                    moe_stats = (
+                        moe_stats[0] + load,
+                        moe_stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
+                        moe_stats[2] + jnp.max(load))
+            else:
+                wu, su = wq_pair(lp["w_up"])
+                wd, sd = wq_pair(lp["w_down"])
+                wg, sg = (wq_pair(lp["w_gate"]) if "w_gate" in lp
+                          else (None, None))
+                wscales = (su, sg, sd) if su is not None else None
+                x = fused_mlp(h, r, wu, wd, wg, lp.get("b_up"),
+                              lp.get("b_gate"), lp.get("b_down"),
+                              act=cfg.activation, wscales=wscales, impl=impl)
+        return x, kc_all, vc_all, moe_stats
+
+    if cfg.is_looped:
+        # ONE trace of the layer bodies inside a rolled loop over the passes:
+        # the per-layer weights are loop-invariant operands (never a slice of
+        # a stacked array, see above), the pool is the carry, updated in
+        # place, and the final norm closes every pass
+        def loop_pass(t, carry):
+            with jax.named_scope("ds_loop_pass"):
+                x, kc, vc, _ = one_pass(*carry, None, t * cfg.num_layers)
+                return model_norm(cfg, x, dparams["final_norm"]), kc, vc
+
+        x, kc_all, vc_all = jax.lax.fori_loop(
+            0, cfg.total_ut_steps, loop_pass, (x, kc_all, vc_all))
+    else:
+        x, kc_all, vc_all, moe_stats = one_pass(x, kc_all, vc_all,
+                                                moe_stats, 0)
     new_cache = {"k": kc_all, "v": vc_all}
-    logits = output_logits(cfg, dparams, x)
+    logits = output_logits(cfg, dparams, x, normed=cfg.is_looped)
     if cfg.lm_head_bias:
         logits = logits + dparams["lm_head_bias"].astype(jnp.float32)
     if moe_live is not None:

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from deepspeed_tpu.comm.mesh import axis_size, get_global_mesh
+from deepspeed_tpu.models.afmoe import close
 from deepspeed_tpu.models.config import ModelConfig, get_model_config
 from deepspeed_tpu.models.layers import (activation_fn, apply_partial_rope,
                                          attention_core, constrain,
@@ -150,11 +151,29 @@ class CausalLM:
         layers = {"attn_norm": norm_p,
                   "mlp_norm": jax.tree.map(jnp.copy, norm_p),
                   "attn": attn, "mlp": mlp}
+        if cfg.sandwich_norm:
+            # a norm on each sub-block's OUTPUT too.  Its gain starts at
+            # (2 L)^-0.5: a normed output has unit scale whatever the stream's,
+            # so with gains of 1 every sub-block would REPLACE a stream of
+            # unit scale (the embedding below, a looped stack's normed pass)
+            # rather than refine it, and nothing would damp a rounding on its
+            # way through the depth; at (2 L)^-0.5 the 2 L outputs of one
+            # pass through the stack together carry the stream's own energy
+            post = jnp.full((L, D), (2 * L) ** -0.5, dtype)
+            layers.update(attn_post_norm={"scale": post},
+                          mlp_post_norm={"scale": jnp.copy(post)})
         fnorm = {"scale": gain((D,), dtype)}
         if cfg.norm == "layernorm":
             fnorm["bias"] = jnp.zeros((D,), dtype)
+        # the token embedding at 0.02 is the start of a stream that the
+        # sub-blocks' raw outputs, of like scale, add to; where every output
+        # is NORMED to unit scale first it would be no part of the stream,
+        # which then were the first sub-blocks' outputs alone (PERF.md,
+        # PR 44's finding), so there it starts at unit scale too
+        embed_std = 1.0 if cfg.sandwich_norm else 0.02
         params = {
-            "embed": {"tok": jax.random.normal(next(keys), (V, D), dtype) * 0.02},
+            "embed": {"tok": jax.random.normal(next(keys), (V, D), dtype)
+                      * embed_std},
             "layers": layers,
             "final_norm": fnorm,
         }
@@ -170,6 +189,10 @@ class CausalLM:
                 next(keys), (D, cfg.num_pred_heads * V), dtype) * s_in
         if cfg.lm_head_bias:
             params["lm_head_bias"] = jnp.zeros((V,), dtype)
+        if cfg.loop_exit_gate:  # a Linear with bias on each pass's stream
+            params["exit_gate"] = {
+                "w": jax.random.normal(next(keys), (D, 1), dtype) * s_in,
+                "b": jnp.zeros((1,), dtype)}
         return params
 
     def logical_pspecs(self) -> Dict[str, Any]:
@@ -234,6 +257,11 @@ class CausalLM:
             specs["lm_head"] = P(None, "tp")
         if cfg.lm_head_bias:
             specs["lm_head_bias"] = P("tp")
+        if cfg.sandwich_norm:
+            specs["layers"].update(attn_post_norm={"scale": P(None, None)},
+                                   mlp_post_norm={"scale": P(None, None)})
+        if cfg.loop_exit_gate:
+            specs["exit_gate"] = {"w": P(None, None), "b": P(None)}
         mesh = self.mesh
         if mesh is not None and not mesh.empty:
             # pipeline parallelism: stage ownership = stacked-layer-dim shard
@@ -298,7 +326,9 @@ class CausalLM:
         return o
 
     def _attn_block(self, lp, x, k_attn, cos, sin, batch_ax, use_drop):
-        x = x + self._attn_out(lp, x, k_attn, cos, sin, batch_ax, use_drop)
+        x = close(self.config, x,
+                  self._attn_out(lp, x, k_attn, cos, sin, batch_ax, use_drop),
+                  lp.get("attn_post_norm", {}).get("scale"))
         return constrain(x, self.mesh, batch_ax, "sp", None)
 
     def _mlp_block(self, lp, x, k_mlp, batch_ax, use_drop):
@@ -335,7 +365,7 @@ class CausalLM:
         mlp_out = mlp_out.astype(x.dtype)
         if use_drop:
             mlp_out = _dropout(mlp_out, k_mlp, cfg.dropout)
-        x = x + mlp_out
+        x = close(cfg, x, mlp_out, lp.get("mlp_post_norm", {}).get("scale"))
         return constrain(x, mesh, batch_ax, "sp", None), aux
 
     def _layer(self, lp, x, key, cos, sin, batch_ax, use_drop):
@@ -351,12 +381,26 @@ class CausalLM:
         x = self._attn_block(lp, x, k_attn, cos, sin, batch_ax, use_drop)
         return self._mlp_block(lp, x, k_mlp, batch_ax, use_drop)
 
-    def apply(self, params, tokens, labels=None, rngs=None, loss_mask=None):
+    def apply(self, params, tokens, labels=None, rngs=None, loss_mask=None,
+              exit_distribution: bool = False):
+        """``exit_distribution`` (a looped stack with ``loop_exit_gate``):
+        return (logits, p [B, S, total_ut_steps]), the distribution over the
+        pass at which each token would leave (:func:`exit_probabilities`)."""
         cfg = self.config
         mesh = self.mesh
         batch_ax = _BATCH_AX
         if cfg.is_afmoe:
             return self._apply_afmoe(params, tokens, labels)
+        if exit_distribution and not cfg.loop_exit_gate:
+            raise ValueError("exit_distribution needs loop_exit_gate")
+        if cfg.is_looped and (labels is not None or cfg.param_offload
+                              or not cfg.scan_layers):
+            raise NotImplementedError(
+                "a looped stack (total_ut_steps > 1) is served only: its "
+                "training loss (an expected loss over the exit distribution "
+                "with an entropy term) is not built, and its pass loop is a "
+                "scan around the scan over stacked layers (scan_layers, no "
+                "param_offload)")
         if cfg.param_offload:
             # ZeRO-Infinity param tiering: non-layer params come over once
             # here; scanned layer weights stream per-layer inside the scan
@@ -502,6 +546,11 @@ class CausalLM:
             return y, aux
 
         if pp > 1:
+            if cfg.is_looped:
+                raise NotImplementedError(
+                    "a looped stack (total_ut_steps > 1) with pp > 1: the "
+                    "stream would pass the ring of stages once a pass, which "
+                    "runtime/pipe/spmd.py does not build")
             if not cfg.scan_layers:
                 raise ValueError("pipeline parallelism requires scan_layers=True "
                                  "(stacked layer params)")
@@ -630,6 +679,17 @@ class CausalLM:
                                         quantize_boundary=cfg.pp_boundary_q,
                                         quant_block=cfg.comm_quant_block,
                                         comm_record=cfg.pp_comm_record)
+        elif cfg.is_looped:
+            # the SAME stacked weights in every pass; the final norm closes
+            # each pass and feeds the next, and the gate reads what it left
+            def one_pass(x, _):
+                x, _ = jax.lax.scan(scan_body, x, (params["layers"], keys))
+                x = model_norm(cfg, x, params["final_norm"], mesh)
+                return x, (_exit_gate(params["exit_gate"], x)
+                           if exit_distribution else None)
+
+            x, gates = jax.lax.scan(one_pass, x, None,
+                                    length=cfg.total_ut_steps)
         elif cfg.scan_layers:
             x, auxes = jax.lax.scan(scan_body, x, (params["layers"], keys))
             aux_loss = jnp.sum(auxes)
@@ -664,13 +724,17 @@ class CausalLM:
                 aux_loss = aux_loss + aux
 
         if labels is None:
-            x = model_norm(cfg, x, params["final_norm"], mesh)
+            if not cfg.is_looped:     # a pass loop has normed its last pass
+                x = model_norm(cfg, x, params["final_norm"], mesh)
             head = (params["embed"]["tok"].T if cfg.tie_embeddings
                     else params["lm_head"]).astype(x.dtype)
             logits = x @ head
             if cfg.lm_head_bias:
                 logits = logits + params["lm_head_bias"].astype(logits.dtype)
-            return constrain(logits, mesh, batch_ax, "sp", "tp")
+            logits = constrain(logits, mesh, batch_ax, "sp", "tp")
+            if exit_distribution:
+                return logits, exit_probabilities(gates)
+            return logits
         head = (params["embed"]["tok"].T if cfg.tie_embeddings
                 else params["lm_head"])
         loss = self._loss_tail(params["final_norm"], head, x, labels, loss_mask,
@@ -805,6 +869,26 @@ class CausalLM:
             "head_loss": head_loss,
             "rope": rope,
         }
+
+
+def _exit_gate(gate, x):
+    """sigmoid(x w + b) in float32: [..., D] -> [...]."""
+    return jax.nn.sigmoid(
+        jnp.dot(x.astype(jnp.float32), gate["w"].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)[..., 0]
+        + gate["b"].astype(jnp.float32)[0])
+
+
+def exit_probabilities(gates):
+    """The passes' gates ``g`` [T, ...] -> p [..., T], the probability that a
+    token leaves after pass t: ``p_t = g_t prod_{s<t} (1 - g_s)`` for
+    ``t < T - 1`` and the rest of the mass at ``T - 1``.  A token leaves at
+    the first pass whose cumulated p reaches ``early_exit_threshold``; at
+    the only threshold built, 1, that is the last."""
+    stay = jnp.cumprod(1.0 - gates[:-1], axis=0)           # prod_{s<=t}
+    before = jnp.concatenate([jnp.ones_like(gates[:1]), stay[:-1]], axis=0)
+    p = jnp.concatenate([gates[:-1] * before, stay[-1:]], axis=0)
+    return jnp.moveaxis(p, 0, -1)
 
 
 def _dropout(x, key, rate: float):

@@ -405,11 +405,13 @@ def _kv_append_kernel(pp_ref, po_ref, *refs, rows):
         out[0] = jnp.where(hit, new[0], old[0])
 
 
-def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer: int,
+def paged_kv_append(kcache, vcache, k, v, pos, page_table, *, layer,
                     impl: Optional[str] = None):
     """Write this step's K/V rows into the stacked paged pool, in place:
     row b of ``k``/``v`` [B, Hkv, Dh] lands at row ``pos[b] % page`` of
-    physical page ``page_table[b, pos[b] // page]`` of layer ``layer``.
+    physical page ``page_table[b, pos[b] // page]`` of layer ``layer``, a
+    Python int or a traced scalar (a looped stack's ``pass * layers +
+    layer`` inside its rolled pass loop).
 
     The XLA form is one batched scatter.  On the chip that scatter wants
     its index dims minor-most while the flash-decode kernel takes the pool
@@ -431,9 +433,12 @@ def paged_row_append(cache, row, pos, page_table, *, layer: int,
                          layer, impl)[0]
 
 
-def _paged_append(pools, new_rows, pos, page_table, layer: int, impl):
+def _paged_append(pools, new_rows, pos, page_table, layer, impl):
     """The one scatter and the one ``pallas_call`` behind the appends: each
-    of ``pools`` [L, P, Hkv, page, Dh] takes its rows [B, Hkv, Dh]."""
+    of ``pools`` [L, P, Hkv, page, Dh] takes its rows [B, Hkv, Dh].  A
+    static ``layer`` is folded into the index map; a traced one into the
+    scalar-prefetched page numbers, which the index map then takes as they
+    are (an index map may close over no traced value)."""
     impl = resolve_impl(impl)
     L, P, Hkv, page, Dh = pools[0].shape
     B = new_rows[0].shape[0]
@@ -447,9 +452,12 @@ def _paged_append(pools, new_rows, pos, page_table, layer: int, impl):
     rows = 32 // pools[0].dtype.itemsize      # one (sublane x lane) tile
     kernel = functools.partial(_kv_append_kernel, rows=rows)
     n = len(pools)
+    base = layer * P
+    if not isinstance(layer, int):
+        pp, base = pp + base, 0
 
     def group(b, pp_ref, po_ref):
-        return layer * P + pp_ref[b], 0, po_ref[b] // rows, 0
+        return base + pp_ref[b], 0, po_ref[b] // rows, 0
 
     new = pl.BlockSpec((1, Hkv, 1, Dh), lambda b, pp_ref, po_ref: (b, 0, 0, 0))
     old = pl.BlockSpec((1, Hkv, rows, Dh), group)
@@ -473,7 +481,7 @@ def _paged_append(pools, new_rows, pos, page_table, layer: int, impl):
 
 
 def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
-                        layer: Optional[int], alibi: bool, live, impl: str):
+                        layer, alibi: bool, live, impl: str):
     """Decode attention over the PAGED pool (``serving/paged_kv.py``):
     caches [P, Hkv, page, Dh] (or stacked [L, P, Hkv, page, Dh] with
     ``layer=l``), ``page_table`` [B, maxp] int32 naming each row's
@@ -491,7 +499,9 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
     cost their grid step, a row that does not decode costs nothing and
     pages past every live row are no grid steps.  The XLA path gathers
     the logical per-slot view and runs the dense reference (CPU tests, and
-    the page sizes :func:`paged_decode_reference_reason` names)."""
+    the page sizes :func:`paged_decode_reference_reason` names).  A traced
+    ``layer`` (:func:`paged_kv_append`) is added to the prefetched table's
+    page numbers."""
     kc = kcache if layer is None else kcache[layer]
     vc = vcache if layer is None else vcache[layer]
     page = kc.shape[2]
@@ -504,13 +514,16 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
                                  paged_logical_view(vc, page_table), pos,
                                  scale=scale, alibi=alibi)
     base = 0 if layer is None else layer * kc.shape[0]
+    page_table = page_table.astype(jnp.int32)
+    if not isinstance(base, int):
+        page_table, base = page_table + base, 0
 
     def page_map(b, g, j, pos_ref, pt_ref):
         jl = jnp.minimum(j, pos_ref[b] // page)     # per-row DMA clamp
         return base + pt_ref[b, jl], g, 0, 0
 
     return _decode_attention(
-        q, kcache, vcache, pos, (page_table.astype(jnp.int32),), page_map,
+        q, kcache, vcache, pos, (page_table,), page_map,
         live=live, block=page, nb=page_table.shape[1], scale=scale,
         alibi=alibi, impl=impl, name="flash_decode_paged")
 
@@ -521,9 +534,10 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
                  page_table=None, live=None):
     """Single-launch decode attention.  q: [B, H, Dh]; caches:
     [B, Hkv, Smax, Dh] — or, with ``layer=l``, stacked [L, B, Hkv, Smax, Dh]
-    read at static layer offset ``l`` through the index map (no cache slice
-    materializes); ``pos`` the (traced) absolute position of the query — a
-    scalar shared by the batch, or an int32 [B] vector of per-row depths
+    read at layer offset ``l`` (a Python int or a traced scalar) through
+    the index map (no cache slice materializes); ``pos`` the (traced)
+    absolute position of the query — a scalar shared by the batch, or an
+    int32 [B] vector of per-row depths
     (continuous batching: each slot masks and clamps independently).
     ``page_table`` [B, maxp] switches to the paged pool layout
     ([P, Hkv, page, Dh] physical pages; see :func:`_flash_decode_paged`).
@@ -556,12 +570,19 @@ def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,
     if impl == "xla":
         return _flash_decode_ref(q, kc, vc, pos, scale=scale, alibi=alibi)
     base = 0 if layer is None else layer * q.shape[0]
+    if isinstance(base, int):
+        tables = ()
 
-    def clamp(b, g, j, pos_ref):                    # per-row DMA clamp
-        return base + b, g, jnp.minimum(j, pos_ref[b] // block), 0
+        def clamp(b, g, j, pos_ref):                # per-row DMA clamp
+            return base + b, g, jnp.minimum(j, pos_ref[b] // block), 0
+    else:       # a traced layer: its offset is prefetched, a table of one
+        tables = (jnp.reshape(base, (1,)).astype(jnp.int32),)
+
+        def clamp(b, g, j, pos_ref, base_ref):
+            return base_ref[0] + b, g, jnp.minimum(j, pos_ref[b] // block), 0
 
     return _decode_attention(
-        q, kcache, vcache, pos, (), clamp, live=live, block=block,
+        q, kcache, vcache, pos, tables, clamp, live=live, block=block,
         nb=Smax // block, scale=scale, alibi=alibi, impl=impl,
         name="flash_decode")
 

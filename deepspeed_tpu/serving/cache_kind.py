@@ -5,10 +5,11 @@ requests; neither knows what a page holds.  That depends on the model's
 attention form, and :func:`cache_kind` is the one place that decides it
 (the layouts themselves are set out in ``serving/paged_kv.py``'s docstring):
 
-- :class:`FullPages` (GPT-2, Mistral, OLMoE): per-head K and V rows of
-  ``page`` consecutive positions in every layer, for ever.  Position-pure,
-  so prefix caching, the prefill -> decode hand-off, the host tier and the
-  int8 cache all work on it: it refuses nothing;
+- :class:`FullPages` (GPT-2, Mistral, OLMoE, Ouro): per-head K and V rows
+  of ``page`` consecutive positions in every cache layer (``cfg.cache_layers``:
+  a looped stack has one a (pass, layer) pair, all under the one table), for
+  ever.  Position-pure, so prefix caching, the prefill -> decode hand-off,
+  the host tier and the int8 cache all work on it: it refuses nothing;
 - :class:`WindowSummaryPages` (``attention="eva"``, ``models/eva.py``);
 - :class:`TwoBudgets` (sliding and global layers, ``models/afmoe.py``);
 - :class:`LatentPages` (latent-attention layers only,
@@ -100,6 +101,11 @@ class FullPages:
     # since a program under it saves no work and costs a compile.  Here the
     # attention pads nothing and a chat prompt does end in a short chunk
     chunk_rows = 8
+    # the pool's arrays of pages ([layers, pages, heads, page, width]; a
+    # kind holds those of them it has), and the ones whose leading extents
+    # add up to its cache layers
+    page_arrays = ("k", "v", "k_scale", "v_scale")
+    layer_arrays = ("k",)
 
     def __init__(self, cfg):
         self.cfg = cfg
@@ -186,6 +192,23 @@ class FullPages:
             for kind in ("window", "summary", "full", "index")}
         self._reg = registry
         self._m = {name: registry.counter(name) for name in self.counters}
+
+    def cache_gauges(self, cache) -> None:
+        """``ds_serve_kv_cache_layers`` and ``ds_serve_kv_bytes_per_token``,
+        from the pool's arrays as built: fixed for the engine's life."""
+        self._reg.gauge(
+            "ds_serve_kv_cache_layers",
+            "layers of the page pool's arrays: the model's layers that keep "
+            "rows in pages, times the passes of a looped stack (one cache "
+            "layer a (pass, layer) pair)").set(
+                sum(cache[k].shape[0] for k in self.layer_arrays))
+        self._reg.gauge(
+            "ds_serve_kv_bytes_per_token",
+            "bytes one position holds in the page pool: a row in every "
+            "array of pages, over all their layers (int8 rows with their "
+            "scales)").set(
+                sum(cache[k].nbytes // (cache[k].shape[1] * cache[k].shape[3])
+                    for k in self.page_arrays if k in cache))
 
     def count_admit(self) -> None:
         """A request took a slot."""
@@ -339,6 +362,8 @@ class TwoBudgets(FullPages):
     }
     takes_valid_len = True          # a ring takes no pad row
     pages_by_kind = True
+    page_arrays = ("k_win", "v_win", "k_full", "v_full")
+    layer_arrays = ("k_win", "k_full")
 
     def pool_args(self, dtype):
         return {"ring_tokens": self.cfg.sliding_window}
@@ -453,6 +478,8 @@ class LatentPages(FullPages):
     # mla_chunk_attention, dsa_index_scores_chunk and dsa_chunk_attention
     # pad a chunk's queries to one lane tile inside the call
     chunk_rows = _LANES
+    page_arrays = ("latent", "index")
+    layer_arrays = ("latent",)
 
     def init_cache(self, pool, num_slots, dtype, quantized):
         cfg = self.cfg

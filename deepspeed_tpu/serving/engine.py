@@ -432,6 +432,13 @@ class ServingEngine:
                        for name, what in SERVE_MOE_COUNTERS.items()}
         # page and state series: every kind's registered, this kind's moved
         self.kind.attach(reg, self.pool)
+        self.kind.cache_gauges(self._cache)
+        self._m_loop_passes = reg.counter(
+            "ds_serve_loop_passes_total",
+            "passes through the layer stack dispatched: total_ut_steps a "
+            "prefill chunk and a decode step (decode_block_tokens x "
+            "total_ut_steps a block); 1 a chunk and a step unless the stack "
+            "is looped")
         self._m_first_overlapped = reg.counter(
             "ds_serve_first_token_overlapped_total",
             "first tokens fetched with a decode block already enqueued "
@@ -1849,6 +1856,7 @@ class ServingEngine:
             self._tracer.span(req.request_id, "prefill_chunk", t0,
                               time.perf_counter(), c, seq=seq)
             self._m_prefill_chunks.inc()
+            self._m_loop_passes.inc(self.module.config.total_ut_steps)
             self._m_prefill_toks.inc(c)
             self._m_prefill_pad_rows.inc(cb - c)
             self.kind.count_chunk(self.pool, self._cache, off, c, cb)
@@ -2050,6 +2058,7 @@ class ServingEngine:
         idx = self._next_block
         self._next_block += 1
         self._m_row_slots.inc(self.num_slots * self._K)
+        self._m_loop_passes.inc(self._K * self.module.config.total_ut_steps)
         refs = 0
         drainers: List[Request] = []
         for req in running:

@@ -158,9 +158,11 @@ def init_paged_kv_cache(cfg, num_pages: int, page_tokens: int,
     """Device arrays for the shared page pool — the paged analog of
     :func:`~deepspeed_tpu.models.decoding.init_kv_cache`, with the slot
     dim replaced by the page dim and the sequence dim by the page depth:
-    one budget of per-head K and V pages in every layer.  The arrays of the
-    other kinds of cache are their kinds' (``serving/cache_kind.py``)."""
-    L, Hkv, Dh = cfg.num_layers, cfg.num_kv_heads, cfg.head_dim
+    one budget of per-head K and V pages in every cache layer (a looped
+    stack has one a (pass, layer) pair, ``cfg.cache_layers``, all under the
+    one page table).  The arrays of the other kinds of cache are their
+    kinds' (``serving/cache_kind.py``)."""
+    L, Hkv, Dh = cfg.cache_layers, cfg.num_kv_heads, cfg.head_dim
     if quantized:
         return {
             "k": jnp.zeros((L, num_pages, Hkv, page_tokens, Dh), jnp.int8),

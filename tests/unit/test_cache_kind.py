@@ -1,6 +1,8 @@
 """The contract of ``serving/cache_kind.py``, once over the six kinds of
 slot cache at the tiny sizes their model tests build: full pages (a tiny
-Llama), window + summary pages (``test_evabyte``), two page budgets
+Llama; and a LOOPED stack, two layers run three times with post-norms,
+whose pool is the same kind over ``cache_layers`` = 6 layers), window +
+summary pages (``test_evabyte``), two page budgets
 (``test_trinity``), latent pages alone (``test_axk1``), latent pages + slot
 state (``test_kimi_linear``), latent pages + index keys + slot rings
 (``test_dots3_note``).
@@ -42,9 +44,16 @@ CASES = {
               test_kimi_linear.ENGINE),
     "indexed": (IndexedLatentPagesAndRing, test_dots3_note.FIELDS,
                 test_dots3_note.ENGINE),
+    # no kind of its own: full pages, one layer a (pass, layer) pair
+    "looped": (FullPages, dict(
+        vocab_size=96, hidden_size=64, intermediate_size=128, num_layers=2,
+        num_heads=4, num_kv_heads=2, max_seq_len=2048, total_ut_steps=3,
+        sandwich_norm=True, loop_exit_gate=True), ENGINE),
 }
 BY_SLOT = ("state", "tail", "ring")     # entries [layers, slots, ...]
 NAMES = list(CASES)
+# the kinds with a table of what they cannot be served with
+REFUSING = [n for n in NAMES if CASES[n][0] is not FullPages]
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +100,14 @@ def prompts_of(built, name, lengths, seed):
 def test_the_chooser_picks_the_kind(built, name):
     kind = cache_kind(built(name)[0].config)
     assert type(kind) is CASES[name][0] and type(kind) in KINDS
-    assert bool(kind.cannot) == (name != "full")
+    assert bool(kind.cannot) == (name in REFUSING)
+    if name == "looped":
+        cfg = built(name)[0].config
+        assert (cfg.cache_layers, cfg.num_layers) == (6, 2)
+        pool = PagedKVPool(3, 96, page_tokens=8)
+        assert {v.shape for v in kind.init_cache(
+            pool, 3, jnp.float32, False).values()} == {
+                (6, pool.num_pages, 2, 8, 16)}
 
 
 @pytest.mark.parametrize("name", NAMES)
@@ -223,7 +239,7 @@ ASKED = {
 }
 
 
-@pytest.mark.parametrize("name", NAMES[1:])
+@pytest.mark.parametrize("name", REFUSING)
 def test_prefill_only_is_refused_with_the_kinds_reason(built, name):
     serve = serve_of(built, name)
     kind = serve.kind
@@ -245,11 +261,13 @@ def test_the_decode_role_is_refused_with_the_kinds_reason(built, name):
                              f"{kind.cannot['handoff']}"
 
 
+@pytest.mark.parametrize("name", ["full", "looped"])
 @pytest.mark.parametrize("option", [*ASKED, "prefill_only"])
-def test_full_pages_refuse_nothing(built, option):
+def test_full_pages_refuse_nothing(built, option, name):
     """Every option another kind's table lists builds (and ``prefill_only``
-    submits) on position-pure pages; prefix caching stays on."""
-    serve = serve_of(built, "full", **ASKED.get(option, {}))
+    submits) on position-pure pages, whatever the number of cache layers
+    under the table; prefix caching stays on."""
+    serve = serve_of(built, name, **ASKED.get(option, {}))
     assert serve.kind.cannot == {} and serve.prefix_cache is not None
     assert (serve.host_store is not None) == (option == "kv_host_tier_pages")
     if option == "prefill_only":

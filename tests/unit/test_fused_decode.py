@@ -547,3 +547,66 @@ def test_flash_decode_visits_live_rows(case, layout):
     want = _flash_decode_ref(q, k, v, posv, scale=Dh ** -0.5)
     np.testing.assert_allclose(got[rows], want[rows], rtol=2e-4, atol=2e-4)
     np.testing.assert_array_equal(got[parked], q[parked])
+
+
+# -- a traced cache layer (a looped stack's ``pass * layers + layer``) ---------
+@pytest.mark.parametrize("impl", ["interpret", "xla"])
+def test_a_traced_cache_layer_appends_and_attends_as_the_int_layer_does(impl):
+    """``paged_kv_append`` and ``flash_decode(page_table=)`` inside a rolled
+    loop over the cache layers, the layer a traced scalar (folded into the
+    scalar-prefetched page numbers), against one call a Python-int layer:
+    the same pools bit for bit, the same attention."""
+    B, Hkv, Dh, page, maxp, L = 3, 2, 64, 128, 2, 3
+    ks, vs, kp_all, vp_all, pt = _stacked_pools(B, Hkv, Dh, page, maxp, L,
+                                                seed=3)
+    pt = _park(pt, 1)
+    q = _rand(0, B, 2 * Hkv, Dh)
+    k, v = _rand(1, L, B, Hkv, Dh), _rand(2, L, B, Hkv, Dh)
+    pos = jnp.asarray([130, 0, 255], jnp.int32)
+    live = jnp.asarray([True, False, True])
+
+    def step(layer, kc, vc):
+        kc, vc = paged_kv_append(kc, vc, k[layer], v[layer], pos, pt,
+                                 layer=layer, impl=impl)
+        return kc, vc, flash_decode(q, kc, vc, pos, layer=layer,
+                                    page_table=pt, live=live, impl=impl)
+
+    def rolled(kc, vc):
+        def body(layer, carry):
+            kc, vc, outs = carry
+            kc, vc, o = step(layer, kc, vc)
+            return kc, vc, outs.at[layer].set(o)
+        return jax.lax.fori_loop(0, L, body,
+                                 (kc, vc, jnp.zeros((L,) + q.shape, q.dtype)))
+
+    got_k, got_v, got = jax.jit(rolled)(kp_all, vp_all)
+    want_k, want_v, want = kp_all, vp_all, []
+    for layer in range(L):
+        want_k, want_v, o = step(layer, want_k, want_v)
+        want.append(o)
+    rows = np.asarray([0, 2])                   # row 1 is parked on page 0
+    np.testing.assert_array_equal(np.asarray(got_k[:, 1:]),
+                                  np.asarray(want_k[:, 1:]))
+    np.testing.assert_array_equal(np.asarray(got_v[:, 1:]),
+                                  np.asarray(want_v[:, 1:]))
+    np.testing.assert_allclose(np.asarray(got)[:, rows],
+                               np.asarray(jnp.stack(want))[:, rows],
+                               rtol=1e-6, atol=1e-6)
+    # ... and each layer read ITS pool: another layer's rows give another o
+    assert np.abs(np.asarray(got)[0, rows] - np.asarray(got)[1, rows]).max() \
+        > 1e-2
+
+
+def test_a_traced_layer_offsets_the_contiguous_cache():
+    """``flash_decode(layer=)`` over a stacked [L, B, Hkv, Smax, Dh] cache
+    with the layer traced: its offset rides as a prefetched scalar."""
+    L, B, Hkv, Smax, Dh = 3, 2, 2, 512, 64
+    q = _rand(0, B, 2 * Hkv, Dh)
+    k, v = _rand(1, L, B, Hkv, Smax, Dh), _rand(2, L, B, Hkv, Smax, Dh)
+    pos = jnp.asarray([300, 17], jnp.int32)
+    got = jax.jit(lambda layer: flash_decode(q, k, v, pos, layer=layer,
+                                             impl="interpret"))
+    for l in range(L):
+        want = flash_decode(q, k, v, pos, layer=l, impl="interpret")
+        np.testing.assert_allclose(got(jnp.int32(l)), want, rtol=1e-6,
+                                   atol=1e-6)

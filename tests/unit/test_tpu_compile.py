@@ -902,3 +902,40 @@ def test_dots3_note_cell_programs_compile_with_pages_and_rings_in_place(
         calls(text, "kda_decode_step") == 0
     assert calls(text, "dsa_index_scores_paged") == \
         calls(text, "dsa_decode_selected") > 0
+
+
+def test_ouro_cell_programs_roll_the_pass_loop_with_the_pool_in_place(
+        v5e, chip_kernels):
+    """ISSUE 57: the decode block and the chunk programs of the
+    ``ouro-2.6b-L12.serve-reason-768`` cell (two layers of its twelve, run
+    four times; four slots and two slots' worth of pool, which changes no
+    shape the loop or a copy turns on) compile for the v5e with the pass
+    loop ROLLED: the kernel calls of a one-pass model (five a layer and the
+    final norm, which here closes every pass inside the loop), one
+    attention call a LAYER and not a (pass, layer) pair, its page table the
+    prefetched one plus the traced layer's offset, the pool of ``passes x
+    layers`` cache layers updated in place and the donations taken."""
+    cell = _ServeCell(v5e, "ouro-2.6b-L12", "ouro-2.6b-L12.serve-reason-768",
+                      fields=dict(num_layers=2),
+                      engine=dict(num_slots=4, kv_pool_tokens=2560))
+    cfg, serve = cell.model.config, cell.serve
+    assert (cfg.total_ut_steps, cfg.cache_layers) == (4, 8)
+    assert serve._cache["k"].shape[0] == 8 and serve.pool.slot_pages == 5
+    block = cell.block()
+    assert len(serve.engine._dparams["layers"]) == 2
+    text = block.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 5 * 2 + 1
+    for name in ("flash_decode_paged", "paged_kv_append"):
+        calls = [line for line in text.splitlines()
+                 if "custom-call(" in line and name in line]
+        assert len(calls) == 2, name
+    # the pass loop is a loop of the program, inside the block's own
+    assert len(re.findall(r" while\(", text)) >= 2
+    cell.assert_pools_stay_in_place(block)
+    # no serve program reads the exit gate (a threshold of 1), so jit drops
+    # its two leaves from a chunk program's arguments
+    cell.params = {k: v for k, v in cell.params.items() if k != "exit_gate"}
+    for bucket in (8, serve.prefill_chunk):
+        chunk = cell.chunk(bucket)
+        cell.assert_pools_stay_in_place(chunk)
+        cell.assert_donations_taken(chunk, donated=5)
