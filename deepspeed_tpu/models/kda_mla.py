@@ -94,7 +94,10 @@ each of its queries (``dsa_index_scores_chunk``), finds each query's
 decompressed and scored, because the selection differs by query); a decode
 step scores its row's pages (``dsa_index_scores_paged``), takes a top-k and
 attends the rows at those POSITIONS, gathered out of the pool
-(``dsa_decode_selected``).  A sliding latent layer keeps a per-slot ``ring``
+(``dsa_decode_selected``); the sort and the gather, like the kernels, work
+the rows that decode and no others, a group of them an iteration of a loop
+whose trip count is read at run time.  A sliding latent layer keeps a
+per-slot ``ring``
 ``[sliding layers, slots, ring rows, row width]``: the row of position p at
 ``p % ring rows``, attended where the position a ring row holds lies in the
 window; a chunk attends the ring's rows before it and its own, then writes
@@ -600,14 +603,59 @@ def select_keys(scores_t, q_pos, k: int):
     return jnp.where(keep, 0.0, afmoe.NEG_INF).astype(jnp.bfloat16)
 
 
+# rows of one group of :func:`select_positions`' loop (one sort a group).
+# Eight: the chip sorts [2, 32,768], [4, .] and [8, .] float32 scores in the
+# same 193-204 us and [16, .] in 387 (a vreg's eight sublanes hold eight
+# batch rows), so a smaller group sorts no faster and pads no less (my chip
+# runs, PR 58, tools/dsa_select_bench.py; the whole table: PERF.md section
+# 5, ``dots3-note-L5-ep16.serve-doc-48k``)
+SORT_GROUP = 8
+
+
 def select_positions(scores, pos, k: int):
     """A decode step's selection as positions: ``scores`` [B, keys] float32
     (``NEG_INF`` past ``pos``) -> (positions [B, k'] int32 of the ``k' =
-    min(k, keys)`` largest, best first; how many of them are real,
-    ``min(pos + 1, k')`` [B])."""
+    min(k, keys)`` largest, best first, BY SLOT; how many of them are real,
+    ``min(pos + 1, k')`` [B]).
+
+    A NEGATIVE ``pos`` says that the row does not decode
+    (:func:`fused_layers` hands ``where(live, pos, -1)``): its positions are
+    zeros and none of them is real.  The rows that decode are sorted
+    :data:`SORT_GROUP` at a time, live rows first
+    (``ops/pallas/decode.py:over_live_groups``), in a loop whose trip count is
+    read at run time: one ``jax.lax.top_k`` over a group's score rows an
+    iteration, written at those slots, so a parked slot costs no sort unless
+    it pads the last group, and no live row at all is a loop of no
+    iterations.  A row's selection is ``jax.lax.top_k``'s on that row alone,
+    ties at the k-th score by index order included."""
+    from deepspeed_tpu.ops.pallas.decode import over_live_groups
+
+    B = scores.shape[0]
     k = min(k, scores.shape[1])
-    _, idx = jax.lax.top_k(scores, k)
-    return idx.astype(jnp.int32), jnp.minimum(pos + 1, k).astype(jnp.int32)
+    live = pos >= 0
+
+    def group(at):
+        _, idx = jax.lax.top_k(jnp.take(scores, at, axis=0), k)
+        return jnp.where(jnp.take(live, at)[:, None], idx.astype(jnp.int32),
+                         0)
+
+    sel = over_live_groups(live, B, SORT_GROUP, group,
+                           jnp.zeros((B, k), jnp.int32))
+    return sel, jnp.minimum(pos + 1, k).astype(jnp.int32)
+
+
+def selection_rows(live):
+    """(row, step) pairs one indexed layer's selection works in a decode
+    step with the live mask ``live`` [B], group padding included: [rows
+    :func:`select_positions` sorts, rows ``dsa_decode_selected`` looks up,
+    gathers and attends] int32."""
+    from deepspeed_tpu.ops.pallas.decode import GATHER_GROUP, live_groups
+
+    def worked(group):
+        _, G, groups = live_groups(live, live.shape[0], group)
+        return G * groups
+
+    return jnp.stack([worked(SORT_GROUP), worked(GATHER_GROUP)])
 
 
 # ----------------------------------------------------------------------
@@ -868,11 +916,20 @@ def inject(cfg, params) -> Dict[str, Any]:
 
 
 def moe_counts_zero(cfg):
-    """``afmoe.moe_counts_zero`` and, for a model with linear layers, a last
+    """``afmoe.moe_counts_zero``; for a model with linear layers one more
     entry: (row, linear layer) pairs that were LIVE, and pairs whose state
-    the decode kernel VISITED."""
-    return afmoe.moe_counts_zero(cfg) + (
-        (jnp.zeros((2,), jnp.int32),) if kind_layers(cfg)[0] else ())
+    the decode kernel VISITED; for a model with an indexer a LAST entry:
+    (row, step) pairs the selection of ONE indexed layer sorted, and pairs
+    it gathered and attended (:func:`selection_rows`)."""
+    pair = lambda has: (jnp.zeros((2,), jnp.int32),) if has else ()
+    return (afmoe.moe_counts_zero(cfg) + pair(kind_layers(cfg)[0])
+            + pair(cfg.mla_index_topk))
+
+
+def _counted(stats, i: int, more):
+    """The counts with ``more`` added to entry ``i``."""
+    i %= len(stats)
+    return stats[:i] + (stats[i] + more,) + stats[i + 1:]
 
 
 def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
@@ -883,8 +940,12 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     layers, ``state`` [linear layers, B, H, d, d] and ``tail`` [linear
     layers, B, K - 1, 3 H d] by row (a row of the batch is a slot).
     ``moe_live`` [B] bool: the rows that decode; only their state, tail and
-    latent pages move, and the kernels visit only them.  Returns (x, cache,
-    counts | None)."""
+    latent pages move, and the kernels visit only them.  In an indexed layer
+    it governs the selection between the two kernels too: only the rows that
+    decode are sorted (:func:`select_positions`, which is told by a negative
+    position), looked up, gathered and attended (``dsa_decode_selected``),
+    a group at a time, and the others' attention output is zeros.  Returns
+    (x, cache, counts | None)."""
     from deepspeed_tpu.ops.pallas.decode import (dsa_decode_selected,
                                                  dsa_index_scores_paged,
                                                  fused_norm_qkv,
@@ -898,6 +959,9 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
     index, ring = cache.get("index"), cache.get("ring")
     stats = moe_counts_zero(cfg) if moe_live is not None else None
+    # the indexer's counts are the last of them, the linear layers' the
+    # last of the others
+    i_state = -2 if cfg.mla_index_topk else -1
     i_lin = i_lat = i_sw = 0
     for l, lp in enumerate(dparams["layers"]):
         y = fused_norm_qkv(x, lp["n1_scale"], None, lp["w_in"], None,
@@ -914,8 +978,8 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                 state, q, k, v, g, beta, layer=i_lin, live=moe_live,
                 impl=impl)
             if stats is not None:
-                stats = stats[:-1] + (stats[-1] + jnp.stack(
-                    [jnp.sum(moe_live, dtype=jnp.int32), visited]),)
+                stats = _counted(stats, i_state, jnp.stack(
+                    [jnp.sum(moe_live, dtype=jnp.int32), visited]))
             ctx = kda_out(cfg, lp, o, gate)
             i_lin += 1
         else:
@@ -963,11 +1027,15 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                     scores = dsa_index_scores_paged(
                         qi, wi, index, pos, page_table, layer=i_lat,
                         live=moe_live, impl=impl)
-                    sel, n_sel = select_positions(scores, pos, topk)
+                    sel, n_sel = select_positions(
+                        scores, pos if moe_live is None
+                        else jnp.where(moe_live, pos, -1), topk)
                     o = dsa_decode_selected(
                         mla_absorb(kd, lp, q), latent, sel, n_sel,
                         page_table, layer=i_lat, sm_scale=_mla_scale(kd),
-                        impl=impl)
+                        live=moe_live, impl=impl)
+                    if stats is not None and i_lat == 0:
+                        stats = _counted(stats, -1, selection_rows(moe_live))
                 else:
                     o = mla_decode_paged(mla_absorb(kd, lp, q), latent, pos,
                                          page_table, layer=i_lat,

@@ -300,6 +300,33 @@ def _live_rows(live, B: int):
             jnp.sum(live, dtype=jnp.int32))
 
 
+def live_groups(live, B: int, group: int):
+    """:func:`_live_rows` cut into groups for a loop whose trip count is read
+    at run time: (the rows live-first, the rows ``G = min(group, B)`` of a
+    group, ``ceil(live rows / G)`` groups that hold a live row).  Group ``i``
+    is ``rows[i G : (i + 1) G]`` (the last one of a batch that is no
+    multiple of ``G`` starts at ``B - G``: ``dynamic_slice`` clamps it, and
+    the rows it works twice come out the same); what pads the last group are
+    rows that do not decode."""
+    rows, n_live = _live_rows(live, B)
+    G = min(group, B)
+    return rows, G, (n_live + G - 1) // G
+
+
+def over_live_groups(live, B: int, group: int, work, out):
+    """``out`` [B, ...] with ``work(at)`` [G, ...] written at the rows ``at``
+    [G] of every group of :func:`live_groups` that holds a live row: the
+    loop, its trip count read at run time."""
+    rows, G, groups = live_groups(live, B, group)
+
+    def one(i, out):
+        at = jax.lax.dynamic_slice_in_dim(rows, i * G, G)
+        return out.at[at].set(work(at), unique_indices=True,
+                              mode="promise_in_bounds")
+
+    return jax.lax.fori_loop(0, groups, one, out)
+
+
 def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
                       nb, scale, alibi, impl, name, kernel=None):
     """The one ``pallas_call`` behind every cache layout.  The caches are
@@ -757,49 +784,86 @@ def selected_reference_reason(K: int, W: int) -> Optional[str]:
     return None
 
 
+# rows of one group of :func:`dsa_decode_selected`'s loop (one page lookup,
+# one gather and one kernel call a group).  One: at the cell's 16 slots of
+# 2,048 selected rows of 640 values an iteration costs 37.7 us a row alone,
+# 36.5 in pairs and 35 in fours (the parent's straight line over 16 rows:
+# 31.8), so a group's pad rows cost more than its size saves: 266 | 292 | 281
+# us a layer at 7 live rows, 302 | 293 | 282 at 8, 339 | 363 | 415 at 9 for
+# groups of 1 | 2 | 4 (my chip runs, PR 58, tools/dsa_select_bench.py; the
+# whole table: PERF.md section 5, ``dots3-note-L5-ep16.serve-doc-48k``)
+GATHER_GROUP = 1
+
+
 def dsa_decode_selected(q, cache, sel, n_sel, page_table, *, layer: int,
-                        sm_scale: float, impl: Optional[str] = None):
+                        sm_scale: float, live=None,
+                        impl: Optional[str] = None):
     """Decode attention of a latent layer in its absorbed form over the
     SELECTED rows: ``q`` [B, H, W] (``kda_mla.mla_absorb``), ``cache`` [L,
     P, 1, page, W] latent pages, ``sel`` [B, K] int32 the positions each row
     attends (best first; the first ``n_sel[b]`` are real), through
-    ``page_table``.  The rows are gathered out of the pool ([B, K, W], one
-    gather over the pool as a flat array of rows), then one grid step a
-    batch row scores them, takes the softmax and sums them.  Returns [B, H,
-    W] as :func:`mla_decode_paged` does."""
+    ``page_table``.  Returns [B, H, W] as :func:`mla_decode_paged` does.
+
+    The work follows the batch, not the slot count: ``live`` [B] bool names
+    the rows that decode (None: all of them), and a loop whose trip count is
+    read at run time takes them :data:`GATHER_GROUP` at a time, live rows
+    first (:func:`over_live_groups`).  One iteration looks up its rows' pages,
+    gathers their selected rows out of the pool ([G, K, W], one gather over
+    the pool as a flat array of rows) and runs the kernel on them, one grid
+    step a row (scores, one softmax, the weighted sum), then writes the G
+    outputs at those slots.  A row that does not decode costs no lookup, no
+    gather and no grid step unless it pads the last group, and its output is
+    zeros either way; no live row at all is a loop of no iterations."""
     impl = resolve_impl(impl)
     B, H, W = q.shape
     L, P, _, page, _ = cache.shape
     K = sel.shape[1]
-    # the page each position lies on, as a one-hot product with the row's
-    # table (exact in float32; a gather of B x K scalars through the table
-    # took 0.33 ms a layer a step on the v5e, as long as half the sort that
-    # made the positions: my chip run, PR 52)
-    phys = jnp.einsum(
-        "bkp,bp->bk", jax.nn.one_hot(sel // page, page_table.shape[1],
-                                     dtype=jnp.float32),
-        page_table.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST).astype(jnp.int32)
-    flat = (layer * P + phys) * page + sel % page
-    rows = cache.reshape(L * P * page, W).at[flat].get(
-        mode="promise_in_bounds")                                  # [B, K, W]
     impl = kernel_or_reference("dsa_decode_selected", impl,
                                selected_reference_reason(K, W))
-    if impl == "xla":
-        return _selected_ref(q.astype(rows.dtype), rows, n_sel,
-                             scale=sm_scale)
+    G = min(GATHER_GROUP, B)
+    pool = cache.reshape(L * P * page, W)
+    q, n_sel = q.astype(cache.dtype), n_sel.astype(jnp.int32)
+    table = page_table.astype(jnp.float32)
     by_row = lambda b, n_ref: (b, 0, 0)
-    return pl.pallas_call(
-        functools.partial(_selected_kernel, scale=sm_scale),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B,),
-            in_specs=[pl.BlockSpec((1, H, W), by_row),
-                      pl.BlockSpec((1, K, W), by_row)],
-            out_specs=pl.BlockSpec((1, H, W), by_row)),
-        out_shape=jax.ShapeDtypeStruct((B, H, W), q.dtype),
-        interpret=interpret_flag(impl),
-        name="dsa_decode_selected",
-    )(n_sel.astype(jnp.int32), q.astype(rows.dtype), rows)
+
+    def group(at):
+        take = lambda a: jnp.take(a, at, axis=0)
+        s, n = take(sel), take(n_sel)
+        on = None if live is None else take(live)
+        if on is not None:
+            # a row that pads the group: K different rows, not one row K
+            # times (2,048 reads of one address cost more than a live
+            # row's: my chip run, PR 58)
+            s = jnp.where(on[:, None], s, jnp.arange(K))
+        # the page each position lies on, as a one-hot product with the
+        # row's table (exact in float32; a gather of B x K scalars through
+        # the table took 0.33 ms a layer a step on the v5e, as long as half
+        # the sort that made the positions: my chip run, PR 52)
+        phys = jnp.einsum(
+            "bkp,bp->bk", jax.nn.one_hot(s // page, page_table.shape[1],
+                                         dtype=jnp.float32),
+            take(table), precision=jax.lax.Precision.HIGHEST
+        ).astype(jnp.int32)
+        flat = (layer * P + phys) * page + s % page
+        rows = pool.at[flat].get(mode="promise_in_bounds")      # [G, K, W]
+        if impl == "xla":
+            o = _selected_ref(take(q), rows, n, scale=sm_scale)
+        else:
+            o = pl.pallas_call(
+                functools.partial(_selected_kernel, scale=sm_scale),
+                grid_spec=pltpu.PrefetchScalarGridSpec(
+                    num_scalar_prefetch=1, grid=(G,),
+                    in_specs=[pl.BlockSpec((1, H, W), by_row),
+                              pl.BlockSpec((1, K, W), by_row)],
+                    out_specs=pl.BlockSpec((1, H, W), by_row)),
+                out_shape=jax.ShapeDtypeStruct((G, H, W), q.dtype),
+                interpret=interpret_flag(impl),
+                name="dsa_decode_selected",
+            )(n, take(q), rows)
+        return o if on is None else jnp.where(on[:, None, None], o, 0)
+
+    return over_live_groups(live, B, GATHER_GROUP, group,
+                            jnp.zeros((B, H, W), q.dtype))
 
 
 # heads of one grid step of :func:`kda_decode_step`: their k, decay and q

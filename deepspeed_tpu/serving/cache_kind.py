@@ -654,6 +654,16 @@ class IndexedLatentPagesAndRing(LatentPages):
         "ds_serve_dsa_chunk_keys_attended_total":
             "keys the real tokens of the prefill chunks attended in ONE "
             "indexed layer (min(t + 1, mla_index_topk))",
+        "ds_serve_dsa_rows_sorted_total":
+            "(row, step) pairs the decode steps' top-k sorted in ONE indexed "
+            "layer: the live rows in groups of kda_mla.SORT_GROUP, the rows "
+            "that pad a step's last group included (counted on the device)",
+        "ds_serve_dsa_rows_gathered_total":
+            "(row, step) pairs whose selected rows the decode steps looked "
+            "up, gathered and attended in ONE indexed layer: the live rows "
+            "in groups of ops/pallas/decode.py:GATHER_GROUP, padding "
+            "included; ds_serve_decode_tokens_total over it is how full "
+            "the groups are (dsa_select_rows_live_share in BENCHMARK.json)",
         "ds_serve_attn_window_rows_total":
             TwoBudgets.counters["ds_serve_attn_window_rows_total"],
     }
@@ -762,6 +772,16 @@ class IndexedLatentPagesAndRing(LatentPages):
         if cfg.sliding_window:
             m["ds_serve_attn_window_rows_total"].inc(
                 int(np.minimum(p, cfg.sliding_window).sum()))
+
+    def count_block(self, counts):
+        """``ds_serve_dsa_rows_*``: the (row, step) pairs the block's
+        selection sorted and gathered in one indexed layer (the last of
+        ``kda_mla.fused_layers``' counts, where the model has an indexer)."""
+        if self.cfg.mla_index_topk:
+            rows = counts.pop()
+            self._m["ds_serve_dsa_rows_sorted_total"].inc(int(rows[0]))
+            self._m["ds_serve_dsa_rows_gathered_total"].inc(int(rows[1]))
+        return counts
 
     def page_gauges(self, pool):
         """The three budgets: the rings of the slots that hold pages (as

@@ -430,3 +430,141 @@ def test_the_older_configurations_lower_to_the_parents_programs(name):
         text = re.sub(r"loc\(.*?\)", "", text)
         assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
             PARENT_PROGRAMS[name, what], (name, what)
+
+
+# ------------------------ the selection follows the rows that decode (PR 58)
+def _mask(*live):
+    m = np.zeros(16, bool)
+    m[list(live)] = True
+    return m
+
+
+# the groups are of 8 rows (the sort) and of 1 (the lookup, the gather and
+# the kernel): counts at, and one past, both
+LIVE_MASKS = {
+    "none": _mask(), "one_row": _mask(11), "two_rows": _mask(2, 14),
+    "five_rows": _mask(0, 3, 4, 10, 15),
+    "a_sort_group": _mask(0, 2, 3, 5, 8, 11, 12, 15),
+    "a_sort_group_and_one": _mask(1, 2, 4, 6, 7, 9, 10, 13, 14),
+    "every_other": _mask(*range(1, 16, 2)), "all": _mask(*range(16)),
+    "no_mask": None}
+
+
+@pytest.mark.parametrize("impl", ["xla", "interpret"])
+@pytest.mark.parametrize("gather_group", [1, 4])
+@pytest.mark.parametrize("mask", sorted(LIVE_MASKS))
+def test_the_selection_works_the_rows_that_decode(kernels, monkeypatch, mask,
+                                                  gather_group, impl):
+    """``select_positions`` (told the parked rows by a negative position)
+    and ``dsa_decode_selected(live=)`` in groups of live rows against ONE
+    ``jax.lax.top_k`` and ``_selected_ref`` over all sixteen rows: a live
+    row's positions and count, and its output BY SLOT (bit-equal on the
+    ``jnp`` path); a row that does not decode: no position, zeros.  The
+    second loop at its group of one row and at a group that pads."""
+    _, dec = kernels
+    assert (kda_mla.SORT_GROUP, dec.GATHER_GROUP) == (8, 1)
+    monkeypatch.setattr(dec, "GATHER_GROUP", gather_group)
+    from deepspeed_tpu.models.decoding import paged_logical_view
+
+    rng = np.random.default_rng(3)
+    f = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    B, page, cols, L, H, W, K = 16, 128, 4, 2, 4, 256, 128
+    Pg = B * cols + 1
+    live = LIVE_MASKS[mask]
+    on = np.ones(B, bool) if live is None else live
+    pt = jnp.asarray(rng.permutation(Pg - 1).reshape(B, cols) + 1, jnp.int32)
+    pos = rng.integers(K, page * cols, size=B)
+    pos[[0, 5, 11]] = [3, 127, 128]        # under, at and past the top-k
+    scores = np.where((np.arange(page * cols)[None] <= pos[:, None])
+                      & on[:, None], rng.normal(size=(B, page * cols)),
+                      afmoe.NEG_INF).astype(np.float32)
+    told = pos if live is None else np.where(live, pos, -1)
+    sel, n_sel = jax.jit(kda_mla.select_positions, static_argnums=2)(
+        jnp.asarray(scores), jnp.asarray(told, jnp.int32), K)
+    _, want = jax.lax.top_k(jnp.asarray(scores), K)
+    want_n = np.minimum(pos + 1, K)
+    sel, n_sel, want = np.asarray(sel), np.asarray(n_sel), np.asarray(want)
+    for b in range(B):
+        if on[b]:
+            assert n_sel[b] == want_n[b]
+            assert set(sel[b, :n_sel[b]]) == set(want[b, :want_n[b]]), b
+        else:
+            assert n_sel[b] == 0 and not sel[b].any()
+    lat, q = f(L, Pg, 1, page, W), f(B, H, W)
+    got = np.asarray(jax.jit(
+        lambda *a: dec.dsa_decode_selected(
+            *a, layer=1, sm_scale=0.07,
+            live=None if live is None else jnp.asarray(live), impl=impl))(
+        q, lat, jnp.asarray(sel), jnp.asarray(n_sel), pt))
+    rows = jnp.take_along_axis(paged_logical_view(lat[1], pt)[:, 0],
+                               jnp.asarray(want)[:, :, None], axis=1)
+    ref = np.asarray(dec._selected_ref(q, rows, jnp.asarray(want_n),
+                                       scale=0.07))
+    assert np.isfinite(got).all()
+    assert not got[~on].any()
+    if impl == "xla":
+        # the same rows in the same order through the same arithmetic
+        np.testing.assert_array_equal(sel[on], want[on])
+        np.testing.assert_array_equal(got[on], ref[on])
+    else:
+        np.testing.assert_allclose(got[on], ref[on], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("levels", [2, 5, 11])
+def test_a_tie_at_the_edge_of_the_top_k_is_cut_as_top_k_cuts_it(levels):
+    """Scores of few distinct values (four index heads give one key in
+    sixteen a score of exactly 0): every row of a group selects the SET
+    ``jax.lax.top_k`` selects on that row alone, whatever the group's other
+    rows hold."""
+    rng = np.random.default_rng(levels)
+    B, S, K = 16, 512, 64
+    scores = rng.integers(0, levels, size=(B, S)).astype(np.float32)
+    pos = rng.integers(K, S, size=B)
+    live = _mask(0, 1, 4, 6, 7, 9, 10, 12, 13, 15)
+    scores = np.where((np.arange(S)[None] <= pos[:, None]) & live[:, None],
+                      scores, afmoe.NEG_INF).astype(np.float32)
+    sel, n = kda_mla.select_positions(
+        jnp.asarray(scores), jnp.asarray(np.where(live, pos, -1), jnp.int32),
+        K)
+    cut = 0
+    for b in np.flatnonzero(live):
+        _, alone = jax.lax.top_k(jnp.asarray(scores[b]), K)
+        alone = np.asarray(alone)
+        # where the k-th score is tied the cut is by index order
+        kth = scores[b][alone[-1]]
+        cut += (scores[b] == kth).sum() > (scores[b][alone] == kth).sum()
+        assert int(n[b]) == K
+        assert set(np.asarray(sel)[b].tolist()) == set(alone.tolist()), b
+    assert cut >= 8
+
+
+@pytest.mark.parametrize("short", [3, 5])
+def test_the_selections_rows_are_counted_on_the_device(model, short):
+    """Four requests that reach their limit inside the first decode block
+    beside one that decodes for two blocks: ``ds_serve_dsa_rows_sorted_total``
+    and ``_gathered_total`` grow by every step's live rows rounded up to
+    whole groups (six slots: a sort group is the batch, a gather group one
+    row), ``ds_serve_decode_tokens_total`` by the live pairs."""
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+    from deepspeed_tpu.ops.pallas.decode import GATHER_GROUP
+
+    m, params = model
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(
+        m, config=dict(ENGINE, num_slots=6, max_prefill_chunks=8),
+        params=params, mesh=m.mesh, registry=reg)
+    rng = np.random.default_rng(short)
+    for new in (short, short, short, short, 9):
+        serve.submit(rng.integers(0, 96, 12), max_new_tokens=new)
+    serve.run()
+    snap = reg.snapshot()
+    # a request's first token is its last chunk's: ``new - 1`` decode steps
+    live = [5] * (short - 1) + [1] * (8 - (short - 1))
+    up = lambda n, G: -(-n // G) * G
+    Gs, Gg = min(kda_mla.SORT_GROUP, 6), min(GATHER_GROUP, 6)
+    assert snap["ds_serve_decode_tokens_total"] == sum(live)
+    assert snap["ds_serve_dsa_rows_sorted_total"] == sum(
+        up(n, Gs) for n in live) == 8 * 6
+    assert snap["ds_serve_dsa_rows_gathered_total"] == sum(
+        up(n, Gg) for n in live)
+    serve.close()
