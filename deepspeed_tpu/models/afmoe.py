@@ -69,8 +69,10 @@ KEY_BLOCK = 1024          # keys a step of :func:`attend`'s online softmax
 
 def form(cfg):
     """The module that holds a ``layer_types`` model's three forwards: this
-    one (sliding and global layers), or its sibling for linear-attention and
-    latent-attention layers, which calls this one's pieces."""
+    one (sliding and global layers), or its sibling for every pattern with a
+    linear-attention or a latent-attention layer in it (a global layer
+    beside linear ones is the sibling's per-head kind), which calls this
+    one's pieces."""
     if cfg.is_kda_mla:
         from deepspeed_tpu.models import kda_mla
         return kda_mla
@@ -78,7 +80,9 @@ def form(cfg):
     return sys.modules[__name__]
 
 
-CACHE_KEY = "k_full"      # the cache entry whose dtype the stream takes
+def cache_key(cfg) -> str:
+    """The cache entry whose dtype the stream takes."""
+    return "k_full"
 
 
 def is_sliding(cfg, l: int) -> bool:

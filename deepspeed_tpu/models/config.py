@@ -59,6 +59,15 @@ families instead; `ModelConfig` spans them with feature flags:
   ``sliding_window`` positions, a sigmoid gate a head (``mla_head_gate``)
   and the low-rank rescale (``mla_lora_rescale``); the same module;
   benchmarks/configs/dots3-note-L5-ep16.json.  SERVED ONLY likewise
+- Linear-attention layers beside per-head softmax layers (Solar-Open2):
+  ``linear_attention`` beside ``full_attention`` (NO position encoding,
+  ``num_heads`` query heads over ``num_kv_heads`` key-value heads of
+  ``head_dim``, per-head K and V rows in pages, ``attn_output_gate`` the
+  elementwise gate) in the same module, and a delta rule whose ``beta`` runs
+  to 2 (``kda_neg_eigval``: ``I - beta k k^T`` has its moving eigenvalue in
+  (-1, 1)); K/V pages in the full layers ONLY
+  (:attr:`ModelConfig.cache_layers` counts them) beside the slot state;
+  benchmarks/configs/solar-open2-L4-ep8.json.  SERVED ONLY likewise
 - A looped stack (Ouro): the Llama backbone's ``num_layers`` layers run
   ``total_ut_steps`` times with the SAME weights, the final norm closing
   every pass and feeding the next, a norm on both sides of each sub-block
@@ -173,6 +182,9 @@ class ModelConfig:
     kda_head_dim: int = 0
     kda_conv_kernel: int = 0
     kda_gate_rank: int = 0
+    # the delta rule's ``beta = 2 sigmoid(.)`` in (0, 2), so that ``I - beta k
+    # k^T`` may reflect (an eigenvalue in (-1, 1)); False: ``sigmoid(.)``
+    kda_neg_eigval: bool = False
     # latent: the cache row is ``mla_kv_rank`` normed values plus
     # ``mla_rot_dim`` shared key values; a query head is ``mla_nope_dim +
     # mla_rot_dim`` wide, a value head ``mla_v_dim``; ``num_heads`` query
@@ -353,17 +365,23 @@ class ModelConfig:
 
     def _check_afmoe(self):
         """The combinations the layer form is written for: sliding and
-        global layers (models/afmoe.py), or linear-attention and
-        latent-attention layers (models/kda_mla.py), not a mix of the two."""
+        global layers (models/afmoe.py); linear-attention and
+        latent-attention layers (models/kda_mla.py); or linear-attention
+        layers beside per-head ``full_attention`` layers (the same module:
+        :data:`_HYBRID_KINDS`, both of them present).  No other mix of the
+        two families: a sliding layer's ring beside a state, or latent rows
+        beside per-head K and V pages, is not built."""
         self.layer_types = tuple(self.layer_types)
         got = set(self.layer_types)
         if len(self.layer_types) != self.num_layers or not (
-                got <= _WINDOW_KINDS or got <= _STATE_KINDS):
+                got <= _WINDOW_KINDS or got <= _STATE_KINDS
+                or got == _HYBRID_KINDS):
             raise ValueError(
                 f"layer_types must name, for each of the {self.num_layers} "
-                f"layers, one of {sorted(_WINDOW_KINDS)} (models/afmoe.py) or "
-                f"one of {sorted(_STATE_KINDS)} (models/kda_mla.py), got "
-                f"{self.layer_types!r}")
+                f"layers, one of {sorted(_WINDOW_KINDS)} (models/afmoe.py), "
+                f"one of {sorted(_STATE_KINDS)} (models/kda_mla.py), or both "
+                f"of {sorted(_HYBRID_KINDS)} (models/kda_mla.py: a state a "
+                f"slot beside per-head K/V pages), got {self.layer_types!r}")
         self._check_kda_mla()
         if "sliding_attention" in self.layer_types and self.sliding_window < 1:
             raise ValueError("sliding_attention layers need sliding_window")
@@ -410,16 +428,17 @@ class ModelConfig:
         sizes = {k: getattr(self, k) for k in _KDA_MLA_ONLY}
         if not self.is_kda_mla:
             if any(sizes.values()) or any(
-                    getattr(self, k) for k in _MLA_FORMS):
+                    getattr(self, k) for k in _KDA_FORMS + _MLA_FORMS):
                 raise ValueError(
-                    f"{sorted(_KDA_MLA_ONLY + _MLA_FORMS)} belong to "
-                    "linear_attention and latent_attention layers "
+                    f"{sorted(_KDA_MLA_ONLY + _KDA_FORMS + _MLA_FORMS)} "
+                    "belong to linear_attention and latent_attention layers "
                     "(models/kda_mla.py)")
             return
+        # (a full_attention layer's sizes are the top-level head counts)
         used = {"linear_attention": "kda_", "latent_attention": "mla_",
                 "latent_sliding_attention": "mla_sliding"}
         need = [k for k in _KDA_MLA_ONLY if sizes[k] < 1 and any(
-            k.startswith(used[t]) for t in set(self.layer_types))]
+            k.startswith(used[t]) for t in used.keys() & set(self.layer_types))]
         if need:
             raise ValueError(
                 f"layer_types {self.layer_types!r} (models/kda_mla.py) "
@@ -432,14 +451,29 @@ class ModelConfig:
                     f"pairs of an even mla_rot_dim, got "
                     f"{sorted(self.mla_rope)}, mla_rot_dim={self.mla_rot_dim}")
         self._check_mla_kinds()
+        hybrid = "full_attention" in self.layer_types
+        if hybrid and (
+                any(v for k, v in sizes.items() if k.startswith("mla_"))
+                or any(getattr(self, k) for k in _MLA_FORMS)):
+            raise ValueError(
+                "full_attention beside linear_attention layers "
+                "(models/kda_mla.py) is per-head K and V: the mla_* sizes "
+                "and forms belong to latent layers, which are not built "
+                "beside it (latent rows and per-head pages under one table)")
+        if self.kda_neg_eigval and "linear_attention" not in self.layer_types:
+            raise ValueError("kda_neg_eigval is the linear_attention "
+                             "layers' beta = 2 sigmoid(.)")
         if self.sandwich_norm or self.qk_norm_per_head \
-                or self.attn_output_gate or self.embed_scale != 1.0 \
+                or (self.attn_output_gate and not hybrid) \
+                or self.embed_scale != 1.0 \
                 or (self.sliding_window and self.mla_sliding is None):
             raise ValueError(
                 "linear_attention and latent_attention layers "
                 "(models/kda_mla.py) are plain pre-norm: sandwich_norm, "
-                "qk_norm_per_head, attn_output_gate, embed_scale and "
-                "sliding_window belong to models/afmoe.py's two kinds")
+                "qk_norm_per_head, embed_scale and sliding_window belong to "
+                "models/afmoe.py's two kinds, and attn_output_gate to a "
+                "model with full_attention layers (models/afmoe.py's, or "
+                "those beside linear_attention layers)")
 
     def _check_mla_kinds(self):
         """The second latent kind's group and the indexer's sizes."""
@@ -530,7 +564,11 @@ class ModelConfig:
 
     @property
     def cache_layers(self) -> int:
-        """Layers of a K/V cache: one a (pass, layer) pair."""
+        """Layers of a K/V cache: one a (pass, layer) pair; beside
+        linear-attention layers (models/kda_mla.py) the per-head
+        ``full_attention`` layers alone keep K and V rows."""
+        if self.is_kda_mla:
+            return self.layer_types.count("full_attention")
         return self.total_ut_steps * self.num_layers
 
     @property
@@ -540,9 +578,10 @@ class ModelConfig:
     @property
     def is_kda_mla(self) -> bool:
         """A ``layer_types`` model of linear-attention and latent-attention
-        layers (models/kda_mla.py)."""
+        layers, or of linear-attention layers beside per-head
+        ``full_attention`` ones (models/kda_mla.py)."""
         return self.layer_types is not None and \
-            set(self.layer_types) <= _STATE_KINDS
+            bool(set(self.layer_types) & _STATE_KINDS)
 
     @property
     def num_expert_layers(self) -> int:
@@ -579,13 +618,17 @@ class MlaKind:
 _WINDOW_KINDS = frozenset({"sliding_attention", "full_attention"})
 _STATE_KINDS = frozenset({"linear_attention", "latent_attention",
                           "latent_sliding_attention"})
+# a state a slot beside per-head K/V pages: both kinds, nothing else
+_HYBRID_KINDS = frozenset({"linear_attention", "full_attention"})
 _MLA_SLIDING_KEYS = frozenset({"num_heads", "kv_rank", "nope_dim", "rot_dim",
                                "v_dim", "q_rank", "rope"})
 # fields only models/kda_mla.py reads
 _KDA_MLA_ONLY = ("kda_num_heads", "kda_head_dim", "kda_conv_kernel",
                  "kda_gate_rank", "mla_kv_rank", "mla_nope_dim",
                  "mla_rot_dim", "mla_v_dim")
-# ... and the forms of a latent layer that a model may leave at their zero
+# ... the forms of a linear layer and of a latent layer that a model may
+# leave at their zero
+_KDA_FORMS = ("kda_neg_eigval",)
 _MLA_FORMS = ("mla_q_rank", "mla_rope", "mla_sliding", "mla_index_heads",
               "mla_index_dim", "mla_index_topk", "mla_head_gate",
               "mla_lora_rescale")
@@ -601,7 +644,8 @@ _AFMOE_ONLY = frozenset({
     "qk_norm_per_head", "attn_output_gate", "embed_scale",
     "moe_score_func", "moe_route_scale", "moe_select_bias",
     "num_shared_experts", "moe_router_experts", "moe_first_expert",
-    "moe_n_group", "moe_topk_group", *_KDA_MLA_ONLY, *_MLA_FORMS})
+    "moe_n_group", "moe_topk_group", *_KDA_MLA_ONLY, *_KDA_FORMS,
+    *_MLA_FORMS})
 
 
 _PRESETS = {

@@ -341,7 +341,7 @@ def forward_with_cache(model, params, tokens, cache, start_pos,
         afmoe.refuse_parallel(cfg, mesh, "forward_with_cache")
         form = afmoe.form(cfg)      # afmoe itself, or models/kda_mla.py
         x = afmoe.embed(cfg, params["embed"]["tok"], tokens,
-                        cache[form.CACHE_KEY].dtype)
+                        cache[form.cache_key(cfg)].dtype)
         x, new_cache = form.cached_layers(
             cfg, params, x, cache, start_pos,
             s if valid_len is None else valid_len)

@@ -216,7 +216,7 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
                 "paged pool's two budgets (serving/paged_kv.py)")
         form = afmoe.form(cfg)      # afmoe itself, or models/kda_mla.py
         x = afmoe.embed(cfg, dparams["embed"]["tok"], tokens[:, 0],
-                        cache[form.CACHE_KEY].dtype)
+                        cache[form.cache_key(cfg)].dtype)
         x, new_cache, moe_stats = form.fused_layers(
             cfg, dparams, x, cache, pos, page_table, moe_live=moe_live,
             impl=impl)

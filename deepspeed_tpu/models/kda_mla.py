@@ -2,7 +2,9 @@
 both kinds, the latent one without a position encoding; skt A.X-K1: latent
 layers only, rotated, with a low-rank query; dots-studio dots3-note-prev:
 latent layers of TWO kinds, one under a learned selection of its keys, one
-under a window; ``ModelConfig.layer_types``): the layer form of
+under a window; upstage Solar-Open2: linear layers whose ``beta`` runs to 2
+beside PER-HEAD ``full_attention`` layers, gated, without a position
+encoding, over K/V pages; ``ModelConfig.layer_types``): the layer form of
 ``models/afmoe.py`` (a static pattern, leading dense layers, the sigmoid
 router over ONE CHIP'S SHARE of the experts) over further attention kinds,
 plain pre-norm.
@@ -19,6 +21,10 @@ N_in(x)``:
         q = l2norm(c_q) * d^-0.5;  k = l2norm(c_k);  v = c_v   per head
         g = -exp(a_log[head]) * softplus((h Wf_down) Wf_up + dt_bias)   <= 0
         beta = sigmoid(h Wb)                                   [H]
+            times 2 with ``kda_neg_eigval``: beta in (0, 2), so that ``I -
+            beta k k^T`` (k of unit length) has its moving eigenvalue in
+            (-1, 1) and a step may reflect the state along k, not only
+            shrink it
         per head, S [d, d] float32, S_0 = 0:
             S' = diag(exp(g_t)) S_{t-1}
             S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
@@ -41,6 +47,15 @@ N_in(x)``:
         cache row of token j: [c(j) | k_r(j)] (k_r as the scores use it:
             rotated where the model rotates), shared by all heads
 
+    "full_attention" beside linear layers (per-head softmax attention, H =
+    num_heads query heads over num_kv_heads key-value heads of head_dim, NO
+    position encoding, no head norms; ``models/afmoe.py``'s global layer
+    without its sandwich):
+        q, k, v, g = h Wq, h Wk, h Wv, h Wg
+        a = (softmax(q k^T / sqrt(head_dim)) v * sigmoid(g)) Wo   every j <= t;
+            the gate with ``attn_output_gate``, elementwise (afmoe.gated)
+        cache rows of token j: k(j), v(j) per key-value head, in pages
+
     A latent layer's sizes, head count and base are its KIND's
     (``ModelConfig.mla_kind(layer type)``, an ``MlaKind``), and a kind may add:
         rescale:  c_q and c times sqrt(hidden / rank) after their norms
@@ -55,7 +70,9 @@ N_in(x)``:
         a window ("latent_sliding_attention"): keys 0 <= t - j < window
 
 Each piece is ONE function here (:func:`short_conv`, :func:`kda_activate`,
-:func:`kda_step` / :func:`kda_chunk`, :func:`kda_out`; :func:`mla_project`,
+:func:`kda_step` / :func:`kda_chunk`, :func:`kda_out`; :func:`gqa_split`
+(``afmoe.attend`` / ``flash_decode`` and ``afmoe.gated`` do the rest);
+:func:`mla_project`,
 :func:`mla_query`, :func:`mla_row`, :func:`rotate`, :func:`mla_decompress`,
 :func:`mla_absorb` / :func:`mla_unabsorb`,
 :func:`head_gate`; :func:`index_key`, :func:`index_query`,
@@ -63,10 +80,14 @@ Each piece is ONE function here (:func:`short_conv`, :func:`kda_activate`,
 :func:`ring_after`, :func:`window_attention`, :func:`ring_decode`) and the
 three forwards call them, as ``afmoe.py``'s do; the expert block, the pattern
 loop's parameter stacks and the fused path's layer end are ``afmoe``'s own
-functions.  The attention parameters are two stacks by KIND (``params["kda"]``
-``[linear layers, ...]``, ``params["mla"]`` ``[latent layers, ...]``): the
-kinds interleave inside ``afmoe``'s two MLP stacks.  A model without linear
-layers has no ``kda`` stack, and its cache no ``state`` and no ``tail``.
+functions.  The attention parameters are stacks by KIND (``params["kda"]``
+``[linear layers, ...]``, ``params["mla"]`` ``[latent layers, ...]``,
+``params["gqa"]`` ``[per-head full layers, ...]``): the kinds interleave
+inside ``afmoe``'s two MLP stacks.  A model without linear layers has no
+``kda`` stack, and its cache no ``state`` and no ``tail``; a model without
+latent layers has no ``latent`` pages, and one with per-head full layers
+keeps ``k`` and ``v`` pages ``[full layers, pages, Hkv, page, Dh]`` for THOSE
+layers only (``serving/cache_kind.py:FullPagesAndState``).
 
 Cache (``serving/paged_kv.py``): a linear layer keeps, for each SLOT, its
 state ``[H, d, d]`` float32 and the convolution's tail, the last ``K - 1``
@@ -123,7 +144,11 @@ from deepspeed_tpu.ops.pallas.flash_attention import (
 SUB = 64                  # tokens a sub-chunk of the chunkwise delta rule
 HI = jax.lax.Precision.HIGHEST
 L2_EPS = 1e-6
-CACHE_KEY = "latent"      # the cache entry whose dtype the stream takes
+
+
+def cache_key(cfg) -> str:
+    """The cache entry whose dtype the stream takes."""
+    return "k" if full_layers(cfg) else "latent"
 
 
 def is_linear(cfg, l: int) -> bool:
@@ -136,6 +161,13 @@ def kind_layers(cfg):
     L = range(cfg.num_layers)
     return ([l for l in L if is_linear(cfg, l)],
             [l for l in L if cfg.layer_types[l] == "latent_attention"])
+
+
+def full_layers(cfg):
+    """Indices of the per-head "full_attention" layers (K and V rows in
+    pages)."""
+    return [l for l in range(cfg.num_layers)
+            if cfg.layer_types[l] == "full_attention"]
 
 
 def sliding_layers(cfg):
@@ -233,6 +265,15 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
                      * 0.1)
         return a
 
+    if full_layers(cfg):
+        # drawn after the linear stack, so that a model without them keeps
+        # its weights
+        L = len(full_layers(cfg))
+        M, Mkv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
+        params["gqa"] = {
+            "wq": uni((L, D, M), D), "wk": uni((L, D, Mkv), D),
+            "wv": uni((L, D, Mkv), D), "wo": uni((L, M, D), M),
+            **({"wg": uni((L, D, M), D)} if cfg.attn_output_gate else {})}
     kinds = [(cfg.mla_kind(t), len(ls)) for t, ls in (
         ("latent_attention", lat),
         ("latent_sliding_attention", sliding_layers(cfg))) if ls]
@@ -248,7 +289,8 @@ def layer_params(cfg, params, l: int):
     attention stack as ``attn``."""
     lp, le = afmoe.layer_params(cfg, params, l)
     t = cfg.layer_types[l]
-    kind = "kda" if is_linear(cfg, l) else cfg.mla_kind(t).stack
+    kind = ("kda" if is_linear(cfg, l) else
+            "gqa" if t == "full_attention" else cfg.mla_kind(t).stack)
     i = sum(cfg.layer_types[j] == t for j in range(l))
     return {**lp, "attn": jax.tree.map(lambda a: a[i], params[kind])}, le
 
@@ -283,7 +325,8 @@ def kda_activate(cfg, a, c, f_low, g_low, b_raw):
     """From the convolved rows ``c`` [..., 3 H d] float32 and the gates'
     low-rank rows to the recurrence's inputs, float32: q, k, v [..., H, d]
     (q and k l2-normed per head, q scaled), the log-decay g [..., H, d] <=
-    0, beta [..., H], and the output gate before its sigmoid [..., H d]."""
+    0, beta [..., H] (a sigmoid, times 2 with ``kda_neg_eigval``), and the
+    output gate before its sigmoid [..., H d]."""
     H, d = cfg.kda_num_heads, cfg.kda_head_dim
     lead = c.shape[:-1]
     q, k, v = (t.reshape(lead + (H, d)) for t in jnp.split(c, 3, axis=-1))
@@ -293,8 +336,9 @@ def kda_activate(cfg, a, c, f_low, g_low, b_raw):
         + a[b].astype(F32)
     f = up(f_low, "wf_up", "dt_bias").reshape(lead + (H, d))
     g = -jnp.exp(a["a_log"].astype(F32))[:, None] * jax.nn.softplus(f)
+    beta = lambda: jax.nn.sigmoid(b_raw[..., :H].astype(F32))
     return (l2(q) * d ** -0.5, l2(k), v, g,
-            jax.nn.sigmoid(b_raw[..., :H].astype(F32)),
+            2.0 * beta() if cfg.kda_neg_eigval else beta(),
             up(g_low, "wg_up", "b_g"))
 
 
@@ -376,6 +420,26 @@ def kda_out(cfg, a, o, gate):
     dtype (the input of ``wo``)."""
     y = rms(o, a["o_norm"], cfg.norm_eps).reshape(gate.shape)
     return (y * jax.nn.sigmoid(gate)).astype(a["wo"].dtype)
+
+
+# ----------------------------------------------------------------------
+# the per-head full-attention piece (beside linear layers)
+# ----------------------------------------------------------------------
+GQA_IN = ("wq", "wk", "wv", "wg")     # a full layer's projections of ``h``
+
+
+def gqa_split(cfg, y):
+    """The projections ``y`` [..., H Dh + 2 Hkv Dh (+ H Dh)] of a per-head
+    full layer (``q | k | v | gate``, :data:`GQA_IN`'s order) as q [..., H,
+    Dh], k, v [..., Hkv, Dh] and the gate's logits [..., H Dh] | None.  No
+    rotation and no head norm: the layer has no position encoding."""
+    H, Hkv, Dh = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+    M, Mkv = H * Dh, Hkv * Dh
+    heads = lambda t, n: t.reshape(t.shape[:-1] + (n, Dh))
+    return (heads(y[..., :M], H), heads(y[..., M:M + Mkv], Hkv),
+            heads(y[..., M + Mkv:M + 2 * Mkv], Hkv),
+            y[..., M + 2 * Mkv:2 * M + 2 * Mkv]
+            if cfg.attn_output_gate else None)
 
 
 # ----------------------------------------------------------------------
@@ -739,8 +803,10 @@ def apply_layers(cfg, params, x, mesh=None):
 
     def one(xb):
         xb = jnp.pad(xb, ((0, pad), (0, 0)))[None]
-        cache = {"latent": jnp.zeros(
-            (len(lat), 1, 1, S + pad, row_width(cfg)), x.dtype)}
+        cache = {}
+        if lat:
+            cache["latent"] = jnp.zeros(
+                (len(lat), 1, 1, S + pad, row_width(cfg)), x.dtype)
         if lin:
             cache.update(state=jnp.zeros(state, F32),
                          tail=jnp.zeros(tail, x.dtype))
@@ -752,9 +818,14 @@ def apply_layers(cfg, params, x, mesh=None):
 
 def _empty_extras(cfg, positions: int, dtype):
     """The cache entries beside ``latent`` that one sequence of
-    ``positions`` rows needs where the model has an indexer (``index``) or
-    sliding latent layers (``ring``): zeros."""
+    ``positions`` rows needs where the model has an indexer (``index``),
+    sliding latent layers (``ring``) or per-head full layers (``k``, ``v``):
+    zeros."""
     out = {}
+    if full_layers(cfg):
+        kv = jnp.zeros((len(full_layers(cfg)), 1, cfg.num_kv_heads,
+                        positions, cfg.head_dim), dtype)
+        out.update(k=kv, v=kv)
     kd = cfg.mla_kind("latent_attention") if kind_layers(cfg)[1] else None
     if kd is not None and kd.index:
         out["index"] = jnp.zeros((len(kind_layers(cfg)[1]), 1, 1, positions,
@@ -771,9 +842,11 @@ def cached_layers(cfg, params, x, cache, start, valid_len,
     """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
     over ONE slot's views (``latent`` [latent layers, 1, 1, positions,
     row_width]; with linear layers also ``state`` [linear layers, 1, H, d, d]
-    float32 and ``tail`` [linear layers, 1, K - 1, 3 H d]: what
-    ``cache_kind.LatentPages.view`` / ``LatentPagesAndState.view`` slice
-    out); only the first ``valid_len`` rows are real.  A chunk at position 0
+    float32 and ``tail`` [linear layers, 1, K - 1, 3 H d]; with per-head
+    full layers ``k`` and ``v`` [full layers, 1, Hkv, positions, Dh] in
+    ``latent``'s place: what ``cache_kind.LatentPages.view`` /
+    ``LatentPagesAndState.view`` / ``FullPagesAndState.view`` slice out);
+    only the first ``valid_len`` rows are real.  A chunk at position 0
     starts from a zero state whatever the slot held (the state's reset at
     admission).  ``impl``: the latent layers' chunk attention
     (``ops/pallas/common.py``'s three names; None: by the device).  Returns
@@ -785,9 +858,10 @@ def cached_layers(cfg, params, x, cache, start, valid_len,
     real = jnp.arange(s) < valid_len
     latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
     index, ring = cache.get("index"), cache.get("ring")
+    k_full, v_full = cache.get("k"), cache.get("v")
     kept = (start != 0)
     experts = afmoe._experts(params)
-    i_lin = i_lat = i_sw = 0
+    i_lin = i_lat = i_sw = i_full = 0
     for l in range(cfg.num_layers):
         lp, le = layer_params(cfg, params, l)
         a = lp["attn"]
@@ -807,6 +881,24 @@ def cached_layers(cfg, params, x, cache, start, valid_len,
             tail = tail.at[i_lin].set(t1)
             ctx = kda_out(cfg, a, o[None], gate)
             i_lin += 1
+        elif cfg.layer_types[l] == "full_attention":
+            # per-head keys and values, unrotated: the chunk's rows join the
+            # slot's view, and the queries attend every row up to their own
+            q, k, v, g = gqa_split(cfg, jnp.concatenate(
+                [h @ a[n].astype(h.dtype) for n in GQA_IN if n in a], -1))
+            heads = lambda t: t.transpose(0, 2, 1, 3)
+            at = (i_full, 0, 0, start, 0)
+            k_full = jax.lax.dynamic_update_slice(
+                k_full, heads(k)[None].astype(k_full.dtype), at)
+            v_full = jax.lax.dynamic_update_slice(
+                v_full, heads(v)[None].astype(v_full.dtype), at)
+            o = afmoe.attend(
+                heads(q), [(k_full[i_full], v_full[i_full],
+                            jnp.arange(k_full.shape[3]))],
+                pos, window=0, scale=cfg.head_dim ** -0.5,
+                live_keys=start + s)
+            ctx = afmoe.gated(cfg, heads(o).reshape(B, s, -1), g)
+            i_full += 1
         else:
             kd = cfg.mla_kind(cfg.layer_types[l])
             q, row, cq = mla_project(kd, a, h, pos[None])
@@ -861,19 +953,13 @@ def cached_layers(cfg, params, x, cache, start, valid_len,
                             if kd.gate else None, (B, s))
         x = afmoe.mlp_block(cfg, lp, x, ctx @ a["wo"].astype(ctx.dtype),
                             None if le is None else experts, le)
-    return x, _views(latent, state, tail, index, ring)
+    return x, _views(latent=latent, state=state, tail=tail, index=index,
+                     ring=ring, k=k_full, v=v_full)
 
 
-def _views(latent, state, tail, index=None, ring=None):
-    """The cache a forward hands back: what it was given."""
-    out = {"latent": latent}
-    if state is not None:
-        out.update(state=state, tail=tail)
-    if index is not None:
-        out["index"] = index
-    if ring is not None:
-        out["ring"] = ring
-    return out
+def _views(**entries):
+    """The cache a forward hands back: the entries it was given."""
+    return {k: v for k, v in entries.items() if v is not None}
 
 
 # ----------------------------------------------------------------------
@@ -896,7 +982,8 @@ def _w_in_names(kd):
 def inject(cfg, params) -> Dict[str, Any]:
     """The kernel-injected view (``afmoe.inject``'s shape): per-layer dicts,
     every projection of ``h`` in one ``[D, N]`` matrix ``w_in`` (linear: q |
-    k | v | decay gate down | output gate down | beta; latent: q | [c_raw |
+    k | v | decay gate down | output gate down | beta; per-head full: q | k |
+    v | gate; latent: q | [c_raw |
     k_r], or, with a low-rank query, Wqa | [c_raw | k_r] with ``wqb`` and
     ``q_norm`` beside it; then the head gate's and the indexer's key and
     head-weight columns where the kind has them), the small per-kind arrays
@@ -906,9 +993,11 @@ def inject(cfg, params) -> Dict[str, Any]:
     for l in range(cfg.num_layers):
         lp, le = layer_params(cfg, params, l)
         a = lp["attn"]
+        t = cfg.layer_types[l]
         names = (("wq", "wk", "wv", "wf_down", "wg_down", "wb")
                  if is_linear(cfg, l) else
-                 _w_in_names(cfg.mla_kind(cfg.layer_types[l])))
+                 tuple(n for n in GQA_IN if n in a)
+                 if t == "full_attention" else _w_in_names(cfg.mla_kind(t)))
         d = {k: v for k, v in a.items() if k not in names}
         d["w_in"] = _pad_cols(jnp.concatenate([a[k] for k in names], axis=-1))
         layers.append({**d, **afmoe.inject_rest(cfg, lp, le)})
@@ -938,9 +1027,11 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     positions ``pos`` [B]; ``cache``: ``latent`` [latent layers, pages, 1,
     page, row_width] through ``page_table`` [B, columns] and, with linear
     layers, ``state`` [linear layers, B, H, d, d] and ``tail`` [linear
-    layers, B, K - 1, 3 H d] by row (a row of the batch is a slot).
+    layers, B, K - 1, 3 H d] by row (a row of the batch is a slot); with
+    per-head full layers ``k`` and ``v`` [full layers, pages, Hkv, page, Dh]
+    through the same table in ``latent``'s place.
     ``moe_live`` [B] bool: the rows that decode; only their state, tail and
-    latent pages move, and the kernels visit only them.  In an indexed layer
+    pages move, and the kernels visit only them.  In an indexed layer
     it governs the selection between the two kernels too: only the rows that
     decode are sorted (:func:`select_positions`, which is told by a negative
     position), looked up, gathered and attended (``dsa_decode_selected``),
@@ -948,9 +1039,11 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     (x, cache, counts | None)."""
     from deepspeed_tpu.ops.pallas.decode import (dsa_decode_selected,
                                                  dsa_index_scores_paged,
+                                                 flash_decode,
                                                  fused_norm_qkv,
                                                  kda_decode_step,
                                                  mla_decode_paged,
+                                                 paged_kv_append,
                                                  paged_row_append)
 
     B = x.shape[0]
@@ -958,11 +1051,12 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     C3, r = 3 * Hk * d, cfg.kda_gate_rank
     latent, state, tail = (cache.get(k) for k in ("latent", "state", "tail"))
     index, ring = cache.get("index"), cache.get("ring")
+    k_full, v_full = cache.get("k"), cache.get("v")
     stats = moe_counts_zero(cfg) if moe_live is not None else None
     # the indexer's counts are the last of them, the linear layers' the
     # last of the others
     i_state = -2 if cfg.mla_index_topk else -1
-    i_lin = i_lat = i_sw = 0
+    i_lin = i_lat = i_sw = i_full = 0
     for l, lp in enumerate(dparams["layers"]):
         y = fused_norm_qkv(x, lp["n1_scale"], None, lp["w_in"], None,
                            kind="rmsnorm", eps=cfg.norm_eps, impl=impl)
@@ -982,6 +1076,18 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
                     [jnp.sum(moe_live, dtype=jnp.int32), visited]))
             ctx = kda_out(cfg, lp, o, gate)
             i_lin += 1
+        elif cfg.layer_types[l] == "full_attention":
+            # the row's K and V join its pages, then the paged kernel: every
+            # position up to its own, a key-value head serving its group
+            q, k, v, g = gqa_split(cfg, y)
+            k_full, v_full = paged_kv_append(k_full, v_full, k, v, pos,
+                                             page_table, layer=i_full,
+                                             impl=impl)
+            o = flash_decode(q, k_full, v_full, pos,
+                             sm_scale=cfg.head_dim ** -0.5, layer=i_full,
+                             page_table=page_table, live=moe_live, impl=impl)
+            ctx = afmoe.gated(cfg, o.reshape(B, -1), g)
+            i_full += 1
         else:
             kd = cfg.mla_kind(cfg.layer_types[l])
             # the query's columns of ``w_in``: the heads, or the bottleneck
@@ -1045,4 +1151,5 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
             ctx = head_gate(kd, mla_unabsorb(kd, lp, o), gate, (B,))
         x, stats = afmoe.fused_close(cfg, dparams, lp, l, ctx, x, stats,
                                      moe_live, impl)
-    return x, _views(latent, state, tail, index, ring), stats
+    return x, _views(latent=latent, state=state, tail=tail, index=index,
+                     ring=ring, k=k_full, v=v_full), stats

@@ -61,13 +61,24 @@ NEG_INF = -1e30
 # VMEM weight-tile budget per grid step (bytes). ~6MB leaves room for the
 # double-buffered next tile + activations inside the ~16MB/core VMEM.
 _TILE_BYTES = 6 * 2**20
+# what a kernel's grid step may hold in VMEM in all (the compiler's scoped
+# limit of 16 MB less 1.25 MB for output tiles, small operands and its own
+# stack): two weight tiles and the ``resident`` activations
+_VMEM_STEP_BYTES = 16 * 2**20 - 5 * 2**18
 
 
-def _col_block(d_in: int, n_cols: int, itemsize: int = 2) -> int:
+def _col_block(d_in: int, n_cols: int, itemsize: int = 2,
+               resident: int = 0) -> int:
     """Largest 128-multiple block of the tiled weight dim (``n_cols`` long,
     ``d_in`` elements across) with d_in*block*itemsize under the tile
-    budget, and dividing n_cols (falls back to n_cols for small ops)."""
-    cap = max(128, _TILE_BYTES // max(1, d_in * itemsize) // 128 * 128)
+    budget, and dividing n_cols (falls back to n_cols for small ops).
+    ``resident``: bytes of activations the kernel keeps in VMEM beside the
+    double-buffered tile (whole-row blocks and scratch); the tile budget
+    gives way where they leave less than two full tiles (128 rows of 4,096:
+    3 MB, and the tile of a [4096, 18432] projection goes from 768 columns
+    to 512; under 2.75 MB nothing changes)."""
+    budget = min(_TILE_BYTES, (_VMEM_STEP_BYTES - resident) // 2)
+    cap = max(128, budget // max(1, d_in * itemsize) // 128 * 128)
     if n_cols <= cap:
         return n_cols
     for b in range(cap, 127, -128):
@@ -162,7 +173,10 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
     cd = x.dtype if quant else wqkv.dtype
     # quant sizing counts the in-kernel fp32 dequant intermediate, not the
     # int8 payload — a payload-sized block would overflow VMEM at 1B+ scale
-    bn = _col_block(D, N, 4 if quant else wqkv.dtype.itemsize)
+    # beside the tile: the rows (double-buffered) and their normed copy
+    bn = _col_block(D, N, 4 if quant else wqkv.dtype.itemsize,
+                    resident=B * D * (2 * x.dtype.itemsize
+                                      + jnp.dtype(cd).itemsize))
     has_bias = bqkv is not None
     bq = (bqkv if has_bias else jnp.zeros((N,), cd)).reshape(1, N)
     ws = (wscale if quant else jnp.ones((N,), jnp.float32)).reshape(1, N)

@@ -16,6 +16,11 @@ attention form, and :func:`cache_kind` is the one place that decides it
   ``models/kda_mla.py``): one row a position that all heads share;
 - :class:`LatentPagesAndState` (linear-attention layers beside them): the
   same pages and a recurrent state a slot;
+- :class:`FullPagesAndState` (linear-attention layers beside per-head
+  ``full_attention`` layers): :class:`FullPages`' K and V arrays in the full
+  layers ONLY (``cfg.cache_layers`` counts them, so a token costs those
+  layers' bytes) and the same state a slot, under :class:`SlotState`'s
+  budget;
 - :class:`IndexedLatentPagesAndRing` (latent layers that attend a learned
   selection of their keys, beside sliding latent layers of other sizes): a
   latent row AND an index key a position under one page table, and a ring of
@@ -152,10 +157,11 @@ class FullPages:
     def view(self, cache, pt_row, slot, start, cb: int):
         """The slot's rows as the contiguous cache ``forward_with_cache``
         takes for a chunk of ``cb`` rows at ``start``: its pages sliced out
-        of the pool one by one (:func:`_slot_view`); scalars pass."""
+        of the pool one by one (:func:`_slot_view`); what is no array of
+        pages passes."""
         cols = range(pt_row.shape[0])
-        return {k: (_slot_view(v, pt_row, cols) if v.ndim == 5 else v)
-                for k, v in cache.items()}
+        return {k: (_slot_view(v, pt_row, cols) if k in self.page_arrays
+                    else v) for k, v in cache.items()}
 
     def write_back(self, cache, sub, pt_row, slot, start, cb: int):
         """``sub``, the view as the forward left it, back in place in the
@@ -166,7 +172,7 @@ class FullPages:
         page."""
         cols = range(pt_row.shape[0])
         return {k: (_slot_write_back(cache[k], sub[k], pt_row, 0, cols)
-                    if cache[k].ndim == 5 else sub[k]) for k in cache}
+                    if k in self.page_arrays else sub[k]) for k in cache}
 
     # -- counters ------------------------------------------------------
     def attach(self, registry, pool) -> None:
@@ -520,36 +526,16 @@ class LatentPages(FullPages):
         self._m["ds_serve_mla_rows_written_total"].inc(layers * c)
 
 
-class LatentPagesAndState(LatentPages):
-    """Linear-attention layers beside latent-attention layers: the latent
-    pages, and per SLOT a float32 recurrent ``state`` and a convolution
-    ``tail``, never allocated or freed (a request's first chunk program
-    reads zeros)."""
+class SlotState:
+    """A float32 recurrent ``state`` and a convolution ``tail`` per SLOT for
+    the linear-attention layers, beside whatever pages the class after this
+    one in a kind's bases keeps: never allocated or freed (a request's first
+    chunk program reads zeros), sliced out by the slot's index for a chunk
+    and put back in place."""
 
-    what = "linear_attention / latent_attention layers"
-    cannot = {
-        "handoff": "serving/handoff.py ships pages as the K and V of a token "
-                   "prefix, and a recurrent state is not a page",
-        "kv_host_tier_pages":
-            "serving/host_tier.py demotes and promotes pages for the prefix "
-            "cache, which is off for this model (a state is not "
-            "position-pure)",
-        "prefix_caching":
-            "serving/prefix_cache.py shares pages as a function of the token "
-            "prefix, and a recurrent state is a slot's, not a page's (the "
-            "latent pages alone are position-pure, and shared they would "
-            "still not be per-head K and V arrays: LatentPages)",
-        "quantize_kv_cache":
-            "the int8 cache of models/decoding.py scales per-head K and V "
-            "rows; a latent row and a float32 state have no int8 form",
-        "use_fused_decode":
-            "the decode step over latent pages and slot state is built on "
-            "the fused path only (models/kda_mla.py:fused_layers)",
-    }
     # the row steps are counted by the decode-block program itself (the
     # live mask and the state kernel's grid) and fetched with its tokens
-    counters = {
-        **LatentPages.counters,
+    state_counters = {
         "ds_serve_state_row_steps_total":
             "(live row, linear-attention layer, decode step) triples: the "
             "state updates the decode steps really made",
@@ -577,7 +563,7 @@ class LatentPagesAndState(LatentPages):
                 + f", and {pool.state_bytes} bytes of slot state")
 
     def view(self, cache, pt_row, slot, start, cb):
-        """The latent pages, and the slot's state and tail sliced out by its
+        """The pages' view, and the slot's state and tail sliced out by its
         index (the chunk carries them through and starts from zeros at
         position 0)."""
         own = lambda v: jax.lax.dynamic_slice_in_dim(v, slot, 1, axis=1)
@@ -585,8 +571,8 @@ class LatentPagesAndState(LatentPages):
                 "state": own(cache["state"]), "tail": own(cache["tail"])}
 
     def write_back(self, cache, sub, pt_row, slot, start, cb):
-        """The latent pages as :class:`LatentPages` puts them back; state
-        and tail in place at the slot's index."""
+        """The pages as the kind puts them back; state and tail in place at
+        the slot's index."""
         put = lambda k: jax.lax.dynamic_update_slice_in_dim(
             cache[k], sub[k], slot, axis=1)
         return {**super().write_back(cache, sub, pt_row, slot, start, cb),
@@ -607,6 +593,101 @@ class LatentPagesAndState(LatentPages):
         self._m["ds_serve_state_row_steps_total"].inc(int(steps[0]))
         self._m["ds_serve_state_row_steps_visited_total"].inc(int(steps[1]))
         return counts
+
+
+class LatentPagesAndState(SlotState, LatentPages):
+    """Linear-attention layers beside latent-attention layers: the latent
+    pages, and :class:`SlotState`'s state and tail a slot."""
+
+    what = "linear_attention / latent_attention layers"
+    cannot = {
+        "handoff": "serving/handoff.py ships pages as the K and V of a token "
+                   "prefix, and a recurrent state is not a page",
+        "kv_host_tier_pages":
+            "serving/host_tier.py demotes and promotes pages for the prefix "
+            "cache, which is off for this model (a state is not "
+            "position-pure)",
+        "prefix_caching":
+            "serving/prefix_cache.py shares pages as a function of the token "
+            "prefix, and a recurrent state is a slot's, not a page's (the "
+            "latent pages alone are position-pure, and shared they would "
+            "still not be per-head K and V arrays: LatentPages)",
+        "quantize_kv_cache":
+            "the int8 cache of models/decoding.py scales per-head K and V "
+            "rows; a latent row and a float32 state have no int8 form",
+        "use_fused_decode":
+            "the decode step over latent pages and slot state is built on "
+            "the fused path only (models/kda_mla.py:fused_layers)",
+    }
+    counters = {**LatentPages.counters, **SlotState.state_counters}
+
+
+class FullPagesAndState(SlotState, FullPages):
+    """Linear-attention layers beside per-head ``full_attention`` layers
+    (``models/kda_mla.py``): ``k`` and ``v`` pages ``[full layers, pages,
+    Hkv, page, Dh]`` for the full layers alone (``cfg.cache_layers``), one
+    budget and one table column a page as under :class:`FullPages`, and
+    :class:`SlotState`'s state and tail a slot.  The pages are position-pure
+    but the state is not, so what rests on a page being a function of the
+    token prefix is off or refused until a state can be snapshot at a page
+    boundary (ROADMAP R5 / R7)."""
+
+    what = "linear_attention / full_attention layers"
+    _state = ("a recurrent state is a slot's, not a page's: the K/V pages of "
+              "the full layers alone do not restore a request (state "
+              "snapshots at page boundaries: ROADMAP R5 / R7)")
+    cannot = {
+        "handoff": "serving/handoff.py ships pages as the K and V of a token "
+                   "prefix in every layer; " + _state,
+        "kv_host_tier_pages":
+            "serving/host_tier.py demotes and promotes pages for the prefix "
+            "cache, which is off for this model; " + _state,
+        "prefix_caching":
+            "serving/prefix_cache.py shares pages as a function of the token "
+            "prefix; " + _state,
+        "quantize_kv_cache":
+            "the int8 cache of models/decoding.py is read by the Llama "
+            "backbone's decode step, and the fused path of this model "
+            "(models/kda_mla.py:fused_layers) reads no int8 rows; a float32 "
+            "state has no int8 form",
+        "use_fused_decode":
+            "the decode step over K/V pages and slot state is built on the "
+            "fused path only (models/kda_mla.py:fused_layers)",
+    }
+    counters = {
+        **SlotState.state_counters,
+        "ds_serve_full_kv_rows_read_total":
+            "(live row, full_attention layer, decode step) context rows the "
+            "decode attention kernel was asked to read, K and V counted "
+            "once: pos + 1 a row a step in each full layer of a model whose "
+            "other layers keep a state",
+    }
+    pages_by_kind = True
+    # the chunkwise recurrence works sub-chunks of ``SUB`` rows, and under
+    # one sub-chunk a chunk program's time is its weights' stream whatever
+    # its rows: a smaller bucket saves no work and costs a compile (three of
+    # eight chunk programs, 7 s each cold at Solar-Open2's widths)
+    chunk_rows = kda_mla.SUB
+
+    def layout(self, pool, num_slots):
+        return (super().layout(pool, num_slots)
+                + f" (pages in {self.cfg.cache_layers} of "
+                  f"{self.cfg.num_layers} layers)")
+
+    def count_rows(self, pos, n):
+        """``ds_serve_full_kv_rows_read_total``: ``p + 1`` rows a step in
+        each full layer."""
+        if not self._reg.enabled:
+            return
+        p = np.arange(pos, pos + n) + 1
+        self._m["ds_serve_full_kv_rows_read_total"].inc(
+            int(p.sum()) * self.cfg.cache_layers)
+
+    def page_gauges(self, pool):
+        """Every page is a full layer's (the state is no page:
+        ``ds_serve_state_bytes``)."""
+        if self._reg.enabled:
+            self._pages_kind["full"].set(pool.pages_used)
 
 
 class IndexedLatentPagesAndRing(LatentPages):
@@ -798,7 +879,7 @@ class IndexedLatentPagesAndRing(LatentPages):
 
 
 KINDS = (FullPages, WindowSummaryPages, TwoBudgets, LatentPages,
-         LatentPagesAndState, IndexedLatentPagesAndRing)
+         LatentPagesAndState, IndexedLatentPagesAndRing, FullPagesAndState)
 
 
 def cache_kind(cfg) -> FullPages:
@@ -808,6 +889,8 @@ def cache_kind(cfg) -> FullPages:
     if getattr(cfg, "is_kda_mla", False):
         if cfg.mla_index_topk or kda_mla.sliding_layers(cfg):
             return IndexedLatentPagesAndRing(cfg)
+        if kda_mla.full_layers(cfg):
+            return FullPagesAndState(cfg)
         return (LatentPagesAndState if kda_mla.kind_layers(cfg)[0]
                 else LatentPages)(cfg)
     if getattr(cfg, "is_afmoe", False):

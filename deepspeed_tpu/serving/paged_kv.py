@@ -98,7 +98,12 @@ kind of budget as a state (``ring`` ``[sliding layers, slots, ring rows, the
 kind's row width]``, ``ring rows`` the whole pages that hold a window: the
 row of position p at ``p % ring rows``, attended where the position it
 holds lies in the window; never reset, because a row is read only under the
-position it was written for).  ``ensure`` /
+position it was written for).  Beside PER-HEAD ``full_attention`` layers
+(``cache_kind.FullPagesAndState``) the same state budget lies beside the K
+and V arrays of the first paragraph, held for the full layers ALONE (``k`` /
+``v`` ``[full layers, pages, Hkv, page, Dh]``, ``cfg.cache_layers`` of them:
+a token costs those layers' rows and no others, 4 KB where one layer in four
+is a full one at 8 key-value heads of 128).  ``ensure`` /
 ``release`` / ``check_no_leak`` keep their meaning, for pages.
 
 Physical **page 0 is reserved as the junk page**: it is never allocated,
@@ -159,8 +164,9 @@ def init_paged_kv_cache(cfg, num_pages: int, page_tokens: int,
     :func:`~deepspeed_tpu.models.decoding.init_kv_cache`, with the slot
     dim replaced by the page dim and the sequence dim by the page depth:
     one budget of per-head K and V pages in every cache layer (a looped
-    stack has one a (pass, layer) pair, ``cfg.cache_layers``, all under the
-    one page table).  The arrays of the other kinds of cache are their
+    stack has one a (pass, layer) pair, a model whose other layers keep a
+    state has the full layers only: ``cfg.cache_layers``, all under the one
+    page table).  The arrays of the other kinds of cache are their
     kinds' (``serving/cache_kind.py``)."""
     L, Hkv, Dh = cfg.cache_layers, cfg.num_kv_heads, cfg.head_dim
     if quantized:

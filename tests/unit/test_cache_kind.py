@@ -1,11 +1,12 @@
-"""The contract of ``serving/cache_kind.py``, once over the six kinds of
+"""The contract of ``serving/cache_kind.py``, once over the seven kinds of
 slot cache at the tiny sizes their model tests build: full pages (a tiny
 Llama; and a LOOPED stack, two layers run three times with post-norms,
 whose pool is the same kind over ``cache_layers`` = 6 layers), window +
 summary pages (``test_evabyte``), two page budgets
 (``test_trinity``), latent pages alone (``test_axk1``), latent pages + slot
 state (``test_kimi_linear``), latent pages + index keys + slot rings
-(``test_dots3_note``).
+(``test_dots3_note``), K/V pages in the full layers only + slot state
+(``test_solar_open2``).
 
 What every kind owes the engine: a slot's view written back unchanged leaves
 the pool as it was, and a changed one touches nobody else's pages; an
@@ -22,6 +23,7 @@ import deepspeed_tpu
 from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, causal_lm
 from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages,
+                                              FullPagesAndState,
                                               IndexedLatentPagesAndRing,
                                               LatentPages,
                                               LatentPagesAndState, TwoBudgets,
@@ -29,7 +31,7 @@ from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages,
 from deepspeed_tpu.serving.paged_kv import PagedKVPool
 
 from . import (test_axk1, test_dots3_note, test_evabyte, test_kimi_linear,
-               test_trinity)
+               test_solar_open2, test_trinity)
 
 ENGINE = dict(num_slots=3, prefill_chunk=16, max_prefill_chunks=2,
               decode_block_tokens=4, max_out_tokens=96, kv_page_tokens=8,
@@ -44,6 +46,8 @@ CASES = {
               test_kimi_linear.ENGINE),
     "indexed": (IndexedLatentPagesAndRing, test_dots3_note.FIELDS,
                 test_dots3_note.ENGINE),
+    "hybrid": (FullPagesAndState, test_solar_open2.FIELDS,
+               test_solar_open2.ENGINE),
     # no kind of its own: full pages, one layer a (pass, layer) pair
     "looped": (FullPages, dict(
         vocab_size=96, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -252,7 +256,7 @@ def test_prefill_only_is_refused_with_the_kinds_reason(built, name):
 
 
 @pytest.mark.parametrize("name", ["two_budgets", "latent", "state",
-                                  "indexed"])
+                                  "indexed", "hybrid"])
 def test_the_decode_role_is_refused_with_the_kinds_reason(built, name):
     with pytest.raises(NotImplementedError) as err:
         serve_of(built, name, **ASKED["handoff"])

@@ -1,8 +1,9 @@
 """Which chunk programs a serve engine is ever asked for
 (``ServingEngine.chunk_bucket``), over the six kinds of slot cache at the
 tiny sizes of ``test_cache_kind``: the buckets' floor follows the kind
-(``chunk_rows``: the kernels' 128-query tile under the three latent kinds, 8
-under the others), a last chunk of a few real rows in a bucket of 128 is
+(``chunk_rows``: the kernels' 128-query tile under the three latent kinds, the
+recurrence's 64-row sub-chunk under a state beside per-head pages, 8 under
+the others), a last chunk of a few real rows in a bucket of 128 is
 served the tokens of the unchunked reference, a bucket's first call is its
 only compile, and ``ds_serve_prefill_pad_rows_total`` counts what the floor
 adds."""
@@ -43,6 +44,8 @@ def test_the_floor_is_the_kernels_tile_and_is_written_once():
     assert "chunk_rows" not in vars(ck.IndexedLatentPagesAndRing)
     assert {k.chunk_rows for k in (ck.FullPages, ck.WindowSummaryPages,
                                    ck.TwoBudgets)} == {8}
+    from deepspeed_tpu.models import kda_mla
+    assert ck.FullPagesAndState.chunk_rows == kda_mla.SUB == 64
 
 
 @pytest.mark.parametrize("name,chunk", [
@@ -58,7 +61,8 @@ def test_the_buckets_asked_for_over_every_chunk_length(built, name, chunk):
                                        max_out_tokens=max(96, 2 * chunk))
     serve = serve_of(built, name, **kw)
     chunk = serve.prefill_chunk
-    floor = max(8, min(128 if name in LATENT else 8, chunk))
+    floor = max(8, min(128 if name in LATENT else
+                       64 if name == "hybrid" else 8, chunk))
     got = {serve.chunk_bucket(c, 0) for c in range(1, chunk + 1)}
     assert got == powers(floor, max(chunk, 8)) and min(got) == floor
     assert floor <= max(chunk, 8)

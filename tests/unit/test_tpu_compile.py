@@ -807,6 +807,52 @@ def test_kimi_linear_cell_programs_compile_with_state_and_pool_in_place(
         assert name in text, name
 
 
+def test_solar_open2_cell_programs_compile_with_state_and_pages_in_place(
+        v5e, chip_kernels):
+    """ISSUE 59: the chunk programs (buckets 1,024 and 64) and the decode
+    block of the ``solar-open2-L4-ep8.serve-reason-4k`` cell (the published
+    widths: 64 KDA heads of 128, 64 query heads over 8 key-value heads of
+    128, experts of 1,280; the pattern cut to [full | linear], which changes
+    no shape; the pool cut to 16 slots' worth but all 128 SLOTS kept: 128 rows
+    of 4,096 beside a [4096, 18432] projection's tiles are what overflowed
+    ``fused_norm_qkv``'s VMEM on the chip before ``_col_block(resident=)``)
+    compile for the v5e: the K/V
+    pages of the ONE full layer, the recurrent state and the convolution
+    tails stay where they are, every kernel the decode block calls is the
+    Pallas one at these sizes (no reference fallback: ``kda_decode_step`` at
+    64 heads, ``flash_decode_paged`` and ``paged_kv_append`` over a pool
+    that holds the full layers only, ``fused_moe_mlp`` over 40 experts of
+    width 1,280), and a chunk's grouped matmuls take the held-share pad."""
+    cell = _ServeCell(
+        v5e, "solar-open2-L4-ep8", "solar-open2-L4-ep8.serve-reason-4k",
+        fields=dict(num_layers=2, layer_types=["full_attention",
+                                               "linear_attention"]),
+        engine=dict(kv_pool_tokens=16 * 4864))
+    cache = cell.serve._cache
+    assert cache["k"].shape == (1, 16 * 19 + 1, 8, 256, 128)
+    assert cache["state"].shape == (1, 128, 64, 128, 128)
+    cell.smallest_pool = cache["k"].nbytes         # the tails are smaller
+    shape = lambda k: ",".join(str(d) for d in cache[k].shape)
+    chunks = (cell.chunk(1024), cell.chunk(64))
+    for program, tokens in zip(chunks, (1024, 64)):
+        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+            program, tokens * 8)
+    for program in chunks + (cell.block(),):
+        cell.assert_pools_stay_in_place(program)
+        for kind, key in (("f32", "state"), ("bf16", "tail")):
+            assert not re.findall(rf"{kind}\[{shape(key)}\]\S* copy\(",
+                                  program.as_text()), key
+        mem = program.memory_analysis()
+        print("memory", mem.temp_size_in_bytes, mem.argument_size_in_bytes,
+              mem.output_size_in_bytes, mem.alias_size_in_bytes)
+    text = program.as_text()
+    for name in ("kda_decode_step", "flash_decode_paged", "paged_kv_append",
+                 "fused_norm_qkv", "fused_proj_norm", "fused_mlp",
+                 "fused_moe_mlp"):
+        assert name in text, name
+    assert "mla_decode_paged" not in text
+
+
 def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
         v5e, chip_kernels):
     """ISSUE 48: the chunk programs (buckets 1,024 and 64) and the decode
