@@ -273,17 +273,24 @@ def _softmax_finish(o_ref, l_scr, acc_scr):
     o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
-def _softmax_block(s, v_ref, m_scr, l_scr, acc_scr):
+def _softmax_block(s, v_ref, m_scr, l_scr, acc_scr, keep=None):
     """One key block of the online softmax: masked scores ``s`` [hb, rep,
     block] and the block's values into the running max, sum and weighted
-    values."""
+    values.  ``keep`` [1, block, 1] bool: the value rows that were fetched
+    and attended; the others are taken as 0, whatever the buffer holds
+    there (their weight is exactly 0, but ``0 x NaN`` is not)."""
     m_prev = m_scr[:]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     alpha = jnp.exp(m_prev - m_new)
     l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+
+    def values():      # read where the parent's programs read them
+        v = v_ref[0].astype(jnp.float32)
+        return v if keep is None else jnp.where(keep, v, 0.0)
+
     acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-        p, v_ref[0].astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+        p, values(), (((2,), (1,)), ((0,), (0,))),
         preferred_element_type=jnp.float32)
     m_scr[:] = m_new
 
@@ -341,6 +348,15 @@ def over_live_groups(live, B: int, group: int, work, out):
     return jax.lax.fori_loop(0, groups, one, out)
 
 
+def _alibi_slopes(H: int, hkv: int, alibi: bool):
+    """The heads' ALiBi slopes [Hkv, rep, 1] float32 (zeros without)."""
+    if not alibi:
+        return jnp.zeros((hkv, H // hkv, 1), jnp.float32)
+    from deepspeed_tpu.models.layers import alibi_slopes
+
+    return alibi_slopes(H).reshape(hkv, H // hkv, 1)
+
+
 def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
                       nb, scale, alibi, impl, name, kernel=None):
     """The one ``pallas_call`` behind every cache layout.  The caches are
@@ -369,12 +385,7 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
     hkv = view[1]
     rep = H // hkv
     hb = _kv_heads_per_step(hkv, block, Dh, kcache.dtype.itemsize)
-    if alibi:
-        from deepspeed_tpu.models.layers import alibi_slopes
-
-        slopes = alibi_slopes(H).reshape(hkv, rep, 1)
-    else:
-        slopes = jnp.zeros((hkv, rep, 1), jnp.float32)
+    slopes = _alibi_slopes(H, hkv, alibi)
     depth = pos // block + 1
     rows, n_live = _live_rows(live, B)
     if live is not None:
@@ -521,28 +532,150 @@ def _paged_append(pools, new_rows, pos, page_table, layer, impl):
     return tuple(o.reshape(c.shape) for o, c in zip(out, pools))
 
 
+# tokens one copy of a live row's LAST page fetches: that page comes in
+# ``(pos % page) // FETCH_ROWS + 1`` copies where every page before it comes in
+# one, so a row fetches 32 rows it does not attend on average, not half a page.
+# ``serving/cache_kind.py`` counts the fetched keys by this rule
+FETCH_ROWS = 64
+
+
+def walks_pages(head_dim: int) -> bool:
+    """Whether :func:`_flash_decode_paged` walks a row's pages inside one grid
+    step with copies of its own: where a row fills the 128 lanes.  Rows
+    under the lane tile (GPT-2's 64) lie padded in HBM, a copy issued in the
+    kernel can slice no such pool, and they keep a page a grid step
+    (:func:`_decode_attention`)."""
+    return head_dim % 128 == 0
+
+
+def paged_keys_fetched(pos, page: int, head_dim: int):
+    """Keys a decode step at ``pos`` (an int or an array of them) brings
+    into VMEM for one KV head of one cache layer: the pages before the
+    row's last whole, the last in pieces of :data:`FETCH_ROWS` up to ``pos``
+    where the kernel walks the pages, whole where it does not."""
+    if not walks_pages(head_dim):
+        return (pos // page + 1) * page
+    return pos // page * page + (pos % page // FETCH_ROWS + 1) * FETCH_ROWS
+
+
+def _flash_decode_paged_kernel(rows_ref, pos_ref, base_ref, pt_ref, q_ref,
+                               k_hbm, v_hbm, slope_ref, o_ref, m_scr, l_scr,
+                               acc_scr, k_buf, v_buf, sems, slot_ref, *,
+                               scale, alibi):
+    """One grid step = one LIVE batch row x ``hb`` KV heads, the row's pages
+    walked inside it (:func:`_flash_decode_paged`).  ``k_hbm`` / ``v_hbm``
+    are the pools where they lie, ``k_buf`` / ``v_buf`` [2, hb, page, Dh] the
+    two pages in VMEM, ``sems`` [K | V, slot] their copies' semaphores,
+    ``base_ref`` [1] the layer's first page in the pools and ``slot_ref`` the
+    slot the step's first page is on its way to (set by the step before it,
+    which started that copy)."""
+    i, g = pl.program_id(0), pl.program_id(1)
+    _, hb, page, _ = k_buf.shape
+    groups = k_hbm.shape[1] // hb
+    whole = page // FETCH_ROWS
+    b = rows_ref[i]
+    pos = pos_ref[b]
+    last = pos // page
+
+    def copies(b, g, j, slot, rows):
+        at = base_ref[0] + pt_ref[b, j]
+        return [pltpu.make_async_copy(pool.at[at, pl.ds(g * hb, hb), rows],
+                                      buf.at[slot, :, rows], sems.at[n, slot])
+                for n, (pool, buf) in enumerate(((k_hbm, k_buf),
+                                                 (v_hbm, v_buf)))]
+
+    def page_copies(b, g, j, slot, act):
+        """``act`` ("start" or "wait") each copy of row ``b``'s page ``j``:
+        one of the page, or of its last page the pieces up to ``pos``."""
+        p = pos_ref[b]
+        n = (p % page) // FETCH_ROWS + 1
+        n = jnp.where((j < p // page) | (n == whole), 0, n)
+
+        @pl.when(n == 0)
+        def _whole():
+            for c in copies(b, g, j, slot, slice(None)):
+                getattr(c, act)()
+
+        def piece(c, carry):
+            at = pl.multiple_of(c * FETCH_ROWS, FETCH_ROWS)
+            for d in copies(b, g, j, slot, pl.ds(at, FETCH_ROWS)):
+                getattr(d, act)()
+            return carry
+
+        jax.lax.fori_loop(0, n, piece, 0)
+
+    @pl.when((i == 0) & (g == 0))
+    def _first():
+        slot_ref[0] = 0
+        page_copies(b, g, 0, 0, "start")
+
+    slot0 = slot_ref[0]
+    _softmax_init(m_scr, l_scr, acc_scr)
+    # what follows this step's last page: the next grid step's first
+    g1 = (g + 1) % groups
+    i1 = jnp.where(g1 == 0, i + 1, i)
+    more = i1 < pl.num_programs(0)
+    b1 = rows_ref[jnp.where(more, i1, i)]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1,) + v_buf.shape[2:], 1)
+
+    def one_page(j, carry):
+        slot = (slot0 + j) % 2
+        inside = j < last
+
+        # the page after this one leaves before this one is scored
+        @pl.when(inside | more)
+        def _next():
+            page_copies(jnp.where(inside, b, b1), jnp.where(inside, g, g1),
+                        jnp.where(inside, j + 1, 0), 1 - slot, "start")
+
+        page_copies(b, g, j, slot, "wait")
+        q, k = q_ref[0], k_buf[slot]                # [hb, rep | page, Dh]
+        if q.dtype != k.dtype:
+            q, k = q.astype(jnp.float32), k.astype(jnp.float32)
+        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
+                                preferred_element_type=jnp.float32) * scale
+        key_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        if alibi:
+            s = s + slope_ref[:] * (key_pos - pos).astype(jnp.float32)
+        s = jnp.where(key_pos <= pos, s, NEG_INF)   # [hb, rep, page]
+        # rows of the buffer past ``pos`` hold what the pool holds there, or
+        # an earlier page: they weigh exactly 0, and their values count as 0
+        _softmax_block(s, v_buf.at[pl.ds(slot, 1)], m_scr, l_scr, acc_scr,
+                       j * page + rows <= pos)
+        return carry
+
+    jax.lax.fori_loop(0, last + 1, one_page, 0)
+    slot_ref[0] = (slot0 + last + 1) % 2
+    _softmax_finish(o_ref, l_scr, acc_scr)
+
+
 def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
                         layer, alibi: bool, live, impl: str):
     """Decode attention over the PAGED pool (``serving/paged_kv.py``):
     caches [P, Hkv, page, Dh] (or stacked [L, P, Hkv, page, Dh] with
     ``layer=l``), ``page_table`` [B, maxp] int32 naming each row's
     physical page per logical block.  One grid step is one LIVE batch row
-    x ``hb`` KV heads x one logical page (``grid=(live rows, Hkv // hb,
-    the deepest live row's pages)``, :func:`_decode_attention`;
+    x ``hb`` KV heads (``grid=(live rows, Hkv // hb)``, the first extent
+    read at run time as :func:`_decode_attention`'s;
     :func:`_kv_heads_per_step` sizes ``hb`` from the shapes, all of ``Hkv``
-    at GQA widths): a physical page holds its KV heads contiguously, so
-    the step's K and V blocks are ``[hb, page, Dh]`` slabs of one page.
-    The block index map indirects through the scalar-prefetched table
-    (``pt_ref[row, min(j, pos // page)]``), so each fetch lands on the
-    right physical page and — exactly as in the contiguous kernel —
-    pages past each row's ``pos`` are neither fetched nor computed: a
-    shallower row's steps up to the deepest live row's last page still
-    cost their grid step, a row that does not decode costs nothing and
-    pages past every live row are no grid steps.  The XLA path gathers
+    at GQA widths), and the step WALKS the row's ``pos // page + 1`` pages
+    itself: the pools stay in HBM, a physical page holds its KV heads
+    contiguously, so a page's K and V are ``[hb, page, Dh]`` slabs that the
+    step copies into one of two VMEM buffers each, page ``j + 1`` on its way
+    while page ``j`` is scored, the next grid step's first page while this
+    one's last is.  A page before the row's last is one copy; the last is
+    ``(pos % page) // FETCH_ROWS + 1`` copies of :data:`FETCH_ROWS` tokens,
+    so what a row fetches past ``pos`` is under one piece.  Pages past a
+    row's ``pos`` are neither fetched nor computed, a shallower row costs
+    nothing for a deeper one's pages, a row that does not decode costs
+    nothing and gets its ``q`` back.  The XLA path gathers
     the logical per-slot view and runs the dense reference (CPU tests, and
-    the page sizes :func:`paged_decode_reference_reason` names).  A traced
-    ``layer`` (:func:`paged_kv_append`) is added to the prefetched table's
-    page numbers."""
+    the page sizes :func:`paged_decode_reference_reason` names).  The
+    layer's offset in the pools, a Python int or a traced scalar
+    (:func:`paged_kv_append`), is prefetched with the table
+    (:func:`_walk_pages`); at a head dim under the lane tile
+    (:func:`walks_pages`) a traced one is added to the table's page
+    numbers."""
     kc = kcache if layer is None else kcache[layer]
     vc = vcache if layer is None else vcache[layer]
     page = kc.shape[2]
@@ -556,17 +689,69 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
                                  scale=scale, alibi=alibi)
     base = 0 if layer is None else layer * kc.shape[0]
     page_table = page_table.astype(jnp.int32)
-    if not isinstance(base, int):
-        page_table, base = page_table + base, 0
+    B, H, Dh = q.shape
+    if not walks_pages(Dh):
+        if not isinstance(base, int):
+            page_table, base = page_table + base, 0
 
-    def page_map(b, g, j, pos_ref, pt_ref):
-        jl = jnp.minimum(j, pos_ref[b] // page)     # per-row DMA clamp
-        return base + pt_ref[b, jl], g, 0, 0
+        def page_map(b, g, j, pos_ref, pt_ref):
+            jl = jnp.minimum(j, pos_ref[b] // page)     # per-row DMA clamp
+            return base + pt_ref[b, jl], g, 0, 0
 
-    return _decode_attention(
-        q, kcache, vcache, pos, (page_table,), page_map,
-        live=live, block=page, nb=page_table.shape[1], scale=scale,
-        alibi=alibi, impl=impl, name="flash_decode_paged")
+        return _decode_attention(
+            q, kcache, vcache, pos, (page_table,), page_map,
+            live=live, block=page, nb=page_table.shape[1], scale=scale,
+            alibi=alibi, impl=impl, name="flash_decode_paged")
+    view = (-1,) + kc.shape[-3:]
+    hkv = view[1]
+    rows, n_live = _live_rows(live, B)
+    o = _walk_pages(
+        rows, jnp.asarray(n_live, jnp.int32), pos,
+        jnp.reshape(base, (1,)).astype(jnp.int32), page_table,
+        q.reshape(B, hkv, H // hkv, Dh), kcache.reshape(view),
+        vcache.reshape(view), _alibi_slopes(H, hkv, alibi), scale=scale,
+        alibi=alibi, impl=impl)
+    return o.reshape(B, H, Dh)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "alibi", "impl"))
+def _walk_pages(rows, n_live, pos, base, page_table, q, kcache, vcache,
+                slopes, *, scale, alibi, impl):
+    """:func:`_flash_decode_paged`'s ``pallas_call``: ``q`` [B, Hkv, rep,
+    Dh], the pools [N, Hkv, page, Dh], ``base`` [1] the first page of the
+    layer read.  A function of its own under ``jit`` with the layer's
+    offset an OPERAND, so the calls of a model's layers are one traced and
+    lowered kernel, not one a layer: the decode block of a twelve-layer
+    stack is traced and lowered in the time of one call's."""
+    B, hkv, rep, Dh = q.shape
+    page = kcache.shape[2]
+    hb = _kv_heads_per_step(hkv, page, Dh, kcache.dtype.itemsize)
+    heads = pl.BlockSpec((1, hb, rep, Dh),
+                         lambda i, g, rows_ref, *_: (rows_ref[i], g, 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, hb, page, Dh), kcache.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_live, hkv // hb),
+        in_specs=[heads, pool, pool,
+                  pl.BlockSpec((hb, rep, 1), lambda i, g, *_: (g, 0, 0))],
+        out_specs=heads,
+        scratch_shapes=[pltpu.VMEM((hb, rep, 1), jnp.float32),
+                        pltpu.VMEM((hb, rep, 1), jnp.float32),
+                        pltpu.VMEM((hb, rep, Dh), jnp.float32),
+                        buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_flash_decode_paged_kernel, scale=scale,
+                          alibi=alibi),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        # operands count the scalar-prefetch arrays: q follows them
+        input_output_aliases={4: 0},
+        interpret=interpret_flag(impl),
+        name="flash_decode_paged",
+    )(rows, pos, base, page_table, q, kcache, vcache, slopes)
 
 
 def flash_decode(q, kcache, vcache, pos, *, sm_scale: Optional[float] = None,

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models import afmoe, kda_mla
+from deepspeed_tpu.ops.pallas.decode import paged_keys_fetched
 from deepspeed_tpu.ops.pallas.flash_attention import (_LANES,
                                                       eva_chunk_schedule,
                                                       mla_chunk_schedule)
@@ -98,7 +99,18 @@ class FullPages:
     # ``quantize_kv_cache``, ``use_fused_decode`` (False), all refused by
     # name; ``prefix_caching``, which is turned off with the reason logged
     cannot: Dict[str, str] = {}
-    counters: Dict[str, str] = {}   # the series this kind moves
+    # the series this kind moves
+    counters: Dict[str, str] = {
+        "ds_serve_attn_keys_attended_total":
+            "(live row, decode step) keys the decode attention kernel over "
+            "per-head K/V pages under one page table attends in one KV head "
+            "of one cache layer: pos + 1 a row a step",
+        "ds_serve_attn_keys_fetched_total":
+            "(live row, decode step) keys that kernel brings into VMEM for "
+            "them: a row's pages before its last whole, the last in pieces "
+            "of ops/pallas/decode.py:FETCH_ROWS tokens up to pos "
+            "(paged_keys_fetched; whole at head dims under the lane tile)",
+    }
     takes_valid_len = False         # the chunk's forward is told its real rows
     pages_by_kind = False           # ds_serve_kv_pages_used_by_kind moves
     # the query rows the chunk's attention pads a bucket to anyway: the
@@ -197,6 +209,7 @@ class FullPages:
                 "pages' index keys)", labels={"kind": kind})
             for kind in ("window", "summary", "full", "index")}
         self._reg = registry
+        self._page = pool.page
         self._m = {name: registry.counter(name) for name in self.counters}
 
     def cache_gauges(self, cache) -> None:
@@ -225,6 +238,17 @@ class FullPages:
 
     def count_rows(self, pos: int, n: int) -> None:
         """A row of a decode block was scheduled ``n`` steps from ``pos``."""
+        self._count_keys(pos, n)
+
+    def _count_keys(self, pos: int, n: int) -> None:
+        """``ds_serve_attn_keys_*``: what ``n`` steps from ``pos`` attend,
+        and what ``flash_decode_paged`` fetches for it by its own rule."""
+        if not self._reg.enabled:
+            return
+        p = np.arange(pos, pos + n)
+        self._m["ds_serve_attn_keys_attended_total"].inc(int((p + 1).sum()))
+        self._m["ds_serve_attn_keys_fetched_total"].inc(int(
+            paged_keys_fetched(p, self._page, self.cfg.head_dim).sum()))
 
     def count_iteration(self, pool) -> None:
         """A scheduler iteration ended."""
@@ -525,6 +549,10 @@ class LatentPages(FullPages):
                 itemsize=cache["latent"].dtype.itemsize)["visited"])
         self._m["ds_serve_mla_rows_written_total"].inc(layers * c)
 
+    def count_rows(self, pos, n):
+        """Nothing: the rows ``mla_decode_paged`` attends are taken from the
+        benchmark loop's marks, and its pages are no per-head K/V pages."""
+
 
 class SlotState:
     """A float32 recurrent ``state`` and a convolution ``tail`` per SLOT for
@@ -656,6 +684,7 @@ class FullPagesAndState(SlotState, FullPages):
     }
     counters = {
         **SlotState.state_counters,
+        **FullPages.counters,
         "ds_serve_full_kv_rows_read_total":
             "(live row, full_attention layer, decode step) context rows the "
             "decode attention kernel was asked to read, K and V counted "
@@ -682,6 +711,7 @@ class FullPagesAndState(SlotState, FullPages):
         p = np.arange(pos, pos + n) + 1
         self._m["ds_serve_full_kv_rows_read_total"].inc(
             int(p.sum()) * self.cfg.cache_layers)
+        self._count_keys(pos, n)
 
     def page_gauges(self, pool):
         """Every page is a full layer's (the state is no page:

@@ -363,6 +363,54 @@ def test_the_indexed_kinds_counters_follow_the_positions(built):
     serve.close()
 
 
+@pytest.mark.parametrize("name", NAMES)
+def test_the_keys_a_paged_decode_step_fetches_are_counted_by_its_rule(built,
+                                                                      name):
+    """``ds_serve_attn_keys_*``: the kinds whose pages are per-head K and V
+    rows under ONE table (full pages, a looped stack's, the hybrid's full
+    layers) count ``pos + 1`` keys a step attended and what the kernel's own
+    rule fetches for them (``ops/pallas/decode.py:paged_keys_fetched``; head
+    dim 16 here, so whole pages: a page a grid step); every other kind
+    registers the pair and leaves it still."""
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+    from deepspeed_tpu.ops.pallas.decode import paged_keys_fetched
+
+    model, params = built(name)
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(
+        model, config=dict(CASES[name][2]), params=params, mesh=model.mesh,
+        registry=reg)
+    prompt, n_out = 21, 10
+    serve.submit(np.arange(prompt) % 96, max_new_tokens=n_out)
+    serve.run()
+    attended = reg.get("ds_serve_attn_keys_attended_total").value
+    fetched = reg.get("ds_serve_attn_keys_fetched_total").value
+    if name in ("full", "looped", "hybrid"):
+        p = np.arange(prompt, prompt + n_out - 1)
+        page, dh = serve.pool.page, model.config.head_dim
+        assert attended == (p + 1).sum()
+        assert fetched == paged_keys_fetched(p, page, dh).sum() \
+            == ((p // page + 1) * page).sum()
+    else:
+        assert attended == fetched == 0
+    serve.close()
+
+
+@pytest.mark.parametrize("pos,want", [
+    (0, 64), (63, 64), (64, 128), (255, 256), (256, 320), (300, 320),
+    (767, 768), (1000, 1024)])
+def test_the_fetch_rule_at_a_head_dim_that_fills_the_lanes(pos, want):
+    """Pages of 256 walked inside a grid step: the pages before the last
+    whole, the last in pieces of 64 up to ``pos``; under the lane tile a
+    whole page whatever ``pos``."""
+    from deepspeed_tpu.ops.pallas.decode import FETCH_ROWS, paged_keys_fetched
+
+    assert FETCH_ROWS == 64
+    assert paged_keys_fetched(pos, 256, 128) == want
+    assert paged_keys_fetched(np.asarray([pos]), 256, 128)[0] == want
+    assert paged_keys_fetched(pos, 256, 64) == (pos // 256 + 1) * 256
+
+
 @pytest.mark.parametrize("fields,words", [
     (dict(mla_sliding=None), "latent_sliding_attention layers and the group"),
     (dict(mla_sliding=dict(test_dots3_note.SLIDING, kv_rank=0)),

@@ -83,17 +83,21 @@ def _paged_from_logical(k, v, maxp, page, seed=7):
 
 # (Hkv, rep, Dh) of the serving configurations: the benchmark's GQA cell,
 # gpt2-xl (25 heads: five per grid step by the VMEM rule) and gpt2-small
-PAGED_WIDTHS = [(8, 4, 128), (25, 1, 64), (12, 1, 64)]
+# and Ouro's / OLMoE's MHA 16 x 128 (float32 here: eight KV heads a grid step,
+# so a head-group axis of two).  Head dim 128 walks a row's pages inside a
+# grid step, head dim 64 keeps a page a grid step (``decode.walks_pages``)
+PAGED_WIDTHS = [(8, 4, 128), (25, 1, 64), (12, 1, 64), (16, 1, 128)]
 
 
+@pytest.mark.parametrize("Dh", [64, 128])
 @pytest.mark.parametrize("pos", [[5, 300], [255, 256], [767, 0]])
 @pytest.mark.parametrize("alibi", [False, True])
-def test_flash_decode_paged_matches_logical(pos, alibi):
-    """The page-table-indirected index map must reproduce the contiguous
-    kernel exactly: a shuffled physical page assignment with per-row
-    positions (and per-row DMA clamps) against the dense reference over
-    the logical view."""
-    B, Hkv, rep, Dh, page, maxp = 2, 2, 2, 64, 256, 3
+def test_flash_decode_paged_matches_logical(pos, alibi, Dh):
+    """The page-table-indirected index map (head dim 64) and the pages a
+    grid step walks through the table itself (128) must reproduce the
+    contiguous kernel exactly: a shuffled physical page assignment with
+    per-row positions against the dense reference over the logical view."""
+    B, Hkv, rep, page, maxp = 2, 2, 2, 256, 3
     H = Hkv * rep
     q = _rand(0, B, H, Dh)
     k = _rand(1, B, Hkv, maxp * page, Dh)
@@ -155,10 +159,11 @@ def _stacked_pools(B, Hkv, Dh, page, maxp, L, seed=3):
             jnp.stack([p[1] for p in pools]), pt)
 
 
-def test_flash_decode_paged_layer_stacked():
+@pytest.mark.parametrize("Dh", [64, 128])
+def test_flash_decode_paged_layer_stacked(Dh):
     """decode_step reads the stacked [L, P, Hkv, page, Dh] pool at a
     static layer offset through the index map — no slice materializes."""
-    B, Hkv, Dh, page, maxp, L = 2, 2, 64, 256, 2, 2
+    B, Hkv, page, maxp, L = 2, 2, 256, 2, 2
     ks, vs, kp_all, vp_all, pt = _stacked_pools(B, Hkv, Dh, page, maxp, L)
     q = _rand(0, B, Hkv, Dh)
     posv = jnp.asarray([300, 511], jnp.int32)
@@ -520,7 +525,7 @@ LIVE_BATCHES = {
 }
 
 
-@pytest.mark.parametrize("layout", ["paged", "contiguous"])
+@pytest.mark.parametrize("layout", ["paged", "contiguous", "paged128"])
 @pytest.mark.parametrize("case", sorted(LIVE_BATCHES))
 def test_flash_decode_visits_live_rows(case, layout):
     """The grid follows ``live``: the rows that decode equal the dense
@@ -528,7 +533,8 @@ def test_flash_decode_visits_live_rows(case, layout):
     visited, so a NaN in every page a PARKED row's table and ``pos`` name
     is never read), and no live row at all is a kernel of no steps."""
     mask, pos = LIVE_BATCHES[case]
-    B, Hkv, rep, Dh, page, maxp = len(pos), 2, 2, 64, 256, 3
+    B, Hkv, rep, page, maxp = len(pos), 2, 2, 256, 3
+    Dh = 128 if layout == "paged128" else 64    # pages walked in a grid step
     q = _rand(0, B, Hkv * rep, Dh)
     k = _rand(1, B, Hkv, maxp * page, Dh)
     v = _rand(2, B, Hkv, maxp * page, Dh)
@@ -538,7 +544,7 @@ def test_flash_decode_visits_live_rows(case, layout):
     # whatever a parked row could reach is poison
     kx, vx = k.at[parked].set(jnp.nan), v.at[parked].set(jnp.nan)
     posv = jnp.asarray(pos, jnp.int32)
-    if layout == "paged":
+    if layout.startswith("paged"):
         kp, vp, pt = _paged_from_logical(kx, vx, maxp, page)
         got = flash_decode(q, kp, vp, posv, page_table=pt, live=live,
                            impl="interpret")
@@ -549,14 +555,207 @@ def test_flash_decode_visits_live_rows(case, layout):
     np.testing.assert_array_equal(got[parked], q[parked])
 
 
+# -- pages walked inside a grid step (head dims that fill the lanes) -----------
+def _poisoned_pool(k, v, pos, live, maxp, page, seed=7):
+    """:func:`_paged_from_logical` with NaN wherever no live row attends:
+    past every row's ``pos`` inside its pages, in every page of a row that
+    does not decode, in the junk page and in the pages nobody owns."""
+    B = k.shape[0]
+    at = jnp.arange(maxp * page)[None, None, :, None]
+    keep = (at <= jnp.asarray(pos)[:, None, None, None]) \
+        & jnp.asarray(live)[:, None, None, None]
+    kp, vp, pt = _paged_from_logical(jnp.where(keep, k, jnp.nan),
+                                     jnp.where(keep, v, jnp.nan), maxp, page,
+                                     seed=seed)
+    pad = jnp.full((2,) + kp.shape[1:], jnp.nan, kp.dtype)
+    return (jnp.concatenate([kp.at[0].set(jnp.nan), pad]),
+            jnp.concatenate([vp.at[0].set(jnp.nan), pad]), pt)
+
+
+# page 256, 4 pages a row: every piece boundary of a last page, both sides of
+# a page boundary, a page multiple less one, the table's last row
+WALK_POSITIONS = [0, 63, 64, 255, 256, 300, 767, 1023]
+WALK_LIVE = {
+    "all": [True] * 4, "one": [False, False, True, False],
+    "alternating": [True, False, True, False], "none": [False] * 4}
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("pos", WALK_POSITIONS)
+def test_walked_pages_attend_up_to_pos_in_a_poisoned_pool(pos, alibi):
+    """The stale-V hazard: a row's last page comes in pieces of 64 tokens up
+    to ``pos``, so its buffer holds an earlier page's rows (or nothing yet)
+    past the last piece and the pool's own rows between ``pos`` and the
+    piece's end.  Both are NaN here; the scores there are masked, so their
+    weight is exactly 0, and the values are selected by ``key_pos <= pos``
+    (``0 x NaN`` is NaN): the output is finite and the reference's over the
+    attended rows."""
+    B, Hkv, rep, Dh, page, maxp = 3, 2, 2, 128, 256, 4
+    posv = [pos, 300, 1023 - pos]
+    q = _rand(0, B, Hkv * rep, Dh)
+    k = _rand(1, B, Hkv, maxp * page, Dh)
+    v = _rand(2, B, Hkv, maxp * page, Dh)
+    kp, vp, pt = _poisoned_pool(k, v, posv, [True] * B, maxp, page)
+    assert np.isnan(np.asarray(kp)).mean() > 0.3
+    got = flash_decode(q, kp, vp, jnp.asarray(posv, jnp.int32), page_table=pt,
+                       alibi=alibi, impl="interpret")
+    want = _flash_decode_ref(q, k, v, jnp.asarray(posv, jnp.int32),
+                             scale=Dh ** -0.5, alibi=alibi)
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("layer", ["static", "traced", None])
+@pytest.mark.parametrize("case", sorted(WALK_LIVE))
+def test_walked_pages_follow_the_live_mask_in_a_poisoned_pool(case, layer):
+    """Live masks over rows one, two and four pages deep, MHA with two head
+    groups a row, the pool a stacked one read at a static and at a TRACED
+    layer (Ouro's cache-layer offset) and a flat one: a live row equals the
+    reference, a parked row (its pages, ``pos`` and the junk page all NaN)
+    gets its ``q`` back, no live row is a kernel of no steps."""
+    live = WALK_LIVE[case]
+    B, Hkv, rep, Dh, page, maxp, L = 4, 16, 1, 128, 256, 4, 2
+    posv = [255, 700, 64, 1023]
+    q = _rand(0, B, Hkv * rep, Dh)
+    k = _rand(1, B, Hkv, maxp * page, Dh)
+    v = _rand(2, B, Hkv, maxp * page, Dh)
+    kp, vp, pt = _poisoned_pool(k, v, posv, live, maxp, page)
+    if layer is None:
+        at = None
+    else:               # layer 0 is poison all over; the call reads layer 1
+        kp = jnp.stack([jnp.full_like(kp, jnp.nan), kp])
+        vp = jnp.stack([jnp.full_like(vp, jnp.nan), vp])
+        at = 1
+    call = lambda at: flash_decode(
+        q, kp, vp, jnp.asarray(posv, jnp.int32), layer=at, page_table=pt,
+        live=jnp.asarray(live), impl="interpret")
+    got = jax.jit(call)(jnp.int32(at)) if layer == "traced" else call(at)
+    want = _flash_decode_ref(q, k, v, jnp.asarray(posv, jnp.int32),
+                             scale=Dh ** -0.5)
+    rows = np.flatnonzero(live)
+    parked = np.setdiff1d(np.arange(B), rows)
+    np.testing.assert_allclose(got[rows], want[rows], rtol=2e-4, atol=2e-4)
+    np.testing.assert_array_equal(got[parked], q[parked])
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("Hkv,rep", [(8, 4), (16, 1)])
+def test_walked_pages_under_the_chips_interpreter(Hkv, rep, dtype, capfd,
+                                                  monkeypatch):
+    """The copies' bookkeeping, under jax's TPU interpreter: VMEM starts as
+    NaN, a copy lands only when it is waited for, and races between a copy
+    and the vector units are looked for.  A page scored before its copy was
+    waited for would read NaN; a copy started and never waited for (the
+    next row's first page after the LAST grid step, a piece past ``pos``)
+    leaves its semaphore above 0 at the kernel's end, which the interpreter
+    reports."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deepspeed_tpu.ops.pallas import decode
+
+    params = pltpu.InterpretParams(detect_races=True,
+                                   dma_execution_mode="on_wait")
+    monkeypatch.setattr(decode, "interpret_flag", lambda impl: params)
+    # the call is a jitted function: none traced under the other interpreter
+    jax.clear_caches()
+    B, Dh, page, maxp = 4, 128, 256, 3
+    posv, live = [300, 63, 767, 5], [True, True, False, True]
+    q = _rand(0, B, Hkv * rep, Dh, dtype=dtype)
+    k = _rand(1, B, Hkv, maxp * page, Dh, dtype=dtype)
+    v = _rand(2, B, Hkv, maxp * page, Dh, dtype=dtype)
+    kp, vp, pt = _poisoned_pool(k.astype(jnp.float32), v.astype(jnp.float32),
+                                posv, live, maxp, page)
+    got = flash_decode(q, kp.astype(dtype), vp.astype(dtype),
+                       jnp.asarray(posv, jnp.int32), page_table=pt,
+                       live=jnp.asarray(live), impl="interpret")
+    got = np.asarray(got, np.float32)
+    want = _flash_decode_ref(q, k, v, jnp.asarray(posv, jnp.int32),
+                             scale=Dh ** -0.5)
+    rows = np.flatnonzero(live)
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[rows], np.float32(want)[rows], rtol=tol,
+                               atol=tol)
+    np.testing.assert_array_equal(got[2], np.float32(q[2]))
+    assert not interpret_pallas_call.races.races_found
+    assert "non-zero count" not in capfd.readouterr().out
+    jax.clear_caches()
+
+
+def _pallas_calls(jaxpr):
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                out += _pallas_calls(getattr(inner, "jaxpr", inner))
+    return out
+
+
+def _decode_call(name):
+    """One decode attention call of each cache layout, traced with a live
+    mask: two rows, four pages of 256 a row."""
+    from deepspeed_tpu.ops.pallas.decode import (eva_decode_paged,
+                                                 mla_decode_paged)
+
+    B = 2
+    pos, live = jnp.zeros((B,), jnp.int32), jnp.ones((B,), bool)
+    pt = jnp.zeros((B, 4), jnp.int32)
+    pool = lambda dh, hkv=2: jnp.zeros((2, 9, hkv, 256, dh))
+    q = lambda dh: jnp.zeros((B, 4, dh))
+    return {
+        "contiguous": lambda: flash_decode(
+            q(128), jnp.zeros((B, 2, 512, 128)), jnp.zeros((B, 2, 512, 128)),
+            pos, live=live, impl="interpret"),
+        "latent": lambda: mla_decode_paged(
+            jnp.zeros((B, 8, 256)), pool(256, 1), pos, pt, layer=1,
+            sm_scale=0.1, live=live, impl="interpret"),
+        "eva": lambda: eva_decode_paged(
+            q(128), pool(128), pool(128), pos, pt, layer=1, window=512,
+            chunk=16, live=live, impl="interpret"),
+        "paged": lambda: flash_decode(
+            q(128), pool(128), pool(128), pos, layer=1, page_table=pt,
+            live=live, impl="interpret"),
+        "paged_head_dim_64": lambda: flash_decode(
+            q(64), pool(64), pool(64), pos, layer=1, page_table=pt,
+            live=live, impl="interpret"),
+    }[name]
+
+
+@pytest.mark.parametrize("call,name,grid_rank,operands,blocks", [
+    ("contiguous", "flash_decode", 3, 8, 5),
+    ("latent", "mla_decode_paged", 3, 8, 4),
+    ("eva", "eva_decode_paged", 3, 8, 5),
+    ("paged_head_dim_64", "flash_decode_paged", 3, 9, 5),
+    ("paged", "flash_decode_paged", 2, 9, 5)])
+def test_the_schedule_is_chosen_by_the_cache_layout(call, name, grid_rank,
+                                                    operands, blocks):
+    """ISSUE 60: per-head K and V pools under one page table, at a head dim
+    that fills the lanes, take the schedule that walks a row's pages inside
+    a grid step (grid (live rows, head groups): rank 2, ONE run-time extent
+    before rows, pos, the layer's first page, the table, q, K, V and the
+    slopes); every other caller
+    keeps :func:`_decode_attention`'s, the parent's ``pallas_call`` by name,
+    grid rank, operand count and blocks.  One kernel a call, whatever the
+    schedule."""
+    (eqn,) = _pallas_calls(jax.make_jaxpr(_decode_call(call))().jaxpr)
+    gm = eqn.params["grid_mapping"]
+    assert (eqn.params["name"], len(gm.grid), len(eqn.invars),
+            len(gm.block_mappings)) == (name, grid_rank, operands, blocks)
+
+
 # -- a traced cache layer (a looped stack's ``pass * layers + layer``) ---------
+@pytest.mark.parametrize("Dh", [64, 128])
 @pytest.mark.parametrize("impl", ["interpret", "xla"])
-def test_a_traced_cache_layer_appends_and_attends_as_the_int_layer_does(impl):
+def test_a_traced_cache_layer_appends_and_attends_as_the_int_layer_does(impl,
+                                                                        Dh):
     """``paged_kv_append`` and ``flash_decode(page_table=)`` inside a rolled
     loop over the cache layers, the layer a traced scalar (folded into the
     scalar-prefetched page numbers), against one call a Python-int layer:
     the same pools bit for bit, the same attention."""
-    B, Hkv, Dh, page, maxp, L = 3, 2, 64, 128, 2, 3
+    B, Hkv, page, maxp, L = 3, 2, 128, 2, 3
     ks, vs, kp_all, vp_all, pt = _stacked_pools(B, Hkv, Dh, page, maxp, L,
                                                 seed=3)
     pt = _park(pt, 1)

@@ -298,6 +298,54 @@ def test_paged_kernels_compile_at_the_serve_chat_shape(v5e, kernel):
     assert _custom_calls(fn, v5e, *shapes) >= want
 
 
+# (heads, KV heads, slots, pages a row, cache layers, pool pages) of the
+# cells whose decode blocks walk per-head K/V pages of 256 x 128 (ISSUE 60):
+# Ouro's 48 cache layers, Trinity's rings of 16 pages and full tables of 64,
+# Solar's one full layer under 128 slots of 19 pages
+WALKED_CELLS = {
+    "ouro-2.6b-L12.serve-reason-768": (16, 16, 16, 5, 48, 81),
+    "mistral-7b-L8.serve-chat": (32, 8, 64, 4, 8, 129),
+    "olmoe-1b-7b-L8.serve-chat": (16, 16, 64, 4, 8, 129),
+    "trinity-large-L5-ep8.serve-mixed-16k.ring": (48, 8, 32, 16, 4, 513),
+    "trinity-large-L5-ep8.serve-mixed-16k.full": (48, 8, 32, 64, 1, 1537),
+    "solar-open2-L4-ep8.serve-reason-4k": (64, 8, 128, 19, 1, 2433),
+}
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["static", "traced"])
+@pytest.mark.parametrize("cell", sorted(WALKED_CELLS))
+def test_walked_pages_compile_at_the_cells_shapes(v5e, cell, traced):
+    """ISSUE 60: ``flash_decode_paged`` at the shape each changed cell's
+    decode block calls it (the slots, a live mask, the layer a Python int
+    or a TRACED scalar as in Ouro's rolled pass loop) compiles for the v5e
+    as ONE kernel of that name whose grid has one run-time extent (the live
+    rows; operands: it, rows, pos, the layer's first page, the table, q, K,
+    V, the slopes), with no
+    reference in its place: its two K and two V page buffers, the float32
+    copy of a page's values and the score tile fit the scoped VMEM."""
+    from deepspeed_tpu.ops.pallas.common import reference_selections
+
+    H, Hkv, slots, maxp, layers, pool_pages = WALKED_CELLS[cell]
+    pool = ((layers, pool_pages, Hkv, 256, 128), BF16)
+    shapes = [((slots, H, 128), BF16), pool, pool, ((slots,), I32),
+              ((slots, maxp), I32), ((slots,), jnp.bool_), ((), I32)]
+
+    def fn(q, k, v, pos, pt, live, layer):
+        return flash_decode(q, k, v, pos, page_table=pt, live=live,
+                            layer=layer if traced else layers - 1,
+                            impl="pallas")
+
+    before = len(reference_selections())
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 1 and "flash_decode_paged" in calls[0]
+    operands = re.search(r"custom-call\(([^)]*)\)", calls[0]).group(1)
+    assert len(operands.split(", ")) == 9
+    assert len(reference_selections()) == before
+
+
 # the benchmark's olmoe-1b-7b-L8.serve-chat cell: MHA 16 x 128 (all 16 KV
 # heads of a page are exactly the attention kernel's 4 MiB of K and V
 # buffers), 64 experts of width 1024 read from the stacked arrays
@@ -642,7 +690,10 @@ def test_decode_blocks_visit_the_live_rows(v5e, chip_kernels, config,
     before, the live rows sorted ONCE a step for all layers' calls, each
     call's output in its ``q``'s buffer, and no pool copied for it (at head
     dim 64 the block converts the pool's layout on its way in and out, four
-    copies before this change and after)."""
+    copies before this change and after).  ISSUE 60: at head dim 128 the
+    kernel walks a row's pages itself, so its grid has ONE run-time extent
+    (the live rows) where head dim 64 keeps two (and the deepest row's
+    pages)."""
     cell = _ServeCell(v5e, config, "mistral-7b-L8.serve-chat",
                       fields=dict(num_layers=2))
     block = cell.block()
@@ -651,9 +702,11 @@ def test_decode_blocks_visit_the_live_rows(v5e, chip_kernels, config,
     calls = [line for line in text.splitlines()
              if "custom-call(" in line and "flash_decode_paged" in line]
     assert len(calls) == 2
-    # operands: the grid's two run-time extents, rows, pos, the table, q
-    rows = {re.search(r"custom-call\(([^)]*)\)", line).group(1).split(", ")[2]
-            for line in calls}
+    # operands: the grid's run-time extents, rows, pos, (where the kernel
+    # walks the pages, the layer's first page,) the table, q
+    extents = 1 if in_place else 2
+    rows = {re.search(r"custom-call\(([^)]*)\)",
+                      line).group(1).split(", ")[extents] for line in calls}
     assert len(rows) == 1 and rows.pop().startswith("%sort")
     assert len(re.findall(r" sort\(%.*argsort", text)) == 1
     assert all("output_to_operand_aliasing={{}: (5, {})}" in line

@@ -4,18 +4,25 @@
 once per layer of a stacked pool as a decode step calls them, for a list of
 position mixes.  The default shape is the benchmark's
 ``mistral-7b-L8.serve-chat`` cell (64 slots, 32 heads over 8 KV heads of
-128, pages of 256, 4 pages a row, 8 layers x 129 pages, bf16).  One row of
-JSON per mix, appended to ``chiprun_out/paged_decode_bench.jsonl``.
+128, pages of 256, 4 pages a row, 8 layers x 129 pages, bf16); the
+``ouro-2.6b-L12.serve-reason-768`` cell's is ``--heads 16 --kv-heads 16
+--head-dim 128 --slots 16 --page 256 --maxp 5 --layers 48 --mixes
+reason8,1023``.  One row of JSON per mix, appended to
+``chiprun_out/paged_decode_bench.jsonl``.
 
     python3 tools/paged_decode_bench.py [--tree <checkout>] [--label parent]
         [--heads 32 --kv-heads 8 --head-dim 128 --slots 64 --page 256
-         --maxp 4 --layers 8] [--mixes 0,255,300,1023,cell,cell16]
+         --maxp 4 --layers 8] [--mixes 0,255,300,1023,cell,cell16,reason8]
 
 ``--tree`` imports ``deepspeed_tpu`` from another checkout (the parent
 commit, unpacked beside this one), so one call times both on one chip.  A
-mix is a position shared by every row, or ``cell``: 33 rows at 150-500 and
+mix is a position shared by every row (``1023``; ``1023x8``: by 8 live rows,
+the others parked), or ``cell``: 33 rows at 150-500 and
 31 parked (position 0 on the junk page 0), the cell's mean occupancy when
-PR 26 took it; ``cell<n>`` has ``n`` such rows.  A ``cell`` mix hands the
+PR 26 took it; ``cell<n>`` has ``n`` such rows; ``reason<n>`` has ``n`` live
+rows where the ``reason-768`` mix leaves them (a prompt lognormal median 128,
+16-512, an answer median 384, 64-768, the request met at a random moment of
+its decode: answers weighted by their length).  Such a mix hands the
 kernel its live mask where the tree's kernel takes one (its grid then
 visits the live rows only, ISSUE 39; ``masked`` in the row says so).  The
 time of a call is the host clock around ``--reps`` programs of ``layers``
@@ -82,18 +89,31 @@ def main() -> int:
     kn = jax.random.normal(keys[3], (B, Hkv, Dh), jnp.bfloat16)
     vn = jax.random.normal(keys[4], (B, Hkv, Dh), jnp.bfloat16)
 
+    def reason_positions(n):
+        """Where ``n`` rows of the ``reason-768`` mix stand at a random
+        moment: a request is met with a chance that grows with its answer's
+        length, anywhere in it."""
+        def cut(median, sigma, lo, hi):
+            return np.clip(np.exp(np.log(median) + sigma
+                                  * rng.standard_normal(64 * n)), lo, hi)
+        prompt, out = cut(128, 0.8, 16, 512), cut(384, 0.5, 64, 768)
+        at = rng.choice(out.size, n, p=out / out.sum())
+        return (prompt[at] + rng.uniform(0, 1, n) * out[at]).astype(np.int64)
+
     def mix(name):
         """(pos [B], page_table [B, maxp], live [B]) of one mix; live rows
         own distinct shuffled pages as far as the pool goes, parked rows sit
         on the junk page."""
         if name.startswith("cell"):
-            live = np.zeros(B, bool)
-            live[rng.permutation(B)[:int(name[4:] or B * 33 // 64)]] = True
-            pos = np.where(live, rng.randint(150, 501, B), 0)
-            pos = np.minimum(pos, maxp * page - 1)
+            n, at = name[4:] or B * 33 // 64, lambda: rng.randint(150, 501, B)
+        elif name.startswith("reason"):
+            n, at = name[6:], lambda: reason_positions(B)
         else:
-            live = np.ones(B, bool)
-            pos = np.full(B, int(name))
+            shared, _, n = name.partition("x")
+            n, at = n or B, lambda: int(shared)
+        live = np.zeros(B, bool)
+        live[rng.permutation(B)[:int(n)]] = True
+        pos = np.where(live, np.minimum(at(), maxp * page - 1), 0)
         pt = np.zeros((B, maxp), np.int32)
         free = list(rng.permutation(P - 1) + 1)
         for b in np.flatnonzero(live):
@@ -159,7 +179,9 @@ def main() -> int:
         app_us = timed(step, reps)
         pages = int(jnp.sum(jnp.where(live, pos // page + 1, 0)))
         row = {"label": args.label, "device": dev.device_kind, "mix": name,
-               "masked": masked and name.startswith("cell"),
+               "masked": masked and not bool(jnp.all(live)),
+               "positions": sorted(int(p) for p in np.asarray(pos)[
+                   np.asarray(live)]) if int(jnp.sum(live)) <= 16 else None,
                "live_rows": int(jnp.sum(live)), "live_pages": pages,
                "context_tokens": int(jnp.sum(jnp.where(live, pos + 1, 0))),
                "flash_decode_paged_us_per_call": attn_us,
