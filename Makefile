@@ -16,11 +16,14 @@ lint-json:
 profile-report:
 	python tools/trace_report.py --history profile_history
 
-# lint first (seconds), then the tier-1 suite (minutes)
+# lint first (seconds), then the tier-1 suite as the driver runs it (a
+# quarter of an hour): six workers, a file the unit of work.  The two files
+# that compile for a described v5e may land on two workers, each of which
+# loads the TPU's library: ALLOW_MULTIPLE_LIBTPU_LOAD lets them (ROADMAP D6)
 tier1: lint
-	env JAX_PLATFORMS=cpu python -m pytest tests/ -q -m 'not slow' \
-		--continue-on-collection-errors -p no:cacheprovider \
-		-p no:xdist -p no:randomly
+	env JAX_PLATFORMS=cpu ALLOW_MULTIPLE_LIBTPU_LOAD=1 python -m pytest \
+		tests/ -q -m 'not slow' --continue-on-collection-errors \
+		-p no:cacheprovider -p xdist -n 6 --dist loadfile -p no:randomly
 
 # the slow-marked chaos suites (outside tier-1): the mid-stream
 # decode-replica kill in tests/unit/test_serving_chaos.py and the

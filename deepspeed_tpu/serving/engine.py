@@ -1846,10 +1846,14 @@ class ServingEngine:
             # token, wake flag, EOS id (-1: none)
             meta = jnp.asarray(
                 [slot, off, c - 1, int(wake), req.eos_token_id], jnp.int32)
+            # the table row is a SNAPSHOT: the allocator rewrites the row
+            # in place (preempt, release, adopt) while this program may
+            # still be queued, and ``jnp.asarray`` of host memory need not
+            # copy it (the CPU client aliases a 64-byte aligned array)
             tok_dev, self._cache, carries = self._prefill_fn(cb)(
                 self.engine._params, self._cache,
                 (self._last_dev, self._pos_dev, self._act_dev),
-                jnp.asarray(self.pool.page_table[slot]),
+                jnp.asarray(self.pool.page_table[slot].copy()),
                 jnp.asarray(chunk), meta, srng)
             self._last_dev, self._pos_dev, self._act_dev = carries
             req.prefill_pos += c
@@ -2051,9 +2055,12 @@ class ServingEngine:
             (toks, valid, self._last_dev, self._pos_dev, self._act_dev,
              self._cache, self._rng, moe) = self._block()(
                 self._loop_params(), self._cache, self._last_dev,
-                self._pos_dev, self._act_dev, jnp.asarray(self._limit),
-                jnp.asarray(self._eos), self._rng,
-                jnp.asarray(self.pool.page_table))
+                self._pos_dev, self._act_dev,
+                # snapshots, as the chunk program's table row is: the host
+                # writes all three in place while the block is queued
+                jnp.asarray(self._limit.copy()),
+                jnp.asarray(self._eos.copy()), self._rng,
+                jnp.asarray(self.pool.page_table.copy()))
         t1 = time.perf_counter()
         idx = self._next_block
         self._next_block += 1

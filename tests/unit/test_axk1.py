@@ -26,6 +26,8 @@ from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, afmoe, kda_mla
 from deepspeed_tpu.ops.pallas.flash_attention import mla_chunk_schedule
 from deepspeed_tpu.serving import cache_kind
+from tests.unit._serving import (as_found, read_served, tapped_engine,
+                                  with_noise)
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
                                     os.pardir))
@@ -76,11 +78,8 @@ def ref():
 def model():
     m = CausalLM(ModelConfig(**FIELDS),
                  build_mesh(devices=jax.devices()[:1]))
-    params = m.init(jax.random.PRNGKey(0))
     # gains of exactly 1 would hide a dropped norm
-    noise = iter(jax.random.split(jax.random.PRNGKey(1), 64))
-    return m, jax.tree.map(
-        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+    return m, with_noise(m.init(jax.random.PRNGKey(0)))
 
 
 def ref_logits(ref, params, seq, rows, config=REF_CONFIG, **kw):
@@ -92,6 +91,13 @@ def serve_of(model, **kw):
     m, params = model
     return deepspeed_tpu.init_serving(m, config=dict(ENGINE, **kw),
                                       params=params, mesh=m.mesh)
+
+
+@pytest.fixture(scope="module")
+def tapped(model):
+    """One engine at ``ENGINE``, its programs traced under the serve taps,
+    for the cases that differ in their requests alone."""
+    yield from tapped_engine(lambda: serve_of(model))
 
 
 # ----------------------------------------- the three forwards, by logits
@@ -126,21 +132,15 @@ def test_each_control_of_the_reference_moves_the_logits(ref, model, variant):
                               "one_past_and_a_padded_bucket",
                               "three_chunks_and_tiny"])
 def test_chunked_prefill_then_decode_is_the_references_one_forward(
-        ref, model, prompts):
+        ref, model, tapped, prompts):
     """Prefill in chunks of 16 (a later chunk attends the ROTATED key parts
     the earlier ones wrote), then decode through the latent pages on the
     fused path (the absorbed query carries the rotated part), in float32,
     two requests in flight: the program's LOGITS at every generated position
     are the reference's full forward's, and so is every token."""
-    from benchmarks.lib.serve_taps import ServeTaps, serve_and_read
-
-    with ServeTaps() as taps:
-        serve = serve_of(model)
-        ps = [np.random.default_rng(n).integers(0, 96, n) for n in prompts]
-        served = serve_and_read(taps, serve, ps, [21, 13])
-        serve.pool.check_no_leak()
-        assert serve.pool.pages_used == 0
-        serve.close()
+    ps = [np.random.default_rng(n).integers(0, 96, n) for n in prompts]
+    served = read_served(tapped, ps, [21, 13])
+    assert tapped[1].pool.pages_used == 0
     for p, rec in zip(ps, served):
         seq = np.concatenate([p, rec["tokens"]])
         rows = list(range(len(p) - 1, len(seq) - 1))
@@ -475,27 +475,25 @@ def test_cached_layers_through_the_chunk_kernel_match_the_jnp_path(start,
 
 
 # --------------------------------------------------------- the cache kind
-def test_a_latent_only_model_has_pages_and_no_slot_state(model):
+def test_a_latent_only_model_has_pages_and_no_slot_state(model, tapped):
     m, params = model
     kind = cache_kind.cache_kind(m.config)
     assert type(kind) is cache_kind.LatentPages
     assert isinstance(cache_kind.LatentPagesAndState(m.config),
                       cache_kind.LatentPages)
     assert kind.pool_args(jnp.bfloat16) == {} and not kind.takes_valid_len
-    serve = serve_of(model)
-    assert set(serve._cache) == {"latent"}
-    assert serve._cache["latent"].shape == (5, serve.pool.num_pages, 1, 8,
-                                            128)
-    assert serve.pool.state_bytes == 0
-    assert len(kda_mla.moe_counts_zero(m.config)) == 5
-    rng = np.random.default_rng(6)
-    reqs = [serve.submit(rng.integers(0, 96, n), max_new_tokens=k)
-            for n, k in ((12, 30), (40, 9), (20, 17), (33, 5))]
-    serve.run()
-    serve.pool.check_no_leak()
-    assert serve.pool.pages_used == 0
-    assert [len(r.output_tokens) for r in reqs] == [30, 9, 17, 5]
-    serve.close()
+    with as_found(tapped[1]) as serve:
+        assert set(serve._cache) == {"latent"}
+        assert serve._cache["latent"].shape == (5, serve.pool.num_pages, 1,
+                                                8, 128)
+        assert serve.pool.state_bytes == 0
+        assert len(kda_mla.moe_counts_zero(m.config)) == 5
+        rng = np.random.default_rng(6)
+        reqs = [serve.submit(rng.integers(0, 96, n), max_new_tokens=k)
+                for n, k in ((12, 30), (40, 9), (20, 17), (33, 5))]
+        serve.run()
+        assert serve.pool.pages_used == 0
+        assert [len(r.output_tokens) for r in reqs] == [30, 9, 17, 5]
 
 
 def test_kimis_engine_still_builds_pages_and_state_with_its_series():

@@ -30,6 +30,7 @@ from deepspeed_tpu.serving.cache_kind import (KINDS, FullPages,
                                               WindowSummaryPages, cache_kind)
 from deepspeed_tpu.serving.paged_kv import PagedKVPool
 
+from ._serving import as_found, with_noise
 from . import (test_axk1, test_dots3_note, test_evabyte, test_kimi_linear,
                test_solar_open2, test_trinity)
 
@@ -76,11 +77,8 @@ def built():
                                   remat=False)
             else:
                 model = CausalLM(ModelConfig(**fields), mesh)
-            params = model.init(jax.random.PRNGKey(0))
-            noise = iter(jax.random.split(jax.random.PRNGKey(1), 64))
-            made[name] = model, jax.tree.map(      # no gain of exactly 1
-                lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape),
-                params)
+            made[name] = model, with_noise(        # no gain of exactly 1
+                model.init(jax.random.PRNGKey(0)))
         return made[name]
 
     return get
@@ -92,6 +90,22 @@ def serve_of(built, name, **kw):
     return deepspeed_tpu.init_serving(
         model, config=dict(CASES[name][2], **kw), params=params,
         mesh=model.mesh, **role)
+
+
+@pytest.fixture(scope="module")
+def engines(built):
+    """name -> one engine of that kind at its ``CASES`` settings, built on
+    demand, for the cases that differ in their requests alone."""
+    made = {}
+
+    def get(name):
+        if name not in made:
+            made[name] = serve_of(built, name)
+        return made[name]
+
+    yield get
+    for serve in made.values():
+        serve.close()
 
 
 def prompts_of(built, name, lengths, seed):
@@ -165,69 +179,66 @@ def test_a_view_written_back_leaves_the_pool_and_a_changed_one_its_neighbours(
 
 
 @pytest.mark.parametrize("name", NAMES)
-def test_an_aborted_request_returns_every_page(built, name):
+def test_an_aborted_request_returns_every_page(built, engines, name):
     """A request aborted mid-flight, after chunks and decode blocks have
     given it pages of every kind the slot holds, returns them all."""
-    serve = serve_of(built, name)
     long, short = prompts_of(built, name, (70 if name == "eva" else 40, 12),
                              seed=5)
-    req = serve.submit(long, max_new_tokens=50)
-    other = serve.submit(short, max_new_tokens=8)
-    for _ in range(6):
-        serve.step()
-    held = serve.pool.pages_used_by_kind()
-    assert serve.pool.slot_pages_used(req.slot) > 1 and not req.done
-    if name == "eva":
-        assert held["summary"] > 0
-    if name == "two_budgets":
-        assert held["window"] > 0 and held["full"] > 0
-    serve.abort(req)
-    serve.run()
+    with as_found(engines(name)) as serve:
+        req = serve.submit(long, max_new_tokens=50)
+        other = serve.submit(short, max_new_tokens=8)
+        for _ in range(6):
+            serve.step()
+        held = serve.pool.pages_used_by_kind()
+        assert serve.pool.slot_pages_used(req.slot) > 1 and not req.done
+        if name == "eva":
+            assert held["summary"] > 0
+        if name == "two_budgets":
+            assert held["window"] > 0 and held["full"] > 0
+        serve.abort(req)
+        serve.run()
     assert req.done and req.finish_reason == "cancelled"
     assert other.done and len(other.output_tokens) == 8
-    serve.pool.check_no_leak()
     assert serve.pool.pages_used == 0
-    serve.close()
 
 
 @pytest.mark.parametrize("name", NAMES)
-def test_a_reused_slot_serves_what_a_fresh_engine_serves(built, name):
+def test_a_reused_slot_serves_what_a_fresh_engine_serves(built, engines,
+                                                         name):
     """Five requests over three slots: the fourth and fifth take slots a
     finished request left its rows (and its state) in, and are served what
-    an engine of their own serves them."""
+    a fresh engine serves them in slots nothing was in."""
     prompts = prompts_of(built, name, (20, 33, 9, 25, 18), seed=2)
-    serve = serve_of(built, name)
-    reqs = [serve.submit(p, max_new_tokens=12) for p in prompts]
-    serve.run()
-    serve.pool.check_no_leak()
-    serve.close()
-    for p, r in zip(prompts[3:], reqs[3:]):
-        alone = serve_of(built, name)
-        want = alone.submit(p, max_new_tokens=12)
-        alone.run()
-        assert list(r.output_tokens) == list(want.output_tokens)
-        alone.close()
+    with as_found(engines(name)) as serve:
+        reqs = [serve.submit(p, max_new_tokens=12) for p in prompts]
+        serve.run()
+    fresh = serve_of(built, name)
+    want = [fresh.submit(p, max_new_tokens=12) for p in prompts[3:]]
+    fresh.run()
+    for r, w in zip(reqs[3:], want):
+        assert list(r.output_tokens) == list(w.output_tokens)
+    fresh.close()
 
 
-def test_two_budgets_preempt_and_resume_are_token_identical(built):
+def test_two_budgets_preempt_and_resume_are_token_identical(built, engines):
     """A full budget of 13 pages for three slots: the youngest is preempted,
     gives back its ring and its full pages, re-prefills prompt + outputs
     through both budgets, and every request still gets the tokens an
     unpressed engine gives it."""
     prompts = prompts_of(built, "two_budgets", (30, 41, 22), seed=3)
     news = (40, 30, 50)
-    easy = serve_of(built, "two_budgets")
     tight = serve_of(built, "two_budgets", kv_pool_tokens=104)
-    want = [easy.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    with as_found(engines("two_budgets")) as easy:
+        want = [easy.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        easy.run()
     got = [tight.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    easy.run()
     tight.run()
     tight.pool.check_no_leak()
     assert tight.pool.pages_used == 0
     assert sum(r.preemptions for r in got) > 0
     for w, g in zip(want, got):
         assert list(g.output_tokens) == list(w.output_tokens)
-    easy.close()
     tight.close()
 
 
@@ -244,15 +255,14 @@ ASKED = {
 
 
 @pytest.mark.parametrize("name", REFUSING)
-def test_prefill_only_is_refused_with_the_kinds_reason(built, name):
-    serve = serve_of(built, name)
-    kind = serve.kind
-    with pytest.raises(NotImplementedError) as err:
-        serve.submit([1, 2, 3], prefill_only=True)
+def test_prefill_only_is_refused_with_the_kinds_reason(engines, name):
+    with as_found(engines(name)) as serve:
+        kind = serve.kind
+        with pytest.raises(NotImplementedError) as err:
+            serve.submit([1, 2, 3], prefill_only=True)
     assert str(err.value) == (f"prefill_only with {kind.what}: "
                               f"{kind.cannot['handoff']}")
     assert "handoff.py" in str(err.value)
-    serve.close()
 
 
 @pytest.mark.parametrize("name", ["two_budgets", "latent", "state",
@@ -315,13 +325,12 @@ def test_the_indexed_kinds_arrays_and_pool_arguments(built):
 
 
 @pytest.mark.parametrize("option", [*ASKED, "prefix_caching"])
-def test_the_indexed_kind_refuses_by_its_own_reasons(built, option):
+def test_the_indexed_kind_refuses_by_its_own_reasons(built, engines, option):
     kind = cache_kind(built("indexed")[0].config)
     assert set(kind.cannot) == {*ASKED, "prefix_caching"}
     if option == "prefix_caching":          # turned off, with the reason
-        serve = serve_of(built, "indexed")
+        serve = engines("indexed")
         assert serve.prefix_cache is None and "index key" in kind.cannot[option]
-        serve.close()
         return
     with pytest.raises(NotImplementedError) as err:
         serve_of(built, "indexed", **ASKED[option])

@@ -25,6 +25,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, afmoe, kda_mla
+from tests.unit._serving import read_served, tapped_engine, with_noise
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
                                     os.pardir))
@@ -85,10 +86,7 @@ def ref():
 
 def _noisy(m, key=1):
     """Gains of exactly 1 would hide a dropped norm."""
-    params = m.init(jax.random.PRNGKey(0))
-    noise = iter(jax.random.split(jax.random.PRNGKey(key), 96))
-    return jax.tree.map(
-        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+    return with_noise(m.init(jax.random.PRNGKey(0)), key, keys=96)
 
 
 @pytest.fixture(scope="module")
@@ -115,6 +113,13 @@ def serve_of(model, **kw):
     m, params = model
     return deepspeed_tpu.init_serving(m, config=dict(ENGINE, **kw),
                                       params=params, mesh=m.mesh)
+
+
+@pytest.fixture(scope="module")
+def tapped(model):
+    """One engine at ``ENGINE``, its programs traced under the serve taps,
+    for the cases that differ in their requests alone."""
+    yield from tapped_engine(lambda: serve_of(model))
 
 
 # ----------------------------------------- the three forwards, by logits
@@ -147,7 +152,7 @@ def test_each_control_of_the_reference_moves_the_logits(ref, model, variant):
                               "three_chunks_and_tiny",
                               "the_window_less_one_and_plus_one"])
 def test_chunked_prefill_then_decode_is_the_references_one_forward(
-        ref, model, prompts):
+        ref, model, tapped, prompts):
     """Prefill in chunks of 16 (a later chunk scores the index keys and
     attends the selected rows the earlier ones wrote, and reads the ring
     they left), then decode through index pages, selected rows and rings on
@@ -156,13 +161,9 @@ def test_chunked_prefill_then_decode_is_the_references_one_forward(
     prompts put the selection's 16 keys and the window's 13 on both sides of
     a prompt's end and of a chunk's; the answers run the rings (16 rows)
     round more than once, past rows that are no page's first."""
-    from benchmarks.lib.serve_taps import ServeTaps, serve_and_read
-
     m, params = model
-    with ServeTaps() as taps:
-        serve = serve_of(model)
-        ps = [np.random.default_rng(n).integers(0, 96, n) for n in prompts]
-        served = serve_and_read(taps, serve, ps, [41, 23])
+    ps = [np.random.default_rng(n).integers(0, 96, n) for n in prompts]
+    served = read_served(tapped, ps, [41, 23])
     for p, rec in zip(ps, served):
         seq = np.concatenate([p, rec["tokens"]])
         rows = list(range(len(p) - 1, len(seq) - 1))

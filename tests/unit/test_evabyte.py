@@ -31,6 +31,7 @@ from deepspeed_tpu.models.decoding import (_scatter_view, forward_with_cache,
 from deepspeed_tpu.models.fused_decode import (decode_step,
                                                inject_decode_params)
 from deepspeed_tpu.serving.paged_kv import PagedKVPool, init_paged_kv_cache
+from tests.unit._serving import as_found
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -164,19 +165,21 @@ def test_prefill_chunks_then_decode_across_two_boundaries(ref, model, params,
     n_prompt = 40
     got, cache = _prefill_paged(model, params, pool, cache, TOKENS[:n_prompt])
     dparams = inject_decode_params(params, cfg)
+    # one program for the 64 steps, position and table its operands (as the
+    # engine's decode block has them), not 64 op-by-op dispatches
+    if path == "fused":
+        step = jax.jit(lambda tok, cache, pos, pt: decode_step(
+            cfg, dparams, tok, cache, pos, page_table=pt))
+    else:
+        step = jax.jit(lambda tok, cache, pos, pt: forward_with_cache(
+            model, params, tok, cache, pos, page_table=pt))
     rows = [got]
     for p in range(n_prompt, len(TOKENS)):
         assert pool.ensure(0, p + 1)
-        pt = jnp.asarray(pool.page_table)
+        pt = jnp.asarray(pool.page_table.copy())
         tok, pos = jnp.asarray(TOKENS[p:p + 1])[None], jnp.asarray([p])
-        if path == "fused":
-            lg, cache = decode_step(cfg, dparams, tok, cache, pos,
-                                    page_table=pt)
-        else:
-            lg, cache = forward_with_cache(model, params, tok, cache, pos,
-                                           page_table=pt)
-            lg = lg[:, -1]
-        rows.append(np.asarray(lg))
+        lg, cache = step(tok, cache, pos, pt)
+        rows.append(np.asarray(lg if path == "fused" else lg[:, -1]))
     # 4 window pages, and the summary pages of the three closed windows
     assert pool.slot_pages_used(0) == 4 + -(-3 * (W // C) // PAGE)
     np.testing.assert_allclose(np.concatenate(rows),
@@ -503,6 +506,15 @@ def _serve(model, params, **over):
                                       params=params)
 
 
+@pytest.fixture(scope="module")
+def engine(model, params):
+    """One engine at ``ENGINE`` for the cases that differ in their requests
+    alone."""
+    serve = _serve(model, params)
+    yield serve
+    serve.close()
+
+
 def _prompts():
     rng = np.random.default_rng(5)
     return ([rng.integers(0, V, size=n) for n in (45, 70, 20, 33)],
@@ -518,24 +530,26 @@ def _served_tokens_are_the_references_best(ref, params, prompt, out):
 
 @pytest.mark.parametrize("fused", [True, False])
 def test_engine_serves_across_windows_and_returns_both_page_kinds(
-        ref, model, params, fused):
+        ref, model, params, engine, fused):
     """Four requests on three slots through ``init_serving`` and ``step()``:
     chunked prefill, continuous batching, windows closing in prefill and in
     decode; every served token is the reference's best of head 0, and the
     pool is whole afterwards."""
-    serve = _serve(model, params, use_fused_decode=fused)
+    assert engine.engine._dparams is not None  # ENGINE's own is fused
+    serve = engine if fused else _serve(model, params, use_fused_decode=False)
     assert serve.prefix_cache is None          # switched off, not refused
     prompts, news = _prompts()
-    reqs = [serve.submit(p, max_new_tokens=n, stream=True)
-            for p, n in zip(prompts, news)]
-    serve.run()
-    serve.pool.check_no_leak()
+    with as_found(serve):
+        reqs = [serve.submit(p, max_new_tokens=n, stream=True)
+                for p, n in zip(prompts, news)]
+        serve.run()
     assert serve.pool.pages_used == 0
     for p, r, n in zip(prompts, reqs, news):
         assert len(r.output_tokens) == n and r.finish_reason == "length"
         _served_tokens_are_the_references_best(
             ref, params, p, np.asarray(r.output_tokens))
-    serve.close()
+    if not fused:
+        serve.close()
 
 
 @pytest.mark.parametrize("places", [1, 2, 4])
@@ -571,15 +585,15 @@ def test_chunks_of_one_iteration_close_windows_for_each_other(ref, model,
     reg.disable()
 
 
-def test_preempted_request_resumes_to_the_same_tokens(model, params):
+def test_preempted_request_resumes_to_the_same_tokens(model, params, engine):
     """A pool too small for three long requests: the youngest is preempted,
     gives back every page of both kinds, resumes by recompute and yields the
     tokens an unpressed engine yields."""
     prompts, news = _prompts()
-    calm = _serve(model, params)
-    want = [calm.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    calm.run()
-    calm.close()
+    with as_found(engine) as calm:
+        want = [calm.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        calm.run()
     # 9 pages a slot at most; 14 usable pages for three slots
     tight = _serve(model, params, kv_pool_tokens=14 * PAGE)
     reqs = [tight.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]

@@ -656,9 +656,16 @@ def test_walked_pages_under_the_chips_interpreter(Hkv, rep, dtype, capfd,
 
     params = pltpu.InterpretParams(detect_races=True,
                                    dma_execution_mode="on_wait")
-    monkeypatch.setattr(decode, "interpret_flag", lambda impl: params)
-    # the call is a jitted function: none traced under the other interpreter
-    jax.clear_caches()
+    asked = []
+    monkeypatch.setattr(decode, "interpret_flag",
+                        lambda impl: asked.append(impl) or params)
+    # the call is a jitted function: traced here, under this interpreter,
+    # through a ``jit`` of its own, which goes with the case; clearing jax's
+    # caches to the same end cost the worker every file's programs
+    walk = decode._walk_pages.__wrapped__
+    monkeypatch.setattr(decode, "_walk_pages", jax.jit(
+        lambda *a, **kw: walk(*a, **kw),
+        static_argnames=("scale", "alibi", "impl")))
     B, Dh, page, maxp = 4, 128, 256, 3
     posv, live = [300, 63, 767, 5], [True, True, False, True]
     q = _rand(0, B, Hkv * rep, Dh, dtype=dtype)
@@ -677,9 +684,9 @@ def test_walked_pages_under_the_chips_interpreter(Hkv, rep, dtype, capfd,
     np.testing.assert_allclose(got[rows], np.float32(want)[rows], rtol=tol,
                                atol=tol)
     np.testing.assert_array_equal(got[2], np.float32(q[2]))
+    assert asked == ["interpret"]     # traced here, under this interpreter
     assert not interpret_pallas_call.races.races_found
     assert "non-zero count" not in capfd.readouterr().out
-    jax.clear_caches()
 
 
 def _pallas_calls(jaxpr):

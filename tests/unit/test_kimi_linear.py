@@ -22,6 +22,7 @@ import pytest
 import deepspeed_tpu
 from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, afmoe, kda_mla
+from tests.unit._serving import as_found, with_noise
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
                                     os.pardir))
@@ -68,11 +69,8 @@ def ref():
 def model():
     m = CausalLM(ModelConfig(**FIELDS),
                  build_mesh(devices=jax.devices()[:1]))
-    params = m.init(jax.random.PRNGKey(0))
     # gains of exactly 1 would hide a dropped norm
-    noise = iter(jax.random.split(jax.random.PRNGKey(1), 64))
-    return m, jax.tree.map(
-        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+    return m, with_noise(m.init(jax.random.PRNGKey(0)))
 
 
 def ref_logits(ref, params, seq, rows, config=REF_CONFIG, **kw):
@@ -84,6 +82,21 @@ def serve_of(model, **kw):
     m, params = model
     return deepspeed_tpu.init_serving(m, config=dict(ENGINE, **kw),
                                       params=params, mesh=m.mesh)
+
+
+@pytest.fixture(scope="module")
+def engine(model):
+    """One engine at ``ENGINE`` for the cases that differ in their requests
+    alone."""
+    serve = serve_of(model)
+    yield serve
+    serve.close()
+
+
+@pytest.fixture
+def shared(engine):
+    with as_found(engine):
+        yield engine
 
 
 def test_reference_agrees_with_the_no_cache_forward(ref, model):
@@ -100,12 +113,13 @@ def test_reference_agrees_with_the_no_cache_forward(ref, model):
 @pytest.mark.parametrize("prompt", [16, 15, 17, 37, 48, 5],
                          ids=["on_a_chunk", "one_short", "one_past",
                               "padded_bucket", "three_chunks", "tiny"])
-def test_served_tokens_are_the_references_argmax(ref, model, prompt):
+def test_served_tokens_are_the_references_argmax(ref, model, shared,
+                                                 prompt):
     """Prefill in chunks of 16 (the state carried from chunk to chunk, pad
     rows of the last bucket idle), then decode through the slot state and the
     latent pages, in float32: every served token is the argmax of the
     reference's full forward at its position."""
-    serve = serve_of(model)
+    serve = shared
     p = np.random.default_rng(prompt).integers(0, 96, prompt)
     r = serve.submit(p, max_new_tokens=21)
     serve.run()
@@ -114,7 +128,6 @@ def test_served_tokens_are_the_references_argmax(ref, model, prompt):
     seq = np.concatenate([p, r.output_tokens])
     want = ref_logits(ref, model[1], seq, list(range(prompt - 1, len(seq) - 1)))
     assert list(want.argmax(-1)) == list(r.output_tokens)
-    serve.close()
 
 
 @pytest.mark.parametrize("places", [1, 2, 4])
@@ -142,14 +155,14 @@ def test_chunks_of_one_iteration_hand_the_state_on(ref, model, places):
     serve.close()
 
 
-def test_preempt_and_resume_are_token_identical(model):
+def test_preempt_and_resume_are_token_identical(model, shared):
     """A pool of nine pages for three slots: the youngest is preempted,
     re-prefills prompt + outputs onto a zeroed state, and every request
     still gets the tokens an unpressed engine gives it."""
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, 96, n) for n in (22, 30, 17)]
     news = (30, 24, 36)
-    easy, tight = serve_of(model), serve_of(model, kv_pool_tokens=96)
+    easy, tight = shared, serve_of(model, kv_pool_tokens=96)
     want = [easy.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
     got = [tight.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
     easy.run()
@@ -158,7 +171,6 @@ def test_preempt_and_resume_are_token_identical(model):
     assert sum(r.preemptions for r in got) > 0
     for w, g in zip(want, got):
         assert list(g.output_tokens) == list(w.output_tokens)
-    easy.close()
     tight.close()
 
 

@@ -27,6 +27,7 @@ from deepspeed_tpu.models import CausalLM, ModelConfig, get_model_config
 from deepspeed_tpu.moe.sharded_moe import moe_mlp
 from deepspeed_tpu.ops.pallas import common
 from deepspeed_tpu.ops.pallas.decode import fused_moe_mlp
+from tests.unit._serving import with_noise
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -68,10 +69,7 @@ def ref_config(cfg):
 
 def seeded(model, seed=0):
     """Weights with every norm scale off 1, so a dropped scale shows."""
-    params = model.init(jax.random.PRNGKey(seed))
-    noise = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-    return jax.tree.map(
-        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+    return with_noise(model.init(jax.random.PRNGKey(seed)), seed + 1)
 
 
 def tokens_of(n, seed=0):

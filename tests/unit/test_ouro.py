@@ -28,6 +28,7 @@ from deepspeed_tpu.monitor.metrics import MetricsRegistry
 from deepspeed_tpu.ops.pallas import common
 from deepspeed_tpu.serving import handoff
 from deepspeed_tpu.serving.cache_kind import FullPages, cache_kind
+from tests.unit._serving import with_noise
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -70,10 +71,7 @@ def ref_config(cfg):
 
 def seeded(model, seed=0):
     """Weights with every norm gain off 1, so a dropped gain shows."""
-    params = model.init(jax.random.PRNGKey(seed))
-    noise = iter(jax.random.split(jax.random.PRNGKey(seed + 1), 64))
-    return jax.tree.map(
-        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+    return with_noise(model.init(jax.random.PRNGKey(seed)), seed + 1)
 
 
 def tokens_of(n, seed=0):

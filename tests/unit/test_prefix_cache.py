@@ -355,6 +355,66 @@ def test_preempt_resume_re_prefills_through_cache(setup, rng):
         serve.close()
 
 
+def _on_64_bytes(a):
+    """A copy of ``a`` that starts on a 64-byte boundary, where the CPU
+    client takes host memory as the device's own instead of copying it."""
+    raw = np.zeros(a.nbytes + 64, np.uint8)
+    off = -raw.ctypes.data % 64
+    out = raw[off:off + a.nbytes].view(a.dtype).reshape(a.shape)
+    out[...] = a
+    return out
+
+
+def test_programs_get_snapshots_of_the_hosts_tables(setup, rng, monkeypatch):
+    """ISSUE 61: the page table, the position bounds and the EOS ids are host
+    arrays that the engine writes in place (ensure, preempt, release, adopt,
+    park) while the programs it handed them to may still be queued, and
+    ``jnp.asarray`` of host memory need not copy: the CPU client aliases an
+    array on a 64-byte boundary, which numpy's allocator gives about one
+    array in four.  So what a chunk program and a decode block are handed
+    shares no memory with the host's arrays, and with every one of them
+    ON such a boundary the preempt-resume schedule of the case above (red
+    about one process in eight before, on the same schedule) serves the
+    reference's tokens."""
+    model, params, ref = setup
+    serve = _serve(model, params, kv_pool_tokens=80)
+    try:
+        serve.pool.page_table = _on_64_bytes(serve.pool.page_table)
+        serve._limit = _on_64_bytes(serve._limit)
+        serve._eos = _on_64_bytes(serve._eos)
+        hosts = (serve.pool.page_table, serve._limit, serve._eos)
+        handed = []
+
+        def watched(program, positions):
+            def call(*args):
+                handed.extend(
+                    any(np.shares_memory(np.asarray(args[i]), h)
+                        for h in hosts) for i in positions)
+                return program(*args)
+            return call
+
+        chunk_fn, block = serve._prefill_fn, serve._block
+        # (params, cache, carries, table row, chunk, meta, rng)
+        monkeypatch.setattr(serve, "_prefill_fn",
+                            lambda cb: watched(chunk_fn(cb), (3,)))
+        # (params, cache, last, pos, active, limit, eos, rng, table)
+        monkeypatch.setattr(serve, "_block",
+                            lambda: watched(block(), (5, 6, 8)))
+        k1, k2 = jax.random.split(rng)
+        prompts = [np.asarray(jax.random.randint(k1, (18,), 0, 256)),
+                   np.asarray(jax.random.randint(k2, (19,), 0, 256))]
+        reqs = [serve.submit(p, max_new_tokens=30) for p in prompts]
+        serve.run()
+        assert sum(r.preemptions for r in reqs) >= 1
+        assert len(handed) > 8 and not any(handed), \
+            "a program was handed the host's own array, not a snapshot"
+        for req, p in zip(reqs, prompts):
+            np.testing.assert_array_equal(np.asarray(req.output_tokens),
+                                          _ref_out(ref, p, 30))
+    finally:
+        serve.close()
+
+
 def test_prefix_cache_off_serves_the_same_tokens(setup, rng):
     """``prefix_caching=False`` serves token-identically with zero cache
     state."""

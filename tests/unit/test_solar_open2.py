@@ -25,6 +25,8 @@ from deepspeed_tpu.comm.mesh import build_mesh
 from deepspeed_tpu.models import CausalLM, ModelConfig, afmoe, kda_mla
 from deepspeed_tpu.serving import cache_kind
 from deepspeed_tpu.serving.paged_kv import PagedKVPool
+from tests.unit._serving import (as_found, read_served, tapped_engine,
+                                  with_noise)
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir,
                                     os.pardir))
@@ -69,11 +71,8 @@ def ref():
 def model():
     m = CausalLM(ModelConfig(**FIELDS),
                  build_mesh(devices=jax.devices()[:1]))
-    params = m.init(jax.random.PRNGKey(0))
     # gains of exactly 1 would hide a dropped norm
-    noise = iter(jax.random.split(jax.random.PRNGKey(1), 64))
-    return m, jax.tree.map(
-        lambda a: a + 0.05 * jax.random.normal(next(noise), a.shape), params)
+    return m, with_noise(m.init(jax.random.PRNGKey(0)))
 
 
 def ref_logits(ref, params, seq, rows, config=REF_CONFIG, **kw):
@@ -85,6 +84,13 @@ def serve_of(model, **kw):
     m, params = model
     return deepspeed_tpu.init_serving(m, config=dict(ENGINE, **kw),
                                       params=params, mesh=m.mesh)
+
+
+@pytest.fixture(scope="module")
+def tapped(model):
+    """One engine at ``ENGINE``, its programs traced under the serve taps,
+    for the cases that differ in their requests alone."""
+    yield from tapped_engine(lambda: serve_of(model))
 
 
 # ------------------------------------------- (a) system against reference
@@ -109,7 +115,7 @@ def test_reference_agrees_with_the_no_cache_forward(ref, model):
                               "two_chunks_and_a_pad_bucket_then_tiny",
                               "three_chunks_then_one_past"])
 def test_chunked_prefill_then_decode_is_the_references_one_forward(
-        ref, model, prompts):
+        ref, model, tapped, prompts):
     """Prefill in chunks of UNEQUAL size (16 then 11 real rows in a bucket
     of 16: the state and the tails carried over, the later chunk's queries
     attending the K/V rows the earlier one wrote through the page table),
@@ -119,15 +125,9 @@ def test_chunked_prefill_then_decode_is_the_references_one_forward(
     program's routing (5e-4: the same two approximations as above plus the
     cache's round trip, no bf16 anywhere), and every token is the argmax of
     the reference under its OWN routing."""
-    from benchmarks.lib.serve_taps import ServeTaps, serve_and_read
-
-    with ServeTaps() as taps:
-        serve = serve_of(model)
-        ps = [np.random.default_rng(n).integers(0, 96, n) for n in prompts]
-        served = serve_and_read(taps, serve, ps, [21, 13])
-        serve.pool.check_no_leak()
-        assert serve.pool.pages_used == 0
-        serve.close()
+    ps = [np.random.default_rng(n).integers(0, 96, n) for n in prompts]
+    served = read_served(tapped, ps, [21, 13])
+    assert tapped[1].pool.pages_used == 0
     for p, rec in zip(ps, served):
         seq = np.concatenate([p, rec["tokens"]])
         rows = list(range(len(p) - 1, len(seq) - 1))
@@ -161,23 +161,24 @@ def test_the_served_logits_tell_a_beta_without_the_two(ref, model):
     assert np.abs(got - want).max() > 0.05
 
 
-def test_preempt_and_resume_are_token_identical(model):
+def test_preempt_and_resume_are_token_identical(model, tapped):
     """A pool of twelve pages for three slots: the youngest is preempted,
     re-prefills prompt + outputs onto a zeroed state and fresh pages, and
     every request still gets the tokens an unpressed engine gives it."""
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, 96, n) for n in (22, 30, 17)]
     news = (30, 24, 36)
-    easy, tight = serve_of(model), serve_of(model, kv_pool_tokens=96)
-    want = [easy.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    tight = serve_of(model, kv_pool_tokens=96)
+    with as_found(tapped[1]) as easy:
+        want = [easy.submit(p, max_new_tokens=n)
+                for p, n in zip(prompts, news)]
+        easy.run()
     got = [tight.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
-    easy.run()
     tight.run()
     tight.pool.check_no_leak()
     assert sum(r.preemptions for r in got) > 0
     for w, g in zip(want, got):
         assert list(g.output_tokens) == list(w.output_tokens)
-    easy.close()
     tight.close()
 
 
@@ -480,17 +481,17 @@ def test_every_cannot_entry_is_refused_by_name(model, option):
     assert kind.cannot[option] in str(err.value) and names in str(err.value)
 
 
-def test_generate_training_and_prefill_only_are_refused(model):
+def test_generate_training_and_prefill_only_are_refused(model, tapped):
     m, params = model
-    serve = serve_of(model)
-    with pytest.raises(NotImplementedError, match="prefill_only"):
-        serve.submit([1, 2, 3], prefill_only=True)
-    with pytest.raises(NotImplementedError, match="init_serving"):
-        serve.engine.generate(np.zeros((1, 4), np.int32), max_new_tokens=2)
+    with as_found(tapped[1]) as serve:
+        with pytest.raises(NotImplementedError, match="prefill_only"):
+            serve.submit([1, 2, 3], prefill_only=True)
+        with pytest.raises(NotImplementedError, match="init_serving"):
+            serve.engine.generate(np.zeros((1, 4), np.int32),
+                                  max_new_tokens=2)
     with pytest.raises(NotImplementedError, match="served only"):
         m.apply(params, np.zeros((1, 4), np.int32),
                 labels=np.zeros((1, 4), np.int32))
-    serve.close()
 
 
 def test_counters_count_state_steps_resets_and_kv_rows(model):
