@@ -358,13 +358,15 @@ def _alibi_slopes(H: int, hkv: int, alibi: bool):
 
 
 def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
-                      nb, scale, alibi, impl, name, kernel=None):
-    """The one ``pallas_call`` behind every cache layout.  The caches are
+                      nb, scale, alibi, impl, name):
+    """The ``pallas_call`` behind every cache layout whose key blocks come a
+    grid step each (the contiguous cache, latent pages, paged rows under
+    the lane tile; per-head pages that fill the lanes are walked inside a
+    step: :func:`_walk_pages`).  The caches are
     taken as ``[N, Hkv, S, Dh]`` views (N = stacked layers x batch rows, or
     x physical pages: a free reshape); ``kv_map(b, g, j, pos_ref,
     *table_refs)`` places the ``(1, hb, block, Dh)`` K and V blocks of batch
-    row ``b``, head group ``g``, key block ``j``.  ``kernel`` replaces the
-    body (same refs; ``_eva_decode_kernel`` masks by two lengths a row).
+    row ``b``, head group ``g``, key block ``j``.
 
     The grid follows the batch, not the slot count: ``live`` [B] bool names
     the rows that decode (None: all of them), and the grid is ``(live rows,
@@ -373,9 +375,9 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
     (scalar-prefetched ahead of ``pos``), so a row that does not decode costs
     no grid step and no page fetch; its output is its ``q``, which the
     output is aliased onto.  No live row at all is a grid of no steps.
-    ``nb`` is a row's key blocks; under the default body, whose blocks are
-    one run up to ``pos``, the third extent stops at the deepest live row's
-    last one, ``max(pos // block + 1)`` over them.
+    ``nb`` is a row's key blocks, one run up to ``pos``: the third extent
+    stops at the deepest live row's last one, ``max(pos // block + 1)`` over
+    them.
 
     ``vcache`` None (latent pages, :func:`mla_decode_paged`): the values
     are the key rows themselves, and the block is fetched ONCE: the body
@@ -390,10 +392,9 @@ def _decode_attention(q, kcache, vcache, pos, tables, kv_map, *, live, block,
     rows, n_live = _live_rows(live, B)
     if live is not None:
         depth = jnp.where(live, depth, 0)
-    if kernel is None:
-        nb = jnp.minimum(nb, jnp.max(depth))
-        kernel = functools.partial(_flash_decode_kernel, scale=scale,
-                                   block=block, alibi=alibi)
+    nb = jnp.minimum(nb, jnp.max(depth))
+    kernel = functools.partial(_flash_decode_kernel, scale=scale,
+                               block=block, alibi=alibi)
     caches = [kcache.reshape(view)]
     if vcache is None:
         body = kernel      # refs end q, k, slopes, o, m, l, acc: k again as v
@@ -532,9 +533,9 @@ def _paged_append(pools, new_rows, pos, page_table, layer, impl):
     return tuple(o.reshape(c.shape) for o, c in zip(out, pools))
 
 
-# tokens one copy of a live row's LAST page fetches: that page comes in
-# ``(pos % page) // FETCH_ROWS + 1`` copies where every page before it comes in
-# one, so a row fetches 32 rows it does not attend on average, not half a page.
+# rows one copy of the LAST page of a run of pages fetches: that page comes
+# in ``_last_page_pieces`` copies where every page before it comes in one, so
+# a run fetches 32 rows it does not attend on average, not half a page.
 # ``serving/cache_kind.py`` counts the fetched keys by this rule
 FETCH_ROWS = 64
 
@@ -548,61 +549,117 @@ def walks_pages(head_dim: int) -> bool:
     return head_dim % 128 == 0
 
 
+def _page_runs(page: int, window: Optional[int] = None,
+               chunk: Optional[int] = None):
+    """The runs of table columns a decoding row attends, in the order its
+    grid step walks them (:func:`_walk_pages`): ``(first column, rows that
+    count at pos)`` each, the rows a function of the row's position (an int,
+    an array or a scalar read from SMEM).  A run of ``n`` rows is its first
+    ``ceil(n / page)`` columns, none at ``n = 0``; the first run is never
+    empty.  Full pages are one run up to ``pos``; EVA (``window``,
+    ``chunk``: ``models/eva.py``) attends the rows of its open window, then
+    the ``window / chunk`` summary rows of every window that has closed."""
+    if window is None:
+        return ((0, lambda pos: pos + 1),)
+    return ((0, lambda pos: pos % window + 1),
+            (window // page, lambda pos: pos // window * (window // chunk)))
+
+
+def _last_page_pieces(n, page: int):
+    """Copies of :data:`FETCH_ROWS` rows that bring in the last page of a
+    run of ``n > 0`` rows that count: the kernel's copies and the counters'
+    keys both come from here."""
+    return (n - 1) % page // FETCH_ROWS + 1
+
+
+def _keys_fetched(pos, page: int, head_dim: int, **runs):
+    """Keys the step at ``pos`` (an int or an array of them) brings into
+    VMEM for one KV head of one cache layer: of each run of
+    :func:`_page_runs` the pages before its last whole, the last in pieces
+    up to the rows that count where the kernel walks the pages
+    (:func:`walks_pages`), whole where it does not."""
+    total = 0
+    for _, rows in _page_runs(page, **runs):
+        n = rows(pos)
+        last = (_last_page_pieces(n, page) * FETCH_ROWS
+                if walks_pages(head_dim) else page)
+        total = total + (n > 0) * ((n - 1) // page * page + last)
+    return total
+
+
 def paged_keys_fetched(pos, page: int, head_dim: int):
-    """Keys a decode step at ``pos`` (an int or an array of them) brings
-    into VMEM for one KV head of one cache layer: the pages before the
-    row's last whole, the last in pieces of :data:`FETCH_ROWS` up to ``pos``
-    where the kernel walks the pages, whole where it does not."""
-    if not walks_pages(head_dim):
-        return (pos // page + 1) * page
-    return pos // page * page + (pos % page // FETCH_ROWS + 1) * FETCH_ROWS
+    """:func:`_keys_fetched` of ``flash_decode`` over the paged pool: one
+    run of pages up to ``pos``."""
+    return _keys_fetched(pos, page, head_dim)
+
+
+def eva_keys_fetched(pos, page: int, head_dim: int, window: int, chunk: int):
+    """:func:`_keys_fetched` of :func:`eva_decode_paged`: the window pages
+    up to row ``pos % window``, then the summary pages up to the closed
+    windows' last row."""
+    return _keys_fetched(pos, page, head_dim, window=window, chunk=chunk)
 
 
 def _flash_decode_paged_kernel(rows_ref, pos_ref, base_ref, pt_ref, q_ref,
                                k_hbm, v_hbm, slope_ref, o_ref, m_scr, l_scr,
                                acc_scr, k_buf, v_buf, sems, slot_ref, *,
-                               scale, alibi):
+                               scale, alibi, runs):
     """One grid step = one LIVE batch row x ``hb`` KV heads, the row's pages
-    walked inside it (:func:`_flash_decode_paged`).  ``k_hbm`` / ``v_hbm``
-    are the pools where they lie, ``k_buf`` / ``v_buf`` [2, hb, page, Dh] the
-    two pages in VMEM, ``sems`` [K | V, slot] their copies' semaphores,
-    ``base_ref`` [1] the layer's first page in the pools and ``slot_ref`` the
-    slot the step's first page is on its way to (set by the step before it,
-    which started that copy)."""
+    walked inside it (:func:`_walk_pages`), run after run of ``runs``
+    (:func:`_page_runs`).  ``k_hbm`` / ``v_hbm`` are the pools where they
+    lie, ``k_buf`` / ``v_buf`` [2, hb, page, Dh] the two pages in VMEM,
+    ``sems`` [K | V, slot] their copies' semaphores, ``base_ref`` [1] the
+    layer's first page in the pools and ``slot_ref`` the slot the step's
+    first page is on its way to (set by the step before it, which started
+    that copy)."""
     i, g = pl.program_id(0), pl.program_id(1)
     _, hb, page, _ = k_buf.shape
     groups = k_hbm.shape[1] // hb
     whole = page // FETCH_ROWS
     b = rows_ref[i]
     pos = pos_ref[b]
-    last = pos // page
 
-    def copies(b, g, j, slot, rows):
-        at = base_ref[0] + pt_ref[b, j]
+    def locate(b, t):
+        """(table column, rows that count from its first on) of page ``t``
+        of row ``b``'s walk, and the pages the walk has."""
+        p, total, at = pos_ref[b], 0, None
+        for col0, rows in runs:
+            n, j = rows(p), t - total
+            here = (col0 + j, n - j * page)
+            # a later run's page where ``t`` has come to it
+            at = here if at is None else tuple(
+                jnp.where(j >= 0, new, old) for new, old in zip(here, at))
+            total = total + (n + page - 1) // page
+        return (*at, total)
+
+    def copies(b, g, col, slot, rows):
+        at = base_ref[0] + pt_ref[b, col]
         return [pltpu.make_async_copy(pool.at[at, pl.ds(g * hb, hb), rows],
                                       buf.at[slot, :, rows], sems.at[n, slot])
                 for n, (pool, buf) in enumerate(((k_hbm, k_buf),
                                                  (v_hbm, v_buf)))]
 
-    def page_copies(b, g, j, slot, act):
-        """``act`` ("start" or "wait") each copy of row ``b``'s page ``j``:
-        one of the page, or of its last page the pieces up to ``pos``."""
-        p = pos_ref[b]
-        n = (p % page) // FETCH_ROWS + 1
-        n = jnp.where((j < p // page) | (n == whole), 0, n)
+    def page_copies(b, g, t, slot, act):
+        """``act`` ("start" or "wait") each copy of page ``t`` of row
+        ``b``'s walk: one of the page, or of a run's last page the pieces up
+        to its last row that counts.  Returns the page's rows that count."""
+        col, cnt, _ = locate(b, t)
+        n = _last_page_pieces(cnt, page)
+        n = jnp.where((cnt > page) | (n == whole), 0, n)
 
         @pl.when(n == 0)
         def _whole():
-            for c in copies(b, g, j, slot, slice(None)):
+            for c in copies(b, g, col, slot, slice(None)):
                 getattr(c, act)()
 
         def piece(c, carry):
             at = pl.multiple_of(c * FETCH_ROWS, FETCH_ROWS)
-            for d in copies(b, g, j, slot, pl.ds(at, FETCH_ROWS)):
+            for d in copies(b, g, col, slot, pl.ds(at, FETCH_ROWS)):
                 getattr(d, act)()
             return carry
 
         jax.lax.fori_loop(0, n, piece, 0)
+        return cnt
 
     @pl.when((i == 0) & (g == 0))
     def _first():
@@ -617,35 +674,37 @@ def _flash_decode_paged_kernel(rows_ref, pos_ref, base_ref, pt_ref, q_ref,
     more = i1 < pl.num_programs(0)
     b1 = rows_ref[jnp.where(more, i1, i)]
     rows = jax.lax.broadcasted_iota(jnp.int32, (1,) + v_buf.shape[2:], 1)
+    pages = locate(b, 0)[2]
 
-    def one_page(j, carry):
-        slot = (slot0 + j) % 2
-        inside = j < last
+    def one_page(t, carry):
+        slot = (slot0 + t) % 2
+        inside = t + 1 < pages
 
         # the page after this one leaves before this one is scored
         @pl.when(inside | more)
         def _next():
             page_copies(jnp.where(inside, b, b1), jnp.where(inside, g, g1),
-                        jnp.where(inside, j + 1, 0), 1 - slot, "start")
+                        jnp.where(inside, t + 1, 0), 1 - slot, "start")
 
-        page_copies(b, g, j, slot, "wait")
+        cnt = page_copies(b, g, t, slot, "wait")
         q, k = q_ref[0], k_buf[slot]                # [hb, rep | page, Dh]
         if q.dtype != k.dtype:
             q, k = q.astype(jnp.float32), k.astype(jnp.float32)
         s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
                                 preferred_element_type=jnp.float32) * scale
-        key_pos = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        if alibi:
-            s = s + slope_ref[:] * (key_pos - pos).astype(jnp.float32)
-        s = jnp.where(key_pos <= pos, s, NEG_INF)   # [hb, rep, page]
-        # rows of the buffer past ``pos`` hold what the pool holds there, or
-        # an earlier page: they weigh exactly 0, and their values count as 0
+        key = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        if alibi:       # one run: page t holds positions t * page and on
+            s = s + slope_ref[:] * (t * page + key - pos).astype(jnp.float32)
+        s = jnp.where(key < cnt, s, NEG_INF)        # [hb, rep, page]
+        # rows of the buffer past those that count hold what the pool holds
+        # there (a window page: the window before's keys), or an earlier
+        # page: they weigh exactly 0, and their values count as 0
         _softmax_block(s, v_buf.at[pl.ds(slot, 1)], m_scr, l_scr, acc_scr,
-                       j * page + rows <= pos)
+                       rows < cnt)
         return carry
 
-    jax.lax.fori_loop(0, last + 1, one_page, 0)
-    slot_ref[0] = (slot0 + last + 1) % 2
+    jax.lax.fori_loop(0, pages, one_page, 0)
+    slot_ref[0] = (slot0 + pages) % 2
     _softmax_finish(o_ref, l_scr, acc_scr)
 
 
@@ -673,7 +732,8 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
     the page sizes :func:`paged_decode_reference_reason` names).  The
     layer's offset in the pools, a Python int or a traced scalar
     (:func:`paged_kv_append`), is prefetched with the table
-    (:func:`_walk_pages`); at a head dim under the lane tile
+    (:func:`_walk_pages`, which :func:`eva_decode_paged` calls with its two
+    runs of pages a row); at a head dim under the lane tile
     (:func:`walks_pages`) a traced one is added to the table's page
     numbers."""
     kc = kcache if layer is None else kcache[layer]
@@ -710,19 +770,24 @@ def _flash_decode_paged(q, kcache, vcache, pos, page_table, *, scale,
         jnp.reshape(base, (1,)).astype(jnp.int32), page_table,
         q.reshape(B, hkv, H // hkv, Dh), kcache.reshape(view),
         vcache.reshape(view), _alibi_slopes(H, hkv, alibi), scale=scale,
-        alibi=alibi, impl=impl)
+        alibi=alibi, impl=impl, name="flash_decode_paged")
     return o.reshape(B, H, Dh)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "alibi", "impl"))
+@functools.partial(jax.jit, static_argnames=("scale", "alibi", "impl", "name",
+                                             "window", "chunk"))
 def _walk_pages(rows, n_live, pos, base, page_table, q, kcache, vcache,
-                slopes, *, scale, alibi, impl):
-    """:func:`_flash_decode_paged`'s ``pallas_call``: ``q`` [B, Hkv, rep,
-    Dh], the pools [N, Hkv, page, Dh], ``base`` [1] the first page of the
-    layer read.  A function of its own under ``jit`` with the layer's
-    offset an OPERAND, so the calls of a model's layers are one traced and
-    lowered kernel, not one a layer: the decode block of a twelve-layer
-    stack is traced and lowered in the time of one call's."""
+                slopes, *, scale, alibi, impl, name, window=None, chunk=None):
+    """The ``pallas_call`` that walks a live row's pages inside one grid
+    step, :func:`_flash_decode_paged`'s and (``window``, ``chunk``)
+    :func:`eva_decode_paged`'s: ``q`` [B, Hkv, rep, Dh], the pools [N, Hkv,
+    page, Dh], ``base`` [1] the first page of the layer read, the runs of
+    table columns a row attends by :func:`_page_runs`.  A function of its
+    own under ``jit`` with the layer's offset an OPERAND, so the calls of a
+    model's layers are one traced and lowered kernel, not one a layer: the
+    decode block of a twelve-layer stack is traced and lowered in the time
+    of one call's."""
+    assert window is None or not alibi      # key positions: one run's
     B, hkv, rep, Dh = q.shape
     page = kcache.shape[2]
     hb = _kv_heads_per_step(hkv, page, Dh, kcache.dtype.itemsize)
@@ -744,13 +809,14 @@ def _walk_pages(rows, n_live, pos, base, page_table, q, kcache, vcache,
     )
     return pl.pallas_call(
         functools.partial(_flash_decode_paged_kernel, scale=scale,
-                          alibi=alibi),
+                          alibi=alibi,
+                          runs=_page_runs(page, window, chunk)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         # operands count the scalar-prefetch arrays: q follows them
         input_output_aliases={4: 0},
         interpret=interpret_flag(impl),
-        name="flash_decode_paged",
+        name=name,
     )(rows, pos, base, page_table, q, kcache, vcache, slopes)
 
 
@@ -1162,70 +1228,50 @@ def kda_decode_step(state, q, k, v, g, beta, *, layer: int, live=None,
 # and the pooling of a filled window into its summary rows
 # ---------------------------------------------------------------------------
 
-def _eva_decode_kernel(*refs, scale, block, wp, window, per):
-    """``_flash_decode_kernel`` with two valid lengths a row: grid steps
-    ``j < wp`` walk the row's window pages, of which rows ``[0, pos % W]``
-    count; steps ``j >= wp`` its summary pages, of which the first
-    ``(pos // W) * W/C`` rows count.  One online softmax over both."""
-    rows_ref, pos_ref = refs[:2]
-    q_ref, k_ref, v_ref, _, o_ref, m_scr, l_scr, acc_scr = refs[-8:]
-    j = pl.program_id(2)
-
-    pl.when(j == 0)(functools.partial(_softmax_init, m_scr, l_scr, acc_scr))
-
-    pos = pos_ref[rows_ref[pl.program_id(0)]]
-    # rows of this page that count
-    n_valid = jnp.where(j < wp, pos % window + 1 - j * block,
-                        (pos // window) * per - (j - wp) * block)
-
-    @pl.when(n_valid > 0)
-    def _compute():
-        q, k = q_ref[0], k_ref[0]                   # [hb, 1 | block, Dh]
-        s = jax.lax.dot_general(q, k, (((2,), (2,)), ((0,), (0,))),
-                                preferred_element_type=jnp.float32) * scale
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        _softmax_block(jnp.where(row < n_valid, s, NEG_INF), v_ref, m_scr,
-                       l_scr, acc_scr)
-
-    pl.when(j == pl.num_programs(2) - 1)(
-        functools.partial(_softmax_finish, o_ref, l_scr, acc_scr))
-
-
-def eva_reference_reason(page: int, window: int, chunk: int) -> Optional[str]:
+def eva_reference_reason(page: int, window: int, chunk: int,
+                         head_dim: Optional[int] = None) -> Optional[str]:
     """Why the EVA kernels cannot take these sizes (None = they can): a page
     is the decode kernel's key block along the 128 lanes, and the pooling
     kernel writes a page's ``page / chunk`` summaries as whole 16-row
-    tiles."""
+    tiles; the decode kernel (``head_dim`` given) copies its pages itself,
+    which takes rows that fill the lanes (:func:`walks_pages`)."""
     if page % 128:
         return f"page of {page} tokens is not a multiple of the 128-lane tile"
     if window % page or (page // chunk) % 16:
         return (f"window {window} / page {page} / chunk {chunk}: a page's "
                 f"summaries are not whole 16-row tiles")
+    if head_dim is not None and not walks_pages(head_dim):
+        return f"head dim {head_dim} does not fill the 128 lanes"
     return None
 
 
-def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer: int,
+def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer,
                      window: int, chunk: int,
                      sm_scale: Optional[float] = None, live=None,
                      impl: Optional[str] = None):
     """EVA decode attention over the paged pool.  q [B, H, Dh] at absolute
-    positions ``pos`` [B]; caches stacked [L, P, H, page, Dh] read at static
-    layer ``layer``; ``page_table`` [B, wp + sp]: a row's ``wp = W / page``
-    window pages, then its summary pages (``serving/paged_kv.py``).  One
-    grid step is one LIVE row (``live`` [B] bool, None: all;
-    :func:`_decode_attention`) x ``hb`` heads x one page, window pages first;
-    the index map clamps past the last page with a row that counts, so pages
-    a row does not attend are neither fetched nor computed.  The page axis
-    is two segments, so its extent stays the table's width: a live row still
-    pays a grid step for each page it does not attend, a row that does not
-    decode pays nothing."""
+    positions ``pos`` [B]; caches stacked [L, P, H, page, Dh] read at layer
+    ``layer`` (a Python int or a traced scalar); ``page_table`` [B, wp +
+    sp]: a row's ``wp = W / page`` window pages, then its summary pages
+    (``serving/paged_kv.py``).
+    :func:`_flash_decode_paged`'s schedule with two runs of pages a row
+    (:func:`_page_runs`): one grid step is one LIVE row (``live`` [B] bool,
+    None: all) x ``hb`` heads and walks the window pages ``0 .. (pos % W) //
+    page``, of which rows ``[0, pos % W]`` count, then the summary pages
+    that hold the ``(pos // W) W / C`` rows of the closed windows (none
+    before the first close), one online softmax over both.  A page before a
+    run's last is one copy, the last :func:`_last_page_pieces` copies of
+    :data:`FETCH_ROWS` rows; what a buffer holds past the rows that count (a
+    window page: the window before's keys) weighs 0 and its values count as
+    0.  Pages a row does not attend cost nothing, a row that does not decode
+    costs nothing and gets its ``q`` back."""
     impl = resolve_impl(impl)
     B, H, Dh = q.shape
-    L, P, _, page, _ = kcache.shape
+    L, P, hkv, page, _ = kcache.shape
     scale = sm_scale if sm_scale is not None else 1.0 / (Dh ** 0.5)
     pos = jnp.asarray(pos, jnp.int32)
     impl = kernel_or_reference("eva_decode_paged", impl,
-                               eva_reference_reason(page, window, chunk))
+                               eva_reference_reason(page, window, chunk, Dh))
     if impl == "xla":
         from deepspeed_tpu.models import eva
         from deepspeed_tpu.models.decoding import paged_logical_view
@@ -1234,25 +1280,16 @@ def eva_decode_paged(q, kcache, vcache, pos, page_table, *, layer: int,
             q[:, :, None], paged_logical_view(kcache[layer], page_table),
             paged_logical_view(vcache[layer], page_table), pos[:, None],
             window=window, chunk=chunk, scale=scale)[:, :, 0]
-    wp, per = window // page, window // chunk
-    base = layer * P
-
-    def page_map(b, g, j, pos_ref, pt_ref):
-        p = pos_ref[b]
-        last_win = (p % window) // page
-        sum_pages = ((p // window) * per + page - 1) // page
-        col = jnp.where(
-            j < wp, jnp.minimum(j, last_win),
-            jnp.where(sum_pages > 0,
-                      wp + jnp.minimum(j - wp, sum_pages - 1), last_win))
-        return base + pt_ref[b, col], g, 0, 0
-
-    kernel = functools.partial(_eva_decode_kernel, scale=scale, block=page,
-                               wp=wp, window=window, per=per)
-    return _decode_attention(
-        q, kcache, vcache, pos, (page_table.astype(jnp.int32),), page_map,
-        live=live, block=page, nb=page_table.shape[1], scale=scale,
-        alibi=False, impl=impl, name="eva_decode_paged", kernel=kernel)
+    view = (L * P, hkv, page, Dh)
+    rows, n_live = _live_rows(live, B)
+    o = _walk_pages(
+        rows, jnp.asarray(n_live, jnp.int32), pos,
+        jnp.reshape(layer * P, (1,)).astype(jnp.int32),
+        page_table.astype(jnp.int32), q.reshape(B, hkv, H // hkv, Dh),
+        kcache.reshape(view), vcache.reshape(view),
+        _alibi_slopes(H, hkv, False), scale=scale, alibi=False, impl=impl,
+        name="eva_decode_paged", window=window, chunk=chunk)
+    return o.reshape(B, H, Dh)
 
 
 def _eva_summarize_kernel(pp_ref, sp_ref, k_ref, v_ref, mu_ref, phi_ref,

@@ -45,7 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.models import afmoe, kda_mla
-from deepspeed_tpu.ops.pallas.decode import paged_keys_fetched
+from deepspeed_tpu.ops.pallas.decode import (eva_keys_fetched,
+                                             paged_keys_fetched)
 from deepspeed_tpu.ops.pallas.flash_attention import (_LANES,
                                                       eva_chunk_schedule,
                                                       mla_chunk_schedule)
@@ -104,12 +105,15 @@ class FullPages:
         "ds_serve_attn_keys_attended_total":
             "(live row, decode step) keys the decode attention kernel over "
             "per-head K/V pages under one page table attends in one KV head "
-            "of one cache layer: pos + 1 a row a step",
+            "of one cache layer: pos + 1 a row a step (EVA: the pos % W + 1 "
+            "rows of its window and the W/C summaries of each closed one)",
         "ds_serve_attn_keys_fetched_total":
             "(live row, decode step) keys that kernel brings into VMEM for "
             "them: a row's pages before its last whole, the last in pieces "
             "of ops/pallas/decode.py:FETCH_ROWS tokens up to pos "
-            "(paged_keys_fetched; whole at head dims under the lane tile)",
+            "(paged_keys_fetched; whole at head dims under the lane tile; "
+            "EVA: its window pages, then its summary pages, the last page of "
+            "either in pieces: eva_keys_fetched)",
     }
     takes_valid_len = False         # the chunk's forward is told its real rows
     pages_by_kind = False           # ds_serve_kv_pages_used_by_kind moves
@@ -283,6 +287,7 @@ class WindowSummaryPages(FullPages):
             "their own",
     }
     counters = {
+        **FullPages.counters,
         "ds_serve_eva_window_closes_total":
             "windows closed (pooled into summary rows), by prefill chunks and "
             "decode steps",
@@ -338,17 +343,23 @@ class WindowSummaryPages(FullPages):
     def count_rows(self, pos, n):
         """``ds_serve_eva_*``: the rows each step attends (``pos % W + 1``
         window rows and the ``W/C`` summaries of each closed window) and the
-        windows the steps close; an EOS row that stops early is counted to
-        its bound."""
+        windows the steps close; ``ds_serve_attn_keys_*``: the two together,
+        and what ``eva_decode_paged`` fetches for them by its own rule.  An
+        EOS row that stops early is counted to its bound."""
         if not self._reg.enabled:
             return
-        W, per = self.cfg.eva_window, self.cfg.eva_window // self.cfg.eva_chunk
+        cfg = self.cfg
+        W, C = cfg.eva_window, cfg.eva_chunk
         p = np.arange(pos, pos + n)
         m = self._m
-        m["ds_serve_eva_window_rows_total"].inc(int((p % W + 1).sum()))
-        m["ds_serve_eva_summary_rows_total"].inc(int((p // W).sum()) * per)
+        window, summary = int((p % W + 1).sum()), int((p // W).sum()) * (W // C)
+        m["ds_serve_eva_window_rows_total"].inc(window)
+        m["ds_serve_eva_summary_rows_total"].inc(summary)
         m["ds_serve_eva_window_closes_total"].inc(
             int(((p + 1) % W == 0).sum()))
+        m["ds_serve_attn_keys_attended_total"].inc(window + summary)
+        m["ds_serve_attn_keys_fetched_total"].inc(int(
+            eva_keys_fetched(p, self._page, cfg.head_dim, W, C).sum()))
 
 
 class TwoBudgets(FullPages):

@@ -115,3 +115,42 @@ def mesh8(devices):
 @pytest.fixture()
 def rng():
     return jax.random.PRNGKey(0)
+
+
+@pytest.fixture
+def chips_interpreter(monkeypatch, capfd):
+    """The kernels that walk a row's pages (``ops/pallas/decode.py:
+    _walk_pages``) under jax's TPU interpreter for the test: VMEM starts as
+    NaN, a copy lands only when it is waited for, and races between a copy
+    and the vector units are looked for.  A page scored before its copy was
+    waited for would read NaN; a copy started and never waited for (the
+    next row's first page after the LAST grid step, a piece past the rows
+    that count) leaves its semaphore above 0 at the kernel's end, which the
+    interpreter reports.  Returns the check to make after ``calls`` kernel
+    calls: each was traced under this interpreter, none raced, every
+    semaphore ended at 0."""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+    from jax.experimental.pallas import tpu as pltpu
+
+    from deepspeed_tpu.ops.pallas import decode
+
+    params = pltpu.InterpretParams(detect_races=True,
+                                   dma_execution_mode="on_wait")
+    asked = []
+    monkeypatch.setattr(decode, "interpret_flag",
+                        lambda impl: asked.append(impl) or params)
+    # the call is a jitted function: traced here, under this interpreter,
+    # through a ``jit`` of its own, which goes with the test; clearing jax's
+    # caches to the same end cost the worker every file's programs
+    walk = decode._walk_pages.__wrapped__
+    monkeypatch.setattr(decode, "_walk_pages", jax.jit(
+        lambda *a, **kw: walk(*a, **kw),
+        static_argnames=("scale", "alibi", "impl", "name", "window",
+                         "chunk")))
+
+    def check(calls=1):
+        assert asked == ["interpret"] * calls
+        assert not interpret_pallas_call.races.races_found
+        assert "non-zero count" not in capfd.readouterr().out
+
+    return check

@@ -379,10 +379,13 @@ def test_the_keys_a_paged_decode_step_fetches_are_counted_by_its_rule(built,
     rows under ONE table (full pages, a looped stack's, the hybrid's full
     layers) count ``pos + 1`` keys a step attended and what the kernel's own
     rule fetches for them (``ops/pallas/decode.py:paged_keys_fetched``; head
-    dim 16 here, so whole pages: a page a grid step); every other kind
-    registers the pair and leaves it still."""
+    dim 16 here, so whole pages: a page a grid step); EVA's window and
+    summary pages count the rows of its two runs and the pages that hold
+    them (``eva_keys_fetched``); every other kind registers the pair and
+    leaves it still."""
     from deepspeed_tpu.monitor.metrics import MetricsRegistry
-    from deepspeed_tpu.ops.pallas.decode import paged_keys_fetched
+    from deepspeed_tpu.ops.pallas.decode import (eva_keys_fetched,
+                                                 paged_keys_fetched)
 
     model, params = built(name)
     reg = MetricsRegistry().enable()
@@ -400,6 +403,15 @@ def test_the_keys_a_paged_decode_step_fetches_are_counted_by_its_rule(built,
         assert attended == (p + 1).sum()
         assert fetched == paged_keys_fetched(p, page, dh).sum() \
             == ((p // page + 1) * page).sum()
+    elif name == "eva":
+        cfg = model.config
+        W, C, page = cfg.eva_window, cfg.eva_chunk, serve.pool.page
+        p = np.arange(prompt, prompt + n_out - 1)
+        rows = [p % W + 1, p // W * (W // C)]
+        assert attended == sum(rows).sum() > 0
+        assert fetched == eva_keys_fetched(p, page, cfg.head_dim, W,
+                                           C).sum() \
+            == sum(-(-n // page) * page for n in rows).sum()
     else:
         assert attended == fetched == 0
     serve.close()
@@ -418,6 +430,27 @@ def test_the_fetch_rule_at_a_head_dim_that_fills_the_lanes(pos, want):
     assert paged_keys_fetched(pos, 256, 128) == want
     assert paged_keys_fetched(np.asarray([pos]), 256, 128)[0] == want
     assert paged_keys_fetched(pos, 256, 64) == (pos // 256 + 1) * 256
+
+
+@pytest.mark.parametrize("pos,window,summary", [
+    (0, 64, 0), (255, 256, 0), (256, 320, 0), (2047, 2048, 0),
+    (2048, 64, 128), (2048 + 300, 320, 128), (2 * 2048 + 63, 64, 256),
+    (3 * 2048 + 1023, 1024, 256 + 128), (7 * 2048 + 2047, 2048, 768 + 128)])
+def test_the_fetch_rule_of_a_window_and_its_summaries(pos, window, summary):
+    """EVA at the cell's sizes (window 2,048 over pages of 256, 128
+    summaries a window): each of a row's two runs of pages is fetched as a
+    run of full pages is, whole before its last page and that one in pieces
+    of 64 up to the rows that count; under the lane tile, whole pages."""
+    from deepspeed_tpu.ops.pallas.decode import eva_keys_fetched
+
+    assert eva_keys_fetched(pos, 256, 128, 2048, 16) == window + summary
+    assert eva_keys_fetched(np.asarray([pos]), 256, 128, 2048, 16)[0] \
+        == window + summary
+    rows = pos % 2048 + 1, pos // 2048 * 128
+    assert eva_keys_fetched(pos, 256, 64, 2048, 16) \
+        == sum(-(-n // 256) * 256 for n in rows)
+    # a window of 512 leaves 32 summaries: one piece holds them
+    assert eva_keys_fetched(512, 256, 128, 512, 16) == 64 + 64
 
 
 @pytest.mark.parametrize("fields,words", [

@@ -236,8 +236,8 @@ def test_eva_decode_kernel_matches_xla(pos):
     from deepspeed_tpu.ops.pallas.decode import eva_decode_paged
 
     rng = np.random.default_rng(0)
-    kc, vc = _pool_arrays(rng)
-    q = jnp.asarray(rng.normal(size=(3, 4, 32)), jnp.float32)
+    kc, vc = _pool_arrays(rng, Dh=128)
+    q = jnp.asarray(rng.normal(size=(3, 4, 128)), jnp.float32)
     pt = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
     kw = dict(layer=1, window=256, chunk=8)
@@ -246,6 +246,7 @@ def test_eva_decode_kernel_matches_xla(pos):
         eva_decode_paged(q, kc, vc, pos, pt, impl="xla", **kw), atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", ["static", "traced"])
 @pytest.mark.parametrize("live,pos", [
     # a row before its first closed window beside one after it, parked rows
     # between them whose stale pos is deeper than either
@@ -255,29 +256,160 @@ def test_eva_decode_kernel_matches_xla(pos):
     ([True] * 4, [300, 255, 17, 600]),                     # every row
     (None, [300, 255, 17, 600]),                           # no mask given
 ], ids=["interleaved", "one_parked", "none_live", "all_live", "no_mask"])
-def test_eva_decode_kernel_visits_live_rows(live, pos):
+def test_eva_decode_kernel_visits_live_rows(live, pos, layer):
     """The grid follows ``live``: live rows equal the XLA form whichever
     side of their first window close they stand; a row that does not decode
     is never visited (every page its table names is NaN) and gets its ``q``
-    back; no live row at all is a kernel of no steps."""
+    back; no live row at all is a kernel of no steps.  The layer a Python
+    int, and a traced scalar that rides with the table."""
     from deepspeed_tpu.ops.pallas.decode import eva_decode_paged
 
     rng = np.random.default_rng(2)
-    kc, vc = _pool_arrays(rng, P=13)
-    q = jnp.asarray(rng.normal(size=(4, 4, 32)), jnp.float32)
+    kc, vc = _pool_arrays(rng, P=13, Dh=128)
+    q = jnp.asarray(rng.normal(size=(4, 4, 128)), jnp.float32)
     pt = jnp.asarray(np.arange(1, 13).reshape(4, 3), jnp.int32)
     pos = jnp.asarray(pos, jnp.int32)
     rows = np.flatnonzero(np.ones(4) if live is None else live)
     parked = np.setdiff1d(np.arange(4), rows)
     poison = np.asarray(pt)[parked].reshape(-1)
-    kx, vx = kc.at[:, poison].set(jnp.nan), vc.at[:, poison].set(jnp.nan)
-    kw = dict(layer=1, window=256, chunk=8)
-    got = eva_decode_paged(q, kx, vx, pos, pt, impl="interpret",
-                           live=None if live is None else jnp.asarray(live),
-                           **kw)
-    want = eva_decode_paged(q, kc, vc, pos, pt, impl="xla", **kw)
+    # the other layer is poison all over: the call reads layer 1
+    kx = kc.at[:, poison].set(jnp.nan).at[0].set(jnp.nan)
+    vx = vc.at[:, poison].set(jnp.nan).at[0].set(jnp.nan)
+    kw = dict(window=256, chunk=8)
+    call = lambda at: eva_decode_paged(
+        q, kx, vx, pos, pt, impl="interpret", layer=at,
+        live=None if live is None else jnp.asarray(live), **kw)
+    got = jax.jit(call)(jnp.int32(1)) if layer == "traced" else call(1)
+    want = eva_decode_paged(q, kc, vc, pos, pt, impl="xla", layer=1, **kw)
     np.testing.assert_allclose(got[rows], want[rows], atol=1e-5)
     np.testing.assert_array_equal(got[parked], q[parked])
+
+
+# -- the decode kernel walks a row's window pages, then its summary pages ------
+# window 512 over pages of 256 with a summary a 16 positions: two window
+# pages and 32 summaries a window, two summary pages a row
+WALK = dict(window=512, chunk=16)
+WALK_PAGE, WALK_COLS = 256, 4
+# where the first row stands (what its two runs of pages then end in)
+WALK_POSITIONS = {
+    "before_its_first_close": 300,              # no second run of pages
+    "first_row_of_a_window": 2 * 512,           # one piece; 64 summaries
+    "last_row_of_a_page": 3 * 512 + 255,        # a whole page; 96: 2 pieces
+    "first_row_of_a_page": 4 * 512 + 256,       # a page and a piece; half one
+    "last_row_of_a_window": 8 * 512 + 511,      # two pages; a whole page
+    "summaries_no_multiple_of_a_piece": 512 + 63,       # 32 of a piece's 64
+    "a_second_summary_page": 9 * 512 + 64,      # a page and 32 rows
+    "position_0": 0,
+}
+
+
+def _poisoned_eva_pool(rng, pos, live, H, dtype=jnp.float32, layer=1):
+    """Stacked pools [2, P, H, page, 128], clean and poisoned, and the table
+    [B, 2 window + 2 summary pages] of shuffled pages: the poisoned pair is
+    NaN wherever no live row's step counts a row: window rows past ``pos %
+    W``, summary rows past the closed windows', the pages past either run,
+    the pages of rows that do not decode, the junk page 0, the pages no
+    table names and the whole of the other layer."""
+    B, page, W = len(pos), WALK_PAGE, WALK["window"]
+    P = B * WALK_COLS + 3
+    mk = lambda: jnp.asarray(rng.normal(size=(2, P, H, page, 128)), dtype)
+    k, v = mk(), mk()
+    pt = 1 + rng.permutation(B * WALK_COLS).reshape(B, WALK_COLS)
+    counts = np.zeros((P, page), bool)
+    for b in np.flatnonzero(live):
+        runs = ((0, pos[b] % W + 1),
+                (W // page, pos[b] // W * (W // WALK["chunk"])))
+        for first, n in runs:
+            r = np.arange(n)
+            counts[pt[b, first + r // page], r % page] = True
+    keep = jnp.asarray(counts)[None, :, None, :, None] \
+        & (jnp.arange(2) == layer)[:, None, None, None, None]
+    nan = jnp.asarray(jnp.nan, dtype)
+    return (k, v, jnp.where(keep, k, nan), jnp.where(keep, v, nan),
+            jnp.asarray(pt, jnp.int32))
+
+
+@pytest.mark.parametrize("case", sorted(WALK_POSITIONS))
+def test_eva_walk_attends_the_rows_that_count_in_a_poisoned_pool(
+        case, chips_interpreter):
+    """ISSUE 62: a grid step walks the row's window pages and then its
+    summary pages, the last page of either in pieces of 64 rows, under
+    jax's TPU interpreter (NaN in VMEM until a copy is waited for, races
+    looked for, every semaphore 0 at the end).  Past the rows that count a
+    window page holds the window before's keys, a summary page whatever the
+    pool holds, a buffer an earlier page: all NaN here, all weigh 0, and
+    their values are selected away (``0 x NaN`` is NaN)."""
+    from deepspeed_tpu.ops.pallas.decode import eva_decode_paged
+
+    rng = np.random.default_rng(3)
+    pos = [WALK_POSITIONS[case], 5 * 512 + 200, 300]
+    k, v, kx, vx, pt = _poisoned_eva_pool(rng, pos, [True] * 3, H=4)
+    assert np.isnan(np.asarray(kx[1])).mean() > 0.3
+    q = jnp.asarray(rng.normal(size=(3, 4, 128)), jnp.float32)
+    pos = jnp.asarray(pos, jnp.int32)
+    got = eva_decode_paged(q, kx, vx, pos, pt, layer=1, impl="interpret",
+                           **WALK)
+    want = eva_decode_paged(q, k, v, pos, pt, layer=1, impl="xla", **WALK)
+    assert np.all(np.isfinite(np.asarray(got)))
+    np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+    chips_interpreter()
+
+
+@pytest.mark.parametrize("layer", ["static", "traced"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_eva_walk_over_two_head_groups_and_parked_rows(dtype, layer,
+                                                       chips_interpreter):
+    """Two head groups a row (32 bf16 / 16 float32 heads over pages of 256:
+    16 / 8 a grid step), so a row's walk hands its first page to its own
+    second group and that one to the next live row; a parked row between
+    them, its pages NaN, is never visited."""
+    from deepspeed_tpu.ops.pallas.decode import (_kv_heads_per_step,
+                                                 eva_decode_paged)
+
+    H = 32 if dtype == jnp.bfloat16 else 16
+    assert H == 2 * _kv_heads_per_step(H, WALK_PAGE, 128,
+                                       jnp.dtype(dtype).itemsize)
+    rng = np.random.default_rng(4)
+    pos, live = [4 * 512 + 300, 8 * 512 + 5, 40, 512 + 255], \
+        [True, False, True, True]
+    k, v, kx, vx, pt = _poisoned_eva_pool(rng, pos, live, H=H, dtype=dtype)
+    q = jnp.asarray(rng.normal(size=(4, H, 128)), dtype)
+    pos = jnp.asarray(pos, jnp.int32)
+    call = lambda at: eva_decode_paged(
+        q, kx, vx, pos, pt, layer=at, live=jnp.asarray(live),
+        impl="interpret", **WALK)
+    got = jax.jit(call)(jnp.int32(1)) if layer == "traced" else call(1)
+    got = np.asarray(got, np.float32)
+    want = np.asarray(eva_decode_paged(q, k, v, pos, pt, layer=1, impl="xla",
+                                       **WALK), np.float32)
+    rows = np.flatnonzero(live)
+    tol = 2e-4 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got[rows], want[rows], rtol=tol, atol=tol)
+    np.testing.assert_array_equal(got[1], np.asarray(q[1], np.float32))
+    chips_interpreter()
+
+
+def test_eva_decode_under_the_lane_tile_takes_the_xla_form():
+    """A head dim that does not fill the 128 lanes: the kernel copies its
+    pages itself and can slice no padded pool, ``eva_reference_reason``
+    says so, and the call is the jnp form (no second EVA kernel stays for
+    it); the pooling kernel, which copies nothing itself, takes it."""
+    from deepspeed_tpu.ops.pallas.decode import (eva_decode_paged,
+                                                 eva_reference_reason)
+
+    assert eva_reference_reason(256, 2048, 16, 128) is None
+    assert eva_reference_reason(256, 2048, 16) is None
+    assert "128 lanes" in eva_reference_reason(256, 2048, 16, 64)
+    rng = np.random.default_rng(0)
+    kc, vc = _pool_arrays(rng, Dh=64)
+    q = jnp.asarray(rng.normal(size=(3, 4, 64)), jnp.float32)
+    pt = jnp.asarray([[1, 2, 3], [4, 5, 6], [7, 8, 9]], jnp.int32)
+    pos = jnp.asarray([300, 255, 17], jnp.int32)
+    call = lambda impl: eva_decode_paged(q, kc, vc, pos, pt, impl=impl,
+                                         layer=1, window=256, chunk=8)
+    assert "pallas_call" not in str(jax.make_jaxpr(
+        lambda: call("interpret"))())
+    np.testing.assert_array_equal(call("interpret"), call("xla"))
 
 
 @pytest.mark.parametrize("pos", [[300, 255, 17], [511, 767, 130],

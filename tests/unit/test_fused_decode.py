@@ -640,32 +640,12 @@ def test_walked_pages_follow_the_live_mask_in_a_poisoned_pool(case, layer):
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
 @pytest.mark.parametrize("Hkv,rep", [(8, 4), (16, 1)])
-def test_walked_pages_under_the_chips_interpreter(Hkv, rep, dtype, capfd,
-                                                  monkeypatch):
-    """The copies' bookkeeping, under jax's TPU interpreter: VMEM starts as
-    NaN, a copy lands only when it is waited for, and races between a copy
-    and the vector units are looked for.  A page scored before its copy was
-    waited for would read NaN; a copy started and never waited for (the
-    next row's first page after the LAST grid step, a piece past ``pos``)
-    leaves its semaphore above 0 at the kernel's end, which the interpreter
-    reports."""
-    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
-    from jax.experimental.pallas import tpu as pltpu
-
-    from deepspeed_tpu.ops.pallas import decode
-
-    params = pltpu.InterpretParams(detect_races=True,
-                                   dma_execution_mode="on_wait")
-    asked = []
-    monkeypatch.setattr(decode, "interpret_flag",
-                        lambda impl: asked.append(impl) or params)
-    # the call is a jitted function: traced here, under this interpreter,
-    # through a ``jit`` of its own, which goes with the case; clearing jax's
-    # caches to the same end cost the worker every file's programs
-    walk = decode._walk_pages.__wrapped__
-    monkeypatch.setattr(decode, "_walk_pages", jax.jit(
-        lambda *a, **kw: walk(*a, **kw),
-        static_argnames=("scale", "alibi", "impl")))
+def test_walked_pages_under_the_chips_interpreter(Hkv, rep, dtype,
+                                                  chips_interpreter):
+    """The copies' bookkeeping, under jax's TPU interpreter (the
+    ``chips_interpreter`` fixture of ``conftest.py``): a page scored before
+    its copy was waited for would read NaN, a copy started and never waited
+    for leaves its semaphore above 0 at the kernel's end."""
     B, Dh, page, maxp = 4, 128, 256, 3
     posv, live = [300, 63, 767, 5], [True, True, False, True]
     q = _rand(0, B, Hkv * rep, Dh, dtype=dtype)
@@ -684,9 +664,7 @@ def test_walked_pages_under_the_chips_interpreter(Hkv, rep, dtype, capfd,
     np.testing.assert_allclose(got[rows], np.float32(want)[rows], rtol=tol,
                                atol=tol)
     np.testing.assert_array_equal(got[2], np.float32(q[2]))
-    assert asked == ["interpret"]     # traced here, under this interpreter
-    assert not interpret_pallas_call.races.races_found
-    assert "non-zero count" not in capfd.readouterr().out
+    chips_interpreter()
 
 
 def _pallas_calls(jaxpr):
@@ -734,23 +712,26 @@ def _decode_call(name):
 @pytest.mark.parametrize("call,name,grid_rank,operands,blocks", [
     ("contiguous", "flash_decode", 3, 8, 5),
     ("latent", "mla_decode_paged", 3, 8, 4),
-    ("eva", "eva_decode_paged", 3, 8, 5),
     ("paged_head_dim_64", "flash_decode_paged", 3, 9, 5),
-    ("paged", "flash_decode_paged", 2, 9, 5)])
+    ("paged", "flash_decode_paged", 2, 9, 5),
+    ("eva", "eva_decode_paged", 2, 9, 5)])
 def test_the_schedule_is_chosen_by_the_cache_layout(call, name, grid_rank,
                                                     operands, blocks):
-    """ISSUE 60: per-head K and V pools under one page table, at a head dim
-    that fills the lanes, take the schedule that walks a row's pages inside
-    a grid step (grid (live rows, head groups): rank 2, ONE run-time extent
-    before rows, pos, the layer's first page, the table, q, K, V and the
-    slopes); every other caller
-    keeps :func:`_decode_attention`'s, the parent's ``pallas_call`` by name,
+    """ISSUE 60, 62: per-head K and V pools under one page table, at a head
+    dim that fills the lanes, take the schedule that walks a row's pages
+    inside a grid step (grid (live rows, head groups): rank 2, ONE run-time
+    extent before rows, pos, the layer's first page, the table, q, K, V and
+    the slopes), full pages as one run and EVA's window and summary pages as
+    two; every other caller
+    keeps :func:`_decode_attention`'s, a page a grid step, by name,
     grid rank, operand count and blocks.  One kernel a call, whatever the
     schedule."""
     (eqn,) = _pallas_calls(jax.make_jaxpr(_decode_call(call))().jaxpr)
     gm = eqn.params["grid_mapping"]
     assert (eqn.params["name"], len(gm.grid), len(eqn.invars),
             len(gm.block_mappings)) == (name, grid_rank, operands, blocks)
+    if grid_rank == 2:      # the live rows, read at run time; the groups
+        assert [isinstance(g, int) for g in gm.grid] == [False, True]
 
 
 # -- a traced cache layer (a looped stack's ``pass * layers + layer``) ---------

@@ -347,12 +347,18 @@ EVA = dict(window=2048, chunk=16)
 SERVE_DOC = dict(slots=32, page=256, maxp=12, layers=6, pool_pages=337)
 
 
-def _eva_decode(w):
-    pool, table = _paged_pool(w, **SERVE_DOC)
-    fn = lambda q, k, v, pos, pt: eva_decode_paged(
-        q, k, v, pos, pt, layer=5, impl="pallas", **EVA)
-    return fn, [((32, w["H"], w["Dh"]), BF16), pool, pool, ((32,), I32),
-                table], 1
+def _eva_decode(traced):
+    """The decode block's call: the slots under a live mask, the layer a
+    Python int or a TRACED scalar (it rides with the table either way); the
+    walk's two K and two V page buffers of 16 heads fit the scoped VMEM."""
+    def build(w):
+        pool, table = _paged_pool(w, **SERVE_DOC)
+        fn = lambda q, k, v, pos, pt, live, layer: eva_decode_paged(
+            q, k, v, pos, pt, layer=layer if traced else 5, live=live,
+            impl="pallas", **EVA)
+        return fn, [((32, w["H"], w["Dh"]), BF16), pool, pool, ((32,), I32),
+                    table, ((32,), jnp.bool_), ((), I32)], 1
+    return build
 
 
 def _eva_summarize(w):
@@ -392,10 +398,12 @@ def _f32_stream(kernel):
 
 
 @pytest.mark.parametrize("kernel", [
-    _eva_decode, _eva_summarize, _f32_stream(_norm_qkv),
+    _eva_decode(False), _eva_decode(True), _eva_summarize,
+    _f32_stream(_norm_qkv),
     _f32_stream(_proj_norm), _f32_stream(_mlp), _eva_chunk(1024),
     _eva_chunk(512), _eva_chunk(256), _eva_chunk(128)],
-    ids=["eva_decode_paged", "eva_summarize_paged", "fused_norm_qkv_f32",
+    ids=["eva_decode_paged", "eva_decode_paged_traced_layer",
+         "eva_summarize_paged", "fused_norm_qkv_f32",
          "fused_proj_norm_f32", "fused_mlp_f32", "eva_chunk_attention_1024",
          "eva_chunk_attention_512", "eva_chunk_attention_256",
          "eva_chunk_attention_128"])
