@@ -76,6 +76,9 @@ def form(cfg):
     if cfg.is_kda_mla:
         from deepspeed_tpu.models import kda_mla
         return kda_mla
+    if cfg.is_mixer:
+        from deepspeed_tpu.models import ssm_moe
+        return ssm_moe
     import sys
     return sys.modules[__name__]
 
@@ -291,7 +294,14 @@ def held(cfg, weight, idx):
             jnp.where(here, local, cfg.num_experts))
 
 
-def glu_mlp(h, m):
+def glu_mlp(h, m, act: str = "silu"):
+    """The gated MLP ``(silu(h Wgate) * (h Wup)) Wdown``; where ``m`` has no
+    ``w_gate`` the two-matrix form ``act(h Wup) Wdown`` (models/ssm_moe.py's
+    relu2 experts)."""
+    if "w_gate" not in m:
+        from deepspeed_tpu.models.layers import activation_fn
+        return activation_fn(act)(h @ m["w_up"].astype(h.dtype)) \
+            @ m["w_down"].astype(h.dtype)
     return (jax.nn.silu(h @ m["w_gate"].astype(h.dtype))
             * (h @ m["w_up"].astype(h.dtype))) @ m["w_down"].astype(h.dtype)
 
@@ -313,7 +323,7 @@ def mlp(cfg, lp, h, experts=None, layer=None):
                         layer=jnp.asarray(layer, jnp.int32),
                         assign=(weight, local))
     if cfg.num_shared_experts:
-        y = y + glu_mlp(ht, m["shared"])
+        y = y + glu_mlp(ht, m["shared"], cfg.activation)
     return y.reshape(B, s, D)
 
 
@@ -433,7 +443,8 @@ def _finish_layer(cfg, lp, x, o, g, experts, layer):
 def _experts(params):
     ly = params.get("layers")
     return None if ly is None else {k: ly["mlp"][k]
-                                    for k in ("w_up", "w_gate", "w_down")}
+                                    for k in ("w_up", "w_gate", "w_down")
+                                    if k in ly["mlp"]}
 
 
 def embed(cfg, table, tokens, dtype):
@@ -599,8 +610,7 @@ def fused_close(cfg, dparams, lp, l: int, ctx, x, stats, moe_live, impl):
     [B, M] (gated, before ``wo``) to its end, whatever kind attended: the
     output projection, the norms, the dense MLP or the expert block, and the
     routing counts.  Returns (x, stats)."""
-    from deepspeed_tpu.ops.pallas.decode import (fused_mlp, fused_moe_mlp,
-                                                 fused_proj_norm)
+    from deepspeed_tpu.ops.pallas.decode import fused_mlp, fused_proj_norm
 
     eps = cfg.norm_eps
     zeros = jnp.zeros_like(x)
@@ -619,33 +629,46 @@ def fused_close(cfg, dparams, lp, l: int, ctx, x, stats, moe_live, impl):
         y = fused_mlp(h, base, lp["w_up"], lp["w_down"], lp["w_gate"],
                       act=cfg.activation, impl=impl)
     else:
-        if cfg.num_shared_experts:
-            sh = lp["shared"]
-            base = fused_mlp(h, base, sh["w_up"], sh["w_down"],
-                             sh["w_gate"], act=cfg.activation, impl=impl)
-        weight, idx, kept = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
-        weight, local = held(cfg, weight, idx)
-        onehot = jax.nn.one_hot(local, cfg.num_experts, dtype=F32)
-        combine = jnp.sum(onehot * weight[..., None], axis=1)
-        ex = dparams["experts"]
-        y = fused_moe_mlp(h, base, combine, ex["w_up"], ex["w_down"],
-                          ex["w_gate"], layer=l - cfg.num_dense_layers,
-                          act=cfg.activation, impl=impl)
-        if stats is not None:
-            load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
-                           & moe_live[:, None], axis=0, dtype=jnp.int32)
-            rest = stats[4:]
-            if kept is not None:      # the group limit's count leads them
-                rest = (rest[0] + jnp.sum(
-                    held_group_kept(cfg, kept) & moe_live,
-                    dtype=jnp.int32),) + rest[1:]
-            stats = (stats[0] + load,
-                     stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
-                     stats[2] + jnp.max(load),
-                     stats[3] + jnp.sum(moe_live, dtype=jnp.int32)
-                     * cfg.num_experts_per_tok) + rest
+        y, stats = fused_experts(cfg, dparams, lp, l - cfg.num_dense_layers,
+                                 h, base, stats, moe_live, impl)
     x = close(cfg, x, y, lp.get("n2_post")) if cfg.sandwich_norm else y
     return x, stats
+
+
+def fused_experts(cfg, dparams, lp, le: int, h, base, stats, moe_live, impl):
+    """The expert block of the fused path on normed rows ``h`` [B, D]:
+    ``base`` plus the shared expert plus this chip's share of the routed
+    ones (expert layer ``le`` of the stacked arrays; experts of three
+    matrices, or of two where the stack has no ``w_gate``), and the routing
+    counts.  Returns (y, stats)."""
+    from deepspeed_tpu.ops.pallas.decode import fused_mlp, fused_moe_mlp
+
+    if cfg.num_shared_experts:
+        sh = lp["shared"]
+        base = fused_mlp(h, base, sh["w_up"], sh["w_down"],
+                         sh.get("w_gate"), act=cfg.activation, impl=impl)
+    weight, idx, kept = route(cfg, h, lp["gate_w"], lp.get("gate_bias"))
+    weight, local = held(cfg, weight, idx)
+    onehot = jax.nn.one_hot(local, cfg.num_experts, dtype=F32)
+    combine = jnp.sum(onehot * weight[..., None], axis=1)
+    ex = dparams["experts"]
+    y = fused_moe_mlp(h, base, combine, ex["w_up"], ex["w_down"],
+                      ex.get("w_gate"), layer=le, act=cfg.activation,
+                      impl=impl)
+    if stats is not None:
+        load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
+                       & moe_live[:, None], axis=0, dtype=jnp.int32)
+        rest = stats[4:]
+        if kept is not None:      # the group limit's count leads them
+            rest = (rest[0] + jnp.sum(
+                held_group_kept(cfg, kept) & moe_live,
+                dtype=jnp.int32),) + rest[1:]
+        stats = (stats[0] + load,
+                 stats[1] + jnp.sum(load > 0, dtype=jnp.int32),
+                 stats[2] + jnp.max(load),
+                 stats[3] + jnp.sum(moe_live, dtype=jnp.int32)
+                 * cfg.num_experts_per_tok) + rest
+    return y, stats
 
 
 def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,

@@ -77,6 +77,19 @@ families instead; `ModelConfig` spans them with feature flags:
   under one page table), at the same rotary positions in every pass;
   benchmarks/configs/ouro-2.6b-L12.json.  SERVED ONLY, every token through
   every pass (``early_exit_threshold`` 1): no training loss, no early exit
+- A layer that is ONE mixer (NVIDIA Nemotron-3-Nano, ``nemotron_h``): ``x = x
+  + mixer(N(x))`` with ``layer_types`` kinds ``mamba2`` (a selective state
+  space: ``ssm_num_heads`` heads of ``ssm_head_dim`` over a float32 state of
+  ``ssm_state_size`` a slot, a scalar decay a head, B and C shared by the
+  heads of one of ``ssm_groups`` groups, a short causal convolution of
+  ``ssm_conv_kernel`` taps WITH bias, a gated norm over groups of channels),
+  ``full_attention`` (per-head softmax, NO position encoding, K/V pages in
+  THOSE layers only) and ``experts`` (the held-share expert block of
+  ``models/afmoe.py`` with TWO matrices an expert and ``relu2``:
+  ``activation="relu2"``, ``glu=False``, a shared expert of
+  ``shared_intermediate_size``); no layer has both an attention and an MLP
+  (``models/ssm_moe.py``; benchmarks/configs/nemotron3-nano-L9-ep2.json).
+  SERVED ONLY likewise
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -185,6 +198,22 @@ class ModelConfig:
     # the delta rule's ``beta = 2 sigmoid(.)`` in (0, 2), so that ``I - beta k
     # k^T`` may reflect (an eigenvalue in (-1, 1)); False: ``sigmoid(.)``
     kda_neg_eigval: bool = False
+    # -- the one-mixer layer form (models/ssm_moe.py): ``layer_types`` kinds
+    # "mamba2", "experts" and "full_attention", each layer ``x + mixer(N(x))``.
+    # A Mamba-2 layer: heads, a head's width (its state is [width,
+    # ssm_state_size] float32), groups of heads that share B and C (and
+    # groups of channels the gated output norm runs over), taps of the short
+    # causal convolution (with bias) on x, B and C, rows of a block of the
+    # chunked (SSD) form
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state_size: int = 0
+    ssm_conv_kernel: int = 0
+    ssm_chunk: int = 128
+    # the shared expert's width where it is a key of its own (0:
+    # ``intermediate_size * num_shared_experts``)
+    shared_intermediate_size: int = 0
     # latent: the cache row is ``mla_kv_rank`` normed values plus
     # ``mla_rot_dim`` shared key values; a query head is ``mla_nope_dim +
     # mla_rot_dim`` wide, a value head ``mla_v_dim``; ``num_heads`` query
@@ -322,9 +351,7 @@ class ModelConfig:
                              f"'sigmoid', got {self.moe_score_func!r}")
         if self.layer_types is not None:
             self._check_afmoe()
-        elif any(getattr(self, f.name) != f.default
-                 for f in dataclasses.fields(self)
-                 if f.name in _AFMOE_ONLY):
+        elif self._moved(_AFMOE_ONLY):
             raise ValueError(
                 f"{sorted(_AFMOE_ONLY)} belong to the layer form that "
                 "``layer_types`` turns on (models/afmoe.py: sliding_attention "
@@ -375,13 +402,26 @@ class ModelConfig:
         got = set(self.layer_types)
         if len(self.layer_types) != self.num_layers or not (
                 got <= _WINDOW_KINDS or got <= _STATE_KINDS
-                or got == _HYBRID_KINDS):
+                or got == _HYBRID_KINDS
+                or ("mamba2" in got and got <= _MIXER_KINDS)):
             raise ValueError(
                 f"layer_types must name, for each of the {self.num_layers} "
                 f"layers, one of {sorted(_WINDOW_KINDS)} (models/afmoe.py), "
-                f"one of {sorted(_STATE_KINDS)} (models/kda_mla.py), or both "
+                f"one of {sorted(_STATE_KINDS)} (models/kda_mla.py), both "
                 f"of {sorted(_HYBRID_KINDS)} (models/kda_mla.py: a state a "
-                f"slot beside per-head K/V pages), got {self.layer_types!r}")
+                f"slot beside per-head K/V pages), or one of "
+                f"{sorted(_MIXER_KINDS)} with a mamba2 layer among them "
+                f"(models/ssm_moe.py: a layer is one mixer), got "
+                f"{self.layer_types!r}")
+        if self.is_mixer:
+            self._check_router()
+            self._check_mixer()
+            return
+        if self._moved(_MIXER_ONLY):
+            raise ValueError(
+                f"{sorted(_MIXER_ONLY)} belong to the one-mixer layer form "
+                "(models/ssm_moe.py: mamba2, experts and full_attention "
+                "layers)")
         self._check_kda_mla()
         if "sliding_attention" in self.layer_types and self.sliding_window < 1:
             raise ValueError("sliding_attention layers need sliding_window")
@@ -392,6 +432,34 @@ class ModelConfig:
         if self.num_dense_layers < self.num_layers and not self.is_moe:
             raise ValueError("the layers after num_dense_layers are expert "
                              "layers: num_experts must be > 0")
+        self._check_router()
+        if (self.norm, self.position, self.glu, self.attention) != (
+                "rmsnorm", "rope", True, "full") or self._not_layer_form():
+            raise ValueError(
+                "layer_types (models/afmoe.py, models/kda_mla.py) is built "
+                "for RMSNorm, RoPE on the sliding layers and no position "
+                "encoding elsewhere, gated MLPs without biases, dropless "
+                "experts (moe_drop_tokens=False), an untied head and a "
+                "stream in the weights' dtype")
+
+    def _moved(self, names):
+        """Those of the fields ``names`` that are not at their default."""
+        return sorted(f.name for f in dataclasses.fields(self)
+                      if f.name in names
+                      and getattr(self, f.name) != f.default)
+
+    def _not_layer_form(self) -> bool:
+        """A field no ``layer_types`` model is built with."""
+        return bool(
+            self.moe_drop_tokens or self.use_bias or self.qkv_bias
+            or self.mlp_bias or self.qk_norm or self.parallel_residual
+            or self.fp32_residual or self.norm_add_unit_offset
+            or self.tie_embeddings or self.num_pred_heads != 1
+            or self.rotary_pct != 1.0 or self.dropout)
+
+    def _check_router(self):
+        """The held share and the group limit of a ``layer_types`` model's
+        router."""
         if not self.moe_router_experts:
             self.moe_router_experts = self.num_experts
         if not (0 <= self.moe_first_expert and self.moe_first_expert
@@ -408,19 +476,32 @@ class ModelConfig:
                 f"moe_topk_group={Gk} of moe_n_group={G} equal groups of the "
                 f"router's {self.moe_router_experts} experts must hold the "
                 f"top-{self.num_experts_per_tok}")
-        if (self.norm, self.position, self.glu, self.attention) != (
-                "rmsnorm", "rope", True, "full") or self.moe_drop_tokens \
-                or self.use_bias or self.qkv_bias or self.mlp_bias \
-                or self.qk_norm or self.parallel_residual \
-                or self.fp32_residual or self.norm_add_unit_offset \
-                or self.tie_embeddings or self.num_pred_heads != 1 \
-                or self.rotary_pct != 1.0 or self.dropout:
+
+    def _check_mixer(self):
+        """The one-mixer form (models/ssm_moe.py): the sizes a mamba2 layer
+        needs and what the module does not build."""
+        need = [k for k in _MIXER_ONLY if k != "shared_intermediate_size"
+                and getattr(self, k) < 1]
+        if need:
+            raise ValueError(f"mamba2 layers (models/ssm_moe.py) need {need}")
+        if self.ssm_num_heads % self.ssm_groups:
             raise ValueError(
-                "layer_types (models/afmoe.py, models/kda_mla.py) is built "
-                "for RMSNorm, RoPE on the sliding layers and no position "
-                "encoding elsewhere, gated MLPs without biases, dropless "
-                "experts (moe_drop_tokens=False), an untied head and a "
-                "stream in the weights' dtype")
+                f"ssm_num_heads={self.ssm_num_heads} must be whole groups "
+                f"of ssm_groups={self.ssm_groups}: the heads of a group "
+                "share B and C")
+        if "experts" in self.layer_types and not self.is_moe:
+            raise ValueError("experts layers need num_experts > 0")
+        wrong = self._moved(_AFMOE_ONLY - _MIXER_READS)
+        if wrong or (self.norm, self.glu, self.activation, self.attention) \
+                != ("rmsnorm", False, "relu2", "full") \
+                or self._not_layer_form() or self.sandwich_norm:
+            raise ValueError(
+                "the one-mixer form (models/ssm_moe.py) is built for "
+                "RMSNorm before each mixer, attention without a position "
+                "encoding, norm or gate, experts of two matrices and relu2 "
+                "(activation='relu2', glu=False) without biases, dropless "
+                "(moe_drop_tokens=False), an untied head and a stream in "
+                f"the weights' dtype; not for {wrong or 'these fields'}")
 
     def _check_kda_mla(self):
         """The sizes the two kinds of models/kda_mla.py need, and what that
@@ -567,7 +648,7 @@ class ModelConfig:
         """Layers of a K/V cache: one a (pass, layer) pair; beside
         linear-attention layers (models/kda_mla.py) the per-head
         ``full_attention`` layers alone keep K and V rows."""
-        if self.is_kda_mla:
+        if self.is_kda_mla or self.is_mixer:
             return self.layer_types.count("full_attention")
         return self.total_ut_steps * self.num_layers
 
@@ -584,9 +665,18 @@ class ModelConfig:
             bool(set(self.layer_types) & _STATE_KINDS)
 
     @property
+    def is_mixer(self) -> bool:
+        """A ``layer_types`` model whose layers are ONE mixer each: mamba2,
+        experts and full_attention layers (models/ssm_moe.py)."""
+        return self.layer_types is not None and "mamba2" in self.layer_types
+
+    @property
     def num_expert_layers(self) -> int:
         """Layers that carry the expert block (all of a MoE model's but an
-        afmoe model's leading dense ones)."""
+        afmoe model's leading dense ones; a one-mixer model's ``experts``
+        layers)."""
+        if self.is_mixer:
+            return self.layer_types.count("experts")
         return (self.num_layers - self.num_dense_layers) if self.is_moe else 0
 
 
@@ -620,6 +710,12 @@ _STATE_KINDS = frozenset({"linear_attention", "latent_attention",
                           "latent_sliding_attention"})
 # a state a slot beside per-head K/V pages: both kinds, nothing else
 _HYBRID_KINDS = frozenset({"linear_attention", "full_attention"})
+# a layer is ONE mixer (models/ssm_moe.py); "mamba2" turns the form on
+_MIXER_KINDS = frozenset({"mamba2", "experts", "full_attention"})
+# fields only that form reads
+_MIXER_ONLY = ("ssm_num_heads", "ssm_head_dim", "ssm_groups",
+               "ssm_state_size", "ssm_conv_kernel", "ssm_chunk",
+               "shared_intermediate_size")
 _MLA_SLIDING_KEYS = frozenset({"num_heads", "kv_rank", "nope_dim", "rot_dim",
                                "v_dim", "q_rank", "rope"})
 # fields only models/kda_mla.py reads
@@ -645,7 +741,12 @@ _AFMOE_ONLY = frozenset({
     "moe_score_func", "moe_route_scale", "moe_select_bias",
     "num_shared_experts", "moe_router_experts", "moe_first_expert",
     "moe_n_group", "moe_topk_group", *_KDA_MLA_ONLY, *_KDA_FORMS,
-    *_MLA_FORMS})
+    *_MLA_FORMS, *_MIXER_ONLY})
+# ... of which the one-mixer form reads the router's and its own
+_MIXER_READS = frozenset({
+    "moe_score_func", "moe_route_scale", "moe_select_bias",
+    "num_shared_experts", "moe_router_experts", "moe_first_expert",
+    "moe_n_group", "moe_topk_group", *_MIXER_ONLY})
 
 
 _PRESETS = {

@@ -188,6 +188,12 @@ def ring_rows(cfg, page: int) -> int:
     return -(-cfg.sliding_window // page) * page
 
 
+def chunk_rows(cfg) -> int:
+    """Rows under which a prefill chunk's bucket saves no work beside a
+    slot state and per-head pages: one sub-chunk of the recurrence."""
+    return SUB
+
+
 def state_shapes(cfg, num_slots: int):
     """Per-slot state of the linear layers: ``state`` float32 and ``tail``
     (the stream's dtype) shapes."""
@@ -306,16 +312,19 @@ def kda_project(a, h):
     return u, h @ w("wf_down"), h @ w("wg_down"), h @ w("wb")
 
 
-def short_conv(u, tail, w, valid_len=None):
+def short_conv(u, tail, w, valid_len=None, bias=None):
     """The depthwise causal convolution and its SiLU: ``u`` [B, s, C] after
     ``tail`` [B, K - 1, C] (the K - 1 rows before them; zeros at a
-    sequence's start), ``w`` [C, K].  Returns (float32 [B, s, C], the tail
-    after the first ``valid_len`` rows (None: all ``s``): the last K - 1 REAL
-    rows, so pad rows do not move it)."""
+    sequence's start), ``w`` [C, K], ``bias`` [C] | None inside the SiLU.
+    Returns (float32 [B, s, C], the tail after the first ``valid_len`` rows
+    (None: all ``s``): the last K - 1 REAL rows, so pad rows do not move
+    it)."""
     K, s = w.shape[-1], u.shape[1]
     full = jnp.concatenate([tail.astype(u.dtype), u], axis=1)
     w32 = w.astype(F32)
     y = sum(full[:, i:i + s].astype(F32) * w32[:, i] for i in range(K))
+    if bias is not None:
+        y = y + bias.astype(F32)
     new_tail = jax.lax.dynamic_slice_in_dim(
         full, s if valid_len is None else valid_len, K - 1, axis=1)
     return jax.nn.silu(y), new_tail.astype(tail.dtype)

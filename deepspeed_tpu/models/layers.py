@@ -138,7 +138,8 @@ def activation_fn(name: str):
     return {"silu": jax.nn.silu,
             "gelu": functools.partial(jax.nn.gelu, approximate=True),
             "gelu_exact": functools.partial(jax.nn.gelu, approximate=False),
-            "relu": jax.nn.relu}[name]
+            "relu": jax.nn.relu,
+            "relu2": lambda x: jnp.square(jnp.maximum(x, 0.0))}[name]
 
 
 def _repeat_kv(k, n_rep: int):

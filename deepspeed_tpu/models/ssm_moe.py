@@ -1,0 +1,509 @@
+"""Layers that are ONE mixer each (NVIDIA Nemotron-3-Nano, ``model_type:
+nemotron_h``; ``ModelConfig.layer_types`` kinds ``mamba2``, ``experts`` and
+``full_attention``): a third layer form beside ``models/afmoe.py`` and
+``models/kda_mla.py``, whose layers are "attention of some kind, THEN an MLP
+of some kind".  Here no layer has both:
+
+    x = embed[tokens]
+    layer l:  x = x + mixer_l(N_l(x))          N RMSNorm, own gain, norm_eps
+    logits = N_f(x) W_head
+
+    "mamba2" (a selective state space: H = ssm_num_heads heads of P =
+    ssm_head_dim, G = ssm_groups groups of heads, N = ssm_state_size), h =
+    N_l(x):
+        [z | xBC | dt] = h W_in            widths H P | H P + 2 G N | H
+        xBC = silu(conv_K(xBC) + b_conv)   depthwise causal convolution of K
+            = ssm_conv_kernel taps, zeros before position 0
+        x [H, P] | B [G, N] | C [G, N] = xBC        head i reads group i // (H / G)
+        dt = softplus(dt + dt_bias);  a = -exp(a_log)     one a head
+        per head, S [P, N] float32, S_0 = 0:
+            S_t = exp(dt_t a) S_{t-1} + (dt_t x_t) B_t^T
+            y_t = S_t C_t + D x_t
+        o = N_groups(y * silu(z)) * w      the gate BEFORE the norm, the norm
+            over each of the G groups of H P / G channels
+        mixer = o W_out
+
+    "full_attention" (num_heads query heads over num_kv_heads key-value
+    heads of head_dim; NO position encoding, no head norm, no gate):
+        mixer = softmax(q k^T / sqrt(head_dim)) v W_o,  every j <= t
+
+    "experts": ``afmoe.route`` (sigmoid scores over the router's experts in
+    float32, a bias that picks and does not weigh, the kept scores normalised
+    and scaled) over ONE CHIP'S SHARE of the experts (``afmoe.held``), an
+    expert ``relu(h W_up)^2 W_down`` (two matrices), the shared expert the
+    same at ``shared_intermediate_size``, added unweighted.
+
+Each piece is ONE function here (:func:`ssm_split`, :func:`ssm_inputs`,
+:func:`ssm_chunk_scan` / ``ops/pallas/decode.py:ssm_step_ref``,
+:func:`gated_group_norm`; ``kda_mla.short_conv`` is the convolution,
+``kda_mla.gqa_split``, ``afmoe.attend`` / ``flash_decode`` the attention
+(the per-head kind of that module without its gate), ``afmoe.mlp`` /
+``afmoe.fused_experts`` the expert block) and the three forwards call them.
+The parameters are stacks by KIND (``params["ssm"]`` ``[mamba2 layers,
+...]``, ``params["gqa"]`` ``[attention layers, ...]``, ``params["layers"]``
+``[expert layers, ...]`` with the stacked routed experts under ``mlp``) and
+one stack of the layers' norms (``params["norms"]`` ``[layers, D]``).
+
+The experts' widths are stored PADDED with zero columns (``w_up``) and zero
+rows (``w_down``) to whole :data:`WIDTH_TILE`\\ s (1,856 -> 2,048; the shared
+expert's 3,712 -> 4,096): the decode kernels tile a weight's width in
+128-lane blocks that divide it, 1,856 is 14.5 of them, the chip's grouped
+matmul works a width in the largest of 128 / 256 / 512 that divides it, and
+the stacked experts are resident ONCE (a padded second copy for the kernels
+would not fit).  ``relu(0)^2 = 0``: the pad adds nothing.
+
+Cache (``serving/cache_kind.py:FullPagesAndState``): a mamba2 layer keeps,
+for each SLOT, its state and the convolution's tail (the last K - 1 rows of
+``xBC``), fixed, never paged, read as zeros by a chunk at position 0.  The
+state is kept as ``ops/pallas/decode.py:ssm_state_pack`` lays it out ([H /
+pk, N, pk P] float32: the state dim down the sublanes, ``pk`` heads' values
+across the 128 lanes); :func:`state_shapes` says so to the cache kind.  The
+attention layers keep per-head K and V rows in pages, THOSE layers only
+(``cfg.cache_layers``).  A prefill chunk runs the recurrence in its chunked
+(SSD) form over blocks of ``ssm_chunk`` rows and carries state and tail; pad
+rows of its bucket get ``dt = 0`` (no decay, no input) and leave both as of
+the last REAL row.  A decode step updates the state of the LIVE rows in
+place (``ssm_decode_step``).
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional
+
+import jax
+import jax.numpy as jnp
+
+from deepspeed_tpu.models import afmoe
+from deepspeed_tpu.models.afmoe import F32, refuse_parallel, rms
+from deepspeed_tpu.models.kda_mla import _pad_cols, gqa_split, short_conv
+from deepspeed_tpu.ops.pallas.decode import (ssm_heads_per_tile,
+                                             ssm_state_pack,
+                                             ssm_state_unpack)
+
+HI = jax.lax.Precision.HIGHEST
+# the experts' widths are whole multiples of this (module docstring): the
+# widest tile of the chip's grouped matmul, which at 1,920 columns (15 lane
+# tiles: tiles of 128) took 9.3 ms a call of a 1,024-row chunk's where 2,048
+# takes 3.1, whatever the rows (my chip run, PR 63, PERF.md section 6); the
+# decode kernels' block is then 512 columns too
+WIDTH_TILE = 512
+GQA_IN = ("wq", "wk", "wv")       # an attention layer's projections of ``h``
+
+
+def cache_key(cfg) -> str:
+    """The cache entry whose dtype the stream takes."""
+    return "k"
+
+
+def kinds(cfg):
+    """(kind, index among the layers of its kind) for each layer."""
+    seen: Dict[str, int] = {}
+    out = []
+    for t in cfg.layer_types:
+        out.append((t, seen.get(t, 0)))
+        seen[t] = seen.get(t, 0) + 1
+    return out
+
+
+def count(cfg, kind: str) -> int:
+    return cfg.layer_types.count(kind)
+
+
+def padded_width(width: int) -> int:
+    return -(-width // WIDTH_TILE) * WIDTH_TILE
+
+
+def shared_width(cfg) -> int:
+    return cfg.shared_intermediate_size or (cfg.intermediate_size
+                                            * cfg.num_shared_experts)
+
+
+def ssm_sizes(cfg):
+    """(H, P, G, N, inner width H P, convolved channels H P + 2 G N, heads a
+    lane tile of the kept state)."""
+    H, P, G, N = (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                  cfg.ssm_state_size)
+    return H, P, G, N, H * P, H * P + 2 * G * N, ssm_heads_per_tile(H, P, G)
+
+
+def chunk_rows(cfg) -> int:
+    """Rows under which a prefill chunk's bucket saves no work: one block of
+    the chunked scan."""
+    return cfg.ssm_chunk
+
+
+def state_shapes(cfg, num_slots: int):
+    """Per-slot state of the mamba2 layers: ``state`` float32 (as
+    ``ssm_state_pack`` lays it out) and ``tail`` (the stream's dtype)
+    shapes."""
+    n = count(cfg, "mamba2")
+    H, P, _, N, _, C, pk = ssm_sizes(cfg)
+    return ((n, num_slots, H // pk, N, pk * P),
+            (n, num_slots, cfg.ssm_conv_kernel - 1, C))
+
+
+def slot_state_bytes(cfg, dtype) -> int:
+    """Bytes of :func:`state_shapes` for ONE slot, the tail in ``dtype``."""
+    state, tail = state_shapes(cfg, 1)
+    return math.prod(state) * 4 + math.prod(tail) * jnp.dtype(dtype).itemsize
+
+
+# ----------------------------------------------------------------------
+# parameters
+# ----------------------------------------------------------------------
+def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
+    """Seeded weights: the repo's uniform init (+- fan_in^-0.5) for every
+    matrix, the convolution's filters and bias +- K^-0.5 (a depthwise
+    filter's fan-in is its taps); norm gains 1; ``a_log`` = log U(1, 16) and
+    ``dt_bias`` the inverse softplus of a log-uniform step in [1e-3, 1e-1]
+    floored at 1e-4 (the release's ``time_step_min`` / ``_max`` /
+    ``_floor``), so a step's decay ``exp(dt a)`` runs from 0.999 (a head that
+    remembers thousands of tokens) to about 0.2 (one that forgets in three);
+    ``D`` = 1; the selection bias normal x 0.01 (zeros would leave the bias
+    path untested; the release's bias is what BALANCES its experts' load,
+    and beside sigmoid scores that spread by 0.14 here the 0.05 of
+    ``afmoe.init_params`` un-balances it: an expert's load from 0.0 to 5
+    times the mean, a chip's share of the choices 45-55% a layer by the
+    seed; at 0.01 the bias still changes the six chosen of every second
+    token and the loads stay within 0.5-1.6); the token embedding normal x 1
+    (``kda_mla.init_params``: this form has no embedding multiplier).  EVERY
+    projection that writes the residual stream (the Mamba and the attention
+    out-projections, the experts' and the shared expert's down projections)
+    is seeded at ``fan_in^-0.5 / sqrt(num_layers)``: the release's
+    ``rescale_prenorm_residual`` (GPT-2's rule: a residual out-projection
+    over the root of the residual layers, ONE a layer in this form), applied
+    to all of them alike."""
+    D, V = cfg.hidden_size, cfg.vocab_size
+    H, P, G, N, di, C, _ = ssm_sizes(cfg)
+    K = cfg.ssm_conv_kernel
+    keys = iter(jax.random.split(rng, 32))
+    uni = lambda shape, fan_in: jax.random.uniform(
+        next(keys), shape, dtype, -fan_in ** -0.5, fan_in ** -0.5)
+    out = lambda shape, fan_in: uni(shape, fan_in * cfg.num_layers)
+    params: Dict[str, Any] = {
+        "embed": {"tok": jax.random.normal(next(keys), (V, D), dtype)},
+        "norms": {"scale": jnp.ones((cfg.num_layers, D), dtype)},
+        "final_norm": {"scale": jnp.ones((D,), dtype)},
+        "lm_head": jax.random.normal(next(keys), (D, V), dtype) * D ** -0.5}
+    L = count(cfg, "mamba2")
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(
+        next(keys), (L, H), F32, jnp.log(1e-3), jnp.log(1e-1))), 1e-4)
+    params["ssm"] = {
+        "w_in": uni((L, D, di + C + H), D),
+        "conv": uni((L, C, K), K), "conv_b": uni((L, C), K),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
+        "a_log": jnp.log(jax.random.uniform(
+            next(keys), (L, H), F32, 1.0, 16.0)).astype(dtype),
+        "d_skip": jnp.ones((L, H), dtype),
+        "o_norm": jnp.ones((L, di), dtype), "wo": out((L, di, D), di)}
+    L = count(cfg, "full_attention")
+    if L:
+        M, Mkv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
+        params["gqa"] = {"wq": uni((L, D, M), D), "wk": uni((L, D, Mkv), D),
+                         "wv": uni((L, D, Mkv), D), "wo": out((L, M, D), M)}
+    L = count(cfg, "experts")
+    if L:
+        E, R = cfg.num_experts, cfg.moe_router_experts
+
+        def two(lead, width):
+            # drawn at the padded width and zeroed past the real one: no
+            # unpadded copy beside the 5 GB of a real model's experts
+            wide = padded_width(width)
+            real = jnp.arange(wide) < width
+            return {"w_up": uni(lead + (D, wide), D) * real.astype(dtype),
+                    "w_down": out(lead + (wide, D), width)
+                    * real[:, None].astype(dtype)}
+
+        mlp = {"gate_w": uni((L, D, R), D), **two((L, E),
+                                                  cfg.intermediate_size)}
+        if cfg.moe_select_bias:
+            mlp["gate_bias"] = jax.random.normal(next(keys), (L, R),
+                                                 dtype) * 0.01
+        if cfg.num_shared_experts:
+            mlp["shared"] = two((L,), shared_width(cfg))
+        params["layers"] = {"mlp": mlp}
+    return params
+
+
+def layer_params(cfg, params, l: int):
+    """(layer ``l``'s norm gain, its slice of its kind's stack (an experts
+    layer: without the stacked routed experts, which stay whole), its kind,
+    its index among that kind)."""
+    kind, i = kinds(cfg)[l]
+    if kind == "experts":
+        stack = {k: v for k, v in params["layers"]["mlp"].items()
+                 if k not in ("w_up", "w_down")}
+    else:
+        stack = params["ssm" if kind == "mamba2" else "gqa"]
+    return (params["norms"]["scale"][l],
+            jax.tree.map(lambda a: a[i], stack), kind, i)
+
+
+# ----------------------------------------------------------------------
+# the Mamba-2 pieces
+# ----------------------------------------------------------------------
+def ssm_split(cfg, y):
+    """The in-projection's columns ``y`` [..., H P + (H P + 2 G N) + H (+
+    pad)] as (z [..., H P], xBC [..., H P + 2 G N], dt [..., H])."""
+    H, _, _, _, di, C, _ = ssm_sizes(cfg)
+    return y[..., :di], y[..., di:di + C], y[..., di + C:di + C + H]
+
+
+def ssm_inputs(cfg, a, c, dt_raw):
+    """From the convolved rows ``c`` [..., H P + 2 G N] float32 and the step
+    logits ``dt_raw`` [..., H] to the recurrence's inputs, float32: x [...,
+    H, P], B and C [..., G, N], dt [..., H] > 0 (softplus of the logits plus
+    ``dt_bias``, no clamp), the decay rates a [H] < 0."""
+    H, P, G, N, di, _, _ = ssm_sizes(cfg)
+    lead = c.shape[:-1]
+    x = c[..., :di].reshape(lead + (H, P))
+    Bm = c[..., di:di + G * N].reshape(lead + (G, N))
+    Cm = c[..., di + G * N:].reshape(lead + (G, N))
+    dt = jax.nn.softplus(dt_raw.astype(F32) + a["dt_bias"].astype(F32))
+    return x, Bm, Cm, dt, -jnp.exp(a["a_log"].astype(F32))
+
+
+def ssm_chunk_scan(S, x, dt, a, Bm, Cm, block: int):
+    """The selective state space for ``s`` tokens of one sequence, chunked
+    (the SSD form): ``S`` [H, P, N] float32; x [s, H, P]; dt [s, H]; a [H];
+    Bm, Cm [s, G, N]; ``s`` a multiple of ``block`` or less than it.
+    Returns (S, y [s, H, P]) WITHOUT the skip ``D x``.
+
+    Inside a block, with ``L_t`` the cumulative log-decay ``sum_{j <= t}
+    dt_j a`` and ``S_0`` the state before it,
+
+        S_t = e^{L_t} S_0 + sum_{i <= t} e^{L_t - L_i} (dt_i x_i) B_i^T
+        y_t = e^{L_t} S_0 C_t + sum_{i <= t} e^{L_t - L_i} (C_t . B_i) dt_i x_i
+
+    so a block is three matrix products and the states follow one block
+    after another.  Every exponent is a difference ``L_t - L_i`` with ``i <=
+    t``, masked BEFORE the exponential: at most 0.  A row with ``dt = 0``
+    (a pad row) neither decays the state nor adds to it."""
+    s, H, P = x.shape
+    G = Bm.shape[1]
+    Q = min(block, s)
+    n = s // Q
+    assert s == n * Q, (s, Q)
+    blk = lambda t: t.reshape((n, Q) + t.shape[1:])
+    t_i = jnp.arange(Q)
+    low = t_i[:, None] >= t_i[None, :]                           # i <= t
+    dot = lambda spec, u, v: jnp.einsum(spec, u, v, precision=HI)
+    heads = lambda t: jnp.repeat(t, H // G, axis=1)              # [Q, H, N]
+
+    def step(S, xs):
+        xd, la, Bq, Cq = xs
+        Lc = jnp.cumsum(la, axis=0)                              # [Q, H]
+        E = jnp.exp(jnp.where(low[None], Lc.T[:, :, None] - Lc.T[:, None, :],
+                              -jnp.inf))                         # [H, t, i]
+        W = jnp.repeat(dot("tgn,ign->gti", Cq, Bq), H // G, axis=0) * E
+        y = dot("hti,ihp->thp", W, xd) \
+            + dot("thn,hpn->thp", heads(Cq) * jnp.exp(Lc)[..., None], S)
+        last = Lc[-1]                                            # [H]
+        S = S * jnp.exp(last)[:, None, None] + dot(
+            "ihp,ihn->hpn", xd * jnp.exp(last - Lc)[..., None], heads(Bq))
+        return S, y
+
+    S, y = jax.lax.scan(step, S, (blk(x * dt[..., None]), blk(dt * a),
+                                  blk(Bm), blk(Cm)))
+    return S, y.reshape(s, H, P)
+
+
+def gated_group_norm(cfg, a, y, z):
+    """The gated output norm: ``y * silu(z)`` normed over each of the G
+    groups of H P / G channels (the gate BEFORE the norm), times the gain;
+    y [..., H P] float32, z [..., H P] -> [..., H P] in the weights' dtype
+    (the input of ``wo``)."""
+    G = cfg.ssm_groups
+    g = y * jax.nn.silu(z.astype(F32))
+    g = g.reshape(g.shape[:-1] + (G, -1))
+    g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+    return (g.reshape(y.shape) * a["o_norm"].astype(F32)).astype(
+        a["wo"].dtype)
+
+
+# ----------------------------------------------------------------------
+# forwards 1 and 2: no cache (CausalLM.apply), and a prefill chunk on one
+# slot's views (state carried in and out)
+# ----------------------------------------------------------------------
+def apply_layers(cfg, params, x, mesh=None):
+    """The layer stack on ``x`` [B, S, D], positions ``0 .. S - 1``: every
+    sequence a chunk at position 0 on an empty cache of its own."""
+    refuse_parallel(cfg, mesh, "CausalLM.apply")
+    B, S, _ = x.shape
+    Q = cfg.ssm_chunk
+    pad = -S % Q if S > Q else 0           # whole blocks (pad rows idle)
+    state, tail = state_shapes(cfg, 1)
+    kv = (count(cfg, "full_attention"), 1, cfg.num_kv_heads, S + pad,
+          cfg.head_dim)
+
+    def one(xb):
+        xb = jnp.pad(xb, ((0, pad), (0, 0)))[None]
+        cache = {"k": jnp.zeros(kv, x.dtype), "v": jnp.zeros(kv, x.dtype),
+                 "state": jnp.zeros(state, F32),
+                 "tail": jnp.zeros(tail, x.dtype)}
+        return cached_layers(cfg, params, xb, cache, 0, S)[0][0, :S]
+
+    return jax.lax.map(one, x)
+
+
+def cached_layers(cfg, params, x, cache, start, valid_len):
+    """The layer stack on a chunk ``x`` [1, s, D] at positions ``start ..``
+    over ONE slot's views (``state`` [mamba2 layers, 1, H / pk, N, pk P]
+    float32, ``tail`` [mamba2 layers, 1, K - 1, H P + 2 G N], ``k`` and ``v``
+    [attention layers, 1, Hkv, positions, Dh]: what
+    ``cache_kind.FullPagesAndState.view`` slices out); only the first
+    ``valid_len`` rows are real.  A chunk at position 0 starts from a zero
+    state whatever the slot held.  Returns (x, views)."""
+    B, s, _ = x.shape
+    assert B == 1, "a chunk program prefills one slot"
+    start = jnp.asarray(start, jnp.int32)
+    pos = start + jnp.arange(s)
+    real = jnp.arange(s) < valid_len
+    state, tail = cache["state"], cache["tail"]
+    k_full, v_full = cache["k"], cache["v"]
+    kept = (start != 0)
+    experts = afmoe._experts(params)
+    pk = ssm_sizes(cfg)[-1]
+    for l in range(cfg.num_layers):
+        scale, a, kind, i = layer_params(cfg, params, l)
+        h = rms(x, scale, cfg.norm_eps)
+        if kind == "mamba2":
+            z, xBC, dt_raw = ssm_split(cfg, h @ a["w_in"].astype(h.dtype))
+            with jax.named_scope("ssm_conv"):
+                c, t1 = short_conv(xBC, jnp.where(kept, tail[i], 0),
+                                   a["conv"], valid_len, bias=a["conv_b"])
+            xs, Bm, Cm, dt, rate = ssm_inputs(cfg, a, c[0], dt_raw[0])
+            # a pad row: dt = 0, so the state neither decays nor takes it in
+            dt = jnp.where(real[:, None], dt, 0.0)
+            with jax.named_scope("ssm_chunk_scan"):
+                S1, y = ssm_chunk_scan(
+                    jnp.where(kept, ssm_state_unpack(state[i, 0], pk), 0.0),
+                    xs, dt, rate, Bm, Cm, cfg.ssm_chunk)
+            state = state.at[i, 0].set(ssm_state_pack(S1, pk))
+            tail = tail.at[i].set(t1)
+            y = y + a["d_skip"].astype(F32)[:, None] * xs
+            o = gated_group_norm(cfg, a, y.reshape(1, s, -1), z)
+            out = o @ a["wo"].astype(o.dtype)
+        elif kind == "full_attention":
+            # per-head keys and values, unrotated: the chunk's rows join the
+            # slot's view, and the queries attend every row up to their own
+            q, k, v, _ = gqa_split(cfg, jnp.concatenate(
+                [h @ a[n].astype(h.dtype) for n in GQA_IN], -1))
+            heads = lambda t: t.transpose(0, 2, 1, 3)
+            at = (i, 0, 0, start, 0)
+            k_full = jax.lax.dynamic_update_slice(
+                k_full, heads(k)[None].astype(k_full.dtype), at)
+            v_full = jax.lax.dynamic_update_slice(
+                v_full, heads(v)[None].astype(v_full.dtype), at)
+            o = afmoe.attend(
+                heads(q), [(k_full[i], v_full[i],
+                            jnp.arange(k_full.shape[3]))],
+                pos, window=0, scale=cfg.head_dim ** -0.5,
+                live_keys=start + s)
+            o = heads(o).reshape(B, s, -1)
+            out = o @ a["wo"].astype(o.dtype)
+        else:
+            out = afmoe.mlp(cfg, {"mlp": a}, h, experts, i)
+        x = x + out.astype(x.dtype)
+    return x, {"k": k_full, "v": v_full, "state": state, "tail": tail}
+
+
+# ----------------------------------------------------------------------
+# forward 3: one decode step through the fused kernels
+# ----------------------------------------------------------------------
+def inject(cfg, params) -> Dict[str, Any]:
+    """The kernel-injected view (``afmoe.inject``'s shape): per-layer dicts
+    with their own buffers.  A mamba2 or attention layer: its norm gain, every
+    projection of ``h`` in one ``[D, N]`` matrix ``w_in``, the small arrays
+    under their own names, and ``next_norm``, the gain of the norm that
+    FOLLOWS it (the next layer's, or the final one): its output projection's
+    kernel norms the new stream for an experts layer behind it.  An experts
+    layer: its norm gain, the router and the shared expert; the stacked
+    routed experts by reference."""
+    norms = params["norms"]["scale"]
+    layers = []
+    for l in range(cfg.num_layers):
+        scale, a, kind, _ = layer_params(cfg, params, l)
+        d = {"norm": scale}
+        if kind == "experts":
+            d.update(a)
+        else:
+            names = ("w_in",) if kind == "mamba2" else GQA_IN
+            d.update({k: v for k, v in a.items() if k not in names})
+            d["w_in"] = _pad_cols(jnp.concatenate([a[k] for k in names], -1))
+            d["next_norm"] = (norms[l + 1] if l + 1 < cfg.num_layers
+                              else params["final_norm"]["scale"])
+        layers.append(d)
+    return afmoe.inject_outer(params, layers)
+
+
+def moe_counts_zero(cfg):
+    """``afmoe.moe_counts_zero`` and one more entry: (row, mamba2 layer)
+    pairs that were LIVE, and pairs whose state the decode kernel VISITED."""
+    return afmoe.moe_counts_zero(cfg) + (jnp.zeros((2,), jnp.int32),)
+
+
+def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
+                 impl: Optional[str] = None):
+    """The layer stack for one token a row: ``x`` [B, D] at per-row
+    positions ``pos`` [B]; ``cache``: ``state`` [mamba2 layers, B, H / pk, N,
+    pk P] and ``tail`` [mamba2 layers, B, K - 1, H P + 2 G N] by row (a row
+    of the batch is a slot), ``k`` and ``v`` [attention layers, pages, Hkv,
+    page, Dh] through ``page_table`` [B, columns].  ``moe_live`` [B] bool:
+    the rows that decode; only their state, tail and pages move, and the
+    kernels visit only them.  Returns (x, cache, counts | None)."""
+    from deepspeed_tpu.ops.pallas.decode import (flash_decode,
+                                                 fused_norm_qkv,
+                                                 fused_proj_norm,
+                                                 paged_kv_append,
+                                                 ssm_decode_step)
+
+    B = x.shape[0]
+    eps = cfg.norm_eps
+    state, tail = cache["state"], cache["tail"]
+    k_full, v_full = cache["k"], cache["v"]
+    stats = moe_counts_zero(cfg) if moe_live is not None else None
+    h = None                      # the stream normed for the layer to come
+    for l, ((kind, i), lp) in enumerate(zip(kinds(cfg), dparams["layers"])):
+        if kind == "experts":
+            if h is None:
+                h = rms(x, lp["norm"], eps)
+            x, head = afmoe.fused_experts(
+                cfg, dparams, lp, i, h, x, None if stats is None
+                else stats[:-1], moe_live, impl)
+            stats = None if stats is None else head + stats[-1:]
+            h = None
+            continue
+        y = fused_norm_qkv(x, lp["norm"], None, lp["w_in"], None,
+                           kind="rmsnorm", eps=eps, impl=impl)
+        if kind == "mamba2":
+            z, xBC, dt_raw = ssm_split(cfg, y)
+            c, t1 = short_conv(xBC[:, None], tail[i], lp["conv"],
+                               bias=lp["conv_b"])
+            if moe_live is not None:
+                t1 = jnp.where(moe_live[:, None, None], t1, tail[i])
+            tail = tail.at[i].set(t1)
+            xs, Bm, Cm, dt, rate = ssm_inputs(cfg, lp, c[:, 0], dt_raw)
+            yk, state, visited = ssm_decode_step(
+                state, xs, dt, rate, Bm, Cm, layer=i, live=moe_live,
+                impl=impl)
+            if stats is not None:
+                stats = stats[:-1] + (stats[-1] + jnp.stack(
+                    [jnp.sum(moe_live, dtype=jnp.int32), visited]),)
+            yk = yk + lp["d_skip"].astype(F32)[:, None] * xs
+            ctx = gated_group_norm(cfg, lp, yk.reshape(B, -1), z)
+        else:
+            # the row's K and V join its pages, then the paged kernel: every
+            # position up to its own, a key-value head serving its group
+            q, k, v, _ = gqa_split(cfg, y)
+            k_full, v_full = paged_kv_append(k_full, v_full, k, v, pos,
+                                             page_table, layer=i, impl=impl)
+            ctx = flash_decode(q, k_full, v_full, pos,
+                               sm_scale=cfg.head_dim ** -0.5, layer=i,
+                               page_table=page_table, live=moe_live,
+                               impl=impl).reshape(B, -1)
+        x, h = fused_proj_norm(ctx, x, lp["wo"], None, lp["next_norm"], None,
+                               kind="rmsnorm", eps=eps, impl=impl)
+    return x, {"k": k_full, "v": v_full, "state": state, "tail": tail}, stats

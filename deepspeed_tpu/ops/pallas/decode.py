@@ -109,6 +109,11 @@ def _act(name: str, x):
         return jax.nn.gelu(x, approximate=False)
     if name == "relu":
         return jax.nn.relu(x)
+    if name == "relu2":
+        # ``maximum``, not ``jax.nn.relu``: behind the latter the CPU backend
+        # turns ``_moe_mlp_ref``'s second product into a bf16 x bf16 = f32
+        # dot it cannot run (jax 0.9.0)
+        return jnp.square(jnp.maximum(x, 0.0))
     raise ValueError(f"unsupported activation {name}")
 
 
@@ -173,10 +178,12 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
     cd = x.dtype if quant else wqkv.dtype
     # quant sizing counts the in-kernel fp32 dequant intermediate, not the
     # int8 payload — a payload-sized block would overflow VMEM at 1B+ scale
-    # beside the tile: the rows (double-buffered) and their normed copy
+    # beside the tile: the rows (double-buffered), their normed copy and the
+    # float32 one the first step norms (256 rows of 2,688: 6.9 MB in all, and
+    # a [2688, 10752] projection's tile goes from 896 columns to 512)
     bn = _col_block(D, N, 4 if quant else wqkv.dtype.itemsize,
                     resident=B * D * (2 * x.dtype.itemsize
-                                      + jnp.dtype(cd).itemsize))
+                                      + jnp.dtype(cd).itemsize + 4))
     has_bias = bqkv is not None
     bq = (bqkv if has_bias else jnp.zeros((N,), cd)).reshape(1, N)
     ws = (wscale if quant else jnp.ones((N,), jnp.float32)).reshape(1, N)
@@ -1224,6 +1231,153 @@ def kda_decode_step(state, q, k, v, g, beta, *, layer: int, live=None,
 
 
 # ---------------------------------------------------------------------------
+# A selective state space (models/ssm_moe.py: Mamba-2), one token a row
+# ---------------------------------------------------------------------------
+
+# the state block of one grid step of :func:`ssm_decode_step` (read and
+# written back, each double-buffered: four of these in VMEM)
+_SSM_STEP_BYTES = 2 * 2**20
+
+
+def ssm_heads_per_tile(heads: int, head_dim: int, groups: int) -> int:
+    """Heads of ONE group whose ``head_dim`` values lie side by side across
+    the lanes of a state tile (:func:`ssm_state_pack`): as many as fill 128
+    lanes, a divisor of the heads a group has (1 at a head of 128 or more)."""
+    per_group = heads // groups
+    return max(d for d in range(1, per_group + 1)
+               if per_group % d == 0 and d * head_dim <= max(128, head_dim))
+
+
+def ssm_state_pack(S, pk: int):
+    """A state [..., H, P, N] (head, value, state dim) as the cache keeps it:
+    [..., H / pk, N, pk P], the state dim down the sublanes and ``pk`` heads'
+    values across the lanes, so that a step's ``x``, decay and output are
+    ROWS (their natural flat order) and only B and C are columns."""
+    *lead, H, P, N = S.shape
+    S = S.reshape(*lead, H // pk, pk, P, N)
+    return jnp.moveaxis(S, -1, -3).reshape(*lead, H // pk, N, pk * P)
+
+
+def ssm_state_unpack(S, pk: int):
+    """:func:`ssm_state_pack`'s inverse: [..., H / pk, N, pk P] -> [..., H,
+    P, N]."""
+    *lead, T, N, W = S.shape
+    S = jnp.moveaxis(S.reshape(*lead, T, N, pk, W // pk), -3, -1)
+    return S.reshape(*lead, T * pk, W // pk, N)
+
+
+def ssm_step_ref(S, x, dt, a, Bm, Cm):
+    """One token of the selective state space on the LOGICAL state ``S``
+    [..., H, P, N] float32: x [..., H, P], dt [..., H] (after its softplus),
+    a [H] < 0, Bm and Cm [..., G, N] (head i reads group i // (H / G)):
+
+        S <- exp(dt a) S + (dt x) B^T;   y = S C
+
+    Returns (y [..., H, P], S).  Elementwise float32: the same on every
+    backend."""
+    rep = x.shape[-2] // Bm.shape[-2]
+    Bh, Ch = (jnp.repeat(t, rep, axis=-2) for t in (Bm, Cm))
+    S = S * jnp.exp(dt * a)[..., None, None] \
+        + (dt[..., None] * x)[..., None] * Bh[..., None, :]
+    return (S * Ch[..., None, :]).sum(-1), S
+
+
+def ssm_reference_reason(tiles: int, N: int, W: int,
+                         groups: int) -> Optional[str]:
+    """Why the state kernel cannot take these sizes (None = it can)."""
+    if W % 128 or N % 8:
+        return (f"a state tile of {N} x {W} is not whole (8, 128) float32 "
+                "tiles")
+    if 2 * groups > 128:
+        return f"B and C of {groups} groups do not fit one 128-lane tile"
+    if tiles // groups * N * W * 4 > _SSM_STEP_BYTES:
+        return "one group's state is more than a grid step holds"
+    return None
+
+
+def _ssm_step_kernel(rows_ref, cols_ref, da_ref, x_ref, s_ref, y_ref, s_out,
+                     *, gs, per_group):
+    """One grid step = one LIVE batch row x ``gs`` groups of ``per_group``
+    state tiles [N, W].  ``cols`` [N, 128] holds, as COLUMNS down the state
+    dim, the groups' B (lanes [0, gs)) and C ([gs, 2 gs)); the decay and ``dt
+    x`` are rows across a tile's lanes.  A tile comes into VMEM once, is
+    decayed, takes its outer product, is read out against C, and goes back
+    through the alias."""
+    del rows_ref                  # consumed by the index maps
+    cols = cols_ref[0, 0]
+    for g in range(gs):
+        b_col, c_col = cols[:, g:g + 1], cols[:, gs + g:gs + g + 1]  # [N, 1]
+        for t in range(g * per_group, (g + 1) * per_group):
+            S = s_ref[0, t] * da_ref[0, t:t + 1, :] \
+                + b_col * x_ref[0, t:t + 1, :]
+            s_out[0, t] = S
+            y_ref[0, t:t + 1, :] = jnp.sum(S * c_col, axis=0, keepdims=True)
+
+
+def ssm_decode_step(state, x, dt, a, Bm, Cm, *, layer: int, live=None,
+                    impl: Optional[str] = None):
+    """The selective state space of a Mamba-2 layer for one token a row
+    (:func:`ssm_step_ref`), on the stacked per-slot state in place:
+    ``state`` [L, B, H / pk, N, pk P] float32 (:func:`ssm_state_pack`), x
+    [B, H, P], dt [B, H], a [H], Bm and Cm [B, G, N], all float32.  Returns
+    (y [B, H, P] float32, state, rows visited).
+
+    The grid follows the batch as :func:`kda_decode_step`'s does: ``live``
+    [B] bool names the rows that decode (None: all), a parked row costs no
+    grid step, its state is neither read nor written, and its ``y`` is its
+    ``dt x`` (the output is aliased onto it).  The XLA form updates every
+    row and keeps the old state where a row is not live: it visits all
+    ``B``."""
+    impl = resolve_impl(impl)
+    L, B, T, N, W = state.shape
+    H, P = x.shape[1:]
+    G = Bm.shape[1]
+    pk = H // T
+    impl = kernel_or_reference("ssm_decode_step", impl,
+                               ssm_reference_reason(T, N, W, G))
+    if impl == "xla":
+        y, new = ssm_step_ref(ssm_state_unpack(state[layer], pk), x, dt, a,
+                              Bm, Cm)
+        new = ssm_state_pack(new, pk)
+        if live is not None:
+            new = jnp.where(live[:, None, None, None], new, state[layer])
+        return y, state.at[layer].set(new), jnp.asarray(B, jnp.int32)
+    per_group = T // G
+    gs = max(d for d in range(1, G + 1) if G % d == 0
+             and d * per_group * N * W * 4 <= _SSM_STEP_BYTES)
+    rows, n_live = _live_rows(live, B)
+    # B and C as columns: [B, G / gs, N, 2 gs] padded to the lane tile
+    cols = jnp.concatenate([t.reshape(B, G // gs, gs, N).swapaxes(2, 3)
+                            for t in (Bm, Cm)], axis=-1)
+    cols = jnp.pad(cols, ((0, 0),) * 3 + ((0, 128 - 2 * gs),))
+    flat = lambda t: t.reshape(B, T, W)
+    da = flat(jnp.broadcast_to(jnp.exp(dt * a)[..., None], (B, H, P)))
+    tb = gs * per_group
+    vec = pl.BlockSpec((1, tb, W), lambda i, j, rows_ref: (rows_ref[i], j, 0))
+    mat = pl.BlockSpec((1, tb, N, W),
+                       lambda i, j, rows_ref: (layer * B + rows_ref[i], j,
+                                               0, 0))
+    y, new = pl.pallas_call(
+        functools.partial(_ssm_step_kernel, gs=gs, per_group=per_group),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_live, G // gs),
+            in_specs=[pl.BlockSpec((1, 1, N, 128),
+                                   lambda i, j, rows_ref: (rows_ref[i], j,
+                                                           0, 0)),
+                      vec, vec, mat],
+            out_specs=[vec, mat]),
+        out_shape=[jax.ShapeDtypeStruct((B, T, W), jnp.float32),
+                   jax.ShapeDtypeStruct((L * B, T, N, W), jnp.float32)],
+        # operands count the scalar-prefetch array: dt x is 3, the state 4
+        input_output_aliases={3: 0, 4: 1},
+        interpret=interpret_flag(impl),
+        name="ssm_decode_step",
+    )(rows, cols, da, flat(dt[..., None] * x), state.reshape(L * B, T, N, W))
+    return (y.reshape(B, H, P), new.reshape(state.shape),
+            jnp.asarray(n_live, jnp.int32))
+
+
+# ---------------------------------------------------------------------------
 # EVA (models/eva.py): decode attention over window pages + summary pages,
 # and the pooling of a filled window into its summary rows
 # ---------------------------------------------------------------------------
@@ -1463,7 +1617,11 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
     # output rows, so grid step j accumulates ctx[:, j] @ wo[j, :] into an
     # fp32 scratch and the last step adds the residual and norms.  Sized
     # like fused_norm_qkv (quant counts the fp32 dequant intermediate).
-    bm = _col_block(D, M, 4 if quant else wo.dtype.itemsize)
+    # beside the tile: the stream, the two results and the accumulator
+    bm = _col_block(D, M, 4 if quant else wo.dtype.itemsize,
+                    resident=B * D * (resid.dtype.itemsize
+                                      + resid.dtype.itemsize
+                                      + ctx.dtype.itemsize + 4))
     bo2 = (bo if has_bias else jnp.zeros((D,), ctx.dtype)).reshape(1, D)
     ws = (wscale if quant else jnp.ones((D,), jnp.float32)).reshape(1, D)
     kernel = functools.partial(_proj_norm_kernel, kind=kind, eps=eps,
@@ -1642,11 +1800,13 @@ def _moe_mlp_ref(h, r, combine, w_up, w_gate, w_down, *, act):
     return (r.astype(jnp.float32) + y).astype(h.dtype)
 
 
-def _moe_mlp_kernel(h_ref, r_ref, c_ref, wu_ref, wg_ref, wd_ref, o_ref,
-                    acc_scr, *, act, glu, ne, nf):
+def _moe_mlp_kernel(h_ref, r_ref, c_ref, wu_ref, *rest, act, glu, ne, nf):
     """One grid step = one expert x one FFN tile: all rows against the tile,
     the down-projection weighed by this expert's combine column and added
-    into the float32 accumulator (which starts at the residual)."""
+    into the float32 accumulator (which starts at the residual).  ``rest``:
+    the gate's tile where the experts have one, the down tile, the output
+    and the accumulator."""
+    wg_ref, (wd_ref, o_ref, acc_scr) = (rest[0] if glu else None), rest[-3:]
     e, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((e == 0) & (j == 0))
@@ -1700,9 +1860,10 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
     return pl.pallas_call(
         kernel,
         grid=(E, F // bf),
+        # (experts of two matrices stream two: no tile stands in for a gate)
         in_specs=[rows, rows,
                   pl.BlockSpec((None, B, 1), lambda e, j: (e, 0, 0)),
-                  cols, cols,
+                  cols, *([cols] if glu else []),
                   pl.BlockSpec((None, bf, D), lambda e, j: (base + e, j, 0))],
         out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((B, D), h.dtype),
@@ -1710,5 +1871,6 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
         interpret=interpret_flag(impl),
         name="fused_moe_mlp",
     )(h, r, combine.astype(jnp.float32).T[:, :, None],
-      w_up.reshape(-1, D, F), (w_gate if glu else w_up).reshape(-1, D, F),
+      w_up.reshape(-1, D, F),
+      *([w_gate.reshape(-1, D, F)] if glu else []),
       w_down.reshape(-1, F, D))

@@ -17,10 +17,12 @@ attention form, and :func:`cache_kind` is the one place that decides it
 - :class:`LatentPagesAndState` (linear-attention layers beside them): the
   same pages and a recurrent state a slot;
 - :class:`FullPagesAndState` (linear-attention layers beside per-head
-  ``full_attention`` layers): :class:`FullPages`' K and V arrays in the full
+  ``full_attention`` layers; or the one-mixer form of ``models/ssm_moe.py``,
+  mamba2 layers beside ``full_attention`` and ``experts`` layers):
+  :class:`FullPages`' K and V arrays in the full
   layers ONLY (``cfg.cache_layers`` counts them, so a token costs those
-  layers' bytes) and the same state a slot, under :class:`SlotState`'s
-  budget;
+  layers' bytes) and a state a slot, its shape the model module's
+  (``afmoe.form(cfg).state_shapes``), under :class:`SlotState`'s budget;
 - :class:`IndexedLatentPagesAndRing` (latent layers that attend a learned
   selection of their keys, beside sliding latent layers of other sizes): a
   latent row AND an index key a position under one page table, and a ring of
@@ -567,10 +569,13 @@ class LatentPages(FullPages):
 
 class SlotState:
     """A float32 recurrent ``state`` and a convolution ``tail`` per SLOT for
-    the linear-attention layers, beside whatever pages the class after this
-    one in a kind's bases keeps: never allocated or freed (a request's first
-    chunk program reads zeros), sliced out by the slot's index for a chunk
-    and put back in place."""
+    the linear-attention layers (``models/kda_mla.py``) or the mamba2 layers
+    (``models/ssm_moe.py``), their shapes the model module's
+    (``state_shapes``: heads of a square matrix, or a state tile that is
+    not), beside whatever pages the class after this one in a kind's bases
+    keeps: never allocated or freed (a request's first chunk program reads
+    zeros), sliced out by the slot's index for a chunk and put back in
+    place."""
 
     # the row steps are counted by the decode-block program itself (the
     # live mask and the state kernel's grid) and fetched with its tokens
@@ -585,14 +590,19 @@ class SlotState:
         "ds_serve_state_resets_total":
             "slot states reset: a request (or a preempted one's resume) took "
             "a slot and its first chunk starts from a zero state",
+        "ds_serve_ssm_chunk_rows_total":
+            "(row, mamba2 layer) pairs the prefill chunk programs' chunked "
+            "scan (models/ssm_moe.py:ssm_chunk_scan) worked, the pad rows of "
+            "their buckets included (beside ds_serve_prefill_pad_rows_total)",
     }
     takes_valid_len = True          # the state is left as of the last real row
 
     def pool_args(self, dtype):
-        return {"slot_state_bytes": kda_mla.slot_state_bytes(self.cfg, dtype)}
+        return {"slot_state_bytes":
+                afmoe.form(self.cfg).slot_state_bytes(self.cfg, dtype)}
 
     def init_cache(self, pool, num_slots, dtype, quantized):
-        state, tail = kda_mla.state_shapes(self.cfg, num_slots)
+        state, tail = afmoe.form(self.cfg).state_shapes(self.cfg, num_slots)
         return {**super().init_cache(pool, num_slots, dtype, quantized),
                 "state": jnp.zeros(state, jnp.float32),
                 "tail": jnp.zeros(tail, dtype)}
@@ -623,6 +633,11 @@ class SlotState:
 
     def count_admit(self):
         self._m["ds_serve_state_resets_total"].inc()
+
+    def count_chunk(self, pool, cache, off, c, cb):
+        super().count_chunk(pool, cache, off, c, cb)
+        self._m["ds_serve_ssm_chunk_rows_total"].inc(
+            cb * self.cfg.layer_types.count("mamba2"))
 
     def count_block(self, counts):
         """``ds_serve_state_row_steps_*``: the block's (row, linear layer)
@@ -671,7 +686,6 @@ class FullPagesAndState(SlotState, FullPages):
     token prefix is off or refused until a state can be snapshot at a page
     boundary (ROADMAP R5 / R7)."""
 
-    what = "linear_attention / full_attention layers"
     _state = ("a recurrent state is a slot's, not a page's: the K/V pages of "
               "the full layers alone do not restore a request (state "
               "snapshots at page boundaries: ROADMAP R5 / R7)")
@@ -703,11 +717,22 @@ class FullPagesAndState(SlotState, FullPages):
             "other layers keep a state",
     }
     pages_by_kind = True
-    # the chunkwise recurrence works sub-chunks of ``SUB`` rows, and under
-    # one sub-chunk a chunk program's time is its weights' stream whatever
-    # its rows: a smaller bucket saves no work and costs a compile (three of
-    # eight chunk programs, 7 s each cold at Solar-Open2's widths)
-    chunk_rows = kda_mla.SUB
+
+    def __init__(self, cfg):
+        super().__init__(cfg)
+        # the recurrence works blocks of the model module's ``chunk_rows``
+        # (a sub-chunk of kda_mla's chunkwise delta rule, a block of
+        # ssm_moe's chunked scan), and under one block a chunk program's time
+        # is its weights' stream whatever its rows: a smaller bucket saves no
+        # work and costs a compile (three of eight chunk programs, 7 s each
+        # cold at Solar-Open2's widths)
+        self.chunk_rows = afmoe.form(cfg).chunk_rows(cfg)
+        # the kinds of layer that keep a cache, as the pattern names them:
+        # the state's, then the pages'
+        self.what = " / ".join(
+            [k for k in dict.fromkeys(cfg.layer_types)
+             if k not in ("experts", "full_attention")]
+            + ["full_attention"]) + " layers"
 
     def layout(self, pool, num_slots):
         return (super().layout(pool, num_slots)
@@ -934,6 +959,8 @@ def cache_kind(cfg) -> FullPages:
             return FullPagesAndState(cfg)
         return (LatentPagesAndState if kda_mla.kind_layers(cfg)[0]
                 else LatentPages)(cfg)
+    if getattr(cfg, "is_mixer", False):
+        return FullPagesAndState(cfg)
     if getattr(cfg, "is_afmoe", False):
         return TwoBudgets(cfg)
     return FullPages(cfg)

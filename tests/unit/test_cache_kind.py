@@ -6,7 +6,8 @@ summary pages (``test_evabyte``), two page budgets
 (``test_trinity``), latent pages alone (``test_axk1``), latent pages + slot
 state (``test_kimi_linear``), latent pages + index keys + slot rings
 (``test_dots3_note``), K/V pages in the full layers only + slot state
-(``test_solar_open2``).
+(``test_solar_open2``; and, the state's shape the model module's,
+``test_nemotron3_nano``).
 
 What every kind owes the engine: a slot's view written back unchanged leaves
 the pool as it was, and a changed one touches nobody else's pages; an
@@ -32,7 +33,7 @@ from deepspeed_tpu.serving.paged_kv import PagedKVPool
 
 from ._serving import as_found, with_noise
 from . import (test_axk1, test_dots3_note, test_evabyte, test_kimi_linear,
-               test_solar_open2, test_trinity)
+               test_nemotron3_nano, test_solar_open2, test_trinity)
 
 ENGINE = dict(num_slots=3, prefill_chunk=16, max_prefill_chunks=2,
               decode_block_tokens=4, max_out_tokens=96, kv_page_tokens=8,
@@ -49,6 +50,9 @@ CASES = {
                 test_dots3_note.ENGINE),
     "hybrid": (FullPagesAndState, test_solar_open2.FIELDS,
                test_solar_open2.ENGINE),
+    # the same kind under the one-mixer form: a state that is no square
+    "mixer": (FullPagesAndState, test_nemotron3_nano.FIELDS,
+              test_nemotron3_nano.ENGINE),
     # no kind of its own: full pages, one layer a (pass, layer) pair
     "looped": (FullPages, dict(
         vocab_size=96, hidden_size=64, intermediate_size=128, num_layers=2,
@@ -397,7 +401,7 @@ def test_the_keys_a_paged_decode_step_fetches_are_counted_by_its_rule(built,
     serve.run()
     attended = reg.get("ds_serve_attn_keys_attended_total").value
     fetched = reg.get("ds_serve_attn_keys_fetched_total").value
-    if name in ("full", "looped", "hybrid"):
+    if name in ("full", "looped", "hybrid", "mixer"):
         p = np.arange(prompt, prompt + n_out - 1)
         page, dh = serve.pool.page, model.config.head_dim
         assert attended == (p + 1).sum()
@@ -468,3 +472,34 @@ def test_model_config_refuses_by_name(fields, words):
     with pytest.raises(ValueError) as err:
         ModelConfig(**dict(test_dots3_note.FIELDS, **fields))
     assert words in str(err.value)
+
+
+def test_the_slot_states_shape_is_the_model_modules(built):
+    """``SlotState`` takes its arrays from the model's module: heads of a
+    square matrix under ``models/kda_mla.py`` (Kimi's and Solar's byte
+    counts as they were), the packed tile of ``models/ssm_moe.py`` that is
+    not square; the tail in the cache's dtype, the state float32 always."""
+    from deepspeed_tpu.models import kda_mla, ssm_moe
+
+    want = {"state": (kda_mla, 4 * 4 * 16 * 16 * 4 + 4 * 3 * 192 * 4),
+            "hybrid": (kda_mla, 3 * 4 * 16 * 16 * 4 + 3 * 3 * 192 * 4),
+            "mixer": (ssm_moe, 3 * 4 * 16 * 16 * 4 + 3 * 3 * 128 * 4)}
+    for name, (module, nbytes) in want.items():
+        model, _ = built(name)
+        cfg = model.config
+        kind = cache_kind(cfg)
+        assert kind.pool_args(jnp.float32) == {"slot_state_bytes": nbytes}
+        state, tail = module.state_shapes(cfg, 3)
+        pool = PagedKVPool(3, 96, page_tokens=8,
+                           **kind.pool_args(jnp.bfloat16))
+        cache = kind.init_cache(pool, 3, jnp.bfloat16, False)
+        assert cache["state"].shape == state and cache["tail"].shape == tail
+        assert cache["state"].dtype == jnp.float32
+        assert cache["tail"].dtype == jnp.bfloat16
+        assert pool.state_bytes == 3 * kind.pool_args(jnp.bfloat16)[
+            "slot_state_bytes"]
+    # square under KDA, [heads / 2, state dim, 2 heads' values] here
+    assert kda_mla.state_shapes(built("hybrid")[0].config, 3)[0][-2:] \
+        == (16, 16)
+    assert ssm_moe.state_shapes(built("mixer")[0].config, 3)[0] \
+        == (3, 3, 2, 16, 32)

@@ -45,7 +45,9 @@ def test_the_floor_is_the_kernels_tile_and_is_written_once():
     assert {k.chunk_rows for k in (ck.FullPages, ck.WindowSummaryPages,
                                    ck.TwoBudgets)} == {8}
     from deepspeed_tpu.models import kda_mla
-    assert ck.FullPagesAndState.chunk_rows == kda_mla.SUB == 64
+    # a slot state beside per-head pages: the model module's block
+    assert "chunk_rows" not in vars(ck.FullPagesAndState)
+    assert kda_mla.chunk_rows(None) == kda_mla.SUB == 64
 
 
 @pytest.mark.parametrize("name,chunk", [

@@ -338,6 +338,75 @@ def test_kernels_compile_at_the_olmoe_serve_chat_shape(v5e, kernel):
     assert _custom_calls(fn, v5e, *shapes) >= want
 
 
+# the benchmark's nemotron3-nano-L9-ep2.serve-reason-4k cell: 256 slots of
+# hidden 2,688; 64 Mamba-2 heads of 64 over a state of 128 (a row's state of
+# one layer is 32 tiles [128, 128] float32); 64 held experts of TWO matrices
+# stored 2,048 wide, the shared one 4,096; no gate matrix anywhere
+NEMOTRON = dict(D=2688, slots=256, H=64, P=64, G=8, N=128, F=2048, Fs=4096,
+                E=64, L=4)
+
+
+def _ssm_step(w):
+    from deepspeed_tpu.ops.pallas.decode import (ssm_decode_step,
+                                                 ssm_heads_per_tile)
+
+    B, H, P, G, N, L = (w[k] for k in ("slots", "H", "P", "G", "N", "L"))
+    pk = ssm_heads_per_tile(H, P, G)
+    fn = lambda s, x, dt, a, bm, cm, live: ssm_decode_step(
+        s, x, dt, a, bm, cm, layer=L - 1, live=live, impl="pallas")[:2]
+    return fn, [((L, B, H // pk, N, pk * P), F32), ((B, H, P), F32),
+                ((B, H), F32), ((H,), F32), ((B, G, N), F32),
+                ((B, G, N), F32), ((B,), jax.numpy.bool_)], 1
+
+
+def _relu2_moe(w):
+    B, D, F, E, L = (w[k] for k in ("slots", "D", "F", "E", "L"))
+    fn = lambda h, r, c, wu, wd: fused_moe_mlp(
+        h, r, c, wu, wd, None, layer=L - 1, act="relu2", impl="pallas")
+    return fn, [((B, D), BF16), ((B, D), BF16), ((B, E), F32),
+                ((L, E, D, F), BF16), ((L, E, F, D), BF16)], 1
+
+
+def _relu2_shared(w):
+    B, D, F = w["slots"], w["D"], w["Fs"]
+    fn = lambda h, r, wu, wd: fused_mlp(h, r, wu, wd, None, act="relu2",
+                                        impl="pallas")
+    return fn, [((B, D), BF16), ((B, D), BF16), ((D, F), BF16),
+                ((F, D), BF16)], 1
+
+
+def _wide_in_proj(w):
+    B, D = w["slots"], w["D"]
+    fn = lambda x, s, wi: fused_norm_qkv(x, s, None, wi, None,
+                                         kind="rmsnorm", impl="pallas")
+    return fn, [((B, D), BF16), ((D,), BF16), ((D, 10752), BF16)], 1
+
+
+def _out_proj(w):
+    B, D = w["slots"], w["D"]
+    fn = lambda c, r, wo, s: fused_proj_norm(c, r, wo, None, s, None,
+                                             kind="rmsnorm", impl="pallas")
+    return fn, [((B, 4096), BF16), ((B, D), BF16), ((4096, D), BF16),
+                ((D,), BF16)], 1
+
+
+@pytest.mark.parametrize("kernel", [_ssm_step, _relu2_moe, _relu2_shared,
+                                    _wide_in_proj, _out_proj],
+                         ids=["ssm_decode_step", "fused_moe_mlp_no_gate",
+                              "fused_mlp_no_gate", "fused_norm_qkv",
+                              "fused_proj_norm"])
+def test_kernels_compile_at_the_nemotron_cells_256_slots(v5e, kernel):
+    """256 rows of 2,688 resident beside the weight tiles: what a grid step
+    holds at the cell's slots (ROADMAP's lesson of PR 59), and the state
+    kernel's 2 MB blocks, read and written."""
+    from deepspeed_tpu.ops.pallas.common import reference_selections
+
+    before = len(reference_selections())
+    fn, shapes, want = kernel(NEMOTRON)
+    assert _custom_calls(fn, v5e, *shapes) >= want
+    assert len(reference_selections()) == before
+
+
 # the benchmark's evabyte-L6.serve-doc cell: MHA 32 x 128, window 2,048 and
 # chunk 16 over pages of 256 (8 window + 4 summary pages a row), 32 slots,
 # the residual stream float32 between the kernels

@@ -458,6 +458,56 @@ def test_solar_open2_cell_programs_compile_with_state_and_pages_in_place(
     assert "mla_decode_paged" not in text
 
 
+def test_nemotron3_nano_cell_programs_compile_at_the_cells_256_slots(
+        v5e, chip_kernels):
+    """ISSUE 63: the chunk programs (buckets 1,024 and 128: the scan's one
+    block) and the decode block of the
+    ``nemotron3-nano-L9-ep2.serve-reason-4k`` cell (the published widths: 64
+    Mamba-2 heads of 64 over a state of 128, 32 query heads over 2 key-value
+    heads of 128, experts of 1,856 stored at 2,048, the shared one of 3,712 at
+    4,096; the pattern cut to one layer of each kind, which changes no shape;
+    the pool cut to 32 slots' worth (half of it is then more than the 34 MB
+    of expert rows a 1,024-row chunk gathers) but all 256 SLOTS kept,
+    ROADMAP's lesson of PR 59: 256 rows of 2,688 beside the weight tiles are
+    what a grid step holds) compile for the v5e: the K/V pages of the ONE attention layer, the
+    state and the convolution tails stay where they are, every kernel the
+    decode block calls is the Pallas one at these sizes (no reference
+    fallback: ``ssm_decode_step`` over [32, 128, 128] tiles,
+    ``flash_decode_paged`` at a group of 16 query heads a key-value head,
+    ``fused_moe_mlp`` and ``fused_mlp`` WITHOUT a gate matrix), and a chunk's
+    grouped matmuls take the held-share pad."""
+    cell = _ServeCell(
+        v5e, "nemotron3-nano-L9-ep2", "nemotron3-nano-L9-ep2.serve-reason-4k",
+        fields=dict(num_layers=3, layer_types=["mamba2", "full_attention",
+                                               "experts"]),
+        engine=dict(kv_pool_tokens=32 * 4864))
+    cache = cell.serve._cache
+    assert cell.serve.num_slots == 256
+    assert cache["k"].shape == (1, 32 * 19 + 1, 2, 256, 128)
+    assert cache["state"].shape == (1, 256, 32, 128, 128)
+    assert cache["tail"].shape == (1, 256, 3, 6144)
+    cell.smallest_pool = cache["k"].nbytes
+    shape = lambda k: ",".join(str(d) for d in cache[k].shape)
+    chunks = (cell.chunk(1024), cell.chunk(128))
+    for program, tokens in zip(chunks, (1024, 128)):
+        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+            program, tokens * 6)
+    for program in chunks + (cell.block(),):
+        cell.assert_pools_stay_in_place(program)
+        for kind, key in (("f32", "state"), ("bf16", "tail")):
+            assert not re.findall(rf"{kind}\[{shape(key)}\]\S* copy\(",
+                                  program.as_text()), key
+        mem = program.memory_analysis()
+        print("memory", mem.temp_size_in_bytes, mem.argument_size_in_bytes,
+              mem.output_size_in_bytes, mem.alias_size_in_bytes)
+    text = program.as_text()
+    for name in ("ssm_decode_step", "flash_decode_paged", "paged_kv_append",
+                 "fused_norm_qkv", "fused_proj_norm", "fused_mlp",
+                 "fused_moe_mlp"):
+        assert name in text, name
+    assert "kda_decode_step" not in text
+
+
 def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
         v5e, chip_kernels):
     """ISSUE 48: the chunk programs (buckets 1,024 and 64) and the decode
