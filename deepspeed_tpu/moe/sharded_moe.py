@@ -23,10 +23,11 @@ Two paths, chosen by ``cfg.moe_drop_tokens``:
   drops, scatter or einsum dispatch, the ``ep`` all-to-all.  Training.
 - ``False``: **dropless**.  The ``N*k`` assignments are sorted by expert and
   the three projections run as grouped matmuls over ``[N*k, D]`` with
-  ``group_sizes [E]`` (``jax.lax.ragged_dot``), then un-sorted and combined
-  with the router weights: the cost is the routed tokens, not ``E x N``,
-  and no capacity exists to overflow.  Serving prefill and the unfused
-  decode loop run it; the fused decode path has its own kernel
+  ``group_sizes [E]`` (``jax.lax.ragged_dot``; a chip's chunk programs,
+  over the stacked arrays: ``ops/pallas/grouped_matmul.py``), then un-sorted
+  and combined with the router weights: the cost is the routed tokens, not
+  ``E x N``, and no capacity exists to overflow.  Serving prefill and the
+  unfused decode loop run it; the fused decode path has its own kernel
   (``ops/pallas/decode.py:fused_moe_mlp``).  Not built under ``ep > 1``.
 
 Expert weights are sharded over ``ep`` (expert parallelism) and optionally
@@ -43,7 +44,10 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.comm.mesh import axis_size
 from deepspeed_tpu.models.layers import activation_fn, constrain
+from deepspeed_tpu.ops.pallas.common import resolve_impl
+from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
 
 def compute_capacity(num_tokens: int, num_experts: int, k: int,
@@ -178,7 +182,7 @@ ROW_TILE = 128
 
 
 def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
-                 assign=None):
+                 assign=None, mesh=None):
     """Dropless expert block on tokens ``xt`` [N, D] with router
     probabilities ``gates`` [N, E]: sort the N*k (token, expert) assignments
     by expert, run each projection as ONE grouped matmul over the sorted rows
@@ -191,21 +195,32 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
     ``e_idx`` counts the ``E`` experts HELD HERE, with ``E`` itself for an
     assignment to an expert another chip holds (a chip's share of the
     experts, ``models/afmoe.py``).  Those rows sort behind every group, lie
-    in none, and add nothing to their token.  The sorted rows then go to the
-    grouped matmuls as an ODD number of 128-row tiles, one tile of pad rows
-    (of no group either) behind them where N*k is an even number: the chip's
-    grouped matmul works a group in row tiles of the largest of 128, 256,
-    512 that divides its ``lhs`` length, a whole tile for a group of sixteen
-    rows too (``ROW_TILE``; where the groups hold 128-256 rows the small
-    tile can cost, ROADMAP R1).
+    in none, and add nothing to their token.  Where ``jax.lax.ragged_dot``
+    runs them, the sorted rows then go to the grouped matmuls as an ODD
+    number of 128-row tiles, one tile of pad rows (of no group either)
+    behind them where N*k is an even number: the chip's grouped matmul works
+    a group in row tiles of the largest of 128, 256, 512 that divides its
+    ``lhs`` length, a whole tile for a group of sixteen rows too
+    (``ROW_TILE``; where the groups hold 128-256 rows the small tile can
+    cost, ROADMAP R1).
 
     ``layer`` (a traced index) says the expert arrays are the model's STACKED
-    [L, E, ...] ones: they go to the grouped matmul whole, as L*E groups of
-    which the other layers' are empty.  Slicing one layer out, dynamically or
-    statically, copies its 0.8 GB in front of every call (the grouped matmul
-    is a custom call, which no slice fuses into): 5.2 against 2.9 ms a layer
-    at OLMoE's widths (tools/moe_grouped_bench.py, PERF.md Findings PR 27);
-    an empty group costs the matmul next to nothing."""
+    [L, E, ...] ones, which is inference (no gradient of the stack is
+    wanted): on a chip the three projections are then the Pallas grouped
+    matmul (``ops/pallas/grouped_matmul.py``: weight blocks of the whole
+    contraction, only the (row tile, group) pairs that hold rows, the stack
+    read in place at ``layer``), on the N*k sorted rows as they are.  Off
+    the chip, and for a layer's own slice (``layer=None``: training, which
+    differentiates through it), they are ``ragged_dot``, the stacked arrays
+    whole as L*E groups of which the other layers' are empty: slicing one
+    layer out, dynamically or statically, copies its 0.8 GB in front of
+    every call (the grouped matmul is a custom call, which no slice fuses
+    into): 5.2 against 2.9 ms a layer at OLMoE's widths
+    (tools/moe_grouped_bench.py, PERF.md Findings PR 27); an empty group
+    costs the matmul next to nothing.  The choice reads the platform
+    (``ops/pallas/common.py``, as every kernel's), ``layer`` and ``mesh``
+    (GSPMD cannot split a Pallas kernel over ``tp`` or ``sp``: expert arrays
+    sharded that way keep ``ragged_dot``), nothing else."""
     N, D = xt.shape
     E, k = cfg.num_experts, cfg.num_experts_per_tok
     if assign is None:
@@ -219,18 +234,26 @@ def _moe_grouped(params, xt, gates, cfg, normalize: bool, layer=None,
     # (an index of E, "held elsewhere", is past ``length`` and not counted)
     sizes = jnp.bincount(flat, length=E).astype(jnp.int32)
     gather = order
+    # inference over the stacked arrays on a chip: the Pallas grouped matmul
+    kernel = layer is not None and resolve_impl(None) != "xla" and (
+        mesh is None or mesh.empty
+        or axis_size(mesh, "tp") == axis_size(mesh, "sp") == 1)
     if assign is not None:
         inside = flat[order] < E                # the row lies in a group
-        if N * k % (2 * ROW_TILE) == 0:         # a tile of rows of no group
+        # a tile of rows of no group (it steers the chip's ``ragged-dot``
+        # alone: the kernel visits the tiles that hold a group's rows)
+        if N * k % (2 * ROW_TILE) == 0 and not kernel:
             gather, inside = (jnp.pad(a, (0, ROW_TILE))
                               for a in (order, inside))
     rows = xt[gather // k]                      # [N*k (+ a tile), D]
-    if layer is not None:
+    if layer is not None and not kernel:
         groups = params["w_up"].shape[0] * E
         sizes = jax.lax.dynamic_update_slice(
             jnp.zeros((groups,), jnp.int32), sizes, (layer * E,))
 
     def dot(a, w):
+        if kernel:
+            return grouped_matmul(a, w, sizes, layer=layer)
         if layer is not None:
             w = w.reshape((groups,) + w.shape[2:])
         return jax.lax.ragged_dot(a, w.astype(a.dtype), sizes)
@@ -281,7 +304,8 @@ def moe_mlp(params, x, cfg, mesh=None, rng=None, layer=None
                 "moe_drop_tokens=False under ep > 1: the dropless grouped "
                 "path has no expert-parallel exchange yet (ROADMAP R1, the "
                 "training half); the capacity path would drop tokens")
-        y, aux = _moe_grouped(params, xt, gates, cfg, normalize, layer)
+        y, aux = _moe_grouped(params, xt, gates, cfg, normalize, layer,
+                              mesh=mesh)
         return y.reshape(B, S, D), aux
     if layer is not None:
         raise ValueError("moe_mlp(layer=...) reads stacked expert arrays, "

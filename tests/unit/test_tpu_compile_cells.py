@@ -97,17 +97,21 @@ class _ServeCell:
             self._on_chip(s._rng),
             self._i32(s.num_slots, s.pool.slot_pages)).compile()
 
-    def assert_expert_rows_are_an_odd_number_of_tiles(self, program, rows):
-        """ISSUE 46: where the chip holds a share of the experts, every
-        grouped matmul of a chunk program takes its bucket's ``rows`` x k
-        sorted rows and one ``sharded_moe.ROW_TILE`` of pad, an odd number
-        of 128-row tiles (the tile the chip's ``ragged-dot`` then works a
-        group in)."""
-        from deepspeed_tpu.moe.sharded_moe import ROW_TILE
-
-        lhs = {int(r) for r in re.findall(
-            r"%ragged-dot-none\S* = bf16\[(\d+),", program.as_text())}
-        assert lhs == {rows + ROW_TILE} and rows % (2 * ROW_TILE) == 0, lhs
+    def assert_grouped_matmuls_are_the_kernel(self, program, rows):
+        """ISSUE 64: every grouped matmul of a chunk program is the Pallas
+        kernel ``moe_grouped_matmul`` over its bucket's ``rows`` x k sorted
+        rows (no tile of pad behind them: ISSUE 46's steered the chip's
+        ``ragged-dot``, of which the program holds none), its operands the
+        run-time extent of its grid, the five scalar-prefetched vectors,
+        the rows and a model's STACKED expert array."""
+        text = program.as_text()
+        assert "ragged-dot" not in text
+        calls = re.findall(
+            r"%moe_grouped_matmul\S* = bf16\[(\d+),\d+\]\S* custom-call\("
+            r"([^\n]*)custom_call_target=\"tpu_custom_call\"", text)
+        assert calls and {int(lhs) for lhs, _ in calls} == {rows}, calls
+        assert all(operands.count("%") == 8 for _, operands in calls), calls
+        return len(calls)
 
     def assert_pools_stay_in_place(self, program):
         """No instruction of ``program`` moves half a pool's bytes or more:
@@ -218,7 +222,8 @@ def test_chat_chunk_programs_never_copy_the_pool(v5e, chip_kernels,
     place: compiled for the v5e, nothing the size of half a pool is copied,
     gathered or scattered (the gather ``v[:, pt_row]`` read and rewrote the
     541 MB / 1.08 GB pools, 3.3 / 6.6 ms of every chunk program), and the
-    donated pools and carries keep their buffers."""
+    donated pools and carries keep their buffers.  ISSUE 64: OLMoE's hold
+    the Pallas grouped matmul and no ``ragged-dot``."""
     if cell not in chat_cells:
         chat_cells[cell] = _ServeCell(v5e, cell, cell + ".serve-chat",
                                       fields=dict(num_layers=2))
@@ -229,6 +234,11 @@ def test_chat_chunk_programs_never_copy_the_pool(v5e, chip_kernels,
     program = built.chunk(bucket)
     built.assert_pools_stay_in_place(program)
     built.assert_donations_taken(program, donated=5)
+    if cell == "olmoe-1b-7b-L8":
+        # ISSUE 64: the router form (``moe_mlp(layer=)``) takes the kernel
+        # too, three calls in the scanned layer's body
+        assert built.assert_grouped_matmuls_are_the_kernel(
+            program, bucket * 8) == 3
 
 
 @pytest.mark.parametrize("config,in_place", [
@@ -288,7 +298,7 @@ def test_trinity_cell_programs_compile_without_copying_a_budget(
     assert (pool.window_pages, pool.slot_pages) == (16, 80)
     chunk = cell.chunk(cell.serve.prefill_chunk)
     cell.assert_pools_stay_in_place(chunk)
-    cell.assert_expert_rows_are_an_odd_number_of_tiles(
+    cell.assert_grouped_matmuls_are_the_kernel(
         chunk, cell.serve.prefill_chunk * 4)
     block = cell.block()
     cell.assert_pools_stay_in_place(block)
@@ -393,7 +403,7 @@ def test_kimi_linear_cell_programs_compile_with_state_and_pool_in_place(
     shape = lambda k: ",".join(str(d) for d in cache[k].shape)
     chunks = (cell.chunk(1024), cell.chunk(64))
     for program, tokens in zip(chunks, (1024, 64)):
-        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+        cell.assert_grouped_matmuls_are_the_kernel(
             program, tokens * 8)
         # ISSUE 49: the one latent layer's chunk attention is the kernel
         assert _latent_chunk_kernels(program, 32, tokens) == (1, False, False)
@@ -440,7 +450,7 @@ def test_solar_open2_cell_programs_compile_with_state_and_pages_in_place(
     shape = lambda k: ",".join(str(d) for d in cache[k].shape)
     chunks = (cell.chunk(1024), cell.chunk(64))
     for program, tokens in zip(chunks, (1024, 64)):
-        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+        cell.assert_grouped_matmuls_are_the_kernel(
             program, tokens * 8)
     for program in chunks + (cell.block(),):
         cell.assert_pools_stay_in_place(program)
@@ -490,7 +500,7 @@ def test_nemotron3_nano_cell_programs_compile_at_the_cells_256_slots(
     shape = lambda k: ",".join(str(d) for d in cache[k].shape)
     chunks = (cell.chunk(1024), cell.chunk(128))
     for program, tokens in zip(chunks, (1024, 128)):
-        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+        cell.assert_grouped_matmuls_are_the_kernel(
             program, tokens * 6)
     for program in chunks + (cell.block(),):
         cell.assert_pools_stay_in_place(program)
@@ -527,7 +537,7 @@ def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
     assert cell.serve._cache["latent"].shape[-1] == 640
     chunks = (cell.chunk(1024), cell.chunk(64))
     for program, tokens in zip(chunks, (1024, 64)):
-        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+        cell.assert_grouped_matmuls_are_the_kernel(
             program, tokens * 8)
         # ISSUE 49: a kernel a latent layer, no score array, no copied view
         assert _latent_chunk_kernels(program, 64, tokens) == (2, False, False)
@@ -575,7 +585,7 @@ def test_dots3_note_cell_programs_compile_with_pages_and_rings_in_place(
         rf"custom-call\([^\n]*{name}", text))
     chunks = (cell.chunk(1024), cell.chunk(64))
     for program, tokens in zip(chunks, (1024, 64)):
-        cell.assert_expert_rows_are_an_odd_number_of_tiles(
+        cell.assert_grouped_matmuls_are_the_kernel(
             program, tokens * 8)
         text = program.as_text()
         assert (calls(text, "dsa_index_scores_chunk"),

@@ -1,11 +1,16 @@
 #!/usr/bin/env python3
 """Time the dropless expert block's grouped matmuls on the chip: the three
 projections of ``moe/sharded_moe.py:_moe_grouped`` over ``N*k`` sorted rows
-with ``group_sizes [E]``, as ``jax.lax.ragged_dot`` (what the program runs)
-and as the Pallas grouped matmul JAX ships
-(``jax.experimental.pallas.ops.tpu.megablox.gmm``), beside the whole
+with ``group_sizes [E]``, as ``jax.lax.ragged_dot`` (what a gradient flows
+through, and what a program runs off the chip) and as the repo's Pallas
+grouped matmul (``ops/pallas/grouped_matmul.py``: weight blocks of the whole
+contraction, the (row tile, group) pairs that hold rows; what the chunk
+programs run over the stacked arrays since PR 64), beside the whole
 ``moe_mlp`` block and the bytes and FLOPs the shapes ask for.  The choice
-between the two is made by this measurement (PERF.md, Findings, PR 27).
+between the two is made by the ``--held-share --stacked`` readings below, at
+each cell's widths (PERF.md, Findings, PR 64; PR 27 had timed JAX's own
+``megablox.gmm`` at its default (128, 128, 128) tiling, a k loop over 32 KB
+weight blocks, 10.16 ms a layer against 2.78: that column is gone).
 
     python3 tools/moe_grouped_bench.py [--tokens 256,1024]
 
@@ -32,11 +37,14 @@ a jit) read about twice the cell's per-call time and price nothing in a cell
 
     python3 tools/moe_grouped_bench.py --held-share 8 --stacked \
         --experts 32 --top-k 8 --hidden 2304 --width 1024 --tokens 1024
+    python3 tools/moe_grouped_bench.py --held-share 2 --stacked --two-matrix \
+        --experts 64 --top-k 6 --hidden 2688 --width 2048 --tokens 1024
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -63,36 +71,51 @@ def held_share(args) -> int:
     import jax.numpy as jnp
     from types import SimpleNamespace
 
+    from deepspeed_tpu.models.layers import activation_fn
     from deepspeed_tpu.moe import sharded_moe
+    from deepspeed_tpu.ops.pallas import common
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
     dev = jax.devices()[0]
     E, k, D, F = args.experts, args.top_k, args.hidden, args.width
     ER, L = E * args.held_share, args.stacked_layers or 4
+    glu = not args.two_matrix
     cfg = SimpleNamespace(num_experts=E, num_experts_per_tok=k,
-                          activation="silu", glu=True)
+                          activation="silu" if glu else "relu2", glu=glu)
+    act = activation_fn(cfg.activation)
+    pallas = "pallas" if dev.platform == "tpu" else "interpret"
     keys = jax.random.split(jax.random.PRNGKey(args.seed), 6)
     bf = jnp.bfloat16
     stack = {n: jax.random.normal(key, (L, E) + shape, bf) * shape[0] ** -0.5
              for n, key, shape in (("w_up", keys[0], (D, F)),
                                    ("w_gate", keys[1], (D, F)),
-                                   ("w_down", keys[2], (F, D)))}
+                                   ("w_down", keys[2], (F, D)))
+             if glu or n != "w_gate"}
     layers = jnp.arange(L, dtype=jnp.int32)
     tile = sharded_moe.ROW_TILE
 
-    def matmuls(rows, w, sizes, layers):
-        """The three projections of every expert layer over ``rows``."""
+    def matmuls(kernel, rows, w, sizes, layers):
+        """The projections of every expert layer over ``rows``, as the
+        kernel or as ``ragged_dot`` over the stack's ``L * E`` groups."""
         flat = {n: a.reshape((L * E,) + a.shape[2:]) for n, a in w.items()}
         out = rows
         for l in range(L):
             s = jax.lax.dynamic_update_slice(
                 jnp.zeros((L * E,), jnp.int32), sizes, (layers[l] * E,))
-            dot = lambda a, n: jax.lax.ragged_dot(a, flat[n], s)
-            h = jax.nn.silu(dot(out, "w_gate")) * dot(out, "w_up")
+            if kernel:
+                dot = lambda a, n: grouped_matmul(a, w[n], sizes,
+                                                  layer=layers[l], impl=pallas)
+            else:
+                dot = lambda a, n: jax.lax.ragged_dot(a, flat[n], s)
+            up = dot(out, "w_up")
+            h = act(dot(out, "w_gate")) * up if glu else act(up)
             out = out + dot(h, "w_down")
         return out
 
-    def blocks(row_tile, *args):
-        """Every expert layer's whole block, traced under ``row_tile``."""
+    def blocks(row_tile, impl, *args):
+        """Every expert layer's whole block, traced under ``row_tile`` with
+        ``impl`` for the process's kernels (``_moe_grouped`` takes what
+        ``common.default_impl`` gives: no argument reaches its choice)."""
         def f(x, w, weight, local, layers):
             for l in range(L):
                 y, _ = sharded_moe._moe_grouped(
@@ -100,11 +123,12 @@ def held_share(args) -> int:
                     assign=(weight, local))
                 x = x + y
             return x
-        sharded_moe.ROW_TILE = row_tile
+        sharded_moe.ROW_TILE, was = row_tile, common.default_impl
+        common.default_impl = lambda: impl
         try:
             return jax.jit(f).lower(*args).compile()
         finally:
-            sharded_moe.ROW_TILE = tile
+            sharded_moe.ROW_TILE, common.default_impl = tile, was
 
     for N in (int(n) for n in args.tokens.split(",")):
         x = jax.random.normal(keys[3], (N, D), bf)
@@ -118,24 +142,35 @@ def held_share(args) -> int:
         rows = jax.random.normal(keys[5], (N * k, D), bf)
         held = int(sizes.sum())
         per_layer_us = lambda fn, *a: timed(fn, *a) / L * 1e3
-        mm = jax.jit(matmuls)
+        mm = jax.jit(functools.partial(matmuls, False))
+        mk = jax.jit(functools.partial(matmuls, True))
         args_ = (x, stack, weight, local, layers)
-        # a tile no row count is a multiple of: the parent's form
-        one_call, padded = blocks(1 << 30, *args_), blocks(tile, *args_)
+        # a tile no row count is a multiple of: the form before PR 46
+        one_call = blocks(1 << 30, "xla", *args_)
+        padded = blocks(tile, "xla", *args_)
+        kernel = blocks(tile, pallas, *args_)
+        pad_rows = jnp.pad(rows, ((0, tile), (0, 0)))
+        n_mats = 3 if glu else 2
+        diff = lambda a, b: float(jnp.abs(
+            a(*args_).astype(jnp.float32) - b(*args_)).max())
         row = {"tokens": N, "rows": N * k, "held_rows": held, "tile": tile,
-               "layers": L, "device": dev.device_kind,
-               "weight_bytes_us": 3 * E * D * F * 2 / 819e9 * 1e6,
-               "held_flops_us": 2 * held * 3 * D * F / 197e12 * 1e6,
+               "groups_hit": int((sizes > 0).sum()),
+               "layers": L, "matrices": n_mats, "device": dev.device_kind,
+               "weight_bytes_us": n_mats * E * D * F * 2 / 819e9 * 1e6,
+               "held_flops_us": 2 * held * n_mats * D * F / 197e12 * 1e6,
                "matmuls_all_rows_us": per_layer_us(mm, rows, stack, sizes,
                                                    layers),
                "matmuls_padded_rows_us": per_layer_us(
-                   mm, jnp.pad(rows, ((0, tile), (0, 0))), stack, sizes,
-                   layers),
+                   mm, pad_rows, stack, sizes, layers),
+               "matmuls_kernel_us": per_layer_us(mk, rows, stack, sizes,
+                                                 layers),
+               "matmuls_kernel_padded_us": per_layer_us(
+                   mk, pad_rows, stack, sizes, layers),
                "block_one_call_us": per_layer_us(one_call, *args_),
                "block_padded_us": per_layer_us(padded, *args_),
-               "padded_max_abs_diff": float(jnp.abs(
-                   one_call(*args_).astype(jnp.float32)
-                   - padded(*args_)).max())}
+               "block_kernel_us": per_layer_us(kernel, *args_),
+               "padded_max_abs_diff": diff(one_call, padded),
+               "kernel_max_abs_diff": diff(kernel, padded)}
         for n in (int(n) for n in args.lhs_rows.split(",") if n):
             if held <= n <= N * k:      # the prefix holds every held row
                 row[f"matmuls_{n}_rows_us"] = per_layer_us(
@@ -156,6 +191,9 @@ def main() -> int:
                     help="the --experts held here are one in this many of "
                          "the router's (with --stacked)")
     ap.add_argument("--stacked", action="store_true")
+    ap.add_argument("--two-matrix", action="store_true",
+                    help="--held-share: relu2 experts of an up and a down "
+                         "projection, no gate (models/ssm_moe.py)")
     ap.add_argument("--lhs-rows", default="",
                     help="--held-share: other lengths of the sorted rows' "
                          "prefix to run the matmuls alone over")
@@ -171,6 +209,7 @@ def main() -> int:
     from types import SimpleNamespace
 
     from deepspeed_tpu.moe.sharded_moe import moe_mlp
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
 
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not args.allow_cpu:
@@ -190,10 +229,6 @@ def main() -> int:
               "w_up": jax.random.normal(keys[1], (E, D, F), bf) * D ** -0.5,
               "w_gate": jax.random.normal(keys[2], (E, D, F), bf) * D ** -0.5,
               "w_down": jax.random.normal(keys[3], (E, F, D), bf) * F ** -0.5}
-    try:
-        from jax.experimental.pallas.ops.tpu.megablox import gmm
-    except ImportError:
-        gmm = None
     for N in (int(n) for n in args.tokens.split(",")):
         x = jax.random.normal(keys[4], (1, N, D), bf)
         rows = jax.random.normal(keys[5], (N * k, D), bf)
@@ -215,14 +250,10 @@ def main() -> int:
                                       params, sizes),
                "moe_mlp_block_ms": timed(
                    jax.jit(lambda p, x: moe_mlp(p, x, cfg)[0]), params, x)}
-        if gmm is not None:
-            try:
-                row["megablox_gmm_ms"] = timed(
-                    three(lambda a, w, s: gmm(a, w, s,
-                                              preferred_element_type=bf)),
-                    rows, params, sizes)
-            except Exception as e:       # a tiling it refuses, at this shape
-                row["megablox_gmm_error"] = f"{type(e).__name__}: {e}"[:300]
+        if dev.platform == "tpu":
+            row["grouped_matmul_ms"] = timed(
+                three(lambda a, w, s: grouped_matmul(a, w, s, impl="pallas")),
+                rows, params, sizes)
         if args.stacked_layers:
             # how a layer's experts are best taken out of the model's STACKED
             # [L, E, ...] arrays: a dynamic slice (a scan over layers), a
