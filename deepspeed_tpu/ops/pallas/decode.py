@@ -87,6 +87,52 @@ def _col_block(d_in: int, n_cols: int, itemsize: int = 2,
     return n_cols
 
 
+# fused_moe_mlp alone asks the compiler for more than its scoped 16 MiB: a
+# grid step takes a held expert WHOLE where two such blocks, the resident
+# rows and the step's float32 intermediates fit this share of the chip's
+# VMEM (40 MiB of a v5e's 128: OLMoE's 12.6 MB and Kimi-Linear's 14.2 MB
+# experts pass whole, experts of 22 MB and more keep ``_col_block``'s tiles;
+# PERF.md, Findings, PR 65)
+_WHOLE_EXPERT_VMEM_SHARE = 5 / 16
+# one v5e TensorCore's VMEM (jax/_src/pallas/mosaic/tpu_info.py, "TPU v5
+# lite"): the chip this repo targets, for a process whose default device is
+# no TPU (interpret mode, a compile for a described chip)
+_VMEM_BYTES_V5E = 128 * 2**20
+
+
+def _vmem_capacity_bytes() -> int:
+    try:
+        return int(pltpu.get_tpu_info().vmem_capacity_bytes)
+    except ValueError:              # "Unsupported TPU device kind: cpu"
+        return _VMEM_BYTES_V5E
+
+
+def moe_expert_block(rows: int, d: int, f: int, *, matrices: int = 3,
+                     itemsize: int = 2, row_itemsize: int = 2):
+    """(FFN columns a grid step of :func:`fused_moe_mlp` takes, the VMEM
+    limit its call sets | None) for ``rows`` of ``d`` against experts of
+    ``matrices`` [d, f] / [f, d] arrays.  The whole expert (``f``) where
+    ``_col_block`` gives it anyway (small ops: the compiler's own limit
+    holds them) or where everything a step holds fits
+    ``_WHOLE_EXPERT_VMEM_SHARE`` of the chip's VMEM: the expert's matrices
+    twice (the block in use and the one on its way), ``h``, ``r`` and the
+    output twice, the float32 accumulator, the step's float32 up / gate /
+    activation and down products, the combine column's lane tile, and what
+    ``_VMEM_STEP_BYTES`` leaves the compiler (1.25 MB) — that sum, rounded
+    up to a MiB, is then the call's limit.  ``_col_block``'s tile, under the
+    compiler's scoped limit, elsewhere."""
+    tile = _col_block(d * matrices, f, itemsize)
+    if tile == f:
+        return f, None
+    need = (2 * matrices * d * f * itemsize
+            + rows * d * (3 * 2 * row_itemsize + 4)
+            + 4 * rows * (matrices * f + d) + 2 * 4 * rows * 128
+            + 16 * 2**20 - _VMEM_STEP_BYTES)
+    if need <= _WHOLE_EXPERT_VMEM_SHARE * _vmem_capacity_bytes():
+        return f, round_up(need, 2**20)
+    return tile, None
+
+
 def _normalize(x32, scale, bias, kind: str, eps: float):
     """fp32 norm over the last axis; ``bias`` ignored for rmsnorm."""
     if kind == "rmsnorm":
@@ -1801,7 +1847,8 @@ def _moe_mlp_ref(h, r, combine, w_up, w_gate, w_down, *, act):
 
 
 def _moe_mlp_kernel(h_ref, r_ref, c_ref, wu_ref, *rest, act, glu, ne, nf):
-    """One grid step = one expert x one FFN tile: all rows against the tile,
+    """One grid step = one expert x one FFN tile (the whole expert where
+    ``nf`` is 1): all rows against the tile,
     the down-projection weighed by this expert's combine column and added
     into the float32 accumulator (which starts at the residual).  ``rest``:
     the gate's tile where the experts have one, the down tile, the output
@@ -1839,9 +1886,16 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
     The grid walks (expert, FFN tile): each expert's matrices pass through
     VMEM once and ALL rows run against them, chosen or not — an unchosen
     expert adds ``0 *`` a finite number, so the result is the exact dropless
-    mixture whatever the routing.  At decode batch sizes the block is bound
-    by those weight bytes (every expert is hit by some row almost every
-    step), not by the E/k times more FLOPs than the rows asked for."""
+    mixture whatever the routing.  A small expert is ONE tile
+    (:func:`moe_expert_block`: the grid is then an expert a step, the
+    contraction over F one float32 accumulation, and the call sets its own
+    VMEM limit); a step boundary costs the chip about a microsecond of idle
+    HBM, which is why.  Up to ~128 rows the call is bound by those weight
+    bytes (every expert is hit by some row almost every step), not by the
+    E/k times more FLOPs than the rows asked for; at 256 rows of 2,688
+    against 64 two-matrix experts stored 2,048 wide the MXU's time at peak
+    (361 GFLOP = 1.83 ms) passes the bytes' (1.72 ms) and the call is
+    FLOP-bound (PERF.md, Findings, PR 65)."""
     impl = resolve_impl(impl)
     glu = w_gate is not None
     if impl == "xla":
@@ -1851,7 +1905,9 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
                             act=act)
     B, D = h.shape
     E, _, F = w_up.shape[-3:]
-    bf = _col_block(D * (3 if glu else 2), F, w_up.dtype.itemsize)
+    bf, vmem_limit = moe_expert_block(
+        B, D, F, matrices=3 if glu else 2, itemsize=w_up.dtype.itemsize,
+        row_itemsize=h.dtype.itemsize)
     base = 0 if layer is None else layer * E
     kernel = functools.partial(_moe_mlp_kernel, act=act, glu=glu, ne=E,
                                nf=F // bf)
@@ -1868,6 +1924,8 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
         out_specs=rows,
         out_shape=jax.ShapeDtypeStruct((B, D), h.dtype),
         scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
+        compiler_params=vmem_limit and pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit),
         interpret=interpret_flag(impl),
         name="fused_moe_mlp",
     )(h, r, combine.astype(jnp.float32).T[:, :, None],

@@ -430,6 +430,12 @@ class ServingEngine:
                         f"host seconds inside {name}: {what}")
         self._m_moe = {name: reg.counter(name, what)
                        for name, what in SERVE_MOE_COUNTERS.items()}
+        self._m_moe_block = reg.gauge(
+            "ds_serve_moe_expert_block_share",
+            "the weight block of a grid step of the decode block's "
+            "fused_moe_mlp as a share (%) of an expert's bytes: 100 = a "
+            "held expert passes through VMEM whole; set when the block "
+            "program is built, 0 for a dense model")
         # page and state series: every kind's registered, this kind's moved
         self.kind.attach(reg, self.pool)
         self.kind.cache_gauges(self._cache)
@@ -2295,7 +2301,13 @@ class ServingEngine:
         moe0 = None
         if self.engine._dparams is not None and self.module.config.is_moe:
             from deepspeed_tpu.models.fused_decode import moe_counts_zero
+            from deepspeed_tpu.ops.pallas.decode import moe_expert_block
             moe0 = moe_counts_zero(self.module.config)
+            ex = self.engine._dparams["experts"]
+            (D, F), item = ex["w_up"].shape[-2:], ex["w_up"].dtype.itemsize
+            self._m_moe_block.set(100.0 / F * moe_expert_block(
+                self.num_slots, D, F, matrices=len(ex), itemsize=item,
+                row_itemsize=item)[0])
 
         def body(params, cache, last, pos, active, limit, eos, rng,
                  page_table):

@@ -234,13 +234,38 @@ def test_decode_block_counts_routing_of_live_rows(devices, interpret_kernels):
     assert 0 < value("expert_hits_total") <= value("assignments_total")
     # the fullest expert holds between its fair share and every live row
     assert value("assignments_total") / 8 <= value("max_load_total") <= 12 * 2
+    # a tiny expert is one block of the kernel's: the gauge says so
+    assert value("expert_block_share") == 100.0
 
 
 # -- (d): the kernel alone ------------------------------------------------
+# the block the rule takes at the test's float32 experts (D 64, F 256), as
+# (``_col_block``'s tile budget in columns | None for the module's, the
+# whole-expert share of the chip's VMEM | None for the module's) -> (columns
+# a step, a VMEM limit of the call's own): the whole expert as a small op,
+# tiles of 128 columns where the share refuses the expert, the whole expert
+# under its own limit where the share takes it
+BLOCKS = {"small_op": ((None, None), (256, False)),
+          "tiles": ((128, 0.0), (128, False)),
+          "whole_by_budget": ((128, None), (256, True))}
+
+
 @pytest.mark.parametrize("layer", [0, 2])
 @pytest.mark.parametrize("glu", [True, False])
-def test_fused_moe_mlp_against_jnp_on_stacked_weights(layer, glu):
+@pytest.mark.parametrize("block", sorted(BLOCKS))
+def test_fused_moe_mlp_against_jnp_on_stacked_weights(monkeypatch, layer,
+                                                      glu, block):
+    from deepspeed_tpu.ops.pallas import decode
+
     L, E, D, F, B = 3, 8, 64, 256, 5                    # B: no multiple of 8
+    (tile, share), (columns, limited) = BLOCKS[block]
+    if tile is not None:
+        monkeypatch.setattr(decode, "_TILE_BYTES", tile * D * 3 * 4)
+    if share is not None:
+        monkeypatch.setattr(decode, "_WHOLE_EXPERT_VMEM_SHARE", share)
+    bf, limit = decode.moe_expert_block(B, D, F, matrices=3, itemsize=4,
+                                        row_itemsize=4)
+    assert (bf, limit is not None) == (columns, limited)
     k = jax.random.split(jax.random.PRNGKey(layer), 7)
     h, r = jax.random.normal(k[0], (B, D)), jax.random.normal(k[1], (B, D))
     wu, wg = (0.1 * jax.random.normal(k[i], (L, E, D, F)) for i in (2, 3))
@@ -256,6 +281,92 @@ def test_fused_moe_mlp_against_jnp_on_stacked_weights(layer, glu):
         want = want + combine[:, e:e + 1] * (a @ wd[layer, e])
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5,
                                atol=1e-5)
+    ref_ = decode._moe_mlp_ref(h, r, combine, wu[layer],
+                               wg[layer] if glu else None, wd[layer],
+                               act="silu")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref_), rtol=1e-5,
+                               atol=1e-5)
+
+
+# the decode block's call of each expert cell (rows = the cell's num_slots,
+# hidden, stored expert width, matrices of an expert; bf16): whole expert
+# where the expert is small (ISSUE 65's table), the parent's tile elsewhere
+CELL_BLOCKS = {
+    "olmoe-1b-7b-L8.serve-chat": ((64, 2048, 1024, 3), 1024),
+    "kimi-linear-L5-ep8.serve-reason-doc-tail": ((128, 2304, 1024, 3), 1024),
+    "nemotron3-nano-L9-ep2.serve-reason-4k": ((256, 2688, 2048, 2), 512),
+    "solar-open2-L4-ep8.serve-reason-4k": ((128, 4096, 1280, 3), 256),
+    "trinity-large-L5-ep8.serve-mixed-16k": ((32, 3072, 3072, 3), 256),
+    "axk1-L5-ep16.serve-mixed-16k": ((32, 7168, 2048, 3), 128),
+    "dots3-note-L5-ep16.serve-doc-48k": ((16, 5120, 1536, 3), 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_BLOCKS))
+def test_the_block_rule_takes_small_experts_whole(cell):
+    """The rule's choice at each expert cell's decode shape, read from the
+    cell's own files: the whole expert exactly where ISSUE 65's table says,
+    under a limit that holds at least the two blocks it asks for and stays
+    inside a v5e's VMEM; elsewhere ``_col_block``'s tile and no limit (the
+    parent's call)."""
+    import json
+
+    from deepspeed_tpu.models.ssm_moe import padded_width
+    from deepspeed_tpu.ops.pallas import decode
+
+    (rows, d, f, mats), want = CELL_BLOCKS[cell]
+    with open(os.path.join(REPO, "benchmarks", "workloads",
+                           cell + ".json")) as fh:
+        work = json.load(fh)
+    with open(os.path.join(REPO, "benchmarks", "configs",
+                           work["config"] + ".json")) as fh:
+        fields = json.load(fh)["model_config"]
+    assert work["engine"]["num_slots"] == rows
+    assert fields["hidden_size"] == d and mats == 2 + fields["glu"]
+    assert f in (fields["intermediate_size"],
+                 padded_width(fields["intermediate_size"]))
+    bf, limit = decode.moe_expert_block(rows, d, f, matrices=mats)
+    assert bf == want
+    if bf == f:
+        assert 2 * mats * d * f * 2 <= limit <= decode._VMEM_BYTES_V5E \
+            * decode._WHOLE_EXPERT_VMEM_SHARE
+    else:
+        assert limit is None
+        assert bf == decode._col_block(d * mats, f, 2)
+
+
+@pytest.mark.parametrize("mode", ["alone", "in_layer"])
+def test_the_kernels_bench_tool_rehearses_on_the_cpu(tmp_path, mode):
+    """``tools/moe_decode_bench.py`` end to end in interpret mode at tiny
+    widths: back to back over the stacked arrays, and between the other
+    instructions of scanned decode steps; a forced block is the block the
+    kernel's grid then asks for, and the module's constants are put back."""
+    import json
+
+    from deepspeed_tpu.ops.pallas import decode
+
+    spec = importlib.util.spec_from_file_location(
+        "_moe_decode_bench", os.path.join(REPO, "tools",
+                                          "moe_decode_bench.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    fields = dict(vocab_size=VOCAB, hidden_size=128, intermediate_size=256,
+                  num_layers=2, num_heads=2, num_kv_heads=2, head_dim=64,
+                  max_seq_len=256, num_experts=4, num_experts_per_tok=2,
+                  moe_drop_tokens=False, moe_norm_topk_prob=False,
+                  qk_norm=True)
+    before = decode._TILE_BYTES, decode._WHOLE_EXPERT_VMEM_SHARE
+    out = tmp_path / "rows.jsonl"
+    assert tool.main(
+        ["--allow-cpu", "--model-config", json.dumps(fields), "--rows", "8",
+         "--block", "rule,128", "--rounds", "1", "--steps", "2", "--out",
+         str(out)] + (["--in-layer"] if mode == "in_layer" else [])) == 0
+    rows = [json.loads(line) for line in out.read_text().splitlines()]
+    assert [(r["mode"], r["block"], r["block_cols"]) for r in rows] == \
+        [(mode, "rule", 256), (mode, "128", 128)]
+    assert all("error" not in r and r["matrices"] == 3 for r in rows)
+    assert (decode._TILE_BYTES, decode._WHOLE_EXPERT_VMEM_SHARE) == before
+    assert decode.pl.pallas_call.__name__ == "pallas_call"
 
 
 # -- (e): grouped path == capacity path where nothing drops ---------------
