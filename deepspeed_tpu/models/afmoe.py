@@ -588,7 +588,9 @@ def inject_rest(cfg, lp, le) -> Dict[str, Any]:
 
 def inject_outer(params, layers) -> Dict[str, Any]:
     out = {"embed": params["embed"], "final_norm": params["final_norm"],
-           "lm_head": params["lm_head"], "layers": tuple(layers)}
+           "layers": tuple(layers)}
+    if "lm_head" in params:       # absent: the head is the embedding's
+        out["lm_head"] = params["lm_head"]
     if _experts(params) is not None:
         out["experts"] = _experts(params)
     return out

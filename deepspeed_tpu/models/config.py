@@ -90,6 +90,15 @@ families instead; `ModelConfig` spans them with feature flags:
   ``shared_intermediate_size``); no layer has both an attention and an MLP
   (``models/ssm_moe.py``; benchmarks/configs/nemotron3-nano-L9-ep2.json).
   SERVED ONLY likewise
+- The same form with kinds ``mamba1`` and ``mlp`` (AI21 Jamba2-3B, ``jamba``:
+  a Mamba-1 selective scan, ``ssm_inner_size`` channels with a decay for
+  every (channel, state dim) pair, ``dt`` through a bottleneck of
+  ``ssm_dt_rank``, RMSNorms on dt, B and C (``ssm_inner_norms``), no gated
+  norm; a dense gated MLP as a layer of its own; multi-query attention; a
+  tied head): a published layer is a PAIR of one-mixer layers ``[mamba1 |
+  full_attention, mlp]``, and runs of ``[mamba1, mlp]`` pairs are rolled
+  (``lax.scan``) over stacks both the chunk programs and the decode step
+  read in place; benchmarks/configs/jamba2-3b.json.  SERVED ONLY likewise
 
 All presets follow the public architecture descriptions of those model
 families; sizes match the milestone configs in BASELINE.json.
@@ -211,6 +220,15 @@ class ModelConfig:
     ssm_state_size: int = 0
     ssm_conv_kernel: int = 0
     ssm_chunk: int = 128
+    # kind "mamba1" (Mamba-1's selective scan; Jamba): ``ssm_inner_size``
+    # channels, each with a state of ``ssm_state_size`` float32 and a decay of
+    # its own for every state dim; the step ``dt`` through a bottleneck of
+    # ``ssm_dt_rank``, with bias; ``ssm_inner_norms``: an RMSNorm with its own
+    # gain on each of dt's bottleneck, B and C.  Kind "mlp": a layer that is
+    # the dense MLP of ``intermediate_size`` (``glu`` / ``activation``)
+    ssm_inner_size: int = 0
+    ssm_dt_rank: int = 0
+    ssm_inner_norms: bool = False
     # the shared expert's width where it is a key of its own (0:
     # ``intermediate_size * num_shared_experts``)
     shared_intermediate_size: int = 0
@@ -403,15 +421,15 @@ class ModelConfig:
         if len(self.layer_types) != self.num_layers or not (
                 got <= _WINDOW_KINDS or got <= _STATE_KINDS
                 or got == _HYBRID_KINDS
-                or ("mamba2" in got and got <= _MIXER_KINDS)):
+                or (got & _SSM_KINDS and got <= _MIXER_KINDS)):
             raise ValueError(
                 f"layer_types must name, for each of the {self.num_layers} "
                 f"layers, one of {sorted(_WINDOW_KINDS)} (models/afmoe.py), "
                 f"one of {sorted(_STATE_KINDS)} (models/kda_mla.py), both "
                 f"of {sorted(_HYBRID_KINDS)} (models/kda_mla.py: a state a "
                 f"slot beside per-head K/V pages), or one of "
-                f"{sorted(_MIXER_KINDS)} with a mamba2 layer among them "
-                f"(models/ssm_moe.py: a layer is one mixer), got "
+                f"{sorted(_MIXER_KINDS)} with a mamba2 or a mamba1 layer "
+                f"among them (models/ssm_moe.py: a layer is one mixer), got "
                 f"{self.layer_types!r}")
         if self.is_mixer:
             self._check_router()
@@ -420,8 +438,8 @@ class ModelConfig:
         if self._moved(_MIXER_ONLY):
             raise ValueError(
                 f"{sorted(_MIXER_ONLY)} belong to the one-mixer layer form "
-                "(models/ssm_moe.py: mamba2, experts and full_attention "
-                "layers)")
+                "(models/ssm_moe.py: mamba2, mamba1, experts, mlp and "
+                "full_attention layers)")
         self._check_kda_mla()
         if "sliding_attention" in self.layer_types and self.sliding_window < 1:
             raise ValueError("sliding_attention layers need sliding_window")
@@ -448,13 +466,15 @@ class ModelConfig:
                       if f.name in names
                       and getattr(self, f.name) != f.default)
 
-    def _not_layer_form(self) -> bool:
-        """A field no ``layer_types`` model is built with."""
+    def _not_layer_form(self, tied: bool = False) -> bool:
+        """A field no ``layer_types`` model is built with (``tied``: the
+        form builds a tied head)."""
         return bool(
             self.moe_drop_tokens or self.use_bias or self.qkv_bias
             or self.mlp_bias or self.qk_norm or self.parallel_residual
             or self.fp32_residual or self.norm_add_unit_offset
-            or self.tie_embeddings or self.num_pred_heads != 1
+            or (self.tie_embeddings and not tied)
+            or self.num_pred_heads != 1
             or self.rotary_pct != 1.0 or self.dropout)
 
     def _check_router(self):
@@ -478,29 +498,42 @@ class ModelConfig:
                 f"top-{self.num_experts_per_tok}")
 
     def _check_mixer(self):
-        """The one-mixer form (models/ssm_moe.py): the sizes a mamba2 layer
-        needs and what the module does not build."""
-        need = [k for k in _MIXER_ONLY if k != "shared_intermediate_size"
-                and getattr(self, k) < 1]
-        if need:
-            raise ValueError(f"mamba2 layers (models/ssm_moe.py) need {need}")
-        if self.ssm_num_heads % self.ssm_groups:
+        """The one-mixer form (models/ssm_moe.py): the sizes a mamba2 or a
+        mamba1 layer needs and what the module does not build."""
+        got = set(self.layer_types)
+        if _SSM_KINDS <= got:
+            raise NotImplementedError(
+                "mamba1 and mamba2 layers in one model (models/ssm_moe.py): "
+                "a slot's cache has ONE state shape")
+        kind = "mamba1" if "mamba1" in got else "mamba2"
+        wants = _SSM_SIZES[kind] + _SSM_SHARED
+        need = [k for k in wants if getattr(self, k) < 1]
+        other = self._moved(tuple(k for sizes in _SSM_SIZES.values()
+                                  for k in sizes if k not in wants))
+        if need or other:
+            raise ValueError(
+                f"{kind} layers (models/ssm_moe.py) need {need}, and "
+                f"{other} belong to the other state-space kind")
+        if kind == "mamba2" and self.ssm_num_heads % self.ssm_groups:
             raise ValueError(
                 f"ssm_num_heads={self.ssm_num_heads} must be whole groups "
                 f"of ssm_groups={self.ssm_groups}: the heads of a group "
                 "share B and C")
-        if "experts" in self.layer_types and not self.is_moe:
+        if "experts" in got and not self.is_moe:
             raise ValueError("experts layers need num_experts > 0")
         wrong = self._moved(_AFMOE_ONLY - _MIXER_READS)
-        if wrong or (self.norm, self.glu, self.activation, self.attention) \
-                != ("rmsnorm", False, "relu2", "full") \
-                or self._not_layer_form() or self.sandwich_norm:
+        if wrong or (self.norm, self.attention) != ("rmsnorm", "full") \
+                or (self.glu, self.activation) not in (
+                    (False, "relu2"), (True, "silu")) \
+                or ("experts" in got and self.glu) \
+                or self._not_layer_form(tied=True) or self.sandwich_norm:
             raise ValueError(
                 "the one-mixer form (models/ssm_moe.py) is built for "
                 "RMSNorm before each mixer, attention without a position "
                 "encoding, norm or gate, experts of two matrices and relu2 "
-                "(activation='relu2', glu=False) without biases, dropless "
-                "(moe_drop_tokens=False), an untied head and a stream in "
+                "(activation='relu2', glu=False), mlp layers of that form "
+                "or gated with silu (glu=True, activation='silu'), no "
+                "biases, dropless (moe_drop_tokens=False) and a stream in "
                 f"the weights' dtype; not for {wrong or 'these fields'}")
 
     def _check_kda_mla(self):
@@ -666,9 +699,11 @@ class ModelConfig:
 
     @property
     def is_mixer(self) -> bool:
-        """A ``layer_types`` model whose layers are ONE mixer each: mamba2,
-        experts and full_attention layers (models/ssm_moe.py)."""
-        return self.layer_types is not None and "mamba2" in self.layer_types
+        """A ``layer_types`` model whose layers are ONE mixer each: mamba2
+        or mamba1, experts, mlp and full_attention layers
+        (models/ssm_moe.py)."""
+        return self.layer_types is not None and \
+            bool(_SSM_KINDS & set(self.layer_types))
 
     @property
     def num_expert_layers(self) -> int:
@@ -710,12 +745,17 @@ _STATE_KINDS = frozenset({"linear_attention", "latent_attention",
                           "latent_sliding_attention"})
 # a state a slot beside per-head K/V pages: both kinds, nothing else
 _HYBRID_KINDS = frozenset({"linear_attention", "full_attention"})
-# a layer is ONE mixer (models/ssm_moe.py); "mamba2" turns the form on
-_MIXER_KINDS = frozenset({"mamba2", "experts", "full_attention"})
+# a layer is ONE mixer (models/ssm_moe.py); a state-space kind turns the
+# form on
+_SSM_KINDS = frozenset({"mamba2", "mamba1"})
+_MIXER_KINDS = _SSM_KINDS | {"experts", "mlp", "full_attention"}
+# the sizes each state-space kind needs, and those both do
+_SSM_SIZES = {"mamba2": ("ssm_num_heads", "ssm_head_dim", "ssm_groups"),
+              "mamba1": ("ssm_inner_size", "ssm_dt_rank")}
+_SSM_SHARED = ("ssm_state_size", "ssm_conv_kernel", "ssm_chunk")
 # fields only that form reads
-_MIXER_ONLY = ("ssm_num_heads", "ssm_head_dim", "ssm_groups",
-               "ssm_state_size", "ssm_conv_kernel", "ssm_chunk",
-               "shared_intermediate_size")
+_MIXER_ONLY = (*_SSM_SIZES["mamba2"], *_SSM_SIZES["mamba1"], *_SSM_SHARED,
+               "ssm_inner_norms", "shared_intermediate_size")
 _MLA_SLIDING_KEYS = frozenset({"num_heads", "kv_rank", "nope_dim", "rot_dim",
                                "v_dim", "q_rank", "rope"})
 # fields only models/kda_mla.py reads

@@ -33,6 +33,37 @@ of some kind".  Here no layer has both:
     expert ``relu(h W_up)^2 W_down`` (two matrices), the shared expert the
     same at ``shared_intermediate_size``, added unweighted.
 
+    "mamba1" (AI21 Jamba: Mamba-1's selective scan over ``d_inner`` =
+    ssm_inner_size channels, N = ssm_state_size, R = ssm_dt_rank), h = N_l(x):
+        [u | z] = h W_in                   widths d_inner | d_inner
+        u = silu(conv_K(u) + b_conv)       the same convolution, over u alone
+        [r | B | C] = u W_x                widths R | N | N
+        r, B, C = RMSNorm(r), RMSNorm(B), RMSNorm(C)    own gains
+            (``ssm_inner_norms``; Mamba's own form has none)
+        dt = softplus(r W_dt + b_dt);  A = -exp(a_log)    [d_inner, N]
+        per channel c, S [d_inner, N] float32, S_0 = 0:
+            S_t[c, n] = exp(dt_t[c] A[c, n]) S_{t-1}[c, n] + dt_t[c] u_t[c] B_t[n]
+            y_t[c]    = sum_n S_t[c, n] C_t[n] + D[c] u_t[c]
+        mixer = (y * silu(z)) W_out        no gated norm
+    A decay for every (channel, state dim) pair: no matrix form, so the
+    recurrence is a KERNEL on the vector unit
+    (``ops/pallas/selective_scan.py``: ``selective_scan_chunk`` walks a
+    chunk's rows with a channel tile's state in registers,
+    ``mamba1_decode_step`` is ``ssm_decode_step``'s sibling with the
+    exponentials inside); the skip ``D u`` and the gate are XLA's, OUTSIDE
+    both, fused into the out-projection's input.
+
+    "mlp": the dense MLP as a layer of its own, ``(silu(h W_gate) * (h
+    W_up)) W_down`` (``glu``) or ``act(h W_up) W_down``.  A published Jamba
+    layer ``x + mixer(N1(x))``, ``x + mlp(N2(x))`` is TWO layers here.
+
+Runs of ``[mamba1, mlp]`` pairs are ROLLED (:func:`runs`: a ``lax.scan`` a
+run in the chunk programs and in the decode step alike), their weights
+stacks by kind that BOTH read in place: the decode kernels take a stack and
+the layer's index (``fused_norm_qkv(layer=)`` and its siblings), so a dense
+layer's weights are resident ONCE and a program's size does not grow with
+the depth.  The other kinds stay unrolled, with buffers of their own.
+
 Each piece is ONE function here (:func:`ssm_split`, :func:`ssm_inputs`,
 :func:`ssm_chunk_scan` / ``ops/pallas/decode.py:ssm_step_ref``,
 :func:`gated_group_norm`; ``kda_mla.short_conv`` is the convolution,
@@ -80,6 +111,10 @@ from deepspeed_tpu.models.kda_mla import _pad_cols, gqa_split, short_conv
 from deepspeed_tpu.ops.pallas.decode import (ssm_heads_per_tile,
                                              ssm_state_pack,
                                              ssm_state_unpack)
+from deepspeed_tpu.ops.pallas.selective_scan import (mamba1_decode_step,
+                                                     mamba1_pack,
+                                                     mamba1_tile,
+                                                     selective_scan_chunk)
 
 HI = jax.lax.Precision.HIGHEST
 # the experts' widths are whole multiples of this (module docstring): the
@@ -89,6 +124,13 @@ HI = jax.lax.Precision.HIGHEST
 # decode kernels' block is then 512 columns too
 WIDTH_TILE = 512
 GQA_IN = ("wq", "wk", "wv")       # an attention layer's projections of ``h``
+# a kind's stack in the parameters (an experts layer: ``layers``/``mlp``)
+STACK = {"mamba2": "ssm", "mamba1": "ssm1", "full_attention": "gqa",
+         "mlp": "mlp"}
+PAIR = ("mamba1", "mlp")          # the layers a rolled run repeats
+# rows the fused kernels of a mamba1 / mlp layer keep resident in VMEM: a
+# decode step's slots, or a chunk's bucket (the cell's 256 of 2,560)
+KERNEL_ROWS = 256
 
 
 def cache_key(cfg) -> str:
@@ -108,6 +150,28 @@ def kinds(cfg):
 
 def count(cfg, kind: str) -> int:
     return cfg.layer_types.count(kind)
+
+
+def ssm_kind(cfg) -> str:
+    """The state-space kind of the model's layers (it has one)."""
+    return "mamba1" if "mamba1" in cfg.layer_types else "mamba2"
+
+
+def runs(cfg):
+    """The layers in order as (first layer, pairs): ``pairs`` >= 2 a run of
+    that many ``[mamba1, mlp]`` pairs, ROLLED; 0 one layer, unrolled."""
+    out, l, L = [], 0, cfg.num_layers
+    while l < L:
+        n = 0
+        while cfg.layer_types[l + 2 * n:l + 2 * n + 2] == PAIR:
+            n += 1
+        if n >= 2:
+            out.append((l, n))
+            l += 2 * n
+        else:
+            out.append((l, 0))
+            l += 1
+    return out
 
 
 def padded_width(width: int) -> int:
@@ -134,9 +198,15 @@ def chunk_rows(cfg) -> int:
 
 
 def state_shapes(cfg, num_slots: int):
-    """Per-slot state of the mamba2 layers: ``state`` float32 (as
-    ``ssm_state_pack`` lays it out) and ``tail`` (the stream's dtype)
-    shapes."""
+    """Per-slot state of the mamba2 (or mamba1) layers: ``state`` float32
+    (as ``ssm_state_pack`` lays it out; a mamba1 layer's ``d_inner``
+    channels are that many heads of one value) and ``tail`` (the stream's
+    dtype) shapes."""
+    if ssm_kind(cfg) == "mamba1":
+        di, W = cfg.ssm_inner_size, mamba1_tile(cfg.ssm_inner_size)
+        n = count(cfg, "mamba1")
+        return ((n, num_slots, di // W, cfg.ssm_state_size, W),
+                (n, num_slots, cfg.ssm_conv_kernel - 1, di))
     n = count(cfg, "mamba2")
     H, P, _, N, _, C, pk = ssm_sizes(cfg)
     return ((n, num_slots, H // pk, N, pk * P),
@@ -167,7 +237,11 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     times the mean, a chip's share of the choices 45-55% a layer by the
     seed; at 0.01 the bias still changes the six chosen of every second
     token and the loads stay within 0.5-1.6); the token embedding normal x 1
-    (``kda_mla.init_params``: this form has no embedding multiplier).  EVERY
+    (``kda_mla.init_params``: this form has no embedding multiplier; a TIED
+    one normal x ``D^-0.5``, the head's scale, which at 2,560 is the 0.02 of
+    a release's ``initializer_range``: it is also the head).  A mamba1
+    layer: ``a_log[c, n] = log(n + 1)`` (Mamba's S4D-real init), ``dt_bias``
+    as above a CHANNEL, ``D`` = 1, the inner norms' gains 1.  EVERY
     projection that writes the residual stream (the Mamba and the attention
     out-projections, the experts' and the shared expert's down projections)
     is seeded at ``fan_in^-0.5 / sqrt(num_layers)``: the release's
@@ -175,8 +249,7 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     over the root of the residual layers, ONE a layer in this form), applied
     to all of them alike."""
     D, V = cfg.hidden_size, cfg.vocab_size
-    H, P, G, N, di, C, _ = ssm_sizes(cfg)
-    K = cfg.ssm_conv_kernel
+    N, K = cfg.ssm_state_size, cfg.ssm_conv_kernel
     keys = iter(jax.random.split(rng, 32))
     uni = lambda shape, fan_in: jax.random.uniform(
         next(keys), shape, dtype, -fan_in ** -0.5, fan_in ** -0.5)
@@ -184,19 +257,51 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     params: Dict[str, Any] = {
         "embed": {"tok": jax.random.normal(next(keys), (V, D), dtype)},
         "norms": {"scale": jnp.ones((cfg.num_layers, D), dtype)},
-        "final_norm": {"scale": jnp.ones((D,), dtype)},
-        "lm_head": jax.random.normal(next(keys), (D, V), dtype) * D ** -0.5}
+        "final_norm": {"scale": jnp.ones((D,), dtype)}}
+    if cfg.tie_embeddings:
+        params["embed"]["tok"] = params["embed"]["tok"] * D ** -0.5
+    else:
+        params["lm_head"] = jax.random.normal(next(keys), (D, V),
+                                              dtype) * D ** -0.5
+
+    def dt_bias(shape):
+        dt = jnp.maximum(jnp.exp(jax.random.uniform(
+            next(keys), shape, F32, jnp.log(1e-3), jnp.log(1e-1))), 1e-4)
+        return (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype)
+
     L = count(cfg, "mamba2")
-    dt = jnp.maximum(jnp.exp(jax.random.uniform(
-        next(keys), (L, H), F32, jnp.log(1e-3), jnp.log(1e-1))), 1e-4)
-    params["ssm"] = {
-        "w_in": uni((L, D, di + C + H), D),
-        "conv": uni((L, C, K), K), "conv_b": uni((L, C), K),
-        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(dtype),
-        "a_log": jnp.log(jax.random.uniform(
-            next(keys), (L, H), F32, 1.0, 16.0)).astype(dtype),
-        "d_skip": jnp.ones((L, H), dtype),
-        "o_norm": jnp.ones((L, di), dtype), "wo": out((L, di, D), di)}
+    if L:
+        H, _, _, _, di, C, _ = ssm_sizes(cfg)
+        params["ssm"] = {
+            "w_in": uni((L, D, di + C + H), D),
+            "conv": uni((L, C, K), K), "conv_b": uni((L, C), K),
+            "dt_bias": dt_bias((L, H)),
+            "a_log": jnp.log(jax.random.uniform(
+                next(keys), (L, H), F32, 1.0, 16.0)).astype(dtype),
+            "d_skip": jnp.ones((L, H), dtype),
+            "o_norm": jnp.ones((L, di), dtype), "wo": out((L, di, D), di)}
+    L = count(cfg, "mamba1")
+    if L:
+        di, R = cfg.ssm_inner_size, cfg.ssm_dt_rank
+        params["ssm1"] = {
+            "w_in": uni((L, D, 2 * di), D),
+            "conv": uni((L, di, K), K), "conv_b": uni((L, di), K),
+            "w_x": uni((L, di, R + 2 * N), di), "w_dt": uni((L, R, di), R),
+            "dt_bias": dt_bias((L, di)),
+            "a_log": jnp.broadcast_to(jnp.log(jnp.arange(1, N + 1, dtype=F32)),
+                                      (L, di, N)).astype(dtype),
+            "d_skip": jnp.ones((L, di), dtype), "wo": out((L, di, D), di)}
+        if cfg.ssm_inner_norms:
+            params["ssm1"].update(dt_norm=jnp.ones((L, R), dtype),
+                                  b_norm=jnp.ones((L, N), dtype),
+                                  c_norm=jnp.ones((L, N), dtype))
+    L = count(cfg, "mlp")
+    if L:
+        F = cfg.intermediate_size
+        params["mlp"] = {"w_up": uni((L, D, F), D),
+                         "w_down": out((L, F, D), F)}
+        if cfg.glu:
+            params["mlp"]["w_gate"] = uni((L, D, F), D)
     L = count(cfg, "full_attention")
     if L:
         M, Mkv = cfg.num_heads * cfg.head_dim, cfg.num_kv_heads * cfg.head_dim
@@ -234,8 +339,10 @@ def layer_params(cfg, params, l: int):
     if kind == "experts":
         stack = {k: v for k, v in params["layers"]["mlp"].items()
                  if k not in ("w_up", "w_down")}
+    elif kind in PAIR:            # read in place, by the layer's index
+        stack = {}
     else:
-        stack = params["ssm" if kind == "mamba2" else "gqa"]
+        stack = params[STACK[kind]]
     return (params["norms"]["scale"][l],
             jax.tree.map(lambda a: a[i], stack), kind, i)
 
@@ -324,6 +431,117 @@ def gated_group_norm(cfg, a, y, z):
 
 
 # ----------------------------------------------------------------------
+# the Mamba-1 pieces, each on layer ``i`` (an index, may be traced: a rolled
+# run's counter) of the stacks ``a`` = ``params["ssm1"]``, read in place
+# ----------------------------------------------------------------------
+def _layer(t, i):
+    return jax.lax.dynamic_index_in_dim(t, i, 0, keepdims=False)
+
+
+def norm_after(cfg, params, norms, l):
+    """The gain of the norm that FOLLOWS layer ``l`` (a traced ``l`` lies
+    inside a rolled run, which an mlp layer ends): the next layer's, or the
+    final one."""
+    last = isinstance(l, int) and l + 1 >= cfg.num_layers
+    return params["final_norm"]["scale"] if last else norms[l + 1]
+
+
+def _at(a, name: str, i):
+    return _layer(a[name], i)
+
+
+def mamba1_rates(a_log, W: int):
+    """``A = -exp(a_log)`` as the kernels read it: ``a_log`` [..., d_inner,
+    N] (one layer's, or every layer's) -> [..., d_inner / W, N, W] float32."""
+    return -jnp.exp(mamba1_pack(a_log.astype(F32), W))
+
+
+def mamba1_inputs(cfg, a, i, c):
+    """From the convolved rows ``c`` [..., d_inner] float32 to the
+    recurrence's inputs, float32: dt [..., d_inner] > 0 (softplus of the
+    bottleneck's projection plus ``dt_bias``, no clamp), B and C [..., N]."""
+    R, N, eps = cfg.ssm_dt_rank, cfg.ssm_state_size, cfg.norm_eps
+    w_x, w_dt = _at(a, "w_x", i), _at(a, "w_dt", i)
+    rbc = jnp.dot(c.astype(w_x.dtype), w_x, preferred_element_type=F32)
+    r, Bm, Cm = rbc[..., :R], rbc[..., R:R + N], rbc[..., R + N:R + 2 * N]
+    if cfg.ssm_inner_norms:
+        r, Bm, Cm = (rms(t, _at(a, n, i), eps) for t, n in (
+            (r, "dt_norm"), (Bm, "b_norm"), (Cm, "c_norm")))
+    dt = jnp.dot(r.astype(w_dt.dtype), w_dt, preferred_element_type=F32)
+    return jax.nn.softplus(dt + _at(a, "dt_bias", i).astype(F32)), Bm, Cm
+
+
+def mamba1_close(a, i, y, u, z):
+    """The skip and the gate: ``(y + D u) * silu(z)`` in the weights' dtype
+    (the input of ``wo``); y, u float32 [..., d_inner]."""
+    g = (y + _at(a, "d_skip", i).astype(F32) * u) * jax.nn.silu(z.astype(F32))
+    return g.astype(a["wo"].dtype)
+
+
+def _row_impl(rows: int, impl: Optional[str]) -> Optional[str]:
+    """The fused kernels keep their rows resident in VMEM: a chunk of more
+    than :data:`KERNEL_ROWS` takes their XLA forms (which copy a layer's
+    slice out of its stack in front of each product)."""
+    return impl if rows <= KERNEL_ROWS else "xla"
+
+
+def mamba1_layer(cfg, a, i, x, gain, next_gain, recur, impl=None):
+    """Mamba1 layer ``i`` on the rows ``x`` [rows, D] (a chunk's tokens, or a
+    decode step's batch): the norm and ``W_in`` (``fused_norm_qkv``), then
+    ``recur(raw u [rows, d_inner]) -> (convolved u, y, aux)``, the forward's
+    own convolution and recurrence over its cache, then skip, gate, ``W_out``,
+    the residual add and the NEXT layer's norm (``fused_proj_norm``), the
+    two matrices read in place at layer ``i`` of their stacks.  Returns (x,
+    the stream normed by ``next_gain``, aux)."""
+    from deepspeed_tpu.ops.pallas.decode import (fused_norm_qkv,
+                                                 fused_proj_norm)
+
+    di, kw = cfg.ssm_inner_size, dict(
+        kind="rmsnorm", eps=cfg.norm_eps, layer=i,
+        impl=_row_impl(x.shape[0], impl))
+    uz = fused_norm_qkv(x, gain, None, a["w_in"], None, **kw)
+    u, y, aux = recur(uz[:, :di])
+    x, h = fused_proj_norm(mamba1_close(a, i, y, u, uz[:, di:]), x, a["wo"],
+                           None, next_gain, None, **kw)
+    return x, h, aux
+
+
+def mlp_layer(cfg, m, i, x, h, impl=None):
+    """Mlp layer ``i`` (of the stacks ``m`` = ``params["mlp"]``, read in
+    place) on the rows ``x`` [rows, D] and their normed form ``h``: ``x +
+    mlp(h)``."""
+    from deepspeed_tpu.ops.pallas.decode import fused_mlp
+
+    return fused_mlp(h, x, m["w_up"], m["w_down"], m.get("w_gate"),
+                     act=cfg.activation, layer=i,
+                     impl=_row_impl(x.shape[0], impl))
+
+
+def chunk_recur(cfg, a, i, state, tail, kept, valid_len):
+    """:func:`mamba1_layer`'s ``recur`` for a prefill chunk of one slot
+    (``state`` [layers, 1, T, N, W], ``tail`` [layers, 1, K - 1, d_inner]):
+    aux = (state, tail) as of the last REAL row."""
+    def recur(raw):
+        s, W = raw.shape[0], state.shape[-1]
+        with jax.named_scope("ssm_conv"):
+            c, t1 = short_conv(raw[None], jnp.where(kept, _layer(tail, i), 0),
+                               _at(a, "conv", i), valid_len,
+                               bias=_at(a, "conv_b", i))
+        u = c[0]
+        dt, Bm, Cm = mamba1_inputs(cfg, a, i, u)
+        # a pad row: dt = 0, so the state neither decays nor takes it in
+        dt = jnp.where((jnp.arange(s) < valid_len)[:, None], dt, 0.0)
+        with jax.named_scope("selective_scan"):
+            S1, y = selective_scan_chunk(
+                jnp.where(kept, _layer(state, i)[0], 0.0), u, dt,
+                mamba1_rates(_at(a, "a_log", i), W), Bm, Cm)
+        return u, y, (jax.lax.dynamic_update_index_in_dim(state, S1[None],
+                                                          i, 0),
+                      jax.lax.dynamic_update_index_in_dim(tail, t1, i, 0))
+    return recur
+
+
+# ----------------------------------------------------------------------
 # forwards 1 and 2: no cache (CausalLM.apply), and a prefill chunk on one
 # slot's views (state carried in and out)
 # ----------------------------------------------------------------------
@@ -334,6 +552,8 @@ def apply_layers(cfg, params, x, mesh=None):
     B, S, _ = x.shape
     Q = cfg.ssm_chunk
     pad = -S % Q if S > Q else 0           # whole blocks (pad rows idle)
+    if ssm_kind(cfg) == "mamba1":          # the scan kernel's blocks of rows
+        pad = -S % 8
     state, tail = state_shapes(cfg, 1)
     kv = (count(cfg, "full_attention"), 1, cfg.num_kv_heads, S + pad,
           cfg.head_dim)
@@ -365,10 +585,43 @@ def cached_layers(cfg, params, x, cache, start, valid_len):
     k_full, v_full = cache["k"], cache["v"]
     kept = (start != 0)
     experts = afmoe._experts(params)
-    pk = ssm_sizes(cfg)[-1]
-    for l in range(cfg.num_layers):
+    pk = ssm_sizes(cfg)[-1] if ssm_kind(cfg) == "mamba2" else 0
+    norms, order = params["norms"]["scale"], kinds(cfg)
+
+    def mamba1(l, i, x, state, tail):
+        """Layer ``l`` = mamba1 layer ``i`` (either may be traced) on the
+        chunk's rows ``x`` [s, D]."""
+        a = params["ssm1"]
+        return mamba1_layer(
+            cfg, a, i, x, norms[l], norm_after(cfg, params, norms, l),
+            chunk_recur(cfg, a, i, state, tail, kept, valid_len))
+
+    for l, pairs in runs(cfg):
+        if pairs:
+            # a run of [mamba1, mlp] pairs, rolled: pair t is layers l + 2 t
+            # and l + 2 t + 1, the stacks read in place by its indices
+            i0, m0 = order[l][1], order[l + 1][1]
+
+            def pair(carry, t, l=l, i0=i0, m0=m0):
+                x, state, tail = carry
+                x, h, (state, tail) = mamba1(l + 2 * t, i0 + t, x, state,
+                                             tail)
+                return (mlp_layer(cfg, params["mlp"], m0 + t, x, h), state,
+                        tail), None
+
+            (x2, state, tail), _ = jax.lax.scan(
+                pair, (x[0], state, tail), jnp.arange(pairs, dtype=jnp.int32))
+            x = x2[None]
+            continue
         scale, a, kind, i = layer_params(cfg, params, l)
+        if kind == "mamba1":
+            x2, _, (state, tail) = mamba1(l, i, x[0], state, tail)
+            x = x2[None]
+            continue
         h = rms(x, scale, cfg.norm_eps)
+        if kind == "mlp":
+            x = mlp_layer(cfg, params["mlp"], i, x[0], h[0])[None]
+            continue
         if kind == "mamba2":
             z, xBC, dt_raw = ssm_split(cfg, h @ a["w_in"].astype(h.dtype))
             with jax.named_scope("ssm_conv"):
@@ -429,6 +682,8 @@ def inject(cfg, params) -> Dict[str, Any]:
         d = {"norm": scale}
         if kind == "experts":
             d.update(a)
+        elif kind in PAIR:
+            pass                  # its weights: the stacks below, in place
         else:
             names = ("w_in",) if kind == "mamba2" else GQA_IN
             d.update({k: v for k, v in a.items() if k not in names})
@@ -436,12 +691,22 @@ def inject(cfg, params) -> Dict[str, Any]:
             d["next_norm"] = (norms[l + 1] if l + 1 < cfg.num_layers
                               else params["final_norm"]["scale"])
         layers.append(d)
-    return afmoe.inject_outer(params, layers)
+    out = afmoe.inject_outer(params, layers)
+    if count(cfg, "mamba1"):
+        # the stacks themselves (no copy), and every layer's rates as the
+        # decode kernel reads them (81,920 float32 a layer)
+        out["ssm1"] = {**params["ssm1"], "rates": mamba1_rates(
+            params["ssm1"]["a_log"], mamba1_tile(cfg.ssm_inner_size))}
+    if count(cfg, "mlp"):
+        out["mlp"] = params["mlp"]
+    out["norms"] = norms
+    return out
 
 
 def moe_counts_zero(cfg):
-    """``afmoe.moe_counts_zero`` and one more entry: (row, mamba2 layer)
-    pairs that were LIVE, and pairs whose state the decode kernel VISITED."""
+    """``afmoe.moe_counts_zero`` and one more entry: (row, mamba2 or mamba1
+    layer) pairs that were LIVE, and pairs whose state the decode kernel
+    VISITED."""
     return afmoe.moe_counts_zero(cfg) + (jnp.zeros((2,), jnp.int32),)
 
 
@@ -466,7 +731,65 @@ def fused_layers(cfg, dparams, x, cache, pos, page_table, *, moe_live=None,
     k_full, v_full = cache["k"], cache["v"]
     stats = moe_counts_zero(cfg) if moe_live is not None else None
     h = None                      # the stream normed for the layer to come
-    for l, ((kind, i), lp) in enumerate(zip(kinds(cfg), dparams["layers"])):
+    order, norms = kinds(cfg), dparams["norms"]
+
+    def mamba1(l, i, x, state, tail, steps):
+        """Layer ``l`` = mamba1 layer ``i`` (either may be traced) for one
+        token a row: (x, the stream normed for layer l + 1, (state, tail,
+        the (live, visited) counts))."""
+        a = dparams["ssm1"]
+
+        def recur(raw):
+            old = _layer(tail, i)
+            with jax.named_scope("ssm_conv"):
+                c, t1 = short_conv(raw[:, None], old, _at(a, "conv", i),
+                                   bias=_at(a, "conv_b", i))
+            if moe_live is not None:
+                t1 = jnp.where(moe_live[:, None, None], t1, old)
+            u = c[:, 0]
+            dt, Bm, Cm = mamba1_inputs(cfg, a, i, u)
+            with jax.named_scope("selective_scan"):
+                y, new, visited = mamba1_decode_step(
+                    state, u, dt, a["rates"], Bm, Cm, layer=i, live=moe_live,
+                    impl=impl)
+            return u, y, (new, jax.lax.dynamic_update_index_in_dim(
+                tail, t1, i, 0), None if steps is None else steps + jnp.stack(
+                    [jnp.sum(moe_live, dtype=jnp.int32), visited]))
+
+        return mamba1_layer(cfg, a, i, x, norms[l],
+                            norm_after(cfg, dparams, norms, l), recur, impl)
+
+    def dense(l, i, x, h):
+        """Layer ``l`` = mlp layer ``i`` on the stream normed for it."""
+        return mlp_layer(cfg, dparams["mlp"], i, x,
+                         rms(x, norms[l], eps) if h is None else h, impl)
+
+    for l, pairs in runs(cfg):
+        kind, i = order[l]
+        lp = dparams["layers"][l]
+        steps = None if stats is None else stats[-1]
+        if pairs:
+            # a run of [mamba1, mlp] pairs, rolled: the kernels read the
+            # stacks at the pair's indices
+            m0 = order[l + 1][1]
+
+            def pair(carry, t, l=l, i0=i, m0=m0):
+                x, h, (state, tail, steps) = mamba1(l + 2 * t, i0 + t,
+                                                    *carry)
+                return (dense(l + 2 * t + 1, m0 + t, x, h), state, tail,
+                        steps), None
+
+            (x, state, tail, steps), _ = jax.lax.scan(
+                pair, (x, state, tail, steps),
+                jnp.arange(pairs, dtype=jnp.int32))
+            h = None
+        elif kind == "mamba1":
+            x, h, (state, tail, steps) = mamba1(l, i, x, state, tail, steps)
+        elif kind == "mlp":
+            x, h = dense(l, i, x, h), None
+        if kind in PAIR:
+            stats = None if stats is None else stats[:-1] + (steps,)
+            continue
         if kind == "experts":
             if h is None:
                 h = rms(x, lp["norm"], eps)

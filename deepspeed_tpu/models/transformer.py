@@ -759,7 +759,9 @@ class CausalLM:
                         params["embed"]["tok"].dtype)
         x = afmoe.form(cfg).apply_layers(cfg, params, x, self.mesh)
         x = model_norm(cfg, x, params["final_norm"], self.mesh)
-        return x @ params["lm_head"].astype(x.dtype)
+        head = (params["embed"]["tok"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        return x @ head.astype(x.dtype)
 
     def _loss_tail(self, fnorm, head, x, labels, loss_mask, head_bias=None):
         """Final norm + LM cross-entropy — the single implementation behind

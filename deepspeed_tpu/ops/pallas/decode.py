@@ -173,6 +173,47 @@ def _deq(w, ws, dtype):
     return (w.astype(jnp.float32) * ws).astype(dtype)
 
 
+def _layer_of(w, layer):
+    """Layer ``layer`` of a stack ``w`` [L, ...] (the XLA forms' slice);
+    ``w`` itself where there is no layer or no array."""
+    if layer is None or w is None:
+        return w
+    return jax.lax.dynamic_index_in_dim(w, layer, 0, keepdims=False)
+
+
+def _layer_call(kernel, layer, stacked, *, grid, in_specs, out_specs, **kw):
+    """``pl.pallas_call`` of a kernel whose weights may be LAYER ``layer`` of
+    stacks ``[L, ...]``: the operands at positions ``stacked`` are then the
+    whole stacks and the layer rides their index maps (one scalar-prefetch
+    operand; ``layer`` may be traced, a ``lax.scan``'s counter), so no slice
+    of a stack is copied out in front of the call.  ``layer`` None: the call
+    as it is written, every operand its own array."""
+    if layer is None:
+        return pl.pallas_call(kernel, grid=grid, in_specs=in_specs,
+                              out_specs=out_specs, **kw)
+
+    def lift(spec, stack):
+        # the default argument freezes THIS spec's map in each lambda
+        if stack:
+            return pl.BlockSpec(
+                (None,) + tuple(spec.block_shape),
+                lambda *a, m=spec.index_map: (a[-1][0],) + tuple(m(*a[:-1])))
+        return pl.BlockSpec(spec.block_shape,
+                            lambda *a, m=spec.index_map: m(*a[:-1]))
+
+    one = isinstance(out_specs, pl.BlockSpec)
+    outs = [lift(o, False) for o in ([out_specs] if one else out_specs)]
+    call = pl.pallas_call(
+        lambda layer_ref, *refs: kernel(*refs),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[lift(sp, i in stacked)
+                      for i, sp in enumerate(in_specs)],
+            out_specs=outs[0] if one else outs,
+            scratch_shapes=kw.pop("scratch_shapes", ())), **kw)
+    return functools.partial(call, jnp.asarray(layer, jnp.int32).reshape(1))
+
+
 def _norm_qkv_ref(x, scale, bias, wqkv, bqkv, *, kind, eps, wscale=None):
     cd = x.dtype if wscale is not None else wqkv.dtype
     h = _normalize(x.astype(jnp.float32), scale.astype(jnp.float32),
@@ -204,11 +245,14 @@ def _norm_qkv_kernel(x_ref, s_ref, b_ref, w_ref, ws_ref, bq_ref, o_ref,
 
 
 def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
-                   eps: float = 1e-5, wscale=None, impl: Optional[str] = None):
+                   eps: float = 1e-5, wscale=None, layer=None,
+                   impl: Optional[str] = None):
     """x: [B, D]; wqkv: [D, N]; returns [B, N] in the weights' dtype (the
     dtype the normed rows meet them in; ``x`` may be wider, a float32
     residual stream).  ``wscale`` [N]-broadcastable fp32 marks ``wqkv`` as
     int8 (dequant in-kernel; rows and result then keep ``x.dtype``).
+    ``layer`` (an index, may be traced): ``wqkv`` is a stack [L, D, N] read
+    in place (:func:`_layer_call`).
 
     Reference: fused ln/rmsnorm + qkv_gemm of ``(R)
     csrc/transformer/inference`` (one launch instead of norm + 3 GEMVs)."""
@@ -216,10 +260,10 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
     if bias is None:
         bias = jnp.zeros_like(scale)
     if impl == "xla":
-        return _norm_qkv_ref(x, scale, bias, wqkv, bqkv, kind=kind, eps=eps,
-                             wscale=wscale)
+        return _norm_qkv_ref(x, scale, bias, _layer_of(wqkv, layer), bqkv,
+                             kind=kind, eps=eps, wscale=wscale)
     B, D = x.shape
-    N = wqkv.shape[1]
+    N = wqkv.shape[-1]
     quant = wscale is not None
     cd = x.dtype if quant else wqkv.dtype
     # quant sizing counts the in-kernel fp32 dequant intermediate, not the
@@ -235,8 +279,8 @@ def fused_norm_qkv(x, scale, bias, wqkv, bqkv=None, *, kind: str = "layernorm",
     ws = (wscale if quant else jnp.ones((N,), jnp.float32)).reshape(1, N)
     kernel = functools.partial(_norm_qkv_kernel, kind=kind, eps=eps,
                                has_bias=has_bias, quant=quant)
-    return pl.pallas_call(
-        kernel,
+    return _layer_call(
+        kernel, layer, (3,),
         grid=(N // bn,),
         in_specs=[pl.BlockSpec((B, D), lambda j: (0, 0)),
                   pl.BlockSpec((1, D), lambda j: (0, 0)),
@@ -1638,9 +1682,11 @@ def _proj_norm_kernel(ctx_ref, res_ref, wo_ref, ws_ref, bo_ref, s_ref, b_ref,
 
 def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
                     kind: str = "layernorm", eps: float = 1e-5,
-                    parallel: bool = False, wscale=None,
+                    parallel: bool = False, wscale=None, layer=None,
                     impl: Optional[str] = None):
-    """ctx: [B, M]; wo: [M, D]; resid: [B, D].  Returns (r, h): the updated
+    """ctx: [B, M]; wo: [M, D] (with ``layer``, an index that may be traced:
+    a stack [L, M, D] read in place, :func:`_layer_call`); resid: [B, D].
+    Returns (r, h): the updated
     residual stream (in ``resid.dtype``) and the normed MLP input (in
     ``ctx.dtype``) (``parallel=True`` norms the
     layer input instead — gpt-neox parallel residual).  ``wscale`` marks
@@ -1652,11 +1698,11 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
     if bias is None:
         bias = jnp.zeros_like(scale)
     if impl == "xla":
-        return _proj_norm_ref(ctx, resid, wo, bo, scale, bias,
-                              kind=kind, eps=eps, parallel=parallel,
+        return _proj_norm_ref(ctx, resid, _layer_of(wo, layer), bo, scale,
+                              bias, kind=kind, eps=eps, parallel=parallel,
                               wscale=wscale)
     B, M = ctx.shape
-    D = wo.shape[1]
+    D = wo.shape[-1]
     quant = wscale is not None
     has_bias = bo is not None
     # blocked over the contraction dim (wo's rows): the norm needs whole
@@ -1664,10 +1710,13 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
     # fp32 scratch and the last step adds the residual and norms.  Sized
     # like fused_norm_qkv (quant counts the fp32 dequant intermediate).
     # beside the tile: the stream, the two results and the accumulator
+    # (a layer of a stack: the two results counted twice, as the pipeline
+    # holds them; at 256 rows of 2,560 against a [5120, 2560] layer the
+    # single count's 640-row tile left the call 52 KB over the scoped 16 MB)
+    twice = 1 if layer is None else 2
     bm = _col_block(D, M, 4 if quant else wo.dtype.itemsize,
-                    resident=B * D * (resid.dtype.itemsize
-                                      + resid.dtype.itemsize
-                                      + ctx.dtype.itemsize + 4))
+                    resident=B * D * (resid.dtype.itemsize + twice * (
+                        resid.dtype.itemsize + ctx.dtype.itemsize) + 4))
     bo2 = (bo if has_bias else jnp.zeros((D,), ctx.dtype)).reshape(1, D)
     ws = (wscale if quant else jnp.ones((D,), jnp.float32)).reshape(1, D)
     kernel = functools.partial(_proj_norm_kernel, kind=kind, eps=eps,
@@ -1675,8 +1724,8 @@ def fused_proj_norm(ctx, resid, wo, bo=None, scale=None, bias=None, *,
                                quant=quant, nm=M // bm)
     row = pl.BlockSpec((1, D), lambda j: (0, 0))
     act = pl.BlockSpec((B, D), lambda j: (0, 0))
-    r, h = pl.pallas_call(
-        kernel,
+    r, h = _layer_call(
+        kernel, layer, (2,),
         grid=(M // bm,),
         in_specs=[pl.BlockSpec((B, bm), lambda j: (0, j)),
                   act,
@@ -1761,10 +1810,12 @@ def _mlp_kernel(h_ref, r_ref, wu_ref, wg_ref, wd_ref, su_ref, sg_ref,
 
 
 def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
-              b_down=None, *, act: str = "gelu", wscales=None,
+              b_down=None, *, act: str = "gelu", wscales=None, layer=None,
               impl: Optional[str] = None):
     """h: [B, D] (normed); r: [B, D] (residual).  Returns r + mlp(h) in
-    ``r.dtype``.  ``wscales`` = (up, gate, down) per-out-channel fp32 scales marking the
+    ``r.dtype``.  ``layer`` (an index, may be traced): the three weights are
+    stacks [L, ...] read in place (:func:`_layer_call`).
+    ``wscales`` = (up, gate, down) per-out-channel fp32 scales marking the
     weights as int8 (dequant in-kernel; gate entry ignored when no GLU).
 
     Blocked over the FFN dim: grid step j computes the partial product of
@@ -1773,10 +1824,11 @@ def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
     MLP gemm chain with fused bias+activation epilogues)."""
     impl = resolve_impl(impl)
     if impl == "xla":
-        return _mlp_ref(h, r, w_up, w_gate, w_down, b_up, b_gate, b_down,
-                        act=act, wscales=wscales)
+        return _mlp_ref(h, r, _layer_of(w_up, layer),
+                        _layer_of(w_gate, layer), _layer_of(w_down, layer),
+                        b_up, b_gate, b_down, act=act, wscales=wscales)
     B, D = h.shape
-    F = w_up.shape[1]
+    F = w_up.shape[-1]
     quant = wscales is not None
     per = 3 if w_gate is not None else 2
     # see fused_norm_qkv: quant blocks sized by the fp32 dequant intermediate
@@ -1805,8 +1857,8 @@ def fused_mlp(h, r, w_up, w_down, w_gate=None, b_up=None, b_gate=None,
                  else pl.BlockSpec((D, bf), lambda j: (0, 0)))
     gate_s_spec = (pl.BlockSpec((1, bf), lambda j: (0, j)) if glu
                    else pl.BlockSpec((1, bf), lambda j: (0, 0)))
-    return pl.pallas_call(
-        kernel,
+    return _layer_call(
+        kernel, layer, (2, 3, 4) if glu else (2, 4),
         grid=(F // bf,),
         in_specs=[pl.BlockSpec((B, D), lambda j: (0, 0)),
                   pl.BlockSpec((B, D), lambda j: (0, 0)),

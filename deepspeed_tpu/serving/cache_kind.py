@@ -18,7 +18,8 @@ attention form, and :func:`cache_kind` is the one place that decides it
   same pages and a recurrent state a slot;
 - :class:`FullPagesAndState` (linear-attention layers beside per-head
   ``full_attention`` layers; or the one-mixer form of ``models/ssm_moe.py``,
-  mamba2 layers beside ``full_attention`` and ``experts`` layers):
+  mamba2 or mamba1 layers beside ``full_attention``, ``experts`` and ``mlp``
+  layers):
   :class:`FullPages`' K and V arrays in the full
   layers ONLY (``cfg.cache_layers`` counts them, so a token costs those
   layers' bytes) and a state a slot, its shape the model module's
@@ -594,6 +595,10 @@ class SlotState:
             "(row, mamba2 layer) pairs the prefill chunk programs' chunked "
             "scan (models/ssm_moe.py:ssm_chunk_scan) worked, the pad rows of "
             "their buckets included (beside ds_serve_prefill_pad_rows_total)",
+        "ds_serve_mamba1_chunk_rows_total":
+            "(row, mamba1 layer) pairs the prefill chunk programs' selective "
+            "scan (ops/pallas/selective_scan.py:selective_scan_chunk) "
+            "walked, the pad rows of their buckets included",
     }
     takes_valid_len = True          # the state is left as of the last real row
 
@@ -636,8 +641,9 @@ class SlotState:
 
     def count_chunk(self, pool, cache, off, c, cb):
         super().count_chunk(pool, cache, off, c, cb)
-        self._m["ds_serve_ssm_chunk_rows_total"].inc(
-            cb * self.cfg.layer_types.count("mamba2"))
+        for kind, name in (("mamba2", "ds_serve_ssm_chunk_rows_total"),
+                           ("mamba1", "ds_serve_mamba1_chunk_rows_total")):
+            self._m[name].inc(cb * self.cfg.layer_types.count(kind))
 
     def count_block(self, counts):
         """``ds_serve_state_row_steps_*``: the block's (row, linear layer)
@@ -731,7 +737,7 @@ class FullPagesAndState(SlotState, FullPages):
         # the state's, then the pages'
         self.what = " / ".join(
             [k for k in dict.fromkeys(cfg.layer_types)
-             if k not in ("experts", "full_attention")]
+             if k not in ("experts", "mlp", "full_attention")]
             + ["full_attention"]) + " layers"
 
     def layout(self, pool, num_slots):

@@ -2299,10 +2299,14 @@ class ServingEngine:
         do_sample, temperature, top_k, top_p = self._sample
         K = self._K
         moe0 = None
-        if self.engine._dparams is not None and self.module.config.is_moe:
+        cfg = self.module.config
+        if self.engine._dparams is not None and (cfg.is_moe or cfg.is_afmoe):
+            # a layer_types model's fused path always returns its counts (a
+            # dense one's routing counts are empty; the state's ride behind)
             from deepspeed_tpu.models.fused_decode import moe_counts_zero
+            moe0 = moe_counts_zero(cfg)
+        if moe0 is not None and cfg.is_moe:
             from deepspeed_tpu.ops.pallas.decode import moe_expert_block
-            moe0 = moe_counts_zero(self.module.config)
             ex = self.engine._dparams["experts"]
             (D, F), item = ex["w_up"].shape[-2:], ex["w_up"].dtype.itemsize
             self._m_moe_block.set(100.0 / F * moe_expert_block(

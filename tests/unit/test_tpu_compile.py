@@ -418,6 +418,106 @@ def test_kernels_compile_at_the_nemotron_cells_256_slots(v5e, kernel):
     assert len(reference_selections()) == before
 
 
+# the benchmark's jamba2-3b.serve-reason-768 cell: 256 slots of hidden 2,560;
+# 26 Mamba-1 layers of 5,120 channels over a state of 16 (a row's state of
+# one layer is 40 tiles [16, 128] float32); chunks of 256 rows; 20 query
+# heads over ONE key-value head of 128 in 2 layers; MLPs of 8,192; every
+# weight a layer of a stack, the layer a TRACED scalar (a rolled run's)
+JAMBA = dict(D=2560, slots=256, di=5120, N=16, F=8192, L=26, H=20, Hkv=1,
+             Dh=128, page=256, maxp=5, layers=2, pool_pages=1281)
+
+
+def _mamba1_step(w):
+    from deepspeed_tpu.ops.pallas.selective_scan import mamba1_decode_step
+
+    B, di, N, L = (w[k] for k in ("slots", "di", "N", "L"))
+    fn = lambda s, u, dt, a, bm, cm, live, layer: mamba1_decode_step(
+        s, u, dt, a, bm, cm, layer=layer, live=live, impl="pallas")[:2]
+    return fn, [((L, B, di // 128, N, 128), F32), ((B, di), F32),
+                ((B, di), F32), ((L, di // 128, N, 128), F32), ((B, N), F32),
+                ((B, N), F32), ((B,), jnp.bool_), ((), I32)], 1
+
+
+def _scan_chunk(rows):
+    def build(w):
+        from deepspeed_tpu.ops.pallas.selective_scan import \
+            selective_scan_chunk
+
+        di, N = w["di"], w["N"]
+        fn = lambda s, u, dt, a, bm, cm: selective_scan_chunk(
+            s, u, dt, a, bm, cm, impl="pallas")
+        return fn, [((di // 128, N, 128), F32), ((rows, di), F32),
+                    ((rows, di), F32), ((di // 128, N, 128), F32),
+                    ((rows, N), F32), ((rows, N), F32)], 1
+    return build
+
+
+def _stacked_in_proj(w):
+    B, D, L = w["slots"], w["D"], w["L"]
+    fn = lambda x, s, wi, layer: fused_norm_qkv(
+        x, s, None, wi, None, kind="rmsnorm", layer=layer, impl="pallas")
+    return fn, [((B, D), BF16), ((D,), BF16), ((L, D, 2 * w["di"]), BF16),
+                ((), I32)], 1
+
+
+def _stacked_out_proj(w):
+    B, D, L = w["slots"], w["D"], w["L"]
+    fn = lambda c, r, wo, s, layer: fused_proj_norm(
+        c, r, wo, None, s, None, kind="rmsnorm", layer=layer, impl="pallas")
+    return fn, [((B, w["di"]), BF16), ((B, D), BF16),
+                ((L, w["di"], D), BF16), ((D,), BF16), ((), I32)], 1
+
+
+def _stacked_mlp(w):
+    B, D, F, L = w["slots"], w["D"], w["F"], w["L"] + 2
+    fn = lambda h, r, wu, wd, wg, layer: fused_mlp(
+        h, r, wu, wd, wg, act="silu", layer=layer, impl="pallas")
+    return fn, [((B, D), BF16), ((B, D), BF16), ((L, D, F), BF16),
+                ((L, F, D), BF16), ((L, D, F), BF16), ((), I32)], 1
+
+
+def _mqa_decode(w):
+    pool, table = _paged_pool(w, **{k: w[k] for k in (
+        "slots", "page", "maxp", "layers", "pool_pages")})
+    B = w["slots"]
+    fn = lambda q, k, v, pos, pt, live: flash_decode(
+        q, k, v, pos, layer=1, page_table=pt, live=live, impl="pallas")
+    return fn, [((B, w["H"], w["Dh"]), BF16), pool, pool, ((B,), I32), table,
+                ((B,), jnp.bool_)], 1
+
+
+def _mqa_append(w):
+    pool, table = _paged_pool(w, **{k: w[k] for k in (
+        "slots", "page", "maxp", "layers", "pool_pages")})
+    B = w["slots"]
+    fn = lambda kc, vc, k, v, pos, pt: paged_kv_append(
+        kc, vc, k, v, pos, pt, layer=1, impl="pallas")
+    row = ((B, w["Hkv"], w["Dh"]), BF16)
+    return fn, [pool, pool, row, row, ((B,), I32), table], 1
+
+
+@pytest.mark.parametrize("kernel", [
+    _mamba1_step, _scan_chunk(256), _scan_chunk(128), _stacked_in_proj,
+    _stacked_out_proj, _stacked_mlp, _mqa_decode, _mqa_append],
+    ids=["mamba1_decode_step", "selective_scan_chunk_256",
+         "selective_scan_chunk_128", "fused_norm_qkv_of_a_stack",
+         "fused_proj_norm_of_a_stack", "fused_mlp_of_a_stack",
+         "flash_decode_paged_group_20", "paged_kv_append_one_kv_head"])
+def test_kernels_compile_at_the_jamba2_cells_sizes(v5e, kernel):
+    """ISSUE 66: the two Mamba-1 kernels over 40 state tiles [16, 128] a row
+    a layer, the three fused kernels reading a TRACED layer of a stack
+    (``_layer_call``: one scalar-prefetch operand), and multi-query
+    attention as Mosaic takes it unchanged: a group of 20 query heads (2.5
+    sublane tiles) over ONE key-value head, in the page walk and in the
+    append's blocks."""
+    from deepspeed_tpu.ops.pallas.common import reference_selections
+
+    before = len(reference_selections())
+    fn, shapes, want = kernel(JAMBA)
+    assert _custom_calls(fn, v5e, *shapes) >= want
+    assert len(reference_selections()) == before
+
+
 # the benchmark's evabyte-L6.serve-doc cell: MHA 32 x 128, window 2,048 and
 # chunk 16 over pages of 256 (8 window + 4 summary pages a row), 32 slots,
 # the residual stream float32 between the kernels

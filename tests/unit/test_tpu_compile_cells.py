@@ -8,6 +8,7 @@ The kernels one at a time are ``test_tpu_compile.py``'s; nothing runs here
 either.
 """
 
+import math
 import os
 import re
 
@@ -516,6 +517,75 @@ def test_nemotron3_nano_cell_programs_compile_at_the_cells_256_slots(
                  "fused_moe_mlp"):
         assert name in text, name
     assert "kda_decode_step" not in text
+
+
+def test_jamba2_cell_programs_compile_whole_with_every_weight_once(
+        v5e, chip_kernels):
+    """ISSUE 66: the chunk programs (buckets 256 and 128: ``chunk_rows``) and
+    the decode block of the ``jamba2-3b.serve-reason-768`` cell AS IT IS RUN
+    (all 56 one-mixer layers, 256 slots, the whole pool: nothing cut)
+    compile for the v5e: the three runs of ``[mamba1, mlp]`` pairs are
+    ROLLED (a program holds three calls of each of their kernels, not 26),
+    every kernel of both kinds of program is the Pallas one (no reference
+    fallback: ``mamba1_decode_step`` and ``selective_scan_chunk`` over 40
+    tiles [16, 128], ``flash_decode_paged`` at a group of 20 query heads
+    over ONE key-value head), no layer of a weight stack is sliced or copied
+    out in front of a product (the stacks are the kernels' operands, the
+    layer in their index maps: a dense layer's weights resident ONCE), and
+    state, tails and pages stay where they are."""
+    cell = _ServeCell(v5e, "jamba2-3b", "jamba2-3b.serve-reason-768")
+    cache = cell.serve._cache
+    assert cell.serve.num_slots == 256 and cell.serve.kind.chunk_rows == 128
+    assert cache["k"].shape == (2, 256 * 5 + 1, 1, 256, 128)
+    assert cache["state"].shape == (26, 256, 40, 16, 128)
+    assert cache["tail"].shape == (26, 256, 3, 5120)
+    cell.smallest_pool = cache["k"].nbytes
+    shape = lambda k: ",".join(str(d) for d in cache[k].shape)
+    weights = sum(a.size * 2 for a in jax.tree.leaves(cell.params))
+    held = sum(v.nbytes for v in cache.values())
+    assert weights == 2 * 3029337472
+    calls = lambda text, name: len(re.findall(
+        rf"%{name}[.\d]* = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+    programs = {256: cell.chunk(256), 128: cell.chunk(128),
+                "block": cell.block()}
+    for which, program in programs.items():
+        text = program.as_text()
+        cell.assert_pools_stay_in_place(program)
+        for kind, key in (("f32", "state"), ("bf16", "tail")):
+            assert not re.findall(rf"{kind}\[{shape(key)}\]\S* copy\(",
+                                  text), key
+        # no layer of a dense stack leaves it: a slice of one is 13 M
+        # elements and more (the two attention layers' 6.5 M projections
+        # have buffers of their own)
+        for dims, op in re.findall(
+                r"= bf16\[([\d,]+)\]\S* (copy|dynamic-slice)\(", text):
+            if (which, dims, op) == ("block", "26,5120,192", "copy"):
+                # ``W_x``'s 192 columns are 1.5 lane tiles: the decode block
+                # lays the stack out anew ONCE a call (51 MB an 8-step
+                # block, outside its loops; XLA's products read it)
+                continue
+            assert math.prod(int(d) for d in dims.split(",")) < 2 ** 23, (
+                which, dims, op)
+        mem = program.memory_analysis()
+        print("memory", which, mem.temp_size_in_bytes,
+              mem.argument_size_in_bytes, mem.output_size_in_bytes,
+              mem.alias_size_in_bytes)
+        # the weights ONCE and the cache, and little beside them
+        assert mem.argument_size_in_bytes < weights + held + 2 ** 27
+        assert mem.temp_size_in_bytes < 2 ** 29
+        for name in ("fused_norm_qkv", "fused_proj_norm", "fused_mlp"):
+            assert calls(text, name) in (5, 3), (which, name)
+        assert calls(text, "fused_mlp") == 5      # 3 runs + 2 after attention
+        if which == "block":
+            assert calls(text, "mamba1_decode_step") == 3
+            assert calls(text, "flash_decode_paged") == 2
+            assert "paged_kv_append" in text
+            assert calls(text, "selective_scan_chunk") == 0
+        else:
+            assert calls(text, "selective_scan_chunk") == 3
+            assert calls(text, "mamba1_decode_step") == 0
+        assert calls(text, "ssm_decode_step") == 0
 
 
 def test_axk1_cell_programs_compile_with_the_latent_pool_in_place(
