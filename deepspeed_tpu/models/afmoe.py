@@ -656,7 +656,7 @@ def fused_experts(cfg, dparams, lp, le: int, h, base, stats, moe_live, impl):
     ex = dparams["experts"]
     y = fused_moe_mlp(h, base, combine, ex["w_up"], ex["w_down"],
                       ex.get("w_gate"), layer=le, act=cfg.activation,
-                      impl=impl)
+                      live=moe_live, impl=impl)
     if stats is not None:
         load = jnp.sum((jnp.sum(onehot, axis=1) > 0)
                        & moe_live[:, None], axis=0, dtype=jnp.int32)

@@ -350,7 +350,8 @@ def decode_step(cfg, dparams, tokens, cache, pos, *,
                 ex = dparams["experts"]
                 x = fused_moe_mlp(h, r, combine, ex["w_up"], ex["w_down"],
                                   ex.get("w_gate"), layer=l,
-                                  act=cfg.activation, impl=impl)
+                                  act=cfg.activation, live=moe_live,
+                                  impl=impl)
                 if count_moe:
                     load = jnp.sum(chosen & moe_live[:, None], axis=0,
                                    dtype=jnp.int32)

@@ -76,12 +76,23 @@ The parameters are stacks by KIND (``params["ssm"]`` ``[mamba2 layers,
 one stack of the layers' norms (``params["norms"]`` ``[layers, D]``).
 
 The experts' widths are stored PADDED with zero columns (``w_up``) and zero
-rows (``w_down``) to whole :data:`WIDTH_TILE`\\ s (1,856 -> 2,048; the shared
-expert's 3,712 -> 4,096): the decode kernels tile a weight's width in
-128-lane blocks that divide it, 1,856 is 14.5 of them, the chip's grouped
-matmul works a width in the largest of 128 / 256 / 512 that divides it, and
-the stacked experts are resident ONCE (a padded second copy for the kernels
-would not fit).  ``relu(0)^2 = 0``: the pad adds nothing.
+rows (``w_down``), the ROUTED experts' to whole 128-lane tiles
+(:data:`LANE_TILE`: 1,856 -> 1,920 = 15 of them; 1,856 is 14.5, and the
+decode kernel and the grouped matmul tile a weight's width in 128-lane blocks
+that divide it: ``ops/pallas/decode.py:moe_expert_block``), the SHARED
+expert's to whole :data:`WIDTH_TILE`\\ s (3,712 -> 4,096: 3,712 is 29 lane
+tiles, a prime, which ``fused_mlp`` could only take a tile at a time or
+whole); the stacked experts are resident ONCE (a padded second copy for the
+kernels would not fit).  ``relu(0)^2 = 0``: the pad adds nothing.  Until PR
+67 the routed experts were stored 2,048 wide too, for the chip's own grouped
+matmul (``jax.lax.ragged_dot`` works a width in the largest of 128 / 256 /
+512 that divides it: 9.3 ms a call at 1,920 columns where 2,048 took 3.1);
+no serving program runs ``ragged_dot`` since PR 64
+(``ops/pallas/grouped_matmul.py``), and the callers that keep it
+(``layer=None``, a mesh that splits ``tp`` / ``sp``, a gradient) are not
+served for this model, so the 512 rule survives for the routed experts only
+as this note: whoever brings that path back for a width of 15 lane tiles
+pays three times the call.
 
 Cache (``serving/cache_kind.py:FullPagesAndState``): a mamba2 layer keeps,
 for each SLOT, its state and the convolution's tail (the last K - 1 rows of
@@ -117,11 +128,15 @@ from deepspeed_tpu.ops.pallas.selective_scan import (mamba1_decode_step,
                                                      selective_scan_chunk)
 
 HI = jax.lax.Precision.HIGHEST
-# the experts' widths are whole multiples of this (module docstring): the
-# widest tile of the chip's grouped matmul, which at 1,920 columns (15 lane
-# tiles: tiles of 128) took 9.3 ms a call of a 1,024-row chunk's where 2,048
-# takes 3.1, whatever the rows (my chip run, PR 63, PERF.md section 6); the
-# decode kernels' block is then 512 columns too
+# the ROUTED experts' stored widths are whole multiples of the lane tile
+# (module docstring): the least the decode kernel and the grouped matmul can
+# tile, 6.25% fewer bytes and FLOPs than the 2,048 they were stored at
+LANE_TILE = 128
+# the SHARED expert's stored width is a whole multiple of this: 3,712 (29
+# lane tiles) -> 4,096, which ``fused_mlp``'s tile divides.  (It was the
+# routed experts' too while the chunk programs ran ``ragged_dot``, whose
+# widest tile it is: 9.3 ms a call of a 1,024-row chunk's at 1,920 columns
+# where 2,048 took 3.1, my chip run, PR 63, PERF.md section 6)
 WIDTH_TILE = 512
 GQA_IN = ("wq", "wk", "wv")       # an attention layer's projections of ``h``
 # a kind's stack in the parameters (an experts layer: ``layers``/``mlp``)
@@ -174,8 +189,10 @@ def runs(cfg):
     return out
 
 
-def padded_width(width: int) -> int:
-    return -(-width // WIDTH_TILE) * WIDTH_TILE
+def padded_width(width: int, tile: int = LANE_TILE) -> int:
+    """``width`` rounded up to whole ``tile``\\ s: a routed expert's stored
+    width (:data:`LANE_TILE`), the shared expert's at :data:`WIDTH_TILE`."""
+    return -(-width // tile) * tile
 
 
 def shared_width(cfg) -> int:
@@ -311,10 +328,10 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
     if L:
         E, R = cfg.num_experts, cfg.moe_router_experts
 
-        def two(lead, width):
+        def two(lead, width, tile=LANE_TILE):
             # drawn at the padded width and zeroed past the real one: no
             # unpadded copy beside the 5 GB of a real model's experts
-            wide = padded_width(width)
+            wide = padded_width(width, tile)
             real = jnp.arange(wide) < width
             return {"w_up": uni(lead + (D, wide), D) * real.astype(dtype),
                     "w_down": out(lead + (wide, D), width)
@@ -326,7 +343,7 @@ def init_params(cfg, rng, dtype=F32) -> Dict[str, Any]:
             mlp["gate_bias"] = jax.random.normal(next(keys), (L, R),
                                                  dtype) * 0.01
         if cfg.num_shared_experts:
-            mlp["shared"] = two((L,), shared_width(cfg))
+            mlp["shared"] = two((L,), shared_width(cfg), WIDTH_TILE)
         params["layers"] = {"mlp": mlp}
     return params
 

@@ -27,8 +27,9 @@ these kernels collapse each layer to four launches:
 - :func:`fused_mlp`        — (gated) MLP → residual add, blocked over the
   FFN dim so VMEM holds one weight tile at a time
 - :func:`fused_moe_mlp`    — the same for a mixture of experts: every
-  expert's weights stream through VMEM once, all rows run against each, and
-  a dense [B, E] combine matrix (zero where not chosen) weighs the sum
+  expert's weights stream through VMEM once, all rows run against each (at
+  256 slots: the tiles of rows that decode), and a dense [B, E] combine
+  matrix (zero where not chosen) weighs the sum
 
 Each op keeps a pure-jnp reference (the CPU path and the parity target); the
 Pallas kernels run in interpret mode on CPU for tests, matching the dispatch
@@ -107,29 +108,50 @@ def _vmem_capacity_bytes() -> int:
         return _VMEM_BYTES_V5E
 
 
+# a dividing tile this share over ``_TILE_BYTES`` is still taken where it
+# cuts an expert into fewer tiles (fewer step boundaries, about a microsecond
+# of idle HBM each), under a limit of the call's own: 15 lane tiles of two
+# [2,688, .] matrices go as 3 tiles of 640 columns (6.9 MB, 9% over) and not
+# as 5 of 384 (PERF.md, Findings, PR 67)
+_TILE_OVER = 1 / 10
+
+
+def _moe_step_bytes(rows: int, d: int, cols: int, matrices: int,
+                    itemsize: int, row_itemsize: int) -> int:
+    """What a grid step of :func:`fused_moe_mlp` holds in VMEM with blocks of
+    ``cols`` FFN columns: the expert's blocks twice (the one in use and the
+    one on its way), ``h``, ``r`` and the output twice, the float32
+    accumulator, the step's float32 up / gate / activation and down
+    products, the combine column's lane tile, and what ``_VMEM_STEP_BYTES``
+    leaves the compiler (1.25 MB)."""
+    return (2 * matrices * d * cols * itemsize
+            + rows * d * (3 * 2 * row_itemsize + 4)
+            + 4 * rows * (matrices * cols + d) + 2 * 4 * rows * 128
+            + 16 * 2**20 - _VMEM_STEP_BYTES)
+
+
 def moe_expert_block(rows: int, d: int, f: int, *, matrices: int = 3,
                      itemsize: int = 2, row_itemsize: int = 2):
     """(FFN columns a grid step of :func:`fused_moe_mlp` takes, the VMEM
     limit its call sets | None) for ``rows`` of ``d`` against experts of
     ``matrices`` [d, f] / [f, d] arrays.  The whole expert (``f``) where
     ``_col_block`` gives it anyway (small ops: the compiler's own limit
-    holds them) or where everything a step holds fits
-    ``_WHOLE_EXPERT_VMEM_SHARE`` of the chip's VMEM: the expert's matrices
-    twice (the block in use and the one on its way), ``h``, ``r`` and the
-    output twice, the float32 accumulator, the step's float32 up / gate /
-    activation and down products, the combine column's lane tile, and what
-    ``_VMEM_STEP_BYTES`` leaves the compiler (1.25 MB) — that sum, rounded
-    up to a MiB, is then the call's limit.  ``_col_block``'s tile, under the
-    compiler's scoped limit, elsewhere."""
+    holds them) or where everything a step holds (:func:`_moe_step_bytes`)
+    fits ``_WHOLE_EXPERT_VMEM_SHARE`` of the chip's VMEM: that sum, rounded
+    up to a MiB, is then the call's limit.  Elsewhere ``_col_block``'s tile
+    under the compiler's scoped limit, unless the next dividing tile up is
+    within ``_TILE_OVER`` of the tile budget: then that one, under a limit
+    of the call's own as well."""
+    step = functools.partial(_moe_step_bytes, rows, d, matrices=matrices,
+                             itemsize=itemsize, row_itemsize=row_itemsize)
     tile = _col_block(d * matrices, f, itemsize)
     if tile == f:
         return f, None
-    need = (2 * matrices * d * f * itemsize
-            + rows * d * (3 * 2 * row_itemsize + 4)
-            + 4 * rows * (matrices * f + d) + 2 * 4 * rows * 128
-            + 16 * 2**20 - _VMEM_STEP_BYTES)
-    if need <= _WHOLE_EXPERT_VMEM_SHARE * _vmem_capacity_bytes():
-        return f, round_up(need, 2**20)
+    if step(f) <= _WHOLE_EXPERT_VMEM_SHARE * _vmem_capacity_bytes():
+        return f, round_up(step(f), 2**20)
+    wider = next(b for b in range(tile + 128, f + 1, 128) if f % b == 0)
+    if matrices * d * wider * itemsize <= (1 + _TILE_OVER) * _TILE_BYTES:
+        return wider, round_up(step(wider), 2**20)
     return tile, None
 
 
@@ -1898,27 +1920,70 @@ def _moe_mlp_ref(h, r, combine, w_up, w_gate, w_down, *, act):
     return (r.astype(jnp.float32) + y).astype(h.dtype)
 
 
-def _moe_mlp_kernel(h_ref, r_ref, c_ref, wu_ref, *rest, act, glu, ne, nf):
+# fused_moe_mlp's row cut.  A pass of the MXU over a weight tile costs the
+# tile's load whatever rows stream behind it, up to the array's height: 128
+# rows is the tile whose pass is never dearer than the rows it works (two
+# passes of 64 cost what one of 128 does; ``grouped_matmul.ROW_TILE``)
+_MOE_ROW_TILE = 128
+# a v5e's MXU FLOP/s over its HBM bytes/s (197e12 / 819e9): a dense matmul
+# of r rows spends 2 r FLOP a weight element, so at bf16 its MXU time passes
+# its weights' stream time from r = 240 rows on
+_FLOPS_PER_HBM_BYTE = 240
+
+
+def moe_row_tile(rows: int, itemsize: int = 2) -> Optional[int]:
+    """Rows a MXU pass of :func:`fused_moe_mlp` works where the call cuts
+    its passes to the live rows' tiles, None where it keeps ONE pass over all
+    ``rows``: the cut is taken where the static shapes say that the dense
+    call's MXU time passes its weights' stream time (256 rows at bf16: yes;
+    128 and fewer: no) and the rows are whole tiles."""
+    if 2 * rows <= _FLOPS_PER_HBM_BYTE * itemsize or rows % _MOE_ROW_TILE:
+        return None
+    return _MOE_ROW_TILE
+
+
+def moe_row_tiles_worked(live, tile: int):
+    """Row tiles the cut call works: ``ceil(live rows / tile)`` (int32)."""
+    return (jnp.sum(live, dtype=jnp.int32) + tile - 1) // tile
+
+
+def _moe_mlp_kernel(*refs, act, glu, ne, nf, row_tile=None):
     """One grid step = one expert x one FFN tile (the whole expert where
-    ``nf`` is 1): all rows against the tile,
-    the down-projection weighed by this expert's combine column and added
-    into the float32 accumulator (which starts at the residual).  ``rest``:
-    the gate's tile where the experts have one, the down tile, the output
-    and the accumulator."""
-    wg_ref, (wd_ref, o_ref, acc_scr) = (rest[0] if glu else None), rest[-3:]
+    ``nf`` is 1): the rows against the tile, the down-projection weighed by
+    this expert's combine column and added into the float32 accumulator
+    (which starts at the residual).  ``refs``: under ``row_tile`` the
+    scalar-prefetched (row tiles to work, the layer's first expert) first;
+    the rows, the residual, the combine column, the up tile, the gate's
+    where the experts have one, the down tile, the output and the
+    accumulator.  Under ``row_tile`` the rows come live-first and only the
+    first ``tiles`` tiles of them meet the weights: the others keep their
+    residual."""
+    if row_tile:
+        tiles, refs = refs[0][0], refs[1:]
+    h_ref, r_ref, c_ref, wu_ref = refs[:4]
+    wg_ref, (wd_ref, o_ref, acc_scr) = (refs[4] if glu else None), refs[-3:]
     e, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((e == 0) & (j == 0))
     def _init():
         acc_scr[:] = r_ref[:].astype(jnp.float32)
 
-    h = h_ref[:]
     dot = functools.partial(jax.lax.dot_general,
                             dimension_numbers=(((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    up = dot(h, wu_ref[:])
-    a = _act(act, dot(h, wg_ref[:])) * up if glu else _act(act, up)
-    acc_scr[:] += c_ref[:] * dot(a.astype(h.dtype), wd_ref[:])
+
+    def work(at):
+        h = h_ref[at]
+        up = dot(h, wu_ref[:])
+        a = _act(act, dot(h, wg_ref[:])) * up if glu else _act(act, up)
+        acc_scr[at] += c_ref[at] * dot(a.astype(h.dtype), wd_ref[:])
+
+    if row_tile:
+        for t in range(h_ref.shape[0] // row_tile):
+            pl.when(t < tiles)(functools.partial(
+                work, pl.ds(t * row_tile, row_tile)))
+    else:
+        work(slice(None))
 
     @pl.when((e == ne - 1) & (j == nf - 1))
     def _finish():
@@ -1927,7 +1992,7 @@ def _moe_mlp_kernel(h_ref, r_ref, c_ref, wu_ref, *rest, act, glu, ne, nf):
 
 def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
                   layer: Optional[int] = None, act: str = "silu",
-                  impl: Optional[str] = None):
+                  live=None, impl: Optional[str] = None):
     """h: [B, D] (normed); r: [B, D] (residual); ``combine`` [B, E] float32,
     each row's router weight per expert and 0 where the expert was not
     chosen.  Expert weights [E, D, F] / [E, F, D] — or, with ``layer=l``,
@@ -1944,10 +2009,17 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
     VMEM limit); a step boundary costs the chip about a microsecond of idle
     HBM, which is why.  Up to ~128 rows the call is bound by those weight
     bytes (every expert is hit by some row almost every step), not by the
-    E/k times more FLOPs than the rows asked for; at 256 rows of 2,688
-    against 64 two-matrix experts stored 2,048 wide the MXU's time at peak
-    (361 GFLOP = 1.83 ms) passes the bytes' (1.72 ms) and the call is
-    FLOP-bound (PERF.md, Findings, PR 65)."""
+    E/k times more FLOPs than the rows asked for; from ~240 rows on the
+    MXU's time for ALL rows passes the bytes' (256 rows of 2,688 against 64
+    two-matrix experts 1,920 wide: 339 GFLOP = 1.72 ms at peak, 1.61 ms of
+    bytes).  ``live`` [B] bool, the rows that decode (None: every row):
+    where :func:`moe_row_tile` says the shapes are such, the rows go
+    through the kernel live-first (:func:`_live_rows`) and the MXU passes
+    run over the ``ceil(live / tile)`` row tiles that hold a live row, the
+    count scalar-prefetched and read at run time; a row that does not
+    decode then gets ``r`` back (elsewhere it gets its mixture like any
+    row: the callers read live rows only).  Every held expert's blocks
+    still pass through VMEM once a call (PERF.md, Findings, PRs 65, 67)."""
     impl = resolve_impl(impl)
     glu = w_gate is not None
     if impl == "xla":
@@ -1957,30 +2029,78 @@ def fused_moe_mlp(h, r, combine, w_up, w_down, w_gate=None, *,
                             act=act)
     B, D = h.shape
     E, _, F = w_up.shape[-3:]
+    item = w_up.dtype.itemsize
     bf, vmem_limit = moe_expert_block(
-        B, D, F, matrices=3 if glu else 2, itemsize=w_up.dtype.itemsize,
+        B, D, F, matrices=3 if glu else 2, itemsize=item,
         row_itemsize=h.dtype.itemsize)
+    weights = (w_up.reshape(-1, D, F),
+               *([w_gate.reshape(-1, D, F)] if glu else []),
+               w_down.reshape(-1, F, D))
     base = 0 if layer is None else layer * E
-    kernel = functools.partial(_moe_mlp_kernel, act=act, glu=glu, ne=E,
-                               nf=F // bf)
-    rows = pl.BlockSpec((B, D), lambda e, j: (0, 0))
-    cols = pl.BlockSpec((None, D, bf), lambda e, j: (base + e, 0, j))
-    return pl.pallas_call(
-        kernel,
+    row_tile = None if live is None else moe_row_tile(B, item)
+    if row_tile:
+        return _moe_mlp_live_rows(
+            h, r, combine, live, jnp.asarray(base, jnp.int32), *weights,
+            act=act, bf=bf, vmem_limit=vmem_limit, row_tile=row_tile,
+            impl=impl)
+    return _moe_mlp_call(h, r, combine, weights, lambda e: base + e, act=act,
+                         bf=bf, vmem_limit=vmem_limit, impl=impl)
+
+
+def _moe_mlp_call(h, r, combine, weights, expert, *prefetch, act, bf,
+                  vmem_limit, impl, row_tile=None):
+    """The ``pallas_call`` of :func:`fused_moe_mlp` over ``weights`` [L * E,
+    D, F] (up, the gate where the experts have one) and [L * E, F, D]:
+    ``expert(e, *scalar refs)`` is grid expert ``e``'s place among them,
+    ``prefetch`` the scalar-prefetched operands (the row cut's)."""
+    B, D = h.shape
+    E, F, glu = combine.shape[1], weights[0].shape[-1], len(weights) == 3
+    rows = pl.BlockSpec((B, D), lambda e, j, *s: (0, 0))
+    cols = pl.BlockSpec((None, D, bf),
+                        lambda e, j, *s: (expert(e, *s), 0, j))
+    grid = dict(
         grid=(E, F // bf),
         # (experts of two matrices stream two: no tile stands in for a gate)
         in_specs=[rows, rows,
-                  pl.BlockSpec((None, B, 1), lambda e, j: (e, 0, 0)),
+                  pl.BlockSpec((None, B, 1), lambda e, j, *s: (e, 0, 0)),
                   cols, *([cols] if glu else []),
-                  pl.BlockSpec((None, bf, D), lambda e, j: (base + e, j, 0))],
+                  pl.BlockSpec((None, bf, D),
+                               lambda e, j, *s: (expert(e, *s), j, 0))],
         out_specs=rows,
+        scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)])
+    if prefetch:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch), **grid))
+    return pl.pallas_call(
+        functools.partial(_moe_mlp_kernel, act=act, glu=glu, ne=E,
+                          nf=F // bf, row_tile=row_tile),
+        **grid,
         out_shape=jax.ShapeDtypeStruct((B, D), h.dtype),
-        scratch_shapes=[pltpu.VMEM((B, D), jnp.float32)],
         compiler_params=vmem_limit and pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit),
         interpret=interpret_flag(impl),
         name="fused_moe_mlp",
-    )(h, r, combine.astype(jnp.float32).T[:, :, None],
-      w_up.reshape(-1, D, F),
-      *([w_gate.reshape(-1, D, F)] if glu else []),
-      w_down.reshape(-1, F, D))
+    )(*prefetch, h, r, combine.astype(jnp.float32).T[:, :, None], *weights)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "act", "bf", "vmem_limit", "row_tile", "impl"))
+def _moe_mlp_live_rows(h, r, combine, live, base, *weights, act, bf,
+                       vmem_limit, row_tile, impl):
+    """:func:`fused_moe_mlp`'s call with the MXU passes cut to the live
+    rows' tiles, jitted with the layer's first expert ``base`` an operand: a
+    program's expert layers are traced and lowered ONCE
+    (``grouped_matmul._visit_groups``' lesson).  The rows are gathered
+    live-first in front of the kernel and put back behind it."""
+    B = h.shape[0]
+    order, _ = _live_rows(live, B)
+    back = jnp.zeros((B,), jnp.int32).at[order].set(
+        jnp.arange(B, dtype=jnp.int32), unique_indices=True)
+    # (a worked tile's rows that do not decode weigh nothing either)
+    combine = jnp.where(live[:, None], combine, 0.0)
+    out = _moe_mlp_call(
+        h[order], r[order], combine[order], weights,
+        lambda e, s: s[1] + e,
+        jnp.stack([moe_row_tiles_worked(live, row_tile), base]),
+        act=act, bf=bf, vmem_limit=vmem_limit, impl=impl, row_tile=row_tile)
+    return out[back]

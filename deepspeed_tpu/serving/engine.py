@@ -136,6 +136,14 @@ SERVE_MOE_COUNTERS = {
         "under a group-limited router (moe_n_group > 1: models/afmoe.py), "
         "over assignments / num_experts_per_tok it is how often the limit "
         "leaves this chip's experts in reach of a row",
+    "ds_serve_moe_row_tiles_total":
+        "(expert layer, decode step) pairs x the row tiles the block's rows "
+        "are to fused_moe_mlp (ops/pallas/decode.py:moe_row_tile; one tile "
+        "where the kernel runs all rows in one pass)",
+    "ds_serve_moe_row_tiles_worked_total":
+        "of ds_serve_moe_row_tiles_total, the tiles the kernel's MXU passes "
+        "worked: those that hold a live row where it cuts its passes to "
+        "them, all of them elsewhere",
 }
 
 
@@ -336,6 +344,9 @@ class ServingEngine:
         self._block_np = {}      # idx -> (toks np, valid np | None)
         self._block_refs = {}    # idx -> pending consumers (refs + drains)
         self._block_moe = {}     # idx -> device routing counts (registry on)
+        # rows a pass of the block's fused_moe_mlp works where it cuts its
+        # passes to the live rows' tiles (set with the block program)
+        self._moe_row_tile = None
         self._outstanding = deque()   # [(idx, [eos Request, ...])]
         # first tokens owed in this iteration: (Request, device scalar),
         # copy to the host started, value not read yet.  Settled before
@@ -2133,6 +2144,9 @@ class ServingEngine:
                 moe = self._block_moe.pop(idx, None)
                 if moe is not None:
                     moe = [np.asarray(a) for a in moe]  # dslint: disable=DSL002 -- rides the block's own deferred fetch, registry on only
+                    if self.module.config.is_moe:
+                        self._count_row_tiles(
+                            int(moe.pop()) if self._moe_row_tile else None)
                     self._count_moe(*self.kind.count_block(moe))
             entry = self._block_np[idx] = (toks, valid)
         return entry
@@ -2156,6 +2170,18 @@ class ServingEngine:
             cfg.num_experts * cfg.num_expert_layers * self._K)
         m["ds_serve_moe_max_load_total"].inc(int(max_load))
         m["ds_serve_moe_group_kept_total"].inc(int(group_kept))
+
+    def _count_row_tiles(self, worked: Optional[int]) -> None:
+        """One decode block's row tiles: ``worked``, those the program
+        counted over its steps where ``fused_moe_mlp`` cuts its passes to
+        the live rows' tiles (one call's; every expert layer's call works
+        the same), None where a call is one pass over one tile."""
+        tiles = self._K * (self.num_slots // self._moe_row_tile
+                           if self._moe_row_tile else 1)
+        layers = self.module.config.num_expert_layers
+        self._m_moe["ds_serve_moe_row_tiles_total"].inc(layers * tiles)
+        self._m_moe["ds_serve_moe_row_tiles_worked_total"].inc(
+            layers * (tiles if worked is None else worked))
 
     def _unref(self, idx: int) -> None:
         self._block_refs[idx] -= 1
@@ -2306,12 +2332,23 @@ class ServingEngine:
             from deepspeed_tpu.models.fused_decode import moe_counts_zero
             moe0 = moe_counts_zero(cfg)
         if moe0 is not None and cfg.is_moe:
-            from deepspeed_tpu.ops.pallas.decode import moe_expert_block
+            from deepspeed_tpu.ops.pallas.decode import (moe_expert_block,
+                                                         moe_row_tile,
+                                                         moe_row_tiles_worked)
             ex = self.engine._dparams["experts"]
             (D, F), item = ex["w_up"].shape[-2:], ex["w_up"].dtype.itemsize
             self._m_moe_block.set(100.0 / F * moe_expert_block(
                 self.num_slots, D, F, matrices=len(ex), itemsize=item,
                 row_itemsize=item)[0])
+            # the kernel's row cut is static a program too: where it is
+            # taken (by the kernel: its jnp reference runs every row), the
+            # tiles a step's call works ride behind the routing counts
+            from deepspeed_tpu.ops.pallas.common import default_impl
+            if default_impl() != "xla":
+                self._moe_row_tile = moe_row_tile(self.num_slots, item)
+            if self._moe_row_tile:
+                moe0 = moe0 + (jnp.zeros((), jnp.int32),)
+        row_tile = self._moe_row_tile
 
         def body(params, cache, last, pos, active, limit, eos, rng,
                  page_table):
@@ -2321,6 +2358,8 @@ class ServingEngine:
                 rng, srng = jax.random.split(rng)
                 logits, cache, routed = step_fn(params, last[:, None], cache,
                                                 pos, page_table, valid)
+                if row_tile:
+                    routed = routed + (moe_row_tiles_worked(valid, row_tile),)
                 moe = jax.tree.map(jnp.add, moe, routed)
                 logits = next_token_logits(self.module.config, logits)
                 nxt = sample_token(logits, srng, temperature=temperature,

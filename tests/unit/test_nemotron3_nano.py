@@ -450,15 +450,55 @@ def test_relu2_experts_through_the_two_mlp_kernels(impl):
 def test_the_experts_are_stored_padded_with_zeros():
     cfg = ModelConfig(**FIELDS)
     p = ssm_moe.init_params(cfg, jax.random.PRNGKey(0))["layers"]["mlp"]
-    assert p["w_up"].shape == (2, 4, 64, 512) \
-        and p["w_down"].shape == (2, 4, 512, 64)
+    # the routed experts to the lane tile, the shared one to 512
+    assert p["w_up"].shape == (2, 4, 64, 128) \
+        and p["w_down"].shape == (2, 4, 128, 64)
     assert p["shared"]["w_up"].shape == (2, 64, 512)
     assert not np.asarray(p["w_up"][..., 48:]).any() \
         and not np.asarray(p["w_down"][..., 48:, :]).any() \
         and np.asarray(p["w_up"][..., :48]).all()
     assert not np.asarray(p["shared"]["w_up"][..., 96:]).any()
-    assert ssm_moe.padded_width(1856) == 2048 \
-        and ssm_moe.padded_width(3712) == 4096
+
+
+@pytest.mark.parametrize("width,tile,stored", [
+    (1856, ssm_moe.LANE_TILE, 1920),      # the routed experts: 15 lane tiles
+    (3712, ssm_moe.WIDTH_TILE, 4096),     # the shared expert: 29 is a prime
+    (1920, ssm_moe.LANE_TILE, 1920), (1024, ssm_moe.LANE_TILE, 1024)])
+def test_the_stored_widths_of_the_published_model(width, tile, stored):
+    assert ssm_moe.padded_width(width, tile) == stored
+    assert ssm_moe.padded_width(width) == ssm_moe.padded_width(
+        width, ssm_moe.LANE_TILE)
+
+
+@pytest.mark.parametrize("n_live", [0, 50, 128, 200])
+def test_the_expert_block_at_256_rows_works_the_live_rows_tiles(n_live):
+    """``afmoe.fused_experts`` (shared expert, router, this chip's share of
+    the routed experts) on 256 bf16 rows, where ``fused_moe_mlp`` cuts its
+    passes to the live rows' tiles: the live rows as the XLA forms give
+    them, and the routing counts the same."""
+    cfg = ModelConfig(**dict(FIELDS, hidden_size=128, intermediate_size=200,
+                             shared_intermediate_size=128))
+    params = jax.tree.map(
+        lambda a: a.astype(jnp.bfloat16),
+        with_noise(ssm_moe.init_params(cfg, jax.random.PRNGKey(9))))
+    assert params["layers"]["mlp"]["w_up"].shape[-1] == 256
+    dparams = ssm_moe.inject(cfg, params)
+    lp = next(lp for lp in dparams["layers"] if "gate_w" in lp)
+    B = 256
+    assert decode.moe_row_tile(B, 2) == 128
+    k = jax.random.split(jax.random.PRNGKey(n_live), 2)
+    h, x = (jax.random.normal(k[i], (B, 128), jnp.bfloat16) for i in (0, 1))
+    live = np.zeros(B, bool)
+    live[np.random.RandomState(n_live).permutation(B)[:n_live]] = True
+    block = lambda impl: afmoe.fused_experts(
+        cfg, dparams, lp, 1, h, x, afmoe.moe_counts_zero(cfg),
+        jnp.asarray(live), impl)
+    (y1, s1), (y2, s2) = block("interpret"), block("xla")
+    np.testing.assert_allclose(np.asarray(y1, np.float32)[live],
+                               np.asarray(y2, np.float32)[live],
+                               rtol=3e-2, atol=3e-2)
+    for a, b in zip(s1, s2):
+        np.testing.assert_array_equal(a, b)
 
 
 @pytest.mark.parametrize("live", [[True, False, True], [True, True, True]],
@@ -593,6 +633,10 @@ def test_counters_count_state_steps_resets_and_scanned_rows(model):
         assert snap["ds_serve_state_bytes"] == 3 * ssm_moe.slot_state_bytes(
             model[0].config, jnp.float32)
         assert grew("ds_serve_moe_assignments_total") == 2 * 2 * 2 * 8
+        # three float32 slots: a call is one pass over one tile, worked
+        assert grew("ds_serve_moe_row_tiles_total") \
+            == grew("ds_serve_moe_row_tiles_worked_total") \
+            == grew("ds_serve_moe_expert_slots_total") / 4
         assert all(len(r.output_tokens) == 9 for r in reqs)
         serve.close()
     finally:

@@ -13,6 +13,7 @@ layers and a 64-wide head.  A bf16 expert matmul would miss it by 30x: bf16
 keeps 8 bits, 4e-3 a product.
 """
 
+import functools
 import importlib.util
 import os
 
@@ -236,6 +237,39 @@ def test_decode_block_counts_routing_of_live_rows(devices, interpret_kernels):
     assert value("assignments_total") / 8 <= value("max_load_total") <= 12 * 2
     # a tiny expert is one block of the kernel's: the gauge says so
     assert value("expert_block_share") == 100.0
+    # four float32 rows go through the kernel in one pass: a tile a call,
+    # worked (2 layers x 4 steps a block)
+    assert value("row_tiles_total") == value("row_tiles_worked_total") \
+        == blocks * 2 * 4
+
+
+def test_decode_block_counts_the_row_tiles_the_kernel_worked(
+        devices, interpret_kernels):
+    """256 bf16 slots: ``fused_moe_mlp`` cuts its MXU passes to the live
+    rows' tiles, and the block's program counts them: two tiles a call, of
+    which two requests' rows fill one while either decodes and none after."""
+    from deepspeed_tpu.monitor.metrics import MetricsRegistry
+
+    cfg = tiny(2)
+    mesh = build_mesh(devices=devices[:1])
+    model = CausalLM(cfg, mesh)
+    reg = MetricsRegistry().enable()
+    serve = deepspeed_tpu.init_serving(
+        model, params=seeded(model), mesh=mesh, registry=reg,
+        config={"dtype": "bfloat16", "num_slots": 256, "prefill_chunk": 64,
+                "decode_block_tokens": 4, "max_out_tokens": 256,
+                "kv_page_tokens": 128, "kv_pool_tokens": 1024})
+    serve.submit(tokens_of(12, 5), max_new_tokens=9)
+    serve.submit(tokens_of(9, 6), max_new_tokens=5)
+    serve.run()
+    serve.close()
+    value = lambda name: reg.get("ds_serve_moe_" + name).value
+    blocks = value("expert_slots_total") / (8 * 2 * 4)
+    assert value("row_tiles_total") == blocks * 2 * 4 * 2
+    # a step with a live row works one tile in each of the two layers: the
+    # longer request's 8 steps at least, the two's 12 apart at most
+    assert 2 * 8 <= value("row_tiles_worked_total") <= 2 * 12
+    assert value("assignments_total") == (8 + 4) * 2 * 2
 
 
 # -- (d): the kernel alone ------------------------------------------------
@@ -288,13 +322,108 @@ def test_fused_moe_mlp_against_jnp_on_stacked_weights(monkeypatch, layer,
                                atol=1e-5)
 
 
+# the tiles of 1,920 columns the rule may take (``_col_block``'s 384, the
+# 640 a tenth over its budget) and the expert whole, as (``_TILE_BYTES`` in
+# columns of the test's experts, the whole-expert share) -> columns a step
+WIDE = {"384": ((384, 0.0), 384), "640": ((600, 0.0), 640),
+        "whole": ((384, None), 1920)}
+
+
+def _live_case(B, D, F, E, glu, seed=0, dtype=jnp.bfloat16):
+    """Operands of a call at ``B`` rows, bf16 unless said (the cut reads the
+    weights' item size: float32 rows engage it only from 481 on)."""
+    k = jax.random.split(jax.random.PRNGKey(seed), 7)
+    h, r = (jax.random.normal(k[i], (B, D), dtype) for i in (0, 1))
+    wu, wg = ((0.1 * jax.random.normal(k[i], (2, E, D, F))).astype(dtype)
+              for i in (2, 3))
+    wd = (0.1 * jax.random.normal(k[4], (2, E, F, D))).astype(dtype)
+    combine = jax.random.uniform(k[5], (B, E)) * \
+        (jax.random.uniform(k[6], (B, E)) > 0.6)
+    return h, r, combine, wu, wd, (wg if glu else None)
+
+
+@pytest.mark.parametrize("glu", [True, False], ids=["gated", "two_matrices"])
+@pytest.mark.parametrize("n_live", [0, 1, 63, 64, 65, 128, 129, 256])
+def test_fused_moe_mlp_works_the_live_rows_tiles(n_live, glu):
+    """256 bf16 rows, ``n_live`` of them scattered: a live row gets what
+    ``_moe_mlp_ref`` gives it, to the bit of the call without a mask, and a
+    row that does not decode gets its residual back."""
+    from deepspeed_tpu.ops.pallas import decode
+
+    B, D, F, E = 256, 128, 256, 4
+    assert decode.moe_row_tile(B, 2) == 128
+    h, r, combine, wu, wd, wg = _live_case(B, D, F, E, glu, seed=n_live)
+    live = np.zeros(B, bool)
+    live[np.random.RandomState(n_live).permutation(B)[:n_live]] = True
+    assert int(decode.moe_row_tiles_worked(jnp.asarray(live), 128)) \
+        == -(-n_live // 128)
+    call = functools.partial(fused_moe_mlp, h, r, combine, wu, wd, wg,
+                             layer=1, act="relu2", impl="interpret")
+    got = np.asarray(call(live=jnp.asarray(live)), np.float32)
+    every = np.asarray(call(), np.float32)
+    np.testing.assert_array_equal(got[live], every[live])
+    np.testing.assert_array_equal(got[~live], np.asarray(r, np.float32)[~live])
+    ref_ = np.asarray(decode._moe_mlp_ref(
+        h, r, combine, wu[1], None if wg is None else wg[1], wd[1],
+        act="relu2"), np.float32)
+    np.testing.assert_allclose(got[live], ref_[live], rtol=2e-2, atol=2e-2)
+
+
+@pytest.mark.parametrize("rows,dtype", [(5, "float32"), (128, "bfloat16"),
+                                        (256, "float32"), (192, "bfloat16")])
+def test_fused_moe_mlp_keeps_one_pass_where_the_cut_does_not_pay(rows, dtype):
+    """Under ~240 rows of bf16 (481 of float32), or rows that are no whole
+    tiles, ``live`` changes nothing: the call is the one without it, bit for
+    bit, the rows that do not decode included."""
+    from deepspeed_tpu.ops.pallas import decode
+
+    dt = jnp.dtype(dtype)
+    assert decode.moe_row_tile(rows, dt.itemsize) is None
+    ops = _live_case(rows, 64, 128, 4, True, dtype=dt)
+    live = jnp.arange(rows) % 3 == 0
+    call = functools.partial(fused_moe_mlp, *ops, layer=0, act="silu",
+                             impl="interpret")
+    np.testing.assert_array_equal(np.asarray(call(live=live), np.float32),
+                                  np.asarray(call(), np.float32))
+
+
+@pytest.mark.parametrize("live", [None, 100], ids=["every_row", "100_live"])
+@pytest.mark.parametrize("block", sorted(WIDE))
+def test_fused_moe_mlp_at_15_lane_tiles(monkeypatch, block, live):
+    """Two-matrix experts 1,920 columns wide, as Nemotron stores them, at
+    each block the rule may take there: five tiles of 384, three of 640 (a
+    tenth over the budget, under a limit of the call's own), the expert
+    whole."""
+    from deepspeed_tpu.ops.pallas import decode
+
+    B, D, F, E = 256, 128, 1920, 2
+    (tile, share), columns = WIDE[block]
+    monkeypatch.setattr(decode, "_TILE_BYTES", tile * D * 2 * 2)
+    if share is not None:
+        monkeypatch.setattr(decode, "_WHOLE_EXPERT_VMEM_SHARE", share)
+    bf, limit = decode.moe_expert_block(B, D, F, matrices=2)
+    assert (bf, limit is None) == (columns, block == "384")
+    h, r, combine, wu, wd, _ = _live_case(B, D, F, E, False)
+    mask = None if live is None else jnp.arange(B) % 256 < live
+    got = np.asarray(fused_moe_mlp(h, r, combine, wu, wd, None, layer=1,
+                                   act="relu2", live=mask,
+                                   impl="interpret"), np.float32)
+    want = np.asarray(decode._moe_mlp_ref(h, r, combine, wu[1], None, wd[1],
+                                          act="relu2"), np.float32)
+    if mask is not None:
+        want = np.where(np.asarray(mask)[:, None], want,
+                        np.asarray(r, np.float32))
+    np.testing.assert_allclose(got, want, rtol=2e-2, atol=2e-2)
+
+
 # the decode block's call of each expert cell (rows = the cell's num_slots,
 # hidden, stored expert width, matrices of an expert; bf16): whole expert
-# where the expert is small (ISSUE 65's table), the parent's tile elsewhere
+# where the expert is small (ISSUE 65's table), the parent's tile elsewhere,
+# but three tiles of 640 columns for Nemotron's 15 lane tiles (ISSUE 67)
 CELL_BLOCKS = {
     "olmoe-1b-7b-L8.serve-chat": ((64, 2048, 1024, 3), 1024),
     "kimi-linear-L5-ep8.serve-reason-doc-tail": ((128, 2304, 1024, 3), 1024),
-    "nemotron3-nano-L9-ep2.serve-reason-4k": ((256, 2688, 2048, 2), 512),
+    "nemotron3-nano-L9-ep2.serve-reason-4k": ((256, 2688, 1920, 2), 640),
     "solar-open2-L4-ep8.serve-reason-4k": ((128, 4096, 1280, 3), 256),
     "trinity-large-L5-ep8.serve-mixed-16k": ((32, 3072, 3072, 3), 256),
     "axk1-L5-ep16.serve-mixed-16k": ((32, 7168, 2048, 3), 128),
@@ -327,12 +456,36 @@ def test_the_block_rule_takes_small_experts_whole(cell):
                  padded_width(fields["intermediate_size"]))
     bf, limit = decode.moe_expert_block(rows, d, f, matrices=mats)
     assert bf == want
-    if bf == f:
-        assert 2 * mats * d * f * 2 <= limit <= decode._VMEM_BYTES_V5E \
+    tile = decode._col_block(d * mats, f, 2)
+    if bf != tile:
+        assert 2 * mats * d * bf * 2 <= limit <= decode._VMEM_BYTES_V5E \
             * decode._WHOLE_EXPERT_VMEM_SHARE
+        # a tile past the budget: by less than ``_TILE_OVER``, and the next
+        # dividing one up from ``_col_block``'s
+        assert bf == f or (mats * d * bf * 2 <= decode._TILE_BYTES
+                           * (1 + decode._TILE_OVER)
+                           and not [b for b in range(tile + 128, bf, 128)
+                                    if f % b == 0])
     else:
         assert limit is None
-        assert bf == decode._col_block(d * mats, f, 2)
+    # the row cut engages at 256 bf16 rows and nowhere else in the table
+    assert decode.moe_row_tile(rows) == (128 if rows == 256 else None)
+
+
+def _bench_tool():
+    spec = importlib.util.spec_from_file_location(
+        "_moe_decode_bench", os.path.join(REPO, "tools",
+                                          "moe_decode_bench.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    return tool
+
+
+BENCH_FIELDS = dict(vocab_size=VOCAB, hidden_size=128, intermediate_size=256,
+                    num_layers=2, num_heads=2, num_kv_heads=2, head_dim=64,
+                    max_seq_len=256, num_experts=4, num_experts_per_tok=2,
+                    moe_drop_tokens=False, moe_norm_topk_prob=False,
+                    qk_norm=True)
 
 
 @pytest.mark.parametrize("mode", ["alone", "in_layer"])
@@ -345,21 +498,12 @@ def test_the_kernels_bench_tool_rehearses_on_the_cpu(tmp_path, mode):
 
     from deepspeed_tpu.ops.pallas import decode
 
-    spec = importlib.util.spec_from_file_location(
-        "_moe_decode_bench", os.path.join(REPO, "tools",
-                                          "moe_decode_bench.py"))
-    tool = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(tool)
-    fields = dict(vocab_size=VOCAB, hidden_size=128, intermediate_size=256,
-                  num_layers=2, num_heads=2, num_kv_heads=2, head_dim=64,
-                  max_seq_len=256, num_experts=4, num_experts_per_tok=2,
-                  moe_drop_tokens=False, moe_norm_topk_prob=False,
-                  qk_norm=True)
+    tool = _bench_tool()
     before = decode._TILE_BYTES, decode._WHOLE_EXPERT_VMEM_SHARE
     out = tmp_path / "rows.jsonl"
     assert tool.main(
-        ["--allow-cpu", "--model-config", json.dumps(fields), "--rows", "8",
-         "--block", "rule,128", "--rounds", "1", "--steps", "2", "--out",
+        ["--allow-cpu", "--model-config", json.dumps(BENCH_FIELDS), "--rows",
+         "8", "--block", "rule,128", "--rounds", "1", "--steps", "2", "--out",
          str(out)] + (["--in-layer"] if mode == "in_layer" else [])) == 0
     rows = [json.loads(line) for line in out.read_text().splitlines()]
     assert [(r["mode"], r["block"], r["block_cols"]) for r in rows] == \
@@ -367,6 +511,34 @@ def test_the_kernels_bench_tool_rehearses_on_the_cpu(tmp_path, mode):
     assert all("error" not in r and r["matrices"] == 3 for r in rows)
     assert (decode._TILE_BYTES, decode._WHOLE_EXPERT_VMEM_SHARE) == before
     assert decode.pl.pallas_call.__name__ == "pallas_call"
+
+
+def test_the_kernels_bench_tool_takes_live_rows_and_a_stored_width(tmp_path):
+    """ISSUE 67's arguments: ``--width`` stores the experts that wide
+    (zeros past the model's own), ``--live-rows`` hands the kernel a mask
+    (256 bf16 rows: the cut engages) and ``--row-tile`` the rows of a pass;
+    a block past the module's budget gets a limit of its own; every patched
+    name is put back."""
+    import json
+
+    from deepspeed_tpu.ops.pallas import decode
+
+    tool = _bench_tool()
+    names = ("_TILE_BYTES", "_WHOLE_EXPERT_VMEM_SHARE", "_TILE_OVER",
+             "_MOE_ROW_TILE", "moe_expert_block")
+    before = [getattr(decode, n) for n in names]
+    out = tmp_path / "rows.jsonl"
+    assert tool.main(
+        ["--allow-cpu", "--model-config", json.dumps(BENCH_FIELDS), "--rows",
+         "256", "--block", "rule,128", "--live-rows", "all,64", "--width",
+         "384", "--row-tile", "64", "--rounds", "1", "--out", str(out)]) == 0
+    rows = [json.loads(line) for line in out.read_text().splitlines()]
+    assert [(r["live_rows"], r["block"], r["block_cols"]) for r in rows] == \
+        [("all", "rule", 384), ("64", "rule", 384), ("all", "128", 128),
+         ("64", "128", 128)]
+    assert all("error" not in r and (r["width"], r["row_tile"]) == (384, 64)
+               for r in rows)
+    assert [getattr(decode, n) for n in names] == before
 
 
 # -- (e): grouped path == capacity path where nothing drops ---------------

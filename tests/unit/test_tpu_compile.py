@@ -352,8 +352,9 @@ def test_kernels_compile_at_the_olmoe_serve_chat_shape(v5e, kernel):
 # the benchmark's nemotron3-nano-L9-ep2.serve-reason-4k cell: 256 slots of
 # hidden 2,688; 64 Mamba-2 heads of 64 over a state of 128 (a row's state of
 # one layer is 32 tiles [128, 128] float32); 64 held experts of TWO matrices
-# stored 2,048 wide, the shared one 4,096; no gate matrix anywhere
-NEMOTRON = dict(D=2688, slots=256, H=64, P=64, G=8, N=128, F=2048, Fs=4096,
+# stored 1,920 wide (15 lane tiles since PR 67: tiles of 640 columns under a
+# VMEM limit of the call's own), the shared one 4,096; no gate matrix anywhere
+NEMOTRON = dict(D=2688, slots=256, H=64, P=64, G=8, N=128, F=1920, Fs=4096,
                 E=64, L=4)
 
 

@@ -475,8 +475,8 @@ def test_nemotron3_nano_cell_programs_compile_at_the_cells_256_slots(
     block) and the decode block of the
     ``nemotron3-nano-L9-ep2.serve-reason-4k`` cell (the published widths: 64
     Mamba-2 heads of 64 over a state of 128, 32 query heads over 2 key-value
-    heads of 128, experts of 1,856 stored at 2,048, the shared one of 3,712 at
-    4,096; the pattern cut to one layer of each kind, which changes no shape;
+    heads of 128, experts of 1,856 stored at 1,920 (2,048 until PR 67), the
+    shared one of 3,712 at 4,096; the pattern cut to one layer of each kind, which changes no shape;
     the pool cut to 32 slots' worth (half of it is then more than the 34 MB
     of expert rows a 1,024-row chunk gathers) but all 256 SLOTS kept,
     ROADMAP's lesson of PR 59: 256 rows of 2,688 beside the weight tiles are
@@ -517,6 +517,88 @@ def test_nemotron3_nano_cell_programs_compile_at_the_cells_256_slots(
                  "fused_moe_mlp"):
         assert name in text, name
     assert "kda_decode_step" not in text
+
+
+# ISSUE 67: Nemotron's routed experts stored 1,920 wide (15 lane tiles)
+NEMOTRON_EXPERTS = dict(rows=256, D=2688, F=1920, E=64, L=4)
+
+
+def _nemotron_moe_mlp(live):
+    from deepspeed_tpu.ops.pallas.decode import fused_moe_mlp
+
+    w = NEMOTRON_EXPERTS
+    B, D, F, E, L = (w[k] for k in ("rows", "D", "F", "E", "L"))
+    fn = lambda h, r, c, wu, wd, mask: fused_moe_mlp(
+        h, r, c, wu, wd, None, layer=L - 1, act="relu2",
+        live=mask if live else None, impl="pallas")
+    return fn, [((B, D), BF16), ((B, D), BF16), ((B, E), F32),
+                ((L, E, D, F), BF16), ((L, E, F, D), BF16),
+                ((B,), jax.numpy.bool_)]
+
+
+def _nemotron_grouped(down):
+    from deepspeed_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    w = NEMOTRON_EXPERTS
+    K, N = (w["F"], w["D"]) if down else (w["D"], w["F"])
+    fn = lambda lhs, rhs, sizes: grouped_matmul(lhs, rhs, sizes,
+                                                layer=w["L"] - 1,
+                                                impl="pallas")
+    return fn, [((1024 * 6, K), BF16), ((w["L"], w["E"], K, N), BF16),
+                ((w["E"],), I32)]
+
+
+@pytest.mark.parametrize("kernel,name", [
+    (lambda: _nemotron_moe_mlp(True), "fused_moe_mlp"),
+    (lambda: _nemotron_moe_mlp(False), "fused_moe_mlp"),
+    (lambda: _nemotron_grouped(False), "moe_grouped_matmul"),
+    (lambda: _nemotron_grouped(True), "moe_grouped_matmul")],
+    ids=["fused_moe_mlp_live_rows", "fused_moe_mlp_every_row",
+         "grouped_matmul_up", "grouped_matmul_down"])
+def test_expert_kernels_compile_at_nemotrons_15_lane_tiles(v5e, kernel, name):
+    """256 rows of 2,688 against 64 two-matrix experts stored 1,920 wide:
+    ``fused_moe_mlp`` in tiles of 640 columns (two 6.9 MB blocks in flight:
+    past the compiler's scoped 16 MiB, under the limit the call sets), with
+    its MXU passes cut to the live rows' tiles and without; the chunk
+    programs' grouped matmuls at a 1,024-token bucket's 6,144 sorted rows,
+    up (640 columns a block) and down (896)."""
+    from deepspeed_tpu.ops.pallas import decode
+
+    w = NEMOTRON_EXPERTS
+    assert decode.moe_expert_block(w["rows"], w["D"], w["F"],
+                                   matrices=2) == (640, 29 * 2**20)
+    assert decode.moe_row_tile(w["rows"]) == 128
+    fn, shapes = kernel()
+    args = [jax.ShapeDtypeStruct(s, d, sharding=v5e) for s, d in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and name in calls[0]
+
+
+# (rows, hidden, stored width, matrices) of the six other expert cells'
+# decode-block call -> what ``moe_expert_block`` gave at the parent of ISSUE
+# 67 (PR 66): their programs did not change
+PARENT_BLOCKS = {
+    "olmoe-1b-7b-L8": ((64, 2048, 1024, 3), (1024, 29 * 2**20)),
+    "kimi-linear-L5-ep8": ((128, 2304, 1024, 3), (1024, 36 * 2**20)),
+    "solar-open2-L4-ep8": ((128, 4096, 1280, 3), (256, None)),
+    "trinity-large-L5-ep8": ((32, 3072, 3072, 3), (256, None)),
+    "axk1-L5-ep16": ((32, 7168, 2048, 3), (128, None)),
+    "dots3-note-L5-ep16": ((16, 5120, 1536, 3), (128, None)),
+}
+
+
+@pytest.mark.parametrize("config", sorted(PARENT_BLOCKS))
+def test_the_other_expert_cells_keep_the_parents_block(config):
+    """ISSUE 67 moved Nemotron's block alone: at the other cells' shapes the
+    rule returns the parent's (tile, limit), and no call of theirs has the
+    rows for the row cut (it starts at ~240 of bf16)."""
+    from deepspeed_tpu.ops.pallas import decode
+
+    (rows, d, f, mats), want = PARENT_BLOCKS[config]
+    assert decode.moe_expert_block(rows, d, f, matrices=mats) == want
+    assert decode.moe_row_tile(rows) is None
 
 
 def test_jamba2_cell_programs_compile_whole_with_every_weight_once(

@@ -38,7 +38,10 @@ a jit) read about twice the cell's per-call time and price nothing in a cell
     python3 tools/moe_grouped_bench.py --held-share 8 --stacked \
         --experts 32 --top-k 8 --hidden 2304 --width 1024 --tokens 1024
     python3 tools/moe_grouped_bench.py --held-share 2 --stacked --two-matrix \
-        --experts 64 --top-k 6 --hidden 2688 --width 2048 --tokens 1024
+        --experts 64 --top-k 6 --hidden 2688 --width 1920 --tokens 1024
+
+``--width`` is the STORED width of an expert (Nemotron's 1,856 published
+columns: 1,920 since PR 67, ``--width 2048`` re-reads what they cost before).
 """
 
 from __future__ import annotations
